@@ -1,0 +1,641 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py
+
+drives the two main paths once, through the entry points a user would call,
+at the full width of ``llama_7b()`` (hidden 4096, 32 x 128 heads, ffn 11008,
+vocab 32000, bf16) with the depth cut and random weights made from a seed:
+
+  kernels  every Pallas kernel on the default serving and training paths,
+           compiled by Mosaic and RUN against its jnp reference;
+  serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
+           answering cold, chunked, concurrent and streamed requests;
+  train    ``python -m paddle_tpu.distributed.launch`` taking a few
+           ``jit.TrainStep`` steps of ``LlamaForCausalLM``, 2 layers;
+  serve4 / train4   the same on four chips (``--tp 4``; ``fleet.init`` with
+           sharding_degree=2, mp_degree=2), when JAX reports four or more.
+
+This process imports no JAX. A chip belongs to one process at a time, so each
+phase is a child that owns the chip and exits before the next starts; every
+child keeps its compiled programs in one cache directory
+(``paddle_tpu.utils.compile_cache``). A child that exits non-zero, a failed
+check or a timeout ends the smoke at once with a non-zero exit code and no
+result line; nothing is caught and carried on from. If JAX's platform is not
+``tpu`` the first child exits at once. The last stdout line of a passing run
+is ``{"ok": true, "device": {...}}`` with the device as JAX reports it.
+
+``--rehearse-cpu`` is for debugging this script's own control flow in a
+sandbox: ``llama_tiny`` on the CPU backend with four virtual devices, Pallas
+in interpret mode. It is chosen by that argument and never by detection, and
+its result line names the platform ``cpu``.
+"""
+import argparse
+import json
+import os
+import random
+import signal
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+LOG_DIR = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+BUDGET_S = 1140             # the contract allows 1200 s, compilation included
+HTTP_TIMEOUT_S = 420        # one request may wait on several ~15 s compiles
+
+# (query heads, kv heads, head dim) the kernels are checked at
+FULL = dict(
+    geometries=[(32, 32, 128), (32, 8, 128)], flash_seq=2048,
+    preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
+    vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
+    train=dict(layers=2, batch=4, seq=2048, steps=4))
+REHEARSAL = dict(
+    geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
+    preset="tiny", slots=4, max_seq_len=128, prefill_chunk=32,
+    vocab=256, medium_prompt=24, long_prompt=70,
+    tp=2,                                   # llama_tiny has two kv heads
+    train=dict(layers=2, batch=4, seq=64, steps=4))
+
+# Forward outputs: kernel and reference both take bf16 inputs (8 significant
+# bits, eps 2^-8 = 3.9e-3) and accumulate in f32, but round the softmax
+# weights to bf16 at different points (the kernel before normalising, the
+# reference after) and round the output to bf16: a few half-ulps, 2^-9 each,
+# that partly average out over the contraction. So the largest deviation is
+# bounded at 2e-2 of the largest reference magnitude.
+TOL_FWD = 2e-2
+# Gradients go through two more bf16 roundings (dS, and P again in the
+# backward kernels) and a recomputed softmax: twice the forward bound.
+TOL_BWD = 4e-2
+# Step-0 loss, four chips against one: the same bf16 forward with every
+# hidden/ffn contraction split over mp=2 (other summation order of bf16
+# partial products); the loss is an f32 mean over batch*seq tokens near
+# ln(vocab) ~ 10.4, so 2e-2 absolute is about 0.2 percent.
+TOL_LOSS_4CHIP = 2e-2
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(cond, what):
+    if not cond:
+        raise SmokeFailure(what)
+
+
+# ===================================================================== parent
+def _spawn(cmd, env, log_name):
+    """Start a child in its own process group; stdout is a pipe, stderr
+    goes to a log under chiprun_out/ so it survives the machine. Every
+    caller reaps it in a ``finally``."""
+    os.makedirs(LOG_DIR, exist_ok=True)
+    with open(os.path.join(LOG_DIR, log_name + ".err"), "w") as err:
+        return subprocess.Popen(cmd, env=env, cwd=ROOT, stderr=err,
+                                stdout=subprocess.PIPE, text=True,
+                                start_new_session=True)
+
+
+def _reap(p, grace_s=20):
+    """Stop a child's whole process group and wait for it."""
+    if p.poll() is None:
+        try:
+            os.killpg(p.pid, signal.SIGTERM)
+        except ProcessLookupError:
+            pass
+        try:
+            p.wait(grace_s)
+        except subprocess.TimeoutExpired:
+            os.killpg(p.pid, signal.SIGKILL)
+            p.wait()
+    else:
+        try:        # the leader is gone; take any straggler of its group
+            os.killpg(p.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+
+
+def _err_tail(log_name, n=2500):
+    try:
+        with open(os.path.join(LOG_DIR, log_name + ".err")) as f:
+            return f.read()[-n:]
+    except OSError:
+        return ""
+
+
+def _child_env(rehearse):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONUNBUFFERED"] = "1"
+    if rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + " --xla_force_host_platform_device_count=4")
+    return env
+
+
+def run_phase_child(name, cmd, env, deadline):
+    """Run one child to its end; its last stdout line is its JSON report."""
+    t0 = time.monotonic()
+    p = _spawn(cmd, env, name)
+    try:
+        try:
+            out, _ = p.communicate(timeout=max(deadline - t0, 1))
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"{name}: timed out\n{_err_tail(name)}")
+        check(p.returncode == 0,
+              f"{name}: child exited {p.returncode}\n{_err_tail(name)}")
+    finally:
+        _reap(p)
+    lines = [ln for ln in out.splitlines() if ln.startswith("{")]
+    check(lines, f"{name}: child printed no report")
+    report = json.loads(lines[-1])
+    report["wall_s"] = round(time.monotonic() - t0, 1)
+    return report
+
+
+def _get(url, timeout=60):
+    with urllib.request.urlopen(url, timeout=timeout) as r:
+        return r.read().decode()
+
+
+def _complete(base, body):
+    """POST /v1/completions; returns the generated token ids (blocking or
+    streamed)."""
+    req = urllib.request.Request(
+        base + "/v1/completions", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=HTTP_TIMEOUT_S) as r:
+        check(r.status == 200, f"completions answered {r.status}")
+        if body.get("stream"):
+            toks, done = [], False
+            for raw in r:
+                line = raw.decode().strip()
+                if not line.startswith("data: "):
+                    continue
+                data = line[len("data: "):]
+                if data == "[DONE]":
+                    done = True
+                    break
+                ch = json.loads(data)["choices"][0]
+                check(ch["finish_reason"] != "error",
+                      f"stream ended in error: {data}")
+                if ch["token_id"] is not None:
+                    toks.append(ch["token_id"])
+            check(done, "stream ended without [DONE]")
+        else:
+            ch = json.loads(r.read())["choices"][0]
+            check(ch["finish_reason"] == "length",
+                  f"finish_reason {ch['finish_reason']!r}")
+            toks = ch["token_ids"]
+    return toks
+
+
+def _metric_samples(text, name):
+    """{label-string: value} of one family in a Prometheus text body."""
+    out = {}
+    for ln in text.splitlines():
+        if ln.startswith(name) and ln[len(name):len(name) + 1] in (" ", "{"):
+            labels, _, val = ln[len(name):].rpartition(" ")
+            out[labels] = float(val)
+    return out
+
+
+def _drive_requests(name, base, size):
+    """The smoke's traffic: every admission path once. Returns the token
+    ids and the wall seconds of each request, by name."""
+    rng = random.Random(0)
+
+    def prompt(n):
+        return [rng.randrange(1, size["vocab"]) for _ in range(n)]
+
+    tokens, request_s = {}, {}
+
+    def ask(key, body):
+        t = time.monotonic()
+        tokens[key] = _complete(base, body)
+        request_s[key] = round(time.monotonic() - t, 2)
+
+    # cold path: a short prompt, prefilled whole; asked twice, greedy
+    short = {"prompt": prompt(12), "max_tokens": 16}
+    ask("short", short)
+    # cold path again, at the first prefill bucket (512 at full size)
+    # long enough for flash_attention.attention to take the kernel
+    ask("medium", {"prompt": prompt(size["medium_prompt"]), "max_tokens": 4})
+    # longer than --prefill-chunk: chunked through the unified step
+    ask("long", {"prompt": prompt(size["long_prompt"]), "max_tokens": 8})
+    # two at once: one batch, two live slots
+    threads = [threading.Thread(target=ask, args=(key, {
+        "prompt": prompt(n), "max_tokens": 12}))
+        for key, n in (("a", 20), ("b", 33))]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(HTTP_TIMEOUT_S + 30)
+    check("a" in tokens and "b" in tokens, f"{name}: a concurrent request "
+          f"failed or hung: got {sorted(tokens)}\n" + _err_tail(name))
+    ask("streamed", {"prompt": prompt(9), "max_tokens": 10, "stream": True})
+    ask("sampled", {"prompt": prompt(16), "max_tokens": 16,
+                    "temperature": 1.0, "seed": 7})
+    ask("short_again", short)
+
+    asked = {"short": 16, "medium": 4, "long": 8, "a": 12, "b": 12,
+             "streamed": 10, "sampled": 16, "short_again": 16}
+    for key, n in asked.items():
+        toks = tokens[key]
+        check(len(toks) == n,
+              f"{name}: {key} returned {len(toks)} tokens, asked {n}")
+        check(all(isinstance(x, int) and 0 <= x < size["vocab"]
+                  for x in toks), f"{name}: {key} token out of range")
+    check(tokens["short_again"] == tokens["short"],
+          f"{name}: same greedy request, other tokens: {tokens}")
+    every = [x for toks in tokens.values() for x in toks]
+    check(len(set(every)) > 1 and len(set(tokens["sampled"])) > 1,
+          f"{name}: all tokens equal: {tokens}")
+    return tokens, request_s
+
+
+def serve_phase(name, size, env, deadline, rehearse, tp=1):
+    """The server as a child, asked over HTTP with urllib only."""
+    t0 = time.monotonic()
+    cmd = [sys.executable, "-u", "-m", "paddle_tpu.serving.server",
+           "--preset", size["preset"], "--port", "0", "--quiet",
+           "--num-slots", str(size["slots"]),
+           "--max-seq-len", str(size["max_seq_len"]),
+           "--prefill-chunk", str(size["prefill_chunk"]), "--tp", str(tp)]
+    p = _spawn(cmd, env, name)
+    try:
+        banner = {}
+
+        def read_banner():
+            line = p.stdout.readline()
+            if line.startswith("{"):
+                banner.update(json.loads(line))
+
+        t = threading.Thread(target=read_banner, daemon=True)
+        t.start()
+        t.join(max(deadline - time.monotonic(), 1))
+        check(banner, f"{name}: no banner (server exit code {p.poll()})\n"
+              + _err_tail(name))
+        want = "cpu" if rehearse else "tpu"
+        check(banner["device"]["platform"] == want
+              and banner["decode_attention"] == "pallas"
+              and banner["pallas_interpret"] is rehearse
+              and banner["tp"] == tp,
+              f"{name}: wrong path in effect: {banner}")
+        base = banner["listening"]
+        tokens, request_s = _drive_requests(name, base, size)
+
+        # a Mosaic or out-of-memory error inside engine.step() is caught by
+        # the gateway, which rebuilds the engine up to eight times: without
+        # these two checks it would look like a slow success
+        health = json.loads(_get(base + "/healthz"))
+        metrics = _get(base + "/metrics")
+        faults = _metric_samples(metrics, "serving_faults_total")
+        check(health["status"] == "ok" and health["engine_restarts"] == 0
+              and sum(faults.values()) == 0,
+              f"{name}: engine faulted: {health} {faults}\n"
+              + _err_tail(name))
+        mem = json.loads(_get(base + "/debug/profile?memory=1",
+                              timeout=HTTP_TIMEOUT_S))["memory"]
+        step = [v for k, v in mem.items() if k.startswith("ragged[")]
+        check(len(step) == 1 and "error" not in step[0],
+              f"{name}: expected one unified step program: {mem}")
+        check(all("pallas" in k for k in mem if k.startswith("ragged[")),
+              f"{name}: step program is not the pallas one: {list(mem)}")
+
+        def per_device(family):     # {device="3"} 123 -> {"3": 123}
+            return {labels.split('"')[1]: int(v) for labels, v
+                    in _metric_samples(metrics, family).items()}
+
+        peak = per_device("serving_device_peak_bytes_in_use")
+        in_use = per_device("serving_device_bytes_in_use")
+        if not rehearse:    # the CPU backend reports no memory statistics
+            check(len(in_use) >= tp and
+                  sum(v > 0 for v in in_use.values()) >= tp,
+                  f"{name}: memory in use on fewer than {tp} devices: "
+                  f"{in_use}")
+        if tp > 1:
+            coll = _metric_samples(metrics, "serving_collective_bytes_total")
+            check(sum(coll.values()) > 0,
+                  f"{name}: no collective bytes counted at tp={tp}: {coll}")
+
+        def one(fam):
+            return next(iter(_metric_samples(metrics, fam).values()))
+
+        report = {
+            "phase": name, "tp": tp, "device": banner["device"],
+            "decode_attention": banner["decode_attention"],
+            "compile_cache": banner["compile_cache"],
+            "compile_s": round(one("serving_compile_seconds_total"), 1),
+            "cache_hits": int(one("serving_compile_cache_hits_total")),
+            "cache_misses": int(one("serving_compile_cache_misses_total")),
+            "step_program": step[0], "programs": sorted(mem),
+            "peak_bytes_in_use": peak,
+            "engine_restarts": health["engine_restarts"],
+            "request_s": request_s, "tokens": tokens}
+
+        os.killpg(p.pid, signal.SIGTERM)    # the server drains and exits 0
+        try:
+            rc = p.wait(90)
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"{name}: server did not stop on SIGTERM")
+        check(rc == 0, f"{name}: server exited {rc}\n{_err_tail(name)}")
+    finally:
+        _reap(p)
+    report["wall_s"] = round(time.monotonic() - t0, 1)
+    return report
+
+
+def parent(rehearse):
+    t_start = time.monotonic()
+    deadline = t_start + BUDGET_S
+    size = REHEARSAL if rehearse else FULL
+    env = _child_env(rehearse)
+    me = os.path.abspath(__file__)
+    flag = ["--rehearse-cpu"] if rehearse else []
+    launch = [sys.executable, "-m", "paddle_tpu.distributed.launch",
+              "--log_dir", os.path.join(LOG_DIR, "launch")]
+    reports = []
+
+    def done(report):
+        print(json.dumps(report), flush=True)
+        reports.append(report)
+        return report
+
+    kernels = done(run_phase_child(
+        "kernels", [sys.executable, me, "--phase", "kernels"] + flag, env,
+        deadline))
+    device = kernels["device"]
+    check(device["platform"] == ("cpu" if rehearse else "tpu"),
+          f"platform is {device['platform']!r}")
+    done(serve_phase("serve", size, env, deadline, rehearse))
+    train = done(run_phase_child(
+        "train", launch + [me, "--phase", "train"] + flag, env, deadline))
+    if device["count"] >= 4:
+        done(serve_phase("serve4", size, env, deadline, rehearse,
+                         tp=size["tp"]))
+        done(run_phase_child(
+            "train4", launch + [me, "--phase", "train4", "--ref-loss",
+                                repr(train["losses"][0])] + flag,
+            env, deadline))
+    for r in reports:
+        check(r["device"] == device, f"{r['phase']}: ran on {r['device']}, "
+              f"the smoke on {device}")
+    print(json.dumps({
+        "phases": [r["phase"] for r in reports],
+        "wall_s": round(time.monotonic() - t_start, 1),
+        "compile_s": round(sum(r["compile_s"] for r in reports), 1),
+        "cache_hits": sum(r["cache_hits"] for r in reports),
+        "cache_misses": sum(r["cache_misses"] for r in reports),
+        "versions": kernels["versions"]}), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+# =================================================================== children
+def _child_start(rehearse):
+    """Every child: fail at once off the chip, place the compile cache, and
+    name the device as JAX reports it."""
+    import jax
+    dev = jax.devices()[0]
+    if not rehearse and dev.platform != "tpu":
+        print(f"chip_smoke: JAX platform is {dev.platform!r}, not 'tpu'",
+              file=sys.stderr)
+        sys.exit(3)
+    from paddle_tpu.core.device import device_summary
+    from paddle_tpu.utils import compile_cache
+    return compile_cache.enable(), device_summary()
+
+
+def _child_report(phase, stats, device, **fields):
+    import jax
+    peaks = {}
+    for d in jax.local_devices():
+        ms = d.memory_stats()
+        if ms is not None:      # the CPU backend reports none
+            peaks[str(d.id)] = int(ms["peak_bytes_in_use"])
+    snap = stats.snapshot()
+    print(json.dumps({
+        "phase": phase, "device": device,
+        "compile_cache": snap["cache_dir"],
+        "compile_s": round(snap["compile_seconds"], 1),
+        "cache_hits": snap["cache_hits"],
+        "cache_misses": snap["cache_misses"],
+        "peak_bytes_in_use": peaks, **fields}), flush=True)
+
+
+def _agree(name, got, want, tol, results):
+    """Largest deviation over the largest reference magnitude <= tol."""
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    check(got.shape == want.shape, f"{name}: shape {got.shape}")
+    check(np.isfinite(got).all(), f"{name}: kernel output is not finite")
+    err = float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-6))
+    results[name] = round(err, 5)
+    check(err <= tol, f"{name}: deviates {err:.4f} from its reference, "
+          f"tolerance {tol}")
+
+
+def phase_kernels(rehearse):
+    stats, device = _child_start(rehearse)
+    import jax
+    import jax.numpy as jnp
+    import jaxlib
+    import numpy as np
+
+    from paddle_tpu.kernels.flash_attention import _ref_attention
+    from paddle_tpu.kernels.pallas_flash import flash_attention_pallas
+    from paddle_tpu.kernels.pallas_paged_decode import (
+        paged_decode_attention_pallas, paged_decode_attention_reference)
+    from paddle_tpu.kernels.pallas_ragged_attention import (
+        ragged_attention_reference, ragged_paged_attention_pallas)
+
+    size = REHEARSAL if rehearse else FULL
+    bf16 = jnp.bfloat16
+    errors = {}
+
+    def reference(fn, *args):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(fn)(*args)
+
+    for nh, nkv, hd in size["geometries"]:
+        tag = f"{nh}/{nkv}/{hd}"
+        rng = np.random.RandomState(nh * 131 + nkv)
+
+        def normal(*shape):
+            return rng.randn(*shape).astype(np.float32)
+
+        # ---- a small pool, poisoned wherever no live row may read -------
+        nb, bs, mb = 24, 32, 4
+        # rows: (query span, kv length after this step). Span-1 rows are
+        # decode rows whose lengths end at a block start, mid-block and at a
+        # block end; one span-n chunk resumes at 37 and ends mid-block at
+        # 77; one row is dead.
+        rows = [(1, 1), (1, 37), (1, 64), (1, 100), (40, 77), (0, 0)]
+        R = len(rows)
+        perm = rng.permutation(nb)
+        tables = np.full((R, mb), nb, np.int32)     # nb = unmapped sentinel
+        pk, pv = normal(nb, bs, nkv, hd), normal(nb, bs, nkv, hd)
+        live = np.zeros((nb, bs), bool)
+        used = 0
+        for r, (_, kvlen) in enumerate(rows):
+            for b in range(-(-kvlen // bs)):
+                tables[r, b] = perm[used]
+                live[perm[used], :min(bs, kvlen - b * bs)] = True
+                used += 1
+        pk[~live] = np.nan      # stale rows and unmapped blocks: a kernel
+        pv[~live] = np.nan      # that reads one of them returns NaN
+        qlen = np.array([q for q, _ in rows], np.int32)
+        kvlen = np.array([k for _, k in rows], np.int32)
+        qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+        T = int(qlen.sum()) + 12                    # 12 rows in no span
+        q = jnp.asarray(normal(T, nh, hd), bf16)
+        pool_k, pool_v = jnp.asarray(pk, bf16), jnp.asarray(pv, bf16)
+        args = (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(qstart),
+                jnp.asarray(qlen), jnp.asarray(kvlen))
+        got = jax.jit(ragged_paged_attention_pallas)(*args)
+        _agree(f"ragged {tag}", got, reference(ragged_attention_reference,
+                                               *args), TOL_FWD, errors)
+        check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
+              f"ragged {tag}: rows outside every span are not exact zeros")
+
+        # ---- single-token decode through the same tables -----------------
+        lengths = np.maximum(kvlen, 0)
+        dargs = (jnp.asarray(normal(R, nh, hd), bf16), pool_k, pool_v,
+                 jnp.asarray(tables), jnp.asarray(lengths))
+        _agree(f"paged_decode {tag}",
+               jax.jit(paged_decode_attention_pallas)(*dargs),
+               reference(paged_decode_attention_reference, *dargs),
+               TOL_FWD, errors)
+
+        # ---- flash attention, forward and backward -----------------------
+        S = size["flash_seq"]
+        fq = jnp.asarray(normal(1, S, nh, hd), bf16)
+        fk = jnp.asarray(normal(1, S, nkv, hd), bf16)
+        fv = jnp.asarray(normal(1, S, nkv, hd), bf16)
+        fw = jnp.asarray(normal(1, S, nh, hd), bf16)     # d(loss)/d(out)
+
+        def both(attn):
+            def loss(q_, k_, v_, w_):
+                o = attn(q_, k_, v_, True)
+                return jnp.sum((o * w_).astype(jnp.float32)), o
+            return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+        (_, o), grads = jax.jit(both(flash_attention_pallas))(fq, fk, fv, fw)
+        (_, o_ref), grads_ref = reference(both(_ref_attention),
+                                          fq, fk, fv, fw)
+        _agree(f"flash fwd {tag}", o, o_ref, TOL_FWD, errors)
+        for g, g_ref, n in zip(grads, grads_ref, ("dq", "dk", "dv")):
+            _agree(f"flash {n} {tag}", g, g_ref, TOL_BWD, errors)
+
+    import importlib.metadata as md
+    _child_report(
+        "kernels", stats, device, max_error=errors,
+        tolerance={"forward": TOL_FWD, "backward": TOL_BWD},
+        versions={"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                  "libtpu": md.version("libtpu"),
+                  "python": sys.version.split()[0]})
+    return 0
+
+
+def phase_train(rehearse, four_chips, ref_loss):
+    stats, device = _child_start(rehearse)
+    import jax
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models.llama import (LlamaForCausalLM, llama_7b,
+                                         llama_tiny)
+    from paddle_tpu.optimizer import AdamW
+
+    size = (REHEARSAL if rehearse else FULL)["train"]
+    make = llama_tiny if rehearse else llama_7b
+    cfg = make(num_hidden_layers=size["layers"], dtype="bfloat16",
+               loss_chunk=size["seq"] // 4,
+               max_position_embeddings=size["seq"])
+    mesh, stage = None, 0
+    if four_chips:
+        from paddle_tpu.distributed import fleet
+        strategy = fleet.DistributedStrategy()
+        strategy.hybrid_configs = {"sharding_degree": 2, "mp_degree": 2}
+        fleet.init(is_collective=True, strategy=strategy)
+        mesh, stage = fleet.get_hybrid_communicate_group().mesh, 2
+        check(dict(mesh.shape)["sharding"] == 2
+              and dict(mesh.shape)["mp"] == 2, f"mesh is {dict(mesh.shape)}")
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    opt = AdamW(learning_rate=3e-4, parameters=model.parameters())
+    step = TrainStep(model, lambda loss, _lab: loss, opt, mesh=mesh,
+                     sharding_stage=stage)
+    ids = paddle.to_tensor(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (size["batch"], size["seq"])).astype(np.int32))
+
+    t0 = time.perf_counter()
+    compiled = step.compile_step((ids, ids), (ids,))
+    aot_s = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    mosaic_calls = compiled.as_text().count("tpu_custom_call")
+    if not rehearse:
+        # otherwise the jnp branch of flash_attention.attention ran
+        check(mosaic_calls > 0, "train step holds no Mosaic custom call")
+
+    losses, step_s = [], []
+    for _ in range(size["steps"]):      # one fixed batch: the loss must fall
+        t0 = time.perf_counter()
+        loss = step.step((ids, ids), (ids,))
+        loss.value.block_until_ready()
+        step_s.append(round(time.perf_counter() - t0, 3))
+        losses.append(float(loss.value))
+    check(all(np.isfinite(losses)), f"loss not finite: {losses}")
+    check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    if ref_loss is not None:
+        check(abs(losses[0] - ref_loss) <= TOL_LOSS_4CHIP,
+              f"step-0 loss {losses[0]} on four chips, {ref_loss} on one: "
+              f"apart by more than {TOL_LOSS_4CHIP}")
+    if four_chips and not rehearse:
+        # code that has only met a virtual CPU mesh may put all on chip 0
+        in_use = {d.id: d.memory_stats()["bytes_in_use"]
+                  for d in jax.local_devices()}
+        check(len(in_use) >= 4 and all(v > 0 for v in in_use.values()),
+              f"memory in use per device: {in_use}")
+    _child_report(
+        "train4" if four_chips else "train", stats, device,
+        mesh=None if mesh is None else dict(mesh.shape),
+        losses=[round(x, 5) for x in losses], ref_loss=ref_loss,
+        aot_compile_s=round(aot_s, 1), step_s=step_s,
+        mosaic_custom_calls=mosaic_calls,
+        step_program={"argument_bytes": int(mem.argument_size_in_bytes),
+                      "output_bytes": int(mem.output_size_in_bytes),
+                      "alias_bytes": int(mem.alias_size_in_bytes),
+                      "temp_bytes": int(mem.temp_size_in_bytes)})
+    return 0
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="debug this script on the CPU backend at a tiny "
+                         "size (never a chip result)")
+    ap.add_argument("--phase", choices=("kernels", "train", "train4"),
+                    help="internal: run one phase in this process")
+    ap.add_argument("--ref-loss", type=float, default=None,
+                    help="internal: the one-chip step-0 loss for train4")
+    args = ap.parse_args()
+    if args.phase == "kernels":
+        return phase_kernels(args.rehearse_cpu)
+    if args.phase in ("train", "train4"):
+        return phase_train(args.rehearse_cpu, args.phase == "train4",
+                           args.ref_loss)
+    try:
+        return parent(args.rehearse_cpu)
+    except SmokeFailure as e:
+        print(f"chip_smoke FAILED: {e}", file=sys.stderr)
+        return 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

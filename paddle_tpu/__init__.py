@@ -11,12 +11,6 @@ fused-op hot paths and ``jax.sharding`` meshes for every parallelism axis.
 """
 from __future__ import annotations
 
-# jax version compat (shard_map promotion, abstract-mesh accessor):
-# installed before anything touches the parallel stack
-from .core import jaxcompat as _jaxcompat
-
-_jaxcompat.install()
-
 # core
 from .core import dtype as _dtype_mod
 from .core.dtype import (bfloat16, bool_, complex64, complex128, float16,

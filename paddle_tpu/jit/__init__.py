@@ -392,16 +392,22 @@ class TrainStep:
                                    labels, key)
         return Tensor(loss)
 
-    def lower_text(self, inputs, labels) -> str:
-        """Lowered (post-SPMD-able) HLO of the train step — for compile-only
-        tests asserting collective placement (SURVEY.md §4 pattern 3)."""
+    def compile_step(self, inputs, labels):
+        """The train step compiled ahead of time for these batch shapes,
+        without running it: ``.as_text()`` is the optimized HLO,
+        ``.memory_analysis()`` the compiler's argument/temp byte counts."""
         lr = jnp.zeros((), jnp.float32)
         key = jax.random.PRNGKey(0)
         inputs, labels = _norm_batch(inputs), _norm_labels(labels)
         inputs, labels = self._place_batch(inputs), self._place_batch(labels)
         return self._compiled.lower(self._params, self._buffers,
                                     self._opt_state, inputs, labels, lr,
-                                    key).compile().as_text()
+                                    key).compile()
+
+    def lower_text(self, inputs, labels) -> str:
+        """Compiled HLO of the train step — for compile-only tests
+        asserting collective placement (SURVEY.md §4 pattern 3)."""
+        return self.compile_step(inputs, labels).as_text()
 
     def sync_to_model(self):
         """Write the device-side params/buffers back into the Layer tree

@@ -175,7 +175,7 @@ def _attention_bhsd(q, k, v, nh, rope=None, block_q=0, block_k=0):
         rep = Hq // Hk
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    from ..kernels.flash_attention import _use_pallas
+    from ..kernels.flash_attention import _use_pallas, shard_over_mesh
     if _use_pallas(S) and S % 128 == 0 and D % 8 == 0:
         from ..kernels.pallas_flash import flash_attention_bhsd
         kw = {}
@@ -183,11 +183,16 @@ def _attention_bhsd(q, k, v, nh, rope=None, block_q=0, block_k=0):
             kw["block_q"] = block_q
         if block_k:
             kw["block_k"] = block_k
-        o = flash_attention_bhsd(q.reshape(B * Hq, S, D),
-                                 k.reshape(B * Hq, S, D),
-                                 v.reshape(B * Hq, S, D), causal=True,
-                                 rope=rope, **kw)
-        return o.reshape(B, Hq, S, D)
+
+        def flash(q, k, v, *rope):      # local [b, h, S, D] shards
+            b, h = q.shape[:2]
+            o = flash_attention_bhsd(q.reshape(b * h, S, D),
+                                     k.reshape(b * h, S, D),
+                                     v.reshape(b * h, S, D), causal=True,
+                                     rope=rope or None, **kw)
+            return o.reshape(b, h, S, D)
+
+        return shard_over_mesh(flash, q, k, v, *(rope or ()), head_axis=1)
     if rope is not None:  # fallback path rotates explicitly
         sin, cos = rope
         q = _apply_rope_bhsd(q, sin, cos)

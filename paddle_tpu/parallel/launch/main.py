@@ -13,8 +13,9 @@ job, so there is no per-device Pod/Container spawn. The launcher:
 3. execs the training script (optionally respawning on failure — elastic
    restart loop; preemption-aware resume comes from checkpoints).
 
-Single-host multi-process simulation (tests): ``--procs K`` forks K local
-processes against a CPU device mesh.
+Single-host multi-process simulation (CPU tests only): ``--procs K`` forks
+K local processes against the CPU backend. On a TPU host K stays 1: a chip
+belongs to one process at a time.
 """
 from __future__ import annotations
 
@@ -38,8 +39,12 @@ def build_parser():
                    help="number of hosts, or min:max for elastic")
     p.add_argument("--rank", type=int,
                    default=int(os.environ.get("PADDLE_TRAINER_ID", "0")))
+    # A chip belongs to one process at a time and one process drives every
+    # chip of its host, so on a TPU host this stays 1. Values above 1 exist
+    # for the CPU tests only: K local processes against the CPU backend.
     p.add_argument("--nproc_per_node", "--procs", dest="procs", type=int,
-                   default=1, help="local processes (testing only; TPU = 1)")
+                   default=1, help="local processes (CPU tests only; on a "
+                                   "TPU host one process owns the chips)")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--run_mode", default="collective")
     p.add_argument("--job_id", default="default")

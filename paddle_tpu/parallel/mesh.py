@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 _STATE: Dict[str, object] = {"mesh": None}
 
@@ -40,24 +40,11 @@ def build_mesh(axis_degrees: Dict[str, int], devices=None) -> Mesh:
         raise ValueError(
             f"mesh degrees {dict(zip(names, degrees))} product {total} != "
             f"device count {len(devices)}")
-    # Auto axis types = GSPMD propagation from annotations (jax>=0.9 defaults
-    # make_mesh to Explicit sharding-in-types, which type-checks eager dots —
+    # Auto axis types = GSPMD propagation from annotations (make_mesh
+    # defaults to Explicit sharding-in-types, which type-checks eager dots —
     # not what the paddle-shaped annotate-and-let-XLA-partition model wants).
-    # Older jax (< 0.5) predates AxisType entirely — everything is Auto
-    # there, so the plain Mesh constructor is the same semantics.
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        AxisType = None
-    if AxisType is not None:
-        auto = (AxisType.Auto,) * len(names)
-        try:
-            return jax.make_mesh(tuple(degrees), tuple(names),
-                                 devices=devices, axis_types=auto)
-        except TypeError:
-            pass
-    arr = np.asarray(devices).reshape(degrees)
-    return Mesh(arr, tuple(names))
+    return jax.make_mesh(tuple(degrees), tuple(names), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def set_mesh(mesh: Mesh):

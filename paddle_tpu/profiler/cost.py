@@ -53,6 +53,16 @@ PROGRAM_KINDS = ("prefill", "suffix", "psuffix", "decode", "pdecode",
                  "ragged", "mtick", "spec")
 
 
+def _abstract(leaf):
+    """Shape/dtype (and placement) of one argument leaf, detached from
+    its buffer."""
+    if isinstance(leaf, jax.Array):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=leaf.sharding)
+    leaf = np.asarray(leaf)
+    return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+
+
 def _nbytes(leaf) -> int:
     """Abstract byte size of one pytree leaf — shape × itemsize, no
     device sync (works on jax Arrays, numpy arrays and scalars).
@@ -231,6 +241,10 @@ class CostObservatory:
         # in-program launch structure of exactly the programs that ran
         # is what exports)
         self.censuses = {}
+        # label -> (jitted fn, abstract args), kept at the program's
+        # first dispatch so memory_analysis() can lower it again on
+        # demand without touching live (possibly donated) buffers
+        self._signatures = {}
 
     # ------------------------------------------------------------- control
     def enable(self):
@@ -304,6 +318,38 @@ class CostObservatory:
             self.censuses[label] = jaxpr_census(fn, *args)
         except Exception:            # noqa: BLE001 — census is advisory
             self.censuses[label] = None
+
+    def record_signature(self, label, fn, args):
+        """Keep one program's jitted callable and abstract argument
+        shapes (idempotent per label; called by the counting facade
+        BEFORE the program's first dispatch, while donated arguments
+        are still alive)."""
+        if label not in self._signatures:
+            self._signatures[label] = (fn, jax.tree_util.tree_map(
+                _abstract, args))
+
+    def memory_analysis(self) -> dict:
+        """Compiled-memory footprint of every program that dispatched:
+        ``{label: {argument_bytes, output_bytes, alias_bytes,
+        temp_bytes}}`` from the compiler's own ``memory_analysis()``.
+        Computed ON DEMAND (``GET /debug/profile?memory=1``): each
+        program is lowered again from its abstract signature and
+        compiled — a load from the persistent compile cache where that
+        is on, a real compile otherwise — so serving pays nothing until
+        someone asks. A program the compiler refuses here reports its
+        error instead of failing the debug request."""
+        out = {}
+        for label, (fn, abstract) in list(self._signatures.items()):
+            try:
+                m = fn.lower(*abstract).compile().memory_analysis()
+                out[label] = {
+                    "argument_bytes": int(m.argument_size_in_bytes),
+                    "output_bytes": int(m.output_size_in_bytes),
+                    "alias_bytes": int(m.alias_size_in_bytes),
+                    "temp_bytes": int(m.temp_size_in_bytes)}
+            except Exception as e:   # noqa: BLE001 — debug surface
+                out[label] = {"error": f"{type(e).__name__}: {e}"[:300]}
+        return out
 
     def record_collective(self, dtype, ops, nbytes):
         """Account one sharded launch's cross-chip all-reduce traffic:
@@ -484,6 +530,8 @@ class _CountedProgram:
     def __call__(self, *args):
         co = self._co
         fn = self._fn
+        if self._label not in co._signatures:
+            co.record_signature(self._label, fn, args)
         t0 = co.clock()
         c0 = fn._cache_size()
         out = fn(*args)

@@ -19,7 +19,9 @@ import time
 
 import jax
 
-# bf16 peak FLOP/s per chip by TPU generation
+# bf16 peak FLOP/s per chip, keyed by a substring of ``device_kind``
+# (Google Cloud TPU documentation, per-generation system pages). A device
+# that is not listed is an error, never a default.
 PEAK_FLOPS = {
     "v4": 275e12,
     "v5e": 197e12,
@@ -37,7 +39,9 @@ def peak_flops_per_chip(device=None) -> float:
     for k, v in PEAK_FLOPS.items():
         if k in kind:
             return v
-    return 197e12  # conservative default
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind "
+        f"{device.device_kind!r}; add it to PEAK_FLOPS with its source")
 
 
 def transformer_flops_per_token(n_params, n_layers=0, hidden=0, seq_len=0,

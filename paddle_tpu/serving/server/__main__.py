@@ -4,10 +4,15 @@ Stands up a LLaMA-family model behind the async gateway and serves
 OpenAI-style completions over HTTP until SIGINT/SIGTERM, then drains
 gracefully (in-flight requests finish; new ones get 503).
 
-The ``tiny`` preset is the CPU-runnable smoke config; ``350m`` is the
-bench-sized model for real chips. Prompts are token-id arrays (the
-framework ships no tokenizer) — see README "Serving over HTTP" for
-curl examples.
+The ``tiny`` preset is the CPU-runnable config; ``350m`` and
+``llama7b-8of32`` (Llama-2-7B widths, depth cut to 8 of 32 layers) are
+sized for one TPU v5e chip. Weights are random, made from ``--seed``.
+Prompts are token-id arrays (the framework ships no tokenizer) — see
+README "Serving over HTTP" for curl examples.
+
+The first stdout line is one JSON banner reporting what actually runs:
+the device JAX placed the server on, the attention path in effect and
+the compile-cache directory, beside the engine's effective settings.
 """
 from __future__ import annotations
 
@@ -18,13 +23,23 @@ import sys
 import threading
 
 
+PRESETS = ("tiny", "350m", "llama7b-8of32")
+
+
 def build_model(preset, decode_attention, seed):
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
-                                         llama_tiny)
+                                         llama_7b, llama_tiny)
     paddle.seed(seed)
     if preset == "tiny":
         return LlamaForCausalLM(llama_tiny(decode_attention=decode_attention))
+    if preset == "llama7b-8of32":
+        # every width of llama_7b() (hidden 4096, 32 x 128 heads, ffn
+        # 11008, vocab 32000); only the depth is cut, 32 -> 8 layers, so
+        # the bf16 weights (3.5 GiB) leave one 16 GB chip room for cache
+        return LlamaForCausalLM(llama_7b(
+            num_hidden_layers=8, dtype="bfloat16",
+            decode_attention=decode_attention))
     if preset == "350m":
         return LlamaForCausalLM(LlamaConfig(
             vocab_size=32000, hidden_size=1024, intermediate_size=2816,
@@ -32,6 +47,49 @@ def build_model(preset, decode_attention, seed):
             num_key_value_heads=16, max_position_embeddings=2048,
             dtype="bfloat16", decode_attention=decode_attention))
     raise ValueError(f"unknown preset {preset!r}")
+
+
+def _runtime_doc(engine):
+    """Banner fields for what the process actually runs on: the device as
+    JAX reports it, the attention path in effect, the compile cache."""
+    from paddle_tpu.core.device import device_summary
+    from paddle_tpu.kernels.pallas_flash import _interpret_mode
+    from paddle_tpu.utils import compile_cache
+    return {"device": device_summary(),
+            "decode_attention": engine.config.decode_attention,
+            "pallas_interpret": _interpret_mode(),
+            "compile_cache": compile_cache.cache_dir()}
+
+
+def _process_registry(compile_stats):
+    """The /metrics registry, seeded with the process-level series the
+    engine does not own: compile accounting and per-device memory."""
+    import jax
+
+    from paddle_tpu.profiler.metrics import MetricsRegistry
+    r = MetricsRegistry()
+    r.counter("serving_compile_cache_hits_total",
+              "Programs loaded from the persistent compile cache."
+              ).set_fn(lambda: compile_stats.cache_hits)
+    r.counter("serving_compile_cache_misses_total",
+              "Programs compiled and written to the persistent compile "
+              "cache.").set_fn(lambda: compile_stats.cache_misses)
+    r.counter("serving_compile_seconds_total",
+              "Seconds spent in the backend compiler (or loading a "
+              "cached program).").set_fn(
+        lambda: compile_stats.compile_seconds)
+    in_use = r.gauge("serving_device_bytes_in_use",
+                     "Device memory in use, per local device.")
+    peak = r.gauge("serving_device_peak_bytes_in_use",
+                   "Peak device memory in use, per local device.")
+    for d in jax.local_devices():
+        if d.memory_stats() is None:    # the CPU backend reports none
+            continue
+        in_use.set_fn(lambda d=d: d.memory_stats()["bytes_in_use"],
+                      device=str(d.id))
+        peak.set_fn(lambda d=d: d.memory_stats()["peak_bytes_in_use"],
+                    device=str(d.id))
+    return r
 
 
 def main(argv=None):
@@ -42,10 +100,14 @@ def main(argv=None):
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000,
                     help="0 = ephemeral (printed at startup)")
-    ap.add_argument("--preset", choices=("tiny", "350m"), default="tiny")
+    ap.add_argument("--preset", choices=PRESETS, default="tiny",
+                    help="tiny: CPU-runnable; 350m; llama7b-8of32: "
+                         "Llama-2-7B widths with 8 of 32 layers, bf16")
     ap.add_argument("--decode-attention", choices=("pallas", "jnp"),
-                    default="jnp",
-                    help="ragged Pallas decode kernel or the jnp oracle")
+                    default="pallas",
+                    help="the Pallas attention kernels (compiled on a "
+                         "TPU, interpreted on the CPU backend), or the "
+                         "jnp oracle the tests compare against")
     ap.add_argument("--replicas", type=int, default=1,
                     help="engine fleet size (README 'Engine fleet'): "
                          ">1 fronts N shared-nothing engine replicas "
@@ -281,6 +343,8 @@ def main(argv=None):
     if len(slots) > 1 and len(slots) != args.replicas:
         ap.error(f"--num-slots names {len(slots)} values for "
                  f"--replicas {args.replicas}")
+    from paddle_tpu.utils import compile_cache
+    registry = _process_registry(compile_cache.enable())
     model = build_model(args.preset, args.decode_attention, args.seed)
     kv_dtype = None if args.kv_dtype == "pool" else args.kv_dtype
     if args.replicas > 1:
@@ -291,7 +355,7 @@ def main(argv=None):
             host=args.host, port=args.port, num_slots=num_slots,
             max_seq_len=args.max_seq_len, decode_chunk=args.decode_chunk,
             max_queue=args.max_queue, model_name=f"llama-{args.preset}",
-            prefix_cache=args.prefix_cache,
+            registry=registry, prefix_cache=args.prefix_cache,
             prefix_blocks=args.prefix_blocks,
             prefix_block_size=args.prefix_block_size,
             host_tier_bytes=args.host_tier_bytes,
@@ -316,6 +380,7 @@ def main(argv=None):
         fleet = server.fleet
         print(json.dumps({
             "listening": server.url, "preset": args.preset,
+            **_runtime_doc(fleet.replicas[0].gateway.engine),
             "replicas": len(fleet.replicas),
             "router": fleet.router.name,
             "num_slots": [r.gateway.engine.num_slots
@@ -372,6 +437,7 @@ def main(argv=None):
         model, host=args.host, port=args.port, num_slots=slots[0],
         max_seq_len=args.max_seq_len, decode_chunk=args.decode_chunk,
         max_queue=args.max_queue, model_name=f"llama-{args.preset}",
+        registry=registry,
         prefix_cache=args.prefix_cache, prefix_blocks=args.prefix_blocks,
         prefix_block_size=args.prefix_block_size,
         host_tier_bytes=args.host_tier_bytes,
@@ -394,6 +460,7 @@ def main(argv=None):
         log_fn=None if args.quiet else
         (lambda m: print(m, file=sys.stderr)))
     print(json.dumps({"listening": server.url, "preset": args.preset,
+                      **_runtime_doc(server.gateway.engine),
                       "num_slots": slots[0],
                       "prefix_cache": bool(args.prefix_cache),
                       "paged_attn": bool(args.paged_attn),

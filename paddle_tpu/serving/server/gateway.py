@@ -78,6 +78,7 @@ from ...profiler.metrics import (QUEUE_WAIT_BUCKETS, SPEC_ACCEPT_BUCKETS,
                                  STEP_BUCKETS, TPOT_BUCKETS, TTFT_BUCKETS,
                                  MetricsRegistry)
 from ...profiler.tracing import TID_GATEWAY, SpanTracer
+from ...utils.log import get_logger
 from ..faults import TransientFault
 
 #: engine ``stats`` counters whose /metrics series must stay monotonic
@@ -1205,6 +1206,11 @@ class ServingGateway:
     def _on_fault(self, exc):
         kind = self._classify(exc)
         self._m_faults.inc(kind=kind)
+        # the supervisor keeps serving through a fault, so this is the one
+        # place the error's own words (a Mosaic rejection, an out-of-memory
+        # report) are written down before a rebuild hides them
+        get_logger("serving").error("engine step fault (%s)", kind,
+                                    exc_info=exc)
         tr = self._tr()
         if tr is not None:
             tr.instant(

@@ -32,7 +32,9 @@ Endpoints:
   compile events, wall EWMA / share of wall, per-decoded-token rates;
   README "Cost attribution & /debug/profile"). ``steps=N`` bounds the
   window to the next N engine steps like ``/debug/trace``; a
-  concurrent window gets 409.
+  concurrent window gets 409. ``memory=1`` adds each dispatched
+  program's compiled ``memory_analysis()`` (argument / output / alias /
+  temp bytes), computed on demand.
 
 Load shedding maps gateway signals onto status codes: full waiting
 room → 429 (with Retry-After), draining gateway → 503, validation →
@@ -180,6 +182,9 @@ class _Handler(BaseHTTPRequestHandler):
             except RuntimeError as e:   # cost observatory disabled
                 self._error(404, str(e), "unavailable")
                 return
+            if qs.get("memory", ["0"])[0] not in ("0", ""):
+                # on demand only: lowers every dispatched program again
+                doc["memory"] = self.gateway.cost.memory_analysis()
             self._send_json(200, doc)
         elif path == "/debug/requests":
             gw = self.gateway

@@ -67,7 +67,7 @@ class TestWindows:
         np.testing.assert_allclose(ours, ref, atol=1e-4)
 
 
-class TestFeatureLayers:
+class TestAudioFeatures:
     def _tone(self, freq=440.0, sr=8000, n=4000):
         t = np.arange(n) / sr
         return np.sin(2 * np.pi * freq * t).astype(np.float32)[None]

@@ -1,0 +1,217 @@
+"""What the sandbox can establish about the chip without one.
+
+libtpu is installed here, so ``jax.experimental.topologies`` hands out
+compile-only v5e devices under ``JAX_PLATFORMS=cpu`` and
+``.lower(...).compile()`` runs the real XLA:TPU and Mosaic compilers. The
+first class compiles every Pallas kernel on the default serving and training
+paths for that topology; ``chip_smoke.py`` is what also RUNS them. The rest
+pins the rules that keep a chip run from passing without the chip: no
+interpret mode, jnp path or default peak on an unknown backend, and one
+compile cache placed from outside.
+"""
+import functools
+import os
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from paddle_tpu.kernels import (flash_attention, pallas_flash,
+                                pallas_paged_decode, pallas_ragged_attention)
+from paddle_tpu.parallel import mesh as mesh_mod
+from paddle_tpu.profiler.metrics import peak_flops_per_chip
+from paddle_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# (query heads, kv heads, head dim): llama_7b() MHA and the GQA variant
+GEOMETRIES = [(32, 32, 128), (32, 8, 128)]
+
+
+@functools.lru_cache(maxsize=None)
+def _v5e_devices():
+    """The four compile-only devices of a v5e 2x2 host, or the reason there
+    are none (no libtpu, one without this topology, or another process
+    holding libtpu's lock file)."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            topology_name="v5e:2x2", platform="tpu").devices, ""
+    except Exception as e:
+        return None, f"{type(e).__name__}: {e}"
+
+
+@pytest.fixture
+def v5e_devices(monkeypatch):
+    """Compile-only v5e devices, with the kernels told to compile (the
+    default backend here is the CPU, where they would interpret)."""
+    devices, why = _v5e_devices()
+    if devices is None:
+        pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
+    for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention):
+        monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
+    return devices
+
+
+@pytest.fixture
+def v5e(v5e_devices):
+    """Abstract-array factory placed on one compile-only v5e device."""
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+def _mosaic_calls(fn, *args):
+    # the kernels' dots carry no precision of their own, and Mosaic rejects
+    # bf16 operands under the "highest" that conftest.py sets for the fp32
+    # oracles ("Bad lhs type"): compile at the precision the chip runs at
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("nh,nkv,hd", GEOMETRIES)
+class TestMosaicCompilesDefaultPathKernels:
+    NB, BS, R, MB = 64, 32, 8, 8        # pool blocks, block size, rows
+
+    def test_ragged_paged_attention(self, v5e, nh, nkv, hd):
+        pool = v5e((self.NB, self.BS, nkv, hd))
+        row = v5e((self.R,), jnp.int32)
+        n = _mosaic_calls(
+            pallas_ragged_attention.ragged_paged_attention_pallas,
+            v5e((72, nh, hd)), pool, pool,
+            v5e((self.R, self.MB), jnp.int32), row, row, row)
+        assert n == 1
+
+    def test_paged_decode_attention(self, v5e, nh, nkv, hd):
+        pool = v5e((self.NB, self.BS, nkv, hd))
+        n = _mosaic_calls(
+            pallas_paged_decode.paged_decode_attention_pallas,
+            v5e((self.R, nh, hd)), pool, pool,
+            v5e((self.R, self.MB), jnp.int32), v5e((self.R,), jnp.int32))
+        assert n == 1
+
+    def test_flash_forward_and_backward(self, v5e, nh, nkv, hd):
+        def loss(q, k, v):
+            o = pallas_flash.flash_attention_pallas(q, k, v, causal=True)
+            return jnp.sum(o.astype(jnp.float32))
+        q, kv = v5e((1, 1024, nh, hd)), v5e((1, 1024, nkv, hd))
+        n = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        assert n == 3                    # forward, dk/dv, dq
+
+
+class TestFlashKernelUnderTheHybridMesh:
+    """GSPMD cannot partition a Mosaic custom call, so under a mesh the flash
+    kernel runs in a shard_map (``flash_attention.shard_over_mesh``): batch
+    over the data axes, heads over ``mp``. At the parent commit the four-chip
+    train step failed to lower: "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"."""
+    DEGREES = {"dp": 1, "pp": 1, "sharding": 2, "sep": 1, "ep": 1, "mp": 2}
+    SPEC = PartitionSpec(("dp", "sharding"), None, "mp", None)
+
+    @pytest.fixture
+    def use_kernel(self, monkeypatch):
+        monkeypatch.setattr(flash_attention, "_use_pallas", lambda s: True)
+        monkeypatch.setitem(mesh_mod._STATE, "mesh", None)  # restored after
+
+    @staticmethod
+    def _loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v, True) ** 2)
+
+    def test_agrees_with_reference_on_the_cpu_mesh(self, use_kernel):
+        mesh = mesh_mod.set_mesh(mesh_mod.build_mesh(
+            self.DEGREES, devices=jax.devices()[:4]))
+        rng = np.random.RandomState(0)
+        put = lambda *shape: jax.device_put(       # noqa: E731
+            jnp.asarray(rng.randn(*shape), jnp.float32) * 0.3,
+            NamedSharding(mesh, self.SPEC))
+        q, k, v = put(4, 128, 4, 32), put(4, 128, 2, 32), put(4, 128, 2, 32)
+        grad = jax.value_and_grad(self._loss(flash_attention.attention),
+                                  argnums=(0, 1, 2))
+        loss, grads = jax.jit(grad)(q, k, v)
+        ref_loss, ref_grads = jax.value_and_grad(
+            self._loss(flash_attention._ref_attention),
+            argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
+        for g, r in zip(grads, ref_grads):
+            np.testing.assert_allclose(g, r, atol=1e-5)
+        assert grads[0].sharding.spec == self.SPEC      # stayed sharded
+
+    def test_lowers_for_the_four_chip_topology(self, v5e_devices,
+                                               use_kernel):
+        mesh = mesh_mod.set_mesh(mesh_mod.build_mesh(
+            self.DEGREES, devices=v5e_devices))
+        arr = lambda heads: jax.ShapeDtypeStruct(   # noqa: E731
+            (4, 1024, heads, 128), jnp.bfloat16,
+            sharding=NamedSharding(mesh, self.SPEC))
+        n = _mosaic_calls(
+            jax.grad(self._loss(flash_attention.attention),
+                     argnums=(0, 1, 2)), arr(32), arr(8), arr(8))
+        assert n == 3
+
+
+class TestNoFallbackHidesTheDevice:
+    def test_interpret_mode_by_backend(self, monkeypatch):
+        assert pallas_flash._interpret_mode() is True       # cpu: tests
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert pallas_flash._interpret_mode() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            pallas_flash._interpret_mode()
+
+    def test_use_pallas_by_backend(self, monkeypatch):
+        assert flash_attention._use_pallas(2048) is False   # cpu: jnp path
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert flash_attention._use_pallas(2048) is True
+        assert flash_attention._use_pallas(128) is False    # short: by design
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            flash_attention._use_pallas(2048)
+
+    def test_compiler_params_are_the_real_class(self):
+        from jax.experimental.pallas import tpu as pltpu
+        p = pallas_flash._cparams(("parallel", "arbitrary"))
+        assert isinstance(p, pltpu.CompilerParams)
+
+    def test_peak_flops_raises_on_unknown_device(self):
+        v5e_dev = types.SimpleNamespace(device_kind="TPU v5 lite")
+        assert peak_flops_per_chip(v5e_dev) == 197e12
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            peak_flops_per_chip(
+                types.SimpleNamespace(device_kind="TPU v9 imaginary"))
+        with pytest.raises(ValueError):
+            peak_flops_per_chip()        # the CPU backend has no peak
+
+
+class TestCompileCachePlacement:
+    def test_cache_dir_resolution(self, monkeypatch):
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        assert compile_cache.cache_dir() == os.path.join(REPO, ".jax_cache")
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+        assert compile_cache.cache_dir() == "/somewhere/else"
+
+    @pytest.mark.parametrize("placed", [True, False])
+    def test_enable_sets_no_other_directory(self, tmp_path, placed):
+        """In a fresh process: with the variable set JAX keeps the cache
+        there and enable() names no other; without it the cache resolves
+        inside the checkout."""
+        env = {k: v for k, v in os.environ.items()
+               if k != compile_cache.ENV_VAR}
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        want = os.path.join(REPO, ".jax_cache")
+        if placed:
+            want = env[compile_cache.ENV_VAR] = str(tmp_path / "cache")
+        code = ("import jax\n"
+                "from paddle_tpu.utils import compile_cache\n"
+                "stats = compile_cache.enable()\n"
+                "print(jax.config.jax_compilation_cache_dir)\n"
+                "print(stats.snapshot()['cache_dir'])\n")
+        p = subprocess.run([sys.executable, "-c", code], env=env, timeout=120,
+                           capture_output=True, text=True)
+        assert p.returncode == 0, p.stderr[-500:]
+        assert p.stdout.split() == [want, want]
