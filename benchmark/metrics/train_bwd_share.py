@@ -1,0 +1,9 @@
+"""Trainer: device time of the backward pass (``transpose(...)`` in the
+op's name, recomputation left out),
+over that of all ops in the trace; with the other three phases and what
+carries no such name ("other") it sums to 100."""
+import timeline
+
+
+def reduce(src):
+    return timeline.phase_share(src, "bwd")

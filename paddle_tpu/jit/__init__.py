@@ -254,15 +254,24 @@ class TrainStep:
                     t_in = jax.tree.map(Tensor, inputs)
                     out = model_ref(*t_in) if isinstance(t_in, tuple) else model_ref(t_in)
                     t_lab = jax.tree.map(Tensor, labels)
-                    if isinstance(t_lab, tuple):
-                        loss = loss_ref(out, *t_lab)
-                    else:
-                        loss = loss_ref(out, t_lab)
+                    with jax.named_scope("loss"):
+                        if isinstance(t_lab, tuple):
+                            loss = loss_ref(out, *t_lab)
+                        else:
+                            loss = loss_ref(out, t_lab)
                     new_b = collect()
             lv = loss.value if isinstance(loss, Tensor) else loss
             return lv.astype(jnp.float32), new_b
 
         opt = optimizer
+
+        def apply_update(p, grads, opt_state, lr):
+            # scopes are compile-time metadata: in a device trace they tell
+            # the update's ops from the backward pass (``transpose(...)`` in
+            # an op's name) and the recomputed forward inside it
+            # (``rematted_computation``)
+            with jax.named_scope("optimizer"):
+                return opt.apply_gradients(p, grads, opt_state, lr)
 
         # Debug NaN/Inf guard (reference FLAGS_check_nan_inf /
         # ``paddle/fluid/framework/details/nan_inf_utils_detail`` †): when
@@ -285,7 +294,7 @@ class TrainStep:
             (loss, new_b), grads = jax.value_and_grad(loss_f, has_aux=True)(
                 p, b, inputs, labels, key)
             bad = _bad_count(loss, grads)
-            new_p, new_opt = opt.apply_gradients(p, grads, opt_state, lr)
+            new_p, new_opt = apply_update(p, grads, opt_state, lr)
             return loss, new_p, new_b, new_opt, bad
 
         donate_argnums = (0, 1, 2) if donate else ()
@@ -317,7 +326,7 @@ class TrainStep:
                 (inputs_m, labels_m))
             grads = jax.tree.map(lambda g: g / accum, g_sum)
             bad = _bad_count(loss_sum, grads)
-            new_p, new_opt = opt.apply_gradients(p, grads, opt_state, lr)
+            new_p, new_opt = apply_update(p, grads, opt_state, lr)
             return loss_sum / accum, new_p, new_b, new_opt, bad
 
         self._accum_compiled = jax.jit(
@@ -352,9 +361,11 @@ class TrainStep:
         key = jax.random.fold_in(self._base_key, self._step_count)
         inputs, labels = _norm_batch(inputs), _norm_labels(labels)
         inputs, labels = self._place_batch(inputs), self._place_batch(labels)
-        loss, self._params, self._buffers, self._opt_state, bad = \
-            self._compiled(self._params, self._buffers, self._opt_state,
-                           inputs, labels, lr, key)
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=self._step_count):
+            loss, self._params, self._buffers, self._opt_state, bad = \
+                self._compiled(self._params, self._buffers,
+                               self._opt_state, inputs, labels, lr, key)
         self._step_count += 1
         self.optimizer._step_count = self._step_count
         self.sync_to_model()

@@ -133,6 +133,7 @@ def _decode_call(q_wide, kv_k, kv_v, lengths, scale, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((B, H, KD), q_wide.dtype),
         compiler_params=_cparams(("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, q_wide, kv_k, kv_v)
     return out
 

@@ -166,6 +166,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         ],
         compiler_params=_cparams(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, lse
 
@@ -350,6 +351,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
         ],
         compiler_params=_cparams(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*base_args, *rope_args)
     dk, dv = dkv
 
@@ -371,6 +373,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_cparams(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*base_args, *rope_args)
     return dq, dk, dv
 

@@ -344,6 +344,7 @@ def _fused_tick_pallas(params, stack, head, tables, sin, cos, tok, pk_all,
         out_shape=out_shape,
         compiler_params=_cparams(("arbitrary",)),
         interpret=_interpret_mode(),
+        name="fused_decode_tick",
     )(tables, att_lens, *const_args, *w_leaves, *pool_leaves)
 
     nxt, nkeys = res[0], res[1]

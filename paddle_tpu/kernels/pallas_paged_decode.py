@@ -229,6 +229,7 @@ def _paged_call(q_wide, pool_k, pool_v, tables, lengths, scale, interpret,
         out_shape=jax.ShapeDtypeStruct((B, H, KD), q_wide.dtype),
         compiler_params=_cparams(("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*args)
     return out
 

@@ -253,8 +253,42 @@ def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
         # across r and accumulated across ki) — no reordering allowed
         compiler_params=_cparams(("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(*args)
     return out
+
+
+def _query_block(block_q, heads, packed_tokens):
+    """The query block in wide rows: a multiple of ``heads`` (so //gh never
+    crosses a pad boundary), at most the whole packed buffer."""
+    return max(heads, min(int(block_q) // heads * heads,
+                          packed_tokens * heads))
+
+
+def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
+                       table_entries, packed_tokens, block_q=256):
+    """What one call of the kernel is asked to do, counted on the host from
+    the step's span metadata (plain integers; no jax): ``grid_steps``, the
+    ``nq x R x nk`` steps of ``_ragged_call``'s grid; ``live_steps``, those
+    that pass ``_ragged_kernel``'s ``pl.when(inter & (ki * block_k <
+    kvlen))`` and compute; ``kv_tokens``, the cache rows the live spans
+    attend over; ``attn_pairs``, their causal (query, key) pairs. A row
+    with ``qlen == 0`` is dead."""
+    bq = _query_block(block_q, heads, packed_tokens)
+    nq = -(-(packed_tokens * heads) // bq)
+    nk = int(table_entries)
+    live = kv_tokens = pairs = 0
+    for qs, ql, kl in zip(qstart, qlen, kvlen):
+        qs, ql, kl = int(qs), int(ql), int(kl)
+        lo, hi = qs * heads, (qs + ql) * heads
+        # query blocks qi with lo < (qi + 1) * bq and hi > qi * bq
+        n_q = min(nq, -(-hi // bq)) - min(nq, lo // bq)
+        live += max(n_q, 0) * min(nk, -(-kl // block_size))
+        if ql > 0:
+            kv_tokens += kl
+            pairs += ql * (kl - ql) + ql * (ql + 1) // 2
+    return {"grid_steps": nq * len(qstart) * nk, "live_steps": live,
+            "kv_tokens": kv_tokens, "attn_pairs": pairs}
 
 
 # Inference-only custom_vjp, same rationale as pallas_paged_decode: the
@@ -357,7 +391,7 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     q_wide = q_wide.reshape(T * H, KD)
     # pad the wide-row dim to a whole number of query blocks; the query
     # block is kept a multiple of H so //gh never crosses a pad boundary
-    bq = max(H, min(int(block_q) // H * H, T * H))
+    bq = _query_block(block_q, H, T)
     th_pad = -(-(T * H) // bq) * bq
     if th_pad != T * H:
         q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))

@@ -215,6 +215,7 @@ def _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _swiglu_raw(hn, lg, lu, ld):
     return jnp.einsum(
         "bsi,ih->bsh",
@@ -436,6 +437,13 @@ def _llama_forward(input_ids, labels, nh, nkv, hd, eps, theta, remat, tied,
         logits = jnp.einsum("bsh,hv->bsv", x, head)
         return _ann(logits, batch_spec, None, "mp")
 
+    return _shifted_ce_loss(x, head, labels, loss_chunk, batch_spec)
+
+
+@jax.named_scope("loss")
+def _shifted_ce_loss(x, head, labels, loss_chunk, batch_spec):
+    """The training loss head on the final hidden states ``x`` [B, S, H]."""
+    B, S, H = x.shape
     # training: shifted CE via logsumexp (loss = lse - picked_logit)
     if loss_chunk > 0 and S % loss_chunk != 0:
         import warnings

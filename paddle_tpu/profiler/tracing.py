@@ -31,6 +31,15 @@ Design constraints, in order:
 - **Dependency-free and host-only.** Plain dicts and a lock; no device
   work, no new packages. The tracer never touches jax — it is safe to
   import anywhere, including the HTTP layer.
+- **One timeline with the device.** A span opened with :meth:`span` on
+  the engine or gateway lane is also opened as ``annotate(name, **args)``
+  when the owner injected such a factory (the gateway injects
+  ``jax.profiler.TraceAnnotation``), so a device trace taken meanwhile
+  carries the same spans on the profiler's clock. The ``step`` span's
+  annotation carries the step number: a ``step`` span here and its twin
+  there give the offset between the two clocks, by which the request-lane
+  spans (not mirrored) convert. With no factory nothing changes, bytes
+  included.
 
 Event vocabulary (Chrome trace phases): spans are COMPLETE events
 (``ph="X"`` with ``ts``/``dur`` in microseconds) — simpler to validate
@@ -58,7 +67,7 @@ from collections import deque
 
 
 class _NullSpan:
-    """Shared no-op context manager — the disabled ``span()`` path."""
+    """Shared no-op span — the disabled ``span()`` path."""
 
     __slots__ = ()
 
@@ -67,6 +76,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def end(self, args=None):
+        pass
 
 
 NULL_SPAN = _NullSpan()
@@ -78,6 +90,7 @@ PID = 1
 TID_ENGINE = 1
 TID_GATEWAY = 2
 TID_REQ0 = 8
+_REAL_CLOCKS = (time.perf_counter, time.monotonic)
 
 
 class SpanTracer:
@@ -92,15 +105,19 @@ class SpanTracer:
     attribute read is the entire disabled-path cost.
     """
 
-    def __init__(self, capacity=65536, clock=None):
+    def __init__(self, capacity=65536, clock=None, annotate=None):
         if int(capacity) < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.clock = clock if clock is not None else time.perf_counter
+        #: ``annotate(name, **args)`` -> context manager, or None: the
+        #: mirror of engine- and gateway-lane spans into another trace
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._events = deque(maxlen=self.capacity)
         self._enabled = False
         self._epoch = 0.0
+        self._epoch_unix_ns = None
         self._req_tids = {}          # request_id -> dense tid
         self._req_seq = 0            # tids ever assigned this window
         self.dropped = 0
@@ -114,9 +131,16 @@ class SpanTracer:
         """Start recording. The first enable (or any :meth:`clear`)
         sets the timestamp epoch, so ts starts near 0."""
         if not self._enabled and not self._events and self.dropped == 0:
-            self._epoch = self.clock()
+            self._set_epoch()
         self._enabled = True
         return self
+
+    def _set_epoch(self):
+        self._epoch = self.clock()
+        # the wall clock is read only beside a real clock: an injected
+        # one (a replay) must stay deterministic
+        self._epoch_unix_ns = time.time_ns() \
+            if self.clock in _REAL_CLOCKS else None
 
     def disable(self):
         self._enabled = False
@@ -130,7 +154,7 @@ class SpanTracer:
             self._req_tids.clear()
             self._req_seq = 0
             self.dropped = 0
-            self._epoch = self.clock()
+            self._set_epoch()
         return self
 
     # -------------------------------------------------------------- clocks
@@ -222,7 +246,10 @@ class SpanTracer:
         self._append(ev)
 
     def span(self, name, tid=TID_ENGINE, args=None):
-        """Context manager emitting one complete span around the body.
+        """Open one span now; it is emitted as a complete event when it
+        is closed, by ``end(more_args)`` or by leaving the ``with``
+        block. ``args`` are what is known at the start: they also go to
+        the ``annotate`` mirror, on the engine and gateway lanes.
         Returns a shared no-op when disabled (nothing allocated)."""
         if not self._enabled:
             return NULL_SPAN
@@ -239,24 +266,46 @@ class SpanTracer:
         with ``json.dumps`` and load in Perfetto."""
         return {"traceEvents": self.events(),
                 "displayTimeUnit": "ms",
-                "otherData": {"clock": "injectable-monotonic",
+                "otherData": {"clock": self.clock_origin(),
                               "dropped_events": self.dropped}}
+
+    def clock_origin(self) -> dict:
+        """Where ``ts`` 0 lies: the capture epoch as the tracer's own
+        clock read it (seconds) and, beside a real clock, as
+        ``time.time_ns()`` at that moment."""
+        origin = {"epoch_s": self._epoch}
+        if self._epoch_unix_ns is not None:
+            origin["epoch_unix_ns"] = self._epoch_unix_ns
+        return origin
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_mirror")
 
     def __init__(self, tracer, name, tid, args):
         self._tracer = tracer
         self._name = name
         self._tid = tid
         self._args = args
+        self._mirror = None
+        if tracer.annotate is not None and tid < TID_REQ0:
+            self._mirror = tracer.annotate(name, **(args or {}))
+            self._mirror.__enter__()
         self._t0 = tracer.clock()
 
     def __enter__(self):
         return self
 
-    def __exit__(self, *exc):
+    def end(self, args=None):
+        """Close the span; ``args`` join those given at the start. The
+        mirror closes even when the tracer was disabled meanwhile."""
+        if args and self._args:
+            args = {**self._args, **args}
         self._tracer.complete(self._name, self._t0, tid=self._tid,
-                              args=self._args)
+                              args=args or self._args)
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+
+    def __exit__(self, *exc):
+        self.end()
         return False

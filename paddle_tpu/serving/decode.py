@@ -192,10 +192,11 @@ def _swiglu_proj(hn, lg, lu, ld):
     weights; under quantize_activations gate/up share one per-row act
     quant and down re-quantizes the gated product."""
     if isinstance(lg, tuple):
-        qx, sx = quantize_act_rows(hn)
-        g = jax.nn.silu(_a8_apply(qx, sx, lg))
-        u = _a8_apply(qx, sx, lu)
-        return _a8_dot(g * u, ld).astype(hn.dtype)
+        with jax.named_scope("mlp"):
+            qx, sx = quantize_act_rows(hn)
+            g = jax.nn.silu(_a8_apply(qx, sx, lg))
+            u = _a8_apply(qx, sx, lu)
+            return _a8_dot(g * u, ld).astype(hn.dtype)
     return _swiglu_raw(hn, lg, lu, ld)
 
 
@@ -206,6 +207,7 @@ def _o_proj(attn2, lwo):
     return jnp.einsum("bsd,dh->bsh", attn2, lwo)
 
 
+@jax.named_scope("lm_head")
 def _head_logits(last_h, head):
     """The lm-head matmul ``[B, H] @ head`` (the pair arrives
     pre-oriented from ``_dq_head`` under a8)."""
@@ -532,6 +534,7 @@ def _apply_rope_rows(x, sin_p, cos_p):
             + rotated * sin_p[:, None, None, :]).astype(x.dtype)
 
 
+@jax.named_scope("sample")
 def sample_rows(logits, keys, temps, top_ks):
     """Per-row sampling: greedy where temps<=0, else top-k temperature.
 
@@ -557,6 +560,7 @@ def sample_rows(logits, keys, temps, top_ks):
 
 
 # ------------------------------------------------------------------ prefill
+@jax.named_scope("prefill")
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
@@ -578,13 +582,14 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     def prefill_layer(h, lp):
         (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost) = \
             _dq_layer(lp, wdt, a8)
-        hn = _rms(h, lin, eps)
-        q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-        q = _apply_rope(q, sin, cos)
-        k = _apply_rope(k, sin, cos)
-        attn = _attention(q, k, v, causal=True)
-        o = _o_proj(attn.reshape(B, S, nh * hd), lwo)
-        h = h + (o if tp_reduce is None else tp_reduce(o))
+        with jax.named_scope("attn"):
+            hn = _rms(h, lin, eps)
+            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
+            q = _apply_rope(q, sin, cos)
+            k = _apply_rope(k, sin, cos)
+            attn = _attention(q, k, v, causal=True)
+            o = _o_proj(attn.reshape(B, S, nh * hd), lwo)
+            h = h + (o if tp_reduce is None else tp_reduce(o))
         m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
         h = h + (m if tp_reduce is None else tp_reduce(m))
         return h, (k, v)
@@ -640,6 +645,7 @@ def _apply_rope_grid(x, sin_p, cos_p):
             + rotated * sin_p[:, :, None, :]).astype(x.dtype)
 
 
+@jax.named_scope("suffix_prefill")
 def _suffix_prefill_impl(params, cache_k, cache_v, slots, prefix_lens, ids,
                          suffix_lens, keys, temps, top_ks, *, nh, nkv, hd,
                          eps, theta, tied):
@@ -690,26 +696,27 @@ def _suffix_prefill_impl(params, cache_k, cache_v, slots, prefix_lens, ids,
     def layer(h, lp):
         (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, ck, cv) = \
             _dq_layer(lp, wdt)
-        hn = _rms(h, lin, eps)
-        q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-        q = _apply_rope_grid(q, sin_p, cos_p)
-        k = _apply_rope_grid(k, sin_p, cos_p)
-        # ragged scatter: column i appends at its row's prefix_len + i;
-        # out-of-range positions (padding rows, clamped tails) drop
-        ck = ck.at[g_idx, pos].set(k, mode="drop")
-        cv = cv.at[g_idx, pos].set(v, mode="drop")
-        kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
-        vf = jnp.repeat(cv, grp, axis=2) if grp > 1 else cv
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(mask[:, None], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        # exact zeros on masked cols + zeroed garbage rows: stale cache
-        # rows can be anything (0 * NaN = NaN)
-        probs = jnp.where(mask[:, None], probs, 0.0)
-        vf = jnp.where(row_valid[:, :, None, None], vf, 0.0)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), vf)
-        h = h + jnp.einsum("bsd,dh->bsh", attn.reshape(G, S, nh * hd), lwo)
+        with jax.named_scope("attn"):
+            hn = _rms(h, lin, eps)
+            q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
+            q = _apply_rope_grid(q, sin_p, cos_p)
+            k = _apply_rope_grid(k, sin_p, cos_p)
+            # ragged scatter: column i appends at its row's prefix_len + i;
+            # out-of-range positions (padding rows, clamped tails) drop
+            ck = ck.at[g_idx, pos].set(k, mode="drop")
+            cv = cv.at[g_idx, pos].set(v, mode="drop")
+            kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
+            vf = jnp.repeat(cv, grp, axis=2) if grp > 1 else cv
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(mask[:, None], logits, NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1)
+            # exact zeros on masked cols + zeroed garbage rows: stale cache
+            # rows can be anything (0 * NaN = NaN)
+            probs = jnp.where(mask[:, None], probs, 0.0)
+            vf = jnp.where(row_valid[:, :, None, None], vf, 0.0)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), vf)
+            h = h + jnp.einsum("bsd,dh->bsh", attn.reshape(G, S, nh * hd), lwo)
         h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
         return h, (ck, cv)
 
@@ -718,7 +725,7 @@ def _suffix_prefill_impl(params, cache_k, cache_v, slots, prefix_lens, ids,
     last = jnp.take_along_axis(
         x, (suffix_lens - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
     last_h = _rms(last, params["final_norm"], eps)
-    logits = jnp.einsum("bh,hv->bv", last_h, head)
+    logits = _head_logits(last_h, head)
     both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
     tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
     # scatter the updated per-slot caches back (padding rows drop)
@@ -740,6 +747,7 @@ def build_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied, donate=None):
 
 
 # ----------------------------------------------------- paged suffix prefill
+@jax.named_scope("paged_suffix_prefill")
 def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
                                ids, suffix_lens, keys, temps, top_ks, *,
                                nh, nkv, hd, eps, theta, tied,
@@ -814,38 +822,39 @@ def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
     def layer(h, lp):
         (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l) = \
             _dq_layer(lp, wdt, a8)
-        hn = _rms(h, lin, eps)
-        q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-        q = _apply_rope_grid(q, sin_p, cos_p)
-        k = _apply_rope_grid(k, sin_p, cos_p)
-        # write the suffix K/V through the table (quantize-on-write on
-        # a quantized pool), then gather each row's logical cache
-        # (shared prefix + own suffix) in the pool's NATIVE dtype — the
-        # upcast fuses into the attention dots and the scales apply
-        # POST-dot (``_row_scale_bhqk``), so a quantized pool never
-        # round-trips through a materialized fp copy; the causal mask
-        # keeps columns from seeing rows past their position
-        pk_l = _kv_write(pk_l, phys, prow, k)
-        pv_l = _kv_write(pv_l, phys, prow, v)
-        ck, ksr = _kv_gather_rows(pk_l, tables, (G, s_tot, nkv, hd))
-        cv, vsr = _kv_gather_rows(pv_l, tables, (G, s_tot, nkv, hd))
-        kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
-        vf = jnp.repeat(cv, grp, axis=2) if grp > 1 else cv
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kf.astype(q.dtype),
-                            preferred_element_type=jnp.float32) * scale
-        if ksr is not None:
-            logits = logits * _row_scale_bhqk(ksr, grp)
-        logits = jnp.where(mask[:, None], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        probs = jnp.where(mask[:, None], probs, 0.0)
-        if vsr is not None:
-            probs = probs * _row_scale_bhqk(vsr, grp)
-        vf = jnp.where(row_valid[:, :, None, None], vf,
-                       jnp.zeros_like(vf))
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype),
-                          vf.astype(q.dtype))
-        o = _o_proj(attn.reshape(G, S, nh * hd), lwo)
-        h = h + (o if tp_reduce is None else tp_reduce(o))
+        with jax.named_scope("attn"):
+            hn = _rms(h, lin, eps)
+            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
+            q = _apply_rope_grid(q, sin_p, cos_p)
+            k = _apply_rope_grid(k, sin_p, cos_p)
+            # write the suffix K/V through the table (quantize-on-write on
+            # a quantized pool), then gather each row's logical cache
+            # (shared prefix + own suffix) in the pool's NATIVE dtype — the
+            # upcast fuses into the attention dots and the scales apply
+            # POST-dot (``_row_scale_bhqk``), so a quantized pool never
+            # round-trips through a materialized fp copy; the causal mask
+            # keeps columns from seeing rows past their position
+            pk_l = _kv_write(pk_l, phys, prow, k)
+            pv_l = _kv_write(pv_l, phys, prow, v)
+            ck, ksr = _kv_gather_rows(pk_l, tables, (G, s_tot, nkv, hd))
+            cv, vsr = _kv_gather_rows(pv_l, tables, (G, s_tot, nkv, hd))
+            kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
+            vf = jnp.repeat(cv, grp, axis=2) if grp > 1 else cv
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, kf.astype(q.dtype),
+                                preferred_element_type=jnp.float32) * scale
+            if ksr is not None:
+                logits = logits * _row_scale_bhqk(ksr, grp)
+            logits = jnp.where(mask[:, None], logits, NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1)
+            probs = jnp.where(mask[:, None], probs, 0.0)
+            if vsr is not None:
+                probs = probs * _row_scale_bhqk(vsr, grp)
+            vf = jnp.where(row_valid[:, :, None, None], vf,
+                           jnp.zeros_like(vf))
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype),
+                              vf.astype(q.dtype))
+            o = _o_proj(attn.reshape(G, S, nh * hd), lwo)
+            h = h + (o if tp_reduce is None else tp_reduce(o))
         m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
         h = h + (m if tp_reduce is None else tp_reduce(m))
         return h, (pk_l, pv_l)
@@ -893,6 +902,7 @@ def build_paged_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied,
 
 
 # -------------------------------------------------------------- decode step
+@jax.named_scope("decode_steps")
 def _decode_steps_impl(params, cache_k, cache_v, tokens, lengths, keys,
                        temps, top_ks, *, n_steps, nh, nkv, hd, eps, theta,
                        tied, decode_attn):
@@ -920,25 +930,27 @@ def _decode_steps_impl(params, cache_k, cache_v, tokens, lengths, keys,
         def layer(h, xs):
             lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, ck, cv = \
                 _dq_layer(xs, wdt)
-            hn = _rms(h, lin, eps)
-            q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-            q = _apply_rope_rows(q, sin_p, cos_p)
-            k = _apply_rope_rows(k, sin_p, cos_p)
-            # ragged scatter: each row appends at its own position
-            ck = ck.at[jnp.arange(B), lens].set(k[:, 0])
-            cv = cv.at[jnp.arange(B), lens].set(v[:, 0])
-            if decode_attn == "pallas":
-                attn = decode_attention_pallas(q[:, 0], ck, cv, lens + 1)
-            else:
-                attn = decode_attention_reference(q[:, 0], ck, cv, lens + 1)
-            h = h + jnp.einsum("bsd,dh->bsh",
-                               attn.reshape(B, 1, nh * hd), lwo)
+            with jax.named_scope("attn"):
+                hn = _rms(h, lin, eps)
+                q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
+                q = _apply_rope_rows(q, sin_p, cos_p)
+                k = _apply_rope_rows(k, sin_p, cos_p)
+                # ragged scatter: each row appends at its own position
+                ck = ck.at[jnp.arange(B), lens].set(k[:, 0])
+                cv = cv.at[jnp.arange(B), lens].set(v[:, 0])
+                if decode_attn == "pallas":
+                    attn = decode_attention_pallas(q[:, 0], ck, cv, lens + 1)
+                else:
+                    attn = decode_attention_reference(
+                        q[:, 0], ck, cv, lens + 1)
+                h = h + jnp.einsum("bsd,dh->bsh",
+                                   attn.reshape(B, 1, nh * hd), lwo)
             h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
             return h, (ck, cv)
 
         x, (nck, ncv) = jax.lax.scan(layer, x, stack + (ck_all, cv_all))
         last = _rms(x[:, 0], params["final_norm"], eps)
-        logits = jnp.einsum("bh,hv->bv", last, head)
+        logits = _head_logits(last, head)
         both = jax.vmap(jax.random.split)(kys)  # [B, 2, 2]
         nxt = sample_rows(logits, both[:, 1], temps, top_ks)
         return (nxt, nck, ncv, lens + 1, both[:, 0]), nxt
@@ -961,6 +973,7 @@ def build_decode_steps_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
 
 
 # ------------------------------------------------------- paged decode step
+@jax.named_scope("paged_decode_steps")
 def _paged_decode_steps_impl(params, pool_k, pool_v, tables, tokens,
                              lengths, keys, temps, top_ks, *, n_steps, nh,
                              nkv, hd, eps, theta, tied, decode_attn):
@@ -1008,27 +1021,28 @@ def _paged_decode_steps_impl(params, pool_k, pool_v, tables, tokens,
         def layer(h, xs):
             lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l = \
                 _dq_layer(xs, wdt)
-            hn = _rms(h, lin, eps)
-            q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-            q = _apply_rope_rows(q, sin_p, cos_p)
-            k = _apply_rope_rows(k, sin_p, cos_p)
-            # ragged append through the table (dead slots drop)
-            pk_l = pk_l.at[phys, prow].set(k[:, 0], mode="drop")
-            pv_l = pv_l.at[phys, prow].set(v[:, 0], mode="drop")
-            if decode_attn == "pallas":
-                attn = paged_decode_attention_pallas(
-                    q[:, 0], pk_l, pv_l, tables, lens + 1)
-            else:
-                attn = paged_decode_attention_reference(
-                    q[:, 0], pk_l, pv_l, tables, lens + 1)
-            h = h + jnp.einsum("bsd,dh->bsh",
-                               attn.reshape(B, 1, nh * hd), lwo)
+            with jax.named_scope("attn"):
+                hn = _rms(h, lin, eps)
+                q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
+                q = _apply_rope_rows(q, sin_p, cos_p)
+                k = _apply_rope_rows(k, sin_p, cos_p)
+                # ragged append through the table (dead slots drop)
+                pk_l = pk_l.at[phys, prow].set(k[:, 0], mode="drop")
+                pv_l = pv_l.at[phys, prow].set(v[:, 0], mode="drop")
+                if decode_attn == "pallas":
+                    attn = paged_decode_attention_pallas(
+                        q[:, 0], pk_l, pv_l, tables, lens + 1)
+                else:
+                    attn = paged_decode_attention_reference(
+                        q[:, 0], pk_l, pv_l, tables, lens + 1)
+                h = h + jnp.einsum("bsd,dh->bsh",
+                                   attn.reshape(B, 1, nh * hd), lwo)
             h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
             return h, (pk_l, pv_l)
 
         x, (npk, npv) = jax.lax.scan(layer, x, stack + (pk_all, pv_all))
         last = _rms(x[:, 0], params["final_norm"], eps)
-        logits = jnp.einsum("bh,hv->bv", last, head)
+        logits = _head_logits(last, head)
         both = jax.vmap(jax.random.split)(kys)  # [B, 2, 2]
         nxt = sample_rows(logits, both[:, 1], temps, top_ks)
         return (nxt, npk, npv, lens + 1, both[:, 0]), nxt
@@ -1098,23 +1112,24 @@ def _fused_decode_tick(params, stack, head, tables, sin, cos, tok, pk_all,
     def layer(h, xs):
         lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l = \
             _dq_layer(xs, wdt, a8)
-        hn = _rms(h, lin, eps)
-        q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-        q = _apply_rope_rows(q, sin_r, cos_r)
-        k = _apply_rope_rows(k, sin_r, cos_r)
-        pk_l = _kv_write(pk_l, phys, prow, k[:, 0])
-        pv_l = _kv_write(pv_l, phys, prow, v[:, 0])
-        kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
-        if decode_attn == "pallas":
-            attn = paged_decode_attention_pallas(
-                q[:, 0], kd, vd, tables, lens + app_mask,
-                k_scale=ksc, v_scale=vsc)
-        else:
-            attn = paged_decode_attention_reference(
-                q[:, 0], kd, vd, tables, lens + app_mask,
-                k_scale=ksc, v_scale=vsc)
-        o = _o_proj(attn.reshape(R, 1, nh * hd), lwo)
-        h = h + (o if tp_reduce is None else tp_reduce(o))
+        with jax.named_scope("attn"):
+            hn = _rms(h, lin, eps)
+            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
+            q = _apply_rope_rows(q, sin_r, cos_r)
+            k = _apply_rope_rows(k, sin_r, cos_r)
+            pk_l = _kv_write(pk_l, phys, prow, k[:, 0])
+            pv_l = _kv_write(pv_l, phys, prow, v[:, 0])
+            kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
+            if decode_attn == "pallas":
+                attn = paged_decode_attention_pallas(
+                    q[:, 0], kd, vd, tables, lens + app_mask,
+                    k_scale=ksc, v_scale=vsc)
+            else:
+                attn = paged_decode_attention_reference(
+                    q[:, 0], kd, vd, tables, lens + app_mask,
+                    k_scale=ksc, v_scale=vsc)
+            o = _o_proj(attn.reshape(R, 1, nh * hd), lwo)
+            h = h + (o if tp_reduce is None else tp_reduce(o))
         m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
         h = h + (m if tp_reduce is None else tp_reduce(m))
         return h, (pk_l, pv_l)
@@ -1181,29 +1196,30 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     def layer0(h, lp):
         (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l) = \
             _dq_layer(lp, wdt, a8)
-        hn = _rms(h, lin, eps)
-        q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-        q = _apply_rope_grid(q, sin_p, cos_p)
-        k = _apply_rope_grid(k, sin_p, cos_p)
-        # write the packed K/V through the tables (quantize-on-write on
-        # an int8 pool), then attend over each span causally at its
-        # row's kv length — THE one dequant site: the ragged kernel
-        # (or its oracle) dequantizes right after the table-indirect
-        # fetch, and every consumer of this forward (unified step,
-        # multi-tick tick 0, speculative verify) rides it
-        pk_l = _kv_write(pk_l, phys0, prow0, k[0])
-        pv_l = _kv_write(pv_l, phys0, prow0, v[0])
-        kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
-        if decode_attn == "pallas":
-            attn = ragged_paged_attention_pallas(
-                q[0], kd, vd, tables, qstart, qlen, kvlen,
-                k_scale=ksc, v_scale=vsc)
-        else:
-            attn = ragged_attention_reference(
-                q[0], kd, vd, tables, qstart, qlen, kvlen,
-                k_scale=ksc, v_scale=vsc)
-        o = _o_proj(attn.reshape(1, T, nh * hd), lwo)
-        h = h + (o if tp_reduce is None else tp_reduce(o))
+        with jax.named_scope("attn"):
+            hn = _rms(h, lin, eps)
+            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
+            q = _apply_rope_grid(q, sin_p, cos_p)
+            k = _apply_rope_grid(k, sin_p, cos_p)
+            # write the packed K/V through the tables (quantize-on-write on
+            # an int8 pool), then attend over each span causally at its
+            # row's kv length — THE one dequant site: the ragged kernel
+            # (or its oracle) dequantizes right after the table-indirect
+            # fetch, and every consumer of this forward (unified step,
+            # multi-tick tick 0, speculative verify) rides it
+            pk_l = _kv_write(pk_l, phys0, prow0, k[0])
+            pv_l = _kv_write(pv_l, phys0, prow0, v[0])
+            kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
+            if decode_attn == "pallas":
+                attn = ragged_paged_attention_pallas(
+                    q[0], kd, vd, tables, qstart, qlen, kvlen,
+                    k_scale=ksc, v_scale=vsc)
+            else:
+                attn = ragged_attention_reference(
+                    q[0], kd, vd, tables, qstart, qlen, kvlen,
+                    k_scale=ksc, v_scale=vsc)
+            o = _o_proj(attn.reshape(1, T, nh * hd), lwo)
+            h = h + (o if tp_reduce is None else tp_reduce(o))
         m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
         h = h + (m if tp_reduce is None else tp_reduce(m))
         return h, (pk_l, pv_l)
@@ -1213,6 +1229,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     return x, pk, pv
 
 
+@jax.named_scope("ragged_step")
 def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, dec_mask, keys, temps, top_ks,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
@@ -1340,6 +1357,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
 
 
 # ------------------------------------------------------- multi-tick decode
+@jax.named_scope("multitick_step")
 def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, dec_mask, keys, temps,
                          top_ks, eos_ids, budgets, n_ticks, *, max_ticks,
@@ -1511,6 +1529,7 @@ def build_multitick_step_fn(*, max_ticks, nh, nkv, hd, eps, theta, tied,
 
 
 # ------------------------------------------------- speculative verify step
+@jax.named_scope("spec_verify")
 def _spec_verify_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, sample_start, keys, temps,
                       top_ks, *, spec_len, nh, nkv, hd, eps, theta, tied,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
 from .decode import build_decode_steps_fn, build_paged_decode_steps_fn, \
     build_paged_suffix_prefill_fn, build_prefill_fn, build_ragged_step_fn, \
@@ -625,6 +626,7 @@ class ContinuousBatchingEngine:
                       "prefill_tokens_saved": 0,
                       "prefill_copy_dispatches": 0,
                       "prefill_chunks": 0, "chunk_tokens": 0,
+                      "step_prefill_tokens": 0, "step_decode_tokens": 0,
                       "unified_steps": 0,
                       "mtick_syncs": 0, "mtick_ticks": 0,
                       "mtick_pure_syncs": 0,
@@ -730,6 +732,21 @@ class ContinuousBatchingEngine:
         if tr is None:
             return NULL_SPAN
         return tr.span(name, args=args)
+
+    def _dispatch_args(self, qstart, qlen, kvlen, packed, decode_rows,
+                       decode_tokens, prefill_tokens):
+        """The ``dispatch`` span's args: what this step asks of the ragged
+        kernel in one layer call (every layer runs the same grid;
+        ``kernels.pallas_ragged_attention.ragged_grid_counts``) and the
+        step's tokens by kind."""
+        work = ragged_grid_counts(
+            qstart, qlen, kvlen, packed_tokens=packed,
+            heads=self.config.num_attention_heads // self._tp,
+            block_size=self.cache.block_size,
+            table_entries=self.cache.max_blocks)
+        work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
+                    prefill_tokens=prefill_tokens)
+        return work
 
     # ------------------------------------------------------------ programs
     def _fn_consts(self):
@@ -1584,7 +1601,8 @@ class ContinuousBatchingEngine:
         t0 = self._clock()
         self._stamp_t = t0
         tr = self._tr()
-        ts0 = tr.now() if tr is not None else None
+        sp = tr.span("step", args={"step": self.stats["steps"]}) \
+            if tr is not None else None
         co = self._co()
         cost0 = co.snapshot() if co is not None else None
         finished = []
@@ -1594,7 +1612,7 @@ class ContinuousBatchingEngine:
         self._expire_deadlines(
             list(self.scheduler.queue)
             + [s for s in self._slots if s is not None], finished)
-        step_tokens, had_chunks = 0, False
+        step_tokens = chunk_tokens = 0
         admitted = []
         for attempt in range(self.num_slots + 2):
             try:
@@ -1619,15 +1637,14 @@ class ContinuousBatchingEngine:
                                          args={"n": len(admitted)}):
                             self._admit_group(admitted, finished)
                 if self._spec:
-                    step_tokens, had_chunks = self._spec_step(finished)
+                    variant = self._spec_step
                 elif self._mtick:
-                    step_tokens, had_chunks = self._multitick_step(
-                        finished)
+                    variant = self._multitick_step
                 elif self._ragged:
-                    step_tokens, had_chunks = self._unified_step(finished)
+                    variant = self._unified_step
                 else:
-                    step_tokens, had_chunks = self._two_program_step(
-                        finished)
+                    variant = self._two_program_step
+                step_tokens, chunk_tokens = variant(finished)
                 break
             except PoolExhausted:
                 # unwind, preempt, retry — no device work was committed
@@ -1648,15 +1665,17 @@ class ContinuousBatchingEngine:
                 self._stamp_t = None
                 raise
         self.stats["steps"] += 1
-        self._record_step(self._clock() - t0, step_tokens, had_chunks)
+        # what the step programs were given, split as an operator needs it
+        # (``serving_step_tokens_total{kind}``): chunk tokens are prefill,
+        # the rest are decode rows and their fused ticks
+        self.stats["step_prefill_tokens"] += chunk_tokens
+        self.stats["step_decode_tokens"] += step_tokens - chunk_tokens
+        self._record_step(self._clock() - t0, step_tokens, chunk_tokens > 0)
         self._stamp_t = None
         if co is not None:
             co.set_phase(None)
         if tr is not None:
-            tr.complete("step", ts0,
-                        args={"step": self.stats["steps"] - 1,
-                              "tokens": step_tokens,
-                              "chunks": bool(had_chunks)})
+            sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0})
             # counter tracks (ph:"C") on the same timeline as the step
             # spans, so Perfetto graphs cost alongside the phases:
             # KV-pool occupancy + table pressure, and (with the cost
@@ -1939,10 +1958,10 @@ class ContinuousBatchingEngine:
         plus a decode call, and a mid-prefill slot costs its chunk span
         instead of a discarded full-length decode row. Pure-decode
         steps still fuse ``choose_num_steps`` ticks (the scan tail of
-        the same program). Returns ``(tokens_processed, had_chunks)``
-        for the headroom EWMAs."""
+        the same program). Returns ``(tokens_processed, chunk_tokens)``
+        for the headroom EWMAs and the prefill / decode token counters."""
         tr = self._tr()
-        tp0 = tr.now() if tr is not None else None
+        sp = tr.span("plan") if tr is not None else None
         co = self._co()
         if co is not None:
             co.set_phase("plan")
@@ -1954,7 +1973,9 @@ class ContinuousBatchingEngine:
         active = [s for s in self._slots
                   if s is not None and s.status == "running"]
         if not active and not plan:
-            return 0, False
+            if tr is not None:
+                sp.end({"rows": 0, "chunks": 0})
+            return 0, 0
         n = self.scheduler.choose_num_steps(active) if active else 1
         R, T = self.num_slots, self._token_budget
         ids = np.zeros(T, np.int32)
@@ -1974,13 +1995,16 @@ class ContinuousBatchingEngine:
             temps, topks)
         if tr is not None:
             # plan: admission already ran in step(); this is the chunk
-            # grant + span packing. launch: the one device program +
-            # the host transfer that fences it. host-accept: token/
-            # chunk bookkeeping (donate spans nest inside it).
-            tr.complete("plan", tp0,
-                        args={"rows": len(active), "chunks": len(plan),
-                              "fused_steps": n})
-            tl0 = tr.now()
+            # grant + span packing. launch: the one device program, as
+            # dispatch (the jitted call returns) and device-wait (the
+            # host transfer that fences it). host-accept: token/chunk
+            # bookkeeping (donate spans nest inside it).
+            sp.end({"rows": len(active), "chunks": len(plan),
+                    "fused_steps": n})
+            launch = tr.span("launch")
+            sp = tr.span("dispatch", args=self._dispatch_args(
+                qstart, qlen, kvlen, T, len(active), n * len(active),
+                cursor - len(active)))
         if co is not None:
             co.set_phase("launch")
         npk, npv, toks, keys_t0, keys_fin = self._ragged_fn(n)(
@@ -1988,6 +2012,9 @@ class ContinuousBatchingEngine:
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             dec_mask, keys, temps, topks)
         self.cache.update(npk, npv)
+        if tr is not None:
+            sp.end()
+            sp = tr.span("device-wait")
         toks_np = np.asarray(toks)          # [n, R]
         keys_t0_np = np.asarray(keys_t0)
         self.stats["unified_steps"] += 1
@@ -1999,9 +2026,9 @@ class ContinuousBatchingEngine:
                 co, [(self._token_budget, 1), (self.num_slots, n - 1)])
             co.set_phase("host-accept")
         if tr is not None:
-            tr.complete("launch", tl0,
-                        args={"packed_tokens": cursor, "fused_steps": n})
-            th0 = tr.now()
+            sp.end()
+            launch.end({"packed_tokens": cursor, "fused_steps": n})
+            sp = tr.span("host-accept")
         if active:
             # decode rows adopt the post-scan key walk; chunk/idle rows
             # keep their host-side key state (a final chunk adopts its
@@ -2024,10 +2051,8 @@ class ContinuousBatchingEngine:
                     s.launches += 1     # rode this step's one program
             self._accept_decode_rows(toks_np, n, dec_mask, finished)
         if tr is not None:
-            tr.complete("host-accept", th0,
-                        args={"emitted": (n * len(active) if active
-                                          else 0)})
-        return cursor + (n - 1) * len(active), bool(chunk_rows)
+            sp.end({"emitted": n * len(active)})
+        return cursor + (n - 1) * len(active), cursor - len(active)
 
     def _pack_decode_rows(self, n, ids, seg, pos, qstart, qlen, kvlen,
                           dec_mask, temps, topks, eos_ids=None,
@@ -2118,9 +2143,9 @@ class ContinuousBatchingEngine:
         first EOS/budget cut — byte-identical to tick-at-a-time —
         and adopts each surviving row's PRNG key at its trim cut from
         the returned key walk. Returns ``(tokens_processed,
-        had_chunks)`` for the headroom EWMAs."""
+        chunk_tokens)`` as :meth:`_unified_step` does."""
         tr = self._tr()
-        tp0 = tr.now() if tr is not None else None
+        sp = tr.span("plan") if tr is not None else None
         co = self._co()
         if co is not None:
             co.set_phase("plan")
@@ -2132,7 +2157,9 @@ class ContinuousBatchingEngine:
         active = [s for s in self._slots
                   if s is not None and s.status == "running"]
         if not active and not plan:
-            return 0, False
+            if tr is not None:
+                sp.end({"rows": 0, "chunks": 0})
+            return 0, 0
         n = self.scheduler.choose_decode_ticks(active,
                                                self._decode_ticks)
         R, T = self.num_slots, self._token_budget
@@ -2160,11 +2187,13 @@ class ContinuousBatchingEngine:
         chunk_rows, cursor = self._pack_chunk_rows(
             plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
             temps, topks)
+        chunk_tokens = cursor - len(active)
         if tr is not None:
-            tr.complete("plan", tp0,
-                        args={"rows": len(active), "chunks": len(plan),
-                              "ticks": n})
-            tl0 = tr.now()
+            sp.end({"rows": len(active), "chunks": len(plan), "ticks": n})
+            launch = tr.span("launch")
+            sp = tr.span("dispatch", args=self._dispatch_args(
+                qstart, qlen, kvlen, T, len(active), n * len(active),
+                chunk_tokens))
         if co is not None:
             co.set_phase("launch")
         npk, npv, toks, kwalk, ticks_run = self._mtick_fn()(
@@ -2173,6 +2202,9 @@ class ContinuousBatchingEngine:
             dec_mask, keys, temps, topks, eos_ids, budgets,
             np.int32(n))
         self.cache.update(npk, npv)
+        if tr is not None:
+            sp.end()
+            sp = tr.span("device-wait")
         toks_np = np.asarray(toks)          # [max_ticks, R]
         kwalk_np = np.asarray(kwalk)        # [max_ticks, R, 2]
         ticks = int(ticks_run)              # <= n: early exit when all
@@ -2186,10 +2218,10 @@ class ContinuousBatchingEngine:
                      (self.num_slots, ticks - 1)])
             co.set_phase("host-accept")
         if tr is not None:
-            tr.complete("launch", tl0,
-                        args={"packed_tokens": cursor, "ticks": n,
-                              "ticks_run": ticks})
-            th0 = tr.now()
+            sp.end()
+            launch.end({"packed_tokens": cursor, "ticks": n,
+                        "ticks_run": ticks})
+            sp = tr.span("host-accept")
         # chunk bookkeeping first — mirrors the unified-step order (a
         # final chunk adopts tick 0's token/key, the same one split as
         # a one-shot prefill)
@@ -2237,10 +2269,8 @@ class ContinuousBatchingEngine:
             if adopted:
                 self._keys = jnp.asarray(knp)
         if tr is not None:
-            tr.complete("host-accept", th0,
-                        args={"emitted": emitted_total,
-                              "ticks_run": ticks})
-        return sum(c for _, c in plan) + emitted_total, bool(chunk_rows)
+            sp.end({"emitted": emitted_total, "ticks_run": ticks})
+        return chunk_tokens + emitted_total, chunk_tokens
 
     def _pack_chunk_rows(self, plan, cursor, ids, seg, pos, qstart, qlen,
                          kvlen, keys, temps, topks, sample_start=None):
@@ -2299,10 +2329,10 @@ class ContinuousBatchingEngine:
         with the chunk grant (``FIFOScheduler.spec_grants`` — a verify
         span spends ``1 + k`` positions), so chunk-heavy steps throttle
         speculation instead of overflowing the compile geometry.
-        Returns ``(tokens_processed, had_chunks)`` for the headroom
-        EWMAs."""
+        Returns ``(tokens_processed, chunk_tokens)`` as
+        :meth:`_unified_step` does."""
         tr = self._tr()
-        tp0 = tr.now() if tr is not None else None
+        sp = tr.span("plan") if tr is not None else None
         co = self._co()
         if co is not None:
             co.set_phase("plan")
@@ -2314,7 +2344,9 @@ class ContinuousBatchingEngine:
         active = [(slot, s) for slot, s in enumerate(self._slots)
                   if s is not None and s.status == "running"]
         if not active and not plan:
-            return 0, False
+            if tr is not None:
+                sp.end({"rows": 0, "chunks": 0})
+            return 0, 0
         R, T = self.num_slots, self._spec_budget
         lens = self.cache.lengths
         chunk_spend = sum(n for _, n in plan)
@@ -2366,10 +2398,12 @@ class ContinuousBatchingEngine:
             plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
             temps, topks, sample_start=sample_start)
         if tr is not None:
-            tr.complete("plan", tp0,
-                        args={"rows": len(active), "chunks": len(plan),
-                              "draft_tokens": int(sum(grants))})
-            tl0 = tr.now()
+            sp.end({"rows": len(active), "chunks": len(plan),
+                    "draft_tokens": int(sum(grants))})
+            launch = tr.span("launch")
+            sp = tr.span("dispatch", args=self._dispatch_args(
+                qstart, qlen, kvlen, T, len(verify_rows),
+                cursor - chunk_spend, chunk_spend))
         if co is not None:
             co.set_phase("launch")
         npk, npv, toks, kwalk = self._spec_fn()(
@@ -2377,6 +2411,9 @@ class ContinuousBatchingEngine:
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             sample_start, keys, temps, topks)
         self.cache.update(npk, npv)
+        if tr is not None:
+            sp.end()
+            sp = tr.span("device-wait")
         toks_np = np.asarray(toks)          # [spec_len, R]
         kwalk_np = np.asarray(kwalk)        # [spec_len, R, 2]
         self.stats["spec_steps"] += 1
@@ -2385,9 +2422,9 @@ class ContinuousBatchingEngine:
             self._record_collectives(co, [(self._spec_budget, 1)])
             co.set_phase("host-accept")
         if tr is not None:
-            tr.complete("launch", tl0,
-                        args={"packed_tokens": cursor})
-            th0 = tr.now()
+            sp.end()
+            launch.end({"packed_tokens": cursor})
+            sp = tr.span("host-accept")
         # chunk bookkeeping first — mirrors the unified-step order (a
         # final chunk adopts its walk-step-0 token/key, the same one
         # split as a one-shot prefill)
@@ -2452,17 +2489,17 @@ class ContinuousBatchingEngine:
                            args={"accept_lens": list(accept_lens),
                                  "proposed": [len(d) for _, _, d, _
                                               in verify_rows]})
-            tr.complete("host-accept", th0,
-                        args={"emitted": emitted_total})
-        return chunk_spend + emitted_total, bool(chunk_rows)
+            sp.end({"emitted": emitted_total})
+        return chunk_spend + emitted_total, chunk_spend
 
     def _two_program_step(self, finished):
         """The PR-5 two-program interleave (``ragged_step=False`` and
         the dense engine): at most one budgeted chunk call, then one
         fused decode call. Kept intact as the A/B baseline the unified
-        step is pinned byte-identical against."""
+        step is pinned byte-identical against. Returns
+        ``(tokens_processed, chunk_tokens)`` as :meth:`_unified_step`."""
         tr = self._tr()
-        tp0 = tr.now() if tr is not None else None
+        sp = tr.span("plan") if tr is not None else None
         co = self._co()
         if co is not None:
             # the chunk device calls below are this engine's prefill
@@ -2487,13 +2524,14 @@ class ContinuousBatchingEngine:
             # unified/spec paths emit plan unconditionally too). On
             # this two-program path the span covers the chunk device
             # calls as well — they ARE this engine's prefill plan.
-            tr.complete("plan", tp0,
-                        args={"rows": len(active), "chunks": len(plan),
-                              "fused_steps": n})
-            tl0 = tr.now()
+            sp.end({"rows": len(active), "chunks": len(plan),
+                    "fused_steps": n})
         if active:
             if co is not None:
                 co.set_phase("launch")
+            if tr is not None:
+                launch = tr.span("launch")
+                sp = tr.span("dispatch")
             if self._paged:
                 # append-block on decode growth: a fused chunk of n
                 # ticks writes rows [len, len+n) per slot, so the table
@@ -2533,12 +2571,16 @@ class ContinuousBatchingEngine:
                     self._temps, self._topks)
             self.cache.update(nk, nv)
             self._keys = keys
+            if tr is not None:
+                sp.end()
+                sp = tr.span("device-wait")
             toks_np = np.asarray(toks)  # [n, num_slots]
             if co is not None:
                 co.set_phase("host-accept")
             if tr is not None:
-                tr.complete("launch", tl0, args={"fused_steps": n})
-                th0 = tr.now()
+                sp.end()
+                launch.end({"fused_steps": n})
+                sp = tr.span("host-accept")
             self.stats["decode_calls"] += 1
             self.stats["decode_steps"] += n
             self.stats["slot_steps"] += n * self.num_slots
@@ -2559,9 +2601,8 @@ class ContinuousBatchingEngine:
                     self._emit(seq, t)
                     self._maybe_finish(seq, finished)
             if tr is not None:
-                tr.complete("host-accept", th0,
-                            args={"emitted": n * len(active)})
-        return chunk_tokens + n * len(active), bool(plan)
+                sp.end({"emitted": n * len(active)})
+        return chunk_tokens + n * len(active), chunk_tokens
 
     def has_work(self) -> bool:
         return bool(self.scheduler.num_queued

@@ -66,11 +66,14 @@ from __future__ import annotations
 import atexit
 import collections
 import itertools
+import os
 import queue
+import tempfile
 import threading
 import time
 import weakref
 
+import jax
 import numpy as np
 
 from ...profiler.cost import PROGRAM_KINDS, CostObservatory
@@ -98,7 +101,8 @@ CARRIED_ENGINE_STATS = (
     "preemptions", "policy_preemptions", "prefill_copy_dispatches",
     "prefill_chunks", "prefill_tokens_saved", "spec_proposed",
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
-    "mtick_syncs", "mtick_ticks")
+    "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
+    "step_decode_tokens")
 
 #: same carry for the prefix cache's own stats dict (a rebuild builds a
 #: fresh trie — and a fresh host tier — zeroing every counter here).
@@ -210,33 +214,6 @@ class TokenStream:
         self._events.put(("error", str(msg)))
 
 
-class _RateWindow:
-    """Sliding-window event rate (the tokens/s gauge): O(1) record via a
-    deque of (second-bucket, count) pairs, pruned at read time."""
-
-    def __init__(self, window_s=10.0):
-        self.window_s = float(window_s)
-        self._lock = threading.Lock()
-        self._buckets = collections.deque()  # (int second, count)
-
-    def record(self, n=1):
-        sec = int(time.monotonic())
-        with self._lock:
-            if self._buckets and self._buckets[-1][0] == sec:
-                self._buckets[-1][1] += n
-            else:
-                self._buckets.append([sec, n])
-
-    def rate(self):
-        now = time.monotonic()
-        horizon = now - self.window_s
-        with self._lock:
-            while self._buckets and self._buckets[0][0] < horizon:
-                self._buckets.popleft()
-            total = sum(c for _, c in self._buckets)
-        return total / self.window_s
-
-
 class ServingGateway:
     """Thread-safe front door + engine-driver thread.
 
@@ -326,9 +303,12 @@ class ServingGateway:
         # every engine incarnation. trace=True records from startup
         # (the --trace flag); otherwise the tracer sits disabled —
         # zero-cost — until /debug/trace?steps=N opens a capture
-        # window via capture_trace().
+        # window via capture_trace(). Its engine- and gateway-lane spans
+        # are mirrored into the JAX profiler's trace, so a device trace
+        # taken meanwhile carries them on the device's clock.
         self.tracer = tracer if tracer is not None else \
-            SpanTracer(capacity=trace_buffer, clock=self._clock)
+            SpanTracer(capacity=trace_buffer, clock=self._clock,
+                       annotate=jax.profiler.TraceAnnotation)
         #: public: whether tracing records continuously (``--trace``) —
         #: the HTTP layer keys its /debug/trace default on it (a
         #: parameterless GET must SNAPSHOT a persistent buffer, never
@@ -337,6 +317,7 @@ class ServingGateway:
         if self.trace_persistent:
             self.tracer.enable()
         self._capture = None        # {"remaining": n, "done": Event}
+        self._loop_span = None      # open from a step's end to the next
         # ---------------------------------------------- cost observatory
         # (README "Cost attribution & /debug/profile") gateway-owned
         # like the tracer, so dispatch/transfer/compile accounting is
@@ -452,7 +433,6 @@ class ServingGateway:
             "the admission-control half of TTFT. Never-admitted "
             "requests (queued timeout/cancel) are not observed.",
             buckets=QUEUE_WAIT_BUCKETS)
-        self._rate = _RateWindow()
         r.gauge("serving_queue_depth",
                 "Requests waiting for a slot (intake + scheduler queue)."
                 ).set_fn(lambda: self._backlog)
@@ -461,9 +441,17 @@ class ServingGateway:
             lambda: self.engine.num_active)
         r.gauge("serving_num_slots", "KV slot capacity.").set(
             self.engine.num_slots)
-        r.gauge("serving_tokens_per_second",
-                "Generated tokens/s over a 10s sliding window.").set_fn(
-            self._rate.rate)
+        step_tokens = r.counter(
+            "serving_step_tokens_total",
+            "Tokens the step programs processed, by kind: prefill "
+            "(chunk tokens packed into the step) and decode (decode "
+            "rows times their fused ticks). rate() of the decode "
+            "series is generated tokens/s. Monotonic across engine "
+            "rebuilds.")
+        step_tokens.set_fn(lambda: self._stat("step_prefill_tokens"),
+                           kind="prefill")
+        step_tokens.set_fn(lambda: self._stat("step_decode_tokens"),
+                           kind="decode")
         r.gauge("serving_decode_compilations",
                 "Decode-program traces (compile-once contract: stays at "
                 "one per (num_slots, max_seq_len, n_steps)).").set_fn(
@@ -871,7 +859,6 @@ class ServingGateway:
     def _on_token(self, seq, token):
         stream = self._live.get(seq.request_id)
         self._m_tokens.inc()
-        self._rate.record()
         if stream is None:
             return
         if stream.first_token_time is None:
@@ -1117,6 +1104,7 @@ class ServingGateway:
                 if self.engine.has_work():
                     self._step_supervised()
                     continue
+                self._end_loop_span()   # the idle wait is not the loop
                 with self._lock:
                     drained = (not self._intake and not self._live
                                and not self._parked
@@ -1167,7 +1155,13 @@ class ServingGateway:
             # restart budget on healthy cold starts
             traces0 = (self.engine.decode_compilations()
                        + self.engine.prefill_compilations())
+            self._end_loop_span()
             self.engine.step()
+            tr = self._tr()
+            if tr is not None:
+                # the driver's own work between two engine steps: the
+                # checks below, then intake, cancels, deadlines, captures
+                self._loop_span = tr.span("loop", tid=TID_GATEWAY)
             dt = self._clock() - t0
             compiled = (self.engine.decode_compilations()
                         + self.engine.prefill_compilations()) > traces0
@@ -1195,6 +1189,11 @@ class ServingGateway:
                 for m in lens:
                     self._m_spec_len.observe(m)
                 self.engine.stats["spec_last_accept"] = []
+
+    def _end_loop_span(self):
+        span, self._loop_span = self._loop_span, None
+        if span is not None:
+            span.end()
 
     def _classify(self, exc) -> str:
         if isinstance(exc, WatchdogTimeout):
@@ -1503,9 +1502,16 @@ class ServingGateway:
                     pc["end"] = self._profile_snapshot()
                     pc["done"].set()
 
-    def capture_trace(self, steps=32, timeout_s=30.0):
+    def capture_trace(self, steps=32, timeout_s=30.0, xplane=False):
         """Capture ``steps`` engine steps of trace and return the
         Chrome trace document (the ``GET /debug/trace`` body).
+
+        ``xplane=True`` (``GET /debug/xplane``) runs the JAX profiler
+        over the same steps, into a new directory named by the
+        document's ``otherData["xplane_dir"]``: the device's ops, and
+        on the host plane the engine- and gateway-lane spans of this
+        document on the device's clock. A profiler session someone else
+        holds is a busy capture too.
 
         ``steps <= 0`` snapshots the current buffer without touching
         recording state — the natural read when tracing is persistent
@@ -1530,7 +1536,17 @@ class ServingGateway:
             done = threading.Event()
             self._capture = {"remaining": int(steps), "done": done,
                              "armed": False}
+        xplane_dir = None
         try:
+            if xplane:
+                xplane_dir = tempfile.mkdtemp(prefix="xplane-")
+                try:
+                    jax.profiler.start_trace(xplane_dir)
+                except RuntimeError as e:
+                    os.rmdir(xplane_dir)
+                    xplane_dir = None
+                    raise TraceBusyError(
+                        f"the JAX profiler is already tracing: {e}")
             self._wake.set()
             done.wait(timeout_s)
         finally:
@@ -1542,7 +1558,12 @@ class ServingGateway:
                 if cap is not None and cap["armed"] \
                         and not self.trace_persistent:
                     tr.disable()
-        return tr.export()
+            if xplane_dir is not None:
+                jax.profiler.stop_trace()
+        doc = tr.export()
+        if xplane_dir is not None:
+            doc["otherData"]["xplane_dir"] = xplane_dir
+        return doc
 
     # ------------------------------------------------------ cost profile
     def _profile_snapshot(self) -> dict:

@@ -24,6 +24,11 @@ Endpoints:
   JSON (load in Perfetto; README "Tracing & debugging").
   ``steps=0`` snapshots the current buffer (the persistent ``--trace``
   mode's read); a concurrent capture gets 409.
+- ``GET /debug/xplane?steps=N`` — the same capture with the JAX
+  profiler running over those steps: the document's
+  ``otherData.xplane_dir`` names the directory of the device trace
+  (XProf / Perfetto), whose host plane carries the engine's spans on
+  the device's clock. 409 while any capture or profiler session runs.
 - ``GET /debug/requests`` — live request table: per-request state,
   slot, token progress, queue-wait/TTFT/TPOT-so-far, KV footprint plus
   the cost columns (device launches ridden, KV bytes held).
@@ -142,24 +147,27 @@ class _Handler(BaseHTTPRequestHandler):
                 "last_step_age_s": round(gw.last_step_age(), 3),
                 "engine_restarts": gw.restarts,
             })
-        elif path == "/debug/trace":
+        elif path in ("/debug/trace", "/debug/xplane"):
             qs = parse_qs(query)
+            xplane = path == "/debug/xplane"
             # persistent (--trace) servers default to a SNAPSHOT: a
             # parameterless probe must never clear hours of recorded
             # history — opening a fresh window there takes an explicit
-            # steps=N
+            # steps=N (a device trace always is a fresh window)
             default_steps = "0" if self.gateway.trace_persistent \
-                else "32"
+                and not xplane else "32"
             try:
                 steps = int(qs.get("steps", [default_steps])[0])
                 timeout_s = float(qs.get("timeout_s", ["30"])[0])
+                if xplane and steps <= 0:
+                    raise ValueError("steps must be positive")
             except ValueError as e:
                 self._error(400, f"bad query parameter: {e}",
                             "invalid_request")
                 return
             try:
-                doc = self.gateway.capture_trace(steps=steps,
-                                                 timeout_s=timeout_s)
+                doc = self.gateway.capture_trace(
+                    steps=steps, timeout_s=timeout_s, xplane=xplane)
             except TraceBusyError as e:
                 self._error(409, str(e), "conflict")
                 return

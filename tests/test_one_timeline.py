@@ -1,0 +1,402 @@
+"""One timeline (ISSUE 24): the span tracer's begin / end form and its
+``annotate`` mirror, the ``launch`` span's two children and the gateway's
+``loop`` span, the clock's stated origin, what each step asked of the ragged
+kernel (``ragged_grid_counts``, the ``dispatch`` span's args,
+``serving_step_tokens_total``), the names on the kernels and the training
+phases, and ``GET /debug/xplane``.
+"""
+import glob
+import json
+import os
+import time
+import urllib.error
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.kernels.pallas_ragged_attention import (_query_block,
+                                                        ragged_grid_counts)
+from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+from paddle_tpu.profiler import chrometrace
+from paddle_tpu.profiler.tracing import (NULL_SPAN, TID_ENGINE, TID_GATEWAY,
+                                         TID_REQ0, SpanTracer)
+from paddle_tpu.serving import (ContinuousBatchingEngine, GenerationRequest,
+                                VirtualClock)
+from paddle_tpu.serving.server import serve
+
+from test_metrics_prom import parse_prometheus
+
+NUM_SLOTS, S_MAX = 2, 256
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(31)
+    return LlamaForCausalLM(llama_tiny())
+
+
+@pytest.fixture(scope="module")
+def jit_cache():
+    return {}
+
+
+def _engine(model, jit_cache, tracer=None, **kw):
+    kw.setdefault("num_slots", NUM_SLOTS)
+    kw.setdefault("max_seq_len", S_MAX)
+    kw.setdefault("decode_chunk", 1)
+    eng = ContinuousBatchingEngine(model, jit_cache=jit_cache, **kw)
+    eng.tracer = tracer
+    return eng
+
+
+def _reqs():
+    long = np.arange(1, 81, dtype=np.int32)        # chunked: 32 + 32 + 16
+    return [GenerationRequest(prompt=long, max_new_tokens=4),
+            GenerationRequest(prompt=[5, 6, 7, 8], max_new_tokens=6)]
+
+
+class Mirror:
+    """An ``annotate`` factory that records what it was asked to open."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+
+    def __call__(self, name, **args):
+        mirror = self
+
+        class _Ann:
+            def __enter__(self):
+                mirror.opened.append((name, args))
+                return self
+
+            def __exit__(self, *exc):
+                mirror.closed.append(name)
+                return False
+        return _Ann()
+
+
+class CountingClock:
+    def __init__(self):
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.reads * 1e-3
+
+
+# ------------------------------------------------------------- the tracer
+class TestSpanForm:
+    def test_end_merges_args_and_mirror_sees_the_start_args(self):
+        mirror = Mirror()
+        tr = SpanTracer(clock=VirtualClock(), annotate=mirror).enable()
+        sp = tr.span("step", args={"step": 7})
+        sp.end({"tokens": 3})
+        with tr.span("loop", tid=TID_GATEWAY):
+            pass
+        evs = tr.events()
+        assert evs[0]["args"] == {"step": 7, "tokens": 3}
+        assert list(evs[0]["args"]) == ["step", "tokens"]
+        assert "args" not in evs[1] and evs[1]["tid"] == TID_GATEWAY
+        assert mirror.opened == [("step", {"step": 7}), ("loop", {})]
+        assert mirror.closed == ["step", "loop"]
+
+    def test_request_lanes_are_not_mirrored(self):
+        mirror = Mirror()
+        tr = SpanTracer(clock=VirtualClock(), annotate=mirror).enable()
+        tr.span("decode", tid=TID_REQ0).end()
+        tr.complete("queued", None, tid=TID_REQ0 + 1)
+        assert mirror.opened == [] and len(tr.events()) == 2
+
+    def test_disabled_opens_nothing_and_reads_no_clock(self):
+        mirror, clock = Mirror(), CountingClock()
+        tr = SpanTracer(clock=clock, annotate=mirror)
+        sp = tr.span("step", args={"step": 0})
+        assert sp is NULL_SPAN
+        sp.end({"tokens": 1})
+        assert mirror.opened == [] and clock.reads == 0
+        assert tr.events() == []
+
+    def test_mirror_closes_when_the_tracer_stopped_meanwhile(self):
+        mirror = Mirror()
+        tr = SpanTracer(clock=VirtualClock(), annotate=mirror).enable()
+        sp = tr.span("loop", tid=TID_GATEWAY)
+        tr.disable()
+        sp.end()
+        assert mirror.closed == ["loop"] and tr.events() == []
+
+    def test_clock_origin_is_stated(self):
+        tr = SpanTracer().enable()            # a real clock
+        origin = tr.export()["otherData"]["clock"]
+        assert abs(origin["epoch_unix_ns"] - time.time_ns()) < 60e9
+        assert abs(origin["epoch_s"] - time.perf_counter()) < 60
+        vc = VirtualClock(start=5.0)
+        origin = SpanTracer(clock=vc).enable().export()["otherData"]["clock"]
+        assert origin == {"epoch_s": 5.0}     # no wall clock in a replay
+
+
+# ----------------------------------------------------- engine and gateway
+class TestEngineSpans:
+    def _run(self, model, jit_cache, annotate, clock=VirtualClock):
+        tr = SpanTracer(clock=clock(), annotate=annotate).enable()
+        eng = _engine(model, jit_cache, tracer=tr, prefill_chunk=32,
+                      prefix_block_size=8)
+        outs = eng.generate(_reqs())
+        return eng, tr, [o.tolist() for o in outs]
+
+    def test_mirror_gets_every_engine_span_once_and_bytes_do_not_move(
+            self, model, jit_cache):
+        _, plain, toks0 = self._run(model, jit_cache, None)
+        mirror = Mirror()
+        eng, tr, toks1 = self._run(model, jit_cache, mirror)
+        assert toks0 == toks1
+        # the same bytes with and without a factory (VirtualClock replay)
+        assert json.dumps(plain.export(), sort_keys=True) \
+            == json.dumps(tr.export(), sort_keys=True)
+        lane = [e for e in tr.events()
+                if e["ph"] == "X" and e["tid"] == TID_ENGINE]
+        assert sorted(n for n, _ in mirror.opened) \
+            == sorted(e["name"] for e in lane)
+        assert sorted(mirror.closed) == sorted(n for n, _ in mirror.opened)
+        steps = [a["step"] for n, a in mirror.opened if n == "step"]
+        assert steps == list(range(eng.stats["steps"]))
+
+    def test_disabled_engine_touches_neither_mirror_nor_clock(
+            self, model, jit_cache):
+        mirror, clock = Mirror(), CountingClock()
+        tr = SpanTracer(clock=clock, annotate=mirror)       # never enabled
+        eng = _engine(model, jit_cache, tracer=tr, prefill_chunk=32,
+                      prefix_block_size=8)
+        eng.generate(_reqs())
+        assert mirror.opened == [] and clock.reads == 0
+
+    def test_launch_holds_dispatch_and_device_wait(self, model, jit_cache):
+        _, tr, _ = self._run(model, jit_cache, None,
+                             clock=lambda: time.perf_counter)
+        evs = [e for e in tr.events() if e["ph"] == "X"]
+        launches = [e for e in evs if e["name"] == "launch"]
+        assert launches
+        for parent in launches:
+            lo, hi = parent["ts"], parent["ts"] + parent["dur"]
+            kids = [e["name"] for e in evs
+                    if e["name"] in ("dispatch", "device-wait")
+                    and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
+            assert kids == ["dispatch", "device-wait"]
+        rows = {(r["lane"], r["name"]): r
+                for r in chrometrace.span_self_times(tr.events())}
+        lane = chrometrace.lane_name(TID_ENGINE)
+        # self times balance: a parent's total is its own time plus its
+        # children's, all the way up to the step
+        kids = rows[lane, "dispatch"]["total_ms"] \
+            + rows[lane, "device-wait"]["total_ms"]
+        assert rows[lane, "launch"]["self_ms"] == pytest.approx(
+            rows[lane, "launch"]["total_ms"] - kids, abs=2e-3)
+        names = ("step", "admit", "plan", "launch", "dispatch",
+                 "device-wait", "host-accept", "donate", "prefill_launch")
+        assert sum(rows[lane, n]["self_ms"] for n in names
+                   if (lane, n) in rows) == pytest.approx(
+            rows[lane, "step"]["total_ms"], abs=2e-2)
+
+    def test_dispatch_args_say_what_the_step_asked(self, model, jit_cache):
+        eng, tr, _ = self._run(model, jit_cache, None)
+        evs = tr.events()
+        disp = [e["args"] for e in evs if e["name"] == "dispatch"]
+        steps = [e["args"] for e in evs if e["name"] == "step"]
+        assert len(disp) == eng.stats["unified_steps"] > 0
+        heads = model.config.num_attention_heads
+        T = eng._token_budget
+        nq = -(-(T * heads) // _query_block(256, heads, T))
+        for a in disp:
+            assert a["grid_steps"] == nq * NUM_SLOTS * eng.cache.max_blocks
+            assert 0 < a["live_steps"] <= a["grid_steps"]
+            assert a["attn_pairs"] >= a["kv_tokens"] > 0
+        # every token a step span counts is a prefill or a decode token
+        assert sum(a["prefill_tokens"] + a["decode_tokens"] for a in disp) \
+            == sum(s["tokens"] for s in steps)
+        assert sum(a["prefill_tokens"] for a in disp) == 80
+        assert eng.stats["step_prefill_tokens"] == 80
+        assert eng.stats["step_decode_tokens"] \
+            == sum(a["decode_tokens"] for a in disp)
+
+
+class TestGatewaySpansAndCounter:
+    def test_loop_span_counter_and_no_rate_gauge(self, model):
+        srv = serve(model, port=0, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
+                    prefill_chunk=32, trace=True)
+        try:
+            gw = srv.gateway
+            assert gw.tracer.annotate is jax.profiler.TraceAnnotation
+            for s in [gw.submit(r) for r in _reqs()]:
+                s.result()
+            # a stream's result arrives inside its last step: let the
+            # driver finish that step and exit before anything is read
+            assert gw.shutdown(drain=True, timeout=60)
+            assert gw._loop_span is None
+            evs = gw.tracer.events()
+            loops = [e for e in evs if e["name"] == "loop"]
+            steps = sorted((e for e in evs if e["name"] == "step"),
+                           key=lambda e: e["ts"])
+            assert loops and all(e["tid"] == TID_GATEWAY for e in loops)
+            assert len(loops) == len(steps)
+            # a loop span starts where a step ended, and ends before the
+            # next step starts
+            ends = [s["ts"] + s["dur"] for s in steps]
+            for lp in loops:
+                assert any(e <= lp["ts"] + 1e-3 for e in ends)
+                later = [s["ts"] for s in steps if s["ts"] >= lp["ts"]]
+                if later:
+                    assert lp["ts"] + lp["dur"] <= min(later) + 1e-3
+            text = gw.registry.render()
+            fams = parse_prometheus(text)
+            series = {labels: v for (_, labels), v in
+                      fams["serving_step_tokens_total"]["samples"].items()}
+            assert set(series) == {(("kind", "decode"),),
+                                   (("kind", "prefill"),)}
+            assert sum(series.values()) \
+                == sum(s["args"]["tokens"] for s in steps)
+            assert series[("kind", "prefill"),] == 80
+            assert "serving_tokens_per_second" not in text
+        finally:
+            srv.shutdown(drain=False, timeout=30)
+
+
+class TestDebugXplane:
+    def test_capture_returns_the_directory_and_the_spans(self, model,
+                                                         tmp_path):
+        srv = serve(model, port=0, num_slots=NUM_SLOTS, max_seq_len=S_MAX)
+        try:
+            srv.gateway.submit(GenerationRequest(
+                prompt=[1, 2, 3, 4], max_new_tokens=2)).result()
+            stream = srv.gateway.submit(GenerationRequest(
+                prompt=[9, 10, 11, 12], max_new_tokens=240))
+            # a profiler session someone else holds is a busy capture
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                with pytest.raises(urllib.error.HTTPError) as busy:
+                    urllib.request.urlopen(
+                        srv.url + "/debug/xplane?steps=1", timeout=60)
+                assert busy.value.code == 409
+                assert srv.gateway._capture is None
+            finally:
+                jax.profiler.stop_trace()
+            with urllib.request.urlopen(
+                    srv.url + "/debug/xplane?steps=3&timeout_s=60",
+                    timeout=120) as r:
+                doc = json.load(r)
+            stream.cancel()
+            xdir = doc["otherData"]["xplane_dir"]
+            assert glob.glob(os.path.join(xdir, "plugins", "profile", "*",
+                                          "*.xplane.pb"))
+            steps = [e for e in doc["traceEvents"] if e["name"] == "step"]
+            assert len(steps) == 3
+            assert srv.gateway.tracer.enabled is False
+            from jax.profiler import ProfileData
+            path = glob.glob(os.path.join(xdir, "plugins", "profile", "*",
+                                          "*.xplane.pb"))[0]
+            names = {ev.name for plane in ProfileData.from_file(path).planes
+                     if plane.name == "/host:CPU"
+                     for line in plane.lines for ev in line.events}
+            assert {"step", "plan", "launch", "dispatch", "device-wait",
+                    "host-accept", "loop"} <= names
+            with pytest.raises(urllib.error.HTTPError) as bad:
+                urllib.request.urlopen(srv.url + "/debug/xplane?steps=0")
+            assert bad.value.code == 400
+        finally:
+            srv.shutdown(drain=False, timeout=30)
+
+
+# ------------------------------------------------ the kernel's work counter
+def _brute_force(qstart, qlen, kvlen, heads, block_q, block_size,
+                 table_entries, packed_tokens):
+    """The kernel's own predicate, enumerated over its whole grid."""
+    bq = _query_block(block_q, heads, packed_tokens)
+    nq = -(-(packed_tokens * heads) // bq)
+    live = 0
+    for qi in range(nq):
+        row0 = qi * bq
+        for r in range(len(qstart)):
+            lo = qstart[r] * heads
+            hi = (qstart[r] + qlen[r]) * heads
+            inter = (lo < row0 + bq) and (hi > row0)
+            for ki in range(table_entries):
+                live += bool(inter and ki * block_size < kvlen[r])
+    pairs = sum(sum(kl - ql + i + 1 for i in range(ql))
+                for ql, kl in zip(qlen, kvlen) if ql)
+    return {"grid_steps": nq * len(qstart) * table_entries,
+            "live_steps": live,
+            "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
+            "attn_pairs": pairs}
+
+
+GRID_CASES = {
+    "decode_only": ([0, 1, 2, 0], [1, 1, 1, 0], [17, 64, 65, 0]),
+    "chunk_only": ([0, 0, 0, 0], [0, 40, 0, 0], [0, 104, 0, 0]),
+    "mixed": ([0, 1, 2, 0], [1, 1, 37, 0], [200, 33, 37, 0]),
+    # a dead row (qlen 0) whose stale qstart and kvlen point inside a
+    # query block: the kernel's predicate lets it through, so must we
+    "dead_rows": ([0, 3, 9, 1], [1, 0, 0, 2], [9, 50, 0, 2]),
+    "all_dead": ([0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+@pytest.mark.parametrize("heads,block_q", [(4, 16), (32, 256), (3, 8)])
+def test_ragged_grid_counts_equals_enumeration(case, heads, block_q):
+    qstart, qlen, kvlen = GRID_CASES[case]
+    kw = dict(heads=heads, block_q=block_q, block_size=16,
+              table_entries=8, packed_tokens=40)
+    assert ragged_grid_counts(np.asarray(qstart), np.asarray(qlen),
+                              np.asarray(kvlen), **kw) \
+        == _brute_force(qstart, qlen, kvlen, **kw)
+
+
+# ---------------------------------------------- names on the device's work
+class TestNamesInTheProgram:
+    def test_ragged_step_names_its_kernel_and_blocks(self, model):
+        eng = ContinuousBatchingEngine(
+            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1,
+            jit_cache={})
+        assert model.config.decode_attention == "pallas"
+        R, T = NUM_SLOTS, eng._token_budget
+        z = lambda n, dt=np.int32: np.zeros(n, dt)      # noqa: E731
+        text = eng._ragged_fn(1).lower(
+            eng._params, *eng.cache.kv_args(), eng.cache.tables, z(T),
+            np.full(T, R, np.int32), z(T), z(R), z(R), z(R), z(R),
+            np.asarray(eng._keys, np.uint32), z(R, np.float32),
+            z(R)).as_text(debug_info=True)
+        for scope in ("ragged_step", "attn", "mlp", "lm_head", "sample",
+                      "ragged_paged_attention"):
+            # a scan body's ops start their name at the body's own scope
+            assert f"/{scope}/" in text or f'"{scope}/' in text, scope
+
+    def test_train_step_names_its_phases_and_kernels(self, monkeypatch):
+        from paddle_tpu.jit import TrainStep
+        from paddle_tpu.kernels import flash_attention
+        from paddle_tpu.optimizer import AdamW
+        monkeypatch.setattr(flash_attention, "_use_pallas", lambda s: True)
+        paddle.seed(0)
+        m = LlamaForCausalLM(llama_tiny(attention_layout="bhsd"))
+        step = TrainStep(m, lambda loss, _lab: loss,
+                         AdamW(parameters=m.parameters(),
+                               learning_rate=1e-3))
+        ids = jnp.zeros((2, 128), jnp.int32)
+        text = step._compiled.lower(
+            step._params, step._buffers, step._opt_state, (ids, ids),
+            (ids,), np.float32(1e-3),
+            jax.random.PRNGKey(0)).as_text(debug_info=True)
+        for name in ("/optimizer/", "jvp(loss)", "transpose(jvp(loss))",
+                     "rematted_computation", "/mlp/", "flash_fwd",
+                     "flash_bwd_dkv", "flash_bwd_dq"):
+            assert name in text, name
+        # and the step itself is one named annotation a step
+        seen = []
+        monkeypatch.setattr(
+            jax.profiler, "StepTraceAnnotation",
+            lambda name, **kw: seen.append((name, kw)) or NULL_SPAN)
+        step.step((ids, ids), (ids,))
+        step.step((ids, ids), (ids,))
+        assert seen == [("train_step", {"step_num": 0}),
+                        ("train_step", {"step_num": 1})]
