@@ -30,28 +30,40 @@ Semantics per sequence ``r`` (dead rows carry ``query_len == 0``):
   positions ``0 .. pos`` through ``tables[r]``;
 - packed rows outside every span produce exact zeros.
 
-Design points, inherited from ``pallas_paged_decode.py`` (same
-Mosaic-conservative lowering, same block-diagonal wide-query GQA
-trick, same table-indirect DMA):
+Design points (the block-diagonal wide-query GQA trick, the table-indirect
+fetch and the Mosaic-conservative 2D tiles are ``pallas_paged_decode.py``'s):
 
-- **Table-indirect DMA + ragged skip**: the KV BlockSpec index map
-  resolves the scalar-prefetched table at DMA-issue time; blocks fully
-  past ``kv_len[r]`` re-reference the last valid block (copy elided on
-  repeat), so HBM traffic scales with the live logical cache. Sentinel
-  entries (``>= num_blocks``) clamp into the pool — a harmless read,
-  masked off.
-- **Span-block gating**: the packed wide-query array is tiled into
-  fixed query blocks; a grid step whose query block does not intersect
-  sequence ``r``'s span is ``pl.when``-gated off entirely (and its KV
-  fetch repeats the resident block, so it costs neither HBM nor MXU).
-  MXU work on the masked remainder of an intersecting block is the
-  same idle-MXU trade the wide-query trick already makes — decode is
-  HBM-bound and KV traffic is unchanged.
+- **The iteration space is the step's live work**, built from the span
+  metadata inside the program (``_work_list``; no host work, no extra
+  argument). The grid is a *work list* of (query block, row) pairs: the
+  packed wide-query array is tiled into fixed query blocks, the packed
+  spans are disjoint and contiguous, so at most ``nq + R`` pairs
+  intersect, ordered by query block then row. A query block that no span
+  touches gets one entry that only zeroes its output; unused entries
+  repeat the last block (no DMA, no compute). Nothing is visited for a
+  (query block, row) pair that does not intersect, or for a dead row.
+- **The KV walk ends where the pair's work ends**: inside a grid step a
+  ``fori_loop`` runs over exactly the pair's KV blocks — up to the row's
+  own ``kv_len`` AND the causal diagonal of the last span token inside
+  the query block, so the early query blocks of a chunk never touch the
+  blocks their mask would remove. The pool stays in HBM (``pl.ANY``); each
+  iteration resolves the scalar-prefetched table in SMEM and fetches one
+  block by double-buffered ``make_async_copy`` (the next block streams in
+  while this one computes), so HBM traffic and MXU work both scale with
+  the live logical cache. Sentinel entries (``>= num_blocks``) clamp into
+  the pool — a harmless read, masked off. An int8 / fp8 pool's scale
+  planes ride the same physical index as their data block.
+- **One output block, several rows**: visits to an output block are
+  consecutive; the first zeroes it, each row's visit writes back only its
+  own span by a masked read-modify-write. MXU work on the masked remainder
+  of an intersecting query block is the same idle-MXU trade the wide-query
+  trick already makes.
 - **2D-tile conservatism**: all blocks are 2D/leading-1 tiles whose
   last-two dims equal the full array dims; compute is plain 2D
-  ``dot_general``; the per-row online-softmax state lives in VMEM
-  scratch exactly like the decode kernels, so span-1 rows reproduce
-  ``paged_decode_attention_pallas``'s accumulation order bit for bit.
+  ``dot_general``; one pool block per online-softmax update, blocks
+  ascending, the per-row state in VMEM scratch exactly like the decode
+  kernels, so span-1 rows reproduce ``paged_decode_attention_pallas``'s
+  accumulation order bit for bit.
 
 Inference-only (no VJP): the serving step never backpropagates.
 """
@@ -71,20 +83,26 @@ from .pallas_paged_decode import _block_scale_vec, _head_scale_mat
 NEG_INF = -1e30
 
 
-def _ragged_kernel(qs_ref, ql_ref, kl_ref, tbl_ref, *refs, scale,
-                   block_k, tq, gh, quantized=False, hkv=0):
+def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
+                   tbl_ref, *refs, scale, block_k, tq, gh, num_blocks,
+                   quantized=False, hkv=0):
     # positional ref layout follows the pallas_call spec lists: inputs
-    # (q, k, v[, k_scale, v_scale]), then the output, then scratch
+    # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
+    # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc)
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
-         acc_scr) = refs
+        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
+         vs_buf, sems, m_scr, l_scr, acc_scr) = refs
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                   (vs_hbm, vs_buf))
     else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        ks_ref = vs_ref = None
-    qi = pl.program_id(0)
-    r = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr,
+         acc_scr) = refs
+        ks_buf = vs_buf = None
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    w = pl.program_id(0)            # one work-list entry: (query block, row)
+    qi = wq_ref[w]
+    r = wr_ref[w]
+    nkb = wn_ref[w]                 # KV blocks this pair walks (0 = dead)
     qstart = qs_ref[r]
     qlen = ql_ref[r]
     kvlen = kl_ref[r]
@@ -92,27 +110,30 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, tbl_ref, *refs, scale,
     span_lo = qstart * gh           # span bounds in wide-row coordinates
     span_hi = (qstart + qlen) * gh
 
-    @pl.when((r == 0) & (ki == 0))
+    @pl.when(wf_ref[w] == 1)
     def _zero_out():
         # first visit of this output block: packed rows outside every
         # span must come back as exact zeros, not stale VMEM
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def _copies(ki, slot):
+        # table-indirect fetch of logical block ki into buffer `slot`:
+        # the table is resolved from SMEM at DMA-issue time; sentinel
+        # entries clamp into the pool (a harmless read, masked by kvlen).
+        # The scale planes ride the SAME physical index as their data.
+        phys = jnp.clip(tbl_ref[r, ki], 0, num_blocks - 1)
+        return [
+            pltpu.make_async_copy(
+                # per-block fp8 planes are [num_blocks, hkv]: a one-row
+                # window keeps the buffer 2D like every other operand
+                hbm.at[pl.ds(phys, 1)] if hbm.ndim == 2 else hbm.at[phys],
+                buf.at[slot], sems.at[i, slot])
+            for i, (hbm, buf) in enumerate(streams)]
 
-    # compute only when this query block intersects the span AND the KV
-    # block is not fully past the row's valid length (ragged skip)
-    inter = (span_lo < row0 + tq) & (span_hi > row0)
-
-    @pl.when(inter & (ki * block_k < kvlen))
-    def _compute():
+    def _compute(ki, slot):
         q = q_ref[:]                        # [tq, KD] block-diag wide
-        k = k_ref[0]                        # [block_k, KD]
-        v = v_ref[0]
+        k = k_buf[slot]                     # [block_k, KD]
+        v = v_buf[slot]
         if quantized:
             # quantized pool: the table-indirect DMA above moved the
             # narrow dtype (the HBM win); the upcast happens HERE,
@@ -128,9 +149,9 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, tbl_ref, *refs, scale,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if quantized == "fp8":
-            s = s * _block_scale_vec(ks_ref[...], tq, gh, hkv)
+            s = s * _block_scale_vec(ks_buf[slot][:, :hkv], tq, gh, hkv)
         elif quantized:
-            s = s * _head_scale_mat(ks_ref[0], tq, gh, hkv)
+            s = s * _head_scale_mat(ks_buf[slot][:, :hkv], tq, gh, hkv)
         wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # causal-within-span: wide row w belongs to span token
@@ -156,18 +177,41 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, tbl_ref, *refs, scale,
         if quantized == "fp8":
             # per-block V scale: constant across pool rows, so it
             # collapses to a per-wide-row factor folded into P
-            p = p * _block_scale_vec(vs_ref[...], tq, gh, hkv)
+            p = p * _block_scale_vec(vs_buf[slot][:, :hkv], tq, gh, hkv)
         elif quantized:
             # V dequant, same separability: fold the scales into P
             # (P_wj * sv[j, head(w)]) and dot with the raw values
-            p = p * _head_scale_mat(vs_ref[0], tq, gh, hkv)
+            p = p * _head_scale_mat(vs_buf[slot][:, :hkv], tq, gh, hkv)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
+    @pl.when(nkb > 0)
+    def _walk():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+        for c in _copies(0, 0):
+            c.start()
+
+        def _block(ki, carry):
+            # double buffer: block ki + 1 streams in while ki computes
+            slot = ki % 2
+
+            @pl.when(ki + 1 < nkb)
+            def _prefetch():
+                for c in _copies(ki + 1, 1 - slot):
+                    c.start()
+
+            for c in _copies(ki, slot):
+                c.wait()
+            _compute(ki, slot)
+            return carry
+
+        # exactly this pair's blocks, ascending: the row's own length and
+        # the causal diagonal both already bound nkb (_work_list)
+        jax.lax.fori_loop(0, nkb, _block, 0)
         # write back ONLY this row's span: the output block is shared by
         # every sequence whose span intersects it, so the write must be
         # a masked read-modify-write (rows not in span keep their value)
@@ -180,6 +224,65 @@ def _ragged_kernel(qs_ref, ql_ref, kl_ref, tbl_ref, *refs, scale,
                              o_ref[:])
 
 
+def _least(a, b):
+    """``min(a, b)`` for plain ints and for broadcasting int arrays alike."""
+    return a - (a > b) * (a - b)
+
+
+def _pair_kv_blocks(qs, ql, kl, qi, *, tokens_per_block, block_size,
+                    table_entries):
+    """KV blocks the pair (query block ``qi``, a live row) walks: up to the
+    row's own length AND the causal diagonal of the last span token inside
+    the query block. Works on ints and on broadcasting int arrays alike —
+    the kernel's work list and the host's counter share this one rule."""
+    last = _least(qs + ql, (qi + 1) * tokens_per_block) - 1
+    pos_max = kl - ql + (last - qs)     # that token's logical position
+    n = _least(_least(pos_max // block_size + 1, -(-kl // block_size)),
+               table_entries)
+    return n * (n > 0)
+
+
+def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
+               table_entries):
+    """The kernel's iteration space, from the step's span metadata (jnp,
+    inside the jitted program): ``nq + R`` entries ``(query block, row,
+    first visit of its output block, KV blocks to walk)`` ordered by query
+    block, then row. The packed spans are disjoint and contiguous, so at
+    most ``nq + R - 1`` (query block, row) pairs intersect; a query block no
+    live span touches gets one dead entry (zero its output, walk nothing),
+    and the tail repeats the last entry dead (same blocks: no DMA)."""
+    R = qstart.shape[0]
+    W = nq + R
+    qi = jnp.arange(nq, dtype=jnp.int32)[:, None]
+    qs, ql, kl = qstart[None, :], qlen[None, :], kvlen[None, :]
+    inter = ((ql > 0) & (qs < (qi + 1) * tokens_per_block)
+             & (qs + ql > qi * tokens_per_block))               # [nq, R]
+    n_mat = jnp.where(inter, _pair_kv_blocks(
+        qs, ql, kl, qi, tokens_per_block=tokens_per_block,
+        block_size=block_size, table_entries=table_entries), 0)
+    # column R: the dead visit of a query block that no span touches
+    untouched = ~jnp.any(inter, axis=1, keepdims=True)
+    mask = jnp.concatenate([inter, untouched], axis=1).reshape(-1)
+    n_flat = jnp.concatenate(
+        [n_mat, jnp.zeros((nq, 1), jnp.int32)], axis=1).reshape(-1)
+    order = jnp.cumsum(mask.astype(jnp.int32))      # 1-based rank of a visit
+    j = jnp.arange(W, dtype=jnp.int32)
+    pad = j >= order[-1]
+    # entry j is the visit of rank j + 1 (the tail: of the last rank);
+    # a [W, nq * (R + 1)] one-hot select, so no gather and no sort
+    pick = mask[None, :] & (order[None, :]
+                            == jnp.minimum(j + 1, order[-1])[:, None])
+    flat = jnp.sum(jnp.where(
+        pick, jnp.arange(mask.shape[0], dtype=jnp.int32)[None, :], 0), axis=1)
+    wq = flat // (R + 1)
+    wr = flat % (R + 1)
+    wr = jnp.where(wr == R, 0, wr)
+    wn = jnp.where(pad, 0, jnp.sum(jnp.where(pick, n_flat[None, :], 0),
+                                   axis=1))
+    first = (j == 0) | (wq != jnp.roll(wq, 1))
+    return wq, wr, first.astype(jnp.int32), wn
+
+
 def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
                  scale, gh, block_q, interpret, scales=None):
     """q_wide: [TH_pad, KD] block-diagonal wide rows (gh per token);
@@ -188,70 +291,65 @@ def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
     quantized pool (upcast in-kernel, right after the table-indirect
     DMA): [num_blocks, bs, Hkv] per-row planes select the int8 path,
     [num_blocks, Hkv] per-block planes select fp8 — the plane rank IS
-    the mode switch, same convention as ``pallas_paged_decode``."""
+    the mode switch, same convention as ``pallas_paged_decode``.
+
+    The grid is the work list (``_work_list``): one step per (query block,
+    row) pair, and inside it a loop over exactly the pair's KV blocks, the
+    pool left in HBM and fetched block by block through the table."""
     TH, KD = q_wide.shape
     num_blocks, bs = pool_k.shape[0], pool_k.shape[1]
     R, nk = tables.shape
     nq = TH // block_q
-    grid = (nq, R, nk)
+    work = _work_list(qstart, qlen, kvlen, nq=nq,
+                      tokens_per_block=block_q // gh, block_size=bs,
+                      table_entries=nk)
     if scales is None:
         quantized = False
     else:
         quantized = "fp8" if scales[0].ndim == 2 else "int8"
     hkv = scales[0].shape[-1] if quantized else 0
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
-                               tq=block_q, gh=gh, quantized=quantized,
-                               hkv=hkv)
+                               tq=block_q, gh=gh, num_blocks=num_blocks,
+                               quantized=quantized, hkv=hkv)
 
-    def _kv_index(qi, r, ki, qs, ql, kl, tbl):
-        # table-indirect fetch with the decode kernel's ragged-skip
-        # clamp: steps past the last valid logical block re-reference it
-        # (copy elided on repeat), and sentinel entries clamp into the
-        # pool — a harmless read, masked by kv_len in the kernel.
-        last = (jnp.maximum(kl[r], 1) - 1) // bs
-        phys = tbl[r, jnp.minimum(ki, last)]
-        return (jnp.clip(phys, 0, num_blocks - 1), 0, 0)
+    def _q_index(w, wq, *_):
+        return (wq[w], 0)
 
-    def _q_index(qi, r, ki, qs, ql, kl, tbl):
-        return (qi, 0)
-
-    in_specs = [
-        pl.BlockSpec((block_q, KD), _q_index),
-        pl.BlockSpec((1, bs, KD), _kv_index),
-        pl.BlockSpec((1, bs, KD), _kv_index),
-    ]
-    args = [qstart, qlen, kvlen, tables, q_wide, pool_k, pool_v]
-    if quantized == "fp8":
-        # per-BLOCK planes [num_blocks, hkv]: one [1, hkv] scale row
-        # rides the same table-indirect fetch as its data block
-        def _kv_index2(qi, r, ki, qs, ql, kl, tbl):
-            return _kv_index(qi, r, ki, qs, ql, kl, tbl)[:2]
-        in_specs += [pl.BlockSpec((1, hkv), _kv_index2),
-                     pl.BlockSpec((1, hkv), _kv_index2)]
-        args += [scales[0], scales[1]]
-    elif quantized:
-        # the scale planes ride the SAME table-indirect index map as
-        # the data blocks: one block's scales arrive with its values
-        in_specs += [pl.BlockSpec((1, bs, hkv), _kv_index),
-                     pl.BlockSpec((1, bs, hkv), _kv_index)]
-        args += [scales[0], scales[1]]
+    in_pool = pl.BlockSpec(memory_space=pl.ANY)     # fetched by the kernel
+    in_specs = [pl.BlockSpec((block_q, KD), _q_index), in_pool, in_pool]
+    args = [*work, qstart, qlen, kvlen, tables, q_wide, pool_k, pool_v]
+    bufs = [pltpu.VMEM((2, bs, KD), pool_k.dtype),
+            pltpu.VMEM((2, bs, KD), pool_v.dtype)]
+    if quantized:
+        # per-row int8 planes [num_blocks, bs, hkv] move one [bs, hkv]
+        # block, per-BLOCK fp8 planes [num_blocks, hkv] one [1, hkv] row.
+        # A DMA window's minor dim must be whole lanes, so the planes are
+        # padded to 128 heads here and the kernel reads the first hkv
+        lanes = -(-hkv // 128) * 128
+        scales = [jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, lanes - hkv)])
+                  for p in scales]
+        plane = (1, lanes) if quantized == "fp8" else (bs, lanes)
+        in_specs += [in_pool, in_pool]
+        args += scales
+        bufs += [pltpu.VMEM((2,) + plane, p.dtype) for p in scales]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
+            num_scalar_prefetch=8,
+            grid=(nq + R,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_q, KD), _q_index),
-            scratch_shapes=[
+            scratch_shapes=bufs + [
+                pltpu.SemaphoreType.DMA((len(bufs), 2)),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, KD), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((TH, KD), q_wide.dtype),
-        # every grid dim revisits blocks (the output block is shared
-        # across r and accumulated across ki) — no reordering allowed
-        compiler_params=_cparams(("arbitrary", "arbitrary", "arbitrary")),
+        # consecutive entries revisit one output block (accumulated
+        # across rows by the masked write) — no reordering allowed
+        compiler_params=_cparams(("arbitrary",)),
         interpret=interpret,
         name="ragged_paged_attention",
     )(*args)
@@ -269,25 +367,27 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
                        table_entries, packed_tokens, block_q=256):
     """What one call of the kernel is asked to do, counted on the host from
     the step's span metadata (plain integers; no jax): ``grid_steps``, the
-    ``nq x R x nk`` steps of ``_ragged_call``'s grid; ``live_steps``, those
-    that pass ``_ragged_kernel``'s ``pl.when(inter & (ki * block_k <
-    kvlen))`` and compute; ``kv_tokens``, the cache rows the live spans
-    attend over; ``attn_pairs``, their causal (query, key) pairs. A row
-    with ``qlen == 0`` is dead."""
+    steps the kernel visits — the ``nq + R`` work-list entries of its grid
+    plus one per KV block its in-kernel loops walk; ``live_steps``, those
+    that compute (the loop iterations: a work-list entry itself only zeroes,
+    resets or writes back); ``kv_tokens``, the cache rows the live spans
+    attend over; ``attn_pairs``, their causal (query, key) pairs. A row with
+    ``qlen == 0`` is dead."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
-    nk = int(table_entries)
+    tpb = bq // heads
     live = kv_tokens = pairs = 0
     for qs, ql, kl in zip(qstart, qlen, kvlen):
         qs, ql, kl = int(qs), int(ql), int(kl)
-        lo, hi = qs * heads, (qs + ql) * heads
-        # query blocks qi with lo < (qi + 1) * bq and hi > qi * bq
-        n_q = min(nq, -(-hi // bq)) - min(nq, lo // bq)
-        live += max(n_q, 0) * min(nk, -(-kl // block_size))
-        if ql > 0:
-            kv_tokens += kl
-            pairs += ql * (kl - ql) + ql * (ql + 1) // 2
-    return {"grid_steps": nq * len(qstart) * nk, "live_steps": live,
+        if ql <= 0:
+            continue
+        kv_tokens += kl
+        pairs += ql * (kl - ql) + ql * (ql + 1) // 2
+        for qi in range(qs // tpb, min(nq, -(-(qs + ql) // tpb))):
+            live += _pair_kv_blocks(
+                qs, ql, kl, qi, tokens_per_block=tpb,
+                block_size=block_size, table_entries=int(table_entries))
+    return {"grid_steps": nq + len(qstart) + live, "live_steps": live,
             "kv_tokens": kv_tokens, "attn_pairs": pairs}
 
 
@@ -368,8 +468,12 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     returns:  [T, H, D]; packed rows outside every span are exact zeros
 
     GQA is resolved with the block-diagonal wide-query trick (see
-    ``pallas_decode.py``); KV blocks past a row's ``kvlen`` are never
-    fetched; sentinel table entries clamp harmlessly. A span of length 1
+    ``pallas_decode.py``). The kernel iterates over a work list of the
+    (query block, row) pairs that intersect, built here from ``qstart`` /
+    ``qlen``, and for each pair over the KV blocks up to the row's
+    ``kvlen`` and the causal diagonal: blocks past either are never
+    fetched, dead rows and non-intersecting pairs are never visited;
+    sentinel table entries clamp harmlessly. A span of length 1
     reproduces ``paged_decode_attention_pallas`` for that row exactly
     (same block walk, same online-softmax accumulation order).
     """

@@ -162,8 +162,8 @@ class ContinuousBatchingEngine:
 
     Substrate note: the unified program's packed buffer is a fixed
     ``num_slots + prefill_chunk`` tokens, which the TPU Pallas kernel
-    prices at the LIVE spans only (span-block gating + ragged DMA
-    skip) but the CPU ``decode_attention="jnp"`` oracle computes
+    prices at the LIVE spans only (its grid is a work list of
+    the spans' query blocks, its KV walk ends at each row's length) but the CPU ``decode_attention="jnp"`` oracle computes
     densely — on that correctness substrate a decode-only step pays
     the padding, so CPU deployments that never chunk should pass
     ``ragged_step=False`` (or ``prefill_chunk=None``, which sizes the

@@ -88,6 +88,35 @@ class TestMosaicCompilesDefaultPathKernels:
             v5e((self.R, self.MB), jnp.int32), row, row, row)
         assert n == 1
 
+    # what the serving cells run (benchmark/configs): 8 + 512 packed tokens,
+    # 8 slots x 128 table entries, the pool PagedKVCache builds for them
+    CELL_T, CELL_MB = 520, 128
+
+    def test_ragged_paged_attention_at_the_serving_cells_shape(
+            self, v5e, nh, nkv, hd):
+        pool = v5e((self.R * self.CELL_MB, self.BS, nkv, hd))
+        row = v5e((self.R,), jnp.int32)
+        n = _mosaic_calls(
+            pallas_ragged_attention.ragged_paged_attention_pallas,
+            v5e((self.CELL_T, nh, hd)), pool, pool,
+            v5e((self.R, self.CELL_MB), jnp.int32), row, row, row)
+        assert n == 1
+
+    def test_ragged_paged_attention_int8_pool(self, v5e, nh, nkv, hd):
+        """The scale planes are fetched by the kernel block by block, like
+        the data they scale: Mosaic must take that DMA too."""
+        pool = v5e((self.NB, self.BS, nkv, hd), jnp.int8)
+        plane = v5e((self.NB, self.BS, nkv), jnp.float32)
+        row = v5e((self.R,), jnp.int32)
+
+        def attend(q, pk, pv, tbl, qs, ql, kl, ks, vs):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tbl, qs, ql, kl, k_scale=ks, v_scale=vs)
+        n = _mosaic_calls(
+            attend, v5e((72, nh, hd)), pool, pool,
+            v5e((self.R, self.MB), jnp.int32), row, row, row, plane, plane)
+        assert n == 1
+
     def test_paged_decode_attention(self, v5e, nh, nkv, hd):
         pool = v5e((self.NB, self.BS, nkv, hd))
         n = _mosaic_calls(

@@ -210,8 +210,10 @@ class TestEngineSpans:
         T = eng._token_budget
         nq = -(-(T * heads) // _query_block(256, heads, T))
         for a in disp:
-            assert a["grid_steps"] == nq * NUM_SLOTS * eng.cache.max_blocks
-            assert 0 < a["live_steps"] <= a["grid_steps"]
+            # the work list's entries, plus the KV blocks the loops walk
+            assert a["grid_steps"] == nq + NUM_SLOTS + a["live_steps"]
+            assert 0 < a["live_steps"] <= (nq + NUM_SLOTS) \
+                * eng.cache.max_blocks
             assert a["attn_pairs"] >= a["kv_tokens"] > 0
         # every token a step span counts is a prefill or a decode token
         assert sum(a["prefill_tokens"] + a["decode_tokens"] for a in disp) \
@@ -309,23 +311,37 @@ class TestDebugXplane:
 
 
 # ------------------------------------------------ the kernel's work counter
-def _brute_force(qstart, qlen, kvlen, heads, block_q, block_size,
-                 table_entries, packed_tokens):
-    """The kernel's own predicate, enumerated over its whole grid."""
+def _live_pairs(qstart, qlen, kvlen, heads, block_q, block_size,
+                table_entries, packed_tokens):
+    """The kernel's own masks, token by token: ``{(query block, row): KV
+    blocks}`` for every pair whose query block holds a token of the row's
+    span, the blocks being those with a column some such token may see
+    (``col <= pos`` and ``col < kvlen``). Returns the pairs and ``nq``."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
-    live = 0
+    tpb = bq // heads
+    pairs = {}
     for qi in range(nq):
-        row0 = qi * bq
         for r in range(len(qstart)):
-            lo = qstart[r] * heads
-            hi = (qstart[r] + qlen[r]) * heads
-            inter = (lo < row0 + bq) and (hi > row0)
-            for ki in range(table_entries):
-                live += bool(inter and ki * block_size < kvlen[r])
+            pos = [kvlen[r] - qlen[r] + (t - qstart[r])
+                   for t in range(qstart[r], qstart[r] + qlen[r])
+                   if qi * tpb <= t < (qi + 1) * tpb]
+            if pos:
+                pairs[qi, r] = sum(
+                    any(ki * block_size <= min(p, kvlen[r] - 1) for p in pos)
+                    for ki in range(table_entries))
+    return pairs, nq
+
+
+def _brute_force(qstart, qlen, kvlen, **geometry):
+    """The steps the kernel visits: its work list (one entry a query block
+    or a row more than the pairs can ever be) plus every KV block its loops
+    walk; only the latter compute."""
+    walked, nq = _live_pairs(qstart, qlen, kvlen, **geometry)
+    live = sum(walked.values())
     pairs = sum(sum(kl - ql + i + 1 for i in range(ql))
                 for ql, kl in zip(qlen, kvlen) if ql)
-    return {"grid_steps": nq * len(qstart) * table_entries,
+    return {"grid_steps": nq + len(qstart) + live,
             "live_steps": live,
             "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
             "attn_pairs": pairs}
@@ -336,7 +352,7 @@ GRID_CASES = {
     "chunk_only": ([0, 0, 0, 0], [0, 40, 0, 0], [0, 104, 0, 0]),
     "mixed": ([0, 1, 2, 0], [1, 1, 37, 0], [200, 33, 37, 0]),
     # a dead row (qlen 0) whose stale qstart and kvlen point inside a
-    # query block: the kernel's predicate lets it through, so must we
+    # query block: it is on no work-list entry and walks nothing
     "dead_rows": ([0, 3, 9, 1], [1, 0, 0, 2], [9, 50, 0, 2]),
     "all_dead": ([0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0]),
 }
@@ -351,6 +367,30 @@ def test_ragged_grid_counts_equals_enumeration(case, heads, block_q):
     assert ragged_grid_counts(np.asarray(qstart), np.asarray(qlen),
                               np.asarray(kvlen), **kw) \
         == _brute_force(qstart, qlen, kvlen, **kw)
+
+
+# the serving cells' own geometry (benchmark/configs: 32 heads a chip, pool
+# blocks of 32, 8 slots x 128 table entries, 8 + 512 packed tokens) and a
+# step of each cell's kind, with the most grid steps a call may take
+CELL_STEPS = {
+    "chat_six_decode_rows": (
+        [0, 1, 2, 3, 4, 5, 0, 0], [1, 1, 1, 1, 1, 1, 0, 0],
+        [200, 311, 427, 512, 640, 768, 0, 0], 2_000),
+    "batch_chunk_and_decode_row": (
+        [1, 0, 0, 0, 0, 0, 0, 0], [484, 1, 0, 0, 0, 0, 0, 0],
+        [1508, 2307, 0, 0, 0, 0, 0, 0], 10_000),
+}
+
+
+@pytest.mark.parametrize("step", sorted(CELL_STEPS))
+def test_ragged_grid_counts_at_the_cells_geometry(step):
+    qstart, qlen, kvlen, most = CELL_STEPS[step]
+    kw = dict(heads=32, block_q=256, block_size=32, table_entries=128,
+              packed_tokens=520)
+    got = ragged_grid_counts(qstart, qlen, kvlen, **kw)
+    assert got == _brute_force(qstart, qlen, kvlen, **kw)
+    assert 65 + 8 < got["grid_steps"] < most
+    assert got["live_steps"] == got["grid_steps"] - (65 + 8)
 
 
 # ---------------------------------------------- names on the device's work

@@ -14,7 +14,12 @@ the unification itself must pin:
   (pallas vs pallas, reference vs reference) — the unified serving step
   cannot perturb decode numerics;
 - sentinel tables / dead rows / packed padding stay finite and come
-  back as exact zeros.
+  back as exact zeros;
+- the kernel's iteration space: its work list of (query block, row)
+  pairs and each pair's KV walk equal a token-by-token enumeration of
+  the masks, and the shapes that walk exercises (sparse decode tables,
+  a causal cut that differs per query block, holes in the packed
+  buffer, an int8 pool) match the oracle.
 """
 import jax
 import jax.numpy as jnp
@@ -24,7 +29,11 @@ import pytest
 from paddle_tpu.kernels.pallas_paged_decode import (
     paged_decode_attention_pallas, paged_decode_attention_reference)
 from paddle_tpu.kernels.pallas_ragged_attention import (
-    ragged_attention_reference, ragged_paged_attention_pallas)
+    _query_block, _work_list, ragged_attention_reference,
+    ragged_paged_attention_pallas)
+from paddle_tpu.serving.kv_cache import quantize_kv_rows
+
+from test_one_timeline import GRID_CASES, _live_pairs
 
 NEG_INF = -1e30
 
@@ -232,3 +241,143 @@ class TestRaggedKernelParity:
         want = ragged_attention_reference(*args)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------- the kernel's work list
+WORK_CASES = dict(GRID_CASES)
+WORK_CASES.update({
+    # (qstart, qlen, kvlen) over 40 packed tokens, 8 table entries of 16
+    "one_span_whole_buffer": ([0, 0, 0, 0], [40, 0, 0, 0], [128, 0, 0, 0]),
+    "spans_of_1_inside_block_0": ([0, 1, 2, 3], [1, 1, 1, 1],
+                                  [1, 16, 17, 128]),
+    "span_from_mid_block_to_mid_block": ([0, 3, 0, 0], [0, 19, 0, 0],
+                                         [0, 19, 0, 0]),
+    # a dead row whose stale qstart / kvlen point inside a live block
+    "stale_dead_row_inside_live_block": ([2, 4, 0, 30], [9, 0, 2, 0],
+                                         [77, 128, 2, 64]),
+    "rows_out_of_packed_order": ([21, 0, 20, 5], [19, 5, 1, 15],
+                                 [19, 128, 100, 47]),
+})
+
+
+@pytest.mark.parametrize("case", sorted(WORK_CASES))
+@pytest.mark.parametrize("heads,block_q", [(4, 16), (32, 256), (3, 8)])
+def test_work_list_equals_enumeration(case, heads, block_q):
+    """``_work_list`` (jnp, inside the program) against the pairs found by
+    walking every token: ordered by query block then row, every query block
+    at least once, the first visit of each flagged, the tail dead and on
+    the last block, and each pair's KV walk ending at the row's own length
+    and the causal diagonal."""
+    qstart, qlen, kvlen = WORK_CASES[case]
+    geometry = dict(heads=heads, block_q=block_q, block_size=16,
+                    table_entries=8, packed_tokens=40)
+    pairs, nq = _live_pairs(qstart, qlen, kvlen, **geometry)
+    want = []
+    for qi in range(nq):
+        here = [(qi, r, n) for (b, r), n in sorted(pairs.items()) if b == qi]
+        want += here or [(qi, None, 0)]
+    R = len(qstart)
+    assert len(want) <= nq + R - 1 or not any(qlen)
+    bq = _query_block(block_q, heads, 40)
+    wq, wr, first, wn = (np.asarray(a) for a in jax.jit(
+        lambda a, b, c: _work_list(
+            a, b, c, nq=nq, tokens_per_block=bq // heads, block_size=16,
+            table_entries=8))(*(jnp.asarray(x, jnp.int32)
+                                for x in (qstart, qlen, kvlen))))
+    assert wq.shape == (nq + R,)
+    for j, (qi, r, n) in enumerate(want):
+        assert (wq[j], wn[j]) == (qi, n), (j, want[j])
+        assert r is None or wr[j] == r
+        assert first[j] == (j == 0 or want[j - 1][0] != qi)
+    tail = slice(len(want), None)
+    assert (wq[tail] == nq - 1).all() and not wn[tail].any()
+    assert not first[tail].any() and (wr[tail] < R).all()
+
+
+def _poison_stale_rows(pool, tables, kvlen, qlen):
+    """NaN wherever no live row may read: unmapped blocks and the rows of
+    a mapped block past its sequence's length."""
+    pool = np.asarray(pool).copy()
+    nb, bs = pool.shape[:2]
+    live = np.zeros((nb, bs), bool)
+    for r, kl in enumerate(np.asarray(kvlen)):
+        for b in range(-(-int(kl) // bs) if int(qlen[r]) else 0):
+            live[np.asarray(tables)[r, b], :min(bs, int(kl) - b * bs)] = True
+    pool[~live] = np.nan
+    return jnp.asarray(pool)
+
+
+WALK_CASES = {
+    # name: (spans, H, Hkv, D, mb, bs, block_q)
+    # decode rows whose 64-entry tables are sentinel past a few blocks
+    "decode_only_sparse_tables": (
+        [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33)],
+        8, 2, 64, 64, 16, 256),
+    # kvlen several times qlen over five query blocks of 8 tokens: the
+    # causal diagonal cuts each block's walk at another KV block
+    "chunk_resumed_far_into_its_prompt": (
+        [(1, 90), (40, 200), (1, 7)], 8, 2, 64, 16, 16, 64),
+    # a first chunk: the diagonal crosses every query block
+    "first_chunk_kvlen_equals_qlen": (
+        [(48, 48), (1, 130)], 8, 4, 32, 12, 16, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_kv_walk_matches_reference(case):
+    spans, H, Hkv, D, mb, bs, block_q = WALK_CASES[case]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, D, mb, bs,
+                                     seed=len(case))
+    tbl = np.asarray(tbl).copy()
+    for r, (_, kvlen) in enumerate(spans):
+        tbl[r, -(-kvlen // bs):] = pk.shape[0]      # unmapped -> sentinel
+    tbl = jnp.asarray(tbl)
+    pk = _poison_stale_rows(pk, tbl, kl, ql)
+    pv = _poison_stale_rows(pv, tbl, kl, ql)
+    got = ragged_paged_attention_pallas(q, pk, pv, tbl, qs, ql, kl,
+                                        block_q=block_q)
+    want = ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("spans", [
+    [(3, 20), (5, 17), (0, 0)],         # holes between spans and a tail
+    [(0, 0), (0, 0), (0, 0)],           # every row dead
+], ids=["holes_and_tail", "all_dead"])
+def test_rows_in_no_span_are_exact_zeros(spans):
+    """Packed rows past the last span AND between spans (the spans need
+    not touch) come back exactly 0 and finite, stale pool rows being
+    NaN."""
+    q, pk, pv, tbl, qs, ql, kl = _mk(3, spans, 8, 4, 16, 4, 8, seed=23,
+                                     T=30)
+    qs = jnp.asarray([2, 11, 19], jnp.int32)        # gaps: 0-1, 5-10, 16-
+    pk = _poison_stale_rows(pk, tbl, kl, ql)
+    pv = _poison_stale_rows(pv, tbl, kl, ql)
+    got = np.asarray(ragged_paged_attention_pallas(
+        q, pk, pv, tbl, qs, ql, kl, block_q=32))
+    ref = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl))
+    in_span = np.zeros(30, bool)
+    for s, n in zip(np.asarray(qs), np.asarray(ql)):
+        in_span[int(s):int(s) + int(n)] = True
+    assert np.isfinite(got).all()
+    assert (got[~in_span] == 0).all() and (ref[~in_span] == 0).all()
+    np.testing.assert_allclose(got[in_span], ref[in_span], rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q", [256, 32])
+def test_int8_pool_parity_mixed_spans(block_q):
+    """An int8 pool's scale planes ride the same table-indirect fetch as
+    their data blocks: kernel and oracle dequantize the same values."""
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(MIXED), MIXED, 8, 4, 16, 4, 16,
+                                     seed=29)
+    (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
+    got = ragged_paged_attention_pallas(q, k8, v8, tbl, qs, ql, kl,
+                                        block_q=block_q, k_scale=ks,
+                                        v_scale=vs)
+    want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
+                                      k_scale=ks, v_scale=vs)
+    assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
