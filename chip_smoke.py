@@ -7,7 +7,8 @@ drives the two main paths once, through the entry points a user would call,
 at the full width of ``llama_7b()`` (hidden 4096, 32 x 128 heads, ffn 11008,
 vocab 32000, bf16) with the depth cut and random weights made from a seed:
 
-  kernels  every Pallas kernel on the default serving and training paths,
+  kernels  every Pallas kernel on the default serving and training paths
+           (and the routed FFN's grouped matmuls at OLMoE widths),
            compiled by Mosaic and RUN against its jnp reference;
   serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
            answering cold, chunked, concurrent and streamed requests;
@@ -51,12 +52,17 @@ FULL = dict(
     geometries=[(32, 32, 128), (32, 8, 128)], flash_seq=2048,
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
+    # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
+    # width, experts a token), then (rows, live rows) of a decode step's
+    # packed buffer and of a whole-prompt prefill
+    moe=dict(widths=(2048, 64, 1024, 8), rows=[(536, 24), (256, 256)]),
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
     preset="tiny", slots=4, max_seq_len=128, prefill_chunk=32,
     vocab=256, medium_prompt=24, long_prompt=70,
     tp=2,                                   # llama_tiny has two kv heads
+    moe=dict(widths=(64, 8, 32, 2), rows=[(36, 4), (16, 16)]),
     train=dict(layers=2, batch=4, seq=64, steps=4))
 
 # Forward outputs: kernel and reference both take bf16 inputs (8 significant
@@ -530,6 +536,27 @@ def phase_kernels(rehearse):
         _agree(f"flash fwd {tag}", o, o_ref, TOL_FWD, errors)
         for g, g_ref, n in zip(grads, grads_ref, ("dq", "dk", "dv")):
             _agree(f"flash {n} {tag}", g, g_ref, TOL_BWD, errors)
+
+    # ---- the routed FFN (grouped matmuls) against every-expert-masked ----
+    from paddle_tpu.kernels.moe_ffn import moe_ffn, moe_ffn_reference
+    H, E, I, K = size["moe"]["widths"]
+    rng = np.random.RandomState(64)
+    weights = [jnp.asarray(0.02 * rng.randn(*shape).astype(np.float32), bf16)
+               for shape in ((H, E), (E, H, I), (E, H, I), (E, I, H))]
+    for rows, n_live in size["moe"]["rows"]:
+        h = jnp.asarray(rng.randn(rows, H).astype(np.float32), bf16)
+        live = np.zeros(rows, bool)         # live rows spread over the buffer
+        live[np.linspace(0, rows - 1, n_live).astype(int)] = True
+        margs = (h, *weights, jnp.asarray(live))
+        got, stats_got = jax.jit(
+            lambda *a: moe_ffn(*a[:5], top_k=K, live=a[5]))(*margs)
+        want, stats_want = reference(
+            lambda *a: moe_ffn_reference(*a[:5], top_k=K, live=a[5]), *margs)
+        _agree(f"moe_ffn {n_live}/{rows}", got, want, TOL_FWD, errors)
+        check(np.array_equal(np.asarray(stats_got), np.asarray(stats_want))
+              and int(stats_got[0]) == n_live * K,
+              f"moe_ffn {n_live}/{rows}: routing summary "
+              f"{np.asarray(stats_got)} != {np.asarray(stats_want)}")
 
     import importlib.metadata as md
     _child_report(

@@ -1,0 +1,161 @@
+"""Dropless routed (mixture-of-experts) SwiGLU FFN for the serving step
+programs: exact top-k for every live row, whatever the skew.
+
+Two functions with one signature. ``moe_ffn_reference`` is the oracle of the
+tests: every expert over every row, masked. ``moe_ffn`` is what the step
+programs run: the live (row, expert) pairs are ordered by expert, three
+grouped matmuls walk the groups (gate, up, down), and the weighted sum goes
+back to row order. Shapes are static (``rows x top_k`` pair slots, a
+length-``E`` count vector), so one program serves every routing; cost follows
+the live pairs and the experts they touch: dead rows (the padding of a packed
+buffer or of a prefill bucket) make no pair, and an expert nobody picked is
+not read.
+
+Router matmul and softmax run in float32 (``Precision.HIGHEST``) so that only
+error upstream of the router can change which experts a row picks. The
+weights are the softmax probabilities of the picked experts as they are
+(OLMoE's ``norm_topk_prob`` is false): ``renormalize=True`` divides them by
+their sum for a model that wants it.
+
+The grouped matmul is ``jax.lax.ragged_dot`` on the CPU backend and the
+Pallas grouped matmul that ships with JAX (``megablox.gmm``) on a TPU, whose
+grid is the list of (row tile, group) visits of the non-empty groups; what
+each cost on the v5e is in PERF.md (PR 26).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .pallas_flash import _interpret_mode
+
+#: rows of a grouped-matmul tile: pair slots are padded to a multiple of it
+PAIR_TILE = 128
+#: stats vector returned beside the output, one int32 each
+STATS = ("pairs", "experts_touched", "max_expert_pairs")
+
+
+def _route(h2, router, top_k, live, renormalize):
+    """Float32 router: (weights [T, K] f32, experts [T, K] i32, counts [E]
+    i32 of live pairs per expert, stats [3] i32)."""
+    n_exp = router.shape[-1]
+    logits = jnp.dot(h2.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    # a dead row's picks go to the sentinel expert E: no group counts them
+    idx = jnp.where(live[:, None], idx, n_exp).astype(jnp.int32)
+    # (a compare-and-sum, not a scatter-add: 4 us against 38 on the v5e)
+    counts = jnp.sum(idx.reshape(-1, 1) == jnp.arange(n_exp)[None, :],
+                     axis=0, dtype=jnp.int32)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.max(counts)]).astype(jnp.int32)
+    return w, idx, counts, stats
+
+
+def _prep(h, live):
+    lead, hid = h.shape[:-1], h.shape[-1]
+    h2 = h.reshape(-1, hid)
+    live = (jnp.ones(h2.shape[0], bool) if live is None
+            else live.reshape(-1).astype(bool))
+    return lead, h2, live
+
+
+def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
+                      renormalize=False):
+    """h [..., H]; router [H, E]; w_gate, w_up [E, H, I]; w_down [E, I, H];
+    live [...] bool (None: every row). Returns (out [..., H], stats [3]).
+    Plain ``jnp``: each expert runs over every row and is masked by the
+    row's weight for it (zero where not picked or the row is dead)."""
+    lead, h2, live = _prep(h, live)
+    n_exp = router.shape[-1]
+    w, idx, _, stats = _route(h2, router, top_k, live, renormalize)
+    # [T, E + 1] weight of every expert for every row; column E is the bin
+    dense = jnp.zeros((h2.shape[0], n_exp + 1), jnp.float32).at[
+        jnp.arange(h2.shape[0])[:, None], idx].set(w)[:, :n_exp]
+
+    def one_expert(acc, xs):
+        wg, wu, wd, col = xs
+        y = jnp.dot(jax.nn.silu(jnp.dot(h2, wg)) * jnp.dot(h2, wu), wd)
+        return acc + col[:, None] * y.astype(jnp.float32), None
+
+    out, _ = jax.lax.scan(one_expert, jnp.zeros(h2.shape, jnp.float32),
+                          (w_gate, w_up, w_down, dense.T))
+    return out.astype(h.dtype).reshape(lead + (h2.shape[1],)), stats
+
+
+def _tiling(k, n):
+    """(tm, tk, tn) of the Pallas grouped matmul: the whole contraction in
+    one tile where it fits (a visit is then one grid step a column tile, and
+    a decode step with three rows a group is bound by the weight stream, not
+    by grid steps); the weight tile is at most 4 MiB in bf16. Of the tilings
+    tried on the v5e this was the fastest at 24 live rows of 536 and at a
+    256-row prefill (PERF.md, PR 26); megablox's default (128, 128, 128) is
+    six times slower."""
+    return PAIR_TILE, min(k, 2048), min(n, 1024)
+
+
+def _grouped_matmul(xs, w, counts, layer=None):
+    """xs [P, K] rows ordered by group; w [E, K, N]; counts [E] rows a
+    group. Rows past ``sum(counts)`` come back undefined (the caller masks
+    them). With ``layer`` (a traced index), ``w`` is the stack ``[L, E, K,
+    N]`` of a layer scan and the call reads layer ``layer`` of it IN PLACE:
+    the stack is viewed as ``L x E`` groups of which only this layer's
+    hold rows, and the kernel never visits an empty group. A Mosaic call
+    needs its operands whole, so slicing the layer out first costs a copy
+    of all ``E`` experts a layer call, read or not (20.7 ms of an 84 ms
+    OLMoE decode step on the v5e; PERF.md, PR 26)."""
+    if _interpret_mode():
+        if layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        return jax.lax.ragged_dot(xs, w, counts)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    if layer is not None:
+        n_layers, n_exp = w.shape[:2]
+        counts = jax.lax.dynamic_update_slice(
+            jnp.zeros(n_layers * n_exp, counts.dtype), counts,
+            (layer * n_exp,))
+        w = w.reshape((n_layers * n_exp,) + w.shape[2:])
+    return gmm(xs, w, counts, xs.dtype, _tiling(w.shape[1], w.shape[2]))
+
+
+def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
+            renormalize=False, return_picks=False, layer=None):
+    """Same contract as :func:`moe_ffn_reference`; the path the serving
+    programs run. Scopes: ``moe`` > ``moe_route`` (router, top-k, ordering,
+    gather, weighted sum) and ``moe_experts`` (the grouped matmuls). With
+    ``return_picks`` a third value: the experts each row picked
+    ``[..., top_k]`` int32 (``E`` for a dead row), for a check of the
+    routing against a reference. With ``layer`` (a traced index) the three
+    expert weights are stacks over layers ``[L, E, ...]``, read in place
+    (``_grouped_matmul``)."""
+    lead, h2, live = _prep(h, live)
+    rows, hid = h2.shape
+    with jax.named_scope("moe"):
+        with jax.named_scope("moe_route"):
+            w, idx, counts, stats = _route(h2, router, top_k, live,
+                                           renormalize)
+            pairs = rows * top_k
+            slots = -(-pairs // PAIR_TILE) * PAIR_TILE
+            # pair slots ordered by expert, dead pairs (expert E) last
+            order = jnp.argsort(idx.reshape(-1), stable=True)
+            order = jnp.pad(order, (0, slots - pairs))
+            xs = jnp.take(h2, order // top_k, axis=0)
+        with jax.named_scope("moe_experts"):
+            g = _grouped_matmul(xs, w_gate, counts, layer)
+            u = _grouped_matmul(xs, w_up, counts, layer)
+            y = _grouped_matmul((jax.nn.silu(g) * u).astype(h2.dtype),
+                                w_down, counts, layer)
+        with jax.named_scope("moe_route"):
+            # back to (row, pick) order; slots past the live pairs hold
+            # whatever the grouped matmul left there, so select, not scale
+            slot_of = jnp.argsort(order[:pairs])
+            y = jnp.take(y, slot_of, axis=0).reshape(rows, top_k, hid)
+            y = jnp.where(live[:, None, None], y.astype(jnp.float32), 0.0)
+            out = jnp.sum(y * w[:, :, None], axis=1)
+    out = out.astype(h.dtype).reshape(lead + (hid,))
+    if return_picks:
+        return out, stats, idx.reshape(lead + (top_k,))
+    return out, stats

@@ -302,6 +302,12 @@ class LlamaForCausalLM(nn.Layer):
         import numpy as np
         return sum(int(np.prod(p.shape)) for p in self.parameters())
 
+    def decode_params(self):
+        """``(params, tied)`` for the serving step programs: what the
+        engine asks of every model it serves."""
+        from ..serving.decode import llama_decode_params
+        return llama_decode_params(self)
+
 
 @tensor_op
 def _llama_forward(input_ids, labels, nh, nkv, hd, eps, theta, remat, tied,
@@ -503,7 +509,8 @@ class LlamaPretrainCriterion(nn.Layer):
 
 # ----------------------------------------------------------------- generate
 def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-             top_k=0, max_cache_len=None, seed=None, eos_token_id=None):
+             top_k=0, max_cache_len=None, seed=None, eos_token_id=None,
+             _decode_chunk=16):
     """Autoregressive generation over the continuous-batching decode
     engine (``serving/engine.py``): a jitted per-prompt prefill feeds a
     slot KV cache, then one compiled single-token decode program —
@@ -544,8 +551,9 @@ def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
         # exact-length prefill: same-shape prompts compile one program,
         # exactly like the pre-engine monolith did. chunk=16 bounds the
         # host round-trips of this offline all-at-once case (no queue to
-        # starve) — floor(m/16)+m%16 dispatches for m decode steps
-        prefill_bucketing="exact", decode_chunk=16,
+        # starve) — floor(m/16)+m%16 dispatches for m decode steps (a
+        # model whose layer the fused tail was not taught passes 1)
+        prefill_bucketing="exact", decode_chunk=_decode_chunk,
         jit_cache=self.__dict__.setdefault("_serving_jit", {}))
     reqs = [GenerationRequest(
         prompt=ids_np[i], max_new_tokens=int(max_new_tokens),

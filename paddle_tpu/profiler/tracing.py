@@ -80,6 +80,9 @@ class _NullSpan:
     def end(self, args=None):
         pass
 
+    def add(self, args):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -295,6 +298,12 @@ class _Span:
 
     def __enter__(self):
         return self
+
+    def add(self, args):
+        """Join ``args`` (a dict, or None) to those the span closes with:
+        what is learned while it is open (a program's fetched counters)."""
+        if args:
+            self._args = {**(self._args or {}), **args}
 
     def end(self, args=None):
         """Close the span; ``args`` join those given at the start. The

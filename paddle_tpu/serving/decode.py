@@ -39,6 +39,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..kernels.flash_attention import attention as _attention
+from ..kernels.moe_ffn import moe_ffn
 from ..kernels.pallas_decode import (decode_attention_pallas,
                                      decode_attention_reference)
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
@@ -53,6 +54,34 @@ NEG_INF = -1e30
 
 _STACK_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "input_ln", "post_ln")
+
+#: per-layer entries only some models bring; what a parameter tree holds of
+#: them chooses the layer body (``_decoder_layer``): ``q_norm`` / ``k_norm``
+#: normalise q and k before the rotary embedding, ``router`` makes the FFN a
+#: routed one over ``w_gate`` / ``w_up`` / ``w_down`` with a leading expert dim
+_STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router")
+
+
+#: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not
+#: slice them (the grouped matmul reads its layer of the stack in place)
+_EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+
+
+def _layer_stack(params):
+    """(names, arrays, experts) of the per-layer entries a layer scan
+    carries: the nine every model has, then whichever extras this tree
+    holds. For a tree with ``router``, ``experts`` is the three expert
+    stacks, whole, their places in the scanned tuple hold None, and the scan
+    carries the layer's index as ``layer``; else ``experts`` is None."""
+    keys = _STACK_KEYS + tuple(k for k in _STACK_EXTRA_KEYS if k in params)
+    if "router" not in params:
+        return keys, tuple(params[k] for k in keys), None
+    n_layers = params["router"].shape[0]
+    stack = tuple(None if k in _EXPERT_KEYS else params[k] for k in keys)
+    return (keys + ("layer",),
+            stack + (jnp.arange(n_layers, dtype=jnp.int32),),
+            tuple(params[k] for k in _EXPERT_KEYS))
+
 
 #: the decode-path projection matmuls quantize_weights=True converts
 #: (README "Quantized serving"); norms and the embedding gather stay
@@ -205,6 +234,54 @@ def _o_proj(attn2, lwo):
     if isinstance(lwo, tuple):
         return _a8_dot(attn2, lwo)
     return jnp.einsum("bsd,dh->bsh", attn2, lwo)
+
+
+def _qk_norm(q, k, q_w, k_w, eps):
+    """RMSNorm of q and k over the WHOLE projection (all heads at once),
+    before the heads are split and before the rotary embedding (OLMoE)."""
+    return (_rms(q.reshape(q.shape[:2] + (-1,)), q_w, eps).reshape(q.shape),
+            _rms(k.reshape(k.shape[:2] + (-1,)), k_w, eps).reshape(k.shape))
+
+
+def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
+                   moe=None, experts=None, tp_reduce=None,
+                   return_picks=False):
+    """ONE pre-norm decoder layer on ``h [B, S, H]``, written once for the
+    programs the default engine runs (whole-prompt prefill, the packed-span
+    forward of the unified step) and for ``OlmoeForCausalLM.forward``.
+
+    ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
+    ``_dq_layer``) and what it holds chooses the body: with ``q_norm`` q and
+    k are normalised before ``rope``; with ``router`` the FFN is the dropless
+    routed one (``kernels.moe_ffn``; ``moe`` is its static ``(top_k,
+    renormalize)``, ``experts`` the three expert stacks ``[L, E, ...]`` of
+    which ``lw["layer"]`` names this layer's, ``live [B, S]`` marks the
+    rows that make pairs), else the dense SwiGLU. The program brings its
+    own ``rope(x)`` and ``attend(q, k, v) -> (attn [B, S, nh, hd],
+    carry)``: cache writes and the attention kernel are the program's
+    business, not the layer's.
+    Returns ``(h, carry, moe_stats or None)``; with ``return_picks`` the
+    third is ``(moe_stats, picked experts [B, S, top_k])``."""
+    B, S = h.shape[0], h.shape[1]
+    with jax.named_scope("attn"):
+        hn = _rms(h, lw["input_ln"], eps)
+        q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"], nh, nkv, hd)
+        if "q_norm" in lw:
+            q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps)
+        attn, carry = attend(rope(q), rope(k), v)
+        o = _o_proj(attn.reshape(B, S, nh * hd), lw["wo"])
+        h = h + (o if tp_reduce is None else tp_reduce(o))
+    hn = _rms(h, lw["post_ln"], eps)
+    if "router" in lw:
+        m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
+                            top_k=moe[0], live=live, renormalize=moe[1],
+                            return_picks=return_picks)
+        stats = tuple(stats) if return_picks else stats[0]
+    else:
+        m, stats = _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
+                                lw["w_down"]), None
+    h = h + (m if tp_reduce is None else tp_reduce(m))
+    return h, carry, stats
 
 
 @jax.named_scope("lm_head")
@@ -562,7 +639,7 @@ def sample_rows(logits, keys, temps, top_ks):
 # ------------------------------------------------------------------ prefill
 @jax.named_scope("prefill")
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
-                  hd, eps, theta, tied, tp_reduce=None, a8=False):
+                  hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -571,42 +648,41 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     of two so the compile count stays bounded). Padding rows/columns
     produce K/V garbage past each row's ``lengths`` — causal masking
     keeps it out of every real position's attention, and the cache slot
-    masks it by ``lengths`` until decode overwrites it.
+    masks it by ``lengths`` until decode overwrites it. A routed-FFN
+    model (``router`` in ``params``) returns a fifth value, the layers'
+    routing summary ``[L, 3]`` int32 (``kernels.moe_ffn.STATS``); its
+    padding columns make no (token, expert) pair.
     """
     B, S = ids.shape
     sin, cos = _rope_tables(S, hd, theta)
-    stack = tuple(params[k] for k in _STACK_KEYS)
+    names, stack, experts = _layer_stack(params)
     wdt = params["embed"].dtype
     head = _dq_head(params, tied, wdt, a8)
+    live = (None if experts is None else
+            jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None])
 
     def prefill_layer(h, lp):
-        (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost) = \
-            _dq_layer(lp, wdt, a8)
-        with jax.named_scope("attn"):
-            hn = _rms(h, lin, eps)
-            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-            q = _apply_rope(q, sin, cos)
-            k = _apply_rope(k, sin, cos)
-            attn = _attention(q, k, v, causal=True)
-            o = _o_proj(attn.reshape(B, S, nh * hd), lwo)
-            h = h + (o if tp_reduce is None else tp_reduce(o))
-        m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
-        h = h + (m if tp_reduce is None else tp_reduce(m))
-        return h, (k, v)
+        h, kv, stats = _decoder_layer(
+            h, dict(zip(names, _dq_layer(lp, wdt, a8))), nh=nh, nkv=nkv,
+            hd=hd, eps=eps, rope=lambda x: _apply_rope(x, sin, cos),
+            attend=lambda q, k, v: (_attention(q, k, v, causal=True),
+                                    (k, v)),
+            live=live, moe=moe, experts=experts, tp_reduce=tp_reduce)
+        return h, (kv, stats)
 
     x = jnp.take(params["embed"], ids, axis=0)
-    x, (pk, pv) = jax.lax.scan(prefill_layer, x, stack)
+    x, ((pk, pv), stats) = jax.lax.scan(prefill_layer, x, stack)
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
     last_h = _rms(last, params["final_norm"], eps)
     logits = _head_logits(last_h, head)
     both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
     tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
-    return pk, pv, tok0, both[:, 0]
+    return (pk, pv, tok0, both[:, 0]) + (() if stats is None else (stats,))
 
 
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
-                     collective_dtype="fp", wq8=False, a8=False):
+                     collective_dtype="fp", wq8=False, a8=False, moe=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -628,7 +704,7 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                        rep, rep)))
     return jax.jit(functools.partial(
         _prefill_impl, nh=nh, nkv=nkv, hd=hd, eps=eps, theta=theta,
-        tied=tied, a8=a8))
+        tied=tied, a8=a8, moe=moe))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -1162,7 +1238,8 @@ def _span_last_sample(params, head, x, qstart, qlen, keys, temps, top_ks,
 
 def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
-                         eps, decode_attn, tp_reduce=None, a8=False):
+                         eps, decode_attn, tp_reduce=None, a8=False,
+                         moe=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -1170,14 +1247,16 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     for every live packed token is scattered through its slot's table
     at its logical position (dead rows — ``seg == R`` — and positions
     past the logical capacity DROP), attention runs through the ragged
-    paged kernel or its jnp oracle. Returns ``(x [1, T, H], pk, pv)``.
+    paged kernel or its jnp oracle. Returns ``(x [1, T, H], pk, pv,
+    moe_stats)``: the layers' routing summary ``[L, 3]`` int32 of a
+    routed-FFN model (dead packed rows make no pair), else None.
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
     mb = tables.shape[1]
     s_tot = mb * bs
     T = ids.shape[0]
-    stack = tuple(params[k] for k in _STACK_KEYS)
+    names, stack, experts = _layer_stack(params)
     wdt = params["embed"].dtype
     sin_p = jnp.take(sin, pos, axis=0, mode="clip")[None]   # [1, T, D]
     cos_p = jnp.take(cos, pos, axis=0, mode="clip")[None]
@@ -1194,22 +1273,18 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     prow0 = pos % bs
 
     def layer0(h, lp):
-        (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l) = \
-            _dq_layer(lp, wdt, a8)
-        with jax.named_scope("attn"):
-            hn = _rms(h, lin, eps)
-            q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
-            q = _apply_rope_grid(q, sin_p, cos_p)
-            k = _apply_rope_grid(k, sin_p, cos_p)
+        pk_l, pv_l = lp[-2:]
+
+        def attend(q, k, v):
             # write the packed K/V through the tables (quantize-on-write on
             # an int8 pool), then attend over each span causally at its
             # row's kv length — THE one dequant site: the ragged kernel
             # (or its oracle) dequantizes right after the table-indirect
             # fetch, and every consumer of this forward (unified step,
             # multi-tick tick 0, speculative verify) rides it
-            pk_l = _kv_write(pk_l, phys0, prow0, k[0])
-            pv_l = _kv_write(pv_l, phys0, prow0, v[0])
-            kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
+            npk = _kv_write(pk_l, phys0, prow0, k[0])
+            npv = _kv_write(pv_l, phys0, prow0, v[0])
+            kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
             if decode_attn == "pallas":
                 attn = ragged_paged_attention_pallas(
                     q[0], kd, vd, tables, qstart, qlen, kvlen,
@@ -1218,22 +1293,28 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                 attn = ragged_attention_reference(
                     q[0], kd, vd, tables, qstart, qlen, kvlen,
                     k_scale=ksc, v_scale=vsc)
-            o = _o_proj(attn.reshape(1, T, nh * hd), lwo)
-            h = h + (o if tp_reduce is None else tp_reduce(o))
-        m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
-        h = h + (m if tp_reduce is None else tp_reduce(m))
-        return h, (pk_l, pv_l)
+            return attn, (npk, npv)
+
+        h, kv, stats = _decoder_layer(
+            h, dict(zip(names, _dq_layer(lp[:-2], wdt, a8))), nh=nh,
+            nkv=nkv, hd=hd, eps=eps,
+            rope=lambda x: _apply_rope_grid(x, sin_p, cos_p),
+            attend=attend, live=live_tok[None], moe=moe, experts=experts,
+            tp_reduce=tp_reduce)
+        return h, (kv, stats)
 
     x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
-    x, (pk, pv) = jax.lax.scan(layer0, x, stack + (pool_k, pool_v))
-    return x, pk, pv
+    x, ((pk, pv), stats) = jax.lax.scan(layer0, x,
+                                        stack + (pool_k, pool_v))
+    return x, pk, pv, stats
 
 
 @jax.named_scope("ragged_step")
 def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, dec_mask, keys, temps, top_ks,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
-                      decode_attn, tp_reduce=None, a8=False, fused=False):
+                      decode_attn, tp_reduce=None, a8=False, fused=False,
+                      moe=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables, collapsing the
@@ -1281,10 +1362,10 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     head = _dq_head(params, tied, params["embed"].dtype, a8)
 
     # ----------------------------------- tick 0 (shared packed forward)
-    x, pk, pv = _packed_span_forward(
+    x, pk, pv, moe_stats = _packed_span_forward(
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-        decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
+        decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8, moe=moe)
     tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
                                       keys, temps, top_ks, eps)
 
@@ -1310,14 +1391,15 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         toks = jnp.concatenate([tok0[None], toks_rest], axis=0)
     else:
         toks, keys_fin = tok0[None], keys_t0
-    return pk, pv, toks, keys_t0, keys_fin
+    return (pk, pv, toks, keys_t0, keys_fin) \
+        + (() if moe_stats is None else (moe_stats,))
 
 
 def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          decode_attn, donate=None, tp=1,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
-                         collective_overlap=False):
+                         collective_overlap=False, moe=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, token_budget)`` plus the fused
     ``n_steps`` — one compilation per step size serves every span mix,
@@ -1352,7 +1434,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
         functools.partial(
             _ragged_step_impl, n_steps=n_steps, nh=nh, nkv=nkv, hd=hd,
             eps=eps, theta=theta, tied=tied, decode_attn=decode_attn,
-            a8=a8, fused=fused),
+            a8=a8, fused=fused, moe=moe),
         donate_argnums=(1, 2) if donate else ())
 
 
@@ -1414,7 +1496,7 @@ def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
 
     # ----------------------------------- tick 0 (shared packed forward)
     def _packed_tick0(pk_in, pv_in):
-        x, pk2, pv2 = _packed_span_forward(
+        x, pk2, pv2, _ = _packed_span_forward(
             params, pk_in, pv_in, tables, ids, seg, pos, qstart, qlen,
             kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
             decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
@@ -1583,7 +1665,7 @@ def _spec_verify_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     sin, cos = _rope_tables(s_tot, hd, theta)
     head = _dq_head(params, tied, params["embed"].dtype, a8)
 
-    x, pk, pv = _packed_span_forward(
+    x, pk, pv, _ = _packed_span_forward(
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
         decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)

@@ -33,11 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
 from .decode import build_decode_steps_fn, build_paged_decode_steps_fn, \
     build_paged_suffix_prefill_fn, build_prefill_fn, build_ragged_step_fn, \
-    build_suffix_prefill_fn, llama_decode_params
+    build_suffix_prefill_fn, _STACK_EXTRA_KEYS
 from .kv_cache import PagedKVCache, PoolExhausted, SlotKVCache
 from .policy import ClassTable, PolicyScheduler, select_victims
 from .request import GenerationRequest, GenerationResult, Sequence
@@ -279,7 +280,31 @@ class ContinuousBatchingEngine:
         self.num_slots = int(num_slots)
         self.max_seq_len = int(max_seq_len or c.max_position_embeddings)
         self._bucketing = prefill_bucketing
-        self._params, self._tied = llama_decode_params(model)
+        # the model brings its decode parameters; what the tree holds
+        # chooses the layer body inside the programs (decode._decoder_layer)
+        self._params, self._tied = model.decode_params()
+        extras = [k for k in _STACK_EXTRA_KEYS if k in self._params]
+        if extras:
+            # only the default engine's two programs (whole-prompt prefill,
+            # the unified step) were taught a layer with these entries:
+            # every switch that runs another program raises, none falls back
+            off = {"quantize_weights": bool(quantize_weights),
+                   "quantize_activations": bool(quantize_activations),
+                   "tp > 1": int(tp) > 1, "fused_tick": bool(fused_tick),
+                   "decode_ticks > 1": int(decode_ticks) > 1,
+                   "spec_decode": bool(spec_decode),
+                   "paged_attn=False": not paged_attn,
+                   "ragged_step=False": not ragged_step,
+                   "decode_chunk > 1": int(decode_chunk) > 1,
+                   "prefix_cache": bool(prefix_cache)}
+            bad = [name for name, on in off.items() if on]
+            if bad:
+                raise ValueError(
+                    f"{type(model).__name__} (a layer with "
+                    f"{'/'.join(extras)}) is served by whole-prompt "
+                    f"prefill and the unified ragged step only; these "
+                    f"switches run a program with a layer body of its "
+                    f"own that was not taught it: {', '.join(bad)}")
         self._paged = bool(paged_attn)
         # quantized KV pool (README "Quantized serving"): "int8" stores
         # int8 with per-row-per-head fp32 scale planes, "fp8" stores
@@ -638,7 +663,9 @@ class ContinuousBatchingEngine:
                       "last_step_duration_s": 0.0, "last_step_tokens": 0,
                       "tokens_generated": 0, "cancelled": 0, "timeouts": 0,
                       "preemptions": 0, "restores": 0,
-                      "policy_preemptions": 0}
+                      "policy_preemptions": 0,
+                      "moe_pairs": 0, "moe_experts_touched": 0,
+                      "moe_max_expert_pairs": 0, "moe_layer_calls": 0}
         # fault-injection hook (serving/faults.py): called with the
         # engine at the top of every step attempt; None in production.
         # Whatever it raises propagates to the driver — except
@@ -751,9 +778,15 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------ programs
     def _fn_consts(self):
         c = self.config
-        return dict(nh=c.num_attention_heads, nkv=c.num_key_value_heads,
-                    hd=c.head_dim, eps=float(c.rms_norm_eps),
-                    theta=float(c.rope_theta), tied=self._tied)
+        consts = dict(nh=c.num_attention_heads, nkv=c.num_key_value_heads,
+                      hd=c.head_dim, eps=float(c.rms_norm_eps),
+                      theta=float(c.rope_theta), tied=self._tied)
+        if self.routed_ffn:
+            # the routed FFN's static numbers, model hyper-parameters like
+            # the head counts above
+            consts["moe"] = (int(c.num_experts_per_tok),
+                             bool(c.norm_topk_prob))
+        return consts
 
     def _tp_consts(self):
         """Builder kwargs of the TP variant ({} on tp=1, so default
@@ -771,6 +804,29 @@ class ContinuousBatchingEngine:
         builders."""
         return dict(a8=True) if self._a8 else {}
 
+    def _moe_out(self, index):
+        """The result index of a program's routing summary, as a tuple:
+        empty for a model with a dense FFN, whose programs return none."""
+        return (index,) if self.routed_ffn else ()
+
+    def _count_moe(self, summary):
+        """Add one program call's routing summary (``()`` from a dense
+        model's program, else one ``[L, 3]`` int32 array, per layer
+        ``kernels.moe_ffn.STATS``: live pairs, experts touched, the
+        fullest expert's pairs) to the
+        always-on counters. The array rides the fetch that fences the
+        step's tokens: no second sync. Returns the call's totals as span
+        args, or None."""
+        if not summary:
+            return None
+        st = np.asarray(summary[0])
+        call = {"moe_" + name: int(st[:, i].sum())
+                for i, name in enumerate(MOE_STATS)}
+        call["moe_layer_calls"] = int(st.shape[0])
+        for k, v in call.items():
+            self.stats[k] += v
+        return call
+
     def _prefill_fn(self):
         # the weight tag (not the kv tag): the cold prefill touches the
         # params but never the pool, so two engines differing only in
@@ -783,9 +839,11 @@ class ContinuousBatchingEngine:
             tpk.pop("kv_quant", None)   # prefill never touches the pool
             self._jit[key] = build_prefill_fn(**self._fn_consts(), **tpk,
                                               **self._q_consts())
-        # host_out: the engine fetches tok0 (result 2); pk/pv feed the
-        # cache writer device-side and keys stay device state
-        return self._wrap_prog(key, self._jit[key], host_out=(2,))
+        # host_out: the engine fetches tok0 (result 2) and, of a
+        # routed-FFN model, the routing summary (result 4); pk/pv feed
+        # the cache writer device-side and keys stay device state
+        return self._wrap_prog(key, self._jit[key],
+                               host_out=(2,) + self._moe_out(4))
 
     def _suffix_fn(self):
         # paged and dense suffix programs are distinct (table-indirect
@@ -835,8 +893,10 @@ class ContinuousBatchingEngine:
                 **self._fn_consts(), **self._tp_consts(),
                 **self._q_consts())
         # host reads the sampled tokens and the tick-0 keys (chunk
-        # installs); keys_fin is adopted device-side via jnp.where
-        return self._wrap_prog(key, self._jit[key], host_out=(2, 3))
+        # installs), and a routed-FFN model's routing summary (result 5);
+        # keys_fin is adopted device-side via jnp.where
+        return self._wrap_prog(key, self._jit[key],
+                               host_out=(2, 3) + self._moe_out(5))
 
     def _mtick_fn(self):
         # like the ragged key: the full packed geometry (num_slots AND
@@ -879,6 +939,13 @@ class ContinuousBatchingEngine:
         # host reads the sampled walk tokens AND the key walk (both are
         # np.asarray'd for acceptance)
         return self._wrap_prog(key, self._jit[key], host_out=(2, 3))
+
+    @property
+    def routed_ffn(self) -> bool:
+        """Whether the model's FFN is a routed (mixture-of-experts) one,
+        whose step programs return a routing summary — the public surface
+        for banners/metrics."""
+        return "router" in self._params
 
     @property
     def spec_decode(self) -> bool:
@@ -1247,13 +1314,14 @@ class ContinuousBatchingEngine:
                 topks[i] = int(seq.request.top_k)
                 keys[i] = np.asarray(seq.key)
             with self._tspan("prefill_launch",
-                             args={"bucket": s_pad, "group": G}):
+                             args={"bucket": s_pad, "group": G}) as sp:
                 # host arrays pass uncoerced: jit device_puts them
                 # identically, and the cost facade then counts the
                 # REAL host→device upload bytes of the call
-                pk, pv, tok0s, keys2 = self._prefill_fn()(
+                pk, pv, tok0s, keys2, *moe = self._prefill_fn()(
                     self._params, ids, lens, keys, temps, topks)
                 tok0s = np.asarray(tok0s)
+                sp.add(self._count_moe(moe))
             co = self._co()
             if co is not None:
                 # sharded cold prefill: one pass over the padded group
@@ -2007,7 +2075,7 @@ class ContinuousBatchingEngine:
                 cursor - len(active)))
         if co is not None:
             co.set_phase("launch")
-        npk, npv, toks, keys_t0, keys_fin = self._ragged_fn(n)(
+        npk, npv, toks, keys_t0, keys_fin, *moe = self._ragged_fn(n)(
             self._params, *self.cache.kv_args(),
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             dec_mask, keys, temps, topks)
@@ -2017,6 +2085,7 @@ class ContinuousBatchingEngine:
             sp = tr.span("device-wait")
         toks_np = np.asarray(toks)          # [n, R]
         keys_t0_np = np.asarray(keys_t0)
+        moe = self._count_moe(moe)
         self.stats["unified_steps"] += 1
         if co is not None:
             # sharded launch: tick 0 all-reduces the PADDED packed
@@ -2026,7 +2095,7 @@ class ContinuousBatchingEngine:
                 co, [(self._token_budget, 1), (self.num_slots, n - 1)])
             co.set_phase("host-accept")
         if tr is not None:
-            sp.end()
+            sp.end(moe)     # the routing this step's wait fenced
             launch.end({"packed_tokens": cursor, "fused_steps": n})
             sp = tr.span("host-accept")
         if active:

@@ -1,12 +1,15 @@
 """CLI entry point: ``python -m paddle_tpu.serving.server``.
 
-Stands up a LLaMA-family model behind the async gateway and serves
+Stands up a model behind the async gateway and serves
 OpenAI-style completions over HTTP until SIGINT/SIGTERM, then drains
 gracefully (in-flight requests finish; new ones get 503).
 
 The ``tiny`` preset is the CPU-runnable config; ``350m`` and
 ``llama7b-8of32`` (Llama-2-7B widths, depth cut to 8 of 32 layers) are
-sized for one TPU v5e chip. Weights are random, made from ``--seed``.
+sized for one TPU v5e chip, as is ``olmoe1b7b-8of16`` (OLMoE-1B-7B-0125
+widths, a routed FFN of 64 experts with 8 a token, depth cut to 8 of 16
+layers); ``olmoe-tiny`` is its CPU-runnable twin. Weights are random, made
+from ``--seed``.
 Prompts are token-id arrays (the framework ships no tokenizer) — see
 README "Serving over HTTP" for curl examples.
 
@@ -23,7 +26,8 @@ import sys
 import threading
 
 
-PRESETS = ("tiny", "350m", "llama7b-8of32")
+PRESETS = ("tiny", "350m", "llama7b-8of32", "olmoe-tiny",
+           "olmoe1b7b-8of16")
 
 
 def build_model(preset, decode_attention, seed):
@@ -31,6 +35,19 @@ def build_model(preset, decode_attention, seed):
     from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                          llama_7b, llama_tiny)
     paddle.seed(seed)
+    if preset.startswith("olmoe"):
+        from paddle_tpu.models.olmoe import (OlmoeConfig, OlmoeForCausalLM,
+                                             olmoe_tiny)
+        if preset == "olmoe-tiny":
+            return OlmoeForCausalLM(olmoe_tiny(
+                decode_attention=decode_attention))
+        # every width of OlmoeConfig()'s defaults, the published ones
+        # (hidden 2048, 16 x 128 heads, 64 experts of 1024, 8 a token,
+        # vocab 50304); only the depth is cut, 16 -> 8 layers, so the
+        # bf16 weights (6.6 GiB) leave one 16 GB chip room for the cache
+        return OlmoeForCausalLM(OlmoeConfig(
+            num_hidden_layers=8, dtype="bfloat16",
+            decode_attention=decode_attention))
     if preset == "tiny":
         return LlamaForCausalLM(llama_tiny(decode_attention=decode_attention))
     if preset == "llama7b-8of32":
@@ -47,6 +64,10 @@ def build_model(preset, decode_attention, seed):
             num_key_value_heads=16, max_position_embeddings=2048,
             dtype="bfloat16", decode_attention=decode_attention))
     raise ValueError(f"unknown preset {preset!r}")
+
+
+def _model_name(preset):
+    return preset if preset.startswith("olmoe") else f"llama-{preset}"
 
 
 def _runtime_doc(engine):
@@ -102,7 +123,11 @@ def main(argv=None):
                     help="0 = ephemeral (printed at startup)")
     ap.add_argument("--preset", choices=PRESETS, default="tiny",
                     help="tiny: CPU-runnable; 350m; llama7b-8of32: "
-                         "Llama-2-7B widths with 8 of 32 layers, bf16")
+                         "Llama-2-7B widths with 8 of 32 layers, bf16; "
+                         "olmoe-tiny: CPU-runnable routed FFN; "
+                         "olmoe1b7b-8of16: OLMoE-1B-7B-0125 widths with 8 "
+                         "of 16 layers, bf16 (serves on the default "
+                         "path only: other engine switches raise)")
     ap.add_argument("--decode-attention", choices=("pallas", "jnp"),
                     default="pallas",
                     help="the Pallas attention kernels (compiled on a "
@@ -354,7 +379,7 @@ def main(argv=None):
             affinity_band=args.affinity_band,
             host=args.host, port=args.port, num_slots=num_slots,
             max_seq_len=args.max_seq_len, decode_chunk=args.decode_chunk,
-            max_queue=args.max_queue, model_name=f"llama-{args.preset}",
+            max_queue=args.max_queue, model_name=_model_name(args.preset),
             registry=registry, prefix_cache=args.prefix_cache,
             prefix_blocks=args.prefix_blocks,
             prefix_block_size=args.prefix_block_size,
@@ -436,7 +461,7 @@ def main(argv=None):
     server = serve(
         model, host=args.host, port=args.port, num_slots=slots[0],
         max_seq_len=args.max_seq_len, decode_chunk=args.decode_chunk,
-        max_queue=args.max_queue, model_name=f"llama-{args.preset}",
+        max_queue=args.max_queue, model_name=_model_name(args.preset),
         registry=registry,
         prefix_cache=args.prefix_cache, prefix_blocks=args.prefix_blocks,
         prefix_block_size=args.prefix_block_size,

@@ -102,7 +102,8 @@ CARRIED_ENGINE_STATS = (
     "prefill_chunks", "prefill_tokens_saved", "spec_proposed",
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
-    "step_decode_tokens")
+    "step_decode_tokens", "moe_pairs", "moe_experts_touched",
+    "moe_max_expert_pairs", "moe_layer_calls")
 
 #: same carry for the prefix cache's own stats dict (a rebuild builds a
 #: fresh trie — and a fresh host tier — zeroing every counter here).
@@ -452,6 +453,23 @@ class ServingGateway:
                            kind="prefill")
         step_tokens.set_fn(lambda: self._stat("step_decode_tokens"),
                            kind="decode")
+        if self.engine.routed_ffn:
+            # a routed-FFN model only: a dense model's /metrics document
+            # stays as it was. Each is summed over the layer calls of
+            # every step and whole-prompt prefill; ratios of their rates
+            # are per-layer-call means.
+            for stat, text in (
+                    ("pairs", "Live (token, expert) pairs the routed FFN "
+                     "multiplied."),
+                    ("experts_touched", "Experts some live pair touched "
+                     "(whose weights a layer call read)."),
+                    ("max_expert_pairs", "Pairs on the fullest expert of "
+                     "each layer call."),
+                    ("layer_calls", "Routed-FFN layer calls (layers x "
+                     "program calls).")):
+                r.counter(f"serving_moe_{stat}_total",
+                          text + " Monotonic across engine rebuilds."
+                          ).set_fn(lambda k="moe_" + stat: self._stat(k))
         r.gauge("serving_decode_compilations",
                 "Decode-program traces (compile-once contract: stays at "
                 "one per (num_slots, max_seq_len, n_steps)).").set_fn(

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import (NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
-from paddle_tpu.kernels import (flash_attention, pallas_flash,
+from paddle_tpu.kernels import (flash_attention, moe_ffn, pallas_flash,
                                 pallas_paged_decode, pallas_ragged_attention)
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.profiler.metrics import peak_flops_per_chip
@@ -53,7 +53,8 @@ def v5e_devices(monkeypatch):
     devices, why = _v5e_devices()
     if devices is None:
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
-    for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention):
+    for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
+                moe_ffn):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -132,6 +133,44 @@ class TestMosaicCompilesDefaultPathKernels:
         q, kv = v5e((1, 1024, nh, hd)), v5e((1, 1024, nkv, hd))
         n = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
         assert n == 3                    # forward, dk/dv, dq
+
+
+class TestMosaicCompilesTheRoutedFfn:
+    """The routed FFN at OLMoE-1B-7B-0125's widths (hidden 2048, 64 experts
+    of 1024, 8 a token): its three grouped matmuls are Mosaic calls with the
+    tiling ``moe_ffn._tiling`` picks, at the unified step's packed buffer
+    (24 slots + a 512-token chunk) and at a whole-prompt prefill."""
+    H, E, I, K = 2048, 64, 1024, 8
+
+    @pytest.mark.parametrize("rows", [536, 256, 8])
+    def test_grouped_matmuls(self, v5e, rows):
+        def ffn(h, r, wg, wu, wd, live):
+            return moe_ffn.moe_ffn(h, r, wg, wu, wd, top_k=self.K, live=live)
+        n = _mosaic_calls(
+            ffn, v5e((rows, self.H)), v5e((self.H, self.E)),
+            v5e((self.E, self.H, self.I)), v5e((self.E, self.H, self.I)),
+            v5e((self.E, self.I, self.H)), v5e((rows,), jnp.bool_))
+        assert n == 3                    # gate, up, down
+
+    def test_a_layer_of_the_stack_in_place(self, v5e):
+        """Inside the layer scan the weights are the stack over layers and
+        the call reads its layer in place: no copy of a layer's 805 MB of
+        experts appears beside the three kernels."""
+        L, rows = 8, 536
+
+        def ffn(h, r, wg, wu, wd, live, layer):
+            return moe_ffn.moe_ffn(h, r, wg, wu, wd, top_k=self.K, live=live,
+                                   layer=layer)
+        args = (v5e((rows, self.H)), v5e((self.H, self.E)),
+                v5e((L, self.E, self.H, self.I)),
+                v5e((L, self.E, self.H, self.I)),
+                v5e((L, self.E, self.I, self.H)), v5e((rows,), jnp.bool_),
+                v5e((), jnp.int32))
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(ffn).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 3
+        # temp holds the pair buffers (tens of MB), not a layer of experts
+        assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
 
 
 class TestFlashKernelUnderTheHybridMesh:

@@ -873,12 +873,20 @@ class TestGuardDiscipline:
         # every scanned layer body routes its projections through the
         # structure-dispatch helpers — an inline einsum could not
         # reintroduce a dequant site unnoticed
+        # (the whole-prompt prefill and the packed-span forward share
+        # ONE body, ``_decoder_layer``, and hand it ``_dq_layer``'s output)
+        for fn_name in ("_decoder_layer", "_fused_decode_tick",
+                        "_paged_suffix_prefill_impl"):
+            body = src.split(f"def {fn_name}(")[1].split("\ndef ")[0]
+            for helper in ("_qkv_proj(", "_swiglu_proj(", "_o_proj("):
+                assert helper in body, (fn_name, helper)
         for fn_name in ("_packed_span_forward", "_fused_decode_tick",
                         "_paged_suffix_prefill_impl", "_prefill_impl"):
             body = src.split(f"def {fn_name}(")[1].split("\ndef ")[0]
-            for helper in ("_qkv_proj(", "_swiglu_proj(", "_o_proj(",
-                           "_dq_layer("):
-                assert helper in body, (fn_name, helper)
+            assert "_dq_layer(" in body, fn_name
+        for fn_name in ("_packed_span_forward", "_prefill_impl"):
+            body = src.split(f"def {fn_name}(")[1].split("\ndef ")[0]
+            assert "_decoder_layer(" in body, fn_name
 
     def test_sweep_sees_the_tp_launch_path(self):
         """ISSUE 15 satellite: the tensor-parallel launch path stays
@@ -916,11 +924,16 @@ class TestGuardDiscipline:
             assert f"def {name}(" in dec, name
         # every layer body applies tp_reduce at BOTH sites (o-proj +
         # down-proj) — the one-all-reduce-pair-per-layer contract
-        for fn_name in ("_packed_span_forward", "_fused_decode_tick",
-                        "_paged_suffix_prefill_impl", "_prefill_impl"):
+        # (``_decoder_layer`` is the body of the whole-prompt prefill and
+        # of the packed-span forward, which pass it their ``tp_reduce``)
+        for fn_name in ("_decoder_layer", "_fused_decode_tick",
+                        "_paged_suffix_prefill_impl"):
             body = dec.split(f"def {fn_name}(")[1].split("\ndef ")[0]
             assert body.count("tp_reduce(o)") == 1, fn_name
             assert body.count("tp_reduce(m)") == 1, fn_name
+        for fn_name in ("_packed_span_forward", "_prefill_impl"):
+            body = dec.split(f"def {fn_name}(")[1].split("\ndef ")[0]
+            assert body.count("tp_reduce=tp_reduce") == 1, fn_name
 
     def test_sweep_sees_the_fused_tick_and_overlap_path(self):
         """ISSUE 20 satellite: the one-kernel decode path stays inside
