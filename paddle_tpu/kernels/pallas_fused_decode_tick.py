@@ -210,8 +210,8 @@ def _fused_tick_pallas(params, stack, head, tables, sin, cos, tok, pk_all,
         else:
             pk_l = pool_refs[0][0]
             pv_l = pool_refs[1][0]
-        pk_l = _kv_write(pk_l, physv, prowv, k[:, 0])
-        pv_l = _kv_write(pv_l, physv, prowv, v[:, 0])
+        pk_l = _kv_write(pk_l, (physv, prowv), k[:, 0])
+        pv_l = _kv_write(pv_l, (physv, prowv), v[:, 0])
         if kvq:
             o_pool_refs[0][0] = pk_l[0]
             o_pool_refs[1][0] = pk_l[1]
@@ -235,8 +235,6 @@ def _fused_tick_pallas(params, stack, head, tables, sin, cos, tok, pk_all,
         q_wide = jnp.einsum("bkgd,kj->bkgjd",
                             qh.reshape(R, nkv, nh // nkv, hd),
                             eye).reshape(R, nh, kd)
-        pool_k2 = kd_.reshape(nb, bs, kd)
-        pool_v2 = vd_.reshape(nb, bs, kd)
         tbl = tbl_ref[...]
         alens = alen_ref[...]
         outs = []
@@ -249,9 +247,9 @@ def _fused_tick_pallas(params, stack, head, tables, sin, cos, tok, pk_all,
             qb = q_wide[b]
             for ki in range(mb):
                 idx = jnp.clip(tbl[b, jnp.minimum(ki, last)], 0, nb - 1)
-                kb = jax.lax.dynamic_index_in_dim(pool_k2, idx, 0,
+                kb = jax.lax.dynamic_index_in_dim(kd_, idx, 0,
                                                   keepdims=False)
-                vb = jax.lax.dynamic_index_in_dim(pool_v2, idx, 0,
+                vb = jax.lax.dynamic_index_in_dim(vd_, idx, 0,
                                                   keepdims=False)
                 if kvq:
                     kb = kb.astype(jnp.float32)

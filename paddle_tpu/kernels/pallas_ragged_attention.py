@@ -46,13 +46,15 @@ fetch and the Mosaic-conservative 2D tiles are ``pallas_paged_decode.py``'s):
   ``fori_loop`` runs over exactly the pair's KV blocks — up to the row's
   own ``kv_len`` AND the causal diagonal of the last span token inside
   the query block, so the early query blocks of a chunk never touch the
-  blocks their mask would remove. The pool stays in HBM (``pl.ANY``); each
-  iteration resolves the scalar-prefetched table in SMEM and fetches one
-  block by double-buffered ``make_async_copy`` (the next block streams in
-  while this one computes), so HBM traffic and MXU work both scale with
-  the live logical cache. Sentinel entries (``>= num_blocks``) clamp into
-  the pool — a harmless read, masked off. An int8 / fp8 pool's scale
-  planes ride the same physical index as their data block.
+  blocks their mask would remove. The stored pool, every layer of it,
+  stays in HBM where it lies (``pl.ANY``); each iteration resolves the
+  scalar-prefetched table in SMEM and fetches one block, at ``(layer,
+  table entry)``, by double-buffered ``make_async_copy`` (the next block
+  streams in while this one computes), so HBM traffic and MXU work both
+  scale with the live logical cache and no layer of the pool is cut out or
+  re-laid-out for the call. Sentinel entries (``>= num_blocks``) clamp into
+  the layer's own blocks — a harmless read, masked off. An int8 / fp8
+  pool's scale planes ride the same physical index as their data block.
 - **One output block, several rows**: visits to an output block are
   consecutive; the first zeroes it, each row's visit writes back only its
   own span by a masked read-modify-write. MXU work on the masked remainder
@@ -84,8 +86,8 @@ NEG_INF = -1e30
 
 
 def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                   tbl_ref, *refs, scale, block_k, tq, gh, num_blocks,
-                   quantized=False, hkv=0):
+                   tbl_ref, layer_ref, *refs, scale, block_k, tq, gh,
+                   num_blocks, quantized=False, hkv=0):
     # positional ref layout follows the pallas_call spec lists: inputs
     # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
     # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc)
@@ -103,6 +105,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     qi = wq_ref[w]
     r = wr_ref[w]
     nkb = wn_ref[w]                 # KV blocks this pair walks (0 = dead)
+    layer = layer_ref[0]            # which layer of the stored pool
     qstart = qs_ref[r]
     qlen = ql_ref[r]
     kvlen = kl_ref[r]
@@ -119,16 +122,22 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     def _copies(ki, slot):
         # table-indirect fetch of logical block ki into buffer `slot`:
         # the table is resolved from SMEM at DMA-issue time; sentinel
-        # entries clamp into the pool (a harmless read, masked by kvlen).
-        # The scale planes ride the SAME physical index as their data.
+        # entries clamp into THIS layer's blocks before the layer is
+        # applied (a harmless read, masked by kvlen; never a block of the
+        # next layer). The scale planes, this layer's already, ride the
+        # SAME block index as their data.
         phys = jnp.clip(tbl_ref[r, ki], 0, num_blocks - 1)
-        return [
-            pltpu.make_async_copy(
-                # per-block fp8 planes are [num_blocks, hkv]: a one-row
-                # window keeps the buffer 2D like every other operand
-                hbm.at[pl.ds(phys, 1)] if hbm.ndim == 2 else hbm.at[phys],
-                buf.at[slot], sems.at[i, slot])
-            for i, (hbm, buf) in enumerate(streams)]
+
+        def window(hbm):
+            if hbm.ndim == 4:           # the stored pool [L, nb, bs, KD]
+                return hbm.at[layer, phys]
+            # per-block fp8 planes are [num_blocks, lanes]: a one-row
+            # window keeps the buffer 2D like every other operand
+            return hbm.at[pl.ds(phys, 1)] if hbm.ndim == 2 else hbm.at[phys]
+
+        return [pltpu.make_async_copy(window(hbm), buf.at[slot],
+                                      sems.at[i, slot])
+                for i, (hbm, buf) in enumerate(streams)]
 
     def _compute(ki, slot):
         q = q_ref[:]                        # [tq, KD] block-diag wide
@@ -283,21 +292,23 @@ def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
     return wq, wr, first.astype(jnp.int32), wn
 
 
-def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
+def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
                  scale, gh, block_q, interpret, scales=None):
     """q_wide: [TH_pad, KD] block-diagonal wide rows (gh per token);
-    pool_*: [num_blocks, bs, KD]; tables: [R, max_blocks] int32;
+    pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole;
+    layer: [1] int32, the layer whose blocks this call reads;
+    tables: [R, max_blocks] int32;
     scales: None, or ``(k_scale, v_scale)`` fp32 planes for a
     quantized pool (upcast in-kernel, right after the table-indirect
-    DMA): [num_blocks, bs, Hkv] per-row planes select the int8 path,
-    [num_blocks, Hkv] per-block planes select fp8 — the plane rank IS
+    DMA): [L, num_blocks, bs, Hkv] per-row planes select the int8 path,
+    [L, num_blocks, Hkv] per-block planes select fp8 — the plane rank IS
     the mode switch, same convention as ``pallas_paged_decode``.
 
     The grid is the work list (``_work_list``): one step per (query block,
-    row) pair, and inside it a loop over exactly the pair's KV blocks, the
-    pool left in HBM and fetched block by block through the table."""
+    row) pair, and inside it a loop over exactly the pair's KV blocks,
+    fetched block by block at ``(layer, table entry)``."""
     TH, KD = q_wide.shape
-    num_blocks, bs = pool_k.shape[0], pool_k.shape[1]
+    num_blocks, bs = pool_k.shape[1], pool_k.shape[2]
     R, nk = tables.shape
     nq = TH // block_q
     work = _work_list(qstart, qlen, kvlen, nq=nq,
@@ -306,7 +317,7 @@ def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
     if scales is None:
         quantized = False
     else:
-        quantized = "fp8" if scales[0].ndim == 2 else "int8"
+        quantized = "fp8" if scales[0].ndim == 3 else "int8"
     hkv = scales[0].shape[-1] if quantized else 0
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
                                tq=block_q, gh=gh, num_blocks=num_blocks,
@@ -317,16 +328,18 @@ def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
 
     in_pool = pl.BlockSpec(memory_space=pl.ANY)     # fetched by the kernel
     in_specs = [pl.BlockSpec((block_q, KD), _q_index), in_pool, in_pool]
-    args = [*work, qstart, qlen, kvlen, tables, q_wide, pool_k, pool_v]
+    args = [*work, qstart, qlen, kvlen, tables, layer, q_wide, pool_k,
+            pool_v]
     bufs = [pltpu.VMEM((2, bs, KD), pool_k.dtype),
             pltpu.VMEM((2, bs, KD), pool_v.dtype)]
     if quantized:
-        # per-row int8 planes [num_blocks, bs, hkv] move one [bs, hkv]
-        # block, per-BLOCK fp8 planes [num_blocks, hkv] one [1, hkv] row.
-        # A DMA window's minor dim must be whole lanes, so the planes are
-        # padded to 128 heads here and the kernel reads the first hkv
+        # per-row int8 planes [nb, bs, hkv] move one [bs, hkv] block,
+        # per-BLOCK fp8 planes [nb, hkv] one [1, hkv] row. A DMA window's
+        # minor dim must be whole lanes, so this layer's planes are cut out
+        # and padded to 128 heads here and the kernel reads the first hkv
         lanes = -(-hkv // 128) * 128
-        scales = [jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, lanes - hkv)])
+        scales = [jnp.pad(jax.lax.dynamic_index_in_dim(p, layer[0], 0, False),
+                          [(0, 0)] * (p.ndim - 2) + [(0, lanes - hkv)])
                   for p in scales]
         plane = (1, lanes) if quantized == "fp8" else (bs, lanes)
         in_specs += [in_pool, in_pool]
@@ -335,7 +348,7 @@ def _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
+            num_scalar_prefetch=9,
             grid=(nq + R,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_q, KD), _q_index),
@@ -393,18 +406,20 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
 
 # Inference-only custom_vjp, same rationale as pallas_paged_decode: the
 # eager dispatch linearizes through every op and scalar-prefetch
-# pallas_calls don't linearize in interpret mode.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _ragged(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen, scale,
-            gh, block_q):
-    return _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen,
-                        kvlen, scale, gh, block_q, _interpret_mode())
+# pallas_calls don't linearize in interpret mode. ``scales`` is ``()`` or
+# the ``(k_scale, v_scale)`` planes of a quantized pool.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart, qlen,
+            kvlen, scale, gh, block_q):
+    return _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen,
+                        kvlen, scale, gh, block_q, _interpret_mode(),
+                        scales=scales or None)
 
 
-def _ragged_fwd_rule(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
-                     scale, gh, block_q):
-    return _ragged(q_wide, pool_k, pool_v, tables, qstart, qlen, kvlen,
-                   scale, gh, block_q), None
+def _ragged_fwd_rule(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
+                     qlen, kvlen, scale, gh, block_q):
+    return _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
+                   qlen, kvlen, scale, gh, block_q), None
 
 
 def _ragged_bwd_rule(scale, gh, block_q, res, g):
@@ -416,40 +431,35 @@ def _ragged_bwd_rule(scale, gh, block_q, res, g):
 _ragged.defvjp(_ragged_fwd_rule, _ragged_bwd_rule)
 
 
-# quantized twin (the arg count differs, so it needs its own custom_vjp
-# wrapper; same inference-only rationale)
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
-def _ragged_q(q_wide, pool_k, pool_v, k_scale, v_scale, tables, qstart,
-              qlen, kvlen, scale, gh, block_q):
-    return _ragged_call(q_wide, pool_k, pool_v, tables, qstart, qlen,
-                        kvlen, scale, gh, block_q, _interpret_mode(),
-                        scales=(k_scale, v_scale))
-
-
-def _ragged_q_fwd_rule(q_wide, pool_k, pool_v, k_scale, v_scale, tables,
-                       qstart, qlen, kvlen, scale, gh, block_q):
-    return _ragged_q(q_wide, pool_k, pool_v, k_scale, v_scale, tables,
-                     qstart, qlen, kvlen, scale, gh, block_q), None
-
-
-def _ragged_q_bwd_rule(scale, gh, block_q, res, g):
-    raise NotImplementedError(
-        "ragged_paged_attention_pallas is inference-only (the serving "
-        "step never backpropagates)")
-
-
-_ragged_q.defvjp(_ragged_q_fwd_rule, _ragged_q_bwd_rule)
+def _stored_pool(pool_k, pool_v, k_scale, v_scale, layer):
+    """Both call forms as ``(pool_k, pool_v, scales, layer [1] int32)`` over
+    a pool ``[L, num_blocks, bs, Hkv * D]``: with ``layer`` the arrays are
+    the stored pool already (``serving.block_manager.BlockManager``); without
+    it they are one layer ``[num_blocks, bs, Hkv, D]`` and become the
+    ``L = 1`` pool (merging the two minor dims is a copy on the chip, which
+    only tests and microbenchmarks pay)."""
+    if layer is None:
+        pool_k, pool_v = (jnp.reshape(p, (1,) + p.shape[:2] + (-1,))
+                          for p in (pool_k, pool_v))
+        if k_scale is not None:
+            k_scale, v_scale = (jnp.asarray(p)[None]
+                                for p in (k_scale, v_scale))
+        layer = 0
+    scales = () if k_scale is None else (k_scale, v_scale)
+    return pool_k, pool_v, scales, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
                                   kvlen, block_q=256, k_scale=None,
-                                  v_scale=None):
+                                  v_scale=None, layer=None):
     """Mixed prefill+decode attention over packed query spans through
     per-sequence block tables.
 
     q:        [T, H, D]              — the packed query buffer
-    pool_k:   [num_blocks, bs, Hkv, D]  — the shared KV block pool
-    pool_v:   [num_blocks, bs, Hkv, D]
+    pool_k, pool_v, layer: the KV block pool and the layer to read
+              (``_stored_pool``): the step programs pass the stored pool
+              whole and a traced ``layer``, and the kernel fetches blocks
+              from it where it lies
     tables:   [R, max_blocks] int32  — physical block ids per sequence
                                        (entries >= num_blocks = unmapped)
     qstart:   [R] int32 — span start (packed row) per sequence
@@ -458,9 +468,10 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
                           step's writes (span token i attends over
                           positions 0 .. kvlen - qlen + i)
     k_scale/v_scale: None, or fp32 scale planes for a quantized pool
-              (README "Quantized serving") — [num_blocks, bs, Hkv]
-              per-row planes for int8, [num_blocks, Hkv] per-block
-              planes for fp8 (plane rank = mode switch). The kernel
+              (README "Quantized serving"), stacked or one layer's like
+              the pool — per-row ``[.., num_blocks, bs, Hkv]`` planes for
+              int8, per-block ``[.., num_blocks, Hkv]`` planes for fp8
+              (plane rank = mode switch). The kernel
               DMAs the narrow blocks and upcasts in VMEM right after
               the table-indirect fetch — one upcast site, fused into
               the dot — so HBM traffic is 1-byte while the MXU math
@@ -478,11 +489,12 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     (same block walk, same online-softmax accumulation order).
     """
     T, H, D = q.shape
-    Hkv = pool_k.shape[2]
+    pool_k, pool_v, scales, layer = _stored_pool(pool_k, pool_v, k_scale,
+                                                 v_scale, layer)
+    KD = pool_k.shape[-1]
+    Hkv = KD // D
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
-    num_blocks, bs = pool_k.shape[0], pool_k.shape[1]
-    KD = Hkv * D
     scale = 1.0 / math.sqrt(D)
     qstart = jnp.asarray(qstart, jnp.int32).reshape(-1)
     qlen = jnp.asarray(qlen, jnp.int32).reshape(-1)
@@ -499,15 +511,8 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     th_pad = -(-(T * H) // bq) * bq
     if th_pad != T * H:
         q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))
-    if k_scale is not None:
-        out_wide = _ragged_q(q_wide, pool_k.reshape(num_blocks, bs, KD),
-                             pool_v.reshape(num_blocks, bs, KD),
-                             k_scale, v_scale, tables, qstart, qlen,
-                             kvlen, scale, H, bq)
-    else:
-        out_wide = _ragged(q_wide, pool_k.reshape(num_blocks, bs, KD),
-                           pool_v.reshape(num_blocks, bs, KD), tables,
-                           qstart, qlen, kvlen, scale, H, bq)
+    out_wide = _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
+                       qlen, kvlen, scale, H, bq)
     out_wide = out_wide[:T * H]
     # extract each head's own kv-group block from the wide accumulator
     out = jnp.einsum("bkgjd,kj->bkgd",
@@ -516,9 +521,11 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
 
 
 def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
-                               kvlen, k_scale=None, v_scale=None):
-    """jnp oracle with identical semantics — and, deliberately, the
-    exact op sequence of the two programs it unifies: a span-1 row
+                               kvlen, k_scale=None, v_scale=None, layer=None):
+    """jnp oracle with identical semantics and operands (``_stored_pool``:
+    with ``layer`` the tables gather straight from the stored pool, no
+    layer of it is cut out) — and, deliberately, the exact op sequence of
+    the two programs it unifies: a span-1 row
     reproduces ``paged_decode_attention_reference`` and a span-n row
     reproduces ``_paged_suffix_prefill_impl``'s in-program attention
     (same einsums, same masking, same plain softmax), so the unified
@@ -528,7 +535,9 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
     kernel; per-block fp8 planes (ndim 2) broadcast over the block's
     rows."""
     T, H, D = q.shape
-    num_blocks, bs, Hkv, _ = pool_k.shape
+    pool_k, pool_v, scales, layer = _stored_pool(pool_k, pool_v, k_scale,
+                                                 v_scale, layer)
+    bs, Hkv = pool_k.shape[2], pool_k.shape[3] // D
     G = H // Hkv
     R, mb = jnp.asarray(tables).shape
     s_tot = mb * bs
@@ -537,6 +546,10 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
     qlen = jnp.asarray(qlen, jnp.int32).reshape(R)
     kvlen = jnp.asarray(kvlen, jnp.int32).reshape(R)
     tables = jnp.asarray(tables, jnp.int32)
+
+    def blocks(pool):               # [R, mb, ...]: this layer's, by table
+        return jnp.asarray(pool).at[layer[0], tables].get(mode="clip")
+
     t_idx = jnp.arange(T, dtype=jnp.int32)
     # token -> sequence map (spans are disjoint; dead tokens match none)
     in_r = (t_idx[None, :] >= qstart[:, None]) \
@@ -552,25 +565,19 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
     # CPU/jnp serving path the packed buffer's padding rows would
     # otherwise multiply the dominant gather cost ~T/R-fold.
     # (clip keeps sentinel entries harmless — masked by kvlen)
-    k_rows = jnp.take(pool_k, tables, axis=0,
-                      mode="clip").reshape(R, s_tot, Hkv, D)
-    v_rows = jnp.take(pool_v, tables, axis=0,
-                      mode="clip").reshape(R, s_tot, Hkv, D)
-    if k_scale is not None:
+    k_rows = blocks(pool_k).reshape(R, s_tot, Hkv, D)
+    v_rows = blocks(pool_v).reshape(R, s_tot, Hkv, D)
+    if scales:
         # quantized pool: upcast right after the per-row gather (the
         # kernel's fetch-then-dequantize order). Per-block fp8 planes
-        # ([num_blocks, Hkv]) broadcast over each block's rows;
+        # ([L, num_blocks, Hkv]) broadcast over each block's rows;
         # per-row int8 planes apply per row and head.
-        if jnp.asarray(k_scale).ndim == 2:
-            ks_rows = jnp.repeat(jnp.take(k_scale, tables, axis=0,
-                                          mode="clip"), bs, axis=1)
-            vs_rows = jnp.repeat(jnp.take(v_scale, tables, axis=0,
-                                          mode="clip"), bs, axis=1)
+        if scales[0].ndim == 3:
+            ks_rows, vs_rows = (jnp.repeat(blocks(p), bs, axis=1)
+                                for p in scales)
         else:
-            ks_rows = jnp.take(k_scale, tables, axis=0,
-                               mode="clip").reshape(R, s_tot, Hkv)
-            vs_rows = jnp.take(v_scale, tables, axis=0,
-                               mode="clip").reshape(R, s_tot, Hkv)
+            ks_rows, vs_rows = (blocks(p).reshape(R, s_tot, Hkv)
+                                for p in scales)
         k_rows = k_rows.astype(jnp.float32) * ks_rows[..., None]
         v_rows = v_rows.astype(jnp.float32) * vs_rows[..., None]
     k = jnp.take(k_rows, seg, axis=0)                     # [T, s_tot, ...]

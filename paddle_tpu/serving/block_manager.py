@@ -1,11 +1,10 @@
 """Ref-counted block pool backing the automatic prefix cache.
 
 The pool is the block-granular half of the serving KV story ("Ragged
-Paged Attention", PAPERS.md): two dense device arrays
-``[L, num_blocks, block_size, Hkv, D]`` holding published prompt-prefix
-KV blocks, plus host-side bookkeeping — a free-block min-heap (same
-O(log n) allocator discipline as :class:`~.kv_cache.SlotKVCache`) and a
-per-block reference count.
+Paged Attention", PAPERS.md): two dense device arrays (K and V, laid out
+as :class:`BlockManager` says) holding KV blocks, plus host-side
+bookkeeping — a free-block min-heap (same O(log n) allocator discipline
+as :class:`~.kv_cache.SlotKVCache`) and a per-block reference count.
 
 Division of labor: this class owns *physical* blocks (allocation,
 refcounts, storage); :class:`~.prefix_cache.PrefixCache` owns *logical*
@@ -86,6 +85,31 @@ class StagingPool:
 class BlockManager:
     """Physical block pool: device arrays + free heap + refcounts.
 
+    **The stored layout** (this is its one statement; ``pool_shape``
+    its one definition): ``k`` and ``v`` are ``[L, num_blocks,
+    block_size, Hkv * D]``, a row's heads side by side on the minor,
+    lane-dense axis, which is how the ragged attention kernel reads a
+    block: it is handed the whole buffer and fetches block ``(layer,
+    table entry)`` from where it lies, so no step program slices,
+    copies or re-lays-out a layer of the pool. The logical ``(Hkv, D)``
+    are ``num_kv_heads`` / ``head_dim``; code that wants them apart
+    reshapes the few rows or the block it holds, never the pool.
+    Writers scatter rows at ``[layer, block, row]``
+    (``serving.decode._kv_write``), in place on the donated buffer.
+    Under tensor parallelism the minor axis is cut into ``tp``
+    contiguous pieces, ``Hkv / tp`` whole heads each.
+
+    **The sentinel rule**: the block id ``num_blocks`` (one past the
+    last block; ``PagedKVCache.sentinel``) marks an unmapped table
+    entry and the target of a write that must not happen (a dead packed
+    row, a padding row, a position past the table). Writes index the
+    pool by ``(layer, block, row)`` in drop mode, so a sentinel write is
+    out of range on the block axis and vanishes; reads clamp the table
+    entry into ``[0, num_blocks)`` BEFORE the layer is applied and mask
+    the rows by length. Never flatten ``(layer, block)`` to ``layer *
+    num_blocks + block`` around a sentinel: that is block 0 of the next
+    layer.
+
     ``kv_dtype="int8"`` stores the pool block-quantized (README
     "Quantized serving"): ``k``/``v`` become int8 and each block
     carries a per-row-per-head fp32 SCALE PLANE alongside it —
@@ -129,8 +153,10 @@ class BlockManager:
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype is not None
         self.fp8 = kv_dtype == "fp8"
-        shape = (num_layers, self.num_blocks, self.block_size,
-                 num_kv_heads, head_dim)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        shape = self.pool_shape(num_layers, self.num_blocks,
+                                self.block_size, num_kv_heads, head_dim)
         store = (jnp.float8_e4m3fn if self.fp8
                  else jnp.int8 if self.quantized else dtype)
         self.k = jnp.zeros(shape, store)
@@ -144,8 +170,9 @@ class BlockManager:
             self.k_scale = jnp.ones(sshape, jnp.float32)
             self.v_scale = jnp.ones(sshape, jnp.float32)
         elif self.quantized:
-            self.k_scale = jnp.zeros(shape[:-1], jnp.float32)
-            self.v_scale = jnp.zeros(shape[:-1], jnp.float32)
+            sshape = shape[:-1] + (num_kv_heads,)
+            self.k_scale = jnp.zeros(sshape, jnp.float32)
+            self.v_scale = jnp.zeros(sshape, jnp.float32)
         else:
             self.k_scale = self.v_scale = None
         # tensor-parallel pool partition (README "Tensor-parallel
@@ -184,6 +211,13 @@ class BlockManager:
         # reusable host buffers for read_block copies, recycled by the
         # host tier's drop/readmit paths through recycle_staging
         self.staging = StagingPool()
+
+    @staticmethod
+    def pool_shape(num_layers, num_blocks, block_size, num_kv_heads,
+                   head_dim):
+        """The stored shape of ``k`` and of ``v`` (class docstring)."""
+        return (int(num_layers), int(num_blocks), int(block_size),
+                int(num_kv_heads) * int(head_dim))
 
     # ---------------------------------------------------------- allocator
     @property

@@ -48,7 +48,7 @@ from ..kernels.pallas_ragged_attention import (ragged_attention_reference,
                                                ragged_paged_attention_pallas)
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
-from .kv_cache import quantize_kv_rows, quantize_kv_rows_fp8
+from .kv_cache import kv_rows, quantize_kv_rows, quantize_kv_rows_fp8
 
 NEG_INF = -1e30
 
@@ -69,18 +69,19 @@ _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
 def _layer_stack(params):
     """(names, arrays, experts) of the per-layer entries a layer scan
-    carries: the nine every model has, then whichever extras this tree
-    holds. For a tree with ``router``, ``experts`` is the three expert
-    stacks, whole, their places in the scanned tuple hold None, and the scan
-    carries the layer's index as ``layer``; else ``experts`` is None."""
+    carries: the nine every model has, whichever extras this tree holds,
+    and last the layer's index as ``layer``: what is too large to slice a
+    layer out of (the KV pool, a routed FFN's expert stacks) is read in
+    place at that index. For a tree with ``router``, ``experts`` is the
+    three expert stacks, whole, and their places in the scanned tuple hold
+    None; else ``experts`` is None."""
     keys = _STACK_KEYS + tuple(k for k in _STACK_EXTRA_KEYS if k in params)
-    if "router" not in params:
-        return keys, tuple(params[k] for k in keys), None
-    n_layers = params["router"].shape[0]
-    stack = tuple(None if k in _EXPERT_KEYS else params[k] for k in keys)
-    return (keys + ("layer",),
-            stack + (jnp.arange(n_layers, dtype=jnp.int32),),
-            tuple(params[k] for k in _EXPERT_KEYS))
+    routed = "router" in params
+    stack = tuple(None if routed and k in _EXPERT_KEYS else params[k]
+                  for k in keys)
+    layers = jnp.arange(params["input_ln"].shape[0], dtype=jnp.int32)
+    return (keys + ("layer",), stack + (layers,),
+            tuple(params[k] for k in _EXPERT_KEYS) if routed else None)
 
 
 #: the decode-path projection matmuls quantize_weights=True converts
@@ -314,24 +315,34 @@ def _kv_attn_args(pool_k, pool_v):
     return pool_k, pool_v, None, None
 
 
-def _kv_write(pool_l, phys, row, x):
-    """Scatter K/V rows ``x [..., Hkv, D]`` into one layer's pool slice
-    at ``(phys, row)`` — quantizing on write on a quantized pool.
-    int8 writes data + per-row-per-head scales to the SAME
+def _kv_write(pool, at, x):
+    """Scatter K/V rows ``x [..., Hkv, D]`` into the pool at the index
+    arrays ``at``: ``(layer, phys, row)`` into the stored pool
+    ``[L, nb, bs, Hkv * D]`` (in place on a donated or carried buffer),
+    ``(phys, row)`` into one layer's view of it — quantizing on write on a
+    quantized pool. int8 writes data + per-row-per-head scales to the SAME
     coordinates; fp8 is a data-only saturating cast
     (``quantize_kv_rows_fp8``) — its per-BLOCK scale planes are the
     constant 1.0 and are never written by appends (the determinism
-    argument in ``BlockManager``'s docstring). Drop-mode both ways: a
-    dead row vanishes from data and scales alike."""
-    if isinstance(pool_l, tuple):
-        data, sc = pool_l
+    argument in ``BlockManager``'s docstring). Drop-mode both ways, index
+    by index: a dead row (``phys`` the sentinel ``nb``) vanishes from data
+    and scales alike and never lands in the next layer's block 0."""
+    if isinstance(pool, tuple):
+        data, sc = pool
         if data.dtype == jnp.float8_e4m3fn:
-            return (data.at[phys, row].set(quantize_kv_rows_fp8(x),
-                                           mode="drop"), sc)
+            return (data.at[at].set(kv_rows(quantize_kv_rows_fp8(x)),
+                                    mode="drop"), sc)
         q, s = quantize_kv_rows(x)
-        return (data.at[phys, row].set(q, mode="drop"),
-                sc.at[phys, row].set(s, mode="drop"))
-    return pool_l.at[phys, row].set(x, mode="drop")
+        return (data.at[at].set(kv_rows(q), mode="drop"),
+                sc.at[at].set(s, mode="drop"))
+    return pool.at[at].set(kv_rows(x), mode="drop")
+
+
+def _kv_heads(pool_l, nkv):
+    """One layer's pool data ``[nb, bs, Hkv * D]`` with its heads apart,
+    ``[nb, bs, Hkv, D]``, for the paged-decode kernels (programs that run
+    in no benchmark cell; the unified step never cuts a layer out)."""
+    return pool_l.reshape(pool_l.shape[:2] + (nkv, -1))
 
 
 def _kv_gather_rows(pool_l, tables, shape4):
@@ -549,8 +560,9 @@ def _params_pspec(wq8):
 
 def _pool_pspec(kv_quant):
     """PartitionSpec for one pool side: blocks replicated, HEADS
-    sharded (axis 3 of ``[L, nb, bs, Hkv, D]``). A quantized pool's
-    scale planes partition on the same head axis — int8's per-row
+    sharded (axis 3 of the stored ``[L, nb, bs, Hkv * D]``: ``tp``
+    contiguous pieces of it are ``Hkv / tp`` whole heads each). A quantized
+    pool's scale planes partition on the same head axis — int8's per-row
     planes ``[L, nb, bs, Hkv]`` on axis 3, fp8's per-BLOCK planes
     ``[L, nb, Hkv]`` on axis 2. ``kv_quant``: False, "int8"/"fp8", or
     True (int8 back-compat)."""
@@ -910,8 +922,8 @@ def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
             # POST-dot (``_row_scale_bhqk``), so a quantized pool never
             # round-trips through a materialized fp copy; the causal mask
             # keeps columns from seeing rows past their position
-            pk_l = _kv_write(pk_l, phys, prow, k)
-            pv_l = _kv_write(pv_l, phys, prow, v)
+            pk_l = _kv_write(pk_l, (phys, prow), k)
+            pv_l = _kv_write(pv_l, (phys, prow), v)
             ck, ksr = _kv_gather_rows(pk_l, tables, (G, s_tot, nkv, hd))
             cv, vsr = _kv_gather_rows(pv_l, tables, (G, s_tot, nkv, hd))
             kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
@@ -1103,14 +1115,13 @@ def _paged_decode_steps_impl(params, pool_k, pool_v, tables, tokens,
                 q = _apply_rope_rows(q, sin_p, cos_p)
                 k = _apply_rope_rows(k, sin_p, cos_p)
                 # ragged append through the table (dead slots drop)
-                pk_l = pk_l.at[phys, prow].set(k[:, 0], mode="drop")
-                pv_l = pv_l.at[phys, prow].set(v[:, 0], mode="drop")
-                if decode_attn == "pallas":
-                    attn = paged_decode_attention_pallas(
-                        q[:, 0], pk_l, pv_l, tables, lens + 1)
-                else:
-                    attn = paged_decode_attention_reference(
-                        q[:, 0], pk_l, pv_l, tables, lens + 1)
+                pk_l = _kv_write(pk_l, (phys, prow), k[:, 0])
+                pv_l = _kv_write(pv_l, (phys, prow), v[:, 0])
+                attend = (paged_decode_attention_pallas
+                          if decode_attn == "pallas"
+                          else paged_decode_attention_reference)
+                attn = attend(q[:, 0], _kv_heads(pk_l, nkv),
+                              _kv_heads(pv_l, nkv), tables, lens + 1)
                 h = h + jnp.einsum("bsd,dh->bsh",
                                    attn.reshape(B, 1, nh * hd), lwo)
             h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
@@ -1193,17 +1204,14 @@ def _fused_decode_tick(params, stack, head, tables, sin, cos, tok, pk_all,
             q, k, v = _qkv_proj(hn, lwq, lwk, lwv, nh, nkv, hd)
             q = _apply_rope_rows(q, sin_r, cos_r)
             k = _apply_rope_rows(k, sin_r, cos_r)
-            pk_l = _kv_write(pk_l, phys, prow, k[:, 0])
-            pv_l = _kv_write(pv_l, phys, prow, v[:, 0])
+            pk_l = _kv_write(pk_l, (phys, prow), k[:, 0])
+            pv_l = _kv_write(pv_l, (phys, prow), v[:, 0])
             kd, vd, ksc, vsc = _kv_attn_args(pk_l, pv_l)
-            if decode_attn == "pallas":
-                attn = paged_decode_attention_pallas(
-                    q[:, 0], kd, vd, tables, lens + app_mask,
-                    k_scale=ksc, v_scale=vsc)
-            else:
-                attn = paged_decode_attention_reference(
-                    q[:, 0], kd, vd, tables, lens + app_mask,
-                    k_scale=ksc, v_scale=vsc)
+            attend = (paged_decode_attention_pallas
+                      if decode_attn == "pallas"
+                      else paged_decode_attention_reference)
+            attn = attend(q[:, 0], _kv_heads(kd, nkv), _kv_heads(vd, nkv),
+                          tables, lens + app_mask, k_scale=ksc, v_scale=vsc)
             o = _o_proj(attn.reshape(R, 1, nh * hd), lwo)
             h = h + (o if tp_reduce is None else tp_reduce(o))
         m = _swiglu_proj(_rms(h, lpost, eps), lg, lu, ld)
@@ -1272,8 +1280,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     phys0 = jnp.where(live_tok & (pos < s_tot), phys0, nb)
     prow0 = pos % bs
 
-    def layer0(h, lp):
-        pk_l, pv_l = lp[-2:]
+    def layer0(carry, lp):
+        h, pk, pv = carry
+        lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
+        at = (lw["layer"], phys0, prow0)
 
         def attend(q, k, v):
             # write the packed K/V through the tables (quantize-on-write on
@@ -1282,30 +1292,28 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
             # (or its oracle) dequantizes right after the table-indirect
             # fetch, and every consumer of this forward (unified step,
             # multi-tick tick 0, speculative verify) rides it
-            npk = _kv_write(pk_l, phys0, prow0, k[0])
-            npv = _kv_write(pv_l, phys0, prow0, v[0])
+            npk = _kv_write(pk, at, k[0])
+            npv = _kv_write(pv, at, v[0])
             kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
-            if decode_attn == "pallas":
-                attn = ragged_paged_attention_pallas(
-                    q[0], kd, vd, tables, qstart, qlen, kvlen,
-                    k_scale=ksc, v_scale=vsc)
-            else:
-                attn = ragged_attention_reference(
-                    q[0], kd, vd, tables, qstart, qlen, kvlen,
-                    k_scale=ksc, v_scale=vsc)
+            ragged = (ragged_paged_attention_pallas
+                      if decode_attn == "pallas"
+                      else ragged_attention_reference)
+            attn = ragged(q[0], kd, vd, tables, qstart, qlen, kvlen,
+                          k_scale=ksc, v_scale=vsc, layer=lw["layer"])
             return attn, (npk, npv)
 
-        h, kv, stats = _decoder_layer(
-            h, dict(zip(names, _dq_layer(lp[:-2], wdt, a8))), nh=nh,
-            nkv=nkv, hd=hd, eps=eps,
+        h, (pk, pv), stats = _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps,
             rope=lambda x: _apply_rope_grid(x, sin_p, cos_p),
             attend=attend, live=live_tok[None], moe=moe, experts=experts,
             tp_reduce=tp_reduce)
-        return h, (kv, stats)
+        return (h, pk, pv), stats
 
+    # the pool rides the scan as CARRY, whole: a layer appends its rows and
+    # reads its blocks at [layer, ...] of the one buffer, so no op of the
+    # scan slices a layer out of the pool or stacks one back into it
     x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
-    x, ((pk, pv), stats) = jax.lax.scan(layer0, x,
-                                        stack + (pool_k, pool_v))
+    (x, pk, pv), stats = jax.lax.scan(layer0, (x, pool_k, pool_v), stack)
     return x, pk, pv, stats
 
 

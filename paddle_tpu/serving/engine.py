@@ -387,7 +387,7 @@ class ContinuousBatchingEngine:
                 pool = prefix_cache.pool
                 want = (c.num_hidden_layers, c.num_key_value_heads,
                         c.head_dim)
-                have = (pool.k.shape[0],) + pool.k.shape[3:]
+                have = (pool.k.shape[0], pool.num_kv_heads, pool.head_dim)
                 if have != want or pool.k.dtype != store \
                         or pool.block_size != bs \
                         or getattr(pool, "kv_dtype",
@@ -453,7 +453,8 @@ class ContinuousBatchingEngine:
                     # with an opaque XLA shape/dtype error on the first hit
                     pool = prefix_cache.pool
                     want = (self.cache.k.shape[0],) + self.cache.k.shape[3:]
-                    have = (pool.k.shape[0],) + pool.k.shape[3:]
+                    have = (pool.k.shape[0], pool.num_kv_heads,
+                            pool.head_dim)
                     if have != want or pool.k.dtype != self.cache.k.dtype:
                         raise ValueError(
                             f"shared PrefixCache pool geometry "

@@ -87,6 +87,13 @@ def quantize_kv_rows(x):
     return q, scale
 
 
+def kv_rows(x):
+    """K/V rows ``[..., Hkv, D]`` as the pool stores them, ``[..., Hkv *
+    D]`` (``BlockManager``'s docstring): every writer reshapes the rows it
+    holds, never the pool."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
 FP8_MAX = 448.0   # float8_e4m3fn finite max — the saturation bound
 
 
@@ -114,28 +121,41 @@ def _write_prefill(cache_k, cache_v, pk, pv, slot):
     return ck, cv
 
 
+def _block_slice(arr, block_id):
+    # one block of a pool array, rank-generic: the data [L, nb, bs, KD],
+    # int8's per-row planes [L, nb, bs, Hkv] and fp8's per-block planes
+    # [L, nb, Hkv] all carry the block on axis 1
+    return jax.lax.dynamic_slice(
+        arr, (0, block_id) + (0,) * (arr.ndim - 2),
+        (arr.shape[0], 1) + arr.shape[2:])
+
+
+def _block_update(arr, block, block_id):
+    return jax.lax.dynamic_update_slice(
+        arr, block, (0, block_id) + (0,) * (arr.ndim - 2))
+
+
 def _copy_block_in(cache_k, cache_v, pool_k, pool_v, slot, row0, block_id):
-    # pool block [L, 1, bs, Hkv, D] -> cache rows [row0, row0+bs) of slot
-    L, _, bs, Hkv, D = pool_k.shape
-    bk = jax.lax.dynamic_slice(pool_k, (0, block_id, 0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    bv = jax.lax.dynamic_slice(pool_v, (0, block_id, 0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    ck = jax.lax.dynamic_update_slice(cache_k, bk, (0, slot, row0, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache_v, bv, (0, slot, row0, 0, 0))
+    # pool block [L, 1, bs, Hkv * D] -> cache rows [row0, row0+bs) of slot
+    # (the dense cache keeps its heads apart: the block is reshaped, never
+    # the pool)
+    bk, bv = _block_slice(pool_k, block_id), _block_slice(pool_v, block_id)
+    to = bk.shape[:3] + cache_k.shape[3:]
+    ck = jax.lax.dynamic_update_slice(cache_k, bk.reshape(to),
+                                      (0, slot, row0, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cache_v, bv.reshape(to),
+                                      (0, slot, row0, 0, 0))
     return ck, cv
 
 
 def _copy_block_out(pool_k, pool_v, cache_k, cache_v, slot, row0, block_id):
     # cache rows [row0, row0+bs) of slot -> pool block (publish)
-    L, _, bs, Hkv, D = pool_k.shape
-    bk = jax.lax.dynamic_slice(cache_k, (0, slot, row0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    bv = jax.lax.dynamic_slice(cache_v, (0, slot, row0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    pk = jax.lax.dynamic_update_slice(pool_k, bk, (0, block_id, 0, 0, 0))
-    pv = jax.lax.dynamic_update_slice(pool_v, bv, (0, block_id, 0, 0, 0))
-    return pk, pv
+    L, _, bs, KD = pool_k.shape
+    size = (L, 1, bs) + cache_k.shape[3:]
+    bk = jax.lax.dynamic_slice(cache_k, (0, slot, row0, 0, 0), size)
+    bv = jax.lax.dynamic_slice(cache_v, (0, slot, row0, 0, 0), size)
+    return (_block_update(pool_k, bk.reshape(L, 1, bs, KD), block_id),
+            _block_update(pool_v, bv.reshape(L, 1, bs, KD), block_id))
 
 
 def _prefill_scatter_coords(pool_k, pk, table_row, prompt_len):
@@ -159,8 +179,8 @@ def _paged_write_prefill(pool_k, pool_v, pk, pv, table_row, prompt_len):
     # (coordinate rule + padding-drop: _prefill_scatter_coords)
     phys, row = _prefill_scatter_coords(pool_k, pk, table_row,
                                         prompt_len)
-    pool_k = pool_k.at[:, phys, row].set(pk, mode="drop")
-    pool_v = pool_v.at[:, phys, row].set(pv, mode="drop")
+    pool_k = pool_k.at[:, phys, row].set(kv_rows(pk), mode="drop")
+    pool_v = pool_v.at[:, phys, row].set(kv_rows(pv), mode="drop")
     return pool_k, pool_v
 
 
@@ -176,8 +196,8 @@ def _paged_write_prefill_q(pool_k, pool_v, pool_ks, pool_vs, pk, pv,
                                         prompt_len)
     qk, sk = quantize_kv_rows(pk)
     qv, sv = quantize_kv_rows(pv)
-    pool_k = pool_k.at[:, phys, row].set(qk, mode="drop")
-    pool_v = pool_v.at[:, phys, row].set(qv, mode="drop")
+    pool_k = pool_k.at[:, phys, row].set(kv_rows(qk), mode="drop")
+    pool_v = pool_v.at[:, phys, row].set(kv_rows(qv), mode="drop")
     pool_ks = pool_ks.at[:, phys, row].set(sk, mode="drop")
     pool_vs = pool_vs.at[:, phys, row].set(sv, mode="drop")
     return pool_k, pool_v, pool_ks, pool_vs
@@ -191,10 +211,10 @@ def _paged_write_prefill_f8(pool_k, pool_v, pk, pv, table_row,
     # (quantize_kv_rows_fp8 docstring), so only the data scatters
     phys, row = _prefill_scatter_coords(pool_k, pk, table_row,
                                         prompt_len)
-    pool_k = pool_k.at[:, phys, row].set(quantize_kv_rows_fp8(pk),
-                                         mode="drop")
-    pool_v = pool_v.at[:, phys, row].set(quantize_kv_rows_fp8(pv),
-                                         mode="drop")
+    pool_k = pool_k.at[:, phys, row].set(
+        kv_rows(quantize_kv_rows_fp8(pk)), mode="drop")
+    pool_v = pool_v.at[:, phys, row].set(
+        kv_rows(quantize_kv_rows_fp8(pv)), mode="drop")
     return pool_k, pool_v
 
 
@@ -279,56 +299,27 @@ def copy_compilations() -> int:
 # tp[, donate]) serves every block — a python-int index would bake into
 # the dispatch-cache key and compile once per block id.
 
-def _tier_fetch_impl(pool_k, pool_v, block_id):
-    # pool block [L, 1, bs, Hkv, D] -> standalone device buffers the
-    # host tier copies down (np.asarray is the d2h)
-    L, _, bs, Hkv, D = pool_k.shape
-    bk = jax.lax.dynamic_slice(pool_k, (0, block_id, 0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    bv = jax.lax.dynamic_slice(pool_v, (0, block_id, 0, 0, 0),
-                               (L, 1, bs, Hkv, D))
-    return bk, bv
+def _tier_fetch_impl(*args):
+    # (pool arrays..., block_id): each array's block [L, 1, ...] as a
+    # standalone device buffer the host tier copies down (np.asarray is the
+    # d2h). On a quantized pool the int8/fp8 data block travels WITH its
+    # fp32 scale planes — same block id, no separate bookkeeping
+    return tuple(_block_slice(a, args[-1]) for a in args[:-1])
 
 
-def _scale_block_slice(planes, block_id):
-    # one block's scale planes, rank-generic: int8 planes are per-row
-    # [L, nb, bs, Hkv], fp8 planes per-block [L, nb, Hkv] — the block
-    # axis is axis 1 in both, so one slice rule serves both pools
-    return jax.lax.dynamic_slice(
-        planes, (0, block_id) + (0,) * (planes.ndim - 2),
-        (planes.shape[0], 1) + planes.shape[2:])
-
-
-def _tier_fetch_q_impl(pool_k, pool_v, pool_ks, pool_vs, block_id):
-    # quantized twin: the int8/fp8 data block travels WITH its fp32
-    # scale planes — same block id, no separate bookkeeping
-    bk, bv = _tier_fetch_impl(pool_k, pool_v, block_id)
-    bks = _scale_block_slice(pool_ks, block_id)
-    bvs = _scale_block_slice(pool_vs, block_id)
-    return bk, bv, bks, bvs
-
-
-def _tier_inject_impl(pool_k, pool_v, bk, bv, block_id):
-    # readmission: one spilled block's buffers -> pool block ``block_id``
-    pk = jax.lax.dynamic_update_slice(pool_k, bk, (0, block_id, 0, 0, 0))
-    pv = jax.lax.dynamic_update_slice(pool_v, bv, (0, block_id, 0, 0, 0))
-    return pk, pv
-
-
-def _tier_inject_q_impl(pool_k, pool_v, pool_ks, pool_vs,
-                        bk, bv, bks, bvs, block_id):
-    pk, pv = _tier_inject_impl(pool_k, pool_v, bk, bv, block_id)
-    at = lambda planes: (0, block_id) + (0,) * (planes.ndim - 2)  # noqa: E731
-    pks = jax.lax.dynamic_update_slice(pool_ks, bks, at(pool_ks))
-    pvs = jax.lax.dynamic_update_slice(pool_vs, bvs, at(pool_vs))
-    return pk, pv, pks, pvs
+def _tier_inject_impl(*args):
+    # readmission, (pool arrays..., their blocks..., block_id): one spilled
+    # block's buffers -> pool block ``block_id``
+    n = len(args) // 2
+    return tuple(_block_update(a, b, args[-1])
+                 for a, b in zip(args[:n], args[n:-1]))
 
 
 _TIER_PROGRAMS = []   # every distinct jitted tier program, for the counter
 
 
 def _tier_pspecs(quantized, tp):
-    # the block buffer [L, 1, bs, Hkv, D] partitions on the SAME head
+    # the block buffer [L, 1, bs, Hkv * D] partitions on the SAME head
     # axis as the pool (serving/decode._pool_pspec — THE spec, not a
     # re-spelling), so fetch hands out shards the host gathers and
     # inject hands the pool back exactly as the sharded step programs
@@ -348,7 +339,7 @@ def _tier_pspecs(quantized, tp):
 def _tier_fetch(quantized=False, tp=1):
     # no donation: the spill READS the pool (eviction frees the block's
     # id, not its storage — pool arrays are dense and preallocated)
-    impl = _tier_fetch_q_impl if quantized else _tier_fetch_impl
+    impl = _tier_fetch_impl
     if tp > 1:
         from jax.sharding import PartitionSpec as P
         from .decode import _tp_mesh
@@ -364,7 +355,7 @@ def _tier_fetch(quantized=False, tp=1):
 @functools.lru_cache(maxsize=None)
 def _tier_inject(donate, quantized=False, tp=1):
     # donate the POOL arrays (readmission updates the pool in place)
-    impl = _tier_inject_q_impl if quantized else _tier_inject_impl
+    impl = _tier_inject_impl
     if tp > 1:
         from jax.sharding import PartitionSpec as P
         from .decode import _tp_mesh
@@ -514,7 +505,10 @@ class PagedKVCache:
 
     The pool's device arrays are the single source of KV truth; the
     decode / suffix-prefill programs update them functionally and the
-    engine adopts the result via :meth:`update`.
+    engine adopts the result via :meth:`update`. How they are laid out,
+    and what :attr:`sentinel` (the block id ``pool.num_blocks`` that fills
+    unmapped table entries) means to a writer and to a reader, is stated
+    once, in :class:`~.block_manager.BlockManager`'s docstring.
     """
 
     def __init__(self, num_layers, num_slots, max_seq_len, num_kv_heads,

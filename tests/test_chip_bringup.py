@@ -173,6 +173,71 @@ class TestMosaicCompilesTheRoutedFfn:
         assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
 
 
+class TestUnifiedStepLeavesThePoolInPlace:
+    """The unified serving step, small, compiled for the described v5e: in
+    the optimised HLO nothing but the in-place row scatter has a result as
+    large as one layer of the KV pool. The copies this keeps from coming back
+    (a layer cut out of the scanned pool for the Mosaic call, re-laid-out for
+    it, stacked back) were 24-68 % of a serving step's device time."""
+    L, H, NH, NKV, HD, FFN, V = 4, 512, 8, 4, 128, 1024, 2048
+    R, MB, BS, T = 8, 32, 32, 136       # pool layer: 256 blocks, 8 MiB
+
+    def _compile(self, v5e):
+        from paddle_tpu.serving import decode
+        from paddle_tpu.serving.block_manager import BlockManager
+        L, H, kd = self.L, self.H, self.NKV * self.HD
+        params = dict(
+            embed=v5e((self.V, H)), wq=v5e((L, H, self.NH * self.HD)),
+            wk=v5e((L, H, kd)), wv=v5e((L, H, kd)),
+            wo=v5e((L, self.NH * self.HD, H)), w_gate=v5e((L, H, self.FFN)),
+            w_up=v5e((L, H, self.FFN)), w_down=v5e((L, self.FFN, H)),
+            input_ln=v5e((L, H)), post_ln=v5e((L, H)),
+            final_norm=v5e((H,)), lm_head=v5e((H, self.V)))
+        pool = v5e(BlockManager.pool_shape(L, self.R * self.MB, self.BS,
+                                           self.NKV, self.HD))
+        i32 = jnp.int32
+        tok, row = v5e((self.T,), i32), v5e((self.R,), i32)
+        step = decode.build_ragged_step_fn(
+            n_steps=1, nh=self.NH, nkv=self.NKV, hd=self.HD, eps=1e-5,
+            theta=1e4, tied=False, decode_attn="pallas", donate=True)
+        with jax.default_matmul_precision("default"):
+            return step.lower(
+                params, pool, pool, v5e((self.R, self.MB), i32), tok, tok,
+                tok, row, row, row, row, v5e((self.R, 2), jnp.uint32),
+                v5e((self.R,), jnp.float32), row).compile()
+
+    def test_no_op_but_the_scatter_returns_a_pool_layer(self, v5e):
+        import re
+        compiled = self._compile(v5e)
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        layer = self.R * self.MB * self.BS * self.NKV * self.HD
+        # computation name -> its text, to see what a fusion wraps
+        bodies = dict(re.findall(
+            r"^(?:ENTRY )?(%[\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+            re.S | re.M))
+        inst = re.compile(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* "
+            r"([\w\-]+)\((.*)$", re.M)
+        passive = {"parameter", "get-tuple-element", "tuple", "while",
+                   "bitcast", "scatter"}
+        moved, scatters = [], 0
+        for name, dims, op, rest in inst.findall(text):
+            if not dims or np.prod([int(d) for d in dims.split(",")]) < layer:
+                continue
+            if op == "fusion":
+                called = re.search(r"calls=(%[\w.\-]+)", rest).group(1)
+                if " scatter(" in bodies[called]:
+                    scatters += 1
+                    continue
+            if op not in passive:
+                moved.append((op, name, dims))
+        assert not moved, moved
+        assert scatters == 2                # K and V, in the layer loop
+        # and the step's temp holds no copy of a layer
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer
+
+
 class TestFlashKernelUnderTheHybridMesh:
     """GSPMD cannot partition a Mosaic custom call, so under a mesh the flash
     kernel runs in a shard_map (``flash_attention.shard_over_mesh``): batch

@@ -200,15 +200,16 @@ class TestRaggedKernelParity:
 
     def test_jit_and_scan_composable(self):
         """Must trace under jit inside a lax.scan over layers — the
-        exact shape of the unified serving step's layer loop (per-layer
-        pool slices, one shared table + span metadata)."""
+        exact shape of the unified serving step's layer loop (the stored
+        pool carried whole, the layer's index scanned, one shared table +
+        span metadata)."""
         R, H, Hkv, D, mb, bs, L = 2, 4, 2, 64, 4, 16, 3
         r = np.random.RandomState(5)
         T = 6
         q = jnp.asarray(r.randn(L, T, H, D), jnp.float32)
         num_blocks = R * mb
-        pk = jnp.asarray(r.randn(L, num_blocks, bs, Hkv, D), jnp.float32)
-        pv = jnp.asarray(r.randn(L, num_blocks, bs, Hkv, D), jnp.float32)
+        pk = jnp.asarray(r.randn(L, num_blocks, bs, Hkv * D), jnp.float32)
+        pv = jnp.asarray(r.randn(L, num_blocks, bs, Hkv * D), jnp.float32)
         tbl = jnp.asarray(
             r.permutation(num_blocks).reshape(R, mb), jnp.int32)
         qs = jnp.asarray([0, 1], jnp.int32)
@@ -217,17 +218,19 @@ class TestRaggedKernelParity:
 
         @jax.jit
         def run(q, pk, pv):
-            def body(carry, xs):
-                qq, kk, vv = xs
-                return carry + 1, ragged_paged_attention_pallas(
-                    qq, kk, vv, tbl, qs, ql, kl)
-            _, outs = jax.lax.scan(body, 0, (q, pk, pv))
+            def body(pool, xs):
+                qq, layer = xs
+                return pool, ragged_paged_attention_pallas(
+                    qq, *pool, tbl, qs, ql, kl, layer=layer)
+            _, outs = jax.lax.scan(body, (pk, pv),
+                                   (q, jnp.arange(L, dtype=jnp.int32)))
             return outs
 
         outs = np.asarray(run(q, pk, pv))
         for layer in range(L):
             want = np.asarray(ragged_attention_reference(
-                q[layer], pk[layer], pv[layer], tbl, qs, ql, kl))
+                q[layer], pk[layer].reshape(num_blocks, bs, Hkv, D),
+                pv[layer].reshape(num_blocks, bs, Hkv, D), tbl, qs, ql, kl))
             np.testing.assert_allclose(outs[layer], want, rtol=2e-5,
                                        atol=2e-5)
 

@@ -369,7 +369,7 @@ class TestTrieInvariantsRandomized:
     """
 
     NB, BSU = 6, 4          # 6-block pool, 4-token blocks
-    SHAPE = (1, 1, BSU, 1, 2)   # one block: [L, 1, bs, Hkv, D]
+    SHAPE = (1, 1, BSU, 1 * 2)  # one block: [L, 1, bs, Hkv * D]
 
     def _expected(self, path):
         v = float(zlib.crc32(repr(path).encode()) % 65536)
