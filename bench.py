@@ -15,10 +15,6 @@ last stdout line is ONE JSON object: {"metric", "value", "unit",
 "failed" and the exit code is 1; nothing is replayed from an earlier run
 and nothing falls back to another backend or kernel.
 
-The per-feature counter legs (``--decode-cb`` ... ``--slo``,
-``scripts/bench_*.py``) count launches, bytes and hit rates on whatever
-backend they are started on. They run by flag only and are not part of the
-default flow: a count taken on a CPU has no place in a run on the chip.
 Turning this file into cells is ROADMAP S1.
 """
 import json
@@ -374,39 +370,6 @@ def main():
     return 1 if failed else 0
 
 
-# flag -> (scripts/ module, function, result name): the per-feature counter
-# legs. Run by flag only; never part of the default flow (module docstring).
-COUNTER_LEGS = {
-    "--decode-cb": ("bench_decode", "measure_continuous_batching",
-                    "decode_cb"),
-    "--serve-http": ("bench_serve", "measure_serve_http", "serve_http"),
-    "--prefix-cache": ("bench_prefix", "measure_prefix_cache",
-                       "prefix_cache"),
-    "--paged-attn": ("bench_paged", "measure_paged_attn", "paged_attn"),
-    "--chunked-prefill": ("bench_chunked", "measure_chunked_prefill",
-                          "chunked_prefill"),
-    "--ragged": ("bench_ragged", "measure_ragged_step", "ragged_step"),
-    "--spec": ("bench_spec", "measure_spec_decode", "spec_decode"),
-    "--chaos": ("bench_chaos", "measure_chaos", "chaos"),
-    "--trace-overhead": ("bench_trace", "measure_trace_overhead",
-                         "trace_overhead"),
-    "--dispatch": ("bench_dispatch", "measure_dispatch_cost", "dispatch"),
-    "--density": ("bench_density", "measure_density", "density"),
-    "--tp": ("bench_tp", "measure_tp", "tp"),
-    "--tier": ("bench_tier", "measure_tier", "tier"),
-    "--slo": ("bench_slo", "measure_slo", "slo"),
-}
-
-
-def main_counter_leg(flag):
-    import importlib
-    module, fn, name = COUNTER_LEGS[flag]
-    sys.path.insert(0, os.path.join(ROOT, "scripts"))
-    measure = getattr(importlib.import_module(module), fn)
-    print(json.dumps({"name": name, "ok": True, **measure(quick=True)}))
-    return 0
-
-
 if __name__ == "__main__":
     if "--config" in sys.argv:
         sys.exit(main_one_config(int(sys.argv[sys.argv.index("--config") + 1])))
@@ -422,7 +385,4 @@ if __name__ == "__main__":
         sys.exit(0)
     if "--trace" in sys.argv:
         sys.exit(main_trace(int(sys.argv[sys.argv.index("--trace") + 1])))
-    for flag in COUNTER_LEGS:
-        if flag in sys.argv:
-            sys.exit(main_counter_leg(flag))
     sys.exit(main())

@@ -36,8 +36,7 @@ Discipline mirrors the tracer's: the observatory is a host-side dict
 updated by the single engine-driver thread; scrape-time readers
 (``/metrics`` gauges, ``/debug/profile``) read ints under the GIL.
 Disabled, every engine instrumentation site reduces to the one
-``_co()`` attribute guard — the ≤1.01× property the dispatch bench
-pins (DISPATCH_BENCH.json, ``scripts/bench_dispatch.py``).
+``_co()`` attribute guard.
 """
 from __future__ import annotations
 
@@ -224,14 +223,13 @@ class CostObservatory:
         # engines; README "Tensor-parallel serving") — deliberately a
         # SEPARATE ledger from h2d/d2h: all-reduce bytes never cross
         # the host boundary, and folding them into transfer totals
-        # would corrupt the banked dispatch-bench baselines
+        # would make the per-program transfer counts unreadable
         self.collectives = {}
         # KV-tier traffic by direction (host-RAM spill tier; README
         # "Tiered KV prefix cache") — the same separate-ledger rule as
         # collectives: spill/readmit bytes ARE host-boundary transfers,
         # but they are cache-plane traffic, not per-program compute
-        # I/O, and folding them into the per-program h2d/d2h records
-        # would corrupt the banked DISPATCH_BENCH.json baselines.
+        # I/O, and do not belong in the per-program h2d/d2h records.
         # Directions: "d2h" (spill), "h2d" (readmit), "peer" (fleet
         # host-to-host transfer in).
         self.tiers = {}

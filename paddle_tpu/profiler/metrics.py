@@ -359,8 +359,7 @@ class Histogram(_Metric):
 
     :meth:`quantile` estimates order statistics from the bucket counts
     (the ``histogram_quantile``-style interpolation) — good enough for
-    p95 acceptance gates (scripts/bench_chunked.py) without recording
-    raw observations.
+    p95 acceptance gates without recording raw observations.
     """
 
     kind = "histogram"

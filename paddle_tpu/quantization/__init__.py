@@ -88,9 +88,9 @@ def quantized_psum_int8(x, axis_name, tp):
     in fp32 — a FIXED summation order, so the result (and therefore
     the token stream) is deterministic and identical on every shard.
     Phase 2 (all-gather): requantize the reduced chunk, ``all_gather``,
-    dequantize and reassemble. The double quantization is the quality
-    price the serving bench MEASURES (greedy divergence in
-    TP_BENCH.json) rather than assumes away.
+    dequantize and reassemble. The double quantization is a quality
+    price: greedy streams may diverge from the fp wire (not measured on
+    the chip).
 
     Requires the last axis divisible by ``tp`` (the engine validates
     ``hidden_size % tp == 0`` at build). Shapes/dtype are preserved."""

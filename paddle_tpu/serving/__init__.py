@@ -1,12 +1,12 @@
 """Continuous-batching serving subsystem (L7, SURVEY §3.5 / PAPERS.md).
 
-Orca-style iteration-level scheduling on top of a slot-based paged KV
-cache: one compiled single-token ``decode_step_fn`` whose shapes depend
-only on ``(num_slots, max_seq_len)`` serves every request mix; requests
-are admitted into free cache slots mid-flight, and a slot is freed the
-moment its sequence hits EOS or its token budget — the ragged Pallas
-decode kernel (``kernels/pallas_decode.py``) already skips KV blocks past
-``lengths[b]``, so a freed slot's stale cache costs no HBM traffic.
+Orca-style iteration-level scheduling on top of a block-table paged KV
+cache: one compiled step program whose shapes depend only on
+``(num_slots, token_budget)`` serves every request mix; requests are
+admitted into free cache slots mid-flight, and a slot is freed the
+moment its sequence hits EOS or its token budget — the ragged paged
+attention kernel (``kernels/pallas_ragged_attention.py``) walks each
+row's own length, so a freed block's stale rows cost no HBM traffic.
 
 Public surface:
 
@@ -14,10 +14,8 @@ Public surface:
   state (per-request deadlines via ``timeout_s``; ``finish_reason`` ∈
   :data:`FINISH_REASONS` = stop|length|cancelled|timeout|error)
 - :class:`GenerationResult` — array-like generate() output + finish_reason
-- :class:`SlotKVCache` — the dense per-slot KV cache (legacy
-  compatibility shim, ``paged_attn=False``)
-- :class:`PagedKVCache` — true block-table paged attention, THE
-  default: the :class:`BlockManager` pool IS the cache, slots address
+- :class:`PagedKVCache` — block-table paged attention: the
+  :class:`BlockManager` pool IS the cache, slots address
   it through per-slot block tables, prefix hits are zero-copy
   references and retirement donates prompt AND generated blocks to the
   trie (README "Paged attention")
@@ -52,7 +50,7 @@ repairs by preempting the youngest sequence (recompute, donated chain);
 supervised gateway driver can rebuild and continue streams
 byte-identically; :mod:`.faults` is the deterministic fault-injection
 harness (:class:`FaultPlan` / :class:`VirtualClock`) the chaos tests
-and ``scripts/bench_chaos.py`` drive.
+drive.
 
 Scale-out: :mod:`paddle_tpu.serving.fleet` (README "Engine fleet")
 replicates the whole stack — N shared-nothing supervised engines
@@ -68,7 +66,7 @@ from .drafter import Drafter, ModelDrafter, NgramDrafter
 from .engine import ContinuousBatchingEngine
 from .faults import (FatalFault, FaultError, FaultPlan, TransientFault,
                      VirtualClock)
-from .kv_cache import PagedKVCache, PoolExhausted, SlotKVCache
+from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, PriorityClass
 from .prefix_cache import HostTier, PrefixCache
 from .request import (FINISH_REASONS, GenerationRequest, GenerationResult,
@@ -77,7 +75,7 @@ from .scheduler import FIFOScheduler
 
 __all__ = [
     "ContinuousBatchingEngine", "GenerationRequest", "GenerationResult",
-    "Sequence", "SlotKVCache", "PagedKVCache", "PoolExhausted",
+    "Sequence", "PagedKVCache", "PoolExhausted",
     "FIFOScheduler", "FINISH_REASONS", "BlockManager", "PrefixCache",
     "HostTier", "PriorityClass", "ClassTable", "PolicyScheduler",
     "FaultPlan", "FaultError", "TransientFault", "FatalFault",

@@ -4,15 +4,14 @@ The pool is the block-granular half of the serving KV story ("Ragged
 Paged Attention", PAPERS.md): two dense device arrays (K and V, laid out
 as :class:`BlockManager` says) holding KV blocks, plus host-side
 bookkeeping — a free-block min-heap (same O(log n) allocator discipline
-as :class:`~.kv_cache.SlotKVCache`) and a per-block reference count.
+as :class:`~.kv_cache.PagedKVCache`'s slots) and a per-block reference
+count.
 
 Division of labor: this class owns *physical* blocks (allocation,
 refcounts, storage); :class:`~.prefix_cache.PrefixCache` owns *logical*
 identity (the hash-trie from token content to block id, LRU eviction
-order, hit/miss accounting). On the dense engine blocks move between
-the pool and the slot cache through the compile-once copy programs in
-``kv_cache.py``; on the paged engine (:class:`~.kv_cache.PagedKVCache`)
-the pool IS the KV cache — live sequences reference blocks through
+order, hit/miss accounting). The pool IS the KV cache
+(:class:`~.kv_cache.PagedKVCache`) — live sequences reference blocks through
 per-slot block tables, published blocks are shared zero-copy (one
 block, N refs), and divergence is safe because writes only ever land
 in blocks the writing sequence privately owns (the COW fork: a table
@@ -206,7 +205,6 @@ class BlockManager:
         self._free_heap = list(range(self.num_blocks))
         self._free_set = set(self._free_heap)
         self._ref = np.zeros(self.num_blocks, np.int32)
-        self._peak_used = 0
         # spill staging (README "Tiered KV prefix cache"): per-shape
         # reusable host buffers for read_block copies, recycled by the
         # host tier's drop/readmit paths through recycle_staging
@@ -229,12 +227,6 @@ class BlockManager:
         """Live blocks (published + pinned) — the ``kv_prefix_blocks``
         gauge on ``/metrics``."""
         return self.num_blocks - self.num_free
-
-    @property
-    def peak_used(self) -> int:
-        """High-water mark of :attr:`num_used` — the paged-vs-dense
-        bench's HBM-footprint metric (scripts/bench_paged.py)."""
-        return self._peak_used
 
     @property
     def num_shared(self) -> int:
@@ -270,7 +262,6 @@ class BlockManager:
             return None
         block = heapq.heappop(self._free_heap)
         self._free_set.discard(block)
-        self._peak_used = max(self._peak_used, self.num_used)
         return block
 
     def free(self, block: int):

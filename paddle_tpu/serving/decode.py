@@ -1,33 +1,35 @@
-"""Jitted prefill / decode-step functions for the LLaMA decode path.
+"""Jitted serving programs for the LLaMA-family decode path.
 
-This is the split of the old monolithic ``_llama_generate_fn``
-(models/llama.py) into the two programs a continuous-batching engine
-needs:
+The engine (``engine.py``) runs two programs on its default path and
+three more behind switches, all over one block-table paged KV pool:
 
-- ``prefill`` — one prompt's full forward, returning its per-layer K/V
-  (to be installed into a cache slot), the first sampled token, and the
-  advanced PRNG key. Prompt lengths are padded to buckets by the engine,
-  so compilations are bounded by the bucket count, not the prompt count.
-- ``decode_steps`` — ``n_steps`` single-token ticks over ALL slots in
-  one device call. Shapes depend only on ``(num_slots, max_seq_len)``:
-  per-slot sampling knobs (temperature / top-k / PRNG key) and per-slot
-  ragged ``lengths`` are runtime ARRAYS, not trace constants, so one
-  compilation serves every request mix — the old path recompiled per
-  ``(max_new_tokens, temperature, top_k)`` tuple.
+- prefill (``build_prefill_fn``) — an admission group's full forward,
+  returning its per-layer K/V (installed into the slots' pool blocks),
+  the first sampled token and the advanced PRNG key. Prompt lengths are
+  padded to buckets by the engine, so compilations are bounded by the
+  bucket count, not the prompt count.
+- the unified step (``build_ragged_step_fn``): every running slot's
+  span-1 decode row and every planned prefill chunk's span-n row in
+  ONE packed token buffer whose shape depends only on ``(num_slots,
+  token_budget)``, then ``n_steps - 1`` fused single-token ticks. Block
+  tables, span metadata and per-slot sampling knobs (temperature /
+  top-k / PRNG key) are runtime ARRAYS, not trace constants, so one
+  compilation serves every request mix.
+- the paged suffix prefill (prefix-cache hits prefill their uncovered
+  suffix), the multi-tick step (``decode_ticks > 1``) and the
+  speculative verify (``spec_decode``).
 
-Per-row raggedness: each slot writes its new K/V at its own
-``lengths[b]`` (scatter) and attends over ``lengths[b]+1`` entries —
-through the ragged Pallas kernel (``decode_attention_pallas``) or the
-jnp oracle with identical semantics. Rows of freed/empty slots compute
-garbage that is never read (their scatter lands in row 0 of a dead slot
-and the engine never surfaces their sampled tokens).
+Per-row raggedness: each row writes its new K/V through its block table
+at its own logical position and attends over its own length — through
+the ragged paged Pallas kernels or their jnp oracles with identical
+semantics. Dead rows carry sentinel tables, so their writes drop.
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
-per-row key; keys advance by the same split-per-token walk the old path
-used, so a request's token stream depends only on its own key — not on
-batch composition, admission timing, or the other slots (the property
-the mid-flight-admission tests pin down).
+per-row key; keys advance by one split per token, so a request's token
+stream depends only on its own key — not on batch composition, admission
+timing, or the other slots (the property the mid-flight-admission tests
+pin down).
 """
 from __future__ import annotations
 
@@ -40,8 +42,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..kernels.flash_attention import attention as _attention
 from ..kernels.moe_ffn import moe_ffn
-from ..kernels.pallas_decode import (decode_attention_pallas,
-                                     decode_attention_reference)
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
 from ..kernels.pallas_ragged_attention import (ragged_attention_reference,
@@ -733,117 +733,18 @@ def _apply_rope_grid(x, sin_p, cos_p):
             + rotated * sin_p[:, :, None, :]).astype(x.dtype)
 
 
-@jax.named_scope("suffix_prefill")
-def _suffix_prefill_impl(params, cache_k, cache_v, slots, prefix_lens, ids,
-                         suffix_lens, keys, temps, top_ks, *, nh, nkv, hd,
-                         eps, theta, tied):
-    """Prefill only the UNCOVERED suffix of prompts whose leading blocks
-    a prefix-cache hit already installed into their slots.
-
-    ids: [G, S_pad] right-padded suffix token ids; prefix_lens: [G] rows
-    already valid in each row's slot (the installed cached blocks);
-    suffix_lens: [G] real suffix token counts; slots: [G] slot indices
-    (padding rows carry ``num_slots`` so their writes drop).
-
-    Each suffix token at column i lives at global position
-    ``prefix_lens[g] + i``: its K/V scatter into the slot at that row
-    (rope'd at that position) and its query attends over rows
-    ``0..pos`` — cached prefix plus the suffix written so far, exactly
-    the rows a cold full prefill would attend. Shapes depend only on
-    (G_pad, S_pad, cache geometry): prefix/suffix lengths, slot ids, and
-    sampling knobs are runtime arrays, so compilations stay bounded by
-    the same pow2 buckets as the cold prefill.
-
-    Returns (cache_k', cache_v', tok0, keys').
-    """
-    G, S = ids.shape
-    num_slots, s_max = cache_k.shape[1], cache_k.shape[2]
-    sin, cos = _rope_tables(s_max, hd, theta)
-    stack = tuple(params[k] for k in _STACK_KEYS)
-    wdt = params["embed"].dtype
-    head = _dq_head(params, tied, wdt)
-
-    # gather each row's slot cache: [L, G, s_max, Hkv, D]. Padding rows
-    # point at slot index num_slots — the gather clips (harmless read of
-    # the last slot), every write below drops.
-    kc0 = jnp.take(cache_k, slots, axis=1, mode="clip")
-    vc0 = jnp.take(cache_v, slots, axis=1, mode="clip")
-    pos = prefix_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    sin_p = jnp.take(sin, pos, axis=0, mode="clip")   # [G, S, D]
-    cos_p = jnp.take(cos, pos, axis=0, mode="clip")
-    g_idx = jnp.arange(G)[:, None]
-    rows = jnp.arange(s_max, dtype=jnp.int32)
-    # causal-over-ragged mask: query at global pos p sees rows r <= p
-    mask = rows[None, None, :] <= pos[:, :, None]        # [G, S, s_max]
-    # rows ever valid in this slot (prefix + the S suffix writes); rows
-    # past that may hold a prior sequence's garbage — zeroed out of PV
-    row_valid = rows[None, :] < (prefix_lens + S)[:, None]  # [G, s_max]
-    grp = nh // nkv
-    scale = 1.0 / (hd ** 0.5)
-
-    def layer(h, lp):
-        (lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, ck, cv) = \
-            _dq_layer(lp, wdt)
-        with jax.named_scope("attn"):
-            hn = _rms(h, lin, eps)
-            q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-            q = _apply_rope_grid(q, sin_p, cos_p)
-            k = _apply_rope_grid(k, sin_p, cos_p)
-            # ragged scatter: column i appends at its row's prefix_len + i;
-            # out-of-range positions (padding rows, clamped tails) drop
-            ck = ck.at[g_idx, pos].set(k, mode="drop")
-            cv = cv.at[g_idx, pos].set(v, mode="drop")
-            kf = jnp.repeat(ck, grp, axis=2) if grp > 1 else ck
-            vf = jnp.repeat(cv, grp, axis=2) if grp > 1 else cv
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
-                                preferred_element_type=jnp.float32) * scale
-            logits = jnp.where(mask[:, None], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1)
-            # exact zeros on masked cols + zeroed garbage rows: stale cache
-            # rows can be anything (0 * NaN = NaN)
-            probs = jnp.where(mask[:, None], probs, 0.0)
-            vf = jnp.where(row_valid[:, :, None, None], vf, 0.0)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), vf)
-            h = h + jnp.einsum("bsd,dh->bsh", attn.reshape(G, S, nh * hd), lwo)
-        h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
-        return h, (ck, cv)
-
-    x = jnp.take(params["embed"], ids, axis=0)
-    x, (nkc, nvc) = jax.lax.scan(layer, x, stack + (kc0, vc0))
-    last = jnp.take_along_axis(
-        x, (suffix_lens - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
-    last_h = _rms(last, params["final_norm"], eps)
-    logits = _head_logits(last_h, head)
-    both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
-    tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
-    # scatter the updated per-slot caches back (padding rows drop)
-    cache_k = cache_k.at[:, slots].set(nkc, mode="drop")
-    cache_v = cache_v.at[:, slots].set(nvc, mode="drop")
-    return cache_k, cache_v, tok0, both[:, 0]
-
-
-def build_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied, donate=None):
-    """One jitted suffix prefill; retraces per (group, suffix-bucket)
-    shape — both padded to powers of two by the engine, same bounded
-    compile set as the cold prefill."""
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    return jax.jit(
-        functools.partial(_suffix_prefill_impl, nh=nh, nkv=nkv, hd=hd,
-                          eps=eps, theta=theta, tied=tied),
-        donate_argnums=(1, 2) if donate else ())
-
-
 # ----------------------------------------------------- paged suffix prefill
 @jax.named_scope("paged_suffix_prefill")
 def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
                                ids, suffix_lens, keys, temps, top_ks, *,
                                nh, nkv, hd, eps, theta, tied,
                                tp_reduce=None, a8=False):
-    """Suffix prefill through per-row block tables: the paged twin of
-    ``_suffix_prefill_impl``, reading/writing the BlockManager pool
-    instead of per-slot dense caches.
+    """Prefill only the UNCOVERED suffix of prompts whose leading blocks
+    a prefix-cache hit already installed into their block tables.
 
+    ids: [G, S_pad] right-padded suffix token ids; prefix_lens: [G] rows
+    already valid behind each row's table (the installed cached blocks);
+    suffix_lens: [G] real suffix token counts.
     tables: [G, max_blocks] int32 physical block ids (sentinel
     ``num_blocks`` marks unmapped entries and padding rows). Suffix
     token K/V at column i lands at logical position
@@ -851,22 +752,13 @@ def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
     — always a block the row privately owns, because the covered prefix
     is block-aligned and everything past it was freshly allocated. The
     shared prefix blocks are READ through the same table but never
-    written: that is the zero-copy COW discipline in one line.
-
-    This program is ALSO the chunked-prefill program (engine
-    ``prefill_chunk``, README "Chunked prefill"): a long cold prompt's
-    chunk c is just a "suffix" whose ``prefix_lens`` is the host resume
-    offset of the rows chunks 0..c-1 already wrote through the table —
-    the offset machinery is row-exact, so nothing new is needed at this
-    layer. The engine buckets chunk lengths on ``prefill_chunk`` (full
-    chunks share ONE bucket; only final remainders ride the pow2 grid)
-    and discards tok0/keys' for every non-final chunk, so the PRNG
-    advances exactly once per prompt — token streams stay byte-identical
-    to a one-shot prefill.
+    written: that is the zero-copy COW discipline in one line. Each
+    query attends over rows ``0..pos`` — cached prefix plus the suffix
+    written so far, exactly the rows a cold full prefill would attend.
 
     Shapes depend only on (G_pad, S_pad, pool geometry, max_blocks);
     tables/lengths/knobs are runtime arrays, so the compile set stays
-    the same pow2 (group, bucket) grid as the dense suffix path.
+    the same pow2 (group, bucket) grid as the cold prefill.
 
     Returns (pool_k', pool_v', tok0, keys'). On a quantized pool
     (int8 or fp8) each side arrives (and returns) as a
@@ -896,11 +788,10 @@ def _paged_suffix_prefill_impl(params, pool_k, pool_v, tables, prefix_lens,
     row_valid = rows[None, :] < (prefix_lens + S)[:, None]  # [G, s_tot]
     grp = nh // nkv
     scale = 1.0 / (hd ** 0.5)
-    # pool write coordinates. Unlike the dense path (which scatters all
-    # S columns into the slot and relies on lengths-masking), padding
-    # columns here MUST drop — a junk write into the pool could land in
-    # a block another sequence owns only via a bug, but dropping keeps
-    # the invariant airtight: only (col < suffix_len) positions write.
+    # pool write coordinates: padding columns MUST drop — a junk write
+    # into the pool could land in a block another sequence owns only via
+    # a bug, but dropping keeps the invariant airtight: only
+    # (col < suffix_len) positions write.
     bi = jnp.minimum(pos // bs, mb - 1)
     phys = jnp.take_along_axis(tables, bi, axis=1)        # [G, S]
     cols = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -962,9 +853,8 @@ def build_paged_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied,
                                   donate=None, tp=1,
                                   collective_dtype="fp", kv_quant=False,
                                   wq8=False, a8=False):
-    """One jitted paged suffix prefill — doubling as THE chunked-prefill
-    program (see ``_paged_suffix_prefill_impl``); retraces per (group,
-    bucket) shape — same bounded pow2 grid as the dense suffix path.
+    """One jitted paged suffix prefill; retraces per (group, bucket)
+    shape — same bounded pow2 grid as the cold prefill.
     ``tp > 1`` runs it sharded over heads with the pool partitioned per
     shard (README "Tensor-parallel serving")."""
     if donate is None:
@@ -986,168 +876,6 @@ def build_paged_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied,
     return jax.jit(
         functools.partial(_paged_suffix_prefill_impl, nh=nh, nkv=nkv, hd=hd,
                           eps=eps, theta=theta, tied=tied, a8=a8),
-        donate_argnums=(1, 2) if donate else ())
-
-
-# -------------------------------------------------------------- decode step
-@jax.named_scope("decode_steps")
-def _decode_steps_impl(params, cache_k, cache_v, tokens, lengths, keys,
-                       temps, top_ks, *, n_steps, nh, nkv, hd, eps, theta,
-                       tied, decode_attn):
-    """``n_steps`` fused single-token decode ticks over all slots.
-
-    tokens:  [B] int32 — each slot's last sampled token
-    lengths: [B] int32 — valid cache rows per slot (ragged)
-    keys:    [B, 2] uint32; temps: [B] f32; top_ks: [B] int32
-
-    Returns (toks [n_steps, B], cache_k', cache_v', keys').
-    """
-    B = tokens.shape[0]
-    s_max = cache_k.shape[2]
-    sin, cos = _rope_tables(s_max, hd, theta)
-    stack = tuple(params[k] for k in _STACK_KEYS)
-    wdt = params["embed"].dtype
-    head = _dq_head(params, tied, wdt)
-
-    def one_step(carry, _):
-        tok, ck_all, cv_all, lens, kys = carry
-        x = jnp.take(params["embed"], tok[:, None], axis=0)  # [B,1,H]
-        sin_p = jnp.take(sin, lens, axis=0)  # [B, D] per-row position
-        cos_p = jnp.take(cos, lens, axis=0)
-
-        def layer(h, xs):
-            lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, ck, cv = \
-                _dq_layer(xs, wdt)
-            with jax.named_scope("attn"):
-                hn = _rms(h, lin, eps)
-                q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-                q = _apply_rope_rows(q, sin_p, cos_p)
-                k = _apply_rope_rows(k, sin_p, cos_p)
-                # ragged scatter: each row appends at its own position
-                ck = ck.at[jnp.arange(B), lens].set(k[:, 0])
-                cv = cv.at[jnp.arange(B), lens].set(v[:, 0])
-                if decode_attn == "pallas":
-                    attn = decode_attention_pallas(q[:, 0], ck, cv, lens + 1)
-                else:
-                    attn = decode_attention_reference(
-                        q[:, 0], ck, cv, lens + 1)
-                h = h + jnp.einsum("bsd,dh->bsh",
-                                   attn.reshape(B, 1, nh * hd), lwo)
-            h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
-            return h, (ck, cv)
-
-        x, (nck, ncv) = jax.lax.scan(layer, x, stack + (ck_all, cv_all))
-        last = _rms(x[:, 0], params["final_norm"], eps)
-        logits = _head_logits(last, head)
-        both = jax.vmap(jax.random.split)(kys)  # [B, 2, 2]
-        nxt = sample_rows(logits, both[:, 1], temps, top_ks)
-        return (nxt, nck, ncv, lens + 1, both[:, 0]), nxt
-
-    carry0 = (tokens, cache_k, cache_v, lengths, keys)
-    (_, ck, cv, _, kf), toks = jax.lax.scan(one_step, carry0, None,
-                                            length=n_steps)
-    return toks, ck, cv, kf
-
-
-def build_decode_steps_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
-                          decode_attn, donate=None):
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    return jax.jit(
-        functools.partial(
-            _decode_steps_impl, n_steps=n_steps, nh=nh, nkv=nkv, hd=hd,
-            eps=eps, theta=theta, tied=tied, decode_attn=decode_attn),
-        donate_argnums=(1, 2) if donate else ())
-
-
-# ------------------------------------------------------- paged decode step
-@jax.named_scope("paged_decode_steps")
-def _paged_decode_steps_impl(params, pool_k, pool_v, tables, tokens,
-                             lengths, keys, temps, top_ks, *, n_steps, nh,
-                             nkv, hd, eps, theta, tied, decode_attn):
-    """``n_steps`` fused single-token ticks over all slots, KV living in
-    the BlockManager pool and addressed through per-slot block tables.
-
-    tables:  [B, max_blocks] int32 — physical block ids per slot
-             (sentinel ``num_blocks`` on dead slots / unmapped tails,
-             so their appends DROP instead of corrupting a shared pool
-             block — the one hazard the dense path never had)
-    tokens/lengths/keys/temps/top_ks: as in ``_decode_steps_impl``.
-
-    The engine pre-grows every active slot's table to cover
-    ``lengths + n_steps`` rows, so a fused chunk can cross block
-    boundaries without host intervention. Shapes depend only on
-    (num_slots, max_blocks, pool geometry): one compilation per
-    ``n_steps`` serves every request/table mix — the compile-once
-    contract is unchanged from the dense engine.
-
-    Returns (toks [n_steps, B], pool_k', pool_v', keys').
-    """
-    B = tokens.shape[0]
-    nb, bs = pool_k.shape[1], pool_k.shape[2]
-    mb = tables.shape[1]
-    s_tot = mb * bs
-    sin, cos = _rope_tables(s_tot, hd, theta)
-    stack = tuple(params[k] for k in _STACK_KEYS)
-    wdt = params["embed"].dtype
-    head = _dq_head(params, tied, wdt)
-
-    def one_step(carry, _):
-        tok, pk_all, pv_all, lens, kys = carry
-        x = jnp.take(params["embed"], tok[:, None], axis=0)  # [B,1,H]
-        sin_p = jnp.take(sin, lens, axis=0, mode="clip")
-        cos_p = jnp.take(cos, lens, axis=0, mode="clip")
-        # append coordinates: each row writes at its own logical length;
-        # rows past the logical capacity (can't happen while budgets are
-        # validated — belt-and-braces) and dead slots (sentinel tables)
-        # both DROP rather than clamp into someone else's block
-        bi = jnp.minimum(lens // bs, mb - 1)
-        phys = jnp.take_along_axis(tables, bi[:, None], axis=1)[:, 0]
-        phys = jnp.where(lens < s_tot, phys, nb)
-        prow = lens % bs
-
-        def layer(h, xs):
-            lwq, lwk, lwv, lwo, lg, lu, ld, lin, lpost, pk_l, pv_l = \
-                _dq_layer(xs, wdt)
-            with jax.named_scope("attn"):
-                hn = _rms(h, lin, eps)
-                q, k, v = _qkv_bshd(hn, lwq, lwk, lwv, nh, nkv, hd)
-                q = _apply_rope_rows(q, sin_p, cos_p)
-                k = _apply_rope_rows(k, sin_p, cos_p)
-                # ragged append through the table (dead slots drop)
-                pk_l = _kv_write(pk_l, (phys, prow), k[:, 0])
-                pv_l = _kv_write(pv_l, (phys, prow), v[:, 0])
-                attend = (paged_decode_attention_pallas
-                          if decode_attn == "pallas"
-                          else paged_decode_attention_reference)
-                attn = attend(q[:, 0], _kv_heads(pk_l, nkv),
-                              _kv_heads(pv_l, nkv), tables, lens + 1)
-                h = h + jnp.einsum("bsd,dh->bsh",
-                                   attn.reshape(B, 1, nh * hd), lwo)
-            h = h + _swiglu_raw(_rms(h, lpost, eps), lg, lu, ld)
-            return h, (pk_l, pv_l)
-
-        x, (npk, npv) = jax.lax.scan(layer, x, stack + (pk_all, pv_all))
-        last = _rms(x[:, 0], params["final_norm"], eps)
-        logits = _head_logits(last, head)
-        both = jax.vmap(jax.random.split)(kys)  # [B, 2, 2]
-        nxt = sample_rows(logits, both[:, 1], temps, top_ks)
-        return (nxt, npk, npv, lens + 1, both[:, 0]), nxt
-
-    carry0 = (tokens, pool_k, pool_v, lengths, keys)
-    (_, pk, pv, _, kf), toks = jax.lax.scan(one_step, carry0, None,
-                                            length=n_steps)
-    return toks, pk, pv, kf
-
-
-def build_paged_decode_steps_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
-                                decode_attn, donate=None):
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    return jax.jit(
-        functools.partial(
-            _paged_decode_steps_impl, n_steps=n_steps, nh=nh, nkv=nkv,
-            hd=hd, eps=eps, theta=theta, tied=tied, decode_attn=decode_attn),
         donate_argnums=(1, 2) if donate else ())
 
 
@@ -1325,9 +1053,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       moe=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
-    through the same block tables, collapsing the
-    ``_paged_suffix_prefill_impl`` + ``_paged_decode_steps_impl`` pair
-    the engine used to interleave (README "Unified ragged attention").
+    through the same block tables (README "Unified ragged attention").
 
     Packed layout (host-built, all runtime arrays — shapes depend only
     on ``(num_slots, token_budget)``):
@@ -1347,14 +1073,14 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
              tail-tick writes are forced to drop)
     keys/temps/top_ks: [R] per-slot sampling state — chunk rows carry
              the sequence's resume key, live (sampling) only on their
-             FINAL chunk, exactly like ``_suffix_call`` rows.
+             FINAL chunk.
 
     Tick 0 runs the packed buffer through one forward pass — K/V
     scattered through the tables at per-token positions, attention via
     the ragged paged kernel (or its jnp oracle) — then samples one
     token per slot from its span's LAST position. Ticks ``1..n_steps-1``
-    are the fused decode scan of ``_paged_decode_steps_impl``, verbatim
-    (the engine only fuses when no prefill work is pending, so the tail
+    are fused single-token decode ticks (``_fused_decode_tick``; the
+    engine only fuses when no prefill work is pending, so the tail
     ticks are pure decode; ``dec_mask`` keeps a stray non-decode row's
     appends out of the pool regardless).
 

@@ -1,20 +1,23 @@
 """Continuous-batching decode engine (the Orca/vLLM serving loop on the
 TPU decode path, SURVEY §3.5 / PAPERS.md).
 
-One engine owns ``num_slots`` KV-cache slots and drives a step function:
-each :meth:`step` (1) admits queued requests into free slots — one
-bucketed prefill each — then (2) runs one fused device call of
-``n`` single-token decode ticks over ALL slots, then (3) retires
-sequences that hit EOS or their token budget, freeing their slots for
-the next admission. Requests join and leave the batch between any two
-steps, so short requests never wait for long ones and the batch never
-restarts.
+One engine owns ``num_slots`` KV-cache slots over a block-table paged
+pool and drives a step function: each :meth:`step` (1) admits queued
+requests into free slots — one bucketed whole-prompt prefill per group,
+or, for a prompt longer than ``prefill_chunk``, a slot claim whose
+prompt then arrives chunk by chunk — then (2) runs ONE device program
+over a packed token buffer holding every running slot's decode row and
+this step's prefill chunks, then (3) retires sequences that hit EOS or
+their token budget, freeing their slots for the next admission. Requests
+join and leave the batch between any two steps, so short requests never
+wait for long ones and the batch never restarts.
 
-Compile discipline (the perf contract): the decode program's shapes
-depend only on ``(num_slots, max_seq_len)``; per-request sampling knobs
-and per-slot ragged lengths are runtime arrays. One compilation serves
-every request mix — :meth:`decode_compilations` counts traces so tests
-can pin this. Prefill compiles once per prompt-length bucket.
+Compile discipline (the perf contract): the step program's shapes depend
+only on ``(num_slots, token_budget)``; block tables, span metadata,
+per-request sampling knobs and per-slot ragged lengths are runtime
+arrays. One compilation serves every request mix —
+:meth:`decode_compilations` counts traces so tests can pin this. Prefill
+compiles once per (group, prompt-length) bucket.
 
 Offline use::
 
@@ -36,10 +39,9 @@ import numpy as np
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
-from .decode import build_decode_steps_fn, build_paged_decode_steps_fn, \
-    build_paged_suffix_prefill_fn, build_prefill_fn, build_ragged_step_fn, \
-    build_suffix_prefill_fn, _STACK_EXTRA_KEYS
-from .kv_cache import PagedKVCache, PoolExhausted, SlotKVCache
+from .decode import build_paged_suffix_prefill_fn, build_prefill_fn, \
+    build_ragged_step_fn, _STACK_EXTRA_KEYS
+from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, select_victims
 from .request import GenerationRequest, GenerationResult, Sequence
 from .scheduler import FIFOScheduler
@@ -48,76 +50,60 @@ from .scheduler import FIFOScheduler
 class ContinuousBatchingEngine:
     """Slot-based continuous batching over a LLaMA-family model.
 
-    ``prefix_cache=True`` enables automatic prefix caching
-    (``serving/prefix_cache.py``): retiring sequences publish their
-    prompt's full KV blocks into a ref-counted LRU pool, and a new
-    admission whose prompt shares a cached block chain installs it with
-    compile-once copy programs and prefills only the uncovered suffix.
-    Pass a :class:`~.prefix_cache.PrefixCache` instance to carry one
-    pool across successive engines — ONLY when every engine is driven
-    from the same single thread (the cache is lock-free by the engine's
-    single-driver contract; two concurrently-stepping engines, e.g. two
-    gateways, must not share one). Its pool geometry must match this
-    engine's layers/heads/dtype. ``prefix_blocks``/``prefix_block_size``
-    size the pool the engine builds itself (default: enough blocks to
-    cache ``num_slots`` full-length prompts at 32-token granularity).
+    The KV cache is block-table paged (:class:`~.kv_cache.PagedKVCache`,
+    README "Paged attention"): the :class:`~.block_manager.BlockManager`
+    pool IS the cache, every live slot addresses it through a per-slot
+    block table (a runtime argument — ``decode_compilations()`` stays at
+    1) and decode growth appends blocks lazily. ``prefix_block_size`` is
+    the KV block size; the pool holds ``num_slots *
+    ceil(max_seq_len / block_size)`` live blocks plus the
+    ``prefix_blocks`` trie budget.
 
-    ``paged_attn=True`` (the default) serves from true block-table
-    paged attention (:class:`~.kv_cache.PagedKVCache`, README "Paged
-    attention"): the :class:`~.block_manager.BlockManager` pool IS the
-    cache, every live slot addresses it through a per-slot block table
-    (a runtime argument — ``decode_compilations()`` stays at 1),
-    prefix-cache hits install by *referencing* published block ids
-    (zero copy dispatches; N holders physically share one block), decode
-    growth appends blocks lazily, and retirement *donates* full prompt
-    AND generated blocks to the trie instead of copying them out (so a
-    multi-turn resubmission of an assistant turn hits that turn's own
-    blocks). Token streams are byte-identical to the dense engine
-    (``paged_attn=False``, the legacy :class:`~.kv_cache.SlotKVCache`
-    path — still selectable, same test matrix).
-    ``prefix_block_size`` doubles as the KV block size; the pool is
-    sized ``num_slots * ceil(max_seq_len/block_size)`` live blocks plus
-    the ``prefix_blocks`` trie budget (trie-only blocks are reclaimed on
-    demand when live growth needs them).
+    Every step is ONE device program, the unified ragged step
+    (``decode.build_ragged_step_fn`` over the ragged paged attention
+    kernel, README "Unified ragged attention"): each slot contributes
+    one variable-length query span (decode = span 1, prefill chunk =
+    span n) to a packed token buffer whose shape depends only on
+    ``(num_slots, token_budget)``. ``decode_chunk`` bounds how many
+    single-token ticks a pure-decode step fuses behind tick 0.
 
     ``prefill_chunk`` bounds TTFT under mixed traffic (README "Chunked
-    prefill"): a cold prompt whose uncovered tail exceeds it is
-    prefilled ``prefill_chunk`` tokens per engine step — through the
-    paged suffix-prefill program at a host-side resume offset, KV
-    landing in the slot's own pool blocks — interleaved with the fused
-    decode tick for every live slot, so a long prompt never monopolizes
-    a step while decode slots idle. Chunk boundaries are block-aligned
-    (the value is rounded up to a block multiple); installed prefix-
-    cache hits count toward the resume offset; cancellation or deadline
-    expiry mid-chunk frees (or donates) the partial block chain.
-    ``prefill_chunk=None``/``0`` disables chunking; the dense engine
-    ignores it (one-shot prefill — chunking rides the block tables).
-
-    ``ragged_step=True`` (the default on the paged engine, README
-    "Unified ragged attention") runs decode rows AND prefill chunks
-    through ONE device program per step — the unified ragged step
-    (``decode.build_ragged_step_fn`` over the ragged paged attention
-    kernel): each slot contributes one variable-length query span
-    (decode = span 1, chunk = span n) to a packed token buffer whose
-    shape depends only on ``(num_slots, token_budget)``, so a mixed
-    prefill+decode step costs one program launch instead of the
-    chunk-call + decode-call pair, and a mid-prefill slot no longer
-    burns a discarded full-length decode row. ``ragged_step=False``
-    keeps the PR-5 two-program interleave as the A/B baseline (token
-    streams are byte-identical either way). With the unified step, the
-    per-step chunk grant is adapted at runtime from a measured
-    tokens-per-second EWMA (the ``headroom`` stat): the engine grants
-    roughly ``headroom_mult`` decode-steps' worth of tokens per step —
+    prefill"): a prompt whose uncovered tail exceeds it is prefilled at
+    most ``prefill_chunk`` tokens per step, as a span of the step
+    program, K/V landing in the slot's own pool blocks at a host-side
+    resume offset, so a long prompt never monopolizes a step while
+    decode slots idle. Chunk boundaries are block-aligned (the value is
+    rounded up to a block multiple); installed prefix-cache hits count
+    toward the resume offset; cancellation or deadline expiry mid-chunk
+    frees (or donates) the partial block chain. ``None``/``0`` disables
+    chunking and sizes the packed buffer to ``num_slots``. The per-step
+    chunk grant is adapted at runtime from a measured tokens-per-second
+    EWMA (the ``headroom`` stat): the engine grants roughly
+    ``headroom_mult`` decode-steps' worth of tokens per step —
     ``prefill_chunk`` remains the hard cap — so chunk work throttles
-    itself under decode load instead of stretching every resident
-    request's latency. ``headroom_mult=None`` pins the grant at the
-    cap (fixed PR-5 pacing, what the deterministic benches use).
-    ``step_clock`` injects the timebase the EWMA reads (tests/benches
+    itself under decode load. ``headroom_mult=None`` pins the grant at
+    the cap. ``step_clock`` injects the timebase the EWMA reads (tests
     pass a virtual clock; default ``time.perf_counter``).
 
-    ``spec_decode=True`` (paged only, default OFF — every banked
-    baseline is an A/B away) turns on speculative multi-token decode
-    (README "Speculative decoding"): a :class:`~.drafter.Drafter`
+    ``prefix_cache=True`` enables automatic prefix caching
+    (``serving/prefix_cache.py``): a retiring sequence DONATES its full
+    prompt and generated blocks to a ref-counted LRU trie (ownership
+    handoff, no copy), and a new admission whose prompt shares a cached
+    block chain installs it by *referencing* the block ids in its table
+    (N holders physically share one block) and prefills only the
+    uncovered suffix. Trie-only blocks are reclaimed on demand when live
+    growth needs them. Pass a :class:`~.prefix_cache.PrefixCache`
+    instance to carry one pool across successive engines — ONLY when
+    every engine is driven from the same single thread (the cache is
+    lock-free by the engine's single-driver contract; two
+    concurrently-stepping engines, e.g. two gateways, must not share
+    one). Its pool geometry must match this engine's
+    layers/heads/dtype/block size. ``prefix_blocks`` sizes the trie
+    budget of the pool the engine builds itself (default: enough blocks
+    to cache ``num_slots`` full-length prompts).
+
+    ``spec_decode=True`` (default off) turns on speculative multi-token
+    decode (README "Speculative decoding"): a :class:`~.drafter.Drafter`
     (default: model-free prompt-lookup n-grams,
     :class:`~.drafter.NgramDrafter`; or a tiny draft model via
     :class:`~.drafter.ModelDrafter`) proposes up to ``spec_k`` tokens
@@ -135,13 +121,9 @@ class ContinuousBatchingEngine:
     the same one-launch-per-step program; drafts share the packed
     buffer's headroom with the chunk grant
     (``FIFOScheduler.spec_grants``). ``decode_compilations()`` counts
-    the verify geometry and stays 1. On the CPU/jnp substrate the
-    verify walk prices the packed buffer densely (same caveat as the
-    unified step below); the modeled win is launches-per-token
-    (``scripts/bench_spec.py``, SPEC_BENCH.json).
+    the verify geometry and stays 1.
 
-    ``decode_ticks > 1`` (unified ragged engine only, default 1 — every
-    banked baseline is an A/B away) turns on multi-tick decode (README
+    ``decode_ticks > 1`` (default 1) turns on multi-tick decode (README
     "Multi-tick decode"): EVERY step runs ONE multi-tick program
     (``decode.build_multitick_step_fn``) whose packed tick 0 is the
     unified step verbatim and whose fused tail runs a RUNTIME number of
@@ -161,23 +143,17 @@ class ContinuousBatchingEngine:
     pure-decode tail to fuse). ``decode_chunk`` fusion is superseded on
     this path — the multi-tick program subsumes it with masking.
 
-    Substrate note: the unified program's packed buffer is a fixed
-    ``num_slots + prefill_chunk`` tokens, which the TPU Pallas kernel
-    prices at the LIVE spans only (its grid is a work list of
-    the spans' query blocks, its KV walk ends at each row's length) but the CPU ``decode_attention="jnp"`` oracle computes
-    densely — on that correctness substrate a decode-only step pays
-    the padding, so CPU deployments that never chunk should pass
-    ``ragged_step=False`` (or ``prefill_chunk=None``, which sizes the
-    buffer back to ``num_slots``). The serving benches pin the
-    two-program baseline for exactly this reason
-    (``RAGGED_BENCH.json``'s ``cpu_oracle_wall_ms`` records the gap).
+    Substrate note: the packed buffer is a fixed ``num_slots +
+    prefill_chunk`` tokens, which the TPU Pallas kernel prices at the
+    LIVE spans only (its grid is a work list of the spans' query blocks,
+    its KV walk ends at each row's length) but the CPU
+    ``decode_attention="jnp"`` oracle computes densely.
     """
 
     def __init__(self, model, num_slots=8, max_seq_len=None, decode_chunk=8,
                  prefill_bucketing="pow2", jit_cache=None,
                  prefix_cache=False, prefix_blocks=None,
-                 prefix_block_size=32, paged_attn=True,
-                 prefill_chunk=512, ragged_step=True, headroom_mult=2.0,
+                 prefix_block_size=32, prefill_chunk=512, headroom_mult=2.0,
                  step_clock=None, spec_decode=False, spec_k=4,
                  drafter=None, decode_ticks=1, kv_dtype=None,
                  quantize_weights=False, quantize_activations=False,
@@ -224,13 +200,6 @@ class ContinuousBatchingEngine:
         # so banners/geometry tuples report the effective value
         self._coll_dtype = collective_dtype if self._tp > 1 else "fp"
         if self._tp > 1:
-            if not (bool(paged_attn) and bool(ragged_step)):
-                raise ValueError(
-                    "tp > 1 requires the unified ragged paged engine "
-                    "(paged_attn=True, ragged_step=True): tensor "
-                    "parallelism shards the packed-span programs and "
-                    "the block pool; the dense / two-program paths "
-                    "never grew mesh plumbing")
             if c.num_attention_heads % self._tp \
                     or c.num_key_value_heads % self._tp:
                 raise ValueError(
@@ -254,13 +223,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"kv_dtype must be None (store KV at the pool dtype), "
                 f"'int8' or 'fp8', got {kv_dtype!r}")
-        if kv_dtype is not None and not (paged_attn and ragged_step):
-            raise ValueError(
-                f"kv_dtype={kv_dtype!r} requires the unified ragged "
-                f"paged engine (paged_attn=True, ragged_step=True): the "
-                f"quantized pool's one upcast site is the ragged "
-                f"attention kernel, and the dense / two-program paths "
-                f"never grew scale-plane plumbing")
         if quantize_activations and not quantize_weights:
             raise ValueError(
                 "quantize_activations=True requires "
@@ -268,13 +230,6 @@ class ContinuousBatchingEngine:
                 "contracts runtime-quantized activations against the "
                 "int8 weight pytree, so there is no activation-only "
                 "variant")
-        if quantize_activations and not (paged_attn and ragged_step):
-            raise ValueError(
-                "quantize_activations=True requires the unified ragged "
-                "paged engine (paged_attn=True, ragged_step=True): only "
-                "the packed-span programs grew the int8xint8 projection "
-                "path, and a dense-path decode would silently fall back "
-                "to weight-dequant matmuls")
         self.model = model
         self.config = c
         self.num_slots = int(num_slots)
@@ -293,8 +248,6 @@ class ContinuousBatchingEngine:
                    "tp > 1": int(tp) > 1, "fused_tick": bool(fused_tick),
                    "decode_ticks > 1": int(decode_ticks) > 1,
                    "spec_decode": bool(spec_decode),
-                   "paged_attn=False": not paged_attn,
-                   "ragged_step=False": not ragged_step,
                    "decode_chunk > 1": int(decode_chunk) > 1,
                    "prefix_cache": bool(prefix_cache)}
             bad = [name for name, on in off.items() if on]
@@ -305,7 +258,6 @@ class ContinuousBatchingEngine:
                     f"prefill and the unified ragged step only; these "
                     f"switches run a program with a layer body of its "
                     f"own that was not taught it: {', '.join(bad)}")
-        self._paged = bool(paged_attn)
         # quantized KV pool (README "Quantized serving"): "int8" stores
         # int8 with per-row-per-head fp32 scale planes, "fp8" stores
         # float8_e4m3fn with per-BLOCK planes (constant 1.0 — e4m3's
@@ -367,154 +319,108 @@ class ContinuousBatchingEngine:
         from .block_manager import BlockManager
         from .prefix_cache import PrefixCache
         self.prefix_cache = None
-        if self._paged:
-            bs = int(prefix_block_size)
-            if bs < 1:
+        bs = int(prefix_block_size)
+        if bs < 1:
+            raise ValueError(
+                f"prefix_block_size must be >= 1, got {bs}")
+        max_blocks = -(-self.max_seq_len // bs)
+        live = self.num_slots * max_blocks
+        # the pool's STORAGE dtype follows kv_dtype (int8 data +
+        # scale planes), not the model dtype — a shared pool must
+        # match the engine's quantization mode exactly
+        store = (jnp.float8_e4m3fn if self._kv_dtype == "fp8"
+                 else jnp.int8 if self._kv_quant else dtype)
+        # TP partitions the pool's HEAD axis across the mesh: the
+        # BlockManager commits its arrays with that sharding once,
+        # so every sharded program adopts them zero-copy
+        tp_mesh = self._tp_mesh
+        if isinstance(prefix_cache, PrefixCache):
+            pool = prefix_cache.pool
+            want = (c.num_hidden_layers, c.num_key_value_heads,
+                    c.head_dim)
+            have = (pool.k.shape[0], pool.num_kv_heads, pool.head_dim)
+            if have != want or pool.k.dtype != store \
+                    or pool.block_size != bs \
+                    or getattr(pool, "kv_dtype",
+                               None) != self._kv_dtype:
                 raise ValueError(
-                    f"prefix_block_size must be >= 1, got {bs}")
-            max_blocks = -(-self.max_seq_len // bs)
-            live = self.num_slots * max_blocks
-            # the pool's STORAGE dtype follows kv_dtype (int8 data +
-            # scale planes), not the model dtype — a shared pool must
-            # match the engine's quantization mode exactly
-            store = (jnp.float8_e4m3fn if self._kv_dtype == "fp8"
-                     else jnp.int8 if self._kv_quant else dtype)
-            # TP partitions the pool's HEAD axis across the mesh: the
-            # BlockManager commits its arrays with that sharding once,
-            # so every sharded program adopts them zero-copy
-            tp_mesh = self._tp_mesh
-            if isinstance(prefix_cache, PrefixCache):
-                pool = prefix_cache.pool
-                want = (c.num_hidden_layers, c.num_key_value_heads,
-                        c.head_dim)
-                have = (pool.k.shape[0], pool.num_kv_heads, pool.head_dim)
-                if have != want or pool.k.dtype != store \
-                        or pool.block_size != bs \
-                        or getattr(pool, "kv_dtype",
-                                   None) != self._kv_dtype:
-                    raise ValueError(
-                        f"shared PrefixCache pool geometry "
-                        f"{have}/bs={pool.block_size}/{pool.k.dtype} does "
-                        f"not match this paged engine "
-                        f"{want}/bs={bs}/{store} "
-                        f"(kv_dtype={self._kv_dtype!r})")
-                if getattr(pool, "tp", 1) != self._tp:
-                    raise ValueError(
-                        f"shared PrefixCache pool is partitioned for "
-                        f"tp={getattr(pool, 'tp', 1)} but this engine "
-                        f"runs tp={self._tp}: a pool's head-axis "
-                        f"sharding must match every engine serving "
-                        f"from it")
-                if pool.num_blocks <= live:
-                    raise ValueError(
-                        f"shared pool of {pool.num_blocks} blocks cannot "
-                        f"back {live} live blocks plus a prefix trie on "
-                        f"the paged engine")
-                if prefix_cache.max_blocks is None:
-                    # a dense-idiom cache (pool IS the budget) adopted by
-                    # a paged engine: bound trie residency to the pool's
-                    # headroom over the live grid, else donations grow
-                    # until every decode-growth alloc pays an eviction
-                    prefix_cache.max_blocks = pool.num_blocks - live
-                self.prefix_cache = prefix_cache
-            elif prefix_cache:
-                if prefix_blocks is None:
-                    budget = self.num_slots * max(self.max_seq_len // bs, 1)
-                else:
-                    budget = int(prefix_blocks)
-                    if budget < 1:
-                        raise ValueError(
-                            f"prefix_blocks must be >= 1, got {budget}")
-                pool = BlockManager(
-                    c.num_hidden_layers, live + budget, bs,
-                    c.num_key_value_heads, c.head_dim, dtype=dtype,
-                    kv_dtype=self._kv_dtype, mesh=tp_mesh)
-                self.prefix_cache = PrefixCache(
-                    pool, max_blocks=budget,
-                    host_tier_bytes=self._host_tier_bytes)
+                    f"shared PrefixCache pool geometry "
+                    f"{have}/bs={pool.block_size}/{pool.k.dtype} does "
+                    f"not match this engine "
+                    f"{want}/bs={bs}/{store} "
+                    f"(kv_dtype={self._kv_dtype!r})")
+            if getattr(pool, "tp", 1) != self._tp:
+                raise ValueError(
+                    f"shared PrefixCache pool is partitioned for "
+                    f"tp={getattr(pool, 'tp', 1)} but this engine "
+                    f"runs tp={self._tp}: a pool's head-axis "
+                    f"sharding must match every engine serving "
+                    f"from it")
+            if pool.num_blocks <= live:
+                raise ValueError(
+                    f"shared pool of {pool.num_blocks} blocks cannot "
+                    f"back {live} live blocks plus a prefix trie")
+            if prefix_cache.max_blocks is None:
+                # a cache built without a budget: bound trie residency
+                # to the pool's headroom over the live grid, else
+                # donations grow until every decode-growth alloc pays
+                # an eviction
+                prefix_cache.max_blocks = pool.num_blocks - live
+            self.prefix_cache = prefix_cache
+        elif prefix_cache:
+            if prefix_blocks is None:
+                budget = self.num_slots * max(self.max_seq_len // bs, 1)
             else:
-                pool = BlockManager(
-                    c.num_hidden_layers, live, bs, c.num_key_value_heads,
-                    c.head_dim, dtype=dtype, kv_dtype=self._kv_dtype,
-                    mesh=tp_mesh)
-            self.cache = PagedKVCache(
-                c.num_hidden_layers, self.num_slots, self.max_seq_len,
+                budget = int(prefix_blocks)
+                if budget < 1:
+                    raise ValueError(
+                        f"prefix_blocks must be >= 1, got {budget}")
+            pool = BlockManager(
+                c.num_hidden_layers, live + budget, bs,
                 c.num_key_value_heads, c.head_dim, dtype=dtype,
-                block_size=bs, pool=pool, prefix_cache=self.prefix_cache,
-                kv_dtype=self._kv_dtype)
+                kv_dtype=self._kv_dtype, mesh=tp_mesh)
+            self.prefix_cache = PrefixCache(
+                pool, max_blocks=budget,
+                host_tier_bytes=self._host_tier_bytes)
         else:
-            self.cache = SlotKVCache(
-                c.num_hidden_layers, self.num_slots, self.max_seq_len,
-                c.num_key_value_heads, c.head_dim, dtype=dtype)
-            if prefix_cache:
-                if isinstance(prefix_cache, PrefixCache):
-                    # fail fast on a geometry mismatch: copies between the
-                    # pool and this cache would otherwise die mid-serving
-                    # with an opaque XLA shape/dtype error on the first hit
-                    pool = prefix_cache.pool
-                    want = (self.cache.k.shape[0],) + self.cache.k.shape[3:]
-                    have = (pool.k.shape[0], pool.num_kv_heads,
-                            pool.head_dim)
-                    if have != want or pool.k.dtype != self.cache.k.dtype:
-                        raise ValueError(
-                            f"shared PrefixCache pool geometry "
-                            f"{have}/{pool.k.dtype} does not match this "
-                            f"engine's cache {want}/{self.cache.k.dtype}")
-                    self.prefix_cache = prefix_cache
-                else:
-                    bs = int(prefix_block_size)
-                    if bs < 1:
-                        raise ValueError(
-                            f"prefix_block_size must be >= 1, got {bs}")
-                    if prefix_blocks is None:
-                        nb = self.num_slots * max(self.max_seq_len // bs, 1)
-                    else:
-                        nb = int(prefix_blocks)  # 0/negative: BlockManager
-                        # raises rather than silently falling back to default
-                    self.prefix_cache = PrefixCache(BlockManager(
-                        c.num_hidden_layers, nb, bs, c.num_key_value_heads,
-                        c.head_dim, dtype=dtype),
-                        host_tier_bytes=self._host_tier_bytes)
-        # chunked prefill (paged only — the dense per-slot cache has no
-        # block tables to resume through; its prefill stays one-shot).
-        # The chunk is rounded UP to a block multiple so every non-final
-        # chunk boundary is block-aligned: a partially prefilled prompt
-        # is exactly a prefix of whole pool blocks + a host resume
-        # offset, which keeps mid-prefill cancellation/donation trivial.
+            pool = BlockManager(
+                c.num_hidden_layers, live, bs, c.num_key_value_heads,
+                c.head_dim, dtype=dtype, kv_dtype=self._kv_dtype,
+                mesh=tp_mesh)
+        self.cache = PagedKVCache(
+            c.num_hidden_layers, self.num_slots, self.max_seq_len,
+            c.num_key_value_heads, c.head_dim, dtype=dtype,
+            block_size=bs, pool=pool, prefix_cache=self.prefix_cache,
+            kv_dtype=self._kv_dtype)
+        # chunked prefill: the chunk is rounded UP to a block multiple so
+        # every non-final chunk boundary is block-aligned: a partially
+        # prefilled prompt is exactly a prefix of whole pool blocks + a
+        # host resume offset, which keeps mid-prefill
+        # cancellation/donation trivial.
         self._chunk = None
         if prefill_chunk and int(prefill_chunk) < 1:
-            # validated on BOTH engines: an A/B toggle of paged_attn
-            # must not turn a hard error into a silent no-op
             raise ValueError(
                 f"prefill_chunk must be >= 1 (or None/0 to disable), "
                 f"got {int(prefill_chunk)}")
-        if self._paged and prefill_chunk:
-            bs = self.cache.block_size
+        if prefill_chunk:
             self._chunk = -(-int(prefill_chunk) // bs) * bs
-        # unified ragged step (paged only): size the packed token buffer
-        # once — num_slots decode rows plus the chunk cap, but only when
-        # a prompt long enough to chunk can exist at all (a chunk cap >=
-        # max_seq_len can never trigger, so the buffer stays num_slots
-        # and a decode-only engine pays nothing for the unification)
-        self._ragged = self._paged and bool(ragged_step)
+        # size the packed token buffer once — num_slots decode rows plus
+        # the chunk cap, but only when a prompt long enough to chunk can
+        # exist at all (a chunk cap >= max_seq_len can never trigger, so
+        # the buffer stays num_slots and a decode-only engine pays
+        # nothing for the chunk rows)
         chunkable = self._chunk is not None and self._chunk < self.max_seq_len
         self._token_budget = self.num_slots + (self._chunk if chunkable
                                                else 0)
-        # speculative decode (paged only — rollback truncates the block
-        # tail; README "Speculative decoding"): every step becomes ONE
-        # draft-extended verify launch whose packed buffer shares its
+        # speculative decode (rollback truncates the block tail; README
+        # "Speculative decoding"): every step becomes ONE draft-extended
+        # verify launch whose packed buffer shares its
         # headroom between prefill-chunk tokens and verify spans (a
         # verify span spends 1 + k positions of it). The buffer is
         # sized for the LARGER of the two demands, not their sum —
         # chunk-heavy steps throttle drafts, decode-heavy steps have
         # the chunk headroom to speculate into.
         self._spec = bool(spec_decode)
-        if self._spec and not self._paged:
-            raise ValueError(
-                "spec_decode requires the paged engine (paged_attn="
-                "True): draft rollback truncates the slot's private "
-                "block tail, which the dense per-slot cache does not "
-                "have")
         if self._spec and int(spec_k) < 1:
             raise ValueError(f"spec_k must be >= 1, got {int(spec_k)}")
         self._spec_k = int(spec_k)
@@ -544,18 +450,12 @@ class ContinuousBatchingEngine:
                 f"decode_ticks must be >= 1, got {int(decode_ticks)}")
         self._decode_ticks = int(decode_ticks)
         self._mtick = self._decode_ticks > 1
-        if self._mtick and not self._ragged:
-            raise ValueError(
-                "decode_ticks > 1 requires the unified ragged engine "
-                "(paged_attn=True, ragged_step=True): multi-tick decode "
-                "is the unified step's fused tail driven past the host "
-                "sync")
         if self._mtick and self._spec:
             raise ValueError(
                 "decode_ticks > 1 is incompatible with spec_decode: a "
                 "speculative step is a verify launch every step, so "
                 "there is no pure-decode tail to multi-tick. spec_decode "
-                "composes with: paged_attn, ragged_step, prefix_cache, "
+                "composes with: prefix_cache, "
                 "prefill_chunk, kv_dtype, quantize_weights, "
                 "quantize_activations, tp, collective_overlap, "
                 "host_tier_bytes, priority_classes. decode_ticks > 1 "
@@ -571,12 +471,6 @@ class ContinuousBatchingEngine:
         # (in-kernel collectives, int8 activations). Default False keeps
         # every banked baseline byte-identical.
         self._fused_tick = bool(fused_tick)
-        if self._fused_tick and not self._ragged:
-            raise ValueError(
-                "fused_tick=True requires the unified ragged paged "
-                "engine (paged_attn=True, ragged_step=True): the fused "
-                "program is the packed-span tick body, and the dense / "
-                "two-program paths never grew its dispatch site")
         if self._fused_tick and self._spec:
             raise ValueError(
                 "fused_tick=True is incompatible with spec_decode: the "
@@ -650,7 +544,6 @@ class ContinuousBatchingEngine:
                       "slot_steps": 0, "active_slot_steps": 0,
                       "prefills": 0, "prefill_tokens": 0,
                       "prefill_tokens_saved": 0,
-                      "prefill_copy_dispatches": 0,
                       "prefill_chunks": 0, "chunk_tokens": 0,
                       "step_prefill_tokens": 0, "step_decode_tokens": 0,
                       "unified_steps": 0,
@@ -800,9 +693,7 @@ class ContinuousBatchingEngine:
     def _q_consts(self):
         """Builder kwargs of the activation-quantized variant ({} when
         off, so default engines call the builders exactly as before).
-        Only the builders that grew the int8xint8 path take ``a8`` —
-        the validation above keeps a8 engines off the dense/two-program
-        builders."""
+        Only the builders that grew the int8xint8 path take ``a8``."""
         return dict(a8=True) if self._a8 else {}
 
     def _moe_out(self, index):
@@ -847,32 +738,14 @@ class ContinuousBatchingEngine:
                                host_out=(2,) + self._moe_out(4))
 
     def _suffix_fn(self):
-        # paged and dense suffix programs are distinct (table-indirect
-        # vs slot-indexed) and may share one jit_cache dict, so they key
-        # apart; the cold prefill is IDENTICAL either way and is shared.
-        # The suffix program touches params AND pool — all three tags.
-        key = (("psuffix",) if self._paged else ("suffix",)) \
-            + self._kvtag + self._wtag + self._atag + self._tptag
+        # the suffix program touches params AND pool — all three tags
+        key = ("psuffix",) + self._kvtag + self._wtag + self._atag \
+            + self._tptag
         if key not in self._jit:
-            build = (build_paged_suffix_prefill_fn if self._paged
-                     else build_suffix_prefill_fn)
-            self._jit[key] = build(**self._fn_consts(),
-                                   **self._tp_consts(),
-                                   **self._q_consts())
+            self._jit[key] = build_paged_suffix_prefill_fn(
+                **self._fn_consts(), **self._tp_consts(),
+                **self._q_consts())
         return self._wrap_prog(key, self._jit[key], host_out=(2,))
-
-    def _decode_fn(self, n_steps):
-        kind = "pdecode" if self._paged else "decode"
-        key = (kind, int(n_steps), self.config.decode_attention) \
-            + self._kvtag + self._wtag
-        if key not in self._jit:
-            build = (build_paged_decode_steps_fn if self._paged
-                     else build_decode_steps_fn)
-            self._jit[key] = build(
-                n_steps=int(n_steps),
-                decode_attn=self.config.decode_attention,
-                **self._fn_consts())
-        return self._wrap_prog(key, self._jit[key], host_out=(0,))
 
     def _ragged_fn(self, n_steps):
         # the full packed-buffer geometry — num_slots AND token budget,
@@ -1035,8 +908,7 @@ class ContinuousBatchingEngine:
         (README "Quantized serving")."""
         if self._kv_quant:
             return self._kv_dtype
-        arr = self.cache.pool.k if self._paged else self.cache.k
-        return str(arr.dtype)
+        return str(self.cache.pool.k.dtype)
 
     @property
     def quantize_weights(self) -> bool:
@@ -1054,18 +926,10 @@ class ContinuousBatchingEngine:
         return self._a8
 
     @property
-    def ragged_step(self) -> bool:
-        """Whether this engine runs the unified ragged step (one device
-        program per step for decode rows + prefill chunks) — the public
-        surface for banners/metrics."""
-        return self._ragged
-
-    @property
     def prefill_chunk(self) -> int:
         """The EFFECTIVE chunked-prefill budget this engine runs: the
         configured value rounded up to a KV-block multiple, or 0 when
-        chunking is disabled (or ignored — the dense engine has no
-        block tables to resume through). The public surface for
+        chunking is disabled. The public surface for
         banners/metrics; ``_chunk`` stays the internal None-able
         form."""
         return self._chunk or 0
@@ -1073,11 +937,10 @@ class ContinuousBatchingEngine:
     def decode_compilations(self) -> int:
         """Total decode-program traces OF THIS ENGINE'S KIND (the
         compiles-once assertion hook): stays at one per ``(num_slots,
-        max_seq_len, n_steps)`` — on the unified engine, one per
-        ``(num_slots, token_budget, n_steps)`` — no matter how request
-        sampling params / token budgets / block tables / span mixes
-        vary. Dense, paged-two-program and unified engines sharing one
-        jit_cache count only their own programs. On the speculative
+        token_budget, n_steps)`` no matter how request sampling params /
+        token budgets / block tables / span mixes vary. Engines of
+        another geometry or variant sharing one jit_cache count only
+        their own programs. On the speculative
         engine the verify program IS the decode program — every step,
         chunk-carrying or not, is one spec-geometry launch — so the
         count covers the verify geometry too. Tag-aware INCLUSIVE of
@@ -1118,15 +981,11 @@ class ContinuousBatchingEngine:
                        and key[2] == self._token_budget
                        and key[3] == self._decode_ticks
                        and key[5:] == tags)
-        if self._ragged:
-            return sum(fn._cache_size() for key, fn in self._jit.items()
-                       if key[0] == "ragged"
-                       and key[1] == self.num_slots
-                       and key[2] == self._token_budget
-                       and key[5:] == tags)
-        kind = "pdecode" if self._paged else "decode"
         return sum(fn._cache_size() for key, fn in self._jit.items()
-                   if key[0] == kind and key[3:] == tags)
+                   if key[0] == "ragged"
+                   and key[1] == self.num_slots
+                   and key[2] == self._token_budget
+                   and key[5:] == tags)
 
     def prefill_compilations(self) -> int:
         """Prefill-side traces, cold + suffix: bounded by the pow2
@@ -1134,12 +993,11 @@ class ContinuousBatchingEngine:
         (the bounded-compile half of the prefix-cache contract). Tag-
         aware like :meth:`decode_compilations`: only THIS engine's
         quantization variant counts."""
-        sfx = "psuffix" if self._paged else "suffix"
         return sum(fn._cache_size() for key, fn in self._jit.items()
                    if (key[0] == "prefill"
                        and key[1:] == self._wtag + self._atag
                        + self._tptag)
-                   or (key[0] == sfx
+                   or (key[0] == "psuffix"
                        and key[1:] == self._kvtag + self._wtag
                        + self._atag + self._tptag))
 
@@ -1242,7 +1100,7 @@ class ContinuousBatchingEngine:
         With chunked prefill on, a sequence whose UNCOVERED prompt
         exceeds ``prefill_chunk`` skips both one-shot paths: it claims
         its slot (and zero-copy-installs any matched chain) now, enters
-        the PREFILLING state, and the step loop feeds it to the suffix
+        the PREFILLING state, and the step loop feeds it to the step
         program one budgeted chunk at a time."""
         tr = self._tr()
         for seq in seqs:
@@ -1265,7 +1123,7 @@ class ContinuousBatchingEngine:
             # immediate EOS) publishes inside _admit_cold, and under
             # pool pressure that publish evicts; an unpinned matched
             # chain could be reaped and its block re-used before
-            # _admit_hits copies from it
+            # _admit_hits installs it
             covered = seq.prefix_hit_tokens   # set at scheduler pop time
             if self._chunk and seq.work_len - covered > self._chunk:
                 self._enter_chunked_prefill(seq, covered)
@@ -1343,44 +1201,57 @@ class ContinuousBatchingEngine:
         per suffix-length bucket covering only the uncovered prompt
         tails.
 
-        Dense: install each sequence's matched chain into its slot with
-        compile-once block copies (one ``copy_block_in`` dispatch per
-        block — counted in ``prefill_copy_dispatches``). Group padding
-        rows carry slot index ``num_slots`` and prefix ``max_seq_len``
-        so every one of their cache writes drops inside the program.
-
-        Paged: ZERO-COPY install — the slot's block table simply
-        references the matched chain's block ids (no device dispatch;
-        N concurrent holders share the physical blocks), private tail
-        blocks are appended to cover the prompt, and the suffix prefill
-        writes through the table. Padding rows carry all-sentinel
-        tables so their writes drop."""
-        pc = self.prefix_cache
-        bs = pc.block_size
+        ZERO-COPY install — the slot's block table simply references
+        the matched chain's block ids (no device dispatch; N concurrent
+        holders share the physical blocks), private tail blocks are
+        appended to cover the prompt, and the suffix prefill writes
+        through the table. Group padding rows carry all-sentinel tables
+        and an all-covered prefix, so every one of their writes drops
+        in-program."""
+        bs, mb = self.cache.block_size, self.cache.max_blocks
         by_bucket = {}
         for seq, matched in hits:
             suffix_len = seq.work_len - len(matched) * bs
             by_bucket.setdefault(self._bucket(suffix_len),
                                  []).append((seq, matched))
         for s_pad, group in sorted(by_bucket.items()):
-            rows = []
-            for seq, matched in group:
+            Gp = 1 << (len(group) - 1).bit_length()
+            tables = np.full((Gp, mb), self.cache.sentinel, np.int32)
+            prefix_lens = np.full(Gp, mb * bs, np.int32)
+            ids = np.zeros((Gp, s_pad), np.int32)
+            suf_lens = np.ones(Gp, np.int32)
+            temps = np.zeros(Gp, np.float32)
+            topks = np.zeros(Gp, np.int32)
+            keys = np.zeros((Gp, 2), np.uint32)
+            for i, (seq, matched) in enumerate(group):
                 # chain already pinned + prefix_hit_tokens already set
                 # by _admission_hit_len at scheduler pop time
                 covered = len(matched) * bs
                 slot = self.cache.alloc()
                 seq.slot = slot
-                if self._paged:
-                    self.cache.install_prefix(
-                        slot, [node.block_id for node in matched])
-                    self.cache.ensure_capacity(slot, seq.work_len)
-                else:
-                    for j, node in enumerate(matched):
-                        self.cache.copy_block_in(slot, j * bs, pc.pool,
-                                                 node.block_id)
-                        self.stats["prefill_copy_dispatches"] += 1
-                rows.append((seq, covered, seq.work_len - covered, True))
-            tok0s, keys2 = self._suffix_call(s_pad, rows)
+                self.cache.install_prefix(
+                    slot, [node.block_id for node in matched])
+                self.cache.ensure_capacity(slot, seq.work_len)
+                tables[i] = self.cache.tables[slot]
+                ids[i, :seq.work_len - covered] = seq.work[covered:]
+                suf_lens[i] = seq.work_len - covered
+                prefix_lens[i] = covered
+                keys[i] = np.asarray(seq.key)
+                temps[i] = float(seq.request.temperature)
+                topks[i] = int(seq.request.top_k)
+            with self._tspan("prefill_launch",
+                             args={"bucket": s_pad, "group": len(group)}):
+                # host arrays pass uncoerced (see _admit_cold): the cost
+                # facade counts the call's real host→device upload bytes
+                nk, nv, tok0s, keys2 = self._suffix_fn()(
+                    self._params, *self.cache.kv_args(), tables,
+                    prefix_lens, ids, suf_lens, keys, temps, topks)
+                self.cache.update(nk, nv)
+                tok0s = np.asarray(tok0s)
+            co = self._co()
+            if co is not None:
+                # sharded suffix prefill: one pass, padded group
+                self._record_collectives(co, [(Gp * s_pad, 1)])
             for i, (seq, matched) in enumerate(group):
                 seq.launches += 1       # rode this bucket's suffix call
                 slot = seq.slot
@@ -1390,96 +1261,11 @@ class ContinuousBatchingEngine:
                                   seq.work_len - seq.prefix_hit_tokens,
                                   finished)
 
-    def _suffix_call(self, s_pad, rows):
-        """ONE suffix-prefill device call for an ``s_pad``-bucket group
-        — THE shared assembly behind the one-shot hit path (dense and
-        paged) and the chunked-prefill path, so their calling
-        conventions can never drift apart. ``rows`` is
-        ``[(seq, offset, n, live)]``: prefill ``prompt[offset:offset+n]``
-        into the sequence's already-claimed slot, whose storage must
-        already cover the span (paged: table blocks installed/appended;
-        dense: matched blocks copied in). Sampling runs only where
-        ``live`` — non-final chunk rows run greedy-off and their output
-        is discarded untouched. Group padding rows carry sentinel
-        tables (paged) / slot ``num_slots`` (dense) and an all-covered
-        prefix, so every one of their writes drops in-program. Returns
-        host ``tok0s`` + device ``keys2``; only live rows' entries are
-        meaningful."""
-        Gp = 1 << (len(rows) - 1).bit_length()
-        if self._paged:
-            mb = self.cache.max_blocks
-            addr = np.full((Gp, mb), self.cache.sentinel, np.int32)
-            prefix_lens = np.full(Gp, mb * self.cache.block_size, np.int32)
-        else:
-            addr = np.full(Gp, self.num_slots, np.int32)   # writes drop
-            prefix_lens = np.full(Gp, self.max_seq_len, np.int32)
-        ids = np.zeros((Gp, s_pad), np.int32)
-        suf_lens = np.ones(Gp, np.int32)
-        temps = np.zeros(Gp, np.float32)
-        topks = np.zeros(Gp, np.int32)
-        keys = np.zeros((Gp, 2), np.uint32)
-        for i, (seq, off, n, live) in enumerate(rows):
-            addr[i] = self.cache.tables[seq.slot] if self._paged \
-                else seq.slot
-            ids[i, :n] = seq.work[off:off + n]
-            suf_lens[i] = n
-            prefix_lens[i] = off
-            keys[i] = np.asarray(seq.key)
-            if live:
-                temps[i] = float(seq.request.temperature)
-                topks[i] = int(seq.request.top_k)
-        # pool arrays in program-argument form: (data, scale) pairs on
-        # an int8 pool, plain arrays otherwise (PagedKVCache.kv_args)
-        kv = self.cache.kv_args()
-        with self._tspan("prefill_launch",
-                         args={"bucket": s_pad, "group": len(rows)}):
-            # host arrays pass uncoerced (see _admit_cold): the cost
-            # facade counts the call's real host→device upload bytes
-            nk, nv, tok0s, keys2 = self._suffix_fn()(
-                self._params, *kv, addr, prefix_lens, ids, suf_lens,
-                keys, temps, topks)
-            self.cache.update(nk, nv)
-            tok0s = np.asarray(tok0s)
-        co = self._co()
-        if co is not None:
-            # sharded suffix/chunk prefill: one pass, padded group
-            self._record_collectives(co, [(Gp * s_pad, 1)])
-        return tok0s, keys2
-
-    def _run_prefill_chunks(self, plan, finished):
-        """Run this step's budgeted slice of the chunked-prefill
-        backlog: ONE paged suffix-prefill device call per chunk-length
-        bucket (normally exactly one — full chunks share the
-        ``prefill_chunk`` bucket, so the compile set stays closed over
-        the pow2 (group, bucket) grid no matter how prompt lengths
-        vary). Each chunk writes K/V through the sequence's block table
-        at its host resume offset — the same program, offset machinery,
-        and zero-copy discipline as the prefix-hit suffix path.
-
-        Only a FINAL chunk (one that completes the prompt) samples:
-        its logits produce token 0 and its split key is adopted, so the
-        PRNG walk — and therefore the token stream — is byte-identical
-        to a one-shot prefill. Non-final chunks run greedy-off rows and
-        their sampled output is discarded untouched."""
-        by_bucket = {}
-        for seq, n in plan:
-            by_bucket.setdefault(self._bucket(n), []).append((seq, n))
-        for s_pad, group in sorted(by_bucket.items()):
-            rows = []
-            for seq, n in group:
-                off = seq.prefilled
-                self.cache.ensure_capacity(seq.slot, off + n)
-                # final chunk (completes the work content): sampling live
-                rows.append((seq, off, n, off + n == seq.work_len))
-            tok0s, keys2 = self._suffix_call(s_pad, rows)
-            for i, (seq, n) in enumerate(group):
-                self._advance_chunk(seq, n, tok0s[i], keys2[i], finished)
-
     def _advance_chunk(self, seq, n, tok0, key0, finished):
-        """Per-chunk completion bookkeeping shared by the two-program
-        chunk call and the unified ragged step — the ONE place chunk
-        accounting and the final-chunk install live, so the two step
-        paths cannot silently diverge. ``tok0``/``key0`` are the chunk
+        """Per-chunk completion bookkeeping shared by the three step
+        variants — the ONE place chunk accounting and the final-chunk
+        install live, so they cannot silently diverge.
+        ``tok0``/``key0`` are the chunk
         row's sampled token + advanced key, consumed only when this
         chunk completes the prompt."""
         slot, end = seq.slot, seq.prefilled + n
@@ -1600,7 +1386,7 @@ class ContinuousBatchingEngine:
         and the sequence's own pins still shield its matched chain from
         eviction during the publish walk.
 
-        Paged + trie: DONATE the slot's full blocks (ownership handoff,
+        With a trie: DONATE the slot's full blocks (ownership handoff,
         zero copies); ``free`` then drops only the undonated private
         tail. The donation range is every row actually written — prompt
         AND generated tokens (a multi-turn resubmission of this
@@ -1608,7 +1394,7 @@ class ContinuousBatchingEngine:
         written row count: the last sampled token's KV is never in the
         cache (it would be appended by the decode tick that never ran),
         and a mid-prefill teardown has only ``prefilled`` valid rows."""
-        if self.prefix_cache is not None and self._paged:
+        if self.prefix_cache is not None:
             with self._tspan("donate", args={"slot": slot}):
                 written = int(self.cache.lengths[slot])
                 content = seq.prompt if not seq.tokens else np.concatenate(
@@ -1616,10 +1402,6 @@ class ContinuousBatchingEngine:
                 donated = self.prefix_cache.publish_donate(
                     content[:written], self.cache.slot_block_ids(slot))
                 self.cache.free(slot, keep=donated)
-        elif self.prefix_cache is not None:
-            with self._tspan("donate", args={"slot": slot}):
-                self.prefix_cache.publish(seq.prompt, slot, self.cache)
-                self.cache.free(slot)
         else:
             self.cache.free(slot)
 
@@ -1647,9 +1429,7 @@ class ContinuousBatchingEngine:
 
     def step(self):
         """Admit + this step's budgeted prefill-chunk grant + decode +
-        retire. On the unified engine (``ragged_step=True``) the grant
-        and the decode tick are ONE device program; on the two-program
-        baseline they are the PR-5 chunk-call + fused-decode-call pair.
+        retire; the grant and the decode tick are ONE device program.
         Returns every sequence this step finished (possibly empty),
         deadline expiries included — queue-side timeouts come back with
         ``slot=None`` and no tokens. Only :meth:`cancel` retires
@@ -1709,10 +1489,8 @@ class ContinuousBatchingEngine:
                     variant = self._spec_step
                 elif self._mtick:
                     variant = self._multitick_step
-                elif self._ragged:
-                    variant = self._unified_step
                 else:
-                    variant = self._two_program_step
+                    variant = self._unified_step
                 step_tokens, chunk_tokens = variant(finished)
                 break
             except PoolExhausted:
@@ -1749,10 +1527,9 @@ class ContinuousBatchingEngine:
             # spans, so Perfetto graphs cost alongside the phases:
             # KV-pool occupancy + table pressure, and (with the cost
             # observatory on) this step's dispatch/transfer deltas
-            if self._paged:
-                tr.counter("kv_blocks", self.cache.occupancy())
-                tr.counter("block_table_fill",
-                           {"fill": round(self.cache.table_fill(), 6)})
+            tr.counter("kv_blocks", self.cache.occupancy())
+            tr.counter("block_table_fill",
+                       {"fill": round(self.cache.table_fill(), 6)})
             if co is not None:
                 d = co.delta(cost0)
                 tr.counter("dispatches",
@@ -1967,12 +1744,12 @@ class ContinuousBatchingEngine:
     def _record_step(self, dt, tokens, had_chunks):
         """Feed the step's measured duration + processed tokens into
         the stats surface (``serving_step_duration_seconds`` /
-        ``serving_step_tokens`` on /metrics read exactly these) and,
-        on the unified engine, into the headroom EWMAs the adaptive
-        chunk budget derives from."""
+        ``serving_step_tokens`` on /metrics read exactly these) and
+        into the headroom EWMAs the adaptive chunk budget derives
+        from."""
         self.stats["last_step_duration_s"] = float(dt)
         self.stats["last_step_tokens"] = int(tokens)
-        if not (self._ragged or self._spec) or tokens <= 0 or dt <= 0:
+        if tokens <= 0 or dt <= 0:
             return
         a = 0.2
         if had_chunks:
@@ -1997,11 +1774,11 @@ class ContinuousBatchingEngine:
         chunk work throttles itself exactly when chunk-carrying steps
         run slower than the decode baseline. Before both EWMAs have a
         measurement — or with ``headroom_mult=None`` — the grant is
-        the fixed cap, i.e. PR-5 pacing; under a SUSTAINED all-chunk
+        the fixed cap; under a SUSTAINED all-chunk
         regime the decode baseline is the last chunk-free step
         measured (decode-only steps are its only feed), so a backlog
-        that never leaves the engine a chunk-free step keeps PR-5
-        pacing rather than inventing a baseline. Sub-block grants are
+        that never leaves the engine a chunk-free step keeps the fixed
+        cap rather than inventing a baseline. Sub-block grants are
         not wasted: the scheduler carries them to the next plan
         (``FIFOScheduler.prefill_plan``)."""
         cap = self._chunk
@@ -2021,11 +1798,9 @@ class ContinuousBatchingEngine:
         """ONE device call for everything this step advances: every
         running slot contributes a span-1 decode row and every planned
         prefill chunk a span-n row to the packed token buffer of the
-        unified ragged program (``decode.build_ragged_step_fn``). This
-        is the whole point of the unification — a mixed step launches
-        one program where the two-program engine launched a chunk call
-        plus a decode call, and a mid-prefill slot costs its chunk span
-        instead of a discarded full-length decode row. Pure-decode
+        unified ragged program (``decode.build_ragged_step_fn``): a
+        mixed step launches one program, and a mid-prefill slot costs
+        its chunk span, not a full-length decode row. Pure-decode
         steps still fuse ``choose_num_steps`` ticks (the scan tail of
         the same program). Returns ``(tokens_processed, chunk_tokens)``
         for the headroom EWMAs and the prefill / decode token counters."""
@@ -2106,8 +1881,8 @@ class ContinuousBatchingEngine:
             self._keys = jnp.where(
                 jnp.asarray(dec_mask[:, None].astype(bool)),
                 keys_fin, self._keys)
-        # chunk bookkeeping first — mirrors the two-program order where
-        # the chunk call ran before the decode ticks surfaced tokens
+        # chunk bookkeeping first: a final chunk's _install_seq emits
+        # its token 0 before this step's decode rows surface theirs
         for slot, seq, ntok, final in chunk_rows:
             self._advance_chunk(seq, ntok, toks_np[0, slot],
                                 keys_t0_np[slot], finished)
@@ -2367,8 +2142,8 @@ class ContinuousBatchingEngine:
             pos[cursor:cursor + ntok] = np.arange(off, off + ntok,
                                                   dtype=np.int32)
             # chunk rows sample (and advance the PRNG) only on their
-            # FINAL chunk — the same rule as the two-program path, so
-            # streams stay byte-identical to a one-shot prefill
+            # FINAL chunk, so streams stay byte-identical to a one-shot
+            # prefill
             keys[slot] = np.asarray(seq.key)
             if final:
                 temps[slot] = float(seq.request.temperature)
@@ -2561,118 +2336,6 @@ class ContinuousBatchingEngine:
                                               in verify_rows]})
             sp.end({"emitted": emitted_total})
         return chunk_spend + emitted_total, chunk_spend
-
-    def _two_program_step(self, finished):
-        """The PR-5 two-program interleave (``ragged_step=False`` and
-        the dense engine): at most one budgeted chunk call, then one
-        fused decode call. Kept intact as the A/B baseline the unified
-        step is pinned byte-identical against. Returns
-        ``(tokens_processed, chunk_tokens)`` as :meth:`_unified_step`."""
-        tr = self._tr()
-        sp = tr.span("plan") if tr is not None else None
-        co = self._co()
-        if co is not None:
-            # the chunk device calls below are this engine's prefill
-            # plan — they attribute to the plan phase, same as the span
-            co.set_phase("plan")
-        plan = []
-        if self._chunk and self.scheduler.num_prefilling:
-            plan = self.scheduler.prefill_plan(self._chunk,
-                                               self.cache.block_size,
-                                               cap=self._chunk)
-            if plan:
-                self._run_prefill_chunks(plan, finished)
-        chunk_tokens = sum(c for _, c in plan)
-        n = 0
-        active = [s for s in self._slots
-                  if s is not None and s.status == "running"]
-        if active:
-            n = self.scheduler.choose_num_steps(active)
-        if tr is not None:
-            # emitted whether or not a decode call follows: a
-            # chunks-only step must still show its plan phase (the
-            # unified/spec paths emit plan unconditionally too). On
-            # this two-program path the span covers the chunk device
-            # calls as well — they ARE this engine's prefill plan.
-            sp.end({"rows": len(active), "chunks": len(plan),
-                    "fused_steps": n})
-        if active:
-            if co is not None:
-                co.set_phase("launch")
-            if tr is not None:
-                launch = tr.span("launch")
-                sp = tr.span("dispatch")
-            if self._paged:
-                # append-block on decode growth: a fused chunk of n
-                # ticks writes rows [len, len+n) per slot, so the table
-                # must cover them BEFORE the device call (block ids are
-                # runtime data — growing them costs no retrace)
-                lens = self.cache.lengths
-                for slot, s in enumerate(self._slots):
-                    if s is not None and s.status == "running":
-                        self.cache.ensure_capacity(
-                            slot, int(lens[slot]) + n)
-                    elif s is not None:
-                        # mid-prefill slot: its table is REAL (prefix +
-                        # installed chunks), so the decode program's
-                        # append must DROP, not land in the block the
-                        # next chunk will write — feed it a length past
-                        # the logical capacity (the program's dead-slot
-                        # clamp) instead of its resume offset. Known
-                        # cost: the slot's discarded attention row runs
-                        # at that full length for the duration of the
-                        # prefill (one array drives both the append
-                        # clamp and the compute gate; skipping the
-                        # compute needs a per-slot active mask in the
-                        # program signature — ROADMAP, rides the
-                        # decode-batch-aware chunk sizing follow-on)
-                        if lens is self.cache.lengths:
-                            lens = lens.copy()
-                        lens[slot] = self.cache.max_blocks * \
-                            self.cache.block_size
-                toks, nk, nv, keys = self._decode_fn(n)(
-                    self._params, self.cache.pool.k, self.cache.pool.v,
-                    self.cache.tables, self._last_tok, lens, self._keys,
-                    self._temps, self._topks)
-            else:
-                toks, nk, nv, keys = self._decode_fn(n)(
-                    self._params, self.cache.k, self.cache.v,
-                    self._last_tok, self.cache.lengths, self._keys,
-                    self._temps, self._topks)
-            self.cache.update(nk, nv)
-            self._keys = keys
-            if tr is not None:
-                sp.end()
-                sp = tr.span("device-wait")
-            toks_np = np.asarray(toks)  # [n, num_slots]
-            if co is not None:
-                co.set_phase("host-accept")
-            if tr is not None:
-                sp.end()
-                launch.end({"fused_steps": n})
-                sp = tr.span("host-accept")
-            self.stats["decode_calls"] += 1
-            self.stats["decode_steps"] += n
-            self.stats["slot_steps"] += n * self.num_slots
-            for s in active:
-                s.launches += 1         # rode this one decode call
-            for i in range(n):
-                for slot in range(self.num_slots):
-                    seq = self._slots[slot]
-                    if seq is None or seq.status != "running":
-                        continue  # freed/mid-prefill slot (or finished
-                        # mid-chunk); its sampled garbage never surfaces
-                    t = int(toks_np[i, slot])
-                    seq.tokens.append(t)
-                    self.cache.lengths[slot] += 1
-                    self._last_tok[slot] = t
-                    self.stats["active_slot_steps"] += 1
-                    self.stats["tokens_generated"] += 1
-                    self._emit(seq, t)
-                    self._maybe_finish(seq, finished)
-            if tr is not None:
-                sp.end({"emitted": n * len(active)})
-        return chunk_tokens + n * len(active), chunk_tokens
 
     def has_work(self) -> bool:
         return bool(self.scheduler.num_queued

@@ -96,8 +96,7 @@ class EngineFleet:
     def __init__(self, model, replicas=2, router="affinity",
                  num_slots=8, max_seq_len=None, decode_chunk=1,
                  max_queue=64, prefix_cache=True, prefix_blocks=None,
-                 prefix_block_size=32, paged_attn=True,
-                 prefill_chunk=512, ragged_step=True, headroom_mult=2.0,
+                 prefix_block_size=32, prefill_chunk=512, headroom_mult=2.0,
                  spec_decode=False, spec_k=4, drafter=None,
                  decode_ticks=1, kv_dtype=None, quantize_weights=False,
                  quantize_activations=False,
@@ -181,8 +180,8 @@ class EngineFleet:
             # way: the fused mega-kernel and the ppermute-chain overlap
             # schedule are different traces of the same step, so
             # replicas differing in either get isolated jit-cache dicts
-            geom = (slots[i], smax[i], chunk[i], bool(paged_attn),
-                    bool(ragged_step), bool(spec_decode), int(spec_k),
+            geom = (slots[i], smax[i], chunk[i],
+                    bool(spec_decode), int(spec_k),
                     int(decode_chunk), int(prefix_block_size),
                     bool(prefix_cache), pblocks[i], int(decode_ticks),
                     kv_dtype, bool(quantize_weights),
@@ -199,8 +198,7 @@ class EngineFleet:
                     prefix_cache=prefix_cache,
                     prefix_blocks=pblocks[i],
                     prefix_block_size=prefix_block_size,
-                    paged_attn=paged_attn, prefill_chunk=chunk[i],
-                    ragged_step=ragged_step,
+                    prefill_chunk=chunk[i],
                     headroom_mult=headroom_mult,
                     spec_decode=spec_decode, spec_k=spec_k,
                     drafter=drafter, decode_ticks=decode_ticks,

@@ -55,20 +55,12 @@ class FleetReplica:
         return self.gateway.health_state
 
     def live_kv_blocks(self) -> int:
-        """Distinct pool blocks live slots reference (paged), or the
-        dense equivalent (active slots × per-slot block budget is
-        meaningless there, so active slots stand in) — the KV half of
+        """Distinct pool blocks live slots reference — the KV half of
         the load signal."""
-        eng = self.gateway.engine
-        if getattr(eng, "_paged", False):
-            return int(eng.cache.occupancy()["live"])
-        return int(eng.num_active)
+        return int(self.gateway.engine.cache.occupancy()["live"])
 
     def free_kv_blocks(self) -> int:
-        eng = self.gateway.engine
-        if getattr(eng, "_paged", False):
-            return int(eng.cache.pool.num_free)
-        return int(eng.cache.num_free)
+        return int(self.gateway.engine.cache.pool.num_free)
 
     def load(self) -> int:
         """The router's load scalar: live KV blocks + waiting-room

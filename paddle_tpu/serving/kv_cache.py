@@ -1,39 +1,22 @@
-"""Slot-based paged KV cache for continuous-batching decode.
+"""Block-table paged KV cache for continuous-batching decode.
 
-The cache is two dense arrays ``[L, num_slots, max_seq_len, Hkv, D]``
-(the paddle cache layout the ragged Pallas decode kernel reads in place,
-``kernels/pallas_decode.py``) plus a host-side ``lengths[num_slots]``
-mirror and a free-slot pool. "Paged" here is at slot granularity — the
-TPU-friendly degenerate page size of one sequence per page: admission
-claims a free slot, finish releases it, and the freed slot's stale rows
-are never touched again (the ragged kernel skips KV blocks past
-``lengths[b]``, so garbage costs no HBM traffic and no zeroing pass).
+:class:`PagedKVCache` ("Ragged Paged Attention", PAPERS.md): the
+:class:`~.block_manager.BlockManager` pool IS the cache — there is no
+per-slot dense array. Each live slot owns a row of a host block table
+``[num_slots, max_blocks]`` naming the physical pool blocks that spell
+its logical cache; prefix-cache hits install by *referencing* published
+block ids (no copy — N holders share one block), decode growth appends
+fresh private blocks lazily, and retirement *donates* full blocks to the
+trie. Admission claims a free slot, finish releases it, and a freed
+block's stale rows are never read (the kernels walk a row's own length).
 
-The device arrays are functionally updated (donated through the jitted
-writers on non-CPU backends, so XLA updates in place); the host mirror is
-the scheduling truth — device-side lengths are always re-fed from it, so
-a freed slot resets by writing one host int, not a device op.
-
-Block copy programs (the prefix-cache transport, ``serving/prefix_cache``):
-``copy_block_in`` installs one published pool block into a slot's rows and
-``copy_block_out`` publishes one slot block into the pool. Both are single
-compile-once jitted programs — shapes depend only on the cache/pool
-geometry; the slot / row / block indices are runtime scalars — so cache
-hits, evictions, and publishes never add traces.
-
-:class:`PagedKVCache` is the zero-copy successor ("Ragged Paged
-Attention", PAPERS.md): the :class:`~.block_manager.BlockManager` pool
-IS the cache — there is no per-slot dense array at all. Each live slot
-owns a row of a host block table ``[num_slots, max_blocks]`` naming the
-physical pool blocks that spell its logical cache; prefix-cache hits
-install by *referencing* published block ids (no ``copy_block_in``
-dispatch, no private copy — N holders share one block), decode growth
-appends fresh private blocks lazily, and retirement *donates* full
-prompt blocks to the trie instead of copying them out. The same
-alloc/free/``write_prefill`` surface as :class:`SlotKVCache` keeps the
-engine's cold path identical; the decode/suffix programs read the pool
-through runtime table arguments (``serving/decode.py``), so the
-compile-once contract survives unchanged.
+The pool's device arrays are functionally updated (donated through the
+jitted writers on non-CPU backends, so XLA updates in place); the host
+``lengths`` / ``tables`` mirrors are the scheduling truth — device-side
+lengths and tables are re-fed from them every step, so a freed slot
+resets by writing host ints, not a device op. The step programs read the
+pool through runtime table arguments (``serving/decode.py``), so table
+growth, hits and evictions never add traces.
 """
 from __future__ import annotations
 
@@ -112,15 +95,6 @@ def quantize_kv_rows_fp8(x):
     return jnp.clip(xf, -FP8_MAX, FP8_MAX).astype(jnp.float8_e4m3fn)
 
 
-def _write_prefill(cache_k, cache_v, pk, pv, slot):
-    # pk/pv: [L, S_pad, Hkv, D] -> one slot's leading rows. Rows past the
-    # real prompt length hold prefill padding garbage; they sit beyond
-    # lengths[slot] (masked) until the decode loop overwrites them.
-    ck = jax.lax.dynamic_update_slice(cache_k, pk[:, None], (0, slot, 0, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache_v, pv[:, None], (0, slot, 0, 0, 0))
-    return ck, cv
-
-
 def _block_slice(arr, block_id):
     # one block of a pool array, rank-generic: the data [L, nb, bs, KD],
     # int8's per-row planes [L, nb, bs, Hkv] and fp8's per-block planes
@@ -133,29 +107,6 @@ def _block_slice(arr, block_id):
 def _block_update(arr, block, block_id):
     return jax.lax.dynamic_update_slice(
         arr, block, (0, block_id) + (0,) * (arr.ndim - 2))
-
-
-def _copy_block_in(cache_k, cache_v, pool_k, pool_v, slot, row0, block_id):
-    # pool block [L, 1, bs, Hkv * D] -> cache rows [row0, row0+bs) of slot
-    # (the dense cache keeps its heads apart: the block is reshaped, never
-    # the pool)
-    bk, bv = _block_slice(pool_k, block_id), _block_slice(pool_v, block_id)
-    to = bk.shape[:3] + cache_k.shape[3:]
-    ck = jax.lax.dynamic_update_slice(cache_k, bk.reshape(to),
-                                      (0, slot, row0, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache_v, bv.reshape(to),
-                                      (0, slot, row0, 0, 0))
-    return ck, cv
-
-
-def _copy_block_out(pool_k, pool_v, cache_k, cache_v, slot, row0, block_id):
-    # cache rows [row0, row0+bs) of slot -> pool block (publish)
-    L, _, bs, KD = pool_k.shape
-    size = (L, 1, bs) + cache_k.shape[3:]
-    bk = jax.lax.dynamic_slice(cache_k, (0, slot, row0, 0, 0), size)
-    bv = jax.lax.dynamic_slice(cache_v, (0, slot, row0, 0, 0), size)
-    return (_block_update(pool_k, bk.reshape(L, 1, bs, KD), block_id),
-            _block_update(pool_v, bv.reshape(L, 1, bs, KD), block_id))
 
 
 def _prefill_scatter_coords(pool_k, pk, table_row, prompt_len):
@@ -219,14 +170,6 @@ def _paged_write_prefill_f8(pool_k, pool_v, pk, pv, table_row,
 
 
 @functools.lru_cache(maxsize=None)
-def _writer(donate):
-    # module-level so every cache instance (one per engine, one engine
-    # per model.generate call) shares the jitted program instead of
-    # re-tracing it
-    return jax.jit(_write_prefill, donate_argnums=(0, 1) if donate else ())
-
-
-@functools.lru_cache(maxsize=None)
 def _paged_writer(donate, quantized=False, tp=1):
     # donate the POOL arrays (the pool is the cache being updated);
     # the int8 writer donates the scale planes too. ``quantized`` is
@@ -269,32 +212,11 @@ def _paged_writer(donate, quantized=False, tp=1):
     return jax.jit(impl, donate_argnums=(0, 1) if donate else ())
 
 
-@functools.lru_cache(maxsize=None)
-def _block_in(donate):
-    # donate the CACHE arrays (they are the ones functionally updated)
-    return jax.jit(_copy_block_in, donate_argnums=(0, 1) if donate else ())
-
-
-@functools.lru_cache(maxsize=None)
-def _block_out(donate):
-    # donate the POOL arrays (publish updates the pool in place)
-    return jax.jit(_copy_block_out, donate_argnums=(0, 1) if donate else ())
-
-
-def copy_compilations() -> int:
-    """Total traces of the block copy programs (both donate modes) — the
-    prefix-cache half of the bounded-compile contract: stays at one per
-    (geometry, donate) no matter how many hits/publishes run."""
-    return sum(fn._cache_size()
-               for fn in (_block_in(True), _block_in(False),
-                          _block_out(True), _block_out(False)))
-
-
 # ------------------------------------------------- tier transfer programs
 # The host-RAM spill tier's device side (README "Tiered KV prefix
 # cache"): fetch slices one pool block out for the d2h spill, inject
 # scatters a readmitted block back. Same compile-once rule as the block
-# copy programs above: the block id is a runtime np.int32 scalar
+# prefill writers above: the block id is a runtime np.int32 scalar
 # (dynamic_slice / dynamic_update_slice), so one trace per (quantized,
 # tp[, donate]) serves every block — a python-int index would bake into
 # the dispatch-cache key and compile once per block id.
@@ -380,115 +302,12 @@ def tier_compilations() -> int:
     return sum(fn._cache_size() for fn in list(_TIER_PROGRAMS))
 
 
-class SlotKVCache:
-    """Dense per-slot KV cache — the LEGACY compatibility path.
-
-    :class:`PagedKVCache` is the engine default (``paged_attn=True``):
-    it subsumes this layout's whole job with zero-copy prefix sharing
-    and block-granular HBM, and the chunked-prefill scheduler exists
-    only on it. SlotKVCache stays as the ``paged_attn=False`` shim —
-    token-identical, one dense ``[L, num_slots, max_seq_len, Hkv, D]``
-    array pair, one-shot prefill only — for A/B pinning in tests and
-    for backends where the table-gather pattern is hostile. No new
-    features land here.
-
-    The free-slot pool is a min-heap plus a membership set: ``alloc`` is
-    O(log n) and still deterministic (lowest index first), ``free``'s
-    double-free guard is O(1) — the seed version's ``slot in list`` scan
-    plus sort-on-alloc was O(n)/O(n log n) per admission.
-    """
-
-    def __init__(self, num_layers, num_slots, max_seq_len, num_kv_heads,
-                 head_dim, dtype=jnp.float32, donate=None):
-        self.num_slots = int(num_slots)
-        self.max_seq_len = int(max_seq_len)
-        shape = (num_layers, num_slots, max_seq_len, num_kv_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
-        # host mirror is the source of truth; device lengths are re-fed
-        # from it every step
-        self.lengths = np.zeros(num_slots, np.int32)
-        self._free_heap = list(range(num_slots))  # already heap-ordered
-        self._free_set = set(self._free_heap)
-        if donate is None:
-            # donation is a no-op (warning) on CPU; an in-place cache
-            # update is the whole point everywhere else
-            donate = jax.default_backend() != "cpu"
-        self._donate = bool(donate)
-        self._write = _writer(self._donate)
-
-    # ------------------------------------------------------------- slots
-    @property
-    def num_free(self) -> int:
-        return len(self._free_set)
-
-    def alloc(self):
-        """Claim a free slot (lowest index first, deterministic)."""
-        if not self._free_set:
-            return None
-        slot = heapq.heappop(self._free_heap)
-        self._free_set.discard(slot)
-        return slot
-
-    def free(self, slot: int):
-        if slot in self._free_set:
-            raise ValueError(f"slot {slot} double-freed")
-        self.lengths[slot] = 0
-        heapq.heappush(self._free_heap, slot)
-        self._free_set.add(slot)
-
-    # ------------------------------------------------------------ writes
-    def write_prefill(self, slot, pk, pv, prompt_len):
-        """Install a prefilled prompt's K/V into ``slot``."""
-        if pk.shape[1] > self.max_seq_len:
-            raise ValueError(
-                f"prefill length {pk.shape[1]} exceeds max_seq_len "
-                f"{self.max_seq_len}")
-        self.k, self.v = self._write(self.k, self.v, pk, pv, np.int32(slot))
-        self.lengths[slot] = int(prompt_len)
-
-    def update(self, new_k, new_v):
-        """Adopt the decode step's functionally-updated cache arrays."""
-        self.k, self.v = new_k, new_v
-
-    def kv_args(self):
-        """The cache arrays as the suffix program takes them — the
-        dense twin of :meth:`PagedKVCache.kv_args` (always plain
-        ``(k, v)``: the dense shim never quantizes)."""
-        return self.k, self.v
-
-    def slot_kv_bytes(self, slot) -> int:
-        """HBM bytes of the slot's valid rows (rows × per-row bytes) —
-        the dense twin of :meth:`PagedKVCache.slot_kv_bytes` for the
-        ``/debug/requests`` cost column."""
-        per_row = (2 * self.k.size * np.dtype(self.k.dtype).itemsize
-                   // (self.num_slots * self.max_seq_len))
-        return int(self.lengths[slot]) * per_row
-
-    # ------------------------------------------------------ block copies
-    def copy_block_in(self, slot, row0, pool, block_id):
-        """Install pool block ``block_id`` into rows [row0, row0+bs) of
-        ``slot`` (a prefix-cache hit). One jitted program total — the
-        three indices are runtime scalars."""
-        self.k, self.v = _block_in(self._donate)(
-            self.k, self.v, pool.k, pool.v, np.int32(slot),
-            np.int32(row0), np.int32(block_id))
-
-    def copy_block_out(self, slot, row0, pool, block_id):
-        """Publish rows [row0, row0+bs) of ``slot`` into pool block
-        ``block_id`` (sequence retirement). One jitted program total."""
-        pool.k, pool.v = _block_out(self._donate)(
-            pool.k, pool.v, self.k, self.v, np.int32(slot),
-            np.int32(row0), np.int32(block_id))
-
-
 class PagedKVCache:
     """Block-table KV cache: slot allocator + host tables over a shared
     :class:`~.block_manager.BlockManager` pool — the zero-copy decode
-    cache (module docstring). Surface-compatible with
-    :class:`SlotKVCache` where the engine's cold path needs it
-    (``alloc``/``free``/``num_free``/``lengths``/``write_prefill``/
-    ``update``); the paged-only surface is table bookkeeping:
+    cache (module docstring). Beside the slot surface (``alloc``/
+    ``free``/``num_free``/``lengths``/``write_prefill``/``update``) it
+    keeps the tables:
 
     - ``install_prefix(slot, block_ids)`` — a prefix-cache hit:
       reference the published blocks in the slot's table. No copy; the

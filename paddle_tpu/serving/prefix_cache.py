@@ -16,33 +16,22 @@ work for every prompt block some earlier request already computed:
 - **Acquire/release**: matched blocks are ref-pinned for the sequence's
   lifetime (a pinned block can't be evicted out from under a later
   publish dedupe); retirement releases the pins.
-- **Publish**: on retirement every full *prompt* block not already in
-  the trie is copied slot→pool (``kv_cache.copy_block_out``, one jitted
-  program) and inserted. Pool pressure evicts LRU zero-ref leaf blocks
-  first; if the pool is exhausted by pinned blocks the remaining
-  publishes are skipped, never failed — the cache degrades to fewer
-  hits, not errors.
-- **Copy-on-install (the dense COW discipline)**: on the dense engine a
-  hit COPIES its matched blocks into the sequence's private slot
-  (``copy_block_in``), so pool blocks are write-once/read-many and two
-  sequences sharing a prefix can diverge freely — their decode appends
-  land in their own slots. At slot granularity install-copy is the
-  aliasing-safe form of COW.
-- **Zero-copy install + donation (the paged engine)**: with block-table
-  paged attention (:class:`~.kv_cache.PagedKVCache`) a hit installs by
-  *referencing* the matched block ids in the sequence's table — no
-  device dispatch at all — and N concurrent holders physically share
-  one block (refcount = N readers). Divergent continuations are still
-  safe: every write lands at a logical row >= the covered prefix, which
-  maps to a privately-owned tail block, never a shared one. Retirement
-  publishes by :meth:`publish_donate` — full prompt blocks already
-  sitting in the sequence's private tail are ADOPTED by the trie
-  in place (ownership handoff, no ``copy_block_out``), so the paged
-  path runs the whole hit/publish lifecycle with zero copy dispatches.
-  Donation covers *generated* full blocks too, not just prompt blocks —
-  the decode loop wrote them through the same table into the same
-  private tail, so adopting them is equally free, and a multi-turn
-  resubmission of an assistant turn hits that turn's own blocks.
+- **Zero-copy install**: a hit installs by *referencing* the matched
+  block ids in the sequence's block table
+  (:class:`~.kv_cache.PagedKVCache`) — no device dispatch at all — and
+  N concurrent holders physically share one block (refcount = N
+  readers). Divergent continuations are safe: every write lands at a
+  logical row >= the covered prefix, which maps to a privately-owned
+  tail block, never a shared one.
+- **Publish by donation**: retirement publishes by
+  :meth:`PrefixCache.publish_donate` — full blocks already sitting in
+  the sequence's private tail are ADOPTED by the trie in place
+  (ownership handoff, no copy). Donation covers *generated* full blocks
+  too, not just prompt blocks — the decode loop wrote them through the
+  same table into the same private tail, so adopting them is equally
+  free, and a multi-turn resubmission of an assistant turn hits that
+  turn's own blocks. The trie's residency is capped by ``max_blocks``:
+  adopt first, then evict LRU zero-ref leaves down to the budget.
   PREEMPTION rides the same path (``engine._preempt``, README "Fault
   tolerance & chaos testing"): a sequence displaced under pool
   pressure donates its written chain exactly like retirement, so its
@@ -67,10 +56,9 @@ work for every prompt block some earlier request already computed:
   the replica about to need it (``fleet/fleet.py``).
 
 Compile discipline: lookups/inserts/evictions are pure host work; the
-only device programs are the two block-copy programs (compile-once, see
-``kv_cache.py``), the tier fetch/inject pair (compile-once for the same
-reason — runtime-scalar block ids, ``kv_cache.tier_compilations``) and
-the bucketed suffix prefill (``decode.py``), so the engine's
+only device programs are the tier fetch/inject pair (compile-once —
+runtime-scalar block ids, ``kv_cache.tier_compilations``) and the
+bucketed suffix prefill (``decode.py``), so the engine's
 ``decode_compilations() == 1`` contract survives any mix of hits,
 misses, evictions, spills, readmissions, and divergence.
 """
@@ -268,11 +256,9 @@ class PrefixCache:
     def __init__(self, pool, max_blocks=None, host_tier_bytes=0):
         self.pool = pool
         self.block_size = pool.block_size
-        # trie residency budget. On the dense engine the pool IS the
-        # budget (publish allocates from it, exhaustion evicts). On the
-        # paged engine the pool also backs live KV, so donation enforces
-        # this explicit cap instead: adopt first, then evict LRU down to
-        # budget. None = bounded by the pool alone.
+        # trie residency budget: the pool also backs live KV, so
+        # donation enforces this explicit cap: adopt first, then evict
+        # LRU down to budget. None = bounded by the pool alone.
         self.max_blocks = None if max_blocks is None else int(max_blocks)
         # host-RAM spill tier (README "Tiered KV prefix cache"): 0
         # (default) keeps eviction = deletion, byte-identical to every
@@ -300,7 +286,7 @@ class PrefixCache:
         self.stats = {"lookups": 0, "hits": 0, "misses": 0,
                       "hit_blocks": 0, "hit_tokens": 0,
                       "published_blocks": 0, "evictions": 0,
-                      "skipped_publishes": 0, "donated_blocks": 0,
+                      "donated_blocks": 0,
                       "spilled_blocks": 0, "tier_hits": 0,
                       "readmitted_blocks": 0, "tier_evictions": 0,
                       "tier_transfers": 0}
@@ -453,46 +439,8 @@ class PrefixCache:
         return matched
 
     # ------------------------------------------------------------ publish
-    def publish(self, prompt, slot, kv_cache):
-        """Insert every full prompt block into the trie, copying
-        slot→pool for blocks not already cached. Runs at retirement,
-        BEFORE the sequence's pins are released, so its own matched
-        chain can't be evicted mid-publish. Under pool pressure evicts
-        LRU zero-ref leaves; skips (never fails) when nothing is
-        evictable."""
-        prompt = np.asarray(prompt).reshape(-1)
-        bs = self.block_size
-        children, parent = self._root, None
-        tick = next(self._tick)
-        walked = []  # this walk's own chain, pinned against its evictions
-        try:
-            for i, key in enumerate(self._blocks_of(prompt, len(prompt))):
-                node = children.get(key)
-                if node is None:
-                    block = self.pool.alloc()
-                    if block is None and self._evict_one():
-                        block = self.pool.alloc()
-                    if block is None:  # everything pinned: degrade, not fail
-                        self.stats["skipped_publishes"] += 1
-                        return
-                    kv_cache.copy_block_out(slot, i * bs, self.pool, block)
-                    node = _Node(key, parent, block)
-                    children[key] = node
-                    self._nodes += 1
-                    self.stats["published_blocks"] += 1
-                node.tick = tick
-                # pin the chain-so-far: a later block's eviction pass must
-                # never reap an earlier link of the chain being published
-                # (it is zero-ref until someone matches it)
-                self.pool.ref(node.block_id)
-                walked.append(node)
-                children, parent = node.children, node
-        finally:
-            for node in walked:
-                self.pool.unref(node.block_id)
-
     def publish_donate(self, tokens, block_ids):
-        """Paged-path publish: insert every full token block by
+        """Publish: insert every full token block by
         ADOPTING the retiring sequence's own pool block — an ownership
         handoff, zero copy dispatches. ``tokens`` is the sequence's
         WRITTEN row content — the prompt plus every generated token
@@ -510,7 +458,7 @@ class PrefixCache:
         dropping them. Blocks whose token content is already cached are
         NOT adopted (the existing node wins; the duplicate stays in the
         caller's tail and is freed with it). Needs no allocation, so it
-        can never evict, skip, or fail — the paged publish degrades to
+        can never evict, skip, or fail — the publish degrades to
         "nothing new to donate", never to lost work."""
         tokens = np.asarray(tokens).reshape(-1)
         children, parent = self._root, None
@@ -563,10 +511,9 @@ class PrefixCache:
         its still-resident descendants); the refcount invariant
         ref(parent) >= ref(child) guarantees a zero-ref leaf exists
         whenever any zero-ref node does. One O(trie) min pass per
-        eviction — the trie is bounded by the pool (and, on the paged
-        engine, the ``max_blocks`` budget). Evictions fire on
-        publish-under-pressure (dense), on the post-donation budget trim
-        (paged), and on paged decode-growth when live allocation finds
+        eviction — the trie is bounded by the pool and the
+        ``max_blocks`` budget. Evictions fire on the post-donation
+        budget trim, and on decode growth when live allocation finds
         the pool dry (``PagedKVCache._alloc_block`` — rare while the
         budget holds trie residency under the pool's live headroom).
         """

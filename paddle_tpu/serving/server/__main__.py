@@ -183,45 +183,32 @@ def main(argv=None):
                          "evicted chains spill d2h and readmit on a hit; "
                          "with --replicas the per-replica tiers form the "
                          "fleet cache plane (/fleet/cacheplane)")
-    ap.add_argument("--paged-attn", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="block-table paged attention (DEFAULT: the block "
-                         "pool IS the KV cache, prefix hits install "
-                         "zero-copy and concurrent holders share physical "
-                         "blocks); --no-paged-attn selects the legacy "
-                         "dense per-slot cache")
     ap.add_argument("--prefill-chunk", type=int, default=512,
                     help="chunked prefill: max prompt tokens prefilled "
-                         "per engine step (paged engine only; bounds TTFT "
-                         "under mixed traffic; 0 disables)")
-    ap.add_argument("--ragged-step", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="unified ragged step (DEFAULT, paged only): "
-                         "decode rows + prefill chunks ride ONE device "
-                         "program per step; --no-ragged-step keeps the "
-                         "two-program chunk+decode interleave")
+                         "per engine step (bounds TTFT under mixed "
+                         "traffic; 0 disables)")
     ap.add_argument("--headroom-mult", type=float, default=2.0,
                     help="adaptive chunk budget: grant ~this many "
                          "decode-steps' worth of measured throughput to "
-                         "prefill chunks per step (unified step only; "
-                         "0 pins the fixed prefill-chunk cap)")
+                         "prefill chunks per step (0 pins the fixed "
+                         "prefill-chunk cap)")
     ap.add_argument("--decode-ticks", type=int, default=1,
-                    help="multi-tick decode (unified ragged engine "
-                         "only): fuse up to this many on-device decode "
+                    help="multi-tick decode: fuse up to this many "
+                         "on-device decode "
                          "ticks behind ONE host sync when every "
                          "running slot is in pure decode — EOS/budget "
                          "cuts are masked on device, streams stay "
                          "byte-identical, and the host round-trip "
                          "amortizes n-fold (tokens stream in bursts "
                          "of up to n). Mixed traffic clamps back to "
-                         "single-tick. 1 = off (the baseline)")
+                         "single-tick. 1 = off")
     ap.add_argument("--kv-dtype", choices=("pool", "int8", "fp8"),
                     default="pool",
                     help="KV cache storage dtype (README 'Quantized "
                          "serving'): 'pool' stores at the model dtype "
-                         "(the default — every banked baseline), "
+                         "(the default), "
                          "'int8' serves from the block-quantized pool "
-                         "(unified ragged paged engine only; appends "
+                         "(appends "
                          "quantize on write, the attention kernels "
                          "upcast in-register after the table-indirect "
                          "DMA, ~4x pool HBM cut vs fp32 = ~4x "
@@ -241,36 +228,33 @@ def main(argv=None):
     ap.add_argument("--quantize-activations",
                     action=argparse.BooleanOptionalAction, default=False,
                     help="int8xint8 decode projections (requires "
-                         "--quantize-weights; unified ragged paged "
-                         "engine only): quantize each projection input "
+                         "--quantize-weights): quantize each projection input "
                          "per-row at runtime and contract int8 against "
                          "the int8 weights with int32 accumulate — the "
                          "per-layer weight dequant disappears from the "
-                         "decode step entirely (greedy divergence "
-                         "measured in DENSITY_BENCH.json, not assumed)")
+                         "decode step entirely (greedy streams may "
+                         "diverge from full precision)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree (README 'Tensor-"
                          "parallel serving'): shard every serving "
                          "program over this many devices on a heads-"
                          "sharded mesh with the paged KV pool "
-                         "partitioned per shard (unified ragged paged "
-                         "engine only; must divide the model's head "
-                         "counts). On CPU set XLA_FLAGS="
+                         "partitioned per shard (must divide the "
+                         "model's head counts). On CPU set XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N "
-                         "before launch. 1 = single-chip (the "
-                         "baseline)")
+                         "before launch. 1 = single-chip")
     ap.add_argument("--collective-dtype", choices=("fp", "int8"),
                     default="fp",
                     help="wire dtype of the per-layer tensor-parallel "
                          "all-reduce: 'fp' is a plain psum, 'int8' "
                          "runs it EQuARX-style block-quantized (~3.5x "
-                         "fewer cross-chip bytes; greedy divergence "
-                         "measured in TP_BENCH.json, not assumed). "
+                         "fewer cross-chip bytes; greedy streams may "
+                         "diverge from the fp wire). "
                          "Ignored (no collectives) at --tp 1")
     ap.add_argument("--fused-tick", action=argparse.BooleanOptionalAction,
                     default=False,
-                    help="one-kernel decode (unified ragged paged "
-                         "engine only; README 'One-kernel decode'): "
+                    help="one-kernel decode (README 'One-kernel "
+                         "decode'): "
                          "run the decode tick's entire layer stack as "
                          "ONE Pallas program with the layer loop as "
                          "the grid dimension — a tick is O(1) device "
@@ -290,7 +274,7 @@ def main(argv=None):
                          "ledger stay exact, streams byte-identical")
     ap.add_argument("--spec-decode", action=argparse.BooleanOptionalAction,
                     default=False,
-                    help="speculative multi-token decode (paged only): "
+                    help="speculative multi-token decode: "
                          "a prompt-lookup n-gram drafter proposes up to "
                          "--spec-k tokens per slot, one ragged-span "
                          "verify scores them, rejected KV rolls back by "
@@ -384,8 +368,7 @@ def main(argv=None):
             prefix_blocks=args.prefix_blocks,
             prefix_block_size=args.prefix_block_size,
             host_tier_bytes=args.host_tier_bytes,
-            paged_attn=args.paged_attn, prefill_chunk=args.prefill_chunk,
-            ragged_step=args.ragged_step,
+            prefill_chunk=args.prefill_chunk,
             headroom_mult=args.headroom_mult or None,
             spec_decode=args.spec_decode, spec_k=args.spec_k,
             decode_ticks=args.decode_ticks, kv_dtype=kv_dtype,
@@ -411,7 +394,6 @@ def main(argv=None):
             "num_slots": [r.gateway.engine.num_slots
                           for r in fleet.replicas],
             "prefix_cache": bool(args.prefix_cache),
-            "paged_attn": bool(args.paged_attn),
             "prefill_chunk": [r.gateway.engine.prefill_chunk
                               for r in fleet.replicas],
             "spec_decode": fleet.replicas[0].gateway.engine.spec_decode,
@@ -466,8 +448,7 @@ def main(argv=None):
         prefix_cache=args.prefix_cache, prefix_blocks=args.prefix_blocks,
         prefix_block_size=args.prefix_block_size,
         host_tier_bytes=args.host_tier_bytes,
-        paged_attn=args.paged_attn, prefill_chunk=args.prefill_chunk,
-        ragged_step=args.ragged_step,
+        prefill_chunk=args.prefill_chunk,
         headroom_mult=args.headroom_mult or None,
         spec_decode=args.spec_decode, spec_k=args.spec_k,
         decode_ticks=args.decode_ticks, kv_dtype=kv_dtype,
@@ -488,15 +469,10 @@ def main(argv=None):
                       **_runtime_doc(server.gateway.engine),
                       "num_slots": slots[0],
                       "prefix_cache": bool(args.prefix_cache),
-                      "paged_attn": bool(args.paged_attn),
                       # report what actually runs: the engine's
-                      # block-rounded chunk, 0 when chunking is off or
-                      # the dense engine ignores it
+                      # block-rounded chunk, 0 when chunking is off
                       "prefill_chunk":
                       server.gateway.engine.prefill_chunk,
-                      # report what actually runs: the dense engine
-                      # ignores --ragged-step
-                      "ragged_step": server.gateway.engine.ragged_step,
                       "spec_decode": server.gateway.engine.spec_decode,
                       "spec_k": server.gateway.engine.spec_k,
                       # report what actually runs: the engine's

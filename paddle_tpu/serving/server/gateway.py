@@ -98,7 +98,7 @@ from ..faults import TransientFault
 #: rebuilding re-bases only its own series (the others never move) —
 #: a fleet scrape can never observe a counter going backwards.
 CARRIED_ENGINE_STATS = (
-    "preemptions", "policy_preemptions", "prefill_copy_dispatches",
+    "preemptions", "policy_preemptions",
     "prefill_chunks", "prefill_tokens_saved", "spec_proposed",
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
@@ -472,19 +472,13 @@ class ServingGateway:
                           ).set_fn(lambda k="moe_" + stat: self._stat(k))
         r.gauge("serving_decode_compilations",
                 "Decode-program traces (compile-once contract: stays at "
-                "one per (num_slots, max_seq_len, n_steps)).").set_fn(
+                "one per (num_slots, token_budget, n_steps)).").set_fn(
             self.engine.decode_compilations)
-        r.counter("serving_prefill_copy_dispatches_total",
-                  "Block copy-in dispatches spent installing prefix "
-                  "hits (dense engine only; the paged path pins this "
-                  "at 0 — hits install by reference). Monotonic "
-                  "across engine rebuilds.").set_fn(
-            lambda: self._stat("prefill_copy_dispatches"))
         r.counter("serving_prefill_chunks_total",
                   "Chunked-prefill device chunks run (one per sequence "
                   "per step while a long cold prompt is interleaved "
-                  "with decode; 0 with chunking off or on the dense "
-                  "engine). Monotonic across engine rebuilds.").set_fn(
+                  "with decode; 0 with chunking off). Monotonic "
+                  "across engine rebuilds.").set_fn(
             lambda: self._stat("prefill_chunks"))
         # per-step telemetry: the SAME duration/token measurements the
         # engine's headroom EWMAs (adaptive chunk budget) read — the
@@ -608,53 +602,50 @@ class ServingGateway:
                 "supervisor's hung-step signal; an orchestrator's "
                 "external liveness probe for a step that never "
                 "returns).").set_fn(self.last_step_age)
-        # paged/prefix gauges read THROUGH self.engine at scrape time:
+        # pool/prefix gauges read THROUGH self.engine at scrape time:
         # a recovery rebuild swaps the engine (and its cache/pool/trie)
         # underneath the registry, and the gauges must follow it rather
         # than keep reporting a dead engine's bookkeeping
-        if getattr(self.engine, "_paged", False) \
-                and getattr(self.engine, "cache", None) is not None:
-            # paged-attention surface: physical sharing + table pressure
-            # (scrape-time reads of host bookkeeping; driver is the only
-            # writer, a scrape reads ints under the GIL)
-            r.gauge("kv_blocks_shared",
-                    "Pool blocks physically shared by concurrent "
-                    "readers (refcount >= 2) — the zero-copy win."
-                    ).set_fn(lambda: self.engine.cache.pool.num_shared)
-            r.gauge("kv_block_table_fill",
-                    "Fraction of the [num_slots, max_blocks] block "
-                    "table grid populated by live sequences."
-                    ).set_fn(lambda: self.engine.cache.table_fill())
-            # quantized-serving surface (README "Quantized serving"):
-            # pool HBM in BYTES, dtype-aware via
-            # PagedKVCache.occupancy_bytes() — an int8 pool reports
-            # int8 data bytes under kind="kv" plus its fp32 scale
-            # planes under kind="scales" (0 on the default pool), and
-            # the per-cached-token marginal HBM cost the density bench
-            # banks against. Allocated (live + trie) blocks x
-            # per-block bytes.
-            kvb = r.gauge(
-                "kv_pool_bytes",
-                "Allocated KV pool HBM bytes by storage kind (kv = "
-                "block data at the pool dtype, scales = the int8 "
-                "pool's fp32 scale planes; 0 when unquantized).")
-            # each kind scans the block tables once (used_blocks);
-            # per-token is pure constants — a scrape pays two cheap
-            # scans total, never three occupancy_bytes() walks
-            kvb.set_fn(
-                lambda: (self.engine.cache.used_blocks()
-                         * self.engine.cache.pool.block_nbytes),
-                kind="kv")
-            kvb.set_fn(
-                lambda: (self.engine.cache.used_blocks()
-                         * self.engine.cache.pool.scale_block_nbytes),
-                kind="scales")
-            r.gauge("serving_kv_bytes_per_token",
-                    "Marginal HBM bytes one cached token costs (block "
-                    "bytes incl. scale planes / block_size) — the "
-                    "denominator of the quantized-density win."
-                    ).set_fn(
-                lambda: self.engine.cache.bytes_per_token())
+        # paged-attention surface: physical sharing + table pressure
+        # (scrape-time reads of host bookkeeping; driver is the only
+        # writer, a scrape reads ints under the GIL)
+        r.gauge("kv_blocks_shared",
+                "Pool blocks physically shared by concurrent "
+                "readers (refcount >= 2) — the zero-copy win."
+                ).set_fn(lambda: self.engine.cache.pool.num_shared)
+        r.gauge("kv_block_table_fill",
+                "Fraction of the [num_slots, max_blocks] block "
+                "table grid populated by live sequences."
+                ).set_fn(lambda: self.engine.cache.table_fill())
+        # quantized-serving surface (README "Quantized serving"):
+        # pool HBM in BYTES, dtype-aware via
+        # PagedKVCache.occupancy_bytes() — an int8 pool reports
+        # int8 data bytes under kind="kv" plus its fp32 scale
+        # planes under kind="scales" (0 on the default pool), and
+        # the per-cached-token marginal HBM cost. Allocated (live +
+        # trie) blocks x per-block bytes.
+        kvb = r.gauge(
+            "kv_pool_bytes",
+            "Allocated KV pool HBM bytes by storage kind (kv = "
+            "block data at the pool dtype, scales = the int8 "
+            "pool's fp32 scale planes; 0 when unquantized).")
+        # each kind scans the block tables once (used_blocks);
+        # per-token is pure constants — a scrape pays two cheap
+        # scans total, never three occupancy_bytes() walks
+        kvb.set_fn(
+            lambda: (self.engine.cache.used_blocks()
+                     * self.engine.cache.pool.block_nbytes),
+            kind="kv")
+        kvb.set_fn(
+            lambda: (self.engine.cache.used_blocks()
+                     * self.engine.cache.pool.scale_block_nbytes),
+            kind="scales")
+        r.gauge("serving_kv_bytes_per_token",
+                "Marginal HBM bytes one cached token costs (block "
+                "bytes incl. scale planes / block_size) — the "
+                "denominator of the quantized-density win."
+                ).set_fn(
+            lambda: self.engine.cache.bytes_per_token())
         if getattr(self.engine, "prefix_cache", None) is not None:
             # scrape-time counters backed by the cache's own stats plus
             # the gateway's carried base (the driver thread is the only
@@ -776,8 +767,7 @@ class ServingGateway:
                     cdt), dtype=cdt)
             # KV-tier cache-plane traffic by direction — the same
             # separate-ledger rule as collectives: spill/readmit bytes
-            # never land in the per-program h2d/d2h records, so the
-            # banked DISPATCH_BENCH.json baselines stay clean.
+            # never land in the per-program h2d/d2h records.
             # Registered up front for all three directions so tierless
             # engines scrape explicit zeros.
             tier = r.counter(
@@ -799,8 +789,7 @@ class ServingGateway:
             r.gauge("serving_dispatches_per_decoded_token",
                     "Device program launches per generated token "
                     "(all program kinds / all tokens since start) — "
-                    "the ROADMAP mega-kernel item's headline; its "
-                    "banked baseline lives in DISPATCH_BENCH.json."
+                    "the ROADMAP mega-kernel item's headline."
                     ).set_fn(
                 lambda: (co.totals["dispatches"]
                          / max(self._stat("tokens_generated"), 1)))
@@ -1618,36 +1607,35 @@ class ServingGateway:
             t["d2h_bytes"] / max(tokens, 1), 3)
         doc["window_steps"] = window_steps
         eng = self.engine
-        if getattr(eng, "_paged", False):
-            # KV columns in BYTES, not blocks (README "Quantized
-            # serving"): block counts hide the density story — an int8
-            # pool's block is ~4x smaller — so the profile reports the
-            # dtype-aware byte footprint (live/trie split from
-            # occupancy(), per-block bytes from the pool) alongside
-            # the storage dtype and per-token rate.
-            # ONE occupancy walk: every byte field below derives from
-            # this reading plus the pool's per-block constants
-            occ = eng.cache.occupancy()
-            kv_b = eng.cache.pool.block_nbytes
-            sc_b = eng.cache.pool.scale_block_nbytes
-            per_block = kv_b + sc_b
-            used = occ["live"] + occ["trie"]
-            doc["kv_pool"] = {
-                "kv_dtype": eng.kv_dtype,
-                # the other two low-precision knobs ride along so the
-                # whole "Quantized serving" posture reads off one block
-                "quantize_weights": getattr(eng, "quantize_weights",
-                                            False),
-                "quantize_activations": getattr(
-                    eng, "quantize_activations", False),
-                "live_bytes": occ["live"] * per_block,
-                "trie_bytes": occ["trie"] * per_block,
-                "free_bytes": occ["free"] * per_block,
-                "used_kv_bytes": used * kv_b,
-                "used_scale_bytes": used * sc_b,
-                "capacity_bytes": eng.cache.pool.num_blocks * per_block,
-                "bytes_per_token": eng.cache.bytes_per_token(),
-            }
+        # KV columns in BYTES, not blocks (README "Quantized
+        # serving"): block counts hide the density story — an int8
+        # pool's block is ~4x smaller — so the profile reports the
+        # dtype-aware byte footprint (live/trie split from
+        # occupancy(), per-block bytes from the pool) alongside
+        # the storage dtype and per-token rate.
+        # ONE occupancy walk: every byte field below derives from
+        # this reading plus the pool's per-block constants
+        occ = eng.cache.occupancy()
+        kv_b = eng.cache.pool.block_nbytes
+        sc_b = eng.cache.pool.scale_block_nbytes
+        per_block = kv_b + sc_b
+        used = occ["live"] + occ["trie"]
+        doc["kv_pool"] = {
+            "kv_dtype": eng.kv_dtype,
+            # the other two low-precision knobs ride along so the
+            # whole "Quantized serving" posture reads off one block
+            "quantize_weights": getattr(eng, "quantize_weights",
+                                        False),
+            "quantize_activations": getattr(
+                eng, "quantize_activations", False),
+            "live_bytes": occ["live"] * per_block,
+            "trie_bytes": occ["trie"] * per_block,
+            "free_bytes": occ["free"] * per_block,
+            "used_kv_bytes": used * kv_b,
+            "used_scale_bytes": used * sc_b,
+            "capacity_bytes": eng.cache.pool.num_blocks * per_block,
+            "bytes_per_token": eng.cache.bytes_per_token(),
+        }
         if getattr(eng, "tp", 1) > 1:
             # per-layer collective-bytes column (README "Tensor-
             # parallel serving"): annotate the window's all-reduce
@@ -1799,8 +1787,7 @@ class ServingGateway:
             if slot is not None:
                 kv_tokens = int(eng.cache.lengths[slot])
                 kv_bytes = eng.cache.slot_kv_bytes(slot)
-                if getattr(eng, "_paged", False):
-                    kv_blocks = len(eng.cache.slot_block_ids(slot))
+                kv_blocks = len(eng.cache.slot_block_ids(slot))
             # TTFT-deadline slack on the engine clock: settled once the
             # first token landed (negative = the miss already counted),
             # counting down from the wait-so-far while still queued
@@ -1831,8 +1818,7 @@ class ServingGateway:
                 # cost columns (README "Cost attribution &
                 # /debug/profile"): device launches this request has
                 # ridden so far, and the HBM bytes its KV currently
-                # holds (paged: blocks x block bytes; dense: rows x
-                # row bytes)
+                # holds (blocks x block bytes)
                 "launches": seq.launches,
                 "kv_bytes": kv_bytes,
                 "slo_slack_s": slack,

@@ -528,7 +528,7 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
           max_seq_len=None, decode_chunk=1, max_queue=64,
           model_name=None, registry=None, log_fn=None, start=True,
           prefix_cache=False, prefix_blocks=None, prefix_block_size=32,
-          paged_attn=True, prefill_chunk=512, ragged_step=True,
+          prefill_chunk=512,
           headroom_mult=2.0, watchdog_deadline_s=30.0, max_restarts=8,
           fault_hook=None, clock=None, spec_decode=False, spec_k=4,
           drafter=None, trace=False, trace_buffer=65536, cost=True,
@@ -545,19 +545,17 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     step-size set at exactly one program). ``prefix_cache=True`` turns
     on automatic prefix caching (README "Automatic prefix caching");
     its hit/miss/eviction counters and the ``kv_prefix_blocks`` gauge
-    land on ``GET /metrics``. ``paged_attn=True`` (the default) serves
-    from the block-table paged KV cache (README "Paged attention") —
-    prefix hits install zero-copy and ``/metrics`` grows the
-    ``kv_blocks_shared`` and ``kv_block_table_fill`` gauges; pass
-    ``paged_attn=False`` for the legacy dense per-slot cache.
-    ``prefill_chunk`` (default 512 tokens, paged only; ``0``/``None``
+    land on ``GET /metrics``. The engine serves from the block-table
+    paged KV cache (README "Paged attention") — prefix hits install
+    zero-copy and ``/metrics`` carries the ``kv_blocks_shared`` and
+    ``kv_block_table_fill`` gauges.
+    ``prefill_chunk`` (default 512 tokens; ``0``/``None``
     disables) interleaves long cold-prompt prefills with decode steps
     so one long prompt can't stall every streaming client — the
     ``serving_ttft_seconds`` histogram and
     ``serving_prefill_chunks_total`` counter on ``/metrics`` watch it
-    (README "Chunked prefill"). ``ragged_step=True`` (the default on
-    the paged engine) runs decode rows and prefill chunks through ONE
-    unified ragged program per step, with the per-step chunk grant
+    (README "Chunked prefill"). Decode rows and prefill chunks run
+    through ONE unified ragged program per step, with the per-step chunk grant
     adapted from the measured throughput EWMA scaled by
     ``headroom_mult`` (README "Unified ragged attention";
     ``headroom_mult=None`` pins fixed-cap pacing) — the
@@ -583,7 +581,7 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     ``serving_engine_restarts_total``, ``serving_preemptions_total``
     and ``serving_recovered_requests_total``.
 
-    ``spec_decode=True`` (paged only, default OFF) turns on
+    ``spec_decode=True`` (default OFF) turns on
     speculative multi-token decode (README "Speculative decoding"):
     ``spec_k`` bounds the draft length, ``drafter`` overrides the
     default prompt-lookup :class:`~..drafter.NgramDrafter` (the one
@@ -605,8 +603,7 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     ``/metrics`` as ``serving_tpot_seconds`` /
     ``serving_queue_wait_seconds``.
 
-    ``decode_ticks > 1`` (unified ragged engine only, default 1 so
-    every banked baseline stays an A/B away) turns on multi-tick
+    ``decode_ticks > 1`` (default 1) turns on multi-tick
     decode (README "Multi-tick decode"): when every running slot is in
     pure decode the engine fuses up to ``decode_ticks`` on-device
     ticks behind ONE host sync, with EOS/budget retirement masked
@@ -615,17 +612,16 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     single-tick so TTFT never regresses. ``/metrics`` grows the
     ``serving_decode_ticks_per_sync`` gauge; the
     ``serving_dispatches_per_decoded_token`` headline drops
-    proportionally (DISPATCH_BENCH.json banks the ladder). Note the
+    proportionally. Note the
     trade: a streaming client sees tokens in bursts of up to
     ``decode_ticks``.
 
-    ``kv_dtype="int8"`` (unified ragged paged engine only, default
-    None so every banked baseline stays byte-identical) serves from
+    ``kv_dtype="int8"`` (default None) serves from
     the int8 block-quantized KV pool (README "Quantized serving"):
     appends quantize on write with per-row-per-head fp32 scale planes
     riding the same physical blocks, the attention kernels upcast
     in-register after the table-indirect DMA, and pool HBM drops ~4x
-    vs fp32 — the density win DENSITY_BENCH.json banks.
+    vs fp32.
     ``kv_dtype="fp8"`` stores ``float8_e4m3fn`` instead with
     per-BLOCK scale planes (constant 1.0 — e4m3's exponent is the
     per-value scale), cutting scale bytes per cached token
@@ -642,24 +638,23 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     upgrades those projections to int8xint8: each projection input is
     quantized per-row at runtime and contracted against the int8
     weights with int32 accumulate, so the per-layer weight dequant
-    disappears from the decode step entirely (greedy divergence
-    measured in DENSITY_BENCH.json, not assumed).
+    disappears from the decode step entirely (greedy streams may
+    diverge from the full-precision ones; not measured on the chip).
 
-    ``tp=N`` (unified ragged paged engine only, default 1) serves
+    ``tp=N`` (default 1) serves
     tensor-parallel over an N-device heads-sharded mesh (README
     "Tensor-parallel serving"): every serving program runs under
     shard_map with the paged KV pool partitioned per shard, one
     all-reduce pair per layer is the only cross-chip traffic, and
     ``collective_dtype="int8"`` runs that pair EQuARX-style
-    block-quantized (~3.5x fewer wire bytes, divergence measured in
-    TP_BENCH.json). ``/metrics`` grows
+    block-quantized (~3.5x fewer wire bytes by the shape-derived wire
+    model; greedy streams may diverge). ``/metrics`` grows
     ``serving_collective_bytes_total{dtype}``; ``/debug/profile``
     gains the per-layer collective-bytes section. On CPU develop with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 
-    ``host_tier_bytes=N`` (prefix-cache engines only, default 0 so
-    every banked baseline stays byte-identical) backs the prefix trie
-    with a host-RAM spill tier (README "Tiered KV prefix cache"):
+    ``host_tier_bytes=N`` (prefix-cache engines only, default 0) backs
+    the prefix trie with a host-RAM spill tier (README "Tiered KV prefix cache"):
     evicted chains spill device→host under this byte budget with
     their own LRU, and a later lookup that lands on a spilled chain
     streams it back h2d and readmits through the normal allocation
@@ -668,9 +663,9 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     and ``serving_tier_bytes_total{direction}``; ``/debug/profile``
     gains the tiers section.
 
-    ``classes`` (default None — single neutral class, every banked
-    baseline byte-identical) turns on multi-tenant SLO policy (README
-    "Multi-tenant SLO serving"): a comma list of
+    ``classes`` (default None — single neutral class) turns on
+    multi-tenant SLO policy (README "Multi-tenant SLO serving"): a
+    comma list of
     ``name[*][:reserved_slots]`` entries, highest priority first, with
     ``slo_ttft_ms`` / ``slo_tpot_ms`` aligned per-class target lists
     (0 = no target). Requests pick a tier via the ``priority_class``
@@ -697,8 +692,7 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
             decode_chunk=decode_chunk, prefix_cache=prefix_cache,
             prefix_blocks=prefix_blocks,
             prefix_block_size=prefix_block_size,
-            paged_attn=paged_attn, prefill_chunk=prefill_chunk,
-            ragged_step=ragged_step, headroom_mult=headroom_mult,
+            prefill_chunk=prefill_chunk, headroom_mult=headroom_mult,
             spec_decode=spec_decode, spec_k=spec_k, drafter=drafter,
             decode_ticks=decode_ticks, kv_dtype=kv_dtype,
             quantize_weights=quantize_weights,
@@ -726,8 +720,8 @@ def serve_fleet(model, replicas=2, router="affinity", host="127.0.0.1",
                 port=8000, num_slots=8, max_seq_len=None, decode_chunk=1,
                 max_queue=64, model_name=None, registry=None, log_fn=None,
                 start=True, prefix_cache=True, prefix_blocks=None,
-                prefix_block_size=32, paged_attn=True, prefill_chunk=512,
-                ragged_step=True, headroom_mult=2.0,
+                prefix_block_size=32, prefill_chunk=512,
+                headroom_mult=2.0,
                 watchdog_deadline_s=30.0, max_restarts=8,
                 fault_hooks=None, clock=None, spec_decode=False,
                 spec_k=4, drafter=None, trace=False, trace_buffer=65536,
@@ -746,8 +740,7 @@ def serve_fleet(model, replicas=2, router="affinity", host="127.0.0.1",
     baseline), ``least-loaded`` (live KV blocks + queue depth), or
     ``affinity`` (the default: longest cached-prefix match wins within
     ``affinity_band`` load units of the least-loaded replica, so
-    prefix-cache hits survive fan-out — FLEET_BENCH.json banks the
-    three-way comparison). ``num_slots`` / ``prefill_chunk`` /
+    prefix-cache hits survive fan-out). ``num_slots`` / ``prefill_chunk`` /
     ``max_seq_len`` / ``max_queue`` / ``prefix_blocks`` accept a
     scalar or one value per replica (mixed pool geometries isolate
     their jit caches automatically; ``decode_compilations() == 1``
@@ -794,8 +787,8 @@ def serve_fleet(model, replicas=2, router="affinity", host="127.0.0.1",
         max_seq_len=max_seq_len, decode_chunk=decode_chunk,
         max_queue=max_queue, prefix_cache=prefix_cache,
         prefix_blocks=prefix_blocks,
-        prefix_block_size=prefix_block_size, paged_attn=paged_attn,
-        prefill_chunk=prefill_chunk, ragged_step=ragged_step,
+        prefix_block_size=prefix_block_size,
+        prefill_chunk=prefill_chunk,
         headroom_mult=headroom_mult, spec_decode=spec_decode,
         spec_k=spec_k, drafter=drafter, decode_ticks=decode_ticks,
         kv_dtype=kv_dtype, quantize_weights=quantize_weights,

@@ -6,8 +6,7 @@ pin what a run on the chip must be able to rely on:
 1. a leg that fails is named in the result line and makes the exit code 1;
    no number is replayed from an earlier run and no leg is retried on a
    different kernel or backend;
-2. the default flow starts no child on a forced CPU platform and contains
-   none of the per-feature counter legs;
+2. the default flow starts no child on a forced CPU platform;
 3. the result line names the device the children reported.
 """
 import contextlib
@@ -81,10 +80,8 @@ class TestBenchDriverFlow:
         assert "decode[pallas] 321" in doc["unit"]
         legs = [leg for leg, _, _ in calls]
         assert legs[0] == "--smoke" and legs[-1] == "--decode"
-        # no child is forced onto another platform, and no counter leg
-        # (a count taken on a CPU) rides a run on the chip
+        # no child is forced onto another platform
         assert all(env is None for _, _, env in calls)
-        assert not set(legs) & set(bench.COUNTER_LEGS)
         # decode goes through the Pallas kernel only: no jnp second try
         decodes = [a for leg, a, _ in calls if leg == "--decode"]
         assert len(decodes) == 1 and decodes[0][-1] == "pallas"

@@ -114,7 +114,6 @@ class TestTransparency:
         chunked, eng = _run(model, reqs, prefix_cache=True)
         assert chunked == unchunked == cold
         assert eng.prefix_cache.stats["hits"] >= 1
-        assert eng.stats["prefill_copy_dispatches"] == 0
         # the hit's covered tokens were never re-prefilled
         assert eng.stats["prefill_tokens_saved"] > 0
 
@@ -356,23 +355,8 @@ class TestConfigSurface:
         assert eng.prefill_chunk == 24   # the public effective value
         with pytest.raises(ValueError, match="prefill_chunk"):
             _engine(model, prefill_chunk=-1)
-        with pytest.raises(ValueError, match="prefill_chunk"):
-            # the dense engine rejects the same bad value (an A/B
-            # toggle must not turn the error into a silent no-op)
-            _engine(model, paged_attn=False, prefill_chunk=-1)
         assert _engine(model, prefill_chunk=0).prefill_chunk == 0
         assert _engine(model, prefill_chunk=None)._chunk is None
-
-    def test_dense_engine_ignores_chunking(self, model):
-        """The dense path has no block tables to resume through:
-        prefill_chunk is inert there, prompts one-shot, streams
-        unchanged."""
-        reqs = [_req(40, n=50), _req(41, n=12)]
-        want, _ = _run(model, reqs, paged_attn=False, prefill_chunk=None)
-        got, eng = _run(model, reqs, paged_attn=False)
-        assert got == want
-        assert eng.prefill_chunk == 0
-        assert eng.stats["prefill_chunks"] == 0
 
     def test_metrics_surface_strict_parsed(self, model):
         """serving_prefill_chunks_total counts chunk work on /metrics

@@ -616,15 +616,3 @@ class TestFleetCLIArgs:
             with pytest.raises(SystemExit) as ei:
                 main(argv)
             assert ei.value.code == 2                        # usage error
-
-
-# ------------------------------------------------------------ fleet bench
-@pytest.mark.slow   # ISSUE 12 satellite: the fleet bench is nightly-class
-def test_bench_fleet_accepts():
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    from bench_fleet import measure_fleet
-    res = measure_fleet(quick=True)
-    assert res["accepted"], res

@@ -370,12 +370,6 @@ class TestJitKeysAndValidation:
         e2.generate([_req(22, max_new_tokens=3)])
         assert e2.decode_compilations() == 1
 
-    def test_fused_requires_ragged_paged(self, model):
-        with pytest.raises(ValueError, match="unified ragged paged"):
-            _engine(model, fused_tick=True, paged_attn=False)
-        with pytest.raises(ValueError, match="unified ragged paged"):
-            _engine(model, fused_tick=True, ragged_step=False)
-
     def test_fused_spec_error_enumerates_knobs(self, model):
         """fused x spec is rejected with the COMPATIBLE knob set
         spelled out (the error is documentation)."""
@@ -395,8 +389,8 @@ class TestJitKeysAndValidation:
                            match="incompatible with spec_decode") as ei:
             _engine(model, decode_ticks=4, spec_decode=True, spec_k=2)
         msg = str(ei.value)
-        for knob in ("fused_tick", "collective_overlap", "paged_attn",
-                     "ragged_step", "prefix_cache", "kv_dtype", "tp",
+        for knob in ("fused_tick", "collective_overlap",
+                     "prefix_cache", "kv_dtype", "tp",
                      "priority_classes"):
             assert knob in msg
 

@@ -183,12 +183,6 @@ class TestPoolBytes:
 
 # ----------------------------------------------------------- validation
 class TestValidation:
-    def test_int8_requires_unified_ragged_paged(self, model):
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, kv_dtype="int8", paged_attn=False)
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, kv_dtype="int8", ragged_step=False)
-
     def test_bad_kv_dtype_rejected(self, model):
         with pytest.raises(ValueError, match="kv_dtype"):
             _engine(model, kv_dtype="int4")

@@ -184,20 +184,9 @@ class TestFp8PoolBytes:
 
 # ------------------------------------------------------------ validation
 class TestValidation:
-    def test_fp8_requires_unified_ragged_paged(self, model):
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, kv_dtype="fp8", paged_attn=False)
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, kv_dtype="fp8", ragged_step=False)
-
     def test_a8_requires_weight_quant(self, model):
         with pytest.raises(ValueError, match="quantize_weights"):
             _engine(model, quantize_activations=True)
-
-    def test_a8_requires_unified_ragged_paged(self, model):
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, quantize_weights=True,
-                    quantize_activations=True, ragged_step=False)
 
     def test_shared_pool_mode_mismatch_raises(self, model):
         """An int8-pool trie adopted by an fp8 engine is a geometry

@@ -135,28 +135,9 @@ class TestTransparency:
         # the fast path really amortized syncs: fewer decode launches
         assert eng.stats["decode_calls"] < base.stats["decode_calls"]
 
-    @pytest.mark.slow   # re-tiered for the 870s tier-1 cap (PR 13):
-    # transitively covered by default reps — multitick ≡ single-tick
-    # (the mixed matrix above) and unified ≡ two-program
-    # (test_ragged_step) — so the direct two-program comparison is the
-    # duplicate chain link
-    def test_multitick_equals_two_program_baseline(self, model):
-        reqs = [_req(11, n=24, max_new_tokens=12),
-                _req(12, n=12, max_new_tokens=10,
-                     temperature=0.7, top_k=3, seed=9)]
-        a = _engine(model, paged_attn=True, ragged_step=False)
-        b = _engine(model, decode_ticks=TICKS)
-        oa = [o.tolist() for o in a.generate([_clone(r) for r in reqs])]
-        ob = [o.tolist() for o in b.generate([_clone(r) for r in reqs])]
-        assert oa == ob
-
     def test_invalid_configs_raise(self, model):
         with pytest.raises(ValueError, match="decode_ticks"):
             _engine(model, decode_ticks=0)
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, decode_ticks=4, paged_attn=False)
-        with pytest.raises(ValueError, match="unified ragged"):
-            _engine(model, decode_ticks=4, ragged_step=False)
         with pytest.raises(ValueError, match="spec_decode"):
             _engine(model, decode_ticks=4, spec_decode=True)
 

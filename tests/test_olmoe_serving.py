@@ -174,8 +174,6 @@ SWITCHES = {
     "fused_tick": dict(fused_tick=True),
     "decode_ticks > 1": dict(decode_ticks=4),
     "spec_decode": dict(spec_decode=True),
-    "paged_attn=False": dict(paged_attn=False),
-    "ragged_step=False": dict(ragged_step=False),
     "decode_chunk > 1": dict(decode_chunk=8),
     "prefix_cache": dict(prefix_cache=True),
 }

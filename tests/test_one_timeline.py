@@ -8,6 +8,7 @@ phases, and ``GET /debug/xplane``.
 import glob
 import json
 import os
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -269,11 +270,23 @@ class TestDebugXplane:
     def test_capture_returns_the_directory_and_the_spans(self, model,
                                                          tmp_path):
         srv = serve(model, port=0, num_slots=NUM_SLOTS, max_seq_len=S_MAX)
+        # The engine is kept stepping until the capture has returned. A
+        # stream of a fixed length races the profiler: starting and
+        # stopping a session takes seconds on a busy host, a 240-token
+        # stream of this model about as long, and a capture armed after
+        # the stream's end sees no step before its timeout.
+        captured = threading.Event()
+
+        def keep_stepping():
+            while not captured.is_set():
+                srv.gateway.submit(GenerationRequest(
+                    prompt=[9, 10, 11, 12], max_new_tokens=16)).result()
+
+        feeder = threading.Thread(target=keep_stepping, daemon=True)
         try:
             srv.gateway.submit(GenerationRequest(
                 prompt=[1, 2, 3, 4], max_new_tokens=2)).result()
-            stream = srv.gateway.submit(GenerationRequest(
-                prompt=[9, 10, 11, 12], max_new_tokens=240))
+            feeder.start()
             # a profiler session someone else holds is a busy capture
             jax.profiler.start_trace(str(tmp_path))
             try:
@@ -284,11 +297,15 @@ class TestDebugXplane:
                 assert srv.gateway._capture is None
             finally:
                 jax.profiler.stop_trace()
-            with urllib.request.urlopen(
-                    srv.url + "/debug/xplane?steps=3&timeout_s=60",
-                    timeout=120) as r:
-                doc = json.load(r)
-            stream.cancel()
+            try:
+                with urllib.request.urlopen(
+                        srv.url + "/debug/xplane?steps=3&timeout_s=60",
+                        timeout=120) as r:
+                    doc = json.load(r)
+            finally:
+                captured.set()
+            feeder.join(timeout=60)
+            assert not feeder.is_alive()
             xdir = doc["otherData"]["xplane_dir"]
             assert glob.glob(os.path.join(xdir, "plugins", "profile", "*",
                                           "*.xplane.pb"))
@@ -307,6 +324,7 @@ class TestDebugXplane:
                 urllib.request.urlopen(srv.url + "/debug/xplane?steps=0")
             assert bad.value.code == 400
         finally:
+            captured.set()
             srv.shutdown(drain=False, timeout=30)
 
 

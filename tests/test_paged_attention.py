@@ -1,19 +1,18 @@
-"""Block-table paged attention (serving/kv_cache.PagedKVCache +
-engine paged_attn=True): zero-copy prefix hits over a shared block pool.
+"""Block-table paged attention (serving/kv_cache.PagedKVCache): zero-copy
+prefix hits over a shared block pool.
 
 The load-bearing properties:
 
-- **Transparency**: token streams of the paged engine are byte-identical
-  to the dense engine — greedy AND seeded sampled — across hits, misses,
-  evictions, COW divergence, and fused decode chunks. Paged changes
-  WHERE KV physically lives (pool blocks behind a table vs dense slot
-  rows), never what gets sampled.
-- **Zero copies**: ``prefill_copy_dispatches`` stays at 0 — hits install
-  by referencing published block ids, retirement DONATES blocks instead
-  of copying out.
+- **Transparency**: token streams with the prefix cache on are
+  byte-identical to the cache-off engine's — greedy AND seeded sampled —
+  across hits, misses, evictions, COW divergence, and fused decode
+  chunks, and the cache-off engine's greedy streams are the forward
+  pass's argmax (``test_serving_oracle.served_equals_forward``). Sharing
+  changes WHERE KV physically lives, never what gets sampled.
+- **Zero copies**: hits install by referencing published block ids,
+  retirement DONATES blocks instead of copying out.
 - **Physical sharing**: concurrent holders of one prefix reference the
-  SAME block ids (refcount >= 2, ``kv_blocks_shared`` gauge), the win
-  the dense install-copy path cannot have.
+  SAME block ids (refcount >= 2, ``kv_blocks_shared`` gauge).
 - **Compile-once survives paging**: block tables are runtime arguments;
   ``decode_compilations() == 1`` under any traffic mix.
 - **Ownership discipline**: a mid-decode cancel frees the private tail
@@ -30,6 +29,7 @@ from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
                                 GenerationRequest, PagedKVCache)
 
 from test_metrics_prom import parse_prometheus
+from test_serving_oracle import served_equals_forward
 
 BS = 8  # block_size for every engine here (tiny model, short prompts)
 
@@ -40,14 +40,13 @@ def model():
     return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
 
 
-def _engine(model, paged=True, prefix_cache=True, **kw):
+def _engine(model, prefix_cache=True, **kw):
     kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("decode_chunk", 1)
     kw.setdefault("prefix_block_size", BS)
-    return ContinuousBatchingEngine(model, prefix_cache=prefix_cache,
-                                    paged_attn=paged, **kw)
+    return ContinuousBatchingEngine(model, prefix_cache=prefix_cache, **kw)
 
 
 _SYS = np.random.RandomState(7).randint(0, 256, (20,)).astype(np.int32)
@@ -68,30 +67,37 @@ def _clone(req):
         eos_token_id=req.eos_token_id, seed=req.seed)
 
 
-def _dense_run(model, reqs, **kw):
-    eng = _engine(model, paged=False, prefix_cache=False, **kw)
-    return [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
+def _reference_run(model, reqs, **kw):
+    """The streams to expect: the cache-off engine's, whose greedy ones
+    are first held to the forward pass (an oracle outside the serving
+    code); a sampled stream is held by being the same with the cache on."""
+    # its own jit dict: a pool of another size is another trace of the
+    # step program, and the tests pin the engine under test at one
+    kw.setdefault("jit_cache", model.__dict__.setdefault("_reference_jit", {}))
+    eng = _engine(model, prefix_cache=False, **kw)
+    outs = [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
+    for r, out in zip(reqs, outs):
+        if r.temperature <= 0:
+            served_equals_forward(model, r.prompt, out)
+    return outs
 
 
 class TestTransparency:
     def test_streams_identical_greedy_and_sampled(self, model):
         """The acceptance pin: hit/miss mixes, greedy and seeded-sampled,
-        stream the exact dense-engine tokens with ZERO copy dispatches
-        and one decode compilation."""
+        stream the exact cache-off tokens with one decode compilation."""
         reqs = [_req(1), _req(2),
                 _req(3, temperature=0.9, top_k=5, seed=123),
                 _req(4, temperature=0.7, top_k=3, seed=9)]
-        want = _dense_run(model, reqs)
+        want = _reference_run(model, reqs)
         eng = _engine(model)
         got = [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
         assert got == want
         pc = eng.prefix_cache
         assert pc.stats["hits"] >= 2           # later admissions reused
         assert pc.stats["donated_blocks"] > 0  # publish = adoption
-        assert eng.stats["prefill_copy_dispatches"] == 0
         assert eng.decode_compilations() == 1
-        # hits really skipped device prefill work, same accounting as
-        # the dense prefix cache
+        # hits really skipped device prefill work
         assert eng.stats["prefill_tokens"] == \
             sum(len(r.prompt) for r in reqs) - pc.stats["hit_tokens"]
 
@@ -103,16 +109,16 @@ class TestTransparency:
         byte-identical and the step-size compile set stays the pow2
         ladder."""
         reqs = [_req(10, max_new_tokens=20), _req(11, max_new_tokens=20)]
-        want = _dense_run(model, reqs, decode_chunk=8)
+        want = _reference_run(model, reqs, decode_chunk=8)
         eng = _engine(model, decode_chunk=8)
         got = [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
         assert got == want
 
     def test_paged_without_prefix_cache(self, model):
-        """paged_attn stands alone: pool sized to the live grid, no
-        trie, same streams."""
+        """The pool stands alone: sized to the live grid, no trie, same
+        streams."""
         reqs = [_req(20), _req(21, temperature=0.8, top_k=4, seed=5)]
-        want = _dense_run(model, reqs)
+        want = _reference_run(model, reqs)
         eng = _engine(model, prefix_cache=False)
         got = [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
         assert got == want
@@ -122,8 +128,8 @@ class TestTransparency:
 
     @pytest.mark.slow  # eviction-pressure duplicate: the unified
     # engine's matrix pins evictions + byte-identical streams on the
-    # default path (test_ragged_step) and the dense eviction-equality
-    # rep stays default in test_prefix_cache
+    # default path (test_ragged_step) and the eviction-equality rep
+    # stays default in test_prefix_cache
     def test_eviction_pressure_keeps_streams_exact(self, model):
         """A trie budget far smaller than the working set: evictions
         fire, live sequences always win the pool (evict-on-demand), and
@@ -131,7 +137,7 @@ class TestTransparency:
         reqs = [_req(30 + i, sys_prompt=np.random.RandomState(100 + i % 5)
                      .randint(0, 256, (16,)).astype(np.int32),
                      max_new_tokens=4) for i in range(10)]
-        want = _dense_run(model, reqs)
+        want = _reference_run(model, reqs)
         eng = _engine(model, prefix_blocks=3)
         pool = eng.prefix_cache.pool
         outs = []
@@ -140,19 +146,18 @@ class TestTransparency:
             assert pool.num_used <= pool.num_blocks
         assert outs == want
         assert eng.prefix_cache.stats["evictions"] > 0
-        assert eng.stats["prefill_copy_dispatches"] == 0
 
 
 class TestZeroCopySharing:
     def test_concurrent_hits_share_physical_blocks(self, model):
         """Two live sequences hitting the same chain REFERENCE the same
-        physical blocks (dense would hold two private copies): their
-        table prefixes are equal, the blocks carry refcount 2, and the
-        kv_blocks_shared accounting sees them. Divergent tails still
-        match the dense streams (writes land in private tail blocks)."""
+        physical blocks: their table prefixes are equal, the blocks
+        carry refcount 2, and the kv_blocks_shared accounting sees them.
+        Divergent tails still match the cache-off streams (writes land
+        in private tail blocks)."""
         a = _req(31, max_new_tokens=8)
         b = _req(32, max_new_tokens=8, temperature=0.9, top_k=4, seed=3)
-        want = _dense_run(model, [a, b])
+        want = _reference_run(model, [a, b])
         eng = _engine(model)
         eng.generate([_req(30, max_new_tokens=2)])  # publish the chain
         sa, sb = eng.submit(_clone(a)), eng.submit(_clone(b))
@@ -175,7 +180,6 @@ class TestZeroCopySharing:
         assert seen_shared
         assert [sa.tokens, sb.tokens] == want
         assert sa.prefix_hit_tokens == sb.prefix_hit_tokens == 2 * BS
-        assert eng.stats["prefill_copy_dispatches"] == 0
         # pins drained at retirement; trie-resident blocks are zero-ref
         assert not eng.prefix_cache.pool._ref.any()
 
@@ -203,7 +207,7 @@ class TestOwnershipDiscipline:
         (pinned by the trie + the surviving holder) stays resident, and
         the survivor's stream is untouched."""
         b = _req(51, max_new_tokens=10)
-        want_b = _dense_run(model, [b])
+        want_b = _reference_run(model, [b])
         eng = _engine(model)
         eng.generate([_req(50, max_new_tokens=2)])  # publish the chain
         pool = eng.prefix_cache.pool
@@ -320,8 +324,7 @@ class TestCompileDiscipline:
     def test_mixed_traffic_keeps_decode_at_one(self, model):
         """Waves of hits/misses/divergence leave decode_compilations()
         at 1 and the prefill/suffix compile set closed over the pow2
-        grid — block tables are runtime data. A dense engine sharing the
-        same jit_cache counts its own programs separately."""
+        grid — block tables are runtime data."""
         jit = {}
         eng = _engine(model, jit_cache=jit)
 
@@ -345,21 +348,12 @@ class TestCompileDiscipline:
         assert third == first
         assert eng.decode_compilations() == 1
         assert eng.prefill_compilations() == prefill0  # zero new traces
-        assert eng.stats["prefill_copy_dispatches"] == 0
-        # dense engine on the SAME jit dict: separate decode kind, its
-        # own count also 1 — and the cold prefill program is shared
-        dense = _engine(model, paged=False, prefix_cache=False,
-                        jit_cache=jit)
-        assert wave(dense) == first
-        assert dense.decode_compilations() == 1
-        assert eng.decode_compilations() == 1
 
 
 class TestMetricsSurface:
     def test_paged_gauges_strict_parsed(self, model):
-        """/metrics grows kv_blocks_shared + kv_block_table_fill and the
-        serving_prefill_copy_dispatches_total counter (pinned at 0 on
-        the paged path), all valid under the strict v0.0.4 parser."""
+        """/metrics carries kv_blocks_shared + kv_block_table_fill, all
+        valid under the strict v0.0.4 parser."""
         from paddle_tpu.serving.server import ServingGateway
         eng = _engine(model, num_slots=2)
         gw = ServingGateway(eng, start=False)  # no driver thread needed
@@ -379,9 +373,6 @@ class TestMetricsSurface:
         assert 0.0 < val("kv_block_table_fill") <= 1.0
         assert val("kv_block_table_fill") == pytest.approx(
             eng.cache.table_fill())
-        assert fams["serving_prefill_copy_dispatches_total"]["type"] == \
-            "counter"
-        assert val("serving_prefill_copy_dispatches_total") == 0
         assert val("serving_prefix_cache_hits_total") >= 2
         assert val("kv_prefix_blocks") == eng.cache.pool.num_used
         eng.cancel(sa)
@@ -394,15 +385,6 @@ class TestMetricsSurface:
         assert fams2["kv_block_table_fill"]["samples"][
             ("kv_block_table_fill", ())] == 0.0
 
-    def test_dense_engine_counts_copy_dispatches(self, model):
-        """The counter the paged path eliminates is real on the dense
-        path: hits there dispatch one copy per installed block."""
-        eng = _engine(model, paged=False)
-        eng.generate([_req(75, max_new_tokens=2)])
-        eng.generate([_req(76, max_new_tokens=2)])   # hit: 2-block chain
-        assert eng.stats["prefill_copy_dispatches"] >= 2
-
-
 class TestConstruction:
     def test_pool_too_small_for_live_grid_rejected(self):
         pool = BlockManager(1, 3, BS, 1, 2)
@@ -412,9 +394,11 @@ class TestConstruction:
     def test_shared_prefix_cache_geometry_validated(self, model):
         """A shared PrefixCache whose pool can't also hold the live
         block grid (or mismatches block size) fails fast at __init__."""
-        donor = _engine(model, paged=False)   # dense-sized pool: too small
+        from paddle_tpu.serving import PrefixCache
+        live = 2 * (64 // BS)
+        small = PrefixCache(BlockManager(4, live, BS, 2, 16))  # no headroom
         with pytest.raises(ValueError, match="cannot back|live blocks"):
-            _engine(model, prefix_cache=donor.prefix_cache)
+            _engine(model, prefix_cache=small)
         paged_donor = _engine(model)
         ok = _engine(model, prefix_cache=paged_donor.prefix_cache)
         assert ok.prefix_cache is paged_donor.prefix_cache

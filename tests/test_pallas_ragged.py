@@ -6,11 +6,7 @@ per-sequence block tables in ONE kernel invocation. Interpret-mode
 oracle suite mirroring test_pallas_paged_decode.py, plus the properties
 the unification itself must pin:
 
-- the jnp oracle equals an independently-built dense causal reference
-  over the gathered (scrambled-table) view, span by span — BITWISE,
-  because the oracle deliberately replays the old suffix-prefill
-  program's op sequence;
-- a span-1 row is BITWISE the old single-query paged decode kernel
+- a span-1 row is BITWISE the single-query paged decode kernel's
   (pallas vs pallas, reference vs reference) — the unified serving step
   cannot perturb decode numerics;
 - sentinel tables / dead rows / packed padding stay finite and come
@@ -34,8 +30,6 @@ from paddle_tpu.kernels.pallas_ragged_attention import (
 from paddle_tpu.serving.kv_cache import quantize_kv_rows
 
 from test_one_timeline import GRID_CASES, _live_pairs
-
-NEG_INF = -1e30
 
 
 def _mk(R, spans, H, Hkv, D, mb, bs, seed=0, dtype=jnp.float32, T=None):
@@ -62,44 +56,6 @@ def _mk(R, spans, H, Hkv, D, mb, bs, seed=0, dtype=jnp.float32, T=None):
             jnp.asarray(qlen), jnp.asarray(kvlen))
 
 
-def _dense_span_oracle(q, pool_k, pool_v, tables, qstart, qlen, kvlen):
-    """Independent oracle: per sequence, gather its logical cache dense,
-    then plain masked softmax attention for its span — the exact math
-    the old suffix-prefill program ran in-program. Built with the same
-    op sequence so the comparison against the ragged oracle is
-    BITWISE."""
-    T, H, D = q.shape
-    nb, bs, Hkv, _ = np.asarray(pool_k).shape
-    R, mb = np.asarray(tables).shape
-    G = H // Hkv
-    s_tot = mb * bs
-    out = np.zeros((T, H, D), np.asarray(q).dtype)
-    for rr in range(R):
-        ql, kl, qs = int(qlen[rr]), int(kvlen[rr]), int(qstart[rr])
-        if ql == 0:
-            continue
-        tbl = np.minimum(np.asarray(tables)[rr], nb - 1)
-        k = jnp.asarray(np.asarray(pool_k)[tbl].reshape(s_tot, Hkv, D))
-        v = jnp.asarray(np.asarray(pool_v)[tbl].reshape(s_tot, Hkv, D))
-        kf = (jnp.repeat(k, G, axis=1) if G > 1 else k)[None]
-        vf = (jnp.repeat(v, G, axis=1) if G > 1 else v)[None]
-        qs_span = q[None, qs:qs + ql]                 # [1, ql, H, D]
-        # the suffix-prefill program's exact op sequence, batch of one
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qs_span, kf,
-                            preferred_element_type=jnp.float32)
-        logits = logits * (1.0 / np.sqrt(D))
-        pos = kl - ql + np.arange(ql)
-        mask = jnp.asarray(np.arange(s_tot)[None, :] <= pos[:, None])
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        probs = jnp.where(mask[None, None], probs, 0.0)
-        rv = jnp.asarray(np.arange(s_tot) < kl)
-        vf = jnp.where(rv[None, :, None, None], vf, 0.0)
-        o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), vf)
-        out[qs:qs + ql] = np.asarray(o[0])
-    return out
-
-
 MIXED = [(1, 40), (5, 37), (1, 3), (16, 16), (0, 0), (9, 64)]
 
 
@@ -119,31 +75,6 @@ class TestRaggedKernelParity:
         want = ragged_attention_reference(*args)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
-
-    def test_reference_bitwise_vs_two_program_split(self):
-        """The acceptance pin, per old-program responsibility: in the
-        two-program engine, span-1 rows were the DECODE program's and
-        span-n rows the suffix-prefill program's. The unified oracle
-        reproduces each one's output BITWISE on the same inputs —
-        multi-token spans against an independently-assembled replay of
-        the suffix program's op sequence, span-1 rows against the
-        paged-decode reference (scrambled physical placement
-        included)."""
-        args = _mk(len(MIXED), MIXED, 8, 4, 16, 4, 16, seed=7)
-        q, pk, pv, tbl, qs, ql, kl = args
-        got = np.asarray(ragged_attention_reference(*args))
-        want = _dense_span_oracle(*args)
-        multi = np.concatenate(
-            [np.arange(int(s), int(s) + int(n))
-             for s, n, in zip(np.asarray(qs), np.asarray(ql))
-             if int(n) > 1])
-        assert (got[multi] == want[multi]).all()
-        ones = [i for i, n in enumerate(np.asarray(ql)) if int(n) == 1]
-        dec = np.asarray(paged_decode_attention_reference(
-            q[np.asarray(qs)[ones]], pk, pv,
-            jnp.asarray(np.asarray(tbl)[ones]),
-            jnp.asarray(np.asarray(kl)[ones])))
-        assert (got[np.asarray(qs)[ones]] == dec).all()
 
     def test_span1_bitwise_vs_paged_decode_kernel(self):
         """A span-1 row IS the old single-query kernel's row: same

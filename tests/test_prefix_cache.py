@@ -6,16 +6,16 @@ The load-bearing properties:
 - **Transparency**: token streams with the cache on are byte-identical
   to the cache-disabled engine — greedy AND seeded sampled — across
   hits, misses, evictions, and COW divergence. The cache changes WHERE
-  prefix KV comes from (pool copy + suffix prefill vs full prefill),
+  prefix KV comes from (shared blocks + suffix prefill vs full prefill),
   never what gets sampled.
 - **Compile-once survives caching**: mixed traffic keeps
-  ``decode_compilations() == 1``; the prefill (cold + suffix) and
-  block-copy compile sets are bounded by geometry, not traffic.
+  ``decode_compilations() == 1``; the prefill (cold + suffix) compile
+  set is bounded by geometry, not traffic.
 - **Ref-count lifecycle**: matched chains are pinned for the sequence
   lifetime, pins drain to zero at retirement, pinned blocks never
-  evict, and pool occupancy never exceeds the block budget.
-- **LRU eviction** under pool pressure degrades hit-rate, never
-  correctness; exhausted-pool publishes skip instead of failing.
+  evict, and pool occupancy never exceeds the pool.
+- **LRU eviction** under the trie budget degrades hit-rate, never
+  correctness.
 """
 import collections
 import zlib
@@ -27,7 +27,6 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
                                 GenerationRequest, PrefixCache)
-from paddle_tpu.serving.kv_cache import copy_compilations
 
 from test_metrics_prom import parse_prometheus
 
@@ -45,10 +44,6 @@ def _engine(model, prefix_cache=True, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("decode_chunk", 1)
-    # this module pins the DENSE prefix-cache semantics (install-copy,
-    # publish-under-pressure skip, pool-as-budget) — the paged default
-    # has its own matrix in test_paged_attention/test_chunked_prefill
-    kw.setdefault("paged_attn", False)
     if prefix_cache:
         kw.setdefault("prefix_block_size", BS)
     return ContinuousBatchingEngine(model, prefix_cache=prefix_cache, **kw)
@@ -70,6 +65,36 @@ def _clone(req):
         prompt=req.prompt, max_new_tokens=req.max_new_tokens,
         temperature=req.temperature, top_k=req.top_k,
         eos_token_id=req.eos_token_id, seed=req.seed)
+
+
+def _donate(pc, tokens, content=None):
+    """Publish ``tokens`` the way a retiring slot does: its full blocks
+    sit in private pool blocks, each holding the slot's ownership ref
+    (allocated evict-on-demand like ``PagedKVCache._alloc_block``); the
+    trie adopts the ones it does not hold yet and the rest go back to
+    the heap. ``content(path)`` gives a block's buffers, when the test
+    reads them back."""
+    pool = pc.pool
+    keys = pc._blocks_of(tokens, len(tokens))
+    ids = []
+    for i in range(len(keys)):
+        block = pool.alloc()
+        while block is None and pc._evict_one():
+            block = pool.alloc()
+        if block is None:       # all pinned: a live slot would be preempted
+            break
+        pool.ref(block)
+        if content is not None:
+            pool.write_block(block, content(tuple(keys[:i + 1])))
+        ids.append(block)
+    donated = pc.publish_donate(
+        np.asarray(tokens)[:len(ids) * pc.block_size], ids)
+    for block in ids:
+        if block in donated:
+            pool.unref(block)   # the trie adopted it
+        else:
+            pool.drop(block)
+    return donated
 
 
 def _cold_run(model, reqs, **kw):
@@ -118,8 +143,8 @@ class TestTransparency:
     def test_cow_divergence_never_aliases(self, model):
         """Two concurrent sequences hitting the SAME cached chain then
         diverging (different tails, one sampled) match their solo runs:
-        install-copy means pool blocks are read-only and appends land in
-        private slots."""
+        shared blocks are read-only and appends land in private tail
+        blocks."""
         a = _req(31, max_new_tokens=8)
         b = _req(32, max_new_tokens=8, temperature=0.9, top_k=4, seed=3)
         want = _cold_run(model, [a, b])
@@ -144,8 +169,8 @@ class TestTransparency:
 
 class TestEvictionAndBudget:
     def test_eviction_under_pressure_keeps_streams_exact(self, model):
-        """A pool far smaller than the working set: evictions fire, the
-        budget is never exceeded, streams stay byte-identical."""
+        """A trie budget far smaller than the working set: evictions
+        fire, the pool is never exceeded, streams stay byte-identical."""
         reqs = [_req(i, sys_prompt=np.random.RandomState(100 + i % 5)
                      .randint(0, 256, (16,)).astype(np.int32),
                      max_new_tokens=4) for i in range(10)]
@@ -160,33 +185,46 @@ class TestEvictionAndBudget:
         assert eng.prefix_cache.stats["evictions"] > 0
 
     def test_pinned_blocks_never_evict_and_publish_degrades(self, model):
-        """Every pool block pinned by a live sequence: a retirement's
-        publish finds nothing evictable and SKIPS (degrade, not fail);
-        the pinned chain survives untouched."""
+        """A chain pinned by a live sequence while the trie sits at its
+        budget: other retirements donate, the trim reaps their blocks
+        (degrade, not fail) and never the pinned chain."""
         eng = _engine(model, prefix_blocks=2, num_slots=2)
         pc = eng.prefix_cache
-        eng.generate([_req(50, max_new_tokens=2)])   # fills both blocks
-        assert pc.pool.num_free == 0
-        holder = eng.submit(_req(51, max_new_tokens=30))  # pins the chain
+        eng.generate([_req(50, max_new_tokens=2)])   # fills the budget
+        assert pc.num_cached_blocks >= 2
+        held = _req(51, max_new_tokens=30)
+        want_held = _cold_run(model, [held])[0]
+        holder = eng.submit(_clone(held))            # pins the chain
         eng.step()
         assert len(holder.prefix_nodes) == 2
-        # a different prompt retires while everything is pinned
-        other = GenerationRequest(prompt=np.random.RandomState(52).randint(
-            0, 256, (2 * BS,)).astype(np.int32), max_new_tokens=2)
-        want = _cold_run(model, [other])[0]
-        got = eng.generate([_clone(other)])[0].tolist()
-        assert got == want
-        assert pc.stats["skipped_publishes"] >= 1
-        assert pc.stats["evictions"] == 0           # pins held
-        eng.cancel(holder)
+        pinned = [n.block_id for n in holder.prefix_nodes]
+        # different prompts retire while the whole budget is pinned
+        others = [GenerationRequest(
+            prompt=np.random.RandomState(52 + i).randint(
+                0, 256, (2 * BS,)).astype(np.int32), max_new_tokens=2)
+            for i in range(3)]
+        want = _cold_run(model, others)
+        for other, tokens in zip(others, want):
+            seq = eng.submit(_clone(other))
+            while not seq.done:
+                eng.step()
+            assert seq.tokens == tokens
+            assert [n.block_id for n in
+                    pc.lookup(held.prompt, record=False)] == pinned
+        # donations were adopted and trimmed back out; the pins stayed
+        assert pc.stats["evictions"] >= 3
+        assert pc.lookup(others[0].prompt, record=False) == []
+        while eng.has_work():
+            eng.step()
+        assert holder.tokens == want_held            # never disturbed
         assert not pc.pool._ref.any()
 
     def test_same_step_cold_retirement_cannot_evict_pending_hit(self, model):
         """Regression: a cold sequence retiring INSIDE the admission
-        group (max_new_tokens=1 publishes under pool pressure) must not
-        evict the chain a same-step hit matched but hasn't installed
-        yet — matched chains are pinned at lookup, before any cold
-        admission runs."""
+        group (max_new_tokens=1 donates into a full trie budget, and
+        the trim evicts) must not evict the chain a same-step hit
+        matched but hasn't installed yet — matched chains are pinned at
+        lookup, before any cold admission runs."""
         sys16 = np.random.RandomState(55).randint(
             0, 256, (16,)).astype(np.int32)
         hit_req = GenerationRequest(
@@ -197,7 +235,9 @@ class TestEvictionAndBudget:
         want_hit = _cold_run(model, [hit_req])[0]
         eng = _engine(model, prefix_blocks=2, num_slots=2)
         eng.generate([GenerationRequest(prompt=sys16, max_new_tokens=1)])
-        assert eng.prefix_cache.pool.num_free == 0  # chain fills the pool
+        pc = eng.prefix_cache
+        assert pc.num_cached_blocks == 2            # chain fills the budget
+        chain = [n.block_id for n in pc.lookup(hit_req.prompt, record=False)]
         cold_seq = eng.submit(_clone(cold_req))  # cold path admits first
         hit_seq = eng.submit(_clone(hit_req))
         while eng.has_work():
@@ -205,26 +245,23 @@ class TestEvictionAndBudget:
         assert cold_seq.finish_reason == "length"
         assert hit_seq.tokens == want_hit        # chain survived intact
         assert hit_seq.prefix_hit_tokens == 2 * BS  # whole chain matched
-        assert eng.prefix_cache.stats["evictions"] == 0  # pin held
-        assert eng.prefix_cache.stats["skipped_publishes"] >= 1
+        # the trim reaped the cold sequence's own donation, not the pins
+        assert pc.stats["evictions"] >= 2
+        assert pc.lookup(cold_req.prompt, record=False) == []
+        assert [n.block_id for n in pc.lookup(hit_req.prompt, record=False)
+                ][:2] == chain
 
     def test_lru_order_evicts_coldest_chain_first(self):
         """Unit-level: trie eviction picks the least-recently-touched
         zero-ref LEAF, keeping interior nodes reachable."""
         pool = BlockManager(1, 3, 4, 1, 2)
         pc = PrefixCache(pool)
-
-        class _FakeKV:  # host-only: no device copies needed
-            def copy_block_out(self, slot, row0, pool_, block):
-                pass
-
-        kv = _FakeKV()
-        pc.publish(np.arange(8), 0, kv)       # chain A: 2 blocks
-        pc.publish(np.arange(100, 104), 0, kv)  # chain B: 1 block
+        _donate(pc, np.arange(8))             # chain A: 2 blocks
+        _donate(pc, np.arange(100, 104))      # chain B: 1 block
         assert pool.num_used == 3
         m = pc.lookup(np.arange(9))           # touch chain A (fresh tick)
         assert len(m) == 2
-        pc.publish(np.arange(200, 204), 0, kv)  # needs an eviction
+        _donate(pc, np.arange(200, 204))      # needs an eviction
         assert pc.stats["evictions"] == 1
         # B (coldest) died; A's chain still matches end to end
         assert len(pc.lookup(np.arange(9))) == 2
@@ -232,17 +269,17 @@ class TestEvictionAndBudget:
 
 
 class TestCompileDiscipline:
-    @pytest.mark.slow  # DENSE-shim compile discipline: the paged
-    # default's twins (test_paged_attention mixed-traffic +
-    # test_chunked_prefill's hit/miss/cancel/divergence matrix) stay
-    # the default reps — no new features land on the dense path
+    @pytest.mark.slow  # compile-discipline duplicate:
+    # test_paged_attention's mixed-traffic test and
+    # test_chunked_prefill's hit/miss/cancel/divergence matrix stay
+    # the default reps
     def test_mixed_traffic_keeps_decode_at_one_and_prefill_bounded(
             self, model):
         """The acceptance pin: hits, misses, evictions, and a COW
         divergence leave ``decode_compilations() == 1``; once the
         bucket/group grid is warm a repeat wave adds ZERO prefill /
-        suffix / copy traces (the compile sets are closed over
-        geometry, not traffic history)."""
+        suffix traces (the compile sets are closed over geometry, not
+        traffic history)."""
         jit = {}
         eng = _engine(model, jit_cache=jit)  # ample pool: steady state
 
@@ -261,28 +298,24 @@ class TestCompileDiscipline:
         second = wave(eng)       # all-hit steady state; grid fully warm
         assert second == first   # caching is deterministic too
         assert eng.decode_compilations() == 1
-        prefill0, copy0 = eng.prefill_compilations(), copy_compilations()
+        prefill0 = eng.prefill_compilations()
         third = wave(eng)
         assert third == first
         assert eng.decode_compilations() == 1
         assert eng.prefill_compilations() == prefill0   # zero new traces
-        assert copy_compilations() == copy0
-        # eviction churn (pool of 4): hit patterns shift wave to wave as
+        # eviction churn (budget of 4): hit patterns shift wave to wave as
         # blocks die, so new (group, bucket) combos may legitimately
         # appear — but only within the static pow2 grid. For this
         # traffic: cold prompts bucket to {16, 32}, suffixes to {8, 16},
         # groups to {1, 2} -> at most 4 cold + 4 suffix shapes total, vs
-        # ~15 per wave if shapes leaked per-request. Copy programs are
-        # geometry-keyed: the smaller pool adds its pair once, then the
-        # count is closed no matter how much churn runs.
-        eng2 = _engine(model, jit_cache=jit, prefix_blocks=4)
+        # ~15 per wave if shapes leaked per-request. The smaller pool is
+        # another shape of the step program: its own jit dict.
+        eng2 = _engine(model, jit_cache={}, prefix_blocks=4)
         assert wave(eng2) == first
-        copy1 = copy_compilations()
         assert wave(eng2) == first
         assert wave(eng2) == first
         assert eng2.prefix_cache.stats["evictions"] > 0
         assert eng2.decode_compilations() == 1
-        assert copy_compilations() == copy1
         assert eng2.prefill_compilations() <= 8
 
 
@@ -317,7 +350,10 @@ class TestMetricsSurface:
             eng.stats["prefill_tokens_saved"] > 0
         assert fams["kv_prefix_blocks"]["type"] == "gauge"
         assert val("kv_prefix_blocks") == eng.prefix_cache.pool.num_used
-        assert val("kv_prefix_blocks_capacity") == 3
+        assert val("kv_prefix_blocks_capacity") == \
+            eng.prefix_cache.pool.num_blocks == 2 * (64 // BS) + 3
+        assert val("serving_prefix_cached_blocks") == \
+            eng.prefix_cache.num_cached_blocks <= 3
         # live gauge: occupancy changes move the next scrape
         before = val("kv_prefix_blocks")
         while eng.prefix_cache._evict_one():
@@ -333,7 +369,7 @@ class TestConstruction:
         geometry fails fast at __init__, not mid-serving in XLA."""
         donor = _engine(model)
         ok = ContinuousBatchingEngine(  # matching geometry: accepted
-            model, num_slots=2, max_seq_len=64, paged_attn=False,
+            model, num_slots=2, max_seq_len=64, prefix_block_size=BS,
             prefix_cache=donor.prefix_cache,
             jit_cache=model.__dict__["_serving_jit"])
         assert ok.prefix_cache is donor.prefix_cache
@@ -341,16 +377,16 @@ class TestConstruction:
         other = LlamaForCausalLM(llama_tiny(hidden_size=32))  # head_dim 8
         with pytest.raises(ValueError, match="geometry"):
             ContinuousBatchingEngine(other, num_slots=2, max_seq_len=64,
-                                     paged_attn=False,
+                                     prefix_block_size=BS,
                                      prefix_cache=donor.prefix_cache)
 
     def test_prefix_blocks_zero_rejected_not_defaulted(self, model):
-        with pytest.raises(ValueError, match="num_blocks"):
+        with pytest.raises(ValueError, match="prefix_blocks must be >= 1"):
             _engine(model, prefix_blocks=0)
 
 
 class TestTrieInvariantsRandomized:
-    """ISSUE 16 satellite: randomized interleavings of publish /
+    """ISSUE 16 satellite: randomized interleavings of donate /
     acquire / release / evict — with the host tier spilling and
     readmitting underneath — uphold the trie's structural invariants
     at every step:
@@ -375,19 +411,6 @@ class TestTrieInvariantsRandomized:
         v = float(zlib.crc32(repr(path).encode()) % 65536)
         return {"k": np.full(self.SHAPE, v, np.float32),
                 "v": np.full(self.SHAPE, v + 0.5, np.float32)}
-
-    class _ContentKV:
-        """publish()-facing stand-in whose copy_block_out writes the
-        path-derived content through the pool's own h2d program."""
-
-        def __init__(self, test, pc):
-            self.test, self.pc, self.tokens = test, pc, None
-
-        def copy_block_out(self, slot, row0, pool, block):
-            i = row0 // pool.block_size
-            path = tuple(self.pc._blocks_of(self.tokens,
-                                            len(self.tokens))[:i + 1])
-            pool.write_block(block, self.test._expected(path))
 
     def _check(self, pc, pool, held, content=False):
         nodes, stack = [], [(None, pc._root)]
@@ -426,7 +449,6 @@ class TestTrieInvariantsRandomized:
         # tier budget of 4 blocks (64 B each): tier-side LRU trims and
         # descendant cascades fire too, not just spill/readmit
         pc = PrefixCache(pool, host_tier_bytes=4 * 64)
-        kv = self._ContentKV(self, pc)
         # small alphabet + short lengths: prompts share prefixes often
         prompts = [rng.randint(0, 3, (int(n),)).astype(np.int32)
                    for n in rng.randint(4, 18, size=12)]
@@ -435,8 +457,7 @@ class TestTrieInvariantsRandomized:
             op = rng.rand()
             prompt = prompts[rng.randint(len(prompts))]
             if op < 0.35:
-                kv.tokens = prompt
-                pc.publish(prompt, 0, kv)
+                _donate(pc, prompt, content=self._expected)
             elif op < 0.65:
                 m = pc.lookup(prompt)       # may readmit from the tier
                 if m:

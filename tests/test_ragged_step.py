@@ -1,16 +1,12 @@
-"""The unified ragged serving step (engine ``ragged_step=True``, README
-"Unified ragged attention"): decode rows and prefill chunks ride ONE
-device program per step, with the chunk grant adapted from measured
-headroom EWMAs. The load-bearing properties:
+"""The unified ragged serving step (README "Unified ragged attention"):
+decode rows and prefill chunks ride ONE device program per step, with
+the chunk grant adapted from measured headroom EWMAs. The load-bearing
+properties (the streams themselves are held to ``model.forward`` in
+``tests/test_serving_oracle.py``):
 
-- **Transparency**: unified token streams are byte-identical to the
-  two-program (PR-5) engine — greedy AND seeded-sampled, across a
-  hit/miss/eviction/cancel/chunked mix — and ``decode_compilations()``
-  stays at 1.
 - **One launch**: a step carrying both a prefill chunk and live decode
-  rows dispatches exactly ONE program where the baseline pair
-  dispatched two — and no discarded decode row runs for a mid-prefill
-  slot.
+  rows dispatches exactly ONE program — and no discarded decode row
+  runs for a mid-prefill slot.
 - **Headroom-adaptive budgeting**: the grant follows the measured
   tokens-per-second EWMA (deterministically, via an injected step
   clock), is capped at ``prefill_chunk``, and a throttled sub-block
@@ -63,82 +59,17 @@ def _clone(r):
                              eos_token_id=r.eos_token_id, seed=r.seed)
 
 
-class TestTransparency:
-    @pytest.mark.slow  # 10 s transparency matrix duplicate: the one-launch and
-    # dense-engine reps below run by default (870s cap)
-    def test_unified_equals_two_program_mixed_matrix(self, model):
-        """The acceptance pin: a hit/miss/eviction/cancel/chunked
-        traffic matrix — varied prompt lengths, shared system prompt,
-        greedy and seeded-sampled rows, a mid-prefill cancellation, a
-        trie small enough to evict under pressure — streams byte-
-        identical between ``ragged_step=True`` and the PR-5 two-program
-        engine, with one unified decode program."""
-        sysp = _prompt(90, 32)
-
-        def drive(ragged):
-            eng = _engine(model, ragged_step=ragged, prefix_cache=True,
-                          prefix_blocks=32)   # tight trie: evictions
-            outs = []
-            for wave in range(2):
-                reqs = [_req(1, n=40), _req(2, n=61),
-                        GenerationRequest(
-                            prompt=np.concatenate([sysp, _prompt(3, 24)]),
-                            max_new_tokens=5),
-                        GenerationRequest(
-                            prompt=np.concatenate([sysp, _prompt(4, 31)]),
-                            max_new_tokens=5, temperature=0.8, top_k=4,
-                            seed=7),
-                        _req(5, n=53, temperature=0.9, top_k=5, seed=123),
-                        _req(6, n=12)]
-                seqs = [eng.submit(_clone(r)) for r in reqs]
-                victim = eng.submit(_req(7, n=70))
-                steps = 0
-                while eng.has_work():
-                    eng.step()
-                    steps += 1
-                    if steps == 4 and victim.status == "prefilling":
-                        eng.cancel(victim)   # mid-chunk cancellation
-                outs.append([s.tokens for s in seqs])
-            return outs, eng
-
-        want, base = drive(False)
-        got, eng = drive(True)
-        assert got == want
-        assert eng.decode_compilations() == 1
-        assert eng.stats["prefill_chunks"] >= 6
-        assert eng.prefix_cache.stats["evictions"] >= 1
-        assert eng.prefix_cache.stats["hits"] >= 1
-        # the unified engine really ran unified steps (not the pair)
-        assert eng.stats["unified_steps"] > 0
-        assert base.stats["unified_steps"] == 0
-
-    def test_dense_engine_ignores_ragged_step(self, model):
-        reqs = [_req(10, n=24), _req(11, n=12)]
-        a = _engine(model, paged_attn=False, ragged_step=True)
-        b = _engine(model, paged_attn=False, ragged_step=False)
-        assert a.ragged_step is False and b.ragged_step is False
-        oa = [o.tolist() for o in a.generate([_clone(r) for r in reqs])]
-        ob = [o.tolist() for o in b.generate([_clone(r) for r in reqs])]
-        assert oa == ob
-        assert a.stats["unified_steps"] == 0
-
-
 class TestOneLaunch:
     def test_mixed_step_single_program_no_dead_decode_row(self, model):
         """While a long prompt chunks, a step that ALSO decodes a live
-        slot dispatches exactly one program — the two-program engine's
-        chunk-call + decode-call pair collapses — and the mid-prefill
-        slot contributes its chunk span instead of a discarded
-        full-length decode row."""
-        calls = {"ragged": 0, "suffix": 0, "decode": 0}
+        slot dispatches exactly one program, and the mid-prefill slot
+        contributes its chunk span instead of a discarded full-length
+        decode row."""
+        calls = {"ragged": 0, "suffix": 0}
         eng = _engine(model, headroom_mult=None)
-        for name, orig in (("ragged", eng._ragged_fn),
-                           ("decode", eng._decode_fn)):
-            def wrap(n, _name=name, _orig=orig):
-                calls[_name] += 1
-                return _orig(n)
-            setattr(eng, "_" + name + "_fn", wrap)
-        orig_sfx = eng._suffix_fn
+        orig_ragged, orig_sfx = eng._ragged_fn, eng._suffix_fn
+        eng._ragged_fn = lambda n: (calls.__setitem__(
+            "ragged", calls["ragged"] + 1) or orig_ragged(n))
         eng._suffix_fn = lambda: (calls.__setitem__(
             "suffix", calls["suffix"] + 1) or orig_sfx())
         short = eng.submit(_req(20, n=8, max_new_tokens=40))
@@ -148,32 +79,11 @@ class TestOneLaunch:
             before = dict(calls)
             toks0 = len(short.tokens)
             eng.step()
-            # one ragged launch; NO separate chunk or decode program
+            # one ragged launch; NO separate chunk program
             assert calls["ragged"] == before["ragged"] + 1
-            assert calls["decode"] == before["decode"]
             assert calls["suffix"] == before["suffix"]
             assert len(short.tokens) == toks0 + 1   # decode kept going
         assert eng.stats["prefill_chunks"] == 5     # ceil(80/16)
-
-    def test_two_program_baseline_pays_the_pair(self, model):
-        """The baseline the bench compares against: the same traffic on
-        ``ragged_step=False`` really does launch chunk + decode
-        programs in one step."""
-        eng = _engine(model, ragged_step=False)
-        calls = {"suffix": 0, "decode": 0}
-        orig_sfx, orig_dec = eng._suffix_fn, eng._decode_fn
-        eng._suffix_fn = lambda: (calls.__setitem__(
-            "suffix", calls["suffix"] + 1) or orig_sfx())
-        eng._decode_fn = lambda n: (calls.__setitem__(
-            "decode", calls["decode"] + 1) or orig_dec(n))
-        short = eng.submit(_req(22, n=8, max_new_tokens=40))
-        eng.step()
-        longy = eng.submit(_req(23, n=80, max_new_tokens=4))
-        before = dict(calls)
-        eng.step()                      # chunk + decode: two programs
-        assert longy.status == "prefilling"
-        assert calls["suffix"] == before["suffix"] + 1
-        assert calls["decode"] == before["decode"] + 1
 
 
 class TestHeadroomBudget:

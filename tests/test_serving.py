@@ -1,5 +1,5 @@
 """Continuous-batching serving engine (serving/engine.py, SURVEY §3.5 /
-PAPERS.md): slot KV cache, mid-flight admission, EOS early-exit, per-slot
+PAPERS.md): the KV cache's slots, mid-flight admission, EOS early-exit, per-slot
 sampling params, and the compile-once contract of the decode step
 function. The load-bearing property throughout: a request's token stream
 depends only on its own prompt/key — never on batch composition or
@@ -10,7 +10,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.serving import (ContinuousBatchingEngine, GenerationRequest,
-                                FIFOScheduler, SlotKVCache)
+                                FIFOScheduler, PagedKVCache)
 
 
 @pytest.fixture(scope="module")
@@ -333,7 +333,7 @@ class TestFinishReasons:
 
 class TestKVCacheManager:
     def test_alloc_free_cycle(self):
-        c = SlotKVCache(2, 3, 16, 2, 8)
+        c = PagedKVCache(2, 3, 16, 2, 8)
         slots = [c.alloc() for _ in range(3)]
         assert slots == [0, 1, 2] and c.alloc() is None
         c.free(1)
@@ -342,7 +342,7 @@ class TestKVCacheManager:
             c.free(1) or c.free(1)
 
     def test_lengths_reset_on_free(self):
-        c = SlotKVCache(2, 2, 16, 2, 8)
+        c = PagedKVCache(2, 2, 16, 2, 8)
         s = c.alloc()
         c.lengths[s] = 9
         c.free(s)
@@ -353,7 +353,7 @@ class TestKVCacheManager:
         sort-on-alloc): lowest-free-index order survives interleaved
         frees, and the double-free guard stays O(1) AND correct across
         alloc/free cycles — the regression the membership set pins."""
-        c = SlotKVCache(2, 4, 16, 2, 8)
+        c = PagedKVCache(2, 4, 16, 2, 8)
         assert [c.alloc() for _ in range(4)] == [0, 1, 2, 3]
         c.free(2)
         c.free(0)

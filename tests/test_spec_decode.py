@@ -172,10 +172,6 @@ class TestTruncate:
 
 
 class TestValidation:
-    def test_spec_requires_paged(self, model):
-        with pytest.raises(ValueError, match="paged"):
-            _engine(model, paged_attn=False, spec_decode=True)
-
     def test_spec_k_validated(self, model):
         with pytest.raises(ValueError, match="spec_k"):
             _engine(model, spec_decode=True, spec_k=0)

@@ -322,10 +322,6 @@ class TestTPValidation:
             _engine(model, tp=0)
         with pytest.raises(ValueError, match="collective_dtype"):
             _engine(model, tp=2, collective_dtype="fp8")
-        with pytest.raises(ValueError, match="unified ragged paged"):
-            _engine(model, tp=2, paged_attn=False)
-        with pytest.raises(ValueError, match="unified ragged paged"):
-            _engine(model, tp=2, ragged_step=False)
         with pytest.raises(ValueError, match="must divide"):
             _engine(model, tp=3)       # nh=4, nkv=2: 3 divides neither
         from paddle_tpu.serving.decode import _tp_mesh
