@@ -1048,6 +1048,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
 @jax.named_scope("ragged_step")
 def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, dec_mask, keys, temps, top_ks,
+                      prev_toks, take, chunk_keys, adopt,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
                       moe=None):
@@ -1071,9 +1072,25 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
              (spans that may keep ticking in the fused tail and whose
              appends are real), 0 for chunk rows / idle slots (their
              tail-tick writes are forced to drop)
-    keys/temps/top_ks: [R] per-slot sampling state — chunk rows carry
-             the sequence's resume key, live (sampling) only on their
-             FINAL chunk.
+    keys:    [R, 2] the engine's per-slot key state, the previous step's
+             ``keys'`` handed back as it is (a device array: no fetch)
+    temps/top_ks: [R] per-slot sampling knobs, live on a chunk row only
+             on its FINAL chunk
+    prev_toks/take: [R] — the dispatch-ahead inputs. ``prev_toks`` is
+             the previous step's ``tok_fin``, still on the device when
+             this step is dispatched before the host has read it;
+             ``take[r] = 1`` makes slot r's decode row read its input
+             token from ``prev_toks[r]`` instead of ``ids[qstart[r]]``.
+             A step with nothing in flight passes zeros for both: one
+             signature, one program.
+    chunk_keys/adopt: [R, 2] / [R] — a chunk row (``qlen > 0`` and
+             ``dec_mask == 0``) samples with the sequence's own resume
+             key ``chunk_keys[r]`` (host-known), merged over ``keys``
+             in-program; ``adopt[r] = 1`` (decode rows, a fresh
+             sequence's final chunk) stores the row's advanced key in
+             ``keys'``, 0 keeps the merged input key (a restored
+             sequence's final chunk resumes ITS walk; idle slots keep
+             theirs).
 
     Tick 0 runs the packed buffer through one forward pass — K/V
     scattered through the tables at per-token positions, attention via
@@ -1084,12 +1101,20 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     ticks are pure decode; ``dec_mask`` keeps a stray non-decode row's
     appends out of the pool regardless).
 
-    Returns ``(pool_k', pool_v', toks [n_steps, R], keys_t0, keys')``:
-    ``toks[0]``/``keys_t0`` are tick 0's per-slot sample + advanced key
-    (what a final chunk row adopts as its token 0 — the same split walk
-    as a one-shot prefill, so streams stay byte-identical); ``keys'``
-    is the post-scan key state the engine adopts for decode rows.
+    Returns ``(pool_k', pool_v', toks [n_steps, R], tok_fin [R],
+    keys' [R, 2])``: ``toks[0]`` is tick 0's per-slot sample (a final
+    chunk row's token 0 — the same split walk as a one-shot prefill, so
+    streams stay byte-identical); ``tok_fin`` is the last tick's sample
+    (the next step's ``prev_toks``) and ``keys'`` the next step's
+    ``keys``, both handed on without a host round trip.
     """
+    # dispatch-ahead: a decode row dispatched before the previous step's
+    # tokens reached the host takes its input token here, on the device
+    T = ids.shape[0]
+    ids = ids.at[jnp.where(take > 0, qstart, T)].set(prev_toks,
+                                                     mode="drop")
+    keys_in = jnp.where(((qlen > 0) & (dec_mask == 0))[:, None],
+                        chunk_keys, keys)
     s_tot = tables.shape[1] * _kv_data(pool_k).shape[2]
     sin, cos = _rope_tables(s_tot, hd, theta)
     stack = tuple(params[k] for k in _STACK_KEYS)
@@ -1101,7 +1126,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
         decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8, moe=moe)
     tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
-                                      keys, temps, top_ks, eps)
+                                      keys_in, temps, top_ks, eps)
 
     # ------------------------------------------- fused tail (pure decode)
     lens0 = jnp.where(dec_mask > 0, kvlen, 0)
@@ -1125,7 +1150,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         toks = jnp.concatenate([tok0[None], toks_rest], axis=0)
     else:
         toks, keys_fin = tok0[None], keys_t0
-    return (pk, pv, toks, keys_t0, keys_fin) \
+    keys_out = jnp.where((adopt > 0)[:, None], keys_fin, keys_in)
+    return (pk, pv, toks, toks[-1], keys_out) \
         + (() if moe_stats is None else (moe_stats,))
 
 
@@ -1161,7 +1187,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
         pool = _pool_pspec(kv_quant)
         return jax.jit(_tp_shard(
             impl, tp,
-            in_specs=(_params_pspec(wq8), pool, pool) + (rep,) * 11,
+            in_specs=(_params_pspec(wq8), pool, pool) + (rep,) * 15,
             out_specs=(pool, pool, rep, rep, rep)),
             donate_argnums=(1, 2) if donate else ())
     return jax.jit(

@@ -27,6 +27,16 @@ Offline use::
 Online use: call :meth:`submit` at arrival time and :meth:`step` in a
 loop; finished sequences come back from the step that retired them.
 ``model.generate()`` is a thin offline wrapper over this engine.
+
+The unified step is a pipeline one program deep (README "Serving", "A
+step is dispatched one ahead"): a :meth:`step` plans and dispatches
+program j and only then fences program j-1 and accepts its tokens, so
+the chip runs j while the host accepts, loops, admits and plans. A
+decode row of j takes its input token from j-1's output on the device;
+the plan counts the token in flight; a token is accepted only for the
+sequence it was computed for; whatever changes slots outside plan ->
+accept (cancel, evict, preemption, the pool repair, a deadline) fences
+and accepts what is in flight first (``_drain``).
 """
 from __future__ import annotations
 
@@ -45,6 +55,42 @@ from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, select_victims
 from .request import GenerationRequest, GenerationResult, Sequence
 from .scheduler import FIFOScheduler
+
+
+#: why the pipeline was emptied (``serving_pipeline_drains_total{reason}``):
+#: ``idle`` is a step with nothing to dispatch (the tail of the work, or
+#: every row's finish already known); the others are events that change
+#: slots outside plan -> accept; ``fault`` is a fence that raised (what was
+#: in flight is dropped, not accepted)
+DRAIN_REASONS = ("idle", "cancel", "evict", "preempt", "pool", "deadline",
+                 "snapshot", "fault")
+
+
+class _InFlight:
+    """One dispatched unified step whose tokens the host has not read."""
+    __slots__ = ("toks", "tok_fin", "moe", "keys_in", "n", "rows",
+                 "chunks", "ahead", "packed", "t_base")
+
+    def __init__(self, toks, tok_fin, moe, keys_in, n, rows, chunks,
+                 packed, t_base):
+        self.toks, self.tok_fin, self.moe = toks, tok_fin, moe
+        self.keys_in = keys_in      # the key state this step started from
+        self.n = n                  # ticks it fused
+        self.rows = rows            # [(slot, seq)]: its decode rows
+        self.chunks = chunks        # [(slot, seq, offset, tokens, final)]
+        self.packed = packed        # tokens in its packed buffer
+        # clock reading its cost counts from: the step() that dispatched
+        # it into an empty pipeline; None when it went behind another
+        # program (its cost then runs from that program's fence)
+        self.t_base = t_base
+        # what the NEXT plan must reckon with, by slot: (sequence, tokens
+        # it will have gained when this step is accepted, whether its
+        # next input token is this step's output)
+        self.ahead = {slot: (seq, n, True) for slot, seq in rows}
+        for slot, seq, _off, _ntok, final in chunks:
+            if final:       # a restored sequence adopts no sampled token
+                fresh = not seq.restore_point
+                self.ahead[slot] = (seq, int(fresh), fresh)
 
 
 class ContinuousBatchingEngine:
@@ -533,9 +579,23 @@ class ContinuousBatchingEngine:
             self.scheduler = FIFOScheduler(decode_chunk)
         self._slots = [None] * self.num_slots
         self._last_tok = np.zeros(self.num_slots, np.int32)
-        self._temps = np.zeros(self.num_slots, np.float32)
-        self._topks = np.zeros(self.num_slots, np.int32)
         self._keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
+        # the unified step's pipeline (module docstring): the program
+        # dispatched and not yet fenced, the zeros a step with nothing in
+        # flight passes as ``prev_toks``, the clock reading of the last
+        # fence, what the current step() fenced (tokens, chunk tokens),
+        # and sequences a drain outside step() finished (the next step()
+        # returns them)
+        self._inflight = None
+        self._no_toks = jnp.zeros((self.num_slots,), jnp.int32)
+        if self._tp_mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            rep = NamedSharding(self._tp_mesh, PartitionSpec())
+            self._keys = jax.device_put(self._keys, rep)
+            self._no_toks = jax.device_put(self._no_toks, rep)
+        self._t_fence = None
+        self._fenced = (0, 0)
+        self._finished_outside = []
         # jitted programs, shareable across engines of the same model so
         # a fresh engine never re-traces (model.generate passes the
         # model-level dict)
@@ -546,7 +606,8 @@ class ContinuousBatchingEngine:
                       "prefill_tokens_saved": 0,
                       "prefill_chunks": 0, "chunk_tokens": 0,
                       "step_prefill_tokens": 0, "step_decode_tokens": 0,
-                      "unified_steps": 0,
+                      "unified_steps": 0, "steps_dispatched_ahead": 0,
+                      **{"drains_" + r: 0 for r in DRAIN_REASONS},
                       "mtick_syncs": 0, "mtick_ticks": 0,
                       "mtick_pure_syncs": 0,
                       "last_decode_ticks": 0,
@@ -592,6 +653,11 @@ class ContinuousBatchingEngine:
         # on_token/on_finish. None on a policy-off engine — the step
         # loop never consults policy there.
         self.on_policy_preempt = None
+        # step-cost hook: on_step(duration_s) fires once per step program
+        # FENCED, where :meth:`_count_step` books it (inside step(), or
+        # at a drain outside one), so a consumer (the gateway's
+        # ``serving_step_duration_seconds``) counts programs, not calls
+        self.on_step = None
 
     # ------------------------------------------------------------- tracing
     def _tr(self):
@@ -766,11 +832,11 @@ class ContinuousBatchingEngine:
                 collective_overlap=self._coll_overlap,
                 **self._fn_consts(), **self._tp_consts(),
                 **self._q_consts())
-        # host reads the sampled tokens and the tick-0 keys (chunk
-        # installs), and a routed-FFN model's routing summary (result 5);
-        # keys_fin is adopted device-side via jnp.where
+        # host reads the sampled tokens and a routed-FFN model's routing
+        # summary (result 5); tok_fin and the key state stay on the
+        # device and feed the next step's program
         return self._wrap_prog(key, self._jit[key],
-                               host_out=(2, 3) + self._moe_out(5))
+                               host_out=(2,) + self._moe_out(5))
 
     def _mtick_fn(self):
         # like the ragged key: the full packed geometry (num_slots AND
@@ -1066,6 +1132,10 @@ class ContinuousBatchingEngine:
         if seq.status == "queued":
             if not self.scheduler.remove(seq):
                 return False
+        else:
+            self._drain("cancel")
+            if seq.done:        # the token in flight was its last
+                return False
         self.stats["cancelled"] += 1
         self._finish(seq, "cancelled", [])
         return True
@@ -1148,6 +1218,9 @@ class ContinuousBatchingEngine:
                 slot, [node.block_id for node in seq.prefix_nodes])
         seq.prefilled = covered
         self.cache.lengths[slot] = covered
+        # the chunk rows carry the key as a host array: fetch it once,
+        # here, where admission may wait for the chip, and never in a plan
+        seq.key = np.asarray(seq.key, np.uint32)
         seq.status = "prefilling"
         if seq.t_admitted is None:      # first claim only: queue wait
             seq.t_admitted = self._stamp_now()  # kept across restore
@@ -1261,14 +1334,18 @@ class ContinuousBatchingEngine:
                                   seq.work_len - seq.prefix_hit_tokens,
                                   finished)
 
-    def _advance_chunk(self, seq, n, tok0, key0, finished):
+    def _advance_chunk(self, seq, n, tok0, key0, finished, offset=None):
         """Per-chunk completion bookkeeping shared by the three step
         variants — the ONE place chunk accounting and the final-chunk
         install live, so they cannot silently diverge.
         ``tok0``/``key0`` are the chunk
         row's sampled token + advanced key, consumed only when this
-        chunk completes the prompt."""
-        slot, end = seq.slot, seq.prefilled + n
+        chunk completes the prompt. The unified step advanced
+        ``seq.prefilled`` when it dispatched the chunk and passes the
+        chunk's own ``offset``; its program installed the key itself
+        (``key0`` None)."""
+        off = seq.prefilled if offset is None else offset
+        slot, end = seq.slot, off + n
         seq.launches += 1               # rode this chunk's device call
         self.stats["prefill_chunks"] += 1
         self.stats["chunk_tokens"] += n
@@ -1279,11 +1356,12 @@ class ContinuousBatchingEngine:
             # prior chunk) to this chunk's host completion
             tr.complete(f"prefill_chunk[{seq.trace_chunk_i}]",
                         seq.trace_mark, tid=tr.req_tid(seq.request_id),
-                        args={"tokens": n, "offset": seq.prefilled})
+                        args={"tokens": n, "offset": off})
             seq.trace_mark = tr.now()
             seq.trace_chunk_i += 1
         self.cache.lengths[slot] = end
-        seq.prefilled = end
+        if offset is None:
+            seq.prefilled = end
         if end == seq.work_len:             # work content complete
             self.scheduler.leave_prefill(seq)
             self.stats["prefill_tokens_saved"] += seq.prefix_hit_tokens
@@ -1308,7 +1386,6 @@ class ContinuousBatchingEngine:
         walk resumes from the key snapshot taken when it was displaced,
         so the continuation is byte-identical and no consumer ever sees
         a replayed token."""
-        req = seq.request
         seq.slot = slot
         seq.status = "running"
         if seq.t_admitted is None:      # first claim only: queue wait
@@ -1320,17 +1397,19 @@ class ContinuousBatchingEngine:
                                "restored": bool(seq.restore_point)})
         seq.trace_phase = "decode"      # tracked even with tracing off
         self._slots[slot] = seq
-        self._temps[slot] = float(req.temperature)
-        self._topks[slot] = int(req.top_k)
         self.stats["prefills"] += 1
         self.stats["prefill_tokens"] += int(prefilled_tokens)
+        # key2 None: the unified step's program merged the slot's key into
+        # the device key state itself (``chunk_keys`` / ``adopt``)
         if seq.restore_point:
             self._last_tok[slot] = int(seq.tokens[-1])
-            self._keys = self._keys.at[slot].set(jnp.asarray(seq.key))
+            if key2 is not None:
+                self._keys = self._keys.at[slot].set(jnp.asarray(seq.key))
             return
         seq.tokens = [int(tok0)]
         self._last_tok[slot] = seq.tokens[0]
-        self._keys = self._keys.at[slot].set(key2)
+        if key2 is not None:
+            self._keys = self._keys.at[slot].set(key2)
         self.stats["tokens_generated"] += 1
         self._emit(seq, seq.tokens[0])
         self._maybe_finish(seq, finished)
@@ -1363,11 +1442,6 @@ class ContinuousBatchingEngine:
         slot = seq.slot
         if slot is not None and self._slots[slot] is seq:
             self._slots[slot] = None
-            # reset the slot's knobs: a stale temperature would keep the
-            # sampler's all-greedy fast path (decode.sample_rows)
-            # disabled for every later greedy-only batch
-            self._temps[slot] = 0.0
-            self._topks[slot] = 0
             self._last_tok[slot] = 0
             self._donate_and_free(seq, slot)
         if self.prefix_cache is not None and seq.prefix_nodes:
@@ -1412,8 +1486,12 @@ class ContinuousBatchingEngine:
         paying for decode at the first step boundary past its
         deadline)."""
         now = time.monotonic()
-        for seq in seqs:
-            if seq.done or seq.deadline is None or now < seq.deadline:
+        due = [seq for seq in seqs if not seq.done
+               and seq.deadline is not None and now >= seq.deadline]
+        if any(seq.status != "queued" for seq in due):
+            self._drain("deadline", finished)   # one may finish there
+        for seq in due:
+            if seq.done:
                 continue
             if seq.status == "queued" and not self.scheduler.remove(seq):
                 continue
@@ -1446,7 +1524,15 @@ class ContinuousBatchingEngine:
         step retries without re-admitting. Exhaustion that no
         preemption can repair re-raises. Anything the injected
         ``fault_hook`` raises other than PoolExhausted propagates to
-        the driver (the gateway's supervisor)."""
+        the driver (the gateway's supervisor).
+
+        The unified step is pipelined one program deep (module
+        docstring): this call dispatches program j and accepts program
+        j-1, so a token surfaces in the call after the one that
+        dispatched its program, and :meth:`has_work` stays true while a
+        program is in flight. Sequences that a drain outside a step
+        finished (:meth:`cancel` / :meth:`evict` with a program in
+        flight) are returned by the next call."""
         t0 = self._clock()
         self._stamp_t = t0
         tr = self._tr()
@@ -1454,14 +1540,14 @@ class ContinuousBatchingEngine:
             if tr is not None else None
         co = self._co()
         cost0 = co.snapshot() if co is not None else None
-        finished = []
+        finished, self._finished_outside = self._finished_outside, []
+        self._fenced = (0, 0)
         # deadline sweep BEFORE admission: an expired queued request
         # must never claim a slot (and a running one stops paying for
         # decode at the first step boundary past its deadline)
         self._expire_deadlines(
             list(self.scheduler.queue)
             + [s for s in self._slots if s is not None], finished)
-        step_tokens = chunk_tokens = 0
         admitted = []
         for attempt in range(self.num_slots + 2):
             try:
@@ -1474,7 +1560,7 @@ class ContinuousBatchingEngine:
                         # work BEFORE admission so the freed slots are
                         # in num_free for this very step's admission
                         self.scheduler.tracer = tr
-                        self._policy_preempt()
+                        self._policy_preempt(finished)
                     admitted = self.scheduler.admissions(
                         self.cache.num_free,
                         hit_len_fn=self._admission_hit_len
@@ -1485,21 +1571,32 @@ class ContinuousBatchingEngine:
                         with self._tspan("admit",
                                          args={"n": len(admitted)}):
                             self._admit_group(admitted, finished)
-                if self._spec:
-                    variant = self._spec_step
-                elif self._mtick:
-                    variant = self._multitick_step
+                if self._spec or self._mtick:
+                    # the synchronous variants: what they dispatched they
+                    # fenced, so the call's own duration is the step's
+                    variant = self._spec_step if self._spec \
+                        else self._multitick_step
+                    tokens, chunk_tokens = variant(finished)
+                    self._count_step(self._clock() - t0, tokens,
+                                     chunk_tokens)
                 else:
-                    variant = self._unified_step
-                step_tokens, chunk_tokens = variant(finished)
+                    self._unified_step(finished, t0)
                 break
             except PoolExhausted:
                 # unwind, preempt, retry — no device work was committed
                 # for the failed attempt (every raise site runs before
-                # its device call), so host bookkeeping is consistent
+                # its device call), so host bookkeeping is consistent.
+                # What an EARLIER step left in flight is fenced and
+                # accepted before a slot is torn down under it.
                 self._abort_admission(admitted)
                 admitted = []
-                if not self._preempt_youngest():
+                try:
+                    self._drain("pool", finished)
+                    repaired = self._preempt_youngest()
+                except BaseException:
+                    self._stamp_t = None
+                    raise
+                if not repaired:
                     self._stamp_t = None    # leaving the step: stamps
                     raise                   # must read a fresh clock
             except BaseException:
@@ -1512,16 +1609,13 @@ class ContinuousBatchingEngine:
                 self._stamp_t = None
                 raise
         self.stats["steps"] += 1
-        # what the step programs were given, split as an operator needs it
-        # (``serving_step_tokens_total{kind}``): chunk tokens are prefill,
-        # the rest are decode rows and their fused ticks
-        self.stats["step_prefill_tokens"] += chunk_tokens
-        self.stats["step_decode_tokens"] += step_tokens - chunk_tokens
-        self._record_step(self._clock() - t0, step_tokens, chunk_tokens > 0)
         self._stamp_t = None
         if co is not None:
             co.set_phase(None)
         if tr is not None:
+            # the tokens of the program this step FENCED (the one it
+            # dispatched is counted by the step that fences it)
+            step_tokens, chunk_tokens = self._fenced
             sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0})
             # counter tracks (ph:"C") on the same timeline as the step
             # spans, so Perfetto graphs cost alongside the phases:
@@ -1587,7 +1681,7 @@ class ContinuousBatchingEngine:
                 used[pclass.name] = used.get(pclass.name, 0) + 1
         return used
 
-    def _policy_preempt(self):
+    def _policy_preempt(self, finished):
         """SLO-driven preemption (README "Multi-tenant SLO serving"):
         when queued requests have burned past the urgency fraction of
         their TTFT budget and free slots cannot cover them, displace
@@ -1603,6 +1697,10 @@ class ContinuousBatchingEngine:
         urgent = self.scheduler.urgent(self._stamp_t)
         if not urgent:
             return
+        if len(urgent) > self.cache.num_free:
+            # a victim's slot is torn down below: first fence and accept
+            # what is in flight (its finishes may free the slots needed)
+            self._drain("preempt", finished)
         free = self.cache.num_free
         tr = self._tr()
         for seq in urgent[free:]:
@@ -1660,8 +1758,6 @@ class ContinuousBatchingEngine:
         if seq.tokens and seq.status == "running":
             seq.key = np.asarray(self._keys, np.uint32)[slot].copy()
         self._slots[slot] = None
-        self._temps[slot] = 0.0
-        self._topks[slot] = 0
         self._last_tok[slot] = 0
         self._donate_and_free(seq, slot)
         if self.prefix_cache is not None and seq.prefix_nodes:
@@ -1699,6 +1795,9 @@ class ContinuousBatchingEngine:
         if seq.status == "queued":
             return self.scheduler.remove(seq)
         if seq.slot is None or self._slots[seq.slot] is not seq:
+            return False
+        self._drain("evict")
+        if seq.done:                # the token in flight was its last
             return False
         self._displace(seq, "evicted")
         seq.status = "queued"   # slotless, awaiting the target restore
@@ -1740,6 +1839,21 @@ class ContinuousBatchingEngine:
         self.stats["restores"] += 1
         self.scheduler.submit(seq)
         return True
+
+    def _count_step(self, dt, tokens, chunk_tokens):
+        """Book one FENCED step program: its tokens by kind
+        (``serving_step_tokens_total{kind}``: chunk tokens are prefill,
+        the rest are decode rows and their fused ticks) and what it cost
+        (:meth:`_record_step`). The synchronous variants pass the call's
+        own duration; the pipelined unified step passes the interval from
+        the previous fence to this one, which is what a step costs while
+        the host's share of it overlaps the chip's."""
+        self.stats["step_prefill_tokens"] += chunk_tokens
+        self.stats["step_decode_tokens"] += tokens - chunk_tokens
+        self._fenced = (tokens, chunk_tokens)
+        self._record_step(dt, tokens, chunk_tokens > 0)
+        if self.on_step is not None:
+            self.on_step(float(dt))
 
     def _record_step(self, dt, tokens, had_chunks):
         """Feed the step's measured duration + processed tokens into
@@ -1794,7 +1908,7 @@ class ContinuousBatchingEngine:
         self.stats["headroom"] = budget
         return budget
 
-    def _unified_step(self, finished):
+    def _unified_step(self, finished, t0):
         """ONE device call for everything this step advances: every
         running slot contributes a span-1 decode row and every planned
         prefill chunk a span-n row to the packed token buffer of the
@@ -1802,25 +1916,37 @@ class ContinuousBatchingEngine:
         mixed step launches one program, and a mid-prefill slot costs
         its chunk span, not a full-length decode row. Pure-decode
         steps still fuse ``choose_num_steps`` ticks (the scan tail of
-        the same program). Returns ``(tokens_processed, chunk_tokens)``
-        for the headroom EWMAs and the prefill / decode token counters."""
+        the same program).
+
+        Pipelined one program deep: plan and dispatch program j, THEN
+        fence program j-1 (``device-wait``) and accept its tokens
+        (``host-accept``). The plan counts what j-1 will have advanced
+        (:meth:`_decode_candidates`; chunk progress moves at dispatch),
+        a decode row whose last token is still on the device takes it
+        there (``take``), and nothing between ``plan`` and ``dispatch``
+        reads an output of j-1. With nothing to dispatch the call only
+        fences (an ``idle`` drain); with nothing in flight it only
+        dispatches. ``t0`` is the step's start reading of the clock."""
         tr = self._tr()
         sp = tr.span("plan") if tr is not None else None
         co = self._co()
         if co is not None:
             co.set_phase("plan")
+        prev = self._inflight
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
             plan = self.scheduler.prefill_plan(self._prefill_budget(),
                                                self.cache.block_size,
                                                cap=self._chunk)
-        active = [s for s in self._slots
-                  if s is not None and s.status == "running"]
-        if not active and not plan:
+        cands = self._decode_candidates()
+        if not cands and not plan:
             if tr is not None:
                 sp.end({"rows": 0, "chunks": 0})
-            return 0, 0
-        n = self.scheduler.choose_num_steps(active) if active else 1
+            self._drain("idle", finished)
+            return
+        n = self.scheduler.choose_num_steps(
+            [c[1] for c in cands], budgets=[c[4] for c in cands]) \
+            if cands else 1
         R, T = self.num_slots, self._token_budget
         ids = np.zeros(T, np.int32)
         seg = np.full(T, R, np.int32)       # sentinel: dead packed rows
@@ -1831,133 +1957,259 @@ class ContinuousBatchingEngine:
         dec_mask = np.zeros(R, np.int32)
         temps = np.zeros(R, np.float32)
         topks = np.zeros(R, np.int32)
-        keys = np.asarray(self._keys, np.uint32).copy()
-        cursor = self._pack_decode_rows(n, ids, seg, pos, qstart, qlen,
-                                        kvlen, dec_mask, temps, topks)
+        take = np.zeros(R, np.int32)
+        chunk_keys = np.zeros((R, 2), np.uint32)
+        rows, cursor = self._pack_decode_rows(
+            cands, n, ids, seg, pos, qstart, qlen, kvlen, dec_mask, temps,
+            topks, take=take)
         chunk_rows, cursor = self._pack_chunk_rows(
-            plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
+            plan, cursor, ids, seg, pos, qstart, qlen, kvlen, chunk_keys,
             temps, topks)
+        # whose advanced key the program stores: decode rows, and a fresh
+        # sequence's final chunk (a restored one resumes its own walk)
+        adopt = dec_mask.copy()
+        for slot, seq, _ntok, final in chunk_rows:
+            adopt[slot] = int(final and not seq.restore_point)
         if tr is not None:
             # plan: admission already ran in step(); this is the chunk
-            # grant + span packing. launch: the one device program, as
-            # dispatch (the jitted call returns) and device-wait (the
-            # host transfer that fences it). host-accept: token/chunk
-            # bookkeeping (donate spans nest inside it).
-            sp.end({"rows": len(active), "chunks": len(plan),
+            # grant + span packing. launch: dispatch (the jitted call of
+            # THIS step's program returns) and device-wait (the host
+            # transfer that fences the PREVIOUS program). host-accept:
+            # that program's token/chunk bookkeeping (donate spans nest
+            # inside it).
+            sp.end({"rows": len(rows), "chunks": len(plan),
                     "fused_steps": n})
             launch = tr.span("launch")
-            sp = tr.span("dispatch", args=self._dispatch_args(
-                qstart, qlen, kvlen, T, len(active), n * len(active),
-                cursor - len(active)))
+            args = self._dispatch_args(
+                qstart, qlen, kvlen, T, len(rows), n * len(rows),
+                cursor - len(rows))
+            args["ahead"] = int(prev is not None)
+            sp = tr.span("dispatch", args=args)
         if co is not None:
             co.set_phase("launch")
-        npk, npv, toks, keys_t0, keys_fin, *moe = self._ragged_fn(n)(
+        keys_in = self._keys
+        npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n)(
             self._params, *self.cache.kv_args(),
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
-            dec_mask, keys, temps, topks)
+            dec_mask, keys_in, temps, topks,
+            self._no_toks if prev is None else prev.tok_fin, take,
+            chunk_keys, adopt)
+        # the program is on the device's queue: commit what it advances
         self.cache.update(npk, npv)
-        if tr is not None:
-            sp.end()
-            sp = tr.span("device-wait")
-        toks_np = np.asarray(toks)          # [n, R]
-        keys_t0_np = np.asarray(keys_t0)
-        moe = self._count_moe(moe)
+        self._keys = keys_out
+        chunks = []
+        for slot, seq, ntok, final in chunk_rows:
+            chunks.append((slot, seq, seq.prefilled, ntok, final))
+            seq.prefilled += ntok
+            if final:       # no further chunk; its decode row comes next
+                self.scheduler.leave_prefill(seq)
+        self._inflight = _InFlight(
+            toks, tok_fin, moe, keys_in, n, rows, chunks, cursor,
+            None if prev is not None else t0)
         self.stats["unified_steps"] += 1
+        self.stats["steps_dispatched_ahead"] += prev is not None
         if co is not None:
             # sharded launch: tick 0 all-reduces the PADDED packed
             # buffer (the device computes full shapes), each fused tail
             # tick the per-slot row block — exact, shape-derived
             self._record_collectives(
                 co, [(self._token_budget, 1), (self.num_slots, n - 1)])
-            co.set_phase("host-accept")
         if tr is not None:
-            sp.end(moe)     # the routing this step's wait fenced
+            sp.end()
+        if prev is not None:
+            self._fence(prev, finished, launch if tr is not None else None,
+                        {"packed_tokens": cursor, "fused_steps": n})
+        elif tr is not None:
             launch.end({"packed_tokens": cursor, "fused_steps": n})
-            sp = tr.span("host-accept")
-        if active:
-            # decode rows adopt the post-scan key walk; chunk/idle rows
-            # keep their host-side key state (a final chunk adopts its
-            # tick-0 key inside _install_seq below)
-            self._keys = jnp.where(
-                jnp.asarray(dec_mask[:, None].astype(bool)),
-                keys_fin, self._keys)
-        # chunk bookkeeping first: a final chunk's _install_seq emits
-        # its token 0 before this step's decode rows surface theirs
-        for slot, seq, ntok, final in chunk_rows:
-            self._advance_chunk(seq, ntok, toks_np[0, slot],
-                                keys_t0_np[slot], finished)
-        if active:
-            self.stats["decode_calls"] += 1
-            self.stats["decode_steps"] += n
-            self.stats["slot_steps"] += n * self.num_slots
-            for slot in range(self.num_slots):
-                s = self._slots[slot]
-                if s is not None and dec_mask[slot]:
-                    s.launches += 1     # rode this step's one program
-            self._accept_decode_rows(toks_np, n, dec_mask, finished)
-        if tr is not None:
-            sp.end({"emitted": n * len(active)})
-        return cursor + (n - 1) * len(active), cursor - len(active)
 
-    def _pack_decode_rows(self, n, ids, seg, pos, qstart, qlen, kvlen,
-                          dec_mask, temps, topks, eos_ids=None,
-                          budgets=None):
-        """Pack every RUNNING slot's span-1 decode row into the packed
-        token buffer — the ONE decode-row assembly shared by the
-        unified and multi-tick steps (``_pack_chunk_rows``' twin), so
-        the packing and table-pre-growth rules cannot silently
-        diverge. Pre-grows each slot's table for the fused block:
-        ``n`` rows on the unified scan (it appends unconditionally);
-        ``min(n, remaining)`` when the alive-mask metadata
-        (``eos_ids``/``budgets``) is being packed, because the device
-        stops a row's appends exactly at its EOS/budget cut. Returns
-        the cursor past the packed decode rows."""
+    def _decode_candidates(self):
+        """Every slot the next step program can carry a decode row for,
+        reckoning with the program in flight: ``(slot, seq, length,
+        take, budget)`` — the KV rows the slot will hold once that
+        program is accepted, whether the row's input token is that
+        program's output (still on the device), and the tokens the
+        sequence may still generate after it. A sequence whose budget the
+        token in flight exhausts is left out: its ``length`` finish is
+        known ahead of time, so no row is wasted on it. Only an EOS is
+        found a step late: that row has then ridden one program too
+        many, whose token :meth:`_accept_decode_rows` drops and whose KV
+        row lies past the sequence's end, in a block nothing reads
+        before rewriting it. With nothing in flight this is the running
+        slots as they stand."""
+        fl = self._inflight
+        ahead = fl.ahead if fl is not None else {}
         lens = self.cache.lengths
-        cursor = 0
+        out = []
         for slot, s in enumerate(self._slots):
-            if s is None or s.status != "running":
+            if s is None:
                 continue
-            grow = n if budgets is None else min(n, s.remaining)
-            self.cache.ensure_capacity(slot, int(lens[slot]) + grow)
+            gained, take = 0, False
+            entry = ahead.get(slot)
+            if entry is not None and entry[0] is s:
+                gained, take = entry[1], entry[2]
+            elif s.status != "running":
+                continue
+            # a final chunk in flight: the slot holds the whole work
+            # content once accepted (token 0's own row comes with the
+            # decode row that reads it)
+            length = int(lens[slot]) + gained if s.status == "running" \
+                else s.work_len
+            budget = s.remaining - gained
+            if budget > 0:
+                out.append((slot, s, length, take, budget))
+        return out
+
+    def _pack_decode_rows(self, cands, n, ids, seg, pos, qstart, qlen,
+                          kvlen, dec_mask, temps, topks, eos_ids=None,
+                          budgets=None, take=None):
+        """Pack the decode candidates' span-1 rows
+        (:meth:`_decode_candidates`) into the packed token buffer — the
+        ONE decode-row assembly shared by the unified and multi-tick
+        steps (``_pack_chunk_rows``' twin), so the packing and
+        table-pre-growth rules cannot silently diverge. Pre-grows each
+        slot's table for the fused block: ``n`` rows on the unified scan
+        (it appends unconditionally); ``min(n, remaining)`` when the
+        alive-mask metadata (``eos_ids``/``budgets``) is being packed,
+        because the device stops a row's appends exactly at its
+        EOS/budget cut. ``take`` (the pipelined unified step) is set
+        where the input token is the in-flight program's output.
+        Returns ``(rows, cursor)``: the ``(slot, seq)`` pairs packed and
+        the cursor past them."""
+        rows, cursor = [], 0
+        for slot, s, length, from_device, budget in cands:
+            grow = n if budgets is None else min(n, budget)
+            self.cache.ensure_capacity(slot, length + grow)
             qstart[slot] = cursor
             qlen[slot] = 1
-            kvlen[slot] = int(lens[slot]) + 1
+            kvlen[slot] = length + 1
             dec_mask[slot] = 1
-            ids[cursor] = self._last_tok[slot]
+            if from_device:
+                take[slot] = 1
+            elif s.status == "running":
+                ids[cursor] = self._last_tok[slot]
+            else:           # restored, its final chunk in flight
+                ids[cursor] = s.tokens[-1]
             seg[cursor] = slot
-            pos[cursor] = int(lens[slot])
-            temps[slot] = self._temps[slot]
-            topks[slot] = self._topks[slot]
+            pos[cursor] = length
+            temps[slot] = float(s.request.temperature)
+            topks[slot] = int(s.request.top_k)
             if eos_ids is not None:
                 eos = s.request.eos_token_id
                 eos_ids[slot] = -1 if eos is None else int(eos)
-                budgets[slot] = s.remaining
+                budgets[slot] = budget
+            rows.append((slot, s))
             cursor += 1
-        return cursor
+        return rows, cursor
 
-    def _accept_decode_rows(self, toks_np, n, dec_mask, finished,
+    def _fence(self, rec, finished, launch=None, launch_args=None):
+        """Fence one dispatched unified step and accept what it computed:
+        ``device-wait`` (the host transfer of its tokens, with the
+        routing summary of the same program as the span's args) under
+        ``launch`` (the caller's, when this call also dispatched), then
+        ``host-accept``. An exception out of the program surfaces here;
+        what was in flight is then dropped, never half accepted."""
+        tr = self._tr()
+        co = self._co()
+        if tr is not None:
+            if launch is None:
+                launch = tr.span("launch")
+            sp = tr.span("device-wait")
+        try:
+            toks_np = np.asarray(rec.toks)          # [n, R]
+            moe = self._count_moe(rec.moe)
+        except BaseException:
+            self._abandon(rec)
+            raise
+        now = self._clock()
+        base = rec.t_base if rec.t_base is not None else self._t_fence
+        self._t_fence = now
+        if co is not None:
+            co.set_phase("host-accept")
+        if tr is not None:
+            sp.end(moe)     # the routing of the step this wait fenced
+            launch.end(launch_args)
+            sp = tr.span("host-accept")
+        n, rows = rec.n, rec.rows
+        # chunk bookkeeping first: a final chunk's _install_seq emits
+        # its token 0 before this step's decode rows surface theirs
+        for slot, seq, off, ntok, final in rec.chunks:
+            if seq.status == "prefilling" and self._slots[slot] is seq:
+                self._advance_chunk(seq, ntok, toks_np[0, slot], None,
+                                    finished, offset=off)
+        emitted = 0
+        if rows:
+            self.stats["decode_calls"] += 1
+            self.stats["decode_steps"] += n
+            self.stats["slot_steps"] += n * self.num_slots
+            emitted = self._accept_decode_rows(toks_np, n, rows, finished)
+        if tr is not None:
+            sp.end({"emitted": emitted})
+        self._count_step(now - base, rec.packed + (n - 1) * len(rows),
+                         rec.packed - len(rows))
+
+    def _drain(self, reason, finished=None):
+        """Fence and accept the program in flight, if any: what every
+        path that changes slots outside plan -> accept calls first, so
+        that it sees (and tears down) only accepted state. Sequences the
+        accept finishes go to ``finished``; outside a step they are kept
+        for the next :meth:`step` to return. Counted by ``reason``
+        (``serving_pipeline_drains_total``; ``snapshot`` is the gateway's
+        recovery snapshot)."""
+        rec = self._inflight
+        if rec is None:
+            return
+        self._inflight = None
+        self._fence(rec, finished if finished is not None
+                    else self._finished_outside)
+        self.stats["drains_" + reason] += 1     # a fence that raised
+        # counted itself, as ``fault``
+
+    def _abandon(self, rec):
+        """A fence raised: drop ``rec`` and anything dispatched behind it
+        without accepting a token. Host state goes back to what was
+        accepted: the key state is the one ``rec`` started from (a
+        program's keys are ahead of the accepted tokens by what it
+        sampled) and chunk progress returns to the first dropped chunk's
+        offset. The rows the dropped programs wrote lie past every
+        accepted length."""
+        later, self._inflight = self._inflight, None
+        self._keys = rec.keys_in
+        self.stats["drains_fault"] += 1
+        for r in (later, rec):      # the earlier offsets win
+            if r is None:
+                continue
+            for _slot, seq, off, _ntok, final in reversed(r.chunks):
+                if seq.status != "prefilling":
+                    continue
+                seq.prefilled = off
+                if final and seq not in self.scheduler.prefilling:
+                    self.scheduler.prefilling.appendleft(seq)
+
+    def _accept_decode_rows(self, toks_np, n, rows, finished,
                             counts=None):
         """Host-accept of the fused ticks' ``[n, R]`` token block —
         the ONE trim loop shared by the unified and multi-tick steps,
         so the accept/trim rules (EOS and budget cuts via
         ``_maybe_finish``, per-token bookkeeping) cannot silently
-        diverge. Tick-major like the device computed it; a slot whose
-        sequence finished at an earlier tick is skipped from then on
-        (on the multi-tick path the device's alive cut equals this
-        trim, so the skipped entries are masked garbage that never
-        surfaces). ``counts`` (optional [R] array) receives each
-        slot's accepted-token count — the multi-tick key-walk
-        adoption index. Returns tokens emitted."""
+        diverge. Tick-major like the device computed it. ``rows`` are
+        the ``(slot, seq)`` pairs the program was DISPATCHED with: a
+        token is accepted only for the sequence it was computed for,
+        still running in that slot — by the time a pipelined step is
+        accepted the slot may be free or hold another sequence (an EOS
+        found a step late, then an admission), and a sequence that
+        finished at an earlier tick is skipped from then on (on the
+        multi-tick path the device's alive cut equals this trim, so the
+        skipped entries are masked garbage that never surfaces).
+        ``counts`` (optional [R] array) receives each slot's
+        accepted-token count — the multi-tick key-walk adoption index.
+        Returns tokens emitted."""
         emitted = 0
         for i in range(n):
-            for slot in range(self.num_slots):
-                seq = self._slots[slot]
-                if seq is None or seq.status != "running" \
-                        or not dec_mask[slot]:
-                    continue  # freed/mid-prefill slot, finished at an
-                    # earlier tick, or a span this call did not decode
-                    # (a chunk row installed above starts decoding
-                    # NEXT step); its sampled garbage never surfaces
+            for slot, seq in rows:
+                if seq.status != "running" or self._slots[slot] is not seq:
+                    continue
+                if i == 0:
+                    seq.launches += 1   # rode this step's one program
                 t = int(toks_np[i, slot])
                 seq.tokens.append(t)
                 if counts is not None:
@@ -1999,8 +2251,8 @@ class ContinuousBatchingEngine:
             plan = self.scheduler.prefill_plan(self._prefill_budget(),
                                                self.cache.block_size,
                                                cap=self._chunk)
-        active = [s for s in self._slots
-                  if s is not None and s.status == "running"]
+        cands = self._decode_candidates()   # nothing is ever in flight
+        active = [c[1] for c in cands]      # here: the running slots
         if not active and not plan:
             if tr is not None:
                 sp.end({"rows": 0, "chunks": 0})
@@ -2025,10 +2277,9 @@ class ContinuousBatchingEngine:
         # (min(n, remaining) rows — the device stops at the cut), so
         # no mid-block host intervention, no fallback at block
         # boundaries
-        cursor = self._pack_decode_rows(n, ids, seg, pos, qstart, qlen,
-                                        kvlen, dec_mask, temps, topks,
-                                        eos_ids=eos_ids,
-                                        budgets=budgets)
+        rows, cursor = self._pack_decode_rows(
+            cands, n, ids, seg, pos, qstart, qlen, kvlen, dec_mask, temps,
+            topks, eos_ids=eos_ids, budgets=budgets)
         chunk_rows, cursor = self._pack_chunk_rows(
             plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
             temps, topks)
@@ -2088,12 +2339,8 @@ class ContinuousBatchingEngine:
                 self.stats["mtick_pure_syncs"] += 1
             self.stats["last_decode_ticks"] = ticks
             counts = np.zeros(R, np.int32)  # accepted tokens per slot
-            for slot in range(self.num_slots):
-                s = self._slots[slot]
-                if s is not None and dec_mask[slot]:
-                    s.launches += 1     # rode this step's one program
             emitted_total = self._accept_decode_rows(
-                toks_np, ticks, dec_mask, finished, counts=counts)
+                toks_np, ticks, rows, finished, counts=counts)
             # adopt each SURVIVING decode row's key at its trim cut:
             # keys_walk[m - 1] for a row that accepted m tokens (a
             # still-running row accepted every tick, so this is the
@@ -2235,8 +2482,8 @@ class ContinuousBatchingEngine:
                 ids[cursor + 1:cursor + q] = d
             seg[cursor:cursor + q] = slot
             pos[cursor:cursor + q] = np.arange(L0, L0 + q, dtype=np.int32)
-            temps[slot] = self._temps[slot]
-            topks[slot] = self._topks[slot]
+            temps[slot] = float(s.request.temperature)
+            topks[slot] = int(s.request.top_k)
             verify_rows.append((slot, s, d, L0))
             cursor += q
         chunk_rows, cursor = self._pack_chunk_rows(
@@ -2339,6 +2586,7 @@ class ContinuousBatchingEngine:
 
     def has_work(self) -> bool:
         return bool(self.scheduler.num_queued
+                    or self._inflight is not None
                     or any(s is not None for s in self._slots))
 
     @property

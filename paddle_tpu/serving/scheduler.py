@@ -229,7 +229,7 @@ class FIFOScheduler:
             s.remaining for s in active_seqs)
         return max(1, min(int(max_ticks), horizon))
 
-    def choose_num_steps(self, active_seqs) -> int:
+    def choose_num_steps(self, active_seqs, budgets=None) -> int:
         """How many decode steps to fuse into the next device call:
         the largest power of two that fits both ``decode_chunk`` and
         every active sequence's remaining budget. Powers of two keep the
@@ -240,11 +240,14 @@ class FIFOScheduler:
         In-flight chunked prefills also force single-stepping: fusing n
         decode ticks would delay the next prompt chunk by n-1 ticks,
         exactly the TTFT head-of-line blocking chunking exists to
-        remove."""
+        remove. ``budgets`` replaces each sequence's ``remaining`` where
+        the engine knows better (a pipelined step: less the tokens
+        already in flight)."""
         if self.decode_chunk == 1 or self.queue or self.prefilling \
                 or not active_seqs:
             return 1
-        m = min(s.remaining for s in active_seqs)
+        m = min(budgets) if budgets is not None \
+            else min(s.remaining for s in active_seqs)
         n = 1
         while n * 2 <= min(m, self.decode_chunk):
             n *= 2

@@ -82,6 +82,7 @@ from ...profiler.metrics import (QUEUE_WAIT_BUCKETS, SPEC_ACCEPT_BUCKETS,
                                  MetricsRegistry)
 from ...profiler.tracing import TID_GATEWAY, SpanTracer
 from ...utils.log import get_logger
+from ..engine import DRAIN_REASONS
 from ..faults import TransientFault
 
 #: engine ``stats`` counters whose /metrics series must stay monotonic
@@ -103,7 +104,8 @@ CARRIED_ENGINE_STATS = (
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
     "step_decode_tokens", "moe_pairs", "moe_experts_touched",
-    "moe_max_expert_pairs", "moe_layer_calls")
+    "moe_max_expert_pairs", "moe_layer_calls", "steps_dispatched_ahead",
+) + tuple("drains_" + r for r in DRAIN_REASONS)
 
 #: same carry for the prefix cache's own stats dict (a rebuild builds a
 #: fresh trie — and a fresh host tier — zeroing every counter here).
@@ -332,6 +334,7 @@ class ServingGateway:
         engine.on_token = self._on_token
         engine.on_finish = self._on_finish
         engine.on_policy_preempt = self._on_policy_preempt
+        engine.on_step = self._on_step
         if fault_hook is not None:
             engine.fault_hook = fault_hook
         self._init_metrics(registry)
@@ -453,6 +456,26 @@ class ServingGateway:
                            kind="prefill")
         step_tokens.set_fn(lambda: self._stat("step_decode_tokens"),
                            kind="decode")
+        # the pipeline of the unified step (README "Serving"): over
+        # serving_step_duration_seconds_count the first is the share of
+        # steps whose program went to the chip behind another one
+        r.counter(
+            "serving_steps_dispatched_ahead_total",
+            "Step programs dispatched while the previous one was still in "
+            "flight (its tokens not yet read by the host). Monotonic "
+            "across engine rebuilds."
+        ).set_fn(lambda: self._stat("steps_dispatched_ahead"))
+        drains = r.counter(
+            "serving_pipeline_drains_total",
+            "Times the program in flight was fenced and accepted with "
+            "none dispatched behind it, by reason: idle (nothing to "
+            "dispatch), cancel, evict, preempt, pool, deadline, snapshot "
+            "(recovery), fault (a fence raised; dropped, not accepted). "
+            "Monotonic across engine rebuilds.")
+        for reason in DRAIN_REASONS:
+            drains.set_fn(
+                lambda reason=reason: self._stat("drains_" + reason),
+                reason=reason)
         if self.engine.routed_ffn:
             # a routed-FFN model only: a dense model's /metrics document
             # stays as it was. Each is summed over the layer calls of
@@ -481,12 +504,14 @@ class ServingGateway:
                   "across engine rebuilds.").set_fn(
             lambda: self._stat("prefill_chunks"))
         # per-step telemetry: the SAME duration/token measurements the
-        # engine's headroom EWMAs (adaptive chunk budget) read — the
-        # driver observes them after every step() it pumps
+        # engine's headroom EWMAs (adaptive chunk budget) read — observed
+        # once per step program FENCED (``engine.on_step``), inside a
+        # step() or at a drain outside one, so _count counts programs
         self._m_step_dur = r.histogram(
             "serving_step_duration_seconds",
-            "Engine step() wall duration (admission + prefill grant + "
-            "decode + retire).", buckets=STEP_BUCKETS)
+            "What one step program cost, fence to fence (admission + "
+            "prefill grant + decode + retire; the driver's loop between "
+            "two steps too).", buckets=STEP_BUCKETS)
         r.gauge("serving_step_tokens",
                 "Tokens the last engine step processed on device "
                 "(decode rows x fused ticks + prefill chunk tokens)."
@@ -936,6 +961,10 @@ class ServingGateway:
                 victim_class=pclass.name if pclass is not None
                 else "unknown")
 
+    def _on_step(self, duration_s):
+        """Engine hook: one step program was fenced and booked."""
+        self._m_step_dur.observe(duration_s)
+
     # ------------------------------------------------------- driver thread
     def _admit_intake(self):
         while True:
@@ -1049,6 +1078,14 @@ class ServingGateway:
                     self._suspect_ids
                     and seq.request_id in self._suspect_ids):
                 continue                # mid-bisection: not migratable
+            if seq.status != "queued" \
+                    and not self._drain_supervised("evict"):
+                # the fence faulted and the supervisor dealt with it: ask
+                # again on the loop's next pass, of the engine that is
+                # there then
+                with self._lock:
+                    self._migrate_out.appendleft((stream, handoff))
+                return
             if not self.engine.evict(seq):
                 continue
             del self._live[seq.request_id]
@@ -1081,7 +1118,28 @@ class ServingGateway:
                 seq.finish_reason = "cancelled"
                 self._on_finish(seq)
                 continue
+            if not seq.done and seq.status != "queued" \
+                    and not self._drain_supervised("cancel"):
+                return      # faulted: the flags stand, the next pass acts
             self.engine.cancel(seq)         # fires _on_finish
+
+    def _drain_supervised(self, reason) -> bool:
+        """Fence and accept the engine's program in flight under the
+        supervisor, before a cancel or an eviction tears a slot down
+        between two steps (the engine's own drain there is then a no-op).
+        In steady decode a program is in flight nearly always, so this is
+        the fence a client's disconnect meets: a device fault that
+        surfaces at it is classified, retried or recovered from like one
+        inside ``step()``, not the driver's death. False when it
+        faulted: the caller leaves its request standing for the loop's
+        next pass, which finds the pipeline empty or the engine
+        rebuilt."""
+        try:
+            self.engine._drain(reason)
+        except Exception as e:
+            self._on_fault(e)
+            return False
+        return True
 
     def _sweep_parked_deadlines(self):
         """Bisection-parked sequences live outside the engine, so its
@@ -1187,7 +1245,6 @@ class ServingGateway:
             # first completed step on the rebuilt engine: recovery done
             self.restart_latencies.append(self._clock() - self._fault_at)
             self._fault_at = None
-        self._m_step_dur.observe(self.engine.stats["last_step_duration_s"])
         if self._m_spec_len is not None:
             # drain the step's per-span acceptance lengths into the
             # histogram (driver thread is the only reader/writer)
@@ -1247,7 +1304,15 @@ class ServingGateway:
         sampled continuations restart mid-walk; unreadable device state
         (real crashes can corrupt it) only costs sampled-stream
         identity, recovery itself runs on host token state — plus the
-        still-queued sequences. Returns ``(live, queued)``."""
+        still-queued sequences. A step program still in flight is
+        fenced and accepted first (its tokens are valid and the key state
+        is ahead of the accepted tokens until they are); a fence that
+        raises drops it, and the engine restores the keys itself.
+        Returns ``(live, queued)``."""
+        try:
+            engine._drain("snapshot")
+        except Exception:
+            pass
         try:
             keys = np.asarray(engine._keys, np.uint32)
         except Exception:
@@ -1320,14 +1385,15 @@ class ServingGateway:
         # aside and swapped in below WITH the new engine — one store —
         # so concurrent scrapes never see base and engine from
         # different epochs.
+        live, queued = self._snapshot_live(old)     # drains: counts move
         base, pc_base, _ = self._counter_state
         new_base = {k: base[k] + old.stats[k]
                     for k in CARRIED_ENGINE_STATS}
-        live, queued = self._snapshot_live(old)
         new = self.engine_factory()
         new.on_token = self._on_token
         new.on_finish = self._on_finish
         new.on_policy_preempt = self._on_policy_preempt
+        new.on_step = self._on_step
         new.tracer = self.tracer     # one timeline across incarnations
         new.cost = self.cost         # one cost account, monotonic too
         if self._fault_hook is not None:

@@ -204,7 +204,8 @@ class TestUnifiedStepLeavesThePoolInPlace:
             return step.lower(
                 params, pool, pool, v5e((self.R, self.MB), i32), tok, tok,
                 tok, row, row, row, row, v5e((self.R, 2), jnp.uint32),
-                v5e((self.R,), jnp.float32), row).compile()
+                v5e((self.R,), jnp.float32), row, row, row,
+                v5e((self.R, 2), jnp.uint32), row).compile()
 
     def test_no_op_but_the_scatter_returns_a_pool_layer(self, v5e):
         import re

@@ -120,21 +120,26 @@ class TestTransparency:
     def test_decode_slots_keep_emitting_while_chunk_runs(self, model):
         """The TTFT property itself: on every step that advances a
         pending prefill chunk, the live decode slot still emits a
-        token — no decode batch ever waits behind the long prompt."""
+        token — no decode batch ever waits behind the long prompt.
+        (A step accepts the program dispatched by the step before it, so
+        the long prompt runs one step after its final chunk went out.)"""
         eng = _engine(model)
         short = eng.submit(_req(10, n=8, max_new_tokens=40))
         eng.step()                      # short admitted + first token
         assert short.status == "running"
         longy = eng.submit(_req(11, n=80, max_new_tokens=4))
-        n_chunk_steps = 0
+        n_steps = 0
         while longy.status != "running":
             before = len(short.tokens)
             chunks0 = eng.stats["prefill_chunks"]
             eng.step()
-            assert eng.stats["prefill_chunks"] == chunks0 + 1
+            # the first of these steps dispatches chunk 0 and accepts a
+            # decode-only program; each later one accepts a chunk
+            assert eng.stats["prefill_chunks"] == chunks0 + (n_steps > 0)
             assert len(short.tokens) == before + 1  # decode kept going
-            n_chunk_steps += 1
-        assert n_chunk_steps == 5       # ceil(80 / 16) chunks
+            n_steps += 1
+        assert n_steps == 5 + 1         # ceil(80 / 16) chunks
+        assert eng.stats["prefill_chunks"] == 5
         # the long prompt's stream is still the solo/unchunked one
         while eng.has_work():
             eng.step()
@@ -152,7 +157,9 @@ class TestTransparency:
             offs.append(seq.prefilled)
             eng.step()
         assert seq.status in ("running", "finished")
-        assert offs == [16, 32, 48]     # block-aligned resume offsets
+        # block-aligned resume offsets, advanced as each chunk is
+        # dispatched; 50 while the final chunk is in flight
+        assert offs == [16, 32, 48, 50]
         assert seq.prefilled == 50
 
 

@@ -1097,7 +1097,7 @@ class TestGuardDiscipline:
         # the step syncs the scheduler's alias before consulting policy
         assert "self.scheduler.tracer = tr" in eng
         assert eng.index("self.scheduler.tracer = tr") < \
-            eng.index("self._policy_preempt()")
+            eng.index("self._policy_preempt(finished)")
 
 
 # ---------------------------------------------------- profiler CLI (json)

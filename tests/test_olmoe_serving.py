@@ -67,9 +67,17 @@ def _serve_recording_logits(model, prompt, n_new, monkeypatch,
     rows = []
 
     def on_token(seq, _tok):
+        # a record a program, in dispatch order: a whole-prompt prefill's
+        # has the group's rows, a unified step's one row a slot. Token 0 of
+        # a whole prompt comes from the newest prefill record; any other
+        # token from the unified step being accepted, which is the last
+        # one dispatched but one while another is in flight behind it
         jax.effects_barrier()
-        last = records[-1]
-        rows.append(last[seq.slot] if last.shape[0] == SLOTS else last[0])
+        if len(seq.tokens) == 1 and seq.work_len <= GEOMETRY["prefill_chunk"]:
+            rows.append([r for r in records if r.shape[0] != SLOTS][-1][0])
+            return
+        steps = [r for r in records if r.shape[0] == SLOTS]
+        rows.append(steps[-2 if eng._inflight is not None else -1][seq.slot])
 
     eng.on_token = on_token
     seq = eng.submit(GenerationRequest(prompt, max_new_tokens=n_new))

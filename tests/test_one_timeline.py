@@ -175,17 +175,44 @@ class TestEngineSpans:
         assert mirror.opened == [] and clock.reads == 0
 
     def test_launch_holds_dispatch_and_device_wait(self, model, jit_cache):
-        _, tr, _ = self._run(model, jit_cache, None,
-                             clock=lambda: time.perf_counter)
+        eng, tr, _ = self._run(model, jit_cache, None,
+                               clock=lambda: time.perf_counter)
         evs = [e for e in tr.events() if e["ph"] == "X"]
         launches = [e for e in evs if e["name"] == "launch"]
         assert launches
-        for parent in launches:
+
+        def inside(parent, names):
             lo, hi = parent["ts"], parent["ts"] + parent["dur"]
-            kids = [e["name"] for e in evs
-                    if e["name"] in ("dispatch", "device-wait")
+            return [e["name"] for e in evs if e["name"] in names
                     and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
-            assert kids == ["dispatch", "device-wait"]
+        # a launch dispatches this step's program, then fences the
+        # previous step's: the first has nothing to fence, the last
+        # (a drain) nothing to dispatch
+        kids = [inside(p, ("dispatch", "device-wait")) for p in launches]
+        assert kids[0] == ["dispatch"] and kids[-1] == ["device-wait"]
+        assert all(k == ["dispatch", "device-wait"] for k in kids[1:-1])
+        # every step holds at most one of each, inside it; launch holds
+        # dispatch and device-wait as before, host-accept follows it
+        leaves = ("plan", "launch", "dispatch", "device-wait", "host-accept")
+        steps = [e for e in evs if e["name"] == "step"]
+        assert len(steps) == eng.stats["steps"]
+        held = [inside(s, leaves) for s in steps]
+        assert sum(map(len, held)) == sum(
+            1 for e in evs if e["name"] in leaves)      # none outside
+        for names in held:
+            assert len(set(names)) == len(names)
+            order = [n for n in ("plan", "launch", "host-accept")
+                     if n in names]
+            assert [n for n in names if n in order] == order
+        # dispatch says whether it went behind a program in flight; ahead
+        # plus the dispatches into an emptied pipeline are all of them
+        ahead = [e["args"]["ahead"] for e in evs if e["name"] == "dispatch"]
+        assert ahead[0] == 0 and set(ahead) == {0, 1}
+        drains = sum(v for k, v in eng.stats.items()
+                     if k.startswith("drains_"))
+        assert sum(ahead) == eng.stats["steps_dispatched_ahead"]
+        assert sum(ahead) + drains == len(ahead) \
+            == eng.stats["unified_steps"]
         rows = {(r["lane"], r["name"]): r
                 for r in chrometrace.span_self_times(tr.events())}
         lane = chrometrace.lane_name(TID_ENGINE)
@@ -424,6 +451,7 @@ class TestNamesInTheProgram:
             eng._params, *eng.cache.kv_args(), eng.cache.tables, z(T),
             np.full(T, R, np.int32), z(T), z(R), z(R), z(R), z(R),
             np.asarray(eng._keys, np.uint32), z(R, np.float32),
+            z(R), z(R), z(R), z((R, 2), np.uint32),
             z(R)).as_text(debug_info=True)
         for scope in ("ragged_step", "attn", "mlp", "lm_head", "sample",
                       "ragged_paged_attention"):

@@ -204,7 +204,9 @@ def test_layer_scan_moves_no_pool_layer(kv_dtype):
         _params(nkv), pool_k, pool_v, i32(tables), i32(ids), i32(seg),
         i32(pos), i32(qstart), i32(qlen), i32(kvlen), i32([1, 0, 0, 0]),
         jnp.zeros((R, 2), jnp.uint32), jnp.zeros((R,), jnp.float32),
-        jnp.zeros((R,), jnp.int32)).jaxpr
+        jnp.zeros((R,), jnp.int32), jnp.zeros((R,), jnp.int32),
+        jnp.zeros((R,), jnp.int32), jnp.zeros((R, 2), jnp.uint32),
+        i32([1, 0, 0, 0])).jaxpr
     layer = NB * BS * nkv * HD
     scans = [e for e in _walk(jaxpr) if e.primitive.name == "scan"
              and e.params["length"] == L]

@@ -111,13 +111,16 @@ class TestHeadroomBudget:
     def test_injected_clock_feeds_ewmas_deterministically(self, model):
         """``step_clock`` is the EWMAs' timebase: a virtual clock
         advancing 10 ms per reading yields exactly reproducible
-        headroom stats — the hook the deterministic benches use."""
+        headroom stats — the hook the deterministic benches use. A step
+        reads the clock at its start and at its fence; a program
+        dispatched behind another costs the interval between the two
+        fences, two readings."""
         ticks = itertools.count()
         eng = _engine(model, step_clock=lambda: next(ticks) * 0.010)
         eng.generate([_req(30, n=50, max_new_tokens=3)])
-        assert eng.stats["last_step_duration_s"] == pytest.approx(0.010)
+        assert eng.stats["last_step_duration_s"] == pytest.approx(0.020)
         assert eng.stats["headroom_tps"] > 0      # chunk steps measured
-        assert eng._dt_decode_ewma == pytest.approx(0.010)
+        assert eng._dt_decode_ewma == pytest.approx(0.020)
 
     def test_throttled_grant_still_completes_one_token_over(self, model):
         """The regression the plan-carry fix exists for: a prompt ONE
@@ -217,10 +220,9 @@ class TestMetricsSurface:
         from paddle_tpu.serving.server import ServingGateway
         eng = _engine(model)
         gw = ServingGateway(eng, start=False)   # no driver thread needed
+        # the gateway hooked ``engine.on_step``: every step program
+        # fenced is observed, whoever pumps the engine
         eng.generate([_req(40, n=50, max_new_tokens=2)])
-        # engine-direct runs bypass the driver's observe; one explicit
-        # observation materializes the histogram series
-        gw._m_step_dur.observe(eng.stats["last_step_duration_s"])
         fams = parse_prometheus(gw.registry.render())
         name = "serving_step_duration_seconds"
         assert fams[name]["type"] == "histogram"
@@ -228,7 +230,8 @@ class TestMetricsSurface:
         bounds = {lbl[1] for _, lbls in le for lbl in lbls
                   if lbl[0] == "le"}
         assert len(bounds) == len(STEP_BUCKETS) + 1  # ladder + +Inf
-        assert fams[name]["samples"][(name + "_count", ())] == 1
+        assert fams[name]["samples"][(name + "_count", ())] == \
+            eng.stats["unified_steps"] > 1
         assert fams["serving_step_tokens"]["type"] == "gauge"
         assert fams["serving_step_tokens"]["samples"][
             ("serving_step_tokens", ())] == eng.stats["last_step_tokens"]

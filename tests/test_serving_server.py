@@ -313,6 +313,34 @@ class TestMetricsEndpoint:
         fin = fams["serving_finished_total"]["samples"]
         assert any(lab == (("reason", "length"),) for (_, lab) in fin)
 
+    def test_scrape_counts_the_pipeline(self, server):
+        """ISSUE 30: the step program goes to the chip one step ahead.
+        /metrics says how often (``serving_steps_dispatched_ahead_total``
+        over ``serving_step_duration_seconds_count``) and why the pipeline
+        was emptied (``serving_pipeline_drains_total{reason}``)."""
+        _post(server, {"prompt": _prompt(51), "max_tokens": 12})
+        with urllib.request.urlopen(server.url + "/metrics",
+                                    timeout=10) as r:
+            fams = parse_prometheus(r.read().decode())
+        ahead = fams["serving_steps_dispatched_ahead_total"]
+        assert ahead["type"] == "counter"
+        n_ahead = ahead["samples"][
+            ("serving_steps_dispatched_ahead_total", ())]
+        steps = fams["serving_step_duration_seconds"]["samples"][
+            ("serving_step_duration_seconds_count", ())]
+        # eleven decode programs, all but the first behind another one
+        assert 10 <= n_ahead < steps
+        drains = fams["serving_pipeline_drains_total"]
+        assert drains["type"] == "counter"
+        by_reason = {dict(lab)["reason"]: v
+                     for (_, lab), v in drains["samples"].items()}
+        assert set(by_reason) == {"idle", "cancel", "evict", "preempt",
+                                  "pool", "deadline", "snapshot", "fault"}
+        assert by_reason["idle"] >= 1 and by_reason["fault"] == 0
+        # the histogram counts step programs fenced, each once, wherever
+        # it was fenced: behind the next dispatch, or at a drain
+        assert steps == n_ahead + sum(by_reason.values())
+
 
 class TestGracefulDrain:
     def test_drain_finishes_inflight_then_503(self, model):
