@@ -8,7 +8,9 @@ at the full width of ``llama_7b()`` (hidden 4096, 32 x 128 heads, ffn 11008,
 vocab 32000, bf16) with the depth cut and random weights made from a seed:
 
   kernels  every Pallas kernel on the default serving and training paths
-           (and the routed FFN's grouped matmuls at OLMoE widths),
+           (the routed FFN's grouped matmuls at OLMoE widths and, with
+           one chip's share of the experts, at DeepSeek-V2's; the
+           absorbed-form latent attention kernel at DeepSeek-V2's),
            compiled by Mosaic and RUN against its jnp reference;
   serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
            answering cold, chunked, concurrent and streamed requests;
@@ -56,6 +58,13 @@ FULL = dict(
     # width, experts a token), then (rows, live rows) of a decode step's
     # packed buffer and of a whole-prompt prefill
     moe=dict(widths=(2048, 64, 1024, 8), rows=[(536, 24), (256, 256)]),
+    # DeepSeek-V2's widths: latent attention (heads, latent rank, rope-free
+    # / rope / value head widths) and its routed FFN with one chip's share
+    # (hidden, router width, held experts, expert width, experts a token,
+    # groups, groups a token), rows as above
+    mla=dict(widths=(128, 512, 128, 64, 128)),
+    moe_share=dict(widths=(5120, 160, 20, 1536, 6, 8, 3),
+                   rows=[(544, 32), (256, 256)]),
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
@@ -63,6 +72,8 @@ REHEARSAL = dict(
     vocab=256, medium_prompt=24, long_prompt=70,
     tp=2,                                   # llama_tiny has two kv heads
     moe=dict(widths=(64, 8, 32, 2), rows=[(36, 4), (16, 16)]),
+    mla=dict(widths=(4, 32, 16, 8, 16)),
+    moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
     train=dict(layers=2, batch=4, seq=64, steps=4))
 
 # Forward outputs: kernel and reference both take bf16 inputs (8 significant
@@ -537,26 +548,96 @@ def phase_kernels(rehearse):
         for g, g_ref, n in zip(grads, grads_ref, ("dq", "dk", "dv")):
             _agree(f"flash {n} {tag}", g, g_ref, TOL_BWD, errors)
 
-    # ---- the routed FFN (grouped matmuls) against every-expert-masked ----
+    # ---- the routed FFN (grouped matmuls) against every-expert-masked:
+    # whole (OLMoE), then with one chip's share of a wider, group-limited,
+    # scaled router (DeepSeek-V2) ----------------------------------------
     from paddle_tpu.kernels.moe_ffn import moe_ffn, moe_ffn_reference
+
+    def routed_ffn(tag, seed, hidden, router_width, held, width, rows,
+                   **routing):
+        rng = np.random.RandomState(seed)
+        weights = [
+            jnp.asarray(0.02 * rng.randn(*shape).astype(np.float32), bf16)
+            for shape in ((hidden, router_width), (held, hidden, width),
+                          (held, hidden, width), (held, width, hidden))]
+        for n_rows, n_live in rows:
+            h = jnp.asarray(rng.randn(n_rows, hidden).astype(np.float32),
+                            bf16)
+            live = np.zeros(n_rows, bool)   # live rows spread over the buffer
+            live[np.linspace(0, n_rows - 1, n_live).astype(int)] = True
+            margs = (h, *weights, jnp.asarray(live))
+            got, stats_got = jax.jit(
+                lambda *a: moe_ffn(*a[:5], live=a[5], **routing))(*margs)
+            want, stats_want = reference(
+                lambda *a: moe_ffn_reference(*a[:5], live=a[5], **routing),
+                *margs)
+            name = f"moe_ffn {tag}{n_live}/{n_rows}"
+            _agree(name, got, want, TOL_FWD, errors)
+            pairs, picks = int(stats_got[0]), int(stats_got[3])
+            check(np.array_equal(np.asarray(stats_got),
+                                 np.asarray(stats_want))
+                  and picks == n_live * routing["top_k"]
+                  and (pairs == picks if held == router_width
+                       else 0 < pairs < picks),
+                  f"{name}: routing summary {np.asarray(stats_got)} != "
+                  f"{np.asarray(stats_want)}")
+
     H, E, I, K = size["moe"]["widths"]
-    rng = np.random.RandomState(64)
-    weights = [jnp.asarray(0.02 * rng.randn(*shape).astype(np.float32), bf16)
-               for shape in ((H, E), (E, H, I), (E, H, I), (E, I, H))]
-    for rows, n_live in size["moe"]["rows"]:
-        h = jnp.asarray(rng.randn(rows, H).astype(np.float32), bf16)
-        live = np.zeros(rows, bool)         # live rows spread over the buffer
-        live[np.linspace(0, rows - 1, n_live).astype(int)] = True
-        margs = (h, *weights, jnp.asarray(live))
-        got, stats_got = jax.jit(
-            lambda *a: moe_ffn(*a[:5], top_k=K, live=a[5]))(*margs)
-        want, stats_want = reference(
-            lambda *a: moe_ffn_reference(*a[:5], top_k=K, live=a[5]), *margs)
-        _agree(f"moe_ffn {n_live}/{rows}", got, want, TOL_FWD, errors)
-        check(np.array_equal(np.asarray(stats_got), np.asarray(stats_want))
-              and int(stats_got[0]) == n_live * K,
-              f"moe_ffn {n_live}/{rows}: routing summary "
-              f"{np.asarray(stats_got)} != {np.asarray(stats_want)}")
+    routed_ffn("", 64, H, E, E, I, size["moe"]["rows"], top_k=K)
+    H, E, held, I, K, groups, top_g = size["moe_share"]["widths"]
+    routed_ffn("held ", 160, H, E, held, I, size["moe_share"]["rows"],
+               top_k=K, n_group=groups, topk_group=top_g, first_held=0,
+               scale=16.0)
+
+    # ---- latent attention, absorbed kernel against expanded oracle ------
+    from paddle_tpu.kernels.pallas_mla_ragged_attention import (
+        latent_row_width, mla_ragged_attention_pallas,
+        mla_ragged_attention_reference)
+    nh, rank, nope, rope, vd = size["mla"]["widths"]
+    rng = np.random.RandomState(512)
+    nb, bs, mb = 48, 32, 24
+    # decode rows ending at a block start, mid-block and after more than one
+    # group of pages; a chunk with a cached prefix; a dead row
+    rows = [(1, 1), (1, 37), (1, 64), (1, 700), (40, 77), (0, 0)]
+    R, width = len(rows), latent_row_width(rank, rope)
+    perm = rng.permutation(nb)
+    tables = np.full((R, mb), nb, np.int32)
+    pool = rng.randn(1, nb, bs, width).astype(np.float32)
+    pool[..., rank + rope:] = 0.0
+    live = np.zeros((nb, bs), bool)
+    used = 0
+    for r, (_, kvlen) in enumerate(rows):
+        for b in range(-(-kvlen // bs)):
+            tables[r, b] = perm[used]
+            live[perm[used], :min(bs, kvlen - b * bs)] = True
+            used += 1
+    pool[0][~live] = np.nan
+    qlen = np.array([q for q, _ in rows], np.int32)
+    kvlen = np.array([k for _, k in rows], np.int32)
+    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+    T = int(qlen.sum()) + 12
+    q_nope = jnp.asarray(rng.randn(T, nh, nope).astype(np.float32), bf16)
+    q_pe = jnp.asarray(rng.randn(T, nh, rope).astype(np.float32), bf16)
+    w_kvb = jnp.asarray(rng.randn(rank, nh * (nope + vd)).astype(np.float32)
+                        * rank ** -0.5, bf16)
+    span = (jnp.asarray(pool, bf16), jnp.asarray(tables),
+            jnp.asarray(qstart), jnp.asarray(qlen), jnp.asarray(kvlen))
+    scale = (nope + rope) ** -0.5
+
+    def absorbed(q_nope, q_pe, w_kvb, *span):
+        w = w_kvb.reshape(rank, nh, nope + vd)
+        o_lat = mla_ragged_attention_pallas(
+            jnp.einsum("thd,rhd->thr", q_nope, w[..., :nope]), q_pe, *span,
+            scale=scale)
+        return jnp.einsum("thr,rhd->thd", o_lat, w[..., nope:])
+
+    got = jax.jit(absorbed)(q_nope, q_pe, w_kvb, *span)
+    want = reference(
+        lambda *a: mla_ragged_attention_reference(*a, scale=scale),
+        q_nope, q_pe, w_kvb, *span)
+    _agree("mla_ragged", got, want, TOL_FWD, errors)
+    check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
+          "mla_ragged: rows outside every span are not exact zeros")
 
     import importlib.metadata as md
     _child_report(

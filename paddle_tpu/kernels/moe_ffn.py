@@ -15,7 +15,26 @@ Router matmul and softmax run in float32 (``Precision.HIGHEST``) so that only
 error upstream of the router can change which experts a row picks. The
 weights are the softmax probabilities of the picked experts as they are
 (OLMoE's ``norm_topk_prob`` is false): ``renormalize=True`` divides them by
-their sum for a model that wants it.
+their sum for a model that wants it, and ``scale`` multiplies them (a
+``routed_scaling_factor``).
+
+What a model may add to that, all static numbers of the one function:
+
+- **groups** (``n_group``, ``topk_group``: group-limited greedy routing): the
+  router's experts are ``n_group`` runs of consecutive ids, a group's score is
+  the largest probability in it, only the ``topk_group`` best groups keep
+  their probabilities (every other expert's is set to 0) and the ``top_k``
+  are taken from what is left. ``n_group = 1`` is plain top-k.
+- **a held range** (``first_held``): the expert stacks hold the contiguous ids
+  ``first_held .. first_held + E_held`` of a router that is wider than they
+  are: this chip's share of an expert-parallel layer. The router keeps its
+  width and its rule, pairs are made only for the picks that land on a held
+  expert, and what the absent experts would add is left out (the partial sum
+  an exchange between chips would complete; no code stands in for it). With
+  every expert held (``E_held`` the router's width) this is the layer whole.
+- the **shared expert** is not here: it is a dense SwiGLU every row runs, which
+  the layer body adds beside this call (``serving.decode._decoder_layer``,
+  scope ``moe_shared``).
 
 The grouped matmul is ``jax.lax.ragged_dot`` on the CPU backend and the
 Pallas grouped matmul that ships with JAX (``megablox.gmm``) on a TPU, whose
@@ -31,28 +50,54 @@ from .pallas_flash import _interpret_mode
 
 #: rows of a grouped-matmul tile: pair slots are padded to a multiple of it
 PAIR_TILE = 128
-#: stats vector returned beside the output, one int32 each
-STATS = ("pairs", "experts_touched", "max_expert_pairs")
+#: stats vector returned beside the output, one int32 each: the live pairs
+#: on held experts (what the grouped matmuls run), the held experts with at
+#: least one, the fullest one's, and the picks the live rows made over the
+#: router's whole width (``pairs`` again when every expert is held)
+STATS = ("pairs", "experts_touched", "max_expert_pairs", "picks")
 
 
-def _route(h2, router, top_k, live, renormalize):
-    """Float32 router: (weights [T, K] f32, experts [T, K] i32, counts [E]
-    i32 of live pairs per expert, stats [3] i32)."""
+def group_limited(probs, n_group, topk_group):
+    """``probs [T, E]`` with every expert outside the row's ``topk_group``
+    best groups set to 0; a group is ``E / n_group`` consecutive ids and its
+    score the largest probability in it."""
+    if n_group <= 1:
+        return probs
+    best = jnp.max(probs.reshape(probs.shape[0], n_group, -1), axis=-1)
+    _, top_g = jax.lax.top_k(best, topk_group)
+    keep = jnp.any(top_g[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                  # [T, n_group]
+    return jnp.where(jnp.repeat(keep, probs.shape[1] // n_group, axis=1),
+                     probs, 0.0)
+
+
+def _route(h2, router, top_k, live, renormalize, n_held, n_group=1,
+           topk_group=1, first_held=0, scale=1.0):
+    """Float32 router: (weights [T, K] f32, experts [T, K] i32 by the
+    router's ids (its width for a dead row), held ids [T, K] i32 (position in
+    the held stack; ``n_held`` for a pick no held expert takes), counts
+    [n_held] i32 of live pairs per held expert, stats [4] i32)."""
     n_exp = router.shape[-1]
     logits = jnp.dot(h2.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = group_limited(jax.nn.softmax(logits, axis=-1), n_group,
+                          topk_group)
     w, idx = jax.lax.top_k(probs, top_k)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scale != 1.0:
+        w = w * scale
     # a dead row's picks go to the sentinel expert E: no group counts them
     idx = jnp.where(live[:, None], idx, n_exp).astype(jnp.int32)
+    loc = idx - first_held
+    loc = jnp.where((loc >= 0) & (loc < n_held), loc, n_held)
     # (a compare-and-sum, not a scatter-add: 4 us against 38 on the v5e)
-    counts = jnp.sum(idx.reshape(-1, 1) == jnp.arange(n_exp)[None, :],
+    counts = jnp.sum(loc.reshape(-1, 1) == jnp.arange(n_held)[None, :],
                      axis=0, dtype=jnp.int32)
     stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
-                       jnp.max(counts)]).astype(jnp.int32)
-    return w, idx, counts, stats
+                       jnp.max(counts),
+                       top_k * jnp.sum(live)]).astype(jnp.int32)
+    return w, idx, loc, counts, stats
 
 
 def _prep(h, live):
@@ -64,14 +109,17 @@ def _prep(h, live):
 
 
 def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
-                      renormalize=False):
-    """h [..., H]; router [H, E]; w_gate, w_up [E, H, I]; w_down [E, I, H];
-    live [...] bool (None: every row). Returns (out [..., H], stats [3]).
-    Plain ``jnp``: each expert runs over every row and is masked by the
+                      renormalize=False, **routing):
+    """h [..., H]; router [H, E]; w_gate, w_up [E_held, H, I]; w_down
+    [E_held, I, H]; live [...] bool (None: every row); ``routing``: the
+    module docstring's ``n_group``, ``topk_group``, ``first_held``,
+    ``scale``. Returns (out [..., H], stats [4]).
+    Plain ``jnp``: each held expert runs over every row and is masked by the
     row's weight for it (zero where not picked or the row is dead)."""
     lead, h2, live = _prep(h, live)
-    n_exp = router.shape[-1]
-    w, idx, _, stats = _route(h2, router, top_k, live, renormalize)
+    n_exp = w_gate.shape[0]
+    w, _, idx, _, stats = _route(h2, router, top_k, live, renormalize,
+                                 n_exp, **routing)
     # [T, E + 1] weight of every expert for every row; column E is the bin
     dense = jnp.zeros((h2.shape[0], n_exp + 1), jnp.float32).at[
         jnp.arange(h2.shape[0])[:, None], idx].set(w)[:, :n_exp]
@@ -122,24 +170,26 @@ def _grouped_matmul(xs, w, counts, layer=None):
 
 
 def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
-            renormalize=False, return_picks=False, layer=None):
+            renormalize=False, return_picks=False, layer=None, **routing):
     """Same contract as :func:`moe_ffn_reference`; the path the serving
     programs run. Scopes: ``moe`` > ``moe_route`` (router, top-k, ordering,
     gather, weighted sum) and ``moe_experts`` (the grouped matmuls). With
-    ``return_picks`` a third value: the experts each row picked
-    ``[..., top_k]`` int32 (``E`` for a dead row), for a check of the
-    routing against a reference. With ``layer`` (a traced index) the three
-    expert weights are stacks over layers ``[L, E, ...]``, read in place
-    (``_grouped_matmul``)."""
+    ``return_picks`` a third value: the experts each row picked, by the
+    router's ids, ``[..., top_k]`` int32 (the router's width for a dead
+    row), held or not, for a check of the routing against a reference. With
+    ``layer`` (a traced index) the three expert weights are stacks over
+    layers ``[L, E_held, ...]``, read in place (``_grouped_matmul``)."""
     lead, h2, live = _prep(h, live)
     rows, hid = h2.shape
     with jax.named_scope("moe"):
         with jax.named_scope("moe_route"):
-            w, idx, counts, stats = _route(h2, router, top_k, live,
-                                           renormalize)
+            w, picks, idx, counts, stats = _route(
+                h2, router, top_k, live, renormalize,
+                w_gate.shape[0 if layer is None else 1], **routing)
             pairs = rows * top_k
             slots = -(-pairs // PAIR_TILE) * PAIR_TILE
-            # pair slots ordered by expert, dead pairs (expert E) last
+            # pair slots ordered by held expert; dead pairs and picks of an
+            # expert held elsewhere (the sentinel id) last
             order = jnp.argsort(idx.reshape(-1), stable=True)
             order = jnp.pad(order, (0, slots - pairs))
             xs = jnp.take(h2, order // top_k, axis=0)
@@ -153,9 +203,10 @@ def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
             # whatever the grouped matmul left there, so select, not scale
             slot_of = jnp.argsort(order[:pairs])
             y = jnp.take(y, slot_of, axis=0).reshape(rows, top_k, hid)
-            y = jnp.where(live[:, None, None], y.astype(jnp.float32), 0.0)
+            y = jnp.where((idx < counts.shape[0])[:, :, None],
+                          y.astype(jnp.float32), 0.0)
             out = jnp.sum(y * w[:, :, None], axis=1)
     out = out.astype(h.dtype).reshape(lead + (hid,))
     if return_picks:
-        return out, stats, idx.reshape(lead + (top_k,))
+        return out, stats, picks.reshape(lead + (top_k,))
     return out, stats

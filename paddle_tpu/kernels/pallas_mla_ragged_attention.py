@@ -1,0 +1,312 @@
+"""Pallas TPU ragged paged attention over a LATENT cache (multi-head latent
+attention, DeepSeek-V2), in the absorbed form.
+
+The cache holds one row a token a layer: the normalised latent ``c_kv``
+(``rank`` values), then the rotated shared key ``k_pe`` (``rope`` values), then
+zeros up to whole lanes: ``[L, num_blocks, bs, W]``, no head axis and no V
+side. With ``W_kvb`` split by head into ``W_UK`` and ``W_UV`` the caller folds
+``W_UK`` into the query (``q_lat = q_nope W_UK^T``) and ``W_UV`` into the
+output, and this kernel computes, for every head of a token alike,
+
+    score = (q_lat . c_kv + q_pe . k_pe) * scale      o_lat = softmax . c_kv
+
+so a fetched block serves all heads at once: the wide query ``[q_lat | q_pe |
+0]`` is one row a (token, head), the key is the stored row as it lies, and the
+value is its first ``rank`` lanes. That is ``pallas_ragged_attention``'s
+wide-query layout with one KV head, and the iteration space is that kernel's
+own: the same work list of (query block, row) pairs (``_work_list``), the same
+bound on a pair's walk (the row's length and the causal diagonal,
+``_pair_kv_blocks``), the same host counter (``ragged_grid_counts``), packed
+spans of length 1 (decode rows) and n (prefill chunks) alike, float32 softmax
+state, rows outside every span exact zeros.
+
+What differs: the pool is ONE buffer, left in HBM whole; a loop iteration
+fetches ``pages`` consecutive table entries (one DMA each, all in flight
+together, the next group streaming in while this one computes) and runs one
+online-softmax update over ``pages * bs`` keys. A 32-token block alone is 40
+KB: one block an iteration is bound by the DMA's latency, not by its bytes
+(PERF.md, PR 25: 1.3 us a block). Entries past the pair's last block clamp
+to the table's last entry: a harmless read, masked off.
+
+``mla_ragged_attention_reference`` is the oracle in the EXPANDED form: it
+gathers the latent rows through the tables, up-projects them to per-head
+keys and values with ``W_kvb`` and attends plainly, so a test of the kernel
+against it is also a test of the absorption.
+
+Inference-only (no VJP).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_flash import _cparams, _interpret_mode
+from .pallas_ragged_attention import NEG_INF, _query_block, _work_list
+
+#: table entries one loop iteration fetches and computes on together
+PAGES = 16
+
+
+def latent_row_width(rank, rope):
+    """Lanes of a stored row: ``rank + rope`` values padded to whole lanes
+    (Mosaic refuses a DMA window whose minor dim is not; 576 -> 640)."""
+    return -(-(int(rank) + int(rope)) // 128) * 128
+
+
+def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
+                tbl_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems, m_scr,
+                l_scr, acc_scr, *, scale, block_k, pages, tq, gh, num_blocks,
+                table_entries, rank):
+    w = pl.program_id(0)            # one work-list entry: (query block, row)
+    qi = wq_ref[w]
+    r = wr_ref[w]
+    nkb = wn_ref[w]                 # pool blocks this pair walks (0 = dead)
+    layer = layer_ref[0]
+    qstart = qs_ref[r]
+    qlen = ql_ref[r]
+    kvlen = kl_ref[r]
+    row0 = qi * tq
+    span_lo = qstart * gh
+    span_hi = (qstart + qlen) * gh
+    group = pages * block_k         # keys of one iteration
+
+    @pl.when(wf_ref[w] == 1)
+    def _zero_out():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    def _copies(gi, slot):
+        # the group's table entries, resolved from SMEM at issue time;
+        # entries past the table clamp to its last, sentinels into the
+        # layer's own blocks (masked by kvlen either way)
+        out = []
+        for j in range(pages):
+            entry = jnp.minimum(gi * pages + j, table_entries - 1)
+            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[layer, phys],
+                buf.at[slot, pl.ds(j * block_k, block_k)],
+                sems.at[slot, j]))
+        return out
+
+    def _walk(nr, load_q, valid_of, write):
+        # one pair's walk on ``nr`` wide rows (static), the softmax state in
+        # the first ``nr`` rows of the scratch
+        m_ref, l_ref, acc_ref = (r.at[pl.ds(0, nr)]
+                                 for r in (m_scr, l_scr, acc_scr))
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        n_groups = (nkb + pages - 1) // pages
+        for c in _copies(0, 0):
+            c.start()
+
+        def _group(gi, carry):
+            slot = gi % 2
+
+            @pl.when(gi + 1 < n_groups)
+            def _prefetch():
+                for c in _copies(gi + 1, 1 - slot):
+                    c.start()
+
+            for c in _copies(gi, slot):
+                c.wait()
+            q = load_q()                        # [nr, W]: q_lat | q_pe | 0
+            k = buf[slot]                       # [group, W]: c_kv | k_pe | 0
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            valid = valid_of(gi * group + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1), s.shape)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            # the value is the latent part of the same rows. Rows past kvlen
+            # may hold another sequence's (or a clamped entry's) values, and
+            # 0 * NaN is NaN: zero them, in the one group that can have any
+            v = jax.lax.cond(
+                (gi + 1) * group > kvlen,
+                lambda v: jnp.where(
+                    gi * group + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v)),
+                lambda v: v, k[:, :rank])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:] = jnp.broadcast_to(
+                alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+            return carry
+
+        jax.lax.fori_loop(0, n_groups, _group, 0)
+        write(acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30))
+
+    # a span of ONE token (a decode row) is ``gh`` wide rows at a multiple
+    # of ``gh`` inside the query block: it computes on those rows alone,
+    # not on the block's other tokens, which belong to other rows (where
+    # ``gh`` rows are whole tiles; else every span takes the general walk)
+    one_token_walk = gh % 16 == 0 and gh < tq
+    alone = (qlen == 1) if one_token_walk else False
+
+    if one_token_walk:
+        @pl.when((nkb > 0) & alone)
+        def _one_token():
+            off = pl.multiple_of(span_lo - row0, gh)
+
+            def write(out):
+                o_ref[pl.ds(off, gh), :] = out.astype(o_ref.dtype)
+
+            _walk(gh, lambda: q_ref[pl.ds(off, gh), :],
+                  lambda cols, shape: cols < kvlen, write)
+
+    @pl.when((nkb > 0) & jnp.logical_not(alone))
+    def _span():
+        def valid_of(cols, shape):
+            # causal within the span: wide row w is span token (w - span_lo)
+            # // gh, at logical position kvlen - qlen + that index
+            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            pos = kvlen - qlen + (wrow - span_lo) // gh
+            return (wrow >= span_lo) & (wrow < span_hi) & (cols <= pos)
+
+        def write(out):
+            # ONLY this row's span: the output block is shared by every
+            # sequence whose span intersects it
+            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            o_ref[:] = jnp.where((wrow >= span_lo) & (wrow < span_hi),
+                                 out.astype(o_ref.dtype), o_ref[:])
+
+        _walk(tq, lambda: q_ref[:], valid_of, write)
+
+
+def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
+              block_q, rank, pages, interpret):
+    """q_wide ``[TH_pad, W]``; pool ``[L, num_blocks, bs, W]`` left in HBM;
+    returns ``[TH_pad, rank]``."""
+    TH, W = q_wide.shape
+    num_blocks, bs = pool.shape[1], pool.shape[2]
+    R, nk = tables.shape
+    nq = TH // block_q
+    pages = max(1, min(int(pages), nk))
+    work = _work_list(qstart, qlen, kvlen, nq=nq,
+                      tokens_per_block=block_q // gh, block_size=bs,
+                      table_entries=nk)
+    kernel = functools.partial(
+        _mla_kernel, scale=scale, block_k=bs, pages=pages, tq=block_q, gh=gh,
+        num_blocks=num_blocks, table_entries=nk, rank=rank)
+
+    def _q_index(w, wq, *_):
+        return (wq[w], 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=9,
+            grid=(nq + R,),
+            in_specs=[pl.BlockSpec((block_q, W), _q_index),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block_q, rank), _q_index),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2, pages)),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((TH, rank), q_wide.dtype),
+        # consecutive entries revisit one output block: no reordering
+        compiler_params=_cparams(("arbitrary",)),
+        interpret=interpret,
+        name="mla_ragged_attention",
+    )(*work, qstart, qlen, kvlen, tables, layer, q_wide, pool)
+
+
+def _spans(tables, qstart, qlen, kvlen):
+    qstart = jnp.asarray(qstart, jnp.int32).reshape(-1)
+    return (jnp.asarray(tables, jnp.int32).reshape(qstart.shape[0], -1),
+            qstart, jnp.asarray(qlen, jnp.int32).reshape(-1),
+            jnp.asarray(kvlen, jnp.int32).reshape(-1))
+
+
+def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
+                                kvlen, *, scale, layer=0, block_q=256,
+                                pages=PAGES):
+    """Absorbed-form attention of packed query spans over the latent pool.
+
+    q_lat:  [T, H, rank]  — ``q_nope W_UK^T`` of every (token, head)
+    q_pe:   [T, H, rope]  — the rotated rope part of the query
+    pool:   [L, num_blocks, bs, W] — the stored latent pool (module docstring)
+    tables, qstart, qlen, kvlen: as ``ragged_paged_attention_pallas``
+    scale:  the softmax scale (the model's: head width and YaRN's mscale)
+    layer:  the layer of the pool to read (a traced index in a layer scan)
+    returns [T, H, rank]: ``softmax . c_kv``, to be multiplied by ``W_UV``;
+    packed rows outside every span are exact zeros.
+    """
+    T, H, rank = q_lat.shape
+    W = pool.shape[-1]
+    tables, qstart, qlen, kvlen = _spans(tables, qstart, qlen, kvlen)
+    q_wide = jnp.concatenate(
+        [q_lat, q_pe,
+         jnp.zeros((T, H, W - rank - q_pe.shape[-1]), q_lat.dtype)],
+        axis=-1).reshape(T * H, W)
+    bq = _query_block(block_q, H, T)
+    th_pad = -(-(T * H) // bq) * bq
+    if th_pad != T * H:
+        q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))
+    out = _mla_call(q_wide, pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                    tables, qstart, qlen, kvlen, float(scale), H, bq, rank,
+                    pages, _interpret_mode())
+    return out[:T * H].reshape(T, H, rank)
+
+
+def mla_ragged_attention_reference(q_nope, q_pe, w_kvb, pool, tables, qstart,
+                                   qlen, kvlen, *, scale, layer=0):
+    """jnp oracle, EXPANDED form, same span semantics.
+
+    q_nope: [T, H, nope]; q_pe: [T, H, rope] (rotated); w_kvb: [rank, H *
+    (nope + v)], a head's columns its ``k_nope`` then its ``v``; pool as the
+    kernel's. Each sequence's latent rows are gathered through its table,
+    up-projected to per-head keys ``[k_nope | k_pe]`` and values, and
+    attended causally within the span. Returns the per-head outputs
+    ``[T, H, v]`` (``W_UV`` already applied)."""
+    T, H, nope = q_nope.shape
+    rope = q_pe.shape[-1]
+    rank = w_kvb.shape[0]
+    tables, qstart, qlen, kvlen = _spans(tables, qstart, qlen, kvlen)
+    R, mb = tables.shape
+    bs = pool.shape[2]
+    s_tot = mb * bs
+    rows = jnp.asarray(pool).at[layer, tables].get(mode="clip")
+    rows = rows.reshape(R, s_tot, -1)
+    kv = jnp.einsum("rsc,cd->rsd", rows[..., :rank], w_kvb).reshape(
+        R, s_tot, H, -1)
+    k_pe = jnp.broadcast_to(rows[:, :, None, rank:rank + rope],
+                            (R, s_tot, H, rope))
+    k_rows = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    v_rows = kv[..., nope:]
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    in_r = (t_idx[None, :] >= qstart[:, None]) \
+        & (t_idx[None, :] < (qstart + qlen)[:, None])     # [R, T]
+    live = jnp.any(in_r, axis=0)
+    seg = jnp.argmax(in_r, axis=0).astype(jnp.int32)
+    k = jnp.take(k_rows, seg, axis=0)                     # [T, s_tot, H, .]
+    v = jnp.take(v_rows, seg, axis=0)
+    pos = (jnp.take(kvlen, seg) - jnp.take(qlen, seg)
+           + (t_idx - jnp.take(qstart, seg)))
+    cols = jnp.arange(s_tot, dtype=jnp.int32)
+    mask = (cols[None, :] <= pos[:, None]) & live[:, None]
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    logits = jnp.einsum("qhd,qkhd->qhk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(mask[:, None, :], logits, NEG_INF)
+    probs = jnp.where(mask[:, None, :], jax.nn.softmax(logits, axis=-1), 0.0)
+    row_valid = cols[None, :] < jnp.take(kvlen, seg)[:, None]
+    v = jnp.where(row_valid[:, :, None, None], v, 0.0)
+    out = jnp.einsum("qhk,qkhd->qhd", probs.astype(q.dtype), v)
+    return jnp.where(live[:, None, None], out, jnp.zeros_like(out))

@@ -98,6 +98,14 @@ class BlockManager:
     Under tensor parallelism the minor axis is cut into ``tp``
     contiguous pieces, ``Hkv / tp`` whole heads each.
 
+    **A latent pool** (``v_dim=0``; multi-head latent attention): a token's
+    row is not heads of K and V but ONE row, the normalised latent and the
+    rotated shared key padded to whole lanes (``num_kv_heads`` 1,
+    ``head_dim`` that width: 512 + 64 -> 640 for DeepSeek-V2), stored once,
+    on the K side; the V side has width 0, so every program signature,
+    writer and lifecycle move carries it unchanged and for nothing. The
+    kernel that reads it is ``kernels.pallas_mla_ragged_attention``.
+
     **The sentinel rule**: the block id ``num_blocks`` (one past the
     last block; ``PagedKVCache.sentinel``) marks an unmapped table
     entry and the target of a write that must not happen (a dead packed
@@ -138,7 +146,8 @@ class BlockManager:
     cast, no scale write)."""
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
-                 head_dim, dtype=jnp.float32, kv_dtype=None, mesh=None):
+                 head_dim, dtype=jnp.float32, kv_dtype=None, mesh=None,
+                 v_dim=None):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         if block_size < 1:
@@ -147,6 +156,10 @@ class BlockManager:
             raise ValueError(
                 f"kv_dtype must be None (store at pool dtype), 'int8' or "
                 f"'fp8', got {kv_dtype!r}")
+        if v_dim is not None and (kv_dtype is not None or mesh is not None):
+            raise ValueError(
+                "a pool whose V side differs from its K side (a latent "
+                "pool) is full-precision and lives on one chip")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.kv_dtype = kv_dtype
@@ -159,7 +172,8 @@ class BlockManager:
         store = (jnp.float8_e4m3fn if self.fp8
                  else jnp.int8 if self.quantized else dtype)
         self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
+        self.v = jnp.zeros(shape if v_dim is None
+                           else shape[:-1] + (int(v_dim),), store)
         if self.fp8:
             # per-BLOCK planes, constant 1.0 (class docstring): never
             # rewritten by appends, only read by the kernels' post-dot
@@ -242,8 +256,8 @@ class BlockManager:
         (shape × itemsize): no device sync. Dtype-aware by
         construction: an int8 pool reports int8 bytes (scale planes are
         accounted separately, :attr:`scale_block_nbytes`)."""
-        per = self.k.size * np.dtype(self.k.dtype).itemsize
-        return 2 * per // self.num_blocks
+        per = (self.k.size + self.v.size) * np.dtype(self.k.dtype).itemsize
+        return per // self.num_blocks
 
     @property
     def scale_block_nbytes(self) -> int:

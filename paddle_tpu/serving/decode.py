@@ -22,7 +22,12 @@ three more behind switches, all over one block-table paged KV pool:
 Per-row raggedness: each row writes its new K/V through its block table
 at its own logical position and attends over its own length — through
 the ragged paged Pallas kernels or their jnp oracles with identical
-semantics. Dead rows carry sentinel tables, so their writes drop.
+semantics. Dead rows carry sentinel tables, so their writes drop. A model
+with latent attention (a tree with ``wkv_a``) writes ONE row a token, the
+normalised latent and the rotated shared key, into a pool with no head axis
+and no V side, and the unified step attends over it in the absorbed form
+(``kernels.pallas_mla_ragged_attention``); its whole-prompt prefill attends
+in the expanded form and hands the same rows to the pool's writer.
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
@@ -34,6 +39,7 @@ pin down).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -44,22 +50,41 @@ from ..kernels.flash_attention import attention as _attention
 from ..kernels.moe_ffn import moe_ffn
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
+from ..kernels.pallas_mla_ragged_attention import (
+    latent_row_width, mla_ragged_attention_pallas,
+    mla_ragged_attention_reference)
 from ..kernels.pallas_ragged_attention import (ragged_attention_reference,
                                                ragged_paged_attention_pallas)
+from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
 from .kv_cache import kv_rows, quantize_kv_rows, quantize_kv_rows_fp8
 
 NEG_INF = -1e30
 
+#: the per-layer entries of a layer with grouped-query attention and a SwiGLU,
+#: in the order every program that scans them unpacks (the seven projections
+#: first: ``_dq_layer``)
 _STACK_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "input_ln", "post_ln")
+
+#: the same for a layer with latent attention (a tree with ``wkv_a``; module
+#: docstring of ``models.deepseek_v2``): the query's and the cache's down- and
+#: up-projections with the two norms between them
+_MLA_STACK_KEYS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up",
+                   "w_down", "input_ln", "post_ln", "q_a_ln", "kv_a_ln")
 
 #: per-layer entries only some models bring; what a parameter tree holds of
 #: them chooses the layer body (``_decoder_layer``): ``q_norm`` / ``k_norm``
 #: normalise q and k before the rotary embedding, ``router`` makes the FFN a
-#: routed one over ``w_gate`` / ``w_up`` / ``w_down`` with a leading expert dim
-_STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router")
+#: routed one over ``w_gate`` / ``w_up`` / ``w_down`` with a leading expert
+#: dim, ``ws_*`` is a shared expert every row runs beside the routed ones
+_STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router", "ws_gate", "ws_up",
+                     "ws_down")
+
+#: what marks a tree whose layer only the default engine's two programs were
+#: taught (``ContinuousBatchingEngine`` raises for every other switch)
+TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a",)
 
 
 #: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not
@@ -69,19 +94,35 @@ _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
 def _layer_stack(params):
     """(names, arrays, experts) of the per-layer entries a layer scan
-    carries: the nine every model has, whichever extras this tree holds,
-    and last the layer's index as ``layer``: what is too large to slice a
-    layer out of (the KV pool, a routed FFN's expert stacks) is read in
-    place at that index. For a tree with ``router``, ``experts`` is the
-    three expert stacks, whole, and their places in the scanned tuple hold
-    None; else ``experts`` is None."""
-    keys = _STACK_KEYS + tuple(k for k in _STACK_EXTRA_KEYS if k in params)
+    carries: the attention's and the FFN's (``_STACK_KEYS``, or
+    ``_MLA_STACK_KEYS`` for a tree with ``wkv_a``), whichever extras this
+    tree holds, and last the layer's index in this stack as ``layer``: what
+    is too large to slice a layer out of (the KV pool, a routed FFN's expert
+    stacks) is read in place at that index. For a tree with ``router``,
+    ``experts`` is the three expert stacks, whole, and their places in the
+    scanned tuple hold None; else ``experts`` is None."""
+    base = _MLA_STACK_KEYS if "wkv_a" in params else _STACK_KEYS
+    keys = base + tuple(k for k in _STACK_EXTRA_KEYS if k in params)
     routed = "router" in params
     stack = tuple(None if routed and k in _EXPERT_KEYS else params[k]
                   for k in keys)
     layers = jnp.arange(params["input_ln"].shape[0], dtype=jnp.int32)
     return (keys + ("layer",), stack + (layers,),
             tuple(params[k] for k in _EXPERT_KEYS) if routed else None)
+
+
+def _layer_stacks(params):
+    """The layer scans of one forward, in order, each ``(first layer, names,
+    arrays, experts)``: a model whose leading layers differ from the rest (a
+    dense FFN before routed ones) brings them as a tree of their own under
+    ``dense_layers``, scanned first; ``first layer`` is where a stack's
+    layers start in the model (and so in the KV pool)."""
+    stacks, first = [], 0
+    for tree in (params.get("dense_layers"), params):
+        if tree is not None:
+            stacks.append((first,) + _layer_stack(tree))
+            first += tree["input_ln"].shape[0]
+    return stacks
 
 
 #: the decode-path projection matmuls quantize_weights=True converts
@@ -244,40 +285,127 @@ def _qk_norm(q, k, q_w, k_w, eps):
             _rms(k.reshape(k.shape[:2] + (-1,)), k_w, eps).reshape(k.shape))
 
 
+def _rope_tables_for(seq_len, hd, theta, mla):
+    """(sin, cos) of a program's rotary embedding: ``hd`` wide over plain
+    frequencies, or for latent attention ``mla.rope`` wide over YaRN's."""
+    if mla is None:
+        return _rope_tables(seq_len, hd, theta)
+    return _mla_rope_tables(seq_len, mla.rope, theta, mla.yarn)
+
+
+def latent_rows(c_kv, k_pe):
+    """What the latent pool stores of a token: ``[c_kv | k_pe | 0]`` at the
+    pool's row width, as one KV head ``[..., 1, W]`` for ``_kv_write``."""
+    pad = latent_row_width(c_kv.shape[-1], k_pe.shape[-1]) \
+        - c_kv.shape[-1] - k_pe.shape[-1]
+    row = jnp.concatenate(
+        [c_kv, k_pe, jnp.zeros(k_pe.shape[:-1] + (pad,), c_kv.dtype)], -1)
+    return row[..., None, :]
+
+
+def mla_expanded_attention(q_nope, q_pe, c_kv, k_pe, w_kvb, *, mla):
+    """Latent attention in the EXPANDED form over a whole sequence (whole-
+    prompt prefill, ``forward``): the latent is up-projected to per-head
+    keys ``[k_nope | k_pe]`` (``k_pe`` the one rotated vector a token, for
+    every head) and values, and attended causally through the attention
+    path every model shares (``kernels.flash_attention.attention``: flash on
+    the chip from 512 tokens, plain below). That path takes one width for
+    q, k and v and scales by its root, so q and k (192 wide) and v (128)
+    are zero-padded to a common 256 and the model's scale is folded into q.
+    q_nope ``[B, S, nh, nope]``, q_pe ``[B, S, nh, rope]``, c_kv ``[B, S,
+    rank]``, k_pe ``[B, S, rope]``; returns ``[B, S, nh, v]``."""
+    B, S, nh, _ = q_nope.shape
+    kv = jnp.einsum("bsr,rd->bsd", c_kv, w_kvb).reshape(B, S, nh, -1)
+    k = jnp.concatenate(
+        [kv[..., :mla.nope],
+         jnp.broadcast_to(k_pe[:, :, None, :], (B, S, nh, mla.rope))], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    width = -(-max(q.shape[-1], mla.v) // 128) * 128
+
+    def wide(x):
+        return jnp.pad(x, [(0, 0)] * 3 + [(0, width - x.shape[-1])])
+
+    q = (q.astype(jnp.float32) * (mla.scale * math.sqrt(width))
+         ).astype(q.dtype)
+    return _attention(wide(q), wide(k), wide(kv[..., mla.nope:]),
+                      causal=True)[..., :mla.v]
+
+
+def _mla_attention(hn, lw, *, nh, eps, rope, attend, mla):
+    """Latent attention's projections around the program's ``attend(q_nope,
+    q_pe, c_kv, k_pe, w_kvb) -> (attn [B, S, nh, v], carry)``, which brings
+    the form (expanded, or absorbed over the latent pool) and the cache
+    write. Scopes ``mla`` > ``mla_proj`` (here: the query's two projections,
+    the cache's down-projection, the norms and the rotary embedding; the
+    program's ``attend`` adds the up-projection or its absorption; the layer
+    adds ``W_o``) and ``mla_attend`` (the program's kernel)."""
+    B, S = hn.shape[0], hn.shape[1]
+    with jax.named_scope("mla_proj"):
+        c_q = _rms(jnp.einsum("bsh,hr->bsr", hn, lw["wq_a"]), lw["q_a_ln"],
+                   eps)
+        q = jnp.einsum("bsr,rd->bsd", c_q, lw["wq_b"]).reshape(B, S, nh, -1)
+        kv = jnp.einsum("bsh,hr->bsr", hn, lw["wkv_a"])
+        c_kv = _rms(kv[..., :mla.rank], lw["kv_a_ln"], eps)
+        k_pe = rope(kv[..., None, mla.rank:])[:, :, 0]
+        q_nope, q_pe = q[..., :mla.nope], rope(q[..., mla.nope:])
+    return attend(q_nope, q_pe, c_kv, k_pe, lw["wkv_b"])
+
+
+#: ``moe``'s entries past ``(top_k, renormalize)``, by ``moe_ffn``'s names
+_ROUTING_KEYS = ("n_group", "topk_group", "first_held", "scale")
+
+
 def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
                    moe=None, experts=None, tp_reduce=None,
-                   return_picks=False):
+                   return_picks=False, mla=None):
     """ONE pre-norm decoder layer on ``h [B, S, H]``, written once for the
     programs the default engine runs (whole-prompt prefill, the packed-span
-    forward of the unified step) and for ``OlmoeForCausalLM.forward``.
+    forward of the unified step) and for the models' own ``forward``.
 
     ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
-    ``_dq_layer``) and what it holds chooses the body: with ``q_norm`` q and
-    k are normalised before ``rope``; with ``router`` the FFN is the dropless
-    routed one (``kernels.moe_ffn``; ``moe`` is its static ``(top_k,
-    renormalize)``, ``experts`` the three expert stacks ``[L, E, ...]`` of
-    which ``lw["layer"]`` names this layer's, ``live [B, S]`` marks the
-    rows that make pairs), else the dense SwiGLU. The program brings its
-    own ``rope(x)`` and ``attend(q, k, v) -> (attn [B, S, nh, hd],
-    carry)``: cache writes and the attention kernel are the program's
-    business, not the layer's.
+    ``_dq_layer``) and what it holds chooses the body: with ``wkv_a`` the
+    attention is the latent one (``_mla_attention``; ``mla`` its static
+    numbers, ``models.deepseek_v2.Mla``); with ``q_norm`` q and k are normalised before ``rope``; with
+    ``router`` the FFN is the dropless routed one (``kernels.moe_ffn``;
+    ``moe`` is its static ``(top_k, renormalize)`` and then ``_ROUTING_KEYS``,
+    ``experts`` the three expert stacks ``[L, E, ...]`` of which
+    ``lw["layer"]`` names this layer's, ``live [B, S]`` marks the rows that
+    make pairs) plus, with ``ws_gate``, a shared expert every row runs (scope
+    ``moe_shared``, beside ``moe``); else the dense SwiGLU. The program
+    brings its own ``rope(x)`` and ``attend(q, k, v) -> (attn [B, S, nh, hd],
+    carry)`` (latent attention: ``_mla_attention``'s): cache writes and the
+    attention kernel are the program's business, not the layer's.
     Returns ``(h, carry, moe_stats or None)``; with ``return_picks`` the
     third is ``(moe_stats, picked experts [B, S, top_k])``."""
     B, S = h.shape[0], h.shape[1]
     with jax.named_scope("attn"):
         hn = _rms(h, lw["input_ln"], eps)
-        q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"], nh, nkv, hd)
-        if "q_norm" in lw:
-            q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps)
-        attn, carry = attend(rope(q), rope(k), v)
-        o = _o_proj(attn.reshape(B, S, nh * hd), lw["wo"])
+        if "wkv_a" in lw:
+            with jax.named_scope("mla"):
+                attn, carry = _mla_attention(hn, lw, nh=nh, eps=eps,
+                                             rope=rope, attend=attend,
+                                             mla=mla)
+                with jax.named_scope("mla_proj"):
+                    o = _o_proj(attn.reshape(B, S, -1), lw["wo"])
+        else:
+            q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"], nh, nkv,
+                                hd)
+            if "q_norm" in lw:
+                q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps)
+            attn, carry = attend(rope(q), rope(k), v)
+            o = _o_proj(attn.reshape(B, S, nh * hd), lw["wo"])
         h = h + (o if tp_reduce is None else tp_reduce(o))
     hn = _rms(h, lw["post_ln"], eps)
     if "router" in lw:
         m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
                             top_k=moe[0], live=live, renormalize=moe[1],
-                            return_picks=return_picks)
+                            return_picks=return_picks,
+                            **dict(zip(_ROUTING_KEYS, moe[2:])))
         stats = tuple(stats) if return_picks else stats[0]
+        if "ws_gate" in lw:
+            with jax.named_scope("moe_shared"):
+                m = m + _swiglu_raw(hn, lw["ws_gate"], lw["ws_up"],
+                                    lw["ws_down"])
     else:
         m, stats = _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
                                 lw["w_down"]), None
@@ -650,8 +778,21 @@ def sample_rows(logits, keys, temps, top_ks):
 
 # ------------------------------------------------------------------ prefill
 @jax.named_scope("prefill")
+def _moe_outputs(stats):
+    """A program's routed-FFN outputs as a tuple: nothing for a dense model,
+    the layers' summary ``[L, 4]``, and where the layers also returned
+    their picks (``[L, ..., top_k]``) those as ``[L, rows, top_k]``."""
+    if stats is None:
+        return ()
+    if isinstance(stats, tuple):
+        st, picks = stats
+        return (st, picks.reshape(picks.shape[0], -1, picks.shape[-1]))
+    return (stats,)
+
+
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
-                  hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None):
+                  hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
+                  mla=None, return_picks=False):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -662,39 +803,61 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     keeps it out of every real position's attention, and the cache slot
     masks it by ``lengths`` until decode overwrites it. A routed-FFN
     model (``router`` in ``params``) returns a fifth value, the layers'
-    routing summary ``[L, 3]`` int32 (``kernels.moe_ffn.STATS``); its
-    padding columns make no (token, expert) pair.
+    routing summary ``[L, 4]`` int32 (``kernels.moe_ffn.STATS``); its
+    padding columns make no (token, expert) pair; with ``return_picks`` a
+    sixth, the experts every position picked, ``[L, G * S_pad, top_k]`` int32
+    by the router's ids (``serving.routing_record``). A model with latent
+    attention (``wkv_a``; ``mla`` its static numbers) attends in the
+    expanded form and returns as ``pk`` the rows its latent pool stores,
+    ``[L, G, S_pad, 1, W]``, and a ``pv`` of width 0: no per-head K or V
+    leaves the layer.
     """
     B, S = ids.shape
-    sin, cos = _rope_tables(S, hd, theta)
-    names, stack, experts = _layer_stack(params)
+    sin, cos = _rope_tables_for(S, hd, theta, mla)
     wdt = params["embed"].dtype
     head = _dq_head(params, tied, wdt, a8)
-    live = (None if experts is None else
-            jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None])
+    live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
 
-    def prefill_layer(h, lp):
-        h, kv, stats = _decoder_layer(
-            h, dict(zip(names, _dq_layer(lp, wdt, a8))), nh=nh, nkv=nkv,
-            hd=hd, eps=eps, rope=lambda x: _apply_rope(x, sin, cos),
-            attend=lambda q, k, v: (_attention(q, k, v, causal=True),
-                                    (k, v)),
-            live=live, moe=moe, experts=experts, tp_reduce=tp_reduce)
-        return h, (kv, stats)
+    if mla is None:
+        def attend(q, k, v):
+            return _attention(q, k, v, causal=True), (k, v)
+    else:
+        def attend(q_nope, q_pe, c_kv, k_pe, w_kvb):
+            with jax.named_scope("mla_attend"):
+                attn = mla_expanded_attention(q_nope, q_pe, c_kv, k_pe,
+                                              w_kvb, mla=mla)
+            rows = latent_rows(c_kv, k_pe)
+            return attn, (rows, rows[..., :0])
 
     x = jnp.take(params["embed"], ids, axis=0)
-    x, ((pk, pv), stats) = jax.lax.scan(prefill_layer, x, stack)
+    kvs, stats = [], None
+    for _, names, stack, experts in _layer_stacks(params):
+        def prefill_layer(h, lp):
+            h, kv, st = _decoder_layer(
+                h, dict(zip(names, _dq_layer(lp, wdt, a8))), nh=nh, nkv=nkv,
+                hd=hd, eps=eps, rope=lambda x: _apply_rope(x, sin, cos),
+                attend=attend, live=live, moe=moe, experts=experts,
+                tp_reduce=tp_reduce, mla=mla,
+                return_picks=return_picks and experts is not None)
+            return h, (kv, st)
+
+        x, (kv, st) = jax.lax.scan(prefill_layer, x, stack)
+        kvs.append(kv)
+        stats = st if experts is not None else stats
+    pk, pv = (jnp.concatenate(side) if len(kvs) > 1 else side[0]
+              for side in zip(*kvs))
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
     last_h = _rms(last, params["final_norm"], eps)
     logits = _head_logits(last_h, head)
     both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
     tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
-    return (pk, pv, tok0, both[:, 0]) + (() if stats is None else (stats,))
+    return (pk, pv, tok0, both[:, 0]) + _moe_outputs(stats)
 
 
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
-                     collective_dtype="fp", wq8=False, a8=False, moe=None):
+                     collective_dtype="fp", wq8=False, a8=False, moe=None,
+                     mla=None, return_picks=False):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -716,7 +879,7 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                        rep, rep)))
     return jax.jit(functools.partial(
         _prefill_impl, nh=nh, nkv=nkv, hd=hd, eps=eps, theta=theta,
-        tied=tied, a8=a8, moe=moe))
+        tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -975,7 +1138,7 @@ def _span_last_sample(params, head, x, qstart, qlen, keys, temps, top_ks,
 def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
-                         moe=None):
+                         moe=None, mla=None, return_picks=False):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -984,15 +1147,18 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     at its logical position (dead rows — ``seg == R`` — and positions
     past the logical capacity DROP), attention runs through the ragged
     paged kernel or its jnp oracle. Returns ``(x [1, T, H], pk, pv,
-    moe_stats)``: the layers' routing summary ``[L, 3]`` int32 of a
-    routed-FFN model (dead packed rows make no pair), else None.
+    moe_stats)``: the routed layers' summary ``[L, 4]`` int32 of a
+    routed-FFN model (dead packed rows make no pair), else None; with
+    ``return_picks`` the pair ``(summary, picked experts [L, 1, T, top_k])``.
+    A model with latent attention (``mla``) writes one row a token into the K side,
+    the latent pool (its V side has width 0), and every span, decode row
+    and chunk alike, attends in the absorbed form.
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
     mb = tables.shape[1]
     s_tot = mb * bs
     T = ids.shape[0]
-    names, stack, experts = _layer_stack(params)
     wdt = params["embed"].dtype
     sin_p = jnp.take(sin, pos, axis=0, mode="clip")[None]   # [1, T, D]
     cos_p = jnp.take(cos, pos, axis=0, mode="clip")[None]
@@ -1008,41 +1174,77 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     phys0 = jnp.where(live_tok & (pos < s_tot), phys0, nb)
     prow0 = pos % bs
 
-    def layer0(carry, lp):
-        h, pk, pv = carry
-        lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
-        at = (lw["layer"], phys0, prow0)
+    def scan_stack(carry, first, names, stack, experts):
+        def layer0(carry, lp):
+            h, pk, pv = carry
+            lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
+            layer = first + lw["layer"]     # this layer's place in the pool
+            at = (layer, phys0, prow0)
 
-        def attend(q, k, v):
-            # write the packed K/V through the tables (quantize-on-write on
-            # an int8 pool), then attend over each span causally at its
-            # row's kv length — THE one dequant site: the ragged kernel
-            # (or its oracle) dequantizes right after the table-indirect
-            # fetch, and every consumer of this forward (unified step,
-            # multi-tick tick 0, speculative verify) rides it
-            npk = _kv_write(pk, at, k[0])
-            npv = _kv_write(pv, at, v[0])
-            kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
-            ragged = (ragged_paged_attention_pallas
-                      if decode_attn == "pallas"
-                      else ragged_attention_reference)
-            attn = ragged(q[0], kd, vd, tables, qstart, qlen, kvlen,
-                          k_scale=ksc, v_scale=vsc, layer=lw["layer"])
-            return attn, (npk, npv)
+            def attend(q, k, v):
+                # write the packed K/V through the tables (quantize-on-write
+                # on an int8 pool), then attend over each span causally at
+                # its row's kv length — THE one dequant site: the ragged
+                # kernel (or its oracle) dequantizes right after the
+                # table-indirect fetch, and every consumer of this forward
+                # (unified step, multi-tick tick 0, speculative verify)
+                # rides it
+                npk = _kv_write(pk, at, k[0])
+                npv = _kv_write(pv, at, v[0])
+                kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
+                ragged = (ragged_paged_attention_pallas
+                          if decode_attn == "pallas"
+                          else ragged_attention_reference)
+                attn = ragged(q[0], kd, vd, tables, qstart, qlen, kvlen,
+                              k_scale=ksc, v_scale=vsc, layer=layer)
+                return attn, (npk, npv)
 
-        h, (pk, pv), stats = _decoder_layer(
-            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps,
-            rope=lambda x: _apply_rope_grid(x, sin_p, cos_p),
-            attend=attend, live=live_tok[None], moe=moe, experts=experts,
-            tp_reduce=tp_reduce)
-        return (h, pk, pv), stats
+            def attend_latent(q_nope, q_pe, c_kv, k_pe, w_kvb):
+                # one row a token into the latent pool, then the ABSORBED
+                # form over it for every span: W_UK folded into the query,
+                # W_UV into the output, all heads reading the same fetched
+                # rows. The jnp path is the oracle in the expanded form.
+                npk = _kv_write(pk, at, latent_rows(c_kv, k_pe)[0])
+                span = dict(scale=mla.scale, layer=layer)
+                if decode_attn != "pallas":
+                    with jax.named_scope("mla_attend"):
+                        attn = mla_ragged_attention_reference(
+                            q_nope[0], q_pe[0], w_kvb, npk, tables, qstart,
+                            qlen, kvlen, **span)
+                    return attn[None], (npk, pv)
+                w = w_kvb.reshape(mla.rank, nh, mla.nope + mla.v)
+                with jax.named_scope("mla_proj"):
+                    q_lat = jnp.einsum("thd,rhd->thr", q_nope[0],
+                                       w[..., :mla.nope])
+                with jax.named_scope("mla_attend"):
+                    o_lat = mla_ragged_attention_pallas(
+                        q_lat, q_pe[0], npk, tables, qstart, qlen, kvlen,
+                        **span)
+                with jax.named_scope("mla_proj"):
+                    attn = jnp.einsum("thr,rhd->thd", o_lat,
+                                      w[..., mla.nope:])
+                return attn[None], (npk, pv)
 
-    # the pool rides the scan as CARRY, whole: a layer appends its rows and
-    # reads its blocks at [layer, ...] of the one buffer, so no op of the
+            h, (pk, pv), stats = _decoder_layer(
+                h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps,
+                rope=lambda x: _apply_rope_grid(x, sin_p, cos_p),
+                attend=attend if mla is None else attend_latent,
+                live=live_tok[None], moe=moe, experts=experts,
+                tp_reduce=tp_reduce, mla=mla,
+                return_picks=return_picks and experts is not None)
+            return (h, pk, pv), stats
+
+        return jax.lax.scan(layer0, carry, stack)
+
+    # the pool rides the scans as CARRY, whole: a layer appends its rows and
+    # reads its blocks at [layer, ...] of the one buffer, so no op of a
     # scan slices a layer out of the pool or stacks one back into it
     x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
-    (x, pk, pv), stats = jax.lax.scan(layer0, (x, pool_k, pool_v), stack)
-    return x, pk, pv, stats
+    carry, stats = (x, pool_k, pool_v), None
+    for first, names, stack, experts in _layer_stacks(params):
+        carry, st = scan_stack(carry, first, names, stack, experts)
+        stats = st if experts is not None else stats
+    return carry + (stats,)
 
 
 @jax.named_scope("ragged_step")
@@ -1051,7 +1253,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       prev_toks, take, chunk_keys, adopt,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
-                      moe=None):
+                      moe=None, mla=None, return_picks=False):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -1116,15 +1318,18 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     keys_in = jnp.where(((qlen > 0) & (dec_mask == 0))[:, None],
                         chunk_keys, keys)
     s_tot = tables.shape[1] * _kv_data(pool_k).shape[2]
-    sin, cos = _rope_tables(s_tot, hd, theta)
-    stack = tuple(params[k] for k in _STACK_KEYS)
+    sin, cos = _rope_tables_for(s_tot, hd, theta, mla)
+    # the fused tail's own layer body scans the GQA entries alone (a model
+    # it was not taught never runs with n_steps > 1: the engine raises)
+    stack = (tuple(params[k] for k in _STACK_KEYS) if n_steps > 1 else None)
     head = _dq_head(params, tied, params["embed"].dtype, a8)
 
     # ----------------------------------- tick 0 (shared packed forward)
     x, pk, pv, moe_stats = _packed_span_forward(
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-        decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8, moe=moe)
+        decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8, moe=moe,
+        mla=mla, return_picks=return_picks)
     tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
                                       keys_in, temps, top_ks, eps)
 
@@ -1151,15 +1356,15 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     else:
         toks, keys_fin = tok0[None], keys_t0
     keys_out = jnp.where((adopt > 0)[:, None], keys_fin, keys_in)
-    return (pk, pv, toks, toks[-1], keys_out) \
-        + (() if moe_stats is None else (moe_stats,))
+    return (pk, pv, toks, toks[-1], keys_out) + _moe_outputs(moe_stats)
 
 
 def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          decode_attn, donate=None, tp=1,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
-                         collective_overlap=False, moe=None):
+                         collective_overlap=False, moe=None, mla=None,
+                         return_picks=False):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, token_budget)`` plus the fused
     ``n_steps`` — one compilation per step size serves every span mix,
@@ -1194,7 +1399,8 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
         functools.partial(
             _ragged_step_impl, n_steps=n_steps, nh=nh, nkv=nkv, hd=hd,
             eps=eps, theta=theta, tied=tied, decode_attn=decode_attn,
-            a8=a8, fused=fused, moe=moe),
+            a8=a8, fused=fused, moe=moe, mla=mla,
+            return_picks=return_picks),
         donate_argnums=(1, 2) if donate else ())
 
 

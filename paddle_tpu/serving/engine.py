@@ -50,7 +50,7 @@ from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
 from .decode import build_paged_suffix_prefill_fn, build_prefill_fn, \
-    build_ragged_step_fn, _STACK_EXTRA_KEYS
+    build_ragged_step_fn, TAUGHT_KEYS, latent_row_width
 from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, select_victims
 from .request import GenerationRequest, GenerationResult, Sequence
@@ -284,7 +284,11 @@ class ContinuousBatchingEngine:
         # the model brings its decode parameters; what the tree holds
         # chooses the layer body inside the programs (decode._decoder_layer)
         self._params, self._tied = model.decode_params()
-        extras = [k for k in _STACK_EXTRA_KEYS if k in self._params]
+        # a routed-FFN model may bring a record for the experts its step
+        # programs pick (serving.routing_record): they then return them
+        self._routing = getattr(model, "routing_record", None) \
+            if "router" in self._params else None
+        extras = [k for k in TAUGHT_KEYS if k in self._params]
         if extras:
             # only the default engine's two programs (whole-prompt prefill,
             # the unified step) were taught a layer with these entries:
@@ -296,6 +300,10 @@ class ContinuousBatchingEngine:
                    "spec_decode": bool(spec_decode),
                    "decode_chunk > 1": int(decode_chunk) > 1,
                    "prefix_cache": bool(prefix_cache)}
+            if "wkv_a" in self._params:
+                # a latent pool has no heads to scale by and no V side:
+                # the quantized pools' planes and kernels do not apply
+                off["kv_dtype"] = kv_dtype is not None
             bad = [name for name, on in off.items() if on]
             if bad:
                 raise ValueError(
@@ -362,6 +370,13 @@ class ContinuousBatchingEngine:
                                                self._wq8)
             self._params = placed[pkey]
         dtype = self._params["embed"].dtype
+        # what a cached token's row is: Hkv heads of head_dim on a K and a
+        # V side, or for latent attention ONE row of the normalised latent
+        # and the rotated shared key, padded to whole lanes, and no V side
+        geom = dict(num_kv_heads=c.num_key_value_heads, head_dim=c.head_dim)
+        if "wkv_a" in self._params:
+            geom = dict(num_kv_heads=1, v_dim=0, head_dim=latent_row_width(
+                c.kv_lora_rank, c.qk_rope_head_dim))
         from .block_manager import BlockManager
         from .prefix_cache import PrefixCache
         self.prefix_cache = None
@@ -422,20 +437,18 @@ class ContinuousBatchingEngine:
                     raise ValueError(
                         f"prefix_blocks must be >= 1, got {budget}")
             pool = BlockManager(
-                c.num_hidden_layers, live + budget, bs,
-                c.num_key_value_heads, c.head_dim, dtype=dtype,
-                kv_dtype=self._kv_dtype, mesh=tp_mesh)
+                c.num_hidden_layers, live + budget, bs, dtype=dtype,
+                kv_dtype=self._kv_dtype, mesh=tp_mesh, **geom)
             self.prefix_cache = PrefixCache(
                 pool, max_blocks=budget,
                 host_tier_bytes=self._host_tier_bytes)
         else:
             pool = BlockManager(
-                c.num_hidden_layers, live, bs, c.num_key_value_heads,
-                c.head_dim, dtype=dtype, kv_dtype=self._kv_dtype,
-                mesh=tp_mesh)
+                c.num_hidden_layers, live, bs, dtype=dtype,
+                kv_dtype=self._kv_dtype, mesh=tp_mesh, **geom)
         self.cache = PagedKVCache(
             c.num_hidden_layers, self.num_slots, self.max_seq_len,
-            c.num_key_value_heads, c.head_dim, dtype=dtype,
+            geom["num_kv_heads"], geom["head_dim"], dtype=dtype,
             block_size=bs, pool=pool, prefix_cache=self.prefix_cache,
             kv_dtype=self._kv_dtype)
         # chunked prefill: the chunk is rounded UP to a block multiple so
@@ -620,7 +633,8 @@ class ContinuousBatchingEngine:
                       "preemptions": 0, "restores": 0,
                       "policy_preemptions": 0,
                       "moe_pairs": 0, "moe_experts_touched": 0,
-                      "moe_max_expert_pairs": 0, "moe_layer_calls": 0}
+                      "moe_max_expert_pairs": 0, "moe_picks": 0,
+                      "moe_layer_calls": 0}
         # fault-injection hook (serving/faults.py): called with the
         # engine at the top of every step attempt; None in production.
         # Whatever it raises propagates to the driver — except
@@ -744,8 +758,12 @@ class ContinuousBatchingEngine:
         if self.routed_ffn:
             # the routed FFN's static numbers, model hyper-parameters like
             # the head counts above
-            consts["moe"] = (int(c.num_experts_per_tok),
-                             bool(c.norm_topk_prob))
+            consts["moe"] = getattr(c, "routing", None) or (
+                int(c.num_experts_per_tok), bool(c.norm_topk_prob))
+        if "wkv_a" in self._params:
+            consts["mla"] = c.mla
+        if self._routing is not None:
+            consts["return_picks"] = True
         return consts
 
     def _tp_consts(self):
@@ -769,9 +787,9 @@ class ContinuousBatchingEngine:
 
     def _count_moe(self, summary):
         """Add one program call's routing summary (``()`` from a dense
-        model's program, else one ``[L, 3]`` int32 array, per layer
-        ``kernels.moe_ffn.STATS``: live pairs, experts touched, the
-        fullest expert's pairs) to the
+        model's program, else one ``[L, 4]`` int32 array, per routed layer
+        ``kernels.moe_ffn.STATS``: live pairs on held experts, experts
+        touched, the fullest expert's pairs, picks made) to the
         always-on counters. The array rides the fetch that fences the
         step's tokens: no second sync. Returns the call's totals as span
         args, or None."""
@@ -1254,6 +1272,10 @@ class ContinuousBatchingEngine:
                     self._params, ids, lens, keys, temps, topks)
                 tok0s = np.asarray(tok0s)
                 sp.add(self._count_moe(moe))
+            if self._routing is not None:
+                self._routing.note(moe[1], [
+                    (seq, i * s_pad, seq.work_len, 0)
+                    for i, seq in enumerate(group)])
             co = self._co()
             if co is not None:
                 # sharded cold prefill: one pass over the padded group
@@ -2006,6 +2028,13 @@ class ContinuousBatchingEngine:
         self._inflight = _InFlight(
             toks, tok_fin, moe, keys_in, n, rows, chunks, cursor,
             None if prev is not None else t0)
+        if self._routing is not None:
+            # which rows of this program's picks are whose, at which
+            # positions (the array itself stays on the device)
+            self._routing.note(moe[1], [
+                (seq, int(qstart[slot]), int(qlen[slot]),
+                 int(kvlen[slot] - qlen[slot]))
+                for slot, seq, *_ in rows + chunk_rows])
         self.stats["unified_steps"] += 1
         self.stats["steps_dispatched_ahead"] += prev is not None
         if co is not None:

@@ -74,7 +74,7 @@ def kv_rows(x):
     """K/V rows ``[..., Hkv, D]`` as the pool stores them, ``[..., Hkv *
     D]`` (``BlockManager``'s docstring): every writer reshapes the rows it
     holds, never the pool."""
-    return x.reshape(x.shape[:-2] + (-1,))
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
 FP8_MAX = 448.0   # float8_e4m3fn finite max — the saturation bound
@@ -324,7 +324,10 @@ class PagedKVCache:
 
     The pool's device arrays are the single source of KV truth; the
     decode / suffix-prefill programs update them functionally and the
-    engine adopts the result via :meth:`update`. How they are laid out,
+    engine adopts the result via :meth:`update`. ``num_kv_heads`` x
+    ``head_dim`` is the K side's row (and the V side's, unless the pool was
+    built with a ``v_dim`` of its own: a latent pool's one row a token has
+    no V side, ``BlockManager``). How they are laid out,
     and what :attr:`sentinel` (the block id ``pool.num_blocks`` that fills
     unmapped table entries) means to a writer and to a reader, is stated
     once, in :class:`~.block_manager.BlockManager`'s docstring.

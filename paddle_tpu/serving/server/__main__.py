@@ -8,8 +8,11 @@ The ``tiny`` preset is the CPU-runnable config; ``350m`` and
 ``llama7b-8of32`` (Llama-2-7B widths, depth cut to 8 of 32 layers) are
 sized for one TPU v5e chip, as is ``olmoe1b7b-8of16`` (OLMoE-1B-7B-0125
 widths, a routed FFN of 64 experts with 8 a token, depth cut to 8 of 16
-layers); ``olmoe-tiny`` is its CPU-runnable twin. Weights are random, made
-from ``--seed``.
+layers) and ``dsv2-8of60-ep8`` (DeepSeek-V2 widths: latent attention, a
+leading dense layer and 7 expert layers of which this chip holds one routing
+group, 20 of the router's 160 experts; vocabulary cut to an eighth);
+``olmoe-tiny`` and ``dsv2-tiny`` are their CPU-runnable twins. Weights are
+random, made from ``--seed``.
 Prompts are token-id arrays (the framework ships no tokenizer) — see
 README "Serving over HTTP" for curl examples.
 
@@ -27,7 +30,7 @@ import threading
 
 
 PRESETS = ("tiny", "350m", "llama7b-8of32", "olmoe-tiny",
-           "olmoe1b7b-8of16")
+           "olmoe1b7b-8of16", "dsv2-tiny", "dsv2-8of60-ep8")
 
 
 def build_model(preset, decode_attention, seed):
@@ -35,6 +38,20 @@ def build_model(preset, decode_attention, seed):
     from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                          llama_7b, llama_tiny)
     paddle.seed(seed)
+    if preset.startswith("dsv2"):
+        from paddle_tpu.models.deepseek_v2 import (
+            DeepseekV2Config, DeepseekV2ForCausalLM, deepseek_v2_tiny)
+        if preset == "dsv2-tiny":
+            return DeepseekV2ForCausalLM(deepseek_v2_tiny(
+                decode_attention=decode_attention))
+        # every width of DeepseekV2Config()'s defaults, the published ones;
+        # the cut is benchmark/configs/deepseek-v2-serve-8L-ep8.json's: 8 of
+        # 60 layers, routing group 0 of the router's eight held, an eighth
+        # of the vocabulary, 8192 tokens a slot (9.6 GiB of bf16 weights)
+        return DeepseekV2ForCausalLM(DeepseekV2Config(
+            num_hidden_layers=8, n_routed_experts=20, router_experts=160,
+            vocab_size=12800, max_position_embeddings=8192,
+            dtype="bfloat16", decode_attention=decode_attention))
     if preset.startswith("olmoe"):
         from paddle_tpu.models.olmoe import (OlmoeConfig, OlmoeForCausalLM,
                                              olmoe_tiny)
@@ -67,7 +84,8 @@ def build_model(preset, decode_attention, seed):
 
 
 def _model_name(preset):
-    return preset if preset.startswith("olmoe") else f"llama-{preset}"
+    return (preset if preset.startswith(("olmoe", "dsv2"))
+            else f"llama-{preset}")
 
 
 def _runtime_doc(engine):
@@ -126,8 +144,13 @@ def main(argv=None):
                          "Llama-2-7B widths with 8 of 32 layers, bf16; "
                          "olmoe-tiny: CPU-runnable routed FFN; "
                          "olmoe1b7b-8of16: OLMoE-1B-7B-0125 widths with 8 "
-                         "of 16 layers, bf16 (serves on the default "
-                         "path only: other engine switches raise)")
+                         "of 16 layers, bf16; dsv2-tiny / "
+                         "dsv2-8of60-ep8: DeepSeek-V2 (latent attention, "
+                         "shared + routed experts of which one routing "
+                         "group is held), CPU-runnable / published widths "
+                         "with 8 of 60 layers, bf16 (the last four serve "
+                         "on the default path only: other engine switches "
+                         "raise)")
     ap.add_argument("--decode-attention", choices=("pallas", "jnp"),
                     default="pallas",
                     help="the Pallas attention kernels (compiled on a "

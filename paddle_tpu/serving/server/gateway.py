@@ -104,7 +104,8 @@ CARRIED_ENGINE_STATS = (
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
     "step_decode_tokens", "moe_pairs", "moe_experts_touched",
-    "moe_max_expert_pairs", "moe_layer_calls", "steps_dispatched_ahead",
+    "moe_max_expert_pairs", "moe_picks", "moe_layer_calls",
+    "steps_dispatched_ahead",
 ) + tuple("drains_" + r for r in DRAIN_REASONS)
 
 #: same carry for the prefix cache's own stats dict (a rebuild builds a
@@ -483,7 +484,11 @@ class ServingGateway:
             # are per-layer-call means.
             for stat, text in (
                     ("pairs", "Live (token, expert) pairs the routed FFN "
-                     "multiplied."),
+                     "multiplied: the picks that landed on an expert "
+                     "this engine holds."),
+                    ("picks", "Experts the live tokens picked over the "
+                     "router's whole width (pairs again when every "
+                     "expert is held here)."),
                     ("experts_touched", "Experts some live pair touched "
                      "(whose weights a layer call read)."),
                     ("max_expert_pairs", "Pairs on the fullest expert of "

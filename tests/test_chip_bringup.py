@@ -23,6 +23,7 @@ from jax.sharding import (NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from paddle_tpu.kernels import (flash_attention, moe_ffn, pallas_flash,
+                                pallas_mla_ragged_attention,
                                 pallas_paged_decode, pallas_ragged_attention)
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.profiler.metrics import peak_flops_per_chip
@@ -54,7 +55,7 @@ def v5e_devices(monkeypatch):
     if devices is None:
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
     for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
-                moe_ffn):
+                pallas_mla_ragged_attention, moe_ffn):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -171,6 +172,53 @@ class TestMosaicCompilesTheRoutedFfn:
         assert compiled.as_text().count("tpu_custom_call") == 3
         # temp holds the pair buffers (tens of MB), not a layer of experts
         assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
+
+
+class TestMosaicCompilesDeepseekV2:
+    """DeepSeek-V2's two kernels at its published widths (128 heads over a
+    latent of 512 + 64 stored in rows of 640 lanes; a router of 160 over a
+    held 20 experts of 1536, 6 a token from 3 of 8 groups) and at the
+    serving cell's shapes: 32 slots x 8192 tokens, a 512-token chunk."""
+    NH, RANK, ROPE, W = 128, 512, 64, 640
+    L, R, MB, BS = 8, 32, 256, 32
+
+    @pytest.mark.parametrize("tokens", [544, 32])
+    def test_latent_attention_over_the_stored_pool(self, v5e, tokens):
+        i32 = jnp.int32
+
+        def attend(q_lat, q_pe, pool, tables, qs, ql, kl, layer):
+            return pallas_mla_ragged_attention.mla_ragged_attention_pallas(
+                q_lat, q_pe, pool, tables, qs, ql, kl, scale=0.1,
+                layer=layer)
+        args = (v5e((tokens, self.NH, self.RANK)),
+                v5e((tokens, self.NH, self.ROPE)),
+                v5e((self.L, self.R * self.MB, self.BS, self.W)),
+                v5e((self.R, self.MB), i32), v5e((self.R,), i32),
+                v5e((self.R,), i32), v5e((self.R,), i32), v5e((), i32))
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(attend).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the pool (2.5 GiB) is read where it lies: no layer of it (320
+        # MiB) is cut out or copied for the call
+        assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
+
+    def test_latent_row_is_whole_lanes(self):
+        assert pallas_mla_ragged_attention.latent_row_width(512, 64) == 640
+        assert pallas_mla_ragged_attention.latent_row_width(32, 8) == 128
+
+    @pytest.mark.parametrize("rows", [544, 256])
+    def test_routed_ffn_with_a_share_of_the_experts(self, v5e, rows):
+        H, E, held, width = 5120, 160, 20, 1536
+
+        def ffn(h, r, wg, wu, wd, live, layer):
+            return moe_ffn.moe_ffn(h, r, wg, wu, wd, top_k=6, live=live,
+                                   layer=layer, n_group=8, topk_group=3,
+                                   first_held=0, scale=16.0)
+        n = _mosaic_calls(
+            ffn, v5e((rows, H)), v5e((H, E)), v5e((7, held, H, width)),
+            v5e((7, held, H, width)), v5e((7, held, width, H)),
+            v5e((rows,), jnp.bool_), v5e((), jnp.int32))
+        assert n == 3                    # gate, up, down over the held stack
 
 
 class TestUnifiedStepLeavesThePoolInPlace:

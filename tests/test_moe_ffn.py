@@ -61,8 +61,10 @@ def test_moe_ffn_equals_reference(routing, live_kind):
     n_live = T if live is None else int(jnp.sum(live))
     stats, ref_stats = np.asarray(stats), np.asarray(ref_stats)
     assert (stats == ref_stats).all()
-    pairs, touched, fullest = (int(stats[STATS.index(n)]) for n in STATS)
-    assert pairs == n_live * K                  # dropless: K per live row
+    pairs, touched, fullest, picks = (int(stats[STATS.index(n)])
+                                      for n in STATS)
+    # dropless: K per live row; every expert held, so every pick a pair
+    assert pairs == picks == n_live * K
     if routing == "forced" and n_live:
         assert touched == K and fullest == n_live
     if not n_live:

@@ -294,12 +294,16 @@ def test_metrics_carry_the_routing_counters(http_server):
             name, _, val = line.rpartition(" ")
             values[name] = float(val)
     assert set(values) == {"serving_moe_pairs_total",
+                           "serving_moe_picks_total",
                            "serving_moe_experts_touched_total",
                            "serving_moe_layer_calls_total",
                            "serving_moe_max_expert_pairs_total"}
     assert values["serving_moe_layer_calls_total"] >= 2 * 4
     # dropless: K pairs for every live token of every layer call
     assert values["serving_moe_pairs_total"] % 2 == 0
+    # every expert is held here: each pick made a pair
+    assert values["serving_moe_picks_total"] \
+        == values["serving_moe_pairs_total"]
     assert values["serving_moe_max_expert_pairs_total"] \
         <= values["serving_moe_pairs_total"]
     assert srv.gateway.engine.decode_compilations() == 1
