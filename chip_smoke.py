@@ -52,6 +52,13 @@ HTTP_TIMEOUT_S = 420        # one request may wait on several ~15 s compiles
 # (query heads, kv heads, head dim) the kernels are checked at
 FULL = dict(
     geometries=[(32, 32, 128), (32, 8, 128)], flash_seq=2048,
+    # the ragged kernel at the two dense serving cells' geometries: heads,
+    # KV heads, head width, table entries, rows of (span, kv length)
+    ragged_cells={
+        "mistral chunk 32/8/128": (32, 8, 128, 128, [
+            (1, 2307), (512, 3584), (1, 33), (1, 1024), (0, 0), (1, 700)]),
+        "olmoe decode 16/16/128": (16, 16, 128, 64, [
+            (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)])},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -68,6 +75,10 @@ FULL = dict(
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
+    ragged_cells={
+        "chunk 4/2/32": (4, 2, 32, 8, [(1, 150), (48, 200), (1, 33), (0, 0)]),
+        "decode 16/16/32": (16, 16, 32, 8, [
+            (1, 20 + 9 * i) for i in range(6)])},
     preset="tiny", slots=4, max_seq_len=128, prefill_chunk=32,
     vocab=256, medium_prompt=24, long_prompt=70,
     tp=2,                                   # llama_tiny has two kv heads
@@ -456,6 +467,37 @@ def _agree(name, got, want, tol, results):
           f"tolerance {tol}")
 
 
+def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12):
+    """The ragged kernel's arguments for ``rows`` of (query span, kv length
+    after this step; span 0 is a dead row) packed back to back with ``pad``
+    rows in no span behind them: bf16, tables of ``mb`` entries scattered
+    over a pool that is NaN wherever no live row may read (stale rows of a
+    mapped block, unmapped blocks), so a kernel that reads one returns
+    NaN."""
+    import jax.numpy as jnp
+    import numpy as np
+    R = len(rows)
+    qlen = np.array([q for q, _ in rows], np.int32)
+    kvlen = np.array([k for _, k in rows], np.int32)
+    nb = int(sum(-(-k // bs) for k in kvlen)) + 4
+    perm = rng.permutation(nb)
+    tables = np.full((R, mb), nb, np.int32)     # nb = unmapped sentinel
+    pool = rng.randn(2, nb, bs, nkv, hd).astype(np.float32)
+    live = np.zeros((nb, bs), bool)
+    used = 0
+    for r, k in enumerate(kvlen):
+        for b in range(-(-k // bs)):
+            tables[r, b] = perm[used]
+            live[perm[used], :min(bs, k - b * bs)] = True
+            used += 1
+    pool[:, ~live] = np.nan
+    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+    q = rng.randn(int(qlen.sum()) + pad, nh, hd).astype(np.float32)
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool[0], jnp.bfloat16),
+            jnp.asarray(pool[1], jnp.bfloat16), jnp.asarray(tables),
+            jnp.asarray(qstart), jnp.asarray(qlen), jnp.asarray(kvlen))
+
+
 def phase_kernels(rehearse):
     stats, device = _child_start(rehearse)
     import jax
@@ -478,6 +520,33 @@ def phase_kernels(rehearse):
         with jax.default_matmul_precision("highest"):
             return jax.jit(fn)(*args)
 
+    def ragged_agrees(name, args, piece=64):
+        # the oracle gathers every token's whole table: row by row, `piece`
+        # span tokens a call (tokens lo .. lo + n of a span are a span of n
+        # whose kv ends where theirs does), or a cell's step is tens of GB
+        q, pk, pv, tables, qstart, qlen, kvlen = args
+        want = np.zeros(q.shape, np.float32)
+        one = jnp.zeros(1, jnp.int32)
+        for r, (at, n_r, end) in enumerate(zip(*map(np.asarray, args[4:]))):
+            for lo in range(0, n_r, piece):
+                n = min(piece, n_r - lo)
+                want[at + lo:at + lo + n] = reference(
+                    ragged_attention_reference, q[at + lo:at + lo + n], pk,
+                    pv, tables[r:r + 1], one, one + n, one + end - n_r + lo + n)
+        got = jax.jit(ragged_paged_attention_pallas)(*args)
+        _agree(name, got, want, TOL_FWD, errors)
+        check(not np.asarray(got[int(np.asarray(qlen).sum()):],
+                             np.float32).any(),
+              f"{name}: rows outside every span are not exact zeros")
+
+    # ---- the ragged kernel at the serving cells' own steps: a 512-token
+    # chunk resumed 3 k into its prompt beside decode rows (Mistral, the
+    # general walk in groups of pages), and 24 decode rows over 100-1,300
+    # cached tokens (OLMoE, the one-token walk) --------------------------
+    for tag, (nh, nkv, hd, mb, rows) in size["ragged_cells"].items():
+        ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
+            np.random.RandomState(len(rows)), rows, nh, nkv, hd, mb=mb))
+
     for nh, nkv, hd in size["geometries"]:
         tag = f"{nh}/{nkv}/{hd}"
         rng = np.random.RandomState(nh * 131 + nkv)
@@ -485,44 +554,19 @@ def phase_kernels(rehearse):
         def normal(*shape):
             return rng.randn(*shape).astype(np.float32)
 
-        # ---- a small pool, poisoned wherever no live row may read -------
-        nb, bs, mb = 24, 32, 4
         # rows: (query span, kv length after this step). Span-1 rows are
         # decode rows whose lengths end at a block start, mid-block and at a
         # block end; one span-n chunk resumes at 37 and ends mid-block at
         # 77; one row is dead.
         rows = [(1, 1), (1, 37), (1, 64), (1, 100), (40, 77), (0, 0)]
-        R = len(rows)
-        perm = rng.permutation(nb)
-        tables = np.full((R, mb), nb, np.int32)     # nb = unmapped sentinel
-        pk, pv = normal(nb, bs, nkv, hd), normal(nb, bs, nkv, hd)
-        live = np.zeros((nb, bs), bool)
-        used = 0
-        for r, (_, kvlen) in enumerate(rows):
-            for b in range(-(-kvlen // bs)):
-                tables[r, b] = perm[used]
-                live[perm[used], :min(bs, kvlen - b * bs)] = True
-                used += 1
-        pk[~live] = np.nan      # stale rows and unmapped blocks: a kernel
-        pv[~live] = np.nan      # that reads one of them returns NaN
-        qlen = np.array([q for q, _ in rows], np.int32)
-        kvlen = np.array([k for _, k in rows], np.int32)
-        qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
-        T = int(qlen.sum()) + 12                    # 12 rows in no span
-        q = jnp.asarray(normal(T, nh, hd), bf16)
-        pool_k, pool_v = jnp.asarray(pk, bf16), jnp.asarray(pv, bf16)
-        args = (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(qstart),
-                jnp.asarray(qlen), jnp.asarray(kvlen))
-        got = jax.jit(ragged_paged_attention_pallas)(*args)
-        _agree(f"ragged {tag}", got, reference(ragged_attention_reference,
-                                               *args), TOL_FWD, errors)
-        check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
-              f"ragged {tag}: rows outside every span are not exact zeros")
+        args = _poisoned_ragged_case(rng, rows, nh, nkv, hd, mb=4)
+        _, pool_k, pool_v, tables, _, _, kvlen = args
+        R, kvlen = len(rows), np.asarray(kvlen)
+        ragged_agrees(f"ragged {tag}", args)
 
         # ---- single-token decode through the same tables -----------------
-        lengths = np.maximum(kvlen, 0)
         dargs = (jnp.asarray(normal(R, nh, hd), bf16), pool_k, pool_v,
-                 jnp.asarray(tables), jnp.asarray(lengths))
+                 tables, jnp.asarray(np.maximum(kvlen, 0)))
         _agree(f"paged_decode {tag}",
                jax.jit(paged_decode_attention_pallas)(*dargs),
                reference(paged_decode_attention_reference, *dargs),

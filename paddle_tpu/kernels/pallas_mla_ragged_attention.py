@@ -45,10 +45,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import _cparams, _interpret_mode
-from .pallas_ragged_attention import NEG_INF, _query_block, _work_list
+from .pallas_ragged_attention import (NEG_INF, _one_token_walk, _query_block,
+                                      _work_list)
 
-#: table entries one loop iteration fetches and computes on together
+#: table entries one loop iteration fetches and computes on together, and
+#: the query block's wide rows
 PAGES = 16
+BLOCK_Q = 256
 
 
 def latent_row_width(rank, rope):
@@ -151,7 +154,7 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     # of ``gh`` inside the query block: it computes on those rows alone,
     # not on the block's other tokens, which belong to other rows (where
     # ``gh`` rows are whole tiles; else every span takes the general walk)
-    one_token_walk = gh % 16 == 0 and gh < tq
+    one_token_walk = _one_token_walk(gh, tq)
     alone = (qlen == 1) if one_token_walk else False
 
     if one_token_walk:
@@ -192,7 +195,6 @@ def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
     num_blocks, bs = pool.shape[1], pool.shape[2]
     R, nk = tables.shape
     nq = TH // block_q
-    pages = max(1, min(int(pages), nk))
     work = _work_list(qstart, qlen, kvlen, nq=nq,
                       tokens_per_block=block_q // gh, block_size=bs,
                       table_entries=nk)
@@ -234,8 +236,17 @@ def _spans(tables, qstart, qlen, kvlen):
             jnp.asarray(kvlen, jnp.int32).reshape(-1))
 
 
+def grid_params(table_entries, heads, packed_tokens, block_q=BLOCK_Q,
+                pages=PAGES):
+    """The tiling of one call, ``{"block_q", "pages"}`` (as
+    ``pallas_ragged_attention.grid_params``: the one derivation the call and
+    the engine's ``ragged_grid_counts`` share)."""
+    return {"block_q": _query_block(block_q, heads, packed_tokens),
+            "pages": max(1, min(int(pages), int(table_entries)))}
+
+
 def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
-                                kvlen, *, scale, layer=0, block_q=256,
+                                kvlen, *, scale, layer=0, block_q=BLOCK_Q,
                                 pages=PAGES):
     """Absorbed-form attention of packed query spans over the latent pool.
 
@@ -255,13 +266,14 @@ def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
         [q_lat, q_pe,
          jnp.zeros((T, H, W - rank - q_pe.shape[-1]), q_lat.dtype)],
         axis=-1).reshape(T * H, W)
-    bq = _query_block(block_q, H, T)
+    tiling = grid_params(tables.shape[1], H, T, block_q, pages)
+    bq = tiling["block_q"]
     th_pad = -(-(T * H) // bq) * bq
     if th_pad != T * H:
         q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))
     out = _mla_call(q_wide, pool, jnp.asarray(layer, jnp.int32).reshape(1),
                     tables, qstart, qlen, kvlen, float(scale), H, bq, rank,
-                    pages, _interpret_mode())
+                    tiling["pages"], _interpret_mode())
     return out[:T * H].reshape(T, H, rank)
 
 

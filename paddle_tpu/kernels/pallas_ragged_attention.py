@@ -47,25 +47,49 @@ fetch and the Mosaic-conservative 2D tiles are ``pallas_paged_decode.py``'s):
   own ``kv_len`` AND the causal diagonal of the last span token inside
   the query block, so the early query blocks of a chunk never touch the
   blocks their mask would remove. The stored pool, every layer of it,
-  stays in HBM where it lies (``pl.ANY``); each iteration resolves the
-  scalar-prefetched table in SMEM and fetches one block, at ``(layer,
-  table entry)``, by double-buffered ``make_async_copy`` (the next block
-  streams in while this one computes), so HBM traffic and MXU work both
-  scale with the live logical cache and no layer of the pool is cut out or
-  re-laid-out for the call. Sentinel entries (``>= num_blocks``) clamp into
-  the layer's own blocks — a harmless read, masked off. An int8 / fp8
-  pool's scale planes ride the same physical index as their data block.
+  stays in HBM where it lies (``pl.ANY``).
+- **Several pool pages an online-softmax update**: a loop iteration takes a
+  *group* of ``pages`` consecutive table entries (``pages_per_update``: 256
+  keys' worth, fewer where a row is wide; 8 for Mistral, 4 for OLMoE). It
+  resolves them from the scalar-prefetched table in SMEM and fetches each
+  block, at ``(layer, table entry)``, by ``make_async_copy`` into one
+  two-slot ``[2, pages * bs, KD]`` buffer a side, all copies in flight
+  together and the next group streaming in while this one computes; then
+  one ``dot_general`` for the scores of ``pages * bs`` keys, one mask, one
+  ``m / l / acc`` update, one ``P V``. The float32 accumulator ``[block_q,
+  KD]`` is rescaled and written once a group, not once a 32-row page (that
+  was 1.3 us a page against 0.17 us of MXU work, PERF.md, PR 25 and 32).
+  Entries past the pair's last block clamp to the table's last entry, and
+  sentinel entries (``>= num_blocks``) into the layer's own blocks — a
+  harmless read, masked off by ``kvlen`` and the causal rule; V rows past
+  ``kvlen`` are zeroed in the one group that can hold any (a stale row may
+  be NaN). So HBM traffic and MXU work scale with the live logical cache
+  rounded up to a group a pair, and no layer of the pool is cut out or
+  re-laid-out for the call. An int8 pool's per-row scale planes ride the
+  same physical index as their data block and lie concatenated over the
+  group; an fp8 pool's per-block scale becomes a factor a column.
+- **A span of one token computes on its own rows**: a decode row is ``gh``
+  wide rows at a multiple of ``gh`` inside the query block. Where those are
+  whole tiles (``gh % 16 == 0``, fewer than the block: Mistral's 32,
+  OLMoE's 16) the pair loads those rows of the query block alone, keeps its
+  softmax state in the first ``gh`` rows of the scratch and writes those
+  ``gh`` output rows: 1 / 16 of a 512-row block for Mistral, 1 / 16 of a
+  256-row block for OLMoE. Every other span takes the general walk on the
+  whole block (``pallas_mla_ragged_attention`` has the same two walks).
 - **One output block, several rows**: visits to an output block are
   consecutive; the first zeroes it, each row's visit writes back only its
-  own span by a masked read-modify-write. MXU work on the masked remainder
-  of an intersecting query block is the same idle-MXU trade the wide-query
-  trick already makes.
+  own span (the one-token walk its ``gh`` rows, the general walk by a
+  masked read-modify-write). MXU work on the masked remainder of an
+  intersecting query block is the same idle-MXU trade the wide-query trick
+  already makes. The last query block may reach past the packed buffer: the
+  rows it holds there belong to no span, and the buffer is never padded.
 - **2D-tile conservatism**: all blocks are 2D/leading-1 tiles whose
   last-two dims equal the full array dims; compute is plain 2D
-  ``dot_general``; one pool block per online-softmax update, blocks
-  ascending, the per-row state in VMEM scratch exactly like the decode
-  kernels, so span-1 rows reproduce ``paged_decode_attention_pallas``'s
-  accumulation order bit for bit.
+  ``dot_general``; groups ascending, the per-row state in VMEM scratch
+  exactly like the decode kernels. At ``pages=1`` (an argument of the Python
+  entry, for tests) a span-1 row reproduces ``paged_decode_attention_pallas``'s
+  accumulation order bit for bit; at the derived ``pages`` it is the same
+  mathematics in another order, equal within float32 rounding.
 
 Inference-only (no VJP): the serving step never backpropagates.
 """
@@ -80,14 +104,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import _cparams, _interpret_mode
-from .pallas_paged_decode import _block_scale_vec, _head_scale_mat
+from .pallas_paged_decode import _head_scale_mat
 
 NEG_INF = -1e30
 
 
 def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                   tbl_ref, layer_ref, *refs, scale, block_k, tq, gh,
-                   num_blocks, quantized=False, hkv=0):
+                   tbl_ref, layer_ref, *refs, scale, block_k, pages, tq, gh,
+                   num_blocks, table_entries, quantized=False, hkv=0):
     # positional ref layout follows the pallas_call spec lists: inputs
     # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
     # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc)
@@ -104,7 +128,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     w = pl.program_id(0)            # one work-list entry: (query block, row)
     qi = wq_ref[w]
     r = wr_ref[w]
-    nkb = wn_ref[w]                 # KV blocks this pair walks (0 = dead)
+    nkb = wn_ref[w]                 # pool blocks this pair walks (0 = dead)
     layer = layer_ref[0]            # which layer of the stored pool
     qstart = qs_ref[r]
     qlen = ql_ref[r]
@@ -112,6 +136,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     row0 = qi * tq                  # first wide row of this query block
     span_lo = qstart * gh           # span bounds in wide-row coordinates
     span_hi = (qstart + qlen) * gh
+    group = pages * block_k         # keys of one online-softmax update
 
     @pl.when(wf_ref[w] == 1)
     def _zero_out():
@@ -119,118 +144,198 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         # span must come back as exact zeros, not stale VMEM
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    def _copies(ki, slot):
-        # table-indirect fetch of logical block ki into buffer `slot`:
-        # the table is resolved from SMEM at DMA-issue time; sentinel
-        # entries clamp into THIS layer's blocks before the layer is
-        # applied (a harmless read, masked by kvlen; never a block of the
-        # next layer). The scale planes, this layer's already, ride the
-        # SAME block index as their data.
-        phys = jnp.clip(tbl_ref[r, ki], 0, num_blocks - 1)
+    def _copies(gi, slot):
+        # table-indirect fetch of group gi, `pages` consecutive table
+        # entries, into buffer `slot`: the table is resolved from SMEM at
+        # DMA-issue time; entries past the table clamp to its last, and
+        # sentinel entries into THIS layer's blocks before the layer is
+        # applied (a harmless read, masked by kvlen and the causal rule;
+        # never a block of the next layer). The scale planes, this layer's
+        # already, ride the SAME block index as their data.
+        out = []
+        for j in range(pages):
+            entry = jnp.minimum(gi * pages + j, table_entries - 1)
+            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+            rows = pl.ds(j * block_k, block_k)
+            for i, (hbm, buf) in enumerate(streams):
+                if hbm.ndim == 4:       # the stored pool [L, nb, bs, KD]
+                    src, dst = hbm.at[layer, phys], buf.at[slot, rows]
+                elif hbm.ndim == 3:     # int8 planes [nb, bs, lanes]
+                    src, dst = hbm.at[phys], buf.at[slot, rows]
+                else:                   # fp8 planes [nb, lanes]: one row a
+                    src = hbm.at[pl.ds(phys, 1)]        # block, 2D windows
+                    dst = buf.at[slot, pl.ds(j, 1)]
+                out.append(pltpu.make_async_copy(src, dst,
+                                                 sems.at[i, slot, j]))
+        return out
 
-        def window(hbm):
-            if hbm.ndim == 4:           # the stored pool [L, nb, bs, KD]
-                return hbm.at[layer, phys]
-            # per-block fp8 planes are [num_blocks, lanes]: a one-row
-            # window keeps the buffer 2D like every other operand
-            return hbm.at[pl.ds(phys, 1)] if hbm.ndim == 2 else hbm.at[phys]
-
-        return [pltpu.make_async_copy(window(hbm), buf.at[slot],
-                                      sems.at[i, slot])
-                for i, (hbm, buf) in enumerate(streams)]
-
-    def _compute(ki, slot):
-        q = q_ref[:]                        # [tq, KD] block-diag wide
-        k = k_buf[slot]                     # [block_k, KD]
-        v = v_buf[slot]
-        if quantized:
-            # quantized pool: the table-indirect DMA above moved the
-            # narrow dtype (the HBM win); the upcast happens HERE,
-            # right after it — values convert in VMEM on the way into
-            # the MXU and the scales apply post-dot via the head
-            # one-hot trick (the query block is a multiple of gh, so
-            # the row->head map is block-position-free). int8 carries
-            # per-(pool-row, head) scales (_head_scale_mat); fp8
-            # carries one scale per (block, head) (_block_scale_vec),
-            # constant across the logits columns.
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def _dequant(plane, nr):
+        # [nr, group] dequant factors of one group's scale planes, applied
+        # post-dot by the head one-hot trick (the rows are whole tokens, so
+        # the row->head map is position-free). int8 carries a scale per
+        # (pool row, head): the pages' planes lie concatenated. fp8 carries
+        # one per (block, head): a factor per page, spread over its columns
+        # by a second one-hot.
+        f = _head_scale_mat(plane[:, :hkv], nr, gh, hkv)
         if quantized == "fp8":
-            s = s * _block_scale_vec(ks_buf[slot][:, :hkv], tq, gh, hkv)
-        elif quantized:
-            s = s * _head_scale_mat(ks_buf[slot][:, :hkv], tq, gh, hkv)
-        wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # causal-within-span: wide row w belongs to span token
-        # (w - span_lo) // gh, whose logical position is
-        # kvlen - qlen + that token index
-        pos = kvlen - qlen + (wrow - span_lo) // gh
-        valid = (wrow >= span_lo) & (wrow < span_hi) & (cols <= pos)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # exp hits exact 0 on masked cols, but pool rows past `kvlen`
-        # may hold another block's garbage — zero them out of PV
-        p = jnp.where(valid, p, 0.0)
-        v = jnp.where(
-            ki * block_k
-            + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < kvlen,
-            v, jnp.zeros_like(v))
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        if quantized == "fp8":
-            # per-block V scale: constant across pool rows, so it
-            # collapses to a per-wide-row factor folded into P
-            p = p * _block_scale_vec(vs_buf[slot][:, :hkv], tq, gh, hkv)
-        elif quantized:
-            # V dequant, same separability: fold the scales into P
-            # (P_wj * sv[j, head(w)]) and dot with the raw values
-            p = p * _head_scale_mat(vs_buf[slot][:, :hkv], tq, gh, hkv)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            page = jax.lax.broadcasted_iota(jnp.int32, (pages, group), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (pages, group), 1)
+            spread = jnp.where(col // block_k == page, 1.0, 0.0)
+            f = jax.lax.dot_general(f, spread.astype(jnp.float32),
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        return f
 
-    @pl.when(nkb > 0)
-    def _walk():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def _walk(nr, load_q, valid_of, write):
+        # one pair's walk on ``nr`` wide rows (static), the softmax state in
+        # the first ``nr`` rows of the scratch
+        m_ref, l_ref, acc_ref = (ref.at[pl.ds(0, nr)]
+                                 for ref in (m_scr, l_scr, acc_scr))
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        n_groups = (nkb + pages - 1) // pages
         for c in _copies(0, 0):
             c.start()
 
-        def _block(ki, carry):
-            # double buffer: block ki + 1 streams in while ki computes
-            slot = ki % 2
+        def _group(gi, carry):
+            # double buffer: group gi + 1 streams in while gi computes
+            slot = gi % 2
 
-            @pl.when(ki + 1 < nkb)
+            @pl.when(gi + 1 < n_groups)
             def _prefetch():
-                for c in _copies(ki + 1, 1 - slot):
+                for c in _copies(gi + 1, 1 - slot):
                     c.start()
 
-            for c in _copies(ki, slot):
+            for c in _copies(gi, slot):
                 c.wait()
-            _compute(ki, slot)
+            q = load_q()                        # [nr, KD] block-diag wide
+            k = k_buf[slot]                     # [group, KD]
+            v = v_buf[slot]
+            if quantized:
+                # quantized pool: the table-indirect DMA above moved the
+                # narrow dtype (the HBM win); the upcast happens HERE,
+                # right after it: values convert in VMEM on the way into
+                # the MXU and the scales apply post-dot (_dequant)
+                k = k.astype(jnp.float32)
+                v = v.astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            if quantized:
+                s = s * _dequant(ks_buf[slot], nr)
+            valid = valid_of(gi * group + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1), s.shape)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # exp hits exact 0 on masked cols only while the row has a
+            # valid one; a row of the block outside the span has none
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            # pool rows past `kvlen` may hold another block's garbage (or
+            # a clamped entry's), and 0 * NaN is NaN: zero them out of PV,
+            # in the one group that can hold any
+            v = jax.lax.cond(
+                (gi + 1) * group > kvlen,
+                lambda v: jnp.where(
+                    gi * group + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v)),
+                lambda v: v, v)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[:] = jnp.broadcast_to(
+                alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape)
+            if quantized:
+                # V dequant, same separability: fold the scales into P
+                # (P_wj * sv[j, head(w)]) and dot with the raw values
+                p = p * _dequant(vs_buf[slot], nr)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
             return carry
 
-        # exactly this pair's blocks, ascending: the row's own length and
-        # the causal diagonal both already bound nkb (_work_list)
-        jax.lax.fori_loop(0, nkb, _block, 0)
-        # write back ONLY this row's span: the output block is shared by
-        # every sequence whose span intersects it, so the write must be
-        # a masked read-modify-write (rows not in span keep their value)
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        wrow = row0 + jax.lax.broadcasted_iota(
-            jnp.int32, acc_scr.shape, 0)
-        in_span = (wrow >= span_lo) & (wrow < span_hi)
-        o_ref[:] = jnp.where(in_span,
-                             (acc_scr[:] / l).astype(o_ref.dtype),
-                             o_ref[:])
+        # exactly this pair's blocks, ascending, `pages` an update: the
+        # row's own length and the causal diagonal both already bound nkb
+        # (_work_list); what the last group holds past them is masked
+        jax.lax.fori_loop(0, n_groups, _group, 0)
+        write(acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30))
+
+    # a span of ONE token (a decode row) is ``gh`` wide rows at a multiple
+    # of ``gh`` inside the query block: it computes on those rows alone,
+    # not on the block's other tokens, which belong to other rows (where
+    # ``gh`` rows are whole tiles; else every span takes the general walk)
+    short_walk = _one_token_walk(gh, tq)
+    alone = (qlen == 1) if short_walk else False
+
+    if short_walk:
+        @pl.when((nkb > 0) & alone)
+        def _one_token():
+            off = pl.multiple_of(span_lo - row0, gh)
+
+            def write(out):
+                o_ref[pl.ds(off, gh), :] = out.astype(o_ref.dtype)
+
+            _walk(gh, lambda: q_ref[pl.ds(off, gh), :],
+                  lambda cols, shape: cols < kvlen, write)
+
+    @pl.when((nkb > 0) & jnp.logical_not(alone))
+    def _span():
+        def valid_of(cols, shape):
+            # causal-within-span: wide row w belongs to span token
+            # (w - span_lo) // gh, whose logical position is
+            # kvlen - qlen + that token index
+            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            pos = kvlen - qlen + (wrow - span_lo) // gh
+            return (wrow >= span_lo) & (wrow < span_hi) & (cols <= pos)
+
+        def write(out):
+            # write back ONLY this row's span: the output block is shared
+            # by every sequence whose span intersects it, so the write is a
+            # masked read-modify-write (rows not in span keep their value)
+            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            o_ref[:] = jnp.where((wrow >= span_lo) & (wrow < span_hi),
+                                 out.astype(o_ref.dtype), o_ref[:])
+
+        _walk(tq, lambda: q_ref[:], valid_of, write)
+
+
+def _one_token_walk(gh, tq):
+    """Whether a span of one token computes on its own ``gh`` wide rows
+    alone: where those are whole tiles and fewer than the query block."""
+    return gh % 16 == 0 and gh < tq
+
+
+#: what an online-softmax update takes: at most this many keys, fewer where
+#: the K and V buffers of that many (two slots each) would pass these bytes.
+#: Settled on the chip (PERF.md, PR 32): past 256 keys the group's masked
+#: tail (half a group a pair, fetched and multiplied for nothing) costs a
+#: short walk more than the fewer rescales of the accumulator save a long one
+_GROUP_KEYS = 256
+_GROUP_BYTES = 2 << 20
+
+
+def pages_per_update(pool_dtype, block_size, kd, table_entries):
+    """Table entries one online-softmax update fetches and computes on
+    together, from what a call can observe: ``_GROUP_KEYS`` keys' worth of
+    pool blocks, fewer where a row is wide (8 for Mistral's ``KD`` 1024 in
+    bf16 at blocks of 32, 4 for OLMoE's 2048), never more than the table
+    holds. A one-byte pool counts at four bytes: it is upcast to float32 in
+    VMEM on its way into the MXU."""
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    row = 4 * int(kd) * (4 if itemsize == 1 else itemsize)
+    keys = min(_GROUP_KEYS, _GROUP_BYTES // row)
+    return max(1, min(keys // int(block_size), int(table_entries)))
+
+
+def query_block_rows(kd):
+    """Wide rows of a query block (before ``_query_block`` fits it to the
+    heads and the packed buffer): 512 up to Mistral's ``KD`` 1024, where a
+    chunk re-reads its prefix once every 16 tokens and not every 8 and the
+    kernel of a 512-token chunk 3 k into its prompt is a sixth shorter than
+    at 256 (PERF.md, PR 32); fewer for wider rows (256 at OLMoE's 2048, 128
+    at 4096), so that the two query and two output blocks and the float32
+    accumulator keep the 6 MiB of scoped VMEM they have there."""
+    return min(512, (512 << 10) // int(kd))
 
 
 def _least(a, b):
@@ -293,8 +398,8 @@ def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
 
 
 def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
-                 scale, gh, block_q, interpret, scales=None):
-    """q_wide: [TH_pad, KD] block-diagonal wide rows (gh per token);
+                 scale, gh, block_q, pages, interpret, scales=None):
+    """q_wide: [TH, KD] block-diagonal wide rows (gh per token);
     pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole;
     layer: [1] int32, the layer whose blocks this call reads;
     tables: [R, max_blocks] int32;
@@ -302,15 +407,16 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     quantized pool (upcast in-kernel, right after the table-indirect
     DMA): [L, num_blocks, bs, Hkv] per-row planes select the int8 path,
     [L, num_blocks, Hkv] per-block planes select fp8 — the plane rank IS
-    the mode switch, same convention as ``pallas_paged_decode``.
+    the mode switch, same convention as ``pallas_paged_decode``;
+    block_q, pages: the call's tiling (``grid_params``).
 
     The grid is the work list (``_work_list``): one step per (query block,
     row) pair, and inside it a loop over exactly the pair's KV blocks,
-    fetched block by block at ``(layer, table entry)``."""
+    fetched at ``(layer, table entry)``, ``pages`` of them an iteration."""
     TH, KD = q_wide.shape
     num_blocks, bs = pool_k.shape[1], pool_k.shape[2]
     R, nk = tables.shape
-    nq = TH // block_q
+    nq = -(-TH // block_q)          # the last block may be partial
     work = _work_list(qstart, qlen, kvlen, nq=nq,
                       tokens_per_block=block_q // gh, block_size=bs,
                       table_entries=nk)
@@ -320,7 +426,8 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
         quantized = "fp8" if scales[0].ndim == 3 else "int8"
     hkv = scales[0].shape[-1] if quantized else 0
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
-                               tq=block_q, gh=gh, num_blocks=num_blocks,
+                               pages=pages, tq=block_q, gh=gh,
+                               num_blocks=num_blocks, table_entries=nk,
                                quantized=quantized, hkv=hkv)
 
     def _q_index(w, wq, *_):
@@ -330,10 +437,10 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     in_specs = [pl.BlockSpec((block_q, KD), _q_index), in_pool, in_pool]
     args = [*work, qstart, qlen, kvlen, tables, layer, q_wide, pool_k,
             pool_v]
-    bufs = [pltpu.VMEM((2, bs, KD), pool_k.dtype),
-            pltpu.VMEM((2, bs, KD), pool_v.dtype)]
+    bufs = [pltpu.VMEM((2, pages * bs, KD), pool_k.dtype),
+            pltpu.VMEM((2, pages * bs, KD), pool_v.dtype)]
     if quantized:
-        # per-row int8 planes [nb, bs, hkv] move one [bs, hkv] block,
+        # per-row int8 planes [nb, bs, hkv] move one [bs, hkv] block a page,
         # per-BLOCK fp8 planes [nb, hkv] one [1, hkv] row. A DMA window's
         # minor dim must be whole lanes, so this layer's planes are cut out
         # and padded to 128 heads here and the kernel reads the first hkv
@@ -341,10 +448,10 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
         scales = [jnp.pad(jax.lax.dynamic_index_in_dim(p, layer[0], 0, False),
                           [(0, 0)] * (p.ndim - 2) + [(0, lanes - hkv)])
                   for p in scales]
-        plane = (1, lanes) if quantized == "fp8" else (bs, lanes)
+        plane = pages * (1 if quantized == "fp8" else bs)
         in_specs += [in_pool, in_pool]
         args += scales
-        bufs += [pltpu.VMEM((2,) + plane, p.dtype) for p in scales]
+        bufs += [pltpu.VMEM((2, plane, lanes), p.dtype) for p in scales]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -353,7 +460,7 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_q, KD), _q_index),
             scratch_shapes=bufs + [
-                pltpu.SemaphoreType.DMA((len(bufs), 2)),
+                pltpu.SemaphoreType.DMA((len(bufs), 2, pages)),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, KD), jnp.float32),
@@ -376,31 +483,55 @@ def _query_block(block_q, heads, packed_tokens):
                           packed_tokens * heads))
 
 
+def grid_params(pool_dtype, block_size, kd, table_entries, heads,
+                packed_tokens, block_q=None, pages=None):
+    """The tiling of one call, ``{"block_q", "pages"}``: the query block in
+    wide rows as the call cuts it and the table entries one online-softmax
+    update takes, from what the call observes (``block_q`` / ``pages`` given:
+    fitted like the derived ones). The ONE derivation:
+    ``ragged_paged_attention_pallas`` tiles with it and the engine passes it
+    to ``ragged_grid_counts``, so the host's counts are the kernel's."""
+    if block_q is None:
+        block_q = query_block_rows(kd)
+    if pages is None:
+        pages = pages_per_update(pool_dtype, block_size, kd, table_entries)
+    return {"block_q": _query_block(block_q, heads, packed_tokens),
+            "pages": max(1, min(int(pages), int(table_entries)))}
+
+
 def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
-                       table_entries, packed_tokens, block_q=256):
+                       table_entries, packed_tokens, block_q=256, pages=1):
     """What one call of the kernel is asked to do, counted on the host from
     the step's span metadata (plain integers; no jax): ``grid_steps``, the
     steps the kernel visits — the ``nq + R`` work-list entries of its grid
     plus one per KV block its in-kernel loops walk; ``live_steps``, those
-    that compute (the loop iterations: a work-list entry itself only zeroes,
-    resets or writes back); ``kv_tokens``, the cache rows the live spans
-    attend over; ``attn_pairs``, their causal (query, key) pairs. A row with
-    ``qlen == 0`` is dead."""
+    that compute (the pool blocks fetched: a work-list entry itself only
+    zeroes, resets or writes back); ``update_steps``, the online-softmax
+    updates that takes at ``pages`` blocks an update (``pages_per_update``;
+    a pair's last group may hold fewer); ``one_token_rows``, the rows that
+    compute on their own ``heads`` wide rows and not on the query block
+    (spans of one token, where the kernel has that walk); ``kv_tokens``, the
+    cache rows the live spans attend over; ``attn_pairs``, their causal
+    (query, key) pairs. A row with ``qlen == 0`` is dead."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
     tpb = bq // heads
-    live = kv_tokens = pairs = 0
+    live = updates = alone = kv_tokens = pairs = 0
     for qs, ql, kl in zip(qstart, qlen, kvlen):
         qs, ql, kl = int(qs), int(ql), int(kl)
         if ql <= 0:
             continue
         kv_tokens += kl
         pairs += ql * (kl - ql) + ql * (ql + 1) // 2
+        alone += ql == 1 and _one_token_walk(heads, bq)
         for qi in range(qs // tpb, min(nq, -(-(qs + ql) // tpb))):
-            live += _pair_kv_blocks(
+            n = _pair_kv_blocks(
                 qs, ql, kl, qi, tokens_per_block=tpb,
                 block_size=block_size, table_entries=int(table_entries))
+            live += n
+            updates += -(-n // int(pages))
     return {"grid_steps": nq + len(qstart) + live, "live_steps": live,
+            "update_steps": updates, "one_token_rows": alone,
             "kv_tokens": kv_tokens, "attn_pairs": pairs}
 
 
@@ -408,21 +539,21 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
 # eager dispatch linearizes through every op and scalar-prefetch
 # pallas_calls don't linearize in interpret mode. ``scales`` is ``()`` or
 # the ``(k_scale, v_scale)`` planes of a quantized pool.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
 def _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart, qlen,
-            kvlen, scale, gh, block_q):
+            kvlen, scale, gh, block_q, pages):
     return _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen,
-                        kvlen, scale, gh, block_q, _interpret_mode(),
+                        kvlen, scale, gh, block_q, pages, _interpret_mode(),
                         scales=scales or None)
 
 
 def _ragged_fwd_rule(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                     qlen, kvlen, scale, gh, block_q):
+                     qlen, kvlen, scale, gh, block_q, pages):
     return _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                   qlen, kvlen, scale, gh, block_q), None
+                   qlen, kvlen, scale, gh, block_q, pages), None
 
 
-def _ragged_bwd_rule(scale, gh, block_q, res, g):
+def _ragged_bwd_rule(scale, gh, block_q, pages, res, g):
     raise NotImplementedError(
         "ragged_paged_attention_pallas is inference-only (the serving "
         "step never backpropagates)")
@@ -450,8 +581,8 @@ def _stored_pool(pool_k, pool_v, k_scale, v_scale, layer):
 
 
 def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
-                                  kvlen, block_q=256, k_scale=None,
-                                  v_scale=None, layer=None):
+                                  kvlen, block_q=None, k_scale=None,
+                                  v_scale=None, layer=None, pages=None):
     """Mixed prefill+decode attention over packed query spans through
     per-sequence block tables.
 
@@ -484,9 +615,12 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     ``qlen``, and for each pair over the KV blocks up to the row's
     ``kvlen`` and the causal diagonal: blocks past either are never
     fetched, dead rows and non-intersecting pairs are never visited;
-    sentinel table entries clamp harmlessly. A span of length 1
-    reproduces ``paged_decode_attention_pallas`` for that row exactly
-    (same block walk, same online-softmax accumulation order).
+    sentinel table entries clamp harmlessly. ``block_q`` (the query block's
+    wide rows; None: ``query_block_rows``) and ``pages`` (table entries an
+    online-softmax update takes; None: ``pages_per_update``) are for tests:
+    the step programs pass neither. At ``pages=1`` a span of length 1
+    reproduces ``paged_decode_attention_pallas`` for that row exactly (same
+    block walk, same online-softmax accumulation order).
     """
     T, H, D = q.shape
     pool_k, pool_v, scales, layer = _stored_pool(pool_k, pool_v, k_scale,
@@ -505,15 +639,15 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     eye = jnp.eye(Hkv, dtype=q.dtype)
     q_wide = jnp.einsum("bkgd,kj->bkgjd", q.reshape(T, Hkv, G, D), eye)
     q_wide = q_wide.reshape(T * H, KD)
-    # pad the wide-row dim to a whole number of query blocks; the query
-    # block is kept a multiple of H so //gh never crosses a pad boundary
-    bq = _query_block(block_q, H, T)
-    th_pad = -(-(T * H) // bq) * bq
-    if th_pad != T * H:
-        q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))
+    # the query block is a multiple of H, so //gh never crosses a token; the
+    # last one may reach past the packed buffer (no pad, no copy: the rows
+    # it holds past the end belong to no span and are neither computed on
+    # nor written back)
+    tiling = grid_params(pool_k.dtype, pool_k.shape[2], KD, tables.shape[1],
+                         H, T, block_q, pages)
     out_wide = _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                       qlen, kvlen, scale, H, bq)
-    out_wide = out_wide[:T * H]
+                       qlen, kvlen, scale, H, tiling["block_q"],
+                       tiling["pages"])
     # extract each head's own kv-group block from the wide accumulator
     out = jnp.einsum("bkgjd,kj->bkgd",
                      out_wide.reshape(T, Hkv, G, Hkv, D), eye)

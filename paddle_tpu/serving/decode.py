@@ -51,10 +51,11 @@ from ..kernels.moe_ffn import moe_ffn
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
 from ..kernels.pallas_mla_ragged_attention import (
-    latent_row_width, mla_ragged_attention_pallas,
-    mla_ragged_attention_reference)
-from ..kernels.pallas_ragged_attention import (ragged_attention_reference,
-                                               ragged_paged_attention_pallas)
+    grid_params as _mla_grid_params, latent_row_width,
+    mla_ragged_attention_pallas, mla_ragged_attention_reference)
+from ..kernels.pallas_ragged_attention import (
+    grid_params as _ragged_grid_params, ragged_attention_reference,
+    ragged_paged_attention_pallas)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -85,6 +86,19 @@ _STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router", "ws_gate", "ws_up",
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch)
 TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a",)
+
+
+def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1):
+    """``{"block_q", "pages"}`` of the attention kernel that
+    ``_packed_span_forward`` runs on this tree over the stored ``pool``
+    ``[L, num_blocks, bs, KD]`` (a chip's share is ``KD // tp``): the
+    kernel's own ``grid_params`` of what its call will see, so the engine's
+    ``ragged_grid_counts`` counts the grid the step really runs."""
+    if "wkv_a" in params:
+        return _mla_grid_params(table_entries, heads, packed_tokens)
+    return _ragged_grid_params(
+        pool.dtype, pool.shape[2], pool.shape[3] // tp, table_entries, heads,
+        packed_tokens)
 
 
 #: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not

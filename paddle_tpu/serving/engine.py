@@ -49,8 +49,8 @@ import numpy as np
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
-from .decode import build_paged_suffix_prefill_fn, build_prefill_fn, \
-    build_ragged_step_fn, TAUGHT_KEYS, latent_row_width
+from .decode import attention_grid, build_paged_suffix_prefill_fn, \
+    build_prefill_fn, build_ragged_step_fn, TAUGHT_KEYS, latent_row_width
 from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, select_victims
 from .request import GenerationRequest, GenerationResult, Sequence
@@ -739,12 +739,16 @@ class ContinuousBatchingEngine:
         """The ``dispatch`` span's args: what this step asks of the ragged
         kernel in one layer call (every layer runs the same grid;
         ``kernels.pallas_ragged_attention.ragged_grid_counts``) and the
-        step's tokens by kind."""
+        step's tokens by kind, counted at the tiling the step's kernel
+        derives for itself (``decode.attention_grid``)."""
+        heads = self.config.num_attention_heads // self._tp
         work = ragged_grid_counts(
-            qstart, qlen, kvlen, packed_tokens=packed,
-            heads=self.config.num_attention_heads // self._tp,
+            qstart, qlen, kvlen, packed_tokens=packed, heads=heads,
             block_size=self.cache.block_size,
-            table_entries=self.cache.max_blocks)
+            table_entries=self.cache.max_blocks,
+            **attention_grid(self._params, self.cache.pool.k,
+                             self.cache.max_blocks, heads, packed,
+                             tp=self._tp))
         work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
                     prefill_tokens=prefill_tokens)
         return work

@@ -19,14 +19,22 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from jax.experimental import pallas as pl
 from paddle_tpu.kernels.pallas_ragged_attention import (_query_block,
+                                                        grid_params,
+                                                        pages_per_update,
+                                                        query_block_rows,
                                                         ragged_grid_counts)
+from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
+                                           deepseek_v2_tiny)
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler import chrometrace
 from paddle_tpu.profiler.tracing import (NULL_SPAN, TID_ENGINE, TID_GATEWAY,
                                          TID_REQ0, SpanTracer)
 from paddle_tpu.serving import (ContinuousBatchingEngine, GenerationRequest,
                                 VirtualClock)
+from paddle_tpu.serving import engine as engine_mod
+from paddle_tpu.serving.decode import attention_grid
 from paddle_tpu.serving.server import serve
 
 from test_metrics_prom import parse_prometheus
@@ -236,13 +244,23 @@ class TestEngineSpans:
         assert len(disp) == eng.stats["unified_steps"] > 0
         heads = model.config.num_attention_heads
         T = eng._token_budget
-        nq = -(-(T * heads) // _query_block(256, heads, T))
+        grid = attention_grid(eng._params, eng.cache.pool.k,
+                              eng.cache.max_blocks, heads, T)
+        nq = -(-(T * heads) // grid["block_q"])
         for a in disp:
             # the work list's entries, plus the KV blocks the loops walk
             assert a["grid_steps"] == nq + NUM_SLOTS + a["live_steps"]
             assert 0 < a["live_steps"] <= (nq + NUM_SLOTS) \
                 * eng.cache.max_blocks
             assert a["attn_pairs"] >= a["kv_tokens"] > 0
+            # an update a group of pages, of which the engine knows as many
+            # as the kernel derives; llama_tiny's 4 heads are no whole tile
+            assert -(-a["live_steps"] // grid["pages"]) \
+                <= a["update_steps"] <= a["live_steps"]
+            assert a["one_token_rows"] == 0
+        # 256 keys an update in blocks of 8: the whole table of 32 entries;
+        # the packed buffer's 34 x 4 wide rows are under one block of 512
+        assert grid == dict(block_q=T * heads, pages=eng.cache.max_blocks)
         # every token a step span counts is a prefill or a decode token
         assert sum(a["prefill_tokens"] + a["decode_tokens"] for a in disp) \
             == sum(s["tokens"] for s in steps)
@@ -250,6 +268,55 @@ class TestEngineSpans:
         assert eng.stats["step_prefill_tokens"] == 80
         assert eng.stats["step_decode_tokens"] \
             == sum(a["decode_tokens"] for a in disp)
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
+        kind, monkeypatch):
+    """The ``dispatch`` span's counts are an enumeration at the query block
+    and the pages an update that the step's ``pallas_call`` was really built
+    with (read off the call itself, not derived a second time): the engine
+    and the kernel share one ``grid_params``."""
+    paddle.seed(31)
+    if kind == "dense":
+        model = LlamaForCausalLM(llama_tiny(decode_attention="pallas"))
+        name = "ragged_paged_attention"
+    else:
+        model = DeepseekV2ForCausalLM(
+            deepseek_v2_tiny(decode_attention="pallas"))
+        name = "mla_ragged_attention"
+    built, asked = [], []
+    real_call, real_counts = pl.pallas_call, engine_mod.ragged_grid_counts
+
+    def pallas_call(kernel, *a, **kw):
+        if kw.get("name") == name:
+            built.append((kernel.keywords["tq"], kernel.keywords["pages"]))
+        return real_call(kernel, *a, **kw)
+
+    def counts(qstart, qlen, kvlen, **kw):
+        asked.append(([int(x) for x in qstart], [int(x) for x in qlen],
+                      [int(x) for x in kvlen], kw))
+        return real_counts(qstart, qlen, kvlen, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", pallas_call)
+    monkeypatch.setattr(engine_mod, "ragged_grid_counts", counts)
+    tr = SpanTracer(clock=VirtualClock()).enable()
+    eng = _engine(model, {}, tracer=tr, prefill_chunk=32,
+                  prefix_block_size=8)
+    eng.generate(_reqs())
+    disp = [e["args"] for e in tr.events() if e["name"] == "dispatch"]
+    assert len(disp) == len(asked) == eng.stats["unified_steps"] > 0
+    # every layer call of every step program was built with one tiling
+    assert len(set(built)) == 1
+    block_q, pages = built[0]
+    assert pages > 1
+    keys = ("grid_steps", "live_steps", "update_steps", "one_token_rows",
+            "kv_tokens", "attn_pairs")
+    for a, (qstart, qlen, kvlen, kw) in zip(disp, asked):
+        kw = dict(kw, block_q=block_q, pages=pages)
+        assert {k: a[k] for k in keys} \
+            == _brute_force(qstart, qlen, kvlen, **kw)
+    assert any(a["update_steps"] < a["live_steps"] for a in disp)
 
 
 class TestGatewaySpansAndCounter:
@@ -378,16 +445,26 @@ def _live_pairs(qstart, qlen, kvlen, heads, block_q, block_size,
     return pairs, nq
 
 
-def _brute_force(qstart, qlen, kvlen, **geometry):
+def _brute_force(qstart, qlen, kvlen, pages=1, **geometry):
     """The steps the kernel visits: its work list (one entry a query block
     or a row more than the pairs can ever be) plus every KV block its loops
-    walk; only the latter compute."""
+    walk; only the latter compute, ``pages`` of them an online-softmax
+    update (block by block: a new update starts at a pair's first block and
+    after every ``pages``). A row takes the one-token walk where its span is
+    one token and a token's ``heads`` wide rows are whole tiles, fewer than
+    the query block."""
     walked, nq = _live_pairs(qstart, qlen, kvlen, **geometry)
     live = sum(walked.values())
+    updates = sum(ki % pages == 0 for n in walked.values()
+                  for ki in range(n))
+    heads = geometry["heads"]
+    bq = _query_block(geometry["block_q"], heads, geometry["packed_tokens"])
     pairs = sum(sum(kl - ql + i + 1 for i in range(ql))
                 for ql, kl in zip(qlen, kvlen) if ql)
     return {"grid_steps": nq + len(qstart) + live,
-            "live_steps": live,
+            "live_steps": live, "update_steps": updates,
+            "one_token_rows": sum(ql == 1 for ql in qlen)
+            if heads % 16 == 0 and heads < bq else 0,
             "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
             "attn_pairs": pairs}
 
@@ -414,6 +491,27 @@ def test_ragged_grid_counts_equals_enumeration(case, heads, block_q):
         == _brute_force(qstart, qlen, kvlen, **kw)
 
 
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+@pytest.mark.parametrize("heads,block_q,pages", [
+    (4, 16, 3), (32, 256, 2), (16, 64, 8), (32, 32, 5)])
+def test_ragged_grid_counts_updates_and_one_token_rows(case, heads, block_q,
+                                                       pages):
+    """The two numbers PR 32 added: an update a group of ``pages`` blocks
+    (one block an update at ``pages=1``, so ``update_steps == live_steps``
+    there), and the rows on the one-token walk (none where ``heads`` is not
+    whole tiles, 4, or is the whole query block, 32 of 32); the older keys
+    do not depend on ``pages``."""
+    qstart, qlen, kvlen = GRID_CASES[case]
+    kw = dict(heads=heads, block_q=block_q, block_size=16,
+              table_entries=8, packed_tokens=40)
+    got = ragged_grid_counts(qstart, qlen, kvlen, pages=pages, **kw)
+    assert got == _brute_force(qstart, qlen, kvlen, pages=pages, **kw)
+    one = ragged_grid_counts(qstart, qlen, kvlen, **kw)
+    assert one["update_steps"] == one["live_steps"]
+    assert {k: v for k, v in got.items() if k != "update_steps"} \
+        == {k: v for k, v in one.items() if k != "update_steps"}
+
+
 # the serving cells' own geometry (benchmark/configs: 32 heads a chip, pool
 # blocks of 32, 8 slots x 128 table entries, 8 + 512 packed tokens) and a
 # step of each cell's kind, with the most grid steps a call may take
@@ -430,12 +528,29 @@ CELL_STEPS = {
 @pytest.mark.parametrize("step", sorted(CELL_STEPS))
 def test_ragged_grid_counts_at_the_cells_geometry(step):
     qstart, qlen, kvlen, most = CELL_STEPS[step]
-    kw = dict(heads=32, block_q=256, block_size=32, table_entries=128,
+    # Mistral's KD 1024: 8 pages an update in query blocks of 512 rows;
+    # OLMoE's 2048 takes half of each, an int8 pool counts as float32
+    pages, block_q = pages_per_update("bfloat16", 32, 8 * 128, 128), \
+        query_block_rows(8 * 128)
+    assert (pages, block_q) == (8, 512)
+    # the call's tiling is those two, fitted to the heads and the table
+    assert grid_params("bfloat16", 32, 8 * 128, 128, 32, 520) \
+        == dict(block_q=512, pages=8)
+    assert grid_params("bfloat16", 32, 8 * 128, 128, 24, 520, pages=999) \
+        == dict(block_q=504, pages=128)
+    assert (pages_per_update("bfloat16", 32, 16 * 128, 64),
+            query_block_rows(16 * 128)) == (4, 256)
+    assert pages_per_update("int8", 32, 8 * 128, 128) == 4
+    assert pages_per_update("float32", 16, 128, 5) == 5
+    kw = dict(heads=32, block_q=block_q, block_size=32, table_entries=128,
               packed_tokens=520)
-    got = ragged_grid_counts(qstart, qlen, kvlen, **kw)
-    assert got == _brute_force(qstart, qlen, kvlen, **kw)
-    assert 65 + 8 < got["grid_steps"] < most
-    assert got["live_steps"] == got["grid_steps"] - (65 + 8)
+    got = ragged_grid_counts(qstart, qlen, kvlen, pages=pages, **kw)
+    assert got == _brute_force(qstart, qlen, kvlen, pages=pages, **kw)
+    assert 33 + 8 < got["grid_steps"] < most
+    assert got["live_steps"] == got["grid_steps"] - (33 + 8)
+    assert got["live_steps"] / 8 <= got["update_steps"] \
+        < got["live_steps"] / 4
+    assert got["one_token_rows"] == sum(n == 1 for n in qlen)
 
 
 # ---------------------------------------------- names on the device's work

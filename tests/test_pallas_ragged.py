@@ -6,9 +6,14 @@ per-sequence block tables in ONE kernel invocation. Interpret-mode
 oracle suite mirroring test_pallas_paged_decode.py, plus the properties
 the unification itself must pin:
 
-- a span-1 row is BITWISE the single-query paged decode kernel's
-  (pallas vs pallas, reference vs reference) — the unified serving step
-  cannot perturb decode numerics;
+- a span-1 row is BITWISE the single-query paged decode kernel's at one
+  pool page an online-softmax update (pallas vs pallas, reference vs
+  reference) and within float32 rounding at the default, several pages an
+  update (another accumulation order, the same mathematics);
+- the walk in groups of pages: spans and causal diagonals that end inside a
+  group, a last group that reaches past ``kvlen`` into a NaN-poisoned pool,
+  quantized planes carried through a group, and the one-token walk of a
+  decode row against the general walk on the same rows;
 - sentinel tables / dead rows / packed padding stay finite and come
   back as exact zeros;
 - the kernel's iteration space: its work list of (query block, row)
@@ -27,7 +32,8 @@ from paddle_tpu.kernels.pallas_paged_decode import (
 from paddle_tpu.kernels.pallas_ragged_attention import (
     _query_block, _work_list, ragged_attention_reference,
     ragged_paged_attention_pallas)
-from paddle_tpu.serving.kv_cache import quantize_kv_rows
+from paddle_tpu.serving.kv_cache import (quantize_kv_rows,
+                                         quantize_kv_rows_fp8)
 
 from test_one_timeline import GRID_CASES, _live_pairs
 
@@ -76,15 +82,17 @@ class TestRaggedKernelParity:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_span1_bitwise_vs_paged_decode_kernel(self):
-        """A span-1 row IS the old single-query kernel's row: same
-        block walk, same online-softmax accumulation — pallas vs pallas
-        and reference vs reference are both bitwise."""
+    @pytest.mark.parametrize("H,Hkv", [(8, 2), (16, 4), (32, 8)])
+    def test_span1_bitwise_vs_paged_decode_kernel(self, H, Hkv):
+        """A span-1 row IS the old single-query kernel's row: at one page
+        an update the same block walk and the same online-softmax
+        accumulation (on the general walk, H 8, and on the one-token walk,
+        H 16 and 32), so pallas vs pallas is bitwise there, and reference
+        vs reference always; at the default (the whole 4-entry table an
+        update) the order differs and float32 rounding is the bound."""
         spans = [(1, 40), (1, 7), (1, 64)]
-        q, pk, pv, tbl, qs, ql, kl = _mk(3, spans, 8, 2, 64, 4, 16,
+        q, pk, pv, tbl, qs, ql, kl = _mk(3, spans, H, Hkv, 64, 4, 16,
                                          seed=3)
-        got_k = np.asarray(ragged_paged_attention_pallas(
-            q, pk, pv, tbl, qs, ql, kl))
         got_r = np.asarray(ragged_attention_reference(
             q, pk, pv, tbl, qs, ql, kl))
         # the packed buffer in span order == one query per sequence
@@ -92,8 +100,12 @@ class TestRaggedKernelParity:
             q, pk, pv, tbl, kl))
         old_r = np.asarray(paged_decode_attention_reference(
             q, pk, pv, tbl, kl))
-        assert (got_k == old_k).all()
         assert (got_r == old_r).all()
+        assert (np.asarray(ragged_paged_attention_pallas(
+            q, pk, pv, tbl, qs, ql, kl, pages=1)) == old_k).all()
+        np.testing.assert_allclose(
+            np.asarray(ragged_paged_attention_pallas(
+                q, pk, pv, tbl, qs, ql, kl)), old_k, rtol=2e-5, atol=2e-5)
 
     def test_sentinel_dead_rows_and_padding_zero_and_finite(self):
         """Sentinel table tails clamp harmlessly; a dead row (qlen 0)
@@ -310,6 +322,114 @@ def test_int8_pool_parity_mixed_spans(block_q):
     got = ragged_paged_attention_pallas(q, k8, v8, tbl, qs, ql, kl,
                                         block_q=block_q, k_scale=ks,
                                         v_scale=vs)
+    want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
+                                      k_scale=ks, v_scale=vs)
+    assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------- the walk in groups of several pages
+@pytest.mark.parametrize("pages", [1, 2, 3, 8])
+@pytest.mark.parametrize("H,Hkv", [(8, 2), (16, 16)])
+def test_mixed_spans_match_reference_at_every_group_size(pages, H, Hkv):
+    """One page an update, two, three (the 8-entry table is no whole number
+    of groups) and the whole table: decode rows, chunks and a dead row
+    against the oracle, on the general walk alone (H 8) and with the
+    one-token walk for the decode rows (H 16)."""
+    spans = [(1, 128), (5, 37), (1, 3), (16, 16), (0, 0), (9, 100)]
+    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=pages + H)
+    got = ragged_paged_attention_pallas(*args, pages=pages, block_q=4 * H)
+    want = ragged_attention_reference(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+GROUP_EDGE_CASES = {
+    # name: (spans, mb): 16-row blocks, 4 pages an update = 64 keys a group
+    # the span's own length ends inside the second group
+    "kvlen_ends_inside_a_group": ([(1, 70), (6, 90)], 8),
+    # a first chunk over two query blocks of 8 tokens: the diagonal of each
+    # ends inside a group (tokens 0-7 in the first, 8-15 the same, 16-23 in
+    # the second), and the blocks past it hold live rows of the same span
+    "diagonal_ends_inside_a_group": ([(40, 100), (1, 5)], 8),
+    # the last group starts inside the table and reaches past its end
+    "last_group_past_the_table": ([(1, 96), (12, 96)], 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_EDGE_CASES))
+def test_group_edges_over_a_poisoned_pool(case):
+    """Where a group holds more than the pair may see: entries past the
+    pair's last block clamp (to the table's last entry, sentinels into the
+    pool) and are masked by ``kvlen`` and the causal rule; stale rows are
+    NaN, so any that reached a product would show. Rows in no span are
+    exact zeros."""
+    spans, mb = GROUP_EDGE_CASES[case]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, 8, 2, 32, mb, 16,
+                                     seed=len(case), T=60)
+    tbl = np.asarray(tbl).copy()
+    for r, (_, kvlen) in enumerate(spans):
+        tbl[r, -(-kvlen // 16):] = pk.shape[0]      # unmapped -> sentinel
+    tbl = jnp.asarray(tbl)
+    pk = _poison_stale_rows(pk, tbl, kl, ql)
+    pv = _poison_stale_rows(pv, tbl, kl, ql)
+    got = np.asarray(ragged_paged_attention_pallas(
+        q, pk, pv, tbl, qs, ql, kl, block_q=64, pages=4))
+    want = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl))
+    used = sum(n for n, _ in spans)
+    assert np.isfinite(got).all()
+    assert not got[used:].any() and not want[used:].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hkv,walks", [
+    (16, 4, "one_token"), (32, 8, "one_token"), (32, 32, "one_token"),
+    (8, 2, "general"), (24, 8, "general")])
+def test_one_token_walk_equals_general_walk(H, Hkv, walks):
+    """A decode row computes on its own ``H`` wide rows where those are
+    whole tiles (``H % 16 == 0``) and fewer than the query block, and on the
+    whole block otherwise. A query block of exactly ``H`` rows leaves the
+    general walk no other token to compute on: the same rows, the same
+    groups, so the same numbers, bit for bit, whichever walk the wide block
+    takes; and both match the oracle."""
+    from paddle_tpu.kernels.pallas_ragged_attention import _one_token_walk
+    assert _one_token_walk(H, 4 * H) == (walks == "one_token")
+    assert not _one_token_walk(H, H)
+    spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33), (1, 128)]
+    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=H)
+    wide = np.asarray(ragged_paged_attention_pallas(
+        *args, block_q=4 * H, pages=3))
+    narrow = np.asarray(ragged_paged_attention_pallas(
+        *args, block_q=H, pages=3))
+    assert (wide == narrow).all()
+    np.testing.assert_allclose(
+        wide, np.asarray(ragged_attention_reference(*args)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hkv", [(8, 4), (16, 4)])
+@pytest.mark.parametrize("pages", [1, 3])
+@pytest.mark.parametrize("mode", ["int8", "fp8"])
+def test_quantized_planes_ride_the_group(mode, pages, H, Hkv):
+    """The scale planes of a quantized pool through a group of more than
+    one page: int8's per-row planes lie concatenated over the group's pages,
+    fp8's per-block scale becomes a factor a column; on the general walk
+    (H 8) and on the one-token walk (H 16)."""
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(MIXED), MIXED, H, Hkv, 16, 4, 16,
+                                     seed=31)
+    if mode == "int8":
+        (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
+    else:
+        # the engine's fp8 planes are the constant 1; a scale a (block,
+        # head) that differs from page to page shows a factor misplaced
+        r = np.random.RandomState(37)
+        k8, v8 = quantize_kv_rows_fp8(pk), quantize_kv_rows_fp8(pv)
+        ks, vs = (jnp.asarray(r.uniform(0.5, 2.0, (pk.shape[0], Hkv)),
+                              jnp.float32) for _ in range(2))
+    got = ragged_paged_attention_pallas(
+        q, k8, v8, tbl, qs, ql, kl, block_q=4 * H, k_scale=ks, v_scale=vs,
+        pages=pages)
     want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
                                       k_scale=ks, v_scale=vs)
     assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
