@@ -10,7 +10,9 @@ vocab 32000, bf16) with the depth cut and random weights made from a seed:
   kernels  every Pallas kernel on the default serving and training paths
            (the routed FFN's grouped matmuls at OLMoE widths and, with
            one chip's share of the experts, at DeepSeek-V2's; the
-           absorbed-form latent attention kernel at DeepSeek-V2's),
+           absorbed-form latent attention kernel at DeepSeek-V2's; the
+           gated delta rule's chunked scan and decode-row update at
+           Olmo-Hybrid-7B's 30 heads of 96 x 192),
            compiled by Mosaic and RUN against its jnp reference;
   serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
            answering cold, chunked, concurrent and streamed requests;
@@ -58,7 +60,12 @@ FULL = dict(
         "mistral chunk 32/8/128": (32, 8, 128, 128, [
             (1, 2307), (512, 3584), (1, 33), (1, 1024), (0, 0), (1, 700)]),
         "olmoe decode 16/16/128": (16, 16, 128, 64, [
-            (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)])},
+            (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)]),
+        # Olmo-Hybrid's full layers: 30 heads, the first count that is no
+        # power of two (4 tokens of 30 wide rows a query block), a pool row
+        # of 3,840; a chunk behind its first chunk beside decode rows
+        "olmo-hybrid chunk 30/30/128": (30, 30, 128, 72, [
+            (1, 700), (488, 1000), (1, 33), (0, 0), (1, 2280), (1, 1)])},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -72,19 +79,24 @@ FULL = dict(
     mla=dict(widths=(128, 512, 128, 64, 128)),
     moe_share=dict(widths=(5120, 160, 20, 1536, 6, 8, 3),
                    rows=[(544, 32), (256, 256)]),
+    # Olmo-Hybrid-7B's linear layers: (heads, key width, value width) of
+    # the gated delta rule, the slots and the packed rows of its cell's step
+    gdn=dict(widths=(30, 96, 192), slots=32, packed=544),
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
     ragged_cells={
         "chunk 4/2/32": (4, 2, 32, 8, [(1, 150), (48, 200), (1, 33), (0, 0)]),
         "decode 16/16/32": (16, 16, 32, 8, [
-            (1, 20 + 9 * i) for i in range(6)])},
+            (1, 20 + 9 * i) for i in range(6)]),
+        "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
     preset="tiny", slots=4, max_seq_len=128, prefill_chunk=32,
     vocab=256, medium_prompt=24, long_prompt=70,
     tp=2,                                   # llama_tiny has two kv heads
     moe=dict(widths=(64, 8, 32, 2), rows=[(36, 4), (16, 16)]),
     mla=dict(widths=(4, 32, 16, 8, 16)),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
+    gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
     train=dict(layers=2, batch=4, seq=64, steps=4))
 
 # Forward outputs: kernel and reference both take bf16 inputs (8 significant
@@ -97,6 +109,10 @@ TOL_FWD = 2e-2
 # Gradients go through two more bf16 roundings (dS, and P again in the
 # backward kernels) and a recomputed softmax: twice the forward bound.
 TOL_BWD = 4e-2
+# The gated delta rule's kernels: float32 in, float32 state, float32 out, the
+# products at precision HIGHEST; what differs from the token-by-token
+# recurrence is the chunked form's algebra and the summation order.
+TOL_GDN = 2e-3
 # Step-0 loss, four chips against one: the same bf16 forward with every
 # hidden/ffn contraction split over mp=2 (other summation order of bf16
 # partial products); the loss is an f32 mean over batch*seq tokens near
@@ -683,10 +699,62 @@ def phase_kernels(rehearse):
     check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
           "mla_ragged: rows outside every span are not exact zeros")
 
+    # ---- the gated delta rule: both kernels against the recurrence ------
+    from paddle_tpu.kernels import gated_delta_rule as gdr
+    nh, dk, dv = size["gdn"]["widths"]
+    R, T = size["gdn"]["slots"], size["gdn"]["packed"]
+    rng = np.random.RandomState(33)
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32))
+
+    q = gdr.l2norm(rand(T, nh, dk), dk ** -0.5)
+    k = gdr.l2norm(rand(T, nh, dk))
+    v = rand(T, nh, dv)
+    g = -1.6 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
+    beta = 2.0 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
+    store = rand(2, R, nh, dk, dv)
+    # a decode step: every slot but two has a row, one starts a sequence
+    live = np.ones(R, bool)
+    live[[1, R - 1]] = False
+    fresh = np.zeros(R, bool)
+    fresh[2] = True
+    got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
+        *a, layer=1, live=live, fresh=fresh))(
+            q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+    want = reference(lambda *a: gdr.gdn_reference(
+        *a, layer=1, seg=np.where(live, np.arange(R), R), first=fresh),
+        q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+    _agree("gdn_recurrent_update o", np.asarray(got[0])[live],
+           np.asarray(want[0])[live], TOL_GDN, errors)
+    _agree("gdn_recurrent_update state", got[1], want[1], TOL_GDN, errors)
+    # a chunk step: decode rows first (not the scan's), then a chunk that
+    # continues its slot's state and a fresh one that starts in the block
+    # where the first ends
+    cut = 5 + (T - 5) * 3 // 5
+    start, length = np.zeros(R, np.int32), np.zeros(R, np.int32)
+    start[3], length[3] = 5, cut - 5
+    start[0], length[0] = cut, T - cut - 3
+    fresh = np.zeros(R, bool)
+    fresh[0] = True
+    seg = np.full(T, R, np.int32)
+    seg[5:cut], seg[cut:T - 3] = 3, 0
+    first = np.zeros(T, bool)
+    first[cut] = True
+    got = jax.jit(lambda *a: gdr.gdn_chunk_scan(
+        *a, layer=0, start=start, length=length, fresh=fresh))(
+            q, k, v, g, beta, store)
+    want = reference(lambda *a: gdr.gdn_reference(
+        *a, layer=0, seg=seg, first=first), q, k, v, g, beta, store)
+    _agree("gdn_chunk_scan o", np.asarray(got[0])[5:T - 3],
+           np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
+    _agree("gdn_chunk_scan state", got[1], want[1], TOL_GDN, errors)
+
     import importlib.metadata as md
     _child_report(
         "kernels", stats, device, max_error=errors,
-        tolerance={"forward": TOL_FWD, "backward": TOL_BWD},
+        tolerance={"forward": TOL_FWD, "backward": TOL_BWD,
+                   "delta_rule": TOL_GDN},
         versions={"jax": jax.__version__, "jaxlib": jaxlib.__version__,
                   "libtpu": md.version("libtpu"),
                   "python": sys.version.split()[0]})

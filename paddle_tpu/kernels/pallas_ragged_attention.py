@@ -476,6 +476,15 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     return out
 
 
+def wide_rows(heads):
+    """Wide rows a token in the call: its heads, or from 8 up the next
+    multiple of 8 where the count is none (30 -> 32), so that ``[T, rows,
+    KD]`` and ``[T * rows, KD]`` are one layout and a query block is whole
+    sublane groups; the added rows are zero queries whose outputs are
+    dropped (``_ragged_padded_heads``)."""
+    return heads if heads % 8 == 0 or heads < 8 else -(-heads // 8) * 8
+
+
 def _query_block(block_q, heads, packed_tokens):
     """The query block in wide rows: a multiple of ``heads`` (so //gh never
     crosses a pad boundary), at most the whole packed buffer."""
@@ -636,6 +645,11 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     tables = jnp.asarray(tables, jnp.int32).reshape(qstart.shape[0], -1)
     # block-diagonal wide query: head h's D values at its kv group's
     # lanes, one wide row per (token, head)
+    rows = wide_rows(H)
+    if rows != H:
+        return _ragged_padded_heads(
+            q, pool_k, pool_v, scales, layer, tables, qstart, qlen, kvlen,
+            scale, rows, block_q, pages)
     eye = jnp.eye(Hkv, dtype=q.dtype)
     q_wide = jnp.einsum("bkgd,kj->bkgjd", q.reshape(T, Hkv, G, D), eye)
     q_wide = q_wide.reshape(T * H, KD)
@@ -652,6 +666,42 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     out = jnp.einsum("bkgjd,kj->bkgd",
                      out_wide.reshape(T, Hkv, G, Hkv, D), eye)
     return out.reshape(T, H, D)
+
+
+def _ragged_padded_heads(q, pool_k, pool_v, scales, layer, tables, qstart,
+                         qlen, kvlen, scale, rows, block_q, pages):
+    """``ragged_paged_attention_pallas`` for a head count that is no whole
+    sublane group (``wide_rows``: 30 -> ``rows`` 32). The block-diagonal
+    wide query is made, and the heads' own blocks taken back out of the wide
+    output, WITHOUT an array whose minor dims are ``(Hkv, D)``: at 30 KV
+    heads those tile to 32 x 128 and every reshape between them and the
+    ``KD`` lanes the kernel reads moves the whole 130 MB (a fifth of the
+    attention's time: PERF.md, PR 33). Instead the query, its heads padded
+    (4 MB), is spread over the lanes by a 0 / 1 selection matmul (``D`` ->
+    ``KD``, exact in any dtype: one non-zero term a sum) and masked to its
+    own kv group's lanes; the output is masked and folded back by the
+    transposed selection. The added rows are zero queries whose outputs are
+    dropped."""
+    T, H, D = q.shape
+    KD = pool_k.shape[-1]
+    G = H // (KD // D)
+    lane = jnp.arange(KD, dtype=jnp.int32)
+    spread = (lane[None, :] % D == jnp.arange(D, dtype=jnp.int32)[:, None]
+              ).astype(q.dtype)                                  # [D, KD]
+    own = (lane[None, :] // D
+           == jnp.arange(rows, dtype=jnp.int32)[:, None] // G)   # [rows, KD]
+    q = jnp.pad(q, ((0, 0), (0, rows - H), (0, 0))).reshape(T * rows, D)
+    q_wide = jnp.where(own, jnp.dot(q, spread).reshape(T, rows, KD), 0)
+    tiling = grid_params(pool_k.dtype, pool_k.shape[2], KD, tables.shape[1],
+                         rows, T, block_q, pages)
+    out_wide = _ragged(q_wide.reshape(T * rows, KD), pool_k, pool_v, scales,
+                       layer, tables, qstart, qlen, kvlen, scale, rows,
+                       tiling["block_q"], tiling["pages"])
+    out_wide = jnp.where(own, out_wide.reshape(T, rows, KD), 0)
+    out = jnp.dot(out_wide.reshape(T * rows, KD), spread.T)
+    # (the barrier keeps XLA from moving the cut of the padded heads above
+    # the fold, where it would copy the wide output to take it)
+    return jax.lax.optimization_barrier(out).reshape(T, rows, D)[:, :H]
 
 
 def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,

@@ -86,7 +86,11 @@ class BlockManager:
 
     **The stored layout** (this is its one statement; ``pool_shape``
     its one definition): ``k`` and ``v`` are ``[L, num_blocks,
-    block_size, Hkv * D]``, a row's heads side by side on the minor,
+    block_size, Hkv * D]``, ``L`` the layers that attend over cached keys
+    and values (every layer of most models; a hybrid model's
+    full-attention layers only: a layer with a recurrent state has no row
+    here, its cache is ``PagedKVCache.state``, by slot), a row's heads
+    side by side on the minor,
     lane-dense axis, which is how the ragged attention kernel reads a
     block: it is handed the whole buffer and fetches block ``(layer,
     table entry)`` from where it lies, so no step program slices,

@@ -27,7 +27,12 @@ with latent attention (a tree with ``wkv_a``) writes ONE row a token, the
 normalised latent and the rotated shared key, into a pool with no head axis
 and no V side, and the unified step attends over it in the absorbed form
 (``kernels.pallas_mla_ragged_attention``); its whole-prompt prefill attends
-in the expanded form and hands the same rows to the pool's writer.
+in the expanded form and hands the same rows to the pool's writer. A hybrid
+model (a tree with ``linear_layers``: periods of Gated DeltaNet layers and
+then one full-attention layer) scans its periods, the pool holding rows for
+the full layers only; a linear layer's cache is a float32 state and its
+convolution's last inputs, in a store by slot that rides the programs like
+the pool (``_hybrid_span_forward``, ``kernels.gated_delta_rule``).
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
@@ -47,6 +52,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..kernels.flash_attention import attention as _attention
+from ..kernels.gated_delta_rule import (gdn_chunk_scan, gdn_recurrent_update,
+                                         gdn_reference, l2norm)
 from ..kernels.moe_ffn import moe_ffn
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
@@ -55,7 +62,7 @@ from ..kernels.pallas_mla_ragged_attention import (
     mla_ragged_attention_pallas, mla_ragged_attention_reference)
 from ..kernels.pallas_ragged_attention import (
     grid_params as _ragged_grid_params, ragged_attention_reference,
-    ragged_paged_attention_pallas)
+    ragged_paged_attention_pallas, wide_rows)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -83,22 +90,46 @@ _MLA_STACK_KEYS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up",
 _STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router", "ws_gate", "ws_up",
                      "ws_down")
 
+#: a hybrid model (``models.olmo_hybrid``): its layers come in PERIODS, some
+#: linear-attention (Gated DeltaNet) layers and then one full-attention layer.
+#: The full layers' entries are the tree's own, ``[periods, ...]``; the linear
+#: layers' lie under ``linear_layers``, a tuple with one tree ``[periods,
+#: ...]`` for each place in the period (arrays of their own: a scan then cuts
+#: ONE layer's weights out of each, which XLA fuses into the matmul that
+#: reads them; cut out of ``[periods, layers a period, ...]`` the three
+#: layers' weights were copied every period, a third of a step: PERF.md).
+#: Both kinds normalise each sub-layer's OUTPUT (``attn_out_ln`` /
+#: ``ffn_out_ln``) and have no ``input_ln`` / ``post_ln``.
+_HYBRID_FULL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                     "q_norm", "k_norm", "attn_out_ln", "ffn_out_ln")
+_GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
+             "gdn_dt_bias", "gdn_o_norm", "gdn_wo", "w_gate", "w_up",
+             "w_down", "attn_out_ln", "ffn_out_ln")
+
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch)
-TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a",)
+TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers")
 
 
 def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1):
     """``{"block_q", "pages"}`` of the attention kernel that
     ``_packed_span_forward`` runs on this tree over the stored ``pool``
     ``[L, num_blocks, bs, KD]`` (a chip's share is ``KD // tp``): the
-    kernel's own ``grid_params`` of what its call will see, so the engine's
-    ``ragged_grid_counts`` counts the grid the step really runs."""
+    kernel's own ``grid_params`` of what its call will see (``heads`` as
+    ``attention_rows`` gives them), so the engine's ``ragged_grid_counts``
+    counts the grid the step really runs."""
     if "wkv_a" in params:
         return _mla_grid_params(table_entries, heads, packed_tokens)
     return _ragged_grid_params(
-        pool.dtype, pool.shape[2], pool.shape[3] // tp, table_entries, heads,
-        packed_tokens)
+        pool.dtype, pool.shape[2], pool.shape[3] // tp, table_entries,
+        wide_rows(heads), packed_tokens)
+
+
+def attention_rows(params, heads):
+    """The wide rows a token that the tree's attention kernel makes of
+    ``heads`` query heads (``pallas_ragged_attention.wide_rows``: 30 -> 32;
+    the latent kernel takes them as they are)."""
+    return heads if "wkv_a" in params else wide_rows(heads)
 
 
 #: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not
@@ -130,7 +161,10 @@ def _layer_stacks(params):
     arrays, experts)``: a model whose leading layers differ from the rest (a
     dense FFN before routed ones) brings them as a tree of their own under
     ``dense_layers``, scanned first; ``first layer`` is where a stack's
-    layers start in the model (and so in the KV pool)."""
+    layers start in the model (and so in the KV pool). A model whose kinds
+    of layer ALTERNATE by period (a tree with ``linear_layers``) is not a
+    sequence of stacks: its forward is ``_hybrid_scan``, one scan over the
+    periods whose body runs a period's layers in order."""
     stacks, first = [], 0
     for tree in (params.get("dense_layers"), params):
         if tree is not None:
@@ -372,9 +406,13 @@ _ROUTING_KEYS = ("n_group", "topk_group", "first_held", "scale")
 def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
                    moe=None, experts=None, tp_reduce=None,
                    return_picks=False, mla=None):
-    """ONE pre-norm decoder layer on ``h [B, S, H]``, written once for the
-    programs the default engine runs (whole-prompt prefill, the packed-span
-    forward of the unified step) and for the models' own ``forward``.
+    """ONE softmax-attention decoder layer on ``h [B, S, H]``, written once
+    for the programs the default engine runs (whole-prompt prefill, the
+    packed-span forward of the unified step) and for the models' own
+    ``forward``. Where the norms sit follows the tree: with ``input_ln`` /
+    ``post_ln`` each sub-layer's INPUT is normalised (pre-norm), with
+    ``attn_out_ln`` / ``ffn_out_ln`` its OUTPUT, before the residual add (a
+    hybrid model's full layers; its linear layers are ``_gdn_layer``).
 
     ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
     ``_dq_layer``) and what it holds chooses the body: with ``wkv_a`` the
@@ -393,7 +431,7 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
     third is ``(moe_stats, picked experts [B, S, top_k])``."""
     B, S = h.shape[0], h.shape[1]
     with jax.named_scope("attn"):
-        hn = _rms(h, lw["input_ln"], eps)
+        hn = _rms(h, lw["input_ln"], eps) if "input_ln" in lw else h
         if "wkv_a" in lw:
             with jax.named_scope("mla"):
                 attn, carry = _mla_attention(hn, lw, nh=nh, eps=eps,
@@ -408,8 +446,10 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
                 q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps)
             attn, carry = attend(rope(q), rope(k), v)
             o = _o_proj(attn.reshape(B, S, nh * hd), lw["wo"])
-        h = h + (o if tp_reduce is None else tp_reduce(o))
-    hn = _rms(h, lw["post_ln"], eps)
+        o = o if tp_reduce is None else tp_reduce(o)
+        h = h + (_rms(o, lw["attn_out_ln"], eps) if "attn_out_ln" in lw
+                 else o)
+    hn = _rms(h, lw["post_ln"], eps) if "post_ln" in lw else h
     if "router" in lw:
         m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
                             top_k=moe[0], live=live, renormalize=moe[1],
@@ -423,8 +463,109 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
     else:
         m, stats = _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
                                 lw["w_down"]), None
-    h = h + (m if tp_reduce is None else tp_reduce(m))
+    m = m if tp_reduce is None else tp_reduce(m)
+    h = h + (_rms(m, lw["ffn_out_ln"], eps) if "ffn_out_ln" in lw else m)
     return h, carry, stats
+
+
+# ------------------------------------------- linear-attention (hybrid) layers
+def gdn_gates(ab, a_log, dt_bias, neg_eigval):
+    """A Gated DeltaNet layer's gates from the fused ``[.., 2 * heads]``
+    projection ``[a | b]``, float32: the log-decay ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` and the write strength ``beta = sigmoid(b)``,
+    doubled where negative eigenvalues are allowed."""
+    ab = ab.astype(jnp.float32)
+    nh = ab.shape[-1] // 2
+    g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+        ab[..., :nh] + dt_bias.astype(jnp.float32))
+    beta = jax.nn.sigmoid(ab[..., nh:])
+    return g, (2.0 * beta if neg_eigval else beta)
+
+
+def conv_silu(cur, prev, w):
+    """The depthwise causal convolution of width ``len(prev) + 1`` and its
+    SiLU, a channel: ``silu(w[-1] * u_t + w[-2] * u_{t-1} + ..)``, float32
+    inside, the rows' dtype out. ``prev[j - 1]`` holds ``u_{t-j}``; ``w`` is
+    ``[width, C]``, its last row the current token's."""
+    f32 = jnp.float32
+    acc = cur.astype(f32) * w[-1].astype(f32)
+    for j, p in enumerate(prev, 1):
+        acc = acc + p.astype(f32) * w[-1 - j].astype(f32)
+    return jax.nn.silu(acc).astype(cur.dtype)
+
+
+def gdn_split(u, gdn):
+    """The convolved channels ``[.., C]`` as normalised ``q, k [.., heads,
+    dk]`` (float32; q carries the ``dk^-0.5``) and ``v [.., heads, dv]``."""
+    nk = gdn.heads * gdn.dk
+    lead = u.shape[:-1]
+    q = l2norm(u[..., :nk].reshape(lead + (gdn.heads, gdn.dk)),
+               gdn.dk ** -0.5)
+    k = l2norm(u[..., nk:2 * nk].reshape(lead + (gdn.heads, gdn.dk)))
+    return q, k, u[..., 2 * nk:].reshape(lead + (gdn.heads, gdn.dv))
+
+
+def _gdn_layer(h, lw, *, eps, gdn, mix):
+    """ONE Gated DeltaNet layer on ``h [B, S, H]`` (``models.olmo_hybrid``'s
+    docstring has the equations), written once for whole-prompt prefill, the
+    unified step and the model's ``forward``. ``gdn`` is the layer's static
+    numbers (``models.olmo_hybrid.Gdn``). The program brings ``mix(u, g,
+    beta, conv_w) -> (o [B, S, heads, dv] float32, carry)``: where the
+    convolution's earlier rows and the state come from, which kernel walks
+    the tokens, and what is written back are the program's business. Scopes
+    ``gdn`` > ``gdn_proj`` (the input projections and ``W_o``) and
+    ``gdn_mix`` (convolution, gates, the kernels, the gated norm)."""
+    B, S = h.shape[0], h.shape[1]
+    with jax.named_scope("gdn"):
+        with jax.named_scope("gdn_proj"):
+            u = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wqkv"])
+            z = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wz"])
+            # the gates' projection leaves in float32: the decay is
+            # exp(-exp(A_log) softplus(a + ..)), and a bf16 rounding of a
+            # moves it by tens of percent where exp(A_log) is near 16
+            ab = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wab"],
+                            preferred_element_type=jnp.float32)
+        with jax.named_scope("gdn_mix"):
+            g, beta = gdn_gates(ab, lw["gdn_A_log"], lw["gdn_dt_bias"],
+                                gdn.neg_eigval)
+            o, carry = mix(u, g, beta, lw["gdn_conv"])
+            y = _rms(o, lw["gdn_o_norm"].astype(jnp.float32), eps) \
+                * jax.nn.silu(z.astype(jnp.float32)).reshape(o.shape)
+            y = y.astype(h.dtype).reshape(B, S, -1)
+        with jax.named_scope("gdn_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y, lw["gdn_wo"])
+        h = h + _rms(out, lw["attn_out_ln"], eps)
+    m = _swiglu_raw(h, lw["w_gate"], lw["w_up"], lw["w_down"])
+    return h + _rms(m, lw["ffn_out_ln"], eps), carry
+
+
+def _hybrid_scan(params, carry, full_layer, linear_layer):
+    """A hybrid model's forward: ONE scan over the periods whose body runs
+    the period's linear layers in order, then its full layer.
+    ``linear_layer(carry, lw, index)`` / ``full_layer(carry, lw, index)``
+    return ``(carry, ys)``; ``index`` is the layer's count among its own
+    kind: a linear layer's place in the state store, a full layer's in the
+    KV pool. Returns ``(carry, linear ys [periods, layers a period, ...],
+    full ys [periods, ...])``."""
+    lin = tuple({k: tree[k] for k in _GDN_KEYS}
+                for tree in params["linear_layers"])
+    full = {k: params[k] for k in _HYBRID_FULL_KEYS}
+    periods, n_lin = full["attn_out_ln"].shape[0], len(lin)
+
+    def period(carry, xs):
+        lin_p, full_p, p = xs
+        ys = []
+        for j in range(n_lin):
+            carry, y = linear_layer(carry, lin_p[j], p * n_lin + j)
+            ys.append(y)
+        carry, y_full = full_layer(carry, full_p, p)
+        ys = None if ys[0] is None else jax.tree.map(
+            lambda *a: jnp.stack(a), *ys)
+        return carry, (ys, y_full)
+
+    carry, (ys_lin, ys_full) = jax.lax.scan(
+        period, carry, (lin, full, jnp.arange(periods, dtype=jnp.int32)))
+    return carry, ys_lin, ys_full
 
 
 @jax.named_scope("lm_head")
@@ -804,9 +945,69 @@ def _moe_outputs(stats):
     return (stats,)
 
 
+def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
+    """A hybrid model's layers over an admission group ``x [G, S_pad, H]``
+    (``_hybrid_scan``): the full layers attend causally and return their
+    K/V, the linear layers run the chunked scan from a zero state over each
+    row's real tokens (a padding column has ``beta`` 0 and ``g`` 0: the
+    state passes it unchanged) and return what their cache holds of a
+    sequence: the final state ``[G, heads, dk, dv]`` float32 and the
+    convolution's last inputs ``[G, conv - 1, C]``. Returns ``(x, pk, pv
+    [full layers, G, S_pad, Hkv, D], (states, tails) [linear layers, G,
+    ...])``."""
+    G, S = x.shape[0], x.shape[1]
+    taps = gdn.conv - 1
+    cols = jnp.arange(S, dtype=jnp.int32)
+    live = cols[None, :] < lengths[:, None]
+    rows_g = jnp.arange(G, dtype=jnp.int32)
+
+    def full_layer(h, lw, _):
+        h, kv, _ = _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=lambda t: t,
+            attend=lambda q, k, v: (_attention(q, k, v, causal=True),
+                                    (k, v)))
+        return h, kv
+
+    def linear_layer(h, lw, _):
+        def mix(u, g, beta, conv_w):
+            ext = jnp.pad(u, ((0, 0), (taps, 0), (0, 0)))
+            up = conv_silu(u, [ext[:, taps - j:taps - j + S]
+                               for j in range(1, taps + 1)], conv_w)
+            tail = jnp.take_along_axis(
+                ext, (lengths[:, None] + jnp.arange(taps)[None])[..., None],
+                axis=1)
+            q, k, v = gdn_split(up, gdn)
+            g = jnp.where(live[..., None], g, 0.0)
+            beta = jnp.where(live[..., None], beta, 0.0)
+
+            def flat(a):
+                return a.reshape((G * S,) + a.shape[2:])
+
+            zero = jnp.zeros((1, G, gdn.heads, gdn.dk, gdn.dv), jnp.float32)
+            if gdn.kernel == "pallas":
+                o, st = gdn_chunk_scan(
+                    flat(q), flat(k), flat(v), flat(g), flat(beta), zero,
+                    layer=0, start=rows_g * S, length=lengths,
+                    fresh=jnp.ones((G,), bool))
+            else:
+                o, st = gdn_reference(
+                    flat(q), flat(k), flat(v), flat(g), flat(beta), zero,
+                    layer=0, seg=jnp.where(live, rows_g[:, None], G).reshape(
+                        -1), first=jnp.broadcast_to(cols == 0, (G, S)
+                                                    ).reshape(-1))
+            o = jnp.where(live[..., None, None],
+                          o.reshape(G, S, gdn.heads, gdn.dv), 0.0)
+            return o, (st[0], tail)
+
+        return _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix)
+
+    x, ys_lin, (pk, pv) = _hybrid_scan(params, x, full_layer, linear_layer)
+    return x, pk, pv, tuple(a.reshape((-1,) + a.shape[2:]) for a in ys_lin)
+
+
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
-                  mla=None, return_picks=False):
+                  mla=None, return_picks=False, gdn=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -824,9 +1025,20 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     attention (``wkv_a``; ``mla`` its static numbers) attends in the
     expanded form and returns as ``pk`` the rows its latent pool stores,
     ``[L, G, S_pad, 1, W]``, and a ``pv`` of width 0: no per-head K or V
-    leaves the layer.
+    leaves the layer. A hybrid model (``linear_layers``; ``gdn`` its linear
+    layers' static numbers) returns ``pk`` / ``pv`` of its FULL layers only
+    and, last, what its linear layers' cache holds of each row
+    (``_hybrid_prefill_layers``).
     """
     B, S = ids.shape
+    if gdn is not None:
+        x = jnp.take(params["embed"], ids, axis=0)
+        x, pk, pv, state = _hybrid_prefill_layers(
+            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, gdn=gdn)
+        tok0, keys2 = _first_token(
+            params, _dq_head(params, tied, params["embed"].dtype, a8), x,
+            lengths, keys, temps, top_ks, eps)
+        return pk, pv, tok0, keys2, state
     sin, cos = _rope_tables_for(S, hd, theta, mla)
     wdt = params["embed"].dtype
     head = _dq_head(params, tied, wdt, a8)
@@ -860,18 +1072,24 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
         stats = st if experts is not None else stats
     pk, pv = (jnp.concatenate(side) if len(kvs) > 1 else side[0]
               for side in zip(*kvs))
+    tok0, keys2 = _first_token(params, head, x, lengths, keys, temps, top_ks,
+                               eps)
+    return (pk, pv, tok0, keys2) + _moe_outputs(stats)
+
+
+def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
+    """A prefilled group's first token: the head on each row's last real
+    position, sampled under the row's key. Returns ``(tok0, keys')``."""
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
-    last_h = _rms(last, params["final_norm"], eps)
-    logits = _head_logits(last_h, head)
+    logits = _head_logits(_rms(last, params["final_norm"], eps), head)
     both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
-    tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
-    return (pk, pv, tok0, both[:, 0]) + _moe_outputs(stats)
+    return sample_rows(logits, both[:, 1], temps, top_ks), both[:, 0]
 
 
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
-                     mla=None, return_picks=False):
+                     mla=None, return_picks=False, gdn=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -893,7 +1111,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                        rep, rep)))
     return jax.jit(functools.partial(
         _prefill_impl, nh=nh, nkv=nkv, hd=hd, eps=eps, theta=theta,
-        tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks))
+        tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks,
+        **({} if gdn is None else {"gdn": gdn})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -1149,10 +1368,105 @@ def _span_last_sample(params, head, x, qstart, qlen, keys, temps, top_ks,
     return tok0, both[:, 0]
 
 
+def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
+                         seg, pos, qstart, qlen, kvlen, nh, nkv, hd, eps,
+                         gdn):
+    """A hybrid model's layers over the packed buffer ``x [1, T, H]``
+    (``_hybrid_scan``). The KV pool (full layers only) and the state store
+    ``(states [linear layers, R, heads, dk, dv] float32, tails [linear
+    layers, R, conv - 1, C])`` ride the scan as carry, whole: a full layer
+    appends and attends at its own count in the pool (``kv_attend(pk, pv,
+    layer)``), a linear layer reads and writes its own count in the store,
+    at the slots that have a span this step and nowhere else.
+
+    What a linear layer needs of the span table: where a span starts in the
+    buffer (``qstart``) says which of the convolution's earlier inputs are
+    rows of this buffer and which the slot's stored tail; a span whose first
+    position ``kvlen - qlen`` is 0 takes a zero tail and a zero state,
+    whatever its slot held (no program ever zeroes a slot); spans of one
+    token (decode rows) go through ``gdn_recurrent_update`` together, longer
+    ones (prefill chunks) through ``gdn_chunk_scan``, both following the
+    live spans and not the buffer (``decode_attention="jnp"``: the
+    token-by-token oracle over the whole buffer). Returns ``(x, pool_k,
+    pool_v, state)``."""
+    R, T = qstart.shape[0], x.shape[1]
+    taps = gdn.conv - 1
+    live_tok = seg < R
+    seg_c = jnp.minimum(seg, R - 1)
+    fresh = (kvlen - qlen) == 0
+    one, many = qlen == 1, qlen > 1
+    tok_one = live_tok & jnp.take(one, seg_c)
+    row_at = jnp.clip(qstart, 0, T - 1)
+    slots = jnp.arange(R, dtype=jnp.int32)
+
+    def full_layer(carry, lw, idx):
+        h, pk, pv, st = carry
+        h, (pk, pv), _ = _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=lambda t: t,
+            attend=kv_attend(pk, pv, idx))
+        return (h, pk, pv, st), None
+
+    def linear_layer(carry, lw, idx):
+        h, pk, pv, (ss, cs) = carry
+
+        def mix(u, g, beta, conv_w):
+            u, g, beta = u[0], g[0], beta[0]
+            held = cs[idx]                                  # [R, taps, C]
+            tail = jnp.where(fresh[:, None, None], jnp.zeros_like(held), held)
+            # a token's earlier inputs are the rows above it, except in a
+            # span's first rows, which take the slot's tail: a few rows a
+            # span, scattered over the shifted buffer (a gather of the
+            # tail for every packed row cost 6 % of a step)
+            prev = []
+            for j in range(1, taps + 1):
+                at = jnp.concatenate([jnp.where(qlen > i, qstart + i, T)
+                                      for i in range(j)])
+                rows = jnp.concatenate([tail[:, taps + i - j]
+                                        for i in range(j)])
+                prev.append(jnp.roll(u, j, axis=0).at[at].set(
+                    rows, mode="drop"))
+            up = conv_silu(u, prev, conv_w)
+            # the slot's new tail: the span's last inputs, and where the
+            # span is shorter than the tail, the old tail moved up
+            rows = []
+            for k in range(taps):
+                at = qlen - taps + k
+                rows.append(jnp.where(
+                    (at >= 0)[:, None],
+                    jnp.take(u, jnp.clip(qstart + at, 0, T - 1), axis=0),
+                    tail[slots, jnp.clip(taps + at, 0, taps - 1)]))
+            new_cs = cs.at[idx].set(jnp.where(
+                (qlen > 0)[:, None, None], jnp.stack(rows, 1), held))
+            q, k, v = gdn_split(up, gdn)
+            if gdn.kernel == "pallas":
+                o1, new_ss = gdn_recurrent_update(
+                    *(jnp.take(a, row_at, axis=0) for a in (q, k, v, g, beta)),
+                    ss, layer=idx, live=one, fresh=fresh)
+                on, new_ss = gdn_chunk_scan(
+                    q, k, v, g, beta, new_ss, layer=idx, start=qstart,
+                    length=jnp.where(many, qlen, 0), fresh=fresh)
+                o = jnp.where(tok_one[:, None, None],
+                              jnp.take(o1, seg_c, axis=0), on)
+            else:
+                o, new_ss = gdn_reference(
+                    q, k, v, g, beta, ss, layer=idx, seg=seg,
+                    first=live_tok & (pos == 0))
+            o = jnp.where(live_tok[:, None, None], o, 0.0)
+            return o[None], (new_ss, new_cs)
+
+        h, st = _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix)
+        return (h, pk, pv, st), None
+
+    (x, pool_k, pool_v, state), _, _ = _hybrid_scan(
+        params, (x, pool_k, pool_v, state), full_layer, linear_layer)
+    return x, pool_k, pool_v, state
+
+
 def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
-                         moe=None, mla=None, return_picks=False):
+                         moe=None, mla=None, return_picks=False, state=None,
+                         gdn=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -1166,7 +1480,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     ``return_picks`` the pair ``(summary, picked experts [L, 1, T, top_k])``.
     A model with latent attention (``mla``) writes one row a token into the K side,
     the latent pool (its V side has width 0), and every span, decode row
-    and chunk alike, attends in the absorbed form.
+    and chunk alike, attends in the absorbed form. A hybrid model (``state``
+    its linear layers' store, ``gdn`` their static numbers) rotates nothing
+    (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
+    the store.
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
@@ -1174,8 +1491,9 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     s_tot = mb * bs
     T = ids.shape[0]
     wdt = params["embed"].dtype
-    sin_p = jnp.take(sin, pos, axis=0, mode="clip")[None]   # [1, T, D]
-    cos_p = jnp.take(cos, pos, axis=0, mode="clip")[None]
+    if sin is not None:
+        sin_p = jnp.take(sin, pos, axis=0, mode="clip")[None]   # [1, T, D]
+        cos_p = jnp.take(cos, pos, axis=0, mode="clip")[None]
     # pool write coordinates: token t appends at its logical position
     # through its OWN slot's table; dead packed rows (seg == R) and
     # positions past the logical capacity drop — never clamp into a
@@ -1188,30 +1506,43 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     phys0 = jnp.where(live_tok & (pos < s_tot), phys0, nb)
     prow0 = pos % bs
 
+    def kv_attend(pk, pv, layer):
+        at = (layer, phys0, prow0)
+
+        def attend(q, k, v):
+            # write the packed K/V through the tables (quantize-on-write
+            # on an int8 pool), then attend over each span causally at
+            # its row's kv length — THE one dequant site: the ragged
+            # kernel (or its oracle) dequantizes right after the
+            # table-indirect fetch, and every consumer of this forward
+            # (unified step, multi-tick tick 0, speculative verify)
+            # rides it
+            npk = _kv_write(pk, at, k[0])
+            npv = _kv_write(pv, at, v[0])
+            kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
+            ragged = (ragged_paged_attention_pallas
+                      if decode_attn == "pallas"
+                      else ragged_attention_reference)
+            attn = ragged(q[0], kd, vd, tables, qstart, qlen, kvlen,
+                          k_scale=ksc, v_scale=vsc, layer=layer)
+            return attn, (npk, npv)
+
+        return attend
+
+    if state is not None:
+        x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
+        return _hybrid_span_forward(
+            params, x, pool_k, pool_v, state, kv_attend, seg=seg, pos=pos,
+            qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
+            eps=eps, gdn=gdn)
+
     def scan_stack(carry, first, names, stack, experts):
         def layer0(carry, lp):
             h, pk, pv = carry
             lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
             layer = first + lw["layer"]     # this layer's place in the pool
             at = (layer, phys0, prow0)
-
-            def attend(q, k, v):
-                # write the packed K/V through the tables (quantize-on-write
-                # on an int8 pool), then attend over each span causally at
-                # its row's kv length — THE one dequant site: the ragged
-                # kernel (or its oracle) dequantizes right after the
-                # table-indirect fetch, and every consumer of this forward
-                # (unified step, multi-tick tick 0, speculative verify)
-                # rides it
-                npk = _kv_write(pk, at, k[0])
-                npv = _kv_write(pv, at, v[0])
-                kd, vd, ksc, vsc = _kv_attn_args(npk, npv)
-                ragged = (ragged_paged_attention_pallas
-                          if decode_attn == "pallas"
-                          else ragged_attention_reference)
-                attn = ragged(q[0], kd, vd, tables, qstart, qlen, kvlen,
-                              k_scale=ksc, v_scale=vsc, layer=layer)
-                return attn, (npk, npv)
+            attend = kv_attend(pk, pv, layer)
 
             def attend_latent(q_nope, q_pe, c_kv, k_pe, w_kvb):
                 # one row a token into the latent pool, then the ABSORBED
@@ -1264,10 +1595,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
 @jax.named_scope("ragged_step")
 def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, dec_mask, keys, temps, top_ks,
-                      prev_toks, take, chunk_keys, adopt,
+                      prev_toks, take, chunk_keys, adopt, state=None,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
-                      moe=None, mla=None, return_picks=False):
+                      moe=None, mla=None, return_picks=False, gdn=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -1322,7 +1653,9 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     chunk row's token 0 — the same split walk as a one-shot prefill, so
     streams stay byte-identical); ``tok_fin`` is the last tick's sample
     (the next step's ``prev_toks``) and ``keys'`` the next step's
-    ``keys``, both handed on without a host round trip.
+    ``keys``, both handed on without a host round trip. A hybrid model
+    passes ``state``, its linear layers' store (``_hybrid_span_forward``;
+    donated like the pool), and gets it back as the last value.
     """
     # dispatch-ahead: a decode row dispatched before the previous step's
     # tokens reached the host takes its input token here, on the device
@@ -1332,13 +1665,23 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     keys_in = jnp.where(((qlen > 0) & (dec_mask == 0))[:, None],
                         chunk_keys, keys)
     s_tot = tables.shape[1] * _kv_data(pool_k).shape[2]
-    sin, cos = _rope_tables_for(s_tot, hd, theta, mla)
+    sin, cos = (None, None) if theta is None \
+        else _rope_tables_for(s_tot, hd, theta, mla)
     # the fused tail's own layer body scans the GQA entries alone (a model
     # it was not taught never runs with n_steps > 1: the engine raises)
     stack = (tuple(params[k] for k in _STACK_KEYS) if n_steps > 1 else None)
     head = _dq_head(params, tied, params["embed"].dtype, a8)
 
     # ----------------------------------- tick 0 (shared packed forward)
+    if state is not None:
+        x, pk, pv, state = _packed_span_forward(
+            params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
+            kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
+            decode_attn=decode_attn, state=state, gdn=gdn)
+        tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
+                                          keys_in, temps, top_ks, eps)
+        keys_out = jnp.where((adopt > 0)[:, None], keys_t0, keys_in)
+        return pk, pv, tok0[None], tok0, keys_out, state
     x, pk, pv, moe_stats = _packed_span_forward(
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
@@ -1378,7 +1721,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
-                         return_picks=False):
+                         return_picks=False, gdn=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, token_budget)`` plus the fused
     ``n_steps`` — one compilation per step size serves every span mix,
@@ -1414,8 +1757,11 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             _ragged_step_impl, n_steps=n_steps, nh=nh, nkv=nkv, hd=hd,
             eps=eps, theta=theta, tied=tied, decode_attn=decode_attn,
             a8=a8, fused=fused, moe=moe, mla=mla,
-            return_picks=return_picks),
-        donate_argnums=(1, 2) if donate else ())
+            return_picks=return_picks,
+            **({} if gdn is None else {"gdn": gdn})),
+        # argument 18: a hybrid model's state store (absent otherwise)
+        donate_argnums=((1, 2) + ((18,) if gdn is not None else ()))
+        if donate else ())
 
 
 # ------------------------------------------------------- multi-tick decode

@@ -49,7 +49,8 @@ import numpy as np
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
-from .decode import attention_grid, build_paged_suffix_prefill_fn, \
+from .decode import attention_grid, attention_rows, \
+    build_paged_suffix_prefill_fn, \
     build_prefill_fn, build_ragged_step_fn, TAUGHT_KEYS, latent_row_width
 from .kv_cache import PagedKVCache, PoolExhausted
 from .policy import ClassTable, PolicyScheduler, select_victims
@@ -300,9 +301,11 @@ class ContinuousBatchingEngine:
                    "spec_decode": bool(spec_decode),
                    "decode_chunk > 1": int(decode_chunk) > 1,
                    "prefix_cache": bool(prefix_cache)}
-            if "wkv_a" in self._params:
+            if "wkv_a" in self._params or "linear_layers" in self._params:
                 # a latent pool has no heads to scale by and no V side:
-                # the quantized pools' planes and kernels do not apply
+                # the quantized pools' planes and kernels do not apply;
+                # a hybrid model's quantized cache would be its recurrent
+                # state's to define as well, and nothing defines it
                 off["kv_dtype"] = kv_dtype is not None
             bad = [name for name, on in off.items() if on]
             if bad:
@@ -370,6 +373,13 @@ class ContinuousBatchingEngine:
                                                self._wq8)
             self._params = placed[pkey]
         dtype = self._params["embed"].dtype
+        # layers with a recurrent state (a hybrid model's linear layers):
+        # their cache is a store by slot beside the pool
+        # (``PagedKVCache.state``), and the pool holds rows for the OTHER
+        # layers only
+        self._stateful = "linear_layers" in self._params
+        kv_layers = c.num_kv_layers if self._stateful \
+            else c.num_hidden_layers
         # what a cached token's row is: Hkv heads of head_dim on a K and a
         # V side, or for latent attention ONE row of the normalised latent
         # and the rotated shared key, padded to whole lanes, and no V side
@@ -437,20 +447,25 @@ class ContinuousBatchingEngine:
                     raise ValueError(
                         f"prefix_blocks must be >= 1, got {budget}")
             pool = BlockManager(
-                c.num_hidden_layers, live + budget, bs, dtype=dtype,
+                kv_layers, live + budget, bs, dtype=dtype,
                 kv_dtype=self._kv_dtype, mesh=tp_mesh, **geom)
             self.prefix_cache = PrefixCache(
                 pool, max_blocks=budget,
                 host_tier_bytes=self._host_tier_bytes)
         else:
             pool = BlockManager(
-                c.num_hidden_layers, live, bs, dtype=dtype,
+                kv_layers, live, bs, dtype=dtype,
                 kv_dtype=self._kv_dtype, mesh=tp_mesh, **geom)
+        state_geometry = None
+        if self._stateful:
+            g = c.gdn
+            state_geometry = (c.num_linear_layers, g.heads, g.dk, g.dv,
+                              g.conv - 1, c.conv_channels)
         self.cache = PagedKVCache(
-            c.num_hidden_layers, self.num_slots, self.max_seq_len,
+            kv_layers, self.num_slots, self.max_seq_len,
             geom["num_kv_heads"], geom["head_dim"], dtype=dtype,
             block_size=bs, pool=pool, prefix_cache=self.prefix_cache,
-            kv_dtype=self._kv_dtype)
+            kv_dtype=self._kv_dtype, state_geometry=state_geometry)
         # chunked prefill: the chunk is rounded UP to a block multiple so
         # every non-final chunk boundary is block-aligned: a partially
         # prefilled prompt is exactly a prefix of whole pool blocks + a
@@ -634,7 +649,9 @@ class ContinuousBatchingEngine:
                       "policy_preemptions": 0,
                       "moe_pairs": 0, "moe_experts_touched": 0,
                       "moe_max_expert_pairs": 0, "moe_picks": 0,
-                      "moe_layer_calls": 0}
+                      "moe_layer_calls": 0,
+                      "state_rows": 0, "state_restarts_fault": 0,
+                      "state_restarts_preempt": 0}
         # fault-injection hook (serving/faults.py): called with the
         # engine at the top of every step attempt; None in production.
         # Whatever it raises propagates to the driver — except
@@ -743,7 +760,8 @@ class ContinuousBatchingEngine:
         derives for itself (``decode.attention_grid``)."""
         heads = self.config.num_attention_heads // self._tp
         work = ragged_grid_counts(
-            qstart, qlen, kvlen, packed_tokens=packed, heads=heads,
+            qstart, qlen, kvlen, packed_tokens=packed,
+            heads=attention_rows(self._params, heads),
             block_size=self.cache.block_size,
             table_entries=self.cache.max_blocks,
             **attention_grid(self._params, self.cache.pool.k,
@@ -751,6 +769,13 @@ class ContinuousBatchingEngine:
                              tp=self._tp))
         work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
                     prefill_tokens=prefill_tokens)
+        if self._stateful:
+            # what ONE linear layer call does: the rows whose state it
+            # reads and writes (every live span's slot), and what it sends
+            # through the chunked scan (the spans longer than one token)
+            spans = [int(n) for n in qlen if n > 1]
+            work.update(state_rows=int((np.asarray(qlen) > 0).sum()),
+                        scan_tokens=sum(spans), scan_spans=len(spans))
         return work
 
     # ------------------------------------------------------------ programs
@@ -758,7 +783,8 @@ class ContinuousBatchingEngine:
         c = self.config
         consts = dict(nh=c.num_attention_heads, nkv=c.num_key_value_heads,
                       hd=c.head_dim, eps=float(c.rms_norm_eps),
-                      theta=float(c.rope_theta), tied=self._tied)
+                      theta=None if c.rope_theta is None
+                      else float(c.rope_theta), tied=self._tied)
         if self.routed_ffn:
             # the routed FFN's static numbers, model hyper-parameters like
             # the head counts above
@@ -766,6 +792,8 @@ class ContinuousBatchingEngine:
                 int(c.num_experts_per_tok), bool(c.norm_topk_prob))
         if "wkv_a" in self._params:
             consts["mla"] = c.mla
+        if self._stateful:
+            consts["gdn"] = c.gdn
         if self._routing is not None:
             consts["return_picks"] = True
         return consts
@@ -1274,6 +1302,9 @@ class ContinuousBatchingEngine:
                 # REAL host→device upload bytes of the call
                 pk, pv, tok0s, keys2, *moe = self._prefill_fn()(
                     self._params, ids, lens, keys, temps, topks)
+                # a model with recurrent layers: last, what their cache
+                # holds of each row (states, convolution tails)
+                state = moe.pop() if self._stateful else None
                 tok0s = np.asarray(tok0s)
                 sp.add(self._count_moe(moe))
             if self._routing is not None:
@@ -1292,6 +1323,10 @@ class ContinuousBatchingEngine:
                 # the claimed slot findable for _abort_admission
                 self.cache.write_prefill(slot, pk[:, i], pv[:, i],
                                          seq.work_len)
+                if state is not None:
+                    self.cache.write_state(slot, state[0][:, i],
+                                           state[1][:, i])
+                    self.stats["state_rows"] += 1
                 self._install_seq(seq, slot, tok0s[i], keys2[i],
                                   seq.work_len, finished)
 
@@ -1791,15 +1826,20 @@ class ContinuousBatchingEngine:
             seq.prefix_nodes = []
         seq.slot = None
 
-    def _preempt(self, seq):
+    def _preempt(self, seq, reason="preempt"):
         """Preemption-by-recompute: displace the sequence
         (:meth:`_displace` — chain donated, PRNG snapshotted) and
         re-queue it HERE via :meth:`restore`. Because the chain was
         just donated, the recompute prefill is typically a zero-copy
         trie hit; the PRNG walk snapshot keeps the continuation
         byte-identical. Nothing is emitted and the sequence does not
-        finish — consumers just see a pause."""
+        finish — consumers just see a pause. A model with recurrent
+        layers recomputes from position 0 whatever the trie holds (its
+        state cannot be resumed from a donated chain), counted in
+        ``serving_state_restarts_total{reason}``."""
         self.stats["preemptions"] += 1
+        if self._stateful:
+            self.stats["state_restarts_" + reason] += 1
         self._displace(seq, "preempted")
         self.restore(seq)
         seq.trace_phase = "preempted"   # restore() named it "recovered"
@@ -2019,9 +2059,13 @@ class ContinuousBatchingEngine:
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             dec_mask, keys_in, temps, topks,
             self._no_toks if prev is None else prev.tok_fin, take,
-            chunk_keys, adopt)
+            chunk_keys, adopt,
+            *((self.cache.state,) if self._stateful else ()))
         # the program is on the device's queue: commit what it advances
         self.cache.update(npk, npv)
+        if self._stateful:
+            self.cache.state = moe.pop()
+            self.stats["state_rows"] += len(rows) + len(chunk_rows)
         self._keys = keys_out
         chunks = []
         for slot, seq, ntok, final in chunk_rows:
@@ -2203,8 +2247,16 @@ class ContinuousBatchingEngine:
         accepted: the key state is the one ``rec`` started from (a
         program's keys are ahead of the accepted tokens by what it
         sampled) and chunk progress returns to the first dropped chunk's
-        offset. The rows the dropped programs wrote lie past every
-        accepted length."""
+        offset. The rows the dropped programs wrote to the KV pool lie past
+        every accepted length, and a pool row is safe to write twice. A
+        recurrent state is not: the dropped programs have applied their
+        tokens to it, and a re-run from the accepted offset would apply
+        them a second time. A model with such layers therefore sends every
+        live sequence the dropped programs carried back to position 0, by
+        the recompute path preemption has (``_preempt``: the slot is freed,
+        the sequence queued again with what it has generated, and its first
+        span starts from a zero state);
+        ``serving_state_restarts_total{reason="fault"}`` counts them."""
         later, self._inflight = self._inflight, None
         self._keys = rec.keys_in
         self.stats["drains_fault"] += 1
@@ -2217,6 +2269,12 @@ class ContinuousBatchingEngine:
                 seq.prefilled = off
                 if final and seq not in self.scheduler.prefilling:
                     self.scheduler.prefilling.appendleft(seq)
+        if self._stateful:
+            carried = [(slot, seq) for r in (rec, later) if r is not None
+                       for slot, seq, *_ in list(r.rows) + list(r.chunks)]
+            for slot, seq in dict.fromkeys(carried):
+                if not seq.done and self._slots[slot] is seq:
+                    self._preempt(seq, reason="fault")
 
     def _accept_decode_rows(self, toks_np, n, rows, finished,
                             counts=None):

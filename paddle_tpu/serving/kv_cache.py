@@ -10,6 +10,11 @@ fresh private blocks lazily, and retirement *donates* full blocks to the
 trie. Admission claims a free slot, finish releases it, and a freed
 block's stale rows are never read (the kernels walk a row's own length).
 
+A token has a row in the pool for the layers that attend over keys and
+values; a layer whose cache is a recurrent state (a hybrid model's linear
+layers) keeps it in a second store, by slot and not paged
+(:attr:`PagedKVCache.state`): one manager, two kinds of cache.
+
 The pool's device arrays are functionally updated (donated through the
 jitted writers on non-CPU backends, so XLA updates in place); the host
 ``lengths`` / ``tables`` mirrors are the scheduling truth — device-side
@@ -292,6 +297,16 @@ def _tier_inject(donate, quantized=False, tp=1):
     return fn
 
 
+@functools.lru_cache(maxsize=None)
+def _state_writer(donate):
+    """The jitted slot write of the recurrent layers' store."""
+    def write(ss, cs, states, tails, slot):
+        return (ss.at[:, slot].set(states.astype(ss.dtype)),
+                cs.at[:, slot].set(tails.astype(cs.dtype)))
+
+    return jax.jit(write, donate_argnums=(0, 1) if donate else ())
+
+
 def tier_compilations() -> int:
     """Total traces of the tier transfer programs — the spill/readmit
     half of the bounded-compile contract: stays at one per (geometry,
@@ -322,6 +337,18 @@ class PagedKVCache:
       heap; shared prefix entries are merely forgotten (their pins are
       released by the engine through ``PrefixCache.release``).
 
+    **Two kinds of cache, one manager.** A token's row in the pool exists
+    for the layers that attend over keys and values, and only for them
+    (``num_layers`` is THEIR count: a hybrid model's full-attention layers).
+    A layer with a recurrent state (``state_geometry``; a Gated DeltaNet
+    layer) caches, a sequence, a float32 state and its convolution's last
+    inputs: constant in the sequence's length, so it is not paged but
+    indexed by SLOT, in :attr:`state` ``(states [layers, num_slots, heads,
+    dk, dv] float32, tails [layers, num_slots, conv rows, channels])``.
+    Nothing here ever zeroes a slot's state: the programs give a span whose
+    first position is 0 a zero state, whatever the slot held.
+    ``bytes_per_token`` counts the pool, ``state_bytes_per_slot`` the store.
+
     The pool's device arrays are the single source of KV truth; the
     decode / suffix-prefill programs update them functionally and the
     engine adopts the result via :meth:`update`. ``num_kv_heads`` x
@@ -335,7 +362,8 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_slots, max_seq_len, num_kv_heads,
                  head_dim, dtype=jnp.float32, block_size=32, pool=None,
-                 prefix_cache=None, donate=None, kv_dtype=None):
+                 prefix_cache=None, donate=None, kv_dtype=None,
+                 state_geometry=None):
         from .block_manager import BlockManager
         bs = int(block_size)
         if bs < 1:
@@ -384,6 +412,34 @@ class PagedKVCache:
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
+        # the recurrent layers' store (class docstring): ``state_geometry``
+        # is ``(layers, heads, dk, dv, conv rows, channels)``
+        self.state = None
+        if state_geometry is not None:
+            ll, heads, dk, dv, rows, channels = (
+                int(n) for n in state_geometry)
+            self.state = (
+                jnp.zeros((ll, self.num_slots, heads, dk, dv), jnp.float32),
+                jnp.zeros((ll, self.num_slots, rows, channels), dtype))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """HBM bytes one slot's recurrent state and convolution tail hold
+        over all their layers, whatever the sequence's length (0 for a
+        model without such layers): the ``serving_state_bytes_per_slot``
+        gauge."""
+        if self.state is None:
+            return 0
+        return sum(a.size * np.dtype(a.dtype).itemsize
+                   for a in self.state) // self.num_slots
+
+    def write_state(self, slot, states, tails):
+        """Install what a whole-prompt prefill computed for ``slot``'s
+        recurrent layers: ``states [layers, heads, dk, dv]``, ``tails
+        [layers, conv rows, channels]`` (one compile-once scatter; the
+        slot is a runtime argument)."""
+        self.state = _state_writer(self._donate)(
+            *self.state, states, tails, np.int32(slot))
 
     # ------------------------------------------------------------- slots
     @property
@@ -573,6 +629,10 @@ class PagedKVCache:
             "capacity_kv": self.pool.num_blocks * kv_b,
             "capacity_scales": self.pool.num_blocks * sc_b,
             "per_token": self.bytes_per_token(),
+            # the recurrent layers' store: by slots, not by blocks
+            "used_state": (self.num_slots - self.num_free)
+            * self.state_bytes_per_slot,
+            "capacity_state": self.num_slots * self.state_bytes_per_slot,
         }
 
     # ------------------------------------------------------------ writes

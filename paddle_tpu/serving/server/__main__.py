@@ -11,8 +11,11 @@ widths, a routed FFN of 64 experts with 8 a token, depth cut to 8 of 16
 layers) and ``dsv2-8of60-ep8`` (DeepSeek-V2 widths: latent attention, a
 leading dense layer and 7 expert layers of which this chip holds one routing
 group, 20 of the router's 160 experts; vocabulary cut to an eighth);
-``olmoe-tiny`` and ``dsv2-tiny`` are their CPU-runnable twins. Weights are
-random, made from ``--seed``.
+``olmohybrid7b-16of32`` is Olmo-Hybrid-7B's widths with 16 of 32 layers
+(four periods of three Gated DeltaNet layers and one full-attention layer:
+a recurrent state by slot beside a KV pool of 4 layers);
+``olmoe-tiny``, ``dsv2-tiny`` and ``olmohybrid-tiny`` are their CPU-runnable
+twins. Weights are random, made from ``--seed``.
 Prompts are token-id arrays (the framework ships no tokenizer) — see
 README "Serving over HTTP" for curl examples.
 
@@ -30,7 +33,8 @@ import threading
 
 
 PRESETS = ("tiny", "350m", "llama7b-8of32", "olmoe-tiny",
-           "olmoe1b7b-8of16", "dsv2-tiny", "dsv2-8of60-ep8")
+           "olmoe1b7b-8of16", "dsv2-tiny", "dsv2-8of60-ep8",
+           "olmohybrid-tiny", "olmohybrid7b-16of32")
 
 
 def build_model(preset, decode_attention, seed):
@@ -52,6 +56,20 @@ def build_model(preset, decode_attention, seed):
             num_hidden_layers=8, n_routed_experts=20, router_experts=160,
             vocab_size=12800, max_position_embeddings=8192,
             dtype="bfloat16", decode_attention=decode_attention))
+    if preset.startswith("olmohybrid"):
+        from paddle_tpu.models.olmo_hybrid import (
+            OlmoHybridConfig, OlmoHybridForCausalLM, olmo_hybrid_tiny)
+        if preset == "olmohybrid-tiny":
+            return OlmoHybridForCausalLM(olmo_hybrid_tiny(
+                decode_attention=decode_attention))
+        # every width of OlmoHybridConfig()'s defaults, the published ones;
+        # the cut is benchmark/configs/olmo-hybrid-7b-serve-16L.json's: 16
+        # of 32 layers (four whole periods of three linear layers and a
+        # full one; 7.64 GiB of bf16 weights), 2304 tokens a slot
+        return OlmoHybridForCausalLM(OlmoHybridConfig(
+            num_hidden_layers=16, layer_types=OlmoHybridConfig().layer_types[
+                :16], max_position_embeddings=2304, dtype="bfloat16",
+            decode_attention=decode_attention))
     if preset.startswith("olmoe"):
         from paddle_tpu.models.olmoe import (OlmoeConfig, OlmoeForCausalLM,
                                              olmoe_tiny)
@@ -84,7 +102,7 @@ def build_model(preset, decode_attention, seed):
 
 
 def _model_name(preset):
-    return (preset if preset.startswith(("olmoe", "dsv2"))
+    return (preset if preset.startswith(("olmo", "dsv2"))
             else f"llama-{preset}")
 
 
@@ -148,9 +166,13 @@ def main(argv=None):
                          "dsv2-8of60-ep8: DeepSeek-V2 (latent attention, "
                          "shared + routed experts of which one routing "
                          "group is held), CPU-runnable / published widths "
-                         "with 8 of 60 layers, bf16 (the last four serve "
-                         "on the default path only: other engine switches "
-                         "raise)")
+                         "with 8 of 60 layers, bf16; olmohybrid-tiny / "
+                         "olmohybrid7b-16of32: Olmo-Hybrid-7B (Gated "
+                         "DeltaNet layers with a recurrent state by slot, "
+                         "every fourth layer full attention), CPU-runnable "
+                         "/ published widths with 16 of 32 layers, bf16 "
+                         "(the last six serve on the default path only: "
+                         "other engine switches raise)")
     ap.add_argument("--decode-attention", choices=("pallas", "jnp"),
                     default="pallas",
                     help="the Pallas attention kernels (compiled on a "

@@ -105,7 +105,8 @@ CARRIED_ENGINE_STATS = (
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
     "step_decode_tokens", "moe_pairs", "moe_experts_touched",
     "moe_max_expert_pairs", "moe_picks", "moe_layer_calls",
-    "steps_dispatched_ahead",
+    "steps_dispatched_ahead", "state_rows", "state_restarts_fault",
+    "state_restarts_preempt",
 ) + tuple("drains_" + r for r in DRAIN_REASONS)
 
 #: same carry for the prefix cache's own stats dict (a rebuild builds a
@@ -498,6 +499,31 @@ class ServingGateway:
                 r.counter(f"serving_moe_{stat}_total",
                           text + " Monotonic across engine rebuilds."
                           ).set_fn(lambda k="moe_" + stat: self._stat(k))
+        if self.engine.cache.state is not None:
+            # a model with recurrent layers only (a hybrid model's linear
+            # layers): their cache is a store by slot beside the KV pool
+            r.gauge("serving_state_bytes_per_slot",
+                    "HBM bytes one slot's recurrent states and "
+                    "convolution tails hold over all their layers, "
+                    "whatever the sequence's length."
+                    ).set_fn(lambda: self.engine.cache.state_bytes_per_slot)
+            r.counter("serving_state_rows_total",
+                      "Spans whose slot's recurrent state a program read "
+                      "and wrote (decode rows, prefill chunks, whole "
+                      "prompts), summed over programs; one layer's count. "
+                      "Monotonic across engine rebuilds."
+                      ).set_fn(lambda: self._stat("state_rows"))
+            restarts = r.counter(
+                "serving_state_restarts_total",
+                "Live sequences sent back to position 0 because their "
+                "recurrent state could not be replayed, by reason: fault "
+                "(a fence raised after programs had applied their tokens), "
+                "preempt (displaced; the state is recomputed, never "
+                "resumed). Monotonic across engine rebuilds.")
+            for reason in ("fault", "preempt"):
+                restarts.set_fn(
+                    lambda reason=reason: self._stat(
+                        "state_restarts_" + reason), reason=reason)
         r.gauge("serving_decode_compilations",
                 "Decode-program traces (compile-once contract: stays at "
                 "one per (num_slots, token_budget, n_steps)).").set_fn(

@@ -22,8 +22,8 @@ import pytest
 from jax.sharding import (NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
-from paddle_tpu.kernels import (flash_attention, moe_ffn, pallas_flash,
-                                pallas_mla_ragged_attention,
+from paddle_tpu.kernels import (flash_attention, gated_delta_rule, moe_ffn,
+                                pallas_flash, pallas_mla_ragged_attention,
                                 pallas_paged_decode, pallas_ragged_attention)
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.profiler.metrics import peak_flops_per_chip
@@ -55,7 +55,7 @@ def v5e_devices(monkeypatch):
     if devices is None:
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
     for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
-                pallas_mla_ragged_attention, moe_ffn):
+                pallas_mla_ragged_attention, moe_ffn, gated_delta_rule):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -219,6 +219,79 @@ class TestMosaicCompilesDeepseekV2:
             v5e((7, held, H, width)), v5e((7, held, width, H)),
             v5e((rows,), jnp.bool_), v5e((), jnp.int32))
         assert n == 3                    # gate, up, down over the held stack
+
+
+class TestMosaicCompilesOlmoHybrid:
+    """Olmo-Hybrid-7B's kernels at its published widths (30 linear heads of
+    96 x 192, a float32 state by slot; 30 full-attention heads of 128 over a
+    pool row of 3,840) and at the serving cell's shapes: 32 slots x 2304
+    tokens, 12 linear layers, a packed buffer of 32 + 512 rows."""
+    H, DK, DV, R, LL, T = 30, 96, 192, 32, 12, 544
+
+    def _store(self, v5e):
+        return v5e((self.LL, self.R, self.H, self.DK, self.DV), jnp.float32)
+
+    def test_the_decode_row_update_in_place(self, v5e):
+        f32 = jnp.float32
+
+        def update(q, k, v, g, b, st, live, fresh, layer):
+            return gated_delta_rule.gdn_recurrent_update(
+                q, k, v, g, b, st, layer=layer, live=live, fresh=fresh)
+        args = (v5e((self.R, self.H, self.DK), f32),
+                v5e((self.R, self.H, self.DK), f32),
+                v5e((self.R, self.H, self.DV), f32),
+                v5e((self.R, self.H), f32), v5e((self.R, self.H), f32),
+                self._store(v5e), v5e((self.R,), jnp.bool_),
+                v5e((self.R,), jnp.bool_), v5e((), jnp.int32))
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(update, donate_argnums=(5,)).lower(
+                *args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the store (over 1 GB) is aliased in and out: no layer of it (88
+        # MB) is copied for the call
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes > 2 ** 30
+        assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+    def test_the_chunked_scan_in_place(self, v5e):
+        f32 = jnp.float32
+
+        def scan(q, k, v, g, b, st, start, length, fresh, layer):
+            return gated_delta_rule.gdn_chunk_scan(
+                q, k, v, g, b, st, layer=layer, start=start, length=length,
+                fresh=fresh)
+        args = (v5e((self.T, self.H, self.DK), f32),
+                v5e((self.T, self.H, self.DK), f32),
+                v5e((self.T, self.H, self.DV)),
+                v5e((self.T, self.H), f32), v5e((self.T, self.H), f32),
+                self._store(v5e), v5e((self.R,), jnp.int32),
+                v5e((self.R,), jnp.int32), v5e((self.R,), jnp.bool_),
+                v5e((), jnp.int32))
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(scan, donate_argnums=(5,)).lower(
+                *args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes > 2 ** 30
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+    def test_ragged_attention_at_30_heads(self, v5e):
+        """The first head count that is no power of two, and the widest pool
+        row: the kernel tiles 4 tokens of 30 wide rows and needs no padding."""
+        i32, hd, mb = jnp.int32, 128, 72
+
+        def attend(q, pk, pv, tables, qs, ql, kl, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer)
+        pool = v5e((4, self.R * mb, 32, self.H * hd))
+        n = _mosaic_calls(
+            attend, v5e((self.T, self.H, hd)), pool, pool,
+            v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
+            v5e((self.R,), i32), v5e((), i32))
+        assert n == 1
+        assert pallas_ragged_attention.grid_params(
+            jnp.bfloat16, 32, self.H * hd, mb, self.H, self.T)["block_q"] \
+            % self.H == 0
 
 
 class TestUnifiedStepLeavesThePoolInPlace:
