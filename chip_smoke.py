@@ -12,7 +12,9 @@ vocab 32000, bf16) with the depth cut and random weights made from a seed:
            one chip's share of the experts, at DeepSeek-V2's; the
            absorbed-form latent attention kernel at DeepSeek-V2's; the
            gated delta rule's chunked scan and decode-row update at
-           Olmo-Hybrid-7B's 30 heads of 96 x 192),
+           Olmo-Hybrid-7B's 30 heads of 96 x 192; the three attention /
+           state kernels of the unified step also at each serving cell's
+           decode-only packed size, 8 / 24 / 32 rows and nothing behind),
            compiled by Mosaic and RUN against its jnp reference;
   serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
            answering cold, chunked, concurrent and streamed requests;
@@ -66,6 +68,17 @@ FULL = dict(
         # of 3,840; a chunk behind its first chunk beside decode rows
         "olmo-hybrid chunk 30/30/128": (30, 30, 128, 72, [
             (1, 700), (488, 1000), (1, 33), (0, 0), (1, 2280), (1, 1)])},
+    # the cells' decode-only steps, whose packed buffer is the slots alone
+    # (8 / 24 / 32 rows and nothing behind them): the chat cell's two live
+    # rows of eight, less than one query block; every row live in the others
+    ragged_decode_only={
+        "mistral 8 rows 32/8/128": (32, 8, 128, 128, [
+            (1, 2307), (0, 0), (0, 0), (1, 300), (0, 0), (0, 0), (0, 0),
+            (0, 0)], 8),
+        "olmoe 24 rows 16/16/128": (16, 16, 128, 64, [
+            (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)], 24),
+        "olmo-hybrid 32 rows 30/30/128": (30, 30, 128, 72, [
+            (1, 520 + 55 * i + (i * 37) % 29) for i in range(32)], 32)},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -76,7 +89,10 @@ FULL = dict(
     # / rope / value head widths) and its routed FFN with one chip's share
     # (hidden, router width, held experts, expert width, experts a token,
     # groups, groups a token), rows as above
-    mla=dict(widths=(128, 512, 128, 64, 128)),
+    # ... and its cell's decode-only step: 32 rows over 2 k - 8 k cached
+    # tokens, tables of 256 entries, nothing behind the rows
+    mla=dict(widths=(128, 512, 128, 64, 128), decode_only=(256, [
+        (1, 2048 + 190 * i + (i * 37) % 29) for i in range(32)])),
     moe_share=dict(widths=(5120, 160, 20, 1536, 6, 8, 3),
                    rows=[(544, 32), (256, 256)]),
     # Olmo-Hybrid-7B's linear layers: (heads, key width, value width) of
@@ -90,11 +106,18 @@ REHEARSAL = dict(
         "decode 16/16/32": (16, 16, 32, 8, [
             (1, 20 + 9 * i) for i in range(6)]),
         "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
+    ragged_decode_only={
+        "8 rows 4/2/32": (4, 2, 32, 8, [
+            (1, 150), (0, 0), (0, 0), (1, 33), (0, 0), (0, 0), (0, 0),
+            (0, 0)], 8),
+        "6 rows 6/6/32": (6, 6, 32, 8, [(1, 20 + 9 * i) for i in range(6)],
+                          6)},
     preset="tiny", slots=4, max_seq_len=128, prefill_chunk=32,
     vocab=256, medium_prompt=24, long_prompt=70,
     tp=2,                                   # llama_tiny has two kv heads
     moe=dict(widths=(64, 8, 32, 2), rows=[(36, 4), (16, 16)]),
-    mla=dict(widths=(4, 32, 16, 8, 16)),
+    mla=dict(widths=(4, 32, 16, 8, 16), decode_only=(8, [
+        (1, 40 + 30 * i) for i in range(6)])),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
     gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
     train=dict(layers=2, batch=4, seq=64, steps=4))
@@ -343,9 +366,13 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
               + _err_tail(name))
         mem = json.loads(_get(base + "/debug/profile?memory=1",
                               timeout=HTTP_TIMEOUT_S))["memory"]
-        step = [v for k, v in mem.items() if k.startswith("ragged[")]
-        check(len(step) == 1 and "error" not in step[0],
-              f"{name}: expected one unified step program: {mem}")
+        # the requests chunk and decode, so both packed sizes of the unified
+        # step were reached: the slots' rows alone, and with the chunk's room
+        steps = {k: v for k, v in mem.items() if k.startswith("ragged[")}
+        check(len(steps) == 2
+              and not any("error" in v for v in steps.values()),
+              f"{name}: expected the unified step at its two packed sizes: "
+              f"{mem}")
         check(all("pallas" in k for k in mem if k.startswith("ragged[")),
               f"{name}: step program is not the pallas one: {list(mem)}")
 
@@ -375,7 +402,7 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
             "compile_s": round(one("serving_compile_seconds_total"), 1),
             "cache_hits": int(one("serving_compile_cache_hits_total")),
             "cache_misses": int(one("serving_compile_cache_misses_total")),
-            "step_program": step[0], "programs": sorted(mem),
+            "step_programs": steps, "programs": sorted(mem),
             "peak_bytes_in_use": peak,
             "engine_restarts": health["engine_restarts"],
             "request_s": request_s, "tokens": tokens}
@@ -483,6 +510,25 @@ def _agree(name, got, want, tol, results):
           f"tolerance {tol}")
 
 
+def _scattered_tables(rng, kvlen, mb, bs):
+    """Block tables of ``mb`` entries for rows of ``kvlen`` cached tokens,
+    scattered over a pool of ``nb`` blocks of ``bs`` rows (``nb`` itself is
+    the unmapped sentinel), and ``live [nb, bs]``: the pool rows some row
+    may read. Returns ``(tables, live, nb)``."""
+    import numpy as np
+    nb = int(sum(-(-k // bs) for k in kvlen)) + 4
+    perm = rng.permutation(nb)
+    tables = np.full((len(kvlen), mb), nb, np.int32)
+    live = np.zeros((nb, bs), bool)
+    used = 0
+    for r, k in enumerate(kvlen):
+        for b in range(-(-k // bs)):
+            tables[r, b] = perm[used]
+            live[perm[used], :min(bs, k - b * bs)] = True
+            used += 1
+    return tables, live, nb
+
+
 def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12):
     """The ragged kernel's arguments for ``rows`` of (query span, kv length
     after this step; span 0 is a dead row) packed back to back with ``pad``
@@ -492,20 +538,10 @@ def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12):
     NaN."""
     import jax.numpy as jnp
     import numpy as np
-    R = len(rows)
     qlen = np.array([q for q, _ in rows], np.int32)
     kvlen = np.array([k for _, k in rows], np.int32)
-    nb = int(sum(-(-k // bs) for k in kvlen)) + 4
-    perm = rng.permutation(nb)
-    tables = np.full((R, mb), nb, np.int32)     # nb = unmapped sentinel
+    tables, live, nb = _scattered_tables(rng, kvlen, mb, bs)
     pool = rng.randn(2, nb, bs, nkv, hd).astype(np.float32)
-    live = np.zeros((nb, bs), bool)
-    used = 0
-    for r, k in enumerate(kvlen):
-        for b in range(-(-k // bs)):
-            tables[r, b] = perm[used]
-            live[perm[used], :min(bs, k - b * bs)] = True
-            used += 1
     pool[:, ~live] = np.nan
     qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
     q = rng.randn(int(qlen.sum()) + pad, nh, hd).astype(np.float32)
@@ -562,6 +598,14 @@ def phase_kernels(rehearse):
     for tag, (nh, nkv, hd, mb, rows) in size["ragged_cells"].items():
         ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
             np.random.RandomState(len(rows)), rows, nh, nkv, hd, mb=mb))
+    # ---- ... and at their decode-only steps, whose buffer holds the slots'
+    # rows and no more (the unified step's smaller packed size) -----------
+    for tag, (nh, nkv, hd, mb, rows, packed) in \
+            size["ragged_decode_only"].items():
+        live = sum(q for q, _ in rows)
+        ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
+            np.random.RandomState(packed), rows, nh, nkv, hd, mb=mb,
+            pad=packed - live))
 
     for nh, nkv, hd in size["geometries"]:
         tag = f"{nh}/{nkv}/{hd}"
@@ -654,34 +698,6 @@ def phase_kernels(rehearse):
         latent_row_width, mla_ragged_attention_pallas,
         mla_ragged_attention_reference)
     nh, rank, nope, rope, vd = size["mla"]["widths"]
-    rng = np.random.RandomState(512)
-    nb, bs, mb = 48, 32, 24
-    # decode rows ending at a block start, mid-block and after more than one
-    # group of pages; a chunk with a cached prefix; a dead row
-    rows = [(1, 1), (1, 37), (1, 64), (1, 700), (40, 77), (0, 0)]
-    R, width = len(rows), latent_row_width(rank, rope)
-    perm = rng.permutation(nb)
-    tables = np.full((R, mb), nb, np.int32)
-    pool = rng.randn(1, nb, bs, width).astype(np.float32)
-    pool[..., rank + rope:] = 0.0
-    live = np.zeros((nb, bs), bool)
-    used = 0
-    for r, (_, kvlen) in enumerate(rows):
-        for b in range(-(-kvlen // bs)):
-            tables[r, b] = perm[used]
-            live[perm[used], :min(bs, kvlen - b * bs)] = True
-            used += 1
-    pool[0][~live] = np.nan
-    qlen = np.array([q for q, _ in rows], np.int32)
-    kvlen = np.array([k for _, k in rows], np.int32)
-    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
-    T = int(qlen.sum()) + 12
-    q_nope = jnp.asarray(rng.randn(T, nh, nope).astype(np.float32), bf16)
-    q_pe = jnp.asarray(rng.randn(T, nh, rope).astype(np.float32), bf16)
-    w_kvb = jnp.asarray(rng.randn(rank, nh * (nope + vd)).astype(np.float32)
-                        * rank ** -0.5, bf16)
-    span = (jnp.asarray(pool, bf16), jnp.asarray(tables),
-            jnp.asarray(qstart), jnp.asarray(qlen), jnp.asarray(kvlen))
     scale = (nope + rope) ** -0.5
 
     def absorbed(q_nope, q_pe, w_kvb, *span):
@@ -691,13 +707,50 @@ def phase_kernels(rehearse):
             scale=scale)
         return jnp.einsum("thr,rhd->thd", o_lat, w[..., nope:])
 
-    got = jax.jit(absorbed)(q_nope, q_pe, w_kvb, *span)
-    want = reference(
-        lambda *a: mla_ragged_attention_reference(*a, scale=scale),
-        q_nope, q_pe, w_kvb, *span)
-    _agree("mla_ragged", got, want, TOL_FWD, errors)
-    check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
-          "mla_ragged: rows outside every span are not exact zeros")
+    def latent_agrees(name, rows, mb, pad, bs=32):
+        """``rows`` of (query span, kv length after this step) packed back
+        to back with ``pad`` rows in no span behind them, over a latent pool
+        that is NaN wherever no live row may read; the expanded oracle runs
+        span by span (every token's whole table at once is tens of GB at
+        the cell's lengths)."""
+        rng = np.random.RandomState(512 + len(rows))
+        qlen = np.array([q for q, _ in rows], np.int32)
+        kvlen = np.array([k for _, k in rows], np.int32)
+        tables, live, nb = _scattered_tables(rng, kvlen, mb, bs)
+        pool = rng.randn(1, nb, bs, latent_row_width(rank, rope)).astype(
+            np.float32)
+        pool[..., rank + rope:] = 0.0
+        pool[0][~live] = np.nan
+        qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+        T = int(qlen.sum()) + pad
+        q_nope = jnp.asarray(rng.randn(T, nh, nope).astype(np.float32), bf16)
+        q_pe = jnp.asarray(rng.randn(T, nh, rope).astype(np.float32), bf16)
+        w_kvb = jnp.asarray(
+            rng.randn(rank, nh * (nope + vd)).astype(np.float32)
+            * rank ** -0.5, bf16)
+        pool, tables = jnp.asarray(pool, bf16), jnp.asarray(tables)
+        got = jax.jit(absorbed)(
+            q_nope, q_pe, w_kvb, pool, tables, jnp.asarray(qstart),
+            jnp.asarray(qlen), jnp.asarray(kvlen))
+        want = np.zeros(got.shape, np.float32)
+        one = jnp.zeros(1, jnp.int32)
+        for r, (at, n, end) in enumerate(zip(qstart, qlen, kvlen)):
+            if n:
+                want[at:at + n] = reference(
+                    lambda *a: mla_ragged_attention_reference(
+                        *a, scale=scale),
+                    q_nope[at:at + n], q_pe[at:at + n], w_kvb, pool,
+                    tables[r:r + 1], one, one + int(n), one + int(end))
+        _agree(name, got, want, TOL_FWD, errors)
+        check(not np.asarray(got[int(qlen.sum()):], np.float32).any(),
+              f"{name}: rows outside every span are not exact zeros")
+
+    # decode rows ending at a block start, mid-block and after more than one
+    # group of pages; a chunk with a cached prefix; a dead row
+    latent_agrees("mla_ragged", [(1, 1), (1, 37), (1, 64), (1, 700),
+                                 (40, 77), (0, 0)], mb=24, pad=12)
+    mb, rows = size["mla"]["decode_only"]
+    latent_agrees(f"mla_ragged {len(rows)} rows", rows, mb=mb, pad=0)
 
     # ---- the gated delta rule: both kernels against the recurrence ------
     from paddle_tpu.kernels import gated_delta_rule as gdr
@@ -728,6 +781,28 @@ def phase_kernels(rehearse):
     _agree("gdn_recurrent_update o", np.asarray(got[0])[live],
            np.asarray(want[0])[live], TOL_GDN, errors)
     _agree("gdn_recurrent_update state", got[1], want[1], TOL_GDN, errors)
+    # the decode-only step: a buffer of the slots' rows alone. Every slot has
+    # a row, the fresh one over a stored state that is NaN (it starts from
+    # zero whatever its slot held), and the chunk scan, which finds no span
+    # over one token there, hands the store back as it is
+    live = np.ones(R, bool)
+    poisoned = store.at[:, 2].set(jnp.nan)
+    got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
+        *a, layer=1, live=live, fresh=fresh))(
+            q[:R], k[:R], v[:R], g[:R], beta[:R], poisoned)
+    want = reference(lambda *a: gdr.gdn_reference(
+        *a, layer=1, seg=np.arange(R), first=fresh),
+        q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+    _agree(f"gdn_recurrent_update {R} rows o", got[0], want[0], TOL_GDN,
+           errors)
+    _agree(f"gdn_recurrent_update {R} rows state", got[1][1], want[1][1],
+           TOL_GDN, errors)
+    idle = jax.jit(lambda *a: gdr.gdn_chunk_scan(
+        *a, layer=0, start=np.arange(R, dtype=np.int32),
+        length=np.zeros(R, np.int32), fresh=fresh))(
+            q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+    check(np.array_equal(np.asarray(idle[1]), np.asarray(store)),
+          f"gdn_chunk_scan {R} rows, no span: the store changed")
     # a chunk step: decode rows first (not the scan's), then a chunk that
     # continues its slot's state and a fresh one that starts in the block
     # where the first ends

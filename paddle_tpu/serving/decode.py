@@ -11,10 +11,12 @@ three more behind switches, all over one block-table paged KV pool:
 - the unified step (``build_ragged_step_fn``): every running slot's
   span-1 decode row and every planned prefill chunk's span-n row in
   ONE packed token buffer whose shape depends only on ``(num_slots,
-  token_budget)``, then ``n_steps - 1`` fused single-token ticks. Block
+  packed size)`` (the engine has two sizes: the slots' rows for a step
+  with no chunk, the token budget for a step with one), then
+  ``n_steps - 1`` fused single-token ticks. Block
   tables, span metadata and per-slot sampling knobs (temperature /
   top-k / PRNG key) are runtime ARRAYS, not trace constants, so one
-  compilation serves every request mix.
+  compilation a packed size serves every request mix.
 - the paged suffix prefill (prefix-cache hits prefill their uncovered
   suffix), the multi-tick step (``decode_ticks > 1``) and the
   speculative verify (``spec_decode``).
@@ -1604,7 +1606,10 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     through the same block tables (README "Unified ragged attention").
 
     Packed layout (host-built, all runtime arrays — shapes depend only
-    on ``(num_slots, token_budget)``):
+    on ``(num_slots, T)``, the packed size ``T`` being whatever the
+    caller's buffer holds: the engine packs a step with no chunk at
+    ``num_slots`` rows rounded up to 8 and a step with one at its token
+    budget, one body specialised on the two shapes):
 
     ids:     [T] int32 — packed input token ids (decode rows carry the
              slot's last sampled token; chunk rows carry their prompt
@@ -1723,9 +1728,13 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          collective_overlap=False, moe=None, mla=None,
                          return_picks=False, gdn=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
-    depend only on ``(num_slots, token_budget)`` plus the fused
-    ``n_steps`` — one compilation per step size serves every span mix,
-    the same compile-once contract as the decode program it replaces.
+    depend only on ``(num_slots, packed size)`` plus the fused
+    ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
+    every span mix. The engine calls it at two packed sizes (the slots'
+    rows alone for a step with no prefill chunk, the token budget for a
+    step with one) and ``jax.jit`` specialises the one body on each;
+    everything a step hands the next is ``[num_slots, ...]`` or
+    pool-shaped, so the two executables follow each other freely.
     ``tp > 1`` wraps the WHOLE step in shard_map over the heads-sharded
     mesh (README "Tensor-parallel serving"): attention and the QKV/MLP
     projections run fully sharded, the paged pool partitions per shard

@@ -13,9 +13,13 @@ join and leave the batch between any two steps, so short requests never
 wait for long ones and the batch never restarts.
 
 Compile discipline (the perf contract): the step program's shapes depend
-only on ``(num_slots, token_budget)``; block tables, span metadata,
+only on ``(num_slots, packed size)``, and the unified step has two packed
+sizes, chosen from what the step's plan holds: ``num_slots`` rounded up to
+8 when it carries no prefill chunk, ``token_budget = num_slots +
+prefill_chunk`` when it carries one. Block tables, span metadata,
 per-request sampling knobs and per-slot ragged lengths are runtime
-arrays. One compilation serves every request mix —
+arrays. One compilation per packed size reached serves every request mix,
+and neither is built before a step needs it —
 :meth:`decode_compilations` counts traces so tests can pin this. Prefill
 compiles once per (group, prompt-length) bucket.
 
@@ -67,19 +71,27 @@ DRAIN_REASONS = ("idle", "cancel", "evict", "preempt", "pool", "deadline",
                  "snapshot", "fault")
 
 
+def program_stat(rows):
+    """The ``stats`` key that counts the step programs fenced at a packed
+    size of ``rows`` (``serving_step_programs_total{rows}``; an engine has
+    one key for each of its :attr:`~ContinuousBatchingEngine.step_rows`)."""
+    return "step_programs_%d" % rows
+
+
 class _InFlight:
     """One dispatched unified step whose tokens the host has not read."""
     __slots__ = ("toks", "tok_fin", "moe", "keys_in", "n", "rows",
-                 "chunks", "ahead", "packed", "t_base")
+                 "chunks", "ahead", "packed", "size", "t_base")
 
     def __init__(self, toks, tok_fin, moe, keys_in, n, rows, chunks,
-                 packed, t_base):
+                 packed, size, t_base):
         self.toks, self.tok_fin, self.moe = toks, tok_fin, moe
         self.keys_in = keys_in      # the key state this step started from
         self.n = n                  # ticks it fused
         self.rows = rows            # [(slot, seq)]: its decode rows
         self.chunks = chunks        # [(slot, seq, offset, tokens, final)]
         self.packed = packed        # tokens in its packed buffer
+        self.size = size            # rows of that buffer: the program's size
         # clock reading its cost counts from: the step() that dispatched
         # it into an empty pipeline; None when it went behind another
         # program (its cost then runs from that program's fence)
@@ -101,8 +113,8 @@ class ContinuousBatchingEngine:
     README "Paged attention"): the :class:`~.block_manager.BlockManager`
     pool IS the cache, every live slot addresses it through a per-slot
     block table (a runtime argument — ``decode_compilations()`` stays at
-    1) and decode growth appends blocks lazily. ``prefix_block_size`` is
-    the KV block size; the pool holds ``num_slots *
+    one per packed size) and decode growth appends blocks lazily.
+    ``prefix_block_size`` is the KV block size; the pool holds ``num_slots *
     ceil(max_seq_len / block_size)`` live blocks plus the
     ``prefix_blocks`` trie budget.
 
@@ -110,8 +122,11 @@ class ContinuousBatchingEngine:
     (``decode.build_ragged_step_fn`` over the ragged paged attention
     kernel, README "Unified ragged attention"): each slot contributes
     one variable-length query span (decode = span 1, prefill chunk =
-    span n) to a packed token buffer whose shape depends only on
-    ``(num_slots, token_budget)``. ``decode_chunk`` bounds how many
+    span n) to a packed token buffer of one of two sizes: ``num_slots``
+    rows (rounded up to 8) when the step's plan holds no chunk,
+    ``token_budget = num_slots + prefill_chunk`` when it holds one — the
+    same body, specialised on the two shapes, each compiled when a step
+    first needs it. ``decode_chunk`` bounds how many
     single-token ticks a pure-decode step fuses behind tick 0.
 
     ``prefill_chunk`` bounds TTFT under mixed traffic (README "Chunked
@@ -123,13 +138,17 @@ class ContinuousBatchingEngine:
     rounded up to a block multiple); installed prefix-cache hits count
     toward the resume offset; cancellation or deadline expiry mid-chunk
     frees (or donates) the partial block chain. ``None``/``0`` disables
-    chunking and sizes the packed buffer to ``num_slots``. The per-step
+    chunking: every step then packs at the decode-only size. The per-step
     chunk grant is adapted at runtime from a measured tokens-per-second
     EWMA (the ``headroom`` stat): the engine grants roughly
     ``headroom_mult`` decode-steps' worth of tokens per step —
     ``prefill_chunk`` remains the hard cap — so chunk work throttles
-    itself under decode load. ``headroom_mult=None`` pins the grant at
-    the cap. ``step_clock`` injects the timebase the EWMA reads (tests
+    itself under decode load, where the decode baseline is measured on
+    the program that carries the chunks (the multi-tick and speculative
+    steps; the unified step runs its chunk-free steps at the smaller
+    size, feeds no baseline and grants the cap: ``_prefill_budget``).
+    ``headroom_mult=None`` pins the grant at the cap. ``step_clock``
+    injects the timebase the EWMA reads (tests
     pass a virtual clock; default ``time.perf_counter``).
 
     ``prefix_cache=True`` enables automatic prefix caching
@@ -190,11 +209,13 @@ class ContinuousBatchingEngine:
     pure-decode tail to fuse). ``decode_chunk`` fusion is superseded on
     this path — the multi-tick program subsumes it with masking.
 
-    Substrate note: the packed buffer is a fixed ``num_slots +
-    prefill_chunk`` tokens, which the TPU Pallas kernel prices at the
-    LIVE spans only (its grid is a work list of the spans' query blocks,
-    its KV walk ends at each row's length) but the CPU
-    ``decode_attention="jnp"`` oracle computes densely.
+    Substrate note: a chunk-carrying step's packed buffer is a fixed
+    ``num_slots + prefill_chunk`` tokens, which the TPU Pallas kernel
+    prices at the LIVE spans only (its grid is a work list of the spans'
+    query blocks, its KV walk ends at each row's length) but every dense
+    matmul of every layer, and the CPU ``decode_attention="jnp"``
+    oracle, compute in full — which is why a step with no chunk does not
+    run at that size.
     """
 
     def __init__(self, model, num_slots=8, max_seq_len=None, decode_chunk=8,
@@ -486,6 +507,12 @@ class ContinuousBatchingEngine:
         chunkable = self._chunk is not None and self._chunk < self.max_seq_len
         self._token_budget = self.num_slots + (self._chunk if chunkable
                                                else 0)
+        # the unified step packs at one of two sizes, chosen from what the
+        # step's plan holds (``_unified_step``): the token budget when the
+        # plan has a chunk row, else the decode rows alone, a whole sublane
+        # group of 8, so that a decode-only step multiplies no chunk rows
+        # nobody reads
+        self._decode_rows = -(-self.num_slots // 8) * 8
         # speculative decode (rollback truncates the block tail; README
         # "Speculative decoding"): every step becomes ONE draft-extended
         # verify launch whose packed buffer shares its
@@ -524,6 +551,18 @@ class ContinuousBatchingEngine:
                 f"decode_ticks must be >= 1, got {int(decode_ticks)}")
         self._decode_ticks = int(decode_ticks)
         self._mtick = self._decode_ticks > 1
+        # the packed size of the program that carries chunks, and every
+        # size this engine's step programs can have, ascending
+        # (``serving_step_programs_total{rows}``): the speculative and the
+        # multi-tick step keep one
+        self._chunk_rows = self._spec_budget if self._spec \
+            else self._token_budget
+        if self._spec or self._mtick:
+            self._step_rows = (self._chunk_rows,)
+        else:
+            self._step_rows = tuple(sorted(
+                {self._decode_rows}
+                | ({self._token_budget} if chunkable else set())))
         if self._mtick and self._spec:
             raise ValueError(
                 "decode_ticks > 1 is incompatible with spec_decode: a "
@@ -635,6 +674,7 @@ class ContinuousBatchingEngine:
                       "prefill_chunks": 0, "chunk_tokens": 0,
                       "step_prefill_tokens": 0, "step_decode_tokens": 0,
                       "unified_steps": 0, "steps_dispatched_ahead": 0,
+                      **{program_stat(r): 0 for r in self._step_rows},
                       **{"drains_" + r: 0 for r in DRAIN_REASONS},
                       "mtick_syncs": 0, "mtick_ticks": 0,
                       "mtick_pure_syncs": 0,
@@ -863,14 +903,17 @@ class ContinuousBatchingEngine:
                 **self._q_consts())
         return self._wrap_prog(key, self._jit[key], host_out=(2,))
 
-    def _ragged_fn(self, n_steps):
+    def _ragged_fn(self, n_steps, rows):
         # the full packed-buffer geometry — num_slots AND token budget,
         # not their sum alone — is part of the key: engines with
         # different geometry sharing one jit_cache must not pool their
         # shape-keyed traces under one fn (decode_compilations counts
         # only THIS engine's geometry, and e.g. slots=8/chunk=64 vs
-        # slots=16/chunk=56 share a token budget of 72)
-        key = ("ragged", self.num_slots, self._token_budget,
+        # slots=16/chunk=56 share a token budget of 72). ``rows``, the
+        # packed size this step runs at (one of the engine's two), joins
+        # it: one body, one program a size, each counted and costed
+        # (``_wrap_prog``) under its own name
+        key = ("ragged", self.num_slots, self._token_budget, int(rows),
                int(n_steps), self.config.decode_attention) \
             + self._kvtag + self._wtag + self._atag + self._tptag \
             + self._fktag
@@ -1050,10 +1093,22 @@ class ContinuousBatchingEngine:
         form."""
         return self._chunk or 0
 
+    @property
+    def step_rows(self) -> tuple:
+        """The packed sizes this engine's step programs can have,
+        ascending: the unified step's decode-only size (``num_slots``
+        rounded up to 8) and, where a prompt can be chunked, its token
+        budget ``num_slots + prefill_chunk``; the multi-tick and the
+        speculative step have one. The label values of
+        ``serving_step_programs_total{rows}``."""
+        return self._step_rows
+
     def decode_compilations(self) -> int:
         """Total decode-program traces OF THIS ENGINE'S KIND (the
-        compiles-once assertion hook): stays at one per ``(num_slots,
-        token_budget, n_steps)`` no matter how request sampling params /
+        compiles-once assertion hook): stays at one per ``(packed size
+        reached, n_steps)`` — at most two sizes (:attr:`step_rows`), a
+        size counted from the first step that ran it — no matter how
+        request sampling params /
         token budgets / block tables / span mixes vary. Engines of
         another geometry or variant sharing one jit_cache count only
         their own programs. On the speculative
@@ -1101,7 +1156,7 @@ class ContinuousBatchingEngine:
                    if key[0] == "ragged"
                    and key[1] == self.num_slots
                    and key[2] == self._token_budget
-                   and key[5:] == tags)
+                   and key[6:] == tags)
 
     def prefill_compilations(self) -> int:
         """Prefill-side traces, cold + suffix: bounded by the pow2
@@ -1639,7 +1694,7 @@ class ContinuousBatchingEngine:
                         else self._multitick_step
                     tokens, chunk_tokens = variant(finished)
                     self._count_step(self._clock() - t0, tokens,
-                                     chunk_tokens)
+                                     chunk_tokens, self._chunk_rows)
                 else:
                     self._unified_step(finished, t0)
                 break
@@ -1906,27 +1961,36 @@ class ContinuousBatchingEngine:
         self.scheduler.submit(seq)
         return True
 
-    def _count_step(self, dt, tokens, chunk_tokens):
-        """Book one FENCED step program: its tokens by kind
+    def _count_step(self, dt, tokens, chunk_tokens, rows):
+        """Book one FENCED step program: its packed size ``rows``
+        (``serving_step_programs_total{rows}``), its tokens by kind
         (``serving_step_tokens_total{kind}``: chunk tokens are prefill,
         the rest are decode rows and their fused ticks) and what it cost
         (:meth:`_record_step`). The synchronous variants pass the call's
         own duration; the pipelined unified step passes the interval from
         the previous fence to this one, which is what a step costs while
         the host's share of it overlaps the chip's."""
+        self.stats[program_stat(rows)] += tokens > 0
         self.stats["step_prefill_tokens"] += chunk_tokens
         self.stats["step_decode_tokens"] += tokens - chunk_tokens
         self._fenced = (tokens, chunk_tokens)
-        self._record_step(dt, tokens, chunk_tokens > 0)
+        self._record_step(dt, tokens, chunk_tokens > 0,
+                          rows == self._chunk_rows)
         if self.on_step is not None:
             self.on_step(float(dt))
 
-    def _record_step(self, dt, tokens, had_chunks):
+    def _record_step(self, dt, tokens, had_chunks, carries_chunks):
         """Feed the step's measured duration + processed tokens into
         the stats surface (``serving_step_duration_seconds`` /
         ``serving_step_tokens`` on /metrics read exactly these) and
         into the headroom EWMAs the adaptive chunk budget derives
-        from."""
+        from. ``carries_chunks``: whether the step ran the program that
+        carries chunks. The decode baseline is "what a chunk step would
+        cost without its chunk", so only that program may feed it: the
+        unified step's decode-only program is a smaller one, whose time
+        says nothing of the large program's floor (a grant reckoned
+        across the two has no fixed point and decays to one token a
+        step)."""
         self.stats["last_step_duration_s"] = float(dt)
         self.stats["last_step_tokens"] = int(tokens)
         if tokens <= 0 or dt <= 0:
@@ -1941,7 +2005,7 @@ class ContinuousBatchingEngine:
             self._tps_ewma = tps if self._tps_ewma is None \
                 else (1 - a) * self._tps_ewma + a * tps
             self.stats["headroom_tps"] = self._tps_ewma
-        else:
+        elif carries_chunks:
             self._dt_decode_ewma = dt if self._dt_decode_ewma is None \
                 else (1 - a) * self._dt_decode_ewma + a * dt
 
@@ -1958,7 +2022,14 @@ class ContinuousBatchingEngine:
         regime the decode baseline is the last chunk-free step
         measured (decode-only steps are its only feed), so a backlog
         that never leaves the engine a chunk-free step keeps the fixed
-        cap rather than inventing a baseline. Sub-block grants are
+        cap rather than inventing a baseline. The baseline is fed only
+        by chunk-free steps of the program that carries chunks
+        (:meth:`_record_step`): the multi-tick and the speculative step
+        have one program and feed it; the unified step runs its
+        chunk-free steps at the smaller packed size, so there the
+        baseline stays unfed and the grant is the cap (with two sizes a
+        smaller grant would save attention time only: the large
+        program's dense cost is fixed). Sub-block grants are
         not wasted: the scheduler carries them to the next plan
         (``FIFOScheduler.prefill_plan``)."""
         cap = self._chunk
@@ -2013,7 +2084,13 @@ class ContinuousBatchingEngine:
         n = self.scheduler.choose_num_steps(
             [c[1] for c in cands], budgets=[c[4] for c in cands]) \
             if cands else 1
-        R, T = self.num_slots, self._token_budget
+        # the packed size follows the plan: a step that carries no chunk
+        # runs the program at the decode rows alone. Everything handed from
+        # program to program (``tok_fin``, the keys, the pool, the state
+        # store) is by slot or pool-shaped, so either size may follow, or
+        # be dispatched behind, the other
+        R = self.num_slots
+        T = self._token_budget if plan else self._decode_rows
         ids = np.zeros(T, np.int32)
         seg = np.full(T, R, np.int32)       # sentinel: dead packed rows
         pos = np.zeros(T, np.int32)
@@ -2050,11 +2127,12 @@ class ContinuousBatchingEngine:
                 qstart, qlen, kvlen, T, len(rows), n * len(rows),
                 cursor - len(rows))
             args["ahead"] = int(prev is not None)
+            args["packed_rows"] = T
             sp = tr.span("dispatch", args=args)
         if co is not None:
             co.set_phase("launch")
         keys_in = self._keys
-        npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n)(
+        npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n, T)(
             self._params, *self.cache.kv_args(),
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             dec_mask, keys_in, temps, topks,
@@ -2074,7 +2152,7 @@ class ContinuousBatchingEngine:
             if final:       # no further chunk; its decode row comes next
                 self.scheduler.leave_prefill(seq)
         self._inflight = _InFlight(
-            toks, tok_fin, moe, keys_in, n, rows, chunks, cursor,
+            toks, tok_fin, moe, keys_in, n, rows, chunks, cursor, T,
             None if prev is not None else t0)
         if self._routing is not None:
             # which rows of this program's picks are whose, at which
@@ -2087,10 +2165,10 @@ class ContinuousBatchingEngine:
         self.stats["steps_dispatched_ahead"] += prev is not None
         if co is not None:
             # sharded launch: tick 0 all-reduces the PADDED packed
-            # buffer (the device computes full shapes), each fused tail
-            # tick the per-slot row block — exact, shape-derived
-            self._record_collectives(
-                co, [(self._token_budget, 1), (self.num_slots, n - 1)])
+            # buffer (the device computes full shapes, at the size this
+            # step ran), each fused tail tick the per-slot row block —
+            # exact, shape-derived
+            self._record_collectives(co, [(T, 1), (self.num_slots, n - 1)])
         if tr is not None:
             sp.end()
         if prev is not None:
@@ -2222,7 +2300,7 @@ class ContinuousBatchingEngine:
         if tr is not None:
             sp.end({"emitted": emitted})
         self._count_step(now - base, rec.packed + (n - 1) * len(rows),
-                         rec.packed - len(rows))
+                         rec.packed - len(rows), rec.size)
 
     def _drain(self, reason, finished=None):
         """Fence and accept the program in flight, if any: what every

@@ -236,7 +236,11 @@ def main(argv=None):
                     help="adaptive chunk budget: grant ~this many "
                          "decode-steps' worth of measured throughput to "
                          "prefill chunks per step (0 pins the fixed "
-                         "prefill-chunk cap)")
+                         "prefill-chunk cap). The decode baseline is "
+                         "measured on the program that carries chunks: "
+                         "the default step runs chunk-free steps at a "
+                         "smaller packed size and grants the cap; "
+                         "--decode-ticks > 1 and --spec-decode adapt")
     ap.add_argument("--decode-ticks", type=int, default=1,
                     help="multi-tick decode: fuse up to this many "
                          "on-device decode "

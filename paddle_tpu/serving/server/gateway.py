@@ -82,7 +82,7 @@ from ...profiler.metrics import (QUEUE_WAIT_BUCKETS, SPEC_ACCEPT_BUCKETS,
                                  MetricsRegistry)
 from ...profiler.tracing import TID_GATEWAY, SpanTracer
 from ...utils.log import get_logger
-from ..engine import DRAIN_REASONS
+from ..engine import DRAIN_REASONS, program_stat
 from ..faults import TransientFault
 
 #: engine ``stats`` counters whose /metrics series must stay monotonic
@@ -283,7 +283,11 @@ class ServingGateway:
         # triple swaps in ONE attribute store: a scrape mid-rebuild
         # must never pair the new base with the old engine's stats
         # (double count, then a backwards step at the engine swap).
-        self._counter_state = (dict.fromkeys(CARRIED_ENGINE_STATS, 0),
+        # the carried counters an engine names itself: its step programs
+        # by packed size (a rebuilt engine has the same sizes)
+        self._carried_stats = CARRIED_ENGINE_STATS + tuple(
+            program_stat(r) for r in engine.step_rows)
+        self._counter_state = (dict.fromkeys(self._carried_stats, 0),
                                dict.fromkeys(CARRIED_PREFIX_STATS, 0),
                                engine)
         self._last_step_done = self._clock()
@@ -467,6 +471,17 @@ class ServingGateway:
             "flight (its tokens not yet read by the host). Monotonic "
             "across engine rebuilds."
         ).set_fn(lambda: self._stat("steps_dispatched_ahead"))
+        programs = r.counter(
+            "serving_step_programs_total",
+            "Step programs fenced, by the rows of their packed buffer: a "
+            "unified step with no prefill chunk runs at num_slots rows "
+            "(rounded up to 8), one with a chunk at num_slots + "
+            "prefill_chunk. On the default step the sizes sum to "
+            "serving_step_duration_seconds_count. "
+            "Monotonic across engine rebuilds.")
+        for rows in self.engine.step_rows:
+            programs.set_fn(lambda k=program_stat(rows): self._stat(k),
+                            rows=str(rows))
         drains = r.counter(
             "serving_pipeline_drains_total",
             "Times the program in flight was fenced and accepted with "
@@ -526,7 +541,8 @@ class ServingGateway:
                         "state_restarts_" + reason), reason=reason)
         r.gauge("serving_decode_compilations",
                 "Decode-program traces (compile-once contract: stays at "
-                "one per (num_slots, token_budget, n_steps)).").set_fn(
+                "one per (packed size reached, n_steps), at most two "
+                "sizes).").set_fn(
             self.engine.decode_compilations)
         r.counter("serving_prefill_chunks_total",
                   "Chunked-prefill device chunks run (one per sequence "
@@ -1419,7 +1435,7 @@ class ServingGateway:
         live, queued = self._snapshot_live(old)     # drains: counts move
         base, pc_base, _ = self._counter_state
         new_base = {k: base[k] + old.stats[k]
-                    for k in CARRIED_ENGINE_STATS}
+                    for k in self._carried_stats}
         new = self.engine_factory()
         new.on_token = self._on_token
         new.on_finish = self._on_finish

@@ -558,7 +558,9 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
     through ONE unified ragged program per step, with the per-step chunk grant
     adapted from the measured throughput EWMA scaled by
     ``headroom_mult`` (README "Unified ragged attention";
-    ``headroom_mult=None`` pins fixed-cap pacing) — the
+    ``headroom_mult=None`` pins fixed-cap pacing; the default step
+    runs its chunk-free steps at a smaller packed size, which feeds no
+    decode baseline, so there the grant is the cap) — the
     ``serving_step_duration_seconds`` histogram,
     ``serving_step_tokens`` and ``serving_prefill_headroom_tokens``
     gauges on ``/metrics`` watch exactly the signals the budget reads.

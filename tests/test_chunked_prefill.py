@@ -11,7 +11,8 @@ The load-bearing properties:
   PRNG), so the key walk is exactly the one-shot prefill's.
 - **Interleaving**: decode slots keep emitting a token on every step a
   chunk runs — the TTFT win chunking exists for.
-- **Compile discipline**: ``decode_compilations() == 1`` and a CLOSED
+- **Compile discipline**: ``decode_compilations() == 2`` (one program a
+  packed size: chunk-carrying steps and decode-only steps) and a CLOSED
   chunk-prefill compile set (full chunks share the ``prefill_chunk``
   bucket; remainders ride the pow2 grid) under varied prompt lengths
   and a mixed hit/miss/cancel/divergence matrix.
@@ -88,7 +89,8 @@ class TestTransparency:
     def test_chunked_equals_unchunked_greedy_and_sampled(self, model):
         """The acceptance pin: varied prompt lengths (sub-chunk,
         multi-chunk, non-block-multiple), greedy and seeded-sampled,
-        stream the exact unchunked tokens, with one decode program."""
+        stream the exact unchunked tokens, with one decode program a
+        packed size (chunk-carrying steps, decode-only steps)."""
         reqs = [_req(1, n=40), _req(2, n=61), _req(3, n=12),
                 _req(4, n=53, temperature=0.9, top_k=5, seed=123),
                 _req(5, n=33, temperature=0.7, top_k=3, seed=9)]
@@ -96,7 +98,7 @@ class TestTransparency:
         got, eng = _run(model, reqs)
         assert got == want
         assert eng.stats["prefill_chunks"] >= 8  # 40,61,53,33 all chunked
-        assert eng.decode_compilations() == 1
+        assert eng.decode_compilations() == 2
 
     def test_chunked_equals_unchunked_with_prefix_hits(self, model):
         """Hit admissions: the installed chain counts toward the resume
@@ -167,7 +169,8 @@ class TestCompileDiscipline:
     def test_closed_compile_set_under_mixed_matrix(self, model):
         """The acceptance pin: a mixed hit/miss/cancel/divergence
         traffic matrix over varied prompt lengths leaves
-        decode_compilations() == 1, and once the (group, bucket) grid
+        decode_compilations() == 2 (both packed sizes reached, and no
+        third program), and once the (group, bucket) grid
         is warm a repeat wave adds ZERO prefill/suffix traces — chunk
         calls all land in the prefill_chunk (or remainder pow2)
         buckets."""
@@ -195,11 +198,11 @@ class TestCompileDiscipline:
 
         first = wave()
         wave(cancel_at=2)               # cancel mid-chunk in the mix
-        assert eng.decode_compilations() == 1
+        assert eng.decode_compilations() == 2
         prefill0 = eng.prefill_compilations()
         third = wave()
         assert third == first           # steady-state determinism
-        assert eng.decode_compilations() == 1
+        assert eng.decode_compilations() == 2
         assert eng.prefill_compilations() == prefill0  # zero new traces
 
     def test_chunk_bucket_is_shared_across_prompt_lengths(self, model):
@@ -213,7 +216,7 @@ class TestCompileDiscipline:
         # full chunks: one (G=1, 16) trace; remainders: pow2 buckets
         # {8, 16} at G=1 -> <= 3 suffix traces total for 8 lengths
         assert eng.prefill_compilations() <= 3
-        assert eng.decode_compilations() == 1
+        assert eng.decode_compilations() == 2
 
 
 class TestLifecycle:

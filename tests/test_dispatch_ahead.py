@@ -12,7 +12,7 @@ wastes no row; every path that changes slots outside plan -> accept drains
 first; a final chunk hands its token 0 to the next program's decode row on
 the device; the routing counts on a ``device-wait`` are those of the step
 it fenced; the order dispatch j before read j-1 holds with no chip; and
-there is one program whether or not anything is in flight.
+there is one program a packed size whether or not anything is in flight.
 """
 import numpy as np
 import pytest
@@ -92,8 +92,8 @@ def spy(eng):
     its outputs (tokens, next-step tokens, keys, routing summary)."""
     log, real_fn, count = [], eng._ragged_fn, [0]
 
-    def ragged_fn(n):
-        fn = real_fn(n)
+    def ragged_fn(n, rows):
+        fn = real_fn(n, rows)
 
         def call(*args):
             j = count[0]
@@ -382,8 +382,8 @@ def test_fence_that_raises_drops_what_was_in_flight(llama):
         def __array__(self, *a, **kw):
             raise RuntimeError("device lost")
 
-    def ragged_fn(n):
-        fn = real_fn(n)
+    def ragged_fn(n, rows):
+        fn = real_fn(n, rows)
 
         def call(*args):
             out = list(fn(*args))
@@ -521,7 +521,7 @@ def test_one_program_with_and_without_a_drain(llama):
     eng = _engine(llama, jit_cache={})
     eng.generate([GenerationRequest(_prompt(90, 20), max_new_tokens=2)])
     warm = eng.decode_compilations()
-    assert warm == 1
+    assert warm == 2        # its chunk steps' size, its decode step's size
     eng.generate([GenerationRequest(_prompt(91, 9), max_new_tokens=64)])
     assert eng.stats["steps_dispatched_ahead"] >= 60
     assert eng.decode_compilations() == warm

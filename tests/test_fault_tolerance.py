@@ -220,7 +220,7 @@ class TestEngineRestore:
         assert [s.tokens for s in seqs] == want
         # every token reached on_token exactly once across both engines
         assert [emitted[s.request_id] for s in seqs] == want
-        assert eng2.decode_compilations() == before == 1
+        assert eng2.decode_compilations() == before == 2
 
     def test_mid_admission_crash_unwinds_to_queue(self, model):
         """A NON-pool exception escaping mid-admission (a real runtime
@@ -386,7 +386,7 @@ class TestSupervisedDriver:
         assert [ids.tolist() for ids, _ in outs] == want
         assert [r for _, r in outs] == ["length"] * 3 + ["length"]
         assert gw.restarts == 1
-        assert gw.engine.decode_compilations() == 1   # the whole point
+        assert gw.engine.decode_compilations() == 2   # the whole point
         assert len(gw.restart_latencies) == 1
         assert gw.restart_latencies[0] >= 0.0
         gw.shutdown(drain=True, timeout=30)

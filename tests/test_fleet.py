@@ -234,8 +234,8 @@ class TestFleetCompileDiscipline:
         fleet.start()
         for st in streams:
             st.result()
-        assert e0.decode_compilations() == 1
-        assert e1.decode_compilations() == 1
+        assert e0.decode_compilations() == 2
+        assert e1.decode_compilations() == 2
         fleet.shutdown(drain=True, timeout=30)
 
     def test_mixed_geometry_isolates_jit_caches(self, model):
@@ -250,8 +250,8 @@ class TestFleetCompileDiscipline:
         fleet.start()
         for st in streams:
             st.result()
-        assert e0.decode_compilations() == 1
-        assert e1.decode_compilations() == 1
+        assert e0.decode_compilations() == 2
+        assert e1.decode_compilations() == 2
         fleet.shutdown(drain=True, timeout=30)
 
     @pytest.mark.slow  # 7 s geometry duplicate: test_mixed_geometry_isolates_
@@ -379,7 +379,7 @@ class TestFailoverToSibling:
         rep0 = fleet.replicas[0]
         assert rep0.state != "dead"
         assert rep0.gateway.restarts == 1
-        assert rep0.gateway.engine.decode_compilations() == 1
+        assert rep0.gateway.engine.decode_compilations() == 2
         assert fleet._m_failovers.value() == 0
         fleet.shutdown(drain=True, timeout=30)
 

@@ -211,8 +211,8 @@ def test_a_fence_that_raises_restarts_from_position_zero(monkeypatch):
     rec.watch(eng)
     real_fn, count = eng._ragged_fn, [0]
 
-    def ragged_fn(n):
-        fn = real_fn(n)
+    def ragged_fn(n, rows):
+        fn = real_fn(n, rows)
 
         def call(*args):
             out = list(fn(*args))
@@ -381,7 +381,7 @@ def test_other_models_programs_take_no_store(make):
             np.zeros(R, i32), eng._keys, np.zeros(R, np.float32),
             np.zeros(R, i32), eng._no_toks, np.zeros(R, i32),
             np.zeros((R, 2), np.uint32), np.zeros(R, i32))
-    text = eng._ragged_fn(1).lower(*args).as_text()
+    text = eng._ragged_fn(1, T).lower(*args).as_text()
     assert "gdn_" not in text
 
 

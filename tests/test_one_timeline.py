@@ -246,9 +246,18 @@ class TestEngineSpans:
         T = eng._token_budget
         grid = attention_grid(eng._params, eng.cache.pool.k,
                               eng.cache.max_blocks, heads, T)
-        nq = -(-(T * heads) // grid["block_q"])
+        # the packed size follows the plan: the token budget where the step
+        # carries a chunk, the slots' rows (a whole 8) where it carries none
+        assert {a["packed_rows"] for a in disp} == {T, 8} \
+            == set(eng.step_rows)
         for a in disp:
-            # the work list's entries, plus the KV blocks the loops walk
+            assert a["packed_rows"] == (T if a["prefill_tokens"] else 8)
+            # the work list's entries (one query block at either size),
+            # plus the KV blocks the loops walk
+            nq = -(-(a["packed_rows"] * heads) // attention_grid(
+                eng._params, eng.cache.pool.k, eng.cache.max_blocks, heads,
+                a["packed_rows"])["block_q"])
+            assert nq == 1
             assert a["grid_steps"] == nq + NUM_SLOTS + a["live_steps"]
             assert 0 < a["live_steps"] <= (nq + NUM_SLOTS) \
                 * eng.cache.max_blocks
@@ -295,7 +304,7 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
 
     def counts(qstart, qlen, kvlen, **kw):
         asked.append(([int(x) for x in qstart], [int(x) for x in qlen],
-                      [int(x) for x in kvlen], kw))
+                      [int(x) for x in kvlen], kw, len(built)))
         return real_counts(qstart, qlen, kvlen, **kw)
 
     monkeypatch.setattr(pl, "pallas_call", pallas_call)
@@ -306,13 +315,24 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
     eng.generate(_reqs())
     disp = [e["args"] for e in tr.events() if e["name"] == "dispatch"]
     assert len(disp) == len(asked) == eng.stats["unified_steps"] > 0
-    # every layer call of every step program was built with one tiling
-    assert len(set(built)) == 1
-    block_q, pages = built[0]
-    assert pages > 1
+    # a program a packed size, traced by the first step that ran it, which
+    # the engine counts before it calls: every layer call of one program was
+    # built with one tiling, each size with its own
+    marks = [m for *_, m in asked] + [len(built)]
+    tiling = {}
+    for i, (_, _, _, kw, _) in enumerate(asked):
+        new = set(built[marks[i]:marks[i + 1]])
+        if new:
+            assert kw["packed_tokens"] not in tiling        # traced once
+            tiling[kw["packed_tokens"]] = new
+    assert set(tiling) == set(eng.step_rows) and len(tiling) == 2
+    assert all(len(t) == 1 for t in tiling.values()), tiling
+    assert all(pages > 1 for t in tiling.values() for _, pages in t)
     keys = ("grid_steps", "live_steps", "update_steps", "one_token_rows",
             "kv_tokens", "attn_pairs")
-    for a, (qstart, qlen, kvlen, kw) in zip(disp, asked):
+    for a, (qstart, qlen, kvlen, kw, _) in zip(disp, asked):
+        assert a["packed_rows"] == kw["packed_tokens"]
+        (block_q, pages), = tiling[a["packed_rows"]]
         kw = dict(kw, block_q=block_q, pages=pages)
         assert {k: a[k] for k in keys} \
             == _brute_force(qstart, qlen, kvlen, **kw)
@@ -562,7 +582,7 @@ class TestNamesInTheProgram:
         assert model.config.decode_attention == "pallas"
         R, T = NUM_SLOTS, eng._token_budget
         z = lambda n, dt=np.int32: np.zeros(n, dt)      # noqa: E731
-        text = eng._ragged_fn(1).lower(
+        text = eng._ragged_fn(1, T).lower(
             eng._params, *eng.cache.kv_args(), eng.cache.tables, z(T),
             np.full(T, R, np.int32), z(T), z(R), z(R), z(R), z(R),
             np.asarray(eng._keys, np.uint32), z(R, np.float32),

@@ -68,8 +68,8 @@ class TestOneLaunch:
         calls = {"ragged": 0, "suffix": 0}
         eng = _engine(model, headroom_mult=None)
         orig_ragged, orig_sfx = eng._ragged_fn, eng._suffix_fn
-        eng._ragged_fn = lambda n: (calls.__setitem__(
-            "ragged", calls["ragged"] + 1) or orig_ragged(n))
+        eng._ragged_fn = lambda n, rows: (calls.__setitem__(
+            "ragged", calls["ragged"] + 1) or orig_ragged(n, rows))
         eng._suffix_fn = lambda: (calls.__setitem__(
             "suffix", calls["suffix"] + 1) or orig_sfx())
         short = eng.submit(_req(20, n=8, max_new_tokens=40))
@@ -114,13 +114,24 @@ class TestHeadroomBudget:
         headroom stats — the hook the deterministic benches use. A step
         reads the clock at its start and at its fence; a program
         dispatched behind another costs the interval between the two
-        fences, two readings."""
+        fences, two readings. The decode baseline is fed only by the
+        program that carries chunks: the unified step runs its
+        chunk-free steps at the smaller packed size, so they feed
+        nothing and the grant stays at the cap (on the multi-tick
+        engine, whose one program carries both, they feed it)."""
         ticks = itertools.count()
         eng = _engine(model, step_clock=lambda: next(ticks) * 0.010)
         eng.generate([_req(30, n=50, max_new_tokens=3)])
         assert eng.stats["last_step_duration_s"] == pytest.approx(0.020)
         assert eng.stats["headroom_tps"] > 0      # chunk steps measured
-        assert eng._dt_decode_ewma == pytest.approx(0.020)
+        assert eng.stats["step_programs_8"] == 2  # the two decode steps
+        assert eng._dt_decode_ewma is None
+        assert eng._prefill_budget() == CHUNK == eng.stats["headroom"]
+        ticks = itertools.count()
+        eng = _engine(model, decode_ticks=2,
+                      step_clock=lambda: next(ticks) * 0.010)
+        eng.generate([_req(30, n=50, max_new_tokens=3)])
+        assert eng._dt_decode_ewma == pytest.approx(0.010)
 
     def test_throttled_grant_still_completes_one_token_over(self, model):
         """The regression the plan-carry fix exists for: a prompt ONE

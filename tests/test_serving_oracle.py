@@ -230,7 +230,7 @@ def _streams(model, **kw):
     eng = _engine(model, **kw)
     traced = eng.decode_compilations()      # engines share the jit cache
     outs = [o.tolist() for o in eng.generate(_workload())]
-    assert eng.decode_compilations() - traced <= 1
+    assert eng.decode_compilations() - traced <= 2      # a packed size each
     return outs, eng
 
 

@@ -176,7 +176,8 @@ class TestCollectiveAccounting:
         """Closed-form collective-byte pin + the cost-observatory
         satellite: one 14-token prompt, 5 greedy tokens, no chunking =
         one cold prefill launch (bucket 16) + four single-tick unified
-        steps (the padded packed buffer) — 2L all-reduces each, bytes
+        steps (the padded packed buffer at its decode-only size, the
+        slots' rows rounded up to 8) — 2L all-reduces each, bytes
         equal to the shared wire model TO THE BYTE. And the h2d/d2h
         boundary ledger of the tp=2 run equals the tp=1 run's exactly:
         per-shard arg/result leaves count LOGICAL bytes once, never
@@ -189,8 +190,8 @@ class TestCollectiveAccounting:
         assert co1.collectives == {}
         assert co1.collective_bytes("fp") == 0
         want = 2 * L * collective_wire_bytes(16, hm, 2, "fp")
-        want += 4 * 2 * L * collective_wire_bytes(
-            e2._token_budget, hm, 2, "fp")
+        assert e2.step_rows[0] == 8 < e2._token_budget
+        want += 4 * 2 * L * collective_wire_bytes(8, hm, 2, "fp")
         assert co2.collective_bytes("fp") == want
         assert co2.collectives["fp"]["ops"] == 2 * L * 5
         # the satellite pin: logical-once boundary accounting — the
