@@ -269,6 +269,13 @@ def _metric_samples(text, name):
     return out
 
 
+def _driver_wall_seconds(text):
+    """{phase: wall seconds} of ``serving_driver_seconds_total``."""
+    return {labels.split('phase="')[1].split('"')[0]: v for labels, v
+            in _metric_samples(text, "serving_driver_seconds_total").items()
+            if 'clock="wall"' in labels}
+
+
 def _drive_requests(name, base, size):
     """The smoke's traffic: every admission path once. Returns the token
     ids and the wall seconds of each request, by name."""
@@ -352,6 +359,8 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
               and banner["tp"] == tp,
               f"{name}: wrong path in effect: {banner}")
         base = banner["listening"]
+        driver0 = _driver_wall_seconds(_get(base + "/metrics"))
+        t_driver0 = time.monotonic()
         tokens, request_s = _drive_requests(name, base, size)
 
         # a Mosaic or out-of-memory error inside engine.step() is caught by
@@ -359,6 +368,7 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
         # these two checks it would look like a slow success
         health = json.loads(_get(base + "/healthz"))
         metrics = _get(base + "/metrics")
+        t_metrics = time.monotonic()
         faults = _metric_samples(metrics, "serving_faults_total")
         check(health["status"] == "ok" and health["engine_restarts"] == 0
               and sum(faults.values()) == 0,
@@ -375,6 +385,18 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
               f"{mem}")
         check(all("pallas" in k for k in mem if k.startswith("ragged[")),
               f"{name}: step program is not the pallas one: {list(mem)}")
+
+        # the driver thread's phase clock is always on: eight phases on two
+        # clocks, and the wall phases partition the thread's time (both
+        # scrapes find the server idle, a mark every 20 ms)
+        driver = _driver_wall_seconds(metrics)
+        elapsed = t_metrics - t_driver0
+        charged = sum(driver.values()) - sum(driver0.values())
+        check(len(driver) == 8 and len(_metric_samples(
+            metrics, "serving_driver_seconds_total")) == 16
+            and abs(charged - elapsed) <= 0.02 * elapsed,
+            f"{name}: the driver clock's wall phases sum to {charged:.3f} s "
+            f"of {elapsed:.3f} s elapsed: {driver}")
 
         def per_device(family):     # {device="3"} 123 -> {"3": 123}
             return {labels.split('"')[1]: int(v) for labels, v
@@ -403,6 +425,8 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
             "cache_hits": int(one("serving_compile_cache_hits_total")),
             "cache_misses": int(one("serving_compile_cache_misses_total")),
             "step_programs": steps, "programs": sorted(mem),
+            "driver_wall_s": {k: round(v - driver0.get(k, 0.0), 3)
+                              for k, v in driver.items()},
             "peak_bytes_in_use": peak,
             "engine_restarts": health["engine_restarts"],
             "request_s": request_s, "tokens": tokens}

@@ -77,7 +77,7 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def end(self, args=None):
+    def end(self, args=None, t1=None):
         pass
 
     def add(self, args):
@@ -248,15 +248,17 @@ class SpanTracer:
             ev["args"] = args
         self._append(ev)
 
-    def span(self, name, tid=TID_ENGINE, args=None):
+    def span(self, name, tid=TID_ENGINE, args=None, t0=None):
         """Open one span now; it is emitted as a complete event when it
         is closed, by ``end(more_args)`` or by leaving the ``with``
         block. ``args`` are what is known at the start: they also go to
         the ``annotate`` mirror, on the engine and gateway lanes.
+        ``t0`` is a reading of this tracer's clock already taken at the
+        span's start (the driver clock's mark); None reads it here.
         Returns a shared no-op when disabled (nothing allocated)."""
         if not self._enabled:
             return NULL_SPAN
-        return _Span(self, name, tid, args)
+        return _Span(self, name, tid, args, t0)
 
     # ------------------------------------------------------------- reading
     def events(self):
@@ -285,7 +287,7 @@ class SpanTracer:
 class _Span:
     __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_mirror")
 
-    def __init__(self, tracer, name, tid, args):
+    def __init__(self, tracer, name, tid, args, t0=None):
         self._tracer = tracer
         self._name = name
         self._tid = tid
@@ -294,7 +296,7 @@ class _Span:
         if tracer.annotate is not None and tid < TID_REQ0:
             self._mirror = tracer.annotate(name, **(args or {}))
             self._mirror.__enter__()
-        self._t0 = tracer.clock()
+        self._t0 = tracer.clock() if t0 is None else t0
 
     def __enter__(self):
         return self
@@ -305,13 +307,14 @@ class _Span:
         if args:
             self._args = {**(self._args or {}), **args}
 
-    def end(self, args=None):
-        """Close the span; ``args`` join those given at the start. The
-        mirror closes even when the tracer was disabled meanwhile."""
+    def end(self, args=None, t1=None):
+        """Close the span, at ``t1`` (a reading of the tracer's clock
+        already taken) or now; ``args`` join those given at the start.
+        The mirror closes even when the tracer was disabled meanwhile."""
         if args and self._args:
             args = {**self._args, **args}
         self._tracer.complete(self._name, self._t0, tid=self._tid,
-                              args=args or self._args)
+                              args=args or self._args, t1=t1)
         if self._mirror is not None:
             self._mirror.__exit__(None, None, None)
 

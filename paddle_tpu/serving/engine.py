@@ -710,6 +710,11 @@ class ContinuousBatchingEngine:
         # compile counts stay monotonic across rebuilds. Every touch
         # guards on _co() — the tracer's one-attribute discipline.
         self.cost = None
+        # the driver thread's phase clock (profiler/driver_clock.py): the
+        # gateway hands its one instance to every engine incarnation; an
+        # engine driven without a gateway has none, and :meth:`_mark` pays
+        # one attribute check for it.
+        self.driver_clock = None
         # streaming hooks (the gateway's wire into the step loop):
         # on_token(seq, token_id) fires for EVERY generated token the
         # moment the host sees it; on_finish(seq) fires exactly once per
@@ -765,6 +770,42 @@ class ContinuousBatchingEngine:
         if co is None:
             return fn
         return co.wrap(key, fn, host_out=host_out)
+
+    #: the cost observatory's name for the phase a mark enters, where it
+    #: names one: its ``launch`` goes on covering ``device-wait``, and
+    #: ``other`` keeps the name the step had
+    _COST_PHASE = {"admit": "admit", "plan": "plan", "dispatch": "launch",
+                   "host-accept": "host-accept"}
+
+    def _mark(self, phase, span=False, args=None, end=None, end_args=None,
+              outer=None, outer_args=None):
+        """A boundary between two phases of the thread driving
+        :meth:`step`, written once and read three ways. It enters
+        ``phase`` on the driver clock (``profiler.driver_clock.PHASES``;
+        always on under a gateway), names the cost observatory's phase
+        (:attr:`_COST_PHASE`), and when the tracer records closes the span
+        ``end`` (then ``outer``, the ``launch`` around it) and, with
+        ``span=True``, opens the span named ``phase``, all at the mark's
+        one wall reading, so that a phase's spans sum to what the clock
+        charged it. Returns the span opened, or None with tracing off:
+        sites build span args behind it (``end_args=sp and {...}``), so
+        the disabled path allocates nothing."""
+        pc = self.driver_clock
+        t = pc.enter(phase) if pc is not None else None
+        name = self._COST_PHASE.get(phase)
+        if name is not None:
+            co = self._co()
+            if co is not None:
+                co.set_phase(name)
+        if end is not None:
+            end.end(end_args, t1=t)
+        if outer is not None:
+            outer.end(outer_args, t1=t)
+        if span:
+            tr = self._tr()
+            if tr is not None:
+                return tr.span(phase, args=args, t0=t)
+        return None
 
     def _stamp_now(self):
         """Timestamp for the Sequence SLO stamps: the current step's
@@ -1682,11 +1723,13 @@ class ContinuousBatchingEngine:
                         hit_len_fn=self._admission_hit_len
                         if self.prefix_cache is not None else None)
                     if admitted:
-                        if co is not None:
-                            co.set_phase("admit")
-                        with self._tspan("admit",
-                                         args={"n": len(admitted)}):
+                        adm = self._mark(
+                            "admit", span=True,
+                            args=tr and {"n": len(admitted)})
+                        try:
                             self._admit_group(admitted, finished)
+                        finally:
+                            self._mark("other", end=adm)
                 if self._spec or self._mtick:
                     # the synchronous variants: what they dispatched they
                     # fenced, so the call's own duration is the step's
@@ -1704,6 +1747,7 @@ class ContinuousBatchingEngine:
                 # its device call), so host bookkeeping is consistent.
                 # What an EARLIER step left in flight is fenced and
                 # accepted before a slot is torn down under it.
+                self._mark("other")     # whichever phase it was raised in
                 self._abort_admission(admitted)
                 admitted = []
                 try:
@@ -1729,15 +1773,17 @@ class ContinuousBatchingEngine:
         if co is not None:
             co.set_phase(None)
         if tr is not None:
-            # the tokens of the program this step FENCED (the one it
-            # dispatched is counted by the step that fences it)
-            step_tokens, chunk_tokens = self._fenced
-            sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0})
             # counter tracks (ph:"C") on the same timeline as the step
             # spans, so Perfetto graphs cost alongside the phases:
             # KV-pool occupancy + table pressure, and (with the cost
-            # observatory on) this step's dispatch/transfer deltas
-            tr.counter("kv_blocks", self.cache.occupancy())
+            # observatory on) this step's dispatch/transfer deltas. Each
+            # is O(1) or one sum over num_slots (the pool's live / trie
+            # split is a walk of every table: that is taken at scrape
+            # rate, ``occupancy``), and all are taken before the ``step``
+            # span closes: what a traced step costs lies under a span
+            pool = self.cache.pool
+            tr.counter("kv_blocks", {"used": pool.num_used,
+                                     "free": pool.num_free})
             tr.counter("block_table_fill",
                        {"fill": round(self.cache.table_fill(), 6)})
             if co is not None:
@@ -1748,6 +1794,10 @@ class ContinuousBatchingEngine:
                 tr.counter("transfer_bytes",
                            {"h2d": d["h2d_bytes"],
                             "d2h": d["d2h_bytes"]})
+            # the tokens of the program this step FENCED (the one it
+            # dispatched is counted by the step that fences it)
+            step_tokens, chunk_tokens = self._fenced
+            sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0})
         return finished
 
     # ----------------------------------------------------- fault recovery
@@ -2065,10 +2115,7 @@ class ContinuousBatchingEngine:
         fences (an ``idle`` drain); with nothing in flight it only
         dispatches. ``t0`` is the step's start reading of the clock."""
         tr = self._tr()
-        sp = tr.span("plan") if tr is not None else None
-        co = self._co()
-        if co is not None:
-            co.set_phase("plan")
+        sp = self._mark("plan", span=True)
         prev = self._inflight
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
@@ -2077,8 +2124,8 @@ class ContinuousBatchingEngine:
                                                cap=self._chunk)
         cands = self._decode_candidates()
         if not cands and not plan:
-            if tr is not None:
-                sp.end({"rows": 0, "chunks": 0})
+            self._mark("other", end=sp,
+                       end_args=sp and {"rows": 0, "chunks": 0})
             self._drain("idle", finished)
             return
         n = self.scheduler.choose_num_steps(
@@ -2113,24 +2160,26 @@ class ContinuousBatchingEngine:
         adopt = dec_mask.copy()
         for slot, seq, _ntok, final in chunk_rows:
             adopt[slot] = int(final and not seq.restore_point)
+        # plan: admission already ran in step(); this is the chunk grant +
+        # span packing. launch: dispatch (the jitted call of THIS step's
+        # program returns) and device-wait (the host transfer that fences
+        # the PREVIOUS program). host-accept: that program's token/chunk
+        # bookkeeping (donate spans nest inside it).
+        self._mark("other", end=sp,
+                   end_args=sp and {"rows": len(rows), "chunks": len(plan),
+                                    "fused_steps": n})
+        launch = args = None
         if tr is not None:
-            # plan: admission already ran in step(); this is the chunk
-            # grant + span packing. launch: dispatch (the jitted call of
-            # THIS step's program returns) and device-wait (the host
-            # transfer that fences the PREVIOUS program). host-accept:
-            # that program's token/chunk bookkeeping (donate spans nest
-            # inside it).
-            sp.end({"rows": len(rows), "chunks": len(plan),
-                    "fused_steps": n})
+            # a traced step counts the kernel's grid on the host: under
+            # ``launch``, and ``other`` on the driver clock
             launch = tr.span("launch")
             args = self._dispatch_args(
                 qstart, qlen, kvlen, T, len(rows), n * len(rows),
                 cursor - len(rows))
             args["ahead"] = int(prev is not None)
             args["packed_rows"] = T
-            sp = tr.span("dispatch", args=args)
-        if co is not None:
-            co.set_phase("launch")
+        sp = self._mark("dispatch", span=True, args=args)
+        co = self._co()
         keys_in = self._keys
         npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n, T)(
             self._params, *self.cache.kv_args(),
@@ -2169,13 +2218,13 @@ class ContinuousBatchingEngine:
             # step ran), each fused tail tick the per-slot row block —
             # exact, shape-derived
             self._record_collectives(co, [(T, 1), (self.num_slots, n - 1)])
-        if tr is not None:
-            sp.end()
+        launch_args = launch and {"packed_tokens": cursor, "fused_steps": n}
         if prev is not None:
-            self._fence(prev, finished, launch if tr is not None else None,
-                        {"packed_tokens": cursor, "fused_steps": n})
-        elif tr is not None:
-            launch.end({"packed_tokens": cursor, "fused_steps": n})
+            self._mark("other", end=sp)
+            self._fence(prev, finished, launch, launch_args)
+        else:
+            self._mark("other", end=sp, outer=launch,
+                       outer_args=launch_args)
 
     def _decode_candidates(self):
         """Every slot the next step program can carry a decode row for,
@@ -2263,12 +2312,11 @@ class ContinuousBatchingEngine:
         ``launch`` (the caller's, when this call also dispatched), then
         ``host-accept``. An exception out of the program surfaces here;
         what was in flight is then dropped, never half accepted."""
-        tr = self._tr()
-        co = self._co()
-        if tr is not None:
-            if launch is None:
-                launch = tr.span("launch")
-            sp = tr.span("device-wait")
+        pc = self.driver_clock
+        back = pc.phase if pc is not None else None     # in a step or not
+        if launch is None:
+            launch = self._tspan("launch")
+        sp = self._mark("device-wait", span=True)
         try:
             toks_np = np.asarray(rec.toks)          # [n, R]
             moe = self._count_moe(rec.moe)
@@ -2278,12 +2326,9 @@ class ContinuousBatchingEngine:
         now = self._clock()
         base = rec.t_base if rec.t_base is not None else self._t_fence
         self._t_fence = now
-        if co is not None:
-            co.set_phase("host-accept")
-        if tr is not None:
-            sp.end(moe)     # the routing of the step this wait fenced
-            launch.end(launch_args)
-            sp = tr.span("host-accept")
+        # device-wait closes with the routing of the step it fenced
+        sp = self._mark("host-accept", span=True, end=sp, end_args=moe,
+                        outer=launch, outer_args=launch_args)
         n, rows = rec.n, rec.rows
         # chunk bookkeeping first: a final chunk's _install_seq emits
         # its token 0 before this step's decode rows surface theirs
@@ -2297,8 +2342,7 @@ class ContinuousBatchingEngine:
             self.stats["decode_steps"] += n
             self.stats["slot_steps"] += n * self.num_slots
             emitted = self._accept_decode_rows(toks_np, n, rows, finished)
-        if tr is not None:
-            sp.end({"emitted": emitted})
+        self._mark(back, end=sp, end_args=sp and {"emitted": emitted})
         self._count_step(now - base, rec.packed + (n - 1) * len(rows),
                          rec.packed - len(rows), rec.size)
 
@@ -2411,10 +2455,8 @@ class ContinuousBatchingEngine:
         the returned key walk. Returns ``(tokens_processed,
         chunk_tokens)`` as :meth:`_unified_step` does."""
         tr = self._tr()
-        sp = tr.span("plan") if tr is not None else None
+        sp = self._mark("plan", span=True)
         co = self._co()
-        if co is not None:
-            co.set_phase("plan")
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
             plan = self.scheduler.prefill_plan(self._prefill_budget(),
@@ -2423,8 +2465,8 @@ class ContinuousBatchingEngine:
         cands = self._decode_candidates()   # nothing is ever in flight
         active = [c[1] for c in cands]      # here: the running slots
         if not active and not plan:
-            if tr is not None:
-                sp.end({"rows": 0, "chunks": 0})
+            self._mark("other", end=sp,
+                       end_args=sp and {"rows": 0, "chunks": 0})
             return 0, 0
         n = self.scheduler.choose_decode_ticks(active,
                                                self._decode_ticks)
@@ -2453,23 +2495,23 @@ class ContinuousBatchingEngine:
             plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
             temps, topks)
         chunk_tokens = cursor - len(active)
+        self._mark("other", end=sp,
+                   end_args=sp and {"rows": len(active), "chunks": len(plan),
+                                    "ticks": n})
+        launch = args = None
         if tr is not None:
-            sp.end({"rows": len(active), "chunks": len(plan), "ticks": n})
             launch = tr.span("launch")
-            sp = tr.span("dispatch", args=self._dispatch_args(
+            args = self._dispatch_args(
                 qstart, qlen, kvlen, T, len(active), n * len(active),
-                chunk_tokens))
-        if co is not None:
-            co.set_phase("launch")
+                chunk_tokens)
+        sp = self._mark("dispatch", span=True, args=args)
         npk, npv, toks, kwalk, ticks_run = self._mtick_fn()(
             self._params, *self.cache.kv_args(),
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             dec_mask, keys, temps, topks, eos_ids, budgets,
             np.int32(n))
         self.cache.update(npk, npv)
-        if tr is not None:
-            sp.end()
-            sp = tr.span("device-wait")
+        sp = self._mark("device-wait", span=True, end=sp)
         toks_np = np.asarray(toks)          # [max_ticks, R]
         kwalk_np = np.asarray(kwalk)        # [max_ticks, R, 2]
         ticks = int(ticks_run)              # <= n: early exit when all
@@ -2481,12 +2523,10 @@ class ContinuousBatchingEngine:
             self._record_collectives(
                 co, [(self._token_budget, 1),
                      (self.num_slots, ticks - 1)])
-            co.set_phase("host-accept")
-        if tr is not None:
-            sp.end()
-            launch.end({"packed_tokens": cursor, "ticks": n,
-                        "ticks_run": ticks})
-            sp = tr.span("host-accept")
+        sp = self._mark(
+            "host-accept", span=True, end=sp, outer=launch,
+            outer_args=launch and {"packed_tokens": cursor, "ticks": n,
+                                   "ticks_run": ticks})
         # chunk bookkeeping first — mirrors the unified-step order (a
         # final chunk adopts tick 0's token/key, the same one split as
         # a one-shot prefill)
@@ -2529,8 +2569,9 @@ class ContinuousBatchingEngine:
                 adopted = True
             if adopted:
                 self._keys = jnp.asarray(knp)
-        if tr is not None:
-            sp.end({"emitted": emitted_total, "ticks_run": ticks})
+        self._mark("other", end=sp,
+                   end_args=sp and {"emitted": emitted_total,
+                                    "ticks_run": ticks})
         return chunk_tokens + emitted_total, chunk_tokens
 
     def _pack_chunk_rows(self, plan, cursor, ids, seg, pos, qstart, qlen,
@@ -2593,10 +2634,8 @@ class ContinuousBatchingEngine:
         Returns ``(tokens_processed, chunk_tokens)`` as
         :meth:`_unified_step` does."""
         tr = self._tr()
-        sp = tr.span("plan") if tr is not None else None
+        sp = self._mark("plan", span=True)
         co = self._co()
-        if co is not None:
-            co.set_phase("plan")
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
             plan = self.scheduler.prefill_plan(self._prefill_budget(),
@@ -2605,8 +2644,8 @@ class ContinuousBatchingEngine:
         active = [(slot, s) for slot, s in enumerate(self._slots)
                   if s is not None and s.status == "running"]
         if not active and not plan:
-            if tr is not None:
-                sp.end({"rows": 0, "chunks": 0})
+            self._mark("other", end=sp,
+                       end_args=sp and {"rows": 0, "chunks": 0})
             return 0, 0
         R, T = self.num_slots, self._spec_budget
         lens = self.cache.lengths
@@ -2658,34 +2697,30 @@ class ContinuousBatchingEngine:
         chunk_rows, cursor = self._pack_chunk_rows(
             plan, cursor, ids, seg, pos, qstart, qlen, kvlen, keys,
             temps, topks, sample_start=sample_start)
+        self._mark("other", end=sp,
+                   end_args=sp and {"rows": len(active), "chunks": len(plan),
+                                    "draft_tokens": int(sum(grants))})
+        launch = args = None
         if tr is not None:
-            sp.end({"rows": len(active), "chunks": len(plan),
-                    "draft_tokens": int(sum(grants))})
             launch = tr.span("launch")
-            sp = tr.span("dispatch", args=self._dispatch_args(
+            args = self._dispatch_args(
                 qstart, qlen, kvlen, T, len(verify_rows),
-                cursor - chunk_spend, chunk_spend))
-        if co is not None:
-            co.set_phase("launch")
+                cursor - chunk_spend, chunk_spend)
+        sp = self._mark("dispatch", span=True, args=args)
         npk, npv, toks, kwalk = self._spec_fn()(
             self._params, *self.cache.kv_args(),
             self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
             sample_start, keys, temps, topks)
         self.cache.update(npk, npv)
-        if tr is not None:
-            sp.end()
-            sp = tr.span("device-wait")
+        sp = self._mark("device-wait", span=True, end=sp)
         toks_np = np.asarray(toks)          # [spec_len, R]
         kwalk_np = np.asarray(kwalk)        # [spec_len, R, 2]
         self.stats["spec_steps"] += 1
         if co is not None:
             # one packed verify forward per spec step (no decode tail)
             self._record_collectives(co, [(self._spec_budget, 1)])
-            co.set_phase("host-accept")
-        if tr is not None:
-            sp.end()
-            launch.end({"packed_tokens": cursor})
-            sp = tr.span("host-accept")
+        sp = self._mark("host-accept", span=True, end=sp, outer=launch,
+                        outer_args=launch and {"packed_tokens": cursor})
         # chunk bookkeeping first — mirrors the unified-step order (a
         # final chunk adopts its walk-step-0 token/key, the same one
         # split as a one-shot prefill)
@@ -2750,7 +2785,7 @@ class ContinuousBatchingEngine:
                            args={"accept_lens": list(accept_lens),
                                  "proposed": [len(d) for _, _, d, _
                                               in verify_rows]})
-            sp.end({"emitted": emitted_total})
+        self._mark("other", end=sp, end_args=sp and {"emitted": emitted_total})
         return chunk_spend + emitted_total, chunk_spend
 
     def has_work(self) -> bool:

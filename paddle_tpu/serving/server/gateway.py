@@ -77,6 +77,7 @@ import jax
 import numpy as np
 
 from ...profiler.cost import PROGRAM_KINDS, CostObservatory
+from ...profiler.driver_clock import PHASES, DriverClock
 from ...profiler.metrics import (QUEUE_WAIT_BUCKETS, SPEC_ACCEPT_BUCKETS,
                                  STEP_BUCKETS, TPOT_BUCKETS, TTFT_BUCKETS,
                                  MetricsRegistry)
@@ -335,8 +336,18 @@ class ServingGateway:
         # every engine cost site to the one _co() attribute check.
         self.cost = CostObservatory(clock=self._clock) if cost else None
         self._pcapture = None       # /debug/profile capture window
+        # ------------------------------------------------ driver clock
+        # (README "Tracing & debugging") where the driver thread's time
+        # goes, by phase, on the wall and on the thread's CPU clock:
+        # always on, gateway-owned like the two above, marked at the
+        # boundaries where the spans are (``_mark`` here and on the
+        # engine); a tracer injected with a clock of its own reads that
+        self.driver_clock = DriverClock(
+            wall=self._clock,
+            stamps_spans=self.tracer.clock is self._clock)
         engine.tracer = self.tracer
         engine.cost = self.cost
+        engine.driver_clock = self.driver_clock
         engine.on_token = self._on_token
         engine.on_finish = self._on_finish
         engine.on_policy_preempt = self._on_policy_preempt
@@ -559,6 +570,22 @@ class ServingGateway:
             "What one step program cost, fence to fence (admission + "
             "prefill grant + decode + retire; the driver's loop between "
             "two steps too).", buckets=STEP_BUCKETS)
+        driver = r.counter(
+            "serving_driver_seconds_total",
+            "Seconds of the engine-driver thread by phase (loop: the "
+            "gateway between two steps; idle-wait: no work; admit, plan, "
+            "dispatch, device-wait, host-accept: the step's spans of "
+            "those names; other: the rest of a step) and clock (wall; "
+            "cpu: the thread's own CPU time). The wall phases sum to the "
+            "thread's elapsed time. device-wait over all but idle-wait "
+            "(wall) is the share of its time the host waits for the "
+            "chip: near 0 the host is the pace. Monotonic across engine "
+            "rebuilds.")
+        for phase in PHASES:
+            for clock in ("wall", "cpu"):
+                driver.set_fn(
+                    lambda p=phase, c=clock: self.driver_clock.seconds(p, c),
+                    phase=phase, clock=clock)
         r.gauge("serving_step_tokens",
                 "Tokens the last engine step processed on device "
                 "(decode rows x fused ticks + prefill chunk tokens)."
@@ -1205,6 +1232,7 @@ class ServingGateway:
 
     def _run(self):
         try:
+            self._mark("loop")
             while True:
                 self._arm_capture()
                 self._admit_migrations()
@@ -1216,7 +1244,7 @@ class ServingGateway:
                 if self.engine.has_work():
                     self._step_supervised()
                     continue
-                self._end_loop_span()   # the idle wait is not the loop
+                self._mark("idle-wait")     # the idle wait is not the loop
                 with self._lock:
                     drained = (not self._intake and not self._live
                                and not self._parked
@@ -1229,6 +1257,7 @@ class ServingGateway:
                 # orchestrator must not kill a healthy idle server)
                 self._last_step_done = self._clock()
                 self._wake.wait(self.idle_wait_s)
+                self._mark("loop")
                 self._wake.clear()
         except BaseException as e:
             # supervision exhausted (max_restarts, no factory, or a
@@ -1267,13 +1296,11 @@ class ServingGateway:
             # restart budget on healthy cold starts
             traces0 = (self.engine.decode_compilations()
                        + self.engine.prefill_compilations())
-            self._end_loop_span()
+            self._mark("other")
             self.engine.step()
-            tr = self._tr()
-            if tr is not None:
-                # the driver's own work between two engine steps: the
-                # checks below, then intake, cancels, deadlines, captures
-                self._loop_span = tr.span("loop", tid=TID_GATEWAY)
+            # the driver's own work between two engine steps: the
+            # checks below, then intake, cancels, deadlines, captures
+            self._mark("loop", span=True)
             dt = self._clock() - t0
             compiled = (self.engine.decode_compilations()
                         + self.engine.prefill_compilations()) > traces0
@@ -1283,6 +1310,7 @@ class ServingGateway:
                     f"engine step took {dt:.3f}s, watchdog deadline is "
                     f"{self.watchdog_deadline_s:.3f}s")
         except Exception as e:
+            self._mark("loop")      # classification, backoff, a rebuild
             self._on_fault(e)
             return
         self._last_step_done = self._clock()
@@ -1301,10 +1329,22 @@ class ServingGateway:
                     self._m_spec_len.observe(m)
                 self.engine.stats["spec_last_accept"] = []
 
-    def _end_loop_span(self):
-        span, self._loop_span = self._loop_span, None
-        if span is not None:
-            span.end()
+    def _mark(self, phase, span=False):
+        """The driver thread passes from one of the gateway's phases to
+        another (``loop``, ``idle-wait``, or ``other`` on entering
+        ``engine.step()``, which marks its own: ``engine._mark``). Ticks
+        the driver clock, and at the same reading closes the ``loop``
+        span on leaving the loop and, with ``span=True`` (a step just
+        ended), opens it."""
+        t = self.driver_clock.enter(phase)
+        if phase != "loop":
+            loop, self._loop_span = self._loop_span, None
+            if loop is not None:
+                loop.end(t1=t)
+        elif span:
+            tr = self._tr()
+            if tr is not None:
+                self._loop_span = tr.span("loop", tid=TID_GATEWAY, t0=t)
 
     def _classify(self, exc) -> str:
         if isinstance(exc, WatchdogTimeout):
@@ -1443,6 +1483,7 @@ class ServingGateway:
         new.on_step = self._on_step
         new.tracer = self.tracer     # one timeline across incarnations
         new.cost = self.cost         # one cost account, monotonic too
+        new.driver_clock = self.driver_clock    # and one phase clock
         if self._fault_hook is not None:
             new.fault_hook = self._fault_hook
         new_pc = dict(pc_base)

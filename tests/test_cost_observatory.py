@@ -447,10 +447,17 @@ class TestCounterTracks:
             eng.cost.totals["dispatches"]
         xfer = [e for e in counters if e["name"] == "transfer_bytes"]
         assert all({"h2d", "d2h"} <= set(e["args"]) for e in xfer)
+        # what the pool counts in O(1): the live / trie split is a walk
+        # of every table, taken at scrape rate (``occupancy``), never by
+        # a traced step
         kv = [e for e in counters if e["name"] == "kv_blocks"]
+        pool = eng.cache.pool
+        assert kv[-1]["args"] == {"used": pool.num_used,
+                                  "free": pool.num_free}
         occ = eng.cache.occupancy()
-        assert kv[-1]["args"] == occ
         assert set(occ) == {"live", "trie", "free"}
+        assert occ["live"] + occ["trie"] == kv[-1]["args"]["used"]
+        assert occ["free"] == kv[-1]["args"]["free"]
 
     def test_no_counters_without_cost_or_tracer(self, model):
         # tracer on, cost absent: spans yes, dispatch counters no
@@ -463,8 +470,11 @@ class TestCounterTracks:
         names = {e["name"] for e in tr.events() if e["ph"] == "C"}
         assert "dispatches" not in names
         assert "transfer_bytes" not in names
-        # KV occupancy is tracer-only — it still rides along
+        # KV occupancy is tracer-only — it still rides along, as the
+        # pool's own two counts
         assert "kv_blocks" in names
+        assert all(set(e["args"]) == {"used", "free"}
+                   for e in tr.events() if e["name"] == "kv_blocks")
 
 
 # ----------------------------------------------------- chaos determinism
