@@ -64,10 +64,12 @@ FULL = dict(
         "olmoe decode 16/16/128": (16, 16, 128, 64, [
             (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)]),
         # Olmo-Hybrid's full layers: 30 heads, the first count that is no
-        # power of two (4 tokens of 30 wide rows a query block), a pool row
-        # of 3,840; a chunk behind its first chunk beside decode rows
+        # power of two (30 planes of the head-major query, 128 tokens a
+        # query block), a pool row of 3,840; a 512-token chunk 688 tokens
+        # into its prompt behind the other 31 slots' decode rows
         "olmo-hybrid chunk 30/30/128": (30, 30, 128, 72, [
-            (1, 700), (488, 1000), (1, 33), (0, 0), (1, 2280), (1, 1)])},
+            (1, 520 + 55 * i + (i * 37) % 29) for i in range(31)]
+            + [(512, 1200)])},
     # the cells' decode-only steps, whose packed buffer is the slots alone
     # (8 / 24 / 32 rows and nothing behind them): the chat cell's two live
     # rows of eight, less than one query block; every row live in the others

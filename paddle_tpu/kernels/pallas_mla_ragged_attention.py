@@ -238,11 +238,13 @@ def _spans(tables, qstart, qlen, kvlen):
 
 def grid_params(table_entries, heads, packed_tokens, block_q=BLOCK_Q,
                 pages=PAGES):
-    """The tiling of one call, ``{"block_q", "pages"}`` (as
+    """The tiling of one call, ``{"block_q", "pages", "one_token"}`` (as
     ``pallas_ragged_attention.grid_params``: the one derivation the call and
     the engine's ``ragged_grid_counts`` share)."""
-    return {"block_q": _query_block(block_q, heads, packed_tokens),
-            "pages": max(1, min(int(pages), int(table_entries)))}
+    block_q = _query_block(block_q, heads, packed_tokens)
+    return {"block_q": block_q,
+            "pages": max(1, min(int(pages), int(table_entries))),
+            "one_token": _one_token_walk(heads, block_q)}
 
 
 def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,

@@ -30,13 +30,20 @@ Semantics per sequence ``r`` (dead rows carry ``query_len == 0``):
   positions ``0 .. pos`` through ``tables[r]``;
 - packed rows outside every span produce exact zeros.
 
-Design points (the block-diagonal wide-query GQA trick, the table-indirect
-fetch and the Mosaic-conservative 2D tiles are ``pallas_paged_decode.py``'s):
+Design points (the table-indirect fetch and the Mosaic-conservative tiles
+are ``pallas_paged_decode.py``'s):
 
+- **The query goes in head-major**: the entry makes ``q [T, H, D]`` into
+  ``[Hkv, T * G, D]`` (row ``t * G + g`` of plane ``k`` is head ``k * G +
+  g`` of token ``t``; for MHA ``[H, T, D]``) and the output comes back the
+  same way: two transposes of ``T * H * D`` elements around the call, and
+  no array anywhere with ``Hkv * D`` values a (token, head). The heads are
+  a LEADING dimension, so a count that is no multiple of 8 (Olmo-Hybrid's
+  30) needs no padding.
 - **The iteration space is the step's live work**, built from the span
   metadata inside the program (``_work_list``; no host work, no extra
   argument). The grid is a *work list* of (query block, row) pairs: the
-  packed wide-query array is tiled into fixed query blocks, the packed
+  packed tokens are tiled into fixed query blocks, the packed
   spans are disjoint and contiguous, so at most ``nq + R`` pairs
   intersect, ordered by query block then row. A query block that no span
   touches gets one entry that only zeroes its output; unused entries
@@ -50,46 +57,61 @@ fetch and the Mosaic-conservative 2D tiles are ``pallas_paged_decode.py``'s):
   stays in HBM where it lies (``pl.ANY``).
 - **Several pool pages an online-softmax update**: a loop iteration takes a
   *group* of ``pages`` consecutive table entries (``pages_per_update``: 256
-  keys' worth, fewer where a row is wide; 8 for Mistral, 4 for OLMoE). It
+  keys' worth, 128 where a cached row is wide: 8 blocks of 32 for Mistral, 4
+  for OLMoE and Olmo-Hybrid). It
   resolves them from the scalar-prefetched table in SMEM and fetches each
   block, at ``(layer, table entry)``, by ``make_async_copy`` into one
   two-slot ``[2, pages * bs, KD]`` buffer a side, all copies in flight
-  together and the next group streaming in while this one computes; then
-  one ``dot_general`` for the scores of ``pages * bs`` keys, one mask, one
-  ``m / l / acc`` update, one ``P V``. The float32 accumulator ``[block_q,
-  KD]`` is rescaled and written once a group, not once a 32-row page (that
-  was 1.3 us a page against 0.17 us of MXU work, PERF.md, PR 25 and 32).
-  Entries past the pair's last block clamp to the table's last entry, and
+  together and the next group streaming in while this one computes.
+  Entries past the table clamp to its last and
   sentinel entries (``>= num_blocks``) into the layer's own blocks — a
   harmless read, masked off by ``kvlen`` and the causal rule; V rows past
-  ``kvlen`` are zeroed in the one group that can hold any (a stale row may
-  be NaN). So HBM traffic and MXU work scale with the live logical cache
-  rounded up to a group a pair, and no layer of the pool is cut out or
-  re-laid-out for the call. An int8 pool's per-row scale planes ride the
-  same physical index as their data block and lie concatenated over the
-  group; an fp8 pool's per-block scale becomes a factor a column.
-- **A span of one token computes on its own rows**: a decode row is ``gh``
-  wide rows at a multiple of ``gh`` inside the query block. Where those are
-  whole tiles (``gh % 16 == 0``, fewer than the block: Mistral's 32,
-  OLMoE's 16) the pair loads those rows of the query block alone, keeps its
-  softmax state in the first ``gh`` rows of the scratch and writes those
-  ``gh`` output rows: 1 / 16 of a 512-row block for Mistral, 1 / 16 of a
-  256-row block for OLMoE. Every other span takes the general walk on the
-  whole block (``pallas_mla_ragged_attention`` has the same two walks).
+  ``kvlen`` are zeroed, in the buffer, in the one group that can hold any
+  (a stale row may be NaN). So HBM traffic scales with the live logical
+  cache rounded up to a group a pair, and no layer of the pool is cut out
+  or re-laid-out for the call.
+- **Each KV head's keys by that head's queries only**: head ``k``'s keys
+  and values are lanes ``k * D .. (k + 1) * D`` of the fetched group, a
+  whole-lane-tile window at ``D`` 128. Per head and update: ``s_k = q_k
+  [rows, D] x K_k^T``, one mask (the same for every head), the ``m / l``
+  update, ``acc_k [rows, D] += p_k x V_k``; the float32 accumulator is
+  ``[Hkv, rows, D]``. No zero is multiplied (the block-diagonal wide query
+  this replaced cost ``Hkv`` x the MXU work and an accumulator ``Hkv`` x
+  this one: PERF.md, PR 32 and 36). An int8 pool's scale for head ``k`` is
+  column ``k`` of the plane that rode the same physical index as its data
+  block, applied to the head's window as it is upcast; an fp8 pool's
+  per-block scale is spread over the block's rows.
+- **The query block is sized by the per-head state** (``query_block_rows``):
+  512 rows of one KV head's plane (one head's float32 score tile at 256
+  keys is 512 KiB), fewer where the accumulator of all planes would pass
+  2 MiB; in whole row tiles of whole tokens. 128 tokens at Mistral's 32 / 8
+  / 128 and Olmo-Hybrid's 30 / 30 / 128, 256 at OLMoE's 16 / 16 / 128: a
+  512-token chunk re-reads its prefix 4 or 5 times (the block-diagonal
+  form's 16 tokens a block: 32 times).
+- **A span of one token takes one product over the whole pool row**: a
+  decode row is bound by its KV bytes, and ``Hkv`` small per-head products
+  an update would make it slower (measured: PERF.md, PR 36). The kernel
+  observes ``qlen == 1``, cuts the token's ``H`` query rows out of the
+  head-major block once a pair (each plane's aligned 16-row tile, a masked
+  sum over its rows), lays them block-diagonal ``[H, KD]`` in a VMEM
+  scratch, and walks the groups with one ``[H, KD] x [KD, keys]`` and one
+  ``[H, keys] x [keys, KD]`` product an update; each head's own ``D`` lanes
+  go back into the token's rows of its plane. The wide tile never leaves
+  VMEM and its zeros are laid once a call. A quantized pool's group is
+  upcast for it head window by head window, each with its own scale. Where
+  a token's ``G`` rows could straddle two tiles (``G`` 3) every span takes
+  the per-head walk (``_token_tile``; ``pallas_mla_ragged_attention`` has
+  two walks too).
 - **One output block, several rows**: visits to an output block are
   consecutive; the first zeroes it, each row's visit writes back only its
-  own span (the one-token walk its ``gh`` rows, the general walk by a
-  masked read-modify-write). MXU work on the masked remainder of an
-  intersecting query block is the same idle-MXU trade the wide-query trick
-  already makes. The last query block may reach past the packed buffer: the
-  rows it holds there belong to no span, and the buffer is never padded.
-- **2D-tile conservatism**: all blocks are 2D/leading-1 tiles whose
-  last-two dims equal the full array dims; compute is plain 2D
-  ``dot_general``; groups ascending, the per-row state in VMEM scratch
-  exactly like the decode kernels. At ``pages=1`` (an argument of the Python
-  entry, for tests) a span-1 row reproduces ``paged_decode_attention_pallas``'s
-  accumulation order bit for bit; at the derived ``pages`` it is the same
-  mathematics in another order, equal within float32 rounding.
+  own span, by a masked read-modify-write. The last query block may reach
+  past the packed buffer: the rows it holds there belong to no span, and
+  the buffer is never padded.
+- **Tile conservatism**: blocks are ``[Hkv, rows, D]`` with ``rows`` whole
+  16-row tiles and ``D`` the full minor dim; compute is plain 2D
+  ``dot_general`` on a plane's rows; groups ascending, the per-row state in
+  VMEM scratch. A span-1 row is ``paged_decode_attention_pallas``'s row
+  within float32 rounding: the same mathematics in another order.
 
 Inference-only (no VJP): the serving step never backpropagates.
 """
@@ -103,28 +125,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_flash import _cparams, _interpret_mode
-from .pallas_paged_decode import _head_scale_mat
+from .pallas_flash import _interpret_mode
 
 NEG_INF = -1e30
 
 
 def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                   tbl_ref, layer_ref, *refs, scale, block_k, pages, tq, gh,
-                   num_blocks, table_entries, quantized=False, hkv=0):
+                   tbl_ref, layer_ref, *refs, scale, block_k, pages, tq, g,
+                   num_blocks, table_entries, quantized=False):
     # positional ref layout follows the pallas_call spec lists: inputs
     # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
-    # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc)
+    # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc
+    # and, where the call has the one-token walk, its wide query and state)
     if quantized:
         (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
-         vs_buf, sems, m_scr, l_scr, acc_scr) = refs
+         vs_buf, sems, m_scr, l_scr, acc_scr, *one_token) = refs
         streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
                    (vs_hbm, vs_buf))
     else:
         (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr,
-         acc_scr) = refs
+         acc_scr, *one_token) = refs
         ks_buf = vs_buf = None
         streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    # the one-token walk's scratch, where the call has that walk
+    tile = _token_tile(q_ref.shape[1], g) if one_token else 0
+    if tile:
+        qw_scr, m1_scr, l1_scr, acc1_scr = one_token
+    hkv, rows, d = q_ref.shape      # KV heads, a plane's rows a block, D
     w = pl.program_id(0)            # one work-list entry: (query block, row)
     qi = wq_ref[w]
     r = wr_ref[w]
@@ -133,16 +160,25 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     qstart = qs_ref[r]
     qlen = ql_ref[r]
     kvlen = kl_ref[r]
-    row0 = qi * tq                  # first wide row of this query block
-    span_lo = qstart * gh           # span bounds in wide-row coordinates
-    span_hi = (qstart + qlen) * gh
+    row0 = qi * (tq // hkv)         # first plane row of this query block
+    span_lo = qstart * g            # span bounds in plane-row coordinates
+    span_hi = (qstart + qlen) * g
     group = pages * block_k         # keys of one online-softmax update
+    alone = (qlen == 1) if tile else False
 
     @pl.when(wf_ref[w] == 1)
     def _zero_out():
         # first visit of this output block: packed rows outside every
         # span must come back as exact zeros, not stale VMEM
         o_ref[:] = jnp.zeros_like(o_ref)
+
+    if tile:
+        @pl.when(w == 0)
+        def _zero_wide():
+            # the one-token walk's wide query: only its diagonal windows are
+            # ever rewritten (every one, each pair), so the zeros between
+            # them are laid once a call
+            qw_scr[:] = jnp.zeros(qw_scr.shape, jnp.float32)
 
     def _copies(gi, slot):
         # table-indirect fetch of group gi, `pages` consecutive table
@@ -156,12 +192,12 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         for j in range(pages):
             entry = jnp.minimum(gi * pages + j, table_entries - 1)
             phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
-            rows = pl.ds(j * block_k, block_k)
+            keys = pl.ds(j * block_k, block_k)
             for i, (hbm, buf) in enumerate(streams):
                 if hbm.ndim == 4:       # the stored pool [L, nb, bs, KD]
-                    src, dst = hbm.at[layer, phys], buf.at[slot, rows]
+                    src, dst = hbm.at[layer, phys], buf.at[slot, keys]
                 elif hbm.ndim == 3:     # int8 planes [nb, bs, lanes]
-                    src, dst = hbm.at[phys], buf.at[slot, rows]
+                    src, dst = hbm.at[phys], buf.at[slot, keys]
                 else:                   # fp8 planes [nb, lanes]: one row a
                     src = hbm.at[pl.ds(phys, 1)]        # block, 2D windows
                     dst = buf.at[slot, pl.ds(j, 1)]
@@ -169,37 +205,33 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
                                                  sems.at[i, slot, j]))
         return out
 
-    def _dequant(plane, nr):
-        # [nr, group] dequant factors of one group's scale planes, applied
-        # post-dot by the head one-hot trick (the rows are whole tokens, so
-        # the row->head map is position-free). int8 carries a scale per
-        # (pool row, head): the pages' planes lie concatenated. fp8 carries
-        # one per (block, head): a factor per page, spread over its columns
-        # by a second one-hot.
-        f = _head_scale_mat(plane[:, :hkv], nr, gh, hkv)
+    def _head_rows(buf, slot, scales, k):
+        # head k's [group, D] window of a fetched group: its D lanes of
+        # every row. A quantized pool's values are upcast HERE, right after
+        # the table-indirect DMA moved the narrow dtype (the HBM win), and
+        # take the head's scale, column k of the plane: int8 carries one a
+        # (pool row, head), the pages' planes lying concatenated; fp8 one a
+        # (block, head), spread over the block's rows
+        x = buf[slot, :, k * d:(k + 1) * d]
+        if not quantized:
+            return x
+        f = scales[slot, :, k:k + 1]
         if quantized == "fp8":
-            page = jax.lax.broadcasted_iota(jnp.int32, (pages, group), 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, (pages, group), 1)
-            spread = jnp.where(col // block_k == page, 1.0, 0.0)
-            f = jax.lax.dot_general(f, spread.astype(jnp.float32),
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        return f
+            f = jnp.broadcast_to(f[:, None, :], (pages, block_k, 1)).reshape(
+                group, 1)
+        return x.astype(jnp.float32) * f
 
-    def _walk(nr, load_q, valid_of, write):
-        # one pair's walk on ``nr`` wide rows (static), the softmax state in
-        # the first ``nr`` rows of the scratch
-        m_ref, l_ref, acc_ref = (ref.at[pl.ds(0, nr)]
-                                 for ref in (m_scr, l_scr, acc_scr))
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def _groups(update):
+        # this pair's groups of pool blocks, ascending, double-buffered:
+        # group gi + 1 streams in while ``update(gi, slot)`` computes on gi.
+        # Exactly the pair's blocks, `pages` an update: the row's own length
+        # and the causal diagonal both already bound nkb (_work_list); what
+        # the last group holds past them is masked
         n_groups = (nkb + pages - 1) // pages
         for c in _copies(0, 0):
             c.start()
 
         def _group(gi, carry):
-            # double buffer: group gi + 1 streams in while gi computes
             slot = gi % 2
 
             @pl.when(gi + 1 < n_groups)
@@ -209,133 +241,225 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
 
             for c in _copies(gi, slot):
                 c.wait()
-            q = load_q()                        # [nr, KD] block-diag wide
-            k = k_buf[slot]                     # [group, KD]
-            v = v_buf[slot]
-            if quantized:
-                # quantized pool: the table-indirect DMA above moved the
-                # narrow dtype (the HBM win); the upcast happens HERE,
-                # right after it: values convert in VMEM on the way into
-                # the MXU and the scales apply post-dot (_dequant)
-                k = k.astype(jnp.float32)
-                v = v.astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if quantized:
-                s = s * _dequant(ks_buf[slot], nr)
-            valid = valid_of(gi * group + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1), s.shape)
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            # exp hits exact 0 on masked cols only while the row has a
-            # valid one; a row of the block outside the span has none
-            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-            # pool rows past `kvlen` may hold another block's garbage (or
-            # a clamped entry's), and 0 * NaN is NaN: zero them out of PV,
-            # in the one group that can hold any
-            v = jax.lax.cond(
-                (gi + 1) * group > kvlen,
-                lambda v: jnp.where(
+
+            # pool rows past `kvlen` may hold another block's garbage (or a
+            # clamped entry's), and 0 * NaN is NaN: zero them out of PV, in
+            # the one group that can hold any
+            @pl.when((gi + 1) * group > kvlen)
+            def _zero_stale():
+                v = v_buf[slot]
+                v_buf[slot] = jnp.where(
                     gi * group + jax.lax.broadcasted_iota(
-                        jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v)),
-                lambda v: v, v)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:] = jnp.broadcast_to(
-                alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-                l_ref.shape)
-            if quantized:
-                # V dequant, same separability: fold the scales into P
-                # (P_wj * sv[j, head(w)]) and dot with the raw values
-                p = p * _dequant(vs_buf[slot], nr)
-            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+                        jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v))
+                if quantized:
+                    # (their scales too: a plane row is a key's, or for
+                    # fp8 a page's, dead where its first key is)
+                    f = vs_buf[slot]
+                    per = block_k if quantized == "fp8" else 1
+                    vs_buf[slot] = jnp.where(
+                        gi * group + per * jax.lax.broadcasted_iota(
+                            jnp.int32, f.shape, 0) < kvlen, f,
+                        jnp.zeros_like(f))
+
+            update(gi, slot)
             return carry
 
-        # exactly this pair's blocks, ascending, `pages` an update: the
-        # row's own length and the causal diagonal both already bound nkb
-        # (_work_list); what the last group holds past them is masked
         jax.lax.fori_loop(0, n_groups, _group, 0)
-        write(acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30))
 
-    # a span of ONE token (a decode row) is ``gh`` wide rows at a multiple
-    # of ``gh`` inside the query block: it computes on those rows alone,
-    # not on the block's other tokens, which belong to other rows (where
-    # ``gh`` rows are whole tiles; else every span takes the general walk)
-    short_walk = _one_token_walk(gh, tq)
-    alone = (qlen == 1) if short_walk else False
+    def _softmax_update(s, valid, v, m_ref, l_ref, acc_ref):
+        # one online-softmax update of the state ``m / l / acc`` (ref views)
+        # with the scores ``s`` of a group of keys and their values ``v``
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # exp hits exact 0 on masked cols only while the row has a valid
+        # one; a row of the block outside the span has none
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_ref.shape)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    if short_walk:
-        @pl.when((nkb > 0) & alone)
-        def _one_token():
-            off = pl.multiple_of(span_lo - row0, gh)
-
-            def write(out):
-                o_ref[pl.ds(off, gh), :] = out.astype(o_ref.dtype)
-
-            _walk(gh, lambda: q_ref[pl.ds(off, gh), :],
-                  lambda cols, shape: cols < kvlen, write)
+    def _reset(m_ref, l_ref, acc_ref):
+        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     @pl.when((nkb > 0) & jnp.logical_not(alone))
     def _span():
-        def valid_of(cols, shape):
-            # causal-within-span: wide row w belongs to span token
-            # (w - span_lo) // gh, whose logical position is
-            # kvlen - qlen + that token index
-            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-            pos = kvlen - qlen + (wrow - span_lo) // gh
-            return (wrow >= span_lo) & (wrow < span_hi) & (cols <= pos)
+        # the general walk, on every plane of the query block: head k's
+        # scores and P V from its own D lanes of the group, [rows, D] x
+        # [D, group] and [rows, group] x [group, D]
+        _reset(m_scr, l_scr, acc_scr)
+        # causal-within-span, the same for every head: plane row j belongs
+        # to span token (j - span_lo) // g, whose logical position is
+        # kvlen - qlen + that token index; a row of the block outside the
+        # span sees no key
+        prow = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        in_span = (prow >= span_lo) & (prow < span_hi)
+        tok = prow - span_lo
+        if g > 1:
+            tok = tok // g
+        pos = jnp.where(in_span, kvlen - qlen + tok, -1)
 
-        def write(out):
-            # write back ONLY this row's span: the output block is shared
-            # by every sequence whose span intersects it, so the write is a
-            # masked read-modify-write (rows not in span keep their value)
-            wrow = row0 + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
-            o_ref[:] = jnp.where((wrow >= span_lo) & (wrow < span_hi),
-                                 out.astype(o_ref.dtype), o_ref[:])
+        def update(gi, slot):
+            valid = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, group), 1) <= pos - gi * group
+            for k in range(hkv):
+                s = jax.lax.dot_general(
+                    q_ref[k], _head_rows(k_buf, slot, ks_buf, k),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                _softmax_update(s, valid, _head_rows(v_buf, slot, vs_buf, k),
+                                m_scr.at[k], l_scr.at[k], acc_scr.at[k])
 
-        _walk(tq, lambda: q_ref[:], valid_of, write)
+        _groups(update)
+        # write back ONLY this row's span: the output block is shared by
+        # every sequence whose span intersects it, so the write is a masked
+        # read-modify-write (rows not in the span keep their value)
+        for k in range(hkv):
+            out = acc_scr[k] / jnp.maximum(l_scr[k, :, :1], 1e-30)
+            o_ref[k] = jnp.where(in_span, out.astype(o_ref.dtype), o_ref[k])
+
+    if not tile:
+        return
+
+    @pl.when((nkb > 0) & alone)
+    def _one_token():
+        # a span of ONE token (a decode row) is bound by its KV bytes: a
+        # batched matrix-vector product whose G rows a KV head would each
+        # make a product of their own, Hkv small products an update. It
+        # takes ONE product over the whole pool row instead: the token's H
+        # query rows, cut out of the head-major block once a pair, laid
+        # block-diagonal ([H, KD], head h's D values at its KV head's lanes)
+        # in VMEM, so the zeros it multiplies cost the MXU nothing it would
+        # not idle through and the softmax works on H rows, not on a row
+        # tile a plane.
+        hp = qw_scr.shape[0]
+        # the token's rows lie in the aligned row tile at ``toff`` of every
+        # plane, from row ``first`` of the tile
+        toff = pl.multiple_of((span_lo - row0) // tile * tile, tile)
+        first = span_lo - row0 - toff
+        at = pl.ds(toff, tile)
+        trow = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        for k in range(hkv):
+            t = q_ref[k, at, :].astype(jnp.float32)
+            for j in range(g):
+                # (a masked sum over the tile's rows: exact, one term)
+                qw_scr[pl.ds(k * g + j, 1), k * d:(k + 1) * d] = jnp.sum(
+                    jnp.where(trow == first + j, t, 0.0), axis=0,
+                    keepdims=True)
+        _reset(m1_scr, l1_scr, acc1_scr)
+
+        def pool_rows(buf, scales, slot):
+            # the whole fetched group [group, KD]; a quantized pool's head
+            # windows upcast and scaled one by one (``_head_rows``) and laid
+            # side by side again
+            if not quantized:
+                return buf[slot]
+            return jnp.concatenate(
+                [_head_rows(buf, slot, scales, k) for k in range(hkv)],
+                axis=1)
+
+        def update(gi, slot):
+            qw = qw_scr[:] if quantized else qw_scr[:].astype(q_ref.dtype)
+            s = jax.lax.dot_general(
+                qw, pool_rows(k_buf, ks_buf, slot), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            valid = gi * group + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, group), 1) < kvlen
+            _softmax_update(s, valid, pool_rows(v_buf, vs_buf, slot),
+                            m1_scr, l1_scr, acc1_scr)
+
+        _groups(update)
+        acc1_scr[:] = acc1_scr[:] / jnp.maximum(l1_scr[:, :1], 1e-30)
+        for k in range(hkv):
+            # head k * g + j's own D lanes back into the token's rows of
+            # plane k; the tile's other rows belong to other spans
+            new = o_ref[k, at, :].astype(jnp.float32)
+            for j in range(g):
+                new = jnp.where(
+                    trow == first + j,
+                    acc1_scr[pl.ds(k * g + j, 1), k * d:(k + 1) * d], new)
+            o_ref[k, at, :] = new.astype(o_ref.dtype)
+
+
+#: rows of the smallest row tile every query dtype loads whole (bf16 packs
+#: 16 rows a tile)
+_ROW_TILE = 16
+
+
+def _token_tile(plane_rows, g):
+    """The aligned row tile of a plane that holds a token's ``g`` rows (16,
+    or ``g`` where that is several tiles), where a query block of
+    ``plane_rows`` is whole such tiles and no token straddles two; else 0:
+    the kernel then has no walk of its own for a span of one token."""
+    tile = max(_ROW_TILE, g)
+    whole = tile % g == 0 and tile % _ROW_TILE == 0
+    return tile if whole and plane_rows % tile == 0 else 0
 
 
 def _one_token_walk(gh, tq):
     """Whether a span of one token computes on its own ``gh`` wide rows
-    alone: where those are whole tiles and fewer than the query block."""
+    alone in a kernel that tiles WIDE rows (``pallas_mla_ragged_attention``):
+    where those are whole tiles and fewer than the query block."""
     return gh % 16 == 0 and gh < tq
 
 
-#: what an online-softmax update takes: at most this many keys, fewer where
-#: the K and V buffers of that many (two slots each) would pass these bytes.
-#: Settled on the chip (PERF.md, PR 32): past 256 keys the group's masked
-#: tail (half a group a pair, fetched and multiplied for nothing) costs a
-#: short walk more than the fewer rescales of the accumulator save a long one
+#: what an online-softmax update takes: at most ``_GROUP_KEYS`` keys, fewer
+#: where the K and V buffers of that many (two slots each) would pass
+#: ``_GROUP_BYTES``, never fewer than ``_LANE_KEYS``. Settled on the chip
+#: (PERF.md, PR 32 and 36): a decode row fetches half a group past its end, so
+#: past 256 keys, and at a wide row past 128, the dead tail costs a short
+#: walk more than the fewer rescales save a long one; under a lane tile of
+#: keys a head's score tile wastes the lanes it has (Olmo-Hybrid's chunk
+#: kernel 1.84 ms at 64 keys an update, 1.45 at 128)
 _GROUP_KEYS = 256
 _GROUP_BYTES = 2 << 20
+_LANE_KEYS = 128
+
+#: what a query block holds: at most this many rows of one KV head's plane
+#: (one head's float32 score tile is that by a group of keys), fewer where
+#: the float32 accumulator of all planes would pass these bytes
+_PLANE_ROWS = 512
+_ACC_BYTES = 2 << 20
+
+#: the scoped VMEM the call asks for: the accumulator, the softmax state at
+#: a lane tile a row, two query and two output blocks, the K and V buffers
+#: and a few score tiles (14 MiB at Mistral's geometry, 16 at Olmo-Hybrid's)
+_VMEM_BYTES = 48 << 20
 
 
 def pages_per_update(pool_dtype, block_size, kd, table_entries):
     """Table entries one online-softmax update fetches and computes on
     together, from what a call can observe: ``_GROUP_KEYS`` keys' worth of
-    pool blocks, fewer where a row is wide (8 for Mistral's ``KD`` 1024 in
-    bf16 at blocks of 32, 4 for OLMoE's 2048), never more than the table
-    holds. A one-byte pool counts at four bytes: it is upcast to float32 in
-    VMEM on its way into the MXU."""
+    pool blocks, fewer where a row is wide but no fewer than a lane tile of
+    keys (8 blocks of 32 for Mistral's ``KD`` 1024 in bf16, 4 for OLMoE's
+    2048 and Olmo-Hybrid's 3840), never more than the table holds. A
+    one-byte pool counts at four bytes: a head's window is upcast to float32
+    in VMEM on its way into the MXU."""
     itemsize = jnp.dtype(pool_dtype).itemsize
     row = 4 * int(kd) * (4 if itemsize == 1 else itemsize)
-    keys = min(_GROUP_KEYS, _GROUP_BYTES // row)
+    keys = max(_LANE_KEYS, min(_GROUP_KEYS, _GROUP_BYTES // row))
     return max(1, min(keys // int(block_size), int(table_entries)))
 
 
-def query_block_rows(kd):
-    """Wide rows of a query block (before ``_query_block`` fits it to the
-    heads and the packed buffer): 512 up to Mistral's ``KD`` 1024, where a
-    chunk re-reads its prefix once every 16 tokens and not every 8 and the
-    kernel of a 512-token chunk 3 k into its prompt is a sixth shorter than
-    at 256 (PERF.md, PR 32); fewer for wider rows (256 at OLMoE's 2048, 128
-    at 4096), so that the two query and two output blocks and the float32
-    accumulator keep the 6 MiB of scoped VMEM they have there."""
-    return min(512, (512 << 10) // int(kd))
+def query_block_rows(kd, heads, head_dim):
+    """(Token, head) rows of a query block (before ``_query_block`` fits it
+    to the packed buffer), from the per-head accumulator and score tile: a
+    KV head's plane holds ``_PLANE_ROWS`` rows of the block, fewer where the
+    accumulator ``[Hkv, rows, D]`` would pass ``_ACC_BYTES``, in whole row
+    tiles of whole tokens. 128 tokens at Mistral's 32 / 8 / 128 (a 512-token
+    chunk walks its prefix 4 or 5 times), 256 at OLMoE's 16 / 16 / 128, 128
+    at Olmo-Hybrid's 30 / 30 / 128."""
+    g = int(heads) * int(head_dim) // int(kd)
+    rows = min(_PLANE_ROWS, _ACC_BYTES // (4 * int(kd)))
+    tokens = max(_ROW_TILE, rows // g // _ROW_TILE * _ROW_TILE)
+    return tokens * int(heads)
 
 
 def _least(a, b):
@@ -397,9 +521,10 @@ def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
     return wq, wr, first.astype(jnp.int32), wn
 
 
-def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
-                 scale, gh, block_q, pages, interpret, scales=None):
-    """q_wide: [TH, KD] block-diagonal wide rows (gh per token);
+def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
+                 scale, g, block_q, pages, interpret, scales=None):
+    """q_hm: [Hkv, T * g, D] head-major planes (row ``t * g + j`` of plane
+    ``k`` is head ``k * g + j`` of token ``t``);
     pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole;
     layer: [1] int32, the layer whose blocks this call reads;
     tables: [R, max_blocks] int32;
@@ -408,42 +533,43 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     DMA): [L, num_blocks, bs, Hkv] per-row planes select the int8 path,
     [L, num_blocks, Hkv] per-block planes select fp8 — the plane rank IS
     the mode switch, same convention as ``pallas_paged_decode``;
-    block_q, pages: the call's tiling (``grid_params``).
+    block_q, pages: the call's tiling (``grid_params``), the query block in
+    (token, head) rows.
 
     The grid is the work list (``_work_list``): one step per (query block,
     row) pair, and inside it a loop over exactly the pair's KV blocks,
     fetched at ``(layer, table entry)``, ``pages`` of them an iteration."""
-    TH, KD = q_wide.shape
+    hkv, TG, D = q_hm.shape
+    KD = pool_k.shape[-1]
     num_blocks, bs = pool_k.shape[1], pool_k.shape[2]
     R, nk = tables.shape
-    nq = -(-TH // block_q)          # the last block may be partial
-    work = _work_list(qstart, qlen, kvlen, nq=nq,
-                      tokens_per_block=block_q // gh, block_size=bs,
-                      table_entries=nk)
+    tokens = block_q // (hkv * g)   # a query block's tokens
+    nq = -(-TG // (tokens * g))     # the last block may be partial
+    rows = _plane_rows(block_q, hkv * g, g, TG // g)
+    work = _work_list(qstart, qlen, kvlen, nq=nq, tokens_per_block=tokens,
+                      block_size=bs, table_entries=nk)
     if scales is None:
         quantized = False
     else:
         quantized = "fp8" if scales[0].ndim == 3 else "int8"
-    hkv = scales[0].shape[-1] if quantized else 0
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
-                               pages=pages, tq=block_q, gh=gh,
+                               pages=pages, tq=block_q, g=g,
                                num_blocks=num_blocks, table_entries=nk,
-                               quantized=quantized, hkv=hkv)
+                               quantized=quantized)
 
     def _q_index(w, wq, *_):
-        return (wq[w], 0)
+        return (0, wq[w], 0)
 
     in_pool = pl.BlockSpec(memory_space=pl.ANY)     # fetched by the kernel
-    in_specs = [pl.BlockSpec((block_q, KD), _q_index), in_pool, in_pool]
-    args = [*work, qstart, qlen, kvlen, tables, layer, q_wide, pool_k,
-            pool_v]
+    in_specs = [pl.BlockSpec((hkv, rows, D), _q_index), in_pool, in_pool]
+    args = [*work, qstart, qlen, kvlen, tables, layer, q_hm, pool_k, pool_v]
     bufs = [pltpu.VMEM((2, pages * bs, KD), pool_k.dtype),
             pltpu.VMEM((2, pages * bs, KD), pool_v.dtype)]
     if quantized:
         # per-row int8 planes [nb, bs, hkv] move one [bs, hkv] block a page,
         # per-BLOCK fp8 planes [nb, hkv] one [1, hkv] row. A DMA window's
         # minor dim must be whole lanes, so this layer's planes are cut out
-        # and padded to 128 heads here and the kernel reads the first hkv
+        # and padded to 128 heads here and the kernel reads column k
         lanes = -(-hkv // 128) * 128
         scales = [jnp.pad(jax.lax.dynamic_index_in_dim(p, layer[0], 0, False),
                           [(0, 0)] * (p.ndim - 2) + [(0, lanes - hkv)])
@@ -452,37 +578,37 @@ def _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
         in_specs += [in_pool, in_pool]
         args += scales
         bufs += [pltpu.VMEM((2, plane, lanes), p.dtype) for p in scales]
-    out = pl.pallas_call(
+    scratch = bufs + [
+        pltpu.SemaphoreType.DMA((len(bufs), 2, pages)),
+        pltpu.VMEM((hkv, rows, 128), jnp.float32),
+        pltpu.VMEM((hkv, rows, 128), jnp.float32),
+        pltpu.VMEM((hkv, rows, D), jnp.float32)]
+    if _token_tile(rows, g):
+        # the one-token walk's block-diagonal query [H, KD] and its softmax
+        # state, the heads rounded up to whole row tiles
+        hp = -(-hkv * g // _ROW_TILE) * _ROW_TILE
+        scratch += [pltpu.VMEM((hp, KD), jnp.float32),
+                    pltpu.VMEM((hp, 128), jnp.float32),
+                    pltpu.VMEM((hp, 128), jnp.float32),
+                    pltpu.VMEM((hp, KD), jnp.float32)]
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=9,
             grid=(nq + R,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((block_q, KD), _q_index),
-            scratch_shapes=bufs + [
-                pltpu.SemaphoreType.DMA((len(bufs), 2, pages)),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, KD), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((hkv, rows, D), _q_index),
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((TH, KD), q_wide.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_hm.shape, q_hm.dtype),
         # consecutive entries revisit one output block (accumulated
         # across rows by the masked write) — no reordering allowed
-        compiler_params=_cparams(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
         name="ragged_paged_attention",
     )(*args)
-    return out
-
-
-def wide_rows(heads):
-    """Wide rows a token in the call: its heads, or from 8 up the next
-    multiple of 8 where the count is none (30 -> 32), so that ``[T, rows,
-    KD]`` and ``[T * rows, KD]`` are one layout and a query block is whole
-    sublane groups; the added rows are zero queries whose outputs are
-    dropped (``_ragged_padded_heads``)."""
-    return heads if heads % 8 == 0 or heads < 8 else -(-heads // 8) * 8
 
 
 def _query_block(block_q, heads, packed_tokens):
@@ -492,24 +618,41 @@ def _query_block(block_q, heads, packed_tokens):
                           packed_tokens * heads))
 
 
+def _plane_rows(block_q, heads, g, packed_tokens):
+    """Rows of one KV head's plane in a query block of ``block_q`` (token,
+    head) rows: ``g`` a token; the one block of a buffer it covers is whole
+    row tiles (it may reach past the buffer, like any last block)."""
+    tokens = block_q // heads
+    rows = tokens * g
+    return -(-rows // _ROW_TILE) * _ROW_TILE if tokens >= packed_tokens \
+        else rows
+
+
 def grid_params(pool_dtype, block_size, kd, table_entries, heads,
-                packed_tokens, block_q=None, pages=None):
-    """The tiling of one call, ``{"block_q", "pages"}``: the query block in
-    wide rows as the call cuts it and the table entries one online-softmax
-    update takes, from what the call observes (``block_q`` / ``pages`` given:
-    fitted like the derived ones). The ONE derivation:
-    ``ragged_paged_attention_pallas`` tiles with it and the engine passes it
-    to ``ragged_grid_counts``, so the host's counts are the kernel's."""
+                packed_tokens, block_q=None, pages=None, *, head_dim):
+    """The tiling of one call, ``{"block_q", "pages", "one_token"}``: the
+    query block in (token, head) rows as the call cuts it, the table entries
+    one online-softmax update takes, and whether a span of one token
+    computes on its own row tile (``_token_tile``), from what the call
+    observes (``block_q`` / ``pages`` given: fitted like the derived ones).
+    The ONE derivation: ``ragged_paged_attention_pallas`` tiles with it and
+    the engine passes it to ``ragged_grid_counts``, so the host's counts are
+    the kernel's."""
     if block_q is None:
-        block_q = query_block_rows(kd)
+        block_q = query_block_rows(kd, heads, head_dim)
     if pages is None:
         pages = pages_per_update(pool_dtype, block_size, kd, table_entries)
-    return {"block_q": _query_block(block_q, heads, packed_tokens),
-            "pages": max(1, min(int(pages), int(table_entries)))}
+    block_q = _query_block(block_q, heads, packed_tokens)
+    g = int(heads) * int(head_dim) // int(kd)
+    return {"block_q": block_q,
+            "pages": max(1, min(int(pages), int(table_entries))),
+            "one_token": bool(_token_tile(
+                _plane_rows(block_q, heads, g, packed_tokens), g))}
 
 
 def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
-                       table_entries, packed_tokens, block_q=256, pages=1):
+                       table_entries, packed_tokens, block_q=256, pages=1,
+                       one_token=False):
     """What one call of the kernel is asked to do, counted on the host from
     the step's span metadata (plain integers; no jax): ``grid_steps``, the
     steps the kernel visits — the ``nq + R`` work-list entries of its grid
@@ -518,10 +661,12 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
     zeroes, resets or writes back); ``update_steps``, the online-softmax
     updates that takes at ``pages`` blocks an update (``pages_per_update``;
     a pair's last group may hold fewer); ``one_token_rows``, the rows that
-    compute on their own ``heads`` wide rows and not on the query block
-    (spans of one token, where the kernel has that walk); ``kv_tokens``, the
-    cache rows the live spans attend over; ``attn_pairs``, their causal
-    (query, key) pairs. A row with ``qlen == 0`` is dead."""
+    compute on their own rows and not on the query block (spans of one
+    token, where the kernel has that walk: ``one_token``, as the kernel's
+    ``grid_params`` gives it); ``kv_tokens``, the cache rows the live spans
+    attend
+    over; ``attn_pairs``, their causal (query, key) pairs. A row with
+    ``qlen == 0`` is dead."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
     tpb = bq // heads
@@ -532,7 +677,7 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
             continue
         kv_tokens += kl
         pairs += ql * (kl - ql) + ql * (ql + 1) // 2
-        alone += ql == 1 and _one_token_walk(heads, bq)
+        alone += ql == 1 and one_token
         for qi in range(qs // tpb, min(nq, -(-(qs + ql) // tpb))):
             n = _pair_kv_blocks(
                 qs, ql, kl, qi, tokens_per_block=tpb,
@@ -549,20 +694,20 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
 # pallas_calls don't linearize in interpret mode. ``scales`` is ``()`` or
 # the ``(k_scale, v_scale)`` planes of a quantized pool.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
-def _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart, qlen,
-            kvlen, scale, gh, block_q, pages):
-    return _ragged_call(q_wide, pool_k, pool_v, layer, tables, qstart, qlen,
-                        kvlen, scale, gh, block_q, pages, _interpret_mode(),
+def _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
+            kvlen, scale, g, block_q, pages):
+    return _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen,
+                        kvlen, scale, g, block_q, pages, _interpret_mode(),
                         scales=scales or None)
 
 
-def _ragged_fwd_rule(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                     qlen, kvlen, scale, gh, block_q, pages):
-    return _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                   qlen, kvlen, scale, gh, block_q, pages), None
+def _ragged_fwd_rule(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
+                     qlen, kvlen, scale, g, block_q, pages):
+    return _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
+                   qlen, kvlen, scale, g, block_q, pages), None
 
 
-def _ragged_bwd_rule(scale, gh, block_q, pages, res, g):
+def _ragged_bwd_rule(scale, g, block_q, pages, res, ct):
     raise NotImplementedError(
         "ragged_paged_attention_pallas is inference-only (the serving "
         "step never backpropagates)")
@@ -618,18 +763,20 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
               stays full-precision
     returns:  [T, H, D]; packed rows outside every span are exact zeros
 
-    GQA is resolved with the block-diagonal wide-query trick (see
-    ``pallas_decode.py``). The kernel iterates over a work list of the
+    GQA is resolved by the layout: the query goes in head-major, ``[Hkv,
+    T * G, D]`` (a transpose of ``T * H * D`` elements each way), and the
+    kernel multiplies each KV head's keys by that head's ``G`` queries a
+    token only. The kernel iterates over a work list of the
     (query block, row) pairs that intersect, built here from ``qstart`` /
     ``qlen``, and for each pair over the KV blocks up to the row's
     ``kvlen`` and the causal diagonal: blocks past either are never
     fetched, dead rows and non-intersecting pairs are never visited;
     sentinel table entries clamp harmlessly. ``block_q`` (the query block's
-    wide rows; None: ``query_block_rows``) and ``pages`` (table entries an
-    online-softmax update takes; None: ``pages_per_update``) are for tests:
-    the step programs pass neither. At ``pages=1`` a span of length 1
-    reproduces ``paged_decode_attention_pallas`` for that row exactly (same
-    block walk, same online-softmax accumulation order).
+    (token, head) rows; None: ``query_block_rows``) and ``pages`` (table
+    entries an online-softmax update takes; None: ``pages_per_update``) are
+    for tests: the step programs pass neither. A span of length 1 is
+    ``paged_decode_attention_pallas``'s row within float32 rounding (the
+    same mathematics, a head's sums taken on their own).
     """
     T, H, D = q.shape
     pool_k, pool_v, scales, layer = _stored_pool(pool_k, pool_v, k_scale,
@@ -643,65 +790,15 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     qlen = jnp.asarray(qlen, jnp.int32).reshape(-1)
     kvlen = jnp.asarray(kvlen, jnp.int32).reshape(-1)
     tables = jnp.asarray(tables, jnp.int32).reshape(qstart.shape[0], -1)
-    # block-diagonal wide query: head h's D values at its kv group's
-    # lanes, one wide row per (token, head)
-    rows = wide_rows(H)
-    if rows != H:
-        return _ragged_padded_heads(
-            q, pool_k, pool_v, scales, layer, tables, qstart, qlen, kvlen,
-            scale, rows, block_q, pages)
-    eye = jnp.eye(Hkv, dtype=q.dtype)
-    q_wide = jnp.einsum("bkgd,kj->bkgjd", q.reshape(T, Hkv, G, D), eye)
-    q_wide = q_wide.reshape(T * H, KD)
-    # the query block is a multiple of H, so //gh never crosses a token; the
-    # last one may reach past the packed buffer (no pad, no copy: the rows
-    # it holds past the end belong to no span and are neither computed on
-    # nor written back)
+    # the query block is whole tokens; the last one may reach past the
+    # packed buffer (no pad, no copy: the rows it holds past the end belong
+    # to no span and are neither computed on nor written back)
     tiling = grid_params(pool_k.dtype, pool_k.shape[2], KD, tables.shape[1],
-                         H, T, block_q, pages)
-    out_wide = _ragged(q_wide, pool_k, pool_v, scales, layer, tables, qstart,
-                       qlen, kvlen, scale, H, tiling["block_q"],
-                       tiling["pages"])
-    # extract each head's own kv-group block from the wide accumulator
-    out = jnp.einsum("bkgjd,kj->bkgd",
-                     out_wide.reshape(T, Hkv, G, Hkv, D), eye)
-    return out.reshape(T, H, D)
-
-
-def _ragged_padded_heads(q, pool_k, pool_v, scales, layer, tables, qstart,
-                         qlen, kvlen, scale, rows, block_q, pages):
-    """``ragged_paged_attention_pallas`` for a head count that is no whole
-    sublane group (``wide_rows``: 30 -> ``rows`` 32). The block-diagonal
-    wide query is made, and the heads' own blocks taken back out of the wide
-    output, WITHOUT an array whose minor dims are ``(Hkv, D)``: at 30 KV
-    heads those tile to 32 x 128 and every reshape between them and the
-    ``KD`` lanes the kernel reads moves the whole 130 MB (a fifth of the
-    attention's time: PERF.md, PR 33). Instead the query, its heads padded
-    (4 MB), is spread over the lanes by a 0 / 1 selection matmul (``D`` ->
-    ``KD``, exact in any dtype: one non-zero term a sum) and masked to its
-    own kv group's lanes; the output is masked and folded back by the
-    transposed selection. The added rows are zero queries whose outputs are
-    dropped."""
-    T, H, D = q.shape
-    KD = pool_k.shape[-1]
-    G = H // (KD // D)
-    lane = jnp.arange(KD, dtype=jnp.int32)
-    spread = (lane[None, :] % D == jnp.arange(D, dtype=jnp.int32)[:, None]
-              ).astype(q.dtype)                                  # [D, KD]
-    own = (lane[None, :] // D
-           == jnp.arange(rows, dtype=jnp.int32)[:, None] // G)   # [rows, KD]
-    q = jnp.pad(q, ((0, 0), (0, rows - H), (0, 0))).reshape(T * rows, D)
-    q_wide = jnp.where(own, jnp.dot(q, spread).reshape(T, rows, KD), 0)
-    tiling = grid_params(pool_k.dtype, pool_k.shape[2], KD, tables.shape[1],
-                         rows, T, block_q, pages)
-    out_wide = _ragged(q_wide.reshape(T * rows, KD), pool_k, pool_v, scales,
-                       layer, tables, qstart, qlen, kvlen, scale, rows,
-                       tiling["block_q"], tiling["pages"])
-    out_wide = jnp.where(own, out_wide.reshape(T, rows, KD), 0)
-    out = jnp.dot(out_wide.reshape(T * rows, KD), spread.T)
-    # (the barrier keeps XLA from moving the cut of the padded heads above
-    # the fold, where it would copy the wide output to take it)
-    return jax.lax.optimization_barrier(out).reshape(T, rows, D)[:, :H]
+                         H, T, block_q, pages, head_dim=D)
+    q_hm = q.reshape(T, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, T * G, D)
+    out = _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
+                  kvlen, scale, G, tiling["block_q"], tiling["pages"])
+    return out.reshape(Hkv, T, G, D).swapaxes(0, 1).reshape(T, H, D)
 
 
 def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,

@@ -64,7 +64,7 @@ from ..kernels.pallas_mla_ragged_attention import (
     mla_ragged_attention_pallas, mla_ragged_attention_reference)
 from ..kernels.pallas_ragged_attention import (
     grid_params as _ragged_grid_params, ragged_attention_reference,
-    ragged_paged_attention_pallas, wide_rows)
+    ragged_paged_attention_pallas)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -113,25 +113,19 @@ _GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
 TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers")
 
 
-def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1):
-    """``{"block_q", "pages"}`` of the attention kernel that
-    ``_packed_span_forward`` runs on this tree over the stored ``pool``
-    ``[L, num_blocks, bs, KD]`` (a chip's share is ``KD // tp``): the
-    kernel's own ``grid_params`` of what its call will see (``heads`` as
-    ``attention_rows`` gives them), so the engine's ``ragged_grid_counts``
-    counts the grid the step really runs."""
+def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1,
+                   *, head_dim):
+    """The tiling (``block_q``, ``pages`` and, for the dense kernel,
+    ``one_token``) of the attention kernel that ``_packed_span_forward``
+    runs on this tree over the stored ``pool`` ``[L, num_blocks, bs, KD]``
+    (a chip's share is ``KD // tp``): the kernel's own ``grid_params`` of
+    what its call will see, the heads as they are, so the engine's
+    ``ragged_grid_counts`` counts the grid the step really runs."""
     if "wkv_a" in params:
         return _mla_grid_params(table_entries, heads, packed_tokens)
     return _ragged_grid_params(
         pool.dtype, pool.shape[2], pool.shape[3] // tp, table_entries,
-        wide_rows(heads), packed_tokens)
-
-
-def attention_rows(params, heads):
-    """The wide rows a token that the tree's attention kernel makes of
-    ``heads`` query heads (``pallas_ragged_attention.wide_rows``: 30 -> 32;
-    the latent kernel takes them as they are)."""
-    return heads if "wkv_a" in params else wide_rows(heads)
+        heads, packed_tokens, head_dim=head_dim)
 
 
 #: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not

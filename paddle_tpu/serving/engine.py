@@ -53,7 +53,7 @@ import numpy as np
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
-from .decode import attention_grid, attention_rows, \
+from .decode import attention_grid, \
     build_paged_suffix_prefill_fn, \
     build_prefill_fn, build_ragged_step_fn, TAUGHT_KEYS, latent_row_width
 from .kv_cache import PagedKVCache, PoolExhausted
@@ -842,12 +842,11 @@ class ContinuousBatchingEngine:
         heads = self.config.num_attention_heads // self._tp
         work = ragged_grid_counts(
             qstart, qlen, kvlen, packed_tokens=packed,
-            heads=attention_rows(self._params, heads),
-            block_size=self.cache.block_size,
+            heads=heads, block_size=self.cache.block_size,
             table_entries=self.cache.max_blocks,
             **attention_grid(self._params, self.cache.pool.k,
                              self.cache.max_blocks, heads, packed,
-                             tp=self._tp))
+                             tp=self._tp, head_dim=self.config.head_dim))
         work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
                     prefill_tokens=prefill_tokens)
         if self._stateful:

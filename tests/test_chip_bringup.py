@@ -277,7 +277,8 @@ class TestMosaicCompilesOlmoHybrid:
 
     def test_ragged_attention_at_30_heads(self, v5e):
         """The first head count that is no power of two, and the widest pool
-        row: the kernel tiles 4 tokens of 30 wide rows and needs no padding."""
+        row: 30 planes of a head-major query, 128 tokens a query block, and
+        no padding."""
         i32, hd, mb = jnp.int32, 128, 72
 
         def attend(q, pk, pv, tables, qs, ql, kl, layer):
@@ -290,8 +291,9 @@ class TestMosaicCompilesOlmoHybrid:
             v5e((self.R,), i32), v5e((), i32))
         assert n == 1
         assert pallas_ragged_attention.grid_params(
-            jnp.bfloat16, 32, self.H * hd, mb, self.H, self.T)["block_q"] \
-            % self.H == 0
+            jnp.bfloat16, 32, self.H * hd, mb, self.H, self.T,
+            head_dim=hd) == dict(block_q=128 * self.H, pages=4,
+                                 one_token=True)
 
 
 class TestUnifiedStepLeavesThePoolInPlace:

@@ -361,6 +361,21 @@ def test_generate_matches_forward_greedy():
     assert out[17:].tolist() == want.tolist()
 
 
+def _unified_step_text(eng):
+    """The lowered text of the engine's unified step at its larger packed
+    size (a hybrid model's store is its last argument)."""
+    R, T = SLOTS, eng._token_budget
+    i32 = np.int32
+    args = (eng._params, *eng.cache.kv_args(), eng.cache.tables,
+            np.zeros(T, i32), np.full(T, R, i32), np.zeros(T, i32),
+            np.zeros(R, i32), np.zeros(R, i32), np.zeros(R, i32),
+            np.zeros(R, i32), eng._keys, np.zeros(R, np.float32),
+            np.zeros(R, i32), eng._no_toks, np.zeros(R, i32),
+            np.zeros((R, 2), np.uint32), np.zeros(R, i32),
+            *((eng.cache.state,) if eng._stateful else ()))
+    return eng._ragged_fn(1, T).lower(*args).as_text()
+
+
 @pytest.mark.parametrize("make", [
     lambda: LlamaForCausalLM(llama_tiny()),
     lambda: OlmoeForCausalLM(olmoe_tiny()),
@@ -373,27 +388,54 @@ def test_other_models_programs_take_no_store(make):
     paddle.seed(0)
     eng = ContinuousBatchingEngine(make(), jit_cache={}, **GEOMETRY)
     assert not eng._stateful and "gdn" not in eng._fn_consts()
-    R, T = SLOTS, eng._token_budget
-    i32 = np.int32
-    args = (eng._params, *eng.cache.kv_args(), eng.cache.tables,
-            np.zeros(T, i32), np.full(T, R, i32), np.zeros(T, i32),
-            np.zeros(R, i32), np.zeros(R, i32), np.zeros(R, i32),
-            np.zeros(R, i32), eng._keys, np.zeros(R, np.float32),
-            np.zeros(R, i32), eng._no_toks, np.zeros(R, i32),
-            np.zeros((R, 2), np.uint32), np.zeros(R, i32))
-    text = eng._ragged_fn(1, T).lower(*args).as_text()
-    assert "gdn_" not in text
+    assert "gdn_" not in _unified_step_text(eng)
+
+
+@pytest.mark.parametrize("make", [
+    lambda: LlamaForCausalLM(llama_tiny(decode_attention="pallas")),
+    lambda: _model("pallas"),
+], ids=["tiny_mistral", "tiny_olmo_hybrid"])
+def test_unified_step_makes_no_wide_query(make):
+    """The lowered unified step holds no array with ``Hkv * D`` values a
+    (token, head): the query goes into the ragged kernel head-major, two
+    transposes of ``T * H * D`` elements, and neither a block-diagonal wide
+    query nor a wide output ``[T * H, KD]`` is made on the way (for a head
+    count padded to whole sublane groups either, the parent's 30 -> 32)."""
+    import re
+    paddle.seed(0)
+    eng = ContinuousBatchingEngine(make(), jit_cache={}, **GEOMETRY)
+    c, T = eng.config, eng._token_budget
+    text = _unified_step_text(eng)
+    nh, kd = c.num_attention_heads, c.num_key_value_heads * c.head_dim
+    wide = {T * rows * kd for rows in (nh, -(-nh // 8) * 8)}
+    shapes = {tuple(int(d) for d in m.split("x")[:-1])
+              for m in re.findall(r"tensor<((?:\d+x)+[a-z]+\d*)>", text)}
+    # (arrays whose minor dim is a head's or a pool row's: the FFN's
+    # [T, intermediate] may have as many elements)
+    sizes = {int(np.prod(sh)) for sh in shapes
+             if sh[-1] in (c.head_dim, kd)}
+    assert T * nh * c.head_dim in sizes         # the query itself is there
+    assert not wide & sizes, sorted(wide & sizes)
 
 
 @pytest.mark.parametrize("nh,nkv", [(12, 12), (12, 4), (30, 30)])
-def test_ragged_attention_pads_head_counts_that_are_no_sublane_group(nh, nkv):
+def test_ragged_attention_pads_no_head_count(nh, nkv, monkeypatch):
     """30 heads are the first count in the benchmark that is no multiple of
-    8: the kernel's wrapper makes 32 wide rows a token of them
-    (``wide_rows``) and the result is the oracle's, MHA and GQA alike."""
+    8: the heads are a LEADING dimension of the kernel's head-major query
+    ``[Hkv, T * G, D]``, so 12 or 30 of them need no padded row (the call
+    sees exactly ``nkv`` planes of ``T * G`` rows and returns as many), and
+    the result is the oracle's, MHA and GQA alike."""
     from paddle_tpu.kernels import pallas_ragged_attention as pra
-    assert pra.wide_rows(nh) == -(-nh // 8) * 8 != nh
-    assert [pra.wide_rows(h) for h in (2, 4, 6, 8, 16, 32, 128)] \
-        == [2, 4, 6, 8, 16, 32, 128]
+    assert not hasattr(pra, "wide_rows")
+    assert not hasattr(pra, "_ragged_padded_heads")
+    seen = []
+    real = pra._ragged_call
+
+    def call(q_hm, *a, **kw):
+        out = real(q_hm, *a, **kw)
+        seen.append((q_hm.shape, out.shape))
+        return out
+    monkeypatch.setattr(pra, "_ragged_call", call)
     rng = np.random.RandomState(nh + nkv)
     hd, bs, nb, mb = 16, 8, 24, 6
     rows = [(1, 20), (9, 30), (0, 0), (1, 1)]
@@ -416,6 +458,7 @@ def test_ragged_attention_pads_head_counts_that_are_no_sublane_group(nh, nkv):
     live = int(qlen.sum())
     assert np.abs(np.asarray(got - want))[:live].max() < 1e-4
     assert not np.asarray(got)[live:].any()
+    assert seen == [((nkv, T * (nh // nkv), hd),) * 2]
 
 
 # ----------------------------------------------------------- over HTTP

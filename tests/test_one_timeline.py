@@ -20,7 +20,8 @@ import pytest
 
 import paddle_tpu as paddle
 from jax.experimental import pallas as pl
-from paddle_tpu.kernels.pallas_ragged_attention import (_query_block,
+from paddle_tpu.kernels.pallas_ragged_attention import (_one_token_walk,
+                                                        _query_block,
                                                         grid_params,
                                                         pages_per_update,
                                                         query_block_rows,
@@ -244,8 +245,9 @@ class TestEngineSpans:
         assert len(disp) == eng.stats["unified_steps"] > 0
         heads = model.config.num_attention_heads
         T = eng._token_budget
+        hd = model.config.head_dim
         grid = attention_grid(eng._params, eng.cache.pool.k,
-                              eng.cache.max_blocks, heads, T)
+                              eng.cache.max_blocks, heads, T, head_dim=hd)
         # the packed size follows the plan: the token budget where the step
         # carries a chunk, the slots' rows (a whole 8) where it carries none
         assert {a["packed_rows"] for a in disp} == {T, 8} \
@@ -254,22 +256,27 @@ class TestEngineSpans:
             assert a["packed_rows"] == (T if a["prefill_tokens"] else 8)
             # the work list's entries (one query block at either size),
             # plus the KV blocks the loops walk
-            nq = -(-(a["packed_rows"] * heads) // attention_grid(
+            tiling = attention_grid(
                 eng._params, eng.cache.pool.k, eng.cache.max_blocks, heads,
-                a["packed_rows"])["block_q"])
+                a["packed_rows"], head_dim=hd)
+            nq = -(-(a["packed_rows"] * heads) // tiling["block_q"])
             assert nq == 1
             assert a["grid_steps"] == nq + NUM_SLOTS + a["live_steps"]
             assert 0 < a["live_steps"] <= (nq + NUM_SLOTS) \
                 * eng.cache.max_blocks
             assert a["attn_pairs"] >= a["kv_tokens"] > 0
             # an update a group of pages, of which the engine knows as many
-            # as the kernel derives; llama_tiny's 4 heads are no whole tile
+            # as the kernel derives; a decode row takes the one-token walk
+            # at either size (2 rows of each KV head's plane a token, the
+            # block whole 16-row tiles)
             assert -(-a["live_steps"] // grid["pages"]) \
                 <= a["update_steps"] <= a["live_steps"]
-            assert a["one_token_rows"] == 0
+            assert tiling["one_token"]
+            assert a["one_token_rows"] == a["decode_rows"]
         # 256 keys an update in blocks of 8: the whole table of 32 entries;
-        # the packed buffer's 34 x 4 wide rows are under one block of 512
-        assert grid == dict(block_q=T * heads, pages=eng.cache.max_blocks)
+        # the packed buffer's 34 tokens are under one block of 128
+        assert grid == dict(block_q=T * heads, pages=eng.cache.max_blocks,
+                            one_token=True)
         # every token a step span counts is a prefill or a decode token
         assert sum(a["prefill_tokens"] + a["decode_tokens"] for a in disp) \
             == sum(s["tokens"] for s in steps)
@@ -465,26 +472,24 @@ def _live_pairs(qstart, qlen, kvlen, heads, block_q, block_size,
     return pairs, nq
 
 
-def _brute_force(qstart, qlen, kvlen, pages=1, **geometry):
+def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False, **geometry):
     """The steps the kernel visits: its work list (one entry a query block
     or a row more than the pairs can ever be) plus every KV block its loops
     walk; only the latter compute, ``pages`` of them an online-softmax
     update (block by block: a new update starts at a pair's first block and
     after every ``pages``). A row takes the one-token walk where its span is
-    one token and a token's ``heads`` wide rows are whole tiles, fewer than
-    the query block."""
+    one token and the kernel has that walk: ``one_token``, as the kernel's
+    ``grid_params`` says."""
     walked, nq = _live_pairs(qstart, qlen, kvlen, **geometry)
     live = sum(walked.values())
     updates = sum(ki % pages == 0 for n in walked.values()
                   for ki in range(n))
-    heads = geometry["heads"]
-    bq = _query_block(geometry["block_q"], heads, geometry["packed_tokens"])
     pairs = sum(sum(kl - ql + i + 1 for i in range(ql))
                 for ql, kl in zip(qlen, kvlen) if ql)
     return {"grid_steps": nq + len(qstart) + live,
             "live_steps": live, "update_steps": updates,
             "one_token_rows": sum(ql == 1 for ql in qlen)
-            if heads % 16 == 0 and heads < bq else 0,
+            if one_token else 0,
             "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
             "attn_pairs": pairs}
 
@@ -505,7 +510,9 @@ GRID_CASES = {
 def test_ragged_grid_counts_equals_enumeration(case, heads, block_q):
     qstart, qlen, kvlen = GRID_CASES[case]
     kw = dict(heads=heads, block_q=block_q, block_size=16,
-              table_entries=8, packed_tokens=40)
+              table_entries=8, packed_tokens=40,
+              one_token=_one_token_walk(
+                  heads, _query_block(block_q, heads, 40)))
     assert ragged_grid_counts(np.asarray(qstart), np.asarray(qlen),
                               np.asarray(kvlen), **kw) \
         == _brute_force(qstart, qlen, kvlen, **kw)
@@ -523,7 +530,9 @@ def test_ragged_grid_counts_updates_and_one_token_rows(case, heads, block_q,
     do not depend on ``pages``."""
     qstart, qlen, kvlen = GRID_CASES[case]
     kw = dict(heads=heads, block_q=block_q, block_size=16,
-              table_entries=8, packed_tokens=40)
+              table_entries=8, packed_tokens=40,
+              one_token=_one_token_walk(
+                  heads, _query_block(block_q, heads, 40)))
     got = ragged_grid_counts(qstart, qlen, kvlen, pages=pages, **kw)
     assert got == _brute_force(qstart, qlen, kvlen, pages=pages, **kw)
     one = ragged_grid_counts(qstart, qlen, kvlen, **kw)
@@ -548,26 +557,34 @@ CELL_STEPS = {
 @pytest.mark.parametrize("step", sorted(CELL_STEPS))
 def test_ragged_grid_counts_at_the_cells_geometry(step):
     qstart, qlen, kvlen, most = CELL_STEPS[step]
-    # Mistral's KD 1024: 8 pages an update in query blocks of 512 rows;
-    # OLMoE's 2048 takes half of each, an int8 pool counts as float32
+    # Mistral's 32 / 8 / 128: 8 pages an update in query blocks of 128 tokens
+    # (512 rows of each KV head's plane); OLMoE's 16 / 16 / 128 takes 4 pages
+    # and 256 tokens, Olmo-Hybrid's 30 / 30 / 128 4 pages (a lane tile of
+    # keys, the least) and 128 tokens; a one-byte pool counts as float32
     pages, block_q = pages_per_update("bfloat16", 32, 8 * 128, 128), \
-        query_block_rows(8 * 128)
-    assert (pages, block_q) == (8, 512)
-    # the call's tiling is those two, fitted to the heads and the table
-    assert grid_params("bfloat16", 32, 8 * 128, 128, 32, 520) \
-        == dict(block_q=512, pages=8)
-    assert grid_params("bfloat16", 32, 8 * 128, 128, 24, 520, pages=999) \
-        == dict(block_q=504, pages=128)
+        query_block_rows(8 * 128, 32, 128)
+    assert (pages, block_q) == (8, 128 * 32)
+    # the call's tiling is those two, fitted to the heads and the table,
+    # and whether a decode row has a row tile of its own (not at 3 heads a
+    # KV head: a token would straddle tiles)
+    tiling = grid_params("bfloat16", 32, 8 * 128, 128, 32, 520, head_dim=128)
+    assert tiling == dict(block_q=128 * 32, pages=8, one_token=True)
+    assert grid_params("bfloat16", 32, 8 * 128, 128, 24, 520, pages=999,
+                       head_dim=128) \
+        == dict(block_q=160 * 24, pages=128, one_token=False)
+    assert grid_params("bfloat16", 32, 8 * 128, 128, 32, 8, head_dim=128) \
+        == dict(block_q=8 * 32, pages=8, one_token=True)
     assert (pages_per_update("bfloat16", 32, 16 * 128, 64),
-            query_block_rows(16 * 128)) == (4, 256)
+            query_block_rows(16 * 128, 16, 128)) == (4, 256 * 16)
+    assert (pages_per_update("bfloat16", 32, 30 * 128, 72),
+            query_block_rows(30 * 128, 30, 128)) == (4, 128 * 30)
     assert pages_per_update("int8", 32, 8 * 128, 128) == 4
     assert pages_per_update("float32", 16, 128, 5) == 5
-    kw = dict(heads=32, block_q=block_q, block_size=32, table_entries=128,
-              packed_tokens=520)
-    got = ragged_grid_counts(qstart, qlen, kvlen, pages=pages, **kw)
-    assert got == _brute_force(qstart, qlen, kvlen, pages=pages, **kw)
-    assert 33 + 8 < got["grid_steps"] < most
-    assert got["live_steps"] == got["grid_steps"] - (33 + 8)
+    kw = dict(heads=32, block_size=32, table_entries=128, packed_tokens=520)
+    got = ragged_grid_counts(qstart, qlen, kvlen, **kw, **tiling)
+    assert got == _brute_force(qstart, qlen, kvlen, **kw, **tiling)
+    assert 5 + 8 < got["grid_steps"] < most
+    assert got["live_steps"] == got["grid_steps"] - (5 + 8)
     assert got["live_steps"] / 8 <= got["update_steps"] \
         < got["live_steps"] / 4
     assert got["one_token_rows"] == sum(n == 1 for n in qlen)

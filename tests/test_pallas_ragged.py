@@ -6,10 +6,13 @@ per-sequence block tables in ONE kernel invocation. Interpret-mode
 oracle suite mirroring test_pallas_paged_decode.py, plus the properties
 the unification itself must pin:
 
-- a span-1 row is BITWISE the single-query paged decode kernel's at one
-  pool page an online-softmax update (pallas vs pallas, reference vs
-  reference) and within float32 rounding at the default, several pages an
-  update (another accumulation order, the same mathematics);
+- a span-1 row is the single-query paged decode kernel's within float32
+  rounding (pallas vs pallas: the ragged kernel sums a KV head's products
+  on their own, the decode kernel over a block-diagonal wide row; the same
+  mathematics) and BITWISE reference vs reference;
+- the per-head walk over the head-major query at the cells' head counts
+  (32 / 8, 16 / 16, 30 / 30) and at groups of 2, 3 and 8, spans crossing
+  query blocks beside one-token spans;
 - the walk in groups of pages: spans and causal diagonals that end inside a
   group, a last group that reaches past ``kvlen`` into a NaN-poisoned pool,
   quantized planes carried through a group, and the one-token walk of a
@@ -83,13 +86,13 @@ class TestRaggedKernelParity:
                                    rtol=2e-5, atol=2e-5)
 
     @pytest.mark.parametrize("H,Hkv", [(8, 2), (16, 4), (32, 8)])
-    def test_span1_bitwise_vs_paged_decode_kernel(self, H, Hkv):
-        """A span-1 row IS the old single-query kernel's row: at one page
-        an update the same block walk and the same online-softmax
-        accumulation (on the general walk, H 8, and on the one-token walk,
-        H 16 and 32), so pallas vs pallas is bitwise there, and reference
-        vs reference always; at the default (the whole 4-entry table an
-        update) the order differs and float32 rounding is the bound."""
+    def test_span1_vs_paged_decode_kernel(self, H, Hkv):
+        """A span-1 row IS the old single-query kernel's row: reference vs
+        reference bitwise; pallas vs pallas the same block walk and the same
+        online-softmax mathematics, a KV head's sums taken on their own
+        (the decode kernel sums over a block-diagonal wide row), so float32
+        rounding is the bound at one page an update and at the default (the
+        whole 4-entry table an update) alike."""
         spans = [(1, 40), (1, 7), (1, 64)]
         q, pk, pv, tbl, qs, ql, kl = _mk(3, spans, H, Hkv, 64, 4, 16,
                                          seed=3)
@@ -101,8 +104,10 @@ class TestRaggedKernelParity:
         old_r = np.asarray(paged_decode_attention_reference(
             q, pk, pv, tbl, kl))
         assert (got_r == old_r).all()
-        assert (np.asarray(ragged_paged_attention_pallas(
-            q, pk, pv, tbl, qs, ql, kl, pages=1)) == old_k).all()
+        np.testing.assert_allclose(
+            np.asarray(ragged_paged_attention_pallas(
+                q, pk, pv, tbl, qs, ql, kl, pages=1)), old_k, rtol=2e-5,
+            atol=2e-5)
         np.testing.assert_allclose(
             np.asarray(ragged_paged_attention_pallas(
                 q, pk, pv, tbl, qs, ql, kl)), old_k, rtol=2e-5, atol=2e-5)
@@ -335,8 +340,8 @@ def test_int8_pool_parity_mixed_spans(block_q):
 def test_mixed_spans_match_reference_at_every_group_size(pages, H, Hkv):
     """One page an update, two, three (the 8-entry table is no whole number
     of groups) and the whole table: decode rows, chunks and a dead row
-    against the oracle, on the general walk alone (H 8) and with the
-    one-token walk for the decode rows (H 16)."""
+    against the oracle, on query blocks of 4 tokens (16 rows a plane at H 8,
+    the general walk alone; 4 rows at H 16)."""
     spans = [(1, 128), (5, 37), (1, 3), (16, 16), (0, 0), (9, 100)]
     args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=pages + H)
     got = ragged_paged_attention_pallas(*args, pages=pages, block_q=4 * H)
@@ -384,28 +389,96 @@ def test_group_edges_over_a_poisoned_pool(case):
 
 
 @pytest.mark.parametrize("H,Hkv,walks", [
-    (16, 4, "one_token"), (32, 8, "one_token"), (32, 32, "one_token"),
-    (8, 2, "general"), (24, 8, "general")])
+    (16, 4, "one_token"), (32, 8, "one_token"), (16, 16, "one_token"),
+    (8, 1, "one_token"), (12, 4, "general")])
 def test_one_token_walk_equals_general_walk(H, Hkv, walks):
-    """A decode row computes on its own ``H`` wide rows where those are
-    whole tiles (``H % 16 == 0``) and fewer than the query block, and on the
-    whole block otherwise. A query block of exactly ``H`` rows leaves the
-    general walk no other token to compute on: the same rows, the same
-    groups, so the same numbers, bit for bit, whichever walk the wide block
-    takes; and both match the oracle."""
-    from paddle_tpu.kernels.pallas_ragged_attention import _one_token_walk
-    assert _one_token_walk(H, 4 * H) == (walks == "one_token")
-    assert not _one_token_walk(H, H)
+    """A decode row takes ONE product over the whole pool row, its ``H``
+    query rows cut out of the head-major block and laid block-diagonal in
+    VMEM, where the query block is whole 16-row tiles and no token straddles
+    two (``G`` divides 16); the per-head walk on the whole block otherwise
+    (``G`` 3, or a block of 5 / 17 / 3 tokens). Both walks on the same rows
+    give the same numbers within float32 rounding, and both match the
+    oracle."""
+    from paddle_tpu.kernels.pallas_ragged_attention import (_token_tile,
+                                                            grid_params)
+    G = H // Hkv
+    tile_tokens = 16 // G if 16 % G == 0 else 16
+    assert bool(_token_tile(4 * tile_tokens * G, G)) == (walks == "one_token")
+    assert not _token_tile((tile_tokens + 1) * G, G)
     spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33), (1, 128)]
-    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=H)
-    wide = np.asarray(ragged_paged_attention_pallas(
-        *args, block_q=4 * H, pages=3))
-    narrow = np.asarray(ragged_paged_attention_pallas(
-        *args, block_q=H, pages=3))
-    assert (wide == narrow).all()
+    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=H,
+               T=4 * tile_tokens)
+    tiling = [grid_params(jnp.float32, 16, Hkv * 32, 8, H, 4 * tile_tokens,
+                          block_q=n * H, head_dim=32)
+              for n in (4 * tile_tokens, tile_tokens + 1)]
+    assert [t["one_token"] for t in tiling] == [walks == "one_token", False]
+    # (a one-byte pool has the same walks)
+    assert grid_params(jnp.int8, 16, Hkv * 32, 8, H, 4 * tile_tokens,
+                       head_dim=32)["one_token"] == (walks == "one_token")
+    own = np.asarray(ragged_paged_attention_pallas(
+        *args, block_q=4 * tile_tokens * H, pages=3))
+    general = np.asarray(ragged_paged_attention_pallas(
+        *args, block_q=(tile_tokens + 1) * H, pages=3))
+    np.testing.assert_allclose(own, general, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(
-        wide, np.asarray(ragged_attention_reference(*args)),
+        own, np.asarray(ragged_attention_reference(*args)),
         rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hkv", [(16, 4), (30, 30)])
+@pytest.mark.parametrize("mode", ["int8", "fp8"])
+def test_one_token_walk_of_a_quantized_pool(mode, H, Hkv):
+    """A decode row over an int8 or fp8 pool takes the one product over the
+    whole pool row too: the group upcast head window by head window, each
+    with column k of its scale plane (fp8: a scale a (block, head) that
+    differs from page to page). The same rows on the per-head walk (a query
+    block that is no whole tile) and the oracle agree; the groups of 3 pages
+    end inside the rows' lengths and past them."""
+    G = H // Hkv
+    tokens = 16 // G
+    spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33), (1, 128)]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 32, 8, 16,
+                                     seed=H, T=4 * tokens)
+    if mode == "int8":
+        (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
+    else:
+        r = np.random.RandomState(41)
+        k8, v8 = quantize_kv_rows_fp8(pk), quantize_kv_rows_fp8(pv)
+        ks, vs = (jnp.asarray(r.uniform(0.5, 2.0, (pk.shape[0], Hkv)),
+                              jnp.float32) for _ in range(2))
+    own, general = (np.asarray(ragged_paged_attention_pallas(
+        q, k8, v8, tbl, qs, ql, kl, block_q=n * H, k_scale=ks, v_scale=vs,
+        pages=3)) for n in (4 * tokens, tokens + 1))
+    want = np.asarray(ragged_attention_reference(
+        q, k8, v8, tbl, qs, ql, kl, k_scale=ks, v_scale=vs))
+    np.testing.assert_allclose(own, general, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(own, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("H,Hkv", [(32, 8), (16, 16), (30, 30), (12, 4),
+                                   (8, 1), (8, 4)])
+def test_per_head_walk_matches_reference(H, Hkv):
+    """Each KV head's keys by that head's queries only, at the cells' head
+    counts and at groups of 3, 8 and 2: query blocks of 16 tokens over 60
+    packed rows, so the chunks cross blocks, end inside a group of pages and
+    share blocks with one-token spans and a dead row; the pool stale rows
+    NaN; packed rows in no span exact zeros."""
+    spans = [(1, 70), (21, 90), (1, 3), (0, 0), (1, 128), (30, 100)]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 16, 8, 16,
+                                     seed=H + Hkv, T=60)
+    tbl = np.asarray(tbl).copy()
+    for r, (_, kvlen) in enumerate(spans):
+        tbl[r, -(-kvlen // 16):] = pk.shape[0]      # unmapped -> sentinel
+    tbl = jnp.asarray(tbl)
+    pk = _poison_stale_rows(pk, tbl, kl, ql)
+    pv = _poison_stale_rows(pv, tbl, kl, ql)
+    got = np.asarray(ragged_paged_attention_pallas(
+        q, pk, pv, tbl, qs, ql, kl, block_q=16 * H, pages=3))
+    want = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl))
+    used = sum(n for n, _ in spans)
+    assert np.isfinite(got).all()
+    assert not got[used:].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("H,Hkv", [(8, 4), (16, 4)])
@@ -414,8 +487,8 @@ def test_one_token_walk_equals_general_walk(H, Hkv, walks):
 def test_quantized_planes_ride_the_group(mode, pages, H, Hkv):
     """The scale planes of a quantized pool through a group of more than
     one page: int8's per-row planes lie concatenated over the group's pages,
-    fp8's per-block scale becomes a factor a column; on the general walk
-    (H 8) and on the one-token walk (H 16)."""
+    fp8's per-block scale a factor on the block's rows, column k of the
+    plane for KV head k; at groups of 2 and 4."""
     q, pk, pv, tbl, qs, ql, kl = _mk(len(MIXED), MIXED, H, Hkv, 16, 4, 16,
                                      seed=31)
     if mode == "int8":
