@@ -38,6 +38,7 @@ in interpret mode. It is chosen by that argument and never by detection, and
 its result line names the platform ``cpu``.
 """
 import argparse
+import functools
 import json
 import os
 import random
@@ -100,6 +101,14 @@ FULL = dict(
     # Olmo-Hybrid-7B's linear layers: (heads, key width, value width) of
     # the gated delta rule, the slots and the packed rows of its cell's step
     gdn=dict(widths=(30, 96, 192), slots=32, packed=544),
+    # Phi-4-mini-flash's Mamba layers: (d_inner, d_state) of the selective
+    # scan, the slots and the packed rows of its cell's step; and its window
+    # layers' call: 40 wide queries over 10 KV pairs of 128, a 512-token
+    # chunk 3 k into its prompt beside 15 decode rows, window 512
+    ssm=dict(widths=(5120, 16), slots=48, packed=560),
+    ragged_window={"window 512 40/10/128": (40, 10, 128, 256, [
+        (512, 3584)] + [(1, 1500 + 290 * i + (i * 37) % 29)
+                        for i in range(15)], 512)},
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
@@ -122,6 +131,9 @@ REHEARSAL = dict(
         (1, 40 + 30 * i) for i in range(6)])),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
     gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
+    ssm=dict(widths=(256, 16), slots=6, packed=150),
+    ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
+        (48, 200), (1, 150), (1, 33), (0, 0)], 40)},
     train=dict(layers=2, batch=4, seq=64, steps=4))
 
 # Forward outputs: kernel and reference both take bf16 inputs (8 significant
@@ -598,7 +610,7 @@ def phase_kernels(rehearse):
         with jax.default_matmul_precision("highest"):
             return jax.jit(fn)(*args)
 
-    def ragged_agrees(name, args, piece=64):
+    def ragged_agrees(name, args, piece=64, window=None):
         # the oracle gathers every token's whole table: row by row, `piece`
         # span tokens a call (tokens lo .. lo + n of a span are a span of n
         # whose kv ends where theirs does), or a cell's step is tens of GB
@@ -609,9 +621,12 @@ def phase_kernels(rehearse):
             for lo in range(0, n_r, piece):
                 n = min(piece, n_r - lo)
                 want[at + lo:at + lo + n] = reference(
-                    ragged_attention_reference, q[at + lo:at + lo + n], pk,
+                    functools.partial(ragged_attention_reference,
+                                      window=window),
+                    q[at + lo:at + lo + n], pk,
                     pv, tables[r:r + 1], one, one + n, one + end - n_r + lo + n)
-        got = jax.jit(ragged_paged_attention_pallas)(*args)
+        got = jax.jit(functools.partial(ragged_paged_attention_pallas,
+                                        window=window))(*args)
         _agree(name, got, want, TOL_FWD, errors)
         check(not np.asarray(got[int(np.asarray(qlen).sum()):],
                              np.float32).any(),
@@ -632,6 +647,14 @@ def phase_kernels(rehearse):
         ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
             np.random.RandomState(packed), rows, nh, nkv, hd, mb=mb,
             pad=packed - live))
+
+    # ---- ... and under a window (Phi-4-mini-flash's window layers): the
+    # walk starts at the group that holds a pair's first visible key ------
+    for tag, (nh, nkv, hd, mb, rows, window) in \
+            size["ragged_window"].items():
+        ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
+            np.random.RandomState(window), rows, nh, nkv, hd, mb=mb),
+            window=window)
 
     for nh, nkv, hd in size["geometries"]:
         tag = f"{nh}/{nkv}/{hd}"
@@ -850,6 +873,61 @@ def phase_kernels(rehearse):
     _agree("gdn_chunk_scan o", np.asarray(got[0])[5:T - 3],
            np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
     _agree("gdn_chunk_scan state", got[1], want[1], TOL_GDN, errors)
+
+    # ---- the selective scan: both kernels against the recurrence --------
+    from paddle_tpu.kernels import selective_scan as ssk
+    C, N = size["ssm"]["widths"]
+    R, T = size["ssm"]["slots"], size["ssm"]["packed"]
+    rng = np.random.RandomState(37)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (T, C))
+                            ).astype(np.float32))
+    u = dt * rand(T, C)
+    bm, cm = rand(T, N), rand(T, N)
+    a = -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, C))
+    store = rand(2, R, N, C)
+    # the decode-only step: every slot has a row, one starts a sequence over
+    # a stored state that is NaN; the chunk scan finds no span and hands the
+    # store back as it is
+    live = np.ones(R, bool)
+    fresh = np.zeros(R, bool)
+    fresh[2] = True
+    got = jax.jit(lambda *x: ssk.ssm_recurrent_update(
+        *x, layer=1, live=live, fresh=fresh))(
+            dt[:R], u[:R], bm[:R], cm[:R], a, store.at[:, 2].set(jnp.nan))
+    want = reference(lambda *x: ssk.ssm_reference(
+        *x, layer=1, seg=np.arange(R), first=fresh),
+        dt[:R], u[:R], bm[:R], cm[:R], a, store)
+    _agree(f"ssm_recurrent_update {R} rows y", got[0], want[0], TOL_GDN,
+           errors)
+    _agree(f"ssm_recurrent_update {R} rows state", got[1][1], want[1][1],
+           TOL_GDN, errors)
+    idle = jax.jit(lambda *x: ssk.ssm_chunk_scan(
+        *x, layer=0, start=np.arange(R, dtype=np.int32),
+        length=np.zeros(R, np.int32), fresh=fresh, min_span=2))(
+            dt[:R], u[:R], bm[:R], cm[:R], a, store)
+    check(np.array_equal(np.asarray(idle[1]), np.asarray(store)),
+          f"ssm_chunk_scan {R} rows, no span: the store changed")
+    # a chunk step: decode rows first (not the scan's), then a chunk that
+    # continues its slot's state and a fresh one that starts in the block
+    # where the first ends
+    cut = 5 + (T - 5) * 3 // 5
+    start, length = np.zeros(R, np.int32), np.zeros(R, np.int32)
+    start[3], length[3] = 5, cut - 5
+    start[0], length[0] = cut, T - cut - 3
+    fresh = np.zeros(R, bool)
+    fresh[0] = True
+    seg = np.full(T, R, np.int32)
+    seg[5:cut], seg[cut:T - 3] = 3, 0
+    first = np.zeros(T, bool)
+    first[cut] = True
+    got = jax.jit(lambda *x: ssk.ssm_chunk_scan(
+        *x, layer=0, start=start, length=length, fresh=fresh, min_span=2))(
+            dt, u, bm, cm, a, store)
+    want = reference(lambda *x: ssk.ssm_reference(
+        *x, layer=0, seg=seg, first=first), dt, u, bm, cm, a, store)
+    _agree("ssm_chunk_scan y", np.asarray(got[0])[5:T - 3],
+           np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
+    _agree("ssm_chunk_scan state", got[1], want[1], TOL_GDN, errors)
 
     import importlib.metadata as md
     _child_report(

@@ -130,9 +130,10 @@ from .pallas_flash import _interpret_mode
 NEG_INF = -1e30
 
 
-def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                   tbl_ref, layer_ref, *refs, scale, block_k, pages, tq, g,
-                   num_blocks, table_entries, quantized=False):
+def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
+                   kl_ref, tbl_ref, layer_ref, *refs, scale, block_k, pages,
+                   tq, g, num_blocks, table_entries, quantized=False,
+                   window=None):
     # positional ref layout follows the pallas_call spec lists: inputs
     # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
     # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc
@@ -156,6 +157,9 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     qi = wq_ref[w]
     r = wr_ref[w]
     nkb = wn_ref[w]                 # pool blocks this pair walks (0 = dead)
+    # the first GROUP it walks: 0, or under a window the group that holds
+    # the first key any of the pair's queries may see (``_pair_first_block``)
+    glo = 0 if window is None else wlo_ref[w] // pages
     layer = layer_ref[0]            # which layer of the stored pool
     qstart = qs_ref[r]
     qlen = ql_ref[r]
@@ -228,7 +232,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         # and the causal diagonal both already bound nkb (_work_list); what
         # the last group holds past them is masked
         n_groups = (nkb + pages - 1) // pages
-        for c in _copies(0, 0):
+        for c in _copies(glo, glo % 2):
             c.start()
 
         def _group(gi, carry):
@@ -264,7 +268,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
             update(gi, slot)
             return carry
 
-        jax.lax.fori_loop(0, n_groups, _group, 0)
+        jax.lax.fori_loop(glo, n_groups, _group, 0)
 
     def _softmax_update(s, valid, v, m_ref, l_ref, acc_ref):
         # one online-softmax update of the state ``m / l / acc`` (ref views)
@@ -307,8 +311,11 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         pos = jnp.where(in_span, kvlen - qlen + tok, -1)
 
         def update(gi, slot):
-            valid = jax.lax.broadcasted_iota(
-                jnp.int32, (rows, group), 1) <= pos - gi * group
+            key = jax.lax.broadcasted_iota(jnp.int32, (rows, group), 1)
+            valid = key <= pos - gi * group
+            if window is not None:
+                # the window's edge, inside the first group walked
+                valid = valid & (key > pos - window - gi * group)
             for k in range(hkv):
                 s = jax.lax.dot_general(
                     q_ref[k], _head_rows(k_buf, slot, ks_buf, k),
@@ -370,8 +377,11 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
             s = jax.lax.dot_general(
                 qw, pool_rows(k_buf, ks_buf, slot), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            valid = gi * group + jax.lax.broadcasted_iota(
-                jnp.int32, (hp, group), 1) < kvlen
+            key = gi * group + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, group), 1)
+            valid = key < kvlen
+            if window is not None:
+                valid = valid & (key >= kvlen - window)
             _softmax_update(s, valid, pool_rows(v_buf, vs_buf, slot),
                             m1_scr, l1_scr, acc1_scr)
 
@@ -480,11 +490,28 @@ def _pair_kv_blocks(qs, ql, kl, qi, *, tokens_per_block, block_size,
     return n * (n > 0)
 
 
+def _pair_first_block(qs, ql, kl, qi, *, tokens_per_block, block_size,
+                      window, pages):
+    """The first KV block the pair (query block ``qi``, a live row) walks:
+    0 with no window; else the block of the first key the window of the
+    FIRST span token inside the query block holds, moved down to a whole
+    group of ``pages`` blocks (the kernel's walk starts at a group). Ints
+    and broadcasting int arrays alike, as ``_pair_kv_blocks``."""
+    if window is None:
+        return 0 * (qs + qi)
+    first = qi * tokens_per_block
+    first = first + (qs > first) * (qs - first)
+    lo = kl - ql + (first - qs) - (int(window) - 1)
+    lo = lo * (lo > 0)
+    return lo // block_size // pages * pages
+
+
 def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
-               table_entries):
+               table_entries, window=None, pages=1):
     """The kernel's iteration space, from the step's span metadata (jnp,
     inside the jitted program): ``nq + R`` entries ``(query block, row,
-    first visit of its output block, KV blocks to walk)`` ordered by query
+    first visit of its output block, KV blocks to walk)`` and, under a
+    ``window``, a fifth array, the first KV block to walk, ordered by query
     block, then row. The packed spans are disjoint and contiguous, so at
     most ``nq + R - 1`` (query block, row) pairs intersect; a query block no
     live span touches gets one dead entry (zero its output, walk nothing),
@@ -518,11 +545,20 @@ def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
     wn = jnp.where(pad, 0, jnp.sum(jnp.where(pick, n_flat[None, :], 0),
                                    axis=1))
     first = (j == 0) | (wq != jnp.roll(wq, 1))
-    return wq, wr, first.astype(jnp.int32), wn
+    if window is None:
+        return wq, wr, first.astype(jnp.int32), wn
+    lo_flat = jnp.concatenate([jnp.where(inter, _pair_first_block(
+        qs, ql, kl, qi, tokens_per_block=tokens_per_block,
+        block_size=block_size, window=window, pages=pages), 0),
+        jnp.zeros((nq, 1), jnp.int32)], axis=1).reshape(-1)
+    wlo = jnp.where(pad, 0, jnp.sum(
+        jnp.where(pick, lo_flat[None, :], 0), axis=1))
+    return wq, wr, first.astype(jnp.int32), wn, wlo
 
 
 def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
-                 scale, g, block_q, pages, interpret, scales=None):
+                 scale, g, block_q, pages, interpret, scales=None,
+                 window=None):
     """q_hm: [Hkv, T * g, D] head-major planes (row ``t * g + j`` of plane
     ``k`` is head ``k * g + j`` of token ``t``);
     pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole;
@@ -547,7 +583,10 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     nq = -(-TG // (tokens * g))     # the last block may be partial
     rows = _plane_rows(block_q, hkv * g, g, TG // g)
     work = _work_list(qstart, qlen, kvlen, nq=nq, tokens_per_block=tokens,
-                      block_size=bs, table_entries=nk)
+                      block_size=bs, table_entries=nk, window=window,
+                      pages=pages)
+    if window is None:      # one signature: a first block of 0, never read
+        work += (jnp.zeros_like(work[-1]),)
     if scales is None:
         quantized = False
     else:
@@ -555,7 +594,7 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
                                pages=pages, tq=block_q, g=g,
                                num_blocks=num_blocks, table_entries=nk,
-                               quantized=quantized)
+                               quantized=quantized, window=window)
 
     def _q_index(w, wq, *_):
         return (0, wq[w], 0)
@@ -594,7 +633,7 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=9,
+            num_scalar_prefetch=10,
             grid=(nq + R,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((hkv, rows, D), _q_index),
@@ -652,7 +691,7 @@ def grid_params(pool_dtype, block_size, kd, table_entries, heads,
 
 def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
                        table_entries, packed_tokens, block_q=256, pages=1,
-                       one_token=False):
+                       one_token=False, window=None):
     """What one call of the kernel is asked to do, counted on the host from
     the step's span metadata (plain integers; no jax): ``grid_steps``, the
     steps the kernel visits — the ``nq + R`` work-list entries of its grid
@@ -666,7 +705,9 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
     ``grid_params`` gives it); ``kv_tokens``, the cache rows the live spans
     attend
     over; ``attn_pairs``, their causal (query, key) pairs. A row with
-    ``qlen == 0`` is dead."""
+    ``qlen == 0`` is dead. Under a ``window`` a pair's walk starts at its
+    first group (``_pair_first_block``) and ``kv_tokens`` / ``attn_pairs``
+    count the keys inside the window."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
     tpb = bq // heads
@@ -675,13 +716,21 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
         qs, ql, kl = int(qs), int(ql), int(kl)
         if ql <= 0:
             continue
-        kv_tokens += kl
-        pairs += ql * (kl - ql) + ql * (ql + 1) // 2
+        if window is None:
+            kv_tokens += kl
+            pairs += ql * (kl - ql) + ql * (ql + 1) // 2
+        else:
+            kv_tokens += min(kl, ql + int(window) - 1)
+            pairs += sum(min(p + 1, int(window))
+                         for p in range(kl - ql, kl))
         alone += ql == 1 and one_token
         for qi in range(qs // tpb, min(nq, -(-(qs + ql) // tpb))):
             n = _pair_kv_blocks(
                 qs, ql, kl, qi, tokens_per_block=tpb,
                 block_size=block_size, table_entries=int(table_entries))
+            n -= _pair_first_block(
+                qs, ql, kl, qi, tokens_per_block=tpb, block_size=block_size,
+                window=window, pages=int(pages)) * (n > 0)
             live += n
             updates += -(-n // int(pages))
     return {"grid_steps": nq + len(qstart) + live, "live_steps": live,
@@ -693,21 +742,21 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
 # eager dispatch linearizes through every op and scalar-prefetch
 # pallas_calls don't linearize in interpret mode. ``scales`` is ``()`` or
 # the ``(k_scale, v_scale)`` planes of a quantized pool.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12, 13))
 def _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
-            kvlen, scale, g, block_q, pages):
+            kvlen, scale, g, block_q, pages, window):
     return _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen,
                         kvlen, scale, g, block_q, pages, _interpret_mode(),
-                        scales=scales or None)
+                        scales=scales or None, window=window)
 
 
 def _ragged_fwd_rule(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
-                     qlen, kvlen, scale, g, block_q, pages):
+                     qlen, kvlen, scale, g, block_q, pages, window):
     return _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
-                   qlen, kvlen, scale, g, block_q, pages), None
+                   qlen, kvlen, scale, g, block_q, pages, window), None
 
 
-def _ragged_bwd_rule(scale, g, block_q, pages, res, ct):
+def _ragged_bwd_rule(scale, g, block_q, pages, window, res, ct):
     raise NotImplementedError(
         "ragged_paged_attention_pallas is inference-only (the serving "
         "step never backpropagates)")
@@ -736,7 +785,8 @@ def _stored_pool(pool_k, pool_v, k_scale, v_scale, layer):
 
 def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
                                   kvlen, block_q=None, k_scale=None,
-                                  v_scale=None, layer=None, pages=None):
+                                  v_scale=None, layer=None, pages=None,
+                                  window=None):
     """Mixed prefill+decode attention over packed query spans through
     per-sequence block tables.
 
@@ -761,6 +811,11 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
               the table-indirect fetch — one upcast site, fused into
               the dot — so HBM traffic is 1-byte while the MXU math
               stays full-precision
+    window:   None, or a static int: a query at position ``p`` sees keys
+              ``p - window < j <= p`` only; a pair's walk then starts at the
+              group of blocks that holds its first visible key
+              (``_pair_first_block``) and the edge is masked inside it. With
+              None the work list and the walk are the unwindowed ones
     returns:  [T, H, D]; packed rows outside every span are exact zeros
 
     GQA is resolved by the layout: the query goes in head-major, ``[Hkv,
@@ -797,12 +852,14 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
                          H, T, block_q, pages, head_dim=D)
     q_hm = q.reshape(T, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, T * G, D)
     out = _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
-                  kvlen, scale, G, tiling["block_q"], tiling["pages"])
+                  kvlen, scale, G, tiling["block_q"], tiling["pages"],
+                  None if window is None else int(window))
     return out.reshape(Hkv, T, G, D).swapaxes(0, 1).reshape(T, H, D)
 
 
 def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
-                               kvlen, k_scale=None, v_scale=None, layer=None):
+                               kvlen, k_scale=None, v_scale=None, layer=None,
+                               window=None):
     """jnp oracle with identical semantics and operands (``_stored_pool``:
     with ``layer`` the tables gather straight from the stored pool, no
     layer of it is cut out) — and, deliberately, the exact op sequence of
@@ -869,6 +926,8 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
            + (t_idx - jnp.take(qstart, seg)))             # [T]
     cols = jnp.arange(s_tot, dtype=jnp.int32)
     mask = (cols[None, :] <= pos[:, None]) & live[:, None]  # [T, s_tot]
+    if window is not None:
+        mask = mask & (cols[None, :] > pos[:, None] - int(window))
     logits = jnp.einsum("qhd,qkhd->qhk", q, kf,
                         preferred_element_type=jnp.float32) * scale
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)

@@ -34,7 +34,15 @@ model (a tree with ``linear_layers``: periods of Gated DeltaNet layers and
 then one full-attention layer) scans its periods, the pool holding rows for
 the full layers only; a linear layer's cache is a float32 state and its
 convolution's last inputs, in a store by slot that rides the programs like
-the pool (``_hybrid_span_forward``, ``kernels.gated_delta_rule``).
+the pool (``_hybrid_span_forward``, ``kernels.gated_delta_rule``). A
+decoder-hybrid-decoder model (a tree with ``self_layers``: pairs of a Mamba
+layer and a window-attention layer, a middle Mamba layer that makes a memory
+and a middle full-attention layer whose keys and values are THE cache, then
+pairs of a Gated Memory Unit and a cross-attention layer that cache nothing)
+keeps ONE pool layer, the Mamba layers' states and the window layers' rings
+of keys in the stores by slot, and narrows the packed buffer to one row a
+slot after the middle layers (``_sambay_span_forward``,
+``kernels.selective_scan``, the ragged kernel's ``window``).
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
@@ -65,6 +73,8 @@ from ..kernels.pallas_mla_ragged_attention import (
 from ..kernels.pallas_ragged_attention import (
     grid_params as _ragged_grid_params, ragged_attention_reference,
     ragged_paged_attention_pallas)
+from ..kernels.selective_scan import (ssm_chunk_scan, ssm_recurrent_update,
+                                      ssm_reference)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -110,7 +120,7 @@ _GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
 
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch)
-TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers")
+TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers")
 
 
 def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1,
@@ -478,16 +488,49 @@ def gdn_gates(ab, a_log, dt_bias, neg_eigval):
     return g, (2.0 * beta if neg_eigval else beta)
 
 
-def conv_silu(cur, prev, w):
+def conv_silu(cur, prev, w, bias=None):
     """The depthwise causal convolution of width ``len(prev) + 1`` and its
-    SiLU, a channel: ``silu(w[-1] * u_t + w[-2] * u_{t-1} + ..)``, float32
-    inside, the rows' dtype out. ``prev[j - 1]`` holds ``u_{t-j}``; ``w`` is
-    ``[width, C]``, its last row the current token's."""
+    SiLU, a channel: ``silu(w[-1] * u_t + w[-2] * u_{t-1} + .. [+ bias])``,
+    float32 inside, the rows' dtype out. ``prev[j - 1]`` holds ``u_{t-j}``;
+    ``w`` is ``[width, C]``, its last row the current token's."""
     f32 = jnp.float32
     acc = cur.astype(f32) * w[-1].astype(f32)
     for j, p in enumerate(prev, 1):
         acc = acc + p.astype(f32) * w[-1 - j].astype(f32)
+    if bias is not None:
+        acc = acc + bias.astype(f32)
     return jax.nn.silu(acc).astype(cur.dtype)
+
+
+def _span_conv(u, held, conv_w, *, fresh, qstart, qlen, bias=None):
+    """The convolution over the packed buffer ``u [T, C]`` of a step whose
+    spans keep their earlier inputs in a store by slot (``held [R, taps,
+    C]``, the slot's last ``taps`` inputs, oldest first). A token's earlier
+    inputs are the rows above it, except in a span's first rows, which take
+    the slot's tail (a zero one where the span is ``fresh``, whatever the
+    slot held): a few rows a span, scattered over the shifted buffer (a
+    gather of the tail for every packed row cost 6 % of a step). Returns
+    ``(silu(conv(u)) [T, C], the slots' new tails [R, taps, C])``: the
+    span's last inputs, and where the span is shorter than the tail, the
+    old tail moved up; a slot without a span keeps what it held."""
+    T, taps = u.shape[0], held.shape[1]
+    slots = jnp.arange(held.shape[0], dtype=jnp.int32)
+    tail = jnp.where(fresh[:, None, None], jnp.zeros_like(held), held)
+    prev = []
+    for j in range(1, taps + 1):
+        at = jnp.concatenate([jnp.where(qlen > i, qstart + i, T)
+                              for i in range(j)])
+        rows = jnp.concatenate([tail[:, taps + i - j] for i in range(j)])
+        prev.append(jnp.roll(u, j, axis=0).at[at].set(rows, mode="drop"))
+    up = conv_silu(u, prev, conv_w, bias)
+    rows = []
+    for k in range(taps):
+        at = qlen - taps + k
+        rows.append(jnp.where(
+            (at >= 0)[:, None],
+            jnp.take(u, jnp.clip(qstart + at, 0, T - 1), axis=0),
+            tail[slots, jnp.clip(taps + at, 0, taps - 1)]))
+    return up, jnp.where((qlen > 0)[:, None, None], jnp.stack(rows, 1), held)
 
 
 def gdn_split(u, gdn):
@@ -562,6 +605,408 @@ def _hybrid_scan(params, carry, full_layer, linear_layer):
     carry, (ys_lin, ys_full) = jax.lax.scan(
         period, carry, (lin, full, jnp.arange(periods, dtype=jnp.int32)))
     return carry, ys_lin, ys_full
+
+
+# ------------------------------------------- decoder-hybrid-decoder (SambaY)
+# ``models.phi4_flash``'s docstring has the equations. A tree with
+# ``self_layers`` is three runs of layers, not a period: the self-decoder's
+# pairs (a Mamba layer, then differential attention inside a window), the two
+# middle layers (the Mamba layer whose scan output is the memory ``m``, the
+# full-attention layer whose keys and values are THE cache) and the
+# cross-decoder's pairs (a Gated Memory Unit over ``m``, then differential
+# cross-attention over the middle layer's cache). Every layer is
+# ``_sambay_block`` around a mixer.
+def _layer_norm(x, w, b, eps):
+    """LayerNorm with a bias, float32 inside, the rows' dtype out."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, -1, keepdims=True)
+    out = xc * jax.lax.rsqrt(jnp.mean(xc * xc, -1, keepdims=True) + eps)
+    return out.astype(x.dtype) * w + b
+
+
+def _final_norm(params, x, eps):
+    """A model's norm before its head: LayerNorm where the tree has its bias
+    (``final_norm_b``), else RMSNorm."""
+    if "final_norm_b" in params:
+        return _layer_norm(x, params["final_norm"], params["final_norm_b"],
+                           eps)
+    return _rms(x, params["final_norm"], eps)
+
+
+def _sambay_block(h, lw, eps, mixer):
+    """``x = x + Mixer(LN(x; ln1))``, ``x = x + SwiGLU(LN(x; ln2))`` on ``h
+    [B, S, H]``; ``mixer(hn) -> (out, carry)``. Returns ``(h, carry)``."""
+    out, carry = mixer(_layer_norm(h, lw["ln1_w"], lw["ln1_b"], eps))
+    h = h + out
+    m = _swiglu_proj(_layer_norm(h, lw["ln2_w"], lw["ln2_b"], eps),
+                     lw["w_gate"], lw["w_up"], lw["w_down"])
+    return h + m, carry
+
+
+def _mamba_mixer(hn, lw, *, conv, scan):
+    """A Mamba-1 mixer on ``hn [B, S, H]``. The program brings ``conv(a, w,
+    bias) -> (silu(conv(a)) [B, S, C], carry)`` (where the convolution's
+    earlier inputs come from) and ``scan(dt, u, b, c, A) -> (y [B, S, C]
+    float32, carry)`` (where the state comes from and which kernel walks the
+    tokens): dt, u ``[B, S, C]``, b, c ``[B, S, N]`` float32, ``A = -exp(A_log)
+    [N, C]``. Returns ``(out [B, S, H], (conv carry, scan carry, y))``, a
+    mixer of ``_sambay_block``: ``y``, the scan's output with the skip ``D *
+    c`` and BEFORE the gate, is what the middle Mamba layer hands on as the
+    memory. Scopes ``ssm_proj`` (the four projections) and ``ssm_mix``
+    (convolution, gates, the kernels)."""
+    f32 = jnp.float32
+    C = lw["ssm_out"].shape[0]
+    N = lw["ssm_A_log"].shape[0]
+    rank = lw["ssm_dt"].shape[0]
+    with jax.named_scope("ssm_proj"):
+        az = jnp.einsum("bsh,hc->bsc", hn, lw["ssm_in"])
+        a, z = az[..., :C], az[..., C:]
+    with jax.named_scope("ssm_mix"):
+        c, conv_carry = conv(a, lw["ssm_conv"], lw["ssm_conv_b"])
+    with jax.named_scope("ssm_proj"):
+        # the step and the two vectors leave in float32: dt is the exponent's
+        # scale, B and C multiply a float32 state
+        xdb = jnp.einsum("bsc,cr->bsr", c, lw["ssm_x"],
+                         preferred_element_type=f32)
+        dt = jnp.einsum("bsr,rc->bsc", xdb[..., :rank].astype(hn.dtype),
+                        lw["ssm_dt"], preferred_element_type=f32)
+    with jax.named_scope("ssm_mix"):
+        dt = jax.nn.softplus(dt + lw["ssm_dt_b"].astype(f32))
+        cf = c.astype(f32)
+        y, scan_carry = scan(dt, dt * cf, xdb[..., rank:rank + N],
+                             xdb[..., rank + N:],
+                             -jnp.exp(lw["ssm_A_log"].astype(f32)))
+        y = y + lw["ssm_D"].astype(f32) * cf
+        gated = (y * jax.nn.silu(z.astype(f32))).astype(hn.dtype)
+    with jax.named_scope("ssm_proj"):
+        out = jnp.einsum("bsc,ch->bsh", gated, lw["ssm_out"])
+    return out, (conv_carry, scan_carry, y)
+
+
+def _diff_queries(q):
+    """Differential attention's queries ``[.., nh, hd]`` as the ragged kernel
+    takes them: a KV PAIR is one head of ``2 hd`` (the pool's row is the same
+    values a side) and head ``2n`` / ``2n + 1`` of a pair's four queries is
+    ``[q1 | 0]`` / ``[0 | q2]``, so ``P1 V`` and ``P2 V`` over the pair's
+    whole ``V`` come out of one walk of the keys. The kernel scales by ``(2
+    hd)^-0.5``: the queries carry the other ``sqrt(2)``."""
+    nh, hd = q.shape[-2], q.shape[-1]
+    q = (q.astype(jnp.float32) * math.sqrt(2.0)).astype(q.dtype)
+    q = q.reshape(q.shape[:-2] + (nh // 2, 2, hd))
+    z = jnp.zeros_like(q[..., 0, :])
+    wide = jnp.stack([jnp.concatenate([q[..., 0, :], z], -1),
+                      jnp.concatenate([z, q[..., 1, :]], -1)], axis=-2)
+    return wide.reshape(q.shape[:-3] + (nh, 2 * hd))
+
+
+def _diff_combine(o, lw, eps, dtype):
+    """``(1 - lambda_init) RMSNorm(o1 - lambda o2; subln)`` a differential
+    head, from the kernel's ``o [.., nh, 2 hd]`` (``_diff_queries``' order).
+    Returns ``[.., nh * hd]`` in ``dtype``."""
+    f32 = jnp.float32
+    nh = o.shape[-2]
+    o = o.astype(f32).reshape(o.shape[:-2] + (nh // 2, 2, o.shape[-1]))
+    lam_v = lw["lam"].astype(f32)
+    init = lw["lambda_init"].astype(f32)
+    lam = jnp.exp(jnp.sum(lam_v[0] * lam_v[1])) \
+        - jnp.exp(jnp.sum(lam_v[2] * lam_v[3])) + init
+    a = o[..., 0, :] - lam * o[..., 1, :]
+    a = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + eps) \
+        * lw["subln"].astype(f32) * (1.0 - init)
+    return a.astype(dtype).reshape(a.shape[:-2] + (-1,))
+
+
+def _diff_attention(hn, lw, *, nh, nkv, hd, eps, attend):
+    """Differential attention's projections and combine around the program's
+    ``attend(q wide [B, S, nh, 2 hd], k, v [B, S, nkv, hd] or None) -> (o
+    [B, S, nh, 2 hd], carry)``: a tree with ``wqkv`` projects its own keys
+    and values (the self layers), one with ``wq`` alone reads another
+    layer's (the cross layers). Returns ``(out [B, S, H], carry)``."""
+    B, S = hn.shape[0], hn.shape[1]
+    nq = nh * hd
+    if "wqkv" in lw:
+        qkv = jnp.einsum("bsh,hc->bsc", hn, lw["wqkv"]) + lw["bqkv"]
+        k = qkv[..., nq:nq + nkv * hd].reshape(B, S, nkv, hd)
+        v = qkv[..., nq + nkv * hd:].reshape(B, S, nkv, hd)
+        q = qkv[..., :nq]
+    else:
+        q, k, v = jnp.einsum("bsh,hc->bsc", hn, lw["wq"]) + lw["bq"], \
+            None, None
+    o, carry = attend(_diff_queries(q.reshape(B, S, nh, hd)), k, v)
+    a = _diff_combine(o, lw, eps, hn.dtype)
+    return jnp.einsum("bsc,ch->bsh", a, lw["wo"]) + lw["bo"], carry
+
+
+def _sambay_attn_layer(h, lw, scope, attend, *, nh, nkv, hd, eps):
+    """A block whose mixer is differential attention under the named
+    ``scope`` (``window_attn`` / ``yoco_attn``). Returns ``(h, attend's
+    carry)``."""
+    def mixer(hn):
+        with jax.named_scope(scope):
+            return _diff_attention(hn, lw, nh=nh, nkv=nkv, hd=hd, eps=eps,
+                                   attend=attend)
+    return _sambay_block(h, lw, eps, mixer)
+
+
+@jax.named_scope("gmu")
+def _gmu(hn, lw, m):
+    """A Gated Memory Unit: ``(m * silu(h W_1)) W_2``, ``m [B, S, C]`` float32
+    the middle Mamba layer's scan output of the SAME token."""
+    g = jnp.einsum("bsh,hc->bsc", hn, lw["gmu_in"]).astype(jnp.float32)
+    return jnp.einsum("bsc,ch->bsh", (m * jax.nn.silu(g)).astype(hn.dtype),
+                      lw["gmu_out"])
+
+
+def _diff_attend_plain(qw, k, v, mask):
+    """``_diff_queries``' attention in ``jax.numpy`` over whole rows: qw ``[G,
+    Q, nh, 2 hd]``, k, v ``[G, S, nkv, hd]``, mask ``[G, Q, S]``."""
+    G, S, nkv, hd = k.shape
+    grp = qw.shape[2] // (nkv // 2)
+    kp = jnp.repeat(k.reshape(G, S, nkv // 2, 2 * hd), grp, axis=2)
+    vp = jnp.repeat(v.reshape(G, S, nkv // 2, 2 * hd), grp, axis=2)
+    logits = jnp.einsum("gqhd,gkhd->ghqk", qw, kp,
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / math.sqrt(2 * hd))
+    logits = jnp.where(mask[:, None], logits, NEG_INF)
+    probs = jnp.where(mask[:, None], jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("ghqk,gkhd->gqhd", probs.astype(qw.dtype), vp)
+
+
+def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
+                           narrow=True):
+    """A decoder-hybrid-decoder model's layers over an admission group ``x
+    [G, S_pad, H]``: every Mamba layer scans from a zero state over each
+    row's real tokens, the window layers attend inside their window, the
+    middle full layer over everything before, and the cross-decoder (GMUs
+    over the middle Mamba layer's memory, cross-attention over the middle
+    full layer's keys and values) runs, with ``narrow``, on each row's LAST
+    real token only: nothing in it is cached and only that token's output is
+    used. Returns ``(x [G, 1 or S_pad, H], pk, pv [1, G, S_pad, Hkv, D] (the
+    middle full layer's), (states [Mamba layers, G, N, C] float32, tails
+    [Mamba layers, G, conv - 1, C], window keys, values [window layers, G,
+    ssm.ring_rows, KD]))``: what each store holds of a sequence; a window
+    layer's ring row ``j`` holds the last position ``p`` with ``p %
+    ring_rows == j``."""
+    G, S = x.shape[0], x.shape[1]
+    taps = ssm.conv - 1
+    cols = jnp.arange(S, dtype=jnp.int32)
+    live = cols[None, :] < lengths[:, None]
+    rows_g = jnp.arange(G, dtype=jnp.int32)
+    causal = cols[None, :, None] >= cols[None, None, :]
+    in_row = live[:, None, :] & causal
+    in_window = in_row & (cols[None, :, None] - cols[None, None, :]
+                          < ssm.window)
+    ring = jnp.arange(ssm.ring_rows, dtype=jnp.int32)[None, :]
+    span = max(ssm.ring_rows, 1)
+    ring_at = jnp.clip(
+        ring + (lengths[:, None] - 1 - ring) // span * span, 0, S - 1)
+
+    def conv(a, w, bias):
+        ext = jnp.pad(a, ((0, 0), (taps, 0), (0, 0)))
+        c = conv_silu(a, [ext[:, taps - j:taps - j + S]
+                          for j in range(1, taps + 1)], w, bias)
+        tail = jnp.take_along_axis(
+            ext, (lengths[:, None] + jnp.arange(taps)[None])[..., None],
+            axis=1)
+        return c, tail
+
+    def scan(dt, u, b, c, a):
+        def flat(t):
+            return t.reshape((G * S,) + t.shape[2:])
+
+        zero = jnp.zeros((1, G) + a.shape, jnp.float32)
+        if ssm.kernel == "pallas":
+            y, st = ssm_chunk_scan(
+                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
+                start=rows_g * S, length=lengths,
+                fresh=jnp.ones((G,), bool))
+        else:
+            y, st = ssm_reference(
+                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
+                seg=jnp.where(live, rows_g[:, None], G).reshape(-1),
+                first=jnp.broadcast_to(cols == 0, (G, S)).reshape(-1))
+        return jnp.where(live[..., None], y.reshape(G, S, -1), 0.0), st[0]
+
+    def mamba_layer(h, lw):
+        h, (tail, st, y) = _sambay_block(
+            h, lw, eps, lambda hn: _mamba_mixer(hn, lw, conv=conv, scan=scan))
+        return h, (st, tail), y
+
+    def self_attend(mask):
+        def attend(qw, k, v):
+            return _diff_attend_plain(qw, k, v, mask), (k, v)
+        return attend
+
+    attn_layer = functools.partial(_sambay_attn_layer, nh=nh, nkv=nkv, hd=hd,
+                                   eps=eps)
+
+    def self_pair(h, xs):
+        mw, aw = xs
+        h, kept, _ = mamba_layer(h, mw)
+        h, (k, v) = attn_layer(h, aw, "window_attn", self_attend(in_window))
+        held = tuple(jnp.take_along_axis(
+            kv_rows(t), ring_at[..., None], axis=1) for t in (k, v))
+        return h, (kept, held)
+
+    x, (kept, held) = jax.lax.scan(self_pair, x, params["self_layers"])
+    mw, aw = params["mid_layers"]
+    x, kept_mid, m = mamba_layer(x, mw)
+    x, (k, v) = attn_layer(x, aw, "yoco_attn", self_attend(in_row))
+    states, tails = (jnp.concatenate([a, b[None]]) for a, b in
+                     zip(kept, kept_mid))
+    if narrow:
+        last = (lengths - 1)[:, None, None]
+        x = jnp.take_along_axis(x, last, axis=1)
+        m = jnp.take_along_axis(m, last, axis=1)
+        seen = live[:, None, :]
+    else:
+        seen = in_row
+
+    def cross_pair(h, xs):
+        gw, cw = xs
+        h, _ = _sambay_block(h, gw, eps, lambda hn: (_gmu(hn, gw, m), None))
+        h, _ = attn_layer(
+            h, cw, "yoco_attn",
+            lambda qw, _k, _v: (_diff_attend_plain(qw, k, v, seen), None))
+        return h, None
+
+    x, _ = jax.lax.scan(cross_pair, x, params["cross_layers"])
+    return x, k[None], v[None], (states, tails) + tuple(held)
+
+
+def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
+                         tables, *, seg, pos, qstart, qlen, kvlen, nh, nkv,
+                         hd, eps, ssm, decode_attn):
+    """A decoder-hybrid-decoder model's layers over the packed buffer ``x
+    [1, T, H]``. ``store`` is ``(states [Mamba layers, R, N, C] float32,
+    tails [Mamba layers, R, conv - 1, C], window keys, values [window
+    layers, R, ring blocks, bs, KD])``, carried whole like the pool:
+
+    - a Mamba layer reads and writes its own index of the first two at the
+      slots that have a span this step (``_hybrid_span_forward``'s rules: a
+      span whose first position is 0 takes a zero state and a zero tail;
+      spans of one token through ``ssm_recurrent_update``, longer ones
+      through ``ssm_chunk_scan``, which the decode-only program, ``T ==
+      ssm.decode_rows``, leaves out: the plan gave it no chunk);
+    - a window layer writes a token's keys and values at ring row ``pos %
+      (ring blocks * bs)`` of its slot and attends through the ragged kernel
+      over a table the program computes (logical block ``b`` of slot ``r``
+      is ring block ``b % ring blocks``), bounded below by the window: the
+      ring is long enough that no key a query of this step may see was
+      overwritten (``engine``: window + the longest span + a block);
+    - the middle full layer appends to the pool's one layer and attends over
+      it (``kv_attend(pk, pv, 0)``);
+    - the cross-decoder holds no cache and its output is used at a span's
+      LAST token only, so the buffer NARROWS after the middle layers to one
+      row a slot (``[1, R, H]``, a dead slot's row is row 0's): its GMUs gate
+      that token's memory and its attention reads the pool layer the middle
+      layer has just written, never writes it.
+
+    Returns ``(x [1, R, H] by slot, pool_k, pool_v, store)``."""
+    ss, cs, wk, wv = store
+    R, T = qstart.shape[0], x.shape[1]
+    ring_blocks, bs = wk.shape[2], wk.shape[3]
+    live_tok = seg < R
+    seg_c = jnp.minimum(seg, R - 1)
+    fresh = (kvlen - qlen) == 0
+    one, many = qlen == 1, qlen > 1
+    tok_one = live_tok & jnp.take(one, seg_c)
+    row_at = jnp.clip(qstart, 0, T - 1)
+    ragged = (ragged_paged_attention_pallas if decode_attn == "pallas"
+              else ragged_attention_reference)
+    # the window store as the kernel walks it: a pool of R * ring blocks
+    # (merging two leading dims moves nothing) and a table of ring blocks
+    ring_at = (jnp.where(live_tok, seg, R), pos // bs % ring_blocks,
+               pos % bs)
+    ring_tables = (
+        jnp.arange(R, dtype=jnp.int32)[:, None] * ring_blocks
+        + jnp.arange(tables.shape[1], dtype=jnp.int32)[None, :]
+        % ring_blocks)
+
+    def as_pool(w):
+        return w.reshape((w.shape[0], R * ring_blocks) + w.shape[3:])
+
+    def mamba_layer(h, lw, idx, ss, cs):
+        def conv(a, w, bias):
+            c, tails = _span_conv(a[0], cs[idx], w, fresh=fresh,
+                                  qstart=qstart, qlen=qlen, bias=bias)
+            return c[None], cs.at[idx].set(tails)
+
+        def scan(dt, u, b, c, a):
+            dt, u, b, c = dt[0], u[0], b[0], c[0]
+            if ssm.kernel == "pallas":
+                y1, new_ss = ssm_recurrent_update(
+                    *(jnp.take(t, row_at, axis=0) for t in (dt, u, b, c)), a,
+                    ss, layer=idx, live=one, fresh=fresh)
+                y = jnp.take(y1, seg_c, axis=0)
+                if T != ssm.decode_rows:
+                    yn, new_ss = ssm_chunk_scan(
+                        dt, u, b, c, a, new_ss, layer=idx, start=qstart,
+                        length=jnp.where(many, qlen, 0), fresh=fresh,
+                        min_span=2)
+                    y = jnp.where(tok_one[:, None], y, yn)
+            else:
+                y, new_ss = ssm_reference(
+                    dt, u, b, c, a, ss, layer=idx, seg=seg,
+                    first=live_tok & (pos == 0))
+            return jnp.where(live_tok[:, None], y, 0.0)[None], new_ss
+
+        h, (cs, ss, y) = _sambay_block(
+            h, lw, eps, lambda hn: _mamba_mixer(hn, lw, conv=conv, scan=scan))
+        return h, ss, cs, y
+
+    attn_layer = functools.partial(_sambay_attn_layer, nh=nh, nkv=nkv, hd=hd,
+                                   eps=eps)
+
+    def self_pair(carry, xs):
+        mw, aw, idx = xs
+        h, ss, cs, wk, wv = carry
+        h, ss, cs, _ = mamba_layer(h, mw, idx, ss, cs)
+
+        def attend(qw, k, v):
+            at = (idx,) + ring_at
+            nwk = wk.at[at].set(kv_rows(k[0]), mode="drop")
+            nwv = wv.at[at].set(kv_rows(v[0]), mode="drop")
+            attn = ragged(qw[0], as_pool(nwk), as_pool(nwv), ring_tables,
+                          qstart, qlen, kvlen, layer=idx, window=ssm.window)
+            return attn[None], (nwk, nwv)
+
+        h, (wk, wv) = attn_layer(h, aw, "window_attn", attend)
+        return (h, ss, cs, wk, wv), None
+
+    mamba_self, attn_self = params["self_layers"]
+    n_self = attn_self["subln"].shape[0]
+    (x, ss, cs, wk, wv), _ = jax.lax.scan(
+        self_pair, (x, ss, cs, wk, wv),
+        (mamba_self, attn_self, jnp.arange(n_self, dtype=jnp.int32)))
+    mw, aw = params["mid_layers"]
+    x, ss, cs, m = mamba_layer(x, mw, n_self, ss, cs)
+    write_attend = kv_attend(pool_k, pool_v, 0)
+
+    def mid_attend(qw, k, v):
+        attn, pools = write_attend(qw, k, v)
+        return attn[None], pools
+
+    x, (pool_k, pool_v) = attn_layer(x, aw, "yoco_attn", mid_attend)
+    # the buffer narrows: a span's last token, by slot
+    last = jnp.clip(qstart + qlen - 1, 0, T - 1)
+    x, m = jnp.take(x, last, axis=1), jnp.take(m, last, axis=1)
+    kd, vd, _, _ = _kv_attn_args(pool_k, pool_v)
+    rows = jnp.arange(R, dtype=jnp.int32)
+    has = (qlen > 0).astype(jnp.int32)
+
+    def cross_attend(qw, _k, _v):
+        return ragged(qw[0], kd, vd, tables, rows, has, kvlen,
+                      layer=0)[None], None
+
+    def cross_pair(h, xs):
+        gw, cw = xs
+        h, _ = _sambay_block(h, gw, eps, lambda hn: (_gmu(hn, gw, m), None))
+        h, _ = attn_layer(h, cw, "yoco_attn", cross_attend)
+        return h, None
+
+    x, _ = jax.lax.scan(cross_pair, x, params["cross_layers"])
+    return x, pool_k, pool_v, (ss, cs, wk, wv)
 
 
 @jax.named_scope("lm_head")
@@ -1003,7 +1448,7 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
 
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
-                  mla=None, return_picks=False, gdn=None):
+                  mla=None, return_picks=False, gdn=None, ssm=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -1024,9 +1469,20 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     leaves the layer. A hybrid model (``linear_layers``; ``gdn`` its linear
     layers' static numbers) returns ``pk`` / ``pv`` of its FULL layers only
     and, last, what its linear layers' cache holds of each row
-    (``_hybrid_prefill_layers``).
+    (``_hybrid_prefill_layers``). A decoder-hybrid-decoder model
+    (``self_layers``; ``ssm`` its static numbers) returns ``pk`` / ``pv`` of
+    its ONE layer with a row a token and, last, what its Mamba layers' and
+    window layers' stores hold of each row (``_sambay_prefill_layers``).
     """
     B, S = ids.shape
+    if ssm is not None:
+        x = jnp.take(params["embed"], ids, axis=0)
+        x, pk, pv, state = _sambay_prefill_layers(
+            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, ssm=ssm)
+        tok0, keys2 = _first_token(
+            params, _dq_head(params, tied, params["embed"].dtype, a8), x,
+            jnp.ones_like(lengths), keys, temps, top_ks, eps)
+        return pk, pv, tok0, keys2, state
     if gdn is not None:
         x = jnp.take(params["embed"], ids, axis=0)
         x, pk, pv, state = _hybrid_prefill_layers(
@@ -1078,14 +1534,14 @@ def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
     position, sampled under the row's key. Returns ``(tok0, keys')``."""
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [G, H]
-    logits = _head_logits(_rms(last, params["final_norm"], eps), head)
+    logits = _head_logits(_final_norm(params, last, eps), head)
     both = jax.vmap(jax.random.split)(keys)  # [G, 2, 2]
     return sample_rows(logits, both[:, 1], temps, top_ks), both[:, 0]
 
 
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
-                     mla=None, return_picks=False, gdn=None):
+                     mla=None, return_picks=False, gdn=None, ssm=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -1108,7 +1564,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
     return jax.jit(functools.partial(
         _prefill_impl, nh=nh, nkv=nkv, hd=hd, eps=eps, theta=theta,
         tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks,
-        **({} if gdn is None else {"gdn": gdn})))
+        **({} if gdn is None else {"gdn": gdn}),
+        **({} if ssm is None else {"ssm": ssm})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -1356,8 +1813,14 @@ def _span_last_sample(params, head, x, qstart, qlen, keys, temps, top_ks,
     """
     T = x.shape[1]
     last_idx = jnp.clip(qstart + qlen - 1, 0, T - 1)
-    last = jnp.take(x[0], last_idx, axis=0)                 # [R, H]
-    last_h = _rms(last, params["final_norm"], eps)
+    return _rows_sample(params, head, jnp.take(x[0], last_idx, axis=0),
+                        keys, temps, top_ks, eps)
+
+
+def _rows_sample(params, head, last, keys, temps, top_ks, eps):
+    """One sample a slot from ``last [R, H]``, each slot's hidden state at
+    the position it samples from. Returns ``(tok0, keys')``."""
+    last_h = _final_norm(params, last, eps)
     logits = _head_logits(last_h, head)
     both = jax.vmap(jax.random.split)(keys)                 # [R, 2, 2]
     tok0 = sample_rows(logits, both[:, 1], temps, top_ks)
@@ -1386,14 +1849,12 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
     token-by-token oracle over the whole buffer). Returns ``(x, pool_k,
     pool_v, state)``."""
     R, T = qstart.shape[0], x.shape[1]
-    taps = gdn.conv - 1
     live_tok = seg < R
     seg_c = jnp.minimum(seg, R - 1)
     fresh = (kvlen - qlen) == 0
     one, many = qlen == 1, qlen > 1
     tok_one = live_tok & jnp.take(one, seg_c)
     row_at = jnp.clip(qstart, 0, T - 1)
-    slots = jnp.arange(R, dtype=jnp.int32)
 
     def full_layer(carry, lw, idx):
         h, pk, pv, st = carry
@@ -1407,32 +1868,9 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
 
         def mix(u, g, beta, conv_w):
             u, g, beta = u[0], g[0], beta[0]
-            held = cs[idx]                                  # [R, taps, C]
-            tail = jnp.where(fresh[:, None, None], jnp.zeros_like(held), held)
-            # a token's earlier inputs are the rows above it, except in a
-            # span's first rows, which take the slot's tail: a few rows a
-            # span, scattered over the shifted buffer (a gather of the
-            # tail for every packed row cost 6 % of a step)
-            prev = []
-            for j in range(1, taps + 1):
-                at = jnp.concatenate([jnp.where(qlen > i, qstart + i, T)
-                                      for i in range(j)])
-                rows = jnp.concatenate([tail[:, taps + i - j]
-                                        for i in range(j)])
-                prev.append(jnp.roll(u, j, axis=0).at[at].set(
-                    rows, mode="drop"))
-            up = conv_silu(u, prev, conv_w)
-            # the slot's new tail: the span's last inputs, and where the
-            # span is shorter than the tail, the old tail moved up
-            rows = []
-            for k in range(taps):
-                at = qlen - taps + k
-                rows.append(jnp.where(
-                    (at >= 0)[:, None],
-                    jnp.take(u, jnp.clip(qstart + at, 0, T - 1), axis=0),
-                    tail[slots, jnp.clip(taps + at, 0, taps - 1)]))
-            new_cs = cs.at[idx].set(jnp.where(
-                (qlen > 0)[:, None, None], jnp.stack(rows, 1), held))
+            up, tails = _span_conv(u, cs[idx], conv_w, fresh=fresh,
+                                   qstart=qstart, qlen=qlen)
+            new_cs = cs.at[idx].set(tails)
             q, k, v = gdn_split(up, gdn)
             if gdn.kernel == "pallas":
                 o1, new_ss = gdn_recurrent_update(
@@ -1462,7 +1900,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
                          moe=None, mla=None, return_picks=False, state=None,
-                         gdn=None):
+                         gdn=None, ssm=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -1479,7 +1917,9 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     and chunk alike, attends in the absorbed form. A hybrid model (``state``
     its linear layers' store, ``gdn`` their static numbers) rotates nothing
     (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
-    the store.
+    the store. A decoder-hybrid-decoder model (``ssm``) runs
+    ``_sambay_span_forward`` over ``state``, its Mamba and window layers'
+    stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``.
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
@@ -1525,6 +1965,12 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
 
         return attend
 
+    if ssm is not None:
+        x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
+        return _sambay_span_forward(
+            params, x, pool_k, pool_v, state, kv_attend, tables, seg=seg,
+            pos=pos, qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv,
+            hd=hd, eps=eps, ssm=ssm, decode_attn=decode_attn)
     if state is not None:
         x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
         return _hybrid_span_forward(
@@ -1594,7 +2040,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       prev_toks, take, chunk_keys, adopt, state=None,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
-                      moe=None, mla=None, return_picks=False, gdn=None):
+                      moe=None, mla=None, return_picks=False, gdn=None,
+                      ssm=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -1676,9 +2123,13 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         x, pk, pv, state = _packed_span_forward(
             params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
             kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-            decode_attn=decode_attn, state=state, gdn=gdn)
-        tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
-                                          keys_in, temps, top_ks, eps)
+            decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm)
+        if ssm is not None:     # x came back one row a slot
+            tok0, keys_t0 = _rows_sample(params, head, x[0], keys_in, temps,
+                                         top_ks, eps)
+        else:
+            tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
+                                              keys_in, temps, top_ks, eps)
         keys_out = jnp.where((adopt > 0)[:, None], keys_t0, keys_in)
         return pk, pv, tok0[None], tok0, keys_out, state
     x, pk, pv, moe_stats = _packed_span_forward(
@@ -1720,7 +2171,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
-                         return_picks=False, gdn=None):
+                         return_picks=False, gdn=None, ssm=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -1761,9 +2212,12 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             eps=eps, theta=theta, tied=tied, decode_attn=decode_attn,
             a8=a8, fused=fused, moe=moe, mla=mla,
             return_picks=return_picks,
-            **({} if gdn is None else {"gdn": gdn})),
-        # argument 18: a hybrid model's state store (absent otherwise)
-        donate_argnums=((1, 2) + ((18,) if gdn is not None else ()))
+            **({} if gdn is None else {"gdn": gdn}),
+            **({} if ssm is None else {"ssm": ssm})),
+        # argument 18: the stores by slot of a model with recurrent or
+        # window layers (absent otherwise)
+        donate_argnums=((1, 2) + ((18,) if gdn is not None or ssm is not None
+                                  else ()))
         if donate else ())
 
 

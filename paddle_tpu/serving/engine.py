@@ -322,11 +322,13 @@ class ContinuousBatchingEngine:
                    "spec_decode": bool(spec_decode),
                    "decode_chunk > 1": int(decode_chunk) > 1,
                    "prefix_cache": bool(prefix_cache)}
-            if "wkv_a" in self._params or "linear_layers" in self._params:
+            if any(k in self._params
+                   for k in ("wkv_a", "linear_layers", "self_layers")):
                 # a latent pool has no heads to scale by and no V side:
                 # the quantized pools' planes and kernels do not apply;
-                # a hybrid model's quantized cache would be its recurrent
-                # state's to define as well, and nothing defines it
+                # a quantized cache of a model with recurrent or window
+                # layers would be their stores' to define as well, and
+                # nothing defines it
                 off["kv_dtype"] = kv_dtype is not None
             bad = [name for name, on in off.items() if on]
             if bad:
@@ -398,7 +400,8 @@ class ContinuousBatchingEngine:
         # their cache is a store by slot beside the pool
         # (``PagedKVCache.state``), and the pool holds rows for the OTHER
         # layers only
-        self._stateful = "linear_layers" in self._params
+        self._stateful = "linear_layers" in self._params \
+            or "self_layers" in self._params
         kv_layers = c.num_kv_layers if self._stateful \
             else c.num_hidden_layers
         # what a cached token's row is: Hkv heads of head_dim on a K and a
@@ -477,16 +480,30 @@ class ContinuousBatchingEngine:
             pool = BlockManager(
                 kv_layers, live, bs, dtype=dtype,
                 kv_dtype=self._kv_dtype, mesh=tp_mesh, **geom)
-        state_geometry = None
-        if self._stateful:
+        state_geometry = window_geometry = None
+        if "linear_layers" in self._params:
             g = c.gdn
-            state_geometry = (c.num_linear_layers, g.heads, g.dk, g.dv,
+            state_geometry = (c.num_linear_layers, (g.heads, g.dk, g.dv),
                               g.conv - 1, c.conv_channels)
+        elif self._stateful:
+            state_geometry = (c.num_ssm_layers,
+                              (c.mamba_d_state, c.d_inner),
+                              c.mamba_d_conv - 1, c.d_inner)
+            # a window layer's ring a slot: the window, the longest span a
+            # step may write before it attends (a chunk; one token without
+            # chunking) and a block, in whole blocks: no key a query of the
+            # step may see is overwritten by the step's own rows
+            span = int(prefill_chunk) if prefill_chunk else 1
+            self._ring_blocks = min(
+                max_blocks, -(-(c.sliding_window + -(-span // bs) * bs
+                                + bs - 1) // bs))
+            window_geometry = (c.num_window_layers, self._ring_blocks)
         self.cache = PagedKVCache(
             kv_layers, self.num_slots, self.max_seq_len,
             geom["num_kv_heads"], geom["head_dim"], dtype=dtype,
             block_size=bs, pool=pool, prefix_cache=self.prefix_cache,
-            kv_dtype=self._kv_dtype, state_geometry=state_geometry)
+            kv_dtype=self._kv_dtype, state_geometry=state_geometry,
+            window_geometry=window_geometry)
         # chunked prefill: the chunk is rounded UP to a block multiple so
         # every non-final chunk boundary is block-aligned: a partially
         # prefilled prompt is exactly a prefix of whole pool blocks + a
@@ -840,15 +857,33 @@ class ContinuousBatchingEngine:
         step's tokens by kind, counted at the tiling the step's kernel
         derives for itself (``decode.attention_grid``)."""
         heads = self.config.num_attention_heads // self._tp
-        work = ragged_grid_counts(
-            qstart, qlen, kvlen, packed_tokens=packed,
-            heads=heads, block_size=self.cache.block_size,
-            table_entries=self.cache.max_blocks,
-            **attention_grid(self._params, self.cache.pool.k,
-                             self.cache.max_blocks, heads, packed,
-                             tp=self._tp, head_dim=self.config.head_dim))
+        # (a model whose kernel head is a PAIR of heads says so)
+        head_dim = getattr(self.config, "kernel_head_dim",
+                           self.config.head_dim)
+
+        def counts(qstart, qlen, packed, **window):
+            return ragged_grid_counts(
+                qstart, qlen, kvlen, packed_tokens=packed,
+                heads=heads, block_size=self.cache.block_size,
+                table_entries=self.cache.max_blocks, **window,
+                **attention_grid(self._params, self.cache.pool.k,
+                                 self.cache.max_blocks, heads, packed,
+                                 tp=self._tp, head_dim=head_dim))
+
+        work = counts(qstart, qlen, packed)
         work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
                     prefill_tokens=prefill_tokens)
+        if self.cache.window is not None:
+            # the kernel's calls by layer kind: ``work`` is the middle full
+            # layer's; a window layer's call needs the keys inside the
+            # window only (``window_kv_tokens``); a cross layer's runs one
+            # row a slot (the buffer narrows to ``cross_rows`` after the
+            # middle layers) over the same cache, so its ``kv_tokens`` are
+            # the middle layer's
+            work["window_kv_tokens"] = counts(
+                qstart, qlen, packed,
+                window=self.config.sliding_window)["kv_tokens"]
+            work["cross_rows"] = self.num_slots
         if self._stateful:
             # what ONE linear layer call does: the rows whose state it
             # reads and writes (every live span's slot), and what it sends
@@ -872,8 +907,13 @@ class ContinuousBatchingEngine:
                 int(c.num_experts_per_tok), bool(c.norm_topk_prob))
         if "wkv_a" in self._params:
             consts["mla"] = c.mla
-        if self._stateful:
+        if "linear_layers" in self._params:
             consts["gdn"] = c.gdn
+        elif self._stateful:
+            consts["ssm"] = c.ssm._replace(
+                ring_rows=self._ring_blocks * self.cache.block_size,
+                decode_rows=self._decode_rows
+                if len(self._step_rows) == 2 else 0)
         if self._routing is not None:
             consts["return_picks"] = True
         return consts
@@ -1419,8 +1459,7 @@ class ContinuousBatchingEngine:
                 self.cache.write_prefill(slot, pk[:, i], pv[:, i],
                                          seq.work_len)
                 if state is not None:
-                    self.cache.write_state(slot, state[0][:, i],
-                                           state[1][:, i])
+                    self.cache.write_state(slot, *(a[:, i] for a in state))
                     self.stats["state_rows"] += 1
                 self._install_seq(seq, slot, tok0s[i], keys2[i],
                                   seq.work_len, finished)
@@ -2186,11 +2225,11 @@ class ContinuousBatchingEngine:
             dec_mask, keys_in, temps, topks,
             self._no_toks if prev is None else prev.tok_fin, take,
             chunk_keys, adopt,
-            *((self.cache.state,) if self._stateful else ()))
+            *((self.cache.store,) if self._stateful else ()))
         # the program is on the device's queue: commit what it advances
         self.cache.update(npk, npv)
         if self._stateful:
-            self.cache.state = moe.pop()
+            self.cache.store = moe.pop()
             self.stats["state_rows"] += len(rows) + len(chunk_rows)
         self._keys = keys_out
         chunks = []

@@ -299,12 +299,17 @@ def _tier_inject(donate, quantized=False, tp=1):
 
 @functools.lru_cache(maxsize=None)
 def _state_writer(donate):
-    """The jitted slot write of the recurrent layers' store."""
-    def write(ss, cs, states, tails, slot):
-        return (ss.at[:, slot].set(states.astype(ss.dtype)),
-                cs.at[:, slot].set(tails.astype(cs.dtype)))
+    """The jitted slot write of the stores by slot: every array ``[layers,
+    num_slots, ...]`` takes its ``[layers, ...]`` at ``slot``."""
+    def write(store, held, slot):
+        for a, x in zip(store, held):
+            if x.shape != a.shape[:1] + a.shape[2:]:
+                raise ValueError(
+                    f"a slot of the store {a.shape} does not take {x.shape}")
+        return tuple(a.at[:, slot].set(x.astype(a.dtype))
+                     for a, x in zip(store, held))
 
-    return jax.jit(write, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(write, donate_argnums=(0,) if donate else ())
 
 
 def tier_compilations() -> int:
@@ -347,7 +352,15 @@ class PagedKVCache:
     dk, dv] float32, tails [layers, num_slots, conv rows, channels])``.
     Nothing here ever zeroes a slot's state: the programs give a span whose
     first position is 0 a zero state, whatever the slot held.
-    ``bytes_per_token`` counts the pool, ``state_bytes_per_slot`` the store.
+    A layer that attends inside a WINDOW (``window_geometry``) needs its
+    last ``window`` keys and values only: constant a slot too, so a third
+    kind, :attr:`window` ``(keys, values [layers, num_slots, ring blocks,
+    block_size, KD])``: a ring of blocks a slot, a token's row at ``position
+    % (ring blocks * block_size)``, which the step programs write and walk
+    through a table they compute (``decode._sambay_span_forward``). A layer
+    that caches nothing (a cross-decoder's) appears nowhere.
+    ``bytes_per_token`` counts the pool, ``state_bytes_per_slot`` and
+    ``window_bytes_per_slot`` the two stores.
 
     The pool's device arrays are the single source of KV truth; the
     decode / suffix-prefill programs update them functionally and the
@@ -363,7 +376,7 @@ class PagedKVCache:
     def __init__(self, num_layers, num_slots, max_seq_len, num_kv_heads,
                  head_dim, dtype=jnp.float32, block_size=32, pool=None,
                  prefix_cache=None, donate=None, kv_dtype=None,
-                 state_geometry=None):
+                 state_geometry=None, window_geometry=None):
         from .block_manager import BlockManager
         bs = int(block_size)
         if bs < 1:
@@ -413,14 +426,26 @@ class PagedKVCache:
             donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
         # the recurrent layers' store (class docstring): ``state_geometry``
-        # is ``(layers, heads, dk, dv, conv rows, channels)``
+        # is ``(layers, a state's shape, conv rows, channels)``
         self.state = None
         if state_geometry is not None:
-            ll, heads, dk, dv, rows, channels = (
-                int(n) for n in state_geometry)
+            ll, shape, rows, channels = state_geometry
             self.state = (
-                jnp.zeros((ll, self.num_slots, heads, dk, dv), jnp.float32),
-                jnp.zeros((ll, self.num_slots, rows, channels), dtype))
+                jnp.zeros((int(ll), self.num_slots)
+                          + tuple(int(n) for n in shape), jnp.float32),
+                jnp.zeros((int(ll), self.num_slots, int(rows),
+                           int(channels)), dtype))
+        # the window layers' store: ``window_geometry`` is ``(layers, ring
+        # blocks a slot)``; a row is the pool's
+        self.window = None
+        if window_geometry is not None:
+            wl, ring = (int(n) for n in window_geometry)
+            shape = (wl, self.num_slots, ring, bs, pool.k.shape[-1])
+            self.window = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+    def _bytes_per_slot(self, store) -> int:
+        return sum(a.size * np.dtype(a.dtype).itemsize
+                   for a in store or ()) // self.num_slots
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -428,18 +453,42 @@ class PagedKVCache:
         over all their layers, whatever the sequence's length (0 for a
         model without such layers): the ``serving_state_bytes_per_slot``
         gauge."""
-        if self.state is None:
-            return 0
-        return sum(a.size * np.dtype(a.dtype).itemsize
-                   for a in self.state) // self.num_slots
+        return self._bytes_per_slot(self.state)
 
-    def write_state(self, slot, states, tails):
+    @property
+    def window_bytes_per_slot(self) -> int:
+        """HBM bytes one slot's rings of window keys and values hold over
+        all the window layers, whatever the sequence's length (0 for a model
+        without such layers): the ``serving_window_bytes_per_slot`` gauge."""
+        return self._bytes_per_slot(self.window)
+
+    @property
+    def store(self):
+        """The stores by slot as the step programs take them, one tuple:
+        the recurrent layers' pair and then the window layers'."""
+        return (self.state or ()) + (self.window or ())
+
+    @store.setter
+    def store(self, arrays):
+        n = len(self.state or ())
+        if n:
+            self.state = tuple(arrays[:n])
+        if self.window is not None:
+            self.window = tuple(arrays[n:])
+
+    def write_state(self, slot, *held):
         """Install what a whole-prompt prefill computed for ``slot``'s
-        recurrent layers: ``states [layers, heads, dk, dv]``, ``tails
-        [layers, conv rows, channels]`` (one compile-once scatter; the
-        slot is a runtime argument)."""
-        self.state = _state_writer(self._donate)(
-            *self.state, states, tails, np.int32(slot))
+        layers with a store by slot, in :attr:`store`'s order: ``states
+        [layers, ...]``, ``tails [layers, conv rows, channels]`` and, for
+        window layers, the ring's ``keys, values [layers, ring rows, KD]``
+        (one compile-once scatter; the slot is a runtime argument). The
+        ring's rows are stored as blocks, ``[ring blocks, bs, KD]``."""
+        n = len(self.state or ())
+        ring = () if self.window is None else self.window[0].shape[2:]
+        held = tuple(held[:n]) + tuple(
+            x.reshape(x.shape[:1] + ring) for x in held[n:])
+        self.store = _state_writer(self._donate)(
+            self.store, held, np.int32(slot))
 
     # ------------------------------------------------------------- slots
     @property
@@ -633,6 +682,10 @@ class PagedKVCache:
             "used_state": (self.num_slots - self.num_free)
             * self.state_bytes_per_slot,
             "capacity_state": self.num_slots * self.state_bytes_per_slot,
+            # the window layers' rings: by slots too
+            "used_window": (self.num_slots - self.num_free)
+            * self.window_bytes_per_slot,
+            "capacity_window": self.num_slots * self.window_bytes_per_slot,
         }
 
     # ------------------------------------------------------------ writes

@@ -533,6 +533,13 @@ class ServingGateway:
                     "convolution tails hold over all their layers, "
                     "whatever the sequence's length."
                     ).set_fn(lambda: self.engine.cache.state_bytes_per_slot)
+            if self.engine.cache.window is not None:
+                r.gauge("serving_window_bytes_per_slot",
+                        "HBM bytes one slot's rings of window keys and "
+                        "values hold over all the window layers, whatever "
+                        "the sequence's length."
+                        ).set_fn(
+                    lambda: self.engine.cache.window_bytes_per_slot)
             r.counter("serving_state_rows_total",
                       "Spans whose slot's recurrent state a program read "
                       "and wrote (decode rows, prefill chunks, whole "

@@ -24,7 +24,8 @@ from jax.sharding import (NamedSharding, PartitionSpec,
 
 from paddle_tpu.kernels import (flash_attention, gated_delta_rule, moe_ffn,
                                 pallas_flash, pallas_mla_ragged_attention,
-                                pallas_paged_decode, pallas_ragged_attention)
+                                pallas_paged_decode, pallas_ragged_attention,
+                                selective_scan)
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.profiler.metrics import peak_flops_per_chip
 from paddle_tpu.utils import compile_cache
@@ -55,7 +56,8 @@ def v5e_devices(monkeypatch):
     if devices is None:
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
     for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
-                pallas_mla_ragged_attention, moe_ffn, gated_delta_rule):
+                pallas_mla_ragged_attention, moe_ffn, gated_delta_rule,
+                selective_scan):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -294,6 +296,65 @@ class TestMosaicCompilesOlmoHybrid:
             jnp.bfloat16, 32, self.H * hd, mb, self.H, self.T,
             head_dim=hd) == dict(block_q=128 * self.H, pages=4,
                                  one_token=True)
+
+
+class TestMosaicCompilesPhi4Flash:
+    """Phi-4-mini-flash's kernels at its published widths (Mamba layers of
+    5,120 channels x 16 states, a float32 state by slot; 40 wide queries over
+    10 KV pairs of 128, a pool row of 1,280, window 512) and at the serving
+    cell's shapes: 48 slots, 9 Mamba layers, a packed buffer of 48 + 512
+    rows, rings of 33 blocks."""
+    C, N, R, LL, T = 5120, 16, 48, 9, 560
+
+    def _args(self, v5e, rows):
+        f32 = jnp.float32
+        return (v5e((rows, self.C), f32), v5e((rows, self.C), f32),
+                v5e((rows, self.N), f32), v5e((rows, self.N), f32),
+                v5e((self.N, self.C), f32),
+                v5e((self.LL, self.R, self.N, self.C), f32))
+
+    def _in_place(self, fn, args):
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the store (141 MiB) is aliased in and out, no layer of it copied
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes > 128 * 2 ** 20
+        assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+    def test_the_decode_row_update_in_place(self, v5e):
+        def update(dt, u, b, c, a, st, live, fresh, layer):
+            return selective_scan.ssm_recurrent_update(
+                dt, u, b, c, a, st, layer=layer, live=live, fresh=fresh)
+        self._in_place(update, self._args(v5e, self.R) + (
+            v5e((self.R,), jnp.bool_), v5e((self.R,), jnp.bool_),
+            v5e((), jnp.int32)))
+
+    @pytest.mark.parametrize("rows", [560, 48], ids=["chunk", "decode_only"])
+    def test_the_chunk_scan_in_place(self, v5e, rows):
+        def scan(dt, u, b, c, a, st, start, length, fresh, layer):
+            return selective_scan.ssm_chunk_scan(
+                dt, u, b, c, a, st, layer=layer, start=start, length=length,
+                fresh=fresh, min_span=2)
+        self._in_place(scan, self._args(v5e, rows) + (
+            v5e((self.R,), jnp.int32), v5e((self.R,), jnp.int32),
+            v5e((self.R,), jnp.bool_), v5e((), jnp.int32)))
+
+    def test_windowed_ragged_attention_over_the_rings(self, v5e):
+        i32, hd, heads, ring, mb = jnp.int32, 128, 40, 33, 256
+
+        def attend(q, wk, wv, tables, qs, ql, kl, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, wk, wv, tables, qs, ql, kl, layer=layer, window=512)
+        store = v5e((8, self.R * ring, 32, 10 * hd))
+        n = _mosaic_calls(
+            attend, v5e((self.T, heads, hd)), store, store,
+            v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
+            v5e((self.R,), i32), v5e((), i32))
+        assert n == 1
+        assert pallas_ragged_attention.grid_params(
+            jnp.bfloat16, 32, 10 * hd, mb, heads, self.T,
+            head_dim=hd) == dict(block_q=96 * heads, pages=6, one_token=True)
 
 
 class TestUnifiedStepLeavesThePoolInPlace:

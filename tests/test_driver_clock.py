@@ -407,7 +407,7 @@ class TestBenchmarkReaders:
             in enumerate(even["metrics_delta"]["scrapes"])]
         assert bench_clock.bracket(even) is None and reduce(even) is None
 
-    def test_every_new_metric_is_listed_for_the_five_serving_cells(self):
+    def test_every_new_metric_is_listed_for_every_serving_cell(self):
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             bench = json.load(f)
@@ -416,6 +416,6 @@ class TestBenchmarkReaders:
         entries = {m["name"]: m for m in bench["per_layer"]}
         for name in EXPECTED:
             m = entries[name]
-            assert m["workloads"] == serving and len(serving) == 5
+            assert m["workloads"] == serving and len(serving) >= 6
             assert m["source"] == "program_counter"
             assert m["moves"] == "gap_p50_ms"
