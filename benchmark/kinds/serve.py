@@ -29,7 +29,7 @@ import trafficgen  # noqa: E402
 import window  # noqa: E402
 
 HTTP_TIMEOUT_S = 600        # a cold first run compiles under a request
-TRACE_S = 3.0               # the device trace covers this much mid-window
+TRACE_S = 3.0               # the device trace covers this much of the window
 
 
 # ===================================================================== parent
@@ -278,8 +278,10 @@ def drive(ctx):
                     parse_prometheus(_get(base + "/metrics")))]
         compile_start = child.ask("stats")
         traced = bool(args.trace)
-        trace_at = (win[0] + win[1]) / 2.0 - TRACE_S / 2.0
-        trace_window = None
+        trace_at_s, traced_req = trafficgen.trace_start_s(
+            schedule, mix, ramp_s, args.seconds, TRACE_S)
+        trace_at = t0 + trace_at_s
+        trace_window = trace_took = None
         next_scrape = win[0] + 1.0
         while True:
             now = time.monotonic()
@@ -288,9 +290,12 @@ def drive(ctx):
             if traced and trace_window is None and now >= trace_at \
                     and args.seconds > TRACE_S:
                 child.ask("trace_start")
+                started = time.monotonic()
                 time.sleep(TRACE_S)
                 child.ask("trace_stop")
                 trace_window = TRACE_S
+                trace_took = [started - now,
+                              time.monotonic() - started - TRACE_S]
                 continue
             if traced and now >= next_scrape:
                 scrapes.append((now, parse_prometheus(
@@ -345,12 +350,26 @@ def drive(ctx):
          "compiles_in_window": compiles_in_window,
          "compile_whole_run": final["compile"],
          "engine_restarts": health["engine_restarts"], "faults": faults,
-         "backlog_mid_end": _backlog(scrapes, win)})
+         "backlog_mid_end": _backlog(scrapes, win),
+         **({"trace_at_s": trace_at_s,
+             "trace_start_stop_took_s": trace_took,
+             "traced_request": traced_req and {
+                 k: traced_req[k] for k in ("index", "due", "max_tokens")}}
+            if trace_window else {})})
     correct = (verdict["ok"] and compiles_in_window == 0
                and health["engine_restarts"] == 0 and faults == 0
                and len(att) > 0)
+    compared = {"worst_margin_share": (verdict["worst_margin_share"],
+                                       verdict["tolerance"]),
+                "compiles_in_window": (compiles_in_window, 0),
+                "engine_restarts": (health["engine_restarts"], 0),
+                "faults": (faults, 0)}
+    if "worst_expert_margin" in verdict:
+        compared["worst_expert_margin"] = (verdict["worst_expert_margin"],
+                                           verdict["expert_margin"])
     return {
         "correct": bool(correct), "attempted": len(att), "failed": len(bad),
+        "compared": compared,
         "setup_s": setup_s, "window": win, "client": records,
         "metrics_delta": {"start": first, "end": last, "scrapes": scrapes},
         "span_export": spans, "debug_profile": profile,
@@ -527,8 +546,10 @@ def child_main(argv):
             out = {"device": common.device_doc(devs),
                    "compile": stats.snapshot()}
             if traced:
+                import timeline
                 import xplane_reduce
-                out["xplane"] = xplane_reduce.reduce_dir(trace_dir)
+                out["xplane"] = xplane_reduce.reduce_dir(
+                    trace_dir, timeline.SPAN_NAMES)
             server.shutdown()
             common.say(out)
             return 0

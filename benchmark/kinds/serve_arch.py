@@ -169,8 +169,10 @@ def child_main(argv):
             out = {"device": common.device_doc(devs),
                    "compile": stats.snapshot()}
             if traced:
+                import timeline
                 import xplane_reduce
-                out["xplane"] = xplane_reduce.reduce_dir(trace_dir)
+                out["xplane"] = xplane_reduce.reduce_dir(
+                    trace_dir, timeline.SPAN_NAMES)
             server.shutdown()
             common.say(out)
             return 0

@@ -83,6 +83,16 @@ def drive(ctx):
     return {
         "correct": bool(correct), "attempted": rep["steps"],
         "failed": rep["steps_nonfinite"],
+        "compared": {
+            "forward_worst_nat": (rep["forward_worst"],
+                                  rep["forward_tolerance"]),
+            "loss0_gap_nat": (abs(rep["loss0"] - rep["ref_loss"]),
+                              rep["tolerance"]),
+            # the first batch's loss after step 0 less before: under 0
+            "loss_change_after_step0": (rep["loss0_after_step0"]
+                                        - rep["loss0"], 0),
+            "steps_nonfinite": (rep["steps_nonfinite"], 0),
+            "compiles_in_window": (rep["compiles_in_window"], 0)},
         "setup_s": rep["window_start_monotonic"] - ctx["t_process_start"],
         "child": rep, "xplane": rep.get("xplane"), "device": rep["device"],
         "trace_window_s": None, "mix": rep["mix"], "model": rep["model"],

@@ -7,7 +7,10 @@ token decoded in the window attends to its request's context at that
 moment, and every prompt whose first token fell in the window was prefilled
 (P (P + 1) / 2 pairs). ``flops_bytes.ragged_work`` turns that into FLOPs
 and bytes; the larger of FLOPs / peak and bytes / peak is the least time,
-for the window, scaled to the traced part (steady state assumed)."""
+for the window, scaled to the traced part (steady state assumed: in an
+open loop below its knee the traced part is laid on a request,
+``trafficgen.trace_start_s``, and is busier than the window's mean, so the
+share reads low there; ``ragged_attn_roofline_counted`` assumes nothing)."""
 import flops_bytes
 import readers
 import window

@@ -21,6 +21,7 @@ T_PROCESS_START = time.monotonic()      # set-up is counted from here
 import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -125,6 +126,16 @@ def main(argv=None, mix_path=None):
             note({"event": "mosaic_calls", "calls": x["mosaic_calls"],
                   "collective_s": x["collective_s"],
                   "annotations": x["annotations"]})
+    # every number ``correct`` compared, beside its limit: the last key of
+    # the result and the last lines on standard error, which is what the
+    # driver's record keeps of a run that is not correct
+    # (a number that is not finite goes as its name: the line stays JSON)
+    result["compared"] = {
+        k: {"value": v if math.isfinite(v) else repr(v), "limit": lim}
+        for k, (v, lim) in src["compared"].items()}
+    for k, c in result["compared"].items():
+        print(f"compared {k}: {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

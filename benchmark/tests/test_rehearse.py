@@ -39,6 +39,13 @@ def test_cell_rehearses(cell, trace):
     assert last["device"]["platform"] == "cpu"      # never a measurement
     assert last["correct"] is True and last["attempted"] > 0
     assert last["failed"] == 0
+    # what ``correct`` compared, each number beside its limit: the result's
+    # last key and standard error's last lines
+    assert list(last)[-1] == "compared" and last["compared"]
+    said = p.stderr.strip().splitlines()[-len(last["compared"]):]
+    for ln, (name, c) in zip(said, last["compared"].items()):
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+        assert ln == f"compared {name}: {c['value']} limit {c['limit']}"
     group = "per_layer" if trace else "end_to_end"
     assert set(last["metrics"]) <= metrics_of(group, cell)
     if not trace:

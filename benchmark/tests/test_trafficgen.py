@@ -107,3 +107,105 @@ def test_train_check_tokens_cover_rows_and_the_sequence():
     # a sequence shorter than the count still gives valid positions
     assert all(0 <= p < 7 for _, p in trafficgen.train_check_tokens(0, 2,
                                                                   8, 16))
+
+
+# ------------------------------------------------- where a traced run traces
+TRACE_S = 3.0
+CHAT_SEEDS = [1949662630] + random.Random(41).sample(range(2 ** 31), 2000)
+CLOSED = sorted(n[:-5] for n in os.listdir(os.path.join(BENCH, "traffic"))
+                if mix(n[:-5]).get("loop") == "closed")
+
+
+def chat_schedule(seed, seconds=40.0):
+    """``serve_schedule`` as ``kinds/serve.py`` asks for it, prompts of one
+    token: 2,000 seeds of whole prompts would take a minute, and the rule
+    may read ``due`` and ``max_tokens`` only."""
+    m = mix("chat-steady")
+    m = {**m, "prompt_tokens": {"dist": "constant", "value": 1}}
+    return trafficgen.serve_schedule(m, seed, m["ramp_s"] + seconds + 5.0, 2)
+
+
+def overlap_s(start, req, s_per_token):
+    end = req["due"] + 0.05 + s_per_token * req["max_tokens"]
+    return min(start + TRACE_S, end) - max(start, req["due"])
+
+
+def test_lengths_of_the_chat_schedule_do_not_depend_on_the_prompts():
+    m = mix("chat-steady")
+    whole = trafficgen.serve_schedule(m, 1949662630, 75.0, 32768)
+    assert [(r["due"], r["max_tokens"]) for r in whole] \
+        == [(r["due"], r["max_tokens"]) for r in chat_schedule(1949662630)]
+
+
+@pytest.mark.parametrize("block", range(8))
+def test_an_open_loop_traces_a_request_of_its_schedule(block):
+    """Every seed's traced 3 s lie inside the window and hold the traced
+    request for 0.1 s or more even at half of today's 11 ms a token, and
+    for 0.25 s wherever a request is due in the rule's first interval."""
+    m = mix("chat-steady")
+    ramp, seconds = float(m["ramp_s"]), 40.0
+    for seed in CHAT_SEEDS[block::8]:
+        sched = chat_schedule(seed)
+        start, req = trafficgen.trace_start_s(sched, m, ramp, seconds,
+                                              TRACE_S)
+        assert (start, req) == trafficgen.trace_start_s(
+            chat_schedule(seed), m, ramp, seconds, TRACE_S)
+        assert ramp <= start and start + TRACE_S <= ramp + seconds
+        assert req is not None and req in sched
+        first = [r for r in sched
+                 if ramp + 2 <= r["due"] <= ramp + seconds - 10]
+        assert overlap_s(start, req, 0.0055) >= (0.25 if first else 0.1), seed
+        if first:
+            assert req["max_tokens"] == max(r["max_tokens"] for r in first)
+            assert start == max(req["due"] - trafficgen.TRACE_LEAD_S, ramp)
+
+
+def test_the_seed_whose_middle_is_empty_traces_request_8():
+    """Seed 1949662630 (PR 37's refused check): nothing is due between 44.6
+    and 57.9 s, so the middle, 48.5-51.5 s, held no request."""
+    m = mix("chat-steady")
+    sched = chat_schedule(1949662630)
+    assert not any(44.7 < r["due"] < 57.8 for r in sched)
+    start, req = trafficgen.trace_start_s(sched, m, 30.0, 40.0, TRACE_S)
+    assert (req["index"], req["max_tokens"]) == (8, 203)
+    assert abs(req["due"] - 37.196) < 1e-3 and start == req["due"] - 0.5
+    assert overlap_s(start, req, 0.011) > 2.2
+
+
+@pytest.mark.parametrize("name", CLOSED)
+def test_a_closed_loop_traces_the_middle_as_before(name):
+    m = mix(name)
+    sched = trafficgen.serve_schedule({**m, "max_requests": 8}, 5, 0.0, 64)
+    for seconds in (40.0, 5.0, 37.3):
+        ramp = float(m["ramp_s"])
+        win = (ramp, ramp + seconds)
+        old = (win[0] + win[1]) / 2.0 - TRACE_S / 2.0   # kinds/serve.py:281
+        assert trafficgen.trace_start_s(sched, m, ramp, seconds,
+                                        TRACE_S) == (old, None)
+    assert len(CLOSED) >= 5
+
+
+def test_an_open_loop_with_nothing_due_traces_the_middle():
+    m = mix("chat-steady")
+    late = [{"index": 0, "due": 12.0, "max_tokens": 64},
+            {"index": 1, "due": 68.5, "max_tokens": 256}]
+    assert trafficgen.trace_start_s(late, m, 30.0, 40.0, TRACE_S) \
+        == (48.5, None)
+    assert trafficgen.trace_start_s([], m, 30.0, 40.0, TRACE_S) \
+        == (48.5, None)
+
+
+def test_the_second_interval_and_the_ties():
+    m = mix("chat-steady")
+    # nothing due in [32, 60]: the wider interval [30, 67] takes its longest;
+    # a request due before the lead fits starts the trace with the window
+    edge = [{"index": 0, "due": 30.2, "max_tokens": 200},
+            {"index": 1, "due": 66.0, "max_tokens": 100}]
+    assert trafficgen.trace_start_s(edge, m, 30.0, 40.0, TRACE_S) \
+        == (30.0, edge[0])
+    # equal lengths: the earliest
+    ties = [{"index": 0, "due": 40.0, "max_tokens": 90},
+            {"index": 1, "due": 45.0, "max_tokens": 256},
+            {"index": 2, "due": 50.0, "max_tokens": 256}]
+    assert trafficgen.trace_start_s(ties, m, 30.0, 40.0, TRACE_S) \
+        == (44.5, ties[1])

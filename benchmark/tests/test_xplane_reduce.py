@@ -102,3 +102,43 @@ def test_recorded_v5e_trace():
     assert [g[0] for g in s["gaps"][:2]] == ["probe_sleep", "probe_sleep"]
     assert all(0.0215 < g[1] < 0.0225 for g in s["gaps"][:2])
     assert s["annotations"]["probe_step"]["count"] == 3
+
+
+def test_a_gap_is_named_by_the_innermost_span_that_covers_it():
+    """The serving children pass the engine's and the gateway's span names
+    (``timeline.SPAN_NAMES``), which nest: ``step`` > ``launch``
+    > ``dispatch``. A gap wholly under all three is the leaf's; one that
+    runs across two leaves is the enclosing span's; one under none keeps
+    ``host, unattributed`` (an empty server between requests)."""
+    import timeline
+    names = timeline.SPAN_NAMES
+
+    def op(n, s, e):
+        return (f"%fusion.{n} = f32[1]{{0}} fusion(f32[1]{{0}} %a)", s, e)
+
+    ops = [op(1, 0.0, 1.0), op(2, 3.0, 4.0), op(3, 4.5, 5.0),
+           op(4, 10.0, 10.5)]
+    host = [("step", 0.5, 5.5), ("launch", 0.8, 4.2),
+            ("dispatch", 0.9, 3.2), ("device-wait", 3.9, 4.2),
+            ("host-accept", 4.2, 4.8), ("sleep", 0.0, 11.0)]
+    s = xr.summarize({"/device:TPU:0": {"ops": ops, "async": []}}, host,
+                     annotations=names)
+    assert s["gaps"] == [["host, unattributed", 5.0], ["dispatch", 2.0],
+                         ["step", 0.5]]
+    bare = xr.summarize({"/device:TPU:0": {"ops": ops, "async": []}}, host)
+    assert [g[1] for g in bare["gaps"]] == [g[1] for g in s["gaps"]]
+    assert {g[0] for g in bare["gaps"]} == {"host, unattributed"}
+
+
+def test_span_names_change_the_labels_of_the_recorded_trace_and_no_number():
+    pytest.importorskip("jax")
+    devices, host = xr.read_xplane(DATA)
+    bare = xr.summarize(devices, host)
+    named = xr.summarize(devices, host, ("probe_step", "probe_sleep"))
+    for key in ("window_s", "busy_s", "idle_share", "mosaic_s",
+                "mosaic_calls", "collective_s", "collective_exposed_s",
+                "top_ops", "devices"):
+        assert bare[key] == named[key], key
+    assert [g[1] for g in bare["gaps"]] == [g[1] for g in named["gaps"]]
+    assert {g[0] for g in bare["gaps"]} == {"host, unattributed"}
+    assert named["gaps"][0][0] == "probe_sleep"

@@ -36,6 +36,8 @@ LEAF_SPANS = ("admit", "plan", "dispatch", "device-wait", "host-accept",
 #: their enclosing spans: idle under these and under no leaf is the engine's
 #: own bookkeeping between phases
 OUTER_SPANS = ("step", "launch")
+#: every span a gap may lie under: what names a gap of ``breakdown.idle_gaps``
+SPAN_NAMES = LEAF_SPANS + OUTER_SPANS
 KERNEL_NAMES = ("ragged_paged_attention", "paged_decode_attention",
                 "decode_attention", "fused_decode_tick", "flash_fwd",
                 "flash_bwd_dkv", "flash_bwd_dq")
@@ -249,7 +251,7 @@ def lay_out(devices, host, extras):
     by_phase = dict.fromkeys(PHASES, 0.0)
     spans = {}
     for name, s, e in host:
-        if name in LEAF_SPANS or name in OUTER_SPANS:
+        if name in SPAN_NAMES:
             spans.setdefault(name, []).append((s, e))
     named = [iv for ivs in spans.values() for iv in ivs]
     under_any = 0.0

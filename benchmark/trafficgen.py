@@ -95,6 +95,40 @@ def serve_schedule(mix, seed, horizon_s, vocab_size):
     return reqs
 
 
+TRACE_LEAD_S = 0.5     # the trace starts this long before its request is due
+
+
+def trace_start_s(schedule, mix, ramp_s, seconds, trace_s):
+    """Where a traced run lays its ``trace_s`` seconds of device trace:
+    ``(start, request)``, the start in seconds from the generator's start.
+
+    A closed loop keeps every slot busy, so it traces the middle of the
+    window and ``request`` is None. An open loop below its knee stands empty
+    between arrivals, and the middle may hold no request at all; there the
+    trace is laid on a request of the schedule: of those due at least 2 s
+    into the window and at least 10 s before its end (``stop_trace`` takes
+    seconds, and should not run far past the window), the one with most
+    ``max_tokens``, the earliest of equals; the trace starts ``TRACE_LEAD_S``
+    before it is due, so that its admission and prefill are inside, and
+    never before the window does. With none due there, the same among those
+    due anywhere a whole trace still fits; with none at all, the middle.
+
+    Only ``due`` and ``max_tokens`` are read, nothing of how fast the system
+    is: a parent and a change trace the same request on the same seed."""
+    # the window's two ends halved, as the harness always wrote it: the
+    # closed-loop cells' traced seconds do not move by a rounding
+    middle = (ramp_s + (ramp_s + seconds)) / 2.0 - trace_s / 2.0
+    if mix["loop"] != "open":
+        return middle, None
+    for lo, hi in ((ramp_s + 2.0, ramp_s + seconds - 10.0),
+                   (ramp_s, ramp_s + seconds - trace_s)):
+        due = [r for r in schedule if lo <= r["due"] <= hi]
+        if due:
+            req = max(due, key=lambda r: (r["max_tokens"], -r["due"]))
+            return max(req["due"] - TRACE_LEAD_S, ramp_s), req
+    return middle, None
+
+
 def check_prompts(spec, seed, vocab_size):
     """The few prompts whose served tokens the reference judges."""
     rng = random.Random(f"check/{seed}")

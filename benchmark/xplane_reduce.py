@@ -166,12 +166,15 @@ def summarize(devices, host_spans=(), annotations=()):
     all_gaps.sort(reverse=True)
     named = []
     for length, s, e, _plane in all_gaps[:TOP_N]:
-        label, best = "host, unattributed", 0.0
+        # the span that covers most of the gap, and half of it or more; of
+        # nested spans that cover it alike (``step`` > ``launch`` >
+        # ``dispatch``), the innermost
+        label, best = "host, unattributed", (0.0, 0.0)
         for name, hs, he in host_spans:
             if name in annotations:
-                cover = min(e, he) - max(s, hs)
-                if cover > best:
-                    label, best = name, cover
+                rank = (min(e, he) - max(s, hs), hs - he)
+                if 2 * rank[0] >= length and rank > best:
+                    label, best = name, rank
         named.append([label, length])
     ann = {}
     for name, hs, he in host_spans:
