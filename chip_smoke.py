@@ -109,6 +109,14 @@ FULL = dict(
     ragged_window={"window 512 40/10/128": (40, 10, 128, 256, [
         (512, 3584)] + [(1, 1500 + 290 * i + (i * 37) % 29)
                         for i in range(15)], 512)},
+    # the same geometry's pipeline across pairs (6 pages = 192 keys a
+    # group): decode rows that walk one group and an odd number of groups
+    # (5, or 3 under the window) in turn, so every pair's first group is
+    # started by the pair before it, into the slot that pair is not
+    # computing on, and a read before it landed would be NaN or a miss
+    ragged_handover={"hand-over 40/10/128": (40, 10, 128, 256, [
+        (1, 40 + 9 * i) if i % 2 else (1, 900 + 4 * i)
+        for i in range(16)], 512)},
     train=dict(layers=2, batch=4, seq=2048, steps=4))
 REHEARSAL = dict(
     geometries=[(4, 4, 32), (4, 2, 32)], flash_seq=256,
@@ -134,6 +142,9 @@ REHEARSAL = dict(
     ssm=dict(widths=(256, 16), slots=6, packed=150),
     ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
         (48, 200), (1, 150), (1, 33), (0, 0)], 40)},
+    ragged_handover={"hand-over 4/2/32": (4, 2, 32, 24, [
+        (1, 40 + 9 * i) if i % 2 else (1, 600 + 10 * i)
+        for i in range(6)], 300)},
     train=dict(layers=2, batch=4, seq=64, steps=4))
 
 # Forward outputs: kernel and reference both take bf16 inputs (8 significant
@@ -655,6 +666,16 @@ def phase_kernels(rehearse):
         ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
             np.random.RandomState(window), rows, nh, nkv, hd, mb=mb),
             window=window)
+
+    # ---- ... and where every pair's first group of pool pages is fetched
+    # while the pair before it still computes, with and without the window:
+    # rows of one group and of an odd number of groups in turn ------------
+    for tag, (nh, nkv, hd, mb, rows, window) in \
+            size["ragged_handover"].items():
+        for w in (None, window):
+            ragged_agrees(f"ragged {tag} window {w}", _poisoned_ragged_case(
+                np.random.RandomState(len(rows)), rows, nh, nkv, hd, mb=mb),
+                window=w)
 
     for nh, nkv, hd in size["geometries"]:
         tag = f"{nh}/{nkv}/{hd}"

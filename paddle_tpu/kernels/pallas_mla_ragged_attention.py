@@ -26,7 +26,9 @@ together, the next group streaming in while this one computes) and runs one
 online-softmax update over ``pages * bs`` keys. A 32-token block alone is 40
 KB: one block an iteration is bound by the DMA's latency, not by its bytes
 (PERF.md, PR 25: 1.3 us a block). Entries past the pair's last block clamp
-to the table's last entry: a harmless read, masked off.
+to the table's last entry: a harmless read, masked off. This walk still
+drains at a pair's end (a pair's first group is fetched with nothing to
+overlap it; ``pallas_ragged_attention`` hands it over: ROADMAP S15 (e)).
 
 ``mla_ragged_attention_reference`` is the oracle in the EXPANDED form: it
 gathers the latent rows through the tables, up-projects them to per-head

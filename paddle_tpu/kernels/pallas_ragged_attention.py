@@ -63,6 +63,21 @@ are ``pallas_paged_decode.py``'s):
   block, at ``(layer, table entry)``, by ``make_async_copy`` into one
   two-slot ``[2, pages * bs, KD]`` buffer a side, all copies in flight
   together and the next group streaming in while this one computes.
+  **The pipeline does not drain at the end of a pair**: where two
+  work-list entries in a row are live (``_hands_over``), the pair's LAST
+  loop iteration starts the copies of the NEXT pair's first group (its row,
+  its first group under a window; all in SMEM already) into the slot it is
+  not computing on: K, V and a quantized pool's two scale planes, one set.
+  They land behind the last update, the divide, the masked write-back, the
+  grid step and the next pair's reset and query build, and that pair
+  rebuilds the same descriptors and only waits. So at most two groups are in
+  flight, as inside a pair: no buffer and no semaphore more. The slot of a
+  pair's first group is the number of groups every earlier entry walks, mod
+  2 (``_first_slots``, one more scalar-prefetched array), so whatever the
+  pair before walked, its last group lies in the other slot. Nothing is
+  handed across a dead entry (an untouched query block, the padded tail)
+  and the last live pair hands to nobody: every copy a call starts is
+  waited for inside it and none is in flight when it ends.
   Entries past the table clamp to its last and
   sentinel entries (``>= num_blocks``) into the layer's own blocks — a
   harmless read, masked off by ``kvlen`` and the causal rule; V rows past
@@ -130,9 +145,9 @@ from .pallas_flash import _interpret_mode
 NEG_INF = -1e30
 
 
-def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
-                   kl_ref, tbl_ref, layer_ref, *refs, scale, block_k, pages,
-                   tq, g, num_blocks, table_entries, quantized=False,
+def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
+                   ql_ref, kl_ref, tbl_ref, layer_ref, *refs, scale, block_k,
+                   pages, tq, g, num_blocks, table_entries, quantized=False,
                    window=None):
     # positional ref layout follows the pallas_call spec lists: inputs
     # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
@@ -157,9 +172,20 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
     qi = wq_ref[w]
     r = wr_ref[w]
     nkb = wn_ref[w]                 # pool blocks this pair walks (0 = dead)
-    # the first GROUP it walks: 0, or under a window the group that holds
-    # the first key any of the pair's queries may see (``_pair_first_block``)
-    glo = 0 if window is None else wlo_ref[w] // pages
+
+    def _first_group(e):
+        # the first GROUP entry e walks: 0, or under a window the group that
+        # holds the first key any of the pair's queries may see
+        # (``_pair_first_block``)
+        return 0 if window is None else wlo_ref[e] // pages
+
+    glo = _first_group(w)
+    # the entries before and after this one (clamped into the list), for
+    # the group that crosses the pair boundary (``_groups``)
+    before = jnp.maximum(w - 1, 0)
+    after = jnp.minimum(w + 1, pl.num_programs(0) - 1)
+    handed = (w > 0) & _hands_over(wn_ref[before], nkb)
+    hands = (after > w) & _hands_over(nkb, wn_ref[after])
     layer = layer_ref[0]            # which layer of the stored pool
     qstart = qs_ref[r]
     qlen = ql_ref[r]
@@ -184,8 +210,8 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
             # them are laid once a call
             qw_scr[:] = jnp.zeros(qw_scr.shape, jnp.float32)
 
-    def _copies(gi, slot):
-        # table-indirect fetch of group gi, `pages` consecutive table
+    def _copies(row, gi, slot):
+        # table-indirect fetch of group gi of `row`, `pages` consecutive table
         # entries, into buffer `slot`: the table is resolved from SMEM at
         # DMA-issue time; entries past the table clamp to its last, and
         # sentinel entries into THIS layer's blocks before the layer is
@@ -195,7 +221,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
         out = []
         for j in range(pages):
             entry = jnp.minimum(gi * pages + j, table_entries - 1)
-            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+            phys = jnp.clip(tbl_ref[row, entry], 0, num_blocks - 1)
             keys = pl.ds(j * block_k, block_k)
             for i, (hbm, buf) in enumerate(streams):
                 if hbm.ndim == 4:       # the stored pool [L, nb, bs, KD]
@@ -226,24 +252,38 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, qs_ref, ql_ref,
         return x.astype(jnp.float32) * f
 
     def _groups(update):
-        # this pair's groups of pool blocks, ascending, double-buffered:
-        # group gi + 1 streams in while ``update(gi, slot)`` computes on gi.
-        # Exactly the pair's blocks, `pages` an update: the row's own length
-        # and the causal diagonal both already bound nkb (_work_list); what
-        # the last group holds past them is masked
+        # this pair's groups of pool blocks, ascending, double-buffered: the
+        # group after gi streams in while ``update(gi, slot)`` computes on
+        # gi, and after the pair's LAST group that is the first group of the
+        # next work-list entry, where both entries are live (``_hands_over``):
+        # its copies overlap this pair's last update, divide and write-back,
+        # the grid step, and the next pair's reset and query build; that pair
+        # only waits for them. The first group's slot is the work list's
+        # (``_first_slots``), so the group before it, whoever's, lies in the
+        # other one. Exactly the pair's blocks, `pages` an update: the row's
+        # own length and the causal diagonal both already bound nkb
+        # (_work_list); what the last group holds past them is masked
         n_groups = (nkb + pages - 1) // pages
-        for c in _copies(glo, glo % 2):
-            c.start()
+        s0 = ws_ref[w]
+        r_after, glo_after = wr_ref[after], _first_group(after)
+
+        @pl.when(jnp.logical_not(handed))
+        def _first():
+            for c in _copies(r, glo, s0):
+                c.start()
 
         def _group(gi, carry):
-            slot = gi % 2
+            slot = (s0 + gi - glo) % 2
+            last = gi + 1 == n_groups
 
-            @pl.when(gi + 1 < n_groups)
+            @pl.when(jnp.logical_not(last) | hands)
             def _prefetch():
-                for c in _copies(gi + 1, 1 - slot):
+                for c in _copies(jnp.where(last, r_after, r),
+                                 jnp.where(last, glo_after, gi + 1),
+                                 1 - slot):
                     c.start()
 
-            for c in _copies(gi, slot):
+            for c in _copies(r, gi, slot):
                 c.wait()
 
             # pool rows past `kvlen` may hold another block's garbage (or a
@@ -506,6 +546,31 @@ def _pair_first_block(qs, ql, kl, qi, *, tokens_per_block, block_size,
     return lo // block_size // pages * pages
 
 
+def _hands_over(n_before, n):
+    """Whether a work-list entry's first group of pool pages is started by
+    the entry BEFORE it, from the KV blocks the two walk: both live (two
+    pairs in a row; nothing crosses a dead entry, so every copy a call
+    starts is waited for inside it). Ints, traced scalars and arrays alike:
+    the kernel, the work list's slots and the host's counter share this one
+    rule."""
+    return (n_before > 0) & (n > 0)
+
+
+def _first_slots(wn, wlo, pages):
+    """The buffer slot of each work-list entry's FIRST group, from the
+    list's own arrays: the groups every earlier entry walks, ``ceil(wn /
+    pages) - wlo // pages`` each (``wlo`` is whole groups), mod 2. The walk
+    alternates slots from there, so the last group of the pair before lies
+    in the other slot whatever its count, and the group that crosses the
+    pair boundary lands where the next pair looks for it. One more
+    scalar-prefetched array and not a word of SMEM the kernel keeps: a pure
+    function of the list, which a test can hold against an enumeration, and
+    ``_work_list`` itself stays what ``pallas_mla_ragged_attention``, whose
+    walk still drains at a pair's end, unpacks."""
+    walked = jnp.where(wn > 0, -(-wn // pages) - wlo // pages, 0)
+    return (jnp.cumsum(walked) - walked) % 2
+
+
 def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
                table_entries, window=None, pages=1):
     """The kernel's iteration space, from the step's span metadata (jnp,
@@ -587,6 +652,7 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
                       pages=pages)
     if window is None:      # one signature: a first block of 0, never read
         work += (jnp.zeros_like(work[-1]),)
+    work += (_first_slots(work[3], work[4], pages),)
     if scales is None:
         quantized = False
     else:
@@ -633,7 +699,7 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=10,
+            num_scalar_prefetch=11,
             grid=(nq + R,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((hkv, rows, D), _q_index),
@@ -704,14 +770,19 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
     token, where the kernel has that walk: ``one_token``, as the kernel's
     ``grid_params`` gives it); ``kv_tokens``, the cache rows the live spans
     attend
-    over; ``attn_pairs``, their causal (query, key) pairs. A row with
-    ``qlen == 0`` is dead. Under a ``window`` a pair's walk starts at its
-    first group (``_pair_first_block``) and ``kv_tokens`` / ``attn_pairs``
-    count the keys inside the window."""
+    over; ``attn_pairs``, their causal (query, key) pairs;
+    ``prefetched_pairs``, the (query block, row) pairs whose first group of
+    pool pages the pair before them in the work list started while it still
+    computed (``_hands_over``, the kernel's own rule, on the list's order:
+    by query block, then row, an untouched query block one dead entry). A
+    row with ``qlen == 0`` is dead. Under a ``window`` a pair's walk starts
+    at its first group (``_pair_first_block``) and ``kv_tokens`` /
+    ``attn_pairs`` count the keys inside the window."""
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
     tpb = bq // heads
     live = updates = alone = kv_tokens = pairs = 0
+    walks = [[] for _ in range(nq)]     # a query block's pairs, by row
     for qs, ql, kl in zip(qstart, qlen, kvlen):
         qs, ql, kl = int(qs), int(ql), int(kl)
         if ql <= 0:
@@ -728,14 +799,18 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
             n = _pair_kv_blocks(
                 qs, ql, kl, qi, tokens_per_block=tpb,
                 block_size=block_size, table_entries=int(table_entries))
+            walks[qi].append(n)
             n -= _pair_first_block(
                 qs, ql, kl, qi, tokens_per_block=tpb, block_size=block_size,
                 window=window, pages=int(pages)) * (n > 0)
             live += n
             updates += -(-n // int(pages))
+    entries = [n for ns in walks for n in (ns or [0])]
     return {"grid_steps": nq + len(qstart) + live, "live_steps": live,
             "update_steps": updates, "one_token_rows": alone,
-            "kv_tokens": kv_tokens, "attn_pairs": pairs}
+            "kv_tokens": kv_tokens, "attn_pairs": pairs,
+            "prefetched_pairs": int(sum(map(_hands_over, entries,
+                                                entries[1:])))}
 
 
 # Inference-only custom_vjp, same rationale as pallas_paged_decode: the

@@ -336,7 +336,7 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
     assert all(len(t) == 1 for t in tiling.values()), tiling
     assert all(pages > 1 for t in tiling.values() for _, pages in t)
     keys = ("grid_steps", "live_steps", "update_steps", "one_token_rows",
-            "kv_tokens", "attn_pairs")
+            "kv_tokens", "attn_pairs", "prefetched_pairs")
     for a, (qstart, qlen, kvlen, kw, _) in zip(disp, asked):
         assert a["packed_rows"] == kw["packed_tokens"]
         (block_q, pages), = tiling[a["packed_rows"]]
@@ -479,8 +479,14 @@ def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False, **geometry):
     update (block by block: a new update starts at a pair's first block and
     after every ``pages``). A row takes the one-token walk where its span is
     one token and the kernel has that walk: ``one_token``, as the kernel's
-    ``grid_params`` says."""
+    ``grid_params`` says. ``prefetched_pairs``: the list in its order (by
+    query block, then row; a query block no span touches is one dead entry),
+    a pair counted where it and the entry before it both walk something."""
     walked, nq = _live_pairs(qstart, qlen, kvlen, **geometry)
+    entries = []
+    for qi in range(nq):
+        entries += [n for (b, _), n in sorted(walked.items())
+                    if b == qi] or [0]
     live = sum(walked.values())
     updates = sum(ki % pages == 0 for n in walked.values()
                   for ki in range(n))
@@ -491,7 +497,9 @@ def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False, **geometry):
             "one_token_rows": sum(ql == 1 for ql in qlen)
             if one_token else 0,
             "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
-            "attn_pairs": pairs}
+            "attn_pairs": pairs,
+            "prefetched_pairs": sum(a > 0 and b > 0 for a, b
+                                    in zip(entries, entries[1:]))}
 
 
 GRID_CASES = {
