@@ -98,6 +98,16 @@ FULL = dict(
         (1, 2048 + 190 * i + (i * 37) % 29) for i in range(32)])),
     moe_share=dict(widths=(5120, 160, 20, 1536, 6, 8, 3),
                    rows=[(544, 32), (256, 256)]),
+    # GLM-5.2's sparse attention: (heads, latent rank, rope-free / rope /
+    # value head widths, index heads, index head width, rows selected), and
+    # its cell's two packed sizes over tables of 640 entries: 16 decode rows
+    # at 6 k - 19 k, and 15 of them beside a chunk that ends 16 k into its
+    # prompt (128 tokens of it: the oracle up-projects 2,048 rows a query)
+    dsa=dict(widths=(64, 512, 192, 64, 256, 32, 128, 2048), mb=640, rows={
+        "16 decode rows": [(1, 6144 + 800 * i + (i * 37) % 29)
+                           for i in range(16)],
+        "chunk at 16k": [(1, 6144 + 800 * i + (i * 37) % 29)
+                         for i in range(15)] + [(128, 16384)]}),
     # Olmo-Hybrid-7B's linear layers: (heads, key width, value width) of
     # the gated delta rule, the slots and the packed rows of its cell's step
     gdn=dict(widths=(30, 96, 192), slots=32, packed=544),
@@ -138,6 +148,9 @@ REHEARSAL = dict(
     mla=dict(widths=(4, 32, 16, 8, 16), decode_only=(8, [
         (1, 40 + 30 * i) for i in range(6)])),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
+    dsa=dict(widths=(4, 32, 16, 8, 16, 4, 16, 16), mb=8, rows={
+        "6 decode rows": [(1, 40 + 30 * i) for i in range(6)],
+        "chunk at 200": [(1, 150), (40, 200), (0, 0), (1, 1)]}),
     gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
     ssm=dict(widths=(256, 16), slots=6, packed=150),
     ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
@@ -821,6 +834,80 @@ def phase_kernels(rehearse):
                                  (40, 77), (0, 0)], mb=24, pad=12)
     mb, rows = size["mla"]["decode_only"]
     latent_agrees(f"mla_ragged {len(rows)} rows", rows, mb=mb, pad=0)
+
+    # ---- sparse attention over the latent pool: index scores, the
+    # selection and the attention over it against their oracles -----------
+    from paddle_tpu.kernels import dsa
+    nh, rank, nope, rope, vd, hi, hd_i, topk = size["dsa"]["widths"]
+    scale = (nope + rope) ** -0.5
+
+    def sparse_agrees(name, rows, mb, bs=32):
+        """``rows`` as ``latent_agrees``, over a latent pool and an
+        index-key pool that are NaN wherever no live row may read. The
+        selection is judged as a SET on the kernel's own scores; the
+        attention on that set, the expanded oracle a few queries at a
+        time."""
+        rng = np.random.RandomState(43 + len(rows))
+        qlen = np.array([q for q, _ in rows], np.int32)
+        kvlen = np.array([k for _, k in rows], np.int32)
+        tables, live, nb = _scattered_tables(rng, kvlen, mb, bs)
+        pool = rng.randn(1, nb, bs, latent_row_width(rank, rope)).astype(
+            np.float32)
+        pool[..., rank + rope:] = 0.0
+        ipool = rng.randn(1, nb, bs, hd_i).astype(np.float32)
+        pool[0][~live] = np.nan
+        ipool[0][~live] = np.nan
+        qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+        T = int(qlen.sum())
+        q_nope = jnp.asarray(rng.randn(T, nh, nope).astype(np.float32), bf16)
+        q_pe = jnp.asarray(rng.randn(T, nh, rope).astype(np.float32), bf16)
+        w_kvb = jnp.asarray(
+            rng.randn(rank, nh * (nope + vd)).astype(np.float32)
+            * rank ** -0.5, bf16)
+        q_i = jnp.asarray(rng.randn(T, hi, hd_i).astype(np.float32), bf16)
+        w_i = jnp.asarray(rng.randn(T, hi).astype(np.float32))
+        pool, ipool = jnp.asarray(pool, bf16), jnp.asarray(ipool, bf16)
+        span = tuple(jnp.asarray(x) for x in (tables, qstart, qlen, kvlen))
+        scores = jax.jit(dsa.dsa_index_scores_pallas)(q_i, w_i, ipool, *span)
+        want = reference(dsa.dsa_index_scores_reference, q_i, w_i, ipool,
+                         *span)
+        seen = np.asarray(want) > 0.5 * dsa.NEG_INF
+        check(((np.asarray(scores) > 0.5 * dsa.NEG_INF) == seen).all(),
+              f"{name}: the kernel scores other keys than the oracle")
+        _agree(f"dsa_index_scores {name}", np.where(seen, scores, 0.0),
+               np.where(seen, want, 0.0), TOL_FWD, errors)
+        mask = jax.jit(lambda s: dsa.dsa_select(s, topk))(scores)
+        check(bool((mask == jax.jit(lambda s: dsa.dsa_select_reference(
+            s, topk))(scores)).all()),
+            f"{name}: the selection is not top_k's set")
+        check((np.asarray(mask).sum(-1) == np.minimum(
+            seen.sum(-1), topk)).all(), f"{name}: a set of the wrong size")
+
+        def absorbed(q_nope, q_pe, w_kvb, pool, mask, *span):
+            w = w_kvb.reshape(rank, nh, nope + vd)
+            o_lat = dsa.dsa_attention_pallas(
+                jnp.einsum("thd,rhd->thr", q_nope, w[..., :nope]), q_pe,
+                pool, *span, dsa.selection_bias(
+                    mask, nh, table_entries=mb, block_size=bs), scale=scale)
+            return jnp.einsum("thr,rhd->thd", o_lat, w[..., nope:])
+
+        got = jax.jit(absorbed)(q_nope, q_pe, w_kvb, pool, mask, *span)
+        want = np.zeros(got.shape, np.float32)
+        one = jnp.zeros(1, jnp.int32)
+        for r, (at, n, end) in enumerate(zip(qstart, qlen, kvlen)):
+            for i in range(0, int(n), 4):       # four queries' rows a call
+                m = min(4, int(n) - i)
+                want[at + i:at + i + m] = reference(
+                    lambda *a: dsa.dsa_attention_reference(
+                        *a, scale=scale, k=topk),
+                    q_nope[at + i:at + i + m], q_pe[at + i:at + i + m],
+                    w_kvb, pool, span[0][r:r + 1], one, one + m,
+                    one + int(end) - int(n) + i + m,
+                    mask[at + i:at + i + m])
+        _agree(f"dsa_attention {name}", got, want, TOL_FWD, errors)
+
+    for name, rows in size["dsa"]["rows"].items():
+        sparse_agrees(name, rows, size["dsa"]["mb"])
 
     # ---- the gated delta rule: both kernels against the recurrence ------
     from paddle_tpu.kernels import gated_delta_rule as gdr

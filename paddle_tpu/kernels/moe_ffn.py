@@ -32,6 +32,13 @@ What a model may add to that, all static numbers of the one function:
   expert, and what the absent experts would add is left out (the partial sum
   an exchange between chips would complete; no code stands in for it). With
   every expert held (``E_held`` the router's width) this is the layer whole.
+- **a sigmoid router with a selection bias** (``router_bias``, a ``[E]``
+  vector; DeepSeek-V3's ``noaux_tc``): the scores are ``sigmoid(h W_r)``, not
+  a softmax; the ``top_k`` are the largest of ``score + bias`` (under the
+  group limit, where there is one), and a picked expert's weight is its
+  UNBIASED score. The bias decides near-ties only: it is in no weight. With
+  ``renormalize`` the weights are divided by their sum over all ``top_k``
+  picks, held here or not (the rule is over the router's width).
 - the **shared expert** is not here: it is a dense SwiGLU every row runs, which
   the layer body adds beside this call (``serving.decode._decoder_layer``,
   scope ``moe_shared``).
@@ -72,7 +79,7 @@ def group_limited(probs, n_group, topk_group):
 
 
 def _route(h2, router, top_k, live, renormalize, n_held, n_group=1,
-           topk_group=1, first_held=0, scale=1.0):
+           topk_group=1, first_held=0, scale=1.0, router_bias=None):
     """Float32 router: (weights [T, K] f32, experts [T, K] i32 by the
     router's ids (its width for a dead row), held ids [T, K] i32 (position in
     the held stack; ``n_held`` for a pick no held expert takes), counts
@@ -80,9 +87,16 @@ def _route(h2, router, top_k, live, renormalize, n_held, n_group=1,
     n_exp = router.shape[-1]
     logits = jnp.dot(h2.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = group_limited(jax.nn.softmax(logits, axis=-1), n_group,
-                          topk_group)
-    w, idx = jax.lax.top_k(probs, top_k)
+    if router_bias is None:
+        probs = group_limited(jax.nn.softmax(logits, axis=-1), n_group,
+                              topk_group)
+        w, idx = jax.lax.top_k(probs, top_k)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(group_limited(
+            probs + router_bias.astype(jnp.float32), n_group, topk_group),
+            top_k)
+        w = jnp.take_along_axis(probs, idx, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     if scale != 1.0:
@@ -113,7 +127,7 @@ def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
     """h [..., H]; router [H, E]; w_gate, w_up [E_held, H, I]; w_down
     [E_held, I, H]; live [...] bool (None: every row); ``routing``: the
     module docstring's ``n_group``, ``topk_group``, ``first_held``,
-    ``scale``. Returns (out [..., H], stats [4]).
+    ``scale``, ``router_bias``. Returns (out [..., H], stats [4]).
     Plain ``jnp``: each held expert runs over every row and is masked by the
     row's weight for it (zero where not picked or the row is dead)."""
     lead, h2, live = _prep(h, live)

@@ -62,10 +62,53 @@ def latent_row_width(rank, rope):
     return -(-(int(rank) + int(rope)) // 128) * 128
 
 
+def _copies(pool_hbm, buf, sems, tbl_ref, r, layer, gi, slot, *, pages,
+            block_k, num_blocks, table_entries):
+    """The DMAs of one group of ``pages`` table entries of row ``r``,
+    resolved from SMEM at issue time; entries past the table clamp to its
+    last, sentinels into the layer's own blocks (masked by the caller either
+    way)."""
+    out = []
+    for j in range(pages):
+        entry = jnp.minimum(gi * pages + j, table_entries - 1)
+        phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+        out.append(pltpu.make_async_copy(
+            pool_hbm.at[layer, phys],
+            buf.at[slot, pl.ds(j * block_k, block_k)],
+            sems.at[slot, j]))
+    return out
+
+
+def _walk_groups(n_groups, copies, body):
+    """Run ``body(gi, slot)`` over a pair's groups, group ``gi + 1``
+    streaming into the other slot while ``gi`` computes."""
+    for c in copies(0, 0):
+        c.start()
+
+    def _group(gi, carry):
+        slot = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _prefetch():
+            for c in copies(gi + 1, 1 - slot):
+                c.start()
+
+        for c in copies(gi, slot):
+            c.wait()
+        body(gi, slot)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, _group, 0)
+
+
 def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                tbl_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems, m_scr,
-                l_scr, acc_scr, *, scale, block_k, pages, tq, gh, num_blocks,
-                table_entries, rank):
+                tbl_ref, layer_ref, q_ref, *refs, selected, scale, block_k,
+                pages, tq, gh, num_blocks, table_entries, rank):
+    # with a selection (``kernels.dsa``) its tiles come before the pool: a
+    # key is then valid where the walk's own rule says so AND the query's
+    # tile holds 0 for it
+    b_ref = refs[0] if selected else None
+    pool_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr = refs[selected:]
     w = pl.program_id(0)            # one work-list entry: (query block, row)
     qi = wq_ref[w]
     r = wr_ref[w]
@@ -83,21 +126,11 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     def _zero_out():
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    def _copies(gi, slot):
-        # the group's table entries, resolved from SMEM at issue time;
-        # entries past the table clamp to its last, sentinels into the
-        # layer's own blocks (masked by kvlen either way)
-        out = []
-        for j in range(pages):
-            entry = jnp.minimum(gi * pages + j, table_entries - 1)
-            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
-            out.append(pltpu.make_async_copy(
-                pool_hbm.at[layer, phys],
-                buf.at[slot, pl.ds(j * block_k, block_k)],
-                sems.at[slot, j]))
-        return out
+    copies = functools.partial(
+        _copies, pool_hbm, buf, sems, tbl_ref, r, layer, pages=pages,
+        block_k=block_k, num_blocks=num_blocks, table_entries=table_entries)
 
-    def _walk(nr, load_q, valid_of, write):
+    def _walk(nr, load_q, valid_of, write, picked_of):
         # one pair's walk on ``nr`` wide rows (static), the softmax state in
         # the first ``nr`` rows of the scratch
         m_ref, l_ref, acc_ref = (r.at[pl.ds(0, nr)]
@@ -105,20 +138,8 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        n_groups = (nkb + pages - 1) // pages
-        for c in _copies(0, 0):
-            c.start()
 
-        def _group(gi, carry):
-            slot = gi % 2
-
-            @pl.when(gi + 1 < n_groups)
-            def _prefetch():
-                for c in _copies(gi + 1, 1 - slot):
-                    c.start()
-
-            for c in _copies(gi, slot):
-                c.wait()
+        def _update(gi, slot):
             q = load_q()                        # [nr, W]: q_lat | q_pe | 0
             k = buf[slot]                       # [group, W]: c_kv | k_pe | 0
             s = jax.lax.dot_general(
@@ -126,6 +147,8 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
                 preferred_element_type=jnp.float32) * scale
             valid = valid_of(gi * group + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1), s.shape)
+            if selected:
+                valid = valid & (picked_of(b_ref[0, gi]) >= 0.0)
             s = jnp.where(valid, s, NEG_INF)
             m_prev = m_ref[:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -147,9 +170,8 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-            return carry
 
-        jax.lax.fori_loop(0, n_groups, _group, 0)
+        _walk_groups((nkb + pages - 1) // pages, copies, _update)
         write(acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30))
 
     # a span of ONE token (a decode row) is ``gh`` wide rows at a multiple
@@ -164,14 +186,28 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
         def _one_token():
             off = pl.multiple_of(span_lo - row0, gh)
 
+            def picked_of(tile):
+                # the token's row of the selection's tile, for all its heads
+                mine = jax.lax.broadcasted_iota(
+                    jnp.int32, tile.shape, 0) == off // gh
+                row = jnp.sum(jnp.where(mine, tile, 0.0), axis=0,
+                              keepdims=True)
+                return jnp.broadcast_to(row, (gh, tile.shape[1]))
+
             def write(out):
                 o_ref[pl.ds(off, gh), :] = out.astype(o_ref.dtype)
 
             _walk(gh, lambda: q_ref[pl.ds(off, gh), :],
-                  lambda cols, shape: cols < kvlen, write)
+                  lambda cols, shape: cols < kvlen, write, picked_of)
 
     @pl.when((nkb > 0) & jnp.logical_not(alone))
     def _span():
+        def picked_of(tile):
+            # token i of the block on its gh wide rows
+            return jnp.concatenate(
+                [jnp.broadcast_to(tile[i:i + 1], (gh, tile.shape[1]))
+                 for i in range(tq // gh)], axis=0)
+
         def valid_of(cols, shape):
             # causal within the span: wide row w is span token (w - span_lo)
             # // gh, at logical position kvlen - qlen + that index
@@ -186,13 +222,14 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
             o_ref[:] = jnp.where((wrow >= span_lo) & (wrow < span_hi),
                                  out.astype(o_ref.dtype), o_ref[:])
 
-        _walk(tq, lambda: q_ref[:], valid_of, write)
+        _walk(tq, lambda: q_ref[:], valid_of, write, picked_of)
 
 
 def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
-              block_q, rank, pages, interpret):
+              block_q, rank, pages, interpret, selection=None):
     """q_wide ``[TH_pad, W]``; pool ``[L, num_blocks, bs, W]`` left in HBM;
-    returns ``[TH_pad, rank]``."""
+    ``selection`` ``[nq, n_grp, 8, group]`` tiles or None; returns ``[TH_pad,
+    rank]``."""
     TH, W = q_wide.shape
     num_blocks, bs = pool.shape[1], pool.shape[2]
     R, nk = tables.shape
@@ -200,20 +237,26 @@ def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
     work = _work_list(qstart, qlen, kvlen, nq=nq,
                       tokens_per_block=block_q // gh, block_size=bs,
                       table_entries=nk)
+    selected = selection is not None
     kernel = functools.partial(
-        _mla_kernel, scale=scale, block_k=bs, pages=pages, tq=block_q, gh=gh,
-        num_blocks=num_blocks, table_entries=nk, rank=rank)
+        _mla_kernel, selected=selected, scale=scale, block_k=bs, pages=pages,
+        tq=block_q, gh=gh, num_blocks=num_blocks, table_entries=nk, rank=rank)
 
     def _q_index(w, wq, *_):
         return (wq[w], 0)
 
+    def _tile_index(w, wq, *_):
+        return (wq[w], 0, 0, 0)
+
+    tiles = [pl.BlockSpec((1,) + selection.shape[1:], _tile_index)] \
+        if selected else []
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=9,
             grid=(nq + R,),
-            in_specs=[pl.BlockSpec((block_q, W), _q_index),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[pl.BlockSpec((block_q, W), _q_index)] + tiles
+            + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block_q, rank), _q_index),
             scratch_shapes=[
                 pltpu.VMEM((2, pages * bs, W), pool.dtype),
@@ -227,8 +270,9 @@ def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
         # consecutive entries revisit one output block: no reordering
         compiler_params=_cparams(("arbitrary",)),
         interpret=interpret,
-        name="mla_ragged_attention",
-    )(*work, qstart, qlen, kvlen, tables, layer, q_wide, pool)
+        name="dsa_attention" if selected else "mla_ragged_attention",
+    )(*work, qstart, qlen, kvlen, tables, layer, q_wide,
+      *([selection] if selected else []), pool)
 
 
 def _spans(tables, qstart, qlen, kvlen):
@@ -251,7 +295,7 @@ def grid_params(table_entries, heads, packed_tokens, block_q=BLOCK_Q,
 
 def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
                                 kvlen, *, scale, layer=0, block_q=BLOCK_Q,
-                                pages=PAGES):
+                                pages=PAGES, selection=None):
     """Absorbed-form attention of packed query spans over the latent pool.
 
     q_lat:  [T, H, rank]  — ``q_nope W_UK^T`` of every (token, head)
@@ -260,6 +304,9 @@ def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
     tables, qstart, qlen, kvlen: as ``ragged_paged_attention_pallas``
     scale:  the softmax scale (the model's: head width and YaRN's mscale)
     layer:  the layer of the pool to read (a traced index in a layer scan)
+    selection: None, or the queries' selected sets as ``kernels.dsa.
+            selection_bias`` lays them out: a key outside its query's set
+            scores ``-inf`` (the kernel is then named ``dsa_attention``)
     returns [T, H, rank]: ``softmax . c_kv``, to be multiplied by ``W_UV``;
     packed rows outside every span are exact zeros.
     """
@@ -277,7 +324,7 @@ def mla_ragged_attention_pallas(q_lat, q_pe, pool, tables, qstart, qlen,
         q_wide = jnp.pad(q_wide, ((0, th_pad - T * H), (0, 0)))
     out = _mla_call(q_wide, pool, jnp.asarray(layer, jnp.int32).reshape(1),
                     tables, qstart, qlen, kvlen, float(scale), H, bq, rank,
-                    tiling["pages"], _interpret_mode())
+                    tiling["pages"], _interpret_mode(), selection)
     return out[:T * H].reshape(T, H, rank)
 
 

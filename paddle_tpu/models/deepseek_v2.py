@@ -64,7 +64,7 @@ from ..core import random as _random
 from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from ..nn.layer import Parameter
-from .llama import _rms
+from .llama import _rms, build_once
 from .llama import generate as _llama_generate
 
 _YARN = dict(type="yarn", factor=40, original_max_position_embeddings=4096,
@@ -288,7 +288,6 @@ class DeepseekV2ForCausalLM(nn.Layer):
             return (std * jax.random.normal(key, shape, jnp.float32)
                     ).astype(dt)
 
-        @jax.jit
         def build(key):
             keys = jax.random.split(key, len(normal))
             out = {n: draw(k, s, 0.02)
@@ -296,7 +295,8 @@ class DeepseekV2ForCausalLM(nn.Layer):
             out.update({n: jnp.ones(s, dt) for n, s in ones.items()})
             return out
 
-        for name, value in build(_random.next_key()).items():
+        built = build_once(config, build)(_random.next_key())
+        for name, value in built.items():
             setattr(self, name, Parameter(value))
         if config.tie_word_embeddings:
             self.lm_head = None

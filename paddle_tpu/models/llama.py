@@ -148,6 +148,20 @@ def _rope_tables(seq_len, head_dim, theta):
     return jnp.sin(emb), jnp.cos(emb)
 
 
+_BUILDERS = {}
+
+
+def build_once(config, build):
+    """``jax.jit(build)`` of the first model with this configuration (its
+    ``repr``: every field, the dtype among them), for every later one: a
+    model's ``build(key)`` closes over shapes that follow from the
+    configuration alone, and a new jit an instance compiled the same
+    program again for every model a process made (2-5 s each at the test
+    presets' sizes)."""
+    return _BUILDERS.setdefault((type(config).__name__, repr(config)),
+                                jax.jit(build))
+
+
 def _apply_rope(x, sin, cos):
     # x: [B, S, H, D] neox-style
     d = x.shape[-1]

@@ -59,7 +59,7 @@ from ..core import random as _random
 from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from ..nn.layer import Parameter
-from .llama import _rms
+from .llama import _rms, build_once
 from .llama import generate as _llama_generate
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -212,7 +212,6 @@ class OlmoHybridForCausalLM(nn.Layer):
 
         # every parameter in its own dtype, in ONE jitted call (as
         # ``models.olmoe``): never float32 first
-        @jax.jit
         def build(key):
             def draw(key, shapes):
                 keys = jax.random.split(key, len(shapes))
@@ -237,7 +236,7 @@ class OlmoHybridForCausalLM(nn.Layer):
             out.update({n: jnp.ones(s, dt) for n, s in ones.items()})
             return out, tuple(linear(k) for k in k_lin)
 
-        full, linear = build(_random.next_key())
+        full, linear = build_once(config, build)(_random.next_key())
         for name, value in full.items():
             setattr(self, name, Parameter(value))
         for j, tree in enumerate(linear):

@@ -34,7 +34,7 @@ from ..core import random as _random
 from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from ..nn.layer import Parameter
-from .llama import _rms, _rope_tables
+from .llama import _rms, _rope_tables, build_once
 from .llama import generate as _llama_generate
 
 
@@ -104,7 +104,6 @@ class OlmoeForCausalLM(nn.Layer):
         # every parameter in its own dtype, in ONE jitted call: eager
         # float32 draws would put two 4 GiB temporaries beside a 2 GiB
         # expert matrix at the published widths
-        @jax.jit
         def build(key):
             keys = jax.random.split(key, len(normal))
             out = {n: (0.02 * jax.random.normal(k, s, jnp.float32)).astype(dt)
@@ -112,7 +111,8 @@ class OlmoeForCausalLM(nn.Layer):
             out.update({n: jnp.ones(s, dt) for n, s in ones.items()})
             return out
 
-        for name, value in build(_random.next_key()).items():
+        built = build_once(config, build)(_random.next_key())
+        for name, value in built.items():
             setattr(self, name, Parameter(value))
         if config.tie_word_embeddings:
             self.lm_head = None

@@ -73,6 +73,7 @@ from ..core import random as _random
 from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from ..nn.layer import Parameter
+from .llama import build_once
 from .llama import generate as _llama_generate
 
 
@@ -246,7 +247,6 @@ class Phi4FlashForCausalLM(nn.Layer):
 
         # every parameter in its own dtype, in ONE jitted call (as
         # ``models.olmoe``): never float32 first
-        @jax.jit
         def build(key):
             def one(key, kind, layers):
                 lead = () if isinstance(layers, int) else (len(layers),)
@@ -287,7 +287,7 @@ class Phi4FlashForCausalLM(nn.Layer):
             return embed, [one(k, kind, layers)
                            for k, (_, kind, layers) in zip(ks, stacks)]
 
-        embed, trees = build(_random.next_key())
+        embed, trees = build_once(config, build)(_random.next_key())
         self.embed_tokens = Parameter(embed)
         self.final_norm = Parameter(jnp.ones((c.hidden_size,), dt))
         self.final_norm_b = Parameter(jnp.zeros((c.hidden_size,), dt))

@@ -109,6 +109,11 @@ class BlockManager:
     on the K side; the V side has width 0, so every program signature,
     writer and lifecycle move carries it unchanged and for nothing. The
     kernel that reads it is ``kernels.pallas_mla_ragged_attention``.
+    Where that attention is over a learned selection (``kernels.dsa``) the V
+    side is the SECOND per-token cache, the index keys: ``v_dim`` their width
+    and ``v_layers`` the layers that have an indexer, ``[v_layers,
+    num_blocks, block_size, v_dim]``, addressed by the same block ids, so a
+    block is both rows in every lifecycle move.
 
     **The sentinel rule**: the block id ``num_blocks`` (one past the
     last block; ``PagedKVCache.sentinel``) marks an unmapped table
@@ -151,7 +156,7 @@ class BlockManager:
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.float32, kv_dtype=None, mesh=None,
-                 v_dim=None):
+                 v_dim=None, v_layers=None):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         if block_size < 1:
@@ -176,8 +181,13 @@ class BlockManager:
         store = (jnp.float8_e4m3fn if self.fp8
                  else jnp.int8 if self.quantized else dtype)
         self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape if v_dim is None
-                           else shape[:-1] + (int(v_dim),), store)
+        #: layers of the V side where it is a second per-token cache of its
+        #: own (the index keys), else None
+        self.v_layers = None if v_layers is None else int(v_layers)
+        self.v = jnp.zeros(
+            shape if v_dim is None
+            else (int(num_layers if v_layers is None else v_layers),)
+            + shape[1:-1] + (int(v_dim),), store)
         if self.fp8:
             # per-BLOCK planes, constant 1.0 (class docstring): never
             # rewritten by appends, only read by the kernels' post-dot

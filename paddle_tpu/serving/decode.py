@@ -64,6 +64,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..kernels.flash_attention import attention as _attention
 from ..kernels.gated_delta_rule import (gdn_chunk_scan, gdn_recurrent_update,
                                          gdn_reference, l2norm)
+from ..kernels.dsa import (dsa_attention_pallas, dsa_attention_reference,
+                           dsa_index_scores_pallas,
+                           dsa_index_scores_reference, dsa_select,
+                           selection_bias)
 from ..kernels.moe_ffn import moe_ffn
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
@@ -98,9 +102,21 @@ _MLA_STACK_KEYS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up",
 #: them chooses the layer body (``_decoder_layer``): ``q_norm`` / ``k_norm``
 #: normalise q and k before the rotary embedding, ``router`` makes the FFN a
 #: routed one over ``w_gate`` / ``w_up`` / ``w_down`` with a leading expert
-#: dim, ``ws_*`` is a shared expert every row runs beside the routed ones
+#: dim, ``ws_*`` is a shared expert every row runs beside the routed ones,
+#: ``router_bias`` makes the router a sigmoid one whose selection (not its
+#: weights) adds that bias (``kernels.moe_ffn``), ``idx_layer`` marks latent
+#: attention over a learned SELECTION of the cached rows (``kernels.dsa``):
+#: for a layer with an indexer its layer of the index-key pool, -1 for a
+#: layer that attends over the set the nearest such layer before it selected;
+#: ``idx_slot`` is then the indexer's place in ``_INDEXER_KEYS``' stacks
 _STACK_EXTRA_KEYS = ("q_norm", "k_norm", "router", "ws_gate", "ws_up",
-                     "ws_down")
+                     "ws_down", "router_bias", "idx_layer", "idx_slot")
+
+#: an indexer's weights, stacked over the layers of a tree that HAVE one
+#: (``[indexers, ...]``, not scanned: a layer reads its own at ``idx_slot``):
+#: the index query's up-projection from the query's latent, the index key's
+#: projection and LayerNorm, the per-token head weights
+_INDEXER_KEYS = ("idx_wq_b", "idx_wk", "idx_k_ln_w", "idx_k_ln_b", "idx_w")
 
 #: a hybrid model (``models.olmo_hybrid``): its layers come in PERIODS, some
 #: linear-attention (Gated DeltaNet) layers and then one full-attention layer.
@@ -177,6 +193,16 @@ def _layer_stacks(params):
             stacks.append((first,) + _layer_stack(tree))
             first += tree["input_ln"].shape[0]
     return stacks
+
+
+def _indexers(params):
+    """The indexers' weights of each stack of ``_layer_stacks``, in its
+    order: a dict by ``_INDEXER_KEYS``, or None for a stack none of whose
+    layers has one."""
+    return [{k: tree[k] for k in _INDEXER_KEYS} if "idx_wq_b" in tree
+            else None
+            for tree in (params.get("dense_layers"), params)
+            if tree is not None]
 
 
 #: the decode-path projection matmuls quantize_weights=True converts
@@ -402,7 +428,140 @@ def _mla_attention(hn, lw, *, nh, eps, rope, attend, mla):
         c_kv = _rms(kv[..., :mla.rank], lw["kv_a_ln"], eps)
         k_pe = rope(kv[..., None, mla.rank:])[:, :, 0]
         q_nope, q_pe = q[..., :mla.nope], rope(q[..., mla.nope:])
+    if "idx_layer" in lw:
+        # attention over a selection: the program's ``attend`` also takes
+        # what an indexer reads, the query's latent and the layer's input
+        return attend(q_nope, q_pe, c_kv, k_pe, lw["wkv_b"], c_q, hn)
     return attend(q_nope, q_pe, c_kv, k_pe, lw["wkv_b"])
+
+
+def _indexer_at(indexer, slot, keys=_INDEXER_KEYS):
+    """One indexer's weights out of the stacks, at a traced ``slot``."""
+    return {k: jax.lax.dynamic_index_in_dim(indexer[k], slot, 0,
+                                            keepdims=False) for k in keys}
+
+
+def _rope_head(x, rope, width):
+    """``x [..., heads, D]`` with its first ``width`` values rotated."""
+    return jnp.concatenate([rope(x[..., :width]), x[..., width:]], axis=-1)
+
+
+def index_key(hn, iw, *, dsa, rope):
+    """The index key a token caches in a layer with an indexer, ``[B, S,
+    D]``: ``LayerNorm(hn W_k)`` (weight and bias, float32 inside), its first
+    ``dsa.rope`` values rotated. Scope ``dsa_index_proj``."""
+    with jax.named_scope("dsa_index_proj"):
+        k = _layer_norm(jnp.einsum("bsh,hd->bsd", hn, iw["idx_wk"]),
+                        iw["idx_k_ln_w"], iw["idx_k_ln_b"], dsa.eps)
+        return _rope_head(k[:, :, None, :], rope, dsa.rope)[:, :, 0]
+
+
+def index_query(c_q, hn, iw, *, dsa, rope):
+    """An indexer's queries ``[B, S, heads, D]`` (from the query's latent
+    ``c_q``; the first ``dsa.rope`` values of each head rotated) and the
+    per-token head weights ``[B, S, heads]`` float32, ``heads^-0.5 D^-0.5``
+    folded in. Scope ``dsa_index_proj``."""
+    B, S = hn.shape[0], hn.shape[1]
+    with jax.named_scope("dsa_index_proj"):
+        q = jnp.einsum("bsr,rd->bsd", c_q, iw["idx_wq_b"]).reshape(
+            B, S, dsa.heads, dsa.dim)
+        w = jnp.einsum("bsh,hj->bsj", hn, iw["idx_w"]).astype(jnp.float32)
+        return _rope_head(q, rope, dsa.rope), \
+            w * (dsa.heads ** -0.5 * dsa.dim ** -0.5)
+
+
+def dsa_sequence_select(q_idx, k_idx, w_idx, topk, block=128):
+    """The selection of ONE whole sequence with no cache (whole-prompt
+    prefill, ``forward``): q_idx ``[S, heads, D]``, k_idx ``[S, D]``, w_idx
+    ``[S, heads]`` -> mask ``[S, S]``, row ``t`` the ``min(topk, t + 1)``
+    positions ``s <= t`` with the largest index score, a block of queries
+    at a time. Scopes ``dsa_index_score`` / ``dsa_select``."""
+    S = q_idx.shape[0]
+    blk = min(block, S)
+    pad = (-S) % blk
+    qp = jnp.pad(q_idx, ((0, pad), (0, 0), (0, 0)))
+    wp = jnp.pad(w_idx, ((0, pad), (0, 0)))
+    cols = jnp.arange(S, dtype=jnp.int32)
+
+    def one_block(start):
+        with jax.named_scope("dsa_index_score"):
+            qb = jax.lax.dynamic_slice_in_dim(qp, start, blk, 0)
+            wb = jax.lax.dynamic_slice_in_dim(wp, start, blk, 0)
+            s = jnp.einsum("qhd,sd->qhs", qb, k_idx,
+                           preferred_element_type=jnp.float32)
+            s = jnp.einsum("qh,qhs->qs", wb, jnp.maximum(s, 0.0),
+                           precision=jax.lax.Precision.HIGHEST)
+            s = jnp.where(cols[None, :] <= start + jnp.arange(blk)[:, None],
+                          s, NEG_INF)
+        with jax.named_scope("dsa_select"):
+            return dsa_select(s, topk)
+
+    mask = jax.lax.map(one_block, jnp.arange(0, S + pad, blk))
+    return mask.reshape(S + pad, S)[:S]
+
+
+def sequence_attend_selected(lw, indexer, sel, rope, *, mla, dsa):
+    """``_mla_attention``'s ``attend`` for whole sequences with no cache
+    (whole-prompt prefill, ``forward``), over a selection: a layer with an
+    indexer (``lw["idx_layer"] >= 0``; ``indexer`` its stack's weights, None
+    where the stack has none) scores and selects, every other attends over
+    ``sel [B, S, S]``, the set it was handed. The carry is what the caches
+    take of the layer and the set it used: ``(latent rows, index keys [B, S,
+    1, D] (width 0 without an indexer), sel')``."""
+    def attend(q_nope, q_pe, c_kv, k_pe, w_kvb, c_q, hn):
+        new_sel, key = sel, jnp.zeros(hn.shape[:2] + (0,), hn.dtype)
+        if indexer is not None:
+            iw = _indexer_at(indexer, lw["idx_slot"])
+            key = index_key(hn, iw, dsa=dsa, rope=rope)
+
+            def select(_):
+                q_i, w_i = index_query(c_q, hn, iw, dsa=dsa, rope=rope)
+                return jax.lax.map(
+                    lambda a: dsa_sequence_select(*a, dsa.topk),
+                    (q_i, key, w_i))
+
+            new_sel = jax.lax.cond(lw["idx_layer"] >= 0, select,
+                                   lambda _: sel, None)
+        with jax.named_scope("dsa_attend"):
+            attn = jax.lax.map(
+                lambda a: dsa_expanded_attention(*a[:4], w_kvb, a[4],
+                                                 mla=mla),
+                (q_nope, q_pe, c_kv, k_pe, new_sel))
+        return attn, (latent_rows(c_kv, k_pe), key[:, :, None, :], new_sel)
+
+    return attend
+
+
+def dsa_expanded_attention(q_nope, q_pe, c_kv, k_pe, w_kvb, mask, *, mla,
+                           block=128):
+    """Latent attention in the EXPANDED form of ONE whole sequence over a
+    selection ``mask [S, S]`` (``dsa_sequence_select``): as
+    ``mla_expanded_attention``, plain ``jnp`` a block of queries at a time
+    (the flash path has no mask). q_nope ``[S, nh, nope]``, q_pe ``[S, nh,
+    rope]``, c_kv ``[S, rank]``, k_pe ``[S, rope]``; returns ``[S, nh, v]``."""
+    S, nh, _ = q_nope.shape
+    kv = jnp.einsum("sr,rd->sd", c_kv, w_kvb).reshape(S, nh, -1)
+    k = jnp.concatenate(
+        [kv[..., :mla.nope],
+         jnp.broadcast_to(k_pe[:, None, :], (S, nh, mla.rope))], -1)
+    v = kv[..., mla.nope:]
+    blk = min(block, S)
+    pad = (-S) % blk
+    q = jnp.pad(jnp.concatenate([q_nope, q_pe], -1),
+                ((0, pad), (0, 0), (0, 0)))
+    mp = jnp.pad(mask, ((0, pad), (0, 0)))
+
+    def one_block(start):
+        qb = jax.lax.dynamic_slice_in_dim(q, start, blk, 0)
+        mb = jax.lax.dynamic_slice_in_dim(mp, start, blk, 0)[None]
+        logits = jnp.einsum("qhd,khd->hqk", qb, k,
+                            preferred_element_type=jnp.float32) * mla.scale
+        logits = jnp.where(mb, logits, NEG_INF)
+        probs = jnp.where(mb, jax.nn.softmax(logits, axis=-1), 0.0)
+        return jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v)
+
+    out = jax.lax.map(one_block, jnp.arange(0, S + pad, blk))
+    return out.reshape(S + pad, nh, -1)[:S]
 
 
 #: ``moe``'s entries past ``(top_k, renormalize)``, by ``moe_ffn``'s names
@@ -460,6 +619,8 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
         m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
                             top_k=moe[0], live=live, renormalize=moe[1],
                             return_picks=return_picks,
+                            **({"router_bias": lw["router_bias"]}
+                               if "router_bias" in lw else {}),
                             **dict(zip(_ROUTING_KEYS, moe[2:])))
         stats = tuple(stats) if return_picks else stats[0]
         if "ws_gate" in lw:
@@ -1448,7 +1609,8 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
 
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
-                  mla=None, return_picks=False, gdn=None, ssm=None):
+                  mla=None, return_picks=False, gdn=None, ssm=None,
+                  dsa=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -1466,7 +1628,11 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     attention (``wkv_a``; ``mla`` its static numbers) attends in the
     expanded form and returns as ``pk`` the rows its latent pool stores,
     ``[L, G, S_pad, 1, W]``, and a ``pv`` of width 0: no per-head K or V
-    leaves the layer. A hybrid model (``linear_layers``; ``gdn`` its linear
+    leaves the layer. Where that attention is over a learned selection
+    (``idx_layer``; ``dsa`` its static numbers, ``models.glm_moe_dsa.Dsa``)
+    ``pv`` is the index keys of the layers that have an indexer, ``[L_full,
+    G, S_pad, 1, D]``: the pool's second side. A hybrid model
+    (``linear_layers``; ``gdn`` its linear
     layers' static numbers) returns ``pk`` / ``pv`` of its FULL layers only
     and, last, what its linear layers' cache holds of each row
     (``_hybrid_prefill_layers``). A decoder-hybrid-decoder model
@@ -1507,19 +1673,39 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
             rows = latent_rows(c_kv, k_pe)
             return attn, (rows, rows[..., :0])
 
+    def rope(x):
+        return _apply_rope(x, sin, cos)
+
     x = jnp.take(params["embed"], ids, axis=0)
     kvs, stats = [], None
-    for _, names, stack, experts in _layer_stacks(params):
-        def prefill_layer(h, lp):
+    # (attention over a selection: the set rides the layers as carry, from
+    # a layer with an indexer to the ones that borrow it)
+    sel = None if dsa is None else jnp.zeros((B, S, S), bool)
+    for (_, names, stack, experts), indexer in zip(_layer_stacks(params),
+                                                   _indexers(params)):
+        def prefill_layer(carry, lp):
+            h, sel = carry
+            lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
+
             h, kv, st = _decoder_layer(
-                h, dict(zip(names, _dq_layer(lp, wdt, a8))), nh=nh, nkv=nkv,
-                hd=hd, eps=eps, rope=lambda x: _apply_rope(x, sin, cos),
-                attend=attend, live=live, moe=moe, experts=experts,
+                h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope,
+                attend=attend if dsa is None else sequence_attend_selected(
+                    lw, indexer, sel, rope, mla=mla, dsa=dsa),
+                live=live, moe=moe, experts=experts,
                 tp_reduce=tp_reduce, mla=mla,
                 return_picks=return_picks and experts is not None)
-            return h, (kv, st)
+            if dsa is not None:
+                kv, sel = kv[:2], kv[2]
+            return (h, sel), (kv, st)
 
-        x, (kv, st) = jax.lax.scan(prefill_layer, x, stack)
+        (x, sel), (kv, st) = jax.lax.scan(prefill_layer, (x, sel), stack)
+        if dsa is not None:
+            # the index keys of the layers that have an indexer, in order
+            full = () if indexer is None else jnp.nonzero(
+                stack[names.index("idx_layer")] >= 0,
+                size=indexer["idx_wk"].shape[0])[0]
+            kv = (kv[0], jnp.take(kv[1], jnp.asarray(full, jnp.int32),
+                                  axis=0))
         kvs.append(kv)
         stats = st if experts is not None else stats
     pk, pv = (jnp.concatenate(side) if len(kvs) > 1 else side[0]
@@ -1541,7 +1727,8 @@ def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
 
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
-                     mla=None, return_picks=False, gdn=None, ssm=None):
+                     mla=None, return_picks=False, gdn=None, ssm=None,
+                     dsa=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -1565,7 +1752,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
         _prefill_impl, nh=nh, nkv=nkv, hd=hd, eps=eps, theta=theta,
         tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks,
         **({} if gdn is None else {"gdn": gdn}),
-        **({} if ssm is None else {"ssm": ssm})))
+        **({} if ssm is None else {"ssm": ssm}),
+        **({} if dsa is None else {"dsa": dsa})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -1900,7 +2088,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
                          moe=None, mla=None, return_picks=False, state=None,
-                         gdn=None, ssm=None):
+                         gdn=None, ssm=None, dsa=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -1914,7 +2102,11 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     ``return_picks`` the pair ``(summary, picked experts [L, 1, T, top_k])``.
     A model with latent attention (``mla``) writes one row a token into the K side,
     the latent pool (its V side has width 0), and every span, decode row
-    and chunk alike, attends in the absorbed form. A hybrid model (``state``
+    and chunk alike, attends in the absorbed form. Where that attention is
+    over a learned selection (``dsa``), the V side is the index-key pool: a
+    layer with an indexer writes the step's index keys into it, scores each
+    query against its row's cached keys and selects, and every layer attends
+    over the set of the last such layer (``attend_selected`` below). A hybrid model (``state``
     its linear layers' store, ``gdn`` their static numbers) rotates nothing
     (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
     the store. A decoder-hybrid-decoder model (``ssm``) runs
@@ -1978,9 +2170,9 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
             qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
             eps=eps, gdn=gdn)
 
-    def scan_stack(carry, first, names, stack, experts):
+    def scan_stack(carry, first, names, stack, experts, indexer=None):
         def layer0(carry, lp):
-            h, pk, pv = carry
+            h, pk, pv = carry[:3]
             lw = dict(zip(names, _dq_layer(lp, wdt, a8)))
             layer = first + lw["layer"]     # this layer's place in the pool
             at = (layer, phys0, prow0)
@@ -2012,14 +2204,72 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                                       w[..., mla.nope:])
                 return attn[None], (npk, pv)
 
-            h, (pk, pv), stats = _decoder_layer(
-                h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps,
-                rope=lambda x: _apply_rope_grid(x, sin_p, cos_p),
-                attend=attend if mla is None else attend_latent,
+            def rope(x):
+                return _apply_rope_grid(x, sin_p, cos_p)
+
+            def attend_selected(q_nope, q_pe, c_kv, k_pe, w_kvb, c_q, hn):
+                # attention over a selection (``kernels.dsa``): the latent
+                # row as above; a layer with an indexer also writes the
+                # step's index keys (a layer without one drops the write),
+                # scores and selects under ``lax.cond``; every layer then
+                # attends over the set the carry holds
+                sel = carry[3]
+                npk = _kv_write(pk, at, latent_rows(c_kv, k_pe)[0])
+                npv, has = pv, lw["idx_layer"] >= 0
+                key_layer = jnp.maximum(lw["idx_layer"], 0)
+                if indexer is not None:
+                    small = _indexer_at(indexer, lw["idx_slot"],
+                                        _INDEXER_KEYS[1:])
+                    key = index_key(hn, small, dsa=dsa, rope=rope)
+                    npv = _kv_write(
+                        pv, (key_layer, jnp.where(has, phys0, nb), prow0),
+                        key[0][:, None, :])
+
+                    def select(_):
+                        iw = dict(small, idx_wq_b=_indexer_at(
+                            indexer, lw["idx_slot"], ("idx_wq_b",)
+                        )["idx_wq_b"])
+                        q_i, w_i = index_query(c_q, hn, iw, dsa=dsa,
+                                               rope=rope)
+                        with jax.named_scope("dsa_index_score"):
+                            scores = (
+                                dsa_index_scores_pallas
+                                if decode_attn == "pallas"
+                                else dsa_index_scores_reference)(
+                                q_i[0], w_i[0], npv, tables, qstart, qlen,
+                                kvlen, layer=key_layer)
+                        with jax.named_scope("dsa_select"):
+                            return _selection(dsa_select(scores, dsa.topk))
+
+                    sel = jax.lax.cond(has, select, lambda _: sel, None)
+                span = dict(scale=mla.scale, layer=layer)
+                if decode_attn != "pallas":
+                    with jax.named_scope("dsa_attend"):
+                        attn = dsa_attention_reference(
+                            q_nope[0], q_pe[0], w_kvb, npk, tables, qstart,
+                            qlen, kvlen, sel, k=dsa.topk, **span)
+                    return attn[None], (npk, npv, sel)
+                w = w_kvb.reshape(mla.rank, nh, mla.nope + mla.v)
+                with jax.named_scope("mla_proj"):
+                    q_lat = jnp.einsum("thd,rhd->thr", q_nope[0],
+                                       w[..., :mla.nope])
+                with jax.named_scope("dsa_attend"):
+                    o_lat = dsa_attention_pallas(
+                        q_lat, q_pe[0], npk, tables, qstart, qlen, kvlen,
+                        sel, **span)
+                with jax.named_scope("mla_proj"):
+                    attn = jnp.einsum("thr,rhd->thd", o_lat,
+                                      w[..., mla.nope:])
+                return attn[None], (npk, npv, sel)
+
+            h, kv, stats = _decoder_layer(
+                h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope,
+                attend=attend if mla is None else attend_latent
+                if dsa is None else attend_selected,
                 live=live_tok[None], moe=moe, experts=experts,
                 tp_reduce=tp_reduce, mla=mla,
                 return_picks=return_picks and experts is not None)
-            return (h, pk, pv), stats
+            return (h,) + kv, stats
 
         return jax.lax.scan(layer0, carry, stack)
 
@@ -2028,10 +2278,22 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     # scan slices a layer out of the pool or stacks one back into it
     x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
     carry, stats = (x, pool_k, pool_v), None
-    for first, names, stack, experts in _layer_stacks(params):
-        carry, st = scan_stack(carry, first, names, stack, experts)
+    if dsa is not None:
+        # the carry holds a selection in the form its reader takes: the
+        # oracle a mask, the kernel the walk's own tiles (walk and mask, for
+        # decode rows and chunks alike: gathering 16 rows' 2,048 selected
+        # rows took twice the masked walk's time on the v5e, PERF.md PR 43)
+        def _selection(mask):
+            if decode_attn != "pallas":
+                return mask
+            return selection_bias(mask, nh, table_entries=mb, block_size=bs)
+
+        carry += (_selection(jnp.zeros((T, s_tot), bool)),)
+    for (first, names, stack, experts), indexer in zip(
+            _layer_stacks(params), _indexers(params)):
+        carry, st = scan_stack(carry, first, names, stack, experts, indexer)
         stats = st if experts is not None else stats
-    return carry + (stats,)
+    return carry[:3] + (stats,)
 
 
 @jax.named_scope("ragged_step")
@@ -2041,7 +2303,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
                       moe=None, mla=None, return_picks=False, gdn=None,
-                      ssm=None):
+                      ssm=None, dsa=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -2136,7 +2398,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
         decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8, moe=moe,
-        mla=mla, return_picks=return_picks)
+        mla=mla, return_picks=return_picks, dsa=dsa)
     tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
                                       keys_in, temps, top_ks, eps)
 
@@ -2171,7 +2433,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
-                         return_picks=False, gdn=None, ssm=None):
+                         return_picks=False, gdn=None, ssm=None, dsa=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -2213,7 +2475,8 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             a8=a8, fused=fused, moe=moe, mla=mla,
             return_picks=return_picks,
             **({} if gdn is None else {"gdn": gdn}),
-            **({} if ssm is None else {"ssm": ssm})),
+            **({} if ssm is None else {"ssm": ssm}),
+            **({} if dsa is None else {"dsa": dsa})),
         # argument 18: the stores by slot of a model with recurrent or
         # window layers (absent otherwise)
         donate_argnums=((1, 2) + ((18,) if gdn is not None or ssm is not None

@@ -411,6 +411,10 @@ class ContinuousBatchingEngine:
         if "wkv_a" in self._params:
             geom = dict(num_kv_heads=1, v_dim=0, head_dim=latent_row_width(
                 c.kv_lora_rank, c.qk_rope_head_dim))
+        if "idx_layer" in self._params:
+            # attention over a learned selection: the V side is the second
+            # per-token cache, an index key a layer that has an indexer
+            geom.update(v_dim=c.dsa.dim, v_layers=c.dsa.layers)
         from .block_manager import BlockManager
         from .prefix_cache import PrefixCache
         self.prefix_cache = None
@@ -884,6 +888,22 @@ class ContinuousBatchingEngine:
                 qstart, qlen, packed,
                 window=self.config.sliding_window)["kv_tokens"]
             work["cross_rows"] = self.num_slots
+        if "idx_layer" in self._params:
+            # attention over a selection, summed over the step's layers:
+            # the queries an indexer scored and the keys it scored them
+            # against, the sets' sizes, and the pool rows the attention
+            # scored (a masked walk's whole diagonal; a gather would read
+            # the sets themselves)
+            d, layers = self.config.dsa, self.config.num_hidden_layers
+            spans = [(int(ql), int(kl)) for ql, kl in zip(qlen, kvlen)
+                     if ql > 0]
+            picked = sum(int(np.minimum(np.arange(kl - ql, kl) + 1,
+                                        d.topk).sum()) for ql, kl in spans)
+            work.update(
+                index_query_rows=d.layers * sum(ql for ql, _ in spans),
+                index_key_rows=d.layers * work["attn_pairs"],
+                selected_rows=layers * picked,
+                attended_rows=layers * work["attn_pairs"])
         if self._stateful:
             # what ONE linear layer call does: the rows whose state it
             # reads and writes (every live span's slot), and what it sends
@@ -907,6 +927,8 @@ class ContinuousBatchingEngine:
                 int(c.num_experts_per_tok), bool(c.norm_topk_prob))
         if "wkv_a" in self._params:
             consts["mla"] = c.mla
+        if "idx_layer" in self._params:
+            consts["dsa"] = c.dsa
         if "linear_layers" in self._params:
             consts["gdn"] = c.gdn
         elif self._stateful:

@@ -653,10 +653,23 @@ class PagedKVCache:
 
     def bytes_per_token(self) -> float:
         """Marginal HBM bytes one cached token costs (block data +
-        scale-plane bytes / block_size). Pure constants — no occupancy
-        scan — so the scrape-time gauge is free."""
+        scale-plane bytes / block_size), the index keys of a pool that has
+        them apart (:attr:`index_bytes_per_token`). Pure constants — no
+        occupancy scan — so the scrape-time gauge is free."""
         return (self.pool.block_nbytes
-                + self.pool.scale_block_nbytes) / self.block_size
+                + self.pool.scale_block_nbytes) / self.block_size \
+            - self.index_bytes_per_token
+
+    @property
+    def index_bytes_per_token(self) -> int:
+        """HBM bytes one cached token's INDEX KEYS hold over the layers that
+        have an indexer (the V side of a latent pool whose attention is over
+        a learned selection; 0 for every other pool): the
+        ``serving_index_bytes_per_token`` gauge."""
+        v = self.pool.v
+        if self.pool.v_layers is None:
+            return 0
+        return v.shape[0] * v.shape[-1] * np.dtype(v.dtype).itemsize
 
     def occupancy_bytes(self) -> dict:
         """Pool occupancy in BYTES, split by storage kind — the

@@ -752,6 +752,14 @@ class ServingGateway:
                 "denominator of the quantized-density win."
                 ).set_fn(
             lambda: self.engine.cache.bytes_per_token())
+        if self.engine.cache.index_bytes_per_token:
+            r.gauge("serving_index_bytes_per_token",
+                    "HBM bytes one cached token's index keys hold over "
+                    "the layers that have an indexer (sparse attention's "
+                    "second per-token cache, beside "
+                    "serving_kv_bytes_per_token's latent rows)."
+                    ).set_fn(
+                lambda: self.engine.cache.index_bytes_per_token)
         if getattr(self.engine, "prefix_cache", None) is not None:
             # scrape-time counters backed by the cache's own stats plus
             # the gateway's carried base (the driver thread is the only

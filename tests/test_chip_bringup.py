@@ -22,8 +22,9 @@ import pytest
 from jax.sharding import (NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
-from paddle_tpu.kernels import (flash_attention, gated_delta_rule, moe_ffn,
-                                pallas_flash, pallas_mla_ragged_attention,
+from paddle_tpu.kernels import (dsa, flash_attention, gated_delta_rule,
+                                moe_ffn, pallas_flash,
+                                pallas_mla_ragged_attention,
                                 pallas_paged_decode, pallas_ragged_attention,
                                 selective_scan)
 from paddle_tpu.parallel import mesh as mesh_mod
@@ -57,7 +58,7 @@ def v5e_devices(monkeypatch):
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
     for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
                 pallas_mla_ragged_attention, moe_ffn, gated_delta_rule,
-                selective_scan):
+                selective_scan, dsa):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -221,6 +222,46 @@ class TestMosaicCompilesDeepseekV2:
             v5e((7, held, H, width)), v5e((7, held, width, H)),
             v5e((rows,), jnp.bool_), v5e((), jnp.int32))
         assert n == 3                    # gate, up, down over the held stack
+
+
+class TestMosaicCompilesGlmSparseAttention:
+    """GLM-5.2's sparse attention at its published widths (an indexer of 32
+    heads of 128 over index keys of 128 lanes; 64 heads over a latent of 512
+    + 64 stored in rows of 640 lanes; 2,048 selected) and at the serving
+    cell's two packed sizes: 16 decode rows, and those beside one 512-token
+    chunk, 16 slots x 20,480 tokens."""
+    NH, RANK, ROPE, W, HI, D, TOPK = 64, 512, 64, 640, 32, 128, 2048
+    L, LF, R, MB, BS = 6, 2, 16, 640, 32
+
+    def _span(self, v5e):
+        i32 = jnp.int32
+        return (v5e((self.R, self.MB), i32), v5e((self.R,), i32),
+                v5e((self.R,), i32), v5e((self.R,), i32), v5e((), i32))
+
+    @pytest.mark.parametrize("tokens", [16, 528])
+    def test_index_scores_selection_and_attention(self, v5e, tokens):
+        def sparse(q_i, w_i, ipool, q_lat, q_pe, pool, tables, qs, ql, kl,
+                   layer):
+            scores = dsa.dsa_index_scores_pallas(
+                q_i, w_i, ipool, tables, qs, ql, kl, layer=layer)
+            mask = dsa.dsa_select(scores, self.TOPK)
+            return dsa.dsa_attention_pallas(
+                q_lat, q_pe, pool, tables, qs, ql, kl, dsa.selection_bias(
+                    mask, self.NH, table_entries=self.MB,
+                    block_size=self.BS), scale=0.1, layer=layer)
+        nb = self.R * self.MB
+        args = (v5e((tokens, self.HI, self.D)),
+                v5e((tokens, self.HI), jnp.float32),
+                v5e((self.LF, nb, self.BS, self.D)),
+                v5e((tokens, self.NH, self.RANK)),
+                v5e((tokens, self.NH, self.ROPE)),
+                v5e((self.L, nb, self.BS, self.W))) + self._span(v5e)
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(sparse).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        # the two pools (2.5 GiB) are read where they lie; a chunk's scores
+        # and selection are 40 MiB each
+        assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2 ** 20
 
 
 class TestMosaicCompilesOlmoHybrid:

@@ -3,6 +3,7 @@ expanded form of whole-prompt prefill, the kernel's expanded-form oracle and
 the plain reference agree on one layer; the Pallas kernel (interpret mode)
 equals its oracle over a paged latent pool with mixed spans; the pool stores
 a token's 576 values once (ISSUE 31)."""
+import functools
 import os
 import sys
 
@@ -57,6 +58,12 @@ def _absorbed(q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl, **kw):
     return jnp.einsum("thr,rhd->thd", o_lat, w[..., NOPE:])
 
 
+def _jitted(fn, *args, **static):
+    """``fn`` as one compiled program: called eagerly, every ``jnp`` op
+    around the kernel (the work list alone is dozens) compiles on its own."""
+    return jax.jit(functools.partial(fn, **static))(*args)
+
+
 def test_absorbed_expanded_oracle_and_reference_agree():
     """One sequence, every position a query: four computations of the same
     attention."""
@@ -67,9 +74,11 @@ def test_absorbed_expanded_oracle_and_reference_agree():
     q_nope, q_pe = _rand(rng, n, H, NOPE), _rand(rng, n, H, ROPE)
     w_kvb = _rand(rng, RANK, H * (NOPE + V)) * 0.3
     span = (tables, [0], [n], [n])
-    absorbed = _absorbed(q_nope, q_pe, w_kvb, pool, *span, pages=2)
-    oracle = mla_ragged_attention_reference(
-        q_nope, q_pe, w_kvb, pool, *span, scale=MLA.scale, layer=1)
+    span = tuple(np.asarray(x, np.int32) for x in span)
+    absorbed = _jitted(_absorbed, q_nope, q_pe, w_kvb, pool, *span, pages=2)
+    oracle = _jitted(mla_ragged_attention_reference,
+                     q_nope, q_pe, w_kvb, pool, *span, scale=MLA.scale,
+                     layer=1)
     expanded = mla_expanded_attention(q_nope[None], q_pe[None], c_kv[None],
                                       k_pe[None], w_kvb, mla=MLA)[0]
     kv = (c_kv @ w_kvb).reshape(n, H, NOPE + V)
@@ -117,10 +126,11 @@ def test_kernel_equals_oracle_over_paged_pool(case, heads, monkeypatch):
     qs, ql, kl = (np.asarray(x, np.int32) for x in zip(*rows))
     q_nope, q_pe = _rand(rng, packed, H, NOPE), _rand(rng, packed, H, ROPE)
     w_kvb = _rand(rng, RANK, H * (NOPE + V)) * 0.3
-    got = _absorbed(q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl, **opts)
-    want = mla_ragged_attention_reference(
-        q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl, scale=MLA.scale,
-        layer=1)
+    got = _jitted(_absorbed, q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl,
+                  **opts)
+    want = _jitted(mla_ragged_attention_reference,
+                   q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl,
+                   scale=MLA.scale, layer=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=1e-4)
     live = np.zeros(packed, bool)

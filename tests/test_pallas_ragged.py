@@ -25,20 +25,48 @@ the unification itself must pin:
   a causal cut that differs per query block, holes in the packed
   buffer, an int8 pool) match the oracle.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.kernels.pallas_paged_decode import (
-    paged_decode_attention_pallas, paged_decode_attention_reference)
-from paddle_tpu.kernels.pallas_ragged_attention import (
-    _query_block, _work_list, ragged_attention_reference,
-    ragged_paged_attention_pallas)
+from paddle_tpu.kernels import pallas_paged_decode, pallas_ragged_attention
+from paddle_tpu.kernels.pallas_ragged_attention import (_query_block,
+                                                        _work_list)
 from paddle_tpu.serving.kv_cache import (quantize_kv_rows,
                                          quantize_kv_rows_fp8)
 
 from test_one_timeline import GRID_CASES, _live_pairs
+
+
+def _compiled_once(fn, static=("block_q", "pages", "window")):
+    """``fn`` as ONE jitted program a set of static keywords (and, by
+    ``jax.jit``, a set of shapes): called eagerly, every ``jnp`` op around
+    the kernel (the work list alone is dozens) is a program of its own to
+    compile, which was most of this file's clock (ISSUE 43)."""
+    @functools.lru_cache(maxsize=None)
+    def program(static_kw):
+        return jax.jit(functools.partial(fn, **dict(static_kw)))
+
+    def call(*args, **kw):
+        fixed = tuple(sorted((k, v) for k, v in kw.items()
+                             if k in static and v is not None))
+        return program(fixed)(*args, **{
+            k: v for k, v in kw.items() if k not in static})
+
+    return call
+
+
+ragged_paged_attention_pallas = _compiled_once(
+    pallas_ragged_attention.ragged_paged_attention_pallas)
+ragged_attention_reference = _compiled_once(
+    pallas_ragged_attention.ragged_attention_reference)
+paged_decode_attention_pallas = _compiled_once(
+    pallas_paged_decode.paged_decode_attention_pallas)
+paged_decode_attention_reference = _compiled_once(
+    pallas_paged_decode.paged_decode_attention_reference)
 
 
 def _mk(R, spans, H, Hkv, D, mb, bs, seed=0, dtype=jnp.float32, T=None):
