@@ -10,11 +10,30 @@ the selection of the layer before it:
   ``s <= t``, ``I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])`` in float32,
   no softmax. The keys are a SECOND paged cache, ``[L_full, num_blocks, bs,
   D]``, one ``D``-wide key a token for each layer with an indexer, addressed
-  by the latent pool's block tables. The kernel is
-  ``pallas_mla_ragged_attention``'s walk (the same work list of (query block,
-  row) pairs, ``pages`` table entries a DMA group, two slots) on wide rows of
-  one (token, index head) each; the heads' weighted sum is one small matmul
-  against a matrix that holds a token's weights on its own rows.
+  by the latent pool's block tables. The kernel walks
+  ``pallas_mla_ragged_attention``'s work list of (query block, row) pairs,
+  ``pages`` table entries a DMA group, on wide rows of one (token, index
+  head) each. Its pipeline is its own (``_walk_ahead``: ``SLOTS`` groups in
+  flight, one wait a group, a loop without a branch that computes before it
+  starts the next copies): a block of index keys is 8 KB where a latent
+  block is 40 KB, so this walk is bound by the issue of its copies and not
+  by their bytes, and under the latent kernel's two-slot walk a decode row's
+  compute and the next group's address arithmetic ran in turn (PERF.md,
+  PR 44).
+
+  A span of several tokens (a chunk, a verify span) scores the query
+  block's ``tq`` wide rows against a group and sums the heads by one small
+  matmul against a matrix that holds a token's weights on its own rows. A
+  span of ONE token (a decode row) owns ``heads`` of those rows and one
+  output row: where ``heads`` are whole row tiles (``_one_token_walk``, the
+  latent kernel's rule; 16 or 32 heads, not 4) it scores its own rows alone
+  and sums its heads on the VPU, the weights along sublanes. The block-wide
+  sum is a ``Precision.HIGHEST`` matmul whose ``[tq, group]`` float32
+  operand changes every group: for a decode row 7/8 of it and of the scores
+  belong to other rows' tokens and are masked at the store (PERF.md,
+  PR 44). The kernel chooses by what it sees, ``qlen[r] == 1``;
+  ``index_grid_params`` is the tiling the call and the engine's
+  ``index_one_token_rows`` share.
 - **the selection** (``dsa_select``): the ``k`` largest ``I[t, :]`` a query as
   a SET, by a search for the k-th value (32 counting passes over the scores'
   bit patterns; ``jax.lax.top_k`` at k = 2048 over 20k is a sort on the TPU),
@@ -48,15 +67,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import _cparams, _interpret_mode
-from .pallas_mla_ragged_attention import (PAGES, _copies, _spans,
-                                          _walk_groups,
+from .pallas_mla_ragged_attention import (PAGES, _spans,
                                           mla_ragged_attention_pallas)
 from .pallas_mla_ragged_attention import grid_params as _mla_grid_params
-from .pallas_ragged_attention import NEG_INF, _query_block, _work_list
+from .pallas_ragged_attention import (NEG_INF, _one_token_walk, _query_block,
+                                      _work_list)
 
 BLOCK_Q = 256
 #: rows of a selection tile: a query block's tokens, padded to whole sublanes
 TILE_ROWS = 8
+#: lanes of a vreg: the one-token path's weights, a token a lane
+_LANES = 128
+#: slots of the index kernel's walk: a pool block of index keys is 8 KB
+#: where the latent pool's is 40 KB, so a group's 16 DMAs are bound by their
+#: issue and not by their bytes (PERF.md, PR 44: 16 decode rows at 12.9k on
+#: the v5e, this walk written out in a scratch copy: 0.159 ms at 4 slots,
+#: 0.152 at 8, 0.154 at 16; 0.228 under the latent walk's two)
+SLOTS = 8
 
 
 def _token_meta(T, qstart, qlen, kvlen):
@@ -102,10 +129,63 @@ def dsa_index_scores_reference(q_idx, w_idx, idx_pool, tables, qstart, qlen,
     return jnp.where(valid, out, NEG_INF)
 
 
+def index_grid_params(heads, packed_tokens, block_q=BLOCK_Q):
+    """The tiling of one ``dsa_index_scores`` call, ``{"block_q",
+    "one_token"}``: the query block in (token, index head) wide rows and
+    whether a span of one token computes on its own ``heads`` rows
+    (``_one_token_walk``, the latent kernel's rule). The ONE derivation: the
+    call tiles with it and the engine counts ``index_one_token_rows`` with
+    it."""
+    block_q = _query_block(block_q, heads, packed_tokens)
+    return {"block_q": block_q,
+            "one_token": _one_token_walk(heads, block_q)}
+
+
+def _walk_ahead(n_groups, start, wait, body, slots):
+    """Run ``body(gi, slot)`` over a pair's groups, ``slots - 1`` groups in
+    flight ahead of the one that computes (the pair's first ``slots - 1``
+    started already: ``_start_ahead``). While a group that far ahead exists
+    the loop's body has no branch and computes BEFORE it starts that group:
+    the next copies' addresses (table lookups, scalar work) then pack under
+    the vector work, where a conditional start ahead of the wait
+    (``pallas_mla_ragged_attention._walk_groups``) runs them in turn. The
+    pair's last ``slots - 1`` groups only wait and compute."""
+    ahead = slots - 1
+
+    def _tail(gi, carry):
+        wait(gi % slots)
+        body(gi, gi % slots)
+        return carry
+
+    def _steady(gi, carry):
+        _tail(gi, carry)
+        start(gi + ahead, (gi + ahead) % slots)
+        return carry
+
+    steady = jnp.maximum(n_groups - ahead, 0)
+    jax.lax.fori_loop(0, steady, _steady, 0)
+    jax.lax.fori_loop(steady, n_groups, _tail, 0)
+
+
+def _start_ahead(n_groups, start, slots):
+    """Start a pair's first ``slots - 1`` groups, each into the slot of its
+    own number (a loop, not ``slots - 1`` copies of the group's starts: the
+    step programs trace and lower this kernel a dozen times, and set-up
+    pays for every copy's descriptor)."""
+    def _first(g, carry):
+        start(g, g)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(slots - 1, n_groups), _first, 0)
+
+
 def _index_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
-                  tbl_ref, layer_ref, q_ref, e_ref, pool_hbm, o_ref, buf,
-                  sems, *, block_k, pages, tq, gh, num_blocks,
-                  table_entries):
+                  tbl_ref, layer_ref, q_ref, e_ref, *refs, one_token,
+                  block_k, pages, tq, gh, num_blocks, table_entries):
+    # with the one-token path the heads' weights come a second time, the
+    # heads along sublanes (``wt_ref``), before the pool
+    wt_ref = refs[0] if one_token else None
+    pool_hbm, o_ref, buf, sems = refs[one_token:]
     w = pl.program_id(0)
     qi = wq_ref[w]
     r = wr_ref[w]
@@ -114,17 +194,71 @@ def _index_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     qstart, qlen, kvlen = qs_ref[r], ql_ref[r], kl_ref[r]
     tpb = tq // gh
     group = pages * block_k
+    n_groups = (nkb + pages - 1) // pages
 
     @pl.when(wf_ref[w] == 1)
     def _blank():
         o_ref[:] = jnp.full_like(o_ref, NEG_INF)
 
-    copies = functools.partial(
-        _copies, pool_hbm, buf, sems, tbl_ref, r, layer, pages=pages,
-        block_k=block_k, num_blocks=num_blocks, table_entries=table_entries)
+    def start(gi, slot):
+        # one group of ``pages`` table entries of row ``r``, resolved from
+        # SMEM at issue time, every copy on the slot's ONE semaphore;
+        # entries past the table clamp to its last, sentinels into the
+        # layer's own blocks (masked below either way)
+        for j in range(pages):
+            entry = jnp.minimum(gi * pages + j, table_entries - 1)
+            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+            pltpu.make_async_copy(
+                pool_hbm.at[layer, phys],
+                buf.at[slot, pl.ds(j * block_k, block_k)],
+                sems.at[slot]).start()
 
-    @pl.when(nkb > 0)
-    def _pair():
+    def wait(slot):
+        # a DMA semaphore counts bytes: one wait for the slot's whole
+        # buffer takes the group's ``pages`` copies together
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sems.at[slot]).wait()
+
+    slots = buf.shape[0]
+    _start_ahead(n_groups, start, slots)        # (none for a dead pair)
+    walk = functools.partial(_walk_ahead, n_groups, start, wait, slots=slots)
+
+    # a span of ONE token (a decode row) owns ``gh`` of the block's wide rows
+    # and one of its output rows: it scores those rows alone, and sums its
+    # heads on the VPU (the weights along sublanes, a sublane reduce). The
+    # block-wide sum below is a ``HIGHEST`` matmul over ``[tq, group]`` that
+    # changes every group, 7/8 of it for other rows' tokens
+    alone = (qlen == 1) if one_token else False
+
+    if one_token:
+        @pl.when((nkb > 0) & alone)
+        def _one_token():
+            tok = qstart - qi * tpb             # the token's output row
+            off = pl.multiple_of(tok * gh, gh)
+            wt = wt_ref[0]                      # [gh, lanes]: lane = token
+            w_col = jnp.sum(
+                jnp.where(jax.lax.broadcasted_iota(jnp.int32, wt.shape, 1)
+                          == tok, wt, 0.0), axis=1, keepdims=True)
+
+            def body(gi, slot):
+                s = jax.lax.dot_general(
+                    q_ref[pl.ds(off, gh), :], buf[slot],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)     # [gh, group]
+                red = jnp.sum(jnp.maximum(s, 0.0) * w_col, axis=0,
+                              keepdims=True)                # [1, group]
+                tile = o_ref[0, gi]
+                rows = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+                cols = gi * group + jax.lax.broadcasted_iota(
+                    jnp.int32, tile.shape, 1)
+                o_ref[0, gi] = jnp.where(
+                    rows == tok,
+                    jnp.where(cols < kvlen, red, NEG_INF), tile)
+
+            walk(body)
+
+    @pl.when((nkb > 0) & jnp.logical_not(alone))
+    def _span():
         def body(gi, slot):
             s = jax.lax.dot_general(
                 q_ref[:], buf[slot], (((1,), (1,)), ((), ())),
@@ -144,7 +278,7 @@ def _index_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
             o_ref[0, gi] = jnp.where(
                 mine, jnp.where(seen, red, NEG_INF), o_ref[0, gi])
 
-        _walk_groups((nkb + pages - 1) // pages, copies, body)
+        walk(body)
 
 
 def _tiles_to_rows(tiles, tpb, T, s_tot):
@@ -178,24 +312,28 @@ def dsa_index_scores_pallas(q_idx, w_idx, idx_pool, tables, qstart, qlen,
     num_blocks, bs = idx_pool.shape[1], idx_pool.shape[2]
     tables, qstart, qlen, kvlen = _spans(tables, qstart, qlen, kvlen)
     R, nk = tables.shape
-    tq = _query_block(block_q, H, T)
+    tiling = index_grid_params(H, T, block_q)
+    tq, one_token = tiling["block_q"], tiling["one_token"]
     tpb = tq // H
     nq = -(-(T * H) // tq)
     pages = max(1, min(int(pages), nk))
     n_grp, group = -(-nk // pages), pages * bs
     rows = _tile_rows(tpb)
     q_wide = jnp.pad(q_idx.reshape(T * H, D), ((0, nq * tq - T * H), (0, 0)))
+    w_pad = jnp.pad(w_idx.astype(jnp.float32), ((0, nq * tpb - T), (0, 0)))
     # e[qi, tok, wide row]: the token's weight for that row's head
-    w_pad = jnp.pad(w_idx.astype(jnp.float32).reshape(T * H),
-                    (0, nq * tq - T * H)).reshape(nq, 1, tq)
     own = (jnp.arange(tq, dtype=jnp.int32)[None, :] // H
            == jnp.arange(rows, dtype=jnp.int32)[:, None])
-    e = jnp.where(own[None], w_pad, 0.0)                    # [nq, rows, tq]
+    e = jnp.where(own[None], w_pad.reshape(nq, 1, tq), 0.0)  # [nq, rows, tq]
+    # wt[qi, head, tok]: the same weights, a token's heads along sublanes
+    # (the one-token path's; whole lanes, zeros past the block's tokens)
+    wt = [jnp.pad(jnp.swapaxes(w_pad.reshape(nq, tpb, H), 1, 2),
+                  ((0, 0), (0, 0), (0, _LANES - tpb)))] if one_token else []
     work = _work_list(qstart, qlen, kvlen, nq=nq, tokens_per_block=tpb,
                       block_size=bs, table_entries=nk)
     kernel = functools.partial(
-        _index_kernel, block_k=bs, pages=pages, tq=tq, gh=H,
-        num_blocks=num_blocks, table_entries=nk)
+        _index_kernel, one_token=one_token, block_k=bs, pages=pages, tq=tq,
+        gh=H, num_blocks=num_blocks, table_entries=nk)
 
     def _q_index(w, wq, *_):
         return (wq[w], 0)
@@ -212,12 +350,13 @@ def dsa_index_scores_pallas(q_idx, w_idx, idx_pool, tables, qstart, qlen,
             num_scalar_prefetch=9,
             grid=(nq + R,),
             in_specs=[pl.BlockSpec((tq, D), _q_index),
-                      pl.BlockSpec((1, rows, tq), _t_index),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec((1, rows, tq), _t_index)]
+            + [pl.BlockSpec((1, H, _LANES), _t_index)] * one_token
+            + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, n_grp, rows, group), _o_index),
             scratch_shapes=[
-                pltpu.VMEM((2, group, D), idx_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, pages)),
+                pltpu.VMEM((SLOTS, group, D), idx_pool.dtype),
+                pltpu.SemaphoreType.DMA((SLOTS,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((nq, n_grp, rows, group),
@@ -226,7 +365,7 @@ def dsa_index_scores_pallas(q_idx, w_idx, idx_pool, tables, qstart, qlen,
         interpret=_interpret_mode(),
         name="dsa_index_scores",
     )(*work, qstart, qlen, kvlen, tables,
-      jnp.asarray(layer, jnp.int32).reshape(1), q_wide, e, idx_pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), q_wide, e, *wt, idx_pool)
     return _tiles_to_rows(tiles, tpb, T, nk * bs)
 
 

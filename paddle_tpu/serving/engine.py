@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.dsa import index_grid_params
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..profiler.tracing import NULL_SPAN
@@ -893,14 +894,19 @@ class ContinuousBatchingEngine:
             # the queries an indexer scored and the keys it scored them
             # against, the sets' sizes, and the pool rows the attention
             # scored (a masked walk's whole diagonal; a gather would read
-            # the sets themselves)
+            # the sets themselves); and the spans of one token that the
+            # index-scores kernel scores on their own wide rows, at the
+            # tiling its call derives (0 where that has no such path)
             d, layers = self.config.dsa, self.config.num_hidden_layers
             spans = [(int(ql), int(kl)) for ql, kl in zip(qlen, kvlen)
                      if ql > 0]
             picked = sum(int(np.minimum(np.arange(kl - ql, kl) + 1,
                                         d.topk).sum()) for ql, kl in spans)
+            alone = index_grid_params(d.heads, packed)["one_token"] \
+                * sum(ql == 1 for ql, _ in spans)
             work.update(
                 index_query_rows=d.layers * sum(ql for ql, _ in spans),
+                index_one_token_rows=d.layers * alone,
                 index_key_rows=d.layers * work["attn_pairs"],
                 selected_rows=layers * picked,
                 attended_rows=layers * work["attn_pairs"])

@@ -5,8 +5,10 @@ an indexer of 32 heads of 128, 2,048 selected), for one layer call of the
 two packed sizes the serving cell runs: 16 decode rows, and those 16 beside
 one 512-token chunk, over caches of 6k-19k tokens scattered through a pool.
 
-Times each step (index scores, the selection, the selection's layout, the
-attention by walk-and-mask and by gather, the dense latent walk for scale),
+Times each step (index scores, also as a call inside a loop of one program,
+where the host's dispatch does not bound the reading; the selection, the
+selection's layout, the attention by walk-and-mask and by gather, the dense
+latent walk for scale),
 checks the two ways of attending against each other, and prints one JSON line
 a packed size. What PERF.md (PR 43) says of gather against walk-and-mask is
 this script's output.
@@ -81,6 +83,18 @@ def main():
         jax.block_until_ready(out)
         return out, 1e3 * (time.perf_counter() - t0) / a.iters
 
+    def timed_in_loop(fn, q, w, *rest, calls=4 if tiny else 100):
+        """ms a call of ``calls`` calls inside ONE program, each fed by the
+        one before it: the device's time. ``timed`` dispatches a program a
+        call and reads no lower than the host's dispatch (0.20-0.22 ms a
+        call on the chip's host, PERF.md, PR 44)."""
+        def chain(q, w, *rest):
+            def one(_, acc):
+                return fn(q, w + 0.0 * acc, *rest)[0, 0]
+            return jax.lax.fori_loop(0, calls, one, jnp.float32(0))
+        _, ms = timed(jax.jit(chain), q, w, *rest)
+        return ms / calls
+
     for name, spans in (
             ("decode", [(1, int(k)) for k in rng.randint(lo, hi_ctx, R)]),
             ("chunk", [(1, int(k)) for k in rng.randint(lo, hi_ctx, R - 1)]
@@ -101,6 +115,8 @@ def main():
         scores, out["index_scores_ms"] = timed(jax.jit(
             lambda q, w, p, *s: dsa.dsa_index_scores_pallas(q, w, p, *s)),
             q_i, w_i, ipool, *span)
+        out["index_scores_in_loop_ms"] = timed_in_loop(
+            dsa.dsa_index_scores_pallas, q_i, w_i, ipool, *span)
         mask, out["select_ms"] = timed(jax.jit(
             lambda s: dsa.dsa_select(s, topk)), scores)
         _, out["top_k_ms"] = timed(jax.jit(

@@ -37,12 +37,31 @@ SPANS = {
     "chunk_behind_decode_rows": [(1, 30), (20, 52), (1, 77), (0, 0)],
     "chunk_from_zero": [(24, 24), (1, 11)],
 }
+# for an indexer of 16 or 32 heads, where a span of ONE token scores on its
+# own wide rows (ISSUE 44): a context of one key, one that ends inside a
+# group (of 4 table entries = 32 keys), one on a group's edge, a dead row;
+# decode rows before AND behind a chunk inside one query block; a fresh
+# prompt of one token alone
+ONE_TOKEN_SPANS = {
+    "decode_rows_alone": [(1, 1), (1, 37), (1, 64), (0, 0), (1, 90)],
+    "decode_rows_around_a_chunk": [(1, 30), (1, 45), (4, 72), (1, 77),
+                                   (1, 64)],
+    "fresh_prompt_of_one_token": [(1, 1)],
+}
+# (spans, index heads, table entries a group: None the kernel's own, all 12
+# here). At 4 entries a pair walks at most 3 groups, fewer than the walk
+# keeps in flight; at ONE a pair of 8 to 12 groups also runs the walk's
+# branch-free loop, the old body (4 heads, a chunk) and the new one alike
+INDEX_CASES = [(n, HI, None) for n in sorted(SPANS)] + [
+    (n, hi, pages) for n in sorted(ONE_TOKEN_SPANS) for hi in (16, 32)
+    for pages in (4, 1)] + [("chunk_behind_decode_rows", HI, 1)]
 
 
-def _case(name, seed=0, pad=3):
+def _case(name, seed=0, pad=3, hi=HI):
     rng = np.random.RandomState(seed)
-    qlen = np.array([q for q, _ in SPANS[name]], np.int32)
-    kvlen = np.array([k for _, k in SPANS[name]], np.int32)
+    spans = SPANS.get(name) or ONE_TOKEN_SPANS[name]
+    qlen = np.array([q for q, _ in spans], np.int32)
+    kvlen = np.array([k for _, k in spans], np.int32)
     R = len(qlen)
     nb = R * MB + 2
     tables = rng.permutation(nb)[:R * MB].astype(np.int32).reshape(R, MB)
@@ -63,8 +82,8 @@ def _case(name, seed=0, pad=3):
         T=T, live=int(qlen.sum()), pool=jnp.asarray(pool, f),
         ipool=jnp.asarray(ipool, f),
         span=tuple(jnp.asarray(x) for x in (tables, qstart, qlen, kvlen)),
-        q_i=jnp.asarray(rng.randn(T, HI, D), f),
-        w_i=jnp.asarray(rng.randn(T, HI), f),
+        q_i=jnp.asarray(rng.randn(T, hi, D), f),
+        w_i=jnp.asarray(rng.randn(T, hi), f),
         q_nope=jnp.asarray(rng.randn(T, NH, NOPE), f),
         q_pe=jnp.asarray(rng.randn(T, NH, ROPE), f),
         w_kvb=jnp.asarray(rng.randn(RANK, NH * (NOPE + VD)) * RANK ** -0.5,
@@ -72,23 +91,33 @@ def _case(name, seed=0, pad=3):
 
 
 @functools.lru_cache(maxsize=None)
-def _scored(name):
-    """(case, kernel scores, oracle scores, the selection) of a case, each
-    computed once a module."""
-    c = _case(name)
+def _scored(name, hi=HI, pages=None):
+    """(case, kernel scores, oracle scores, the selection, the kernel's
+    operands) of a case, each computed once a module."""
+    c = _case(name, hi=hi)
     args = (c["q_i"], c["w_i"], c["ipool"]) + c["span"]
-    got = jax.jit(functools.partial(dsa.dsa_index_scores_pallas,
-                                    layer=1))(*args)
+    pages = {} if pages is None else {"pages": pages}
+    kernel = jax.jit(functools.partial(dsa.dsa_index_scores_pallas,
+                                       layer=1, **pages))
     want = jax.jit(functools.partial(dsa.dsa_index_scores_reference,
                                      layer=1))(*args)
-    return (c, np.asarray(got), np.asarray(want),
-            jax.jit(lambda s: dsa.dsa_select(s, TOPK))(want))
+    call, = (e for e in kernel.trace(*args).jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call")
+    return (c, np.asarray(kernel(*args)), np.asarray(want),
+            jax.jit(lambda s: dsa.dsa_select(s, TOPK))(want),
+            len(call.invars))
 
 
-@pytest.mark.parametrize("name", sorted(SPANS))
-def test_index_scores_kernel_equals_oracle(name):
-    c, got, want, _ = _scored(name)
+@pytest.mark.parametrize("name,hi,pages", INDEX_CASES)
+def test_index_scores_kernel_equals_oracle(name, hi, pages):
+    c, got, want, _, operands = _scored(name, hi, pages)
     assert got.shape == want.shape == (c["T"], MB * BS)
+    # a span of one token takes its own path where the tiling says so (16
+    # and 32 index heads: the kernel then takes the weights a second time,
+    # heads along sublanes), and not at this module's 4 heads
+    one_token = dsa.index_grid_params(hi, c["T"])["one_token"]
+    assert one_token == (hi >= 16)
+    assert operands == 9 + 3 + one_token
     seen = want > 0.5 * NEG_INF
     # the same keys are scored: the row's positions up to the query's own
     assert ((got > 0.5 * NEG_INF) == seen).all()
@@ -100,11 +129,14 @@ def test_index_scores_kernel_equals_oracle(name):
     assert np.isfinite(got[seen]).all()
     assert np.abs(got[seen] - want[seen]).max() <= 1e-4 * np.abs(
         want[seen]).max()
+    # and select the oracle's set
+    assert (np.asarray(dsa.dsa_select(jnp.asarray(got), TOPK))
+            == np.asarray(dsa.dsa_select_reference(want, TOPK))).all()
 
 
 @pytest.mark.parametrize("name", sorted(SPANS))
 def test_selection_is_top_k_as_a_set(name):
-    _, _, want, mask = _scored(name)
+    _, _, want, mask, _ = _scored(name)
     mask = np.asarray(mask)
     assert (mask == np.asarray(dsa.dsa_select_reference(want, TOPK))).all()
     n_seen = (want > 0.5 * NEG_INF).sum(-1)
@@ -130,7 +162,7 @@ def test_selection_ties_go_to_the_lowest_positions(k):
 
 @functools.lru_cache(maxsize=None)
 def _attended(name):
-    c, _, _, mask = _scored(name)
+    c, _, _, mask, _ = _scored(name)
     span = dict(scale=(NOPE + ROPE) ** -0.5, layer=1)
     w = c["w_kvb"].reshape(RANK, NH, NOPE + VD)
     q_lat = jnp.einsum("thd,rhd->thr", c["q_nope"], w[..., :NOPE])

@@ -57,11 +57,11 @@ def recorded_logits():
 
 
 @functools.lru_cache(maxsize=None)
-def _model(attention="jnp"):
+def _model(attention="jnp", **config):
     """One model a path for the whole module (building one compiles)."""
     paddle.seed(11)
     return GlmMoeDsaForCausalLM(glm_moe_dsa_tiny(
-        decode_attention=attention))
+        decode_attention=attention, **config))
 
 
 def _prompt(n, seed=0):
@@ -109,15 +109,24 @@ CASES = {
     "whole_prompt_then_decode": (21, 5, "jnp"),
     "three_chunks_then_decode": (75, 4, "jnp"),
     "two_chunks_then_decode_kernels": (40, 3, "pallas"),
+    # 16 index heads: a decode row's index scores on its own wide rows
+    "two_chunks_then_decode_kernels_16_index_heads": (40, 3, "pallas"),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_engine_logits_equal_reference(case):
     n_prompt, n_new, attention = CASES[case]
-    model = _model(attention)
     prompt = _prompt(n_prompt)
-    eng, tokens, rows = _serve_one(model, prompt, n_new)
+    if case.endswith("16_index_heads"):
+        # (other shapes than the module's programs: a cache of its own)
+        model = _model(attention, index_n_heads=16)
+        eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+        assert eng._dispatch_args([0], [1], [41], 8, 1, 1, 0)[
+            "index_one_token_rows"] == 2
+    else:
+        model, eng = _model(attention), None
+    eng, tokens, rows = _serve_one(model, prompt, n_new, eng)
     want = _reference_logits(model, prompt, tokens)
     assert np.abs(rows - want).max() / np.abs(want).max() <= TOLERANCE
     if n_prompt > GEOMETRY["prefill_chunk"]:
@@ -228,6 +237,35 @@ def test_two_caches_under_one_table():
     assert args["index_key_rows"] == 2 * args["attn_pairs"]
     assert args["selected_rows"] == 5 * 21 * 8
     assert args["attended_rows"] == 5 * args["attn_pairs"]
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_indexer_engine():
+    """An engine over an indexer of 16 heads: whole row tiles a token, so
+    the index-scores kernel has its one-token path (ISSUE 44). No program is
+    built: ``_dispatch_args`` counts on the host."""
+    return _engine(_model(index_n_heads=16))
+
+
+@pytest.mark.parametrize("heads,qlen,kvlen,want", [
+    (16, [1, 1], [30, 52], 2 * 2),          # a decode-only plan
+    (16, [1, 20], [30, 52], 2 * 1),         # a chunk: the decode row only
+    (16, [0, 20], [0, 52], 0),              # no span of one token
+    (4, [1, 1], [30, 52], 0),               # 4 wide rows are no row tile
+])
+def test_index_one_token_rows_on_the_dispatch_span(heads, qlen, kvlen, want):
+    """Indexer layers x the step's spans of one token, where the kernel's
+    own tiling (``dsa.index_grid_params``) gives them the path."""
+    from paddle_tpu.kernels import dsa
+    eng = _wide_indexer_engine() if heads == 16 else _engine(_model())
+    assert eng.config.dsa.heads == heads and eng.config.dsa.layers == 2
+    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).tolist()
+    packed = -(-sum(qlen) // 8) * 8
+    args = eng._dispatch_args(qstart, qlen, kvlen, packed, qlen.count(1),
+                              qlen.count(1), sum(q for q in qlen if q > 1))
+    assert dsa.index_grid_params(heads, packed)["one_token"] == (heads == 16)
+    assert args["index_one_token_rows"] == want
+    assert args["index_query_rows"] == 2 * sum(qlen)
 
 
 def test_served_over_http_and_metrics_tell_the_two_caches_apart():
