@@ -778,7 +778,7 @@ def phase_kernels(rehearse):
 
     # ---- latent attention, absorbed kernel against expanded oracle ------
     from paddle_tpu.kernels.pallas_mla_ragged_attention import (
-        latent_row_width, mla_ragged_attention_pallas,
+        PAGES, SLOTS, latent_row_width, mla_ragged_attention_pallas,
         mla_ragged_attention_reference)
     nh, rank, nope, rope, vd = size["mla"]["widths"]
     scale = (nope + rope) ** -0.5
@@ -834,6 +834,16 @@ def phase_kernels(rehearse):
                                  (40, 77), (0, 0)], mb=24, pad=12)
     mb, rows = size["mla"]["decode_only"]
     latent_agrees(f"mla_ragged {len(rows)} rows", rows, mb=mb, pad=0)
+    # decode rows of as many groups of 16 pool pages as lie around the edges
+    # of the walk's pipeline (``_walk_ahead``): fewer than a pair starts
+    # ahead, as many, one and two more, several rounds of the slots. A copy
+    # left unwaited or a slot read early shows only on the chip, as NaN
+    edges = sorted({1, max(SLOTS - 2, 1), SLOTS - 1, SLOTS, SLOTS + 1,
+                    2 * SLOTS + 1})
+    group = PAGES * 32
+    latent_agrees("mla_ragged pipeline edges",
+                  [(1, group * n - 45 - 7 * i) for i, n in enumerate(edges)]
+                  + [(1, group * SLOTS)], mb=PAGES * edges[-1], pad=0)
 
     # ---- sparse attention over the latent pool: index scores, the
     # selection and the attention over it against their oracles -----------

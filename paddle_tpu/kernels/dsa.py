@@ -13,13 +13,15 @@ the selection of the layer before it:
   by the latent pool's block tables. The kernel walks
   ``pallas_mla_ragged_attention``'s work list of (query block, row) pairs,
   ``pages`` table entries a DMA group, on wide rows of one (token, index
-  head) each. Its pipeline is its own (``_walk_ahead``: ``SLOTS`` groups in
-  flight, one wait a group, a loop without a branch that computes before it
-  starts the next copies): a block of index keys is 8 KB where a latent
-  block is 40 KB, so this walk is bound by the issue of its copies and not
-  by their bytes, and under the latent kernel's two-slot walk a decode row's
-  compute and the next group's address arithmetic ran in turn (PERF.md,
-  PR 44).
+  head) each, on that module's pipeline (``_walk_ahead``, which the latent
+  kernel walks on too since PR 45: several groups in flight, one wait a
+  group, a loop without a branch that computes before it starts the next
+  copies) with a slot count of its own, ``SLOTS``: a block of index keys is
+  8 KB where a latent block is 40 KB, so this walk is bound by the issue of
+  its copies and not by their bytes; under the two-slot walk with a
+  conditional start ahead of the wait that both kernels had before, a decode
+  row's compute and the next group's address arithmetic ran in turn
+  (PERF.md, PR 44).
 
   A span of several tokens (a chunk, a verify span) scores the query
   block's ``tq`` wide rows against a group and sums the heads by one small
@@ -67,7 +69,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_flash import _cparams, _interpret_mode
-from .pallas_mla_ragged_attention import (PAGES, _spans,
+from .pallas_mla_ragged_attention import (PAGES, _spans, _start_ahead,
+                                          _walk_ahead,
                                           mla_ragged_attention_pallas)
 from .pallas_mla_ragged_attention import grid_params as _mla_grid_params
 from .pallas_ragged_attention import (NEG_INF, _one_token_walk, _query_block,
@@ -139,44 +142,6 @@ def index_grid_params(heads, packed_tokens, block_q=BLOCK_Q):
     block_q = _query_block(block_q, heads, packed_tokens)
     return {"block_q": block_q,
             "one_token": _one_token_walk(heads, block_q)}
-
-
-def _walk_ahead(n_groups, start, wait, body, slots):
-    """Run ``body(gi, slot)`` over a pair's groups, ``slots - 1`` groups in
-    flight ahead of the one that computes (the pair's first ``slots - 1``
-    started already: ``_start_ahead``). While a group that far ahead exists
-    the loop's body has no branch and computes BEFORE it starts that group:
-    the next copies' addresses (table lookups, scalar work) then pack under
-    the vector work, where a conditional start ahead of the wait
-    (``pallas_mla_ragged_attention._walk_groups``) runs them in turn. The
-    pair's last ``slots - 1`` groups only wait and compute."""
-    ahead = slots - 1
-
-    def _tail(gi, carry):
-        wait(gi % slots)
-        body(gi, gi % slots)
-        return carry
-
-    def _steady(gi, carry):
-        _tail(gi, carry)
-        start(gi + ahead, (gi + ahead) % slots)
-        return carry
-
-    steady = jnp.maximum(n_groups - ahead, 0)
-    jax.lax.fori_loop(0, steady, _steady, 0)
-    jax.lax.fori_loop(steady, n_groups, _tail, 0)
-
-
-def _start_ahead(n_groups, start, slots):
-    """Start a pair's first ``slots - 1`` groups, each into the slot of its
-    own number (a loop, not ``slots - 1`` copies of the group's starts: the
-    step programs trace and lower this kernel a dozen times, and set-up
-    pays for every copy's descriptor)."""
-    def _first(g, carry):
-        start(g, g)
-        return carry
-
-    jax.lax.fori_loop(0, jnp.minimum(slots - 1, n_groups), _first, 0)
 
 
 def _index_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,

@@ -21,14 +21,22 @@ spans of length 1 (decode rows) and n (prefill chunks) alike, float32 softmax
 state, rows outside every span exact zeros.
 
 What differs: the pool is ONE buffer, left in HBM whole; a loop iteration
-fetches ``pages`` consecutive table entries (one DMA each, all in flight
-together, the next group streaming in while this one computes) and runs one
-online-softmax update over ``pages * bs`` keys. A 32-token block alone is 40
-KB: one block an iteration is bound by the DMA's latency, not by its bytes
-(PERF.md, PR 25: 1.3 us a block). Entries past the pair's last block clamp
-to the table's last entry: a harmless read, masked off. This walk still
-drains at a pair's end (a pair's first group is fetched with nothing to
-overlap it; ``pallas_ragged_attention`` hands it over: ROADMAP S15 (e)).
+computes one online-softmax update over a group of ``pages`` consecutive
+table entries (``pages * bs`` keys, one DMA an entry). A 32-token block alone
+is 40 KB: one block an iteration is bound by the DMA's latency, not by its
+bytes (PERF.md, PR 25: 1.3 us a block). Entries past the pair's last block
+clamp to the table's last entry: a harmless read, masked off.
+
+A pair walks its groups on ``_walk_ahead``'s pipeline, which
+``kernels.dsa``'s index-scores kernel shares: ``SLOTS - 1`` groups in flight
+ahead of the one that computes, all of a group's copies on its slot's ONE
+semaphore and one byte-counting wait a group, and a steady loop without a
+branch that computes BEFORE it starts the copies ``SLOTS - 1`` groups ahead,
+so the scalar work of their addresses packs under the update's vector work
+and HBM stays busy across the softmax between the update's two matmuls
+(PERF.md, PR 44 and PR 45). The walk still drains at a pair's end: a pair's
+first groups are started with nothing of the pair before it to overlap them
+(``pallas_ragged_attention`` hands its first group over: ROADMAP S15 (e)).
 
 ``mla_ragged_attention_reference`` is the oracle in the EXPANDED form: it
 gathers the latent rows through the tables, up-projects them to per-head
@@ -54,6 +62,14 @@ from .pallas_ragged_attention import (NEG_INF, _one_token_walk, _query_block,
 #: the query block's wide rows
 PAGES = 16
 BLOCK_Q = 256
+#: slots of the walk's pipeline (``_walk_ahead``): a slot is one group of
+#: latent blocks, 655 KB at the published widths where the index kernel's is
+#: 128 KB. The smallest within 2 % of the best (PERF.md, PR 45, ms a call in
+#: a loop of one program on the v5e: 16 decode rows of 64 heads at 13.4k,
+#: 0.488 at 3 slots, 0.464 at 4, 0.464 at 6, 0.467 at 8; 32 rows of 128
+#: heads at 4.7k, 0.515 / 0.508 / 0.508 / 0.513; the copies alone 0.393 and
+#: 0.306, the update alone 0.354 and 0.417)
+SLOTS = 4
 
 
 def latent_row_width(rank, rope):
@@ -62,43 +78,42 @@ def latent_row_width(rank, rope):
     return -(-(int(rank) + int(rope)) // 128) * 128
 
 
-def _copies(pool_hbm, buf, sems, tbl_ref, r, layer, gi, slot, *, pages,
-            block_k, num_blocks, table_entries):
-    """The DMAs of one group of ``pages`` table entries of row ``r``,
-    resolved from SMEM at issue time; entries past the table clamp to its
-    last, sentinels into the layer's own blocks (masked by the caller either
-    way)."""
-    out = []
-    for j in range(pages):
-        entry = jnp.minimum(gi * pages + j, table_entries - 1)
-        phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
-        out.append(pltpu.make_async_copy(
-            pool_hbm.at[layer, phys],
-            buf.at[slot, pl.ds(j * block_k, block_k)],
-            sems.at[slot, j]))
-    return out
+def _walk_ahead(n_groups, start, wait, body, slots):
+    """Run ``body(gi, slot)`` over a pair's groups, ``slots - 1`` groups in
+    flight ahead of the one that computes (the pair's first ``slots - 1``
+    started already: ``_start_ahead``). While a group that far ahead exists
+    the loop's body has no branch and computes BEFORE it starts that group:
+    the next copies' addresses (table lookups, scalar work) then pack under
+    the vector work, where a conditional start ahead of the wait (this
+    module's walk before PR 45) runs them in turn. The pair's last
+    ``slots - 1`` groups only wait and compute."""
+    ahead = slots - 1
 
-
-def _walk_groups(n_groups, copies, body):
-    """Run ``body(gi, slot)`` over a pair's groups, group ``gi + 1``
-    streaming into the other slot while ``gi`` computes."""
-    for c in copies(0, 0):
-        c.start()
-
-    def _group(gi, carry):
-        slot = gi % 2
-
-        @pl.when(gi + 1 < n_groups)
-        def _prefetch():
-            for c in copies(gi + 1, 1 - slot):
-                c.start()
-
-        for c in copies(gi, slot):
-            c.wait()
-        body(gi, slot)
+    def _tail(gi, carry):
+        wait(gi % slots)
+        body(gi, gi % slots)
         return carry
 
-    jax.lax.fori_loop(0, n_groups, _group, 0)
+    def _steady(gi, carry):
+        _tail(gi, carry)
+        start(gi + ahead, (gi + ahead) % slots)
+        return carry
+
+    steady = jnp.maximum(n_groups - ahead, 0)
+    jax.lax.fori_loop(0, steady, _steady, 0)
+    jax.lax.fori_loop(steady, n_groups, _tail, 0)
+
+
+def _start_ahead(n_groups, start, slots):
+    """Start a pair's first ``slots - 1`` groups, each into the slot of its
+    own number (a loop, not ``slots - 1`` copies of the group's starts: the
+    step programs trace and lower these kernels a dozen times, and set-up
+    pays for every copy's descriptor)."""
+    def _first(g, carry):
+        start(g, g)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(slots - 1, n_groups), _first, 0)
 
 
 def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
@@ -121,14 +136,33 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
     span_lo = qstart * gh
     span_hi = (qstart + qlen) * gh
     group = pages * block_k         # keys of one iteration
+    n_groups = (nkb + pages - 1) // pages
+    slots = buf.shape[0]
 
     @pl.when(wf_ref[w] == 1)
     def _zero_out():
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    copies = functools.partial(
-        _copies, pool_hbm, buf, sems, tbl_ref, r, layer, pages=pages,
-        block_k=block_k, num_blocks=num_blocks, table_entries=table_entries)
+    def start(gi, slot):
+        # one group of ``pages`` table entries of row ``r``, resolved from
+        # SMEM at issue time, every copy on the slot's ONE semaphore;
+        # entries past the table clamp to its last, sentinels into the
+        # layer's own blocks (masked by ``_update`` either way)
+        for j in range(pages):
+            entry = jnp.minimum(gi * pages + j, table_entries - 1)
+            phys = jnp.clip(tbl_ref[r, entry], 0, num_blocks - 1)
+            pltpu.make_async_copy(
+                pool_hbm.at[layer, phys],
+                buf.at[slot, pl.ds(j * block_k, block_k)],
+                sems.at[slot]).start()
+
+    def wait(slot):
+        # a DMA semaphore counts bytes: one wait for the slot's whole
+        # buffer takes the group's ``pages`` copies together
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sems.at[slot]).wait()
+
+    _start_ahead(n_groups, start, slots)        # (none for a dead pair)
 
     def _walk(nr, load_q, valid_of, write, picked_of):
         # one pair's walk on ``nr`` wide rows (static), the softmax state in
@@ -155,13 +189,13 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
             p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
             # the value is the latent part of the same rows. Rows past kvlen
             # may hold another sequence's (or a clamped entry's) values, and
-            # 0 * NaN is NaN: zero them, in the one group that can have any
-            v = jax.lax.cond(
-                (gi + 1) * group > kvlen,
-                lambda v: jnp.where(
-                    gi * group + jax.lax.broadcasted_iota(
-                        jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v)),
-                lambda v: v, k[:, :rank])
+            # 0 * NaN is NaN: zero them. In every group, not under a
+            # ``lax.cond`` in the one that can have any: the select rides on
+            # the operand's load, where the cond's result, ``[group, rank]``,
+            # went through VMEM every group (PERF.md, PR 45: 0.37 us a group)
+            v = k[:, :rank]
+            v = jnp.where(gi * group + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) < kvlen, v, jnp.zeros_like(v))
             alpha = jnp.exp(m_prev - m_new)
             l_ref[:] = jnp.broadcast_to(
                 alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
@@ -171,7 +205,7 @@ def _mla_kernel(wq_ref, wr_ref, wf_ref, wn_ref, qs_ref, ql_ref, kl_ref,
                 preferred_element_type=jnp.float32)
             m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-        _walk_groups((nkb + pages - 1) // pages, copies, _update)
+        _walk_ahead(n_groups, start, wait, _update, slots)
         write(acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30))
 
     # a span of ONE token (a decode row) is ``gh`` wide rows at a multiple
@@ -259,8 +293,8 @@ def _mla_call(q_wide, pool, layer, tables, qstart, qlen, kvlen, scale, gh,
             + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block_q, rank), _q_index),
             scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, W), pool.dtype),
-                pltpu.SemaphoreType.DMA((2, pages)),
+                pltpu.VMEM((SLOTS, pages * bs, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((SLOTS,)),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, rank), jnp.float32),

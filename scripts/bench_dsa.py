@@ -5,10 +5,10 @@ an indexer of 32 heads of 128, 2,048 selected), for one layer call of the
 two packed sizes the serving cell runs: 16 decode rows, and those 16 beside
 one 512-token chunk, over caches of 6k-19k tokens scattered through a pool.
 
-Times each step (index scores, also as a call inside a loop of one program,
-where the host's dispatch does not bound the reading; the selection, the
-selection's layout, the attention by walk-and-mask and by gather, the dense
-latent walk for scale),
+Times each step (index scores; the selection, the selection's layout, the
+attention by walk-and-mask and by gather, the dense latent walk for scale;
+the three kernel calls also inside a loop of one program, ``*_in_loop_ms``,
+where the host's dispatch does not bound the reading),
 checks the two ways of attending against each other, and prints one JSON line
 a packed size. What PERF.md (PR 43) says of gather against walk-and-mask is
 this script's output.
@@ -90,7 +90,8 @@ def main():
         call on the chip's host, PERF.md, PR 44)."""
         def chain(q, w, *rest):
             def one(_, acc):
-                return fn(q, w + 0.0 * acc, *rest)[0, 0]
+                res = fn(q, (w + 0.0 * acc).astype(w.dtype), *rest)
+                return res[(0,) * res.ndim].astype(jnp.float32)
             return jax.lax.fori_loop(0, calls, one, jnp.float32(0))
         _, ms = timed(jax.jit(chain), q, w, *rest)
         return ms / calls
@@ -124,13 +125,22 @@ def main():
         bias, out["selection_bias_ms"] = timed(jax.jit(
             lambda m: dsa.selection_bias(m, nh, table_entries=mb,
                                          block_size=bs)), mask)
-        walk, out["attend_walk_mask_ms"] = timed(jax.jit(
-            lambda ql, qp, p, b, *s: dsa.dsa_attention_pallas(
-                ql, qp, p, *s, b, scale=0.1)), q_lat, q_pe, pool, bias,
-            *span)
-        _, out["attend_dense_walk_ms"] = timed(jax.jit(
-            lambda ql, qp, p, *s: mla_ragged_attention_pallas(
-                ql, qp, p, *s, scale=0.1)), q_lat, q_pe, pool, *span)
+        def attend_masked(ql, qp, p, b, *s):
+            return dsa.dsa_attention_pallas(ql, qp, p, *s, b, scale=0.1)
+
+        def attend_dense(ql, qp, p, *s):
+            return mla_ragged_attention_pallas(ql, qp, p, *s, scale=0.1)
+
+        # (a chunk call is 10 ms: a fifth of the calls time it as well)
+        calls = 4 if tiny else 100 if name == "decode" else 20
+        walk, out["attend_walk_mask_ms"] = timed(
+            jax.jit(attend_masked), q_lat, q_pe, pool, bias, *span)
+        out["attend_walk_mask_in_loop_ms"] = timed_in_loop(
+            attend_masked, q_lat, q_pe, pool, bias, *span, calls=calls)
+        _, out["attend_dense_walk_ms"] = timed(
+            jax.jit(attend_dense), q_lat, q_pe, pool, *span)
+        out["attend_dense_walk_in_loop_ms"] = timed_in_loop(
+            attend_dense, q_lat, q_pe, pool, *span, calls=calls)
         if name == "decode":
             got, out["attend_gather_ms"] = timed(jax.jit(
                 lambda ql, qp, p, m, *s: attention_by_gather(

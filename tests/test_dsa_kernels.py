@@ -26,6 +26,7 @@ from paddle_tpu.serving import decode as decode_mod
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(HERE), "benchmark"))
 import reference_glm_moe_dsa as ref  # noqa: E402
+import test_mla_attention as edges  # noqa: E402
 
 BS, MB = 8, 12                  # block size, table entries: 96 positions
 NH, RANK, ROPE, NOPE, VD, W = 4, 32, 8, 16, 16, 128
@@ -57,14 +58,14 @@ INDEX_CASES = [(n, HI, None) for n in sorted(SPANS)] + [
     for pages in (4, 1)] + [("chunk_behind_decode_rows", HI, 1)]
 
 
-def _case(name, seed=0, pad=3, hi=HI):
+def _case(name, seed=0, pad=3, hi=HI, spans=None, mb=MB, nh=NH):
     rng = np.random.RandomState(seed)
-    spans = SPANS.get(name) or ONE_TOKEN_SPANS[name]
+    spans = spans or SPANS.get(name) or ONE_TOKEN_SPANS[name]
     qlen = np.array([q for q, _ in spans], np.int32)
     kvlen = np.array([k for _, k in spans], np.int32)
     R = len(qlen)
-    nb = R * MB + 2
-    tables = rng.permutation(nb)[:R * MB].astype(np.int32).reshape(R, MB)
+    nb = R * mb + 2
+    tables = rng.permutation(nb)[:R * mb].astype(np.int32).reshape(R, mb)
     live = np.zeros((nb, BS), bool)
     for r, k in enumerate(kvlen):
         for b in range(-(-int(k) // BS)):
@@ -84,9 +85,9 @@ def _case(name, seed=0, pad=3, hi=HI):
         span=tuple(jnp.asarray(x) for x in (tables, qstart, qlen, kvlen)),
         q_i=jnp.asarray(rng.randn(T, hi, D), f),
         w_i=jnp.asarray(rng.randn(T, hi), f),
-        q_nope=jnp.asarray(rng.randn(T, NH, NOPE), f),
-        q_pe=jnp.asarray(rng.randn(T, NH, ROPE), f),
-        w_kvb=jnp.asarray(rng.randn(RANK, NH * (NOPE + VD)) * RANK ** -0.5,
+        q_nope=jnp.asarray(rng.randn(T, nh, NOPE), f),
+        q_pe=jnp.asarray(rng.randn(T, nh, ROPE), f),
+        w_kvb=jnp.asarray(rng.randn(RANK, nh * (NOPE + VD)) * RANK ** -0.5,
                           f))
 
 
@@ -191,6 +192,72 @@ def test_attention_over_the_selection_equals_oracle(name):
     assert not got[c["live"]:].any()        # rows outside every span: zeros
 
 
+EDGE_HEADS = 16
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_attended(path):
+    """ONE call of the attention over a selection, 16 heads, over
+    ``test_mla_attention.edge_spans``: a row for each group count around the
+    edges of the walk's pipeline (``_walk_ahead`` at the latent kernel's
+    ``SLOTS``), ending inside its last group (the rest of it NaN), and one
+    ending on a group's edge. ``path`` "one_token": decode rows; "span":
+    spans of three tokens. A query selects about half the keys it sees, so
+    some groups of a walk hold none of them. Returns (case, kernel, oracle,
+    the rows' (start, span, kv length))."""
+    pages, mb = edges.EDGE_PAGES, edges.EDGE_ENTRIES
+    c = _case(path, seed=len(path), mb=mb, nh=EDGE_HEADS,
+              spans=edges.edge_spans({"one_token": 1, "span": 3}[path]))
+    _, qstart, qlen, kvlen = (np.asarray(x) for x in c["span"])
+    rng = np.random.RandomState(7)
+    mask = np.zeros((c["T"], mb * BS), bool)
+    for qs, ql, kl in zip(qstart, qlen, kvlen):
+        for i in range(ql):
+            seen = kl - ql + i + 1
+            mask[qs + i, :seen] = rng.rand(seen) < 0.5
+            mask[qs + i, rng.randint(seen)] = True
+    mask = jnp.asarray(mask)
+    k = int(mask.sum(-1).max())
+    span = dict(scale=(NOPE + ROPE) ** -0.5, layer=1)
+    w = c["w_kvb"].reshape(RANK, EDGE_HEADS, NOPE + VD)
+    q_lat = jnp.einsum("thd,rhd->thr", c["q_nope"], w[..., :NOPE])
+
+    @jax.jit
+    def walked(q_lat, q_pe, pool, mask, *sp):
+        bias = dsa.selection_bias(mask, EDGE_HEADS, pages=pages,
+                                  table_entries=mb, block_size=BS)
+        return dsa.dsa_attention_pallas(q_lat, q_pe, pool, *sp, bias,
+                                        pages=pages, **span)
+
+    walk = walked(q_lat, c["q_pe"], c["pool"], mask, *c["span"])
+    want = jax.jit(lambda qn, qp, w, p, m, *sp: dsa.dsa_attention_reference(
+        qn, qp, w, p, *sp, m, k=k, **span))(
+        c["q_nope"], c["q_pe"], c["w_kvb"], c["pool"], mask, *c["span"])
+    got = jnp.einsum("thr,rhd->thd", walk, w[..., NOPE:])
+    return c, np.asarray(got), np.asarray(want), list(zip(qstart, qlen,
+                                                          kvlen))
+
+
+@pytest.mark.parametrize("path", ["one_token", "span"])
+@pytest.mark.parametrize("case", edges.EDGE_CASES)
+def test_attention_over_the_selection_at_the_pipelines_edges(case, path):
+    """The walk the index kernel shares, under the latent kernel with a
+    selection: each row of ``_edge_attended`` against the oracle over a
+    NaN-poisoned pool (a slot read before its copy landed, or a group of
+    another pair's, shows as NaN or a miss)."""
+    c, got, want, rows = _edge_attended(path)
+    assert np.isfinite(got).all()
+    qs, ql, kl = rows[edges.EDGE_CASES.index(case)]
+    if case == 0:
+        assert (ql, kl) == (0, 0)               # the dead row: no pair
+        assert not got[c["live"]:].any()
+        return
+    assert -(-kl // (edges.EDGE_PAGES * BS)) == edges.edge_groups(case)
+    assert np.abs(got[qs:qs + ql] - want[qs:qs + ql]).max() \
+        <= 1e-4 * np.abs(want).max()
+    assert np.abs(got[qs:qs + ql]).min() > 0
+
+
 def test_the_selection_binds():
     """The oracle over the selection is not the dense latent attention: a
     dropped mask would fail the comparison above."""
@@ -264,17 +331,24 @@ def test_softmax_router_is_unchanged_by_the_new_argument():
 
 
 #: sha256[:16] of the lowered text of DeepSeek-V2-tiny's unified step and
-#: whole-prompt prefill on PR 42's tree (a450a4c), by attention path
-PR42_PROGRAMS = {"jnp": ("20b9ff0e2b430953", "96dc5e47a4e1614a"),
-                 "pallas": ("8e267d88bc2173d4", "96dc5e47a4e1614a")}
+#: whole-prompt prefill, by attention path: PR 42's tree (a450a4c), but the
+#: "pallas" step, which is PR 45's tree's (PR 42's was 8e267d88bc2173d4)
+PR42_PROGRAMS_PR45_KERNEL = {
+    "jnp": ("20b9ff0e2b430953", "96dc5e47a4e1614a"),
+    "pallas": ("0e9bb0b2f4733e93", "96dc5e47a4e1614a")}
 
 
-@pytest.mark.parametrize("attention", sorted(PR42_PROGRAMS))
+@pytest.mark.parametrize("attention", sorted(PR42_PROGRAMS_PR45_KERNEL))
 def test_a_tree_with_no_indexer_runs_the_programs_it_ran(attention):
     """A tree without ``idx_layer`` (DeepSeek-V2): its unified step and its
     prefill lower to the TEXT they lowered to before this model came: the
     same ops on the same shapes in the same order, so the same bits. The
-    hashes are of this container's jax; another jax re-records them."""
+    hashes are of this container's jax; another jax re-records them. The
+    "pallas" step was recorded again in PR 45, which changed the latent
+    kernel's walk (``_walk_ahead`` in place of the two-slot walk, the
+    values past ``kvlen`` zeroed by a select in every group in place of a
+    ``lax.cond``) and nothing else of the step: the "jnp" step, which runs
+    everything but that kernel, and both prefills still match PR 42's."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the recorded texts are jax 0.9.0's")
     paddle.seed(11)
@@ -305,4 +379,4 @@ def test_a_tree_with_no_indexer_runs_the_programs_it_ran(attention):
     assert "dsa_" not in text and "dsa_" not in prefill
     got = tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
                 for t in (text, prefill))
-    assert got == PR42_PROGRAMS[attention]
+    assert got == PR42_PROGRAMS_PR45_KERNEL[attention]

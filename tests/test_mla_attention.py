@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.kernels import pallas_mla_ragged_attention as mla_mod
 from paddle_tpu.kernels.pallas_mla_ragged_attention import (
     latent_row_width, mla_ragged_attention_pallas,
     mla_ragged_attention_reference)
@@ -103,6 +104,10 @@ SPANS = {
     "several_groups_and_query_blocks": ([(0, 1, 30), (1, 20, 31), (21, 1, 3)],
                                         24, dict(pages=2, block_q=32)),
     "one_page_a_group": ([(0, 3, 27), (3, 1, 16)], 4, dict(pages=1)),
+    # (every case without ``pages`` walks a table of 4 entries, shorter than
+    # a group of ``PAGES``: a group is then the whole table. This one by name)
+    "table_shorter_than_a_group": ([(0, 1, 27), (1, 1, 32), (2, 3, 9)], 8,
+                                   {}),
 }
 
 
@@ -110,7 +115,8 @@ SPANS = {
 # the query block, the kernel's second path)
 @pytest.mark.parametrize("case,heads", [(c, 4) for c in sorted(SPANS)] + [
     ("decode_rows", 16), ("dead_rows_between", 16),
-    ("several_groups_and_query_blocks", 16)])
+    ("several_groups_and_query_blocks", 16),
+    ("table_shorter_than_a_group", 16)])
 def test_kernel_equals_oracle_over_paged_pool(case, heads, monkeypatch):
     monkeypatch.setattr(sys.modules[__name__], "H", heads)
     rows, packed, opts = SPANS[case]
@@ -138,6 +144,86 @@ def test_kernel_equals_oracle_over_paged_pool(case, heads, monkeypatch):
         live[s:s + n] = True
     assert not np.asarray(got)[~live].any()     # exact zeros off every span
     assert np.abs(np.asarray(got)[live]).min() > 0
+
+
+#: groups of pool pages a (query block, row) pair walks, around the edges of
+#: the walk's pipeline (``_walk_ahead``): a dead pair; fewer groups than the
+#: ``SLOTS - 1`` a pair starts ahead (no steady loop at all); exactly that
+#: many; one more (one steady iteration); and a pair that goes round the
+#: slots more than twice. The last case: a row that ends ON a group's edge
+EDGE_GROUPS = sorted({0, 1, max(mla_mod.SLOTS - 2, 1), mla_mod.SLOTS - 1,
+                      mla_mod.SLOTS, 2 * mla_mod.SLOTS + 1})
+EDGE_CASES = EDGE_GROUPS + ["on_a_groups_edge"]
+EDGE_PAGES = 2                  # table entries a group: 16 keys at BS = 8
+EDGE_ENTRIES = EDGE_GROUPS[-1] * EDGE_PAGES
+
+
+def edge_groups(case):
+    return EDGE_GROUPS[-2] if case == "on_a_groups_edge" else case
+
+
+def edge_spans(n_q):
+    """``[(query span, kv length)]``, a row for each of ``EDGE_CASES`` in
+    order (``tests/test_dsa_kernels.py`` walks the same rows under a
+    selection): a row of ``n`` groups ends inside its last group, in the
+    group's first page or its second in turn, so the group's other keys are
+    a stale block's or the sentinel's; 0 groups is a dead row."""
+    group = EDGE_PAGES * BS
+    return [(n_q, group * (n - 1) + (3, BS + 3)[i % 2]) if n else (0, 0)
+            for i, n in enumerate(EDGE_GROUPS)] \
+        + [(n_q, group * EDGE_GROUPS[-2])]
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_walk(path):
+    """(kernel output, oracle, rows) of ONE call at 16 heads over
+    ``edge_spans``. ``path`` "one_token": decode rows, each on its own wide
+    rows; "span": spans of three tokens, the query block's whole width."""
+    spans = edge_spans({"one_token": 1, "span": 3}[path])
+    starts = np.concatenate([[0], np.cumsum([q for q, _ in spans])])
+    rows = [(int(at), q, kl) for at, (q, kl) in zip(starts, spans)]
+    packed = -(-int(starts[-1]) // 8) * 8
+    rng = np.random.default_rng(len(path))
+    nb = len(rows) * EDGE_ENTRIES
+    tables = rng.permutation(nb).reshape(len(rows), -1).astype(np.int32)
+    for r, (_, _, kl) in enumerate(rows):
+        tables[r, -(-kl // BS):] = nb
+    pool, _, _ = _pool(rng, [kl for _, _, kl in rows], tables, nb=nb)
+    qs, ql, kl = (np.asarray(x, np.int32) for x in zip(*rows))
+    q_nope, q_pe = _rand(rng, packed, H, NOPE), _rand(rng, packed, H, ROPE)
+    w_kvb = _rand(rng, RANK, H * (NOPE + V)) * 0.3
+    got = _jitted(_absorbed, q_nope, q_pe, w_kvb, pool, tables, qs, ql, kl,
+                  pages=EDGE_PAGES)
+    want = _jitted(mla_ragged_attention_reference, q_nope, q_pe, w_kvb, pool,
+                   tables, qs, ql, kl, scale=MLA.scale, layer=1)
+    assert mla_mod.grid_params(EDGE_ENTRIES, H, packed, pages=EDGE_PAGES)[
+        "one_token"]
+    return np.asarray(got), np.asarray(want), rows
+
+
+@pytest.mark.parametrize("path", ["one_token", "span"])
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_a_pair_walks_its_groups_at_the_pipelines_edges(case, path,
+                                                        monkeypatch):
+    """Each row of ``_edge_walk`` against the oracle: a pair of fewer groups
+    than the walk starts ahead, of exactly as many, of one more and of
+    several rounds of the slots reads every key of its row once and none of
+    another's (the pool's other rows are noise)."""
+    monkeypatch.setattr(sys.modules[__name__], "H", 16)
+    got, want, rows = _edge_walk(path)
+    if case == 0:
+        # the dead row's pair walks nothing and no other pair missed it:
+        # rows outside every span are exact zeros
+        live = np.zeros(got.shape[0], bool)
+        for s, n, _ in rows:
+            live[s:s + n] = True
+        assert not got[~live].any() and (~live).any()
+        return
+    s, n, kl = rows[EDGE_CASES.index(case)]
+    assert -(-kl // (EDGE_PAGES * BS)) == edge_groups(case)
+    np.testing.assert_allclose(got[s:s + n], want[s:s + n], atol=2e-5,
+                               rtol=1e-4)
+    assert np.abs(got[s:s + n]).min() > 0
 
 
 def test_pool_holds_576_values_a_token_once():
