@@ -1,24 +1,17 @@
-"""CLI entry point: ``python -m paddle_tpu.profiler <trace>``.
+"""CLI entry point: ``python -m paddle_tpu.profiler <trace.json>``.
 
-Two trace formats, auto-detected by what the argument is:
+The argument is a Chrome trace-event JSON FILE, exactly what ``GET
+/debug/trace`` serves (README "Tracing & debugging"): per-lane span
+SELF-time summary through :mod:`paddle_tpu.profiler.chrometrace`, so a
+saved serving capture answers "where did the step go" without Perfetto.
 
-- a DIRECTORY: a ``jax.profiler`` (XPlane) trace dir — per-op time
-  aggregation through :mod:`paddle_tpu.profiler.xplane`;
-- a FILE: Chrome trace-event JSON, exactly what ``GET /debug/trace``
-  serves (README "Tracing & debugging") — per-lane span SELF-time
-  summary through :mod:`paddle_tpu.profiler.chrometrace`, so a saved
-  serving capture answers "where did the step go" without Perfetto.
-
-    python -m paddle_tpu.profiler /tmp/profile_dir            # op table
     python -m paddle_tpu.profiler trace.json --top 25         # span table
     python -m paddle_tpu.profiler trace.json --json           # machine-readable
 
-Device planes (the XLA op timeline) are summarized by default on the
-XPlane path; when a trace has none — CPU-backend traces put the ops on
-host planes — the CLI falls back to all planes automatically and says
-so (pass ``--all-planes`` to start there). Exit status: 0 when events
-were parsed, 1 on unparseable input (no *.xplane.pb, bad JSON, no
-traceEvents) so scripts can gate on it.
+A ``jax.profiler`` (XPlane) trace DIRECTORY is not read here: the
+benchmark's ``benchmark/xplane_reduce.py`` reduces one, offsets and all.
+Exit status: 0 when events were parsed, 1 on unparseable input (a
+directory, bad JSON, no traceEvents) so scripts can gate on it.
 """
 from __future__ import annotations
 
@@ -54,47 +47,21 @@ def _main_chrome(args):
 def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.profiler",
-        description="Per-op time aggregation over a jax.profiler "
-                    "(XPlane) trace directory, or per-lane span "
-                    "self-time over a Chrome trace-event JSON file "
-                    "(as served by GET /debug/trace).")
+        description="Per-lane span self-time over a Chrome trace-event "
+                    "JSON file (as served by GET /debug/trace).")
     ap.add_argument("trace_dir", metavar="trace",
-                    help="directory jax.profiler.start_trace wrote "
-                         "(searched recursively for *.xplane.pb), or a "
-                         "Chrome trace-event JSON file")
+                    help="a Chrome trace-event JSON file")
     ap.add_argument("--top", type=int, default=10,
                     help="rows to report (0 = all)")
     ap.add_argument("--json", action="store_true",
-                    help="emit the op table as JSON instead of text")
-    ap.add_argument("--all-planes", action="store_true",
-                    help="aggregate host planes too (XPlane dirs only; "
-                         "default: device planes, with automatic "
-                         "fallback when a trace has none)")
+                    help="emit the span table as JSON instead of text")
     args = ap.parse_args(argv)
 
-    if os.path.isfile(args.trace_dir):
-        # a file is the Chrome-trace path; directories stay XPlane
-        return _main_chrome(args)
-
-    from .xplane import op_statistics_with_fallback, summarize
-    device_only = not args.all_planes
-    if args.json:
-        rows, fell_back = op_statistics_with_fallback(
-            args.trace_dir, device_only=device_only, top=args.top)
-        print(json.dumps({"trace_dir": args.trace_dir,
-                          "device_only": device_only and not fell_back,
-                          "rows": rows}, indent=1))
-        return 0 if rows else 1
-    # text path: summarize owns the rendering AND the host-plane
-    # fallback, so the table format lives in exactly one place
-    out = summarize(args.trace_dir, top=args.top,
-                    device_only=device_only)
-    if out == "no device events parsed":
-        print("no events parsed (is this a jax.profiler trace "
-              "directory with *.xplane.pb files?)")
+    if os.path.isdir(args.trace_dir):
+        print(f"{args.trace_dir} is a directory: a jax.profiler (XPlane) "
+              f"trace is reduced by benchmark/xplane_reduce.py, not here")
         return 1
-    print(out)
-    return 0
+    return _main_chrome(args)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,8 @@
 """One persistent XLA compile cache, placed from outside.
 
 Every entry point that compiles for the chip (``python -m
-paddle_tpu.serving.server``, ``bench.py``'s children, ``chip_smoke.py``'s
-children) calls :func:`enable` once, before its first compilation. Where
+paddle_tpu.serving.server``, ``chip_smoke.py``'s children) calls
+:func:`enable` once, before its first compilation. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads the directory from
 it and this module sets no other; where it is not, the cache lives at
 ``<checkout>/.jax_cache``, a fixed path derived from this file's own (the

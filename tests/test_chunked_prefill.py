@@ -28,53 +28,31 @@ import time
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine, FIFOScheduler,
-                                GenerationRequest)
+from paddle_tpu.serving import FIFOScheduler, GenerationRequest
 
+import serving_support
+from serving_support import BS, clone as _clone, prompt as _prompt
 from test_metrics_prom import parse_prometheus
-
-BS = 8      # block size
-CHUNK = 16  # 2 blocks per chunk
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(21)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=21)  # GQA: nkv=2 < nh=4
 
 
 def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefix_cache", False)
-    kw.setdefault("prefill_chunk", CHUNK)
-    # fixed-cap chunk pacing: the step-count/offset pins below assume
-    # exactly CHUNK tokens per grant; the headroom-adaptive budget is
-    # wall-clock-fed (nondeterministic on a shared box) and is pinned
-    # separately in test_ragged_step.py with an injected clock
+    """The shared helper under fixed-cap chunk pacing: the step-count and
+    offset pins below assume exactly CHUNK tokens per grant; the
+    headroom-adaptive budget is wall-clock-fed (nondeterministic on a
+    shared box) and is pinned separately in test_ragged_step.py with an
+    injected clock."""
     kw.setdefault("headroom_mult", None)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.engine(model, **kw)
 
 
 def _req(ps, n=40, **kw):
     kw.setdefault("max_new_tokens", 6)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 def _run(model, reqs, **kw):
@@ -174,9 +152,7 @@ class TestCompileDiscipline:
         is warm a repeat wave adds ZERO prefill/suffix traces — chunk
         calls all land in the prefill_chunk (or remainder pow2)
         buckets."""
-        jit = {}
-        eng = _engine(model, jit_cache=jit, prefix_cache=True,
-                      num_slots=2)
+        eng = _engine(model, prefix_cache=True, num_slots=2)
         sysp = _prompt(60, 32)
 
         def wave(cancel_at=None):
@@ -209,13 +185,13 @@ class TestCompileDiscipline:
         """Prompts of many lengths chunk through ONE full-chunk bucket:
         the suffix compile count stays bounded by the pow2 grid, not by
         the number of distinct prompt lengths."""
-        jit = {}
-        eng = _engine(model, jit_cache=jit, max_seq_len=96)
+        eng = _engine(model, max_seq_len=96)
+        built = eng.prefill_compilations()     # by the module's other tests
         for i, n in enumerate((33, 41, 49, 57, 65, 73, 81, 89)):
             eng.generate([_req(70 + i, n=n, max_new_tokens=2)])
         # full chunks: one (G=1, 16) trace; remainders: pow2 buckets
         # {8, 16} at G=1 -> <= 3 suffix traces total for 8 lengths
-        assert eng.prefill_compilations() <= 3
+        assert eng.prefill_compilations() - built <= 3
         assert eng.decode_compilations() == 2
 
 

@@ -47,14 +47,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler.cost import CostObservatory, _CountedProgram
 from paddle_tpu.profiler.tracing import SpanTracer
-from paddle_tpu.serving import (ContinuousBatchingEngine, FaultPlan,
-                                GenerationRequest, VirtualClock)
+from paddle_tpu.serving import FaultPlan, GenerationRequest, VirtualClock
 from paddle_tpu.serving.server import ServingGateway, serve
 
+import serving_support
 from test_metrics_prom import parse_prometheus
 from test_tracing import _chaos_run, _chaos_workload
 
@@ -82,8 +80,17 @@ NUM_SLOTS, S_MAX = 2, 256
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(31)
-    return LlamaForCausalLM(llama_tiny())
+    return serving_support.model("llama", seed=31)
+
+
+def _engine(model, **kw):
+    """The shared helper at this file's geometry: 256 positions and
+    otherwise the ENGINE's own defaults, which is what the ``server``
+    fixture's ``serve()`` builds and what test_tracing's chaos workload,
+    replayed below, was sized for."""
+    kw.setdefault("num_slots", NUM_SLOTS)
+    kw.setdefault("max_seq_len", S_MAX)
+    return serving_support.engine_as_given(model, **kw)
 
 
 def _reqs(n=3, max_new=4, plen=8, long_prompt=False):
@@ -156,8 +163,7 @@ class TestCostObservatoryUnit:
         json.dumps(full)                       # JSON-serializable
 
     def test_disabled_handout_is_raw(self, model):
-        eng = ContinuousBatchingEngine(model, num_slots=NUM_SLOTS,
-                                       max_seq_len=S_MAX, jit_cache={})
+        eng = _engine(model)
         # no observatory / disabled observatory: the accessor hands out
         # the RAW jitted program — zero wrapper on the hot path
         assert not isinstance(eng._prefill_fn(), _CountedProgram)
@@ -218,11 +224,9 @@ class TestTierLedger:
                 reqs.append(GenerationRequest(
                     prompt=np.concatenate([fams[f], tail]),
                     max_new_tokens=3))
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefix_cache=True,
-            prefix_block_size=8, prefix_blocks=2,
-            host_tier_bytes=1 << 24, jit_cache={})
+        eng = _engine(model, decode_chunk=1, prefix_cache=True,
+                      prefix_block_size=8, prefix_blocks=2,
+                      host_tier_bytes=1 << 24)
         co = CostObservatory()
         eng.cost = co
         for r in reqs:     # serial: each publish thrashes the 2-block pool
@@ -251,11 +255,9 @@ class TestTierLedger:
         the tiers section without touching per-program columns."""
         fams = [np.random.RandomState(910 + f).randint(
             0, 256, (16,)).astype(np.int32) for f in range(2)]
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefix_cache=True,
-            prefix_block_size=8, prefix_blocks=2,
-            host_tier_bytes=1 << 24, jit_cache={})
+        eng = _engine(model, decode_chunk=1, prefix_cache=True,
+                      prefix_block_size=8, prefix_blocks=2,
+                      host_tier_bytes=1 << 24)
         gw = ServingGateway(eng, start=False)  # installs gw.cost on eng
         for i in range(3):
             for f in range(2):
@@ -297,10 +299,8 @@ class TestTierLedger:
         assert "bytes_per_decoded_token" in tiers["per_direction"]["d2h"]
 
     def test_tierless_gateway_scrapes_explicit_zeros(self, model):
-        gw = ServingGateway(ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefix_cache=True, prefix_block_size=8,
-            jit_cache={}), start=False)
+        gw = ServingGateway(_engine(model, decode_chunk=1, prefix_cache=True,
+                                    prefix_block_size=8), start=False)
         fams_p = parse_prometheus(gw.registry.render())
         s = fams_p["serving_tier_bytes_total"]["samples"]
         for tdir in ("d2h", "h2d", "peer"):
@@ -317,16 +317,13 @@ class TestTierLedger:
         host tier, zeroing their stats — the gateway banks the dead
         incarnation's tier counts (CARRIED_PREFIX_STATS) so the
         ``serving_prefix_*`` tier series stay monotonic."""
-        jit = {}
         fams = [np.random.RandomState(920 + f).randint(
             0, 256, (16,)).astype(np.int32) for f in range(2)]
 
         def factory():
-            return ContinuousBatchingEngine(
-                model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-                decode_chunk=1, prefix_cache=True,
-                prefix_block_size=8, prefix_blocks=2,
-                host_tier_bytes=1 << 24, jit_cache=jit)
+            return _engine(model, decode_chunk=1, prefix_cache=True,
+                           prefix_block_size=8, prefix_blocks=2,
+                           host_tier_bytes=1 << 24)
 
         plan = FaultPlan().at_step(8, "fatal")
         gw = ServingGateway(factory(), engine_factory=factory,
@@ -371,14 +368,9 @@ class TestExactAccounting:
     def test_counts_exact_streams_unchanged(self, model):
         reqs = _reqs(3, max_new=4, long_prompt=True)
         for name, cfg in self.CONFIGS:
-            jit = {}
-            base_eng = ContinuousBatchingEngine(
-                model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-                decode_chunk=1, jit_cache=jit, **cfg)
+            base_eng = _engine(model, decode_chunk=1, **cfg)
             base = [o.tolist() for o in base_eng.generate(reqs)]
-            eng = ContinuousBatchingEngine(
-                model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-                decode_chunk=1, jit_cache=jit, **cfg)
+            eng = _engine(model, decode_chunk=1, **cfg)
             co = CostObservatory()
             eng.cost = co
             accessor = _count_accessor_launches(eng)
@@ -407,10 +399,8 @@ class TestExactAccounting:
                                         "host-accept"}
 
     def test_launch_attribution_per_request(self, model):
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefill_chunk=32, prefix_block_size=8,
-            headroom_mult=None, jit_cache={})
+        eng = _engine(model, decode_chunk=1, prefill_chunk=32,
+                      prefix_block_size=8, headroom_mult=None)
         seqs = [eng.submit(r) for r in _reqs(2, max_new=4,
                                              long_prompt=True)]
         while eng.has_work():
@@ -426,9 +416,7 @@ class TestExactAccounting:
 class TestCounterTracks:
     def test_step_timeline_counter_events(self, model):
         tr = SpanTracer().enable()
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, jit_cache={})
+        eng = _engine(model, decode_chunk=1)
         eng.tracer = tr
         eng.cost = CostObservatory()
         eng.generate(_reqs(2, max_new=4))
@@ -462,9 +450,7 @@ class TestCounterTracks:
     def test_no_counters_without_cost_or_tracer(self, model):
         # tracer on, cost absent: spans yes, dispatch counters no
         tr = SpanTracer().enable()
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, jit_cache={})
+        eng = _engine(model, decode_chunk=1)
         eng.tracer = tr
         eng.generate(_reqs(1, max_new=2))
         names = {e["name"] for e in tr.events() if e["ph"] == "C"}
@@ -480,14 +466,13 @@ class TestCounterTracks:
 # ----------------------------------------------------- chaos determinism
 class TestChaosDeterminism:
     def test_cost_accounting_byte_identical_and_monotonic(self, model):
-        jit = {}
         reqs = _chaos_workload()
         # warm every program (recovery-path buckets included)
-        _chaos_run(model, jit, reqs, with_plan=True, trace=True)
+        _chaos_run(model, reqs, with_plan=True, trace=True)
         outs1, _, gw1, eng1, plan1 = _chaos_run(
-            model, jit, reqs, with_plan=True, trace=True)
+            model, reqs, with_plan=True, trace=True)
         outs2, _, gw2, eng2, plan2 = _chaos_run(
-            model, jit, reqs, with_plan=True, trace=True)
+            model, reqs, with_plan=True, trace=True)
         assert outs1 == outs2 and plan1.log == plan2.log
         # the accounting replays byte-identically under VirtualClock
         doc1 = json.dumps(gw1.profile_doc(), sort_keys=True)
@@ -511,10 +496,14 @@ class TestChaosDeterminism:
 
 # ------------------------------------------------------- gateway surface
 class TestGatewaySurface:
-    def test_metrics_families_and_values(self, model):
-        gw = ServingGateway(ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, jit_cache={}), start=False)
+    def test_metrics_families_and_values(self):
+        # programs of its own: the last assertion is that a COLD start's
+        # compiles are counted. On the jnp attention path, the cheapest
+        # step program there is to lower
+        model = serving_support.model("llama", seed=31,
+                                      decode_attention="jnp")
+        gw = ServingGateway(_engine(model, decode_chunk=1, jit_cache={}),
+                            start=False)
         streams = [gw.submit(r) for r in _reqs(3, max_new=4)]
         gw.start()
         for s in streams:
@@ -546,18 +535,13 @@ class TestGatewaySurface:
         """An adopted SHARED PrefixCache rides into every rebuilt
         engine with its stats intact — the rebuild carry must not bank
         them too (that would double hits/misses per restart)."""
-        jit = {}
-        seed_eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefix_cache=True, prefix_block_size=8,
-            jit_cache=jit)
+        seed_eng = _engine(model, decode_chunk=1, prefix_cache=True,
+                           prefix_block_size=8)
         shared = seed_eng.prefix_cache
 
         def factory():
-            return ContinuousBatchingEngine(
-                model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-                decode_chunk=1, prefix_cache=shared,
-                prefix_block_size=8, jit_cache=jit)
+            return _engine(model, decode_chunk=1, prefix_cache=shared,
+                           prefix_block_size=8)
 
         plan = FaultPlan().at_step(2, "fatal")
         gw = ServingGateway(factory(), engine_factory=factory,
@@ -578,15 +562,13 @@ class TestGatewaySurface:
         rebuild; every derived /metrics counter must carry a
         gateway-side base. A scrape thread samples the affected series
         THROUGH the fault matrix and each must be non-decreasing."""
-        jit = {}
         clk = VirtualClock()
 
         def factory():
-            return ContinuousBatchingEngine(
-                model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-                decode_chunk=1, prefix_cache=True, prefix_block_size=8,
-                prefill_chunk=32, spec_decode=True, spec_k=3,
-                headroom_mult=None, step_clock=clk, jit_cache=jit)
+            return _engine(model, decode_chunk=1, prefix_cache=True,
+                           prefix_block_size=8, prefill_chunk=32,
+                           spec_decode=True, spec_k=3, headroom_mult=None,
+                           step_clock=clk)
 
         plan = (FaultPlan(clock=clk)
                 .at_step(3, "fatal").at_step(7, "pool")
@@ -1115,9 +1097,7 @@ class TestProfilerCLIChrome:
     @pytest.fixture(scope="class")
     def trace_file(self, model, tmp_path_factory):
         tr = SpanTracer().enable()
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, jit_cache={})
+        eng = _engine(model, decode_chunk=1)
         eng.tracer = tr
         eng.cost = CostObservatory()
         eng.generate(_reqs(2, max_new=4))

@@ -13,12 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.kernels import moe_ffn as moe_mod
 from paddle_tpu.models import deepseek_v2 as dsv2
-from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
-                                           deepseek_v2_tiny)
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving import decode as decode_mod
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -26,16 +23,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(HERE), "benchmark"))
 sys.path.insert(0, HERE)
 import reference_deepseek_v2 as ref  # noqa: E402
 import reference_olmoe  # noqa: E402
-from test_olmoe_serving import (GEOMETRY, _prompt,  # noqa: E402
+import serving_support  # noqa: E402
+from test_olmoe_serving import (GEOMETRY, _engine, _prompt,  # noqa: E402
                                 _serve_recording_logits)
 
 TOLERANCE = 1e-4
 
 
-def _model(attention="jnp", seed=11, **kw):
-    paddle.seed(seed)
-    return DeepseekV2ForCausalLM(deepseek_v2_tiny(
-        decode_attention=attention, **kw))
+def _model(attention="jnp", fresh=False):
+    """The process's model; ``fresh`` one of its own for a test that reads
+    the routing record its step programs write on it."""
+    build = serving_support.fresh_model if fresh else serving_support.model
+    return build("deepseek_v2", seed=11, decode_attention=attention)
 
 
 def _reference_logits(model, prompt, tokens):
@@ -64,7 +63,7 @@ def test_engine_logits_equal_reference(case, monkeypatch):
     want = _reference_logits(model, prompt, tokens)
     assert np.abs(rows - want).max() / np.abs(want).max() <= TOLERANCE
     if n_prompt > GEOMETRY["prefill_chunk"]:
-        assert eng.prefill_compilations() == 0      # chunks only
+        assert eng.prefill_programs_asked == 0      # chunks only
         assert eng.stats["prefill_chunks"] == -(-n_prompt // 32)
     # the routing summary rode the fetches: picks over the router's width,
     # pairs only for those that landed on the held half
@@ -78,13 +77,14 @@ def test_engine_logits_equal_reference(case, monkeypatch):
 def test_wrong_scale_fails(monkeypatch):
     """A dropped ``mscale`` (the softmax scale without YaRN's factor) moves
     the logits far past the tolerance: the comparison above would fail."""
-    model = _model()
+    model = _model(fresh=True)
     real = type(model.config).mla
     monkeypatch.setattr(
         type(model.config), "mla", property(lambda c: real.fget(c)._replace(
             scale=c.head_dim ** -0.5)))
     prompt = _prompt(21)
-    _, tokens, rows = _serve_recording_logits(model, prompt, 4, monkeypatch)
+    _, tokens, rows = _serve_recording_logits(model, prompt, 4, monkeypatch,
+                                              fresh=True)
     want = _reference_logits(model, prompt, tokens)
     assert np.abs(rows - want).max() / np.abs(want).max() > 1e-3
 
@@ -94,7 +94,7 @@ def test_two_requests_of_unequal_length_in_one_step(attention="jnp"):
     unified step; every served token is the reference's best (the
     benchmark's rule, at float32's tolerance)."""
     model = _model(attention)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+    eng = _engine(model)
     prompts = [_prompt(9, seed=1), _prompt(70, seed=2)]
     seqs = [eng.submit(GenerationRequest(p, max_new_tokens=6))
             for p in prompts]
@@ -130,9 +130,9 @@ def test_served_picks_are_the_step_programs(n_prompt):
     chunks, and then the decode rows, picked, read back by position; the
     last sampled token is never fed back. In float32 they are the
     reference's own rule, and ``forward`` hands them on."""
-    model = _model()
+    model = _model(fresh=True)
     prompt, n_new = _prompt(n_prompt), 5
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+    eng = _engine(model)
     seq = eng.submit(GenerationRequest(prompt, max_new_tokens=n_new))
     while eng.has_work():
         eng.step()
@@ -165,7 +165,7 @@ def test_served_picks_through_the_http_server():
     import threading
     from paddle_tpu.serving.server import serve
     from test_olmoe_serving import _complete
-    model = _model()
+    model = _model(fresh=True)
     srv = serve(model, port=0, num_slots=2, max_seq_len=96, prefill_chunk=32)
     try:
         prompts = [_prompt(70, seed=3), _prompt(41, seed=4)]
@@ -206,9 +206,10 @@ def test_teacher_forced_reference_sees_the_held_experts(fault, monkeypatch):
     router, so the picks are the rule's): the reference, following the served
     picks, leaves the served logits by far more than the tolerance."""
     monkeypatch.setattr(decode_mod, "moe_ffn", globals()["_" + fault])
-    model = _model()
+    model = _model(fresh=True)
     prompt = _prompt(75)
-    _, tokens, rows = _serve_recording_logits(model, prompt, 4, monkeypatch)
+    _, tokens, rows = _serve_recording_logits(model, prompt, 4, monkeypatch,
+                                              fresh=True)
     want = _reference_logits(model, prompt, tokens)
     assert np.abs(rows - want).max() / np.abs(want).max() > 1e-2
 
@@ -216,7 +217,7 @@ def test_teacher_forced_reference_sees_the_held_experts(fault, monkeypatch):
 def test_reference_follows_the_picks_it_is_told():
     """Told other experts, the reference uses them, each at its own router's
     score; told nothing (-1), its own rule."""
-    model = _model()
+    model = _model(fresh=True)
     ids = np.random.RandomState(5).randint(1, 256, (1, 17))
     at = np.arange(17)[None]
     w, hy = ref.weights_of(model), ref.hyper_of(model.config)
@@ -359,6 +360,6 @@ SWITCHES = {
 def test_unsupported_switch_raises(switch):
     model = _model()
     with pytest.raises(ValueError) as e:
-        ContinuousBatchingEngine(model, **{**GEOMETRY, **SWITCHES[switch]})
+        _engine(model, **SWITCHES[switch])
     assert "DeepseekV2ForCausalLM" in str(e.value) \
         and switch.split(" fp8")[0] in str(e.value)

@@ -17,49 +17,28 @@ there is one program a packed size whether or not anything is in flight.
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.models.olmoe import OlmoeForCausalLM, olmoe_tiny
 from paddle_tpu.profiler.tracing import SpanTracer
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan, VirtualClock
 from paddle_tpu.serving.server.gateway import ServingGateway
 
+import serving_support
+from serving_support import drain as _run, engine as _engine
 from test_serving_oracle import served_equals_forward
-
-BS = 8          # KV block
-CHUNK = 16      # two blocks per prefill chunk
 
 
 @pytest.fixture(scope="module")
 def llama():
-    paddle.seed(28)
-    return LlamaForCausalLM(llama_tiny())
+    return serving_support.model("llama", seed=28)
 
 
 @pytest.fixture(scope="module")
 def olmoe():
-    paddle.seed(7)
-    return OlmoeForCausalLM(olmoe_tiny())
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_ahead_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
+    return serving_support.model("olmoe", seed=7)
 
 
 def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(1, 256, (n,)).astype(np.int32)
-
-
-def _run(eng):
-    while eng.has_work():
-        eng.step()
+    return serving_support.prompt(seed, n, low=1)
 
 
 def _alone(model, request, **kw):
@@ -261,13 +240,8 @@ def test_pool_exhausted_repair_with_a_step_in_flight(llama):
 def test_gateway_rebuild_with_a_step_in_flight(llama):
     want = [_alone(llama, r) for r in _pair()]
     clk = VirtualClock()
-    jit = llama.__dict__.setdefault("_ahead_jit", {})
-
     def factory():
-        return ContinuousBatchingEngine(
-            llama, num_slots=2, max_seq_len=96, decode_chunk=1,
-            prefix_block_size=BS, prefill_chunk=CHUNK, step_clock=clk,
-            jit_cache=jit)
+        return _engine(llama, step_clock=clk)
 
     first = factory()
     gw = ServingGateway(first, engine_factory=factory,
@@ -314,12 +288,8 @@ def test_fence_that_raises_at_a_drain_between_steps_is_supervised(llama,
     and the request that asked is still honoured."""
     reqs = _pair() + [GenerationRequest(_prompt(63, 11), max_new_tokens=14)]
     want = [_alone(llama, r, num_slots=3) for r in reqs]
-    jit = llama.__dict__.setdefault("_ahead_jit", {})
-
     def factory():
-        return ContinuousBatchingEngine(
-            llama, num_slots=3, max_seq_len=96, decode_chunk=1,
-            prefix_block_size=BS, prefill_chunk=CHUNK, jit_cache=jit)
+        return _engine(llama, num_slots=3)
 
     first = factory()
     gw = ServingGateway(first, engine_factory=factory, retry_backoff_s=0.0,
@@ -518,7 +488,7 @@ def test_dispatch_j_precedes_the_first_read_of_j_minus_1(llama):
 
 # --------------------------------------------------------- (h) one program
 def test_one_program_with_and_without_a_drain(llama):
-    eng = _engine(llama, jit_cache={})
+    eng = _engine(llama)
     eng.generate([GenerationRequest(_prompt(90, 20), max_new_tokens=2)])
     warm = eng.decode_compilations()
     assert warm == 2        # its chunk steps' size, its decode step's size

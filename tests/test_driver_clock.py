@@ -20,14 +20,13 @@ import time
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler.driver_clock import PHASES, DriverClock
 from paddle_tpu.profiler.tracing import TID_GATEWAY, SpanTracer
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan, VirtualClock
 from paddle_tpu.serving.server.gateway import ServingGateway
 
+import serving_support
 from test_metrics_prom import parse_prometheus
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -53,15 +52,15 @@ class TickingClock(VirtualClock):
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(35)
-    return LlamaForCausalLM(llama_tiny())
+    return serving_support.model("llama", seed=35)
 
 
 def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_clock_jit", {}))
-    return ContinuousBatchingEngine(
-        model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1,
-        prefill_chunk=CHUNK, prefix_block_size=8, **kw)
+    """The shared helper at this file's geometry: the phases' sums below
+    are reckoned for three slots, 128 positions and chunks of 32."""
+    return serving_support.engine(
+        model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, prefill_chunk=CHUNK,
+        **kw)
 
 
 def _reqs():

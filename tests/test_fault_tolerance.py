@@ -31,60 +31,37 @@ import urllib.request
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                FINISH_REASONS, FatalFault, FaultPlan,
-                                GenerationRequest, PagedKVCache,
+from paddle_tpu.serving import (BlockManager, FINISH_REASONS, FatalFault,
+                                FaultPlan, GenerationRequest, PagedKVCache,
                                 PoolExhausted, VirtualClock)
 from paddle_tpu.serving.server import ServingGateway, serve
 
+import serving_support
+from serving_support import (BS, CHUNK, S_MAX, SLOTS, clone as _clone,
+                             drain as _drive, prompt as _prompt, wait_until)
 from test_metrics_prom import parse_prometheus
-
-BS = 8       # KV block size
-CHUNK = 16   # chunked-prefill budget (2 blocks)
-SLOTS = 2
-S_MAX = 96
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA tiny, pallas decode
+    return serving_support.model("llama", seed=33)  # GQA, pallas decode
 
 
-def _mk_factory(model, jit_cache=None, **kw):
+def _mk_factory(model, **kw):
     """An engine factory with the fixed test geometry — the SAME
     factory builds the first engine and every recovery rebuild, sharing
-    one jit cache, exactly like ``serve()`` wires it."""
-    cache = jit_cache if jit_cache is not None else \
-        model.__dict__.setdefault("_serving_jit", {})
-    kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("max_seq_len", S_MAX)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
+    one jit cache (the support module's, unless given), exactly like
+    ``serve()`` wires it."""
     kw.setdefault("prefix_cache", True)
 
     def factory():
-        return ContinuousBatchingEngine(model, jit_cache=cache, **kw)
+        return serving_support.engine(model, **kw)
     return factory
-
-
-def _prompt(seed, n=12):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
 
 
 def _req(ps, n=12, **kw):
     kw.setdefault("max_new_tokens", 8)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 #: the standard mixed workload: greedy shorts, one seeded-sampled row,
@@ -99,11 +76,6 @@ def _baseline(model, reqs, **kw):
     """Fault-free oracle streams for the same requests."""
     eng = _mk_factory(model, **kw)()
     return [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
-
-
-def _drive(eng):
-    while eng.has_work():
-        eng.step()
 
 
 class TestPoolExhausted:
@@ -194,9 +166,8 @@ class TestEngineRestore:
         snapshot) continue byte-identically — greedy AND seeded-sampled
         — with no token replayed and no retrace."""
         reqs = _traffic()
-        jit = {}
-        want = _baseline(model, reqs, jit_cache=jit)
-        factory = _mk_factory(model, jit_cache=jit)
+        want = _baseline(model, reqs)
+        factory = _mk_factory(model)
         eng = factory()
         seqs = [eng.submit(_clone(r)) for r in reqs]
         emitted = {s.request_id: [] for s in seqs}
@@ -319,13 +290,6 @@ class TestEngineRestore:
         assert eng.stats["prefill_tokens_saved"] - saved0 >= 40
 
 
-def _await(pred, timeout=30.0):
-    deadline = time.monotonic() + timeout
-    while not pred() and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert pred(), "condition not reached before timeout"
-
-
 def _gateway(model, plan, jit_cache=None, **kw):
     """A supervised gateway wired exactly like serve() does it — one
     factory for the first engine and every rebuild — but NOT started,
@@ -376,10 +340,9 @@ class TestSupervisedDriver:
         byte-identically, with decode_compilations() still 1 on the
         rebuilt engine (shared jit cache: no recompile storm)."""
         reqs = _traffic()
-        jit = {}
-        want = _baseline(model, reqs, jit_cache=jit)
+        want = _baseline(model, reqs)
         plan = FaultPlan().at_step(3, "fatal")
-        gw = _gateway(model, plan, jit_cache=jit)
+        gw = _gateway(model, plan)
         streams = [gw.submit(_clone(r)) for r in reqs]
         gw.start()
         outs = [st.result() for st in streams]
@@ -433,7 +396,10 @@ class TestSupervisedDriver:
         clk = VirtualClock()
         plan = (FaultPlan(clock=clk).at_step(0, "hung", stall_s=99.0)
                 .at_step(5, "hung", stall_s=99.0))
-        gw = _gateway(model, plan, jit_cache={}, watchdog_deadline_s=5.0,
+        # programs of its own: a COLD step is what is exempted. On the jnp
+        # attention path, the cheapest step program there is to lower
+        cold = serving_support.model("llama", seed=33, decode_attention="jnp")
+        gw = _gateway(cold, plan, jit_cache={}, watchdog_deadline_s=5.0,
                       clock=clk)
         streams = [gw.submit(_clone(r)) for r in _traffic()]
         gw.start()
@@ -498,7 +464,7 @@ class TestPoisonQuarantine:
         assert gw.restarts >= 2           # fault recurred, then isolated
         # quarantine drained: nothing parked, nothing suspect
         assert not gw._parked and gw._suspect_ids is None
-        _await(lambda: gw.health_state == "ok")
+        wait_until(lambda: gw.health_state == "ok")
         gw.shutdown(drain=True, timeout=30)
 
     def test_cancel_during_recovery_is_honored(self, model):
@@ -512,7 +478,7 @@ class TestPoisonQuarantine:
         victim = gw.submit(_req(60, n=8, max_new_tokens=60))
         bad = gw.submit(_req(61, n=13, max_new_tokens=60))
         gw.start()
-        _await(lambda: gw.restarts >= 1)
+        wait_until(lambda: gw.restarts >= 1)
         victim.cancel()
         ids, reason = victim.result()
         assert reason in ("cancelled", "length")
@@ -521,7 +487,7 @@ class TestPoisonQuarantine:
             bad.result()
         except RuntimeError:
             pass
-        _await(lambda: gw.engine.cache.num_free == SLOTS)
+        wait_until(lambda: gw.engine.cache.num_free == SLOTS)
         gw.shutdown(drain=True, timeout=30)
 
 

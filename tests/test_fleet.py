@@ -27,49 +27,31 @@ The acceptance matrix:
   ``/healthz``.
 """
 import json
-import time
 import urllib.error
 import urllib.request
 
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine, FaultPlan,
-                                GenerationRequest, VirtualClock)
+from paddle_tpu.serving import FaultPlan, GenerationRequest, VirtualClock
 from paddle_tpu.serving.fleet import (EngineFleet, LeastLoadedRouter,
                                       PrefixAffinityRouter,
                                       RoundRobinRouter, make_router)
 
+import serving_support
+from serving_support import (BS, CHUNK, S_MAX, SLOTS, clone as _clone,
+                             prompt as _prompt, wait_until as _await)
 from test_metrics_prom import parse_prometheus
-
-BS = 8       # KV block size
-CHUNK = 16   # chunked-prefill budget (2 blocks)
-SLOTS = 2    # per replica
-S_MAX = 96
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())
-
-
-def _prompt(seed, n=12):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33)
 
 
 def _req(ps, n=12, **kw):
     kw.setdefault("max_new_tokens", 8)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 #: the standard mixed workload: greedy shorts, one seeded-sampled row,
@@ -82,10 +64,8 @@ def _traffic():
 
 def _baseline(model, reqs, num_slots=SLOTS):
     """Fault-free single-engine oracle streams for the same requests."""
-    eng = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_seq_len=S_MAX, decode_chunk=1,
-        prefix_cache=True, prefix_block_size=BS, prefill_chunk=CHUNK,
-        jit_cache=model.__dict__.setdefault("_serving_jit", {}))
+    eng = serving_support.engine(model, num_slots=num_slots,
+                                 prefix_cache=True)
     return [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
 
 
@@ -100,13 +80,6 @@ def _fleet(model, **kw):
     kw.setdefault("retry_backoff_s", 0.0)
     kw.setdefault("start", False)
     return EngineFleet(model, **kw)
-
-
-def _await(pred, timeout=30.0):
-    deadline = time.monotonic() + timeout
-    while not pred() and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert pred(), "condition not reached before timeout"
 
 
 # ----------------------------------------------------------- router units

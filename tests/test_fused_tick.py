@@ -21,27 +21,22 @@ reduce-scatter/all-gather schedule. The load-bearing properties:
   payload — ``serving_collective_bytes_total{dtype}`` stays exact to
   the byte in both wire dtypes.
 """
-import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler.cost import CostObservatory
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.server.gateway import ServingGateway
 
-BS = 8      # block size
-CHUNK = 16  # 2 blocks per chunk
-SLOTS = 2
-S_MAX = 96
+import serving_support
+from serving_support import (BS, CHUNK, S_MAX, SLOTS, clone as _clone,
+                             engine as _engine, prompt as _prompt)
 
 
 @pytest.fixture(scope="module")
 def model():
     # llama_tiny defaults decode_attention="pallas": fused_tick takes
     # the TRUE mega-kernel path (single pallas_call, interpret on CPU)
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=33)  # GQA: nkv=2 < nh=4
 
 
 @pytest.fixture(scope="module")
@@ -49,39 +44,12 @@ def jnp_model():
     # the jnp-attention oracle route: fused_decode_tick dispatches to
     # the reference replay, byte-identical by construction — pinned
     # here so BOTH dispatch arms stay covered
-    paddle.seed(33)
-    cfg = llama_tiny()
-    cfg.decode_attention = "jnp"
-    return LlamaForCausalLM(cfg)
-
-
-def _jit(model, tag):
-    return model.__dict__.setdefault(f"_serving_jit_fused_{tag}", {})
-
-
-def _engine(model, **kw):
-    kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("max_seq_len", S_MAX)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33, decode_attention="jnp")
 
 
 def _req(ps, n=12, **kw):
     kw.setdefault("max_new_tokens", 5)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 #: the hit/miss/chunked matrix: greedy shorts, a seeded-sampled row,
@@ -92,35 +60,50 @@ def _traffic():
             _req(4, n=40, max_new_tokens=4)]
 
 
-def _run_matrix(model, jit, **kw):
+#: the rows of ``_run_matrix``'s result, in order
+MATRIX_ROWS = [f"{p}-{r}" for p in ("cold", "hit")
+               for r in ("greedy", "greedy10", "sampled", "chunked")]
+
+
+def _run_matrix(model, **kw):
     """Two passes of the traffic (pass 2 = trie hits on pass 1's
     donated chains) through one engine; returns (streams, engine)."""
-    eng = _engine(model, prefix_cache=True, jit_cache=jit, **kw)
+    eng = _engine(model, prefix_cache=True, **kw)
     outs = [o.tolist() for o in eng.generate(_traffic())]
     outs += [o.tolist() for o in
              eng.generate([_clone(r) for r in _traffic()])]
     return outs, eng
 
 
-def _run_once(model, jit, **kw):
+def _run_once(model, **kw):
     """One cold pass of the traffic; returns (streams, engine)."""
-    eng = _engine(model, prefix_cache=True, jit_cache=jit, **kw)
+    eng = _engine(model, prefix_cache=True, **kw)
     return [o.tolist() for o in eng.generate(_traffic())], eng
 
 
 # ----------------------------------------------------------- transparency
+@pytest.fixture(scope="module")
+def fused_matrix(model):
+    """The scanned and the fused engine after the matrix each, run once for
+    the module: ``(base streams, scanned engine, fused streams, fused
+    engine)``."""
+    return _run_matrix(model) + _run_matrix(model, fused_tick=True)
+
+
 class TestFusedByteIdentity:
-    def test_fused_matrix_byte_identical_and_compile_once(self, model):
+    @pytest.mark.parametrize("row", MATRIX_ROWS)
+    def test_fused_matrix_byte_identical_and_compile_once(self, fused_matrix,
+                                                          row):
         """THE tentpole pin: the single-pallas_call fused tick streams
         byte-for-byte equal to the scanned baseline — greedy AND
-        seeded-sampled, cold/hit/chunked — with
+        seeded-sampled, cold/hit/chunked, a case a row — with
         ``decode_compilations() == 1`` on BOTH engines (one shared jit
         cache; the fk tag keys the fused trace apart, so neither
         engine's pin sees the other's programs)."""
-        jit = _jit(model, "fp")
-        base, e1 = _run_matrix(model, jit)
-        fused, e2 = _run_matrix(model, jit, fused_tick=True)
-        assert fused == base
+        base, e1, fused, e2 = fused_matrix
+        i = MATRIX_ROWS.index(row)
+        assert len(fused) == len(base) == len(MATRIX_ROWS)
+        assert fused[i] == base[i]
         assert e1.decode_compilations() == 2
         assert e2.decode_compilations() == 2
         assert e2.prefill_compilations() >= 1
@@ -133,9 +116,8 @@ class TestFusedByteIdentity:
         """decode_attention="jnp" routes fused_decode_tick to the
         reference replay (the oracle arm): streams still equal the
         scanned baseline and the compile pin still holds."""
-        jit = _jit(jnp_model, "jnp")
-        base, _ = _run_once(jnp_model, jit)
-        fused, e2 = _run_once(jnp_model, jit, fused_tick=True)
+        base, _ = _run_once(jnp_model)
+        fused, e2 = _run_once(jnp_model, fused_tick=True)
         assert fused == base
         assert e2.decode_compilations() == 1
 
@@ -143,9 +125,8 @@ class TestFusedByteIdentity:
         """The fused program slots into the multi-tick while body:
         fused x decode_ticks=4 equals scanned x decode_ticks=4 (which
         is itself pinned equal to single-tick)."""
-        jit = _jit(model, "fp")
-        base, _ = _run_once(model, jit, decode_ticks=4)
-        fused, e2 = _run_once(model, jit, decode_ticks=4,
+        base, _ = _run_once(model, decode_ticks=4)
+        fused, e2 = _run_once(model, decode_ticks=4,
                               fused_tick=True)
         assert fused == base
         assert e2.decode_compilations() == 1
@@ -157,9 +138,8 @@ class TestFusedByteIdentity:
         """fp8 KV dequantizes IN-KERNEL on the fused path (no
         host-side dequant launch): streams equal the scanned fp8-KV
         engine, compile-once inclusive of kv8f + fk."""
-        jit = _jit(model, "kv8f")
-        base, _ = _run_matrix(model, jit, kv_dtype="fp8")
-        fused, e2 = _run_matrix(model, jit, kv_dtype="fp8",
+        base, _ = _run_matrix(model, kv_dtype="fp8")
+        fused, e2 = _run_matrix(model, kv_dtype="fp8",
                                 fused_tick=True)
         assert fused == base
         assert e2.decode_compilations() == 1
@@ -167,9 +147,8 @@ class TestFusedByteIdentity:
     @pytest.mark.slow  # 12 s matrix duplicate: the fp8 rep above runs
     # by default (870s cap); int8 adds the scale-plane dequant arm
     def test_fused_int8_kv_byte_identical(self, model):
-        jit = _jit(model, "kv8")
-        base, _ = _run_matrix(model, jit, kv_dtype="int8")
-        fused, e2 = _run_matrix(model, jit, kv_dtype="int8",
+        base, _ = _run_matrix(model, kv_dtype="int8")
+        fused, e2 = _run_matrix(model, kv_dtype="int8",
                                 fused_tick=True)
         assert fused == base
         assert e2.decode_compilations() == 1
@@ -180,9 +159,8 @@ class TestFusedByteIdentity:
         """Sharded fused engine (the TP oracle route — in-kernel
         collectives are the remote-DMA follow-on) equals the TP=1
         scanned baseline."""
-        jit = _jit(model, "fp")
-        base, _ = _run_matrix(model, jit)
-        tp2, e2 = _run_matrix(model, jit, tp=2, fused_tick=True)
+        base, _ = _run_matrix(model)
+        tp2, e2 = _run_matrix(model, tp=2, fused_tick=True)
         assert tp2 == base
         assert e2.decode_compilations() == 1
 
@@ -191,12 +169,11 @@ class TestFusedByteIdentity:
         donates to the trie, recompute readmits as a trie hit through
         the fused program, and the continuation equals the
         uninterrupted scanned baseline."""
-        jit = _jit(model, "fp")
         reqs = _traffic()
         base = [o.tolist() for o in
-                _engine(model, prefix_cache=True, jit_cache=jit)
+                _engine(model, prefix_cache=True)
                 .generate([_clone(r) for r in reqs])]
-        eng = _engine(model, prefix_cache=True, jit_cache=jit,
+        eng = _engine(model, prefix_cache=True,
                       fused_tick=True)
         seqs = [eng.submit(_clone(r)) for r in reqs]
         for _ in range(3):
@@ -227,14 +204,13 @@ class TestCollectiveOverlap:
         proves the schedule really changed — the overlapped decode
         program carries MORE collective eqns (chunked ppermute
         reduce-scatter/all-gather) than the plain all-reduce pair."""
-        jit = _jit(model, f"ovl_{dtype}")
-        base, _ = _run_once(model, jit)
+        base, _ = _run_once(model)
         co_p, co_o = CostObservatory(), CostObservatory()
-        e_p = _engine(model, prefix_cache=True, jit_cache=jit, tp=2,
+        e_p = _engine(model, prefix_cache=True, tp=2,
                       collective_dtype=dtype)
         e_p.cost = co_p
         plain = [o.tolist() for o in e_p.generate(_traffic())]
-        e_o = _engine(model, prefix_cache=True, jit_cache=jit, tp=2,
+        e_o = _engine(model, prefix_cache=True, tp=2,
                       collective_dtype=dtype, collective_overlap=True)
         e_o.cost = co_o
         over = [o.tolist() for o in e_o.generate(_traffic())]
@@ -263,9 +239,8 @@ class TestCollectiveOverlap:
         decode_ticks=4 streams equal the scanned single-chip
         decode_ticks=4 baseline, compile-once inclusive of the
         (tp2, dtype, ov) + fk key tail."""
-        jit = _jit(model, "stack")
-        base, _ = _run_once(model, jit, decode_ticks=4)
-        full, e2 = _run_once(model, jit, decode_ticks=4, tp=2,
+        base, _ = _run_once(model, decode_ticks=4)
+        full, e2 = _run_once(model, decode_ticks=4, tp=2,
                              fused_tick=True, collective_overlap=True)
         assert full == base
         assert e2.decode_compilations() == 1
@@ -288,10 +263,9 @@ class TestLaunchCensus:
             assert keys, (frag, list(cs))
             return cs[keys[0]]
 
-        jit = _jit(model, "census")
         co_s, co_f = CostObservatory(), CostObservatory()
         for co, kw in ((co_s, {}), (co_f, dict(fused_tick=True))):
-            eng = _engine(model, jit_cache=jit, decode_ticks=4, **kw)
+            eng = _engine(model, decode_ticks=4, **kw)
             eng.cost = co
             eng.generate([_req(17, max_new_tokens=6)])
             # export surfaces the census on the program entry — the
@@ -307,9 +281,8 @@ class TestLaunchCensus:
     def test_profile_doc_surfaces_census(self, model):
         """A gateway-owned observatory flows the census into
         ``/debug/profile``: program entries carry the launch counts."""
-        jit = _jit(model, "fp")
         gw = ServingGateway(
-            _engine(model, prefix_cache=True, jit_cache=jit,
+            _engine(model, prefix_cache=True,
                     fused_tick=True),
             max_queue=8, start=False)
         st = gw.submit(_req(19))
@@ -334,7 +307,7 @@ class TestJitKeysAndValidation:
         tpN) and the ov marker rides the tp tag — while knobs-off keys
         stay byte-identical to the pre-fused spelling (banked baselines
         can't have drifted)."""
-        jit = {}
+        jit = {}    # its own: the assertions are on what each engine ADDS
         e1 = _engine(model, jit_cache=jit)
         e1.generate([_req(11, max_new_tokens=2)])
         keys1 = set(jit)
@@ -345,8 +318,7 @@ class TestJitKeysAndValidation:
         assert keys2 and all(k[-1] == "fk" for k in keys2)
         assert e1.decode_compilations() == 1
         assert e2.decode_compilations() == 1
-        e3 = _engine(model, jit_cache=jit, tp=2,
-                     collective_overlap=True)
+        e3 = _engine(model, jit_cache=jit, tp=2, collective_overlap=True)
         e3.generate([_req(11, max_new_tokens=2)])
         keys3 = set(jit) - keys1 - keys2
         assert keys3
@@ -360,13 +332,11 @@ class TestJitKeysAndValidation:
         """The acceptance's hardest compile pin: fk x tp2 x kv8f and
         fk x tp2 x w8+a8 each trace their decode program exactly
         once."""
-        e1 = _engine(model, jit_cache=_jit(model, "fk_kv8f"), tp=2,
-                     kv_dtype="fp8", fused_tick=True)
+        e1 = _engine(model, tp=2, kv_dtype="fp8", fused_tick=True)
         e1.generate([_req(21, max_new_tokens=3)])
         assert e1.decode_compilations() == 1
-        e2 = _engine(model, jit_cache=_jit(model, "fk_a8"), tp=2,
-                     quantize_weights=True, quantize_activations=True,
-                     fused_tick=True)
+        e2 = _engine(model, tp=2, quantize_weights=True,
+                     quantize_activations=True, fused_tick=True)
         e2.generate([_req(22, max_new_tokens=3)])
         assert e2.decode_compilations() == 1
 
@@ -402,12 +372,15 @@ class TestJitKeysAndValidation:
         """(fused_tick, collective_overlap) join the fleet geometry
         tuple — same memory-note discipline as the tp/kv8 tags."""
         from paddle_tpu.serving.fleet import EngineFleet
-        model.__dict__.pop("_serving_jit_fleet", None)
+        # the model is the process's: other files' fleets hang their
+        # programs on it, so read what this fleet adds and pop nothing
+        jits = model.__dict__.setdefault("_serving_jit_fleet", {})
+        before = set(jits)
         fleet = EngineFleet(model, replicas=1, num_slots=SLOTS,
                             max_seq_len=S_MAX, prefill_chunk=CHUNK,
                             prefix_block_size=BS, fused_tick=True,
                             start=False)
-        (geom,) = model.__dict__["_serving_jit_fleet"].keys()
+        (geom,) = set(jits) - before
         assert geom[-2:] == (True, False)
         eng = fleet.replicas[0].gateway.engine
         assert eng.fused_tick is True and eng.collective_overlap is False

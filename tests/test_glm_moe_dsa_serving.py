@@ -16,14 +16,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.kernels import moe_ffn as moe_mod
 from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
-                                           GlmMoeDsaForCausalLM,
                                            glm_moe_dsa_tiny,
                                            published_indexer_types)
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving import decode as decode_mod
+
+import serving_support
+from serving_support import token_list as _prompt
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(HERE), "benchmark"))
@@ -58,18 +59,19 @@ def recorded_logits():
 
 @functools.lru_cache(maxsize=None)
 def _model(attention="jnp", **config):
-    """One model a path for the whole module (building one compiles)."""
-    paddle.seed(11)
-    return GlmMoeDsaForCausalLM(glm_moe_dsa_tiny(
-        decode_attention=attention, **config))
+    """One model a path for the whole module (building one compiles), and
+    the module's own: what it hangs on its models is traced with the
+    recorder inside."""
+    return serving_support.fresh_model("glm_moe_dsa", seed=11,
+                                       decode_attention=attention, **config)
 
 
-def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(1, 256, n).tolist()
-
-
-def _engine(model):
-    return ContinuousBatchingEngine(model, jit_cache=JIT, **GEOMETRY)
+def _engine(model, jit_cache=JIT):
+    """The shared helper at this file's geometry, on the module's recorded
+    programs (``JIT``), which nobody else may run."""
+    return serving_support.watch_prefill_programs(
+        serving_support.engine_as_given(model, jit_cache=jit_cache,
+                                        **GEOMETRY))
 
 
 def _serve_one(model, prompt, n_new, eng=None):
@@ -121,7 +123,7 @@ def test_engine_logits_equal_reference(case):
     if case.endswith("16_index_heads"):
         # (other shapes than the module's programs: a cache of its own)
         model = _model(attention, index_n_heads=16)
-        eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+        eng = _engine(model, jit_cache={})
         assert eng._dispatch_args([0], [1], [41], 8, 1, 1, 0)[
             "index_one_token_rows"] == 2
     else:
@@ -130,7 +132,7 @@ def test_engine_logits_equal_reference(case):
     want = _reference_logits(model, prompt, tokens)
     assert np.abs(rows - want).max() / np.abs(want).max() <= TOLERANCE
     if n_prompt > GEOMETRY["prefill_chunk"]:
-        assert eng.prefill_compilations() == 0      # chunks only
+        assert eng.prefill_programs_asked == 0      # chunks only
         assert eng.stats["prefill_chunks"] == -(-n_prompt // 32)
     c = model.config
     assert eng.stats["moe_layer_calls"] % (
@@ -341,7 +343,7 @@ SWITCHES = {
 @pytest.mark.parametrize("switch", sorted(SWITCHES))
 def test_unsupported_switch_raises(switch):
     with pytest.raises(ValueError) as e:
-        ContinuousBatchingEngine(_model(), **{**GEOMETRY,
-                                              **SWITCHES[switch]})
+        serving_support.engine_as_given(_model(), **{**GEOMETRY,
+                                                     **SWITCHES[switch]})
     assert "GlmMoeDsaForCausalLM" in str(e.value) \
         and "idx_layer" in str(e.value) and switch in str(e.value)

@@ -12,87 +12,32 @@
 - **Compile discipline**: ``decode_compilations() == 1`` inclusive of
   the quantized geometry, with fp32/int8/weight-quantized engines
   sharing ONE jit cache (the variant tags key their traces apart).
-- **Transparency of the step machinery**: speculative decode and
-  multi-tick decode on int8 KV are byte-identical to their own
-  tick-at-a-time quantized baselines; the chaos fault matrix loses
-  nothing and replays deterministically.
+- **Transparency of the step machinery** and the blocks' lifecycle
+  (``tests/test_kv_quant_lifecycle.py``, a file of its own so that no
+  file is the floor under the suite's wall, ROADMAP D6: its programs are
+  other programs than these): speculative decode and multi-tick decode on
+  int8 KV are byte-identical to their own tick-at-a-time quantized
+  baselines; the chaos fault matrix loses nothing and replays
+  deterministically.
 """
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                GenerationRequest)
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan
 from paddle_tpu.serving.kv_cache import PagedKVCache, quantize_kv_rows
 from paddle_tpu.serving.server.gateway import ServingGateway
 
-from test_metrics_prom import parse_prometheus
-
-BS = 8      # block size
-CHUNK = 16  # 2 blocks per chunk
+import serving_support
+from serving_support import (BS, CHUNK, clone as _clone, engine as _engine,
+                             match_fraction as _match_fraction,
+                             mixed_reqs as _reqs, prompt as _prompt,
+                             run as _run)
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
-
-
-def _reqs(sampled=False, n_reqs=4, max_new=8):
-    """Mixed trace: two shared system prompts with unique tails (trie
-    traffic) + repetition so the n-gram drafter has something to hit."""
-    sys_p = [_prompt(100 + i, 24) for i in range(2)]
-    out = []
-    for i in range(n_reqs):
-        tail = np.tile(_prompt(i, 4), 3).astype(np.int32)
-        kw = dict(max_new_tokens=max_new)
-        if sampled:
-            kw.update(temperature=0.8, top_k=20, seed=500 + i)
-        out.append(GenerationRequest(
-            prompt=np.concatenate([sys_p[i % 2], tail]), **kw))
-    return out
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             seed=r.seed, eos_token_id=r.eos_token_id)
-
-
-def _run(eng, reqs):
-    return [list(o) for o in eng.generate([_clone(r) for r in reqs])]
-
-
-def _match_fraction(a, b):
-    """Mean matched-prefix fraction across paired streams — the
-    measured (not assumed) divergence statistic the density bench
-    banks."""
-    fracs = []
-    for x, y in zip(a, b):
-        m = 0
-        for t, u in zip(x, y):
-            if t != u:
-                break
-            m += 1
-        fracs.append(m / max(len(x), 1))
-    return sum(fracs) / len(fracs)
+    return serving_support.model("llama", seed=33)  # GQA: nkv=2 < nh=4
 
 
 # ------------------------------------------------------------ unit: rows
@@ -225,119 +170,19 @@ class TestStreams:
         assert before == after
 
 
-# ---------------------------------------------- lifecycle carries scales
-class TestLifecycleCarriesScales:
-    def test_trie_hit_zero_copy_and_scale_plane_identity(self, model):
-        eng = _engine(model, kv_dtype="int8", prefix_cache=True)
-        p = _prompt(7, 32)                  # 4 whole blocks
-        r = GenerationRequest(prompt=p, max_new_tokens=4)
-        first = list(eng.generate([r])[0])
-        matched = eng.prefix_cache.lookup(p)
-        assert matched, "retirement should have donated the chain"
-        blocks = [n.block_id for n in matched]
-        ks_before = np.asarray(eng.cache.pool.k_scale)[:, blocks].copy()
-        vs_before = np.asarray(eng.cache.pool.v_scale)[:, blocks].copy()
-        second = list(eng.generate([GenerationRequest(
-            prompt=p, max_new_tokens=4)])[0])
-        assert eng.prefix_cache.stats["hits"] >= 1
-        assert second == first              # hit ≡ cold, quantized
-        # the donated blocks' scale planes were READ, never rewritten:
-        # scale identity is what makes zero-copy hits exact on int8
-        np.testing.assert_array_equal(
-            np.asarray(eng.cache.pool.k_scale)[:, blocks], ks_before)
-        np.testing.assert_array_equal(
-            np.asarray(eng.cache.pool.v_scale)[:, blocks], vs_before)
-
-    def test_spec_truncate_restores_num_free_exactly(self, model):
-        eng = _engine(model, kv_dtype="int8", spec_decode=True,
-                      spec_k=3)
-        free0 = eng.cache.pool.num_free
-        outs = _run(eng, _reqs())
-        assert all(len(s) == 8 for s in outs)
-        # every slot retired; with no trie, every draft-rejected and
-        # private block went back to the heap exactly once
-        assert eng.cache.pool.num_free == free0
-        assert eng.cache.num_free == eng.num_slots
-
-    def test_preempt_restore_byte_identical_on_int8(self, model):
-        want = _run(_engine(model, kv_dtype="int8",
-                            prefix_cache=True), _reqs())
-        eng = _engine(model, kv_dtype="int8", prefix_cache=True)
-        FaultPlan().at_step(3, "pool").install(eng)
-        got = _run(eng, _reqs())
-        assert eng.stats["preemptions"] >= 1
-        assert eng.stats["restores"] >= 1
-        assert got == want
-
-    def test_cancel_mid_decode_restores_pool(self, model):
-        eng = _engine(model, kv_dtype="int8")
-        free0 = eng.cache.pool.num_free
-        seqs = [eng.submit(r) for r in _reqs(max_new=24)]
-        for _ in range(3):
-            eng.step()
-        for s in seqs:
-            if not s.done:
-                eng.cancel(s)
-        assert eng.cache.pool.num_free == free0
-        assert eng.cache.num_free == eng.num_slots
-
-
-# ------------------------------------------------------ chaos, int8 leg
-class TestChaosInt8:
-    def _factory(self, model, jit):
-        def factory():
-            return _engine(model, kv_dtype="int8", prefix_cache=True,
-                           jit_cache=jit)
-        return factory
-
-    def test_fault_matrix_zero_lost_deterministic(self, model):
-        # dedicated jit dict: the trie-backed pool is a different arg
-        # SHAPE than the no-trie engines elsewhere in this module, and
-        # pool-geometry-keyed caches must not collide under the
-        # compile pin (jit-cache-per-pool-geometry rule)
-        jit = {}
-        want = _run(_engine(model, kv_dtype="int8", prefix_cache=True,
-                            jit_cache=jit), _reqs())
-
-        def chaos_once():
-            plan = (FaultPlan().at_step(2, "transient")
-                    .at_step(4, "pool").at_step(6, "fatal")
-                    .at_step(8, "nan"))
-            factory = self._factory(model, jit)
-            gw = ServingGateway(factory(), engine_factory=factory,
-                                fault_hook=plan, start=False,
-                                max_queue=16)
-            streams = [gw.submit(_clone(r)) for r in _reqs()]
-            gw.start()
-            outs = [st.result() for st in streams]
-            kinds = [k for _, k in plan.log]
-            comp = gw.engine.decode_compilations()
-            gw.shutdown(drain=True, timeout=30)
-            return ([ids.tolist() for ids, _ in outs],
-                    [r for _, r in outs], kinds, comp)
-
-        ids1, reasons1, kinds1, comp1 = chaos_once()
-        ids2, reasons2, kinds2, comp2 = chaos_once()
-        assert ids1 == want                 # 0 lost, byte-identical
-        assert ids1 == ids2 and reasons1 == reasons2    # deterministic
-        assert set(kinds1) >= {"transient", "pool", "fatal", "nan"}
-        assert comp1 == 2 and comp2 == 2
-
-
 # --------------------------------------------------- compile discipline
 class TestCompileDiscipline:
     @pytest.mark.slow  # 6 s four-engine matrix duplicate: test_lowprec_decode
     # TestCompileDiscipline keys fp/kv8f/w8+a8 apart by default (870s cap)
     def test_compile_once_inclusive_of_quantized_geometry(self, model):
-        # fresh dict: all four engines share one POOL geometry (no
-        # trie), so the pin isolates exactly the quantization variants
-        jit = {}
+        # all four engines share one POOL geometry (no trie), so one
+        # cache, and the pin isolates exactly the quantization variants
         engines = {
-            "fp": _engine(model, jit_cache=jit),
-            "int8": _engine(model, kv_dtype="int8", jit_cache=jit),
-            "w8": _engine(model, quantize_weights=True, jit_cache=jit),
+            "fp": _engine(model),
+            "int8": _engine(model, kv_dtype="int8"),
+            "w8": _engine(model, quantize_weights=True),
             "both": _engine(model, kv_dtype="int8",
-                            quantize_weights=True, jit_cache=jit),
+                            quantize_weights=True),
         }
         for eng in engines.values():
             _run(eng, _reqs())
@@ -353,16 +198,15 @@ class TestCompileDiscipline:
                 for n, e in engines.items()} == pre
 
     def test_variant_tags_key_programs_apart(self, model):
-        jit = {}
-        fp = _engine(model, jit_cache=jit)
-        q8 = _engine(model, kv_dtype="int8", quantize_weights=True,
-                     jit_cache=jit)
+        # on the shared cache, beside whatever the module's tests built
+        fp = _engine(model)
+        q8 = _engine(model, kv_dtype="int8", quantize_weights=True)
         # a short prompt (under the chunk) takes the COLD prefill path
         short = [GenerationRequest(prompt=_prompt(9, 10),
                                    max_new_tokens=2)]
         _run(fp, _reqs(n_reqs=1)), _run(fp, short)
         _run(q8, _reqs(n_reqs=1)), _run(q8, short)
-        keys = set(jit)
+        keys = set(fp._jit)
         attn = model.config.decode_attention
         # a program a packed size: chunk-carrying steps, decode-only steps
         for rows in (2 + CHUNK, 8):
@@ -373,25 +217,6 @@ class TestCompileDiscipline:
         # each engine counts ONLY its own variant
         assert fp.decode_compilations() == 2
         assert q8.decode_compilations() == 2
-
-
-# ----------------------------------------- spec + multi-tick, int8 pool
-class TestSpecAndMultitickInt8:
-    @pytest.mark.parametrize("sampled", [False, True])
-    def test_spec_decode_byte_identical_to_int8_baseline(self, model,
-                                                         sampled):
-        base = _run(_engine(model, kv_dtype="int8"), _reqs(sampled))
-        spec = _run(_engine(model, kv_dtype="int8", spec_decode=True,
-                            spec_k=3), _reqs(sampled))
-        assert spec == base
-
-    @pytest.mark.parametrize("sampled", [False, True])
-    def test_multitick_byte_identical_to_int8_baseline(self, model,
-                                                       sampled):
-        base = _run(_engine(model, kv_dtype="int8"), _reqs(sampled))
-        mt = _run(_engine(model, kv_dtype="int8", decode_ticks=4),
-                  _reqs(sampled))
-        assert mt == base
 
 
 # ------------------------------------------------------- weight-only w8
@@ -413,12 +238,10 @@ class TestWeightOnly:
         assert s.shape[1] == 1              # per-channel, axis-1 reduced
 
     def test_rebuild_shares_qparams_and_jit(self, model):
-        jit = model.__dict__.setdefault("_serving_jit", {})
-        want = _run(_engine(model, quantize_weights=True,
-                            jit_cache=jit), _reqs())
+        want = _run(_engine(model, quantize_weights=True), _reqs())
 
         def factory():
-            return _engine(model, quantize_weights=True, jit_cache=jit)
+            return _engine(model, quantize_weights=True)
         plan = FaultPlan().at_step(3, "fatal")
         gw = ServingGateway(factory(), engine_factory=factory,
                             fault_hook=plan, start=False, max_queue=16)
@@ -429,48 +252,3 @@ class TestWeightOnly:
         assert gw.restarts == 1
         assert gw.engine.decode_compilations() == 2
         gw.shutdown(drain=True, timeout=30)
-
-
-# -------------------------------------------------------------- metrics
-class TestQuantMetrics:
-    def test_kv_pool_bytes_gauges_strict_parse(self, model):
-        eng = _engine(model, kv_dtype="int8", prefix_cache=True)
-        gw = ServingGateway(eng, start=False, max_queue=16)
-        eng.submit(GenerationRequest(prompt=_prompt(1, 20),
-                                     max_new_tokens=4))
-        eng.step()                          # we are the driver thread
-        fams = parse_prometheus(gw.registry.render())
-        ob = eng.cache.occupancy_bytes()
-        kv = fams["kv_pool_bytes"]["samples"]
-        assert kv[("kv_pool_bytes", (("kind", "kv"),))] == ob["used_kv"]
-        assert kv[("kv_pool_bytes",
-                   (("kind", "scales"),))] == ob["used_scales"]
-        assert ob["used_kv"] > 0 and ob["used_scales"] > 0
-        # int8 data is exactly D bytes per fp32-scale's 4: the ratio
-        # of the two gauges is D/4, dtype-awareness in one line
-        assert ob["used_kv"] / ob["used_scales"] == \
-            model.config.head_dim / 4
-        per_tok = fams["serving_kv_bytes_per_token"]["samples"][
-            ("serving_kv_bytes_per_token", ())]
-        assert per_tok == ob["per_token"]
-        gw.shutdown(drain=False, timeout=10)
-
-    def test_profile_doc_reports_bytes_not_blocks(self, model):
-        eng = _engine(model, kv_dtype="int8")
-        gw = ServingGateway(eng, start=False, max_queue=16)
-        eng.submit(GenerationRequest(prompt=_prompt(2, 20),
-                                     max_new_tokens=4))
-        eng.step()
-        doc = gw.profile_doc()
-        kvp = doc["kv_pool"]
-        assert kvp["kv_dtype"] == "int8"
-        per_block = (eng.cache.pool.block_nbytes
-                     + eng.cache.pool.scale_block_nbytes)
-        occ = eng.cache.occupancy()
-        assert kvp["live_bytes"] == occ["live"] * per_block
-        assert kvp["live_bytes"] > 0
-        assert kvp["capacity_bytes"] == \
-            eng.cache.pool.num_blocks * per_block
-        assert kvp["bytes_per_token"] == \
-            eng.cache.occupancy_bytes()["per_token"]
-        gw.shutdown(drain=False, timeout=10)

@@ -26,62 +26,28 @@ one ``host-accept``. The load-bearing properties:
 """
 import itertools
 
-import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine, FIFOScheduler,
-                                GenerationRequest)
+from paddle_tpu.serving import FIFOScheduler, GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan
 from paddle_tpu.serving.server import ServingGateway
 
+import serving_support
+from serving_support import (clone as _clone, engine as _engine,
+                             prompt as _prompt)
 from test_metrics_prom import parse_prometheus
 
-BS = 8      # KV block size
-CHUNK = 16  # 2 blocks per chunk
 TICKS = 8
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
-
-
-def _jit(model, tag):
-    """One jit-cache dict PER POOL GEOMETRY: a trie-backed engine's
-    pool has more blocks than a bare one's, so their pool_k/pool_v arg
-    shapes differ and sharing one dict would retrace the one mtick fn
-    per geometry — breaking the compile-once pins (the fleet isolates
-    caches by geometry for exactly this reason)."""
-    return model.__dict__.setdefault(f"_serving_jit_mtick_{tag}", {})
-
-
-def _engine(model, jit_tag="plain", **kw):
-    kw.setdefault("jit_cache", _jit(model, jit_tag))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33)  # GQA: nkv=2 < nh=4
 
 
 def _req(ps, n=12, **kw):
     kw.setdefault("max_new_tokens", 8)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 def _greedy_ref(model, n=24, seed=5):
@@ -104,7 +70,7 @@ class TestTransparency:
         byte-identical between ``decode_ticks=8`` and ``1``, with ONE
         decode program inclusive of the multi-tick geometry."""
         def drive(ticks):
-            eng = _engine(model, jit_tag="trie32", decode_ticks=ticks,
+            eng = _engine(model, decode_ticks=ticks,
                           prefix_cache=True, prefix_blocks=32)
             outs = []
             for wave in range(2):
@@ -293,18 +259,12 @@ class TestAdaptiveTicks:
 
 
 # -------------------------------------------------------- fault interplay
-def _mk_factory(model, jit_tag="trie", **kw):
-    cache = _jit(model, jit_tag)
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
+def _mk_factory(model, **kw):
     kw.setdefault("prefix_cache", True)
     kw.setdefault("decode_ticks", TICKS)
 
     def factory():
-        return ContinuousBatchingEngine(model, jit_cache=cache, **kw)
+        return _engine(model, **kw)
     return factory
 
 
@@ -315,21 +275,34 @@ def _traffic():
             _req(4, n=60, max_new_tokens=6)]
 
 
+#: one fault plan a kind, then all four in one run: each a case of its own
+_CHAOS = {
+    "transient": [(2, "transient")],
+    "pool": [(4, "pool")],
+    "fatal": [(6, "fatal")],
+    "nan": [(4, "nan")],
+    "all": [(2, "transient"), (4, "pool"), (6, "fatal"), (9, "nan")],
+}
+
+
 class TestFaultInterplay:
-    def test_chaos_matrix_byte_identical(self, model):
+    @pytest.mark.parametrize("case", sorted(_CHAOS))
+    def test_chaos_matrix_byte_identical(self, model, case):
         """The acceptance pin under faults: transient retry, pool
         exhaustion -> preemption, fatal rebuild and nan KV corruption
-        all mid-multi-tick-traffic — a fault unwinds to the last
+        all mid-multi-tick-traffic (each alone, then all in one run)
+        — a fault unwinds to the last
         accepted token, restore() recomputes from accepted tokens
         only, streams land byte-identical to the fault-free
         ``decode_ticks=1`` oracle, and the rebuilt engine still counts
         ONE decode program."""
         reqs = _traffic()
-        base = _engine(model, jit_tag="trie", prefix_cache=True)
+        base = _engine(model, prefix_cache=True)
         want = [o.tolist()
                 for o in base.generate([_clone(r) for r in reqs])]
-        plan = (FaultPlan().at_step(2, "transient").at_step(4, "pool")
-                .at_step(6, "fatal").at_step(9, "nan"))
+        plan = FaultPlan()
+        for step, kind in _CHAOS[case]:
+            plan.at_step(step, kind)
         factory = _mk_factory(model)
         gw = ServingGateway(factory(), engine_factory=factory,
                             fault_hook=plan, start=False, max_queue=16,
@@ -338,9 +311,10 @@ class TestFaultInterplay:
         gw.start()
         outs = [st.result() for st in streams]
         assert [ids.tolist() for ids, _ in outs] == want
-        assert {k for _, k in plan.log} >= {"transient", "pool",
-                                            "fatal", "nan"}
-        assert gw.restarts >= 1
+        fired = {k for _, k in plan.log}
+        assert fired >= {kind for _, kind in _CHAOS[case]}
+        if "fatal" in fired:
+            assert gw.restarts >= 1
         assert gw.engine.decode_compilations() == 1
         assert gw.engine.decode_ticks == TICKS
         gw.shutdown(drain=True, timeout=30)
@@ -358,8 +332,7 @@ class TestMetricsSurface:
                 _req(42, n=10, max_new_tokens=24)]
 
         def run(ticks):
-            factory = _mk_factory(model, jit_tag="plain",
-                                  prefix_cache=False,
+            factory = _mk_factory(model, prefix_cache=False,
                                   decode_ticks=ticks)
             gw = ServingGateway(factory(), engine_factory=factory,
                                 start=False, max_queue=16)
@@ -394,7 +367,7 @@ class TestMetricsSurface:
         for n ticks under multi-tick decode)."""
         tick = itertools.count()
         clock = lambda: float(next(tick))   # noqa: E731
-        factory = _mk_factory(model, jit_tag="plain", prefix_cache=False,
+        factory = _mk_factory(model, prefix_cache=False,
                               step_clock=clock)
         gw = ServingGateway(factory(), engine_factory=factory,
                             start=False, max_queue=16)

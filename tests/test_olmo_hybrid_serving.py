@@ -23,16 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
-                                           deepseek_v2_tiny)
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.olmo_hybrid import (FULL, LINEAR, OlmoHybridConfig,
                                            OlmoHybridForCausalLM,
                                            olmo_hybrid_tiny)
-from paddle_tpu.models.olmoe import OlmoeForCausalLM, olmoe_tiny
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving import decode as decode_mod
+
+import serving_support
+from serving_support import drain as _run, token_list as _prompt
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -44,21 +42,25 @@ GEOMETRY = dict(num_slots=SLOTS, max_seq_len=96, decode_chunk=1,
                 prefill_chunk=32)
 
 
-def _model(kernel="jnp", seed=7):
-    paddle.seed(seed)
-    return OlmoHybridForCausalLM(olmo_hybrid_tiny(decode_attention=kernel))
+def _model(kernel="jnp"):
+    return serving_support.model("olmo_hybrid", seed=7,
+                                 decode_attention=kernel)
 
 
-def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(1, 256, n).tolist()
+def _engine(model, **kw):
+    """The shared helper at this file's geometry (3 slots, chunks of 32, the
+    engine's own block)."""
+    return serving_support.engine_as_given(model, **{**GEOMETRY, **kw})
 
 
 class _Recorder:
     """Every program's logits (``decode._head_logits``), in dispatch order,
-    and for every token a sequence is given the row it was sampled from."""
+    and for every token a sequence is given the row it was sampled from.
+    One for the module: the programs a recording test traces hold it, and
+    are kept (``programs``) for the recording tests that follow."""
 
-    def __init__(self, monkeypatch):
-        self.records, self.rows = [], {}
+    def __init__(self):
+        self.records, self.rows, self.jit = [], {}, {}
         real = decode_mod._head_logits
 
         def recording(last_h, head):
@@ -67,7 +69,17 @@ class _Recorder:
                                logits, ordered=True)
             return logits
 
-        monkeypatch.setattr(decode_mod, "_head_logits", recording)
+        self.recording = recording
+
+    def engine(self, model):
+        """An engine on the recorded programs of ``model``, watched. They are
+        this file's own: nobody else may run a program with the recorder in
+        it."""
+        eng = serving_support.watch_prefill_programs(_engine(
+            model, jit_cache=self.jit.setdefault(
+                model.config.decode_attention, {})))
+        self.watch(eng)
+        return eng
 
     def watch(self, eng):
         def on_token(seq, _tok):
@@ -101,11 +113,16 @@ def _deviation(model, seq, rows):
     return float(np.abs(np.stack(rows) - want).max() / np.abs(want).max())
 
 
-def _run(eng, between=None):
-    while eng.has_work():
-        eng.step()
-        if between is not None:
-            between()
+_RECORDER = _Recorder()
+
+
+@pytest.fixture
+def rec(monkeypatch):
+    """The module's recorder, emptied, and patched in for this test."""
+    del _RECORDER.records[:]
+    _RECORDER.rows.clear()
+    monkeypatch.setattr(decode_mod, "_head_logits", _RECORDER.recording)
+    return _RECORDER
 
 
 CASES = {
@@ -118,12 +135,10 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_engine_logits_equal_reference(case, monkeypatch):
+def test_engine_logits_equal_reference(case, rec):
     n_prompt, n_new, kernel = CASES[case]
     model = _model(kernel)
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    eng = rec.engine(model)
     seq = eng.submit(GenerationRequest(_prompt(n_prompt), max_new_tokens=n_new))
     _run(eng)
     assert seq.done and len(seq.tokens) == n_new
@@ -131,19 +146,17 @@ def test_engine_logits_equal_reference(case, monkeypatch):
     if n_prompt > GEOMETRY["prefill_chunk"]:
         # chunks through the unified step, no whole-prompt program
         assert eng.stats["prefill_chunks"] == -(-n_prompt // 32)
-        assert eng.prefill_compilations() == 0
+        assert eng.prefill_programs_asked == 0
     # one state row a span a program: the prompt's spans and the decode rows
     spans = max(1, eng.stats["prefill_chunks"])
     assert eng.stats["state_rows"] == spans + n_new - 1
 
 
-def test_two_requests_of_unequal_length_share_steps(monkeypatch):
+def test_two_requests_of_unequal_length_share_steps(rec):
     """A chunked prompt and a whole one, decoding together: chunks and decode
     rows of different slots in one packed buffer."""
     model = _model()
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    eng = rec.engine(model)
     seqs = [eng.submit(GenerationRequest(_prompt(n, seed=n),
                                          max_new_tokens=new))
             for n, new in ((70, 5), (11, 9))]
@@ -154,13 +167,11 @@ def test_two_requests_of_unequal_length_share_steps(monkeypatch):
 
 
 @pytest.mark.parametrize("second", [40, 9], ids=["chunked", "whole"])
-def test_a_reused_slot_starts_from_a_zero_state(second, monkeypatch):
+def test_a_reused_slot_starts_from_a_zero_state(second, rec):
     """No program zeroes a slot: the second sequence in slot 0 reads the
     logits a fresh engine gives, because its first span starts at 0."""
     model = _model()
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    eng = rec.engine(model)
     first = eng.submit(GenerationRequest(_prompt(50, 1), max_new_tokens=7))
     _run(eng)
     assert first.done and first.slot == 0
@@ -172,11 +183,9 @@ def test_a_reused_slot_starts_from_a_zero_state(second, monkeypatch):
     assert _deviation(model, seq, rec.rows[seq.request_id]) <= TOLERANCE
 
 
-def test_preempted_and_recomputed(monkeypatch):
+def test_preempted_and_recomputed(rec):
     model = _model()
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    eng = rec.engine(model)
     seq = eng.submit(GenerationRequest(_prompt(21), max_new_tokens=9))
     armed = [True]
 
@@ -201,14 +210,12 @@ class _Broken:
         raise RuntimeError("device lost")
 
 
-def test_a_fence_that_raises_restarts_from_position_zero(monkeypatch):
+def test_a_fence_that_raises_restarts_from_position_zero(rec):
     """The dropped programs applied their tokens to the recurrent states:
     every sequence they carried is recomputed from position 0, and every
     stream reads the logits of the undisturbed one."""
     model = _model()
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    eng = rec.engine(model)
     real_fn, count = eng._ragged_fn, [0]
 
     def ragged_fn(n, rows):
@@ -258,15 +265,19 @@ WRONG = ("beta_without_its_2", "dropped_decay", "lost_conv_tail",
 
 
 @pytest.mark.parametrize("variant", WRONG)
-def test_wrong_variant_fails(variant, monkeypatch):
+def test_wrong_variant_fails(variant, rec, monkeypatch):
     model = _model()
     if variant == "beta_without_its_2":
         monkeypatch.setattr(decode_mod, "gdn_gates", _no_doubling)
     if variant == "dropped_decay":
         monkeypatch.setattr(decode_mod, "gdn_gates", _no_decay)
-    rec = _Recorder(monkeypatch)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
-    rec.watch(eng)
+    if variant == "lost_conv_tail":
+        eng = rec.engine(model)         # the fault is made on the host
+    else:
+        # programs of its own: the wrong function is patched in before the
+        # trace, and nobody else may run what is traced with it
+        eng = _engine(model, jit_cache={})
+        rec.watch(eng)
     if variant == "state_never_zeroed":
         eng.cache.state = tuple(jnp.ones_like(a) for a in eng.cache.state)
         real_ref = decode_mod.gdn_reference
@@ -289,7 +300,7 @@ def test_wrong_variant_fails(variant, monkeypatch):
 def test_the_pool_holds_the_full_layers_only():
     model = _model()
     c = model.config
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+    eng = _engine(model)
     assert c.num_hidden_layers == 8 and c.num_kv_layers == 2
     assert eng.cache.pool.k.shape[0] == 2 == eng.cache.pool.v.shape[0]
     per_token = 2 * 2 * c.num_key_value_heads * c.head_dim * 4
@@ -304,8 +315,7 @@ def test_the_pool_holds_the_full_layers_only():
     occ = eng.cache.occupancy_bytes()
     assert occ["capacity_state"] == SLOTS * per_slot and occ["used_state"] == 0
     # a dense model has no store and pays nothing for it
-    plain = ContinuousBatchingEngine(LlamaForCausalLM(llama_tiny()),
-                                     jit_cache={}, **GEOMETRY)
+    plain = _engine(serving_support.model("llama", seed=0))
     assert plain.cache.state is None
     assert plain.cache.state_bytes_per_slot == 0
 
@@ -349,7 +359,7 @@ SWITCHES = {
 def test_unsupported_switch_raises(switch):
     kw = {**GEOMETRY, **SWITCHES[switch]}
     with pytest.raises(ValueError, match="OlmoHybridForCausalLM"):
-        ContinuousBatchingEngine(_model(), jit_cache={}, **kw)
+        serving_support.engine_as_given(_model(), **kw)
 
 
 def test_generate_matches_forward_greedy():
@@ -376,23 +386,18 @@ def _unified_step_text(eng):
     return eng._ragged_fn(1, T).lower(*args).as_text()
 
 
-@pytest.mark.parametrize("make", [
-    lambda: LlamaForCausalLM(llama_tiny()),
-    lambda: OlmoeForCausalLM(olmoe_tiny()),
-    lambda: DeepseekV2ForCausalLM(deepseek_v2_tiny()),
-], ids=["llama", "olmoe", "deepseek_v2"])
-def test_other_models_programs_take_no_store(make):
+@pytest.mark.parametrize("arch", ["llama", "olmoe", "deepseek_v2"])
+def test_other_models_programs_take_no_store(arch):
     """Programs of models without the new keys do not change: their unified
     step is lowered with the arguments it had (no store), runs no kernel of
     the delta rule, and returns what it returned."""
-    paddle.seed(0)
-    eng = ContinuousBatchingEngine(make(), jit_cache={}, **GEOMETRY)
+    eng = _engine(serving_support.model(arch, seed=0))
     assert not eng._stateful and "gdn" not in eng._fn_consts()
     assert "gdn_" not in _unified_step_text(eng)
 
 
 @pytest.mark.parametrize("make", [
-    lambda: LlamaForCausalLM(llama_tiny(decode_attention="pallas")),
+    lambda: serving_support.model("llama", seed=0),     # "pallas"
     lambda: _model("pallas"),
 ], ids=["tiny_mistral", "tiny_olmo_hybrid"])
 def test_unified_step_makes_no_wide_query(make):
@@ -402,8 +407,7 @@ def test_unified_step_makes_no_wide_query(make):
     query nor a wide output ``[T * H, KD]`` is made on the way (for a head
     count padded to whole sublane groups either, the parent's 30 -> 32)."""
     import re
-    paddle.seed(0)
-    eng = ContinuousBatchingEngine(make(), jit_cache={}, **GEOMETRY)
+    eng = _engine(make())
     c, T = eng.config, eng._token_budget
     text = _unified_step_text(eng)
     nh, kd = c.num_attention_heads, c.num_key_value_heads * c.head_dim
@@ -416,49 +420,6 @@ def test_unified_step_makes_no_wide_query(make):
              if sh[-1] in (c.head_dim, kd)}
     assert T * nh * c.head_dim in sizes         # the query itself is there
     assert not wide & sizes, sorted(wide & sizes)
-
-
-@pytest.mark.parametrize("nh,nkv", [(12, 12), (12, 4), (30, 30)])
-def test_ragged_attention_pads_no_head_count(nh, nkv, monkeypatch):
-    """30 heads are the first count in the benchmark that is no multiple of
-    8: the heads are a LEADING dimension of the kernel's head-major query
-    ``[Hkv, T * G, D]``, so 12 or 30 of them need no padded row (the call
-    sees exactly ``nkv`` planes of ``T * G`` rows and returns as many), and
-    the result is the oracle's, MHA and GQA alike."""
-    from paddle_tpu.kernels import pallas_ragged_attention as pra
-    assert not hasattr(pra, "wide_rows")
-    assert not hasattr(pra, "_ragged_padded_heads")
-    seen = []
-    real = pra._ragged_call
-
-    def call(q_hm, *a, **kw):
-        out = real(q_hm, *a, **kw)
-        seen.append((q_hm.shape, out.shape))
-        return out
-    monkeypatch.setattr(pra, "_ragged_call", call)
-    rng = np.random.RandomState(nh + nkv)
-    hd, bs, nb, mb = 16, 8, 24, 6
-    rows = [(1, 20), (9, 30), (0, 0), (1, 1)]
-    qlen = np.array([q for q, _ in rows], np.int32)
-    kvlen = np.array([k for _, k in rows], np.int32)
-    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
-    tables = np.full((len(rows), mb), nb, np.int32)
-    perm, used = rng.permutation(nb), 0
-    for r, n in enumerate(kvlen):
-        for b in range(-(-int(n) // bs)):
-            tables[r, b] = perm[used]
-            used += 1
-    T = int(qlen.sum()) + 3
-    q = jnp.asarray(rng.randn(T, nh, hd), jnp.float32)
-    pk = jnp.asarray(rng.randn(2, nb, bs, nkv * hd), jnp.float32)
-    pv = jnp.asarray(rng.randn(2, nb, bs, nkv * hd), jnp.float32)
-    args = (q, pk, pv, tables, qstart, qlen, kvlen)
-    got = pra.ragged_paged_attention_pallas(*args, layer=1)
-    want = pra.ragged_attention_reference(*args, layer=1)
-    live = int(qlen.sum())
-    assert np.abs(np.asarray(got - want))[:live].max() < 1e-4
-    assert not np.asarray(got)[live:].any()
-    assert seen == [((nkv, T * (nh // nkv), hd),) * 2]
 
 
 # ----------------------------------------------------------- over HTTP
@@ -489,9 +450,8 @@ def test_http_completion_equals_the_direct_engine(http_server, n_prompt):
     gateway, scheduler and unified step; the HTTP stream is the engine's."""
     model, srv = http_server
     prompt = _prompt(n_prompt, 11)
-    direct = ContinuousBatchingEngine(
-        model, num_slots=2, max_seq_len=96, decode_chunk=1, prefill_chunk=32,
-        jit_cache=model.__dict__.setdefault("_serving_jit", {}))
+    direct = _engine(model, num_slots=2,
+                     jit_cache=model.__dict__["_serving_jit"])   # serve()'s
     want = direct.generate([GenerationRequest(prompt, max_new_tokens=6)])[0]
     assert _complete(srv, prompt, 6) == want.tolist()
 

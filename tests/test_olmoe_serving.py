@@ -20,12 +20,13 @@ import jax
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.kernels import moe_ffn as moe_mod
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.models.olmoe import OlmoeForCausalLM, olmoe_tiny
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.models.olmoe import OlmoeForCausalLM
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving import decode as decode_mod
+
+import serving_support
+from serving_support import token_list as _prompt
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -37,33 +38,44 @@ GEOMETRY = dict(num_slots=SLOTS, max_seq_len=96, decode_chunk=1,
                 prefill_chunk=32)
 
 
-def _model(attention="jnp", seed=7):
-    paddle.seed(seed)
-    return OlmoeForCausalLM(olmoe_tiny(decode_attention=attention))
+def _model(attention="jnp"):
+    return serving_support.model("olmoe", seed=7, decode_attention=attention)
 
 
-def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(1, 256, n).tolist()
+def _engine(model, **kw):
+    """The shared helper at this file's geometry (3 slots, chunks of 32, the
+    engine's own block)."""
+    return serving_support.engine_as_given(model, **{**GEOMETRY, **kw})
+
+
+#: what the recording programs write to: one list for the process, because
+#: the programs traced with the recorder inside are kept on their model
+#: (``_recorded_programs``) for the recording tests that follow
+_RECORDS = []
+_REAL_HEAD_LOGITS = decode_mod._head_logits
+
+
+def _recording(last_h, head):
+    logits = _REAL_HEAD_LOGITS(last_h, head)
+    jax.debug.callback(lambda x: _RECORDS.append(np.asarray(x)), logits,
+                       ordered=True)
+    return logits
 
 
 def _serve_recording_logits(model, prompt, n_new, monkeypatch,
-                            preempt_after=None):
-    """Run one request through a fresh engine (fresh programs, so a
-    monkeypatched layer is traced) and return (tokens, the logits row each
-    token was sampled from). Every program computes its logits in
-    ``decode._head_logits``: a whole-prompt prefill for the group's rows, a
-    unified step for every slot."""
-    records = []
-    real = decode_mod._head_logits
-
-    def recording(last_h, head):
-        logits = real(last_h, head)
-        jax.debug.callback(lambda x: records.append(np.asarray(x)), logits,
-                           ordered=True)
-        return logits
-
-    monkeypatch.setattr(decode_mod, "_head_logits", recording)
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+                            preempt_after=None, fresh=False):
+    """Run one request through an engine whose programs record, and return
+    (tokens, the logits row each token was sampled from). Every program
+    computes its logits in ``decode._head_logits``: a whole-prompt prefill
+    for the group's rows, a unified step for every slot. The recording
+    programs are the model's, traced once; ``fresh`` for a test that patched
+    a layer in, which must be traced and which nobody else may run."""
+    records = _RECORDS
+    del records[:]
+    monkeypatch.setattr(decode_mod, "_head_logits", _recording)
+    eng = serving_support.watch_prefill_programs(_engine(
+        model, jit_cache={} if fresh else model.__dict__.setdefault(
+            "_recorded_programs", {})))
     rows = []
 
     def on_token(seq, _tok):
@@ -121,7 +133,7 @@ def test_engine_logits_equal_reference(case, monkeypatch):
     if case == "chunked_through_unified_step":
         # three chunks through the unified step, no whole-prompt program
         assert eng.stats["prefill_chunks"] == 3
-        assert eng.prefill_compilations() == 0
+        assert eng.prefill_programs_asked == 0
     if preempt is not None:
         assert eng.stats["preemptions"] == 1 and eng.stats["restores"] == 1
     # the routing summary rode the fetches: K pairs for every live token
@@ -170,7 +182,8 @@ def test_wrong_variant_fails(variant, monkeypatch):
     monkeypatch.setattr(decode_mod, name, fn)
     model = _model()
     prompt = _prompt(21)
-    _, tokens, rows = _serve_recording_logits(model, prompt, 6, monkeypatch)
+    _, tokens, rows = _serve_recording_logits(model, prompt, 6, monkeypatch,
+                                              fresh=True)
     assert _worst_deviation(model, prompt, tokens, rows) > 1e-2
 
 
@@ -192,7 +205,7 @@ def test_unsupported_switch_raises(switch):
     model = _model()
     kw = {**GEOMETRY, **SWITCHES[switch]}
     with pytest.raises(ValueError) as e:
-        ContinuousBatchingEngine(model, **kw)
+        serving_support.engine_as_given(model, **kw)
     assert "OlmoeForCausalLM" in str(e.value) and switch in str(e.value)
 
 
@@ -202,7 +215,9 @@ def test_routing_changes_share_one_program():
     packed size (the second built when the first chunk is planned), and
     whole-prompt programs bounded by the (group, bucket) grid."""
     model = _model()
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+    # programs of its own (jnp path): the counts are of what THIS engine
+    # has built so far, the second size only once a chunk is planned
+    eng = _engine(model, jit_cache={})
     eng.generate([GenerationRequest(_prompt(9, 1), max_new_tokens=5)])
     touched = eng.stats["moe_experts_touched"]
     assert eng.decode_compilations() == 1 and eng.prefill_compilations() == 1
@@ -225,8 +240,8 @@ def test_generate_matches_forward_greedy():
 
 
 @pytest.mark.parametrize("make", [
-    lambda: LlamaForCausalLM(llama_tiny()),
-    lambda: LlamaForCausalLM(llama_tiny(tie_word_embeddings=True)),
+    lambda: serving_support.model("llama", seed=7),
+    lambda: serving_support.model("llama", seed=7, tie_word_embeddings=True),
     _model], ids=["llama", "llama_tied", "olmoe"])
 def test_decode_params_stack_over_layers(make):
     """What the layer scan assumes of every model the engine accepts: each
@@ -245,7 +260,7 @@ def test_decode_params_stack_over_layers(make):
     for name, leaf in leaves:
         assert leaf.shape[0] == model.config.num_hidden_layers, name
     assert tied == (model.lm_head is None)
-    ContinuousBatchingEngine(model, **GEOMETRY)     # and it is accepted
+    _engine(model)                      # and it is accepted
 
 
 # ----------------------------------------------------------- over HTTP
@@ -276,9 +291,8 @@ def test_http_completion_equals_the_direct_engine(http_server, n_prompt):
     pool and unified step as Llama; the HTTP stream is the engine's."""
     model, srv = http_server
     prompt = _prompt(n_prompt, 11)
-    direct = ContinuousBatchingEngine(
-        model, num_slots=2, max_seq_len=96, decode_chunk=1, prefill_chunk=32,
-        jit_cache=model.__dict__.setdefault("_serving_jit", {}))
+    direct = _engine(model, num_slots=2,
+                     jit_cache=model.__dict__["_serving_jit"])   # serve()'s
     want = direct.generate([GenerationRequest(prompt, max_new_tokens=6)])[0]
     assert _complete(srv, prompt, 6) == want.tolist()
 
@@ -312,8 +326,7 @@ def test_metrics_carry_the_routing_counters(http_server):
 
 def test_a_dense_models_metrics_have_no_routing_series():
     from paddle_tpu.serving.server import serve
-    paddle.seed(1)
-    srv = serve(LlamaForCausalLM(llama_tiny()), port=0, num_slots=2,
+    srv = serve(serving_support.model("llama", seed=1), port=0, num_slots=2,
                 max_seq_len=64)
     try:
         import urllib.request

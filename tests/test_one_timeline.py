@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from jax.experimental import pallas as pl
 from paddle_tpu.kernels.pallas_ragged_attention import (_one_token_walk,
                                                         _query_block,
@@ -26,41 +25,24 @@ from paddle_tpu.kernels.pallas_ragged_attention import (_one_token_walk,
                                                         pages_per_update,
                                                         query_block_rows,
                                                         ragged_grid_counts)
-from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
-                                           deepseek_v2_tiny)
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler import chrometrace
 from paddle_tpu.profiler.tracing import (NULL_SPAN, TID_ENGINE, TID_GATEWAY,
                                          TID_REQ0, SpanTracer)
-from paddle_tpu.serving import (ContinuousBatchingEngine, GenerationRequest,
-                                VirtualClock)
+from paddle_tpu.serving import GenerationRequest, VirtualClock
 from paddle_tpu.serving import engine as engine_mod
 from paddle_tpu.serving.decode import attention_grid
 from paddle_tpu.serving.server import serve
 
+import serving_support
 from test_metrics_prom import parse_prometheus
-
-NUM_SLOTS, S_MAX = 2, 256
+# test_tracing's engines (256 positions, a tracer hung on), its model and so
+# its programs
+from test_tracing import NUM_SLOTS, S_MAX, _engine
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(31)
-    return LlamaForCausalLM(llama_tiny())
-
-
-@pytest.fixture(scope="module")
-def jit_cache():
-    return {}
-
-
-def _engine(model, jit_cache, tracer=None, **kw):
-    kw.setdefault("num_slots", NUM_SLOTS)
-    kw.setdefault("max_seq_len", S_MAX)
-    kw.setdefault("decode_chunk", 1)
-    eng = ContinuousBatchingEngine(model, jit_cache=jit_cache, **kw)
-    eng.tracer = tracer
-    return eng
+    return serving_support.model("llama", seed=31)
 
 
 def _reqs():
@@ -150,18 +132,17 @@ class TestSpanForm:
 
 # ----------------------------------------------------- engine and gateway
 class TestEngineSpans:
-    def _run(self, model, jit_cache, annotate, clock=VirtualClock):
+    def _run(self, model, annotate, clock=VirtualClock):
         tr = SpanTracer(clock=clock(), annotate=annotate).enable()
-        eng = _engine(model, jit_cache, tracer=tr, prefill_chunk=32,
+        eng = _engine(model, tracer=tr, prefill_chunk=32,
                       prefix_block_size=8)
         outs = eng.generate(_reqs())
         return eng, tr, [o.tolist() for o in outs]
 
-    def test_mirror_gets_every_engine_span_once_and_bytes_do_not_move(
-            self, model, jit_cache):
-        _, plain, toks0 = self._run(model, jit_cache, None)
+    def test_mirror_gets_every_engine_span_once_and_bytes_do_not_move(self, model):
+        _, plain, toks0 = self._run(model, None)
         mirror = Mirror()
-        eng, tr, toks1 = self._run(model, jit_cache, mirror)
+        eng, tr, toks1 = self._run(model, mirror)
         assert toks0 == toks1
         # the same bytes with and without a factory (VirtualClock replay)
         assert json.dumps(plain.export(), sort_keys=True) \
@@ -174,17 +155,16 @@ class TestEngineSpans:
         steps = [a["step"] for n, a in mirror.opened if n == "step"]
         assert steps == list(range(eng.stats["steps"]))
 
-    def test_disabled_engine_touches_neither_mirror_nor_clock(
-            self, model, jit_cache):
+    def test_disabled_engine_touches_neither_mirror_nor_clock(self, model):
         mirror, clock = Mirror(), CountingClock()
         tr = SpanTracer(clock=clock, annotate=mirror)       # never enabled
-        eng = _engine(model, jit_cache, tracer=tr, prefill_chunk=32,
+        eng = _engine(model, tracer=tr, prefill_chunk=32,
                       prefix_block_size=8)
         eng.generate(_reqs())
         assert mirror.opened == [] and clock.reads == 0
 
-    def test_launch_holds_dispatch_and_device_wait(self, model, jit_cache):
-        eng, tr, _ = self._run(model, jit_cache, None,
+    def test_launch_holds_dispatch_and_device_wait(self, model):
+        eng, tr, _ = self._run(model, None,
                                clock=lambda: time.perf_counter)
         evs = [e for e in tr.events() if e["ph"] == "X"]
         launches = [e for e in evs if e["name"] == "launch"]
@@ -237,8 +217,8 @@ class TestEngineSpans:
                    if (lane, n) in rows) == pytest.approx(
             rows[lane, "step"]["total_ms"], abs=2e-2)
 
-    def test_dispatch_args_say_what_the_step_asked(self, model, jit_cache):
-        eng, tr, _ = self._run(model, jit_cache, None)
+    def test_dispatch_args_say_what_the_step_asked(self, model):
+        eng, tr, _ = self._run(model, None)
         evs = tr.events()
         disp = [e["args"] for e in evs if e["name"] == "dispatch"]
         steps = [e["args"] for e in evs if e["name"] == "step"]
@@ -293,13 +273,11 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
     and the pages an update that the step's ``pallas_call`` was really built
     with (read off the call itself, not derived a second time): the engine
     and the kernel share one ``grid_params``."""
-    paddle.seed(31)
     if kind == "dense":
-        model = LlamaForCausalLM(llama_tiny(decode_attention="pallas"))
+        model = serving_support.model("llama", seed=31)     # "pallas"
         name = "ragged_paged_attention"
     else:
-        model = DeepseekV2ForCausalLM(
-            deepseek_v2_tiny(decode_attention="pallas"))
+        model = serving_support.model("deepseek_v2", seed=31)
         name = "mla_ragged_attention"
     built, asked = [], []
     real_call, real_counts = pl.pallas_call, engine_mod.ragged_grid_counts
@@ -317,7 +295,8 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
     monkeypatch.setattr(pl, "pallas_call", pallas_call)
     monkeypatch.setattr(engine_mod, "ragged_grid_counts", counts)
     tr = SpanTracer(clock=VirtualClock()).enable()
-    eng = _engine(model, {}, tracer=tr, prefill_chunk=32,
+    # programs of its own: ``pallas_call`` is patched in before the trace
+    eng = _engine(model, jit_cache={}, tracer=tr, prefill_chunk=32,
                   prefix_block_size=8)
     eng.generate(_reqs())
     disp = [e["args"] for e in tr.events() if e["name"] == "dispatch"]
@@ -601,9 +580,7 @@ def test_ragged_grid_counts_at_the_cells_geometry(step):
 # ---------------------------------------------- names on the device's work
 class TestNamesInTheProgram:
     def test_ragged_step_names_its_kernel_and_blocks(self, model):
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1,
-            jit_cache={})
+        eng = _engine(model)
         assert model.config.decode_attention == "pallas"
         R, T = NUM_SLOTS, eng._token_budget
         z = lambda n, dt=np.int32: np.zeros(n, dt)      # noqa: E731
@@ -623,8 +600,8 @@ class TestNamesInTheProgram:
         from paddle_tpu.kernels import flash_attention
         from paddle_tpu.optimizer import AdamW
         monkeypatch.setattr(flash_attention, "_use_pallas", lambda s: True)
-        paddle.seed(0)
-        m = LlamaForCausalLM(llama_tiny(attention_layout="bhsd"))
+        m = serving_support.fresh_model("llama", seed=0,     # it is trained
+                                        attention_layout="bhsd")
         step = TrainStep(m, lambda loss, _lab: loss,
                          AdamW(parameters=m.parameters(),
                                learning_rate=1e-3))

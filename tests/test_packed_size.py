@@ -22,66 +22,42 @@ import urllib.request
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
-                                           deepseek_v2_tiny)
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
-                                           olmo_hybrid_tiny)
-from paddle_tpu.models.olmoe import OlmoeForCausalLM, olmoe_tiny
 from paddle_tpu.profiler.tracing import SpanTracer
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import VirtualClock
 from paddle_tpu.serving.server import serve
 
-BS = 8          # KV block
-CHUNK = 16      # two blocks a prefill chunk
+import serving_support
+from serving_support import BS, CHUNK
+
 SLOTS = 3       # so the decode-only size is 8 rows, the other 3 + 16
 SMALL, LARGE = 8, SLOTS + CHUNK
 
-FAMILIES = {
-    "llama": (LlamaForCausalLM, llama_tiny, 28),
-    "olmoe": (OlmoeForCausalLM, olmoe_tiny, 7),
-    "deepseek_v2": (DeepseekV2ForCausalLM, deepseek_v2_tiny, 11),
-    "olmo_hybrid": (OlmoHybridForCausalLM, olmo_hybrid_tiny, 7),
-}
+#: family: the seed its sibling files build it with
+FAMILIES = {"llama": 28, "olmoe": 7, "deepseek_v2": 11, "olmo_hybrid": 7}
 
 
-def _build(family, attention="jnp"):
-    cls, config, seed = FAMILIES[family]
-    paddle.seed(seed)
-    return cls(config(decode_attention=attention))
+def _build(family, attention="jnp", build=serving_support.model):
+    return build(family, seed=FAMILIES[family], decode_attention=attention)
 
 
 @pytest.fixture(scope="module")
 def models():
-    """One model a family for the module (the same seed gives the same
-    weights, so a test that needs a second instance builds its own)."""
-    built = {}
-
-    def get(family):
-        if family not in built:
-            built[family] = _build(family)
-        return built[family]
-    return get
+    """One model a family (the process's: ``serving_support.model``)."""
+    return _build
 
 
 def _engine(model, single_size=False, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_packed_jit", {}))
-    kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    eng = ContinuousBatchingEngine(model, **kw)
+    """The shared helper at three slots; ``single_size`` makes the parent's
+    engine, whose every step runs at the token budget."""
+    eng = serving_support.engine(model, **{"num_slots": SLOTS, **kw})
     if single_size:
-        # the parent's engine: every step at the token budget
         eng._decode_rows = eng._token_budget
     return eng
 
 
 def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(1, 256, (n,)).astype(np.int32)
+    return serving_support.prompt(seed, n, low=1)
 
 
 def _script(sampled):
@@ -159,6 +135,8 @@ def test_packed_rows_follow_the_plan_under_a_random_mix(
         **(dict(temperature=0.7, top_k=4, seed=i) if i % 3 == 0 else {})))
         for i in range(12)]
     tr = SpanTracer(clock=VirtualClock()).enable()
+    # programs of its own (on the jnp path, the cheapest there are): the
+    # assertions are on what THIS engine has built, and when
     eng = _engine(model, jit_cache={}, decode_chunk=decode_chunk)
     eng.tracer = tr
     fenced = []
@@ -194,7 +172,7 @@ def test_packed_rows_follow_the_plan_under_a_random_mix(
 def test_no_program_is_built_before_a_step_needs_it(traffic, sizes, models):
     """A mix whose prompts are all prefilled whole never builds the large
     program; one long request builds both, its chunk steps' first."""
-    eng = _engine(models("llama"), jit_cache={})
+    eng = _engine(models("llama"), jit_cache={})      # as above
     n = 9 if traffic == "whole_prompts_only" else 40
     for i in range(3):
         eng.generate([GenerationRequest(_prompt(70 + i, n + i),
@@ -219,7 +197,7 @@ def test_the_sizes_an_engine_can_reach(kw, rows, models):
     """``step_rows``: the slots' rows rounded up to 8 and, where a prompt
     can be chunked, the token budget; the multi-tick and the speculative
     step keep their one size."""
-    eng = _engine(models("llama"), jit_cache={}, **kw)
+    eng = _engine(models("llama"), jit_cache={}, **kw)    # as above
     assert eng.step_rows == rows
     assert all(eng.stats["step_programs_%d" % r] == 0 for r in rows)
     eng.generate([GenerationRequest(_prompt(80, 7), max_new_tokens=6)])
@@ -334,12 +312,13 @@ def test_the_state_store_survives_a_size_change_in_both_directions():
     def record(eng):
         seen.append(tuple(np.asarray(a) for a in eng.cache.state))
     tr = SpanTracer(clock=VirtualClock()).enable()
-    eng = _engine(_build("olmo_hybrid", "pallas"), jit_cache={})
+    model = _build("olmo_hybrid", "pallas")
+    eng = _engine(model)
     eng.tracer = tr
     got = _drive(eng, _script(False), after_step=record)
     two, seen = seen, []
-    want = _drive(_engine(_build("olmo_hybrid", "pallas"), single_size=True,
-                          jit_cache={}), _script(False), after_step=record)
+    want = _drive(_engine(model, single_size=True), _script(False),
+                  after_step=record)
     assert [s.tokens for s in got] == [s.tokens for s in want]
     assert len(two) == len(seen) > 15
     for step, (a, b) in enumerate(zip(two, seen)):
@@ -360,9 +339,9 @@ def test_the_routing_record_survives_a_size_change_in_both_directions():
     decode rows (small program) alike."""
     picks = []
     for single in (False, True):
-        model = _build("deepseek_v2")
-        seqs = _drive(_engine(model, single_size=single, jit_cache={}),
-                      _script(False))
+        # a model of its own: the routing record is written on it
+        model = _build("deepseek_v2", build=serving_support.fresh_model)
+        seqs = _drive(_engine(model, single_size=single), _script(False))
         ids = [np.concatenate([s.prompt, np.asarray(s.tokens, np.int32)])
                for s in seqs]
         picks.append([model.served_router_picks(i[None]) for i in ids])

@@ -23,30 +23,29 @@ The load-bearing properties:
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                GenerationRequest, PagedKVCache)
+from paddle_tpu.serving import BlockManager, GenerationRequest, PagedKVCache
 
+import serving_support
+from serving_support import BS, clone as _clone
 from test_metrics_prom import parse_prometheus
 from test_serving_oracle import served_equals_forward
-
-BS = 8  # block_size for every engine here (tiny model, short prompts)
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(21)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=21)  # GQA: nkv=2 < nh=4
 
 
 def _engine(model, prefix_cache=True, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
+    """The shared helper at this file's geometry: 64 positions and NO
+    chunking (the engine's own 512-token chunk never triggers), because
+    the tests below pin the whole-prompt and suffix programs' buckets."""
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("decode_chunk", 1)
     kw.setdefault("prefix_block_size", BS)
-    return ContinuousBatchingEngine(model, prefix_cache=prefix_cache, **kw)
+    return serving_support.engine_as_given(model, prefix_cache=prefix_cache,
+                                           **kw)
 
 
 _SYS = np.random.RandomState(7).randint(0, 256, (20,)).astype(np.int32)
@@ -60,20 +59,13 @@ def _req(tail_seed, n_tail=6, sys_prompt=_SYS, **kw):
     return GenerationRequest(prompt=np.concatenate([sys_prompt, tail]), **kw)
 
 
-def _clone(req):
-    return GenerationRequest(
-        prompt=req.prompt, max_new_tokens=req.max_new_tokens,
-        temperature=req.temperature, top_k=req.top_k,
-        eos_token_id=req.eos_token_id, seed=req.seed)
-
-
 def _reference_run(model, reqs, **kw):
     """The streams to expect: the cache-off engine's, whose greedy ones
     are first held to the forward pass (an oracle outside the serving
     code); a sampled stream is held by being the same with the cache on."""
-    # its own jit dict: a pool of another size is another trace of the
-    # step program, and the tests pin the engine under test at one
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_reference_jit", {}))
+    # (a pool of another size is another trace of the step program, and
+    # the tests pin the engine under test at one: the support module keys
+    # its caches by the pool's size)
     eng = _engine(model, prefix_cache=False, **kw)
     outs = [o.tolist() for o in eng.generate([_clone(r) for r in reqs])]
     for r, out in zip(reqs, outs):
@@ -325,8 +317,7 @@ class TestCompileDiscipline:
         """Waves of hits/misses/divergence leave decode_compilations()
         at 1 and the prefill/suffix compile set closed over the pow2
         grid — block tables are runtime data."""
-        jit = {}
-        eng = _engine(model, jit_cache=jit)
+        eng = _engine(model)
 
         def wave(e):
             outs = e.generate(

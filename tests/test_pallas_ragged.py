@@ -15,8 +15,11 @@ the unification itself must pin:
   query blocks beside one-token spans;
 - the walk in groups of pages: spans and causal diagonals that end inside a
   group, a last group that reaches past ``kvlen`` into a NaN-poisoned pool,
-  quantized planes carried through a group, and the one-token walk of a
-  decode row against the general walk on the same rows;
+  and the one-token walk of a decode row against the general walk on the
+  same rows (``tests/test_pallas_ragged_groups.py``); quantized planes
+  carried through a group (``tests/test_pallas_ragged_quantized.py``): files
+  of their own, every case being a program of its own to lower, so that no
+  file is the floor under the suite's wall (ROADMAP D6);
 - sentinel tables / dead rows / packed padding stay finite and come
   back as exact zeros;
 - the kernel's iteration space: its work list of (query block, row)
@@ -25,8 +28,6 @@ the unification itself must pin:
   a causal cut that differs per query block, holes in the packed
   buffer, an int8 pool) match the oracle.
 """
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,28 +36,8 @@ import pytest
 from paddle_tpu.kernels import pallas_paged_decode, pallas_ragged_attention
 from paddle_tpu.kernels.pallas_ragged_attention import (_query_block,
                                                         _work_list)
-from paddle_tpu.serving.kv_cache import (quantize_kv_rows,
-                                         quantize_kv_rows_fp8)
-
+from serving_support import compiled_once as _compiled_once
 from test_one_timeline import GRID_CASES, _live_pairs
-
-
-def _compiled_once(fn, static=("block_q", "pages", "window")):
-    """``fn`` as ONE jitted program a set of static keywords (and, by
-    ``jax.jit``, a set of shapes): called eagerly, every ``jnp`` op around
-    the kernel (the work list alone is dozens) is a program of its own to
-    compile, which was most of this file's clock (ISSUE 43)."""
-    @functools.lru_cache(maxsize=None)
-    def program(static_kw):
-        return jax.jit(functools.partial(fn, **dict(static_kw)))
-
-    def call(*args, **kw):
-        fixed = tuple(sorted((k, v) for k, v in kw.items()
-                             if k in static and v is not None))
-        return program(fixed)(*args, **{
-            k: v for k, v in kw.items() if k not in static})
-
-    return call
 
 
 ragged_paged_attention_pallas = _compiled_once(
@@ -345,194 +326,44 @@ def test_rows_in_no_span_are_exact_zeros(spans):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("block_q", [256, 32])
-def test_int8_pool_parity_mixed_spans(block_q):
-    """An int8 pool's scale planes ride the same table-indirect fetch as
-    their data blocks: kernel and oracle dequantize the same values."""
-    q, pk, pv, tbl, qs, ql, kl = _mk(len(MIXED), MIXED, 8, 4, 16, 4, 16,
-                                     seed=29)
-    (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
-    got = ragged_paged_attention_pallas(q, k8, v8, tbl, qs, ql, kl,
-                                        block_q=block_q, k_scale=ks,
-                                        v_scale=vs)
-    want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
-                                      k_scale=ks, v_scale=vs)
-    assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("nh,nkv", [(12, 12), (12, 4), (30, 30)])
+def test_ragged_attention_pads_no_head_count(nh, nkv, monkeypatch):
+    """30 heads are the first count in the benchmark that is no multiple of
+    8: the heads are a LEADING dimension of the kernel's head-major query
+    ``[Hkv, T * G, D]``, so 12 or 30 of them need no padded row (the call
+    sees exactly ``nkv`` planes of ``T * G`` rows and returns as many), and
+    the result is the oracle's, MHA and GQA alike."""
+    from paddle_tpu.kernels import pallas_ragged_attention as pra
+    assert not hasattr(pra, "wide_rows")
+    assert not hasattr(pra, "_ragged_padded_heads")
+    seen = []
+    real = pra._ragged_call
 
-
-# ------------------------------------- the walk in groups of several pages
-@pytest.mark.parametrize("pages", [1, 2, 3, 8])
-@pytest.mark.parametrize("H,Hkv", [(8, 2), (16, 16)])
-def test_mixed_spans_match_reference_at_every_group_size(pages, H, Hkv):
-    """One page an update, two, three (the 8-entry table is no whole number
-    of groups) and the whole table: decode rows, chunks and a dead row
-    against the oracle, on query blocks of 4 tokens (16 rows a plane at H 8,
-    the general walk alone; 4 rows at H 16)."""
-    spans = [(1, 128), (5, 37), (1, 3), (16, 16), (0, 0), (9, 100)]
-    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=pages + H)
-    got = ragged_paged_attention_pallas(*args, pages=pages, block_q=4 * H)
-    want = ragged_attention_reference(*args)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-GROUP_EDGE_CASES = {
-    # name: (spans, mb): 16-row blocks, 4 pages an update = 64 keys a group
-    # the span's own length ends inside the second group
-    "kvlen_ends_inside_a_group": ([(1, 70), (6, 90)], 8),
-    # a first chunk over two query blocks of 8 tokens: the diagonal of each
-    # ends inside a group (tokens 0-7 in the first, 8-15 the same, 16-23 in
-    # the second), and the blocks past it hold live rows of the same span
-    "diagonal_ends_inside_a_group": ([(40, 100), (1, 5)], 8),
-    # the last group starts inside the table and reaches past its end
-    "last_group_past_the_table": ([(1, 96), (12, 96)], 6),
-}
-
-
-@pytest.mark.parametrize("case", sorted(GROUP_EDGE_CASES))
-def test_group_edges_over_a_poisoned_pool(case):
-    """Where a group holds more than the pair may see: entries past the
-    pair's last block clamp (to the table's last entry, sentinels into the
-    pool) and are masked by ``kvlen`` and the causal rule; stale rows are
-    NaN, so any that reached a product would show. Rows in no span are
-    exact zeros."""
-    spans, mb = GROUP_EDGE_CASES[case]
-    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, 8, 2, 32, mb, 16,
-                                     seed=len(case), T=60)
-    tbl = np.asarray(tbl).copy()
-    for r, (_, kvlen) in enumerate(spans):
-        tbl[r, -(-kvlen // 16):] = pk.shape[0]      # unmapped -> sentinel
-    tbl = jnp.asarray(tbl)
-    pk = _poison_stale_rows(pk, tbl, kl, ql)
-    pv = _poison_stale_rows(pv, tbl, kl, ql)
-    got = np.asarray(ragged_paged_attention_pallas(
-        q, pk, pv, tbl, qs, ql, kl, block_q=64, pages=4))
-    want = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl))
-    used = sum(n for n, _ in spans)
-    assert np.isfinite(got).all()
-    assert not got[used:].any() and not want[used:].any()
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("H,Hkv,walks", [
-    (16, 4, "one_token"), (32, 8, "one_token"), (16, 16, "one_token"),
-    (8, 1, "one_token"), (12, 4, "general")])
-def test_one_token_walk_equals_general_walk(H, Hkv, walks):
-    """A decode row takes ONE product over the whole pool row, its ``H``
-    query rows cut out of the head-major block and laid block-diagonal in
-    VMEM, where the query block is whole 16-row tiles and no token straddles
-    two (``G`` divides 16); the per-head walk on the whole block otherwise
-    (``G`` 3, or a block of 5 / 17 / 3 tokens). Both walks on the same rows
-    give the same numbers within float32 rounding, and both match the
-    oracle."""
-    from paddle_tpu.kernels.pallas_ragged_attention import (_token_tile,
-                                                            grid_params)
-    G = H // Hkv
-    tile_tokens = 16 // G if 16 % G == 0 else 16
-    assert bool(_token_tile(4 * tile_tokens * G, G)) == (walks == "one_token")
-    assert not _token_tile((tile_tokens + 1) * G, G)
-    spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33), (1, 128)]
-    args = _mk(len(spans), spans, H, Hkv, 32, 8, 16, seed=H,
-               T=4 * tile_tokens)
-    tiling = [grid_params(jnp.float32, 16, Hkv * 32, 8, H, 4 * tile_tokens,
-                          block_q=n * H, head_dim=32)
-              for n in (4 * tile_tokens, tile_tokens + 1)]
-    assert [t["one_token"] for t in tiling] == [walks == "one_token", False]
-    # (a one-byte pool has the same walks)
-    assert grid_params(jnp.int8, 16, Hkv * 32, 8, H, 4 * tile_tokens,
-                       head_dim=32)["one_token"] == (walks == "one_token")
-    own = np.asarray(ragged_paged_attention_pallas(
-        *args, block_q=4 * tile_tokens * H, pages=3))
-    general = np.asarray(ragged_paged_attention_pallas(
-        *args, block_q=(tile_tokens + 1) * H, pages=3))
-    np.testing.assert_allclose(own, general, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(
-        own, np.asarray(ragged_attention_reference(*args)),
-        rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("H,Hkv", [(16, 4), (30, 30)])
-@pytest.mark.parametrize("mode", ["int8", "fp8"])
-def test_one_token_walk_of_a_quantized_pool(mode, H, Hkv):
-    """A decode row over an int8 or fp8 pool takes the one product over the
-    whole pool row too: the group upcast head window by head window, each
-    with column k of its scale plane (fp8: a scale a (block, head) that
-    differs from page to page). The same rows on the per-head walk (a query
-    block that is no whole tile) and the oracle agree; the groups of 3 pages
-    end inside the rows' lengths and past them."""
-    G = H // Hkv
-    tokens = 16 // G
-    spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 33), (1, 128)]
-    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 32, 8, 16,
-                                     seed=H, T=4 * tokens)
-    if mode == "int8":
-        (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
-    else:
-        r = np.random.RandomState(41)
-        k8, v8 = quantize_kv_rows_fp8(pk), quantize_kv_rows_fp8(pv)
-        ks, vs = (jnp.asarray(r.uniform(0.5, 2.0, (pk.shape[0], Hkv)),
-                              jnp.float32) for _ in range(2))
-    own, general = (np.asarray(ragged_paged_attention_pallas(
-        q, k8, v8, tbl, qs, ql, kl, block_q=n * H, k_scale=ks, v_scale=vs,
-        pages=3)) for n in (4 * tokens, tokens + 1))
-    want = np.asarray(ragged_attention_reference(
-        q, k8, v8, tbl, qs, ql, kl, k_scale=ks, v_scale=vs))
-    np.testing.assert_allclose(own, general, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(own, want, rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("H,Hkv", [(32, 8), (16, 16), (30, 30), (12, 4),
-                                   (8, 1), (8, 4)])
-def test_per_head_walk_matches_reference(H, Hkv):
-    """Each KV head's keys by that head's queries only, at the cells' head
-    counts and at groups of 3, 8 and 2: query blocks of 16 tokens over 60
-    packed rows, so the chunks cross blocks, end inside a group of pages and
-    share blocks with one-token spans and a dead row; the pool stale rows
-    NaN; packed rows in no span exact zeros."""
-    spans = [(1, 70), (21, 90), (1, 3), (0, 0), (1, 128), (30, 100)]
-    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 16, 8, 16,
-                                     seed=H + Hkv, T=60)
-    tbl = np.asarray(tbl).copy()
-    for r, (_, kvlen) in enumerate(spans):
-        tbl[r, -(-kvlen // 16):] = pk.shape[0]      # unmapped -> sentinel
-    tbl = jnp.asarray(tbl)
-    pk = _poison_stale_rows(pk, tbl, kl, ql)
-    pv = _poison_stale_rows(pv, tbl, kl, ql)
-    got = np.asarray(ragged_paged_attention_pallas(
-        q, pk, pv, tbl, qs, ql, kl, block_q=16 * H, pages=3))
-    want = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl))
-    used = sum(n for n, _ in spans)
-    assert np.isfinite(got).all()
-    assert not got[used:].any()
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("H,Hkv", [(8, 4), (16, 4)])
-@pytest.mark.parametrize("pages", [1, 3])
-@pytest.mark.parametrize("mode", ["int8", "fp8"])
-def test_quantized_planes_ride_the_group(mode, pages, H, Hkv):
-    """The scale planes of a quantized pool through a group of more than
-    one page: int8's per-row planes lie concatenated over the group's pages,
-    fp8's per-block scale a factor on the block's rows, column k of the
-    plane for KV head k; at groups of 2 and 4."""
-    q, pk, pv, tbl, qs, ql, kl = _mk(len(MIXED), MIXED, H, Hkv, 16, 4, 16,
-                                     seed=31)
-    if mode == "int8":
-        (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
-    else:
-        # the engine's fp8 planes are the constant 1; a scale a (block,
-        # head) that differs from page to page shows a factor misplaced
-        r = np.random.RandomState(37)
-        k8, v8 = quantize_kv_rows_fp8(pk), quantize_kv_rows_fp8(pv)
-        ks, vs = (jnp.asarray(r.uniform(0.5, 2.0, (pk.shape[0], Hkv)),
-                              jnp.float32) for _ in range(2))
-    got = ragged_paged_attention_pallas(
-        q, k8, v8, tbl, qs, ql, kl, block_q=4 * H, k_scale=ks, v_scale=vs,
-        pages=pages)
-    want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
-                                      k_scale=ks, v_scale=vs)
-    assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
+    def call(q_hm, *a, **kw):
+        out = real(q_hm, *a, **kw)
+        seen.append((q_hm.shape, out.shape))
+        return out
+    monkeypatch.setattr(pra, "_ragged_call", call)
+    rng = np.random.RandomState(nh + nkv)
+    hd, bs, nb, mb = 16, 8, 24, 6
+    rows = [(1, 20), (9, 30), (0, 0), (1, 1)]
+    qlen = np.array([q for q, _ in rows], np.int32)
+    kvlen = np.array([k for _, k in rows], np.int32)
+    qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
+    tables = np.full((len(rows), mb), nb, np.int32)
+    perm, used = rng.permutation(nb), 0
+    for r, n in enumerate(kvlen):
+        for b in range(-(-int(n) // bs)):
+            tables[r, b] = perm[used]
+            used += 1
+    T = int(qlen.sum()) + 3
+    q = jnp.asarray(rng.randn(T, nh, hd), jnp.float32)
+    pk = jnp.asarray(rng.randn(2, nb, bs, nkv * hd), jnp.float32)
+    pv = jnp.asarray(rng.randn(2, nb, bs, nkv * hd), jnp.float32)
+    args = (q, pk, pv, tables, qstart, qlen, kvlen)
+    got = pra.ragged_paged_attention_pallas(*args, layer=1)
+    want = pra.ragged_attention_reference(*args, layer=1)
+    live = int(qlen.sum())
+    assert np.abs(np.asarray(got - want))[:live].max() < 1e-4
+    assert not np.asarray(got)[live:].any()
+    assert seen == [((nkv, T * (nh // nkv), hd),) * 2]

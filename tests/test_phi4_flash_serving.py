@@ -27,12 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.phi4_flash import (Phi4FlashConfig,
-                                          Phi4FlashForCausalLM,
-                                          phi4_flash_tiny)
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.models.phi4_flash import Phi4FlashConfig, phi4_flash_tiny
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving import decode as decode_mod
+
+import serving_support
+from serving_support import drain as _run, token_list as _prompt
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -46,9 +46,8 @@ GEOMETRY = dict(num_slots=SLOTS, max_seq_len=128, decode_chunk=1,
 
 
 def _model(kernel="jnp", seed=7, **kw):
-    paddle.seed(seed)
-    return Phi4FlashForCausalLM(phi4_flash_tiny(decode_attention=kernel,
-                                                **kw))
+    return serving_support.model("phi4_flash", seed=seed,
+                                 decode_attention=kernel, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -59,10 +58,6 @@ def model():
 #: the programs of the module's one jnp model, compiled once: every test's
 #: engine shares them (and the recorder inside them, ``_recorder``)
 JIT = {}
-
-
-def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(1, 256, n).tolist()
 
 
 class _Recorder:
@@ -121,13 +116,6 @@ def _deviation(model, seq, rows):
     return float(np.abs(np.stack(rows) - want).max() / np.abs(want).max())
 
 
-def _run(eng, between=None):
-    while eng.has_work():
-        eng.step()
-        if between is not None:
-            between()
-
-
 @pytest.fixture(scope="module")
 def _recorder():
     """The module's one recorder: the shared programs (``JIT``) were traced
@@ -145,8 +133,12 @@ def rec(_recorder):
 
 
 def _engine(model, rec, jit_cache=None):
-    eng = ContinuousBatchingEngine(
-        model, jit_cache=JIT if jit_cache is None else jit_cache, **GEOMETRY)
+    """The shared helper at this file's geometry, on the module's recorded
+    programs (``JIT``) and watched by the recorder inside them."""
+    eng = serving_support.watch_prefill_programs(
+        serving_support.engine_as_given(
+            model, jit_cache=JIT if jit_cache is None else jit_cache,
+            **GEOMETRY))
     rec.watch(eng)
     return eng
 
@@ -170,7 +162,7 @@ def test_engine_logits_equal_reference(case, model, rec):
     if n_prompt > CHUNK:
         # chunks through the unified step, no whole-prompt program
         assert eng.stats["prefill_chunks"] == -(-n_prompt // CHUNK)
-        assert eng.prefill_compilations() == 0
+        assert eng.prefill_programs_asked == 0
     spans = max(1, eng.stats["prefill_chunks"])
     assert eng.stats["state_rows"] == spans + n_new - 1
 
@@ -373,7 +365,7 @@ def test_a_cross_layer_reading_unwritten_rows_fails(model, rec, monkeypatch):
 # --------------------------------------------------------- the three stores
 def test_three_kinds_of_cache(model):
     c = model.config
-    eng = ContinuousBatchingEngine(model, jit_cache={}, **GEOMETRY)
+    eng = serving_support.engine_as_given(model, **GEOMETRY)
     bs = GEOMETRY["prefix_block_size"]
     assert c.num_hidden_layers == 8 and c.num_kv_layers == 1
     # ONE pool layer: the middle full layer's, a row a token
@@ -390,8 +382,8 @@ def test_three_kinds_of_cache(model):
     wk, wv = eng.cache.window
     assert wk.shape == wv.shape == (2, SLOTS, ring, bs,
                                     c.num_key_value_heads * c.head_dim)
-    long = ContinuousBatchingEngine(
-        model, jit_cache={}, **{**GEOMETRY, "max_seq_len": 1024})
+    long = serving_support.engine_as_given(
+        model, **{**GEOMETRY, "max_seq_len": 1024})
     assert long.cache.window_bytes_per_slot == eng.cache.window_bytes_per_slot
     assert eng.cache.window_bytes_per_slot == 2 * wk[:, 0].size * 4
     assert eng.cache.state_bytes_per_slot == (states[:, 0].size
@@ -439,12 +431,12 @@ SWITCHES = (dict(quantize_weights=True), dict(tp=2), dict(fused_tick=True),
 def test_every_other_switch_raises_by_name(switch, model):
     geometry = {**GEOMETRY, **switch}
     with pytest.raises(ValueError, match="self_layers"):
-        ContinuousBatchingEngine(model, jit_cache={}, **geometry)
+        serving_support.engine_as_given(model, **geometry)
 
 
 def test_dispatch_args_split_the_kernel_by_layer_kind(model):
-    eng = ContinuousBatchingEngine(
-        model, jit_cache={}, **{**GEOMETRY, "max_seq_len": 1024})
+    eng = serving_support.engine_as_given(
+        model, **{**GEOMETRY, "max_seq_len": 1024})
     qstart = np.array([0, 1, 0], np.int32)
     qlen = np.array([1, 32, 0], np.int32)
     kvlen = np.array([900, 800, 0], np.int32)
@@ -464,7 +456,9 @@ def test_dispatch_args_split_the_kernel_by_layer_kind(model):
 def test_the_decode_only_program_has_no_chunk_scan():
     """The plan gives the small program one-token spans only, so it launches
     the in-place update and not the chunked scan (traced, never run)."""
-    eng = ContinuousBatchingEngine(_model("pallas"), jit_cache={}, **GEOMETRY)
+    # programs of its own: the module's recorder may be patched in
+    eng = serving_support.engine_as_given(_model("pallas"), jit_cache={},
+                                          **GEOMETRY)
     R = eng.num_slots
 
     def zeros(shape, dtype=np.int32):

@@ -29,28 +29,21 @@ The acceptance matrix:
   the ``class-headroom`` router stays pure/deterministic.
 """
 import json
-import time
 import urllib.error
 import urllib.request
 
-import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ClassTable, ContinuousBatchingEngine,
-                                FIFOScheduler, GenerationRequest,
+from paddle_tpu.serving import (ClassTable, FIFOScheduler, GenerationRequest,
                                 PolicyScheduler, PriorityClass,
                                 VirtualClock)
 from paddle_tpu.serving.policy import select_victims, victim_key
 from paddle_tpu.serving.server import serve
 
+import serving_support
+from serving_support import (BS, CHUNK, S_MAX, SLOTS, prompt as _prompt,
+                             wait_until)
 from test_metrics_prom import parse_prometheus
-
-BS = 8       # KV block size
-CHUNK = 16   # chunked-prefill budget
-SLOTS = 2
-S_MAX = 96
 
 #: the canonical three-way split the README documents
 SPEC = dict(classes="latency:1,standard,batch*",
@@ -62,12 +55,7 @@ SPEC_NO_RESERVE = dict(SPEC, classes="latency,standard,batch*")
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA tiny, pallas decode
-
-
-def _prompt(seed, n=12):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33)  # GQA, pallas decode
 
 
 def _req(ps, n=12, **kw):
@@ -84,14 +72,9 @@ def _clone(r, drop_class=False):
 
 
 def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("max_seq_len", S_MAX)
-    kw.setdefault("decode_chunk", 1)
+    """The shared helper, with a trie unless told otherwise."""
     kw.setdefault("prefix_cache", True)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
+    return serving_support.engine(model, **kw)
 
 
 def _baseline(model, reqs, **kw):
@@ -362,8 +345,9 @@ class TestEnginePolicy:
                      seed=123, priority_class="batch"),
                 _req(8, n=8, max_new_tokens=4, priority_class="latency")]
         want = [_baseline(model, [r])[0] for r in reqs]
-        eng = _engine(model, step_clock=clk, jit_cache={},
+        eng = _engine(model, step_clock=clk,
                       priority_classes=ClassTable.parse(**SPEC_NO_RESERVE))
+        traced = eng.decode_compilations()  # by the baselines, just above
         b1, b2 = eng.submit(_clone(reqs[0])), eng.submit(_clone(reqs[1]))
         for _ in range(3):          # both batch rows running mid-decode
             eng.step()
@@ -381,7 +365,7 @@ class TestEnginePolicy:
         got = [s.tokens for s in (b1, b2, lat)]
         assert got == want              # byte-identical incl. the victim
         assert eng.stats["restores"] >= 1
-        assert eng.decode_compilations() == 1
+        assert eng.decode_compilations() == traced  # the episode adds none
         assert eng.cache.num_free == eng.num_slots
 
     def test_equals_never_displace_equals(self, model):
@@ -537,14 +521,13 @@ class TestPolicyHTTP:
                 for i in range(SLOTS)]
         waiter = gw.submit(_req(55, max_new_tokens=2,
                                 priority_class="latency"))
-        deadline = time.monotonic() + 10
         rows = []
-        while time.monotonic() < deadline:
-            rows = json.loads(_get(policy_server,
-                                   "/debug/requests"))["requests"]
-            if len(rows) >= 2:
-                break
-            time.sleep(0.01)
+
+        def listed():
+            rows[:] = json.loads(_get(policy_server,
+                                      "/debug/requests"))["requests"]
+            return len(rows) >= 2
+        wait_until(listed, "two requests in the table")
         by_class = {}
         for row in rows:
             assert "class" in row and "slo_slack_s" in row

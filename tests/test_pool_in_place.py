@@ -20,10 +20,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.kernels.pallas_ragged_attention import (
-    ragged_paged_attention_pallas)
+from paddle_tpu.kernels import pallas_ragged_attention
 from paddle_tpu.serving import decode as decode_mod
 from paddle_tpu.serving.block_manager import BlockManager
+
+from serving_support import compiled_once
+
+# one program for the stacked pool (``layer`` is a traced argument) and one
+# for a layer cut out, not one a call
+ragged_paged_attention_pallas = compiled_once(
+    pallas_ragged_attention.ragged_paged_attention_pallas)
 
 L, HID, NH, HD, FFN, VOCAB = 3, 32, 16, 8, 48, 64
 R, MB, BS = 4, 3, 4             # slots, table entries a slot, rows a block
@@ -138,11 +144,11 @@ def test_appends_land_at_their_layer_and_nowhere_else(nkv, kv_dtype):
     assert (np.abs(written) < 1e4).all()
 
 
-@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
-@pytest.mark.parametrize("nkv", [8, 16], ids=["gqa8", "mha16"])
-def test_layer_of_the_stack_reads_as_its_slice_alone(nkv, kv_dtype):
-    """Bitwise: the kernel on ``layer=l`` of the stored pool against the same
-    kernel on layer ``l`` cut out and handed over as a pool of its own."""
+@functools.lru_cache(maxsize=None)
+def _each_layer_both_ways(nkv, kv_dtype):
+    """``[(the kernel on layer l of the stored pool, the kernel on layer l
+    cut out and handed over as a pool of its own)]``, run once for the
+    module: two programs a (heads, dtype), whichever case asks first."""
     tables, qstart, qlen, kvlen, _, _, _, history = _step_inputs()
     # every position a span attends over must hold a value
     history = {0: 6, 1: 7, 2: S_TOT}
@@ -160,10 +166,28 @@ def test_layer_of_the_stack_reads_as_its_slice_alone(nkv, kv_dtype):
             vd[layer].reshape(NB, BS, nkv, HD), *meta,
             k_scale=None if ks is None else ks[layer],
             v_scale=None if vs is None else vs[layer])
-        np.testing.assert_array_equal(_bits(got), _bits(alone))
-        assert (np.abs(np.asarray(got, np.float32)) < 1e4).all()
-        outs.append(np.asarray(got, np.float32))
-    # and the layers are told apart: each holds other values
+        outs.append((np.asarray(got), np.asarray(alone)))
+    return outs
+
+
+@pytest.mark.parametrize("layer", range(L))
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("nkv", [8, 16], ids=["gqa8", "mha16"])
+def test_layer_of_the_stack_reads_as_its_slice_alone(nkv, kv_dtype, layer):
+    """Bitwise: the kernel on ``layer=l`` of the stored pool against the same
+    kernel on layer ``l`` cut out and handed over as a pool of its own."""
+    got, alone = _each_layer_both_ways(nkv, kv_dtype)[layer]
+    np.testing.assert_array_equal(_bits(got), _bits(alone))
+    assert (np.abs(got.astype(np.float32)) < 1e4).all()
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("nkv", [8, 16], ids=["gqa8", "mha16"])
+def test_the_layers_of_the_stack_are_told_apart(nkv, kv_dtype):
+    """Each layer holds other values, so a call that read a neighbour's
+    blocks could not have passed for its own."""
+    outs = [got.astype(np.float32)
+            for got, _ in _each_layer_both_ways(nkv, kv_dtype)]
     assert not np.array_equal(outs[0], outs[1])
     assert not np.array_equal(outs[1], outs[2])
 

@@ -23,30 +23,29 @@ import zlib
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                GenerationRequest, PrefixCache)
+from paddle_tpu.serving import BlockManager, GenerationRequest, PrefixCache
 
+import serving_support
+from serving_support import BS, clone as _clone
 from test_metrics_prom import parse_prometheus
-
-BS = 8  # block_size for every engine here (tiny model, short prompts)
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(21)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=21)  # GQA: nkv=2 < nh=4
 
 
 def _engine(model, prefix_cache=True, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
+    """The shared helper at this file's geometry: 64 positions and NO
+    chunking (the engine's own 512-token chunk never triggers), because
+    the tests below pin the whole-prompt and suffix programs' buckets."""
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("decode_chunk", 1)
     if prefix_cache:
         kw.setdefault("prefix_block_size", BS)
-    return ContinuousBatchingEngine(model, prefix_cache=prefix_cache, **kw)
+    return serving_support.engine_as_given(model, prefix_cache=prefix_cache,
+                                           **kw)
 
 
 _SYS = np.random.RandomState(7).randint(0, 256, (20,)).astype(np.int32)
@@ -58,13 +57,6 @@ def _req(tail_seed, n_tail=6, sys_prompt=_SYS, **kw):
         0, 256, (n_tail,)).astype(np.int32)
     kw.setdefault("max_new_tokens", 6)
     return GenerationRequest(prompt=np.concatenate([sys_prompt, tail]), **kw)
-
-
-def _clone(req):
-    return GenerationRequest(
-        prompt=req.prompt, max_new_tokens=req.max_new_tokens,
-        temperature=req.temperature, top_k=req.top_k,
-        eos_token_id=req.eos_token_id, seed=req.seed)
 
 
 def _donate(pc, tokens, content=None):
@@ -280,8 +272,7 @@ class TestCompileDiscipline:
         bucket/group grid is warm a repeat wave adds ZERO prefill /
         suffix traces (the compile sets are closed over geometry, not
         traffic history)."""
-        jit = {}
-        eng = _engine(model, jit_cache=jit)  # ample pool: steady state
+        eng = _engine(model)  # ample pool: steady state
 
         def wave(e):
             outs = e.generate(
@@ -309,8 +300,9 @@ class TestCompileDiscipline:
         # traffic: cold prompts bucket to {16, 32}, suffixes to {8, 16},
         # groups to {1, 2} -> at most 4 cold + 4 suffix shapes total, vs
         # ~15 per wave if shapes leaked per-request. The smaller pool is
-        # another shape of the step program: its own jit dict.
-        eng2 = _engine(model, jit_cache={}, prefix_blocks=4)
+        # another shape of the step program: its own jit dict (the support
+        # module keys its caches by the pool's size).
+        eng2 = _engine(model, prefix_blocks=4)
         assert wave(eng2) == first
         assert wave(eng2) == first
         assert wave(eng2) == first
@@ -368,17 +360,13 @@ class TestConstruction:
         """Passing another engine's PrefixCache with mismatched pool
         geometry fails fast at __init__, not mid-serving in XLA."""
         donor = _engine(model)
-        ok = ContinuousBatchingEngine(  # matching geometry: accepted
-            model, num_slots=2, max_seq_len=64, prefix_block_size=BS,
-            prefix_cache=donor.prefix_cache,
-            jit_cache=model.__dict__["_serving_jit"])
+        # matching geometry: accepted
+        ok = _engine(model, prefix_cache=donor.prefix_cache)
         assert ok.prefix_cache is donor.prefix_cache
-        paddle.seed(5)
-        other = LlamaForCausalLM(llama_tiny(hidden_size=32))  # head_dim 8
+        other = serving_support.model("llama", seed=5,
+                                      hidden_size=32)      # head_dim 8
         with pytest.raises(ValueError, match="geometry"):
-            ContinuousBatchingEngine(other, num_slots=2, max_seq_len=64,
-                                     prefix_block_size=BS,
-                                     prefix_cache=donor.prefix_cache)
+            _engine(other, prefix_cache=donor.prefix_cache)
 
     def test_prefix_blocks_zero_rejected_not_defaulted(self, model):
         with pytest.raises(ValueError, match="prefix_blocks must be >= 1"):

@@ -15,48 +15,22 @@ properties (the streams themselves are held to ``model.forward`` in
 """
 import itertools
 
-import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine, FIFOScheduler,
-                                GenerationRequest)
+from paddle_tpu.serving import FIFOScheduler, GenerationRequest
 
-BS = 8      # block size
-CHUNK = 16  # 2 blocks per chunk
+import serving_support
+from serving_support import CHUNK, engine as _engine, prompt as _prompt
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33)  # GQA: nkv=2 < nh=4
 
 
 def _req(ps, n=40, **kw):
     kw.setdefault("max_new_tokens", 6)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 class TestOneLaunch:

@@ -8,28 +8,28 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (ContinuousBatchingEngine, GenerationRequest,
-                                FIFOScheduler, PagedKVCache)
+from paddle_tpu.serving import (GenerationRequest, FIFOScheduler,
+                                PagedKVCache)
+
+import serving_support
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(21)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=21)  # GQA: nkv=2 < nh=4
 
 
 def _engine(model, **kw):
-    # share jitted programs across engines like model.generate does, so
-    # the module's tests compile each decode program once
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
+    """The shared helper at 48 positions and otherwise the ENGINE's own
+    defaults (decode fused 8 steps a call, no chunking): what
+    ``model.generate`` builds, which these tests hold the engine to."""
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 48)
-    return ContinuousBatchingEngine(model, **kw)
+    return serving_support.engine_as_given(model, **kw)
 
 
 def _prompt(seed, n=8):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.prompt(seed, n)
 
 
 def _solo(model, req, **ekw):
@@ -83,9 +83,8 @@ class TestDecodePathEquivalence:
         one seed (token-exact, GQA included)."""
         outs = {}
         for attn in ("pallas", "jnp"):
-            paddle.seed(33)
-            m = LlamaForCausalLM(llama_tiny(decode_attention=attn))
-            eng = ContinuousBatchingEngine(m, num_slots=2, max_seq_len=48)
+            eng = _engine(serving_support.model("llama", seed=33,
+                                                decode_attention=attn))
             outs[attn] = eng.generate([
                 GenerationRequest(prompt=_prompt(3), max_new_tokens=8),
                 GenerationRequest(prompt=_prompt(4), max_new_tokens=8,
@@ -181,7 +180,11 @@ class TestCompileOnce:
     def test_decode_compiles_once_across_request_mixes(self, model):
         """One decode trace serves every (max_new, temperature, top_k)
         mix — the knob arrays are runtime values, not trace constants."""
-        # fresh jit cache: count only this (num_slots, max_seq_len)'s traces
+        # programs of its own: the count is over every n_steps of this
+        # geometry, and the module's other engines fuse 8 steps a call. On
+        # the jnp attention path, the cheapest step program there is to lower
+        model = serving_support.model("llama", seed=21,
+                                      decode_attention="jnp")
         eng = _engine(model, decode_chunk=1, jit_cache={})
         eng.generate([GenerationRequest(prompt=_prompt(14), max_new_tokens=4)])
         assert eng.decode_compilations() == 1

@@ -25,14 +25,12 @@ import inspect
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.models.olmoe import OlmoeForCausalLM, olmoe_tiny
 from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
 
+import serving_support
+from serving_support import BS, CHUNK, drain as _drain, engine as _engine
+
 TOLERANCE = 1e-4
-BS = 8          # KV block
-CHUNK = 16      # two blocks per prefill chunk
 
 
 def served_equals_forward(model, prompt, tokens, tolerance=TOLERANCE):
@@ -57,33 +55,16 @@ def served_equals_forward(model, prompt, tokens, tolerance=TOLERANCE):
 
 @pytest.fixture(scope="module")
 def llama():
-    paddle.seed(28)
-    return LlamaForCausalLM(llama_tiny())   # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=28)   # GQA: nkv=2 < nh=4
 
 
 @pytest.fixture(scope="module")
 def olmoe():
-    paddle.seed(7)
-    return OlmoeForCausalLM(olmoe_tiny())
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
+    return serving_support.model("olmoe", seed=7)
 
 
 def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(1, 256, (n,)).astype(np.int32)
-
-
-def _drain(eng):
-    while eng.has_work():
-        eng.step()
+    return serving_support.prompt(seed, n, low=1)
 
 
 # Each case drives one engine and returns the (prompt, served tokens) pairs

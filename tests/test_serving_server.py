@@ -23,15 +23,14 @@ import time
 import urllib.error
 import urllib.request
 
-import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.server import (QueueFullError, ServingGateway,
                                        ServingHTTPServer, serve)
 
+import serving_support
+from serving_support import wait_until
 from test_metrics_prom import parse_prometheus
 
 NUM_SLOTS, S_MAX, MAX_QUEUE = 2, 128, 4
@@ -39,8 +38,14 @@ NUM_SLOTS, S_MAX, MAX_QUEUE = 2, 128, 4
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(21)
-    return LlamaForCausalLM(llama_tiny())  # GQA tiny, pallas decode path
+    return serving_support.model("llama", seed=21)  # GQA, pallas decode
+
+
+def _engine(model):
+    """The shared helper at what ``serve()`` is given below: 128 positions
+    and the ENGINE's own chunk and block, one step a call."""
+    return serving_support.engine_as_given(
+        model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1)
 
 
 @pytest.fixture(scope="module")
@@ -59,15 +64,12 @@ def server(model):
 
 
 def _prompt(seed, n=8):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).tolist()
+    return serving_support.prompt(seed, n).tolist()
 
 
 def _direct(model, req):
     """The oracle: the same request straight through the engine."""
-    eng = ContinuousBatchingEngine(
-        model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1,
-        jit_cache=model.__dict__.setdefault("_serving_jit", {}))
-    out = eng.generate([req])[0]
+    out = _engine(model).generate([req])[0]
     return out.tolist(), out.finish_reason
 
 
@@ -195,10 +197,7 @@ class TestCancellation:
         assert len(got) == 3 and len(got) + len(tail) < 100
         ids, reason = bystander.result()
         assert ids.tolist() == want and reason == "length"
-        deadline = time.monotonic() + 5
-        while eng.cache.num_free != free0 and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert eng.cache.num_free == free0  # both slots back
+        wait_until(lambda: eng.cache.num_free == free0, "the slots back")
 
     def test_http_client_disconnect_cancels(self, server):
         """Dropping the SSE connection mid-stream cancels the request:
@@ -218,15 +217,10 @@ class TestCancellation:
         resp.fp.readline(), resp.fp.readline()
         resp.close()
         conn.close()
-        deadline = time.monotonic() + 10
-        while (eng.stats["cancelled"] == cancelled0
-               and time.monotonic() < deadline):
-            time.sleep(0.02)
+        wait_until(lambda: eng.stats["cancelled"] != cancelled0,
+                   "the cancellation")
         assert eng.stats["cancelled"] == cancelled0 + 1
-        deadline = time.monotonic() + 5
-        while eng.cache.num_free != free0 and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert eng.cache.num_free == free0
+        wait_until(lambda: eng.cache.num_free == free0, "the slots back")
 
 
 class TestDeadlines:
@@ -239,10 +233,7 @@ class TestDeadlines:
         choice = doc["choices"][0]
         assert choice["finish_reason"] == "timeout"
         assert 0 < len(choice["token_ids"]) < 119  # partial output kept
-        deadline = time.monotonic() + 5
-        while eng.cache.num_free != free0 and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert eng.cache.num_free == free0
+        wait_until(lambda: eng.cache.num_free == free0, "the slots back")
 
     def test_queued_timeout_never_claims_slot(self, server):
         """A request whose deadline expires while still queued times out
@@ -382,9 +373,7 @@ class TestCompileOnce:
         lengths, a cancellation, and a timeout over HTTP leave
         ``decode_compilations() == 1`` — serving adds zero retraces."""
         from paddle_tpu.serving.server.gateway import ServingGateway
-        eng = ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX, decode_chunk=1,
-            jit_cache={})  # fresh cache: count only this engine's traces
+        eng = _engine(model)
         gw = ServingGateway(eng, max_queue=8)
         srv = ServingHTTPServer(gw, port=0).start()
         try:

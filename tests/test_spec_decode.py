@@ -21,49 +21,26 @@ decoding"). The load-bearing properties:
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                Drafter, FaultPlan, GenerationRequest,
-                                ModelDrafter, NgramDrafter, PagedKVCache,
-                                FIFOScheduler)
+from paddle_tpu.serving import (BlockManager, Drafter, FaultPlan,
+                                GenerationRequest, NgramDrafter,
+                                PagedKVCache, FIFOScheduler)
 
-BS = 8       # KV block size
-CHUNK = 16   # chunked-prefill budget (2 blocks)
+import serving_support
+from serving_support import (BS, clone as _clone, engine as _engine,
+                             model_drafter as _model_drafter,
+                             prompt as _prompt)
+
 SPEC_K = 3
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA tiny, pallas decode
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", {})  # isolated: decode_compilations()==1
-    # pins need identical pool geometry per cache (see PR-7 notes)
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=33)  # GQA, pallas decode
 
 
 def _req(ps, n=20, **kw):
     kw.setdefault("max_new_tokens", 8)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 class _Seq:
@@ -229,9 +206,8 @@ class TestTransparency:
         (the chunk term of the max dominates both) but differing in
         spec_k trace two DIFFERENT verify programs — each engine must
         count exactly its own (the spec_len key-filter regression)."""
-        cache = {}
-        a = _engine(model, spec_decode=True, spec_k=2, jit_cache=cache)
-        b = _engine(model, spec_decode=True, spec_k=3, jit_cache=cache)
+        a = _engine(model, spec_decode=True, spec_k=2)
+        b = _engine(model, spec_decode=True, spec_k=3)
         assert a._spec_budget == b._spec_budget   # the hazard is real
         a.generate([_req(91, max_new_tokens=3)])
         b.generate([_req(92, max_new_tokens=3)])
@@ -248,7 +224,7 @@ class TestTransparency:
         want = [o.tolist() for o in _engine(model).generate(
             [_req(11, max_new_tokens=12), _req(12, max_new_tokens=12)])]
         eng = _engine(model, spec_decode=True, spec_k=SPEC_K,
-                      drafter=ModelDrafter(model))
+                      drafter=_model_drafter(model))
         launches = {"n": 0}
         orig = eng._spec_fn
         eng._spec_fn = lambda: (launches.__setitem__(
@@ -268,7 +244,7 @@ class TestTransparency:
         base = _engine(model).generate(
             [_req(21, max_new_tokens=24, eos_token_id=3)])
         eng = _engine(model, spec_decode=True, spec_k=SPEC_K,
-                      drafter=ModelDrafter(model))
+                      drafter=_model_drafter(model))
         outs = eng.generate([_req(21, max_new_tokens=24, eos_token_id=3)])
         assert [o.tolist() for o in outs] == [b.tolist() for b in base]
         assert outs[0].finish_reason == base[0].finish_reason
@@ -298,7 +274,7 @@ class TestRollbackAccounting:
 
     def test_cancel_mid_verify_restores_pool(self, model):
         eng = _engine(model, spec_decode=True, spec_k=SPEC_K,
-                      drafter=ModelDrafter(model))
+                      drafter=_model_drafter(model))
         pool = eng.cache.pool
         nfree0 = pool.num_free
         seq = eng.submit(_req(41, max_new_tokens=40))
@@ -346,12 +322,11 @@ class TestFaultInterplay:
                      max_new_tokens=8)]
         want = [o.tolist() for o in _engine(model).generate(
             [_clone(r) for r in reqs])]
-        cache = {}
-        drafter = ModelDrafter(model)
+        drafter = _model_drafter(model)
 
         def factory():
             return _engine(model, spec_decode=True, spec_k=SPEC_K,
-                           drafter=drafter, jit_cache=cache)
+                           drafter=drafter)
 
         plan = FaultPlan().at_step(4, "nan")
         gw = ServingGateway(factory(), engine_factory=factory,
@@ -377,7 +352,7 @@ class TestFaultInterplay:
         want = _engine(model).generate(
             [_req(71, max_new_tokens=14)])[0].tolist()
         eng = _engine(model, spec_decode=True, spec_k=SPEC_K,
-                      drafter=ModelDrafter(model))
+                      drafter=_model_drafter(model))
         seq = eng.submit(_req(71, max_new_tokens=14))
         for _ in range(3):
             eng.step()
@@ -401,12 +376,11 @@ class TestMetricsSurface:
 
         from paddle_tpu.profiler.metrics import SPEC_ACCEPT_BUCKETS
         from paddle_tpu.serving.server import ServingGateway
-        cache = {}
-        drafter = ModelDrafter(model)
+        drafter = _model_drafter(model)
 
         def factory():
             return _engine(model, spec_decode=True, spec_k=SPEC_K,
-                           drafter=drafter, jit_cache=cache)
+                           drafter=drafter)
 
         gw = ServingGateway(factory(), engine_factory=factory,
                             start=False)

@@ -28,36 +28,28 @@ The acceptance matrix:
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                GenerationRequest, HostTier, PrefixCache)
+from paddle_tpu.serving import (BlockManager, GenerationRequest, HostTier,
+                                PrefixCache)
 from paddle_tpu.serving.fleet import EngineFleet
 from paddle_tpu.serving.kv_cache import tier_compilations
 
+import serving_support
+from serving_support import BS, CHUNK, clone as _clone
 from test_metrics_prom import parse_prometheus
 from test_serving_oracle import served_equals_forward
 
-BS = 8       # KV block size
-CHUNK = 16   # chunked-prefill budget (2 blocks)
 TIER = 1 << 24   # a generous host budget: LRU never trims in the legs
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(29)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=29)  # GQA: nkv=2 < nh=4
 
 
 def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("max_seq_len", 96)
-    kw.setdefault("decode_chunk", 1)
+    """The shared helper, with a trie unless told otherwise."""
     kw.setdefault("prefix_cache", True)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
+    return serving_support.engine(model, **kw)
 
 
 #: two 2-block system-prompt families; under a 2-block trie budget only
@@ -89,13 +81,6 @@ def _thrash(rounds=3):
     return reqs
 
 
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             seed=r.seed, eos_token_id=r.eos_token_id)
-
-
 def _serial(eng, reqs):
     """One request at a time, so trie pressure peaks per publish and
     the spill/readmit order is deterministic."""
@@ -112,7 +97,7 @@ class TestTierTransparency:
         cache-disabled tokens, greedy and sampled, whose greedy ones are
         the forward pass's argmax."""
         reqs = _thrash()
-        cold = _engine(model, prefix_cache=False, jit_cache={})
+        cold = _engine(model, prefix_cache=False)
         want = _serial(cold, reqs)
         for r, out in zip(reqs, want):
             if r.temperature <= 0:

@@ -24,59 +24,33 @@ paged KV pool partitioned per shard. The load-bearing properties:
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler.cost import CostObservatory
 from paddle_tpu.quantization import (collective_wire_bytes,
                                      quantized_psum_int8)
-from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
+from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan
 from paddle_tpu.serving.server.gateway import ServingGateway
 
+import serving_support
+from serving_support import (BS, CHUNK, S_MAX, SLOTS, clone as _clone,
+                             engine as _engine, prompt as _prompt)
 from test_metrics_prom import parse_prometheus
-
-BS = 8      # block size
-CHUNK = 16  # 2 blocks per chunk
-SLOTS = 2
-S_MAX = 96
 
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(33)
-    return LlamaForCausalLM(llama_tiny())  # GQA: nkv=2 < nh=4
+    return serving_support.model("llama", seed=33)  # GQA: nkv=2 < nh=4
 
 
 @pytest.fixture(scope="module")
 def mha_model():
-    paddle.seed(34)
-    return LlamaForCausalLM(llama_tiny(num_key_value_heads=4))  # tp=4-able
-
-
-def _engine(model, **kw):
-    kw.setdefault("jit_cache", model.__dict__.setdefault("_serving_jit", {}))
-    kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("max_seq_len", S_MAX)
-    kw.setdefault("decode_chunk", 1)
-    kw.setdefault("prefix_block_size", BS)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return ContinuousBatchingEngine(model, **kw)
-
-
-def _prompt(seed, n):
-    return np.random.RandomState(seed).randint(0, 256, (n,)).astype(np.int32)
+    return serving_support.model("llama", seed=34,
+                                 num_key_value_heads=4)    # tp=4-able
 
 
 def _req(ps, n=12, **kw):
     kw.setdefault("max_new_tokens", 5)
     return GenerationRequest(prompt=_prompt(ps, n), **kw)
-
-
-def _clone(r):
-    return GenerationRequest(prompt=r.prompt,
-                             max_new_tokens=r.max_new_tokens,
-                             temperature=r.temperature, top_k=r.top_k,
-                             eos_token_id=r.eos_token_id, seed=r.seed)
 
 
 #: the hit/miss/chunked matrix: greedy shorts, a seeded-sampled row,
@@ -230,10 +204,8 @@ class TestCollectiveAccounting:
         sharded gateway (fp > 0, int8 an explicit 0 — both series
         exist), and ``/debug/profile`` carries the per-layer
         collective-bytes column."""
-        jit = model.__dict__.setdefault("_serving_jit", {})
-
         def factory():
-            return _engine(model, tp=2, jit_cache=jit)
+            return _engine(model, tp=2)
 
         gw = ServingGateway(factory(), engine_factory=factory,
                             max_queue=8, start=False)
@@ -340,6 +312,10 @@ class TestTPValidation:
         program key of the tp=2 engine carries the ("tp2", dtype) tail
         while tp=1 keys stay byte-identical to the pre-TP spelling (no
         tag — banked baselines can't have drifted)."""
+        # programs of its own (jnp path, the cheapest to lower): the
+        # assertions are on what each engine ADDS to the cache
+        model = serving_support.model("llama", seed=33,
+                                      decode_attention="jnp")
         jit = {}
         e1 = _engine(model, tp=1, jit_cache=jit)
         e1.generate([_req(11, max_new_tokens=2)])
@@ -357,13 +333,15 @@ class TestTPValidation:
         dicts: (tp, collective_dtype) joins the fleet geometry tuple —
         same memory-note discipline as the kv8/w8 tags."""
         from paddle_tpu.serving.fleet import EngineFleet
-        model.__dict__.pop("_serving_jit_fleet", None)
+        # the model is the process's: other files' fleets hang their
+        # programs on it, so read what this fleet adds and pop nothing
+        jits = model.__dict__.setdefault("_serving_jit_fleet", {})
+        before = set(jits)
         fleet = EngineFleet(model, replicas=1, num_slots=SLOTS,
                             max_seq_len=S_MAX, prefill_chunk=CHUNK,
                             prefix_block_size=BS, tp=2,
                             collective_dtype="int8", start=False)
-        jits = model.__dict__["_serving_jit_fleet"]
-        (geom,) = jits.keys()
+        (geom,) = set(jits) - before
         # tail of the geometry tuple: (tp, collective_dtype,
         # fused_tick, collective_overlap)
         assert geom[-4:] == (2, "int8", False, False)
@@ -402,15 +380,12 @@ class TestTPLifecycle:
         engine and recomputed per-shard KV from host token state.
         0 requests lost."""
         reqs = _traffic()
-        jit = model.__dict__.setdefault("_serving_jit", {})
         base = [o.tolist() for o in
-                _engine(model, tp=2, prefix_cache=True,
-                        jit_cache=jit).generate(
+                _engine(model, tp=2, prefix_cache=True).generate(
                     [_clone(r) for r in reqs])]
 
         def factory():
-            return _engine(model, tp=2, prefix_cache=True,
-                           jit_cache=jit)
+            return _engine(model, tp=2, prefix_cache=True)
 
         plan = FaultPlan().at_step(1, "transient") \
                           .at_step(3, "fatal").at_step(6, "nan")

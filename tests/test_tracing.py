@@ -36,15 +36,13 @@ import urllib.request
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
-from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.profiler.tracing import (NULL_SPAN, TID_ENGINE,
                                          TID_GATEWAY, TID_REQ0, SpanTracer)
-from paddle_tpu.serving import (ContinuousBatchingEngine, FaultPlan,
-                                GenerationRequest, VirtualClock)
+from paddle_tpu.serving import FaultPlan, GenerationRequest, VirtualClock
 from paddle_tpu.serving.server import (ServingGateway, TraceBusyError,
                                        serve)
 
+import serving_support
 from test_metrics_prom import parse_prometheus
 
 NUM_SLOTS, S_MAX = 2, 256
@@ -52,8 +50,7 @@ NUM_SLOTS, S_MAX = 2, 256
 
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(31)
-    return LlamaForCausalLM(llama_tiny())
+    return serving_support.model("llama", seed=31)
 
 
 def _reqs(n=3, max_new=5, plen=8, seed0=100):
@@ -69,12 +66,14 @@ def _reqs(n=3, max_new=5, plen=8, seed0=100):
     return out
 
 
-def _engine(model, tracer=None, jit_cache=None, **kw):
+def _engine(model, tracer=None, **kw):
+    """The shared helper with a tracer hung on, at this file's geometry:
+    256 positions and the ENGINE's own chunk and block, which is what the
+    ``server`` fixture's ``serve()`` builds and the spans below describe."""
     kw.setdefault("num_slots", NUM_SLOTS)
     kw.setdefault("max_seq_len", S_MAX)
     kw.setdefault("decode_chunk", 1)
-    eng = ContinuousBatchingEngine(
-        model, jit_cache=jit_cache if jit_cache is not None else {}, **kw)
+    eng = serving_support.engine_as_given(model, **kw)
     eng.tracer = tracer
     return eng
 
@@ -263,18 +262,17 @@ class TestEngineTracing:
         assert dec["ts"] == 0.0         # since capture epoch
 
     def test_tracing_never_changes_tokens_and_off_is_silent(self, model):
-        jit = {}
         reqs = _reqs(3, max_new=6)
         base = [o.tolist() for o in
-                _engine(model, jit_cache=jit).generate(reqs)]
+                _engine(model).generate(reqs)]
         # attached-but-disabled: no events, identical streams
         tr_off = SpanTracer()
-        eng_off = _engine(model, tracer=tr_off, jit_cache=jit)
+        eng_off = _engine(model, tracer=tr_off)
         assert [o.tolist() for o in eng_off.generate(reqs)] == base
         assert tr_off.events() == []
         # recording: identical streams, compile-once intact
         tr_on = SpanTracer().enable()
-        eng_on = _engine(model, tracer=tr_on, jit_cache=jit)
+        eng_on = _engine(model, tracer=tr_on)
         assert [o.tolist() for o in eng_on.generate(reqs)] == base
         assert tr_on.events()
         assert eng_on.decode_compilations() == 1
@@ -325,10 +323,9 @@ class TestSLOSubstrate:
     @pytest.mark.slow  # 5 s rebuild duplicate: test_slo_histograms_strict_parse
     # above is the default SLO-histogram rep (870s cap)
     def test_slo_histograms_accumulate_across_rebuild(self, model):
-        jit = {}
 
         def factory():
-            return _engine(model, jit_cache=jit)
+            return _engine(model)
 
         plan = FaultPlan().at_step(2, "fatal")
         gw = ServingGateway(factory(), engine_factory=factory,
@@ -368,18 +365,16 @@ def _chaos_workload():
     return reqs
 
 
-def _chaos_run(model, jit, reqs, with_plan, trace):
+def _chaos_run(model, reqs, with_plan, trace):
     """One full supervised serving pass under a VirtualClock; the fault
     plan (when on) exercises transient retry, pool preemption, fatal
     rebuild, NaN recompute and a hung-step watchdog rebuild."""
     clk = VirtualClock()
 
     def factory():
-        return ContinuousBatchingEngine(
-            model, num_slots=NUM_SLOTS, max_seq_len=S_MAX,
-            decode_chunk=1, prefix_cache=True, prefix_block_size=8,
-            prefill_chunk=32, spec_decode=True, spec_k=3,
-            step_clock=clk, jit_cache=jit)
+        return _engine(model, prefix_cache=True, prefix_block_size=8,
+                       prefill_chunk=32, spec_decode=True, spec_k=3,
+                       step_clock=clk)
 
     plan = None
     if with_plan:
@@ -408,21 +403,20 @@ class TestDeterministicChaosTrace:
     @pytest.mark.slow  # 6 s chaos-trace duplicate: tracing-off token identity and
     # the chaos byte-identity pins elsewhere run by default (870s cap)
     def test_chaos_spec_trace_byte_stable_and_complete(self, model):
-        jit = {}            # one jit cache: identical config all runs
         reqs = _chaos_workload()
         # fault-free baseline, tracing OFF (also warms every program)
-        base, _, _, base_eng, _ = _chaos_run(model, jit, reqs,
+        base, _, _, base_eng, _ = _chaos_run(model, reqs,
                                              with_plan=False, trace=False)
         assert all(r in ("stop", "length") for _, r in base)
         # warm pass WITH the plan (recovery-path prefill buckets may
         # compile here; the compared replays below must both run warm,
         # or the watchdog's compile exemption could classify the hung
         # step differently between them)
-        _chaos_run(model, jit, reqs, with_plan=True, trace=True)
+        _chaos_run(model, reqs, with_plan=True, trace=True)
         outs1, tr1, gw1, eng1, plan1 = _chaos_run(
-            model, jit, reqs, with_plan=True, trace=True)
+            model, reqs, with_plan=True, trace=True)
         outs2, tr2, gw2, eng2, plan2 = _chaos_run(
-            model, jit, reqs, with_plan=True, trace=True)
+            model, reqs, with_plan=True, trace=True)
         # token streams: byte-identical to the fault-free baseline —
         # tracing observes, recovery recomputes, neither changes a token
         assert outs1 == base and outs2 == base
@@ -592,46 +586,14 @@ class TestDebugEndpointsHTTP:
 
 # -------------------------------------------------------- profiler CLI
 class TestProfilerCLI:
-    @pytest.fixture(scope="class")
-    def trace_dir(self):
-        import tempfile
+    """The FILE path (a Chrome trace) is held by test_cost_observatory's
+    ``TestProfilerCLIChrome``; a device trace DIRECTORY is the benchmark's
+    to read (``benchmark/xplane_reduce.py``, ``benchmark/tests``)."""
 
-        import jax
-        import jax.numpy as jnp
-        d = tempfile.mkdtemp(prefix="profcli_test_")
-        x = jnp.ones((64, 64))
-        f = jax.jit(lambda a: jnp.tanh(a @ a).sum())
-        f(x).block_until_ready()
-        jax.profiler.start_trace(d)
-        for _ in range(3):
-            f(x).block_until_ready()
-        jax.profiler.stop_trace()
-        return d
-
-    def _run(self, argv):
+    def test_a_directory_exits_one_and_names_the_reducer(self, tmp_path):
         from paddle_tpu.profiler.__main__ import main
         buf = io.StringIO()
         with contextlib.redirect_stdout(buf):
-            rc = main(argv)
-        return rc, buf.getvalue()
-
-    def test_text_table(self, trace_dir):
-        rc, out = self._run([trace_dir, "--top", "5"])
-        assert rc == 0
-        assert "total_ms" in out and "avg_us" in out
-        # CPU traces carry ops on host planes: the fallback announces
-        # itself rather than silently printing nothing
-        assert "no device planes" in out
-
-    def test_json_output_and_top(self, trace_dir):
-        rc, out = self._run([trace_dir, "--json", "--top", "3"])
-        assert rc == 0
-        doc = json.loads(out)
-        assert 0 < len(doc["rows"]) <= 3
-        assert all({"name", "total_ms", "count", "avg_us"} <= set(r)
-                   for r in doc["rows"])
-
-    def test_empty_dir_exits_nonzero(self, tmp_path):
-        rc, out = self._run([str(tmp_path)])
+            rc = main([str(tmp_path)])
         assert rc == 1
-        assert "no events parsed" in out
+        assert "benchmark/xplane_reduce.py" in buf.getvalue()
