@@ -70,7 +70,13 @@ FULL = dict(
         # into its prompt behind the other 31 slots' decode rows
         "olmo-hybrid chunk 30/30/128": (30, 30, 128, 72, [
             (1, 520 + 55 * i + (i * 37) % 29) for i in range(31)]
-            + [(512, 1200)])},
+            + [(512, 1200)]),
+        # Nemotron-3-Nano's attention blocks: 32 query heads on 2 KV heads,
+        # a query group of 16 where the widest before was 4; a 512-token
+        # chunk 3 k into its prompt behind 31 decode rows at 1.5 k - 6 k
+        "nemotron chunk 32/2/128": (32, 2, 128, 192, [
+            (1, 1536 + 140 * i + (i * 37) % 29) for i in range(31)]
+            + [(512, 3584)])},
     # the cells' decode-only steps, whose packed buffer is the slots alone
     # (8 / 24 / 32 rows and nothing behind them): the chat cell's two live
     # rows of eight, less than one query block; every row live in the others
@@ -81,7 +87,9 @@ FULL = dict(
         "olmoe 24 rows 16/16/128": (16, 16, 128, 64, [
             (1, 100 + 50 * i + (i * 37) % 29) for i in range(24)], 24),
         "olmo-hybrid 32 rows 30/30/128": (30, 30, 128, 72, [
-            (1, 520 + 55 * i + (i * 37) % 29) for i in range(32)], 32)},
+            (1, 520 + 55 * i + (i * 37) % 29) for i in range(32)], 32),
+        "nemotron 32 rows 32/2/128": (32, 2, 128, 192, [
+            (1, 1536 + 140 * i + (i * 37) % 29) for i in range(32)], 32)},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -116,6 +124,9 @@ FULL = dict(
     # layers' call: 40 wide queries over 10 KV pairs of 128, a 512-token
     # chunk 3 k into its prompt beside 15 decode rows, window 512
     ssm=dict(widths=(5120, 16), slots=48, packed=560),
+    # Nemotron-3-Nano's Mamba-2 blocks: (heads, a head's channels, groups,
+    # state size), the slots and the packed rows of its cell's step
+    ssd=dict(widths=(64, 64, 8, 128), slots=32, packed=544),
     ragged_window={"window 512 40/10/128": (40, 10, 128, 256, [
         (512, 3584)] + [(1, 1500 + 290 * i + (i * 37) % 29)
                         for i in range(15)], 512)},
@@ -134,7 +145,9 @@ REHEARSAL = dict(
         "chunk 4/2/32": (4, 2, 32, 8, [(1, 150), (48, 200), (1, 33), (0, 0)]),
         "decode 16/16/32": (16, 16, 32, 8, [
             (1, 20 + 9 * i) for i in range(6)]),
-        "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
+        "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)]),
+        "chunk 16/1/32": (16, 1, 32, 8, [(1, 70), (40, 100), (0, 0),
+                                         (1, 1)])},
     ragged_decode_only={
         "8 rows 4/2/32": (4, 2, 32, 8, [
             (1, 150), (0, 0), (0, 0), (1, 33), (0, 0), (0, 0), (0, 0),
@@ -153,6 +166,7 @@ REHEARSAL = dict(
         "chunk at 200": [(1, 150), (40, 200), (0, 0), (1, 1)]}),
     gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
     ssm=dict(widths=(256, 16), slots=6, packed=150),
+    ssd=dict(widths=(4, 8, 2, 16), slots=6, packed=150),
     ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
         (48, 200), (1, 150), (1, 33), (0, 0)], 40)},
     ragged_handover={"hand-over 4/2/32": (4, 2, 32, 24, [
@@ -1046,6 +1060,53 @@ def phase_kernels(rehearse):
     _agree("ssm_chunk_scan y", np.asarray(got[0])[5:T - 3],
            np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
     _agree("ssm_chunk_scan state", got[1], want[1], TOL_GDN, errors)
+
+    # ---- the Mamba-2 recurrence: both kernels against the recurrence ----
+    from paddle_tpu.kernels import ssd as ssdk
+    H, P, G, N = size["ssd"]["widths"]
+    R, T = size["ssd"]["slots"], size["ssd"]["packed"]
+    rng = np.random.RandomState(41)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (T, H))
+                            ).astype(np.float32))
+    xh, bm, cm = rand(T, H, P), rand(T, G, N), rand(T, G, N)
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)).astype(np.float32))
+    store = rand(2, R, H, P, N)
+    # the decode-only step: every slot has a row, one starts a sequence over
+    # a stored state that is NaN
+    live = np.ones(R, bool)
+    fresh = np.zeros(R, bool)
+    fresh[2] = True
+    got = jax.jit(lambda *x: ssdk.ssd_recurrent_update(
+        *x, layer=1, live=live, fresh=fresh))(
+            xh[:R], dt[:R], a, bm[:R], cm[:R], store.at[:, 2].set(jnp.nan))
+    want = reference(lambda *x: ssdk.ssd_reference(
+        *x, layer=1, seg=np.arange(R), first=fresh),
+        xh[:R], dt[:R], a, bm[:R], cm[:R], store)
+    _agree(f"ssd_recurrent_update {R} rows y", got[0], want[0], TOL_GDN,
+           errors)
+    _agree(f"ssd_recurrent_update {R} rows state", got[1][1], want[1][1],
+           TOL_GDN, errors)
+    # a chunk step: decode rows first (not the scan's), then a chunk that
+    # continues its slot's state and a fresh one that starts in the block
+    # where the first ends
+    cut = 5 + (T - 5) * 3 // 5
+    start, length = np.zeros(R, np.int32), np.zeros(R, np.int32)
+    start[3], length[3] = 5, cut - 5
+    start[0], length[0] = cut, T - cut - 3
+    fresh = np.zeros(R, bool)
+    fresh[0] = True
+    seg = np.full(T, R, np.int32)
+    seg[5:cut], seg[cut:T - 3] = 3, 0
+    first = np.zeros(T, bool)
+    first[cut] = True
+    got = jax.jit(lambda *x: ssdk.ssd_chunk_scan(
+        *x, layer=0, start=start, length=length, fresh=fresh))(
+            xh, dt, a, bm, cm, store)
+    want = reference(lambda *x: ssdk.ssd_reference(
+        *x, layer=0, seg=seg, first=first), xh, dt, a, bm, cm, store)
+    _agree("ssd_chunk_scan y", np.asarray(got[0])[5:T - 3],
+           np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
+    _agree("ssd_chunk_scan state", got[1], want[1], TOL_GDN, errors)
 
     import importlib.metadata as md
     _child_report(

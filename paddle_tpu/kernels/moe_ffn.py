@@ -1,5 +1,13 @@
-"""Dropless routed (mixture-of-experts) SwiGLU FFN for the serving step
-programs: exact top-k for every live row, whatever the skew.
+"""Dropless routed (mixture-of-experts) FFN for the serving step programs:
+exact top-k for every live row, whatever the skew. An expert is a SwiGLU of
+three matrices, ``(silu(x W_gate) * (x W_up)) W_down``, or, where the caller
+has no gate matrix (``w_gate`` None), two matrices with a squared ReLU
+between them, ``relu(x W_up)^2 W_down``. The two-matrix expert's ``w_up`` is
+stored by OUTPUT unit, ``[E_held, I, H]``: its minor dimension is then the
+model's hidden size and not ``I``, which need be no whole number of lanes
+(1,856 at Nemotron-3-Nano's widths), and a Mosaic operand whose minor
+dimension is not lane-aligned is copied whole, every expert of every layer, a
+step (3.5 GB there: the compile's memory report, PR 47).
 
 Two functions with one signature. ``moe_ffn_reference`` is the oracle of the
 tests: every expert over every row, masked. ``moe_ffn`` is what the step
@@ -122,16 +130,23 @@ def _prep(h, live):
     return lead, h2, live
 
 
+def relu2(x):
+    """``relu(x)^2``, the two-matrix expert's activation."""
+    r = jnp.maximum(x, 0)
+    return r * r
+
+
 def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
                       renormalize=False, **routing):
-    """h [..., H]; router [H, E]; w_gate, w_up [E_held, H, I]; w_down
-    [E_held, I, H]; live [...] bool (None: every row); ``routing``: the
+    """h [..., H]; router [H, E]; w_gate, w_up [E_held, H, I] (w_gate None:
+    a two-matrix expert, whose w_up is [E_held, I, H]); w_down [E_held, I,
+    H]; live [...] bool (None: every row); ``routing``: the
     module docstring's ``n_group``, ``topk_group``, ``first_held``,
     ``scale``, ``router_bias``. Returns (out [..., H], stats [4]).
     Plain ``jnp``: each held expert runs over every row and is masked by the
     row's weight for it (zero where not picked or the row is dead)."""
     lead, h2, live = _prep(h, live)
-    n_exp = w_gate.shape[0]
+    n_exp = w_up.shape[0]
     w, _, idx, _, stats = _route(h2, router, top_k, live, renormalize,
                                  n_exp, **routing)
     # [T, E + 1] weight of every expert for every row; column E is the bin
@@ -140,7 +155,8 @@ def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
 
     def one_expert(acc, xs):
         wg, wu, wd, col = xs
-        y = jnp.dot(jax.nn.silu(jnp.dot(h2, wg)) * jnp.dot(h2, wu), wd)
+        y = jnp.dot(relu2(jnp.dot(h2, wu.T)) if wg is None
+                    else jax.nn.silu(jnp.dot(h2, wg)) * jnp.dot(h2, wu), wd)
         return acc + col[:, None] * y.astype(jnp.float32), None
 
     out, _ = jax.lax.scan(one_expert, jnp.zeros(h2.shape, jnp.float32),
@@ -159,11 +175,12 @@ def _tiling(k, n):
     return PAIR_TILE, min(k, 2048), min(n, 1024)
 
 
-def _grouped_matmul(xs, w, counts, layer=None):
-    """xs [P, K] rows ordered by group; w [E, K, N]; counts [E] rows a
-    group. Rows past ``sum(counts)`` come back undefined (the caller masks
-    them). With ``layer`` (a traced index), ``w`` is the stack ``[L, E, K,
-    N]`` of a layer scan and the call reads layer ``layer`` of it IN PLACE:
+def _grouped_matmul(xs, w, counts, layer=None, transposed=False):
+    """xs [P, K] rows ordered by group; w [E, K, N] (``transposed``: [E, N,
+    K]); counts [E] rows a group. Rows past ``sum(counts)`` come back
+    undefined (the caller masks them). With ``layer`` (a traced index), ``w``
+    is the stack ``[L, E, ...]`` of a layer scan and the call reads layer
+    ``layer`` of it IN PLACE:
     the stack is viewed as ``L x E`` groups of which only this layer's
     hold rows, and the kernel never visits an empty group. A Mosaic call
     needs its operands whole, so slicing the layer out first costs a copy
@@ -172,7 +189,8 @@ def _grouped_matmul(xs, w, counts, layer=None):
     if _interpret_mode():
         if layer is not None:
             w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-        return jax.lax.ragged_dot(xs, w, counts)
+        return jax.lax.ragged_dot(
+            xs, jnp.swapaxes(w, 1, 2) if transposed else w, counts)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     if layer is not None:
         n_layers, n_exp = w.shape[:2]
@@ -180,7 +198,9 @@ def _grouped_matmul(xs, w, counts, layer=None):
             jnp.zeros(n_layers * n_exp, counts.dtype), counts,
             (layer * n_exp,))
         w = w.reshape((n_layers * n_exp,) + w.shape[2:])
-    return gmm(xs, w, counts, xs.dtype, _tiling(w.shape[1], w.shape[2]))
+    k, n = w.shape[1:][::-1] if transposed else w.shape[1:]
+    return gmm(xs, w, counts, xs.dtype, _tiling(k, n),
+               transpose_rhs=transposed)
 
 
 def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
@@ -199,7 +219,7 @@ def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
         with jax.named_scope("moe_route"):
             w, picks, idx, counts, stats = _route(
                 h2, router, top_k, live, renormalize,
-                w_gate.shape[0 if layer is None else 1], **routing)
+                w_up.shape[0 if layer is None else 1], **routing)
             pairs = rows * top_k
             slots = -(-pairs // PAIR_TILE) * PAIR_TILE
             # pair slots ordered by held expert; dead pairs and picks of an
@@ -208,10 +228,14 @@ def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
             order = jnp.pad(order, (0, slots - pairs))
             xs = jnp.take(h2, order // top_k, axis=0)
         with jax.named_scope("moe_experts"):
-            g = _grouped_matmul(xs, w_gate, counts, layer)
-            u = _grouped_matmul(xs, w_up, counts, layer)
-            y = _grouped_matmul((jax.nn.silu(g) * u).astype(h2.dtype),
-                                w_down, counts, layer)
+            if w_gate is None:
+                mid = relu2(_grouped_matmul(xs, w_up, counts, layer,
+                                            transposed=True))
+            else:
+                g = _grouped_matmul(xs, w_gate, counts, layer)
+                u = _grouped_matmul(xs, w_up, counts, layer)
+                mid = jax.nn.silu(g) * u
+            y = _grouped_matmul(mid.astype(h2.dtype), w_down, counts, layer)
         with jax.named_scope("moe_route"):
             # back to (row, pick) order; slots past the live pairs hold
             # whatever the grouped matmul left there, so select, not scale

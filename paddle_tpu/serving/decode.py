@@ -42,7 +42,12 @@ pairs of a Gated Memory Unit and a cross-attention layer that cache nothing)
 keeps ONE pool layer, the Mamba layers' states and the window layers' rings
 of keys in the stores by slot, and narrows the packed buffer to one row a
 slot after the middle layers (``_sambay_span_forward``,
-``kernels.selective_scan``, the ragged kernel's ``window``).
+``kernels.selective_scan``, the ragged kernel's ``window``). A model whose
+blocks are ONE mixer each (a tree with ``ssd_layers``: units of a Mamba-2
+block, optionally an attention block, then a routed FFN) scans its units, the
+pool holding rows for the attention blocks only, a Mamba-2 block's state and
+its convolution's last inputs in the store by slot (``_mixer_span_forward``,
+``kernels.ssd``).
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
@@ -68,7 +73,7 @@ from ..kernels.dsa import (dsa_attention_pallas, dsa_attention_reference,
                            dsa_index_scores_pallas,
                            dsa_index_scores_reference, dsa_select,
                            selection_bias)
-from ..kernels.moe_ffn import moe_ffn
+from ..kernels.moe_ffn import moe_ffn, relu2
 from ..kernels.pallas_paged_decode import (paged_decode_attention_pallas,
                                            paged_decode_attention_reference)
 from ..kernels.pallas_mla_ragged_attention import (
@@ -79,6 +84,8 @@ from ..kernels.pallas_ragged_attention import (
     ragged_paged_attention_pallas)
 from ..kernels.selective_scan import (ssm_chunk_scan, ssm_recurrent_update,
                                       ssm_reference)
+from ..kernels.ssd import (ssd_chunk_scan, ssd_recurrent_update,
+                           ssd_reference)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -134,9 +141,24 @@ _GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
              "gdn_dt_bias", "gdn_o_norm", "gdn_wo", "w_gate", "w_up",
              "w_down", "attn_out_ln", "ffn_out_ln")
 
+#: a model whose blocks are ONE mixer each (``models.nemotron_h``): UNITS of a
+#: Mamba-2 block, optionally an attention block, then a routed FFN of
+#: two-matrix experts. The routed FFNs' entries are the tree's own, ``[units,
+#: ...]`` (no ``w_gate``: ``kernels.moe_ffn``); the Mamba-2 blocks' lie under
+#: ``ssd_layers`` ``[units, ...]``, the attention blocks' under
+#: ``attn_layers`` ``[attention blocks, ...]``, read at ``attn_at`` ``[units]``:
+#: a unit's attention block's place among them, which is its layer of the KV
+#: pool (-1: the unit has none). Every block normalises its INPUT (``ln`` /
+#: ``moe_ln``).
+_SSD_KEYS = ("ln", "ssd_in", "ssd_dt", "ssd_conv", "ssd_conv_b", "ssd_A_log",
+             "ssd_D", "ssd_dt_b", "ssd_norm", "ssd_out")
+_MIXER_ATTN_KEYS = ("ln", "wq", "wk", "wv", "wo")
+_MIXER_MOE_KEYS = ("moe_ln", "router", "router_bias", "ws_up", "ws_down")
+
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch)
-TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers")
+TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers",
+                                   "ssd_layers")
 
 
 def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1,
@@ -694,6 +716,19 @@ def _span_conv(u, held, conv_w, *, fresh, qstart, qlen, bias=None):
     return up, jnp.where((qlen > 0)[:, None, None], jnp.stack(rows, 1), held)
 
 
+def _rows_conv(a, w, bias, lengths):
+    """The convolution over whole rows ``a [G, S, C]`` from a zero start:
+    ``(silu(conv(a)) [G, S, C], tails [G, taps, C])``, a row's tail its last
+    ``taps`` inputs before ``lengths[g]`` (zeros where the row is shorter)."""
+    S, taps = a.shape[1], w.shape[0] - 1
+    ext = jnp.pad(a, ((0, 0), (taps, 0), (0, 0)))
+    c = conv_silu(a, [ext[:, taps - j:taps - j + S]
+                      for j in range(1, taps + 1)], w, bias)
+    tail = jnp.take_along_axis(
+        ext, (lengths[:, None] + jnp.arange(taps)[None])[..., None], axis=1)
+    return c, tail
+
+
 def gdn_split(u, gdn):
     """The convolved channels ``[.., C]`` as normalised ``q, k [.., heads,
     dk]`` (float32; q carries the ``dk^-0.5``) and ``v [.., heads, dv]``."""
@@ -949,7 +984,6 @@ def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
     layer's ring row ``j`` holds the last position ``p`` with ``p %
     ring_rows == j``."""
     G, S = x.shape[0], x.shape[1]
-    taps = ssm.conv - 1
     cols = jnp.arange(S, dtype=jnp.int32)
     live = cols[None, :] < lengths[:, None]
     rows_g = jnp.arange(G, dtype=jnp.int32)
@@ -963,13 +997,7 @@ def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
         ring + (lengths[:, None] - 1 - ring) // span * span, 0, S - 1)
 
     def conv(a, w, bias):
-        ext = jnp.pad(a, ((0, 0), (taps, 0), (0, 0)))
-        c = conv_silu(a, [ext[:, taps - j:taps - j + S]
-                          for j in range(1, taps + 1)], w, bias)
-        tail = jnp.take_along_axis(
-            ext, (lengths[:, None] + jnp.arange(taps)[None])[..., None],
-            axis=1)
-        return c, tail
+        return _rows_conv(a, w, bias, lengths)
 
     def scan(dt, u, b, c, a):
         def flat(t):
@@ -1168,6 +1196,276 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
 
     x, _ = jax.lax.scan(cross_pair, x, params["cross_layers"])
     return x, pool_k, pool_v, (ss, cs, wk, wv)
+
+
+# ----------------------------------------------- one mixer a block (Nemotron-H)
+# ``models.nemotron_h``'s docstring has the equations. A tree with
+# ``ssd_layers`` is UNITS of a Mamba-2 block, optionally an attention block,
+# then a routed FFN; every block is ``x = x + Mixer(RMSNorm(x))``.
+def _ssd_mixer(hn, lw, *, ssd, eps, conv, scan):
+    """A Mamba-2 mixer on ``hn [B, S, H]``. The program brings ``conv(xbc, w,
+    bias) -> (silu(conv(xbc)) [B, S, C], carry)`` (where the convolution's
+    earlier inputs come from) and ``scan(x, dt, A, b, c) -> (y [B, S, heads,
+    P] float32, carry)`` (where the state comes from and which kernel walks
+    the tokens): x ``[B, S, heads, P]``, dt ``[B, S, heads]``, b, c ``[B, S,
+    groups, N]`` float32, ``A = -exp(A_log) [heads]``. Returns ``(out [B, S,
+    H], (conv carry, scan carry))``. Scopes ``ssd_proj`` (the two
+    projections) and ``ssd_mix`` (convolution, gates, the kernels, the gated
+    norm)."""
+    f32 = jnp.float32
+    B, S = hn.shape[0], hn.shape[1]
+    H, P, G, N = ssd.heads, ssd.head_dim, ssd.groups, ssd.state
+    C, GN = H * P, G * N
+    with jax.named_scope("ssd_proj"):
+        zx = jnp.einsum("bsh,hc->bsc", hn, lw["ssd_in"])
+        # the step leaves in float32: it is the exponent's scale
+        dt = jnp.einsum("bsh,hc->bsc", hn, lw["ssd_dt"],
+                        preferred_element_type=f32)
+    with jax.named_scope("ssd_mix"):
+        # (the convolved channels stay float32: x, B and C meet a float32
+        # state; the stored tail is the projection's own rows, exactly)
+        xbc, conv_carry = conv(zx[..., C:].astype(f32), lw["ssd_conv"],
+                               lw["ssd_conv_b"])
+        x = xbc[..., :C].reshape(B, S, H, P)
+        dt = jax.nn.softplus(dt + lw["ssd_dt_b"].astype(f32))
+        y, scan_carry = scan(
+            x, dt, -jnp.exp(lw["ssd_A_log"].astype(f32)),
+            xbc[..., C:C + GN].reshape(B, S, G, N),
+            xbc[..., C + GN:].reshape(B, S, G, N))
+        y = y + lw["ssd_D"].astype(f32)[:, None] * x
+        y = _gated_group_norm(y.reshape(B, S, C), zx[..., :C],
+                              lw["ssd_norm"], G, eps).astype(hn.dtype)
+    with jax.named_scope("ssd_proj"):
+        out = jnp.einsum("bsc,ch->bsh", y, lw["ssd_out"])
+    return out, (conv_carry, scan_carry)
+
+
+def _gated_group_norm(y, z, w, groups, eps):
+    """Mamba-2's gated norm, float32: the gate BEFORE the norm, the norm by
+    group: ``RMSNorm(y * silu(z))`` over ``groups`` runs of channels, times
+    ``w``."""
+    f32 = jnp.float32
+    y = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    g = y.reshape(y.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    return g.reshape(y.shape) * w.astype(f32)
+
+
+def _mixer_qkv(h, params, at, *, nh, nkv, hd, eps):
+    """The attention block at place ``at`` (traced) of ``attn_layers``: its
+    weights and its projections of ``h [B, S, H]`` after the block's norm,
+    ``(lw, q [B, S, nh, hd], k, v [B, S, nkv, hd])``. ``wq`` is stored by
+    output feature, ``[nh * hd, H]``: the ragged kernel takes the query
+    head-major, XLA therefore computes ``W_q^T`` times the rows, and with the
+    stack read in place under a ``lax.cond`` it would turn a ``[H, nh * hd]``
+    stack whole, every step (a 126 MB copy at Nemotron-3-Nano's sizes: the
+    compile's memory report, PR 47)."""
+    lw = {k: jax.lax.dynamic_index_in_dim(params["attn_layers"][k], at, 0,
+                                          keepdims=False)
+          for k in _MIXER_ATTN_KEYS}
+    B, S = h.shape[0], h.shape[1]
+    hn = _rms(h, lw["ln"], eps)
+    q = jnp.einsum("bsh,dh->bsd", hn, lw["wq"]).reshape(B, S, nh, hd)
+    k = jnp.einsum("bsh,hd->bsd", hn, lw["wk"]).reshape(B, S, nkv, hd)
+    v = jnp.einsum("bsh,hd->bsd", hn, lw["wv"]).reshape(B, S, nkv, hd)
+    return lw, q, k, v
+
+
+def _mixer_moe(hn, lw, params, unit, *, moe, live, return_picks):
+    """A unit's routed FFN on ``hn``: two-matrix experts read in place at
+    ``unit`` of the stacks, plus the shared expert. Returns ``(out,
+    stats)``, stats as ``_decoder_layer``'s."""
+    m, *stats = moe_ffn(hn, lw["router"], None, params["w_up"],
+                        params["w_down"], layer=unit, top_k=moe[0],
+                        live=live, renormalize=moe[1],
+                        return_picks=return_picks,
+                        router_bias=lw["router_bias"],
+                        **dict(zip(_ROUTING_KEYS, moe[2:])))
+    with jax.named_scope("moe_shared"):
+        m = m + jnp.einsum(
+            "bsi,ih->bsh",
+            relu2(jnp.einsum("bsh,hi->bsi", hn, lw["ws_up"])),
+            lw["ws_down"])
+    return m, (tuple(stats) if return_picks else stats[0])
+
+
+def _mixer_units_scan(params, carry, ssd_block, attn_block, moe_block):
+    """The forward of a model whose blocks are one mixer each: ONE scan over
+    the units whose body runs the unit's Mamba-2 block, its attention block
+    where it has one, and its routed FFN. ``ssd_block(carry, lw, unit)`` /
+    ``moe_block(carry, lw, unit)`` return ``(carry, ys)``; ``attn_block(carry,
+    at)`` likewise, ``at`` the unit's place among the attention blocks (-1:
+    none; the ``lax.cond`` on it is the program's, which knows what a
+    skipped block must hand on). Returns ``(carry, ssd ys, attention ys, FFN
+    ys)``, each ``[units, ...]``."""
+    ssd_w = {k: params["ssd_layers"][k] for k in _SSD_KEYS}
+    moe_w = {k: params[k] for k in _MIXER_MOE_KEYS}
+    units = params["attn_at"].shape[0]
+
+    def unit(carry, xs):
+        sw, mw, at, u = xs
+        carry, y_ssd = ssd_block(carry, sw, u)
+        carry, y_attn = attn_block(carry, at)
+        carry, y_moe = moe_block(carry, mw, u)
+        return carry, (y_ssd, y_attn, y_moe)
+
+    carry, ys = jax.lax.scan(
+        unit, carry, (ssd_w, moe_w, params["attn_at"],
+                      jnp.arange(units, dtype=jnp.int32)))
+    return (carry,) + ys
+
+
+def _mixer_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssd, moe,
+                          return_picks=False):
+    """The blocks of a one-mixer-a-block model over an admission group ``x
+    [G, S_pad, H]``: every Mamba-2 block scans from a zero state over each
+    row's real tokens, the attention blocks attend causally, the routed FFNs
+    make pairs for the real tokens only. Returns ``(x, pk, pv [attention
+    blocks, G, S_pad, Hkv, D], (states [units, G, heads, P, N] float32, tails
+    [units, G, conv - 1, C]), moe stats)``: what each cache holds of a
+    sequence; the last as ``_packed_span_forward``'s."""
+    G, S = x.shape[0], x.shape[1]
+    cols = jnp.arange(S, dtype=jnp.int32)
+    live = cols[None, :] < lengths[:, None]
+    rows_g = jnp.arange(G, dtype=jnp.int32)
+
+    def conv(a, w, bias):
+        return _rows_conv(a, w, bias, lengths)
+
+    def scan(xh, dt, a, b, c):
+        def flat(t):
+            return t.reshape((G * S,) + t.shape[2:])
+
+        zero = jnp.zeros((1, G) + xh.shape[2:] + b.shape[-1:], jnp.float32)
+        if ssd.kernel == "pallas":
+            y, st = ssd_chunk_scan(
+                flat(xh), flat(dt), a, flat(b), flat(c), zero, layer=0,
+                start=rows_g * S, length=lengths, fresh=jnp.ones((G,), bool))
+        else:
+            y, st = ssd_reference(
+                flat(xh), flat(dt), a, flat(b), flat(c), zero, layer=0,
+                seg=jnp.where(live, rows_g[:, None], G).reshape(-1),
+                first=jnp.broadcast_to(cols == 0, (G, S)).reshape(-1))
+        return jnp.where(live[..., None, None], y.reshape(xh.shape), 0.0), \
+            st[0]
+
+    def ssd_block(h, lw, _):
+        out, (tail, st) = _ssd_mixer(_rms(h, lw["ln"], eps), lw, ssd=ssd,
+                                     eps=eps, conv=conv, scan=scan)
+        return h + out, (st, tail.astype(h.dtype))
+
+    def attn_block(h, at):
+        def attend(h):
+            with jax.named_scope("mixer_attn"):
+                lw, q, k, v = _mixer_qkv(h, params, at, nh=nh, nkv=nkv,
+                                         hd=hd, eps=eps)
+                o = _o_proj(_attention(q, k, v, causal=True).reshape(
+                    G, S, nh * hd), lw["wo"])
+            return h + o, (k, v)
+
+        def skip(h):
+            z = jnp.zeros((G, S, nkv, hd), h.dtype)
+            return h, (z, z)
+
+        return jax.lax.cond(at >= 0, attend, skip, h)
+
+    def moe_block(h, lw, u):
+        m, stats = _mixer_moe(_rms(h, lw["moe_ln"], eps), lw, params, u,
+                              moe=moe, live=live, return_picks=return_picks)
+        return h + m, stats
+
+    x, kept, (k, v), stats = _mixer_units_scan(params, x, ssd_block,
+                                               attn_block, moe_block)
+    with_attn = jnp.nonzero(params["attn_at"] >= 0,
+                            size=params["attn_layers"]["ln"].shape[0])[0]
+    return (x, jnp.take(k, with_attn, axis=0), jnp.take(v, with_attn, axis=0),
+            kept, stats)
+
+
+def _mixer_span_forward(params, x, pool_k, pool_v, store, kv_attend, *,
+                        seg, pos, qstart, qlen, kvlen, nh, nkv, hd, eps, ssd,
+                        moe, return_picks=False):
+    """The blocks of a one-mixer-a-block model over the packed buffer ``x
+    [1, T, H]`` (``_mixer_units_scan``). The KV pool (attention blocks only)
+    and the state store ``(states [units, R, heads, P, N] float32, tails
+    [units, R, conv - 1, C])`` ride the scan as carry, whole:
+
+    - a Mamba-2 block reads and writes its unit's index of the store at the
+      slots that have a span this step (``_hybrid_span_forward``'s rules: a
+      span whose first position is 0 takes a zero state and a zero tail;
+      spans of one token through ``ssd_recurrent_update``, longer ones
+      through ``ssd_chunk_scan``, which the decode-only program, ``T ==
+      ssd.decode_rows``, leaves out: the plan gave it no chunk);
+    - an attention block appends and attends at its own place in the pool
+      (``kv_attend(pk, pv, at)``), under a ``lax.cond``: a unit without one
+      hands the stream and the pool on as they are;
+    - a routed FFN makes pairs for the live packed rows.
+
+    Returns ``(x, pool_k, pool_v, store, moe stats)``, the last as
+    ``_packed_span_forward``'s."""
+    R, T = qstart.shape[0], x.shape[1]
+    live_tok = seg < R
+    seg_c = jnp.minimum(seg, R - 1)
+    fresh = (kvlen - qlen) == 0
+    one, many = qlen == 1, qlen > 1
+    tok_one = live_tok & jnp.take(one, seg_c)
+    row_at = jnp.clip(qstart, 0, T - 1)
+
+    def ssd_block(carry, lw, idx):
+        h, pk, pv, ss, cs = carry
+
+        def conv(a, w, bias):
+            c, tails = _span_conv(a[0], cs[idx], w, fresh=fresh,
+                                  qstart=qstart, qlen=qlen, bias=bias)
+            return c[None], cs.at[idx].set(tails.astype(cs.dtype))
+
+        def scan(xh, dt, a, b, c):
+            xh, dt, b, c = xh[0], dt[0], b[0], c[0]
+            if ssd.kernel == "pallas":
+                x1, dt1, b1, c1 = (jnp.take(t, row_at, axis=0)
+                                   for t in (xh, dt, b, c))
+                y1, new_ss = ssd_recurrent_update(
+                    x1, dt1, a, b1, c1, ss, layer=idx, live=one, fresh=fresh)
+                y = jnp.take(y1, seg_c, axis=0)
+                if T != ssd.decode_rows:
+                    yn, new_ss = ssd_chunk_scan(
+                        xh, dt, a, b, c, new_ss, layer=idx, start=qstart,
+                        length=jnp.where(many, qlen, 0), fresh=fresh)
+                    y = jnp.where(tok_one[:, None, None], y, yn)
+            else:
+                y, new_ss = ssd_reference(
+                    xh, dt, a, b, c, ss, layer=idx, seg=seg,
+                    first=live_tok & (pos == 0))
+            return jnp.where(live_tok[:, None, None], y, 0.0)[None], new_ss
+
+        out, (cs, ss) = _ssd_mixer(_rms(h, lw["ln"], eps), lw, ssd=ssd,
+                                   eps=eps, conv=conv, scan=scan)
+        return (h + out, pk, pv, ss, cs), None
+
+    def attn_block(carry, at):
+        def attend(hkv):
+            h, pk, pv = hkv
+            with jax.named_scope("mixer_attn"):
+                lw, q, k, v = _mixer_qkv(h, params, at, nh=nh, nkv=nkv,
+                                         hd=hd, eps=eps)
+                attn, (pk, pv) = kv_attend(pk, pv, at)(q, k, v)
+                o = _o_proj(attn.reshape(1, T, nh * hd), lw["wo"])
+            return h + o, pk, pv
+
+        return jax.lax.cond(at >= 0, attend, lambda hkv: hkv,
+                            carry[:3]) + carry[3:], None
+
+    def moe_block(carry, lw, u):
+        h = carry[0]
+        m, stats = _mixer_moe(_rms(h, lw["moe_ln"], eps), lw, params, u,
+                              moe=moe, live=live_tok[None],
+                              return_picks=return_picks)
+        return (h + m,) + carry[1:], stats
+
+    (x, pool_k, pool_v, ss, cs), _, _, stats = _mixer_units_scan(
+        params, (x, pool_k, pool_v) + tuple(store), ssd_block, attn_block,
+        moe_block)
+    return x, pool_k, pool_v, (ss, cs), stats
 
 
 @jax.named_scope("lm_head")
@@ -1558,7 +1856,6 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
     [full layers, G, S_pad, Hkv, D], (states, tails) [linear layers, G,
     ...])``."""
     G, S = x.shape[0], x.shape[1]
-    taps = gdn.conv - 1
     cols = jnp.arange(S, dtype=jnp.int32)
     live = cols[None, :] < lengths[:, None]
     rows_g = jnp.arange(G, dtype=jnp.int32)
@@ -1572,12 +1869,7 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
 
     def linear_layer(h, lw, _):
         def mix(u, g, beta, conv_w):
-            ext = jnp.pad(u, ((0, 0), (taps, 0), (0, 0)))
-            up = conv_silu(u, [ext[:, taps - j:taps - j + S]
-                               for j in range(1, taps + 1)], conv_w)
-            tail = jnp.take_along_axis(
-                ext, (lengths[:, None] + jnp.arange(taps)[None])[..., None],
-                axis=1)
+            up, tail = _rows_conv(u, conv_w, None, lengths)
             q, k, v = gdn_split(up, gdn)
             g = jnp.where(live[..., None], g, 0.0)
             beta = jnp.where(live[..., None], beta, 0.0)
@@ -1610,7 +1902,7 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
                   mla=None, return_picks=False, gdn=None, ssm=None,
-                  dsa=None):
+                  dsa=None, ssd=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -1638,9 +1930,22 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     (``_hybrid_prefill_layers``). A decoder-hybrid-decoder model
     (``self_layers``; ``ssm`` its static numbers) returns ``pk`` / ``pv`` of
     its ONE layer with a row a token and, last, what its Mamba layers' and
-    window layers' stores hold of each row (``_sambay_prefill_layers``).
+    window layers' stores hold of each row (``_sambay_prefill_layers``). A
+    model whose blocks are one mixer each (``ssd_layers``; ``ssd`` its
+    Mamba-2 blocks' static numbers) returns ``pk`` / ``pv`` of its attention
+    blocks, its routed FFNs' summary (and picks) and, last, what its Mamba-2
+    blocks' cache holds of each row (``_mixer_prefill_layers``).
     """
     B, S = ids.shape
+    if ssd is not None:
+        x = jnp.take(params["embed"], ids, axis=0)
+        x, pk, pv, state, stats = _mixer_prefill_layers(
+            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, ssd=ssd,
+            moe=moe, return_picks=return_picks)
+        tok0, keys2 = _first_token(
+            params, _dq_head(params, tied, params["embed"].dtype, a8), x,
+            lengths, keys, temps, top_ks, eps)
+        return (pk, pv, tok0, keys2) + _moe_outputs(stats) + (state,)
     if ssm is not None:
         x = jnp.take(params["embed"], ids, axis=0)
         x, pk, pv, state = _sambay_prefill_layers(
@@ -1728,7 +2033,7 @@ def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
                      mla=None, return_picks=False, gdn=None, ssm=None,
-                     dsa=None):
+                     dsa=None, ssd=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -1753,7 +2058,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
         tied=tied, a8=a8, moe=moe, mla=mla, return_picks=return_picks,
         **({} if gdn is None else {"gdn": gdn}),
         **({} if ssm is None else {"ssm": ssm}),
-        **({} if dsa is None else {"dsa": dsa})))
+        **({} if dsa is None else {"dsa": dsa}),
+        **({} if ssd is None else {"ssd": ssd})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -2088,7 +2394,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
                          moe=None, mla=None, return_picks=False, state=None,
-                         gdn=None, ssm=None, dsa=None):
+                         gdn=None, ssm=None, dsa=None, ssd=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -2111,7 +2417,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
     the store. A decoder-hybrid-decoder model (``ssm``) runs
     ``_sambay_span_forward`` over ``state``, its Mamba and window layers'
-    stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``.
+    stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``. A
+    model whose blocks are one mixer each (``ssd``) runs
+    ``_mixer_span_forward`` over ``state``, its Mamba-2 blocks' store, and
+    returns the store fourth and its routed FFNs' stats fifth.
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
@@ -2157,6 +2466,12 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
 
         return attend
 
+    if ssd is not None:
+        x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
+        return _mixer_span_forward(
+            params, x, pool_k, pool_v, state, kv_attend, seg=seg, pos=pos,
+            qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
+            eps=eps, ssd=ssd, moe=moe, return_picks=return_picks)
     if ssm is not None:
         x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
         return _sambay_span_forward(
@@ -2303,7 +2618,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
                       moe=None, mla=None, return_picks=False, gdn=None,
-                      ssm=None, dsa=None):
+                      ssm=None, dsa=None, ssd=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -2363,7 +2678,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     (the next step's ``prev_toks``) and ``keys'`` the next step's
     ``keys``, both handed on without a host round trip. A hybrid model
     passes ``state``, its linear layers' store (``_hybrid_span_forward``;
-    donated like the pool), and gets it back as the last value.
+    donated like the pool), and gets it back as the last value (after the
+    routing summary, where its FFNs are routed: ``_mixer_span_forward``).
     """
     # dispatch-ahead: a decode row dispatched before the previous step's
     # tokens reached the host takes its input token here, on the device
@@ -2382,10 +2698,12 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
 
     # ----------------------------------- tick 0 (shared packed forward)
     if state is not None:
-        x, pk, pv, state = _packed_span_forward(
+        x, pk, pv, state, *moe_stats = _packed_span_forward(
             params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
             kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-            decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm)
+            decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm,
+            **({} if ssd is None else dict(
+                ssd=ssd, moe=moe, return_picks=return_picks)))
         if ssm is not None:     # x came back one row a slot
             tok0, keys_t0 = _rows_sample(params, head, x[0], keys_in, temps,
                                          top_ks, eps)
@@ -2393,7 +2711,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
             tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen,
                                               keys_in, temps, top_ks, eps)
         keys_out = jnp.where((adopt > 0)[:, None], keys_t0, keys_in)
-        return pk, pv, tok0[None], tok0, keys_out, state
+        return (pk, pv, tok0[None], tok0, keys_out) + _moe_outputs(
+            moe_stats[0] if moe_stats else None) + (state,)
     x, pk, pv, moe_stats = _packed_span_forward(
         params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
         kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
@@ -2433,7 +2752,8 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          collective_dtype="fp", kv_quant=False,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
-                         return_picks=False, gdn=None, ssm=None, dsa=None):
+                         return_picks=False, gdn=None, ssm=None, dsa=None,
+                         ssd=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -2476,10 +2796,11 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             return_picks=return_picks,
             **({} if gdn is None else {"gdn": gdn}),
             **({} if ssm is None else {"ssm": ssm}),
-            **({} if dsa is None else {"dsa": dsa})),
+            **({} if dsa is None else {"dsa": dsa}),
+            **({} if ssd is None else {"ssd": ssd})),
         # argument 18: the stores by slot of a model with recurrent or
         # window layers (absent otherwise)
-        donate_argnums=((1, 2) + ((18,) if gdn is not None or ssm is not None
+        donate_argnums=((1, 2) + ((18,) if (gdn, ssm, ssd) != (None,) * 3
                                   else ()))
         if donate else ())
 

@@ -324,7 +324,8 @@ class ContinuousBatchingEngine:
                    "decode_chunk > 1": int(decode_chunk) > 1,
                    "prefix_cache": bool(prefix_cache)}
             if any(k in self._params
-                   for k in ("wkv_a", "linear_layers", "self_layers")):
+                   for k in ("wkv_a", "linear_layers", "self_layers",
+                             "ssd_layers")):
                 # a latent pool has no heads to scale by and no V side:
                 # the quantized pools' planes and kernels do not apply;
                 # a quantized cache of a model with recurrent or window
@@ -401,8 +402,8 @@ class ContinuousBatchingEngine:
         # their cache is a store by slot beside the pool
         # (``PagedKVCache.state``), and the pool holds rows for the OTHER
         # layers only
-        self._stateful = "linear_layers" in self._params \
-            or "self_layers" in self._params
+        self._stateful = any(k in self._params for k in (
+            "linear_layers", "self_layers", "ssd_layers"))
         kv_layers = c.num_kv_layers if self._stateful \
             else c.num_hidden_layers
         # what a cached token's row is: Hkv heads of head_dim on a K and a
@@ -490,6 +491,10 @@ class ContinuousBatchingEngine:
             g = c.gdn
             state_geometry = (c.num_linear_layers, (g.heads, g.dk, g.dv),
                               g.conv - 1, c.conv_channels)
+        elif "ssd_layers" in self._params:
+            d = c.ssd
+            state_geometry = (c.num_units, (d.heads, d.head_dim, d.state),
+                              d.conv - 1, c.conv_channels)
         elif self._stateful:
             state_geometry = (c.num_ssm_layers,
                               (c.mamba_d_state, c.d_inner),
@@ -917,6 +922,15 @@ class ContinuousBatchingEngine:
             spans = [int(n) for n in qlen if n > 1]
             work.update(state_rows=int((np.asarray(qlen) > 0).sum()),
                         scan_tokens=sum(spans), scan_spans=len(spans))
+        if "ssd_layers" in self._params:
+            # the Mamba-2 kernels' work summed over the step's blocks: the
+            # rows of one token through ``ssd_recurrent_update``, the longer
+            # spans' tokens through ``ssd_chunk_scan``
+            units = self.config.num_units
+            work.update(
+                ssd_update_rows=units * int((np.asarray(qlen) == 1).sum()),
+                ssd_scan_tokens=units * work["scan_tokens"],
+                ssd_scan_spans=units * work["scan_spans"])
         return work
 
     # ------------------------------------------------------------ programs
@@ -937,6 +951,10 @@ class ContinuousBatchingEngine:
             consts["dsa"] = c.dsa
         if "linear_layers" in self._params:
             consts["gdn"] = c.gdn
+        elif "ssd_layers" in self._params:
+            consts["ssd"] = c.ssd._replace(
+                decode_rows=self._decode_rows
+                if len(self._step_rows) == 2 else 0)
         elif self._stateful:
             consts["ssm"] = c.ssm._replace(
                 ring_rows=self._ring_blocks * self.cache.block_size,

@@ -55,6 +55,7 @@ _ARCH = {
     "phi4_flash": ("phi4_flash", "Phi4FlashForCausalLM", "phi4_flash_tiny"),
     "glm_moe_dsa": ("glm_moe_dsa", "GlmMoeDsaForCausalLM",
                     "glm_moe_dsa_tiny"),
+    "nemotron_h": ("nemotron_h", "NemotronHForCausalLM", "nemotron_h_tiny"),
 }
 
 
@@ -228,3 +229,72 @@ def wait_until(done, what="the condition", hang_s=600.0):
     while not done():
         assert time.monotonic() < guard, f"hung waiting for {what}"
         time.sleep(0.005)
+
+
+class LogitsRecorder:
+    """Every program's logits (``decode._head_logits``), in dispatch order,
+    and for every token a sequence is given the row it was sampled from, for
+    a module whose engines have ``slots`` slots and chunks of ``chunk``
+    (a step program's logits are ``[slots, V]``, a whole-prompt group's are
+    not: the tests that use it serve groups of one). Patched in through
+    ``monkeypatch`` for as long as the programs traced with it are kept."""
+
+    def __init__(self, monkeypatch, slots, chunk):
+        from paddle_tpu.serving import decode as decode_mod
+        self.records, self.rows = [], {}
+        self.slots, self.chunk = slots, chunk
+        real = decode_mod._head_logits
+        while hasattr(real, "recorded"):    # never a recorder in a recorder
+            real = real.recorded
+
+        def recording(last_h, head):
+            logits = real(last_h, head)
+            jax.debug.callback(lambda x: self.records.append(np.asarray(x)),
+                               logits, ordered=True)
+            return logits
+
+        recording.recorded = real
+        monkeypatch.setattr(decode_mod, "_head_logits", recording)
+
+    def clear(self):
+        del self.records[:]
+        self.rows.clear()
+        return self
+
+    def watch(self, eng):
+        def on_token(seq, _tok):
+            jax.effects_barrier()
+            rows = self.rows.setdefault(seq.request_id, [])
+            whole = seq.work_len <= self.chunk
+            if len(seq.tokens) == 1 and whole:
+                group = [r for r in self.records
+                         if r.shape[0] != self.slots][-1]
+                rows.append(group[0])    # groups of one in these tests
+                return
+            steps = [r for r in self.records if r.shape[0] == self.slots]
+            rows.append(steps[-2 if eng._inflight is not None
+                              else -1][seq.slot])
+
+        eng.on_token = on_token
+
+
+def reference_logits(ref, model, ids, at, width, config=None):
+    """A plain reference's (``benchmark/reference_*.py``) logits ``[len(at),
+    V]`` at positions ``at`` of ONE sequence, read at one padded ``width``
+    (every layer is causal, so what follows a position is not seen, and the
+    reference compiles once)."""
+    row = np.zeros((1, width), np.int32)
+    row[0, :len(ids)] = ids
+    return np.asarray(ref.logits_at(
+        ref.weights_of(model), ref.hyper_of(config or model.config), row,
+        np.asarray([at], np.int32)))[0]
+
+
+def deviation(ref, model, seq, rows, width):
+    """max |engine logits - reference logits| over ``seq``'s generated
+    positions, as a share of the reference's largest |logit|."""
+    prompt, tokens = list(seq.prompt), list(seq.tokens)
+    want = reference_logits(ref, model, prompt + tokens, [
+        len(prompt) - 1 + k for k in range(len(tokens))], width)
+    assert len(rows) == len(tokens)
+    return float(np.abs(np.stack(rows) - want).max() / np.abs(want).max())

@@ -26,7 +26,7 @@ from paddle_tpu.kernels import (dsa, flash_attention, gated_delta_rule,
                                 moe_ffn, pallas_flash,
                                 pallas_mla_ragged_attention,
                                 pallas_paged_decode, pallas_ragged_attention,
-                                selective_scan)
+                                selective_scan, ssd)
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.profiler.metrics import peak_flops_per_chip
 from paddle_tpu.utils import compile_cache
@@ -58,7 +58,7 @@ def v5e_devices(monkeypatch):
         pytest.skip(f"libtpu gives no v5e:2x2 topology: {why}")
     for mod in (pallas_flash, pallas_paged_decode, pallas_ragged_attention,
                 pallas_mla_ragged_attention, moe_ffn, gated_delta_rule,
-                selective_scan, dsa):
+                selective_scan, dsa, ssd):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     return devices
 
@@ -396,6 +396,66 @@ class TestMosaicCompilesPhi4Flash:
         assert pallas_ragged_attention.grid_params(
             jnp.bfloat16, 32, 10 * hd, mb, heads, self.T,
             head_dim=hd) == dict(block_q=96 * heads, pages=6, one_token=True)
+
+
+class TestMosaicCompilesNemotronH:
+    """Nemotron-3-Nano's kernels at its published widths (Mamba-2 blocks of
+    64 heads x 64 channels in 8 groups, a state of 128, float32 by slot; two
+    matrices an expert at 1,856, no whole number of lanes) and at the serving
+    cell's shapes: 32 slots, 23 Mamba-2 blocks, a packed buffer of 32 + 512
+    rows, 16 held experts a layer."""
+    H, P, G, N, R, LL, T = 64, 64, 8, 128, 32, 23, 544
+
+    def _args(self, v5e, rows):
+        f32 = jnp.float32
+        return (v5e((rows, self.H, self.P), f32), v5e((rows, self.H), f32),
+                v5e((self.H,), f32), v5e((rows, self.G, self.N), f32),
+                v5e((rows, self.G, self.N), f32),
+                v5e((self.LL, self.R, self.H, self.P, self.N), f32))
+
+    def _in_place(self, fn, args):
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the store (1.44 GiB) is aliased in and out, no layer of it copied
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes > 1.4 * 2 ** 30
+        assert mem.temp_size_in_bytes < 32 * 2 ** 20
+
+    def test_the_decode_row_update_in_place(self, v5e):
+        def update(x, dt, a, b, c, st, live, fresh, layer):
+            return ssd.ssd_recurrent_update(
+                x, dt, a, b, c, st, layer=layer, live=live, fresh=fresh)
+        self._in_place(update, self._args(v5e, self.R) + (
+            v5e((self.R,), jnp.bool_), v5e((self.R,), jnp.bool_),
+            v5e((), jnp.int32)))
+
+    def test_the_chunk_scan_in_place(self, v5e):
+        def scan(x, dt, a, b, c, st, start, length, fresh, layer):
+            return ssd.ssd_chunk_scan(
+                x, dt, a, b, c, st, layer=layer, start=start, length=length,
+                fresh=fresh)
+        self._in_place(scan, self._args(v5e, self.T) + (
+            v5e((self.R,), jnp.int32), v5e((self.R,), jnp.int32),
+            v5e((self.R,), jnp.bool_), v5e((), jnp.int32)))
+
+    def test_two_matrix_experts_read_their_stacks_in_place(self, v5e):
+        """``w_up`` by output unit: no operand's minor dimension is the
+        expert width, and neither stack (3.4 GiB each) is copied."""
+        hid, wid, exp = 2688, 1856, 16
+
+        def ffn(h, router, bias, w_up, w_down, layer):
+            return moe_ffn.moe_ffn(
+                h, router, None, w_up, w_down, layer=layer, top_k=6,
+                renormalize=True, first_held=0, scale=2.5,
+                router_bias=bias)[0]
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(ffn).lower(
+                v5e((1, 32, hid)), v5e((hid, 128)),
+                v5e((128,), jnp.float32), v5e((self.LL, exp, wid, hid)),
+                v5e((self.LL, exp, wid, hid)), v5e((), jnp.int32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
 class TestUnifiedStepLeavesThePoolInPlace:

@@ -82,6 +82,7 @@ class TestRaggedKernelParity:
         (8, 2, 64, 4, 32),        # GQA group 4
         (8, 1, 64, 3, 16),        # MQA, small blocks
         (4, 4, 64, 4, 16),        # MHA
+        (32, 2, 32, 4, 16),       # GQA group 16 (Nemotron-3-Nano's 32 on 2)
     ])
     def test_matches_reference_mixed_spans(self, H, Hkv, D, mb, bs):
         """Decode rows, multi-token chunks (1..block and beyond), a

@@ -60,60 +60,14 @@ def model():
 JIT = {}
 
 
-class _Recorder:
-    """Every program's logits (``decode._head_logits``), in dispatch order,
-    and for every token a sequence is given the row it was sampled from."""
-
-    def __init__(self, monkeypatch):
-        self.records, self.rows = [], {}
-        real = decode_mod._head_logits
-        while hasattr(real, "recorded"):    # never a recorder in a recorder
-            real = real.recorded
-
-        def recording(last_h, head):
-            logits = real(last_h, head)
-            jax.debug.callback(lambda x: self.records.append(np.asarray(x)),
-                               logits, ordered=True)
-            return logits
-
-        recording.recorded = real
-        monkeypatch.setattr(decode_mod, "_head_logits", recording)
-
-    def watch(self, eng):
-        def on_token(seq, _tok):
-            jax.effects_barrier()
-            rows = self.rows.setdefault(seq.request_id, [])
-            whole = seq.work_len <= CHUNK
-            if len(seq.tokens) == 1 and whole:
-                group = [r for r in self.records if r.shape[0] != SLOTS][-1]
-                rows.append(group[0])    # groups of one in these tests
-                return
-            steps = [r for r in self.records if r.shape[0] == SLOTS]
-            rows.append(steps[-2 if eng._inflight is not None
-                              else -1][seq.slot])
-
-        eng.on_token = on_token
-
-
 def _reference_logits(model, ids, at, config=None):
-    """The reference's logits ``[len(at), V]`` at positions ``at`` of ONE
-    sequence, read at one padded width (every layer is causal, so what
-    follows a position is not seen, and the reference compiles once)."""
-    row = np.zeros((1, GEOMETRY["max_seq_len"]), np.int32)
-    row[0, :len(ids)] = ids
-    return np.asarray(ref.logits_at(
-        ref.weights_of(model), ref.hyper_of(config or model.config), row,
-        np.asarray([at], np.int32)))[0]
+    return serving_support.reference_logits(
+        ref, model, ids, at, GEOMETRY["max_seq_len"], config)
 
 
 def _deviation(model, seq, rows):
-    """max |engine logits - reference logits| over the generated positions,
-    as a share of the reference's largest |logit|."""
-    prompt, tokens = list(seq.prompt), list(seq.tokens)
-    want = _reference_logits(model, prompt + tokens, [
-        len(prompt) - 1 + k for k in range(len(tokens))])
-    assert len(rows) == len(tokens)
-    return float(np.abs(np.stack(rows) - want).max() / np.abs(want).max())
+    return serving_support.deviation(ref, model, seq, rows,
+                                     GEOMETRY["max_seq_len"])
 
 
 @pytest.fixture(scope="module")
@@ -121,15 +75,13 @@ def _recorder():
     """The module's one recorder: the shared programs (``JIT``) were traced
     with it inside, so it is patched in for the module's whole life."""
     mp = pytest.MonkeyPatch()
-    yield _Recorder(mp)
+    yield serving_support.LogitsRecorder(mp, SLOTS, CHUNK)
     mp.undo()
 
 
 @pytest.fixture
 def rec(_recorder):
-    del _recorder.records[:]
-    _recorder.rows.clear()
-    return _recorder
+    return _recorder.clear()
 
 
 def _engine(model, rec, jit_cache=None):
