@@ -1070,7 +1070,7 @@ def phase_kernels(rehearse):
                             ).astype(np.float32))
     xh, bm, cm = rand(T, H, P), rand(T, G, N), rand(T, G, N)
     a = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)).astype(np.float32))
-    store = rand(2, R, H, P, N)
+    store = rand(2, R, *ssdk.state_shape(H, P, G, N))
     # the decode-only step: every slot has a row, one starts a sequence over
     # a stored state that is NaN
     live = np.ones(R, bool)

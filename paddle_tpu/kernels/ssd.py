@@ -2,9 +2,9 @@
 recurrent state that lives in a store beside the paged KV pool.
 
 A head's state ``S`` is ``[P, N]`` float32 (``P`` the head's channels, ``N``
-the state size, on the lanes), ``H`` heads a (layer, slot). A token ``t`` with
-input ``x_t`` (``[H, P]``), step ``dt_t`` (``[H]``, after the softplus), input
-and output vectors ``B_t``, ``C_t`` (``[G, N]``, one a GROUP of ``H / G``
+the state size), ``H`` heads a (layer, slot). A token ``t`` with input
+``x_t`` (``[H, P]``), step ``dt_t`` (``[H]``, after the softplus), input and
+output vectors ``B_t``, ``C_t`` (``[G, N]``, one a GROUP of ``H / G``
 consecutive heads) and the layer's ``A`` (``[H]``, negative: a SCALAR a head,
 which is what makes the dual form below a matrix product) does, a head
 
@@ -13,10 +13,29 @@ which is what makes the dual form below a matrix product) does, a head
 
 (the skip ``D x_t``, the gate and the norm are the caller's).
 
+**The store's layout** (``state_to_store`` / ``state_from_store`` are its one
+statement): a (layer, slot) holds ``[G, N, (H / G) P]``, a group's ``S^T``
+side by side: the state size on the SUBLANES, the group's channels, head after
+head, on the LANES (``[8, 128, 512]`` at the published 64 / 64 / 8 / 128), as
+the Mamba-1 store does (``kernels.selective_scan``). What varies along the
+lanes of a state vreg is then what a token brings as a lane-dense ROW (``dt
+x`` and the decay ``exp(dt A)``, one value a head repeated over its ``P``
+lanes: a sublane broadcast, which a load does for nothing), and what varies
+along its sublanes (``B``, ``C``) is a column ONE group shares: two lane
+broadcasts a sublane tile serve the group's eight heads, the read-out ``sum_n
+S[n, :] C[n]`` is vreg adds, and ``y`` leaves lane-dense. With ``[H, P, N]``
+(``N`` on the lanes; until PR 48) every state vreg took four cross-lane
+operations: the lane broadcasts of a head's decay and of ``x[p]``, a lane
+reduction for ``y``, and a broadcast-and-select to park it; the update ran at
+24 cycles a vreg, 63 % of what its bytes allow. Now a group's 64 state vregs
+share 32 lane broadcasts.
+
 Three implementations, one semantics:
 
 - ``ssd_reference``: the recurrence token by token over a packed buffer (the
-  oracle; the serving programs' ``decode_attention="jnp"`` path).
+  oracle; the serving programs' ``decode_attention="jnp"`` path), the
+  mathematics above on ``[H, P, N]``, the store read and written through the
+  two helpers.
 - ``ssd_chunk_scan`` (Pallas): the spans of a prefill chunk, from each slot's
   state, in the chunked (dual) form over ``gated_delta_rule``'s work list
   (one entry a (span, block of ``CHUNK`` packed rows it touches), built on
@@ -24,19 +43,22 @@ Three implementations, one semantics:
   running sum inside a block, ``L[i, j] = exp(G_i - G_j)`` for ``j <= i``:
 
       Y = ((C B^T) * L) (dt * X) + exp(G) * (C S0^T)
-      S1 = exp(G_end) S0 + (exp(G_end - G) * dt * X)^T B
+      S1^T = exp(G_end) S0^T + B^T (exp(G_end - G) * dt * X)
 
   four matrix products a head on the MXU (``C B^T`` once a group), where
-  Mamba-1's diagonal state (``kernels.selective_scan``) has none. Decays
-  appear only as differences ``exp(G_i - G_j)`` with ``i >= j``. A row of the
-  block that is another span's takes ``dt`` 0: the state passes it unchanged
-  and it adds nothing. An entry holds ALL the heads (a state of 2 MiB at 64 x
-  64 x 128): the list's dead entries then cost one grid step each, not one a
-  head block.
+  Mamba-1's diagonal state (``kernels.selective_scan``) has none; the two
+  with the state run once for the heads that share a lane tile (a PAIR at
+  ``P`` = 64), on the store's own ``S^T``. Decays appear only as differences
+  ``exp(G_i - G_j)`` with ``i >= j``. A row of the block that is another
+  span's takes ``dt`` 0: the state passes it unchanged and it adds nothing.
+  An entry holds ALL the heads (a state of 2 MiB at 64 x 64 x 128): the
+  list's dead entries then cost one grid step each, not one a head block.
 - ``ssd_recurrent_update`` (Pallas): every decode row of a step in one call,
   one grid step a live row, the state aliased in and out, on the VPU (a
-  rank-one update and a read-out a head: a row reads and writes its state
-  once, 2 x 2 MiB at the published sizes, and is bound by that).
+  rank-one update and a read-out a group: a row reads and writes its state
+  once, 2 x 2 MiB at the published sizes, and is bound by that: with the
+  body taken out the call takes the same time, the copies' own 630-650 GB/s,
+  PERF.md, PR 48).
 
 Entries past the live ones repeat the last live entry's block indices (no
 DMA) and skip the body. A span marked ``fresh`` (its first position is 0)
@@ -47,17 +69,38 @@ Inference-only (no VJP).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gated_delta_rule import (CHUNK, _dot, _dot_nt, _dot_tn, _head_major,
-                               _scan_work, _span_args, scan_work_items)
+from .gated_delta_rule import (CHUNK, _dot, _dot_nt, _head_major, _scan_work,
+                               _span_args, scan_work_items)
 from .pallas_flash import _interpret_mode
 
 _VMEM = 96 * 1024 * 1024
+
+
+# ------------------------------------------------------- the store's layout
+def state_shape(heads, head_dim, groups, state):
+    """What a (layer, slot) of the store holds: ``[G, N, (H / G) P]``."""
+    return (groups, state, heads // groups * head_dim)
+
+
+def state_to_store(s, groups):
+    """``[..., H, P, N] -> [..., G, N, (H / G) P]``."""
+    *lead, H, P, N = s.shape
+    s = s.reshape(*lead, groups, H // groups, P, N)
+    return jnp.moveaxis(s, -1, -3).reshape(*lead, groups, N, -1)
+
+
+def state_from_store(st, heads):
+    """``[..., G, N, (H / G) P] -> [..., H, P, N]``."""
+    *lead, G, N, C = st.shape
+    st = st.reshape(*lead, G, N, heads // G, -1)
+    return jnp.moveaxis(st, -3, -1).reshape(*lead, heads, -1, N)
 
 
 def _token_step(s, x, dt, a, b, c):
@@ -73,7 +116,7 @@ def _token_step(s, x, dt, a, b, c):
 def ssd_recurrence(x, dt, a, b, c, s0=None):
     """One sequence, token by token: x ``[S, H, P]``, dt ``[S, H]``, a
     ``[H]``, b, c ``[S, G, N]``, s0 ``[H, P, N]`` or None (zero). Returns ``(y
-    [S, H, P], s)``, float32."""
+    [S, H, P], s [H, P, N])``, float32."""
     f32 = jnp.float32
     a = a.astype(f32)
     if s0 is None:
@@ -93,10 +136,12 @@ def ssd_reference(x, dt, a, b, c, state, *, layer, seg, first):
     ``seg[t]`` (``R`` = a dead row: nothing is read or written) and
     ``first[t]`` says it is its sequence's position 0 (the slot's state is
     zeroed before it). Decode rows and chunk rows alike, in buffer order. x
-    ``[T, H, P]``, dt ``[T, H]``, a ``[H]``, b, c ``[T, G, N]``, state ``[Ll,
-    R, H, P, N]``. Returns ``(y [T, H, P] float32, state')``."""
+    ``[T, H, P]``, dt ``[T, H]``, a ``[H]``, b, c ``[T, G, N]``, state the
+    store ``[Ll, R, G, N, (H / G) P]``. Returns ``(y [T, H, P] float32,
+    state')``."""
     f32 = jnp.float32
     R = state.shape[1]
+    H, G = x.shape[1], b.shape[1]
     a = a.astype(f32)
     seg = jnp.asarray(seg, jnp.int32)
 
@@ -106,43 +151,62 @@ def ssd_reference(x, dt, a, b, c, state, *, layer, seg, first):
         s, y = _token_step(s, xt, dtt, a, bt, ct)
         return st.at[sg].set(s, mode="drop"), y
 
-    st, y = jax.lax.scan(step, state[layer], tuple(
+    st, y = jax.lax.scan(step, state_from_store(state[layer], H), tuple(
         t.astype(f32) for t in (x, dt, b, c)) + (
             seg, jnp.asarray(first, bool)))
-    return y, state.at[layer].set(st)
+    return y, state.at[layer].set(state_to_store(st, G))
 
 
 # ------------------------------------------------------------ the chunk scan
-def _chunk_math(x, dt, g, cb, b, c, s0):
-    """One block of one head in the dual form (module docstring). x ``[C,
-    P]``, dt, g ``[C, 1]`` (``g = dt A``; a masked row carries 0 in both), cb
-    ``[C, C]`` (``C_i . B_j``, the head's group's), b, c ``[C, N]``, s0 ``[P,
-    N]``. Returns ``(y [C, P], s1 [P, N])``."""
-    C = x.shape[0]
+def _chunk_math(x, dts, gs, cb, bt, c, s0t):
+    """One block of the ``k`` heads of a lane tile in the dual form (module
+    docstring). x ``[C, k P]`` (head after head on the lanes), dts, gs ``k``
+    columns ``[C, 1]`` each (``g = dt A``; a masked row carries 0 in both),
+    cb ``[C, C]`` (``C_i . B_j``, the heads' group's), bt ``[N, C]``, c ``[C,
+    N]``, s0t ``[N, k P]`` (the heads' ``S^T`` side by side). Returns ``(y
+    [C, k P], s1t [N, k P])``."""
+    C, W = x.shape
+    P = W // len(dts)
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     incl = row >= col
     ones = jnp.ones((C, C), jnp.float32)
-    gb = g * ones                                    # [C, C], row i = g_i
-    gi = _dot(incl.astype(jnp.float32), gb)          # gi[i, j] = G_i
-    gj = _dot(ones, jnp.where(row <= col, gb, 0.0))  # gj[i, j] = G_j
-    m = jnp.where(incl, jnp.exp(jnp.where(incl, gi - gj, 0.0)), 0.0)
-    g_col = gi[:, :1]                                # [C, 1] G_i
-    g_end = gi[C - 1:, :1]                           # [1, 1]
-    xdt = x * dt
-    y = _dot(cb * m, xdt) + jnp.exp(g_col) * _dot_nt(c, s0)
-    # (Mosaic broadcasts a [1, 1] along one axis at a time)
-    e_end = jnp.exp(jnp.broadcast_to(g_end, (1, s0.shape[1])))
-    s1 = e_end * s0 + _dot_tn(xdt * jnp.exp(g_end - g_col), b)
-    return y, s1
+    head = jax.lax.broadcasted_iota(jnp.int32, (C, W), 1) // P
+
+    def lanes(cols):    # a value a head -> each over its head's lanes
+        n = cols[0].shape[0]
+        out = jnp.broadcast_to(cols[0], (n, W))
+        for i, t in enumerate(cols[1:], 1):
+            out = jnp.where(head[:n] == i, t, out)
+        return out
+
+    ms, g_cols, g_ends = [], [], []
+    for g in gs:
+        gb = g * ones                                    # [C, C], row i = g_i
+        gi = _dot(incl.astype(jnp.float32), gb)          # gi[i, j] = G_i
+        gj = _dot(ones, jnp.where(row <= col, gb, 0.0))  # gj[i, j] = G_j
+        ms.append(jnp.where(incl, jnp.exp(jnp.where(incl, gi - gj, 0.0)),
+                            0.0))
+        g_cols.append(gi[:, :1])                         # [C, 1] G_i
+        g_ends.append(gi[C - 1:, :1])                    # [1, 1]
+    g_col, g_end = lanes(g_cols), lanes(g_ends)          # [C, W], [1, W]
+    xdt = x * lanes(dts)
+    y = jnp.exp(g_col) * _dot(c, s0t)
+    # a head's own lanes of its own product (the MXU's tile is as wide
+    # whatever the head's share of it)
+    for i, m in enumerate(ms):
+        y = y + jnp.where(head == i, _dot(cb * m, xdt), 0.0)
+    s1t = jnp.exp(g_end) * s0t + _dot(bt, xdt * jnp.exp(g_end - g_col))
+    return y, s1t
 
 
 def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
-                 x_ref, gd_ref, b_ref, c_ref, s_in, y_ref, s_out, *, hg):
+                 x_ref, gd_ref, b_ref, c_ref, s_in, y_ref, s_out, *, hg, k):
     w = pl.program_id(0)
     flags = flag_ref[w]
     live, first = (flags & 1) > 0, (flags & 2) > 0
     fresh, newblk = (flags & 4) > 0, (flags & 8) > 0
+    W = x_ref.shape[-1] // hg * k
 
     @pl.when(first | (w == 0))
     def _load():
@@ -157,16 +221,18 @@ def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
 
         def group(gr, _):
             b, c = b_ref[gr], c_ref[gr]
-            cb = _dot_nt(c, b)
+            cb, bt = _dot_nt(c, b), b.T
             gd = jnp.where(mine, gd_ref[gr], 0.0)
-            for i in range(hg):
-                h = gr * hg + i
-                y, s1 = _chunk_math(x_ref[h], gd[:, i:i + 1],
-                                    gd[:, hg + i:hg + i + 1], cb, b, c,
-                                    s_out[0, 0, h])
-                s_out[0, 0, h] = s1
-                y_ref[h] = jnp.where(mine, y,
-                                     jnp.where(newblk, 0.0, y_ref[h]))
+            for j in range(hg // k):
+                at = slice(j * W, (j + 1) * W)
+                heads = range(j * k, (j + 1) * k)
+                y, s1t = _chunk_math(
+                    x_ref[gr, :, at], [gd[:, i:i + 1] for i in heads],
+                    [gd[:, hg + i:hg + i + 1] for i in heads], cb, bt, c,
+                    s_out[0, 0, gr, :, at])
+                s_out[0, 0, gr, :, at] = s1t
+                y_ref[gr, :, at] = jnp.where(
+                    mine, y, jnp.where(newblk, 0.0, y_ref[gr, :, at]))
             return 0
 
         jax.lax.fori_loop(0, b_ref.shape[0], group, 0)
@@ -190,55 +256,61 @@ def _scan_call(x, dt, a, b, c, state, layer, start, length, fresh,
         for t in (dt, dt * a.astype(f32))], axis=-1)
     gd = jnp.pad(gd, ((0, 0), (0, t_pad - T),
                       (0, max(128 - 2 * hg, 0))))
+    # group-major, a group's channels head after head on the lanes, as the
+    # store holds them
+    xg, bg, cg = (_head_major(t.astype(f32), t_pad, t.shape[-1])
+                  for t in (x.reshape(T, G, hg * P), b, c))
 
-    xh, bh, ch = (_head_major(t.astype(f32), t_pad, t.shape[-1])
-                  for t in (x, b, c))
-
-    def tok(lead, width):
-        return pl.BlockSpec((lead, CHUNK, width),
+    def tok(width):
+        return pl.BlockSpec((G, CHUNK, width),
                             lambda w, blk, *_: (0, blk[w], 0))
 
     st = pl.BlockSpec(
-        (1, 1, H, P, N),
+        (1, 1) + state.shape[2:],
         lambda w, blk, slot, lo, hi, fl, layer: (layer[0], slot[w], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(n_items,),
-        in_specs=[tok(H, P), tok(G, gd.shape[-1]), tok(G, N), tok(G, N), st],
-        out_specs=[tok(H, P), st])
+        in_specs=[tok(hg * P), tok(gd.shape[-1]), tok(N), tok(N), st],
+        out_specs=[tok(hg * P), st])
     y, state = pl.pallas_call(
-        functools.partial(_scan_kernel, hg=hg), grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((H, t_pad, P), f32),
+        # the two state products take the heads that fill a lane tile
+        # together (2 at ``P`` = 64), a divisor of the group's ``hg``
+        functools.partial(_scan_kernel, hg=hg,
+                          k=math.gcd(hg, max(128 // P, 1))),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(xg.shape, f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operand 10 (after the six prefetched scalars): the state store
         input_output_aliases={10: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
         interpret=interpret, name="ssd_chunk_scan",
-    )(*work, layer, xh, gd, bh, ch, state)
-    return jnp.swapaxes(y, 0, 1)[:T], state
+    )(*work, layer, xg, gd, bg, cg, state)
+    return jnp.swapaxes(y, 0, 1)[:T].reshape(T, H, P), state
 
 
 def ssd_chunk_scan(x, dt, a, b, c, state, *, layer, start, length, fresh):
     """The chunked scan of every span with ``length > 0`` (Pallas). x
-    ``[T, H, P]``, dt ``[T, H]``, a ``[H]``, b, c ``[T, G, N]``, state ``[Ll,
-    R, H, P, N]`` float32 (updated in place when donated), start / length /
-    fresh ``[R]`` by slot: the span of slot ``r`` is packed rows ``start[r]
-    .. start[r] + length[r]``. The caller's promise is that no more than ``T
-    // 2`` spans are live (a step's spans of one token are
-    ``ssd_recurrent_update``'s): the work list holds room for as many.
-    Returns ``(y [T, H, P] float32, state')``; rows of ``y`` outside every
-    span are unspecified."""
+    ``[T, H, P]``, dt ``[T, H]``, a ``[H]``, b, c ``[T, G, N]``, state the
+    store ``[Ll, R, G, N, (H / G) P]`` float32 (updated in place when
+    donated), start / length / fresh ``[R]`` by slot: the span of slot ``r``
+    is packed rows ``start[r] .. start[r] + length[r]``. The caller's promise
+    is that no more than ``T // 2`` spans are live (a step's spans of one
+    token are ``ssd_recurrent_update``'s): the work list holds room for as
+    many. Returns ``(y [T, H, P] float32, state')``; rows of ``y`` outside
+    every span are unspecified."""
     return _scan_call(x, dt, a, b, c, state,
                       *_span_args(layer, start, length, fresh),
                       interpret=_interpret_mode())
 
 
 # ------------------------------------------------------ the decode-row update
-def _update_kernel(slot_ref, flag_ref, layer_ref, xt_ref, at_ref, b_ref,
-                   c_ref, s_in, y_ref, s_out, *, H, hg):
+def _update_kernel(slot_ref, flag_ref, layer_ref, x_ref, d_ref, bc_ref,
+                   s_in, y_ref, s_out):
     i = pl.program_id(0)
     flags = flag_ref[i]
     live, fresh = (flags & 1) > 0, (flags & 2) > 0
+    G = s_in.shape[2]
 
     @pl.when(jnp.logical_not(live) & (i == 0))
     def _through():     # no live row at all: hand the mapped block back
@@ -247,24 +319,22 @@ def _update_kernel(slot_ref, flag_ref, layer_ref, xt_ref, at_ref, b_ref,
     @pl.when(live)
     def _compute():
         r = slot_ref[i]
-        xt, at = xt_ref[r], at_ref[r]               # [P, lanes >= H]
-        b, c = b_ref[r], c_ref[r]                   # [G, N]
-        lane = jax.lax.broadcasted_iota(jnp.int32, xt.shape, 1)
-        y = jnp.zeros(xt.shape, jnp.float32)
-        for h in range(H):
-            g = h // hg
-            s = jnp.where(fresh, 0.0, s_in[0, 0, h]) * at[:, h:h + 1] \
-                + xt[:, h:h + 1] * b[g:g + 1]
-            s_out[0, 0, h] = s
-            y = jnp.where(lane == h,
-                          jnp.sum(s * c[g:g + 1], axis=1, keepdims=True), y)
-        y_ref[0] = y
+        bc = bc_ref[r]                              # [N, lanes >= 2 G]
+        for g in range(G):
+            row = pl.ds(g, 1)
+            # (a select, not a product: 0 * NaN of a slot's former tenant)
+            s = jnp.where(fresh, 0.0, s_in[0, 0, g]) * d_ref[r, row, :] \
+                + bc[:, g:g + 1] * x_ref[r, row, :]
+            s_out[0, 0, g] = s
+            y_ref[r, row, :] = jnp.sum(s * bc[:, G + g:G + g + 1], axis=0,
+                                       keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _update_call(x, dt, a, b, c, state, layer, live, fresh, interpret):
     R, H, P = x.shape
     G, N = b.shape[1:]
+    C = H // G * P
     f32, i32 = jnp.float32, jnp.int32
     # live rows first, in slot order; the rest repeat the last live row
     order = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(i32)
@@ -274,49 +344,44 @@ def _update_call(x, dt, a, b, c, state, layer, live, fresh, interpret):
     slots = order[idx]
     flags = ((jnp.arange(R) < n_live).astype(i32)
              + 2 * fresh[slots].astype(i32))
-    lanes = -(-H // 128) * 128
     dt = dt.astype(f32)
-
-    def columns(t):     # [R, H, P] -> [R, P, lanes]: a head's column
-        return jnp.pad(jnp.swapaxes(t, 1, 2),
-                       ((0, 0), (0, 0), (0, lanes - H)))
-
-    xt = columns(dt[..., None] * x.astype(f32))
-    at = columns(jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
-                                  (R, H, P)))
+    # a row's input and decay, lane-dense as the store's channels lie
+    xt = (dt[..., None] * x.astype(f32)).reshape(R, G, C)
+    decay = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
+                             (R, H, P)).reshape(R, G, C)
+    # a row's B then C, a group's vector a column: [R, N, lanes]
+    bc = jnp.swapaxes(jnp.concatenate([b, c], axis=1).astype(f32), 1, 2)
+    bc = jnp.pad(bc, ((0, 0), (0, 0), (0, -2 * G % 128)))
 
     def whole(*shape):  # resident whole: rows are picked by slot in-kernel
         return pl.BlockSpec(shape, lambda i, *_: (0,) * 3)
 
-    st = pl.BlockSpec((1, 1, H, P, N),
+    st = pl.BlockSpec((1, 1, G, N, C),
                       lambda i, slot, fl, layer: (layer[0], slot[i], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(R,),
-        in_specs=[whole(R, P, lanes), whole(R, P, lanes), whole(R, G, N),
-                  whole(R, G, N), st],
-        out_specs=[pl.BlockSpec((1, P, lanes),
-                                lambda i, slot, *_: (slot[i], 0, 0)), st])
+        in_specs=[whole(R, G, C), whole(R, G, C), whole(*bc.shape), st],
+        out_specs=[whole(R, G, C), st])
     y, state = pl.pallas_call(
-        functools.partial(_update_kernel, H=H, hg=H // G),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, P, lanes), f32),
+        _update_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((R, G, C), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operand 7 (after the three prefetched scalars): the state store
-        input_output_aliases={7: 1},
+        # operand 6 (after the three prefetched scalars): the state store
+        input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
         interpret=interpret, name="ssd_recurrent_update",
-    )(slots, flags, layer, xt, at, b.astype(f32), c.astype(f32), state)
-    return jnp.swapaxes(y[..., :H], 1, 2), state
+    )(slots, flags, layer, xt, decay, bc, state)
+    return y.reshape(R, H, P), state
 
 
 def ssd_recurrent_update(x, dt, a, b, c, state, *, layer, live, fresh):
     """One token a slot (Pallas): row ``r`` of x ``[R, H, P]``, dt ``[R,
     H]``, b, c ``[R, G, N]`` is slot ``r``'s; ``live[r]`` says the slot has a
-    row this step, ``fresh[r]`` that it is its sequence's position 0. state
-    ``[Ll, R, H, P, N]`` float32 is read and written at the live slots only
-    (in place when donated). Returns ``(y [R, H, P] float32, state')``; rows
-    of ``y`` that are not live are unspecified."""
+    row this step, ``fresh[r]`` that it is its sequence's position 0. state,
+    the store ``[Ll, R, G, N, (H / G) P]`` float32, is read and written at
+    the live slots only (in place when donated). Returns ``(y [R, H, P]
+    float32, state')``; rows of ``y`` that are not live are unspecified."""
     i32 = jnp.int32
     return _update_call(x, dt, a, b, c, state,
                         jnp.asarray(layer, i32).reshape(1),

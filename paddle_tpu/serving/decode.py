@@ -85,7 +85,7 @@ from ..kernels.pallas_ragged_attention import (
 from ..kernels.selective_scan import (ssm_chunk_scan, ssm_recurrent_update,
                                       ssm_reference)
 from ..kernels.ssd import (ssd_chunk_scan, ssd_recurrent_update,
-                           ssd_reference)
+                           ssd_reference, state_shape as ssd_state_shape)
 from ..models.deepseek_v2 import rope_tables as _mla_rope_tables
 from ..models.llama import _apply_rope, _qkv_bshd, _rms, _rope_tables, \
     _swiglu_raw
@@ -1321,9 +1321,10 @@ def _mixer_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssd, moe,
     [G, S_pad, H]``: every Mamba-2 block scans from a zero state over each
     row's real tokens, the attention blocks attend causally, the routed FFNs
     make pairs for the real tokens only. Returns ``(x, pk, pv [attention
-    blocks, G, S_pad, Hkv, D], (states [units, G, heads, P, N] float32, tails
-    [units, G, conv - 1, C]), moe stats)``: what each cache holds of a
-    sequence; the last as ``_packed_span_forward``'s."""
+    blocks, G, S_pad, Hkv, D], (states [units, G, groups, N, heads / groups *
+    P] float32, the store's layout (``kernels.ssd``), tails [units, G, conv -
+    1, C]), moe stats)``: what each cache holds of a sequence; the last as
+    ``_packed_span_forward``'s."""
     G, S = x.shape[0], x.shape[1]
     cols = jnp.arange(S, dtype=jnp.int32)
     live = cols[None, :] < lengths[:, None]
@@ -1336,7 +1337,8 @@ def _mixer_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssd, moe,
         def flat(t):
             return t.reshape((G * S,) + t.shape[2:])
 
-        zero = jnp.zeros((1, G) + xh.shape[2:] + b.shape[-1:], jnp.float32)
+        zero = jnp.zeros((1, G) + ssd_state_shape(
+            ssd.heads, ssd.head_dim, ssd.groups, ssd.state), jnp.float32)
         if ssd.kernel == "pallas":
             y, st = ssd_chunk_scan(
                 flat(xh), flat(dt), a, flat(b), flat(c), zero, layer=0,
@@ -1387,8 +1389,9 @@ def _mixer_span_forward(params, x, pool_k, pool_v, store, kv_attend, *,
                         moe, return_picks=False):
     """The blocks of a one-mixer-a-block model over the packed buffer ``x
     [1, T, H]`` (``_mixer_units_scan``). The KV pool (attention blocks only)
-    and the state store ``(states [units, R, heads, P, N] float32, tails
-    [units, R, conv - 1, C])`` ride the scan as carry, whole:
+    and the state store ``(states [units, R, groups, N, heads / groups * P]
+    float32 (``kernels.ssd``'s layout), tails [units, R, conv - 1, C])`` ride
+    the scan as carry, whole:
 
     - a Mamba-2 block reads and writes its unit's index of the store at the
       slots that have a span this step (``_hybrid_span_forward``'s rules: a

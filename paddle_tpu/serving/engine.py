@@ -53,6 +53,7 @@ import numpy as np
 from ..kernels.dsa import index_grid_params
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
+from ..kernels.ssd import state_shape as ssd_state_shape
 from ..profiler.tracing import NULL_SPAN
 from .decode import attention_grid, \
     build_paged_suffix_prefill_fn, \
@@ -493,7 +494,9 @@ class ContinuousBatchingEngine:
                               g.conv - 1, c.conv_channels)
         elif "ssd_layers" in self._params:
             d = c.ssd
-            state_geometry = (c.num_units, (d.heads, d.head_dim, d.state),
+            state_geometry = (c.num_units,
+                              ssd_state_shape(d.heads, d.head_dim, d.groups,
+                                              d.state),
                               d.conv - 1, c.conv_channels)
         elif self._stateful:
             state_geometry = (c.num_ssm_layers,

@@ -411,7 +411,8 @@ class TestMosaicCompilesNemotronH:
         return (v5e((rows, self.H, self.P), f32), v5e((rows, self.H), f32),
                 v5e((self.H,), f32), v5e((rows, self.G, self.N), f32),
                 v5e((rows, self.G, self.N), f32),
-                v5e((self.LL, self.R, self.H, self.P, self.N), f32))
+                v5e((self.LL, self.R) + ssd.state_shape(
+                    self.H, self.P, self.G, self.N), f32))
 
     def _in_place(self, fn, args):
         with jax.default_matmul_precision("default"):

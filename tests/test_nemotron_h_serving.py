@@ -255,8 +255,11 @@ def test_two_kinds_of_cache(model):
     per_token = 2 * c.num_key_value_heads * c.head_dim * 4
     assert eng.cache.bytes_per_token() == per_token
     states, tails = eng.cache.state
-    assert states.shape == (3, SLOTS, c.mamba_num_heads, c.mamba_head_dim,
-                            c.ssm_state_size)
+    # the state size on the sublanes, a group's channels on the lanes
+    # (``kernels.ssd``'s layout)
+    assert states.shape == (3, SLOTS, c.n_groups, c.ssm_state_size,
+                            c.mamba_num_heads // c.n_groups
+                            * c.mamba_head_dim)
     assert states.dtype == jnp.float32
     assert tails.shape == (3, SLOTS, c.conv_kernel - 1, c.conv_channels)
     assert eng.cache.state_bytes_per_slot == (states[:, 0].size
