@@ -947,7 +947,7 @@ def phase_kernels(rehearse):
     v = rand(T, nh, dv)
     g = -1.6 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
     beta = 2.0 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
-    store = rand(2, R, nh, dk, dv)
+    store = rand(2, R, *gdr.state_shape(nh, dk, dv))
     # a decode step: every slot but two has a row, one starts a sequence
     live = np.ones(R, bool)
     live[[1, R - 1]] = False

@@ -1,18 +1,35 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464) over a recurrent
 state that lives in a store beside the paged KV pool.
 
-A head's state ``S`` is ``[dk, dv]`` float32, one a (layer, slot). A token
-``t`` with normalised query and key ``q_t, k_t`` (``dk``), value ``v_t``
+A head's state ``S`` is ``[dk, dv]`` float32, ``H`` heads a (layer, slot). A
+token ``t`` with normalised query and key ``q_t, k_t`` (``dk``), value ``v_t``
 (``dv``), log-decay ``g_t <= 0`` and write strength ``beta_t`` (0..2 with
 negative eigenvalues allowed, arXiv:2411.12537) does
 
     S = exp(g_t) * S;  r = v_t - S^T k_t;  S = S + k_t (beta_t r)^T;
     o_t = S^T q_t
 
+**The store's layout** (``state_shape``; ``state_to_store`` /
+``state_from_store`` are its one statement): a (layer, slot) holds ``[dk, H
+dv]``, the key width on the SUBLANES and every head's values side by side on
+the LANES (``[96, 5760]`` at the published 30 / 96 / 192: 45 whole lane
+tiles), the Mamba-2 store's form (``kernels.ssd``). The device tiles the last
+two dimensions (8, 128): with ``[H, dk, dv]`` (until PR 49) a minor dimension
+of 192 lay in 256 lanes, and a decode row's update, which is bound by the
+copy of its state in and out, moved a third more bytes than the state holds.
+Now only the last lane tile of a (layer, slot) can be partial, whatever the
+widths. What varies along a state vreg's lanes is what a token brings as a
+lane-dense row (``v``, and ``exp(g)`` and ``beta`` repeated over their head's
+``dv`` lanes); its key and query are a column a head, and a lane tile that
+two heads share (the boundary at lane 192 lies inside a tile) takes one
+select between their columns.
+
 Three implementations, one semantics:
 
 - ``gdn_reference``: the recurrence run token by token over a packed buffer
-  (the oracle; the serving programs' ``decode_attention="jnp"`` path).
+  (the oracle; the serving programs' ``decode_attention="jnp"`` path), the
+  mathematics above on ``[H, dk, dv]``, the store read and written through
+  the two helpers.
 - ``gdn_chunk_scan`` (Pallas, and ``gdn_chunk_scan_jnp``, the same walk in
   ``jax.numpy``): spans of a prefill chunk, from each slot's state, in
   chunks of ``CHUNK`` tokens in the WY / UT form. With ``G`` the running sum
@@ -31,7 +48,9 @@ Three implementations, one semantics:
   the block-strictly-lower remainder.
 - ``gdn_recurrent_update`` (Pallas): every decode row of a step in one
   call, the state aliased in and out, on the VPU (a state is a fresh
-  operand every row: the MXU would reload its weights 30 times a row).
+  operand every row: the MXU would reload its weights 30 times a row), one
+  lane tile of the state at a time: the sums over ``dk`` are vreg adds down
+  the sublanes, and no lane is reduced or moved.
 
 **Work follows live spans.** Both kernels walk a list built on the device
 from the spans: the chunk scan one entry a (span, 64-token block of the
@@ -70,6 +89,24 @@ def l2norm(x, scale=1.0, eps=1e-6):
     x = x.astype(jnp.float32)
     return x * (jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
                 * scale)
+
+
+# ------------------------------------------------------- the store's layout
+def state_shape(heads, dk, dv):
+    """What a (layer, slot) of the store holds: ``[dk, H dv]``."""
+    return (dk, heads * dv)
+
+
+def state_to_store(s):
+    """``[..., H, dk, dv] -> [..., dk, H dv]``."""
+    *lead, H, dk, dv = s.shape
+    return jnp.moveaxis(s, -3, -2).reshape(*lead, dk, H * dv)
+
+
+def state_from_store(st, heads):
+    """``[..., dk, H dv] -> [..., H, dk, dv]``."""
+    *lead, dk, _ = st.shape
+    return jnp.moveaxis(st.reshape(*lead, dk, heads, -1), -2, -3)
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -169,10 +206,10 @@ def gdn_reference(q, k, v, g, beta, state, *, layer, seg, first):
         s, o = _token_step(s, qt, kt, vt, gt, bt)
         return st.at[sg].set(s, mode="drop"), o
 
-    st, o = jax.lax.scan(step, state[layer], tuple(
-        x.astype(f32) for x in (q, k, v, g, beta)) + (
-            seg, jnp.asarray(first, bool)))
-    return o, state.at[layer].set(st)
+    st, o = jax.lax.scan(step, state_from_store(state[layer], q.shape[1]),
+                         tuple(x.astype(f32) for x in (q, k, v, g, beta)) + (
+                             seg, jnp.asarray(first, bool)))
+    return o, state.at[layer].set(state_to_store(st))
 
 
 # ------------------------------------------------------------ the chunk scan
@@ -222,8 +259,12 @@ def _head_major(x, t_pad, width):
                    ((0, 0), (0, t_pad - T), (0, width - d)))
 
 
-def _scan_heads(H):
-    return max(h for h in range(1, min(SCAN_HEADS, H) + 1) if H % h == 0)
+def _scan_heads(H, dv):
+    """Heads one grid step of the chunk scan holds: a divisor of the head
+    count whose values are whole lane tiles of the store (a block of it must
+    be), or every head."""
+    return max((h for h in range(1, min(SCAN_HEADS, H) + 1)
+                if H % h == 0 and h * dv % 128 == 0), default=H)
 
 
 def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
@@ -243,17 +284,20 @@ def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
     def _compute():
         rows = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0)
         mine = (rows >= lo_ref[w]) & (rows < hi_ref[w])
-        kp = q_ref.shape[-1]
-        pad = jnp.zeros((kp - dk, v_ref.shape[-1]), jnp.float32)
+        kp, dv = q_ref.shape[-1], v_ref.shape[-1]
+        pad = jnp.zeros((kp - dk, dv), jnp.float32)
         for i in range(hb):
             q = jnp.where(mine, q_ref[i], 0.0)
             k = jnp.where(mine, k_ref[i], 0.0)
             g = jnp.where(mine, gb_ref[0, :, i:i + 1], 0.0)
             beta = jnp.where(mine, gb_ref[0, :, hb + i:hb + i + 1], 0.0)
-            s0 = jnp.concatenate([s_out[0, 0, i], pad], axis=0)
+            # the head's lanes of the block (every other head's start inside
+            # a lane tile at 192: a shift a vreg, once a 64-token entry)
+            at = slice(i * dv, (i + 1) * dv)
+            s0 = jnp.concatenate([s_out[0, 0, :, at], pad], axis=0)
             o, s1 = _chunk_math(q, k, v_ref[i].astype(jnp.float32), g, beta,
                                 s0)
-            s_out[0, 0, i] = s1[:dk]
+            s_out[0, 0, :, at] = s1[:dk]
             o_ref[i] = jnp.where(mine, o, jnp.where(newblk, 0.0, o_ref[i]))
 
 
@@ -263,7 +307,7 @@ def _scan_call(q, k, v, g, beta, state, layer, start, length, fresh,
     T, H, dk = q.shape
     dv = v.shape[-1]
     R = start.shape[0]
-    hb = _scan_heads(H)
+    hb = _scan_heads(H, dv)
     n_items = scan_work_items(T, R)
     t_pad = -(-T // CHUNK) * CHUNK
     kp = -(-dk // 128) * 128
@@ -276,20 +320,19 @@ def _scan_call(q, k, v, g, beta, state, layer, start, length, fresh,
     gbh = jnp.concatenate([
         jnp.swapaxes(x.astype(f32).reshape(T, H // hb, hb), 0, 1)
         for x in (g, beta)], axis=-1)
-    gbh = jnp.pad(gbh, ((0, 0), (0, t_pad - T), (0, 128 - 2 * hb)))
+    gbh = jnp.pad(gbh, ((0, 0), (0, t_pad - T), (0, -2 * hb % 128)))
 
     def tok(width):
         return pl.BlockSpec(
             (hb, CHUNK, width), lambda h, w, blk, *_: (h, blk[w], 0))
 
     st = pl.BlockSpec(
-        (1, 1, hb, dk, dv),
-        lambda h, w, blk, slot, lo, hi, fl, layer: (layer[0], slot[w], h, 0,
-                                                     0))
+        (1, 1, dk, hb * dv),
+        lambda h, w, blk, slot, lo, hi, fl, layer: (layer[0], slot[w], 0, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(H // hb, n_items),
         in_specs=[tok(kp), tok(kp), tok(dv),
-                  pl.BlockSpec((1, CHUNK, 128),
+                  pl.BlockSpec((1, CHUNK, gbh.shape[-1]),
                                lambda h, w, blk, *_: (h, blk[w], 0)), st],
         out_specs=[tok(dv), st])
     o, state = pl.pallas_call(
@@ -317,9 +360,9 @@ def _span_args(layer, start, length, fresh):
 def gdn_chunk_scan(q, k, v, g, beta, state, *, layer, start, length, fresh):
     """The chunked scan of every span with ``length > 0`` (Pallas). q, k
     ``[T, H, dk]`` normalised, v ``[T, H, dv]``, g, beta ``[T, H]``, state
-    ``[Ll, R, H, dk, dv]`` float32 (updated in place when donated), start /
-    length / fresh ``[R]`` by slot: the span of slot ``r`` is packed rows
-    ``start[r] .. start[r] + length[r]``. Returns ``(o [T, H, dv] float32,
+    the store ``[Ll, R, dk, H dv]`` float32 (updated in place when donated),
+    start / length / fresh ``[R]`` by slot: the span of slot ``r`` is packed
+    rows ``start[r] .. start[r] + length[r]``. Returns ``(o [T, H, dv] float32,
     state')``; rows of ``o`` outside every span are unspecified."""
     return _scan_call(q, k, v, g, beta, state,
                       *_span_args(layer, start, length, fresh),
@@ -364,13 +407,15 @@ def gdn_chunk_scan_jnp(q, k, v, g, beta, state, *, layer, start, length,
         return (st, o_all), None
 
     (st, o), _ = jax.lax.scan(
-        item, (state[layer[0]], jnp.zeros((H, t_pad, dv), f32)), work)
-    return jnp.swapaxes(o, 0, 1)[:T], state.at[layer[0]].set(st)
+        item, (state_from_store(state[layer[0]], H),
+               jnp.zeros((H, t_pad, dv), f32)), work)
+    return jnp.swapaxes(o, 0, 1)[:T], state.at[layer[0]].set(
+        state_to_store(st))
 
 
 # ------------------------------------------------------ the decode-row update
 def _update_kernel(slot_ref, flag_ref, layer_ref, qt_ref, kt_ref, v_ref,
-                   a_ref, b_ref, s_in, o_ref, s_out, *, H):
+                   a_ref, b_ref, s_in, o_ref, s_out, *, dv):
     i = pl.program_id(0)
     flags = flag_ref[i]
     live, fresh = (flags & 1) > 0, (flags & 2) > 0
@@ -382,16 +427,44 @@ def _update_kernel(slot_ref, flag_ref, layer_ref, qt_ref, kt_ref, v_ref,
     @pl.when(live)
     def _compute():
         r = slot_ref[i]
-        qt, kt = qt_ref[r], kt_ref[r]               # [dk, H]
-        v, a, b = v_ref[r], a_ref[r], b_ref[r]      # [H, dv]
-        for h in range(H):
-            s = jnp.where(fresh, 0.0, s_in[0, 0, h]) * a[h:h + 1]
-            k_col = kt[:, h:h + 1]
-            res = v[h:h + 1] - jnp.sum(s * k_col, axis=0, keepdims=True)
-            s = s + k_col * (b[h:h + 1] * res)
-            s_out[0, 0, h] = s
-            o_ref[0, h:h + 1, :] = jnp.sum(s * qt[:, h:h + 1], axis=0,
-                                           keepdims=True)
+        dk, W = s_in.shape[2:]
+
+        def spread(t):
+            """``column(lo, width)``: lanes ``lo .. lo + width`` of the ``[dk,
+            H dv]`` whose head ``h``'s lanes all hold column ``h`` of ``t [dk,
+            H]``: one lane broadcast a head and sublane tile (shared by the
+            tiles the head lies in), one select where two heads meet inside
+            the tile."""
+            wide = {}
+
+            def column(lo, width):
+                out = None
+                for h in range(lo // dv, (lo + width - 1) // dv + 1):
+                    if (h, width) not in wide:
+                        wide[h, width] = jnp.broadcast_to(t[:, h:h + 1],
+                                                          (dk, width))
+                    if out is None:
+                        out = wide[h, width]
+                    else:
+                        lane = jax.lax.broadcasted_iota(jnp.int32,
+                                                        (dk, width), 1)
+                        out = jnp.where(lane >= h * dv - lo, wide[h, width],
+                                        out)
+                return out
+            return column
+
+        k_at, q_at = spread(kt_ref[r]), spread(qt_ref[r])
+        for lo in range(0, W, 128):
+            width = min(128, W - lo)
+            at = pl.ds(lo, width)
+            k_col = k_at(lo, width)
+            # (a select, not a product: 0 * NaN of a slot's former tenant)
+            s = jnp.where(fresh, 0.0, s_in[0, 0, :, at]) * a_ref[0, :, at]
+            res = v_ref[0, :, at] - jnp.sum(s * k_col, axis=0, keepdims=True)
+            s = s + k_col * (b_ref[0, :, at] * res)
+            s_out[0, 0, :, at] = s
+            o_ref[0, :, at] = jnp.sum(s * q_at(lo, width), axis=0,
+                                      keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -408,24 +481,28 @@ def _update_call(q, k, v, g, beta, state, layer, live, fresh, interpret):
     flags = ((jnp.arange(R) < n_live).astype(i32)
              + 2 * fresh[slots].astype(i32))
 
-    def rows(w):        # a head's scalar as a row of its state's lanes
-        return jnp.broadcast_to(w.astype(f32)[..., None], (R, H, dv))
+    def lanes(x):       # [R, H, dv] as a row of its state's lanes
+        return x.astype(f32).reshape(R, 1, H * dv)
 
-    def whole(*shape):  # resident whole: rows are picked by slot in-kernel
+    def rows(w):        # a head's scalar over its head's lanes
+        return lanes(jnp.broadcast_to(w[..., None], (R, H, dv)))
+
+    def whole(*shape):  # resident whole: a row's is picked by slot in-kernel
         return pl.BlockSpec(shape, lambda i, *_: (0,) * 3)
 
-    st = pl.BlockSpec((1, 1, H, dk, dv),
-                      lambda i, slot, fl, layer: (layer[0], slot[i], 0, 0, 0))
+    # (a block a live row: held whole as [R, H dv], a row would be a dynamic
+    # sublane offset, which Mosaic does not load)
+    row = pl.BlockSpec((1, 1, H * dv), lambda i, slot, *_: (slot[i], 0, 0))
+    st = pl.BlockSpec((1, 1, dk, H * dv),
+                      lambda i, slot, fl, layer: (layer[0], slot[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(R,),
-        in_specs=[whole(R, dk, H), whole(R, dk, H), whole(R, H, dv),
-                  whole(R, H, dv), whole(R, H, dv), st],
-        out_specs=[pl.BlockSpec((1, H, dv),
-                                lambda i, slot, *_: (slot[i], 0, 0)), st])
+        in_specs=[whole(R, dk, H), whole(R, dk, H), row, row, row, st],
+        out_specs=[row, st])
     o, state = pl.pallas_call(
-        functools.partial(_update_kernel, H=H),
+        functools.partial(_update_kernel, dv=dv),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, H, dv), f32),
+        out_shape=[jax.ShapeDtypeStruct((R, 1, H * dv), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operand 8 (after the three prefetched scalars): the state store
         input_output_aliases={8: 1},
@@ -434,19 +511,19 @@ def _update_call(q, k, v, g, beta, state, layer, live, fresh, interpret):
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret, name="gdn_recurrent_update",
     )(slots, flags, layer, jnp.swapaxes(q.astype(f32), 1, 2),
-      jnp.swapaxes(k.astype(f32), 1, 2), v.astype(f32),
+      jnp.swapaxes(k.astype(f32), 1, 2), lanes(v),
       rows(jnp.exp(g.astype(f32))), rows(beta), state)
-    return o, state
+    return o.reshape(R, H, dv), state
 
 
 def gdn_recurrent_update(q, k, v, g, beta, state, *, layer, live, fresh):
     """One token a slot (Pallas): row ``r`` of q, k ``[R, H, dk]``
     (normalised), v ``[R, H, dv]``, g, beta ``[R, H]`` is slot ``r``'s;
     ``live[r]`` says the slot has a row this step, ``fresh[r]`` that it is
-    its sequence's position 0. state ``[Ll, R, H, dk, dv]`` float32 is read
-    and written at the live slots only (in place when donated). Returns
-    ``(o [R, H, dv] float32, state')``; rows of ``o`` that are not live are
-    unspecified."""
+    its sequence's position 0. state, the store ``[Ll, R, dk, H dv]``
+    float32, is read and written at the live slots only (in place when
+    donated). Returns ``(o [R, H, dv] float32, state')``; rows of ``o`` that
+    are not live are unspecified."""
     i32 = jnp.int32
     return _update_call(q, k, v, g, beta, state,
                         jnp.asarray(layer, i32).reshape(1),

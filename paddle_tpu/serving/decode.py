@@ -68,7 +68,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..kernels.flash_attention import attention as _attention
 from ..kernels.gated_delta_rule import (gdn_chunk_scan, gdn_recurrent_update,
-                                         gdn_reference, l2norm)
+                                         gdn_reference, l2norm,
+                                         state_shape as gdn_state_shape)
 from ..kernels.dsa import (dsa_attention_pallas, dsa_attention_reference,
                            dsa_index_scores_pallas,
                            dsa_index_scores_reference, dsa_select,
@@ -1854,7 +1855,8 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
     K/V, the linear layers run the chunked scan from a zero state over each
     row's real tokens (a padding column has ``beta`` 0 and ``g`` 0: the
     state passes it unchanged) and return what their cache holds of a
-    sequence: the final state ``[G, heads, dk, dv]`` float32 and the
+    sequence: the final state ``[G, dk, heads * dv]`` float32 (the store's
+    layout, ``kernels.gated_delta_rule.state_shape``) and the
     convolution's last inputs ``[G, conv - 1, C]``. Returns ``(x, pk, pv
     [full layers, G, S_pad, Hkv, D], (states, tails) [linear layers, G,
     ...])``."""
@@ -1880,7 +1882,8 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
             def flat(a):
                 return a.reshape((G * S,) + a.shape[2:])
 
-            zero = jnp.zeros((1, G, gdn.heads, gdn.dk, gdn.dv), jnp.float32)
+            zero = jnp.zeros((1, G) + gdn_state_shape(
+                gdn.heads, gdn.dk, gdn.dv), jnp.float32)
             if gdn.kernel == "pallas":
                 o, st = gdn_chunk_scan(
                     flat(q), flat(k), flat(v), flat(g), flat(beta), zero,
@@ -2329,7 +2332,7 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
                          gdn):
     """A hybrid model's layers over the packed buffer ``x [1, T, H]``
     (``_hybrid_scan``). The KV pool (full layers only) and the state store
-    ``(states [linear layers, R, heads, dk, dv] float32, tails [linear
+    ``(states [linear layers, R, dk, heads * dv] float32, tails [linear
     layers, R, conv - 1, C])`` ride the scan as carry, whole: a full layer
     appends and attends at its own count in the pool (``kv_attend(pk, pv,
     layer)``), a linear layer reads and writes its own count in the store,

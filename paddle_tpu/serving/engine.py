@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.dsa import index_grid_params
+from ..kernels.gated_delta_rule import state_shape as gdn_state_shape
 from ..kernels.moe_ffn import STATS as MOE_STATS
 from ..kernels.pallas_ragged_attention import ragged_grid_counts
 from ..kernels.ssd import state_shape as ssd_state_shape
@@ -490,7 +491,8 @@ class ContinuousBatchingEngine:
         state_geometry = window_geometry = None
         if "linear_layers" in self._params:
             g = c.gdn
-            state_geometry = (c.num_linear_layers, (g.heads, g.dk, g.dv),
+            state_geometry = (c.num_linear_layers,
+                              gdn_state_shape(g.heads, g.dk, g.dv),
                               g.conv - 1, c.conv_channels)
         elif "ssd_layers" in self._params:
             d = c.ssd

@@ -348,12 +348,13 @@ class PagedKVCache:
     A layer with a recurrent state (``state_geometry``; a Gated DeltaNet
     layer) caches, a sequence, a float32 state and its convolution's last
     inputs: constant in the sequence's length, so it is not paged but
-    indexed by SLOT, in :attr:`state` ``(states [layers, num_slots, heads,
-    dk, dv] float32, tails [layers, num_slots, conv rows, channels])``. (A
-    state's shape is its kernels' to say: a Mamba-1 layer's is ``[d_state,
+    indexed by SLOT, in :attr:`state` ``(states [layers, num_slots, dk,
+    heads * dv] float32, tails [layers, num_slots, conv rows, channels])``.
+    (A state's shape is its kernels' to say: a Gated DeltaNet layer's is
+    ``kernels.gated_delta_rule.state_shape``, a Mamba-1 layer's ``[d_state,
     d_inner]``, a Mamba-2 block's ``[groups, N, heads / groups * P]``,
     ``kernels.ssd.state_shape``: the state size on the sublanes, the
-    channels on the lanes.)
+    channels on the lanes, no minor dimension the device would pad.)
     Nothing here ever zeroes a slot's state: the programs give a span whose
     first position is 0 a zero state, whatever the slot held.
     A layer that attends inside a WINDOW (``window_geometry``) needs its

@@ -12,31 +12,36 @@ H, DK, DV = 4, 24, 48
 R = 5
 
 
-def _inputs(seed, T, g_lo=-1.6, g_hi=0.0, beta_lo=0.0, beta_hi=2.0):
+def _inputs(seed, T, g_lo=-1.6, g_hi=0.0, beta_lo=0.0, beta_hi=2.0,
+            widths=(H, DK, DV), slots=R):
+    """A packed buffer of ``T`` tokens and a store of two layers (in the
+    store's own layout, ``gdr.state_shape``)."""
+    nh, dk, dv = widths
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = gdr.l2norm(jax.random.normal(ks[0], (T, H, DK)), DK ** -0.5)
-    k = gdr.l2norm(jax.random.normal(ks[1], (T, H, DK)))
-    v = jax.random.normal(ks[2], (T, H, DV))
-    g = jax.random.uniform(ks[3], (T, H), minval=g_lo, maxval=g_hi)
-    beta = jax.random.uniform(ks[4], (T, H), minval=beta_lo, maxval=beta_hi)
-    state = jax.random.normal(ks[5], (2, R, H, DK, DV))
+    q = gdr.l2norm(jax.random.normal(ks[0], (T, nh, dk)), dk ** -0.5)
+    k = gdr.l2norm(jax.random.normal(ks[1], (T, nh, dk)))
+    v = jax.random.normal(ks[2], (T, nh, dv))
+    g = jax.random.uniform(ks[3], (T, nh), minval=g_lo, maxval=g_hi)
+    beta = jax.random.uniform(ks[4], (T, nh), minval=beta_lo, maxval=beta_hi)
+    state = jax.random.normal(ks[5], (2, slots) + gdr.state_shape(*widths))
     return q, k, v, g, beta, state
 
 
 def _oracle(q, k, v, g, beta, state, layer, start, length, fresh):
-    """Every span through ``gdn_recurrence``, by hand."""
+    """Every span through ``gdn_recurrence``, by hand, on head-major states;
+    the store is read and written through the layout's two helpers."""
     o = np.zeros(v.shape, np.float32)
-    st = np.array(state)
+    heads = np.array(gdr.state_from_store(state, q.shape[1]))
     for r in range(len(start)):
         if length[r] == 0:
             continue
         sl = slice(start[r], start[r] + length[r])
-        s0 = None if fresh[r] else state[layer, r]
+        s0 = None if fresh[r] else jnp.asarray(heads[layer, r])
         o_r, s_r = gdr.gdn_recurrence(q[sl], k[sl], v[sl], g[sl], beta[sl],
                                       s0)
         o[sl] = np.asarray(o_r)
-        st[layer, r] = np.asarray(s_r)
-    return o, st
+        heads[layer, r] = np.asarray(s_r)
+    return o, np.asarray(gdr.state_to_store(jnp.asarray(heads)))
 
 
 def _close(got, want, tol=2e-4):
@@ -172,3 +177,85 @@ def test_reference_walks_the_packed_buffer():
                               [0, 0, 1, 0, 0])
     _close(np.asarray(o)[:32], want_o[:32], 1e-5)
     _close(st, want_st, 1e-5)
+
+
+# ---- the store's layout (PR 49): [dk, H dv], every head's values side by
+# side on the lanes, whatever the widths
+WIDTHS = {
+    # the published Olmo-Hybrid widths: a head boundary inside every second
+    # lane tile (192 = 1.5 tiles), 45 whole tiles
+    "published": (30, 96, 192),
+    # an odd head count: the last lane tile is half full (3 x 192 = 4.5)
+    "odd_heads": (3, 16, 192),
+    # a value width that is whole lane tiles: no tile holds two heads
+    "lane_multiple": (2, 8, 128),
+    # several heads inside one tile, and less than one tile in all
+    "narrow": (4, 8, 16),
+}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_the_layout_helpers_round_trip(widths):
+    nh, dk, dv = WIDTHS[widths]
+    assert gdr.state_shape(nh, dk, dv) == (dk, nh * dv)
+    s = jnp.arange(2 * 3 * nh * dk * dv, dtype=jnp.float32).reshape(
+        2, 3, nh, dk, dv)
+    st = gdr.state_to_store(s)
+    assert st.shape == (2, 3) + gdr.state_shape(nh, dk, dv)
+    # head h's value j at key row i lies at lane h dv + j of sublane i
+    h, i, j = nh - 1, dk - 2, dv - 3
+    assert float(st[1, 2, i, h * dv + j]) == float(s[1, 2, h, i, j])
+    assert np.array_equal(np.asarray(gdr.state_from_store(st, nh)),
+                          np.asarray(s))
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_recurrent_update_over_the_stored_layout(widths):
+    """The update against ``gdn_reference`` at each shape of the layout: a
+    dead slot, and a fresh row over a slot that holds ``NaN`` (it starts from
+    zero whatever its slot held)."""
+    slots = 3
+    q, k, v, g, beta, state = _inputs(11, slots, widths=WIDTHS[widths],
+                                      slots=slots)
+    live, fresh = np.array([1, 0, 1], bool), np.array([0, 0, 1], bool)
+    poisoned = state.at[:, 2].set(jnp.nan)
+    o, st = gdr.gdn_recurrent_update(q, k, v, g, beta, poisoned, layer=1,
+                                     live=live, fresh=fresh)
+    want_o, want_st = gdr.gdn_reference(
+        q, k, v, g, beta, state, layer=1, seg=np.where(live, np.arange(slots),
+                                                       slots), first=fresh)
+    assert st.shape == state.shape
+    assert bool(jnp.isfinite(o[live]).all()) and bool(jnp.isfinite(
+        st[1, live]).all())
+    _close(np.asarray(o)[live], np.asarray(want_o)[live], 1e-5)
+    _close(np.asarray(st)[1, live], np.asarray(want_st)[1, live], 1e-5)
+    assert np.array_equal(np.asarray(st)[1, 1], np.asarray(state)[1, 1])
+    assert np.array_equal(np.asarray(st)[0, :2], np.asarray(state)[0, :2])
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_chunk_scan_over_the_stored_layout(impl, widths):
+    """The scan against ``gdn_reference`` at each shape of the layout: a span
+    that continues its slot's state and ends inside the block where a fresh
+    one, over a ``NaN`` slot, starts."""
+    slots, T = 3, 100
+    q, k, v, g, beta, state = _inputs(12, T, widths=WIDTHS[widths],
+                                      slots=slots)
+    start, length = np.array([2, 0, 40]), np.array([38, 0, 57])
+    fresh = np.array([0, 0, 1], bool)
+    seg = np.full(T, slots, np.int32)
+    seg[2:40], seg[40:97] = 0, 2
+    first = np.zeros(T, bool)
+    first[40] = True
+    poisoned = state.at[:, 2].set(jnp.nan)
+    fn = gdr.gdn_chunk_scan if impl == "pallas" else gdr.gdn_chunk_scan_jnp
+    o, st = fn(q, k, v, g, beta, poisoned, layer=0, start=start,
+               length=length, fresh=fresh)
+    want_o, want_st = gdr.gdn_reference(q, k, v, g, beta, state, layer=0,
+                                        seg=seg, first=first)
+    assert st.shape == state.shape
+    assert bool(jnp.isfinite(o[2:97]).all())
+    _close(np.asarray(o)[2:97], np.asarray(want_o)[2:97])
+    _close(np.asarray(st)[0, [0, 2]], np.asarray(want_st)[0, [0, 2]])
+    assert np.array_equal(np.asarray(st)[0, 1], np.asarray(state)[0, 1])

@@ -307,7 +307,9 @@ def test_the_pool_holds_the_full_layers_only():
     assert eng.cache.bytes_per_token() == per_token
     states, tails = eng.cache.state
     g = c.gdn
-    assert states.shape == (6, SLOTS, g.heads, g.dk, g.dv)
+    # the store's layout is its kernels' to say: no minor dimension the
+    # device would pad to whole lanes
+    assert states.shape == (6, SLOTS, g.dk, g.heads * g.dv)
     assert states.dtype == jnp.float32
     assert tails.shape == (6, SLOTS, g.conv - 1, c.conv_channels)
     per_slot = 6 * (g.heads * g.dk * g.dv * 4 + 3 * c.conv_channels * 4)
