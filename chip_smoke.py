@@ -14,7 +14,9 @@ vocab 32000, bf16) with the depth cut and random weights made from a seed:
            gated delta rule's chunked scan and decode-row update at
            Olmo-Hybrid-7B's 30 heads of 96 x 192; the three attention /
            state kernels of the unified step also at each serving cell's
-           decode-only packed size, 8 / 24 / 32 rows and nothing behind),
+           decode-only packed size, 8 / 16 / 24 / 32 rows and nothing behind;
+           the ragged kernel also at 20 query heads on ONE KV head, 16 k
+           into a document),
            compiled by Mosaic and RUN against its jnp reference;
   serve    ``python -m paddle_tpu.serving.server --preset llama7b-8of32``
            answering cold, chunked, concurrent and streamed requests;
@@ -76,7 +78,14 @@ FULL = dict(
         # chunk 3 k into its prompt behind 31 decode rows at 1.5 k - 6 k
         "nemotron chunk 32/2/128": (32, 2, 128, 192, [
             (1, 1536 + 140 * i + (i * 37) % 29) for i in range(31)]
-            + [(512, 3584)])},
+            + [(512, 3584)]),
+        # Jamba2-3B's attention layers: 20 query heads on ONE KV head (a
+        # group that is no power of two, a pool row of 128 lanes), tables of
+        # 1,024 entries; a 512-token chunk 16 k into its document behind 15
+        # decode rows at 8 k - 25 k
+        "jamba chunk 20/1/128": (20, 1, 128, 1024, [
+            (1, 8192 + 1100 * i + (i * 37) % 29) for i in range(15)]
+            + [(512, 16896)])},
     # the cells' decode-only steps, whose packed buffer is the slots alone
     # (8 / 24 / 32 rows and nothing behind them): the chat cell's two live
     # rows of eight, less than one query block; every row live in the others
@@ -89,7 +98,9 @@ FULL = dict(
         "olmo-hybrid 32 rows 30/30/128": (30, 30, 128, 72, [
             (1, 520 + 55 * i + (i * 37) % 29) for i in range(32)], 32),
         "nemotron 32 rows 32/2/128": (32, 2, 128, 192, [
-            (1, 1536 + 140 * i + (i * 37) % 29) for i in range(32)], 32)},
+            (1, 1536 + 140 * i + (i * 37) % 29) for i in range(32)], 32),
+        "jamba 16 rows 20/1/128": (20, 1, 128, 1024, [
+            (1, 8192 + 1100 * i + (i * 37) % 29) for i in range(16)], 16)},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -147,7 +158,8 @@ REHEARSAL = dict(
             (1, 20 + 9 * i) for i in range(6)]),
         "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)]),
         "chunk 16/1/32": (16, 1, 32, 8, [(1, 70), (40, 100), (0, 0),
-                                         (1, 1)])},
+                                         (1, 1)]),
+        "chunk 5/1/32": (5, 1, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
     ragged_decode_only={
         "8 rows 4/2/32": (4, 2, 32, 8, [
             (1, 150), (0, 0), (0, 0), (1, 33), (0, 0), (0, 0), (0, 0),

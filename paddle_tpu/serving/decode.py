@@ -47,7 +47,10 @@ blocks are ONE mixer each (a tree with ``ssd_layers``: units of a Mamba-2
 block, optionally an attention block, then a routed FFN) scans its units, the
 pool holding rows for the attention blocks only, a Mamba-2 block's state and
 its convolution's last inputs in the store by slot (``_mixer_span_forward``,
-``kernels.ssd``).
+``kernels.ssd``). A model of Mamba-1 layers around one attention layer a
+period (a tree with ``mamba_layers``) runs the hybrid model's period scan with
+the full layer where its tree puts it and the decoder-hybrid-decoder's Mamba
+mixer with three inner norms (``_jamba_span_forward``).
 
 Sampling is row-vectorized: greedy where ``temps <= 0``, else top-k
 temperature sampling with a per-row ``jax.random.categorical`` under a
@@ -159,7 +162,7 @@ _MIXER_MOE_KEYS = ("moe_ln", "router", "router_bias", "ws_up", "ws_down")
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch)
 TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers",
-                                   "ssd_layers")
+                                   "ssd_layers", "mamba_layers")
 
 
 def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1,
@@ -777,24 +780,35 @@ def _gdn_layer(h, lw, *, eps, gdn, mix):
 
 def _hybrid_scan(params, carry, full_layer, linear_layer):
     """A hybrid model's forward: ONE scan over the periods whose body runs
-    the period's linear layers in order, then its full layer.
-    ``linear_layer(carry, lw, index)`` / ``full_layer(carry, lw, index)``
-    return ``(carry, ys)``; ``index`` is the layer's count among its own
-    kind: a linear layer's place in the state store, a full layer's in the
-    KV pool. Returns ``(carry, linear ys [periods, layers a period, ...],
-    full ys [periods, ...])``."""
-    lin = tuple({k: tree[k] for k in _GDN_KEYS}
-                for tree in params["linear_layers"])
-    full = {k: params[k] for k in _HYBRID_FULL_KEYS}
-    periods, n_lin = full["attn_out_ln"].shape[0], len(lin)
+    the period's layers in order: its linear layers, and its full layer
+    where the tree puts it: after them (a tree with ``linear_layers``), or
+    between the two runs of a tree with ``mamba_layers`` (``(the places
+    before the full layer, the places after it)``, the full layer's entries
+    under ``attn_layers``). ``linear_layer(carry, lw, index)`` /
+    ``full_layer(carry, lw, index)`` return ``(carry, ys)``; ``index`` is the
+    layer's count among its own kind: a linear layer's place in the state
+    store, a full layer's in the KV pool. Returns ``(carry, linear ys
+    [periods, layers a period, ...], full ys [periods, ...])``."""
+    if "mamba_layers" in params:
+        before, after = params["mamba_layers"]
+        lin, full, full_at = before + after, params["attn_layers"], \
+            len(before)
+    else:
+        lin = tuple({k: tree[k] for k in _GDN_KEYS}
+                    for tree in params["linear_layers"])
+        full = {k: params[k] for k in _HYBRID_FULL_KEYS}
+        full_at = len(lin)
+    periods, n_lin = full["wo"].shape[0], len(lin)
 
     def period(carry, xs):
         lin_p, full_p, p = xs
         ys = []
-        for j in range(n_lin):
-            carry, y = linear_layer(carry, lin_p[j], p * n_lin + j)
-            ys.append(y)
-        carry, y_full = full_layer(carry, full_p, p)
+        for j in range(n_lin + 1):
+            if j == full_at:
+                carry, y_full = full_layer(carry, full_p, p)
+            if j < n_lin:
+                carry, y = linear_layer(carry, lin_p[j], p * n_lin + j)
+                ys.append(y)
         ys = None if ys[0] is None else jax.tree.map(
             lambda *a: jnp.stack(a), *ys)
         return carry, (ys, y_full)
@@ -840,17 +854,21 @@ def _sambay_block(h, lw, eps, mixer):
     return h + m, carry
 
 
-def _mamba_mixer(hn, lw, *, conv, scan):
+def _mamba_mixer(hn, lw, *, conv, scan, eps=None):
     """A Mamba-1 mixer on ``hn [B, S, H]``. The program brings ``conv(a, w,
     bias) -> (silu(conv(a)) [B, S, C], carry)`` (where the convolution's
     earlier inputs come from) and ``scan(dt, u, b, c, A) -> (y [B, S, C]
     float32, carry)`` (where the state comes from and which kernel walks the
     tokens): dt, u ``[B, S, C]``, b, c ``[B, S, N]`` float32, ``A = -exp(A_log)
-    [N, C]``. Returns ``(out [B, S, H], (conv carry, scan carry, y))``, a
-    mixer of ``_sambay_block``: ``y``, the scan's output with the skip ``D *
-    c`` and BEFORE the gate, is what the middle Mamba layer hands on as the
-    memory. Scopes ``ssm_proj`` (the four projections) and ``ssm_mix``
-    (convolution, gates, the kernels)."""
+    [N, C]``. A tree with ``ssm_dt_ln`` / ``ssm_b_ln`` / ``ssm_c_ln``
+    (``models.jamba``) normalises the step's low-rank input and the two
+    vectors between ``W_x`` and ``W_dt`` / the scan (RMSNorm at ``eps``,
+    float32); one without them runs none. Returns ``(out [B, S, H], (conv
+    carry, scan carry, y))``, a mixer of ``_sambay_block``: ``y``, the scan's
+    output with the skip ``D * c`` and BEFORE the gate, is what the middle
+    Mamba layer hands on as the memory. Scopes ``ssm_proj`` (the four
+    projections) and ``ssm_mix`` (convolution, inner norms, gates, the
+    kernels)."""
     f32 = jnp.float32
     C = lw["ssm_out"].shape[0]
     N = lw["ssm_A_log"].shape[0]
@@ -865,6 +883,14 @@ def _mamba_mixer(hn, lw, *, conv, scan):
         # scale, B and C multiply a float32 state
         xdb = jnp.einsum("bsc,cr->bsr", c, lw["ssm_x"],
                          preferred_element_type=f32)
+    if "ssm_dt_ln" in lw:
+        with jax.named_scope("ssm_mix"):
+            xdb = jnp.concatenate(
+                [_rms(xdb[..., lo:hi], lw[k].astype(f32), eps)
+                 for k, lo, hi in (("ssm_dt_ln", 0, rank),
+                                   ("ssm_b_ln", rank, rank + N),
+                                   ("ssm_c_ln", rank + N, rank + 2 * N))], -1)
+    with jax.named_scope("ssm_proj"):
         dt = jnp.einsum("bsr,rc->bsc", xdb[..., :rank].astype(hn.dtype),
                         lw["ssm_dt"], preferred_element_type=f32)
     with jax.named_scope("ssm_mix"):
@@ -878,6 +904,95 @@ def _mamba_mixer(hn, lw, *, conv, scan):
     with jax.named_scope("ssm_proj"):
         out = jnp.einsum("bsc,ch->bsh", gated, lw["ssm_out"])
     return out, (conv_carry, scan_carry, y)
+
+
+def _mamba_rows_mixer(lengths, live, ssm, **norm):
+    """A Mamba mixer of whole-prompt prefill, ``mixer(hn [G, S, H], lw) ->
+    _mamba_mixer's``: the convolution and the scan run from a zero tail and a
+    zero state over each row's real tokens (``live [G, S]``, ``lengths
+    [G]``; ``norm``: the inner norms' ``eps``, for a tree that has them); the
+    carries are what a slot's store holds of the row, its tail
+    ``[G, conv - 1, C]`` and its state ``[G, N, C]`` float32."""
+    G, S = live.shape
+    cols = jnp.arange(S, dtype=jnp.int32)
+    rows_g = jnp.arange(G, dtype=jnp.int32)
+
+    def conv(a, w, bias):
+        return _rows_conv(a, w, bias, lengths)
+
+    def scan(dt, u, b, c, a):
+        def flat(t):
+            return t.reshape((G * S,) + t.shape[2:])
+
+        zero = jnp.zeros((1, G) + a.shape, jnp.float32)
+        if ssm.kernel == "pallas":
+            y, st = ssm_chunk_scan(
+                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
+                start=rows_g * S, length=lengths,
+                fresh=jnp.ones((G,), bool))
+        else:
+            y, st = ssm_reference(
+                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
+                seg=jnp.where(live, rows_g[:, None], G).reshape(-1),
+                first=jnp.broadcast_to(cols == 0, (G, S)).reshape(-1))
+        return jnp.where(live[..., None], y.reshape(G, S, -1), 0.0), st[0]
+
+    return lambda hn, lw: _mamba_mixer(hn, lw, conv=conv, scan=scan, **norm)
+
+
+def _mamba_span_mixer(ssm, *, seg, pos, qstart, qlen, kvlen, T, **norm):
+    """A Mamba mixer of the unified step over the packed buffer, ``mixer(hn
+    [1, T, H], lw, idx, ss, cs) -> _mamba_mixer's``: the layer reads and
+    writes index ``idx`` of the store ``(ss [Mamba layers, R, N, C] float32,
+    cs [Mamba layers, R, conv - 1, C])`` at the slots that have a span this
+    step (``_hybrid_span_forward``'s rules: a span whose first position is 0
+    takes a zero state and a zero tail; spans of one token through
+    ``ssm_recurrent_update``, longer ones through ``ssm_chunk_scan``, which
+    the decode-only program, ``T == ssm.decode_rows``, leaves out: the plan
+    gave it no chunk); the carries are the two stores, whole. ``norm`` as
+    ``_mamba_rows_mixer``'s."""
+    R = qstart.shape[0]
+    live_tok = seg < R
+    seg_c = jnp.minimum(seg, R - 1)
+    fresh = (kvlen - qlen) == 0
+    one, many = qlen == 1, qlen > 1
+    tok_one = live_tok & jnp.take(one, seg_c)
+    row_at = jnp.clip(qstart, 0, T - 1)
+
+    def mixer(hn, lw, idx, ss, cs):
+        if ss.dtype != jnp.float32:
+            # (a state rounded to bfloat16 a token moves the logits by less
+            # than a check on logits can see: the dtype is held here)
+            raise TypeError(f"a Mamba layer's state is float32, the store "
+                            f"holds {ss.dtype}")
+
+        def conv(a, w, bias):
+            c, tails = _span_conv(a[0], cs[idx], w, fresh=fresh,
+                                  qstart=qstart, qlen=qlen, bias=bias)
+            return c[None], cs.at[idx].set(tails)
+
+        def scan(dt, u, b, c, a):
+            dt, u, b, c = dt[0], u[0], b[0], c[0]
+            if ssm.kernel == "pallas":
+                y1, new_ss = ssm_recurrent_update(
+                    *(jnp.take(t, row_at, axis=0) for t in (dt, u, b, c)), a,
+                    ss, layer=idx, live=one, fresh=fresh)
+                y = jnp.take(y1, seg_c, axis=0)
+                if T != ssm.decode_rows:
+                    yn, new_ss = ssm_chunk_scan(
+                        dt, u, b, c, a, new_ss, layer=idx, start=qstart,
+                        length=jnp.where(many, qlen, 0), fresh=fresh,
+                        min_span=2)
+                    y = jnp.where(tok_one[:, None], y, yn)
+            else:
+                y, new_ss = ssm_reference(
+                    dt, u, b, c, a, ss, layer=idx, seg=seg,
+                    first=live_tok & (pos == 0))
+            return jnp.where(live_tok[:, None], y, 0.0)[None], new_ss
+
+        return _mamba_mixer(hn, lw, conv=conv, scan=scan, **norm)
+
+    return mixer
 
 
 def _diff_queries(q):
@@ -987,7 +1102,6 @@ def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
     G, S = x.shape[0], x.shape[1]
     cols = jnp.arange(S, dtype=jnp.int32)
     live = cols[None, :] < lengths[:, None]
-    rows_g = jnp.arange(G, dtype=jnp.int32)
     causal = cols[None, :, None] >= cols[None, None, :]
     in_row = live[:, None, :] & causal
     in_window = in_row & (cols[None, :, None] - cols[None, None, :]
@@ -997,29 +1111,11 @@ def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
     ring_at = jnp.clip(
         ring + (lengths[:, None] - 1 - ring) // span * span, 0, S - 1)
 
-    def conv(a, w, bias):
-        return _rows_conv(a, w, bias, lengths)
-
-    def scan(dt, u, b, c, a):
-        def flat(t):
-            return t.reshape((G * S,) + t.shape[2:])
-
-        zero = jnp.zeros((1, G) + a.shape, jnp.float32)
-        if ssm.kernel == "pallas":
-            y, st = ssm_chunk_scan(
-                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
-                start=rows_g * S, length=lengths,
-                fresh=jnp.ones((G,), bool))
-        else:
-            y, st = ssm_reference(
-                flat(dt), flat(u), flat(b), flat(c), a, zero, layer=0,
-                seg=jnp.where(live, rows_g[:, None], G).reshape(-1),
-                first=jnp.broadcast_to(cols == 0, (G, S)).reshape(-1))
-        return jnp.where(live[..., None], y.reshape(G, S, -1), 0.0), st[0]
+    mamba = _mamba_rows_mixer(lengths, live, ssm)
 
     def mamba_layer(h, lw):
-        h, (tail, st, y) = _sambay_block(
-            h, lw, eps, lambda hn: _mamba_mixer(hn, lw, conv=conv, scan=scan))
+        h, (tail, st, y) = _sambay_block(h, lw, eps,
+                                         lambda hn: mamba(hn, lw))
         return h, (st, tail), y
 
     def self_attend(mask):
@@ -1097,11 +1193,6 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
     R, T = qstart.shape[0], x.shape[1]
     ring_blocks, bs = wk.shape[2], wk.shape[3]
     live_tok = seg < R
-    seg_c = jnp.minimum(seg, R - 1)
-    fresh = (kvlen - qlen) == 0
-    one, many = qlen == 1, qlen > 1
-    tok_one = live_tok & jnp.take(one, seg_c)
-    row_at = jnp.clip(qstart, 0, T - 1)
     ragged = (ragged_paged_attention_pallas if decode_attn == "pallas"
               else ragged_attention_reference)
     # the window store as the kernel walks it: a pool of R * ring blocks
@@ -1116,33 +1207,12 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
     def as_pool(w):
         return w.reshape((w.shape[0], R * ring_blocks) + w.shape[3:])
 
+    mamba = _mamba_span_mixer(ssm, seg=seg, pos=pos, qstart=qstart,
+                              qlen=qlen, kvlen=kvlen, T=T)
+
     def mamba_layer(h, lw, idx, ss, cs):
-        def conv(a, w, bias):
-            c, tails = _span_conv(a[0], cs[idx], w, fresh=fresh,
-                                  qstart=qstart, qlen=qlen, bias=bias)
-            return c[None], cs.at[idx].set(tails)
-
-        def scan(dt, u, b, c, a):
-            dt, u, b, c = dt[0], u[0], b[0], c[0]
-            if ssm.kernel == "pallas":
-                y1, new_ss = ssm_recurrent_update(
-                    *(jnp.take(t, row_at, axis=0) for t in (dt, u, b, c)), a,
-                    ss, layer=idx, live=one, fresh=fresh)
-                y = jnp.take(y1, seg_c, axis=0)
-                if T != ssm.decode_rows:
-                    yn, new_ss = ssm_chunk_scan(
-                        dt, u, b, c, a, new_ss, layer=idx, start=qstart,
-                        length=jnp.where(many, qlen, 0), fresh=fresh,
-                        min_span=2)
-                    y = jnp.where(tok_one[:, None], y, yn)
-            else:
-                y, new_ss = ssm_reference(
-                    dt, u, b, c, a, ss, layer=idx, seg=seg,
-                    first=live_tok & (pos == 0))
-            return jnp.where(live_tok[:, None], y, 0.0)[None], new_ss
-
         h, (cs, ss, y) = _sambay_block(
-            h, lw, eps, lambda hn: _mamba_mixer(hn, lw, conv=conv, scan=scan))
+            h, lw, eps, lambda hn: mamba(hn, lw, idx, ss, cs))
         return h, ss, cs, y
 
     attn_layer = functools.partial(_sambay_attn_layer, nh=nh, nkv=nkv, hd=hd,
@@ -1197,6 +1267,95 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
 
     x, _ = jax.lax.scan(cross_pair, x, params["cross_layers"])
     return x, pool_k, pool_v, (ss, cs, wk, wv)
+
+
+# ------------------------------------- Mamba layers around one attention layer
+# ``models.jamba``'s docstring has the equations. A tree with ``mamba_layers``
+# is PERIODS of Mamba-1 layers with one attention layer somewhere inside
+# (``_hybrid_scan``); every layer is ``_jamba_block`` around a mixer: the
+# shared ``_mamba_mixer`` with its three inner norms, or ``_jamba_attention``.
+def _jamba_block(h, lw, eps, mixer):
+    """``x = x + Mixer(RMSNorm(x; ln1))``, ``x = x + SwiGLU(RMSNorm(x;
+    ln2))`` on ``h [B, S, H]``; ``mixer(hn) -> (out, carry)``. Returns ``(h,
+    carry)``. Scope ``jamba_mlp``: the second half."""
+    out, carry = mixer(_rms(h, lw["ln1"], eps))
+    h = h + out
+    with jax.named_scope("jamba_mlp"):
+        m = _swiglu_proj(_rms(h, lw["ln2"], eps), lw["w_gate"], lw["w_up"],
+                         lw["w_down"])
+    return h + m, carry
+
+
+@jax.named_scope("jamba_attn")
+def _jamba_attention(hn, lw, attend, *, nh, nkv, hd):
+    """Grouped-query attention with no positional term around the program's
+    ``attend(q [B, S, nh, hd], k, v [B, S, nkv, hd]) -> (attn [B, S, nh, hd],
+    carry)``. Returns ``(out [B, S, H], carry)``. Scope ``jamba_attn``: the
+    projections, the kernel's call and ``W_o``."""
+    q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"], nh, nkv, hd)
+    attn, carry = attend(q, k, v)
+    return _o_proj(attn.reshape(hn.shape[:2] + (nh * hd,)), lw["wo"]), carry
+
+
+def _jamba_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
+                          narrow=True):
+    """The layers of a tree with ``mamba_layers`` over an admission group
+    ``x [G, S_pad, H]``: every Mamba layer scans from a zero state over each
+    row's real tokens, the attention layers attend causally. With ``narrow``
+    the stream comes back at each row's LAST real token only (the one the
+    first token is sampled from). Returns ``(x [G, 1 or S_pad, H], pk, pv
+    [attention layers, G, S_pad, Hkv, D], (states [Mamba layers, G, N, C]
+    float32, tails [Mamba layers, G, conv - 1, C]))``: what each cache holds
+    of a sequence."""
+    S = x.shape[1]
+    live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    mamba = _mamba_rows_mixer(lengths, live, ssm, eps=eps)
+
+    def full_layer(h, lw, _):
+        return _jamba_block(h, lw, eps, lambda hn: _jamba_attention(
+            hn, lw, lambda q, k, v: (_attention(q, k, v, causal=True),
+                                     (k, v)), nh=nh, nkv=nkv, hd=hd))
+
+    def linear_layer(h, lw, _):
+        h, (tail, st, _) = _jamba_block(h, lw, eps, lambda hn: mamba(hn, lw))
+        return h, (st, tail)
+
+    x, kept, (pk, pv) = _hybrid_scan(params, x, full_layer, linear_layer)
+    if narrow:
+        x = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+    return x, pk, pv, tuple(a.reshape((-1,) + a.shape[2:]) for a in kept)
+
+
+def _jamba_span_forward(params, x, pool_k, pool_v, store, kv_attend, *,
+                        seg, pos, qstart, qlen, kvlen, nh, nkv, hd, eps, ssm):
+    """The layers of a tree with ``mamba_layers`` over the packed buffer ``x
+    [1, T, H]`` (``_hybrid_scan``). The KV pool (attention layers only) and
+    the store ``(states [Mamba layers, R, N, C] float32, tails [Mamba layers,
+    R, conv - 1, C])`` ride the scan as carry, whole: an attention layer
+    appends and attends at its own count in the pool (``kv_attend(pk, pv,
+    layer)``), a Mamba layer reads and writes its own count in the store
+    (``_mamba_span_mixer``). Returns ``(x [1, R, H], a span's last token by
+    slot as ``_sambay_span_forward``'s, pool_k, pool_v, store)``."""
+    T = x.shape[1]
+    mamba = _mamba_span_mixer(ssm, seg=seg, pos=pos, qstart=qstart,
+                              qlen=qlen, kvlen=kvlen, T=T, eps=eps)
+
+    def full_layer(carry, lw, idx):
+        h, pk, pv, ss, cs = carry
+        h, (pk, pv) = _jamba_block(h, lw, eps, lambda hn: _jamba_attention(
+            hn, lw, kv_attend(pk, pv, idx), nh=nh, nkv=nkv, hd=hd))
+        return (h, pk, pv, ss, cs), None
+
+    def linear_layer(carry, lw, idx):
+        h, pk, pv, ss, cs = carry
+        h, (cs, ss, _) = _jamba_block(
+            h, lw, eps, lambda hn: mamba(hn, lw, idx, ss, cs))
+        return (h, pk, pv, ss, cs), None
+
+    (x, pool_k, pool_v, ss, cs), _, _ = _hybrid_scan(
+        params, (x, pool_k, pool_v) + tuple(store), full_layer, linear_layer)
+    last = jnp.clip(qstart + qlen - 1, 0, T - 1)
+    return jnp.take(x, last, axis=1), pool_k, pool_v, (ss, cs)
 
 
 # ----------------------------------------------- one mixer a block (Nemotron-H)
@@ -1936,7 +2095,10 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     (``_hybrid_prefill_layers``). A decoder-hybrid-decoder model
     (``self_layers``; ``ssm`` its static numbers) returns ``pk`` / ``pv`` of
     its ONE layer with a row a token and, last, what its Mamba layers' and
-    window layers' stores hold of each row (``_sambay_prefill_layers``). A
+    window layers' stores hold of each row (``_sambay_prefill_layers``); a
+    tree with ``mamba_layers`` under the same ``ssm`` returns ``pk`` / ``pv``
+    of its attention layers and its Mamba layers' states and tails
+    (``_jamba_prefill_layers``). A
     model whose blocks are one mixer each (``ssd_layers``; ``ssd`` its
     Mamba-2 blocks' static numbers) returns ``pk`` / ``pv`` of its attention
     blocks, its routed FFNs' summary (and picks) and, last, what its Mamba-2
@@ -1954,7 +2116,9 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
         return (pk, pv, tok0, keys2) + _moe_outputs(stats) + (state,)
     if ssm is not None:
         x = jnp.take(params["embed"], ids, axis=0)
-        x, pk, pv, state = _sambay_prefill_layers(
+        layers = _jamba_prefill_layers if "mamba_layers" in params \
+            else _sambay_prefill_layers
+        x, pk, pv, state = layers(
             params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, ssm=ssm)
         tok0, keys2 = _first_token(
             params, _dq_head(params, tied, params["embed"].dtype, a8), x,
@@ -2423,7 +2587,9 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
     the store. A decoder-hybrid-decoder model (``ssm``) runs
     ``_sambay_span_forward`` over ``state``, its Mamba and window layers'
-    stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``. A
+    stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``; so
+    does a tree with ``mamba_layers`` (``_jamba_span_forward``, ``state`` its
+    Mamba layers' store). A
     model whose blocks are one mixer each (``ssd``) runs
     ``_mixer_span_forward`` over ``state``, its Mamba-2 blocks' store, and
     returns the store fourth and its routed FFNs' stats fifth.
@@ -2478,6 +2644,12 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
             params, x, pool_k, pool_v, state, kv_attend, seg=seg, pos=pos,
             qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
             eps=eps, ssd=ssd, moe=moe, return_picks=return_picks)
+    if "mamba_layers" in params:
+        x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
+        return _jamba_span_forward(
+            params, x, pool_k, pool_v, state, kv_attend, seg=seg, pos=pos,
+            qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
+            eps=eps, ssm=ssm)
     if ssm is not None:
         x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
         return _sambay_span_forward(

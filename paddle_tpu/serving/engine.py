@@ -327,7 +327,7 @@ class ContinuousBatchingEngine:
                    "prefix_cache": bool(prefix_cache)}
             if any(k in self._params
                    for k in ("wkv_a", "linear_layers", "self_layers",
-                             "ssd_layers")):
+                             "ssd_layers", "mamba_layers")):
                 # a latent pool has no heads to scale by and no V side:
                 # the quantized pools' planes and kernels do not apply;
                 # a quantized cache of a model with recurrent or window
@@ -405,7 +405,7 @@ class ContinuousBatchingEngine:
         # (``PagedKVCache.state``), and the pool holds rows for the OTHER
         # layers only
         self._stateful = any(k in self._params for k in (
-            "linear_layers", "self_layers", "ssd_layers"))
+            "linear_layers", "self_layers", "ssd_layers", "mamba_layers"))
         kv_layers = c.num_kv_layers if self._stateful \
             else c.num_hidden_layers
         # what a cached token's row is: Hkv heads of head_dim on a K and a
@@ -501,9 +501,13 @@ class ContinuousBatchingEngine:
                                               d.state),
                               d.conv - 1, c.conv_channels)
         elif self._stateful:
+            # Mamba-1 layers: a decoder-hybrid-decoder model's, which also
+            # has window layers (below), or a tree with ``mamba_layers``
             state_geometry = (c.num_ssm_layers,
                               (c.mamba_d_state, c.d_inner),
                               c.mamba_d_conv - 1, c.d_inner)
+            self._ring_blocks = 0
+        if "self_layers" in self._params:
             # a window layer's ring a slot: the window, the longest span a
             # step may write before it attends (a chunk; one token without
             # chunking) and a block, in whole blocks: no key a query of the

@@ -56,6 +56,7 @@ _ARCH = {
     "glm_moe_dsa": ("glm_moe_dsa", "GlmMoeDsaForCausalLM",
                     "glm_moe_dsa_tiny"),
     "nemotron_h": ("nemotron_h", "NemotronHForCausalLM", "nemotron_h_tiny"),
+    "jamba": ("jamba", "JambaForCausalLM", "jamba_tiny"),
 }
 
 

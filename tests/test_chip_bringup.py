@@ -406,6 +406,62 @@ class TestMosaicCompilesPhi4Flash:
             head_dim=hd) == dict(block_q=96 * heads, pages=6, one_token=True)
 
 
+class TestMosaicCompilesJamba:
+    """Jamba2-3B's kernels at its serving cell's shapes: ONE store of all 26
+    Mamba layers' states (16 slots x ``[16, 5120]`` float32, 136 MiB), a
+    packed buffer of 16 + 512 rows or of 16; 20 query heads on one KV head
+    of 128: a group that is no power of two, a pool row of 128 lanes, tables
+    of 1,024 entries."""
+    C, N, R, LL = 5120, 16, 16, 26
+
+    @pytest.mark.parametrize("rows", [528, 16], ids=["chunk", "decode_only"])
+    def test_the_scan_kernels_in_place(self, v5e, rows):
+        f32, i32 = jnp.float32, jnp.int32
+
+        def both(dt, u, b, c, a, st, start, length, live, fresh, layer):
+            y1, st = selective_scan.ssm_recurrent_update(
+                dt[:self.R], u[:self.R], b[:self.R], c[:self.R], a, st,
+                layer=layer, live=live, fresh=fresh)
+            yn, st = selective_scan.ssm_chunk_scan(
+                dt, u, b, c, a, st, layer=layer, start=start, length=length,
+                fresh=fresh, min_span=2)
+            return y1, yn, st
+        args = (v5e((rows, self.C), f32), v5e((rows, self.C), f32),
+                v5e((rows, self.N), f32), v5e((rows, self.N), f32),
+                v5e((self.N, self.C), f32),
+                v5e((self.LL, self.R, self.N, self.C), f32),
+                v5e((self.R,), i32), v5e((self.R,), i32),
+                v5e((self.R,), jnp.bool_), v5e((self.R,), jnp.bool_),
+                v5e((), i32))
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(both, donate_argnums=(5,)).lower(
+                *args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes > 128 * 2 ** 20
+        assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+    @pytest.mark.parametrize("rows", [528, 16], ids=["chunk", "decode_only"])
+    def test_ragged_attention_at_twenty_heads_on_one(self, v5e, rows):
+        i32, hd, heads, mb = jnp.int32, 128, 20, 1024
+
+        def attend(q, pk, pv, tables, qs, ql, kl, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer)
+        pool = v5e((2, self.R * mb, 32, hd))
+        n = _mosaic_calls(
+            attend, v5e((rows, heads, hd)), pool, pool,
+            v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
+            v5e((self.R,), i32), v5e((), i32))
+        assert n == 1
+        # 16 tokens a query block (320 of a plane's 512 rows: whole row tiles
+        # of whole tokens), 256 keys an update, no walk of its own for a span
+        # of one token (20 rows are no whole row tile)
+        assert pallas_ragged_attention.grid_params(
+            jnp.bfloat16, 32, hd, mb, heads, rows, head_dim=hd) == dict(
+                block_q=16 * heads, pages=8, one_token=False)
+
+
 class TestMosaicCompilesNemotronH:
     """Nemotron-3-Nano's kernels at its published widths (Mamba-2 blocks of
     64 heads x 64 channels in 8 groups, a state of 128, float32 by slot; two

@@ -83,6 +83,8 @@ class TestRaggedKernelParity:
         (8, 1, 64, 3, 16),        # MQA, small blocks
         (4, 4, 64, 4, 16),        # MHA
         (32, 2, 32, 4, 16),       # GQA group 16 (Nemotron-3-Nano's 32 on 2)
+        (20, 1, 128, 4, 16),      # MQA group 20, not a power of two, D 128
+                                  # (Jamba2-3B's 20 on 1): decode rows, chunks
     ])
     def test_matches_reference_mixed_spans(self, H, Hkv, D, mb, bs):
         """Decode rows, multi-token chunks (1..block and beyond), a
