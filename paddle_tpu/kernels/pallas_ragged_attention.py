@@ -96,27 +96,39 @@ are ``pallas_paged_decode.py``'s):
   column ``k`` of the plane that rode the same physical index as its data
   block, applied to the head's window as it is upcast; an fp8 pool's
   per-block scale is spread over the block's rows.
-- **The query block is sized by the per-head state** (``query_block_rows``):
-  512 rows of one KV head's plane (one head's float32 score tile at 256
-  keys is 512 KiB), fewer where the accumulator of all planes would pass
-  2 MiB; in whole row tiles of whole tokens. 128 tokens at Mistral's 32 / 8
-  / 128 and Olmo-Hybrid's 30 / 30 / 128, 256 at OLMoE's 16 / 16 / 128: a
-  512-token chunk re-reads its prefix 4 or 5 times (the block-diagonal
-  form's 16 tokens a block: 32 times).
+- **Everything the kernel cuts is whole tokens in whole 16-row tiles, at
+  any group width.** The query block is sized by the state it carries
+  (``query_block_rows``): as many whole row tiles of TOKENS as the float32
+  accumulator of all planes holds under 2 MiB, so ``tokens * G`` rows of a
+  plane are whole row tiles whatever ``G`` is. 128 tokens at Mistral's 32 /
+  8 / 128, Olmo-Hybrid's 30 / 30 / 128 and Nemotron-3-Nano's 32 / 2 / 128,
+  256 at OLMoE's 16 / 16 / 128, 192 at Jamba2-3B's 20 / 1 / 128: a
+  512-token chunk re-reads its prefix 3 to 5 times (a block cut to 512 rows
+  of a plane held 16 tokens at a group of 20 and walked it 32 times). How
+  tall a plane is decides only how the general walk takes it: **in static
+  row chunks of at most 512 rows** (``_row_chunks``: one head's float32
+  score tile at 256 keys stays 512 KiB), each on its own views of ``m / l /
+  acc``, and **a chunk that holds no row of the pair's span, or no key at
+  or under its last row's diagonal, is skipped**, so a short span in a tall
+  block computes on its own chunks only. A plane of at most 512 rows is one
+  chunk and the walk is unconditional.
 - **A span of one token takes one product over the whole pool row**: a
   decode row is bound by its KV bytes, and ``Hkv`` small per-head products
   an update would make it slower (measured: PERF.md, PR 36). The kernel
   observes ``qlen == 1``, cuts the token's ``H`` query rows out of the
-  head-major block once a pair (each plane's aligned 16-row tile, a masked
-  sum over its rows), lays them block-diagonal ``[H, KD]`` in a VMEM
-  scratch, and walks the groups with one ``[H, KD] x [KD, keys]`` and one
-  ``[H, keys] x [keys, KD]`` product an update; each head's own ``D`` lanes
-  go back into the token's rows of its plane. The wide tile never leaves
-  VMEM and its zeros are laid once a call. A quantized pool's group is
-  upcast for it head window by head window, each with its own scale. Where
-  a token's ``G`` rows could straddle two tiles (``G`` 3) every span takes
-  the per-head walk (``_token_tile``; ``pallas_mla_ragged_attention`` has
-  two walks too).
+  head-major block once a pair (each plane's aligned tile of ``lcm(16, G)``
+  rows, ``_token_tile``: whole tokens in whole row tiles, so no token
+  straddles two; 16 rows at a group of 1 to 16 that divides 16, 80 = 4
+  tokens at 20, 48 at 3; a masked sum over its rows), lays them
+  block-diagonal ``[H, KD]`` in a VMEM scratch, and walks the groups with
+  one ``[H, KD] x [KD, keys]`` and one ``[H, keys] x [keys, KD]`` product an
+  update; each head's own ``D`` lanes go back into the token's rows of its
+  plane. The wide tile never leaves VMEM and its zeros are laid once a
+  call. A quantized pool's group is upcast for it head window by head
+  window, each with its own scale. Only where a query block is no whole
+  number of such tiles (a test's block of 5 or 17 tokens) does every span
+  take the per-head walk (``pallas_mla_ragged_attention`` has two walks
+  too).
 - **One output block, several rows**: visits to an output block are
   consecutive; the first zeroes it, each row's visit writes back only its
   own span, by a masked read-modify-write. The last query block may reach
@@ -337,40 +349,87 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
     def _span():
         # the general walk, on every plane of the query block: head k's
         # scores and P V from its own D lanes of the group, [rows, D] x
-        # [D, group] and [rows, group] x [group, D]
-        _reset(m_scr, l_scr, acc_scr)
-        # causal-within-span, the same for every head: plane row j belongs
-        # to span token (j - span_lo) // g, whose logical position is
-        # kvlen - qlen + that token index; a row of the block outside the
-        # span sees no key
-        prow = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        in_span = (prow >= span_lo) & (prow < span_hi)
-        tok = prow - span_lo
-        if g > 1:
-            tok = tok // g
-        pos = jnp.where(in_span, kvlen - qlen + tok, -1)
+        # [D, group] and [rows, group] x [group, D], a plane taller than
+        # ``_PLANE_ROWS`` in static row chunks (``_row_chunks``) so that a
+        # score tile stays at most that by a group of keys. A chunk computes
+        # only where it holds a row of this pair's span and a key at or
+        # under its last row's diagonal: a short span in a tall block (a
+        # chunk's spill into its last block, a span of one token without a
+        # tile of its own) never computes on the whole block. One chunk is
+        # the whole plane, always live (the work list holds only pairs that
+        # intersect, and walks them to their diagonal): it takes the whole
+        # refs under no predicate and computes no chunk scalars, so a plane
+        # of at most ``_PLANE_ROWS`` lowers to the walk it had before the
+        # chunks, operation for operation (PERF.md section 6, PR 51).
+        chunks = _row_chunks(rows)
+        tall = len(chunks) > 1
+
+        def where_live(live):
+            return pl.when(live) if tall else (lambda f: f())
+
+        def rows_live(c0, n):
+            # (scalars) whether the chunk holds a row of the span, and the
+            # position of the last one it holds
+            hi = jnp.minimum(row0 + c0 + n, span_hi)
+            live = hi > jnp.maximum(row0 + c0, span_lo)
+            return live, kvlen - qlen + (hi - 1 - span_lo) // g
+
+        def rows_mask(c0, n):
+            # causal-within-span, the same for every head: plane row j
+            # belongs to span token (j - span_lo) // g, whose logical
+            # position is kvlen - qlen + that token index; a row of the
+            # block outside the span sees no key
+            prow = (row0 + c0 if c0 else row0) \
+                + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+            in_span = (prow >= span_lo) & (prow < span_hi)
+            tok = prow - span_lo
+            if g > 1:
+                tok = tok // g
+            return in_span, jnp.where(in_span, kvlen - qlen + tok, -1)
+
+        state = (m_scr, l_scr, acc_scr)
+        ats = [slice(c0, c0 + n) if tall else slice(None) for c0, n in chunks]
+        lives = [rows_live(*c) if tall else (None, None) for c in chunks]
+        for at, (live, _) in zip(ats, lives):
+            @where_live(live)
+            def _reset_chunk():
+                _reset(*(x.at[:, at] if tall else x for x in state))
+        parts = [(at, *rows_mask(*c), live, last)
+                 for at, c, (live, last) in zip(ats, chunks, lives)]
 
         def update(gi, slot):
-            key = jax.lax.broadcasted_iota(jnp.int32, (rows, group), 1)
-            valid = key <= pos - gi * group
-            if window is not None:
-                # the window's edge, inside the first group walked
-                valid = valid & (key > pos - window - gi * group)
-            for k in range(hkv):
-                s = jax.lax.dot_general(
-                    q_ref[k], _head_rows(k_buf, slot, ks_buf, k),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                _softmax_update(s, valid, _head_rows(v_buf, slot, vs_buf, k),
-                                m_scr.at[k], l_scr.at[k], acc_scr.at[k])
+            for at, _, pos, live, last in parts:
+                @where_live(tall and live & (gi * group <= last))
+                def _chunk():
+                    key = jax.lax.broadcasted_iota(
+                        jnp.int32, (pos.shape[0], group), 1)
+                    valid = key <= pos - gi * group
+                    if window is not None:
+                        # the window's edge, inside the first group walked
+                        valid = valid & (key > pos - window - gi * group)
+                    for k in range(hkv):
+                        s = jax.lax.dot_general(
+                            q_ref[k, at, :],
+                            _head_rows(k_buf, slot, ks_buf, k),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+                        _softmax_update(
+                            s, valid, _head_rows(v_buf, slot, vs_buf, k),
+                            m_scr.at[k, at], l_scr.at[k, at],
+                            acc_scr.at[k, at])
 
         _groups(update)
         # write back ONLY this row's span: the output block is shared by
         # every sequence whose span intersects it, so the write is a masked
         # read-modify-write (rows not in the span keep their value)
-        for k in range(hkv):
-            out = acc_scr[k] / jnp.maximum(l_scr[k, :, :1], 1e-30)
-            o_ref[k] = jnp.where(in_span, out.astype(o_ref.dtype), o_ref[k])
+        for at, in_span, _, live, _ in parts:
+            @where_live(live)
+            def _write_chunk():
+                for k in range(hkv):
+                    out = acc_scr[k, at] / jnp.maximum(l_scr[k, at, :1],
+                                                       1e-30)
+                    o_ref[k, at, :] = jnp.where(
+                        in_span, out.astype(o_ref.dtype), o_ref[k, at, :])
 
     if not tile:
         return
@@ -444,13 +503,25 @@ _ROW_TILE = 16
 
 
 def _token_tile(plane_rows, g):
-    """The aligned row tile of a plane that holds a token's ``g`` rows (16,
-    or ``g`` where that is several tiles), where a query block of
-    ``plane_rows`` is whole such tiles and no token straddles two; else 0:
-    the kernel then has no walk of its own for a span of one token."""
-    tile = max(_ROW_TILE, g)
-    whole = tile % g == 0 and tile % _ROW_TILE == 0
-    return tile if whole and plane_rows % tile == 0 else 0
+    """The aligned row tile of a plane that holds whole tokens' ``g`` rows in
+    whole row tiles, ``lcm(16, g)`` rows (16 at a group of 1, 2, 4, 8 or 16;
+    80, four tokens in five row tiles, at 20; 48 at 3), where a query block
+    of ``plane_rows`` is whole such tiles, so no token straddles two; else
+    0: the kernel then has no walk of its own for a span of one token."""
+    tile = math.lcm(_ROW_TILE, g)
+    return tile if plane_rows % tile == 0 else 0
+
+
+def _row_chunks(plane_rows):
+    """The static ``(first row, rows)`` chunks in which the general walk
+    takes a plane of ``plane_rows``: as few as hold at most ``_PLANE_ROWS``
+    rows each, of equal size in whole row tiles (the last may be shorter).
+    One chunk, the whole plane, up to ``_PLANE_ROWS``; 8 of 480 rows (24
+    tokens) at a group of 20 and 192 tokens, 4 of 512 at 16 and 128."""
+    n = -(-plane_rows // _PLANE_ROWS)
+    size = -(-plane_rows // (n * _ROW_TILE)) * _ROW_TILE
+    return [(c0, min(size, plane_rows - c0))
+            for c0 in range(0, plane_rows, size)]
 
 
 def _one_token_walk(gh, tq):
@@ -472,15 +543,22 @@ _GROUP_KEYS = 256
 _GROUP_BYTES = 2 << 20
 _LANE_KEYS = 128
 
-#: what a query block holds: at most this many rows of one KV head's plane
-#: (one head's float32 score tile is that by a group of keys), fewer where
-#: the float32 accumulator of all planes would pass these bytes
+#: what a query block holds: as many whole row tiles of tokens as keep the
+#: float32 accumulator of all planes, ``[Hkv, tokens * G, D]``, under
+#: ``_ACC_BYTES``; the general walk takes a plane of it in row chunks of at
+#: most ``_PLANE_ROWS`` (one head's float32 score tile is that by a group of
+#: keys: 512 KiB at 256)
 _PLANE_ROWS = 512
 _ACC_BYTES = 2 << 20
 
 #: the scoped VMEM the call asks for: the accumulator, the softmax state at
 #: a lane tile a row, two query and two output blocks, the K and V buffers
-#: and a few score tiles (14 MiB at Mistral's geometry, 16 at Olmo-Hybrid's)
+#: and a few score tiles (14 MiB at Mistral's geometry, 16 at Olmo-Hybrid's;
+#: at the two geometries whose block the accumulator alone sizes, Jamba2-3B's
+#: 20 / 1 / 128 at 192 tokens: accumulator, ``m`` and ``l`` 1.9 MiB each,
+#: four blocks of 0.94 MiB, one score tile of 480 KiB and its temporaries,
+#: about 12 MiB; Nemotron-3-Nano's 32 / 2 / 128 at 128 tokens: three times
+#: 2 MiB, four blocks of 1 MiB, about 13 MiB)
 _VMEM_BYTES = 48 << 20
 
 
@@ -498,18 +576,24 @@ def pages_per_update(pool_dtype, block_size, kd, table_entries):
     return max(1, min(keys // int(block_size), int(table_entries)))
 
 
-def query_block_rows(kd, heads, head_dim):
+def query_block_rows(heads, head_dim):
     """(Token, head) rows of a query block (before ``_query_block`` fits it
-    to the packed buffer), from the per-head accumulator and score tile: a
-    KV head's plane holds ``_PLANE_ROWS`` rows of the block, fewer where the
-    accumulator ``[Hkv, rows, D]`` would pass ``_ACC_BYTES``, in whole row
-    tiles of whole tokens. 128 tokens at Mistral's 32 / 8 / 128 (a 512-token
-    chunk walks its prefix 4 or 5 times), 256 at OLMoE's 16 / 16 / 128, 128
-    at Olmo-Hybrid's 30 / 30 / 128."""
-    g = int(heads) * int(head_dim) // int(kd)
-    rows = min(_PLANE_ROWS, _ACC_BYTES // (4 * int(kd)))
-    tokens = max(_ROW_TILE, rows // g // _ROW_TILE * _ROW_TILE)
-    return tokens * int(heads)
+    to the packed buffer), from the state the block carries: as many tokens
+    as the float32 accumulator ``[Hkv, tokens * G, D]`` holds under
+    ``_ACC_BYTES`` (a row of it is no narrower than a lane tile), in whole
+    row tiles of tokens, so in whole row tiles of whole tokens at any group.
+    128 tokens at Mistral's 32 / 8 / 128 (a 512-token chunk walks its prefix
+    4 or 5 times) and Olmo-Hybrid's 30 / 30 / 128, 256 at OLMoE's 16 / 16 /
+    128, 192 at Phi-4-mini-flash's 20 / 10 / 128 and at Jamba2-3B's 20 / 1 /
+    128 (3,840 rows of its one plane, 3 walks), 128 at Nemotron-3-Nano's 32
+    / 2 / 128 (2,048 rows a plane). How tall a plane is decides how the walk
+    chunks it (``_row_chunks``), not how much a block holds. The lane tile
+    is what ``m`` and ``l`` cost a row whatever ``D`` is, so heads narrower
+    than 128 that the kernel sees unpaired get another block than one cut to
+    512 rows of a plane held (16 / 16 / 64: 256 tokens for 512); no cell has
+    them."""
+    tokens = _ACC_BYTES // (4 * int(heads) * max(int(head_dim), 128))
+    return max(_ROW_TILE, tokens // _ROW_TILE * _ROW_TILE) * int(heads)
 
 
 def _least(a, b):
@@ -744,7 +828,7 @@ def grid_params(pool_dtype, block_size, kd, table_entries, heads,
     the engine passes it to ``ragged_grid_counts``, so the host's counts are
     the kernel's."""
     if block_q is None:
-        block_q = query_block_rows(kd, heads, head_dim)
+        block_q = query_block_rows(heads, head_dim)
     if pages is None:
         pages = pages_per_update(pool_dtype, block_size, kd, table_entries)
     block_q = _query_block(block_q, heads, packed_tokens)

@@ -15,32 +15,24 @@ PERF.md (PR 48) says of the store's layout is this script's output, from the
 parent's checkout and from the change's.
 
     chiprun -- python3 scripts/bench_ssd.py [--seed N] [--iters N] [--repo DIR]
-"""
-import argparse
-import json
-import os
-import sys
-import time
 
-HBM_GBPS = 819.0        # one v5e chip (Google Cloud documentation, "TPU v5e")
+(``--rehearse`` off the chip: ``scripts/kernel_bench.py``.)
+"""
+import sys
+
+import kernel_bench
+from kernel_bench import HBM_GBPS
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), help="the checkout whose kernels run")
-    a = ap.parse_args()
-    sys.path.insert(0, os.path.abspath(a.repo))
+    a = kernel_bench.arguments(__doc__, iters=20).parse_args()
+    platform, tiny = kernel_bench.start(a)
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from paddle_tpu.kernels import ssd
 
-    platform = jax.devices()[0].platform
-    tiny = platform != "tpu"        # a rehearsal: small and interpreted
     H, P, G, N = (4, 8, 2, 16) if tiny else (64, 64, 8, 128)
     R, LL, chunk = (6, 2, 100) if tiny else (32, 23, 512)
     T = R + chunk
@@ -82,20 +74,13 @@ def main():
 
     def timed(fn, store, *args):
         fn = jax.jit(fn, donate_argnums=(0,) if not tiny else ())
-        t0 = time.perf_counter()
-        store, _ = jax.block_until_ready(fn(store, *args))
-        first = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for _ in range(a.iters):
-            store, _ = fn(store, *args)
-        jax.block_until_ready(store)
-        return store, 1e3 * (time.perf_counter() - t0) / a.iters / LL, first
+        store, ms, first = kernel_bench.timed(
+            lambda st: fn(st, *args)[0], store, a.iters)
+        return store, ms / LL, first
 
     def line(case, **out):
-        print(json.dumps({"case": case, "platform": platform,
-                          "widths": [H, P, G, N], "slots": R, "blocks": LL,
-                          **{k: (float(f"{v:.5g}") if isinstance(v, float) else v)
-                             for k, v in out.items()}}), flush=True)
+        kernel_bench.line(case, platform, widths=[H, P, G, N], slots=R,
+                          blocks=LL, **out)
 
     # the update's y on block 0 against the recurrence, before any timing
     # moves the store

@@ -454,12 +454,15 @@ class TestMosaicCompilesJamba:
             v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
             v5e((self.R,), i32), v5e((), i32))
         assert n == 1
-        # 16 tokens a query block (320 of a plane's 512 rows: whole row tiles
-        # of whole tokens), 256 keys an update, no walk of its own for a span
-        # of one token (20 rows are no whole row tile)
+        # the accumulator's 192 tokens a query block (3,840 rows of the one
+        # plane, walked in 8 row chunks of 480; a buffer of 16 is one block of
+        # 320), 256 keys an update, and a span of one token on its own tile
+        # of 80 rows: 4 tokens in 5 row tiles
         assert pallas_ragged_attention.grid_params(
             jnp.bfloat16, 32, hd, mb, heads, rows, head_dim=hd) == dict(
-                block_q=16 * heads, pages=8, one_token=False)
+                block_q=min(192, rows) * heads, pages=8, one_token=True)
+        assert pallas_ragged_attention._row_chunks(192 * heads) \
+            == [(c0, 480) for c0 in range(0, 3840, 480)]
 
 
 class TestMosaicCompilesNemotronH:
@@ -503,6 +506,29 @@ class TestMosaicCompilesNemotronH:
         self._in_place(scan, self._args(v5e, self.T) + (
             v5e((self.R,), jnp.int32), v5e((self.R,), jnp.int32),
             v5e((self.R,), jnp.bool_), v5e((), jnp.int32)))
+
+    @pytest.mark.parametrize("rows", [544, 32], ids=["chunk", "decode_only"])
+    def test_ragged_attention_at_sixteen_heads_a_kv_head(self, v5e, rows):
+        """The six attention blocks' 32 query heads on 2 KV heads of 128 at
+        the cell's shapes (32 slots x 192 table entries): the accumulator's
+        128 tokens a query block, each plane's 2,048 rows walked in 4 row
+        chunks of 512."""
+        i32, hd, heads, mb = jnp.int32, 128, 32, 192
+
+        def attend(q, pk, pv, tables, qs, ql, kl, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer)
+        pool = v5e((6, self.R * mb, 32, 2 * hd))
+        n = _mosaic_calls(
+            attend, v5e((rows, heads, hd)), pool, pool,
+            v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
+            v5e((self.R,), i32), v5e((), i32))
+        assert n == 1
+        assert pallas_ragged_attention.grid_params(
+            jnp.bfloat16, 32, 2 * hd, mb, heads, rows, head_dim=hd) == dict(
+                block_q=min(128, rows) * heads, pages=8, one_token=True)
+        assert pallas_ragged_attention._row_chunks(128 * 16) \
+            == [(c0, 512) for c0 in range(0, 2048, 512)]
 
     def test_two_matrix_experts_read_their_stacks_in_place(self, v5e):
         """``w_up`` by output unit: no operand's minor dimension is the

@@ -549,22 +549,34 @@ def test_ragged_grid_counts_at_the_cells_geometry(step):
     # and 256 tokens, Olmo-Hybrid's 30 / 30 / 128 4 pages (a lane tile of
     # keys, the least) and 128 tokens; a one-byte pool counts as float32
     pages, block_q = pages_per_update("bfloat16", 32, 8 * 128, 128), \
-        query_block_rows(8 * 128, 32, 128)
+        query_block_rows(32, 128)
     assert (pages, block_q) == (8, 128 * 32)
     # the call's tiling is those two, fitted to the heads and the table,
-    # and whether a decode row has a row tile of its own (not at 3 heads a
-    # KV head: a token would straddle tiles)
+    # and whether a decode row has a row tile of its own (at 3 heads a KV
+    # head one of 48 rows, 16 tokens in 3 row tiles, since PR 51)
     tiling = grid_params("bfloat16", 32, 8 * 128, 128, 32, 520, head_dim=128)
     assert tiling == dict(block_q=128 * 32, pages=8, one_token=True)
     assert grid_params("bfloat16", 32, 8 * 128, 128, 24, 520, pages=999,
                        head_dim=128) \
-        == dict(block_q=160 * 24, pages=128, one_token=False)
+        == dict(block_q=160 * 24, pages=128, one_token=True)
     assert grid_params("bfloat16", 32, 8 * 128, 128, 32, 8, head_dim=128) \
         == dict(block_q=8 * 32, pages=8, one_token=True)
     assert (pages_per_update("bfloat16", 32, 16 * 128, 64),
-            query_block_rows(16 * 128, 16, 128)) == (4, 256 * 16)
+            query_block_rows(16, 128)) == (4, 256 * 16)
     assert (pages_per_update("bfloat16", 32, 30 * 128, 72),
-            query_block_rows(30 * 128, 30, 128)) == (4, 128 * 30)
+            query_block_rows(30, 128)) == (4, 128 * 30)
+    # Phi-4-mini-flash's 40 / 10 over pairs of 128 (and as 20 / 10 / 128),
+    # 6 pages: the four geometries above are what they were before PR 51
+    assert (pages_per_update("bfloat16", 32, 10 * 128, 256),
+            query_block_rows(40, 128),
+            query_block_rows(20, 128)) == (6, 96 * 40, 192 * 20)
+    # the two whose block the accumulator alone sizes since PR 51 (a plane
+    # cut to 512 rows held 32 and 16 tokens): Nemotron-3-Nano's 32 / 2 / 128
+    # and Jamba2-3B's 20 / 1 / 128, whose decode rows have a tile of 80 rows
+    assert (pages_per_update("bfloat16", 32, 2 * 128, 192),
+            query_block_rows(32, 128)) == (8, 128 * 32)
+    assert (pages_per_update("bfloat16", 32, 128, 1024),
+            query_block_rows(20, 128)) == (8, 192 * 20)
     assert pages_per_update("int8", 32, 8 * 128, 128) == 4
     assert pages_per_update("float32", 16, 128, 5) == 5
     kw = dict(heads=32, block_size=32, table_entries=128, packed_tokens=520)
@@ -575,6 +587,64 @@ def test_ragged_grid_counts_at_the_cells_geometry(step):
     assert got["live_steps"] / 8 <= got["update_steps"] \
         < got["live_steps"] / 4
     assert got["one_token_rows"] == sum(n == 1 for n in qlen)
+
+
+CELL_TILINGS = {
+    # cell's model: (pool row, table entries, heads, packed tokens), tiling
+    "mistral": ((8 * 128, 128, 32, 520),
+                dict(block_q=128 * 32, pages=8, one_token=True)),
+    "olmoe": ((16 * 128, 64, 16, 536),
+              dict(block_q=256 * 16, pages=4, one_token=True)),
+    "olmo_hybrid": ((30 * 128, 72, 30, 544),
+                    dict(block_q=128 * 30, pages=4, one_token=True)),
+    "phi4_flash": ((10 * 128, 256, 40, 560),
+                   dict(block_q=96 * 40, pages=6, one_token=True)),
+    "phi4_flash_as_pairs": ((10 * 128, 256, 20, 560),
+                            dict(block_q=192 * 20, pages=6, one_token=True)),
+    "nemotron3_nano": ((2 * 128, 192, 32, 544),
+                       dict(block_q=128 * 32, pages=8, one_token=True)),
+    "jamba2": ((128, 1024, 20, 528),
+               dict(block_q=192 * 20, pages=8, one_token=True)),
+    "jamba2_decode_only": ((128, 1024, 20, 16),
+                           dict(block_q=16 * 20, pages=8, one_token=True)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TILINGS))
+def test_grid_params_at_every_dense_cells_geometry(cell):
+    """The call's tiling at each dense serving cell's geometry (bfloat16
+    pools in blocks of 32, heads of 128): the first five are what they were
+    before PR 51 sized the query block by its accumulator alone; Nemotron's
+    block held 32 tokens and Jamba's 16, with no tile for a decode row."""
+    (kd, entries, heads, packed), tiling = CELL_TILINGS[cell]
+    assert grid_params("bfloat16", 32, kd, entries, heads, packed,
+                       head_dim=128) == tiling
+
+
+def test_ragged_grid_counts_at_jambas_geometry():
+    """A step of ``serve-jamba2-longdoc-prefill``: 8 decode rows 16k into
+    their documents and a 512-token chunk 8k into its own, 20 heads on one
+    KV head, tables of 1,024 blocks of 32. The chunk meets 3 query blocks of
+    192 tokens, each walking the prefix to its own diagonal (33 + 34 + 34
+    updates of 256 keys), and every decode row its own 65 on its own row
+    tile; at 16 tokens a block (a plane cut to 512 rows, before PR 51) the
+    chunk met 33 blocks and no row had a tile."""
+    qstart = list(range(8)) + [8] + [0] * 7
+    qlen = [1] * 8 + [512] + [0] * 7
+    kvlen = [16_384 + 1] * 8 + [8_192 + 512] + [0] * 7
+    tiling = grid_params("bfloat16", 32, 128, 1024, 20, 528, head_dim=128)
+    kw = dict(heads=20, block_size=32, table_entries=1024, packed_tokens=528)
+    got = ragged_grid_counts(qstart, qlen, kvlen, **kw, **tiling)
+    assert got == _brute_force(qstart, qlen, kvlen, **kw, **tiling)
+    assert got["one_token_rows"] == 8
+    assert got["update_steps"] == 8 * 65 + 33 + 34 + 34
+    old = dict(block_q=16 * 20, pages=8, one_token=False)
+    before = ragged_grid_counts(qstart, qlen, kvlen, **kw, **old)
+    assert before == _brute_force(qstart, qlen, kvlen, **kw, **old)
+    assert before["one_token_rows"] == 0
+    assert before["update_steps"] == 8 * 65 + 1_106
+    assert before["attn_pairs"] == got["attn_pairs"]
+    assert before["kv_tokens"] == got["kv_tokens"]
 
 
 # ---------------------------------------------- names on the device's work
