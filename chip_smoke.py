@@ -450,14 +450,14 @@ def serve_phase(name, size, env, deadline, rehearse, tp=1):
         check(all("pallas" in k for k in mem if k.startswith("ragged[")),
               f"{name}: step program is not the pallas one: {list(mem)}")
 
-        # the driver thread's phase clock is always on: eight phases on two
+        # the driver thread's phase clock is always on: ten phases on two
         # clocks, and the wall phases partition the thread's time (both
         # scrapes find the server idle, a mark every 20 ms)
         driver = _driver_wall_seconds(metrics)
         elapsed = t_metrics - t_driver0
         charged = sum(driver.values()) - sum(driver0.values())
-        check(len(driver) == 8 and len(_metric_samples(
-            metrics, "serving_driver_seconds_total")) == 16
+        check(len(driver) == 10 and len(_metric_samples(
+            metrics, "serving_driver_seconds_total")) == 20
             and abs(charged - elapsed) <= 0.02 * elapsed,
             f"{name}: the driver clock's wall phases sum to {charged:.3f} s "
             f"of {elapsed:.3f} s elapsed: {driver}")

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import json
 
-from .tracing import TID_ENGINE, TID_GATEWAY, TID_REQ0
+from .tracing import TID_ENGINE, TID_GATEWAY, TID_GC, TID_REQ0
 
 
 def lane_name(tid: int) -> str:
@@ -35,6 +35,8 @@ def lane_name(tid: int) -> str:
         return "engine"
     if tid == TID_GATEWAY:
         return "gateway"
+    if tid == TID_GC:
+        return "gc"
     if tid >= TID_REQ0:
         return f"req{tid - TID_REQ0}"
     return f"tid{tid}"

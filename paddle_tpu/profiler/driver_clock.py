@@ -10,9 +10,15 @@ over phases the wall time equals the time since the first mark. Wall less
 CPU of a phase is the time the thread was runnable or blocked and not
 computing (the GIL held by another thread, a blocking transfer).
 
+A visit to one phase, mark to mark, that lasts longer than
+:data:`LONG_VISIT_S` is counted by its phase besides: the sums say that
+``dispatch`` costs 2.6 ms a step, the long visits that it once cost 107.
+
 The gateway owns one instance across engine rebuilds, as it owns the tracer
 and the cost observatory, and exports it as
-``serving_driver_seconds_total{phase, clock}``. Only the driver thread
+``serving_driver_seconds_total{phase, clock}``,
+``serving_driver_long_visits_total{phase}`` and
+``serving_driver_long_visit_seconds_total{phase}``. Only the driver thread
 writes; a scrape reads floats.
 """
 from __future__ import annotations
@@ -20,12 +26,20 @@ from __future__ import annotations
 import time
 
 #: ``loop``: the gateway between two steps (intake, cancels, deadlines,
-#: captures, supervision); ``idle-wait``: waiting for work; ``admit`` /
-#: ``plan`` / ``dispatch`` / ``device-wait`` / ``host-accept``: the engine's
-#: spans of the same names; ``other``: inside ``step()`` and under none of
-#: those (deadline sweep, dispatch args, step accounting, counter samples)
-PHASES = ("loop", "idle-wait", "admit", "plan", "dispatch", "device-wait",
-          "host-accept", "other")
+#: captures, supervision); ``idle-wait``: waiting for work; ``sweep`` /
+#: ``admit`` / ``plan`` / ``dispatch`` / ``device-wait`` / ``host-accept`` /
+#: ``retire``: the engine's spans of the same names (``sweep``: the step's
+#: start, deadlines, policy and the scheduler's admissions; ``retire``: step
+#: accounting, ``on_step``, a traced step's counter samples, the return);
+#: ``other``: inside ``step()`` and under none of those (a traced step's
+#: dispatch args, the exception paths)
+PHASES = ("loop", "idle-wait", "sweep", "admit", "plan", "dispatch",
+          "device-wait", "host-accept", "retire", "other")
+#: a visit to one phase longer than this is a stall, counted where it
+#: happens: 2.5 x the largest busy phase's mean in any cell of the benchmark
+#: and under its shortest device step (10.7 ms), so a host visit that long
+#: empties the one-deep pipeline everywhere
+LONG_VISIT_S = 0.008
 
 
 class DriverClock:
@@ -42,6 +56,9 @@ class DriverClock:
         self.phase = None           # None until the first mark
         self.wall_s = dict.fromkeys(PHASES, 0.0)
         self.cpu_ns = dict.fromkeys(PHASES, 0)
+        #: visits longer than :data:`LONG_VISIT_S`, and their wall seconds
+        self.long_visits = dict.fromkeys(PHASES, 0)
+        self.long_visit_s = dict.fromkeys(PHASES, 0.0)
         self._t = 0.0
         self._c = 0
 
@@ -52,8 +69,12 @@ class DriverClock:
         t, c = self.wall(), self.cpu()
         cur = self.phase
         if cur is not None:
-            self.wall_s[cur] += t - self._t
+            dt = t - self._t
+            self.wall_s[cur] += dt
             self.cpu_ns[cur] += c - self._c
+            if dt > LONG_VISIT_S:
+                self.long_visits[cur] += 1
+                self.long_visit_s[cur] += dt
         self.phase, self._t, self._c = phase, t, c
         return t if self.stamps_spans else None
 

@@ -8,8 +8,10 @@ host-side ring buffer and renders them as Chrome trace-event JSON —
 the ``{"traceEvents": [...]}`` format Perfetto / ``chrome://tracing``
 load directly — so one capture shows the whole request lifecycle
 (``queued → prefill_chunk[i] → decode → finished``), the engine's
-per-step phases (``plan / launch / host-accept / donate``) and the
-gateway supervisor's fault/rebuild/recovery instants on one timeline.
+per-step phases (``sweep / admit / plan / launch > dispatch > call,
+device-wait / host-accept > donate / retire``), the collector's pauses
+(``gc``, a lane of its own) and the gateway supervisor's
+fault/rebuild/recovery instants on one timeline.
 
 Design constraints, in order:
 
@@ -55,9 +57,11 @@ accepted-draft lengths, fault kinds, finish reasons, counter
 samples).
 
 Thread model: the engine-driver thread is the only writer during
-serving; HTTP handler threads only snapshot (``export``). Both paths
+serving, but for the ``gc`` span, which whichever thread collected
+records; HTTP handler threads only snapshot (``export``). All paths
 take the buffer lock, so concurrent capture control
-(``clear``/``enable``/``disable`` from a handler) is safe too.
+(``clear``/``enable``/``disable`` from a handler) is safe too; the lock
+is re-entrant, because a collection can begin inside it.
 """
 from __future__ import annotations
 
@@ -86,12 +90,14 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
-#: fixed trace tids: one engine lane, one gateway/supervisor lane, then
-#: one lane per request (dense first-seen order, starting at TID_REQ0).
+#: fixed trace tids: one engine lane, one gateway/supervisor lane, one
+#: lane for the collector's pauses (``profiler/gc_watch.py``), then one
+#: lane per request (dense first-seen order, starting at TID_REQ0).
 #: pid is constant — a real os.getpid() would break byte-stable replays.
 PID = 1
 TID_ENGINE = 1
 TID_GATEWAY = 2
+TID_GC = 3
 TID_REQ0 = 8
 _REAL_CLOCKS = (time.perf_counter, time.monotonic)
 
@@ -116,7 +122,10 @@ class SpanTracer:
         #: ``annotate(name, **args)`` -> context manager, or None: the
         #: mirror of engine- and gateway-lane spans into another trace
         self.annotate = annotate
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start between two bytecodes of a
+        # thread that holds the lock, and its ``gc`` span is recorded by
+        # that thread (``profiler/gc_watch.py``)
+        self._lock = threading.RLock()
         self._events = deque(maxlen=self.capacity)
         self._enabled = False
         self._epoch = 0.0
@@ -138,12 +147,17 @@ class SpanTracer:
         self._enabled = True
         return self
 
+    @property
+    def real_clock(self) -> bool:
+        """Whether the clock is the machine's (not an injected one, under
+        which a capture must replay byte for byte)."""
+        return self.clock in _REAL_CLOCKS
+
     def _set_epoch(self):
         self._epoch = self.clock()
         # the wall clock is read only beside a real clock: an injected
         # one (a replay) must stay deterministic
-        self._epoch_unix_ns = time.time_ns() \
-            if self.clock in _REAL_CLOCKS else None
+        self._epoch_unix_ns = time.time_ns() if self.real_clock else None
 
     def disable(self):
         self._enabled = False
@@ -239,10 +253,13 @@ class SpanTracer:
         # window (a prior capture, or tracing enabled mid-flight) must
         # not stretch dur across inter-capture time — ts clamps to 0
         # in _ts, and the duration must clamp with it or the span ends
-        # past every concurrent event (an impossible timeline)
-        t0 = max(self.since_epoch(t0), self._epoch)
-        ev = {"name": name, "ph": "X", "ts": self._ts(t0),
-              "dur": round(max(t1 - t0, 0.0) * 1e6, 3),
+        # past every concurrent event (an impossible timeline). The end
+        # is rounded as a start is and the duration is what lies between:
+        # spans closed at one reading (``retire`` and its ``step``) end at
+        # one timestamp
+        ts = self._ts(max(self.since_epoch(t0), self._epoch))
+        ev = {"name": name, "ph": "X", "ts": ts,
+              "dur": round(max(self._ts(t1) - ts, 0.0), 3),
               "pid": PID, "tid": int(tid)}
         if args:
             ev["args"] = args

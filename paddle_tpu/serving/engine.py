@@ -699,6 +699,12 @@ class ContinuousBatchingEngine:
         self._t_fence = None
         self._fenced = (0, 0)
         self._finished_outside = []
+        # the step's open ``sweep`` and ``retire`` spans (None with tracing
+        # off, and between steps): ``sweep`` is closed by whichever mark
+        # comes first, ``admit`` or a variant's ``plan``; ``retire`` by the
+        # reading that closes ``step``
+        self._sweep = None
+        self._retire_span = None
         # jitted programs, shareable across engines of the same model so
         # a fresh engine never re-traces (model.generate passes the
         # model-level dict)
@@ -825,7 +831,13 @@ class ContinuousBatchingEngine:
         one wall reading, so that a phase's spans sum to what the clock
         charged it. Returns the span opened, or None with tracing off:
         sites build span args behind it (``end_args=sp and {...}``), so
-        the disabled path allocates nothing."""
+        the disabled path allocates nothing. A step passes through
+        ``sweep`` (its start: deadlines, the fault hook, policy, the
+        scheduler's admissions), ``admit``, ``plan``, ``dispatch``,
+        ``device-wait``, ``host-accept`` and ``retire`` (step accounting,
+        ``on_step``, a traced step's counter samples, the return);
+        ``other`` is what lies between them: ten phases with the
+        gateway's ``loop`` and ``idle-wait``."""
         pc = self.driver_clock
         t = pc.enter(phase) if pc is not None else None
         name = self._COST_PHASE.get(phase)
@@ -842,6 +854,22 @@ class ContinuousBatchingEngine:
             if tr is not None:
                 return tr.span(phase, args=args, t0=t)
         return None
+
+    def _mark_plan(self):
+        """Enter ``plan``: the first mark of each of the three steps, which
+        ends the step's ``sweep`` where no admission did."""
+        sweep, self._sweep = self._sweep, None
+        return self._mark("plan", span=True, end=sweep,
+                          end_args=sweep and {"admitted": 0})
+
+    def _mark_retire(self, end=None, end_args=None, outer=None,
+                     outer_args=None):
+        """Enter ``retire``, the last phase of a step: its span stays open
+        until :meth:`step` closes it with the ``step`` span, at one
+        reading; the clock's phase runs on to the driver's next mark."""
+        self._retire_span = self._mark(
+            "retire", span=True, end=end, end_args=end_args, outer=outer,
+            outer_args=outer_args)
 
     def _stamp_now(self):
         """Timestamp for the Sequence SLO stamps: the current step's
@@ -1792,6 +1820,9 @@ class ContinuousBatchingEngine:
         cost0 = co.snapshot() if co is not None else None
         finished, self._finished_outside = self._finished_outside, []
         self._fenced = (0, 0)
+        self._sweep = self._mark(
+            "sweep", span=True,
+            args=tr and {"queued": len(self.scheduler.queue)})
         # deadline sweep BEFORE admission: an expired queued request
         # must never claim a slot (and a running one stops paying for
         # decode at the first step boundary past its deadline)
@@ -1816,9 +1847,11 @@ class ContinuousBatchingEngine:
                         hit_len_fn=self._admission_hit_len
                         if self.prefix_cache is not None else None)
                     if admitted:
+                        sweep, self._sweep = self._sweep, None
                         adm = self._mark(
                             "admit", span=True,
-                            args=tr and {"n": len(admitted)})
+                            args=tr and {"n": len(admitted)}, end=sweep,
+                            end_args=sweep and {"admitted": len(admitted)})
                         try:
                             self._admit_group(admitted, finished)
                         finally:
@@ -1840,18 +1873,21 @@ class ContinuousBatchingEngine:
                 # its device call), so host bookkeeping is consistent.
                 # What an EARLIER step left in flight is fenced and
                 # accepted before a slot is torn down under it.
-                self._mark("other")     # whichever phase it was raised in
+                # whichever phase it was raised in (the fault hook's is
+                # the sweep)
+                sweep, self._sweep = self._sweep, None
+                self._mark("other", end=sweep)
                 self._abort_admission(admitted)
                 admitted = []
                 try:
                     self._drain("pool", finished)
                     repaired = self._preempt_youngest()
                 except BaseException:
-                    self._stamp_t = None
+                    self._leave_step()
                     raise
                 if not repaired:
-                    self._stamp_t = None    # leaving the step: stamps
-                    raise                   # must read a fresh clock
+                    self._leave_step()
+                    raise
             except BaseException:
                 # ANY other failure escaping mid-admission (a real
                 # device/runtime error — the crash class the supervisor
@@ -1859,12 +1895,13 @@ class ContinuousBatchingEngine:
                 # sequences in limbo: back to the queue they go, where
                 # crash recovery's snapshot can see them
                 self._abort_admission(admitted)
-                self._stamp_t = None
+                self._leave_step()
                 raise
         self.stats["steps"] += 1
         self._stamp_t = None
         if co is not None:
             co.set_phase(None)
+        retire, self._retire_span = self._retire_span, None
         if tr is not None:
             # counter tracks (ph:"C") on the same timeline as the step
             # spans, so Perfetto graphs cost alongside the phases:
@@ -1890,8 +1927,21 @@ class ContinuousBatchingEngine:
             # the tokens of the program this step FENCED (the one it
             # dispatched is counted by the step that fences it)
             step_tokens, chunk_tokens = self._fenced
-            sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0})
+            # ``retire`` ends where ``step`` does: spans of a lane nest
+            t1 = tr.now()
+            if retire is not None:
+                retire.end(t1=t1)
+            sp.end({"tokens": step_tokens, "chunks": chunk_tokens > 0},
+                   t1=t1)
+        elif retire is not None:    # a capture that began inside the step
+            retire.end()
         return finished
+
+    def _leave_step(self):
+        """An exception leaves :meth:`step`: stamps must read a fresh
+        clock, and the spans it held open are dropped unrecorded."""
+        self._stamp_t = None
+        self._sweep = self._retire_span = None
 
     # ----------------------------------------------------- fault recovery
     def _abort_admission(self, seqs):
@@ -2208,7 +2258,7 @@ class ContinuousBatchingEngine:
         fences (an ``idle`` drain); with nothing in flight it only
         dispatches. ``t0`` is the step's start reading of the clock."""
         tr = self._tr()
-        sp = self._mark("plan", span=True)
+        sp = self._mark_plan()
         prev = self._inflight
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
@@ -2217,9 +2267,12 @@ class ContinuousBatchingEngine:
                                                cap=self._chunk)
         cands = self._decode_candidates()
         if not cands and not plan:
-            self._mark("other", end=sp,
-                       end_args=sp and {"rows": 0, "chunks": 0})
-            self._drain("idle", finished)
+            plan_args = sp and {"rows": 0, "chunks": 0}
+            if prev is None:
+                self._mark_retire(end=sp, end_args=plan_args)
+            else:
+                self._mark("other", end=sp, end_args=plan_args)
+                self._drain("idle", finished, retire=True)
             return
         n = self.scheduler.choose_num_steps(
             [c[1] for c in cands], budgets=[c[4] for c in cands]) \
@@ -2274,13 +2327,17 @@ class ContinuousBatchingEngine:
         sp = self._mark("dispatch", span=True, args=args)
         co = self._co()
         keys_in = self._keys
-        npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n, T)(
-            self._params, *self.cache.kv_args(),
-            self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
-            dec_mask, keys_in, temps, topks,
-            self._no_toks if prev is None else prev.tok_fin, take,
-            chunk_keys, adopt,
-            *((self.cache.store,) if self._stateful else ()))
+        # ``call``: the jitted call alone, from before its arguments are
+        # handed over until it returns; what of ``dispatch`` is not under
+        # it is the commit below
+        with tr.span("call") if tr is not None else NULL_SPAN:
+            npk, npv, toks, tok_fin, keys_out, *moe = self._ragged_fn(n, T)(
+                self._params, *self.cache.kv_args(),
+                self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
+                dec_mask, keys_in, temps, topks,
+                self._no_toks if prev is None else prev.tok_fin, take,
+                chunk_keys, adopt,
+                *((self.cache.store,) if self._stateful else ()))
         # the program is on the device's queue: commit what it advances
         self.cache.update(npk, npv)
         if self._stateful:
@@ -2314,10 +2371,9 @@ class ContinuousBatchingEngine:
         launch_args = launch and {"packed_tokens": cursor, "fused_steps": n}
         if prev is not None:
             self._mark("other", end=sp)
-            self._fence(prev, finished, launch, launch_args)
+            self._fence(prev, finished, launch, launch_args, retire=True)
         else:
-            self._mark("other", end=sp, outer=launch,
-                       outer_args=launch_args)
+            self._mark_retire(end=sp, outer=launch, outer_args=launch_args)
 
     def _decode_candidates(self):
         """Every slot the next step program can carry a decode row for,
@@ -2398,15 +2454,24 @@ class ContinuousBatchingEngine:
             cursor += 1
         return rows, cursor
 
-    def _fence(self, rec, finished, launch=None, launch_args=None):
+    def _fence(self, rec, finished, launch=None, launch_args=None,
+               retire=False):
         """Fence one dispatched unified step and accept what it computed:
         ``device-wait`` (the host transfer of its tokens, with the
         routing summary of the same program as the span's args) under
         ``launch`` (the caller's, when this call also dispatched), then
-        ``host-accept``. An exception out of the program surfaces here;
+        ``host-accept``. With ``retire`` the fence is the last thing its
+        step does and ``retire`` follows; else the thread goes back to the
+        phase it came from (a drain outside a step, the pool repair, or a
+        deadline drain inside the ``sweep``, whose span stops here and
+        goes on after). An exception out of the program surfaces here;
         what was in flight is then dropped, never half accepted."""
         pc = self.driver_clock
         back = pc.phase if pc is not None else None     # in a step or not
+        sweep, self._sweep = self._sweep, None
+        in_sweep = sweep is not None or back == "sweep"
+        if in_sweep:
+            self._mark("other", end=sweep)      # before ``launch`` opens
         if launch is None:
             launch = self._tspan("launch")
         sp = self._mark("device-wait", span=True)
@@ -2435,11 +2500,18 @@ class ContinuousBatchingEngine:
             self.stats["decode_steps"] += n
             self.stats["slot_steps"] += n * self.num_slots
             emitted = self._accept_decode_rows(toks_np, n, rows, finished)
-        self._mark(back, end=sp, end_args=sp and {"emitted": emitted})
+        accept_args = sp and {"emitted": emitted}
+        if retire:
+            self._mark_retire(end=sp, end_args=accept_args)
+        elif in_sweep:
+            self._sweep = self._mark("sweep", span=True, end=sp,
+                                     end_args=accept_args)
+        else:
+            self._mark(back, end=sp, end_args=accept_args)
         self._count_step(now - base, rec.packed + (n - 1) * len(rows),
                          rec.packed - len(rows), rec.size)
 
-    def _drain(self, reason, finished=None):
+    def _drain(self, reason, finished=None, retire=False):
         """Fence and accept the program in flight, if any: what every
         path that changes slots outside plan -> accept calls first, so
         that it sees (and tears down) only accepted state. Sequences the
@@ -2452,7 +2524,7 @@ class ContinuousBatchingEngine:
             return
         self._inflight = None
         self._fence(rec, finished if finished is not None
-                    else self._finished_outside)
+                    else self._finished_outside, retire=retire)
         self.stats["drains_" + reason] += 1     # a fence that raised
         # counted itself, as ``fault``
 
@@ -2548,7 +2620,7 @@ class ContinuousBatchingEngine:
         the returned key walk. Returns ``(tokens_processed,
         chunk_tokens)`` as :meth:`_unified_step` does."""
         tr = self._tr()
-        sp = self._mark("plan", span=True)
+        sp = self._mark_plan()
         co = self._co()
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
@@ -2558,8 +2630,8 @@ class ContinuousBatchingEngine:
         cands = self._decode_candidates()   # nothing is ever in flight
         active = [c[1] for c in cands]      # here: the running slots
         if not active and not plan:
-            self._mark("other", end=sp,
-                       end_args=sp and {"rows": 0, "chunks": 0})
+            self._mark_retire(end=sp,
+                              end_args=sp and {"rows": 0, "chunks": 0})
             return 0, 0
         n = self.scheduler.choose_decode_ticks(active,
                                                self._decode_ticks)
@@ -2598,11 +2670,12 @@ class ContinuousBatchingEngine:
                 qstart, qlen, kvlen, T, len(active), n * len(active),
                 chunk_tokens)
         sp = self._mark("dispatch", span=True, args=args)
-        npk, npv, toks, kwalk, ticks_run = self._mtick_fn()(
-            self._params, *self.cache.kv_args(),
-            self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
-            dec_mask, keys, temps, topks, eos_ids, budgets,
-            np.int32(n))
+        with tr.span("call") if tr is not None else NULL_SPAN:
+            npk, npv, toks, kwalk, ticks_run = self._mtick_fn()(
+                self._params, *self.cache.kv_args(),
+                self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
+                dec_mask, keys, temps, topks, eos_ids, budgets,
+                np.int32(n))
         self.cache.update(npk, npv)
         sp = self._mark("device-wait", span=True, end=sp)
         toks_np = np.asarray(toks)          # [max_ticks, R]
@@ -2662,9 +2735,9 @@ class ContinuousBatchingEngine:
                 adopted = True
             if adopted:
                 self._keys = jnp.asarray(knp)
-        self._mark("other", end=sp,
-                   end_args=sp and {"emitted": emitted_total,
-                                    "ticks_run": ticks})
+        self._mark_retire(end=sp,
+                          end_args=sp and {"emitted": emitted_total,
+                                           "ticks_run": ticks})
         return chunk_tokens + emitted_total, chunk_tokens
 
     def _pack_chunk_rows(self, plan, cursor, ids, seg, pos, qstart, qlen,
@@ -2727,7 +2800,7 @@ class ContinuousBatchingEngine:
         Returns ``(tokens_processed, chunk_tokens)`` as
         :meth:`_unified_step` does."""
         tr = self._tr()
-        sp = self._mark("plan", span=True)
+        sp = self._mark_plan()
         co = self._co()
         plan = []
         if self._chunk and self.scheduler.num_prefilling:
@@ -2737,8 +2810,8 @@ class ContinuousBatchingEngine:
         active = [(slot, s) for slot, s in enumerate(self._slots)
                   if s is not None and s.status == "running"]
         if not active and not plan:
-            self._mark("other", end=sp,
-                       end_args=sp and {"rows": 0, "chunks": 0})
+            self._mark_retire(end=sp,
+                              end_args=sp and {"rows": 0, "chunks": 0})
             return 0, 0
         R, T = self.num_slots, self._spec_budget
         lens = self.cache.lengths
@@ -2800,10 +2873,11 @@ class ContinuousBatchingEngine:
                 qstart, qlen, kvlen, T, len(verify_rows),
                 cursor - chunk_spend, chunk_spend)
         sp = self._mark("dispatch", span=True, args=args)
-        npk, npv, toks, kwalk = self._spec_fn()(
-            self._params, *self.cache.kv_args(),
-            self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
-            sample_start, keys, temps, topks)
+        with tr.span("call") if tr is not None else NULL_SPAN:
+            npk, npv, toks, kwalk = self._spec_fn()(
+                self._params, *self.cache.kv_args(),
+                self.cache.tables, ids, seg, pos, qstart, qlen, kvlen,
+                sample_start, keys, temps, topks)
         self.cache.update(npk, npv)
         sp = self._mark("device-wait", span=True, end=sp)
         toks_np = np.asarray(toks)          # [spec_len, R]
@@ -2878,7 +2952,8 @@ class ContinuousBatchingEngine:
                            args={"accept_lens": list(accept_lens),
                                  "proposed": [len(d) for _, _, d, _
                                               in verify_rows]})
-        self._mark("other", end=sp, end_args=sp and {"emitted": emitted_total})
+        self._mark_retire(end=sp,
+                          end_args=sp and {"emitted": emitted_total})
         return chunk_spend + emitted_total, chunk_spend
 
     def has_work(self) -> bool:

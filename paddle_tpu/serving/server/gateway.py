@@ -78,6 +78,7 @@ import numpy as np
 
 from ...profiler.cost import PROGRAM_KINDS, CostObservatory
 from ...profiler.driver_clock import PHASES, DriverClock
+from ...profiler.gc_watch import GENERATIONS, GcWatch
 from ...profiler.metrics import (QUEUE_WAIT_BUCKETS, SPEC_ACCEPT_BUCKETS,
                                  STEP_BUCKETS, TPOT_BUCKETS, TTFT_BUCKETS,
                                  MetricsRegistry)
@@ -345,6 +346,10 @@ class ServingGateway:
         self.driver_clock = DriverClock(
             wall=self._clock,
             stamps_spans=self.tracer.clock is self._clock)
+        # the collector's pauses, by generation: counted while the driver
+        # thread runs (:meth:`_run` installs and removes the callback),
+        # and a ``gc`` span each while the tracer records on a real clock
+        self.gc_watch = GcWatch(wall=self._clock, tracer=self.tracer)
         engine.tracer = self.tracer
         engine.cost = self.cost
         engine.driver_clock = self.driver_clock
@@ -580,19 +585,46 @@ class ServingGateway:
         driver = r.counter(
             "serving_driver_seconds_total",
             "Seconds of the engine-driver thread by phase (loop: the "
-            "gateway between two steps; idle-wait: no work; admit, plan, "
-            "dispatch, device-wait, host-accept: the step's spans of "
-            "those names; other: the rest of a step) and clock (wall; "
+            "gateway between two steps; idle-wait: no work; sweep, admit, "
+            "plan, dispatch, device-wait, host-accept, retire: the step's "
+            "spans of those names; other: the rest of a step) and clock "
+            "(wall; "
             "cpu: the thread's own CPU time). The wall phases sum to the "
             "thread's elapsed time. device-wait over all but idle-wait "
             "(wall) is the share of its time the host waits for the "
             "chip: near 0 the host is the pace. Monotonic across engine "
             "rebuilds.")
+        long_n = r.counter(
+            "serving_driver_long_visits_total",
+            "Visits of the engine-driver thread to one phase, mark to "
+            "mark, that lasted longer than driver_clock.LONG_VISIT_S "
+            "(8 ms): a host stall that long empties the one-deep "
+            "pipeline. The waiting phases are counted like the others.")
+        long_s = r.counter(
+            "serving_driver_long_visit_seconds_total",
+            "Wall seconds of those visits, by phase.")
         for phase in PHASES:
             for clock in ("wall", "cpu"):
                 driver.set_fn(
                     lambda p=phase, c=clock: self.driver_clock.seconds(p, c),
                     phase=phase, clock=clock)
+            long_n.set_fn(lambda p=phase: self.driver_clock.long_visits[p],
+                          phase=phase)
+            long_s.set_fn(lambda p=phase: self.driver_clock.long_visit_s[p],
+                          phase=phase)
+        gc_s = r.counter(
+            "serving_gc_pause_seconds_total",
+            "Seconds the collector held every thread of the process, by "
+            "generation (gateway clock, start to stop of a collection, "
+            "whichever thread it ran on), while the driver thread ran.")
+        gc_n = r.counter(
+            "serving_gc_collections_total",
+            "Collections, by generation, while the driver thread ran.")
+        for gen in GENERATIONS:
+            gc_s.set_fn(lambda g=gen: self.gc_watch.pause_s[g],
+                        generation=str(gen))
+            gc_n.set_fn(lambda g=gen: self.gc_watch.collections[g],
+                        generation=str(gen))
         r.gauge("serving_step_tokens",
                 "Tokens the last engine step processed on device "
                 "(decode rows x fused ticks + prefill chunk tokens)."
@@ -1246,6 +1278,7 @@ class ServingGateway:
             self._on_finish(seq)
 
     def _run(self):
+        self.gc_watch.install()
         try:
             self._mark("loop")
             while True:
@@ -1295,6 +1328,8 @@ class ServingGateway:
                 if id(s) not in handed:
                     s._push_error(f"engine driver died: {e!r}")
             raise
+        finally:
+            self.gc_watch.remove()
 
     # ---------------------------------------------------------- supervisor
     def _step_supervised(self):
@@ -1347,7 +1382,8 @@ class ServingGateway:
     def _mark(self, phase, span=False):
         """The driver thread passes from one of the gateway's phases to
         another (``loop``, ``idle-wait``, or ``other`` on entering
-        ``engine.step()``, which marks its own: ``engine._mark``). Ticks
+        ``engine.step()``, which marks its own eight of the ten:
+        ``engine._mark``). Ticks
         the driver clock, and at the same reading closes the ``loop``
         span on leaving the loop and, with ``span=True`` (a step just
         ended), opens it."""

@@ -1,15 +1,20 @@
-"""The driver thread's phase clock (ISSUE 35).
+"""The driver thread's phase clock (ISSUE 35; ten phases and the long visits
+since ISSUE 52).
 
 ``profiler.driver_clock.DriverClock`` partitions the time of the thread that
-runs the gateway's loop and ``engine.step()`` into eight phases, on the wall
+runs the gateway's loop and ``engine.step()`` into ten phases, on the wall
 clock and on the thread's CPU clock, always on; the engine's and the gateway's
 ``_mark`` tick it at the boundaries where the spans are, and the spans carry
 the marks' own readings. Pinned here: the partition is exact over a run with
-every kind of boundary; a phase's spans sum to what the clock charged it; the
-family on ``/metrics`` is whole, monotonic and survives a rebuild; tracing off
-records nothing and a chaos replay is still byte-stable; the benchmark's seven
-readers read what they say, and None where the family is absent; the traced
-step's counter samples lie under its ``step`` span and walk no table.
+every kind of boundary, the two synchronous steps included; a phase's spans
+sum to what the clock charged it (``retire`` less the one reading a step from
+the ``step`` span's end to the gateway's mark); a visit longer than
+``LONG_VISIT_S`` is counted once, in its phase; the families on ``/metrics``
+are whole, monotonic and survive a rebuild; tracing off records nothing and a
+chaos replay is still byte-stable; the benchmark's seven readers read what
+they say (the host's own work is still every phase but the two waiting ones),
+and None where the family is absent; the traced step's counter samples lie
+under its ``step`` span and walk no table.
 """
 import json
 import os
@@ -20,8 +25,10 @@ import time
 import numpy as np
 import pytest
 
-from paddle_tpu.profiler.driver_clock import PHASES, DriverClock
-from paddle_tpu.profiler.tracing import TID_GATEWAY, SpanTracer
+from paddle_tpu.profiler.driver_clock import (LONG_VISIT_S, PHASES,
+                                              DriverClock)
+from paddle_tpu.profiler.tracing import (NULL_SPAN, TID_ENGINE, TID_GATEWAY,
+                                         SpanTracer)
 from paddle_tpu.serving import GenerationRequest
 from paddle_tpu.serving.faults import FaultPlan, VirtualClock
 from paddle_tpu.serving.server.gateway import ServingGateway
@@ -37,7 +44,11 @@ import readers  # noqa: E402
 NUM_SLOTS, S_MAX, CHUNK = 3, 128, 32
 TICK = 0.125        # dyadic, and a whole number of microseconds
 FAMILY = "serving_driver_seconds_total"
-SPAN_PHASES = ("admit", "plan", "dispatch", "device-wait", "host-accept")
+LONG_FAMILIES = ("serving_driver_long_visits_total",
+                 "serving_driver_long_visit_seconds_total")
+#: the phases whose spans open and close at the clock's own marks
+SPAN_PHASES = ("sweep", "admit", "plan", "dispatch", "device-wait",
+               "host-accept")
 
 
 class TickingClock(VirtualClock):
@@ -95,9 +106,19 @@ def _drive(gw, streams, cancel=None):
     return first, last
 
 
-def _gateway(model, clock, **kw):
-    return ServingGateway(_engine(model), clock=clock, start=False,
-                          max_queue=32, **kw)
+def _gateway(model, clock, engine_kw=None, **kw):
+    return ServingGateway(_engine(model, **(engine_kw or {})), clock=clock,
+                          start=False, max_queue=32, **kw)
+
+
+def _spans(evs, name):
+    return [e for e in evs if e["name"] == name and e["ph"] == "X"]
+
+
+def _inside(evs, parent, name):
+    lo, hi = parent["ts"], parent["ts"] + parent["dur"]
+    return [e for e in _spans(evs, name)
+            if lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
 
 
 # ---------------------------------------------------------------- the clock
@@ -118,6 +139,28 @@ class TestDriverClock:
         assert dc.seconds("plan", "cpu") == 40e-9
         assert sum(dc.wall_s.values()) == 4.25 - 1.0
         assert dc.phase == "loop"
+
+    def test_ten_phases_and_no_more(self):
+        assert len(PHASES) == len(set(PHASES)) == 10
+        assert {"sweep", "retire", "other"} <= set(PHASES)
+
+    def test_a_long_visit_is_counted_once_in_its_own_phase(self):
+        tick = 2.0 ** -20                       # exact sums
+        wall = iter([0.0, LONG_VISIT_S + tick,              # plan: long
+                     2 * LONG_VISIT_S + tick,               # dispatch: not
+                     2 * LONG_VISIT_S + 2 * tick,           # loop: not
+                     1.0])                                  # device-wait
+        dc = DriverClock(wall=lambda: next(wall), cpu=lambda: 0)
+        for phase in ("plan", "dispatch", "loop", "device-wait", "loop"):
+            dc.enter(phase)
+        # exactly LONG_VISIT_S is not longer than it; the waiting phases
+        # are counted like the others (their readers leave them out)
+        assert dc.long_visits == {**dict.fromkeys(PHASES, 0), "plan": 1,
+                                  "device-wait": 1}
+        assert dc.long_visit_s == {
+            **dict.fromkeys(PHASES, 0.0), "plan": LONG_VISIT_S + tick,
+            "device-wait": 1.0 - 2 * LONG_VISIT_S - 2 * tick}
+        assert 0.004 < LONG_VISIT_S < 0.0107    # under every device step
 
     def test_default_clocks_are_the_threads_own(self):
         dc = DriverClock()
@@ -154,6 +197,32 @@ class TestPhasesPartitionTheRun:
         assert sum(dc.wall_s.values()) == last - first      # exactly
         assert all(dc.wall_s[p] > 0 for p in PHASES if p != "idle-wait")
         assert gw.tracer.events() == []         # and tracing was off
+        # every reading moves the clock on by far more than LONG_VISIT_S:
+        # every visit is a long one, and they too partition the run
+        assert sum(dc.long_visit_s.values()) == last - first
+        assert dc.long_visit_s == dc.wall_s
+
+    @pytest.mark.parametrize("engine_kw", [
+        dict(spec_decode=True, spec_k=3), dict(decode_ticks=4)],
+        ids=["spec", "multitick"])
+    def test_the_synchronous_steps_partition_and_span_alike(self, model,
+                                                            engine_kw):
+        clk = TickingClock()
+        gw = _gateway(model, clk, engine_kw, trace=True)
+        first, last = _drive(gw, [gw.submit(r) for r in _reqs()])
+        st, dc, evs = gw.engine.stats, gw.driver_clock, gw.tracer.events()
+        assert st["spec_steps"] + st["mtick_syncs"] >= 3
+        assert st["unified_steps"] == st["mtick_syncs"]
+        assert sum(dc.wall_s.values()) == last - first
+        assert all(dc.wall_s[p] > 0 for p in PHASES if p != "idle-wait")
+        steps = _spans(evs, "step")
+        for name in ("sweep", "retire"):        # one each, inside the step
+            assert [len(_inside(evs, s, name)) for s in steps] \
+                == [1] * len(steps), name
+        dispatches = _spans(evs, "dispatch")
+        assert dispatches and all(len(_inside(evs, d, "call")) == 1
+                                  for d in dispatches)
+        assert len(_spans(evs, "call")) == len(dispatches)
 
     def test_spans_sum_to_what_the_clock_charged_their_phase(self, model):
         clk = TickingClock()
@@ -169,16 +238,75 @@ class TestPhasesPartitionTheRun:
         evs = gw.tracer.events()
         wall = gw.driver_clock.wall_s
         for name in SPAN_PHASES + ("loop",):
-            spans = [e for e in evs if e["name"] == name and e["ph"] == "X"]
+            spans = _spans(evs, name)
             assert spans, name
             assert sum(e["dur"] for e in spans) / 1e6 \
                 == wall[name] - at_first_step[name], name
+        # ``retire`` closes at the reading that closes ``step`` (spans of a
+        # lane nest); the clock's phase runs on to the gateway's mark, one
+        # reading later
+        steps, retires = _spans(evs, "step"), _spans(evs, "retire")
+        assert len(retires) == len(steps) == gw.engine.stats["steps"]
+        assert sum(e["dur"] for e in retires) / 1e6 \
+            == wall["retire"] - TICK * len(steps)
+        assert [s["ts"] + s["dur"] for s in steps] \
+            == [r["ts"] + r["dur"] for r in retires]
+        # ``sweep`` and ``retire`` are the step's first and last child, and
+        # ``call`` lies inside ``dispatch`` (a span of the tracer alone)
+        for s in steps:
+            (sweep,) = _inside(evs, s, "sweep")
+            (retire,) = _inside(evs, s, "retire")
+            assert sweep["ts"] > s["ts"] and sweep["tid"] == TID_ENGINE
+            assert set(sweep["args"]) == {"queued", "admitted"}
+        dispatches = _spans(evs, "dispatch")
+        assert [len(_inside(evs, d, "call")) for d in dispatches] \
+            == [1] * len(dispatches)
+        assert sum(e["dur"] for e in _spans(evs, "call")) / 1e6 \
+            < wall["dispatch"]
         # before the first step the loop has no span; after it, all of it
         assert at_first_step["loop"] > 0
         assert all(e["tid"] == TID_GATEWAY for e in evs
                    if e["name"] == "loop")
-        # ``other`` and ``idle-wait`` are phases of the clock alone
+        # ``other`` and ``idle-wait`` are phases of the clock alone, and
+        # ``call`` is a span of the tracer alone
         assert not {"other", "idle-wait"} & {e["name"] for e in evs}
+        assert "call" not in PHASES
+
+    def test_a_deadline_drain_stops_the_sweep_and_it_goes_on_after(
+            self, model):
+        """A running sequence found past its deadline is retired by the
+        sweep, after the program in flight is fenced: ``device-wait`` and
+        ``host-accept`` inside the sweep. Its span stops at the fence and
+        a second one goes on after, so the spans still sum to the clock."""
+        from test_tracing import validate_chrome_trace
+        clk = TickingClock()
+        gw = _gateway(model, clk, trace=True)
+        streams = [gw.submit(r) for r in _reqs()[:2]]
+        seen, on_token = [0], gw.engine.on_token
+
+        def hook(seq, token):
+            on_token(seq, token)
+            if gw._live.get(seq.request_id) is streams[0]:
+                seen[0] += 1
+                if seen[0] == 2:
+                    seq.deadline = 0.0      # due at the next step's sweep
+        gw.engine.on_token = hook
+        _drive(gw, streams)
+        assert gw.engine.stats["drains_deadline"] == 1
+        assert streams[0].finish_reason == "timeout"
+        evs, wall = gw.tracer.events(), gw.driver_clock.wall_s
+        for name in SPAN_PHASES:
+            assert sum(e["dur"] for e in _spans(evs, name)) / 1e6 \
+                == wall[name], name
+        sweeps = [_inside(evs, s, "sweep") for s in _spans(evs, "step")]
+        assert sorted(map(len, sweeps)) == [1] * (len(sweeps) - 1) + [2]
+        (first, second), = [sw for sw in sweeps if len(sw) == 2]
+        between = [e["name"] for e in _spans(evs, "device-wait")
+                   + _spans(evs, "host-accept")
+                   if first["ts"] + first["dur"] <= e["ts"] < second["ts"]]
+        assert between == ["device-wait", "host-accept"]
+        assert "queued" in first["args"] and "admitted" in second["args"]
+        validate_chrome_trace(gw.tracer.export())
 
     def test_a_tracer_on_its_own_clock_reads_that(self, model):
         """The marks' readings are the gateway clock's: a tracer injected
@@ -195,11 +323,17 @@ class TestPhasesPartitionTheRun:
 
 
 # ------------------------------------------------------------------ /metrics
-def _family(gw):
+def _family(gw, long_visits=False):
     fams = parse_prometheus(gw.registry.render())
     assert fams[FAMILY]["type"] == "counter"
-    return {dict(labels)["phase"] + "/" + dict(labels)["clock"]: v
-            for (_, labels), v in fams[FAMILY]["samples"].items()}
+    out = {dict(labels)["phase"] + "/" + dict(labels)["clock"]: v
+           for (_, labels), v in fams[FAMILY]["samples"].items()}
+    if long_visits:
+        for name in LONG_FAMILIES:
+            assert fams[name]["type"] == "counter"
+            out.update({dict(labels)["phase"] + "/" + name: v for
+                        (_, labels), v in fams[name]["samples"].items()})
+    return out
 
 
 class TestMetricsFamily:
@@ -219,6 +353,14 @@ class TestMetricsFamily:
         assert fam["idle-wait/wall"] > 0 and fam["dispatch/wall"] > 0
         # the thread cannot have computed for longer than it lived
         assert sum(v for k, v in fam.items() if k.endswith("/cpu")) <= wall
+        # the long visits: a series a phase in both families, seconds no
+        # more than the phase's own, and a long one is longer than the bar
+        both = _family(gw, long_visits=True)
+        for p in PHASES:
+            n, secs = (both[p + "/" + name] for name in LONG_FAMILIES)
+            assert n == gw.driver_clock.long_visits[p]
+            assert n * LONG_VISIT_S <= secs <= both[p + "/wall"] + 1e-9
+        assert both["idle-wait/" + LONG_FAMILIES[0]] >= 1   # the 0.1 s above
         gw.shutdown(drain=True, timeout=60)
 
     def test_monotonic_through_an_engine_rebuild(self, model):
@@ -231,7 +373,7 @@ class TestMetricsFamily:
 
         def scrape():
             while not stop.is_set():
-                samples.append(_family(gw))
+                samples.append(_family(gw, long_visits=True))
                 time.sleep(0.002)
         th = threading.Thread(target=scrape)
         streams = [gw.submit(r) for r in _reqs()]
@@ -242,7 +384,7 @@ class TestMetricsFamily:
         stop.set()
         th.join(10)
         assert not th.is_alive()
-        samples.append(_family(gw))
+        samples.append(_family(gw, long_visits=True))
         assert gw.restarts >= 1
         assert gw.driver_clock is clock and gw.engine.driver_clock is clock
         for key in samples[0]:
@@ -276,9 +418,38 @@ class TestTracingOffAndReplays:
         assert [k for _, k in plan.log] \
             == ["transient", "pool", "fatal", "hung"]
         # the hung step's virtual stall is the only time that passed, and
-        # it passed inside a step, under none of the named phases
-        assert gw.driver_clock.wall_s["other"] == 60.0
-        assert sum(gw.driver_clock.wall_s.values()) == 60.0
+        # it passed in the fault hook, which the step's ``sweep`` holds:
+        # one long visit, counted there
+        dc = gw.driver_clock
+        assert dc.wall_s["sweep"] == 60.0
+        assert sum(dc.wall_s.values()) == 60.0
+        assert dc.long_visits == {**dict.fromkeys(PHASES, 0), "sweep": 1}
+        assert dc.long_visit_s["sweep"] == 60.0
+
+    def test_tracing_off_builds_no_args_and_opens_no_span_at_any_mark(
+            self, model):
+        """Every mark of a run with the tracer off, the new ``sweep`` and
+        ``retire`` included, is handed no args dict and no span: sites
+        build them behind the tracer (``tr and {...}``)."""
+        gw = _gateway(model, TickingClock())
+        marks, mark = [], gw.engine._mark
+
+        def spy(phase, span=False, **kw):
+            marks.append((phase, kw))
+            opened = mark(phase, span=span, **kw)
+            assert opened is None
+            return opened
+        gw.engine._mark = spy
+        spans = []
+        gw.tracer.span = lambda *a, **kw: spans.append(a)   # never reached
+        _drive(gw, [gw.submit(r) for r in _reqs()])
+        assert {"sweep", "admit", "plan", "dispatch", "device-wait",
+                "host-accept", "retire", "other"} == {p for p, _ in marks}
+        # (a drain's ``launch`` is the tracer's shared no-op span)
+        assert {repr(v) for _, kw in marks for v in kw.values()
+                if v is not None and v is not NULL_SPAN} == set()
+        assert spans == [] and gw.engine._sweep is None \
+            and gw.engine._retire_span is None
 
     def test_two_chaos_replays_are_byte_identical(self, model):
         _chaos(model, trace=True)       # recovery-path programs compile here
@@ -289,9 +460,12 @@ class TestTracingOffAndReplays:
         doc1 = json.dumps(gw1.tracer.export(), sort_keys=True)
         assert doc1 == json.dumps(gw2.tracer.export(), sort_keys=True)
         names = {e["name"] for e in json.loads(doc1)["traceEvents"]}
-        assert {"step", "admit", "plan", "launch", "dispatch", "device-wait",
-                "host-accept", "loop", "rebuild", "kv_blocks"} <= names
+        assert {"step", "sweep", "admit", "plan", "launch", "dispatch",
+                "call", "device-wait", "host-accept", "retire", "loop",
+                "rebuild", "kv_blocks"} <= names
+        assert "gc" not in names        # never under an injected clock
         assert gw1.driver_clock.wall_s == gw2.driver_clock.wall_s
+        assert gw1.driver_clock.long_visits == gw2.driver_clock.long_visits
 
 
 # --------------------------------------------------------- the traced step
@@ -380,10 +554,32 @@ EXPECTED = {
 }
 
 
+def _split_other(src):
+    """The same run as a program with ten phases reports it: what the
+    parent charged to ``other`` is ``sweep``, ``retire`` and a rest."""
+    for _, scrape in src["metrics_delta"]["scrapes"]:
+        fam = scrape[FAMILY]
+        for clock in ("wall", "cpu"):
+            key = '{clock="%s",phase="%%s"}' % clock
+            whole = fam[key % "other"]
+            fam[key % "sweep"] = 0.25 * whole
+            fam[key % "retire"] = 0.5 * whole
+            fam[key % "other"] = 0.25 * whole
+    return src
+
+
 class TestBenchmarkReaders:
     @pytest.mark.parametrize("name", sorted(EXPECTED))
     def test_reads_the_hand_computed_value(self, name):
         assert readers.same_as(name)(_src()) \
+            == pytest.approx(EXPECTED[name], rel=1e-12)
+
+    @pytest.mark.parametrize("name", sorted(EXPECTED))
+    def test_the_same_totals_with_other_split_in_three(self, name):
+        """``busy_s`` sums every phase but the two waiting ones by label:
+        the host's own work, its headroom and its off-CPU time read what
+        they read when ``sweep`` and ``retire`` were part of ``other``."""
+        assert readers.same_as(name)(_split_other(_src())) \
             == pytest.approx(EXPECTED[name], rel=1e-12)
 
     @pytest.mark.parametrize("name", sorted(EXPECTED))

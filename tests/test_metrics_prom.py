@@ -272,3 +272,29 @@ class TestRegistry:
         [t.start() for t in ts]
         [t.join() for t in ts]
         assert c.value() == 8000
+
+
+# ------------------------------------------- the host's stalls on /metrics
+class TestHostStallFamilies:
+    def test_the_four_families_strict_parse_with_every_series(self):
+        """A gateway that has not run a step already exposes a series a
+        phase of the long visits and a series a generation of the
+        collector's pauses, all at 0 (ISSUE 52)."""
+        import serving_support
+        from paddle_tpu.profiler.driver_clock import PHASES
+        from paddle_tpu.serving.server.gateway import ServingGateway
+        gw = ServingGateway(
+            serving_support.engine(serving_support.model("llama", seed=35)),
+            start=False)
+        fams = parse_prometheus(gw.registry.render())
+        for name, label, values in (
+                ("serving_driver_long_visits_total", "phase", PHASES),
+                ("serving_driver_long_visit_seconds_total", "phase", PHASES),
+                ("serving_gc_pause_seconds_total", "generation", "012"),
+                ("serving_gc_collections_total", "generation", "012")):
+            fam = fams[name]
+            assert fam["type"] == "counter" and fam["help"]
+            assert sorted(dict(labels)[label] for _, labels
+                          in fam["samples"]) == sorted(values), name
+            assert set(fam["samples"].values()) == {0.0}, name
+        assert len(PHASES) == 10

@@ -173,7 +173,7 @@ class TestEngineSpans:
         def inside(parent, names):
             lo, hi = parent["ts"], parent["ts"] + parent["dur"]
             return [e["name"] for e in evs if e["name"] in names
-                    and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
+                    and lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-6]
         # a launch dispatches this step's program, then fences the
         # previous step's: the first has nothing to fence, the last
         # (a drain) nothing to dispatch
@@ -182,7 +182,8 @@ class TestEngineSpans:
         assert all(k == ["dispatch", "device-wait"] for k in kids[1:-1])
         # every step holds at most one of each, inside it; launch holds
         # dispatch and device-wait as before, host-accept follows it
-        leaves = ("plan", "launch", "dispatch", "device-wait", "host-accept")
+        leaves = ("sweep", "plan", "launch", "dispatch", "call",
+                  "device-wait", "host-accept", "retire")
         steps = [e for e in evs if e["name"] == "step"]
         assert len(steps) == eng.stats["steps"]
         held = [inside(s, leaves) for s in steps]
@@ -190,9 +191,24 @@ class TestEngineSpans:
             1 for e in evs if e["name"] in leaves)      # none outside
         for names in held:
             assert len(set(names)) == len(names)
-            order = [n for n in ("plan", "launch", "host-accept")
-                     if n in names]
+            order = [n for n in ("sweep", "plan", "launch", "host-accept",
+                                 "retire") if n in names]
             assert [n for n in names if n in order] == order
+            # every step starts in ``sweep`` and ends in ``retire``, which
+            # closes at the reading that closes ``step``
+            assert names[0] == "sweep" and names[-1] == "retire"
+        for s, r in zip(steps, (e for e in evs if e["name"] == "retire")):
+            assert r["ts"] + r["dur"] == pytest.approx(s["ts"] + s["dur"],
+                                                       abs=1e-6)
+        # ``call`` is the jitted call alone, inside ``dispatch``; what of
+        # ``dispatch`` is not under it is the commit
+        calls = [e for e in evs if e["name"] == "call"]
+        dispatches = [e for e in evs if e["name"] == "dispatch"]
+        assert len(calls) == len(dispatches)
+        assert all(inside(d, ("call",)) == ["call"] for d in dispatches)
+        sweeps = [e["args"] for e in evs if e["name"] == "sweep"]
+        assert all(set(a) == {"queued", "admitted"} for a in sweeps)
+        assert sweeps[0] == {"queued": 2, "admitted": 2}
         # dispatch says whether it went behind a program in flight; ahead
         # plus the dispatches into an emptied pipeline are all of them
         ahead = [e["args"]["ahead"] for e in evs if e["name"] == "dispatch"]
@@ -211,8 +227,9 @@ class TestEngineSpans:
             + rows[lane, "device-wait"]["total_ms"]
         assert rows[lane, "launch"]["self_ms"] == pytest.approx(
             rows[lane, "launch"]["total_ms"] - kids, abs=2e-3)
-        names = ("step", "admit", "plan", "launch", "dispatch",
-                 "device-wait", "host-accept", "donate", "prefill_launch")
+        names = ("step", "sweep", "admit", "plan", "launch", "dispatch",
+                 "call", "device-wait", "host-accept", "retire", "donate",
+                 "prefill_launch")
         assert sum(rows[lane, n]["self_ms"] for n in names
                    if (lane, n) in rows) == pytest.approx(
             rows[lane, "step"]["total_ms"], abs=2e-2)
@@ -418,8 +435,8 @@ class TestDebugXplane:
             names = {ev.name for plane in ProfileData.from_file(path).planes
                      if plane.name == "/host:CPU"
                      for line in plane.lines for ev in line.events}
-            assert {"step", "plan", "launch", "dispatch", "device-wait",
-                    "host-accept", "loop"} <= names
+            assert {"step", "sweep", "plan", "launch", "dispatch", "call",
+                    "device-wait", "host-accept", "retire", "loop"} <= names
             with pytest.raises(urllib.error.HTTPError) as bad:
                 urllib.request.urlopen(srv.url + "/debug/xplane?steps=0")
             assert bad.value.code == 400

@@ -145,6 +145,20 @@ class TestSpanTracerUnit:
         assert b["ts"] == pytest.approx(500000.0)
         assert b["dur"] == pytest.approx(250000.0)
 
+    def test_spans_closed_at_one_reading_end_at_one_timestamp(self):
+        """``retire`` closes at the reading that closes its ``step``: the
+        end is rounded as a start is, so the child never sticks out of its
+        parent by the rounding of two durations."""
+        tr = SpanTracer(clock=VirtualClock()).enable()
+        outer = tr.span("step", t0=1.4994e-6)
+        inner = tr.span("retire", t0=2.4996e-6)
+        inner.end(t1=10.0004e-6)
+        outer.end(t1=10.0004e-6)
+        retire, step = tr.events()
+        assert (step["ts"], retire["ts"]) == (1.499, 2.5)
+        assert (step["dur"], retire["dur"]) == (8.501, 7.5)
+        validate_chrome_trace(tr.export())
+
     def test_req_tid_dense_first_seen(self):
         tr = SpanTracer(clock=VirtualClock()).enable()
         assert tr.req_tid(42) == TID_REQ0
@@ -201,8 +215,8 @@ class TestEngineTracing:
         evs = validate_chrome_trace(doc)
         names = {e["name"] for e in evs}
         assert {"queued", "prefill", "decode", "finished", "step",
-                "plan", "launch", "host-accept", "admit",
-                "prefill_launch", "donate"} <= names
+                "sweep", "plan", "launch", "call", "host-accept", "retire",
+                "admit", "prefill_launch", "donate"} <= names
         # one lifecycle lane per request, each with exactly one
         # queued span, one decode span and one finished instant
         lanes = {e["tid"] for e in evs if e["tid"] >= TID_REQ0}
@@ -221,6 +235,39 @@ class TestEngineTracing:
         steps = [e for e in evs
                  if e["name"] == "step" and e["tid"] == TID_ENGINE]
         assert len(steps) == eng.stats["steps"]
+
+    def test_a_step_starts_in_sweep_and_ends_in_retire(self, model):
+        """Every step's first child is ``sweep`` (args: the queue's length
+        and what it admitted) and its last ``retire``, which ends with it;
+        ``call`` is the jitted call inside ``dispatch``. An engine with no
+        gateway has no driver clock: the spans are the tracer's alone."""
+        tracer = SpanTracer(clock=time.perf_counter).enable()
+        eng = _engine(model, tracer=tracer)
+        assert eng.driver_clock is None
+        eng.generate(_reqs(3, max_new=4))
+        evs = [e for e in validate_chrome_trace(tracer.export())
+               if e["ph"] == "X" and e["tid"] == TID_ENGINE]
+        steps = [e for e in evs if e["name"] == "step"]
+        assert len(steps) == eng.stats["steps"]
+        for s in steps:
+            lo, hi = s["ts"], s["ts"] + s["dur"]
+            kids = sorted((e for e in evs if e is not s and lo <= e["ts"]
+                           and e["ts"] + e["dur"] <= hi + 1e-6),
+                          key=lambda e: (e["ts"], -e["dur"]))
+            assert kids[0]["name"] == "sweep"
+            assert set(kids[0]["args"]) == {"queued", "admitted"}
+            last = max(kids, key=lambda e: (e["ts"] + e["dur"], e["ts"]))
+            assert last["name"] == "retire"
+            assert last["ts"] + last["dur"] == pytest.approx(hi, abs=1e-6)
+        sweeps = [e["args"] for e in evs if e["name"] == "sweep"]
+        assert sweeps[0] == {"queued": 3, "admitted": NUM_SLOTS}
+        assert sum(a["admitted"] for a in sweeps) == 3
+        dispatches = [e for e in evs if e["name"] == "dispatch"]
+        calls = [e for e in evs if e["name"] == "call"]
+        assert len(calls) == len(dispatches) > 0
+        for d, c in zip(dispatches, calls):
+            assert d["ts"] <= c["ts"] and \
+                c["ts"] + c["dur"] <= d["ts"] + d["dur"] + 1e-6
 
     def test_chunked_prefill_chunk_spans(self, model):
         tracer = SpanTracer().enable()
@@ -429,9 +476,10 @@ class TestDeterministicChaosTrace:
         # valid chrome trace, and the chaos story is all there
         evs = validate_chrome_trace(json.loads(doc1))
         names = {e["name"] for e in evs}
-        assert {"step", "plan", "launch", "host-accept", "queued",
-                "decode", "finished", "spec_accept", "fault",
-                "rebuild", "recovery", "preempted"} <= names
+        assert {"step", "sweep", "plan", "launch", "call", "host-accept",
+                "retire", "queued", "decode", "finished", "spec_accept",
+                "fault", "rebuild", "recovery", "preempted"} <= names
+        assert "gc" not in names        # never under an injected clock
         kinds = {e["args"]["kind"] for e in evs if e["name"] == "fault"}
         assert kinds == {"transient", "fatal", "hung"}
         assert gw1.restarts >= 3      # fatal + hung + nan
