@@ -90,12 +90,20 @@ are ``pallas_paged_decode.py``'s):
   whole-lane-tile window at ``D`` 128. Per head and update: ``s_k = q_k
   [rows, D] x K_k^T``, one mask (the same for every head), the ``m / l``
   update, ``acc_k [rows, D] += p_k x V_k``; the float32 accumulator is
-  ``[Hkv, rows, D]``. No zero is multiplied (the block-diagonal wide query
-  this replaced cost ``Hkv`` x the MXU work and an accumulator ``Hkv`` x
-  this one: PERF.md, PR 32 and 36). An int8 pool's scale for head ``k`` is
-  column ``k`` of the plane that rode the same physical index as its data
-  block, applied to the head's window as it is upcast; an fp8 pool's
-  per-block scale is spread over the block's rows.
+  ``[Hkv, rows, D]``. **The update is the general walk's own**
+  (``_span_update``): a plane's hundreds of rows made its cost a row's and
+  not a FLOP's, so a row's ``m`` lies on every lane of its tile and is never
+  narrowed to one (no lane broadcast on the XLUs: they bound the schedule,
+  PERF.md section 6, PR 53), and its ``l`` is summed BY LANE, column tile
+  on column tile, the one reduction along the lanes made where the pair
+  writes back; what is left across lanes is the row maximum, once a row
+  tile an update. A decode row's walk keeps ``_softmax_update``: its ``H``
+  rows are bound by their copies. No zero is multiplied (the block-diagonal
+  wide query this replaced cost ``Hkv`` x the MXU work and an accumulator
+  ``Hkv`` x this one: PERF.md, PR 32 and 36). An int8 pool's scale for head
+  ``k`` is column ``k`` of the plane that rode the same physical index as
+  its data block, applied to the head's window as it is upcast; an fp8
+  pool's per-block scale is spread over the block's rows.
 - **Everything the kernel cuts is whole tokens in whole 16-row tiles, at
   any group width.** The query block is sized by the state it carries
   (``query_block_rows``): as many whole row tiles of TOKENS as the float32
@@ -324,7 +332,9 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
 
     def _softmax_update(s, valid, v, m_ref, l_ref, acc_ref):
         # one online-softmax update of the state ``m / l / acc`` (ref views)
-        # with the scores ``s`` of a group of keys and their values ``v``
+        # with the scores ``s`` of a group of keys and their values ``v``:
+        # the one-token walk's, on ``H`` rows (``m`` and ``l`` one value a
+        # row, spread over its lane tile)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -339,6 +349,41 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    def _span_update(s, valid, v, m_ref, l_ref, acc_ref):
+        # the general walk's online-softmax update. A plane's rows are many
+        # (128 to 512 a chunk) and its cost was a row's, not a FLOP's: what
+        # ``_softmax_update`` does to a lane tile a row (``m`` and ``l`` read
+        # at ``[:, :1]`` and spread over the lanes again, three lane
+        # broadcasts a row tile of eight on the XLUs, which bound the
+        # schedule: PERF.md section 6, PR 53). Here a row's state is never
+        # narrowed: ``m`` holds the row's maximum on every lane and is read
+        # and written as whole lane tiles, so ``s - m``, ``alpha`` and
+        # ``alpha * acc`` are elementwise between registers, and ``l`` holds
+        # a row's sum BY LANE (lane j: the keys j, j + 128, ... of every
+        # group, rescaled by the same ``alpha``), so an update adds column
+        # tiles and the one reduction along the lanes is ``_write_chunk``'s.
+        # What is left on the XLUs is the row maximum, one reduction a row
+        # tile. The same mathematics in float32; ``l`` summed in another
+        # order
+        lanes = m_ref.shape[1]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # exp hits exact 0 on masked cols only while the row has a valid
+        # one; a row of the block outside the span has none
+        p = jnp.where(valid, jnp.exp(s - _lanes(m_new, s.shape[1])), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        pad = -s.shape[1] % lanes
+        by_lane = p if not pad else jnp.concatenate(
+            [p, jnp.zeros((p.shape[0], pad), p.dtype)], axis=1)
+        l_ref[:] = alpha * l_ref[:] + sum(
+            by_lane[:, j:j + lanes] for j in range(0, by_lane.shape[1], lanes))
+        acc_ref[:] = acc_ref[:] * _lanes(alpha, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
 
     def _reset(m_ref, l_ref, acc_ref):
         m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -413,7 +458,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                             _head_rows(k_buf, slot, ks_buf, k),
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-                        _softmax_update(
+                        _span_update(
                             s, valid, _head_rows(v_buf, slot, vs_buf, k),
                             m_scr.at[k, at], l_scr.at[k, at],
                             acc_scr.at[k, at])
@@ -426,8 +471,9 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
             @where_live(live)
             def _write_chunk():
                 for k in range(hkv):
-                    out = acc_scr[k, at] / jnp.maximum(l_scr[k, at, :1],
-                                                       1e-30)
+                    # (``l`` lies by lane: ``_span_update``)
+                    out = acc_scr[k, at] / jnp.maximum(jnp.sum(
+                        l_scr[k, at], axis=1, keepdims=True), 1e-30)
                     o_ref[k, at, :] = jnp.where(
                         in_span, out.astype(o_ref.dtype), o_ref[k, at, :])
 
@@ -495,6 +541,15 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                     trow == first + j,
                     acc1_scr[pl.ds(k * g + j, 1), k * d:(k + 1) * d], new)
             o_ref[k, at, :] = new.astype(o_ref.dtype)
+
+
+def _lanes(x, n):
+    """``x [rows, 128]``, a row's one value on every lane, as ``[rows, n]``:
+    itself at a lane tile, whole copies side by side at several, a cut of
+    one under it (a test's narrow head or short group)."""
+    if n != x.shape[1]:
+        x = jnp.concatenate([x] * -(-n // x.shape[1]), axis=1)[:, :n]
+    return x
 
 
 #: rows of the smallest row tile every query dtype loads whole (bf16 packs
@@ -841,7 +896,7 @@ def grid_params(pool_dtype, block_size, kd, table_entries, heads,
 
 def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
                        table_entries, packed_tokens, block_q=256, pages=1,
-                       one_token=False, window=None):
+                       one_token=False, window=None, kv_heads=None):
     """What one call of the kernel is asked to do, counted on the host from
     the step's span metadata (plain integers; no jax): ``grid_steps``, the
     steps the kernel visits — the ``nq + R`` work-list entries of its grid
@@ -852,8 +907,14 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
     a pair's last group may hold fewer); ``one_token_rows``, the rows that
     compute on their own rows and not on the query block (spans of one
     token, where the kernel has that walk: ``one_token``, as the kernel's
-    ``grid_params`` gives it); ``kv_tokens``, the cache rows the live spans
-    attend
+    ``grid_params`` gives it); ``span_row_groups``, what the GENERAL walk's
+    updates work on: the sum, over its live (row chunk, update) pairs, of
+    the chunk's rows in every plane (``_row_chunks`` of the block's plane and
+    ``_span``'s own two predicates: the chunk holds a row of the pair's
+    span, and the update's first key lies at or under the chunk's last such
+    row; one chunk is always live), which needs ``kv_heads`` and is 0
+    without it (the latent kernel has no such walk); ``kv_tokens``, the
+    cache rows the live spans attend
     over; ``attn_pairs``, their causal (query, key) pairs;
     ``prefetched_pairs``, the (query block, row) pairs whose first group of
     pool pages the pair before them in the work list started while it still
@@ -865,8 +926,13 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
     bq = _query_block(block_q, heads, packed_tokens)
     nq = -(-(packed_tokens * heads) // bq)
     tpb = bq // heads
-    live = updates = alone = kv_tokens = pairs = 0
+    live = updates = alone = kv_tokens = pairs = row_groups = 0
     walks = [[] for _ in range(nq)]     # a query block's pairs, by row
+    pages, hkv = int(pages), int(kv_heads or 0)
+    g = heads // hkv if hkv else 0
+    chunks = _row_chunks(_plane_rows(bq, heads, g, packed_tokens)) if g \
+        else []
+    group = pages * int(block_size)
     for qs, ql, kl in zip(qstart, qlen, kvlen):
         qs, ql, kl = int(qs), int(ql), int(kl)
         if ql <= 0:
@@ -884,14 +950,30 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
                 qs, ql, kl, qi, tokens_per_block=tpb,
                 block_size=block_size, table_entries=int(table_entries))
             walks[qi].append(n)
-            n -= _pair_first_block(
+            first = _pair_first_block(
                 qs, ql, kl, qi, tokens_per_block=tpb, block_size=block_size,
-                window=window, pages=int(pages)) * (n > 0)
-            live += n
-            updates += -(-n // int(pages))
+                window=window, pages=pages) * (n > 0)
+            live += n - first
+            updates += -(-(n - first) // pages)
+            if ql == 1 and one_token:
+                continue
+            # the general walk: ``_span``'s ``rows_live`` and its
+            # ``gi * group <= last``, in plane rows of the query block
+            row0, glo, n_groups = qi * tpb * g, first // pages, -(-n // pages)
+            for c0, rows in chunks:
+                hi = min(row0 + c0 + rows, (qs + ql) * g)
+                if len(chunks) == 1:
+                    walked = n_groups - glo
+                elif hi > max(row0 + c0, qs * g):
+                    last = kl - ql + (hi - 1 - qs * g) // g
+                    walked = min(n_groups, last // group + 1) - glo
+                else:
+                    continue
+                row_groups += hkv * rows * max(walked, 0)
     entries = [n for ns in walks for n in (ns or [0])]
     return {"grid_steps": nq + len(qstart) + live, "live_steps": live,
             "update_steps": updates, "one_token_rows": alone,
+            "span_row_groups": row_groups,
             "kv_tokens": kv_tokens, "attn_pairs": pairs,
             "prefetched_pairs": int(sum(map(_hands_over, entries,
                                                 entries[1:])))}

@@ -908,11 +908,17 @@ class ContinuousBatchingEngine:
         head_dim = getattr(self.config, "kernel_head_dim",
                            self.config.head_dim)
 
+        # (KV heads as the dense kernel sees them in the pool's row; the
+        # latent kernel has none, nor the walk ``span_row_groups`` counts)
+        kv_heads = None if "wkv_a" in self._params else \
+            self.cache.pool.k.shape[3] // self._tp // head_dim
+
         def counts(qstart, qlen, packed, **window):
             return ragged_grid_counts(
                 qstart, qlen, kvlen, packed_tokens=packed,
                 heads=heads, block_size=self.cache.block_size,
-                table_entries=self.cache.max_blocks, **window,
+                table_entries=self.cache.max_blocks, kv_heads=kv_heads,
+                **window,
                 **attention_grid(self._params, self.cache.pool.k,
                                  self.cache.max_blocks, heads, packed,
                                  tp=self._tp, head_dim=head_dim))

@@ -12,10 +12,14 @@ halves (the decode rows alone, the chunk alone), so that what a change does
 to a decode row's walk and to a chunk's can be told apart. The calls of a
 case run inside ONE program (a loop whose carry feeds the next call's query,
 as a step's layers follow each other). Prints one JSON line a case: ms a
-call, the online-softmax updates and one-token rows the call makes
-(``ragged_grid_counts`` at the kernel's own ``grid_params``), us an update,
-and the output's largest error against a span-by-span float32 softmax. What
-PERF.md (PR 51) says of the kernel alone is this script's output, from the
+call, the online-softmax updates and one-token rows the call makes and the
+plane rows its general walk's updates work on (``ragged_grid_counts`` at the
+kernel's own ``grid_params``; ``span_row_groups`` where the checkout counts
+it), us an update, ns a plane row and group of the general walk (the chunk
+case is that walk alone), and the output's largest error against a
+span-by-span float32 softmax. ``--window`` times a window layer's call
+(Phi-4-mini-flash's: ``--heads 20 --kv-heads 10 --window 512``). What PERF.md
+(PR 51, PR 53) says of the kernel alone is this script's output, from the
 parent's checkout and from the change's; it runs no code a cell runs but the
 kernel.
 
@@ -45,6 +49,8 @@ def main():
                     help="cached keys before the chunk, one case each")
     ap.add_argument("--block-tokens", type=int, default=None,
                     help="the query block in tokens (default: the kernel's)")
+    ap.add_argument("--window", type=int, default=None,
+                    help="a query sees its last WINDOW keys only")
     a = ap.parse_args()
     platform, tiny = kernel_bench.start(a)
     import jax
@@ -83,7 +89,8 @@ def main():
     @jax.jit
     def attend(q, pool_k, pool_v, tables, qs, ql, kl):
         return ragged.ragged_paged_attention_pallas(
-            q, pool_k, pool_v, tables, qs, ql, kl, block_q=block_q, layer=0)
+            q, pool_k, pool_v, tables, qs, ql, kl, block_q=block_q, layer=0,
+            window=a.window)
 
     @jax.jit
     def many(q, *args):
@@ -106,9 +113,11 @@ def main():
                 n, Hkv, H // Hkv, D)
             s = jnp.einsum("nkgd,skd->nkgs", qr, k,
                            precision="highest") / np.sqrt(D)
-            pos = kv - n + jnp.arange(n)
-            s = jnp.where((jnp.arange(kv)[None, :] <= pos[:, None])[
-                :, None, None, :], s, -1e30)
+            pos, key = (kv - n + jnp.arange(n))[:, None], jnp.arange(kv)
+            seen = key <= pos
+            if a.window is not None:
+                seen &= key > pos - a.window
+            s = jnp.where(seen[:, None, None, :], s, -1e30)
             want = jnp.einsum("nkgs,skd->nkgd", jax.nn.softmax(s, -1), v,
                               precision="highest").reshape(n, H, D)
             got = out[int(qs[r]):int(qs[r]) + n].astype(jnp.float32)
@@ -122,9 +131,13 @@ def main():
                            ("chunk", spans(False, prefix))):
             if case == "decode_rows" and prefix != prefixes[0]:
                 continue            # the same rows whatever the chunk
-            counts = ragged.ragged_grid_counts(
-                *step, heads=H, block_size=bs, table_entries=mb,
-                packed_tokens=T, **tiling)
+            kw = dict(heads=H, block_size=bs, table_entries=mb,
+                      packed_tokens=T, window=a.window, **tiling)
+            try:
+                counts = ragged.ragged_grid_counts(*step, kv_heads=Hkv, **kw)
+            except TypeError:       # a checkout from before PR 53
+                counts = ragged.ragged_grid_counts(*step, **kw)
+            row_groups = counts.get("span_row_groups")
             args = (q, pool_k, pool_v, tables, *step)
             err = oracle_err(jax.block_until_ready(attend(*args)), *step)
             _, ms, first = kernel_bench.timed(
@@ -135,9 +148,12 @@ def main():
                 packed_tokens=T, decode_rows=rows if case != "chunk" else 0,
                 decode_kv=decode_kv, block_tokens=tiling["block_q"] // H,
                 pages=tiling["pages"], one_token=tiling["one_token"],
-                update_steps=counts["update_steps"],
-                one_token_rows=counts["one_token_rows"], call_ms=ms,
+                window=a.window, update_steps=counts["update_steps"],
+                one_token_rows=counts["one_token_rows"],
+                span_row_groups=row_groups, call_ms=ms,
                 us_an_update=1e3 * ms / max(1, counts["update_steps"]),
+                ns_a_row_group=1e6 * ms / row_groups
+                if row_groups and case == "chunk" else None,
                 rel_err=err, first_call_s=first)
     return 0
 

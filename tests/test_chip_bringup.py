@@ -465,6 +465,47 @@ class TestMosaicCompilesJamba:
             == [(c0, 480) for c0 in range(0, 3840, 480)]
 
 
+class TestMosaicCompilesTheSpanUpdate:
+    """The general walk's own online-softmax update (PR 53: a row's ``m`` on
+    every lane, ``l`` by lane) at the packed size of a chunk step of the
+    cells that differ in how it lowers: Jamba2-3B's tall plane in row chunks
+    of 480 (20 / 1 / 128), Mistral's eight planes of 512 rows (32 / 8 /
+    128), Phi-4-mini-flash's window of 512 over its rings (20 / 10 / 128 as
+    the kernel sees its head pairs: the window's edge goes through the same
+    update), each over a bfloat16 and an int8 pool, whose head windows are
+    float32 by the time they reach it."""
+
+    @pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+    @pytest.mark.parametrize("heads,kv_heads,slots,mb,window", [
+        (20, 1, 16, 1024, None), (32, 8, 8, 128, None),
+        (20, 10, 48, 256, 512)], ids=["20-1", "32-8", "20-10-window"])
+    def test_lowers_at_a_chunk_steps_packed_size(self, v5e, heads, kv_heads,
+                                                 slots, mb, window,
+                                                 pool_dtype):
+        i32, f32, hd, bs = jnp.int32, jnp.float32, 128, 32
+        quantized = pool_dtype == "int8"
+
+        def attend(q, pk, pv, tables, qs, ql, kl, layer, *scales):
+            planes = dict(zip(("k_scale", "v_scale"), scales))
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer, window=window,
+                **planes)
+        pool = v5e((2, slots * mb, bs, kv_heads * hd), jnp.dtype(pool_dtype))
+        plane = v5e((2, slots * mb, bs, kv_heads), f32)
+        n = _mosaic_calls(
+            attend, v5e((slots + 512, heads, hd)), pool, pool,
+            v5e((slots, mb), i32), v5e((slots,), i32), v5e((slots,), i32),
+            v5e((slots,), i32), v5e((), i32), *([plane] * 2 * quantized))
+        assert n == 1
+        # keys an update: 256, fewer at a wide pool row (192 at 10 KV heads;
+        # a one-byte pool counts at four bytes a value), never under 128:
+        # one, one and a half or two lane tiles of scores a row
+        tiling = pallas_ragged_attention.grid_params(
+            jnp.dtype(pool_dtype), bs, kv_heads * hd, mb, heads,
+            slots + 512, head_dim=hd)
+        assert tiling["one_token"] and tiling["pages"] * bs in (128, 192, 256)
+
+
 class TestMosaicCompilesNemotronH:
     """Nemotron-3-Nano's kernels at its published widths (Mamba-2 blocks of
     64 heads x 64 channels in 8 groups, a state of 128, float32 by slot; two

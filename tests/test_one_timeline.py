@@ -20,7 +20,9 @@ import pytest
 
 from jax.experimental import pallas as pl
 from paddle_tpu.kernels.pallas_ragged_attention import (_one_token_walk,
+                                                        _plane_rows,
                                                         _query_block,
+                                                        _row_chunks,
                                                         grid_params,
                                                         pages_per_update,
                                                         query_block_rows,
@@ -332,7 +334,7 @@ def test_dispatch_counts_at_the_tiling_the_kernel_was_built_with(
     assert all(len(t) == 1 for t in tiling.values()), tiling
     assert all(pages > 1 for t in tiling.values() for _, pages in t)
     keys = ("grid_steps", "live_steps", "update_steps", "one_token_rows",
-            "kv_tokens", "attn_pairs", "prefetched_pairs")
+            "span_row_groups", "kv_tokens", "attn_pairs", "prefetched_pairs")
     for a, (qstart, qlen, kvlen, kw, _) in zip(disp, asked):
         assert a["packed_rows"] == kw["packed_tokens"]
         (block_q, pages), = tiling[a["packed_rows"]]
@@ -468,7 +470,36 @@ def _live_pairs(qstart, qlen, kvlen, heads, block_q, block_size,
     return pairs, nq
 
 
-def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False, **geometry):
+def _span_row_groups(walked, qstart, qlen, kvlen, pages, one_token, *,
+                     kv_heads, heads, block_q, block_size, packed_tokens,
+                     **_):
+    """What the general walk's updates work on, plane row by plane row: for
+    every pair that takes that walk, each row chunk of the block's planes
+    (``_row_chunks``) counts its rows, in each of the ``kv_heads`` planes,
+    once for every update whose first key
+    a span token owning a row of the chunk may see; a plane that is one
+    chunk counts in every update of the pair."""
+    g = heads // kv_heads
+    tpb = _query_block(block_q, heads, packed_tokens) // heads
+    chunks = _row_chunks(_plane_rows(tpb * heads, heads, g, packed_tokens))
+    total = 0
+    for (qi, r), n in walked.items():
+        if qlen[r] == 1 and one_token:
+            continue
+        for c0, rows in chunks:
+            first = qi * tpb * g + c0
+            pos = [kvlen[r] - qlen[r] + (j // g - qstart[r])
+                   for j in range(first, first + rows)
+                   if qstart[r] <= j // g < qstart[r] + qlen[r]]
+            total += kv_heads * rows * sum(
+                len(chunks) == 1 or any(u * pages * block_size <= p
+                                        for p in pos)
+                for u in range(-(-n // pages)))
+    return total
+
+
+def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False,
+                 kv_heads=None, **geometry):
     """The steps the kernel visits: its work list (one entry a query block
     or a row more than the pairs can ever be) plus every KV block its loops
     walk; only the latter compute, ``pages`` of them an online-softmax
@@ -492,6 +523,9 @@ def _brute_force(qstart, qlen, kvlen, pages=1, one_token=False, **geometry):
             "live_steps": live, "update_steps": updates,
             "one_token_rows": sum(ql == 1 for ql in qlen)
             if one_token else 0,
+            "span_row_groups": _span_row_groups(
+                walked, qstart, qlen, kvlen, pages, one_token,
+                kv_heads=kv_heads, **geometry) if kv_heads else 0,
             "kv_tokens": sum(kl for ql, kl in zip(qlen, kvlen) if ql),
             "attn_pairs": pairs,
             "prefetched_pairs": sum(a > 0 and b > 0 for a, b
@@ -543,6 +577,34 @@ def test_ragged_grid_counts_updates_and_one_token_rows(case, heads, block_q,
     assert one["update_steps"] == one["live_steps"]
     assert {k: v for k, v in got.items() if k != "update_steps"} \
         == {k: v for k, v in one.items() if k != "update_steps"}
+
+
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+@pytest.mark.parametrize("heads,kv_heads,block_q,pages", [
+    (4, 4, 16, 3), (32, 8, 256, 2), (16, 1, 64, 8), (20, 1, 32 * 20, 2),
+    (20, 10, 24 * 20, 1), (32, 2, 40 * 32, 3)])
+def test_ragged_grid_counts_span_row_groups(case, heads, kv_heads, block_q,
+                                            pages):
+    """The number PR 53 added: the plane rows the general walk's updates
+    work on, by the kernel's own predicates (a plane in row chunks where it
+    is taller than 512 rows: 20 / 1 and 32 / 2 here, whose chunks with no
+    row of the span or no key under their last row's diagonal count
+    nothing), and 0 without ``kv_heads``; the older keys do not depend on
+    it."""
+    qstart, qlen, kvlen = GRID_CASES[case]
+    g = heads // kv_heads
+    tiling = grid_params("float32", 16, kv_heads * 16, 8, heads, 40, block_q,
+                         pages, head_dim=16)
+    kw = dict(heads=heads, block_size=16, table_entries=8, packed_tokens=40,
+              **tiling)
+    got = ragged_grid_counts(qstart, qlen, kvlen, kv_heads=kv_heads, **kw)
+    assert got == _brute_force(qstart, qlen, kvlen, kv_heads=kv_heads, **kw)
+    assert (len(_row_chunks(_plane_rows(tiling["block_q"], heads, g, 40)))
+            > 1) == ((heads, kv_heads) in [(20, 1), (32, 2)])
+    spans = [ql for ql in qlen if ql > 1 or (ql and not tiling["one_token"])]
+    assert (got["span_row_groups"] > 0) == bool(spans)
+    without = ragged_grid_counts(qstart, qlen, kvlen, **kw)
+    assert without == dict(got, span_row_groups=0)
 
 
 # the serving cells' own geometry (benchmark/configs: 32 heads a chip, pool
@@ -651,10 +713,16 @@ def test_ragged_grid_counts_at_jambas_geometry():
     kvlen = [16_384 + 1] * 8 + [8_192 + 512] + [0] * 7
     tiling = grid_params("bfloat16", 32, 128, 1024, 20, 528, head_dim=128)
     kw = dict(heads=20, block_size=32, table_entries=1024, packed_tokens=528)
+    kw["kv_heads"] = 1
     got = ragged_grid_counts(qstart, qlen, kvlen, **kw, **tiling)
     assert got == _brute_force(qstart, qlen, kvlen, **kw, **tiling)
     assert got["one_token_rows"] == 8
     assert got["update_steps"] == 8 * 65 + 33 + 34 + 34
+    # the chunk's 10,240 plane rows in 480-row chunks of its three blocks,
+    # each chunk in the updates up to its own last row's diagonal, 33 to 35
+    # of them (101 updates of whole 3,840-row blocks would be 387,840); the
+    # decode rows walk on their own tiles
+    assert got["span_row_groups"] == 353_760
     old = dict(block_q=16 * 20, pages=8, one_token=False)
     before = ragged_grid_counts(qstart, qlen, kvlen, **kw, **old)
     assert before == _brute_force(qstart, qlen, kvlen, **kw, **old)

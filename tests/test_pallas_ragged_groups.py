@@ -200,3 +200,90 @@ def test_tall_planes_without_a_token_tile_take_the_chunked_walk(H, Hkv):
     np.testing.assert_allclose(
         got, np.asarray(ragged_attention_reference(*args)), rtol=2e-5,
         atol=2e-5)
+
+
+# ------------------- the general walk's own online-softmax update (PR 53)
+# the six dense cells' head counts, Phi-4-mini-flash's with its window too
+CELL_GROUPS = [(32, 8, None), (16, 16, None), (30, 30, None), (20, 10, None),
+               (20, 10, 100), (32, 2, None), (20, 1, None)]
+SPAN_UPDATE_CASES = {
+    # name: (spans, table entries, packed tokens): blocks of 16, 16 pages an
+    # update = 256 keys, two lane tiles of scores a row as on the chip.
+    # Spans of 1 and 17 tokens behind 0 and 300 keys share a query block
+    # with a dead row and a 150-token chunk behind 300 keys, whose lengths
+    # end inside their second group; the buffer ends inside a block
+    "mixed": ([(1, 1), (17, 17), (1, 301), (17, 317), (0, 0), (150, 450)],
+              32, 200),
+    # the same short spans 3,000 keys in: twelve updates a pair
+    "deep": ([(1, 3001), (17, 3017)], 192, 18),
+}
+
+
+# (the deep case at the two claimed cells' heads and under the window only:
+# every case is a program of its own to lower)
+@pytest.mark.parametrize("H,Hkv,window,case", [
+    (*cell, case) for cell in CELL_GROUPS for case in sorted(SPAN_UPDATE_CASES)
+    if case == "mixed" or cell in [(32, 8, None), (20, 10, 100),
+                                   (20, 1, None)]])
+def test_span_update_matches_reference_at_the_cells_groups(H, Hkv, window,
+                                                           case):
+    """``_span_update`` (a row's ``m`` on every lane, ``l`` by lane, its
+    lane reduction at the write) against the oracle at every dense cell's
+    heads: query blocks of 64 tokens, so the chunk crosses three and its
+    first shares one with four other spans; a tall plane (20 / 1, 32 / 2) in
+    row chunks. The spans of one token take their own walk where the block
+    has a tile. Stale pool rows NaN, unmapped entries sentinels, rows in no
+    span exact zeros."""
+    spans, mb, T = SPAN_UPDATE_CASES[case]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 16, mb, 16,
+                                     seed=H + Hkv, T=T)
+    pk, pv, tbl = _sentinels_and_poison(spans, pk, pv, tbl, ql, kl)
+    got = np.asarray(ragged_paged_attention_pallas(
+        q, pk, pv, tbl, qs, ql, kl, block_q=64 * H, pages=16, window=window))
+    want = np.asarray(ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl,
+                                                 window=window))
+    used = sum(n for n, _ in spans)
+    assert np.isfinite(got).all()
+    assert not got[used:].any() and not want[used:].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_span_update_on_a_512_token_chunk_in_jambas_blocks():
+    """A 512-token chunk behind 300 keys at 20 / 1 in the kernel's own query
+    block (192 tokens at heads of 128: 3,840 plane rows in eight row chunks
+    of 480, three blocks, the last two thirds full), behind two decode rows
+    on their own tiles."""
+    from paddle_tpu.kernels.pallas_ragged_attention import grid_params
+    spans = [(1, 200), (1, 77), (512, 812)]
+    args = _mk(len(spans), spans, 20, 1, 128, 26, 32, seed=53, T=528)
+    assert grid_params(jnp.float32, 32, 128, 26, 20, 528, head_dim=128) \
+        == dict(block_q=192 * 20, pages=8, one_token=True)
+    got = np.asarray(ragged_paged_attention_pallas(*args))
+    want = np.asarray(ragged_attention_reference(*args))
+    assert not got[514:].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+#: sha256 of the float32 outputs below at the parent commit (PR 52's tree,
+#: this file's ``_mk`` at seed 53 on the CPU's interpreter): what
+#: ``_one_token`` and the ``_softmax_update`` it keeps computed before the
+#: general walk got an update of its own
+ONE_TOKEN_DIGESTS = {
+    None: "e28047fcb2a33fab10af5e176afc2ba4b89b0f0129f87f26df589766ea0f3389",
+    24: "5c654037282581e88a9946d2675b0cc7ec10b89335f210e0f80dde23f26b199e",
+}
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_one_token_walk_is_bit_identical_to_the_parents(window):
+    """The decode medians' guard: rows of one token (16 / 4 / 32, a tile of
+    their own) over 1 to 200 keys in groups of 48 give, bit for bit, what
+    the parent commit's kernel gave: their walk kept ``_softmax_update`` and
+    nothing of the general walk's new update reaches it."""
+    import hashlib
+    spans = [(1, 40), (1, 1), (1, 97), (0, 0), (1, 16), (1, 200), (1, 128)]
+    args = _mk(len(spans), spans, 16, 4, 32, 13, 16, seed=53, T=16)
+    got = np.asarray(ragged_paged_attention_pallas(
+        *args, pages=3, window=window), np.float32)
+    assert hashlib.sha256(got.tobytes()).hexdigest() \
+        == ONE_TOKEN_DIGESTS[window]

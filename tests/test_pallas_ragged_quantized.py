@@ -90,3 +90,32 @@ def test_quantized_planes_ride_the_group(mode, pages, H, Hkv):
     assert not np.asarray(got)[int(sum(n for n, _ in MIXED)):].any()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("H,Hkv", [(32, 8), (20, 1)])
+@pytest.mark.parametrize("mode", ["int8", "fp8"])
+def test_quantized_pool_through_the_span_update(mode, H, Hkv):
+    """A chunk behind a prefix over an int8 or fp8 pool through the general
+    walk's own update (PR 53), 256 keys an update as on the chip (two lane
+    tiles of scores a row; ``l`` by lane): ``_head_rows`` upcasts a head's
+    window with its scale as before, and the chunk's lengths end inside
+    their second group. 32 / 8 and a tall plane in row chunks (20 / 1 at 40
+    tokens a block)."""
+    spans = [(1, 301), (0, 0), (70, 370), (17, 17)]
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, 16, 24, 16,
+                                     seed=53 + H, T=96)
+    if mode == "int8":
+        (k8, ks), (v8, vs) = quantize_kv_rows(pk), quantize_kv_rows(pv)
+    else:
+        r = np.random.RandomState(43)
+        k8, v8 = quantize_kv_rows_fp8(pk), quantize_kv_rows_fp8(pv)
+        ks, vs = (jnp.asarray(r.uniform(0.5, 2.0, (pk.shape[0], Hkv)),
+                              jnp.float32) for _ in range(2))
+    got = ragged_paged_attention_pallas(
+        q, k8, v8, tbl, qs, ql, kl, block_q=40 * H, k_scale=ks, v_scale=vs,
+        pages=16)
+    want = ragged_attention_reference(q, k8, v8, tbl, qs, ql, kl,
+                                      k_scale=ks, v_scale=vs)
+    assert not np.asarray(got)[88:].any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
