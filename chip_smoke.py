@@ -85,7 +85,13 @@ FULL = dict(
         # decode rows at 8 k - 25 k
         "jamba chunk 20/1/128": (20, 1, 128, 1024, [
             (1, 8192 + 1100 * i + (i * 37) % 29) for i in range(15)]
-            + [(512, 16896)])},
+            + [(512, 16896)]),
+        # Qwen3-Next's full layers: 16 query heads on 2 KV heads of 256, the
+        # first head wider than 128; the second chunk of a 1,000-token prompt
+        # behind 127 decode rows at 0.6 k - 3 k
+        "qwen3-next chunk 16/2/256": (16, 2, 256, 128, [
+            (1, 600 + 19 * i + (i * 37) % 29) for i in range(127)]
+            + [(488, 1000)])},
     # the cells' decode-only steps, whose packed buffer is the slots alone
     # (8 / 24 / 32 rows and nothing behind them): the chat cell's two live
     # rows of eight, less than one query block; every row live in the others
@@ -100,7 +106,9 @@ FULL = dict(
         "nemotron 32 rows 32/2/128": (32, 2, 128, 192, [
             (1, 1536 + 140 * i + (i * 37) % 29) for i in range(32)], 32),
         "jamba 16 rows 20/1/128": (20, 1, 128, 1024, [
-            (1, 8192 + 1100 * i + (i * 37) % 29) for i in range(16)], 16)},
+            (1, 8192 + 1100 * i + (i * 37) % 29) for i in range(16)], 16),
+        "qwen3-next 128 rows 16/2/256": (16, 2, 256, 128, [
+            (1, 600 + 19 * i + (i * 37) % 29) for i in range(128)], 128)},
     preset="llama7b-8of32", slots=8, max_seq_len=4096, prefill_chunk=512,
     vocab=32000, medium_prompt=300, long_prompt=700, tp=4,
     # the routed FFN at OLMoE-1B-7B widths: (hidden, experts, expert
@@ -117,6 +125,11 @@ FULL = dict(
         (1, 2048 + 190 * i + (i * 37) % 29) for i in range(32)])),
     moe_share=dict(widths=(5120, 160, 20, 1536, 6, 8, 3),
                    rows=[(544, 32), (256, 256)]),
+    # Qwen3-Next's: (hidden, router width, held experts, expert width,
+    # experts a token): 64 of a plain softmax router's 512 held, ten a token,
+    # renormalised; the cell's chunk step and its decode-only step
+    moe_wide=dict(widths=(2048, 512, 64, 512, 10),
+                  rows=[(640, 640), (128, 128)]),
     # GLM-5.2's sparse attention: (heads, latent rank, rope-free / rope /
     # value head widths, index heads, index head width, rows selected), and
     # its cell's two packed sizes over tables of 640 entries: 16 decode rows
@@ -130,6 +143,10 @@ FULL = dict(
     # Olmo-Hybrid-7B's linear layers: (heads, key width, value width) of
     # the gated delta rule, the slots and the packed rows of its cell's step
     gdn=dict(widths=(30, 96, 192), slots=32, packed=544),
+    # Qwen3-Next's: (value heads, key width, value width, KEY heads): 32 on
+    # 16 of 128 x 128, a key width of whole lane tiles; 32 of its cell's 128
+    # slots (a slot's state is 2 MiB a layer) and the cell's packed rows
+    gdn_grouped=dict(widths=(32, 128, 128, 16), slots=32, packed=640),
     # Phi-4-mini-flash's Mamba layers: (d_inner, d_state) of the selective
     # scan, the slots and the packed rows of its cell's step; and its window
     # layers' call: 40 wide queries over 10 KV pairs of 128, a 512-token
@@ -159,7 +176,8 @@ REHEARSAL = dict(
         "chunk 6/6/32": (6, 6, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)]),
         "chunk 16/1/32": (16, 1, 32, 8, [(1, 70), (40, 100), (0, 0),
                                          (1, 1)]),
-        "chunk 5/1/32": (5, 1, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
+        "chunk 5/1/32": (5, 1, 32, 8, [(1, 70), (40, 100), (0, 0), (1, 1)]),
+        "chunk 4/2/64": (4, 2, 64, 8, [(1, 70), (40, 100), (0, 0), (1, 1)])},
     ragged_decode_only={
         "8 rows 4/2/32": (4, 2, 32, 8, [
             (1, 150), (0, 0), (0, 0), (1, 33), (0, 0), (0, 0), (0, 0),
@@ -173,10 +191,12 @@ REHEARSAL = dict(
     mla=dict(widths=(4, 32, 16, 8, 16), decode_only=(8, [
         (1, 40 + 30 * i) for i in range(6)])),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
+    moe_wide=dict(widths=(64, 16, 4, 32, 3), rows=[(36, 36), (16, 16)]),
     dsa=dict(widths=(4, 32, 16, 8, 16, 4, 16, 16), mb=8, rows={
         "6 decode rows": [(1, 40 + 30 * i) for i in range(6)],
         "chunk at 200": [(1, 150), (40, 200), (0, 0), (1, 1)]}),
     gdn=dict(widths=(4, 8, 16), slots=6, packed=150),
+    gdn_grouped=dict(widths=(4, 8, 16, 2), slots=6, packed=150),
     ssm=dict(widths=(256, 16), slots=6, packed=150),
     ssd=dict(widths=(4, 8, 2, 16), slots=6, packed=150),
     ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
@@ -801,6 +821,9 @@ def phase_kernels(rehearse):
     routed_ffn("held ", 160, H, E, held, I, size["moe_share"]["rows"],
                top_k=K, n_group=groups, topk_group=top_g, first_held=0,
                scale=16.0)
+    H, E, held, I, K = size["moe_wide"]["widths"]
+    routed_ffn("64 of 512 ", 512, H, E, held, I, size["moe_wide"]["rows"],
+               top_k=K, renormalize=True, first_held=0)
 
     # ---- latent attention, absorbed kernel against expanded oracle ------
     from paddle_tpu.kernels.pallas_mla_ragged_attention import (
@@ -947,76 +970,83 @@ def phase_kernels(rehearse):
 
     # ---- the gated delta rule: both kernels against the recurrence ------
     from paddle_tpu.kernels import gated_delta_rule as gdr
-    nh, dk, dv = size["gdn"]["widths"]
-    R, T = size["gdn"]["slots"], size["gdn"]["packed"]
     rng = np.random.RandomState(33)
 
     def rand(*shape):
         return jnp.asarray(rng.randn(*shape).astype(np.float32))
 
-    q = gdr.l2norm(rand(T, nh, dk), dk ** -0.5)
-    k = gdr.l2norm(rand(T, nh, dk))
-    v = rand(T, nh, dv)
-    g = -1.6 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
-    beta = 2.0 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
-    store = rand(2, R, *gdr.state_shape(nh, dk, dv))
-    # a decode step: every slot but two has a row, one starts a sequence
-    live = np.ones(R, bool)
-    live[[1, R - 1]] = False
-    fresh = np.zeros(R, bool)
-    fresh[2] = True
-    got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
-        *a, layer=1, live=live, fresh=fresh))(
+    def delta_rule_agrees(tag, nh, dk, dv, nk, R, T):
+        """Both kernels at ``nh`` value heads on ``nk`` key heads."""
+        q = gdr.l2norm(rand(T, nk, dk), dk ** -0.5)
+        k = gdr.l2norm(rand(T, nk, dk))
+        v = rand(T, nh, dv)
+        g = -1.6 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
+        beta = 2.0 * jnp.asarray(rng.rand(T, nh).astype(np.float32))
+        store = rand(2, R, *gdr.state_shape(nh, dk, dv))
+        # a decode step: every slot but two has a row, one starts a sequence
+        live = np.ones(R, bool)
+        live[[1, R - 1]] = False
+        fresh = np.zeros(R, bool)
+        fresh[2] = True
+        got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
+            *a, layer=1, live=live, fresh=fresh))(
+                q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+        want = reference(lambda *a: gdr.gdn_reference(
+            *a, layer=1, seg=np.where(live, np.arange(R), R), first=fresh),
             q[:R], k[:R], v[:R], g[:R], beta[:R], store)
-    want = reference(lambda *a: gdr.gdn_reference(
-        *a, layer=1, seg=np.where(live, np.arange(R), R), first=fresh),
-        q[:R], k[:R], v[:R], g[:R], beta[:R], store)
-    _agree("gdn_recurrent_update o", np.asarray(got[0])[live],
-           np.asarray(want[0])[live], TOL_GDN, errors)
-    _agree("gdn_recurrent_update state", got[1], want[1], TOL_GDN, errors)
-    # the decode-only step: a buffer of the slots' rows alone. Every slot has
-    # a row, the fresh one over a stored state that is NaN (it starts from
-    # zero whatever its slot held), and the chunk scan, which finds no span
-    # over one token there, hands the store back as it is
-    live = np.ones(R, bool)
-    poisoned = store.at[:, 2].set(jnp.nan)
-    got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
-        *a, layer=1, live=live, fresh=fresh))(
-            q[:R], k[:R], v[:R], g[:R], beta[:R], poisoned)
-    want = reference(lambda *a: gdr.gdn_reference(
-        *a, layer=1, seg=np.arange(R), first=fresh),
-        q[:R], k[:R], v[:R], g[:R], beta[:R], store)
-    _agree(f"gdn_recurrent_update {R} rows o", got[0], want[0], TOL_GDN,
-           errors)
-    _agree(f"gdn_recurrent_update {R} rows state", got[1][1], want[1][1],
-           TOL_GDN, errors)
-    idle = jax.jit(lambda *a: gdr.gdn_chunk_scan(
-        *a, layer=0, start=np.arange(R, dtype=np.int32),
-        length=np.zeros(R, np.int32), fresh=fresh))(
+        _agree(f"gdn_recurrent_update {tag}o", np.asarray(got[0])[live],
+               np.asarray(want[0])[live], TOL_GDN, errors)
+        _agree(f"gdn_recurrent_update {tag}state", got[1], want[1], TOL_GDN, errors)
+        # the decode-only step: a buffer of the slots' rows alone. Every slot has
+        # a row, the fresh one over a stored state that is NaN (it starts from
+        # zero whatever its slot held), and the chunk scan, which finds no span
+        # over one token there, hands the store back as it is
+        live = np.ones(R, bool)
+        poisoned = store.at[:, 2].set(jnp.nan)
+        got = jax.jit(lambda *a: gdr.gdn_recurrent_update(
+            *a, layer=1, live=live, fresh=fresh))(
+                q[:R], k[:R], v[:R], g[:R], beta[:R], poisoned)
+        want = reference(lambda *a: gdr.gdn_reference(
+            *a, layer=1, seg=np.arange(R), first=fresh),
             q[:R], k[:R], v[:R], g[:R], beta[:R], store)
-    check(np.array_equal(np.asarray(idle[1]), np.asarray(store)),
-          f"gdn_chunk_scan {R} rows, no span: the store changed")
-    # a chunk step: decode rows first (not the scan's), then a chunk that
-    # continues its slot's state and a fresh one that starts in the block
-    # where the first ends
-    cut = 5 + (T - 5) * 3 // 5
-    start, length = np.zeros(R, np.int32), np.zeros(R, np.int32)
-    start[3], length[3] = 5, cut - 5
-    start[0], length[0] = cut, T - cut - 3
-    fresh = np.zeros(R, bool)
-    fresh[0] = True
-    seg = np.full(T, R, np.int32)
-    seg[5:cut], seg[cut:T - 3] = 3, 0
-    first = np.zeros(T, bool)
-    first[cut] = True
-    got = jax.jit(lambda *a: gdr.gdn_chunk_scan(
-        *a, layer=0, start=start, length=length, fresh=fresh))(
-            q, k, v, g, beta, store)
-    want = reference(lambda *a: gdr.gdn_reference(
-        *a, layer=0, seg=seg, first=first), q, k, v, g, beta, store)
-    _agree("gdn_chunk_scan o", np.asarray(got[0])[5:T - 3],
-           np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
-    _agree("gdn_chunk_scan state", got[1], want[1], TOL_GDN, errors)
+        _agree(f"gdn_recurrent_update {tag}{R} rows o", got[0], want[0], TOL_GDN,
+               errors)
+        _agree(f"gdn_recurrent_update {tag}{R} rows state", got[1][1], want[1][1],
+               TOL_GDN, errors)
+        idle = jax.jit(lambda *a: gdr.gdn_chunk_scan(
+            *a, layer=0, start=np.arange(R, dtype=np.int32),
+            length=np.zeros(R, np.int32), fresh=fresh))(
+                q[:R], k[:R], v[:R], g[:R], beta[:R], store)
+        check(np.array_equal(np.asarray(idle[1]), np.asarray(store)),
+              f"gdn_chunk_scan {tag}{R} rows, no span: the store changed")
+        # a chunk step: decode rows first (not the scan's), then a chunk that
+        # continues its slot's state and a fresh one that starts in the block
+        # where the first ends
+        cut = 5 + (T - 5) * 3 // 5
+        start, length = np.zeros(R, np.int32), np.zeros(R, np.int32)
+        start[3], length[3] = 5, cut - 5
+        start[0], length[0] = cut, T - cut - 3
+        fresh = np.zeros(R, bool)
+        fresh[0] = True
+        seg = np.full(T, R, np.int32)
+        seg[5:cut], seg[cut:T - 3] = 3, 0
+        first = np.zeros(T, bool)
+        first[cut] = True
+        got = jax.jit(lambda *a: gdr.gdn_chunk_scan(
+            *a, layer=0, start=start, length=length, fresh=fresh))(
+                q, k, v, g, beta, store)
+        want = reference(lambda *a: gdr.gdn_reference(
+            *a, layer=0, seg=seg, first=first), q, k, v, g, beta, store)
+        _agree(f"gdn_chunk_scan {tag}o", np.asarray(got[0])[5:T - 3],
+               np.asarray(want[0])[5:T - 3], TOL_GDN, errors)
+        _agree(f"gdn_chunk_scan {tag}state", got[1], want[1], TOL_GDN, errors)
+
+    delta_rule_agrees("", *size["gdn"]["widths"], size["gdn"]["widths"][0],
+                      size["gdn"]["slots"], size["gdn"]["packed"])
+    # Qwen3-Next's linear layers: value head h on key head h // 2
+    delta_rule_agrees("grouped ", *size["gdn_grouped"]["widths"],
+                      size["gdn_grouped"]["slots"],
+                      size["gdn_grouped"]["packed"])
 
     # ---- the selective scan: both kernels against the recurrence --------
     from paddle_tpu.kernels import selective_scan as ssk

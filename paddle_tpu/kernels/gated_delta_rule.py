@@ -9,6 +9,13 @@ negative eigenvalues allowed, arXiv:2411.12537) does
     S = exp(g_t) * S;  r = v_t - S^T k_t;  S = S + k_t (beta_t r)^T;
     o_t = S^T q_t
 
+**Grouped key heads.** ``q`` and ``k`` may arrive at FEWER heads than ``v``
+(``Hk`` dividing ``H``; Qwen3-Next: 16 under 32): value head ``h`` reads key
+head ``h // (H / Hk)``. Both kernels take them as they are, once: the chunk
+scan's grid step holds a whole number of key heads and their value heads, the
+update reads column ``h // (H / Hk)`` of a row's keys; the two ``jnp`` forms
+repeat them.
+
 **The store's layout** (``state_shape``; ``state_to_store`` /
 ``state_from_store`` are its one statement): a (layer, slot) holds ``[dk, H
 dv]``, the key width on the SUBLANES and every head's values side by side on
@@ -109,6 +116,13 @@ def state_from_store(st, heads):
     return jnp.moveaxis(st.reshape(*lead, dk, heads, -1), -2, -3)
 
 
+def _to_value_heads(x, heads):
+    """``[T, Hk, d] -> [T, H, d]``: value head ``h`` reads key head ``h //
+    (H / Hk)`` (the ``jnp`` forms; the kernels index instead)."""
+    rep = heads // x.shape[1]
+    return x if rep == 1 else jnp.repeat(x, rep, axis=1)
+
+
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(a, b, dims, precision=_HI,
                                preferred_element_type=jnp.float32)
@@ -194,11 +208,13 @@ def gdn_reference(q, k, v, g, beta, state, *, layer, seg, first):
     ``seg[t]`` (``R`` = a dead row: nothing is read or written) and
     ``first[t]`` says it is its sequence's position 0 (the slot's state is
     zeroed before it). Decode rows and chunk rows alike, in buffer order. q,
-    k ``[T, H, dk]`` normalised, v ``[T, H, dv]``, g, beta ``[T, H]``, state
-    ``[Ll, R, H, dk, dv]``. Returns ``(o [T, H, dv] float32, state')``."""
+    k ``[T, Hk, dk]`` normalised, v ``[T, H, dv]``, g, beta ``[T, H]``, state
+    the store ``[Ll, R, dk, H dv]``. Returns ``(o [T, H, dv] float32,
+    state')``."""
     f32 = jnp.float32
     R = state.shape[1]
     seg = jnp.asarray(seg, jnp.int32)
+    q, k = (_to_value_heads(x, v.shape[1]) for x in (q, k))
 
     def step(st, x):
         qt, kt, vt, gt, bt, sg, fr = x
@@ -259,16 +275,19 @@ def _head_major(x, t_pad, width):
                    ((0, 0), (0, t_pad - T), (0, width - d)))
 
 
-def _scan_heads(H, dv):
-    """Heads one grid step of the chunk scan holds: a divisor of the head
-    count whose values are whole lane tiles of the store (a block of it must
-    be), or every head."""
+def _scan_heads(H, dv, rep=1):
+    """Value heads one grid step of the chunk scan holds: a divisor of the
+    head count whose values are whole lane tiles of the store (a block of it
+    must be) and whose key heads are whole (``rep`` value heads a key head),
+    or every head."""
     return max((h for h in range(1, min(SCAN_HEADS, H) + 1)
-                if H % h == 0 and h * dv % 128 == 0), default=H)
+                if H % h == 0 and h * dv % 128 == 0 and h % rep == 0),
+               default=H)
 
 
 def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
-                 q_ref, k_ref, v_ref, gb_ref, s_in, o_ref, s_out, *, hb, dk):
+                 q_ref, k_ref, v_ref, gb_ref, s_in, o_ref, s_out, *, hb, dk,
+                 rep):
     w = pl.program_id(1)
     flags = flag_ref[w]
     live, first = (flags & 1) > 0, (flags & 2) > 0
@@ -285,16 +304,19 @@ def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
         rows = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0)
         mine = (rows >= lo_ref[w]) & (rows < hi_ref[w])
         kp, dv = q_ref.shape[-1], v_ref.shape[-1]
-        pad = jnp.zeros((kp - dk, dv), jnp.float32)
         for i in range(hb):
-            q = jnp.where(mine, q_ref[i], 0.0)
-            k = jnp.where(mine, k_ref[i], 0.0)
+            q = jnp.where(mine, q_ref[i // rep], 0.0)
+            k = jnp.where(mine, k_ref[i // rep], 0.0)
             g = jnp.where(mine, gb_ref[0, :, i:i + 1], 0.0)
             beta = jnp.where(mine, gb_ref[0, :, hb + i:hb + i + 1], 0.0)
             # the head's lanes of the block (every other head's start inside
             # a lane tile at 192: a shift a vreg, once a 64-token entry)
             at = slice(i * dv, (i + 1) * dv)
-            s0 = jnp.concatenate([s_out[0, 0, :, at], pad], axis=0)
+            s0 = s_out[0, 0, :, at]
+            if kp > dk:     # (a key width of whole lane tiles pads nothing:
+                            # Mosaic has no vector of 0 rows)
+                s0 = jnp.concatenate(
+                    [s0, jnp.zeros((kp - dk, dv), jnp.float32)], axis=0)
             o, s1 = _chunk_math(q, k, v_ref[i].astype(jnp.float32), g, beta,
                                 s0)
             s_out[0, 0, :, at] = s1[:dk]
@@ -304,10 +326,11 @@ def _scan_kernel(blk_ref, slot_ref, lo_ref, hi_ref, flag_ref, layer_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _scan_call(q, k, v, g, beta, state, layer, start, length, fresh,
                interpret):
-    T, H, dk = q.shape
-    dv = v.shape[-1]
+    T, Hk, dk = q.shape
+    H, dv = v.shape[1:]
     R = start.shape[0]
-    hb = _scan_heads(H, dv)
+    rep = H // Hk
+    hb = _scan_heads(H, dv, rep)
     n_items = scan_work_items(T, R)
     t_pad = -(-T // CHUNK) * CHUNK
     kp = -(-dk // 128) * 128
@@ -322,21 +345,21 @@ def _scan_call(q, k, v, g, beta, state, layer, start, length, fresh,
         for x in (g, beta)], axis=-1)
     gbh = jnp.pad(gbh, ((0, 0), (0, t_pad - T), (0, -2 * hb % 128)))
 
-    def tok(width):
+    def tok(width, heads=hb):
         return pl.BlockSpec(
-            (hb, CHUNK, width), lambda h, w, blk, *_: (h, blk[w], 0))
+            (heads, CHUNK, width), lambda h, w, blk, *_: (h, blk[w], 0))
 
     st = pl.BlockSpec(
         (1, 1, dk, hb * dv),
         lambda h, w, blk, slot, lo, hi, fl, layer: (layer[0], slot[w], 0, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(H // hb, n_items),
-        in_specs=[tok(kp), tok(kp), tok(dv),
+        in_specs=[tok(kp, hb // rep), tok(kp, hb // rep), tok(dv),
                   pl.BlockSpec((1, CHUNK, gbh.shape[-1]),
                                lambda h, w, blk, *_: (h, blk[w], 0)), st],
         out_specs=[tok(dv), st])
     o, state = pl.pallas_call(
-        functools.partial(_scan_kernel, hb=hb, dk=dk),
+        functools.partial(_scan_kernel, hb=hb, dk=dk, rep=rep),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((H, t_pad, dv), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -359,7 +382,7 @@ def _span_args(layer, start, length, fresh):
 
 def gdn_chunk_scan(q, k, v, g, beta, state, *, layer, start, length, fresh):
     """The chunked scan of every span with ``length > 0`` (Pallas). q, k
-    ``[T, H, dk]`` normalised, v ``[T, H, dv]``, g, beta ``[T, H]``, state
+    ``[T, Hk, dk]`` normalised, v ``[T, H, dv]``, g, beta ``[T, H]``, state
     the store ``[Ll, R, dk, H dv]`` float32 (updated in place when donated),
     start / length / fresh ``[R]`` by slot: the span of slot ``r`` is packed
     rows ``start[r] .. start[r] + length[r]``. Returns ``(o [T, H, dv] float32,
@@ -374,6 +397,7 @@ def gdn_chunk_scan_jnp(q, k, v, g, beta, state, *, layer, start, length,
     """``gdn_chunk_scan`` in ``jax.numpy``: the same work list, the same
     chunk math, a ``lax.scan`` over the entries."""
     layer, start, length, fresh = _span_args(layer, start, length, fresh)
+    q, k = (_to_value_heads(x, v.shape[1]) for x in (q, k))
     T, H, dk = q.shape
     dv = v.shape[-1]
     f32 = jnp.float32
@@ -415,7 +439,7 @@ def gdn_chunk_scan_jnp(q, k, v, g, beta, state, *, layer, start, length,
 
 # ------------------------------------------------------ the decode-row update
 def _update_kernel(slot_ref, flag_ref, layer_ref, qt_ref, kt_ref, v_ref,
-                   a_ref, b_ref, s_in, o_ref, s_out, *, dv):
+                   a_ref, b_ref, s_in, o_ref, s_out, *, dv, rep):
     i = pl.program_id(0)
     flags = flag_ref[i]
     live, fresh = (flags & 1) > 0, (flags & 2) > 0
@@ -431,25 +455,26 @@ def _update_kernel(slot_ref, flag_ref, layer_ref, qt_ref, kt_ref, v_ref,
 
         def spread(t):
             """``column(lo, width)``: lanes ``lo .. lo + width`` of the ``[dk,
-            H dv]`` whose head ``h``'s lanes all hold column ``h`` of ``t [dk,
-            H]``: one lane broadcast a head and sublane tile (shared by the
-            tiles the head lies in), one select where two heads meet inside
-            the tile."""
+            H dv]`` whose value head ``h``'s lanes all hold column ``h //
+            rep``, its key head's, of ``t [dk, Hk]``: one lane broadcast a key
+            head and sublane tile (shared by the tiles its value heads lie
+            in), one select where two key heads meet inside the tile."""
             wide = {}
 
             def column(lo, width):
                 out = None
-                for h in range(lo // dv, (lo + width - 1) // dv + 1):
-                    if (h, width) not in wide:
-                        wide[h, width] = jnp.broadcast_to(t[:, h:h + 1],
-                                                          (dk, width))
+                first = lo // dv // rep
+                for hk in range(first, (lo + width - 1) // dv // rep + 1):
+                    if (hk, width) not in wide:
+                        wide[hk, width] = jnp.broadcast_to(t[:, hk:hk + 1],
+                                                           (dk, width))
                     if out is None:
-                        out = wide[h, width]
+                        out = wide[hk, width]
                     else:
                         lane = jax.lax.broadcasted_iota(jnp.int32,
                                                         (dk, width), 1)
-                        out = jnp.where(lane >= h * dv - lo, wide[h, width],
-                                        out)
+                        out = jnp.where(lane >= hk * rep * dv - lo,
+                                        wide[hk, width], out)
                 return out
             return column
 
@@ -469,8 +494,8 @@ def _update_kernel(slot_ref, flag_ref, layer_ref, qt_ref, kt_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _update_call(q, k, v, g, beta, state, layer, live, fresh, interpret):
-    R, H, dk = q.shape
-    dv = v.shape[-1]
+    R, Hk, dk = q.shape
+    H, dv = v.shape[1:]
     f32, i32 = jnp.float32, jnp.int32
     # live rows first, in slot order; the rest repeat the last live row
     order = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(i32)
@@ -497,10 +522,10 @@ def _update_call(q, k, v, g, beta, state, layer, live, fresh, interpret):
                       lambda i, slot, fl, layer: (layer[0], slot[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(R,),
-        in_specs=[whole(R, dk, H), whole(R, dk, H), row, row, row, st],
+        in_specs=[whole(R, dk, Hk), whole(R, dk, Hk), row, row, row, st],
         out_specs=[row, st])
     o, state = pl.pallas_call(
-        functools.partial(_update_kernel, dv=dv),
+        functools.partial(_update_kernel, dv=dv, rep=H // Hk),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((R, 1, H * dv), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -517,7 +542,7 @@ def _update_call(q, k, v, g, beta, state, layer, live, fresh, interpret):
 
 
 def gdn_recurrent_update(q, k, v, g, beta, state, *, layer, live, fresh):
-    """One token a slot (Pallas): row ``r`` of q, k ``[R, H, dk]``
+    """One token a slot (Pallas): row ``r`` of q, k ``[R, Hk, dk]``
     (normalised), v ``[R, H, dv]``, g, beta ``[R, H]`` is slot ``r``'s;
     ``live[r]`` says the slot has a row this step, ``fresh[r]`` that it is
     its sequence's position 0. state, the store ``[Ll, R, dk, H dv]``

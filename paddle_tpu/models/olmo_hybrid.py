@@ -41,8 +41,11 @@ one ``gdn_wab``).
 Normal(0, 0.02), norm weights 1, and for the decay FLA's own initialisation
 (float32): ``A_log = log U(0, 16)``, ``dt_bias = softplus^-1(exp(U(log 0.001,
 log 0.1)))``. The layer bodies are the serving programs' own
-(``serving.decode._decoder_layer`` / ``_gdn_layer``), chosen by what the
-tree holds.
+(``serving.decode._decoder_layer`` / ``_gdn_layer``): both are ONE body, a
+mixer and then an FFN (``serving.decode._mixer_ffn_layer``), which this tree
+shares with ``models.qwen3_next``'s; what the tree holds (``attn_out_ln`` /
+``ffn_out_ln``, dense ``w_gate`` / ``w_up`` / ``w_down``, whole-projection
+``q_norm``) chooses this model's variant of it.
 """
 from __future__ import annotations
 
@@ -66,16 +69,23 @@ LINEAR, FULL = "linear_attention", "full_attention"
 
 
 class Gdn(NamedTuple):
-    """A linear layer's static numbers for the step programs
-    (``config.gdn``): heads, a head's key and value widths, the
-    convolution's width, whether ``beta`` is doubled, and which
-    implementation of the delta rule runs (``decode_attention``)."""
+    """A hybrid model's static numbers for the step programs
+    (``config.gdn``): a linear layer's (value) heads, a head's key and value
+    widths, the convolution's width, whether ``beta`` is doubled, and which
+    implementation of the delta rule runs (``decode_attention``); then what
+    only some hybrids have: ``key_heads``, where q and k come at fewer heads
+    than v (0: as many; value head ``h`` reads key head ``h // (heads /
+    key_heads)``), and the packed size of the engine's decode-only step
+    program, whose spans are one token each (0: no such program; the engine
+    sets it, as it does the Mamba stores')."""
     heads: int
     dk: int
     dv: int
     conv: int
     neg_eigval: bool
     kernel: str
+    key_heads: int = 0
+    decode_rows: int = 0
 
 
 def _period():
@@ -291,8 +301,8 @@ def _hybrid_forward(params, ids, *, nh, nkv, hd, eps, tied, gdn):
     from ..serving.decode import _hybrid_prefill_layers
     x = jnp.take(params["embed"], ids, axis=0)
     lengths = jnp.full((ids.shape[0],), ids.shape[1], jnp.int32)
-    x, _, _, _ = _hybrid_prefill_layers(params, x, lengths, nh=nh, nkv=nkv,
-                                        hd=hd, eps=eps, gdn=gdn)
+    x, *_ = _hybrid_prefill_layers(params, x, lengths, nh=nh, nkv=nkv, hd=hd,
+                                   eps=eps, gdn=gdn)
     x = _rms(x, params["final_norm"], eps)
     head = params["lm_head"].T if tied else params["lm_head"]
     return jnp.einsum("bsh,hv->bsv", x, head)

@@ -34,7 +34,10 @@ model (a tree with ``linear_layers``: periods of Gated DeltaNet layers and
 then one full-attention layer) scans its periods, the pool holding rows for
 the full layers only; a linear layer's cache is a float32 state and its
 convolution's last inputs, in a store by slot that rides the programs like
-the pool (``_hybrid_span_forward``, ``kernels.gated_delta_rule``). A
+the pool (``_hybrid_span_forward``, ``kernels.gated_delta_rule``); both kinds
+of layer are ONE body, a mixer and then an FFN (``_mixer_ffn_layer``), so a
+hybrid tree with ``router`` (``models.qwen3_next``) routes its FFNs inside the
+period scan. A
 decoder-hybrid-decoder model (a tree with ``self_layers``: pairs of a Mamba
 layer and a window-attention layer, a middle Mamba layer that makes a memory
 and a middle full-attention layer whose keys and values are THE cache, then
@@ -137,13 +140,19 @@ _INDEXER_KEYS = ("idx_wq_b", "idx_wk", "idx_k_ln_w", "idx_k_ln_b", "idx_w")
 #: ONE layer's weights out of each, which XLA fuses into the matmul that
 #: reads them; cut out of ``[periods, layers a period, ...]`` the three
 #: layers' weights were copied every period, a third of a step: PERF.md).
-#: Both kinds normalise each sub-layer's OUTPUT (``attn_out_ln`` /
-#: ``ffn_out_ln``) and have no ``input_ln`` / ``post_ln``.
-_HYBRID_FULL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                     "q_norm", "k_norm", "attn_out_ln", "ffn_out_ln")
+#: Both kinds are ``_mixer_ffn_layer`` around their mixer, and what a layer's
+#: tree holds of ``_HYBRID_FFN_KEYS`` chooses the rest: ``attn_out_ln`` /
+#: ``ffn_out_ln`` normalise each sub-layer's OUTPUT (Olmo-Hybrid), ``input_ln``
+#: / ``post_ln`` its INPUT; ``router`` makes the FFN a routed one whose expert
+#: stacks ``[periods, E_held, ...]`` (one set for each place in the period)
+#: are read in place at the period's index, ``ws_*`` a shared expert beside
+#: it and ``ws_sgate`` that expert's sigmoid gate (Qwen3-Next).
+_HYBRID_FULL_KEYS = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 _GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
-             "gdn_dt_bias", "gdn_o_norm", "gdn_wo", "w_gate", "w_up",
-             "w_down", "attn_out_ln", "ffn_out_ln")
+             "gdn_dt_bias", "gdn_o_norm", "gdn_wo")
+_HYBRID_FFN_KEYS = ("w_gate", "w_up", "w_down", "attn_out_ln", "ffn_out_ln",
+                    "input_ln", "post_ln", "router", "ws_gate", "ws_up",
+                    "ws_down", "ws_sgate")
 
 #: a model whose blocks are ONE mixer each (``models.nemotron_h``): UNITS of a
 #: Mamba-2 block, optionally an attention block, then a routed FFN of
@@ -160,7 +169,13 @@ _MIXER_ATTN_KEYS = ("ln", "wq", "wk", "wv", "wo")
 _MIXER_MOE_KEYS = ("moe_ln", "router", "router_bias", "ws_up", "ws_down")
 
 #: what marks a tree whose layer only the default engine's two programs were
-#: taught (``ContinuousBatchingEngine`` raises for every other switch)
+#: taught (``ContinuousBatchingEngine`` raises for every other switch): the
+#: extras of ``_decoder_layer``, and the key that names each model whose
+#: forward is a scan of its own (``wkv_a``: latent attention;
+#: ``linear_layers``: periods of Gated DeltaNet layers around a full layer,
+#: Olmo-Hybrid's and Qwen3-Next's alike; ``self_layers``: decoder-hybrid-
+#: decoder; ``ssd_layers``: one mixer a block; ``mamba_layers``: Mamba-1
+#: layers around one attention layer)
 TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers",
                                    "ssd_layers", "mamba_layers")
 
@@ -384,11 +399,32 @@ def _o_proj(attn2, lwo):
     return jnp.einsum("bsd,dh->bsh", attn2, lwo)
 
 
-def _qk_norm(q, k, q_w, k_w, eps):
-    """RMSNorm of q and k over the WHOLE projection (all heads at once),
-    before the heads are split and before the rotary embedding (OLMoE)."""
-    return (_rms(q.reshape(q.shape[:2] + (-1,)), q_w, eps).reshape(q.shape),
-            _rms(k.reshape(k.shape[:2] + (-1,)), k_w, eps).reshape(k.shape))
+def _rms_1p(x, w, eps):
+    """RMSNorm with a ZERO-CENTRED weight: ``x / sqrt(mean(x^2) + eps) * (1 +
+    w)``, all of it float32, the rows' dtype out (Qwen3-Next; a tree with
+    ``norm_plus_one``)."""
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                             + eps)
+    return (out * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def _norm_of(params):
+    """The RMSNorm a model's tree asks for: ``_rms_1p`` with the marker
+    ``norm_plus_one`` (every norm weight but a Gated DeltaNet layer's output
+    norm is then zero-centred), else ``_rms``."""
+    return _rms_1p if "norm_plus_one" in params else _rms
+
+
+def _qk_norm(q, k, q_w, k_w, eps, norm=_rms):
+    """RMSNorm of q and k ``[B, S, heads, D]`` before the rotary embedding:
+    over the WHOLE projection, all heads at once (OLMoE, Olmo-Hybrid), or,
+    where the weight is one head wide, a HEAD at a time (Qwen3-Next; with one
+    head the two are the same)."""
+    if q_w.shape[-1] == q.shape[-1]:
+        return norm(q, q_w, eps), norm(k, k_w, eps)
+    return (norm(q.reshape(q.shape[:2] + (-1,)), q_w, eps).reshape(q.shape),
+            norm(k.reshape(k.shape[:2] + (-1,)), k_w, eps).reshape(k.shape))
 
 
 def _rope_tables_for(seq_len, hd, theta, mla):
@@ -594,53 +630,32 @@ def dsa_expanded_attention(q_nope, q_pe, c_kv, k_pe, w_kvb, mask, *, mla,
 _ROUTING_KEYS = ("n_group", "topk_group", "first_held", "scale")
 
 
-def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
-                   moe=None, experts=None, tp_reduce=None,
-                   return_picks=False, mla=None):
-    """ONE softmax-attention decoder layer on ``h [B, S, H]``, written once
-    for the programs the default engine runs (whole-prompt prefill, the
-    packed-span forward of the unified step) and for the models' own
-    ``forward``. Where the norms sit follows the tree: with ``input_ln`` /
-    ``post_ln`` each sub-layer's INPUT is normalised (pre-norm), with
-    ``attn_out_ln`` / ``ffn_out_ln`` its OUTPUT, before the residual add (a
-    hybrid model's full layers; its linear layers are ``_gdn_layer``).
-
-    ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
-    ``_dq_layer``) and what it holds chooses the body: with ``wkv_a`` the
-    attention is the latent one (``_mla_attention``; ``mla`` its static
-    numbers, ``models.deepseek_v2.Mla``); with ``q_norm`` q and k are normalised before ``rope``; with
-    ``router`` the FFN is the dropless routed one (``kernels.moe_ffn``;
-    ``moe`` is its static ``(top_k, renormalize)`` and then ``_ROUTING_KEYS``,
-    ``experts`` the three expert stacks ``[L, E, ...]`` of which
-    ``lw["layer"]`` names this layer's, ``live [B, S]`` marks the rows that
-    make pairs) plus, with ``ws_gate``, a shared expert every row runs (scope
-    ``moe_shared``, beside ``moe``); else the dense SwiGLU. The program
-    brings its own ``rope(x)`` and ``attend(q, k, v) -> (attn [B, S, nh, hd],
-    carry)`` (latent attention: ``_mla_attention``'s): cache writes and the
-    attention kernel are the program's business, not the layer's.
-    Returns ``(h, carry, moe_stats or None)``; with ``return_picks`` the
-    third is ``(moe_stats, picked experts [B, S, top_k])``."""
-    B, S = h.shape[0], h.shape[1]
-    with jax.named_scope("attn"):
-        hn = _rms(h, lw["input_ln"], eps) if "input_ln" in lw else h
-        if "wkv_a" in lw:
-            with jax.named_scope("mla"):
-                attn, carry = _mla_attention(hn, lw, nh=nh, eps=eps,
-                                             rope=rope, attend=attend,
-                                             mla=mla)
-                with jax.named_scope("mla_proj"):
-                    o = _o_proj(attn.reshape(B, S, -1), lw["wo"])
-        else:
-            q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"], nh, nkv,
-                                hd)
-            if "q_norm" in lw:
-                q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps)
-            attn, carry = attend(rope(q), rope(k), v)
-            o = _o_proj(attn.reshape(B, S, nh * hd), lw["wo"])
+def _mixer_ffn_layer(h, lw, scope, mixer, *, eps, live=None, moe=None,
+                     experts=None, tp_reduce=None, return_picks=False,
+                     norm=_rms):
+    """ONE layer of "a mixer, then an FFN" on ``h [B, S, H]``, written once
+    for every kind of layer that is two residual sub-layers: ``_decoder_layer``
+    (softmax attention) and ``_gdn_layer`` (Gated DeltaNet) bring
+    ``mixer(hn) -> (out [B, S, H], carry)`` and the named ``scope`` it runs
+    under. What ``lw`` holds chooses the rest: with ``input_ln`` / ``post_ln``
+    each sub-layer's INPUT is normalised (pre-norm), with ``attn_out_ln`` /
+    ``ffn_out_ln`` its OUTPUT, before the residual add; with ``router`` the FFN
+    is the dropless routed one (``kernels.moe_ffn``; ``moe`` is its static
+    ``(top_k, renormalize)`` and then ``_ROUTING_KEYS``, ``experts`` the three
+    expert stacks ``[L, E, ...]`` of which ``lw["layer"]`` names this layer's,
+    ``live [B, S]`` marks the rows that make pairs) plus, with ``ws_gate``, a
+    shared expert every row runs (scope ``moe_shared``, beside ``moe``), times
+    ``sigmoid(hn ws_sgate)`` a row where the tree has that gate; else the
+    dense SwiGLU. ``norm`` is the tree's RMSNorm (``_norm_of``). Returns ``(h,
+    carry, moe_stats or None)``; with ``return_picks`` the third is
+    ``(moe_stats, picked experts [B, S, top_k])``."""
+    with jax.named_scope(scope):
+        o, carry = mixer(norm(h, lw["input_ln"], eps) if "input_ln" in lw
+                         else h)
         o = o if tp_reduce is None else tp_reduce(o)
-        h = h + (_rms(o, lw["attn_out_ln"], eps) if "attn_out_ln" in lw
+        h = h + (norm(o, lw["attn_out_ln"], eps) if "attn_out_ln" in lw
                  else o)
-    hn = _rms(h, lw["post_ln"], eps) if "post_ln" in lw else h
+    hn = norm(h, lw["post_ln"], eps) if "post_ln" in lw else h
     if "router" in lw:
         m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
                             top_k=moe[0], live=live, renormalize=moe[1],
@@ -651,14 +666,71 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, live=None,
         stats = tuple(stats) if return_picks else stats[0]
         if "ws_gate" in lw:
             with jax.named_scope("moe_shared"):
-                m = m + _swiglu_raw(hn, lw["ws_gate"], lw["ws_up"],
-                                    lw["ws_down"])
+                shared = _swiglu_raw(hn, lw["ws_gate"], lw["ws_up"],
+                                     lw["ws_down"])
+                if "ws_sgate" in lw:
+                    gate = jax.nn.sigmoid(jnp.einsum(
+                        "bsh,hj->bsj", hn, lw["ws_sgate"],
+                        preferred_element_type=jnp.float32))
+                    shared = (gate * shared).astype(shared.dtype)
+                m = m + shared
     else:
         m, stats = _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
                                 lw["w_down"]), None
     m = m if tp_reduce is None else tp_reduce(m)
-    h = h + (_rms(m, lw["ffn_out_ln"], eps) if "ffn_out_ln" in lw else m)
+    h = h + (norm(m, lw["ffn_out_ln"], eps) if "ffn_out_ln" in lw else m)
     return h, carry, stats
+
+
+def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, mla=None,
+                   norm=_rms, **ffn):
+    """ONE softmax-attention decoder layer on ``h [B, S, H]``, written once
+    for the programs the default engine runs (whole-prompt prefill, the
+    packed-span forward of the unified step) and for the models' own
+    ``forward``: ``_mixer_ffn_layer`` (which takes ``ffn``: ``live``, ``moe``,
+    ``experts``, ``tp_reduce``, ``return_picks``) around the attention below,
+    under the scope ``attn``.
+
+    ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
+    ``_dq_layer``) and what it holds chooses the attention: with ``wkv_a`` the
+    latent one (``_mla_attention``; ``mla`` its static numbers,
+    ``models.deepseek_v2.Mla``); with ``q_norm`` q and k are normalised before
+    ``rope``, over the whole projection or a head at a time (``_qk_norm``); a
+    ``wq`` twice as wide as the heads holds a query and then an output GATE a
+    head, and the heads' output is multiplied by ``sigmoid(gate)`` before
+    ``W_o`` (Qwen3-Next). The program
+    brings its own ``rope(x)`` (a rotation of part of a head is the
+    program's: ``_rope_head``) and ``attend(q, k, v) -> (attn [B, S, nh, hd],
+    carry)`` (latent attention: ``_mla_attention``'s): cache writes and the
+    attention kernel are the program's business, not the layer's.
+    Returns ``_mixer_ffn_layer``'s ``(h, carry, moe_stats or None)``."""
+    B, S = h.shape[0], h.shape[1]
+
+    def mixer(hn):
+        if "wkv_a" in lw:
+            with jax.named_scope("mla"):
+                attn, carry = _mla_attention(hn, lw, nh=nh, eps=eps,
+                                             rope=rope, attend=attend,
+                                             mla=mla)
+                with jax.named_scope("mla_proj"):
+                    return _o_proj(attn.reshape(B, S, -1), lw["wo"]), carry
+        gated = not isinstance(lw["wq"], tuple) \
+            and lw["wq"].shape[-1] == 2 * nh * hd
+        q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"],
+                            2 * nh if gated else nh, nkv, hd)
+        gate = None
+        if gated:       # a head's columns: its query, then its gate
+            q = q.reshape(B, S, nh, 2 * hd)
+            q, gate = q[..., :hd], q[..., hd:]
+        if "q_norm" in lw:
+            q, k = _qk_norm(q, k, lw["q_norm"], lw["k_norm"], eps, norm)
+        attn, carry = attend(rope(q), rope(k), v)
+        if gate is not None:
+            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(hn.dtype)
+        return _o_proj(attn.reshape(B, S, nh * hd), lw["wo"]), carry
+
+    return _mixer_ffn_layer(h, lw, "attn", mixer, eps=eps, norm=norm, **ffn)
 
 
 # ------------------------------------------- linear-attention (hybrid) layers
@@ -734,35 +806,42 @@ def _rows_conv(a, w, bias, lengths):
 
 
 def gdn_split(u, gdn):
-    """The convolved channels ``[.., C]`` as normalised ``q, k [.., heads,
-    dk]`` (float32; q carries the ``dk^-0.5``) and ``v [.., heads, dv]``."""
-    nk = gdn.heads * gdn.dk
+    """The convolved channels ``[.., C]`` as normalised ``q, k [.., key
+    heads, dk]`` (float32; q carries the ``dk^-0.5``) and ``v [.., heads,
+    dv]``; the kernels read value head ``h``'s q and k at key head ``h //
+    (heads / key heads)``."""
+    hk = gdn.key_heads or gdn.heads
+    nk = hk * gdn.dk
     lead = u.shape[:-1]
-    q = l2norm(u[..., :nk].reshape(lead + (gdn.heads, gdn.dk)),
-               gdn.dk ** -0.5)
-    k = l2norm(u[..., nk:2 * nk].reshape(lead + (gdn.heads, gdn.dk)))
+    q = l2norm(u[..., :nk].reshape(lead + (hk, gdn.dk)), gdn.dk ** -0.5)
+    k = l2norm(u[..., nk:2 * nk].reshape(lead + (hk, gdn.dk)))
     return q, k, u[..., 2 * nk:].reshape(lead + (gdn.heads, gdn.dv))
 
 
-def _gdn_layer(h, lw, *, eps, gdn, mix):
+def _gdn_layer(h, lw, *, eps, gdn, mix, norm=_rms, **ffn):
     """ONE Gated DeltaNet layer on ``h [B, S, H]`` (``models.olmo_hybrid``'s
-    docstring has the equations), written once for whole-prompt prefill, the
-    unified step and the model's ``forward``. ``gdn`` is the layer's static
-    numbers (``models.olmo_hybrid.Gdn``). The program brings ``mix(u, g,
-    beta, conv_w) -> (o [B, S, heads, dv] float32, carry)``: where the
-    convolution's earlier rows and the state come from, which kernel walks
-    the tokens, and what is written back are the program's business. Scopes
-    ``gdn`` > ``gdn_proj`` (the input projections and ``W_o``) and
-    ``gdn_mix`` (convolution, gates, the kernels, the gated norm)."""
+    and ``models.qwen3_next``'s docstrings have the equations), written once
+    for whole-prompt prefill, the unified step and the models' ``forward``:
+    ``_mixer_ffn_layer`` (which takes ``ffn`` and chooses the norms' places
+    and the FFN by what ``lw`` holds) around the mixer below, under the scope
+    ``gdn``. ``gdn`` is the layer's static numbers
+    (``models.olmo_hybrid.Gdn``). The program brings ``mix(u, g, beta, conv_w) -> (o [B, S, heads, dv]
+    float32, carry)``: where the convolution's earlier rows and the state come
+    from, which kernel walks the tokens, and what is written back are the
+    program's business. Scopes ``gdn`` > ``gdn_proj`` (the input projections
+    and ``W_o``) and ``gdn_mix`` (convolution, gates, the kernels, the gated
+    norm, whose weight is NOT zero-centred in any tree). Returns
+    ``_mixer_ffn_layer``'s ``(h, carry, moe_stats or None)``."""
     B, S = h.shape[0], h.shape[1]
-    with jax.named_scope("gdn"):
+
+    def mixer(hn):
         with jax.named_scope("gdn_proj"):
-            u = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wqkv"])
-            z = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wz"])
+            u = jnp.einsum("bsh,hc->bsc", hn, lw["gdn_wqkv"])
+            z = jnp.einsum("bsh,hc->bsc", hn, lw["gdn_wz"])
             # the gates' projection leaves in float32: the decay is
             # exp(-exp(A_log) softplus(a + ..)), and a bf16 rounding of a
             # moves it by tens of percent where exp(A_log) is near 16
-            ab = jnp.einsum("bsh,hc->bsc", h, lw["gdn_wab"],
+            ab = jnp.einsum("bsh,hc->bsc", hn, lw["gdn_wab"],
                             preferred_element_type=jnp.float32)
         with jax.named_scope("gdn_mix"):
             g, beta = gdn_gates(ab, lw["gdn_A_log"], lw["gdn_dt_bias"],
@@ -770,12 +849,11 @@ def _gdn_layer(h, lw, *, eps, gdn, mix):
             o, carry = mix(u, g, beta, lw["gdn_conv"])
             y = _rms(o, lw["gdn_o_norm"].astype(jnp.float32), eps) \
                 * jax.nn.silu(z.astype(jnp.float32)).reshape(o.shape)
-            y = y.astype(h.dtype).reshape(B, S, -1)
+            y = y.astype(hn.dtype).reshape(B, S, -1)
         with jax.named_scope("gdn_proj"):
-            out = jnp.einsum("bsc,ch->bsh", y, lw["gdn_wo"])
-        h = h + _rms(out, lw["attn_out_ln"], eps)
-    m = _swiglu_raw(h, lw["w_gate"], lw["w_up"], lw["w_down"])
-    return h + _rms(m, lw["ffn_out_ln"], eps), carry
+            return jnp.einsum("bsc,ch->bsh", y, lw["gdn_wo"]), carry
+
+    return _mixer_ffn_layer(h, lw, "gdn", mixer, eps=eps, norm=norm, **ffn)
 
 
 def _hybrid_scan(params, carry, full_layer, linear_layer):
@@ -784,19 +862,31 @@ def _hybrid_scan(params, carry, full_layer, linear_layer):
     where the tree puts it: after them (a tree with ``linear_layers``), or
     between the two runs of a tree with ``mamba_layers`` (``(the places
     before the full layer, the places after it)``, the full layer's entries
-    under ``attn_layers``). ``linear_layer(carry, lw, index)`` /
-    ``full_layer(carry, lw, index)`` return ``(carry, ys)``; ``index`` is the
-    layer's count among its own kind: a linear layer's place in the state
-    store, a full layer's in the KV pool. Returns ``(carry, linear ys
-    [periods, layers a period, ...], full ys [periods, ...])``."""
+    under ``attn_layers``). ``linear_layer(carry, lw, index, experts)`` /
+    ``full_layer(carry, lw, index, experts)`` return ``(carry, ys)``;
+    ``index`` is the layer's count among its own kind: a linear layer's place
+    in the state store, a full layer's in the KV pool. Where the tree's FFNs
+    are routed (``router``), ``experts`` is the three expert stacks
+    ``[periods, E_held, ...]`` of the layer's PLACE in the period, whole (no
+    scan slices them: ``_EXPERT_KEYS``), and ``lw["layer"]``, the period, is
+    where the grouped matmul reads them; else None. Returns ``(carry, linear
+    ys [periods, layers a period, ...], full ys [periods, ...])``."""
     if "mamba_layers" in params:
         before, after = params["mamba_layers"]
         lin, full, full_at = before + after, params["attn_layers"], \
             len(before)
+        lin_experts, full_experts = (None,) * len(lin), None
     else:
-        lin = tuple({k: tree[k] for k in _GDN_KEYS}
-                    for tree in params["linear_layers"])
-        full = {k: params[k] for k in _HYBRID_FULL_KEYS}
+        routed = "router" in params
+
+        def cut(tree, keys):
+            held = {k: tree[k] for k in keys + _HYBRID_FFN_KEYS if k in tree}
+            return held, (tuple(held.pop(k) for k in _EXPERT_KEYS)
+                          if routed else None)
+
+        lin, lin_experts = zip(*(cut(tree, _GDN_KEYS)
+                                 for tree in params["linear_layers"]))
+        full, full_experts = cut(params, _HYBRID_FULL_KEYS)
         full_at = len(lin)
     periods, n_lin = full["wo"].shape[0], len(lin)
 
@@ -805,9 +895,11 @@ def _hybrid_scan(params, carry, full_layer, linear_layer):
         ys = []
         for j in range(n_lin + 1):
             if j == full_at:
-                carry, y_full = full_layer(carry, full_p, p)
+                carry, y_full = full_layer(carry, dict(full_p, layer=p), p,
+                                           full_experts)
             if j < n_lin:
-                carry, y = linear_layer(carry, lin_p[j], p * n_lin + j)
+                carry, y = linear_layer(carry, dict(lin_p[j], layer=p),
+                                        p * n_lin + j, lin_experts[j])
                 ys.append(y)
         ys = None if ys[0] is None else jax.tree.map(
             lambda *a: jnp.stack(a), *ys)
@@ -816,6 +908,33 @@ def _hybrid_scan(params, carry, full_layer, linear_layer):
     carry, (ys_lin, ys_full) = jax.lax.scan(
         period, carry, (lin, full, jnp.arange(periods, dtype=jnp.int32)))
     return carry, ys_lin, ys_full
+
+
+def _hybrid_moe_stats(params, lin_stats, full_stats):
+    """A hybrid model's routed FFNs' outputs in LAYER order, as
+    ``_packed_span_forward``'s: ``lin_stats [periods, linear layers a period,
+    ...]`` and ``full_stats [periods, ...]`` (``_hybrid_scan``'s ys) joined
+    with the full layer after a period's linear ones, ``[L, ...]``; None for
+    a tree whose FFNs are dense."""
+    if "router" not in params:
+        return None
+    return jax.tree.map(
+        lambda a, b: jnp.concatenate([a, b[:, None]], axis=1).reshape(
+            (-1,) + b.shape[1:]), lin_stats, full_stats)
+
+
+def _hybrid_rope(rotary, hd, rotate):
+    """A hybrid model's rotary embedding of q or k ``[B, S, heads, hd]``
+    around the program's ``rotate(x)`` over tables ``rotary`` wide (the
+    program's static beside ``theta``; None: the whole head): the FIRST
+    ``rotary`` values of a head are rotated, the rest left
+    (``partial_rotary_factor``); a ``rotate`` of None rotates nothing
+    (``rope_theta`` null: Olmo-Hybrid)."""
+    if rotate is None:
+        return lambda t: t
+    width = rotary or hd
+    return rotate if width == hd else \
+        (lambda t: _rope_head(t, rotate, width))
 
 
 # ------------------------------------------- decoder-hybrid-decoder (SambaY)
@@ -837,11 +956,11 @@ def _layer_norm(x, w, b, eps):
 
 def _final_norm(params, x, eps):
     """A model's norm before its head: LayerNorm where the tree has its bias
-    (``final_norm_b``), else RMSNorm."""
+    (``final_norm_b``), else the tree's RMSNorm (``_norm_of``)."""
     if "final_norm_b" in params:
         return _layer_norm(x, params["final_norm"], params["final_norm_b"],
                            eps)
-    return _rms(x, params["final_norm"], eps)
+    return _norm_of(params)(x, params["final_norm"], eps)
 
 
 def _sambay_block(h, lw, eps, mixer):
@@ -1311,12 +1430,12 @@ def _jamba_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
     live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
     mamba = _mamba_rows_mixer(lengths, live, ssm, eps=eps)
 
-    def full_layer(h, lw, _):
+    def full_layer(h, lw, *_):
         return _jamba_block(h, lw, eps, lambda hn: _jamba_attention(
             hn, lw, lambda q, k, v: (_attention(q, k, v, causal=True),
                                      (k, v)), nh=nh, nkv=nkv, hd=hd))
 
-    def linear_layer(h, lw, _):
+    def linear_layer(h, lw, *_):
         h, (tail, st, _) = _jamba_block(h, lw, eps, lambda hn: mamba(hn, lw))
         return h, (st, tail)
 
@@ -1340,13 +1459,13 @@ def _jamba_span_forward(params, x, pool_k, pool_v, store, kv_attend, *,
     mamba = _mamba_span_mixer(ssm, seg=seg, pos=pos, qstart=qstart,
                               qlen=qlen, kvlen=kvlen, T=T, eps=eps)
 
-    def full_layer(carry, lw, idx):
+    def full_layer(carry, lw, idx, _):
         h, pk, pv, ss, cs = carry
         h, (pk, pv) = _jamba_block(h, lw, eps, lambda hn: _jamba_attention(
             hn, lw, kv_attend(pk, pv, idx), nh=nh, nkv=nkv, hd=hd))
         return (h, pk, pv, ss, cs), None
 
-    def linear_layer(carry, lw, idx):
+    def linear_layer(carry, lw, idx, _):
         h, pk, pv, ss, cs = carry
         h, (cs, ss, _) = _jamba_block(
             h, lw, eps, lambda hn: mamba(hn, lw, idx, ss, cs))
@@ -2008,30 +2127,46 @@ def _moe_outputs(stats):
     return (stats,)
 
 
-def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
+def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn,
+                           theta=None, rotary=None, moe=None,
+                           return_picks=False):
     """A hybrid model's layers over an admission group ``x [G, S_pad, H]``
-    (``_hybrid_scan``): the full layers attend causally and return their
-    K/V, the linear layers run the chunked scan from a zero state over each
-    row's real tokens (a padding column has ``beta`` 0 and ``g`` 0: the
-    state passes it unchanged) and return what their cache holds of a
-    sequence: the final state ``[G, dk, heads * dv]`` float32 (the store's
-    layout, ``kernels.gated_delta_rule.state_shape``) and the
-    convolution's last inputs ``[G, conv - 1, C]``. Returns ``(x, pk, pv
-    [full layers, G, S_pad, Hkv, D], (states, tails) [linear layers, G,
-    ...])``."""
+    (``_hybrid_scan``): the full layers attend causally (rotating what
+    ``_hybrid_rope`` says, by ``theta``) and return their K/V, the linear
+    layers run the chunked scan from a zero state over each row's real tokens
+    (a padding column has ``beta`` 0 and ``g`` 0: the state passes it
+    unchanged) and return what their cache holds of a sequence: the final
+    state ``[G, dk, heads * dv]`` float32 (the store's layout,
+    ``kernels.gated_delta_rule.state_shape``) and the convolution's last
+    inputs ``[G, conv - 1, C]``; routed FFNs make pairs for the real tokens
+    only. Returns ``(x, pk, pv [full layers, G, S_pad, Hkv, D], (states,
+    tails) [linear layers, G, ...], moe stats)``, the last as
+    ``_packed_span_forward``'s."""
     G, S = x.shape[0], x.shape[1]
     cols = jnp.arange(S, dtype=jnp.int32)
     live = cols[None, :] < lengths[:, None]
     rows_g = jnp.arange(G, dtype=jnp.int32)
+    norm = _norm_of(params)
+    rotate = None
+    if theta is not None:
+        sin, cos = _rope_tables(S, rotary or hd, theta)
 
-    def full_layer(h, lw, _):
-        h, kv, _ = _decoder_layer(
-            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=lambda t: t,
+        def rotate(t):
+            return _apply_rope(t, sin, cos)
+    rope = _hybrid_rope(rotary, hd, rotate)
+
+    def ffn(experts):
+        return dict(norm=norm, live=live, moe=moe, experts=experts,
+                    return_picks=return_picks and experts is not None)
+
+    def full_layer(h, lw, _, experts):
+        h, kv, stats = _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope,
             attend=lambda q, k, v: (_attention(q, k, v, causal=True),
-                                    (k, v)))
-        return h, kv
+                                    (k, v)), **ffn(experts))
+        return h, (kv, stats)
 
-    def linear_layer(h, lw, _):
+    def linear_layer(h, lw, _, experts):
         def mix(u, g, beta, conv_w):
             up, tail = _rows_conv(u, conv_w, None, lengths)
             q, k, v = gdn_split(up, gdn)
@@ -2058,16 +2193,20 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn):
                           o.reshape(G, S, gdn.heads, gdn.dv), 0.0)
             return o, (st[0], tail)
 
-        return _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix)
+        h, kept, stats = _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix,
+                                    **ffn(experts))
+        return h, (kept, stats)
 
-    x, ys_lin, (pk, pv) = _hybrid_scan(params, x, full_layer, linear_layer)
-    return x, pk, pv, tuple(a.reshape((-1,) + a.shape[2:]) for a in ys_lin)
+    x, (kept, lin_stats), ((pk, pv), full_stats) = _hybrid_scan(
+        params, x, full_layer, linear_layer)
+    return (x, pk, pv, tuple(a.reshape((-1,) + a.shape[2:]) for a in kept),
+            _hybrid_moe_stats(params, lin_stats, full_stats))
 
 
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
                   mla=None, return_picks=False, gdn=None, ssm=None,
-                  dsa=None, ssd=None):
+                  dsa=None, ssd=None, rotary=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -2090,9 +2229,10 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     ``pv`` is the index keys of the layers that have an indexer, ``[L_full,
     G, S_pad, 1, D]``: the pool's second side. A hybrid model
     (``linear_layers``; ``gdn`` its linear
-    layers' static numbers) returns ``pk`` / ``pv`` of its FULL layers only
-    and, last, what its linear layers' cache holds of each row
-    (``_hybrid_prefill_layers``). A decoder-hybrid-decoder model
+    layers' static numbers) returns ``pk`` / ``pv`` of its FULL layers only,
+    its routed FFNs' summary (and picks) where it has them and, last, what
+    its linear layers' cache holds of each row (``_hybrid_prefill_layers``).
+    A decoder-hybrid-decoder model
     (``self_layers``; ``ssm`` its static numbers) returns ``pk`` / ``pv`` of
     its ONE layer with a row a token and, last, what its Mamba layers' and
     window layers' stores hold of each row (``_sambay_prefill_layers``); a
@@ -2126,12 +2266,13 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
         return pk, pv, tok0, keys2, state
     if gdn is not None:
         x = jnp.take(params["embed"], ids, axis=0)
-        x, pk, pv, state = _hybrid_prefill_layers(
-            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, gdn=gdn)
+        x, pk, pv, state, stats = _hybrid_prefill_layers(
+            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, gdn=gdn,
+            theta=theta, rotary=rotary, moe=moe, return_picks=return_picks)
         tok0, keys2 = _first_token(
             params, _dq_head(params, tied, params["embed"].dtype, a8), x,
             lengths, keys, temps, top_ks, eps)
-        return pk, pv, tok0, keys2, state
+        return (pk, pv, tok0, keys2) + _moe_outputs(stats) + (state,)
     sin, cos = _rope_tables_for(S, hd, theta, mla)
     wdt = params["embed"].dtype
     head = _dq_head(params, tied, wdt, a8)
@@ -2203,7 +2344,7 @@ def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
                      mla=None, return_picks=False, gdn=None, ssm=None,
-                     dsa=None, ssd=None):
+                     dsa=None, ssd=None, rotary=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -2229,7 +2370,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
         **({} if gdn is None else {"gdn": gdn}),
         **({} if ssm is None else {"ssm": ssm}),
         **({} if dsa is None else {"dsa": dsa}),
-        **({} if ssd is None else {"ssd": ssd})))
+        **({} if ssd is None else {"ssd": ssd}),
+        **({} if rotary is None else {"rotary": rotary})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -2493,14 +2635,18 @@ def _rows_sample(params, head, last, keys, temps, top_ks, eps):
 
 def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
                          seg, pos, qstart, qlen, kvlen, nh, nkv, hd, eps,
-                         gdn):
+                         gdn, rotate=None, rotary=None, moe=None,
+                         return_picks=False):
     """A hybrid model's layers over the packed buffer ``x [1, T, H]``
     (``_hybrid_scan``). The KV pool (full layers only) and the state store
     ``(states [linear layers, R, dk, heads * dv] float32, tails [linear
     layers, R, conv - 1, C])`` ride the scan as carry, whole: a full layer
     appends and attends at its own count in the pool (``kv_attend(pk, pv,
-    layer)``), a linear layer reads and writes its own count in the store,
-    at the slots that have a span this step and nowhere else.
+    layer)``; ``rotate`` the program's rotation at the packed rows'
+    positions, ``_hybrid_rope``), a linear layer reads and writes its own
+    count in the store, at the slots that have a span this step and nowhere
+    else; a routed FFN, after either kind of mixer, makes pairs for the live
+    packed rows.
 
     What a linear layer needs of the span table: where a span starts in the
     buffer (``qstart``) says which of the convolution's earlier inputs are
@@ -2508,10 +2654,12 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
     position ``kvlen - qlen`` is 0 takes a zero tail and a zero state,
     whatever its slot held (no program ever zeroes a slot); spans of one
     token (decode rows) go through ``gdn_recurrent_update`` together, longer
-    ones (prefill chunks) through ``gdn_chunk_scan``, both following the
-    live spans and not the buffer (``decode_attention="jnp"``: the
-    token-by-token oracle over the whole buffer). Returns ``(x, pool_k,
-    pool_v, state)``."""
+    ones (prefill chunks) through ``gdn_chunk_scan``, which the decode-only
+    program, ``T == gdn.decode_rows``, leaves out (the plan gave it no chunk),
+    both following the live spans and not the buffer
+    (``decode_attention="jnp"``: the token-by-token oracle over the whole
+    buffer). Returns ``(x, pool_k, pool_v, state, moe stats)``, the last as
+    ``_packed_span_forward``'s."""
     R, T = qstart.shape[0], x.shape[1]
     live_tok = seg < R
     seg_c = jnp.minimum(seg, R - 1)
@@ -2519,16 +2667,27 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
     one, many = qlen == 1, qlen > 1
     tok_one = live_tok & jnp.take(one, seg_c)
     row_at = jnp.clip(qstart, 0, T - 1)
+    norm = _norm_of(params)
+    rope = _hybrid_rope(rotary, hd, rotate)
 
-    def full_layer(carry, lw, idx):
+    def ffn(experts):
+        return dict(norm=norm, live=live_tok[None], moe=moe, experts=experts,
+                    return_picks=return_picks and experts is not None)
+
+    def full_layer(carry, lw, idx, experts):
         h, pk, pv, st = carry
-        h, (pk, pv), _ = _decoder_layer(
-            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=lambda t: t,
-            attend=kv_attend(pk, pv, idx))
-        return (h, pk, pv, st), None
+        h, (pk, pv), stats = _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope,
+            attend=kv_attend(pk, pv, idx), **ffn(experts))
+        return (h, pk, pv, st), stats
 
-    def linear_layer(carry, lw, idx):
+    def linear_layer(carry, lw, idx, experts):
         h, pk, pv, (ss, cs) = carry
+        if ss.dtype != jnp.float32:
+            # (as ``_mamba_span_mixer``: a state rounded to bfloat16 a token
+            # moves the logits by less than a check on logits can see)
+            raise TypeError(f"a Gated DeltaNet layer's state is float32, "
+                            f"the store holds {ss.dtype}")
 
         def mix(u, g, beta, conv_w):
             u, g, beta = u[0], g[0], beta[0]
@@ -2540,11 +2699,12 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
                 o1, new_ss = gdn_recurrent_update(
                     *(jnp.take(a, row_at, axis=0) for a in (q, k, v, g, beta)),
                     ss, layer=idx, live=one, fresh=fresh)
-                on, new_ss = gdn_chunk_scan(
-                    q, k, v, g, beta, new_ss, layer=idx, start=qstart,
-                    length=jnp.where(many, qlen, 0), fresh=fresh)
-                o = jnp.where(tok_one[:, None, None],
-                              jnp.take(o1, seg_c, axis=0), on)
+                o = jnp.take(o1, seg_c, axis=0)
+                if T != gdn.decode_rows:
+                    on, new_ss = gdn_chunk_scan(
+                        q, k, v, g, beta, new_ss, layer=idx, start=qstart,
+                        length=jnp.where(many, qlen, 0), fresh=fresh)
+                    o = jnp.where(tok_one[:, None, None], o, on)
             else:
                 o, new_ss = gdn_reference(
                     q, k, v, g, beta, ss, layer=idx, seg=seg,
@@ -2552,12 +2712,14 @@ def _hybrid_span_forward(params, x, pool_k, pool_v, state, kv_attend, *,
             o = jnp.where(live_tok[:, None, None], o, 0.0)
             return o[None], (new_ss, new_cs)
 
-        h, st = _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix)
-        return (h, pk, pv, st), None
+        h, st, stats = _gdn_layer(h, lw, eps=eps, gdn=gdn, mix=mix,
+                                  **ffn(experts))
+        return (h, pk, pv, st), stats
 
-    (x, pool_k, pool_v, state), _, _ = _hybrid_scan(
+    (x, pool_k, pool_v, state), lin_stats, full_stats = _hybrid_scan(
         params, (x, pool_k, pool_v, state), full_layer, linear_layer)
-    return x, pool_k, pool_v, state
+    return x, pool_k, pool_v, state, _hybrid_moe_stats(
+        params, lin_stats, full_stats)
 
 
 def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
@@ -2583,9 +2745,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     layer with an indexer writes the step's index keys into it, scores each
     query against its row's cached keys and selects, and every layer attends
     over the set of the last such layer (``attend_selected`` below). A hybrid model (``state``
-    its linear layers' store, ``gdn`` their static numbers) rotates nothing
-    (``sin`` None), runs ``_hybrid_span_forward`` and returns a fifth value,
-    the store. A decoder-hybrid-decoder model (``ssm``) runs
+    its linear layers' store, ``gdn`` their static numbers) rotates what
+    ``_hybrid_rope`` says (nothing where ``sin`` is None), runs
+    ``_hybrid_span_forward`` and returns the store fourth and its routed
+    FFNs' stats (None: dense FFNs) fifth. A decoder-hybrid-decoder model (``ssm``) runs
     ``_sambay_span_forward`` over ``state``, its Mamba and window layers'
     stores, and returns ``x`` NARROWED to one row a slot, ``[1, R, H]``; so
     does a tree with ``mamba_layers`` (``_jamba_span_forward``, ``state`` its
@@ -2661,7 +2824,11 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
         return _hybrid_span_forward(
             params, x, pool_k, pool_v, state, kv_attend, seg=seg, pos=pos,
             qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv, hd=hd,
-            eps=eps, gdn=gdn)
+            eps=eps, gdn=gdn, moe=moe, return_picks=return_picks,
+            # (the tables are as wide as what of a head is rotated)
+            rotary=None if sin is None else sin.shape[-1],
+            rotate=None if sin is None
+            else lambda t: _apply_rope_grid(t, sin_p, cos_p))
 
     def scan_stack(carry, first, names, stack, experts, indexer=None):
         def layer0(carry, lp):
@@ -2796,7 +2963,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
                       moe=None, mla=None, return_picks=False, gdn=None,
-                      ssm=None, dsa=None, ssd=None):
+                      ssm=None, dsa=None, ssd=None, rotary=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -2857,7 +3024,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     ``keys``, both handed on without a host round trip. A hybrid model
     passes ``state``, its linear layers' store (``_hybrid_span_forward``;
     donated like the pool), and gets it back as the last value (after the
-    routing summary, where its FFNs are routed: ``_mixer_span_forward``).
+    routing summary, where its FFNs are routed: ``_mixer_span_forward``,
+    ``_hybrid_span_forward``).
     """
     # dispatch-ahead: a decode row dispatched before the previous step's
     # tokens reached the host takes its input token here, on the device
@@ -2867,8 +3035,9 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     keys_in = jnp.where(((qlen > 0) & (dec_mask == 0))[:, None],
                         chunk_keys, keys)
     s_tot = tables.shape[1] * _kv_data(pool_k).shape[2]
-    sin, cos = (None, None) if theta is None \
-        else _rope_tables_for(s_tot, hd, theta, mla)
+    # (a model may rotate part of a head: the tables are that wide)
+    sin, cos = (None, None) if theta is None else _rope_tables_for(
+        s_tot, rotary or hd, theta, mla)
     # the fused tail's own layer body scans the GQA entries alone (a model
     # it was not taught never runs with n_steps > 1: the engine raises)
     stack = (tuple(params[k] for k in _STACK_KEYS) if n_steps > 1 else None)
@@ -2879,9 +3048,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         x, pk, pv, state, *moe_stats = _packed_span_forward(
             params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
             kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-            decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm,
-            **({} if ssd is None else dict(
-                ssd=ssd, moe=moe, return_picks=return_picks)))
+            decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm, ssd=ssd,
+            moe=moe, return_picks=return_picks)
         if ssm is not None:     # x came back one row a slot
             tok0, keys_t0 = _rows_sample(params, head, x[0], keys_in, temps,
                                          top_ks, eps)
@@ -2931,7 +3099,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
                          return_picks=False, gdn=None, ssm=None, dsa=None,
-                         ssd=None):
+                         ssd=None, rotary=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -2975,7 +3143,8 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             **({} if gdn is None else {"gdn": gdn}),
             **({} if ssm is None else {"ssm": ssm}),
             **({} if dsa is None else {"dsa": dsa}),
-            **({} if ssd is None else {"ssd": ssd})),
+            **({} if ssd is None else {"ssd": ssd}),
+            **({} if rotary is None else {"rotary": rotary})),
         # argument 18: the stores by slot of a model with recurrent or
         # window layers (absent otherwise)
         donate_argnums=((1, 2) + ((18,) if (gdn, ssm, ssd) != (None,) * 3

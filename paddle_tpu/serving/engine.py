@@ -73,6 +73,14 @@ from .scheduler import FIFOScheduler
 DRAIN_REASONS = ("idle", "cancel", "evict", "preempt", "pool", "deadline",
                  "snapshot", "fault")
 
+#: the rows (padded group x length bucket) of ONE whole-prompt prefill call:
+#: a call's temporaries grow with its rows (a routed FFN's pair buffers are
+#: rows x top_k x hidden in float32), so a larger burst of short prompts is
+#: prefilled in several calls. 8,192 is the largest call an engine of up to
+#: 32 slots makes of prompts up to 256 tokens; at 128 slots x 512 tokens one
+#: call of 65,536 rows asked for 13.9 GiB beside the weights (PERF.md, PR 54)
+WHOLE_PROMPT_ROWS = 8192
+
 
 def program_stat(rows):
     """The ``stats`` key that counts the step programs fenced at a packed
@@ -983,6 +991,10 @@ class ContinuousBatchingEngine:
                       hd=c.head_dim, eps=float(c.rms_norm_eps),
                       theta=None if c.rope_theta is None
                       else float(c.rope_theta), tied=self._tied)
+        if getattr(c, "rotary_dim", None):
+            # the first values of a head that the rotary embedding turns,
+            # where that is not the whole head
+            consts["rotary"] = int(c.rotary_dim)
         if self.routed_ffn:
             # the routed FFN's static numbers, model hyper-parameters like
             # the head counts above
@@ -992,17 +1004,17 @@ class ContinuousBatchingEngine:
             consts["mla"] = c.mla
         if "idx_layer" in self._params:
             consts["dsa"] = c.dsa
+        # a store's kernels leave the chunk scan out of the decode-only
+        # program, whose spans are one token each (where there is one)
+        rows = self._decode_rows if len(self._step_rows) == 2 else 0
         if "linear_layers" in self._params:
-            consts["gdn"] = c.gdn
+            consts["gdn"] = c.gdn._replace(decode_rows=rows)
         elif "ssd_layers" in self._params:
-            consts["ssd"] = c.ssd._replace(
-                decode_rows=self._decode_rows
-                if len(self._step_rows) == 2 else 0)
+            consts["ssd"] = c.ssd._replace(decode_rows=rows)
         elif self._stateful:
             consts["ssm"] = c.ssm._replace(
                 ring_rows=self._ring_blocks * self.cache.block_size,
-                decode_rows=self._decode_rows
-                if len(self._step_rows) == 2 else 0)
+                decode_rows=rows)
         if self._routing is not None:
             consts["return_picks"] = True
         return consts
@@ -1435,7 +1447,8 @@ class ContinuousBatchingEngine:
     def _admit_group(self, seqs, finished):
         """Admit a batch of sequences. With the prefix cache enabled the
         batch splits on cached-chain lookup: misses take the cold path
-        (ONE full-prompt prefill device call per prompt-length bucket),
+        (ONE full-prompt prefill device call per prompt-length bucket, or
+        several of at most ``WHOLE_PROMPT_ROWS`` rows where a burst is larger),
         hits install their cached blocks and take the suffix path (ONE
         suffix prefill per suffix-length bucket). Both pad the group dim
         to a power of two, so compile count stays bounded at
@@ -1505,7 +1518,14 @@ class ContinuousBatchingEngine:
         by_bucket = {}
         for seq in seqs:
             by_bucket.setdefault(self._bucket(seq.work_len), []).append(seq)
+        # a bucket's sequences in calls of at most WHOLE_PROMPT_ROWS rows
+        # (a power of two of them, as the padded group is)
+        calls = []
         for s_pad, group in sorted(by_bucket.items()):
+            most = 1 << (max(WHOLE_PROMPT_ROWS // s_pad, 1).bit_length() - 1)
+            calls += [(s_pad, group[i:i + most])
+                      for i in range(0, len(group), most)]
+        for s_pad, group in calls:
             G = len(group)
             Gp = 1 << (G - 1).bit_length()
             ids = np.zeros((Gp, s_pad), np.int32)

@@ -57,6 +57,7 @@ _ARCH = {
                     "glm_moe_dsa_tiny"),
     "nemotron_h": ("nemotron_h", "NemotronHForCausalLM", "nemotron_h_tiny"),
     "jamba": ("jamba", "JambaForCausalLM", "jamba_tiny"),
+    "qwen3_next": ("qwen3_next", "Qwen3NextForCausalLM", "qwen3_next_tiny"),
 }
 
 

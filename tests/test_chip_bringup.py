@@ -590,6 +590,100 @@ class TestMosaicCompilesNemotronH:
         assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
+class TestMosaicCompilesQwen3Next:
+    """Qwen3-Next's kernels at its published widths (Gated DeltaNet at 32
+    value heads on 16 key heads of 128 x 128, a float32 state ``[128, 4096]``
+    by slot: a key width of whole lane tiles, which pads nothing; 16 query
+    heads on 2 KV heads of 256; 64 held experts of 512 under a router of 512,
+    10 a token) and at the serving cell's shapes: 128 slots x 4,096, 9 linear
+    layers, a packed buffer of 128 + 512 rows or of 128."""
+    H, HK, DK, DV, R, LL, T = 32, 16, 128, 128, 128, 9, 640
+
+    def _store(self, v5e):
+        return v5e((self.LL, self.R) + gated_delta_rule.state_shape(
+            self.H, self.DK, self.DV), jnp.float32)
+
+    def _in_place(self, fn, args):
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the store (2.25 GiB) is aliased in and out at its logical size: no
+        # layer of it (256 MiB) is copied, q and k are not repeated to 32
+        # heads (a chunk's would be 2 x 640 x 32 x 128 x 4 B = 20 MiB)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == 4 * self.LL * self.R * self.H \
+            * self.DK * self.DV
+        assert mem.temp_size_in_bytes < 48 * 2 ** 20
+
+    def test_the_decode_row_update_in_place(self, v5e):
+        f32 = jnp.float32
+
+        def update(q, k, v, g, b, st, live, fresh, layer):
+            return gated_delta_rule.gdn_recurrent_update(
+                q, k, v, g, b, st, layer=layer, live=live, fresh=fresh)
+        self._in_place(update, (
+            v5e((self.R, self.HK, self.DK), f32),
+            v5e((self.R, self.HK, self.DK), f32),
+            v5e((self.R, self.H, self.DV), f32), v5e((self.R, self.H), f32),
+            v5e((self.R, self.H), f32), self._store(v5e),
+            v5e((self.R,), jnp.bool_), v5e((self.R,), jnp.bool_),
+            v5e((), jnp.int32)))
+
+    def test_the_chunked_scan_in_place(self, v5e):
+        f32 = jnp.float32
+
+        def scan(q, k, v, g, b, st, start, length, fresh, layer):
+            return gated_delta_rule.gdn_chunk_scan(
+                q, k, v, g, b, st, layer=layer, start=start, length=length,
+                fresh=fresh)
+        self._in_place(scan, (
+            v5e((self.T, self.HK, self.DK), f32),
+            v5e((self.T, self.HK, self.DK), f32),
+            v5e((self.T, self.H, self.DV)), v5e((self.T, self.H), f32),
+            v5e((self.T, self.H), f32), self._store(v5e),
+            v5e((self.R,), jnp.int32), v5e((self.R,), jnp.int32),
+            v5e((self.R,), jnp.bool_), v5e((), jnp.int32)))
+        # four value heads and their two key heads a grid step
+        assert gated_delta_rule._scan_heads(self.H, self.DV, 2) == 4
+
+    @pytest.mark.parametrize("rows", [640, 128], ids=["chunk", "decode_only"])
+    def test_ragged_attention_at_heads_of_256(self, v5e, rows):
+        """The three full layers' 16 query heads on 2 KV heads of 256 at the
+        cell's shapes (128 slots x 128 table entries): the first head wider
+        than 128."""
+        i32, hd, heads, mb = jnp.int32, 256, 16, 128
+
+        def attend(q, pk, pv, tables, qs, ql, kl, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer)
+        pool = v5e((3, self.R * mb, 32, 2 * hd))
+        n = _mosaic_calls(
+            attend, v5e((rows, heads, hd)), pool, pool,
+            v5e((self.R, mb), i32), v5e((self.R,), i32), v5e((self.R,), i32),
+            v5e((self.R,), i32), v5e((), i32))
+        assert n == 1
+
+    @pytest.mark.parametrize("rows", [640, 128], ids=["chunk", "decode_only"])
+    def test_sixty_four_held_experts_read_their_stacks_in_place(self, v5e,
+                                                                 rows):
+        """Three matrices an expert at 512, 64 of a router's 512 held: three
+        grouped matmuls, and none of the three stacks ``[3, 64, ...]`` (384
+        MiB each) is copied."""
+        hid, wid, exp, places = 2048, 512, 64, 3
+
+        def ffn(h, router, w_gate, w_up, w_down, layer):
+            return moe_ffn.moe_ffn(
+                h, router, w_gate, w_up, w_down, layer=layer, top_k=10,
+                renormalize=True, first_held=0)[0]
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(ffn).lower(
+                v5e((1, rows, hid)), v5e((hid, 512)),
+                v5e((places, exp, hid, wid)), v5e((places, exp, hid, wid)),
+                v5e((places, exp, wid, hid)), v5e((), jnp.int32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 3
+        assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
 class TestUnifiedStepLeavesThePoolInPlace:
     """The unified serving step, small, compiled for the described v5e: in
     the optimised HLO nothing but the in-place row scatter has a result as

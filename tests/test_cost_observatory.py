@@ -705,6 +705,16 @@ SERVING_DIR = (pathlib.Path(__file__).resolve().parent.parent
                / "paddle_tpu" / "serving")
 
 
+def _layer_body_source(src, fn_name):
+    """The source of a scanned layer body by its function's name;
+    ``_decoder_layer``'s is its own and ``_mixer_ffn_layer``'s, the FFN half
+    it shares with the hybrid models' linear layers."""
+    names = (fn_name, "_mixer_ffn_layer") if fn_name == "_decoder_layer" \
+        else (fn_name,)
+    return "".join(src.split(f"def {n}(")[1].split("\ndef ")[0]
+                   for n in names)
+
+
 class TestGuardDiscipline:
     """ISSUE 11 satellite: the ≤1%-disabled-overhead property holds
     only while every tracer/cost instrumentation site goes through the
@@ -866,9 +876,14 @@ class TestGuardDiscipline:
         # reintroduce a dequant site unnoticed
         # (the whole-prompt prefill and the packed-span forward share
         # ONE body, ``_decoder_layer``, and hand it ``_dq_layer``'s output)
+        # (since PR 54 ``_decoder_layer`` is the attention around
+        # ``_mixer_ffn_layer``, which holds the FFN half for both kinds of
+        # layer: the two are the one body)
+        assert "_mixer_ffn_layer(" in src.split(
+            "def _decoder_layer(")[1].split("\ndef ")[0]
         for fn_name in ("_decoder_layer", "_fused_decode_tick",
                         "_paged_suffix_prefill_impl"):
-            body = src.split(f"def {fn_name}(")[1].split("\ndef ")[0]
+            body = _layer_body_source(src, fn_name)
             for helper in ("_qkv_proj(", "_swiglu_proj(", "_o_proj("):
                 assert helper in body, (fn_name, helper)
         for fn_name in ("_packed_span_forward", "_fused_decode_tick",
@@ -919,7 +934,7 @@ class TestGuardDiscipline:
         # of the packed-span forward, which pass it their ``tp_reduce``)
         for fn_name in ("_decoder_layer", "_fused_decode_tick",
                         "_paged_suffix_prefill_impl"):
-            body = dec.split(f"def {fn_name}(")[1].split("\ndef ")[0]
+            body = _layer_body_source(dec, fn_name)
             assert body.count("tp_reduce(o)") == 1, fn_name
             assert body.count("tp_reduce(m)") == 1, fn_name
         for fn_name in ("_packed_span_forward", "_prefill_impl"):

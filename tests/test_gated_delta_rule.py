@@ -259,3 +259,78 @@ def test_chunk_scan_over_the_stored_layout(impl, widths):
     _close(np.asarray(o)[2:97], np.asarray(want_o)[2:97])
     _close(np.asarray(st)[0, [0, 2]], np.asarray(want_st)[0, [0, 2]])
     assert np.array_equal(np.asarray(st)[0, 1], np.asarray(state)[0, 1])
+
+
+# ---- grouped key heads (PR 54): q and k at fewer heads than v, value head h
+# on key head h // (H / Hk)
+GROUPED = {
+    # the published Qwen3-Next widths: 32 value heads on 16 key heads, a
+    # value head a lane tile
+    "published_32_on_16": (32, 16, 128, 128),
+    # four value heads a key head, several value heads inside one lane tile
+    "narrow_8_on_2": (8, 2, 8, 16),
+    # a head boundary inside a lane tile between two KEY heads' value heads
+    "split_tile_6_on_3": (6, 3, 16, 192),
+}
+
+
+def _grouped_inputs(seed, T, widths, slots):
+    nh, nk, dk, dv = widths
+    q, k, v, g, beta, state = _inputs(seed, T, widths=(nh, dk, dv),
+                                      slots=slots)
+    # key head j is the first of its value heads' draws: any 'nk' will do
+    return q[:, ::nh // nk], k[:, ::nh // nk], v, g, beta, state
+
+
+def _by_value_head(x, nh):
+    """The test's own statement of the grouping, not the kernels' helper."""
+    rep = nh // x.shape[1]
+    return jnp.stack([x[:, h // rep] for h in range(nh)], axis=1)
+
+
+@pytest.mark.parametrize("impl", ["reference", "jnp", "pallas", "update"])
+@pytest.mark.parametrize("widths", sorted(GROUPED))
+def test_grouped_key_heads_match_the_recurrence(impl, widths):
+    """All four implementations at 'Hk' key heads under 'H' value heads
+    against the float32 recurrence run on q and k repeated by hand; and a
+    grouping by ``h % Hk`` must NOT match (the fault the layout invites)."""
+    nh, nk, dk, dv = GROUPED[widths]
+    slots = 3
+    if impl == "update":
+        T = slots
+        start, length = np.arange(slots), np.array([1, 0, 1])
+        fresh = np.array([0, 0, 1], bool)
+    else:
+        T = 70
+        start, length = np.array([1, 0, 30]), np.array([29, 0, 38])
+        fresh = np.array([0, 0, 1], bool)
+    q, k, v, g, beta, state = _grouped_inputs(21, T, GROUPED[widths], slots)
+    seg = np.full(T, slots, np.int32)
+    first = np.zeros(T, bool)
+    for r in range(slots):
+        seg[start[r]:start[r] + length[r]] = r
+        if fresh[r] and length[r]:
+            first[start[r]] = True
+    if impl == "reference":
+        o, st = gdr.gdn_reference(q, k, v, g, beta, state, layer=1, seg=seg,
+                                  first=first)
+    elif impl == "update":
+        o, st = gdr.gdn_recurrent_update(q, k, v, g, beta, state, layer=1,
+                                         live=length > 0, fresh=fresh)
+    else:
+        fn = gdr.gdn_chunk_scan if impl == "pallas" \
+            else gdr.gdn_chunk_scan_jnp
+        o, st = fn(q, k, v, g, beta, state, layer=1, start=start,
+                   length=length, fresh=fresh)
+    want_o, want_st = _oracle(_by_value_head(q, nh), _by_value_head(k, nh),
+                              v, g, beta, state, 1, start, length, fresh)
+    rows = seg < slots
+    _close(np.asarray(o)[rows], want_o[rows])
+    _close(np.asarray(st)[1, [0, 2]], want_st[1, [0, 2]])
+    assert np.array_equal(np.asarray(st)[1, 1], np.asarray(state)[1, 1])
+    assert np.array_equal(np.asarray(st)[0], np.asarray(state)[0])
+    # value head h on key head h % Hk is another model
+    wrong = jnp.stack([q[:, h % nk] for h in range(nh)], axis=1)
+    bad_o, _ = _oracle(wrong, _by_value_head(k, nh), v, g, beta, state, 1,
+                       start, length, fresh)
+    assert float(np.abs(bad_o[rows] - want_o[rows]).max()) > 1e-2

@@ -168,7 +168,7 @@ def _dropped_token(h, *w, live, **kw):
 
 WRONG = {
     "wrong_expert": ("moe_ffn", _wrong_expert),
-    "no_qk_norm": ("_qk_norm", lambda q, k, q_w, k_w, eps: (q, k)),
+    "no_qk_norm": ("_qk_norm", lambda q, k, *_: (q, k)),
     "renormalised_weights": ("moe_ffn", _renormalised),
     "dropped_token": ("moe_ffn", _dropped_token),
 }
