@@ -47,12 +47,15 @@ class DriverClock:
     injectable clock), ``cpu`` any zero-arg nanoseconds callable of the
     calling thread's CPU time. ``stamps_spans``: whether the tracer whose
     spans open and close at the marks reads ``wall`` too, so that a mark's
-    reading may stand for the span's own."""
+    reading may stand for the span's own. ``on_mark``: called at every mark
+    before the clocks are read, so that what it does is charged to the
+    phase being left (the gateway hands a step's stream events over there)."""
 
-    def __init__(self, wall=None, cpu=None, stamps_spans=True):
+    def __init__(self, wall=None, cpu=None, stamps_spans=True, on_mark=None):
         self.wall = wall if wall is not None else time.perf_counter
         self.cpu = cpu if cpu is not None else time.thread_time_ns
         self.stamps_spans = bool(stamps_spans)
+        self.on_mark = on_mark
         self.phase = None           # None until the first mark
         self.wall_s = dict.fromkeys(PHASES, 0.0)
         self.cpu_ns = dict.fromkeys(PHASES, 0)
@@ -66,6 +69,8 @@ class DriverClock:
         """Leave the current phase for ``phase``; returns the wall reading
         for a span opened or closed at this boundary to carry (None where
         the tracer is on a clock of its own and reads that)."""
+        if self.on_mark is not None:
+            self.on_mark()
         t, c = self.wall(), self.cpu()
         cur = self.phase
         if cur is not None:

@@ -7,9 +7,12 @@ driver thread owns the engine and pumps ``step()``; every other thread
 talks to the gateway through a thread-safe front door —
 
 - :meth:`ServingGateway.submit` enqueues a request from any thread and
-  hands back a :class:`TokenStream`, a per-token iterator fed by the
-  engine's ``on_token`` callback the moment each token reaches the
-  host;
+  hands back a :class:`TokenStream`, fed by the engine's ``on_token``
+  callback the moment each token reaches the host: a per-token iterator
+  for an in-process consumer, or, once a streaming HTTP response has
+  attached its sink (:meth:`TokenStream.attach`), events that the driver
+  gathers into one batch a step and hands to the writer of the SSE
+  sockets (:meth:`ServingGateway._hand_over`, ``sse.py``);
 - :meth:`TokenStream.cancel` flags a request from any thread; the
   driver applies it between steps via ``engine.cancel`` — the KV slot
   frees mid-decode and the ragged decode kernel skips it from the next
@@ -162,6 +165,11 @@ class TokenStream:
         self.first_token_time = None
         self.finish_time = None
         self._events = queue.SimpleQueue()  # ("token", id) | ("finish", r) | ("error", msg)
+        # a streaming HTTP response's sink (``sse.py``): once attached,
+        # events go to it through the driver's batch and no longer to the
+        # queue. The lock orders an attachment against the driver's pushes.
+        self._sink = None
+        self._sink_lock = threading.Lock()
         self._collected = []
         self._cancel = False
         self._waiting = True       # still counted against max_queue
@@ -206,19 +214,43 @@ class TokenStream:
         self._cancel = True
         self.gateway._wake.set()
 
-    # --------------------------------------------------------- driver side
-    def _push_token(self, token):
-        self._events.put(("token", int(token)))
+    def attach(self, sink):
+        """Send this stream's events to ``sink`` from now on, in place of
+        its iterator: ``sink.writer.write([(sink, event), ...])`` takes
+        first, here, what the driver had queued, then each of the
+        driver's batches takes what follows. Any thread; the one consumer
+        the stream has."""
+        with self._sink_lock:
+            queued = []
+            while not self._events.empty():
+                queued.append((sink, self._events.get_nowait()))
+            if queued:
+                sink.writer.write(queued)
+            self._sink = sink
 
-    def _push_finish(self, reason):
+    # --------------------------------------------------------- driver side
+    def _push(self, event, batch):
+        """Onto the queue an iterator reads, or with the stream's sink into
+        ``batch``, which the driver hands over (``_hand_over``)."""
+        with self._sink_lock:
+            sink = self._sink
+            if sink is None:
+                self._events.put(event)
+                return
+        batch.append((sink, event))
+
+    def _push_token(self, token, batch):
+        self._push(("token", int(token)), batch)
+
+    def _push_finish(self, reason, batch):
         self.finish_time = time.monotonic()
         self.finish_reason = reason
-        self._events.put(("finish", reason))
+        self._push(("finish", reason), batch)
 
-    def _push_error(self, msg):
+    def _push_error(self, msg, batch):
         self.finish_time = time.monotonic()
         self.finish_reason = "error"
-        self._events.put(("error", str(msg)))
+        self._push(("error", str(msg)), batch)
 
 
 class ServingGateway:
@@ -308,6 +340,10 @@ class ServingGateway:
         # engine mutation (restore/evict) happens only on its thread.
         self._migrate_in = collections.deque()
         self._migrate_out = collections.deque()
+        # the events the driver has pushed to streaming responses since
+        # its last hand-over, ``[(sink, event)]`` in push order: the driver
+        # thread's own (:meth:`_hand_over`)
+        self._batch = []
         # ------------------------------------------------ tracing state
         # (README "Tracing & debugging") the gateway OWNS the tracer so
         # one timeline survives engine rebuilds; it is installed on
@@ -345,7 +381,8 @@ class ServingGateway:
         # engine); a tracer injected with a clock of its own reads that
         self.driver_clock = DriverClock(
             wall=self._clock,
-            stamps_spans=self.tracer.clock is self._clock)
+            stamps_spans=self.tracer.clock is self._clock,
+            on_mark=self._hand_over)
         # the collector's pauses, by generation: counted while the driver
         # thread runs (:meth:`_run` installs and removes the callback),
         # and a ``gc`` span each while the tracer records on a real clock
@@ -1031,7 +1068,7 @@ class ServingGateway:
                         and ttft > pclass.ttft_slo_s):
                     self._m_slo_miss.inc(**{"class": pclass.name,
                                             "slo": "ttft"})
-        stream._push_token(token)
+        stream._push_token(token, self._batch)
 
     def _finish_teardown(self, seq):
         """Bookkeeping shared by every terminal path — engine finishes
@@ -1069,7 +1106,7 @@ class ServingGateway:
     def _on_finish(self, seq):
         stream = self._finish_teardown(seq)
         if stream is not None:
-            stream._push_finish(seq.finish_reason)
+            stream._push_finish(seq.finish_reason, self._batch)
 
     def _on_policy_preempt(self, seq):
         """Engine hook: an SLO-urgent request displaced ``seq``. Counts
@@ -1086,6 +1123,20 @@ class ServingGateway:
         """Engine hook: one step program was fenced and booked."""
         self._m_step_dur.observe(duration_s)
 
+    def _hand_over(self):
+        """Give the writer of the SSE sockets what the driver has pushed to
+        streaming responses since the last call, in one batch. The driver
+        clock calls this at every mark, before it reads its clocks: a
+        step's tokens and finishes leave at the end of the ``host-accept``
+        that saw them and are charged to it, a refusal, a cancellation or
+        a conviction between two steps leaves at the loop's next mark, and
+        no event waits through a ``device-wait`` or an ``idle-wait``."""
+        batch = self._batch
+        if batch:
+            self._batch = []
+            # one HTTP server's sinks share its one writer
+            batch[0][0].writer.write(batch)
+
     # ------------------------------------------------------- driver thread
     def _admit_intake(self):
         while True:
@@ -1096,13 +1147,13 @@ class ServingGateway:
             if stream._cancel:
                 self._leave_waiting_room(stream)
                 self._m_finished.inc(reason="cancelled")
-                stream._push_finish("cancelled")
+                stream._push_finish("cancelled", self._batch)
                 continue
             try:
                 seq = self.engine.submit(stream.request)
             except Exception as e:  # validated at submit(); belt+braces
                 self._leave_waiting_room(stream)
-                stream._push_error(e)
+                stream._push_error(e, self._batch)
                 continue
             stream.seq = seq
             self._live[seq.request_id] = stream
@@ -1126,14 +1177,14 @@ class ServingGateway:
                     seq.finish_reason = "cancelled"
                 self._leave_waiting_room(stream)
                 self._m_finished.inc(reason="cancelled")
-                stream._push_finish("cancelled")
+                stream._push_finish("cancelled", self._batch)
                 continue
             if seq is None:
                 try:
                     seq = self.engine.submit(stream.request)
                 except Exception as e:
                     self._leave_waiting_room(stream)
-                    stream._push_error(e)
+                    stream._push_error(e, self._batch)
                     continue
             elif seq.done:
                 # finished in flight between gateways (shouldn't
@@ -1141,7 +1192,7 @@ class ServingGateway:
                 # but a terminal event beats a stranded consumer)
                 self._leave_waiting_room(stream)
                 self._m_finished.inc(reason=seq.finish_reason)
-                stream._push_finish(seq.finish_reason)
+                stream._push_finish(seq.finish_reason, self._batch)
                 continue
             elif (seq.prompt_len + int(seq.request.max_new_tokens)
                     > self.engine.max_seq_len):
@@ -1155,7 +1206,7 @@ class ServingGateway:
                     f"migrated sequence needs "
                     f"{seq.prompt_len + int(seq.request.max_new_tokens)}"
                     f" KV rows; this engine holds "
-                    f"{self.engine.max_seq_len}")
+                    f"{self.engine.max_seq_len}", self._batch)
                 continue
             elif self.engine.restore(seq):
                 self._m_recovered.inc()
@@ -1214,6 +1265,9 @@ class ServingGateway:
             if stream._waiting:
                 with self._lock:
                     self._backlog -= 1
+            # the sibling's driver writes to the stream's sink from here
+            # on: nothing of the stream may still lie in this batch
+            self._hand_over()
             try:
                 handoff(stream, seq)
             except Exception:
@@ -1326,7 +1380,8 @@ class ServingGateway:
                 self._migrate_in.clear()
             for s in stranded:
                 if id(s) not in handed:
-                    s._push_error(f"engine driver died: {e!r}")
+                    s._push_error(f"engine driver died: {e!r}", self._batch)
+            self._hand_over()       # no mark follows: the thread ends here
             raise
         finally:
             self.gc_watch.remove()
@@ -1500,6 +1555,7 @@ class ServingGateway:
                     pairs.append((st, sq))
                     seen.add(id(st))
             if pairs:
+                self._hand_over()   # as before a migration's handoff
                 res = self.on_fatal(self, pairs)
                 if res is True:
                     return frozenset(id(st) for st, _ in pairs)
@@ -1658,7 +1714,7 @@ class ServingGateway:
         if stream is not None:
             stream._push_error(
                 "poisoned request: engine fault recurred pinned to this "
-                "request; bystanders recovered")
+                "request; bystanders recovered", self._batch)
 
     # ----------------------------------------------------- trace capture
     def _arm_capture(self):

@@ -3,7 +3,9 @@
 Stdlib only (``http.server`` on a thread-per-connection
 ``ThreadingHTTPServer``) — no new dependencies; the heavy lifting is
 the gateway's single engine-driver thread, so handler threads only
-parse JSON, block on token queues, and write bytes.
+parse JSON and wait: a blocking completion on its stream's queue, a
+streaming one parked while the server's one stream writer (``sse.py``),
+fed by the driver once a step, writes its events.
 
 Endpoints:
 
@@ -44,14 +46,13 @@ Endpoints:
 Load shedding maps gateway signals onto status codes: full waiting
 room → 429 (with Retry-After), draining gateway → 503, validation →
 400. A client that disconnects mid-SSE cancels its request — the
-broken-pipe write error reaches ``TokenStream.cancel()``, the engine
-frees the KV slot at the next step boundary, and the remaining
-streams are untouched.
+writer's send finds the broken pipe and calls ``TokenStream.cancel()``,
+the engine frees the KV slot at the next step boundary, and the
+remaining streams are untouched.
 """
 from __future__ import annotations
 
 import json
-import socket
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -60,6 +61,7 @@ from urllib.parse import parse_qs
 from ..request import GenerationRequest
 from .gateway import (GatewayClosedError, QueueFullError, ServingGateway,
                       TraceBusyError)
+from .sse import StreamWriter
 
 SSE_HEADERS = (("Content-Type", "text/event-stream"),
                ("Cache-Control", "no-cache"),
@@ -418,51 +420,17 @@ class _Handler(BaseHTTPRequestHandler):
             **kw)
 
     def _stream_response(self, stream, prompt_tokens):
+        """The headers, then the stream and the socket go to the server's
+        one writer (``sse.py``), which the engine-driver thread feeds once
+        a step; this thread parks until the last frame is written or the
+        client is gone, and ``http.server`` closes the connection."""
         self.send_response(200)
         for k, v in SSE_HEADERS:
             self.send_header(k, v)
         self.end_headers()
-
-        def event(obj):
-            data = obj if isinstance(obj, str) else json.dumps(obj)
-            self.wfile.write(f"data: {data}\n\n".encode())
-            self.wfile.flush()
-
-        try:
-            for token in stream:
-                event({"id": stream.id, "object": "text_completion.chunk",
-                       "model": self.server.model_name,
-                       "choices": [{"index": 0, "token_id": int(token),
-                                    "finish_reason": None}]})
-            event({"id": stream.id, "object": "text_completion.chunk",
-                   "model": self.server.model_name,
-                   "choices": [{"index": 0, "token_id": None,
-                                "finish_reason": stream.finish_reason}],
-                   "usage": {"prompt_tokens": prompt_tokens,
-                             "completion_tokens": len(stream.tokens()),
-                             "total_tokens":
-                                 prompt_tokens + len(stream.tokens())}})
-            event("[DONE]")
-        except (BrokenPipeError, ConnectionResetError, socket.timeout):
-            # client went away mid-stream: free the KV slot, leave the
-            # rest of the batch untouched
-            stream.cancel()
-        except RuntimeError as e:
-            # engine-side failure: a FINAL terminal error event (with
-            # finish_reason="error") so the client sees a proper end of
-            # stream, never a silently dropped connection
-            try:
-                event({"id": stream.id, "object": "text_completion.chunk",
-                       "model": self.server.model_name,
-                       "choices": [{"index": 0, "token_id": None,
-                                    "finish_reason": "error"}],
-                       "error": {"message": str(e),
-                                 "type": "server_error"}})
-                event("[DONE]")
-            except OSError:
-                pass
-        finally:
-            self.close_connection = True
+        self.close_connection = True
+        self.server.stream_writer.serve(
+            stream, self.connection, self.server.model_name, prompt_tokens)
 
 
 class ServingHTTPServer:
@@ -486,6 +454,8 @@ class ServingHTTPServer:
         self._httpd.fleet = fleet
         self._httpd.model_name = model_name
         self._httpd.log_fn = log_fn
+        self.stream_writer = self._httpd.stream_writer = StreamWriter(
+            (fleet if fleet is not None else gateway).registry)
         self._thread = threading.Thread(
             target=self._httpd.serve_forever, kwargs={"poll_interval": 0.05},
             name="http-accept", daemon=True)
@@ -511,6 +481,9 @@ class ServingHTTPServer:
         drain (or cancel) in-flight work, then stop the accept loop."""
         front = self.fleet if self.fleet is not None else self.gateway
         front.shutdown(drain=drain, timeout=timeout)
+        # the drivers have written every stream's last frame by now, but
+        # for a reader that is behind: its rest is the writer's to drain
+        self.stream_writer.close(timeout)
         self._httpd.shutdown()
         self._httpd.server_close()
         if self._thread.is_alive():
