@@ -158,6 +158,25 @@ FULL = dict(
     ragged_window={"window 512 40/10/128": (40, 10, 128, 256, [
         (512, 3584)] + [(1, 1500 + 290 * i + (i * 37) % 29)
                         for i in range(15)], 512)},
+    # MiMo-V2-Flash's two kinds of layer: 64 query heads, keys 192 wide and
+    # values 128 (a KV head's key window starts at half a lane tile for every
+    # odd head); 4 KV heads over the pool, 8 over a ring under a window of
+    # 128 with a sink a head: (heads, KV heads, key width, value width, table
+    # entries, rows, window, packed rows): a 512-token chunk 8 k into its
+    # prompt behind 31 decode rows at 1 k - 28 k, and 32 decode rows alone
+    ragged_widths={
+        "mimo full chunk 64/4/192|128": (64, 4, 192, 128, 1024, [
+            (1, 1100 + 870 * i + (i * 37) % 29) for i in range(31)]
+            + [(512, 8704)], None, 544),
+        "mimo full 32 rows 64/4/192|128": (64, 4, 192, 128, 1024, [
+            (1, 1100 + 870 * i + (i * 37) % 29) for i in range(32)], None,
+            32),
+        "mimo window chunk 64/8/192|128": (64, 8, 192, 128, 1024, [
+            (1, 1100 + 870 * i + (i * 37) % 29) for i in range(31)]
+            + [(512, 8704)], 128, 544),
+        "mimo window 32 rows 64/8/192|128": (64, 8, 192, 128, 1024, [
+            (1, 100 + 870 * i + (i * 37) % 29) for i in range(32)], 128,
+            32)},
     # the same geometry's pipeline across pairs (6 pages = 192 keys a
     # group): decode rows that walk one group and an odd number of groups
     # (5, or 3 under the window) in turn, so every pair's first group is
@@ -201,6 +220,11 @@ REHEARSAL = dict(
     ssd=dict(widths=(4, 8, 2, 16), slots=6, packed=150),
     ragged_window={"window 40 4/2/32": (4, 2, 32, 8, [
         (48, 200), (1, 150), (1, 33), (0, 0)], 40)},
+    ragged_widths={
+        "full chunk 8/2/24|16": (8, 2, 24, 16, 8, [
+            (1, 150), (48, 200), (1, 33), (0, 0)], None, 62),
+        "window 6 rows 8/4/24|16": (8, 4, 24, 16, 8, [
+            (1, 20 + 40 * i) for i in range(6)], 16, 6)},
     ragged_handover={"hand-over 4/2/32": (4, 2, 32, 24, [
         (1, 40 + 9 * i) if i % 2 else (1, 600 + 10 * i)
         for i in range(6)], 300)},
@@ -637,13 +661,14 @@ def _scattered_tables(rng, kvlen, mb, bs):
     return tables, live, nb
 
 
-def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12):
+def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12,
+                          vd=None):
     """The ragged kernel's arguments for ``rows`` of (query span, kv length
     after this step; span 0 is a dead row) packed back to back with ``pad``
     rows in no span behind them: bf16, tables of ``mb`` entries scattered
     over a pool that is NaN wherever no live row may read (stale rows of a
     mapped block, unmapped blocks), so a kernel that reads one returns
-    NaN."""
+    NaN. ``vd``: a value's width where it is not a key's."""
     import jax.numpy as jnp
     import numpy as np
     qlen = np.array([q for q, _ in rows], np.int32)
@@ -654,7 +679,7 @@ def _poisoned_ragged_case(rng, rows, nh, nkv, hd, *, mb, bs=32, pad=12):
     qstart = np.concatenate([[0], np.cumsum(qlen)[:-1]]).astype(np.int32)
     q = rng.randn(int(qlen.sum()) + pad, nh, hd).astype(np.float32)
     return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool[0], jnp.bfloat16),
-            jnp.asarray(pool[1], jnp.bfloat16), jnp.asarray(tables),
+            jnp.asarray(pool[1][..., :vd], jnp.bfloat16), jnp.asarray(tables),
             jnp.asarray(qstart), jnp.asarray(qlen), jnp.asarray(kvlen))
 
 
@@ -680,23 +705,25 @@ def phase_kernels(rehearse):
         with jax.default_matmul_precision("highest"):
             return jax.jit(fn)(*args)
 
-    def ragged_agrees(name, args, piece=64, window=None):
+    def ragged_agrees(name, args, piece=64, window=None, sink=None):
         # the oracle gathers every token's whole table: row by row, `piece`
         # span tokens a call (tokens lo .. lo + n of a span are a span of n
         # whose kv ends where theirs does), or a cell's step is tens of GB
         q, pk, pv, tables, qstart, qlen, kvlen = args
-        want = np.zeros(q.shape, np.float32)
+        want = np.zeros(q.shape[:2] + (pv.shape[-1],), np.float32)
         one = jnp.zeros(1, jnp.int32)
+        kernel_kw = dict(window=window, **({} if sink is None
+                                           else {"sink": sink}))
         for r, (at, n_r, end) in enumerate(zip(*map(np.asarray, args[4:]))):
             for lo in range(0, n_r, piece):
                 n = min(piece, n_r - lo)
                 want[at + lo:at + lo + n] = reference(
                     functools.partial(ragged_attention_reference,
-                                      window=window),
+                                      **kernel_kw),
                     q[at + lo:at + lo + n], pk,
                     pv, tables[r:r + 1], one, one + n, one + end - n_r + lo + n)
         got = jax.jit(functools.partial(ragged_paged_attention_pallas,
-                                        window=window))(*args)
+                                        **kernel_kw))(*args)
         _agree(name, got, want, TOL_FWD, errors)
         check(not np.asarray(got[int(np.asarray(qlen).sum()):],
                              np.float32).any(),
@@ -725,6 +752,22 @@ def phase_kernels(rehearse):
         ragged_agrees(f"ragged {tag}", _poisoned_ragged_case(
             np.random.RandomState(window), rows, nh, nkv, hd, mb=mb),
             window=window)
+
+    # ---- ... and with keys wider than values, under a window with a sink a
+    # head and without either (MiMo-V2-Flash's window and full layers) ----
+    for tag, (nh, nkv, hd, vd, mb, rows, window, packed) in \
+            size["ragged_widths"].items():
+        live = sum(q for q, _ in rows)
+        rng = np.random.RandomState(packed + nkv)
+        # (the oracle's float32 [piece, the table's keys, heads, key width]
+        # is 1.6 GB a token at 1,024 entries of 32 x 64 heads x 192: one
+        # token a call there; 64 a call, the default, asked for 103 GB)
+        ragged_agrees(
+            f"ragged {tag}", _poisoned_ragged_case(
+                rng, rows, nh, nkv, hd, mb=mb, pad=packed - live, vd=vd),
+            piece=max(1, min(64, (1 << 31) // (mb * 32 * nh * hd * 4))),
+            window=window, sink=None if window is None else jnp.asarray(
+                4.0 + rng.randn(nh), jnp.float32))
 
     # ---- ... and where every pair's first group of pool pages is fetched
     # while the pair before it still computes, with and without the window:

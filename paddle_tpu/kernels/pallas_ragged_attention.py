@@ -86,11 +86,19 @@ are ``pallas_paged_decode.py``'s):
   cache rounded up to a group a pair, and no layer of the pool is cut out
   or re-laid-out for the call.
 - **Each KV head's keys by that head's queries only**: head ``k``'s keys
-  and values are lanes ``k * D .. (k + 1) * D`` of the fetched group, a
-  whole-lane-tile window at ``D`` 128. Per head and update: ``s_k = q_k
+  are lanes ``k * D .. (k + 1) * D`` of the fetched group's K side and its
+  values lanes ``k * Dv .. (k + 1) * Dv`` of its V side, a whole-lane-tile
+  window at 128; **a key and a value need not be as wide** (``Dv`` is the V
+  side's row over the KV heads: MiMo-V2-Flash's 192 | 128, where an odd
+  head's key window starts at half a lane tile, which Mosaic cuts; the two
+  sides are two buffers of their own row). Per head and update: ``s_k = q_k
   [rows, D] x K_k^T``, one mask (the same for every head), the ``m / l``
-  update, ``acc_k [rows, D] += p_k x V_k``; the float32 accumulator is
-  ``[Hkv, rows, D]``. **The update is the general walk's own**
+  update, ``acc_k [rows, Dv] += p_k x V_k``; the float32 accumulator and the
+  output are ``[Hkv, rows, Dv]``. **A sink** (``sink``, a logit a query head)
+  is one more column of a head's softmax with no value: the online softmax
+  starts at it (``m`` the logit, ``l`` 1) instead of at nothing, in both
+  walks, under a window or not; a call without one is the program it was.
+  **The update is the general walk's own**
   (``_span_update``): a plane's hundreds of rows made its cost a row's and
   not a FLOP's, so a row's ``m`` lies on every lane of its tile and is never
   narrowed to one (no lane broadcast on the XLUs: they bound the schedule,
@@ -107,7 +115,7 @@ are ``pallas_paged_decode.py``'s):
 - **Everything the kernel cuts is whole tokens in whole 16-row tiles, at
   any group width.** The query block is sized by the state it carries
   (``query_block_rows``): as many whole row tiles of TOKENS as the float32
-  accumulator of all planes holds under 2 MiB, so ``tokens * G`` rows of a
+  accumulator of all planes (a VALUE wide) holds under 2 MiB, so ``tokens * G`` rows of a
   plane are whole row tiles whatever ``G`` is. 128 tokens at Mistral's 32 /
   8 / 128, Olmo-Hybrid's 30 / 30 / 128 and Nemotron-3-Nano's 32 / 2 / 128,
   256 at OLMoE's 16 / 16 / 128, 192 at Jamba2-3B's 20 / 1 / 128: a
@@ -129,9 +137,9 @@ are ``pallas_paged_decode.py``'s):
   straddles two; 16 rows at a group of 1 to 16 that divides 16, 80 = 4
   tokens at 20, 48 at 3; a masked sum over its rows), lays them
   block-diagonal ``[H, KD]`` in a VMEM scratch, and walks the groups with
-  one ``[H, KD] x [KD, keys]`` and one ``[H, keys] x [keys, KD]`` product an
-  update; each head's own ``D`` lanes go back into the token's rows of its
-  plane. The wide tile never leaves VMEM and its zeros are laid once a
+  one ``[H, KD] x [KD, keys]`` and one ``[H, keys] x [keys, VD]`` product an
+  update (``VD`` the V side's row); each head's own ``Dv`` lanes go back into
+  the token's rows of its plane. The wide tile never leaves VMEM and its zeros are laid once a
   call. A quantized pool's group is upcast for it head window by head
   window, each with its own scale. Only where a query block is no whole
   number of such tiles (a test's block of 5 or 17 tokens) does every span
@@ -168,11 +176,19 @@ NEG_INF = -1e30
 def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                    ql_ref, kl_ref, tbl_ref, layer_ref, *refs, scale, block_k,
                    pages, tq, g, num_blocks, table_entries, quantized=False,
-                   window=None):
+                   window=None, sink=False):
     # positional ref layout follows the pallas_call spec lists: inputs
-    # (q, k, v[, k_scale, v_scale]), then the output, then scratch (one
+    # (q, k, v[, k_scale, v_scale][, the sink by plane row, by head]), then
+    # the output, then scratch (one
     # two-slot VMEM buffer per pool-side input, the DMA semaphores, m/l/acc
     # and, where the call has the one-token walk, its wide query and state)
+    sink_rows = sink_heads = None
+    if sink:
+        # the sink's logit, a row of a plane and a head of the one-token
+        # walk's wide query, on every lane (``_sink_planes``)
+        n = 5 if quantized else 3
+        sink_rows, sink_heads = refs[n:n + 2]
+        refs = refs[:n] + refs[n + 2:]
     if quantized:
         (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
          vs_buf, sems, m_scr, l_scr, acc_scr, *one_token) = refs
@@ -188,6 +204,7 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
     if tile:
         qw_scr, m1_scr, l1_scr, acc1_scr = one_token
     hkv, rows, d = q_ref.shape      # KV heads, a plane's rows a block, D
+    dv = o_ref.shape[2]             # a value's width (``d`` is a key's)
     w = pl.program_id(0)            # one work-list entry: (query block, row)
     qi = wq_ref[w]
     r = wr_ref[w]
@@ -255,9 +272,9 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                                                  sems.at[i, slot, j]))
         return out
 
-    def _head_rows(buf, slot, scales, k):
-        # head k's [group, D] window of a fetched group: its D lanes of
-        # every row. A quantized pool's values are upcast HERE, right after
+    def _head_rows(buf, slot, scales, k, d):
+        # head k's [group, d] window of a fetched group: its d lanes of
+        # every row (a key's width on the K side, a value's on the V side). A quantized pool's values are upcast HERE, right after
         # the table-indirect DMA moved the narrow dtype (the HBM win), and
         # take the head's scale, column k of the plane: int8 carries one a
         # (pool row, head), the pages' planes lying concatenated; fp8 one a
@@ -385,9 +402,21 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                 preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    def _reset(m_ref, l_ref, acc_ref):
-        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+    def _reset(m_ref, l_ref, acc_ref, m0=None, by_lane=False):
+        # a softmax with a sink (``m0``, the sink's logit a row on every
+        # lane) starts where one update with that column alone would leave
+        # it: the maximum at the logit, the sum at 1 (on lane 0 where ``l``
+        # lies by lane, ``_span_update``), no value
+        if m0 is None:
+            m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        else:
+            m_ref[:] = m0
+            one = jnp.ones(l_ref.shape, jnp.float32)
+            l_ref[:] = one if not by_lane else jnp.where(
+                jax.lax.broadcasted_iota(
+                    jnp.int32, l_ref.shape, len(l_ref.shape) - 1) == 0,
+                one, 0.0)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     @pl.when((nkb > 0) & jnp.logical_not(alone))
@@ -438,7 +467,10 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
         for at, (live, _) in zip(ats, lives):
             @where_live(live)
             def _reset_chunk():
-                _reset(*(x.at[:, at] if tall else x for x in state))
+                _reset(*(x.at[:, at] if tall else x for x in state),
+                       **({} if sink_rows is None else {
+                           "m0": sink_rows[:, at] if tall else sink_rows[:],
+                           "by_lane": True}))
         parts = [(at, *rows_mask(*c), live, last)
                  for at, c, (live, last) in zip(ats, chunks, lives)]
 
@@ -455,11 +487,11 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                     for k in range(hkv):
                         s = jax.lax.dot_general(
                             q_ref[k, at, :],
-                            _head_rows(k_buf, slot, ks_buf, k),
+                            _head_rows(k_buf, slot, ks_buf, k, d),
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
                         _span_update(
-                            s, valid, _head_rows(v_buf, slot, vs_buf, k),
+                            s, valid, _head_rows(v_buf, slot, vs_buf, k, dv),
                             m_scr.at[k, at], l_scr.at[k, at],
                             acc_scr.at[k, at])
 
@@ -505,29 +537,31 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
                 qw_scr[pl.ds(k * g + j, 1), k * d:(k + 1) * d] = jnp.sum(
                     jnp.where(trow == first + j, t, 0.0), axis=0,
                     keepdims=True)
-        _reset(m1_scr, l1_scr, acc1_scr)
+        _reset(m1_scr, l1_scr, acc1_scr,
+               **({} if sink_heads is None else {"m0": sink_heads[:]}))
 
-        def pool_rows(buf, scales, slot):
+        def pool_rows(buf, scales, slot, d):
             # the whole fetched group [group, KD]; a quantized pool's head
             # windows upcast and scaled one by one (``_head_rows``) and laid
             # side by side again
             if not quantized:
                 return buf[slot]
             return jnp.concatenate(
-                [_head_rows(buf, slot, scales, k) for k in range(hkv)],
+                [_head_rows(buf, slot, scales, k, d) for k in range(hkv)],
                 axis=1)
 
         def update(gi, slot):
             qw = qw_scr[:] if quantized else qw_scr[:].astype(q_ref.dtype)
             s = jax.lax.dot_general(
-                qw, pool_rows(k_buf, ks_buf, slot), (((1,), (1,)), ((), ())),
+                qw, pool_rows(k_buf, ks_buf, slot, d),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             key = gi * group + jax.lax.broadcasted_iota(
                 jnp.int32, (hp, group), 1)
             valid = key < kvlen
             if window is not None:
                 valid = valid & (key >= kvlen - window)
-            _softmax_update(s, valid, pool_rows(v_buf, vs_buf, slot),
+            _softmax_update(s, valid, pool_rows(v_buf, vs_buf, slot, dv),
                             m1_scr, l1_scr, acc1_scr)
 
         _groups(update)
@@ -539,7 +573,8 @@ def _ragged_kernel(wq_ref, wr_ref, wf_ref, wn_ref, wlo_ref, ws_ref, qs_ref,
             for j in range(g):
                 new = jnp.where(
                     trow == first + j,
-                    acc1_scr[pl.ds(k * g + j, 1), k * d:(k + 1) * d], new)
+                    acc1_scr[pl.ds(k * g + j, 1), k * dv:(k + 1) * dv],
+                    new)
             o_ref[k, at, :] = new.astype(o_ref.dtype)
 
 
@@ -617,16 +652,18 @@ _ACC_BYTES = 2 << 20
 _VMEM_BYTES = 48 << 20
 
 
-def pages_per_update(pool_dtype, block_size, kd, table_entries):
+def pages_per_update(pool_dtype, block_size, kd, table_entries, vd=None):
     """Table entries one online-softmax update fetches and computes on
     together, from what a call can observe: ``_GROUP_KEYS`` keys' worth of
     pool blocks, fewer where a row is wide but no fewer than a lane tile of
     keys (8 blocks of 32 for Mistral's ``KD`` 1024 in bf16, 4 for OLMoE's
-    2048 and Olmo-Hybrid's 3840), never more than the table holds. A
-    one-byte pool counts at four bytes: a head's window is upcast to float32
-    in VMEM on its way into the MXU."""
+    2048 and Olmo-Hybrid's 3840), never more than the table holds. A row is
+    its K side and its V side (``vd``; None: as wide as ``kd``), two slots
+    each. A one-byte pool counts at four bytes: a head's window is upcast to
+    float32 in VMEM on its way into the MXU."""
     itemsize = jnp.dtype(pool_dtype).itemsize
-    row = 4 * int(kd) * (4 if itemsize == 1 else itemsize)
+    row = 2 * (int(kd) + int(kd if vd is None else vd)) \
+        * (4 if itemsize == 1 else itemsize)
     keys = max(_LANE_KEYS, min(_GROUP_KEYS, _GROUP_BYTES // row))
     return max(1, min(keys // int(block_size), int(table_entries)))
 
@@ -635,7 +672,8 @@ def query_block_rows(heads, head_dim):
     """(Token, head) rows of a query block (before ``_query_block`` fits it
     to the packed buffer), from the state the block carries: as many tokens
     as the float32 accumulator ``[Hkv, tokens * G, D]`` holds under
-    ``_ACC_BYTES`` (a row of it is no narrower than a lane tile), in whole
+    ``_ACC_BYTES`` (``head_dim`` is a VALUE's width where keys and values
+    differ; a row of it is no narrower than a lane tile), in whole
     row tiles of tokens, so in whole row tiles of whole tokens at any group.
     128 tokens at Mistral's 32 / 8 / 128 (a 512-token chunk walks its prefix
     4 or 5 times) and Olmo-Hybrid's 30 / 30 / 128, 256 at OLMoE's 16 / 16 /
@@ -762,10 +800,14 @@ def _work_list(qstart, qlen, kvlen, *, nq, tokens_per_block, block_size,
 
 def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
                  scale, g, block_q, pages, interpret, scales=None,
-                 window=None):
+                 window=None, sink=None):
     """q_hm: [Hkv, T * g, D] head-major planes (row ``t * g + j`` of plane
     ``k`` is head ``k * g + j`` of token ``t``);
-    pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole;
+    pool_*: the stored pool ``[L, num_blocks, bs, KD]``, left in HBM whole
+    (the V side ``Hkv * Dv`` wide, a value's width its own: the accumulator
+    and the output ``[Hkv, T * g, Dv]`` are then a value's);
+    sink: None, or ``[Hkv * g]`` float32, a head's sink logit: one more
+    column of its softmax that has no value (``_sink_planes``);
     layer: [1] int32, the layer whose blocks this call reads;
     tables: [R, max_blocks] int32;
     scales: None, or ``(k_scale, v_scale)`` fp32 planes for a
@@ -780,7 +822,8 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     row) pair, and inside it a loop over exactly the pair's KV blocks,
     fetched at ``(layer, table entry)``, ``pages`` of them an iteration."""
     hkv, TG, D = q_hm.shape
-    KD = pool_k.shape[-1]
+    KD, VD = pool_k.shape[-1], pool_v.shape[-1]
+    Dv = VD // hkv
     num_blocks, bs = pool_k.shape[1], pool_k.shape[2]
     R, nk = tables.shape
     tokens = block_q // (hkv * g)   # a query block's tokens
@@ -799,7 +842,8 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     kernel = functools.partial(_ragged_kernel, scale=scale, block_k=bs,
                                pages=pages, tq=block_q, g=g,
                                num_blocks=num_blocks, table_entries=nk,
-                               quantized=quantized, window=window)
+                               quantized=quantized, window=window,
+                               **({} if sink is None else {"sink": True}))
 
     def _q_index(w, wq, *_):
         return (0, wq[w], 0)
@@ -808,7 +852,7 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
     in_specs = [pl.BlockSpec((hkv, rows, D), _q_index), in_pool, in_pool]
     args = [*work, qstart, qlen, kvlen, tables, layer, q_hm, pool_k, pool_v]
     bufs = [pltpu.VMEM((2, pages * bs, KD), pool_k.dtype),
-            pltpu.VMEM((2, pages * bs, KD), pool_v.dtype)]
+            pltpu.VMEM((2, pages * bs, VD), pool_v.dtype)]
     if quantized:
         # per-row int8 planes [nb, bs, hkv] move one [bs, hkv] block a page,
         # per-BLOCK fp8 planes [nb, hkv] one [1, hkv] row. A DMA window's
@@ -822,29 +866,35 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
         in_specs += [in_pool, in_pool]
         args += scales
         bufs += [pltpu.VMEM((2, plane, lanes), p.dtype) for p in scales]
+    hp = -(-hkv * g // _ROW_TILE) * _ROW_TILE   # the heads, whole row tiles
+    if sink is not None:
+        # fetched once a call: neither block's index ever moves
+        planes = _sink_planes(sink, hkv, g, rows, hp)
+        in_specs += [pl.BlockSpec(p.shape, lambda *_, n=p.ndim: (0,) * n)
+                     for p in planes]
+        args += planes
     scratch = bufs + [
         pltpu.SemaphoreType.DMA((len(bufs), 2, pages)),
         pltpu.VMEM((hkv, rows, 128), jnp.float32),
         pltpu.VMEM((hkv, rows, 128), jnp.float32),
-        pltpu.VMEM((hkv, rows, D), jnp.float32)]
+        pltpu.VMEM((hkv, rows, Dv), jnp.float32)]
     if _token_tile(rows, g):
         # the one-token walk's block-diagonal query [H, KD] and its softmax
         # state, the heads rounded up to whole row tiles
-        hp = -(-hkv * g // _ROW_TILE) * _ROW_TILE
         scratch += [pltpu.VMEM((hp, KD), jnp.float32),
                     pltpu.VMEM((hp, 128), jnp.float32),
                     pltpu.VMEM((hp, 128), jnp.float32),
-                    pltpu.VMEM((hp, KD), jnp.float32)]
+                    pltpu.VMEM((hp, VD), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=11,
             grid=(nq + R,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((hkv, rows, D), _q_index),
+            out_specs=pl.BlockSpec((hkv, rows, Dv), _q_index),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct(q_hm.shape, q_hm.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, TG, Dv), q_hm.dtype),
         # consecutive entries revisit one output block (accumulated
         # across rows by the masked write) — no reordering allowed
         compiler_params=pltpu.CompilerParams(
@@ -853,6 +903,21 @@ def _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen, kvlen,
         interpret=interpret,
         name="ragged_paged_attention",
     )(*args)
+
+
+def _sink_planes(sink, hkv, g, rows, hp):
+    """A head's sink logit as the two walks read it, float32 on every lane
+    of a lane tile: ``[Hkv, rows, 128]`` by plane row (row ``j`` of plane
+    ``k`` is head ``k * g + j % g``: a query block starts at a whole token)
+    for the general walk, ``[hp, 128]`` by head for the one-token walk's wide
+    rows (the padding heads 0)."""
+    sink = jnp.asarray(sink, jnp.float32).reshape(hkv, 1, g, 1)
+    by_row = jnp.broadcast_to(sink, (hkv, -(-rows // g), g, 128)).reshape(
+        hkv, -1, 128)[:, :rows]
+    by_head = jnp.pad(jnp.broadcast_to(sink.reshape(hkv * g, 1),
+                                       (hkv * g, 128)),
+                      ((0, hp - hkv * g), (0, 0)))
+    return [by_row, by_head]
 
 
 def _query_block(block_q, heads, packed_tokens):
@@ -873,21 +938,26 @@ def _plane_rows(block_q, heads, g, packed_tokens):
 
 
 def grid_params(pool_dtype, block_size, kd, table_entries, heads,
-                packed_tokens, block_q=None, pages=None, *, head_dim):
+                packed_tokens, block_q=None, pages=None, *, head_dim,
+                value_dim=None):
     """The tiling of one call, ``{"block_q", "pages", "one_token"}``: the
     query block in (token, head) rows as the call cuts it, the table entries
     one online-softmax update takes, and whether a span of one token
     computes on its own row tile (``_token_tile``), from what the call
-    observes (``block_q`` / ``pages`` given: fitted like the derived ones).
-    The ONE derivation: ``ragged_paged_attention_pallas`` tiles with it and
+    observes (``block_q`` / ``pages`` given: fitted like the derived ones;
+    ``head_dim`` a key's width and ``value_dim`` a value's where it is
+    another: the state a block carries and the V side of a pool row are then
+    that wide). The ONE derivation: ``ragged_paged_attention_pallas`` tiles with it and
     the engine passes it to ``ragged_grid_counts``, so the host's counts are
     the kernel's."""
-    if block_q is None:
-        block_q = query_block_rows(heads, head_dim)
-    if pages is None:
-        pages = pages_per_update(pool_dtype, block_size, kd, table_entries)
-    block_q = _query_block(block_q, heads, packed_tokens)
     g = int(heads) * int(head_dim) // int(kd)
+    if block_q is None:
+        block_q = query_block_rows(heads, value_dim or head_dim)
+    if pages is None:
+        pages = pages_per_update(
+            pool_dtype, block_size, kd, table_entries,
+            None if value_dim is None else int(heads) // g * int(value_dim))
+    block_q = _query_block(block_q, heads, packed_tokens)
     return {"block_q": block_q,
             "pages": max(1, min(int(pages), int(table_entries))),
             "one_token": bool(_token_tile(
@@ -983,17 +1053,18 @@ def ragged_grid_counts(qstart, qlen, kvlen, *, heads, block_size,
 # eager dispatch linearizes through every op and scalar-prefetch
 # pallas_calls don't linearize in interpret mode. ``scales`` is ``()`` or
 # the ``(k_scale, v_scale)`` planes of a quantized pool.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12, 13))
-def _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11, 12, 13, 14))
+def _ragged(q_hm, pool_k, pool_v, scales, sink, layer, tables, qstart, qlen,
             kvlen, scale, g, block_q, pages, window):
     return _ragged_call(q_hm, pool_k, pool_v, layer, tables, qstart, qlen,
                         kvlen, scale, g, block_q, pages, _interpret_mode(),
-                        scales=scales or None, window=window)
+                        scales=scales or None, window=window,
+                        sink=sink[0] if sink else None)
 
 
-def _ragged_fwd_rule(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
-                     qlen, kvlen, scale, g, block_q, pages, window):
-    return _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart,
+def _ragged_fwd_rule(q_hm, pool_k, pool_v, scales, sink, layer, tables,
+                     qstart, qlen, kvlen, scale, g, block_q, pages, window):
+    return _ragged(q_hm, pool_k, pool_v, scales, sink, layer, tables, qstart,
                    qlen, kvlen, scale, g, block_q, pages, window), None
 
 
@@ -1027,7 +1098,7 @@ def _stored_pool(pool_k, pool_v, k_scale, v_scale, layer):
 def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
                                   kvlen, block_q=None, k_scale=None,
                                   v_scale=None, layer=None, pages=None,
-                                  window=None):
+                                  window=None, sink=None):
     """Mixed prefill+decode attention over packed query spans through
     per-sequence block tables.
 
@@ -1035,7 +1106,9 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     pool_k, pool_v, layer: the KV block pool and the layer to read
               (``_stored_pool``): the step programs pass the stored pool
               whole and a traced ``layer``, and the kernel fetches blocks
-              from it where it lies
+              from it where it lies. ``D`` is a KEY's width; the V side may
+              be narrower or wider, ``Hkv * Dv`` (192 | 128: MiMo-V2-Flash),
+              and the output is then ``[T, H, Dv]``
     tables:   [R, max_blocks] int32  — physical block ids per sequence
                                        (entries >= num_blocks = unmapped)
     qstart:   [R] int32 — span start (packed row) per sequence
@@ -1057,7 +1130,12 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
               group of blocks that holds its first visible key
               (``_pair_first_block``) and the edge is masked inside it. With
               None the work list and the walk are the unwindowed ones
-    returns:  [T, H, D]; packed rows outside every span are exact zeros
+    sink:     None, or ``[H]`` float32: head ``h``'s softmax has one more
+              column, of logit ``sink[h]`` (as it is: not scaled) and no
+              value, so a row's probabilities sum to less than one. The
+              online softmax STARTS at it (``m = sink``, ``l = 1``), in both
+              walks, with and without a window
+    returns:  [T, H, Dv]; packed rows outside every span are exact zeros
 
     GQA is resolved by the layout: the query goes in head-major, ``[Hkv,
     T * G, D]`` (a transpose of ``T * H * D`` elements each way), and the
@@ -1081,6 +1159,7 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     Hkv = KD // D
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
+    Dv = pool_v.shape[-1] // Hkv
     scale = 1.0 / math.sqrt(D)
     qstart = jnp.asarray(qstart, jnp.int32).reshape(-1)
     qlen = jnp.asarray(qlen, jnp.int32).reshape(-1)
@@ -1090,17 +1169,20 @@ def ragged_paged_attention_pallas(q, pool_k, pool_v, tables, qstart, qlen,
     # packed buffer (no pad, no copy: the rows it holds past the end belong
     # to no span and are neither computed on nor written back)
     tiling = grid_params(pool_k.dtype, pool_k.shape[2], KD, tables.shape[1],
-                         H, T, block_q, pages, head_dim=D)
+                         H, T, block_q, pages, head_dim=D,
+                         value_dim=None if Dv == D else Dv)
     q_hm = q.reshape(T, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, T * G, D)
-    out = _ragged(q_hm, pool_k, pool_v, scales, layer, tables, qstart, qlen,
+    out = _ragged(q_hm, pool_k, pool_v, scales,
+                  () if sink is None else (jnp.asarray(sink, jnp.float32),),
+                  layer, tables, qstart, qlen,
                   kvlen, scale, G, tiling["block_q"], tiling["pages"],
                   None if window is None else int(window))
-    return out.reshape(Hkv, T, G, D).swapaxes(0, 1).reshape(T, H, D)
+    return out.reshape(Hkv, T, G, Dv).swapaxes(0, 1).reshape(T, H, Dv)
 
 
 def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
                                kvlen, k_scale=None, v_scale=None, layer=None,
-                               window=None):
+                               window=None, sink=None):
     """jnp oracle with identical semantics and operands (``_stored_pool``:
     with ``layer`` the tables gather straight from the stored pool, no
     layer of it is cut out) — and, deliberately, the exact op sequence of
@@ -1145,7 +1227,7 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
     # otherwise multiply the dominant gather cost ~T/R-fold.
     # (clip keeps sentinel entries harmless — masked by kvlen)
     k_rows = blocks(pool_k).reshape(R, s_tot, Hkv, D)
-    v_rows = blocks(pool_v).reshape(R, s_tot, Hkv, D)
+    v_rows = blocks(pool_v).reshape(R, s_tot, Hkv, -1)
     if scales:
         # quantized pool: upcast right after the per-row gather (the
         # kernel's fetch-then-dequantize order). Per-block fp8 planes
@@ -1172,7 +1254,14 @@ def ragged_attention_reference(q, pool_k, pool_v, tables, qstart, qlen,
     logits = jnp.einsum("qhd,qkhd->qhk", q, kf,
                         preferred_element_type=jnp.float32) * scale
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        # the sink: one more column of a head's softmax, with no value
+        col = jnp.broadcast_to(jnp.asarray(sink, jnp.float32)[None, :, None],
+                               (T, H, 1))
+        probs = jax.nn.softmax(jnp.concatenate([logits, col], -1),
+                               axis=-1)[..., :-1]
     # exact zeros on masked cols + zeroed garbage rows: stale pool rows
     # can be anything (0 * NaN = NaN)
     probs = jnp.where(mask[:, None, :], probs, 0.0)

@@ -41,7 +41,10 @@ cross-attention over layer 17's keys and values.
 
 What the cache holds of a sequence: ONE layer of keys and values (layer 17's,
 read by eight layers), the last ``sliding_window`` keys and values of every
-window layer, and a Mamba layer's state and the convolution's last 3 inputs.
+window layer (a ring of blocks a slot, written and walked through
+``serving.decode.ring_coords`` / ``ring_as_pool``, which ``models.
+mimo_v2_flash``'s window layers share), and a Mamba layer's state and the
+convolution's last 3 inputs.
 The cross-decoder holds nothing, and its output is used at the LAST token of
 a span only: the serving programs run it at one row a slot
 (``serving.decode._sambay_span_forward``).

@@ -146,13 +146,14 @@ _INDEXER_KEYS = ("idx_wq_b", "idx_wk", "idx_k_ln_w", "idx_k_ln_b", "idx_w")
 #: / ``post_ln`` its INPUT; ``router`` makes the FFN a routed one whose expert
 #: stacks ``[periods, E_held, ...]`` (one set for each place in the period)
 #: are read in place at the period's index, ``ws_*`` a shared expert beside
-#: it and ``ws_sgate`` that expert's sigmoid gate (Qwen3-Next).
+#: it and ``ws_sgate`` that expert's sigmoid gate (Qwen3-Next), ``router_bias``
+#: a sigmoid router's selection bias (MiMo-V2-Flash).
 _HYBRID_FULL_KEYS = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 _GDN_KEYS = ("gdn_wqkv", "gdn_wz", "gdn_wab", "gdn_conv", "gdn_A_log",
              "gdn_dt_bias", "gdn_o_norm", "gdn_wo")
 _HYBRID_FFN_KEYS = ("w_gate", "w_up", "w_down", "attn_out_ln", "ffn_out_ln",
-                    "input_ln", "post_ln", "router", "ws_gate", "ws_up",
-                    "ws_down", "ws_sgate")
+                    "input_ln", "post_ln", "router", "router_bias", "ws_gate",
+                    "ws_up", "ws_down", "ws_sgate")
 
 #: a model whose blocks are ONE mixer each (``models.nemotron_h``): UNITS of a
 #: Mamba-2 block, optionally an attention block, then a routed FFN of
@@ -168,6 +169,19 @@ _SSD_KEYS = ("ln", "ssd_in", "ssd_dt", "ssd_conv", "ssd_conv_b", "ssd_A_log",
 _MIXER_ATTN_KEYS = ("ln", "wq", "wk", "wv", "wo")
 _MIXER_MOE_KEYS = ("moe_ln", "router", "router_bias", "ws_up", "ws_down")
 
+#: a model of WINDOW layers around one full layer a period (``models.
+#: mimo_v2_flash``): a leading stack of dense layers (full attention, a dense
+#: SwiGLU) under ``dense_layers``, then PERIODS of some window layers and one
+#: full layer, every one with a routed FFN (``_hybrid_scan``). The full
+#: layers' entries are the tree's own ``[periods, ...]``, the window layers'
+#: lie under ``window_layers``, a tuple with one tree ``[periods, ...]`` a
+#: place in the period; a window layer holds ``sink``, a float32 logit a query
+#: head, and may have KV heads of its own count (what ``wk`` holds). The pool
+#: has a layer a dense and a full layer (the dense ones first); a window
+#: layer's keys and values lie in its ring of the store by slot
+#: (``ring_coords``), whose row is the window layers' own.
+_WINDOW_KEYS = ("wq", "wk", "wv", "wo", "sink")
+
 #: what marks a tree whose layer only the default engine's two programs were
 #: taught (``ContinuousBatchingEngine`` raises for every other switch): the
 #: extras of ``_decoder_layer``, and the key that names each model whose
@@ -175,24 +189,32 @@ _MIXER_MOE_KEYS = ("moe_ln", "router", "router_bias", "ws_up", "ws_down")
 #: ``linear_layers``: periods of Gated DeltaNet layers around a full layer,
 #: Olmo-Hybrid's and Qwen3-Next's alike; ``self_layers``: decoder-hybrid-
 #: decoder; ``ssd_layers``: one mixer a block; ``mamba_layers``: Mamba-1
-#: layers around one attention layer)
+#: layers around one attention layer; ``window_layers``: window layers with a
+#: sink around one full layer)
 TAUGHT_KEYS = _STACK_EXTRA_KEYS + ("wkv_a", "linear_layers", "self_layers",
-                                   "ssd_layers", "mamba_layers")
+                                   "ssd_layers", "mamba_layers",
+                                   "window_layers")
 
 
 def attention_grid(params, pool, table_entries, heads, packed_tokens, tp=1,
-                   *, head_dim):
+                   *, head_dim, pool_v=None):
     """The tiling (``block_q``, ``pages`` and, for the dense kernel,
     ``one_token``) of the attention kernel that ``_packed_span_forward``
     runs on this tree over the stored ``pool`` ``[L, num_blocks, bs, KD]``
-    (a chip's share is ``KD // tp``): the kernel's own ``grid_params`` of
+    (a chip's share is ``KD // tp``; a window layers' ring ``[L, R, ring
+    blocks, bs, KD]`` alike) and, where its row is another, the V side
+    ``pool_v``: the kernel's own ``grid_params`` of
     what its call will see, the heads as they are, so the engine's
     ``ragged_grid_counts`` counts the grid the step really runs."""
     if "wkv_a" in params:
         return _mla_grid_params(table_entries, heads, packed_tokens)
+    kd = pool.shape[-1] // tp
+    value_dim = None
+    if pool_v is not None and pool_v.shape[-1] != pool.shape[-1]:
+        value_dim = pool_v.shape[-1] // tp // (kd // head_dim)
     return _ragged_grid_params(
-        pool.dtype, pool.shape[2], pool.shape[3] // tp, table_entries,
-        heads, packed_tokens, head_dim=head_dim)
+        pool.dtype, pool.shape[-2], kd, table_entries,
+        heads, packed_tokens, head_dim=head_dim, value_dim=value_dim)
 
 
 #: a routed FFN's expert weights ``[L, E, ...]``: a layer scan does not
@@ -630,9 +652,31 @@ def dsa_expanded_attention(q_nope, q_pe, c_kv, k_pe, w_kvb, mask, *, mla,
 _ROUTING_KEYS = ("n_group", "topk_group", "first_held", "scale")
 
 
+def _by_row_blocks(ffn, hn, live, rows):
+    """``ffn(hn [B, S, H], live [B, S]) -> (m, stats)`` over ``rows``
+    positions at a time (``_mixer_ffn_layer``'s ``ffn_rows``), the positions
+    padded to whole blocks with dead rows. Of a routed FFN's ``stats`` the
+    picked experts ``[B, S, top_k]`` are kept; the counts are a block's own
+    and are dropped (None)."""
+    B, S = hn.shape[:2]
+    n = -(-S // rows)
+    live = jnp.ones((B, S), bool) if live is None else live
+
+    def blocks(a):
+        a = jnp.pad(a, ((0, 0), (0, n * rows - S)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape((B, n, rows) + a.shape[2:]), 1, 0)
+
+    def whole(a):
+        return jnp.moveaxis(a, 0, 1).reshape((B, n * rows) + a.shape[3:])[:, :S]
+
+    m, stats = jax.lax.map(lambda xs: ffn(*xs), (blocks(hn), blocks(live)))
+    return whole(m), ((None, whole(stats[1])) if isinstance(stats, tuple)
+                      else None)
+
+
 def _mixer_ffn_layer(h, lw, scope, mixer, *, eps, live=None, moe=None,
                      experts=None, tp_reduce=None, return_picks=False,
-                     norm=_rms):
+                     norm=_rms, ffn_rows=None):
     """ONE layer of "a mixer, then an FFN" on ``h [B, S, H]``, written once
     for every kind of layer that is two residual sub-layers: ``_decoder_layer``
     (softmax attention) and ``_gdn_layer`` (Gated DeltaNet) bring
@@ -648,7 +692,11 @@ def _mixer_ffn_layer(h, lw, scope, mixer, *, eps, live=None, moe=None,
     ``sigmoid(hn ws_sgate)`` a row where the tree has that gate; else the
     dense SwiGLU. ``norm`` is the tree's RMSNorm (``_norm_of``). Returns ``(h,
     carry, moe_stats or None)``; with ``return_picks`` the third is
-    ``(moe_stats, picked experts [B, S, top_k])``."""
+    ``(moe_stats, picked experts [B, S, top_k])``. With ``ffn_rows`` (a
+    model's whole-sequence ``forward``, never a step program) the FFN runs
+    that many positions at a time, so that its temporaries (a routed FFN's
+    float32 ``[rows x top_k, H]``) are a block's and not the sequence's
+    (``_by_row_blocks``: ``moe_stats`` is None where ``S`` is more)."""
     with jax.named_scope(scope):
         o, carry = mixer(norm(h, lw["input_ln"], eps) if "input_ln" in lw
                          else h)
@@ -656,14 +704,17 @@ def _mixer_ffn_layer(h, lw, scope, mixer, *, eps, live=None, moe=None,
         h = h + (norm(o, lw["attn_out_ln"], eps) if "attn_out_ln" in lw
                  else o)
     hn = norm(h, lw["post_ln"], eps) if "post_ln" in lw else h
-    if "router" in lw:
+
+    def ffn(hn, live):
+        if "router" not in lw:
+            return _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
+                                lw["w_down"]), None
         m, *stats = moe_ffn(hn, lw["router"], *experts, layer=lw["layer"],
                             top_k=moe[0], live=live, renormalize=moe[1],
                             return_picks=return_picks,
                             **({"router_bias": lw["router_bias"]}
                                if "router_bias" in lw else {}),
                             **dict(zip(_ROUTING_KEYS, moe[2:])))
-        stats = tuple(stats) if return_picks else stats[0]
         if "ws_gate" in lw:
             with jax.named_scope("moe_shared"):
                 shared = _swiglu_raw(hn, lw["ws_gate"], lw["ws_up"],
@@ -674,22 +725,24 @@ def _mixer_ffn_layer(h, lw, scope, mixer, *, eps, live=None, moe=None,
                         preferred_element_type=jnp.float32))
                     shared = (gate * shared).astype(shared.dtype)
                 m = m + shared
-    else:
-        m, stats = _swiglu_proj(hn, lw["w_gate"], lw["w_up"],
-                                lw["w_down"]), None
+        return m, tuple(stats) if return_picks else stats[0]
+
+    m, stats = ffn(hn, live) if ffn_rows is None or h.shape[1] <= ffn_rows \
+        else _by_row_blocks(ffn, hn, live, ffn_rows)
     m = m if tp_reduce is None else tp_reduce(m)
     h = h + (norm(m, lw["ffn_out_ln"], eps) if "ffn_out_ln" in lw else m)
     return h, carry, stats
 
 
 def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, mla=None,
-                   norm=_rms, **ffn):
+                   norm=_rms, scope="attn", v_scale=None, **ffn):
     """ONE softmax-attention decoder layer on ``h [B, S, H]``, written once
     for the programs the default engine runs (whole-prompt prefill, the
     packed-span forward of the unified step) and for the models' own
     ``forward``: ``_mixer_ffn_layer`` (which takes ``ffn``: ``live``, ``moe``,
     ``experts``, ``tp_reduce``, ``return_picks``) around the attention below,
-    under the scope ``attn``.
+    under the scope ``attn`` (or the program's ``scope``: a window layer's
+    ``window_attn``).
 
     ``lw`` maps names to this layer's weights (``_layer_stack`` order, after
     ``_dq_layer``) and what it holds chooses the attention: with ``wkv_a`` the
@@ -698,7 +751,13 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, mla=None,
     ``rope``, over the whole projection or a head at a time (``_qk_norm``); a
     ``wq`` twice as wide as the heads holds a query and then an output GATE a
     head, and the heads' output is multiplied by ``sigmoid(gate)`` before
-    ``W_o`` (Qwen3-Next). The program
+    ``W_o`` (Qwen3-Next); the KV heads are as many as ``wk`` holds heads of
+    ``hd`` (a model whose layer kinds differ in them, MiMo-V2-Flash's 4 and 8,
+    passes either ``nkv``), a value is as wide as ``wv`` makes it (its 128
+    under keys of 192: ``W_o`` then reads ``nh`` values) and is multiplied by
+    the static ``v_scale`` after its projection, where the model has one. A
+    SINK (``lw["sink"]``, a logit a query head) is the program's ``attend``'s
+    to apply, like the window it comes with. The program
     brings its own ``rope(x)`` (a rotation of part of a head is the
     program's: ``_rope_head``) and ``attend(q, k, v) -> (attn [B, S, nh, hd],
     carry)`` (latent attention: ``_mla_attention``'s): cache writes and the
@@ -714,10 +773,19 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, mla=None,
                                              mla=mla)
                 with jax.named_scope("mla_proj"):
                     return _o_proj(attn.reshape(B, S, -1), lw["wo"]), carry
-        gated = not isinstance(lw["wq"], tuple) \
-            and lw["wq"].shape[-1] == 2 * nh * hd
-        q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"],
-                            2 * nh if gated else nh, nkv, hd)
+        dense = not isinstance(lw["wq"], tuple)
+        gated = dense and lw["wq"].shape[-1] == 2 * nh * hd
+        kv = lw["wk"].shape[-1] // hd if dense else nkv
+        if dense and lw["wv"].shape[-1] != kv * hd:
+            # a value narrower (or wider) than a key: a reshape of its own
+            q, k, v = (jnp.einsum("bsh,hd->bsd", hn, lw[n]).reshape(
+                B, S, heads, -1) for n, heads in (("wq", nh), ("wk", kv),
+                                                  ("wv", kv)))
+        else:
+            q, k, v = _qkv_proj(hn, lw["wq"], lw["wk"], lw["wv"],
+                                2 * nh if gated else nh, kv, hd)
+        if v_scale is not None:
+            v = (v.astype(jnp.float32) * v_scale).astype(v.dtype)
         gate = None
         if gated:       # a head's columns: its query, then its gate
             q = q.reshape(B, S, nh, 2 * hd)
@@ -728,9 +796,9 @@ def _decoder_layer(h, lw, *, nh, nkv, hd, eps, rope, attend, mla=None,
         if gate is not None:
             attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(hn.dtype)
-        return _o_proj(attn.reshape(B, S, nh * hd), lw["wo"]), carry
+        return _o_proj(attn.reshape(B, S, -1), lw["wo"]), carry
 
-    return _mixer_ffn_layer(h, lw, "attn", mixer, eps=eps, norm=norm, **ffn)
+    return _mixer_ffn_layer(h, lw, scope, mixer, eps=eps, norm=norm, **ffn)
 
 
 # ------------------------------------------- linear-attention (hybrid) layers
@@ -859,7 +927,9 @@ def _gdn_layer(h, lw, *, eps, gdn, mix, norm=_rms, **ffn):
 def _hybrid_scan(params, carry, full_layer, linear_layer):
     """A hybrid model's forward: ONE scan over the periods whose body runs
     the period's layers in order: its linear layers, and its full layer
-    where the tree puts it: after them (a tree with ``linear_layers``), or
+    where the tree puts it: after them (a tree with ``linear_layers``, or
+    with ``window_layers``: the "linear" layers are then window-attention
+    ones, ``_WINDOW_KEYS``), or
     between the two runs of a tree with ``mamba_layers`` (``(the places
     before the full layer, the places after it)``, the full layer's entries
     under ``attn_layers``). ``linear_layer(carry, lw, index, experts)`` /
@@ -884,8 +954,9 @@ def _hybrid_scan(params, carry, full_layer, linear_layer):
             return held, (tuple(held.pop(k) for k in _EXPERT_KEYS)
                           if routed else None)
 
-        lin, lin_experts = zip(*(cut(tree, _GDN_KEYS)
-                                 for tree in params["linear_layers"]))
+        kind, keys = ("window_layers", _WINDOW_KEYS) \
+            if "window_layers" in params else ("linear_layers", _GDN_KEYS)
+        lin, lin_experts = zip(*(cut(tree, keys) for tree in params[kind]))
         full, full_experts = cut(params, _HYBRID_FULL_KEYS)
         full_at = len(lin)
     periods, n_lin = full["wo"].shape[0], len(lin)
@@ -1114,6 +1185,42 @@ def _mamba_span_mixer(ssm, *, seg, pos, qstart, qlen, kvlen, T, **norm):
     return mixer
 
 
+def ring_coords(seg, pos, ring, table_entries):
+    """Where a step's packed rows lie in the window layers' store ``ring
+    [layers, R, ring blocks, bs, KD]`` and how the ragged kernel walks it:
+    ``(ring_at, ring_tables)``. ``ring_at`` is a token's ``(slot, ring block,
+    row)``: its row ``pos % (ring blocks * bs)`` of its slot's ring (a dead
+    packed row's slot is ``R``: the write drops); a layer writes at ``(layer,)
+    + ring_at``. ``ring_tables [R, table_entries]`` is the table the program
+    computes for ``ring_as_pool``: logical block ``b`` of slot ``r`` is ring
+    block ``b % ring blocks``. The ring is long enough that no key a query of
+    the step may see was overwritten (``engine``: window + the longest span +
+    a block), and the kernel's ``window`` bounds the walk below."""
+    R, ring_blocks, bs = ring.shape[1:4]
+    ring_at = (jnp.where(seg < R, seg, R), pos // bs % ring_blocks, pos % bs)
+    ring_tables = (
+        jnp.arange(R, dtype=jnp.int32)[:, None] * ring_blocks
+        + jnp.arange(table_entries, dtype=jnp.int32)[None, :] % ring_blocks)
+    return ring_at, ring_tables
+
+
+def ring_as_pool(ring):
+    """The window store as the kernel walks it: a pool of ``R * ring blocks``
+    (merging two leading dims moves nothing)."""
+    return ring.reshape((ring.shape[0], -1) + ring.shape[3:])
+
+
+def ring_rows_at(lengths, ring_rows, seq_len):
+    """What a whole-prompt prefill leaves in a slot's ring: ``[G, ring_rows]``,
+    for ring row ``j`` the last position ``p < lengths[g]`` with ``p %
+    ring_rows == j`` (a row no position of the prompt has maps to position 0:
+    nothing a later query may see)."""
+    ring = jnp.arange(ring_rows, dtype=jnp.int32)[None, :]
+    span = max(ring_rows, 1)
+    return jnp.clip(ring + (lengths[:, None] - 1 - ring) // span * span, 0,
+                    seq_len - 1)
+
+
 def _diff_queries(q):
     """Differential attention's queries ``[.., nh, hd]`` as the ragged kernel
     takes them: a KV PAIR is one head of ``2 hd`` (the pool's row is the same
@@ -1225,10 +1332,7 @@ def _sambay_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, ssm,
     in_row = live[:, None, :] & causal
     in_window = in_row & (cols[None, :, None] - cols[None, None, :]
                           < ssm.window)
-    ring = jnp.arange(ssm.ring_rows, dtype=jnp.int32)[None, :]
-    span = max(ssm.ring_rows, 1)
-    ring_at = jnp.clip(
-        ring + (lengths[:, None] - 1 - ring) // span * span, 0, S - 1)
+    ring_at = ring_rows_at(lengths, ssm.ring_rows, S)
 
     mamba = _mamba_rows_mixer(lengths, live, ssm)
 
@@ -1293,12 +1397,9 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
       spans of one token through ``ssm_recurrent_update``, longer ones
       through ``ssm_chunk_scan``, which the decode-only program, ``T ==
       ssm.decode_rows``, leaves out: the plan gave it no chunk);
-    - a window layer writes a token's keys and values at ring row ``pos %
-      (ring blocks * bs)`` of its slot and attends through the ragged kernel
-      over a table the program computes (logical block ``b`` of slot ``r``
-      is ring block ``b % ring blocks``), bounded below by the window: the
-      ring is long enough that no key a query of this step may see was
-      overwritten (``engine``: window + the longest span + a block);
+    - a window layer writes a token's keys and values at its row of its
+      slot's ring and attends through the ragged kernel over the table the
+      program computes, bounded below by the window (``ring_coords``);
     - the middle full layer appends to the pool's one layer and attends over
       it (``kv_attend(pk, pv, 0)``);
     - the cross-decoder holds no cache and its output is used at a span's
@@ -1310,21 +1411,10 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
     Returns ``(x [1, R, H] by slot, pool_k, pool_v, store)``."""
     ss, cs, wk, wv = store
     R, T = qstart.shape[0], x.shape[1]
-    ring_blocks, bs = wk.shape[2], wk.shape[3]
     live_tok = seg < R
     ragged = (ragged_paged_attention_pallas if decode_attn == "pallas"
               else ragged_attention_reference)
-    # the window store as the kernel walks it: a pool of R * ring blocks
-    # (merging two leading dims moves nothing) and a table of ring blocks
-    ring_at = (jnp.where(live_tok, seg, R), pos // bs % ring_blocks,
-               pos % bs)
-    ring_tables = (
-        jnp.arange(R, dtype=jnp.int32)[:, None] * ring_blocks
-        + jnp.arange(tables.shape[1], dtype=jnp.int32)[None, :]
-        % ring_blocks)
-
-    def as_pool(w):
-        return w.reshape((w.shape[0], R * ring_blocks) + w.shape[3:])
+    ring_at, ring_tables = ring_coords(seg, pos, wk, tables.shape[1])
 
     mamba = _mamba_span_mixer(ssm, seg=seg, pos=pos, qstart=qstart,
                               qlen=qlen, kvlen=kvlen, T=T)
@@ -1346,8 +1436,9 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
             at = (idx,) + ring_at
             nwk = wk.at[at].set(kv_rows(k[0]), mode="drop")
             nwv = wv.at[at].set(kv_rows(v[0]), mode="drop")
-            attn = ragged(qw[0], as_pool(nwk), as_pool(nwv), ring_tables,
-                          qstart, qlen, kvlen, layer=idx, window=ssm.window)
+            attn = ragged(qw[0], ring_as_pool(nwk), ring_as_pool(nwv),
+                          ring_tables, qstart, qlen, kvlen, layer=idx,
+                          window=ssm.window)
             return attn[None], (nwk, nwv)
 
         h, (wk, wv) = attn_layer(h, aw, "window_attn", attend)
@@ -1386,6 +1477,211 @@ def _sambay_span_forward(params, x, pool_k, pool_v, store, kv_attend,
 
     x, _ = jax.lax.scan(cross_pair, x, params["cross_layers"])
     return x, pool_k, pool_v, (ss, cs, wk, wv)
+
+
+# ------------------------------- window layers with a sink around a full layer
+# ``models.mimo_v2_flash``'s docstring has the equations. A tree with
+# ``window_layers`` is a leading stack of dense layers and then PERIODS of
+# window layers and one full layer (``_hybrid_scan``), every layer
+# ``_decoder_layer``: what differs between the kinds (KV heads, the rotary
+# base, the window, the sink, where the keys are cached) is the layer's tree
+# and the program's ``rope`` / ``attend``.
+def _gqa_attend_plain(q, k, v, lengths, window=None, sink=None):
+    """Causal grouped-query softmax attention in ``jax.numpy`` over whole rows
+    (whole-prompt prefill, ``forward``), a row of the group and a block of
+    queries at a time, so that a long sequence's scores never exist whole: q
+    ``[G, S, nh, hd]``, k ``[G, S, nkv, hd]``, v ``[G, S, nkv, vd]`` (a
+    value's width its own); a query at position ``i`` of a row sees keys ``j
+    <= i`` under ``lengths[g]``, with ``window`` only ``j > i - window``;
+    ``sink [nh]`` float32 is one more column of a head's softmax with no
+    value. Scores at ``hd ** -0.5``, float32. Returns ``[G, S, nh, vd]``."""
+    S, nh, hd = q.shape[1:]
+    nkv = k.shape[2]
+    # (a block's float32 scores, every head's, stay under 64 MiB)
+    block = min(S, max(8, (1 << 24) // (nh * S) // 8 * 8))
+    n = -(-S // block)
+    cols = jnp.arange(S, dtype=jnp.int32)[None, :]
+
+    def one(row):
+        q, k, v, length = row
+        qp = jnp.pad(q, ((0, n * block - S), (0, 0), (0, 0))).reshape(
+            n * block, nkv, nh // nkv, hd)
+
+        def one_block(start):
+            rows = start + jnp.arange(block, dtype=jnp.int32)[:, None]
+            mask = (cols <= rows) & (cols < length)
+            if window is not None:
+                mask = mask & (cols > rows - window)
+            logits = jnp.einsum(
+                "qkgd,skd->kgqs", jax.lax.dynamic_slice_in_dim(qp, start,
+                                                               block),
+                k, preferred_element_type=jnp.float32) * hd ** -0.5
+            logits = jnp.where(mask, logits, NEG_INF)
+            if sink is not None:
+                logits = jnp.concatenate([logits, jnp.broadcast_to(
+                    sink.astype(jnp.float32).reshape(nkv, -1, 1, 1),
+                    logits.shape[:3] + (1,))], -1)
+            probs = jnp.where(mask, jax.nn.softmax(logits, axis=-1)[..., :S],
+                              0.0)
+            return jnp.einsum("kgqs,skd->qkgd", probs.astype(q.dtype), v)
+
+        out = jax.lax.map(one_block,
+                          jnp.arange(n, dtype=jnp.int32) * block)
+        return out.reshape(n * block, nh, -1)[:S]
+
+    return jax.lax.map(one, (q, k, v, lengths))
+
+
+def _swa_dense_stack(params, carry, layer):
+    """The leading dense layers of a tree with ``window_layers``, scanned:
+    ``layer(carry, lw, index) -> (carry, ys)``, ``index`` the layer's place in
+    the KV pool. Returns ``(carry, ys [dense layers, ...], their count)``."""
+    dense = params["dense_layers"]
+    n = dense["input_ln"].shape[0]
+    carry, ys = jax.lax.scan(
+        lambda c, xs: layer(c, xs[0], xs[1]), carry,
+        (dense, jnp.arange(n, dtype=jnp.int32)))
+    return carry, ys, n
+
+
+def _swa_ropes(swa, theta, rotary, hd, seq_len, take):
+    """``(the full layers' rope, the window layers')``: the first ``rotary``
+    values of a head rotated over ``theta`` / ``swa.theta``, the rest left;
+    ``take(x, sin=, cos=)`` rotates ``x`` at the program's positions given
+    the ``[seq_len, rotary]`` tables."""
+    ropes = []
+    for base in (theta, swa.theta):
+        sin, cos = _rope_tables(seq_len, rotary or hd, base)
+        ropes.append(_hybrid_rope(
+            rotary, hd, functools.partial(take, sin=sin, cos=cos)))
+    return ropes
+
+
+def _swa_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, swa, theta,
+                        rotary=None, moe=None, return_picks=False,
+                        ffn_rows=None):
+    """The layers of a tree with ``window_layers`` over an admission group
+    ``x [G, S_pad, H]``: the dense and full layers attend causally, the window
+    layers inside their window with their sink, all through
+    ``_gqa_attend_plain``; routed FFNs make pairs for the real tokens only.
+    Returns ``(x, pk, pv [dense + full layers, G, S_pad, Hkv, key width |
+    value width], (window keys, values [window layers, G, swa.ring_rows,
+    KD]), moe stats)``: what the pool and the rings hold of a sequence
+    (``ring_rows_at``), the stats as ``_packed_span_forward``'s.
+    ``ffn_rows`` is ``_mixer_ffn_layer``'s, for the model's ``forward``."""
+    S = x.shape[1]
+    live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    ring_at = ring_rows_at(lengths, swa.ring_rows, S)
+    rope_full, rope_win = _swa_ropes(swa, theta, rotary, hd, S, _apply_rope)
+
+    def attend(**window):
+        return lambda q, k, v: (_gqa_attend_plain(q, k, v, lengths, **window),
+                                (k, v))
+
+    def layer(h, lw, experts, rope, attend, **kw):
+        return _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope, attend=attend,
+            v_scale=swa.v_scale, live=live, moe=moe, experts=experts,
+            return_picks=return_picks and experts is not None,
+            ffn_rows=ffn_rows, **kw)
+
+    def dense_layer(h, lw, _):
+        h, kv, _ = layer(h, lw, None, rope_full, attend())
+        return h, kv
+
+    def full_layer(h, lw, _, experts):
+        h, kv, stats = layer(h, lw, experts, rope_full, attend())
+        return h, (kv, stats)
+
+    def window_layer(h, lw, _, experts):
+        h, (k, v), stats = layer(h, lw, experts, rope_win,
+                                 attend(window=swa.window,
+                                        sink=lw["sink"]),
+                                 scope="window_attn")
+        held = tuple(jnp.take_along_axis(kv_rows(t), ring_at[..., None],
+                                         axis=1) for t in (k, v))
+        return h, (held, stats)
+
+    x, kv0, _ = _swa_dense_stack(params, x, dense_layer)
+    x, (held, win_stats), (kv, full_stats) = _hybrid_scan(
+        params, x, full_layer, window_layer)
+    pk, pv = (jnp.concatenate(side) for side in zip(kv0, kv))
+    # (by window layer; explicit: a forward that keeps no ring has 0 rows)
+    return (x, pk, pv, tuple(a.reshape((a.shape[0] * a.shape[1],)
+                                       + a.shape[2:]) for a in held),
+            _hybrid_moe_stats(params, win_stats, full_stats))
+
+
+def _swa_span_forward(params, x, pool_k, pool_v, store, kv_attend, tables,
+                      *, seg, pos, qstart, qlen, kvlen, nh, nkv, hd, eps, swa,
+                      theta, rotary, decode_attn, moe=None,
+                      return_picks=False):
+    """The layers of a tree with ``window_layers`` over the packed buffer ``x
+    [1, T, H]``. TWO stores of different rows ride the scan as carry, whole:
+    the KV pool (the dense and the full layers; ``kv_attend(pk, pv, layer)``
+    appends and attends) and ``store``, the window layers' rings ``(keys,
+    values [window layers, R, ring blocks, bs, KD])``, whose rows are the
+    window layers' own (their KV heads, a key and a value of different
+    widths). A window layer writes a token's keys and values at its row of its
+    slot's ring and attends through the ragged kernel over the table the
+    program computes, bounded below by the window, its sink one more column of
+    the softmax (``ring_coords``). Both kinds rotate the first ``rotary``
+    values of a head, the full layers over ``theta`` and the window layers
+    over ``swa.theta``. Returns ``(x, pool_k, pool_v, store,
+    moe stats)``, the last as ``_packed_span_forward``'s."""
+    R = qstart.shape[0]
+    live_tok = seg < R
+    ragged = (ragged_paged_attention_pallas if decode_attn == "pallas"
+              else ragged_attention_reference)
+    s_tot = tables.shape[1] * _kv_data(pool_k).shape[2]
+    ring_at, ring_tables = ring_coords(seg, pos, store[0], tables.shape[1])
+    rope_full, rope_win = _swa_ropes(
+        swa, theta, rotary, hd, s_tot,
+        lambda t, sin, cos: _apply_rope_grid(
+            t, jnp.take(sin, pos, axis=0, mode="clip")[None],
+            jnp.take(cos, pos, axis=0, mode="clip")[None]))
+    def layer(h, lw, experts, rope, attend, **kw):
+        return _decoder_layer(
+            h, lw, nh=nh, nkv=nkv, hd=hd, eps=eps, rope=rope, attend=attend,
+            v_scale=swa.v_scale, live=live_tok[None], moe=moe,
+            experts=experts,
+            return_picks=return_picks and experts is not None, **kw)
+
+    def dense_layer(carry, lw, idx):
+        h, pk, pv = carry
+        h, (pk, pv), _ = layer(h, lw, None, rope_full,
+                               kv_attend(pk, pv, idx))
+        return (h, pk, pv), None
+
+    (x, pool_k, pool_v), _, n_dense = _swa_dense_stack(
+        params, (x, pool_k, pool_v), dense_layer)
+
+    def full_layer(carry, lw, idx, experts):
+        h, pk, pv, st = carry
+        h, (pk, pv), stats = layer(h, lw, experts, rope_full,
+                                   kv_attend(pk, pv, n_dense + idx))
+        return (h, pk, pv, st), stats
+
+    def window_layer(carry, lw, idx, experts):
+        h, pk, pv, (wk, wv) = carry
+
+        def attend(q, k, v):
+            at = (idx,) + ring_at
+            nwk = wk.at[at].set(kv_rows(k[0]), mode="drop")
+            nwv = wv.at[at].set(kv_rows(v[0]), mode="drop")
+            attn = ragged(q[0], ring_as_pool(nwk), ring_as_pool(nwv),
+                          ring_tables, qstart, qlen, kvlen, layer=idx,
+                          window=swa.window, sink=lw["sink"])
+            return attn, (nwk, nwv)
+
+        h, st, stats = layer(h, lw, experts, rope_win, attend,
+                             scope="window_attn")
+        return (h, pk, pv, st), stats
+
+    (x, pool_k, pool_v, store), win_stats, full_stats = _hybrid_scan(
+        params, (x, pool_k, pool_v, tuple(store)), full_layer, window_layer)
+    return x, pool_k, pool_v, store, _hybrid_moe_stats(
+        params, win_stats, full_stats)
 
 
 # ------------------------------------- Mamba layers around one attention layer
@@ -2206,7 +2502,7 @@ def _hybrid_prefill_layers(params, x, lengths, *, nh, nkv, hd, eps, gdn,
 def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
                   hd, eps, theta, tied, tp_reduce=None, a8=False, moe=None,
                   mla=None, return_picks=False, gdn=None, ssm=None,
-                  dsa=None, ssd=None, rotary=None):
+                  dsa=None, ssd=None, rotary=None, swa=None):
     """Batched prefill: ids [G, S_pad] (right-padded prompts), lengths
     [G] real token counts, per-row keys/temps/top_ks.
 
@@ -2242,9 +2538,22 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     model whose blocks are one mixer each (``ssd_layers``; ``ssd`` its
     Mamba-2 blocks' static numbers) returns ``pk`` / ``pv`` of its attention
     blocks, its routed FFNs' summary (and picks) and, last, what its Mamba-2
-    blocks' cache holds of each row (``_mixer_prefill_layers``).
+    blocks' cache holds of each row (``_mixer_prefill_layers``). A tree with
+    ``window_layers`` (``swa``) returns ``pk`` / ``pv`` of its dense and full
+    layers (a key and a value of different widths), its routed FFNs' summary
+    (and picks) and, last, what its window layers' rings hold of each row
+    (``_swa_prefill_layers``).
     """
     B, S = ids.shape
+    if swa is not None:
+        x = jnp.take(params["embed"], ids, axis=0)
+        x, pk, pv, state, stats = _swa_prefill_layers(
+            params, x, lengths, nh=nh, nkv=nkv, hd=hd, eps=eps, swa=swa,
+            theta=theta, rotary=rotary, moe=moe, return_picks=return_picks)
+        tok0, keys2 = _first_token(
+            params, _dq_head(params, tied, params["embed"].dtype, a8), x,
+            lengths, keys, temps, top_ks, eps)
+        return (pk, pv, tok0, keys2) + _moe_outputs(stats) + (state,)
     if ssd is not None:
         x = jnp.take(params["embed"], ids, axis=0)
         x, pk, pv, state, stats = _mixer_prefill_layers(
@@ -2344,7 +2653,7 @@ def _first_token(params, head, x, lengths, keys, temps, top_ks, eps):
 def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
                      collective_dtype="fp", wq8=False, a8=False, moe=None,
                      mla=None, return_picks=False, gdn=None, ssm=None,
-                     dsa=None, ssd=None, rotary=None):
+                     dsa=None, ssd=None, rotary=None, swa=None):
     """One jitted prefill; jax retraces per (group, prompt-bucket)
     shape — both padded to powers of two by the engine. ``tp > 1``
     wraps it in shard_map over the heads-sharded mesh (README
@@ -2371,7 +2680,8 @@ def build_prefill_fn(*, nh, nkv, hd, eps, theta, tied, tp=1,
         **({} if ssm is None else {"ssm": ssm}),
         **({} if dsa is None else {"dsa": dsa}),
         **({} if ssd is None else {"ssd": ssd}),
-        **({} if rotary is None else {"rotary": rotary})))
+        **({} if rotary is None else {"rotary": rotary}),
+        **({} if swa is None else {"swa": swa})))
 
 
 # ------------------------------------------------------------ suffix prefill
@@ -2726,7 +3036,8 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, sin, cos, *, nh, nkv, hd,
                          eps, decode_attn, tp_reduce=None, a8=False,
                          moe=None, mla=None, return_picks=False, state=None,
-                         gdn=None, ssm=None, dsa=None, ssd=None):
+                         gdn=None, ssm=None, dsa=None, ssd=None, swa=None,
+                         theta=None):
     """ONE forward pass over a packed buffer of variable-length query
     spans through the block tables — the shared tick-0 assembly of the
     unified ragged step AND the speculative verify program (the two
@@ -2755,7 +3066,10 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     Mamba layers' store). A
     model whose blocks are one mixer each (``ssd``) runs
     ``_mixer_span_forward`` over ``state``, its Mamba-2 blocks' store, and
-    returns the store fourth and its routed FFNs' stats fifth.
+    returns the store fourth and its routed FFNs' stats fifth; so does a
+    tree with ``window_layers`` (``swa``; ``_swa_span_forward`` over
+    ``state``, its window layers' rings, building its two rotary tables from
+    ``theta`` and ``swa.theta`` itself).
     """
     R = tables.shape[0]
     nb, bs = _kv_data(pool_k).shape[1], _kv_data(pool_k).shape[2]
@@ -2801,6 +3115,14 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
 
         return attend
 
+    if swa is not None:
+        x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
+        return _swa_span_forward(
+            params, x, pool_k, pool_v, state, kv_attend, tables, seg=seg,
+            pos=pos, qstart=qstart, qlen=qlen, kvlen=kvlen, nh=nh, nkv=nkv,
+            hd=hd, eps=eps, swa=swa, theta=theta,
+            rotary=None if sin is None else sin.shape[-1],
+            decode_attn=decode_attn, moe=moe, return_picks=return_picks)
     if ssd is not None:
         x = jnp.take(params["embed"], ids[None], axis=0)        # [1, T, H]
         return _mixer_span_forward(
@@ -2963,7 +3285,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
                       decode_attn, tp_reduce=None, a8=False, fused=False,
                       moe=None, mla=None, return_picks=False, gdn=None,
-                      ssm=None, dsa=None, ssd=None, rotary=None):
+                      ssm=None, dsa=None, ssd=None, rotary=None, swa=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -3049,7 +3371,8 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
             params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
             kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
             decode_attn=decode_attn, state=state, gdn=gdn, ssm=ssm, ssd=ssd,
-            moe=moe, return_picks=return_picks)
+            moe=moe, return_picks=return_picks,
+            **({} if swa is None else {"swa": swa, "theta": theta}))
         if ssm is not None:     # x came back one row a slot
             tok0, keys_t0 = _rows_sample(params, head, x[0], keys_in, temps,
                                          top_ks, eps)
@@ -3099,7 +3422,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          wq8=False, a8=False, fused=False,
                          collective_overlap=False, moe=None, mla=None,
                          return_picks=False, gdn=None, ssm=None, dsa=None,
-                         ssd=None, rotary=None):
+                         ssd=None, rotary=None, swa=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -3144,11 +3467,12 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             **({} if ssm is None else {"ssm": ssm}),
             **({} if dsa is None else {"dsa": dsa}),
             **({} if ssd is None else {"ssd": ssd}),
-            **({} if rotary is None else {"rotary": rotary})),
+            **({} if rotary is None else {"rotary": rotary}),
+            **({} if swa is None else {"swa": swa})),
         # argument 18: the stores by slot of a model with recurrent or
         # window layers (absent otherwise)
-        donate_argnums=((1, 2) + ((18,) if (gdn, ssm, ssd) != (None,) * 3
-                                  else ()))
+        donate_argnums=((1, 2) + ((18,) if (gdn, ssm, ssd, swa)
+                                  != (None,) * 4 else ()))
         if donate else ())
 
 

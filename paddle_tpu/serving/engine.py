@@ -335,7 +335,7 @@ class ContinuousBatchingEngine:
                    "prefix_cache": bool(prefix_cache)}
             if any(k in self._params
                    for k in ("wkv_a", "linear_layers", "self_layers",
-                             "ssd_layers", "mamba_layers")):
+                             "ssd_layers", "mamba_layers", "window_layers")):
                 # a latent pool has no heads to scale by and no V side:
                 # the quantized pools' planes and kernels do not apply;
                 # a quantized cache of a model with recurrent or window
@@ -413,7 +413,8 @@ class ContinuousBatchingEngine:
         # (``PagedKVCache.state``), and the pool holds rows for the OTHER
         # layers only
         self._stateful = any(k in self._params for k in (
-            "linear_layers", "self_layers", "ssd_layers", "mamba_layers"))
+            "linear_layers", "self_layers", "ssd_layers", "mamba_layers",
+            "window_layers"))
         kv_layers = c.num_kv_layers if self._stateful \
             else c.num_hidden_layers
         # what a cached token's row is: Hkv heads of head_dim on a K and a
@@ -427,6 +428,9 @@ class ContinuousBatchingEngine:
             # attention over a learned selection: the V side is the second
             # per-token cache, an index key a layer that has an indexer
             geom.update(v_dim=c.dsa.dim, v_layers=c.dsa.layers)
+        if "window_layers" in self._params:
+            # keys wider than values: the V side has a row of its own
+            geom["v_dim"] = c.num_key_value_heads * c.v_head_dim
         from .block_manager import BlockManager
         from .prefix_cache import PrefixCache
         self.prefix_cache = None
@@ -508,14 +512,14 @@ class ContinuousBatchingEngine:
                               ssd_state_shape(d.heads, d.head_dim, d.groups,
                                               d.state),
                               d.conv - 1, c.conv_channels)
-        elif self._stateful:
+        elif self._stateful and "window_layers" not in self._params:
             # Mamba-1 layers: a decoder-hybrid-decoder model's, which also
             # has window layers (below), or a tree with ``mamba_layers``
             state_geometry = (c.num_ssm_layers,
                               (c.mamba_d_state, c.d_inner),
                               c.mamba_d_conv - 1, c.d_inner)
-            self._ring_blocks = 0
-        if "self_layers" in self._params:
+        self._ring_blocks = 0
+        if "self_layers" in self._params or "window_layers" in self._params:
             # a window layer's ring a slot: the window, the longest span a
             # step may write before it attends (a chunk; one token without
             # chunking) and a block, in whole blocks: no key a query of the
@@ -525,6 +529,13 @@ class ContinuousBatchingEngine:
                 max_blocks, -(-(c.sliding_window + -(-span // bs) * bs
                                 + bs - 1) // bs))
             window_geometry = (c.num_window_layers, self._ring_blocks)
+            if "window_layers" in self._params:
+                # the window layers' own row: their KV heads, a key and a
+                # value of different widths (the pool's row is the full
+                # layers')
+                window_geometry += (
+                    c.swa_num_key_value_heads * c.head_dim,
+                    c.swa_num_key_value_heads * c.v_head_dim)
         self.cache = PagedKVCache(
             kv_layers, self.num_slots, self.max_seq_len,
             geom["num_kv_heads"], geom["head_dim"], dtype=dtype,
@@ -916,25 +927,40 @@ class ContinuousBatchingEngine:
         head_dim = getattr(self.config, "kernel_head_dim",
                            self.config.head_dim)
 
-        # (KV heads as the dense kernel sees them in the pool's row; the
-        # latent kernel has none, nor the walk ``span_row_groups`` counts)
-        kv_heads = None if "wkv_a" in self._params else \
-            self.cache.pool.k.shape[3] // self._tp // head_dim
+        pool = self.cache.pool
 
-        def counts(qstart, qlen, packed, **window):
+        def counts(qstart, qlen, packed, k=pool.k, v=pool.v, **window):
+            # (KV heads as the dense kernel sees them in the store's row,
+            # the pool's or a ring's of its own; the latent kernel has none,
+            # nor the walk ``span_row_groups`` counts)
+            kv_heads = None if "wkv_a" in self._params else \
+                k.shape[-1] // self._tp // head_dim
             return ragged_grid_counts(
                 qstart, qlen, kvlen, packed_tokens=packed,
                 heads=heads, block_size=self.cache.block_size,
                 table_entries=self.cache.max_blocks, kv_heads=kv_heads,
                 **window,
-                **attention_grid(self._params, self.cache.pool.k,
-                                 self.cache.max_blocks, heads, packed,
-                                 tp=self._tp, head_dim=head_dim))
+                **attention_grid(self._params, k, self.cache.max_blocks,
+                                 heads, packed, tp=self._tp,
+                                 head_dim=head_dim, pool_v=v))
 
         work = counts(qstart, qlen, packed)
         work.update(decode_rows=decode_rows, decode_tokens=decode_tokens,
                     prefill_tokens=prefill_tokens)
-        if self.cache.window is not None:
+        if "window_layers" in self._params:
+            # the kernel's calls by layer kind: ``work`` is a full layer's,
+            # over the pool; a window layer's call walks its ring, whose row
+            # is its own (other KV heads: another tiling), and needs the
+            # keys inside the window only; what it FETCHES is whole blocks
+            # of whole groups from the group its window starts in
+            win = counts(qstart, qlen, packed, *self.cache.window,
+                         window=self.config.sliding_window)
+            work.update(
+                window_kv_tokens=win["kv_tokens"],
+                window_attn_pairs=win["attn_pairs"],
+                window_fetched_keys=win["live_steps"] * self.cache.block_size,
+                window_update_steps=win["update_steps"])
+        elif self.cache.window is not None:
             # the kernel's calls by layer kind: ``work`` is the middle full
             # layer's; a window layer's call needs the keys inside the
             # window only (``window_kv_tokens``); a cross layer's runs one
@@ -1011,6 +1037,9 @@ class ContinuousBatchingEngine:
             consts["gdn"] = c.gdn._replace(decode_rows=rows)
         elif "ssd_layers" in self._params:
             consts["ssd"] = c.ssd._replace(decode_rows=rows)
+        elif "window_layers" in self._params:
+            consts["swa"] = c.swa._replace(
+                ring_rows=self._ring_blocks * self.cache.block_size)
         elif self._stateful:
             consts["ssm"] = c.ssm._replace(
                 ring_rows=self._ring_blocks * self.cache.block_size,

@@ -362,7 +362,13 @@ class PagedKVCache:
     kind, :attr:`window` ``(keys, values [layers, num_slots, ring blocks,
     block_size, KD])``: a ring of blocks a slot, a token's row at ``position
     % (ring blocks * block_size)``, which the step programs write and walk
-    through a table they compute (``decode._sambay_span_forward``). A layer
+    through a table they compute (``decode.ring_coords``). ``window_geometry``
+    is ``(layers, ring blocks)`` where a ring's row is the pool's, or
+    ``(layers, ring blocks, key row, value row)`` where the window layers
+    have KV heads of their own (MiMo-V2-Flash: 8 against the full layers' 4,
+    keys wider than values on both): two stores of different rows under the
+    one manager, the ring constant a slot and so admitted, grown and released
+    with the slot itself. A layer
     that caches nothing (a cross-decoder's) appears nowhere.
     ``bytes_per_token`` counts the pool, ``state_bytes_per_slot`` and
     ``window_bytes_per_slot`` the two stores.
@@ -441,12 +447,15 @@ class PagedKVCache:
                 jnp.zeros((int(ll), self.num_slots, int(rows),
                            int(channels)), dtype))
         # the window layers' store: ``window_geometry`` is ``(layers, ring
-        # blocks a slot)``; a row is the pool's
+        # blocks a slot[, key row, value row])``; a row is the pool's unless
+        # the geometry brings its own (class docstring)
         self.window = None
         if window_geometry is not None:
-            wl, ring = (int(n) for n in window_geometry)
-            shape = (wl, self.num_slots, ring, bs, pool.k.shape[-1])
-            self.window = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            wl, ring, *rows = (int(n) for n in window_geometry)
+            rows = rows or (pool.k.shape[-1], pool.k.shape[-1])
+            self.window = tuple(
+                jnp.zeros((wl, self.num_slots, ring, bs, w), dtype)
+                for w in rows)
 
     def _bytes_per_slot(self, store) -> int:
         return sum(a.size * np.dtype(a.dtype).itemsize
@@ -489,9 +498,9 @@ class PagedKVCache:
         (one compile-once scatter; the slot is a runtime argument). The
         ring's rows are stored as blocks, ``[ring blocks, bs, KD]``."""
         n = len(self.state or ())
-        ring = () if self.window is None else self.window[0].shape[2:]
+        ring = () if self.window is None else self.window[0].shape[2:4]
         held = tuple(held[:n]) + tuple(
-            x.reshape(x.shape[:1] + ring) for x in held[n:])
+            x.reshape(x.shape[:1] + ring + x.shape[-1:]) for x in held[n:])
         self.store = _state_writer(self._donate)(
             self.store, held, np.int32(slot))
 

@@ -567,14 +567,17 @@ class ServingGateway:
                 r.counter(f"serving_moe_{stat}_total",
                           text + " Monotonic across engine rebuilds."
                           ).set_fn(lambda k="moe_" + stat: self._stat(k))
-        if self.engine.cache.state is not None:
-            # a model with recurrent layers only (a hybrid model's linear
-            # layers): their cache is a store by slot beside the KV pool
-            r.gauge("serving_state_bytes_per_slot",
-                    "HBM bytes one slot's recurrent states and "
-                    "convolution tails hold over all their layers, "
-                    "whatever the sequence's length."
-                    ).set_fn(lambda: self.engine.cache.state_bytes_per_slot)
+        if self.engine.cache.store:
+            # a model with recurrent or window layers only (a hybrid
+            # model's linear layers, a window layer's ring): their cache is
+            # a store by slot beside the KV pool, each kind's bytes apart
+            if self.engine.cache.state is not None:
+                r.gauge("serving_state_bytes_per_slot",
+                        "HBM bytes one slot's recurrent states and "
+                        "convolution tails hold over all their layers, "
+                        "whatever the sequence's length."
+                        ).set_fn(
+                    lambda: self.engine.cache.state_bytes_per_slot)
             if self.engine.cache.window is not None:
                 r.gauge("serving_window_bytes_per_slot",
                         "HBM bytes one slot's rings of window keys and "

@@ -58,6 +58,8 @@ _ARCH = {
     "nemotron_h": ("nemotron_h", "NemotronHForCausalLM", "nemotron_h_tiny"),
     "jamba": ("jamba", "JambaForCausalLM", "jamba_tiny"),
     "qwen3_next": ("qwen3_next", "Qwen3NextForCausalLM", "qwen3_next_tiny"),
+    "mimo_v2_flash": ("mimo_v2_flash", "MiMoV2FlashForCausalLM",
+                      "mimo_v2_flash_tiny"),
 }
 
 
@@ -75,7 +77,10 @@ def model(arch="llama", seed=33, **config):
     (``config`` overrides the architecture's ``*_tiny()``) and seed. Its
     weights are a function of the three, so a file reads the same model
     whichever file built it."""
-    key = (arch, seed, tuple(sorted(config.items())))
+    # (a list, such as a layer pattern, keys as a tuple)
+    key = (arch, seed, tuple(sorted(
+        (k, tuple(v) if isinstance(v, list) else v)
+        for k, v in config.items())))
     if key not in _MODELS:
         _MODELS[key] = fresh_model(arch, seed, **config)
     return _MODELS[key]

@@ -684,6 +684,70 @@ class TestMosaicCompilesQwen3Next:
         assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
 
 
+class TestMosaicCompilesMiMoV2Flash:
+    """MiMo-V2-Flash's kernels at its published widths (64 query heads, keys
+    192 wide and values 128: a KV head's key window starts at half a lane tile
+    for every odd head; 4 KV heads in the pool, 8 in the rings, under a window
+    of 128 with a sink a head; 16 held experts of 2,048 under a sigmoid router
+    of 256, 8 a token, nothing beside them) and at the serving cell's shapes:
+    32 slots x 1,024 table entries, rings of 21 blocks, a packed buffer of 32
+    + 512 rows or of 32."""
+    H, DK, DV, R, MB = 64, 192, 128, 32, 1024
+
+    def _attend(self, v5e, rows, nkv, layers, blocks, window):
+        i32 = jnp.int32
+
+        def attend(q, pk, pv, tables, qs, ql, kl, sink, layer):
+            return pallas_ragged_attention.ragged_paged_attention_pallas(
+                q, pk, pv, tables, qs, ql, kl, layer=layer, window=window,
+                sink=sink if window else None)
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(attend).lower(
+                v5e((rows, self.H, self.DK)),
+                v5e((layers, blocks, 32, nkv * self.DK)),
+                v5e((layers, blocks, 32, nkv * self.DV)),
+                v5e((self.R, self.MB), i32), v5e((self.R,), i32),
+                v5e((self.R,), i32), v5e((self.R,), i32),
+                v5e((self.H,), jnp.float32), v5e((), i32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        # the output is a VALUE wide; neither store is copied or padded
+        assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+        return compiled
+
+    @pytest.mark.parametrize("rows", [544, 32], ids=["chunk", "decode_only"])
+    def test_the_pool_at_keys_of_192_under_values_of_128(self, v5e, rows):
+        """The two full layers: 64 heads on 4 KV heads, a pool of 32 x 1,024
+        blocks whose K side is 768 lanes and whose V side 512."""
+        self._attend(v5e, rows, 4, 2, self.R * self.MB, None)
+
+    @pytest.mark.parametrize("rows", [544, 32], ids=["chunk", "decode_only"])
+    def test_the_rings_under_the_window_with_the_sink(self, v5e, rows):
+        """The five window layers: 64 heads on 8 KV heads over the rings as a
+        pool of 32 x 21 blocks (1,536 | 1,024 lanes), a window of 128, the
+        sink one more column of a head's softmax."""
+        self._attend(v5e, rows, 8, 5, self.R * 21, 128)
+
+    @pytest.mark.parametrize("rows", [544, 32], ids=["chunk", "decode_only"])
+    def test_sixteen_held_experts_under_the_sigmoid_router(self, v5e, rows):
+        """Three matrices an expert at 2,048, 16 of a router's 256 held, one
+        place of the period: three grouped matmuls, and none of the three
+        stacks ``[1, 16, ...]`` (256 MiB each) is copied."""
+        hid, wid, exp = 4096, 2048, 16
+
+        def ffn(h, router, bias, w_gate, w_up, w_down, layer):
+            return moe_ffn.moe_ffn(
+                h, router, w_gate, w_up, w_down, layer=layer, top_k=8,
+                renormalize=True, first_held=0, router_bias=bias)[0]
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(ffn).lower(
+                v5e((1, rows, hid)), v5e((hid, 256)),
+                v5e((256,), jnp.float32), v5e((1, exp, hid, wid)),
+                v5e((1, exp, hid, wid)), v5e((1, exp, wid, hid)),
+                v5e((), jnp.int32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 3
+        assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
 class TestUnifiedStepLeavesThePoolInPlace:
     """The unified serving step, small, compiled for the described v5e: in
     the optimised HLO nothing but the in-place row scatter has a result as

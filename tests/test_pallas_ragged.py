@@ -370,3 +370,121 @@ def test_ragged_attention_pads_no_head_count(nh, nkv, monkeypatch):
     assert np.abs(np.asarray(got - want))[:live].max() < 1e-4
     assert not np.asarray(got)[live:].any()
     assert seen == [((nkv, T * (nh // nkv), hd),) * 2]
+
+
+# ---------------------------------------------- key width != value width, sink
+#: ``(H, Hkv, Dk, Dv, mb, bs, block_q)``: MiMo-V2-Flash's 192 | 128 at a group
+#: of 16 (its full layers' 64 on 4, cut to 32 on 2) and of 8 (its window
+#: layers'), and a small pair whose value is the WIDER side; ``block_q`` None
+#: is the derived block (a plane of whole token tiles: decode rows take the
+#: one-token walk), 5 tokens a block has no such tile (every span the general
+#: walk)
+WIDTH_CASES = {
+    "192_128_group16": (32, 2, 192, 128, 4, 16, None),
+    "192_128_group8": (16, 2, 192, 128, 4, 16, None),
+    "24_16_small": (8, 2, 24, 16, 5, 8, None),
+    "16_32_value_wider": (8, 4, 16, 32, 5, 8, None),
+    "24_16_general_walk": (8, 2, 24, 16, 5, 8, 5 * 8),
+}
+
+
+def _mk_widths(spans, H, Hkv, Dk, Dv, mb, bs, seed):
+    q, pk, pv, tbl, qs, ql, kl = _mk(len(spans), spans, H, Hkv, Dk, mb, bs,
+                                     seed=seed)
+    r = np.random.RandomState(seed + 1)
+    pv = jnp.asarray(r.randn(pk.shape[0], bs, Hkv, Dv), jnp.float32)
+    sink = jnp.asarray(r.randn(H) + 1.0, jnp.float32)
+    return (q, pk, pv, tbl, qs, ql, kl), sink
+
+
+#: MiMo-V2-Flash's group of 16 and the general walk with and without a window
+#: and a sink, all four; of the others what the model runs (its window
+#: layers' group of 8 under the window with the sink) and one case each side
+#: (every case is a trace of its own through the interpreter, 2-4 s)
+WIDTH_RUNS = [(case, window, sink)
+              for case in ("192_128_group16", "24_16_general_walk")
+              for window in (None, 11) for sink in (False, True)] + [
+    ("192_128_group8", 11, True), ("24_16_small", 11, True),
+    ("16_32_value_wider", None, True), ("16_32_value_wider", 11, False)]
+
+
+@pytest.mark.parametrize(
+    "case, window, sink", WIDTH_RUNS,
+    ids=[f"{c}-{'window11' if w else 'full'}-{'sink' if s else 'no_sink'}"
+         for c, w, s in WIDTH_RUNS])
+def test_key_and_value_widths_and_sink_match_reference(case, window, sink):
+    """Keys wider (or narrower) than values and a per-head sink, with and
+    without a window, decode rows and spans that cross query blocks in
+    one call, over a NaN-poisoned pool: the output is a VALUE wide and the
+    oracle's. The sink is one more column of a head's softmax with no
+    value, so a row's weights sum to less than one."""
+    H, Hkv, Dk, Dv, mb, bs, block_q = WIDTH_CASES[case]
+    spans = [(1, mb * bs), (min(5, bs), 12), (0, 0), (bs + 3, 2 * bs + 3),
+             (1, 1), (1, 2 * bs)]
+    args, b = _mk_widths(spans, H, Hkv, Dk, Dv, mb, bs, seed=H + Dk)
+    q, pk, pv, tbl, qs, ql, kl = args
+    pk = _poison_stale_rows(pk, tbl, kl, ql)
+    pv = _poison_stale_rows(pv, tbl, kl, ql)
+    kw = dict(window=window, **({"sink": b} if sink else {}))
+    got = ragged_paged_attention_pallas(q, pk, pv, tbl, qs, ql, kl,
+                                        block_q=block_q, **kw)
+    want = ragged_attention_reference(q, pk, pv, tbl, qs, ql, kl, **kw)
+    assert got.shape == (q.shape[0], H, Dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_sink_is_a_column_without_a_value():
+    """The oracle's own rule against the equation: ``p_ij = exp(s_ij) /
+    (exp(b_h) + sum_j' exp(s_ij'))``, one decode row over 9 keys by hand."""
+    H, Hkv, Dk, Dv, mb, bs = 4, 2, 24, 16, 3, 8
+    (q, pk, pv, tbl, qs, ql, kl), b = _mk_widths(
+        [(1, 9), (4, 4)], H, Hkv, Dk, Dv, mb, bs, seed=5)
+    want = np.asarray(ragged_attention_reference(
+        q, pk, pv, tbl, qs, ql, kl, sink=b))
+    k = np.asarray(pk)[np.asarray(tbl)[0]].reshape(-1, Hkv, Dk)[:9]
+    v = np.asarray(pv)[np.asarray(tbl)[0]].reshape(-1, Hkv, Dv)[:9]
+    for h in range(H):
+        s = k[:, h // 2] @ np.asarray(q)[0, h] * Dk ** -0.5
+        e = np.exp(s - s.max())
+        p = e / (np.exp(float(b[h]) - s.max()) + e.sum())
+        assert p.sum() < 1.0
+        np.testing.assert_allclose(want[0, h], p @ v[:, h // 2], rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_widths_and_sink_in_the_tiling_and_the_counts():
+    """``grid_params`` sizes the query block by the VALUE's width (the
+    accumulator's) and an update's pages by both sides of a row; with one
+    width it is what it was. The host's counts take that tiling: a window
+    counts the keys inside it, whatever the widths."""
+    from paddle_tpu.kernels.pallas_ragged_attention import (
+        grid_params, ragged_grid_counts)
+    same = grid_params(jnp.bfloat16, 32, 1024, 64, 32, 544, head_dim=128)
+    assert same == grid_params(jnp.bfloat16, 32, 1024, 64, 32, 544,
+                               head_dim=128, value_dim=128)
+    # MiMo-V2-Flash's full layers: 64 heads on 4 KV heads, a key 192 lanes, a
+    # value 128: 64 tokens a block (the float32 accumulator [4, 1024, 128] is
+    # 2 MiB), 256 keys an update
+    full = grid_params(jnp.bfloat16, 32, 4 * 192, 1024, 64, 544,
+                       head_dim=192, value_dim=128)
+    assert full == {"block_q": 64 * 64, "pages": 8, "one_token": True}
+    # its window layers: 8 KV heads, a row of 1,536 | 1,024 lanes: 2 MiB of
+    # two slots a side hold 204 keys, 6 blocks of 32
+    ring = grid_params(jnp.bfloat16, 32, 8 * 192, 1024, 64, 544,
+                       head_dim=192, value_dim=128)
+    assert ring == {"block_q": 64 * 64, "pages": 6, "one_token": True}
+    qs, ql, kl = [0, 1, 2], [1, 1, 512], [300, 9000, 4096]
+    got = ragged_grid_counts(qs, ql, kl, heads=64, block_size=32,
+                             table_entries=1024, packed_tokens=544,
+                             window=128, kv_heads=8, **ring)
+    assert got["kv_tokens"] == 128 + 128 + (512 + 127)
+    assert got["one_token_rows"] == 2
+    # a decode row 9,000 tokens in walks the group(s) that hold its window
+    # and not its prefix: at most two groups of 6 pages
+    alone = ragged_grid_counts([0], [1], [9000], heads=64, block_size=32,
+                               table_entries=1024, packed_tokens=32,
+                               window=128, kv_heads=8, **grid_params(
+                                   jnp.bfloat16, 32, 1536, 1024, 64, 32,
+                                   head_dim=192, value_dim=128))
+    assert alone["update_steps"] <= 2 and alone["live_steps"] <= 12
