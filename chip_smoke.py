@@ -129,7 +129,7 @@ FULL = dict(
     # experts a token): 64 of a plain softmax router's 512 held, ten a token,
     # renormalised; the cell's chunk step and its decode-only step
     moe_wide=dict(widths=(2048, 512, 64, 512, 10),
-                  rows=[(640, 640), (128, 128)]),
+                  rows=[(640, 640), (128, 128)], whole_prompt=[(2048, 2000)]),
     # GLM-5.2's sparse attention: (heads, latent rank, rope-free / rope /
     # value head widths, index heads, index head width, rows selected), and
     # its cell's two packed sizes over tables of 640 entries: 16 decode rows
@@ -210,7 +210,8 @@ REHEARSAL = dict(
     mla=dict(widths=(4, 32, 16, 8, 16), decode_only=(8, [
         (1, 40 + 30 * i) for i in range(6)])),
     moe_share=dict(widths=(64, 8, 4, 32, 2, 2, 1), rows=[(36, 4), (16, 16)]),
-    moe_wide=dict(widths=(64, 16, 4, 32, 3), rows=[(36, 36), (16, 16)]),
+    moe_wide=dict(widths=(64, 16, 4, 32, 3), rows=[(100, 100), (16, 16)],
+                  whole_prompt=[(200, 190)]),
     dsa=dict(widths=(4, 32, 16, 8, 16, 4, 16, 16), mb=8, rows={
         "6 decode rows": [(1, 40 + 30 * i) for i in range(6)],
         "chunk at 200": [(1, 150), (40, 200), (0, 0), (1, 1)]}),
@@ -827,34 +828,55 @@ def phase_kernels(rehearse):
     # ---- the routed FFN (grouped matmuls) against every-expert-masked:
     # whole (OLMoE), then with one chip's share of a wider, group-limited,
     # scaled router (DeepSeek-V2) ----------------------------------------
-    from paddle_tpu.kernels.moe_ffn import moe_ffn, moe_ffn_reference
+    from paddle_tpu.kernels.moe_ffn import (_capacity, moe_ffn,
+                                            moe_ffn_reference)
 
     def routed_ffn(tag, seed, hidden, router_width, held, width, rows,
-                   **routing):
+                   stack=0, forced=False, **routing):
+        """``stack``: the expert weights as a stack of that many layers, the
+        last of them read in place. ``forced``: a router that puts every
+        pick on a held expert, so that the held pairs overflow the buffer
+        sized for a chip's share (``moe_ffn._capacity``) and take it in
+        several passes."""
         rng = np.random.RandomState(seed)
+        lead = (stack,) if stack else ()
         weights = [
             jnp.asarray(0.02 * rng.randn(*shape).astype(np.float32), bf16)
-            for shape in ((hidden, router_width), (held, hidden, width),
-                          (held, hidden, width), (held, width, hidden))]
+            for shape in ((hidden, router_width),
+                          lead + (held, hidden, width),
+                          lead + (held, hidden, width),
+                          lead + (held, width, hidden))]
+        if forced:
+            weights[0] = jnp.zeros_like(weights[0]).at[
+                0, :routing["top_k"]].set(50.0)
+        layer = dict(layer=jnp.int32(stack - 1)) if stack else {}
         for n_rows, n_live in rows:
-            h = jnp.asarray(rng.randn(n_rows, hidden).astype(np.float32),
-                            bf16)
+            h = rng.randn(n_rows, hidden).astype(np.float32)
+            if forced:
+                h[:, 0] = 1.0
+            h = jnp.asarray(h, bf16)
             live = np.zeros(n_rows, bool)   # live rows spread over the buffer
             live[np.linspace(0, n_rows - 1, n_live).astype(int)] = True
             margs = (h, *weights, jnp.asarray(live))
             got, stats_got = jax.jit(
-                lambda *a: moe_ffn(*a[:5], live=a[5], **routing))(*margs)
-            want, stats_want = reference(
-                lambda *a: moe_ffn_reference(*a[:5], live=a[5], **routing),
+                lambda *a: moe_ffn(*a[:5], live=a[5], **layer, **routing))(
                 *margs)
+            want, stats_want = reference(
+                lambda *a: moe_ffn_reference(
+                    a[0], a[1], *(w[-1] if stack else w for w in a[2:5]),
+                    live=a[5], **routing), *margs)
             name = f"moe_ffn {tag}{n_live}/{n_rows}"
             _agree(name, got, want, TOL_FWD, errors)
-            pairs, picks = int(stats_got[0]), int(stats_got[3])
+            pairs, picks, compact = (int(stats_got[i]) for i in (0, 3, 4))
+            cap = _capacity(n_rows * routing["top_k"], held, router_width)
             check(np.array_equal(np.asarray(stats_got),
                                  np.asarray(stats_want))
                   and picks == n_live * routing["top_k"]
-                  and (pairs == picks if held == router_width
-                       else 0 < pairs < picks),
+                  and (pairs == picks if held == router_width or forced
+                       else 0 < pairs < picks)
+                  # one pass on the buffer of a chip's share, where there
+                  # is one and the held pairs fit it
+                  and compact == (cap is not None and pairs <= cap),
                   f"{name}: routing summary {np.asarray(stats_got)} != "
                   f"{np.asarray(stats_want)}")
 
@@ -867,6 +889,29 @@ def phase_kernels(rehearse):
     H, E, held, I, K = size["moe_wide"]["widths"]
     routed_ffn("64 of 512 ", 512, H, E, held, I, size["moe_wide"]["rows"],
                top_k=K, renormalize=True, first_held=0)
+    # ... as a layer of a stack, on the buffer of the pairs a chip's share
+    # takes: in one pass and, every pick forced onto the share, in four
+    for forced in (False, True):
+        routed_ffn("64 of 512 in a stack " + "forced " * forced, 513, H, E,
+                   held, I, size["moe_wide"]["rows"], stack=2, forced=forced,
+                   top_k=K, renormalize=True, first_held=0)
+    # ... and at a whole-prompt program's rows, where the buffer is far over
+    # ``PRODUCT_SLOTS`` and the way back is the gather by pair, as at the
+    # 640 rows above and not at the 128 (the rehearsal's sizes reach it by
+    # lowering the bar)
+    from paddle_tpu.kernels import moe_ffn as moe_ffn_module
+    bar = moe_ffn_module.PRODUCT_SLOTS
+    if rehearse:
+        moe_ffn_module.PRODUCT_SLOTS = 0
+    rows = size["moe_wide"]["whole_prompt"]
+    check(all(_capacity(n * K, held, E) > moe_ffn_module.PRODUCT_SLOTS
+              for n, _ in rows), "moe_ffn whole-prompt: the buffer is under "
+          "PRODUCT_SLOTS, so the gather by pair did not run")
+    for forced in (False, True):
+        routed_ffn("64 of 512 whole-prompt " + "forced " * forced, 514, H, E,
+                   held, I, rows, stack=2, forced=forced, top_k=K,
+                   renormalize=True, first_held=0)
+    moe_ffn_module.PRODUCT_SLOTS = bar
 
     # ---- latent attention, absorbed kernel against expanded oracle ------
     from paddle_tpu.kernels.pallas_mla_ragged_attention import (

@@ -13,11 +13,23 @@ Two functions with one signature. ``moe_ffn_reference`` is the oracle of the
 tests: every expert over every row, masked. ``moe_ffn`` is what the step
 programs run: the live (row, expert) pairs are ordered by expert, three
 grouped matmuls walk the groups (gate, up, down), and the weighted sum goes
-back to row order. Shapes are static (``rows x top_k`` pair slots, a
-length-``E`` count vector), so one program serves every routing; cost follows
-the live pairs and the experts they touch: dead rows (the padding of a packed
-buffer or of a prefill bucket) make no pair, and an expert nobody picked is
-not read.
+back to row order. Shapes are static, so one program serves every routing;
+cost follows the live pairs and the experts they touch: dead rows (the padding
+of a packed buffer or of a prefill bucket) make no pair, and an expert nobody
+picked is not read. The buffer of pair slots is ``rows x top_k`` where every
+expert is held. Under a held range it is sized for the picks that land on a
+held expert (``_capacity``: twice an even router's share, a function of the
+shapes alone) and the sentinel pairs get no slot; a call whose held pairs
+overflow it takes the buffer in as many passes as they need
+(``lax.while_loop``; one pass in every step an even router makes): dropless
+and exact whatever the routing, one body a program, and ``STATS``' last entry
+says whether a call fitted one pass. The way back from the slots to the rows
+is a float32 product over the slots while the buffer is small
+(``PRODUCT_SLOTS``: its cost is rows x slots, so rows squared) and the gather
+by pair above that (linear in the rows: a wide chunk step, the whole-prompt
+programs). Either keeps a row's output that is not finite in that row. Which
+forms of the ordering and of the way back are cheaper on the v5e, and where
+they cross, is ``scripts/bench_moe_route.py``'s to say (PERF.md, PR 57).
 
 Router matmul and softmax run in float32 (``Precision.HIGHEST``) so that only
 error upstream of the router can change which experts a row picks. The
@@ -67,9 +79,35 @@ from .pallas_flash import _interpret_mode
 PAIR_TILE = 128
 #: stats vector returned beside the output, one int32 each: the live pairs
 #: on held experts (what the grouped matmuls run), the held experts with at
-#: least one, the fullest one's, and the picks the live rows made over the
-#: router's whole width (``pairs`` again when every expert is held)
-STATS = ("pairs", "experts_touched", "max_expert_pairs", "picks")
+#: least one, the fullest one's, the picks the live rows made over the
+#: router's whole width (``pairs`` again when every expert is held), and 1
+#: where the call ran ONE pass on a buffer of fewer than ``rows x top_k``
+#: pair slots (``_capacity``: a held range whose pairs fit it), else 0
+STATS = ("pairs", "experts_touched", "max_expert_pairs", "picks",
+         "compact_calls")
+
+
+#: the largest buffer of a held range (``_capacity`` slots) whose way back to
+#: row order is the product over the slots; a larger one goes back by the
+#: gather by pair. The product costs rows x slots and the gather rows alone:
+#: on the v5e the product is ahead by 18 us a call at 384 slots, level at
+#: 896-1,024, behind by 33 at 1,664 and four times the gather's whole call
+#: at a whole-prompt program's 20,480 (PERF.md, PR 57)
+PRODUCT_SLOTS = 1024
+
+
+def _tiles(n):
+    return -(-n // PAIR_TILE) * PAIR_TILE
+
+
+def _capacity(pairs, n_held, n_exp):
+    """Pair slots of the buffer a layer call builds for the picks that land
+    on a held expert, of ``pairs`` (row, pick) pairs under ``n_held`` of the
+    router's ``n_exp`` experts: twice what an even router lands there, in
+    whole tiles. None where that is no fewer than a slot a pair takes (every
+    expert held; a step of a few rows): there is one buffer then."""
+    cap = _tiles(-(-2 * pairs * n_held // n_exp))
+    return cap if cap < _tiles(pairs) else None
 
 
 def group_limited(probs, n_group, topk_group):
@@ -91,7 +129,7 @@ def _route(h2, router, top_k, live, renormalize, n_held, n_group=1,
     """Float32 router: (weights [T, K] f32, experts [T, K] i32 by the
     router's ids (its width for a dead row), held ids [T, K] i32 (position in
     the held stack; ``n_held`` for a pick no held expert takes), counts
-    [n_held] i32 of live pairs per held expert, stats [4] i32)."""
+    [n_held] i32 of live pairs per held expert, stats [5] i32: ``STATS``)."""
     n_exp = router.shape[-1]
     logits = jnp.dot(h2.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -116,9 +154,11 @@ def _route(h2, router, top_k, live, renormalize, n_held, n_group=1,
     # (a compare-and-sum, not a scatter-add: 4 us against 38 on the v5e)
     counts = jnp.sum(loc.reshape(-1, 1) == jnp.arange(n_held)[None, :],
                      axis=0, dtype=jnp.int32)
-    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
-                       jnp.max(counts),
-                       top_k * jnp.sum(live)]).astype(jnp.int32)
+    held = jnp.sum(counts)
+    cap = _capacity(loc.size, n_held, n_exp)
+    stats = jnp.stack([held, jnp.sum(counts > 0), jnp.max(counts),
+                       top_k * jnp.sum(live),
+                       0 if cap is None else held <= cap]).astype(jnp.int32)
     return w, idx, loc, counts, stats
 
 
@@ -142,7 +182,7 @@ def moe_ffn_reference(h, router, w_gate, w_up, w_down, *, top_k, live=None,
     a two-matrix expert, whose w_up is [E_held, I, H]); w_down [E_held, I,
     H]; live [...] bool (None: every row); ``routing``: the
     module docstring's ``n_group``, ``topk_group``, ``first_held``,
-    ``scale``, ``router_bias``. Returns (out [..., H], stats [4]).
+    ``scale``, ``router_bias``. Returns (out [..., H], stats [5]).
     Plain ``jnp``: each held expert runs over every row and is masked by the
     row's weight for it (zero where not picked or the row is dead)."""
     lead, h2, live = _prep(h, live)
@@ -215,18 +255,11 @@ def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
     layers ``[L, E_held, ...]``, read in place (``_grouped_matmul``)."""
     lead, h2, live = _prep(h, live)
     rows, hid = h2.shape
-    with jax.named_scope("moe"):
-        with jax.named_scope("moe_route"):
-            w, picks, idx, counts, stats = _route(
-                h2, router, top_k, live, renormalize,
-                w_up.shape[0 if layer is None else 1], **routing)
-            pairs = rows * top_k
-            slots = -(-pairs // PAIR_TILE) * PAIR_TILE
-            # pair slots ordered by held expert; dead pairs and picks of an
-            # expert held elsewhere (the sentinel id) last
-            order = jnp.argsort(idx.reshape(-1), stable=True)
-            order = jnp.pad(order, (0, slots - pairs))
-            xs = jnp.take(h2, order // top_k, axis=0)
+    pairs = rows * top_k
+    n_held = w_up.shape[0 if layer is None else 1]
+    cap = _capacity(pairs, n_held, router.shape[-1])
+
+    def experts(xs, counts):
         with jax.named_scope("moe_experts"):
             if w_gate is None:
                 mid = relu2(_grouped_matmul(xs, w_up, counts, layer,
@@ -235,15 +268,102 @@ def moe_ffn(h, router, w_gate, w_up, w_down, *, top_k, live=None,
                 g = _grouped_matmul(xs, w_gate, counts, layer)
                 u = _grouped_matmul(xs, w_up, counts, layer)
                 mid = jax.nn.silu(g) * u
-            y = _grouped_matmul(mid.astype(h2.dtype), w_down, counts, layer)
+            return _grouped_matmul(mid.astype(h2.dtype), w_down, counts,
+                                   layer)
+
+    def every_pick(w, idx, counts):
+        """A slot for every (row, pick) pair."""
+        with jax.named_scope("moe_route"):
+            # pair slots ordered by held expert; dead pairs and picks of an
+            # expert held elsewhere (the sentinel id) last
+            order = jnp.argsort(idx.reshape(-1), stable=True)
+            order = jnp.pad(order, (0, _tiles(pairs) - pairs))
+            xs = jnp.take(h2, order // top_k, axis=0)
+        y = experts(xs, counts)
         with jax.named_scope("moe_route"):
             # back to (row, pick) order; slots past the live pairs hold
             # whatever the grouped matmul left there, so select, not scale
             slot_of = jnp.argsort(order[:pairs])
             y = jnp.take(y, slot_of, axis=0).reshape(rows, top_k, hid)
-            y = jnp.where((idx < counts.shape[0])[:, :, None],
+            y = jnp.where((idx < n_held)[:, :, None],
                           y.astype(jnp.float32), 0.0)
-            out = jnp.sum(y * w[:, :, None], axis=1)
+            return jnp.sum(y * w[:, :, None], axis=1)
+
+    def held_pairs(w, idx, counts):
+        """``cap`` slots a pass over the pairs on held experts, in expert
+        order: the sentinel pairs sort past them and get none. ONE pass
+        wherever they fit ``cap`` (every step the benchmark serves); a call
+        whose share runs hotter takes ``ceil(held / cap)``, each with the
+        counts of its window of the order: dropless and exact whatever the
+        routing, on one body a program. Up to ``PRODUCT_SLOTS`` a row's sum
+        is a float32 product over the slots, ``[rows, cap]`` weights (a
+        slot's under its row, 0 elsewhere) times ``y``: no slot is looked up
+        by pair, so no second sort and no running sum (130 us a call on the
+        v5e at 1,280 pairs). The weights go in at ``HIGHEST`` (three bf16
+        terms that add up to the float32 exactly); a bf16 ``y`` IS one such
+        term, so it goes in at the default and the product costs three
+        passes, not six. A slot whose ``y`` is not finite goes in as 0 under
+        a weight of NaN: 0 x inf would hand it to every row of the step, and
+        so it stays in its own. Above ``PRODUCT_SLOTS`` (a wide chunk step,
+        a whole-prompt program's thousands of rows) the product's rows x
+        ``cap`` loses to a second sort, which inverts the first, and each
+        pair fetching its slot of the pass."""
+        by_product = cap <= PRODUCT_SLOTS
+        with jax.named_scope("moe_route"):
+            order = jnp.argsort(idx.reshape(-1), stable=True)
+            slot_of = None if by_product \
+                else jnp.argsort(order).reshape(rows, top_k)
+            order = jnp.pad(order, (0, cap))
+            ends = jnp.cumsum(counts)
+            held = ends[-1]
+
+        def product(y, lo, window, row_of):
+            used = jnp.arange(cap) < held - lo
+            sound = used & jnp.all(jnp.isfinite(y), axis=1)
+            ws = jnp.where(used, jnp.where(
+                sound, jnp.take(w.reshape(-1), window), jnp.nan), 0.0)
+            by_row = jnp.where(
+                row_of[None, :] == jnp.arange(rows)[:, None],
+                ws[None, :], 0.0)
+            exact = jax.lax.Precision.HIGHEST
+            return jnp.dot(
+                by_row, jnp.where(sound[:, None], y.astype(jnp.float32), 0.0),
+                precision=(exact, jax.lax.Precision.DEFAULT
+                           if y.dtype == jnp.bfloat16 else exact))
+
+        def gather(y, lo):
+            # pick-major: ``[top_k, rows, hid]`` is the gather's own layout,
+            # where ``[rows, top_k, hid]`` pads ``top_k`` to a sublane tile
+            # and is copied into it
+            at = slot_of.T - lo
+            here = (idx.T < n_held) & (at >= 0) & (at < cap)
+            y = jnp.take(y, jnp.clip(at, 0, cap - 1).reshape(-1),
+                         axis=0).reshape(top_k, rows, hid)
+            y = jnp.where(here[:, :, None], y.astype(jnp.float32), 0.0)
+            return jnp.sum(y * w.T[:, :, None], axis=0)
+
+        def one_pass(carry):
+            lo, out = carry
+            with jax.named_scope("moe_route"):
+                window = jax.lax.dynamic_slice(order, (lo,), (cap,))
+                row_of = window // top_k
+                xs = jnp.take(h2, row_of, axis=0)
+                in_pass = jnp.clip(ends - lo, 0, cap) \
+                    - jnp.clip(ends - counts - lo, 0, cap)
+            y = experts(xs, in_pass)
+            with jax.named_scope("moe_route"):
+                return lo + cap, out + (product(y, lo, window, row_of)
+                                        if by_product else gather(y, lo))
+
+        return jax.lax.while_loop(
+            lambda carry: carry[0] < held, one_pass,
+            (jnp.int32(0), jnp.zeros((rows, hid), jnp.float32)))[1]
+
+    with jax.named_scope("moe"):
+        with jax.named_scope("moe_route"):
+            w, picks, idx, counts, stats = _route(
+                h2, router, top_k, live, renormalize, n_held, **routing)
+        out = (every_pick if cap is None else held_pairs)(w, idx, counts)
     out = out.astype(h.dtype).reshape(lead + (hid,))
     if return_picks:
         return out, stats, picks.reshape(lead + (top_k,))

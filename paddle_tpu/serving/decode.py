@@ -2413,7 +2413,7 @@ def sample_rows(logits, keys, temps, top_ks):
 @jax.named_scope("prefill")
 def _moe_outputs(stats):
     """A program's routed-FFN outputs as a tuple: nothing for a dense model,
-    the layers' summary ``[L, 4]``, and where the layers also returned
+    the layers' summary ``[L, 5]``, and where the layers also returned
     their picks (``[L, ..., top_k]``) those as ``[L, rows, top_k]``."""
     if stats is None:
         return ()
@@ -2513,7 +2513,7 @@ def _prefill_impl(params, ids, lengths, keys, temps, top_ks, *, nh, nkv,
     keeps it out of every real position's attention, and the cache slot
     masks it by ``lengths`` until decode overwrites it. A routed-FFN
     model (``router`` in ``params``) returns a fifth value, the layers'
-    routing summary ``[L, 4]`` int32 (``kernels.moe_ffn.STATS``); its
+    routing summary ``[L, 5]`` int32 (``kernels.moe_ffn.STATS``); its
     padding columns make no (token, expert) pair; with ``return_picks`` a
     sixth, the experts every position picked, ``[L, G * S_pad, top_k]`` int32
     by the router's ids (``serving.routing_record``). A model with latent
@@ -3046,7 +3046,7 @@ def _packed_span_forward(params, pool_k, pool_v, tables, ids, seg, pos,
     at its logical position (dead rows — ``seg == R`` — and positions
     past the logical capacity DROP), attention runs through the ragged
     paged kernel or its jnp oracle. Returns ``(x [1, T, H], pk, pv,
-    moe_stats)``: the routed layers' summary ``[L, 4]`` int32 of a
+    moe_stats)``: the routed layers' summary ``[L, 5]`` int32 of a
     routed-FFN model (dead packed rows make no pair), else None; with
     ``return_picks`` the pair ``(summary, picked experts [L, 1, T, top_k])``.
     A model with latent attention (``mla``) writes one row a token into the K side,

@@ -750,7 +750,7 @@ class ContinuousBatchingEngine:
                       "policy_preemptions": 0,
                       "moe_pairs": 0, "moe_experts_touched": 0,
                       "moe_max_expert_pairs": 0, "moe_picks": 0,
-                      "moe_layer_calls": 0,
+                      "moe_compact_calls": 0, "moe_layer_calls": 0,
                       "state_rows": 0, "state_restarts_fault": 0,
                       "state_restarts_preempt": 0}
         # fault-injection hook (serving/faults.py): called with the
@@ -1069,9 +1069,10 @@ class ContinuousBatchingEngine:
 
     def _count_moe(self, summary):
         """Add one program call's routing summary (``()`` from a dense
-        model's program, else one ``[L, 4]`` int32 array, per routed layer
+        model's program, else one ``[L, 5]`` int32 array, per routed layer
         ``kernels.moe_ffn.STATS``: live pairs on held experts, experts
-        touched, the fullest expert's pairs, picks made) to the
+        touched, the fullest expert's pairs, picks made, 1 for a call of
+        one pass on the buffer of the pairs this chip's experts take) to the
         always-on counters. The array rides the fetch that fences the
         step's tokens: no second sync. Returns the call's totals as span
         args, or None."""

@@ -109,7 +109,8 @@ CARRIED_ENGINE_STATS = (
     "spec_accepted", "spec_tokens", "decode_calls", "tokens_generated",
     "mtick_syncs", "mtick_ticks", "step_prefill_tokens",
     "step_decode_tokens", "moe_pairs", "moe_experts_touched",
-    "moe_max_expert_pairs", "moe_picks", "moe_layer_calls",
+    "moe_max_expert_pairs", "moe_picks", "moe_compact_calls",
+    "moe_layer_calls",
     "steps_dispatched_ahead", "state_rows", "state_restarts_fault",
     "state_restarts_preempt",
 ) + tuple("drains_" + r for r in DRAIN_REASONS)
@@ -562,6 +563,10 @@ class ServingGateway:
                      "(whose weights a layer call read)."),
                     ("max_expert_pairs", "Pairs on the fullest expert of "
                      "each layer call."),
+                    ("compact_calls", "Routed-FFN layer calls that ran one "
+                     "pass on a pair buffer sized for the pairs this "
+                     "engine's experts take, not for every pick (over "
+                     "layer_calls: their share)."),
                     ("layer_calls", "Routed-FFN layer calls (layers x "
                      "program calls).")):
                 r.counter(f"serving_moe_{stat}_total",

@@ -663,12 +663,18 @@ class TestMosaicCompilesQwen3Next:
             v5e((self.R,), i32), v5e((), i32))
         assert n == 1
 
-    @pytest.mark.parametrize("rows", [640, 128], ids=["chunk", "decode_only"])
+    @pytest.mark.parametrize("rows", [640, 128, 8192],
+                             ids=["chunk", "decode_only", "whole_prompt"])
     def test_sixty_four_held_experts_read_their_stacks_in_place(self, v5e,
                                                                  rows):
         """Three matrices an expert at 512, 64 of a router's 512 held: three
-        grouped matmuls, and none of the three stacks ``[3, 64, ...]`` (384
-        MiB each) is copied."""
+        grouped matmuls on a buffer of 1,664 or 384 pair slots (``m`` of the
+        pairs a share takes, ISSUE 57: one body, run in passes), and none of
+        the three stacks ``[3, 64, ...]`` (384 MiB each) is copied into the
+        loop. The decode step goes back by the product over the slots, the
+        other two by the gather by pair, pick-major: at a whole-prompt
+        program's 8,192 rows the buffer is 20,480 slots and the temporaries
+        451 MiB where a slot a pick took 835."""
         hid, wid, exp, places = 2048, 512, 64, 3
 
         def ffn(h, router, w_gate, w_up, w_down, layer):
@@ -680,8 +686,12 @@ class TestMosaicCompilesQwen3Next:
                 v5e((1, rows, hid)), v5e((hid, 512)),
                 v5e((places, exp, hid, wid)), v5e((places, exp, hid, wid)),
                 v5e((places, exp, wid, hid)), v5e((), jnp.int32)).compile()
+        cap = moe_ffn._capacity(rows * 10, exp, 512)
+        assert cap == {640: 1664, 128: 384, 8192: 20480}[rows]
+        assert (cap <= moe_ffn.PRODUCT_SLOTS) == (rows == 128)
         assert compiled.as_text().count("tpu_custom_call") == 3
-        assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+        assert compiled.memory_analysis().temp_size_in_bytes < (
+            512 if rows == 8192 else 128) * 2 ** 20
 
 
 class TestMosaicCompilesMiMoV2Flash:

@@ -332,10 +332,13 @@ def test_softmax_router_is_unchanged_by_the_new_argument():
 
 #: sha256[:16] of the lowered text of DeepSeek-V2-tiny's unified step and
 #: whole-prompt prefill, by attention path: PR 42's tree (a450a4c), but the
-#: "pallas" step, which is PR 45's tree's (PR 42's was 8e267d88bc2173d4)
+#: "pallas" step, which is PR 45's tree's (PR 42's was 8e267d88bc2173d4), and
+#: all four recorded again in PR 57, whose routed FFN returns a fifth stat
+#: and builds the buffer of a chip's share of the pairs (before it: steps
+#: 20b9ff0e2b430953 / 0e9bb0b2f4733e93, prefill 96dc5e47a4e1614a)
 PR42_PROGRAMS_PR45_KERNEL = {
-    "jnp": ("20b9ff0e2b430953", "96dc5e47a4e1614a"),
-    "pallas": ("0e9bb0b2f4733e93", "96dc5e47a4e1614a")}
+    "jnp": ("964a99292e901700", "73034f58a016e0ec"),
+    "pallas": ("a02081de10f46fe2", "73034f58a016e0ec")}
 
 
 @pytest.mark.parametrize("attention", sorted(PR42_PROGRAMS_PR45_KERNEL))
@@ -348,7 +351,11 @@ def test_a_tree_with_no_indexer_runs_the_programs_it_ran(attention):
     kernel's walk (``_walk_ahead`` in place of the two-slot walk, the
     values past ``kvlen`` zeroed by a select in every group in place of a
     ``lax.cond``) and nothing else of the step: the "jnp" step, which runs
-    everything but that kernel, and both prefills still match PR 42's."""
+    everything but that kernel, and both prefills still match PR 42's.
+    PR 57 changed the routed FFN of all four (``kernels/moe_ffn.py``: the
+    pair buffer under a held range, ``STATS``' fifth entry) and nothing of
+    attention: recorded again, the two steps still differing by the kernel
+    alone and the two prefills still one text."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the recorded texts are jax 0.9.0's")
     paddle.seed(11)

@@ -312,6 +312,7 @@ def test_metrics_carry_the_routing_counters(http_server):
                            "serving_moe_picks_total",
                            "serving_moe_experts_touched_total",
                            "serving_moe_layer_calls_total",
+                           "serving_moe_compact_calls_total",
                            "serving_moe_max_expert_pairs_total"}
     assert values["serving_moe_layer_calls_total"] >= 2 * 4
     # dropless: K pairs for every live token of every layer call
@@ -319,6 +320,8 @@ def test_metrics_carry_the_routing_counters(http_server):
     # every expert is held here: each pick made a pair
     assert values["serving_moe_picks_total"] \
         == values["serving_moe_pairs_total"]
+    # ... so no call has a smaller buffer than every pick's to run on
+    assert values["serving_moe_compact_calls_total"] == 0
     assert values["serving_moe_max_expert_pairs_total"] \
         <= values["serving_moe_pairs_total"]
     assert srv.gateway.engine.decode_compilations() == 2
