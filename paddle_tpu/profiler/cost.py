@@ -2,10 +2,8 @@
 "Cost attribution & /debug/profile").
 
 PR 9's span tracer says *where wall-time goes*; this module says *what
-crosses the host↔device boundary* — the quantity the ROADMAP's
-mega-kernel item is gated on ("measured dispatch count per decoded
-token drops ≥5×" needs an exact baseline before any optimisation PR can
-claim the win, MPK / PAPERS.md). A :class:`CostObservatory` wraps every
+crosses the host↔device boundary*: launches and bytes a decoded token
+(MPK / PAPERS.md). A :class:`CostObservatory` wraps every
 jitted program the engine hands out of its shared jit-cache in a
 counting facade (:class:`_CountedProgram`) and records, per program
 key:
@@ -111,8 +109,7 @@ def _census_walk(jaxpr):
     - ``while`` bodies count ONCE into the totals (the trip count is a
       runtime value) and additionally append their own PER-ITERATION
       census to ``loop_bodies`` — the multi-tick tail's while body is
-      exactly the "launches per decode tick" quantity the mega-kernel
-      claim is pinned on;
+      exactly the "launches per decode tick" quantity;
     - ``cond`` branches contribute their maximum (the worst launch
       count a dispatch can pay);
     - a ``pallas_call``'s inner jaxpr is NEVER recursed into — the
@@ -180,9 +177,8 @@ def jaxpr_census(fn, *args) -> dict:
 
     ``loop_bodies`` holds the PER-ITERATION census of each
     ``while_loop`` body — for the serving multi-tick program that is
-    the per-decode-tick launch count: O(num_layers) for the scanned
-    baseline, exactly 1 for the fused whole-tick kernel (README
-    "One-kernel decode")."""
+    the per-decode-tick launch count, O(num_layers) for the scanned
+    layer stack (README "Collective overlap")."""
     closed = jax.make_jaxpr(fn)(*args)
     pallas, coll, bodies = _census_walk(closed.jaxpr)
     return {"pallas_calls": pallas, "collectives": coll,

@@ -2200,7 +2200,7 @@ _OVERLAP_CHUNKS = 2
 def _permute_allreduce(x, tp):
     """Ring reduce-scatter + all-gather over ``collective-permute``
     steps — the fp wire schedule of ``collective_overlap=True`` (README
-    "One-kernel decode"; "Fused Computation-Collective Operations",
+    "Collective overlap"; "Fused Computation-Collective Operations",
     PAPERS.md). The hidden axis splits into ``tp`` pieces; ``tp - 1``
     ``ppermute`` hops accumulate each piece's cross-shard sum around
     the ring (reduce-scatter), ``tp - 1`` more hops gather the summed
@@ -2847,8 +2847,7 @@ def build_paged_suffix_prefill_fn(*, nh, nkv, hd, eps, theta, tied,
 # ------------------------------------------------------ unified ragged step
 def _fused_decode_tick(params, stack, head, tables, sin, cos, tok, pk_all,
                        pv_all, lens, kys, app_mask, temps, top_ks, *, nh,
-                       nkv, hd, eps, decode_attn, tp_reduce=None,
-                       a8=False, fused=False):
+                       nkv, hd, eps, decode_attn, tp_reduce=None, a8=False):
     """ONE fused decode tick over all rows — THE shared tail body of
     the unified ragged step's scan and the multi-tick step's
     while_loop (the two must compute identically or ``decode_ticks>1``
@@ -2858,22 +2857,7 @@ def _fused_decode_tick(params, stack, head, tables, sin, cos, tok, pk_all,
     masked rows drop their append and attend at their frozen length.
     Returns ``(next_tok, pk', pv', keys')``; the CALLER advances
     ``lens`` by ``app_mask``.
-
-    ``fused=True`` (the engine's ``fused_tick`` knob, README
-    "One-kernel decode") dispatches the tick to
-    ``kernels.pallas_fused_decode_tick`` — ONE whole-tick
-    ``pallas_call`` on the single-chip Pallas geometry (the layer loop
-    as the grid dimension, sampling epilogue included), the jnp oracle
-    that replays THIS function's op sequence everywhere else — so a
-    tick is O(1) device launches instead of O(layers), byte-identical
-    either way.
     """
-    if fused:
-        from ..kernels.pallas_fused_decode_tick import fused_decode_tick
-        return fused_decode_tick(
-            params, stack, head, tables, sin, cos, tok, pk_all, pv_all,
-            lens, kys, app_mask, temps, top_ks, nh=nh, nkv=nkv, hd=hd,
-            eps=eps, decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
     R = tok.shape[0]
     nb, bs = _kv_data(pk_all).shape[1], _kv_data(pk_all).shape[2]
     mb = tables.shape[1]
@@ -3283,9 +3267,9 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                       qstart, qlen, kvlen, dec_mask, keys, temps, top_ks,
                       prev_toks, take, chunk_keys, adopt, state=None,
                       *, n_steps, nh, nkv, hd, eps, theta, tied,
-                      decode_attn, tp_reduce=None, a8=False, fused=False,
-                      moe=None, mla=None, return_picks=False, gdn=None,
-                      ssm=None, dsa=None, ssd=None, rotary=None, swa=None):
+                      decode_attn, tp_reduce=None, a8=False, moe=None,
+                      mla=None, return_picks=False, gdn=None, ssm=None,
+                      dsa=None, ssd=None, rotary=None, swa=None):
     """THE unified serving step: one device call that advances every
     slot's span — decode rows (span 1) and prefill chunks (span n) —
     through the same block tables (README "Unified ragged attention").
@@ -3401,8 +3385,7 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         nxt, npk, npv, nkeys = _fused_decode_tick(
             params, stack, head, tables, sin, cos, tok, pk_all, pv_all,
             lens, kys, dec_mask, temps, top_ks, nh=nh, nkv=nkv, hd=hd,
-            eps=eps, decode_attn=decode_attn, tp_reduce=tp_reduce,
-            a8=a8, fused=fused)
+            eps=eps, decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
         return (nxt, npk, npv, lens + dec_mask, nkeys), nxt
 
     if n_steps > 1:
@@ -3419,10 +3402,10 @@ def _ragged_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
 def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
                          decode_attn, donate=None, tp=1,
                          collective_dtype="fp", kv_quant=False,
-                         wq8=False, a8=False, fused=False,
-                         collective_overlap=False, moe=None, mla=None,
-                         return_picks=False, gdn=None, ssm=None, dsa=None,
-                         ssd=None, rotary=None, swa=None):
+                         wq8=False, a8=False, collective_overlap=False,
+                         moe=None, mla=None, return_picks=False, gdn=None,
+                         ssm=None, dsa=None, ssd=None, rotary=None,
+                         swa=None):
     """One jitted unified serving step (``_ragged_step_impl``): shapes
     depend only on ``(num_slots, packed size)`` plus the fused
     ``n_steps`` — one compilation per (packed size, ``n_steps``) serves
@@ -3449,7 +3432,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
             decode_attn=decode_attn,
             tp_reduce=_tp_allreduce(collective_dtype, tp,
                                     overlap=collective_overlap),
-            a8=a8, fused=fused)
+            a8=a8)
         rep = PartitionSpec()
         pool = _pool_pspec(kv_quant)
         return jax.jit(_tp_shard(
@@ -3461,8 +3444,7 @@ def build_ragged_step_fn(*, n_steps, nh, nkv, hd, eps, theta, tied,
         functools.partial(
             _ragged_step_impl, n_steps=n_steps, nh=nh, nkv=nkv, hd=hd,
             eps=eps, theta=theta, tied=tied, decode_attn=decode_attn,
-            a8=a8, fused=fused, moe=moe, mla=mla,
-            return_picks=return_picks,
+            a8=a8, moe=moe, mla=mla, return_picks=return_picks,
             **({} if gdn is None else {"gdn": gdn}),
             **({} if ssm is None else {"ssm": ssm}),
             **({} if dsa is None else {"dsa": dsa}),
@@ -3482,7 +3464,7 @@ def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
                          qstart, qlen, kvlen, dec_mask, keys, temps,
                          top_ks, eos_ids, budgets, n_ticks, *, max_ticks,
                          nh, nkv, hd, eps, theta, tied, decode_attn,
-                         tp_reduce=None, a8=False, fused=False):
+                         tp_reduce=None, a8=False):
     """THE multi-tick serving step (README "Multi-tick decode"): the
     unified ragged step with the host driven out of the per-token loop.
     Tick 0 is ``_ragged_step_impl``'s packed forward verbatim (decode
@@ -3533,41 +3515,12 @@ def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
     head = _dq_head(params, tied, params["embed"].dtype, a8)
 
     # ----------------------------------- tick 0 (shared packed forward)
-    def _packed_tick0(pk_in, pv_in):
-        x, pk2, pv2, _ = _packed_span_forward(
-            params, pk_in, pv_in, tables, ids, seg, pos, qstart, qlen,
-            kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
-            decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
-        tok0, keys_t0 = _span_last_sample(params, head, x, qstart,
-                                          qlen, keys, temps, top_ks,
-                                          eps)
-        return tok0, keys_t0, pk2, pv2
-
-    if fused:
-        # a launch with NO chunk rows — every span a qlen<=1 decode
-        # row, the only state the scheduler fuses ticks for — runs
-        # tick 0 through the SAME fused whole-tick program as the
-        # tail, so the whole sync is one launch per tick; mixed
-        # launches (n_ticks == 1 by scheduler policy) keep the packed
-        # forward verbatim. Byte-identity of the two tick-0 spellings
-        # on pure-decode spans is the standing multi-tick contract
-        # (body ticks ≡ single-tick packed steps), applied at tick 0.
-        tok_in = ids[jnp.maximum(qstart + qlen - 1, 0)]
-        lens_in = jnp.where(dec_mask > 0, kvlen - 1, 0)
-
-        def _fused_tick0(pk_in, pv_in):
-            nxt, npk, npv, nkeys = _fused_decode_tick(
-                params, stack, head, tables, sin, cos, tok_in, pk_in,
-                pv_in, lens_in, keys, dec_mask, temps, top_ks, nh=nh,
-                nkv=nkv, hd=hd, eps=eps, decode_attn=decode_attn,
-                tp_reduce=tp_reduce, a8=a8, fused=True)
-            return nxt, nkeys, npk, npv
-
-        tok0, keys_t0, pk, pv = jax.lax.cond(
-            jnp.all(qlen <= 1), _fused_tick0, _packed_tick0,
-            pool_k, pool_v)
-    else:
-        tok0, keys_t0, pk, pv = _packed_tick0(pool_k, pool_v)
+    x, pk, pv, _ = _packed_span_forward(
+        params, pool_k, pool_v, tables, ids, seg, pos, qstart, qlen,
+        kvlen, sin, cos, nh=nh, nkv=nkv, hd=hd, eps=eps,
+        decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
+    tok0, keys_t0 = _span_last_sample(params, head, x, qstart, qlen, keys,
+                                      temps, top_ks, eps)
 
     # ------------------------------- fused tail (alive-masked, runtime n)
     lens0 = jnp.where(dec_mask > 0, kvlen, 0)
@@ -3592,8 +3545,7 @@ def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
         nxt, npk, npv, nkeys = _fused_decode_tick(
             params, stack, head, tables, sin, cos, tok, pk_all, pv_all,
             lens, kys, am, temps, top_ks, nh=nh, nkv=nkv, hd=hd,
-            eps=eps, decode_attn=decode_attn, tp_reduce=tp_reduce,
-            a8=a8, fused=fused)
+            eps=eps, decode_attn=decode_attn, tp_reduce=tp_reduce, a8=a8)
         tb = tb.at[t].set(nxt)
         kb = kb.at[t].set(nkeys)
         # the host's _maybe_finish rule, in-program: after emitting
@@ -3612,8 +3564,7 @@ def _multitick_step_impl(params, pool_k, pool_v, tables, ids, seg, pos,
 def build_multitick_step_fn(*, max_ticks, nh, nkv, hd, eps, theta, tied,
                             decode_attn, donate=None, tp=1,
                             collective_dtype="fp", kv_quant=False,
-                            wq8=False, a8=False, fused=False,
-                            collective_overlap=False):
+                            wq8=False, a8=False, collective_overlap=False):
     """One jitted multi-tick serving step (``_multitick_step_impl``):
     shapes depend only on ``(num_slots, token_budget, max_ticks)`` —
     the tick count actually run is a RUNTIME argument, so one
@@ -3632,7 +3583,7 @@ def build_multitick_step_fn(*, max_ticks, nh, nkv, hd, eps, theta, tied,
             decode_attn=decode_attn,
             tp_reduce=_tp_allreduce(collective_dtype, tp,
                                     overlap=collective_overlap),
-            a8=a8, fused=fused)
+            a8=a8)
         rep = PartitionSpec()
         pool = _pool_pspec(kv_quant)
         return jax.jit(_tp_shard(
@@ -3644,7 +3595,7 @@ def build_multitick_step_fn(*, max_ticks, nh, nkv, hd, eps, theta, tied,
         functools.partial(
             _multitick_step_impl, max_ticks=int(max_ticks), nh=nh,
             nkv=nkv, hd=hd, eps=eps, theta=theta, tied=tied,
-            decode_attn=decode_attn, a8=a8, fused=fused),
+            decode_attn=decode_attn, a8=a8),
         donate_argnums=(1, 2) if donate else ())
 
 

@@ -238,7 +238,7 @@ class ContinuousBatchingEngine:
                  quantize_weights=False, quantize_activations=False,
                  tp=1, collective_dtype="fp",
                  host_tier_bytes=0, priority_classes=None,
-                 fused_tick=False, collective_overlap=False):
+                 collective_overlap=False):
         c = model.config
         # multi-tenant SLO policy (README "Multi-tenant SLO serving"):
         # like host_tier_bytes, policy not geometry — classes change
@@ -328,7 +328,7 @@ class ContinuousBatchingEngine:
             # every switch that runs another program raises, none falls back
             off = {"quantize_weights": bool(quantize_weights),
                    "quantize_activations": bool(quantize_activations),
-                   "tp > 1": int(tp) > 1, "fused_tick": bool(fused_tick),
+                   "tp > 1": int(tp) > 1,
                    "decode_ticks > 1": int(decode_ticks) > 1,
                    "spec_decode": bool(spec_decode),
                    "decode_chunk > 1": int(decode_chunk) > 1,
@@ -627,27 +627,9 @@ class ContinuousBatchingEngine:
                 "prefill_chunk, kv_dtype, quantize_weights, "
                 "quantize_activations, tp, collective_overlap, "
                 "host_tier_bytes, priority_classes. decode_ticks > 1 "
-                "composes with those plus fused_tick — pick one of the "
-                "two step shapes")
-        # one-kernel decode (README "One-kernel decode"): fused_tick
-        # swaps the scanned per-layer tick body for ONE Pallas program
-        # whose grid dimension IS the layer loop — a tick becomes O(1)
-        # device launches instead of O(layers). Same op sequence, same
-        # bits: the kernel replays _fused_decode_tick exactly, and the
-        # jnp oracle (kernels.pallas_fused_decode_tick) covers the
-        # geometries the single-device mega-kernel can't express
-        # (in-kernel collectives, int8 activations). Default False keeps
-        # every banked baseline byte-identical.
-        self._fused_tick = bool(fused_tick)
-        if self._fused_tick and self._spec:
-            raise ValueError(
-                "fused_tick=True is incompatible with spec_decode: the "
-                "fused program is the one-token tick body, and a verify "
-                "launch is a spec_len-token span. fused_tick composes "
-                "with: prefix_cache, prefill_chunk, decode_ticks, "
-                "kv_dtype, quantize_weights, quantize_activations, tp, "
-                "collective_overlap, host_tier_bytes, priority_classes")
-        # TP compute/collective overlap (README "One-kernel decode"):
+                "composes with the same — pick one of the two step "
+                "shapes")
+        # TP compute/collective overlap (README "Collective overlap"):
         # the per-layer all-reduce pair (post o-proj + post down-proj
         # tp_reduce sites) switches to a chunked reduce-scatter /
         # all-gather schedule so chunk k's wire time hides behind chunk
@@ -664,10 +646,6 @@ class ContinuousBatchingEngine:
                 "to overlap")
         if self._coll_overlap:
             self._tptag = self._tptag + ("ov",)
-        # jit-key tag for the fused-tick variant: appended LAST (after
-        # kv8f/a8/tpN) so every pre-existing key stays byte-identical
-        # on default engines
-        self._fktag = ("fk",) if self._fused_tick else ()
         if headroom_mult is not None and float(headroom_mult) <= 0:
             raise ValueError(
                 f"headroom_mult must be > 0 (or None for fixed-cap chunk "
@@ -738,7 +716,6 @@ class ContinuousBatchingEngine:
                       **{program_stat(r): 0 for r in self._step_rows},
                       **{"drains_" + r: 0 for r in DRAIN_REASONS},
                       "mtick_syncs": 0, "mtick_ticks": 0,
-                      "mtick_pure_syncs": 0,
                       "last_decode_ticks": 0,
                       "spec_steps": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "spec_tokens": 0,
@@ -1126,13 +1103,11 @@ class ContinuousBatchingEngine:
         # (``_wrap_prog``) under its own name
         key = ("ragged", self.num_slots, self._token_budget, int(rows),
                int(n_steps), self.config.decode_attention) \
-            + self._kvtag + self._wtag + self._atag + self._tptag \
-            + self._fktag
+            + self._kvtag + self._wtag + self._atag + self._tptag
         if key not in self._jit:
             self._jit[key] = build_ragged_step_fn(
                 n_steps=int(n_steps),
                 decode_attn=self.config.decode_attention,
-                fused=self._fused_tick,
                 collective_overlap=self._coll_overlap,
                 **self._fn_consts(), **self._tp_consts(),
                 **self._q_consts())
@@ -1150,14 +1125,12 @@ class ContinuousBatchingEngine:
         # argument, so this is the engine's ONE decode program.
         key = ("mtick", self.num_slots, self._token_budget,
                self._decode_ticks, self.config.decode_attention) \
-            + self._kvtag + self._wtag + self._atag + self._tptag \
-            + self._fktag
+            + self._kvtag + self._wtag + self._atag + self._tptag
         if key not in self._jit:
             from .decode import build_multitick_step_fn
             self._jit[key] = build_multitick_step_fn(
                 max_ticks=self._decode_ticks,
                 decode_attn=self.config.decode_attention,
-                fused=self._fused_tick,
                 collective_overlap=self._coll_overlap,
                 **self._fn_consts(), **self._tp_consts(),
                 **self._q_consts())
@@ -1228,19 +1201,11 @@ class ContinuousBatchingEngine:
         return self._coll_dtype
 
     @property
-    def fused_tick(self) -> bool:
-        """Whether the decode tick body runs as ONE fused Pallas
-        program (grid-over-layers mega-kernel; O(1) device launches per
-        tick) instead of the scanned per-layer stack — the public
-        surface for banners/metrics (README "One-kernel decode")."""
-        return self._fused_tick
-
-    @property
     def collective_overlap(self) -> bool:
         """Whether the per-layer TP all-reduce pair runs the chunked
         reduce-scatter/all-gather overlap schedule instead of one psum
         (False on tp=1, where no collective ever runs) — the public
-        surface for banners/metrics (README "One-kernel decode")."""
+        surface for banners/metrics (README "Collective overlap")."""
         return self._coll_overlap
 
     def _record_collectives(self, co, spans):
@@ -1329,13 +1294,8 @@ class ContinuousBatchingEngine:
         the sharded geometry: a tp=N engine counts only its own
         ``("tpN", dtype)``-tagged traces, so the pin covers the
         shard_map program and a tp=1 sibling sharing the jit cache
-        never pollutes it (README "Tensor-parallel serving"). The
-        fused-tick tag joins the tail the same way: a fused engine
-        counts only its own ``fk``-tagged traces, and the pin stays ==1
-        inclusive of the ``fk`` (and ``fk`` x ``tpN`` x ``kv8f``/``a8``)
-        variant geometry (README "One-kernel decode")."""
-        tags = self._kvtag + self._wtag + self._atag + self._tptag \
-            + self._fktag
+        never pollutes it (README "Tensor-parallel serving")."""
+        tags = self._kvtag + self._wtag + self._atag + self._tptag
         if self._spec:
             # spec_len is CONFIG (spec_k + 1), not a runtime variant
             # like the ragged key's n_steps — two engines differing
@@ -2762,12 +2722,6 @@ class ContinuousBatchingEngine:
             self.stats["slot_steps"] += ticks * self.num_slots
             self.stats["mtick_syncs"] += 1
             self.stats["mtick_ticks"] += ticks
-            if not chunk_rows:
-                # every span was a qlen<=1 decode row: the program's
-                # pure-decode predicate held, so a fused engine ran
-                # tick 0 through the whole-tick kernel (the bench's
-                # exact device-launch accounting reads this count)
-                self.stats["mtick_pure_syncs"] += 1
             self.stats["last_decode_ticks"] = ticks
             counts = np.zeros(R, np.int32)  # accepted tokens per slot
             emitted_total = self._accept_decode_rows(

@@ -101,8 +101,7 @@ class EngineFleet:
                  decode_ticks=1, kv_dtype=None, quantize_weights=False,
                  quantize_activations=False,
                  tp=1, collective_dtype="fp", host_tier_bytes=0,
-                 priority_classes=None,
-                 fused_tick=False, collective_overlap=False,
+                 priority_classes=None, collective_overlap=False,
                  registry=None, clock=None, watchdog_deadline_s=None,
                  max_transient_retries=3, retry_backoff_s=0.02,
                  max_restarts=8, fault_hooks=None, trace=False,
@@ -176,10 +175,10 @@ class EngineFleet:
             # different collectives), so replicas with different TP
             # degrees get isolated jit-cache dicts — the same
             # discipline as the kv8/w8 tags
-            # fused_tick and collective_overlap are geometry the same
-            # way: the fused mega-kernel and the ppermute-chain overlap
-            # schedule are different traces of the same step, so
-            # replicas differing in either get isolated jit-cache dicts
+            # collective_overlap is geometry the same way: the
+            # ppermute-chain overlap schedule is a different trace of
+            # the same step, so replicas differing in it get isolated
+            # jit-cache dicts
             geom = (slots[i], smax[i], chunk[i],
                     bool(spec_decode), int(spec_k),
                     int(decode_chunk), int(prefix_block_size),
@@ -187,7 +186,7 @@ class EngineFleet:
                     kv_dtype, bool(quantize_weights),
                     bool(quantize_activations),
                     int(tp), str(collective_dtype),
-                    bool(fused_tick), bool(collective_overlap))
+                    bool(collective_overlap))
             jit = jits.setdefault(geom, {})
 
             def factory(i=i, jit=jit):
@@ -208,7 +207,6 @@ class EngineFleet:
                     tp=tp, collective_dtype=collective_dtype,
                     host_tier_bytes=tiers[i],
                     priority_classes=self.classes,
-                    fused_tick=fused_tick,
                     collective_overlap=collective_overlap,
                     jit_cache=jit)
 

@@ -300,18 +300,6 @@ def main(argv=None):
                          "fewer cross-chip bytes; greedy streams may "
                          "diverge from the fp wire). "
                          "Ignored (no collectives) at --tp 1")
-    ap.add_argument("--fused-tick", action=argparse.BooleanOptionalAction,
-                    default=False,
-                    help="one-kernel decode (README 'One-kernel "
-                         "decode'): "
-                         "run the decode tick's entire layer stack as "
-                         "ONE Pallas program with the layer loop as "
-                         "the grid dimension — a tick is O(1) device "
-                         "launches instead of O(layers), streams stay "
-                         "byte-identical, and the jaxpr launch census "
-                         "on GET /debug/profile pins the count. "
-                         "Composes with --decode-ticks (the fused "
-                         "program is the multi-tick body)")
     ap.add_argument("--collective-overlap",
                     action=argparse.BooleanOptionalAction, default=False,
                     help="TP compute/collective overlap (requires "
@@ -424,7 +412,6 @@ def main(argv=None):
             quantize_weights=args.quantize_weights,
             quantize_activations=args.quantize_activations,
             tp=args.tp, collective_dtype=args.collective_dtype,
-            fused_tick=args.fused_tick,
             collective_overlap=args.collective_overlap,
             classes=args.classes, slo_ttft_ms=args.slo_ttft_ms,
             slo_tpot_ms=args.slo_tpot_ms,
@@ -463,9 +450,8 @@ def main(argv=None):
                 {"tp": fleet.replicas[0].gateway.engine.tp},
             "collective_dtype":
                 fleet.replicas[0].gateway.engine.collective_dtype,
-            # effective-value idiom: whether the engines' decode tick
-            # really runs the one-kernel program / overlap schedule
-            "fused_tick": fleet.replicas[0].gateway.engine.fused_tick,
+            # effective-value idiom: whether the engines' all-reduce
+            # pair really runs the overlap schedule
             "collective_overlap":
                 fleet.replicas[0].gateway.engine.collective_overlap,
             # effective-value idiom: the parsed class table the fleet's
@@ -504,7 +490,6 @@ def main(argv=None):
         quantize_weights=args.quantize_weights,
         quantize_activations=args.quantize_activations,
         tp=args.tp, collective_dtype=args.collective_dtype,
-        fused_tick=args.fused_tick,
         collective_overlap=args.collective_overlap,
         classes=args.classes, slo_ttft_ms=args.slo_ttft_ms,
         slo_tpot_ms=args.slo_tpot_ms,
@@ -543,10 +528,9 @@ def main(argv=None):
                       "mesh_shape": {"tp": server.gateway.engine.tp},
                       "collective_dtype":
                       server.gateway.engine.collective_dtype,
-                      # effective-value idiom: whether the decode tick
-                      # really runs the one-kernel program / overlap
-                      # schedule (README "One-kernel decode")
-                      "fused_tick": server.gateway.engine.fused_tick,
+                      # effective-value idiom: whether the all-reduce
+                      # pair really runs the overlap schedule (README
+                      # "Collective overlap")
                       "collective_overlap":
                       server.gateway.engine.collective_overlap,
                       # effective-value idiom: the EFFECTIVE class

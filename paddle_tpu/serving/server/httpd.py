@@ -509,7 +509,7 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
           quantize_activations=False,
           tp=1, collective_dtype="fp", host_tier_bytes=0,
           classes=None, slo_ttft_ms=None, slo_tpot_ms=None,
-          fused_tick=False, collective_overlap=False):
+          collective_overlap=False):
     """Build engine → gateway → HTTP server and start listening.
 
     ``decode_chunk=1`` is the serving default: chunk fusion trades
@@ -675,7 +675,6 @@ def serve(model, host="127.0.0.1", port=8000, num_slots=8,
             tp=tp, collective_dtype=collective_dtype,
             host_tier_bytes=host_tier_bytes,
             priority_classes=priority_classes,
-            fused_tick=fused_tick,
             collective_overlap=collective_overlap,
             jit_cache=model.__dict__.setdefault("_serving_jit", {}))
 
@@ -705,7 +704,7 @@ def serve_fleet(model, replicas=2, router="affinity", host="127.0.0.1",
                 quantize_activations=False, tp=1,
                 collective_dtype="fp", host_tier_bytes=0,
                 classes=None, slo_ttft_ms=None, slo_tpot_ms=None,
-                fused_tick=False, collective_overlap=False):
+                collective_overlap=False):
     """Build an engine fleet → HTTP server and start listening (README
     "Engine fleet"): ``replicas`` supervised engines — each its own
     paged pool, prefix trie and scheduler, sharing compiled programs
@@ -771,7 +770,7 @@ def serve_fleet(model, replicas=2, router="affinity", host="127.0.0.1",
         tp=tp, collective_dtype=collective_dtype,
         host_tier_bytes=host_tier_bytes,
         priority_classes=priority_classes,
-        fused_tick=fused_tick, collective_overlap=collective_overlap,
+        collective_overlap=collective_overlap,
         registry=registry, clock=clock,
         watchdog_deadline_s=watchdog_deadline_s,
         max_restarts=max_restarts, fault_hooks=fault_hooks,

@@ -31,6 +31,7 @@ import time
 
 import jax
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.serving import ContinuousBatchingEngine, GenerationRequest
@@ -40,6 +41,16 @@ BS = 8       # KV block size
 CHUNK = 16   # prefill chunk: two blocks
 SLOTS = 2
 S_MAX = 96
+
+#: every switch that runs a program other than the default engine's two, a
+#: case each: a model with a state store or a ring raises for each by name
+OTHER_SWITCHES = (
+    dict(quantize_weights=True), dict(tp=2), dict(decode_ticks=4),
+    dict(spec_decode=True), dict(decode_chunk=4), dict(prefix_cache=True),
+    dict(kv_dtype="int8"),
+    pytest.param(dict(kv_dtype="fp8"), id="kv_dtype-fp8"),
+    pytest.param(dict(quantize_weights=True, quantize_activations=True),
+                 id="quantize_activations"))
 
 _MODELS = {}
 

@@ -47,7 +47,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.profiler.cost import CostObservatory, _CountedProgram
+from jax.experimental import pallas as pl
+
+from paddle_tpu.profiler.cost import (CostObservatory, _CountedProgram,
+                                      jaxpr_census)
 from paddle_tpu.profiler.tracing import SpanTracer
 from paddle_tpu.serving import FaultPlan, GenerationRequest, VirtualClock
 from paddle_tpu.serving.server import ServingGateway, serve
@@ -941,22 +944,15 @@ class TestGuardDiscipline:
             body = dec.split(f"def {fn_name}(")[1].split("\ndef ")[0]
             assert body.count("tp_reduce=tp_reduce") == 1, fn_name
 
-    def test_sweep_sees_the_fused_tick_and_overlap_path(self):
-        """ISSUE 20 satellite: the one-kernel decode path stays inside
-        the counted/guarded tree. (a) The fused-tick program launches
-        ONLY through the ``_wrap_prog``-counted ``_ragged_fn``/
-        ``_mtick_fn`` handouts — the ``fk`` tag joins exactly those two
-        keys (never prefill/suffix/spec), so fused dispatches are
-        exactly attributed and the compile pin stays inclusive. (b) The
-        kernel module itself is instrumentation-free (pure program —
-        accounting happens at the engine chokepoint, so the sweep's
-        serving/-scope is sufficient). (c) The overlap schedule is
-        constructed at ONE site (``_tp_allreduce``) and applied at
-        exactly the o-proj + down-proj ``tp_reduce`` pair the
-        per-layer contract already pins — the three DECODE builders
-        pass ``overlap=`` while the prefill/suffix builders cannot
-        (decode latency is the target; prefill keys stay banked). (d)
-        The census accessor rides the ``_wrap_prog`` chokepoint: the
+    def test_sweep_sees_the_overlap_path(self):
+        """The collective-overlap path stays inside the counted/guarded
+        tree. (a) The overlap schedule is constructed at ONE site
+        (``_tp_allreduce``) and applied at exactly the o-proj +
+        down-proj ``tp_reduce`` pair the per-layer contract already
+        pins — the three DECODE builders pass ``overlap=`` while the
+        prefill/suffix builders cannot (decode latency is the target;
+        prefill keys stay banked). (b) Every step program is handed
+        out through ``_wrap_prog``. (c) The census accessor rides the ``_wrap_prog`` chokepoint: the
         ONE ``record_census`` call site is ``_CountedProgram.__call__``
         — no serving code records a census of its own."""
         dec_path = SERVING_DIR / "decode.py"
@@ -971,7 +967,7 @@ class TestGuardDiscipline:
                     and isinstance(n.func, ast.Name)
                     and n.func.id == callee]
 
-        # (c) one construction site: _overlap_reduce/_permute_allreduce
+        # (a) one construction site: _overlap_reduce/_permute_allreduce
         # are referenced (outside their own defs) only from
         # _tp_allreduce and _overlap_reduce respectively
         for helper, owner in (("_overlap_reduce", "_tp_allreduce"),
@@ -998,30 +994,13 @@ class TestGuardDiscipline:
         # no third application point exists anywhere in the module
         assert dec.count("tp_reduce(") == dec.count("tp_reduce(o)") \
             + dec.count("tp_reduce(m)") + dec.count("tp_reduce(x)")
-        # (a) the fused program rides the counted handouts: the kernel
-        # entry point is called ONLY from _fused_decode_tick (lazy
-        # import), and the fk tag joins exactly the ragged+mtick keys
-        assert dec.count("import fused_decode_tick") == 1
-        body = dec.split("def _fused_decode_tick(")[1].split("\ndef ")[0]
-        assert "fused_decode_tick(" in body
+        # (b) the step programs ride the counted handouts
         eng = (SERVING_DIR / "engine.py").read_text()
-        for fn_name, has_fk in (("_ragged_fn", True), ("_mtick_fn", True),
-                                ("_spec_fn", False), ("_suffix_fn", False),
-                                ("_prefill_fn", False)):
+        for fn_name in ("_ragged_fn", "_mtick_fn", "_spec_fn",
+                        "_suffix_fn", "_prefill_fn"):
             fbody = eng.split(f"def {fn_name}(")[1].split("\n    def ")[0]
             assert "_wrap_prog" in fbody, fn_name
-            assert ("_fktag" in fbody) is has_fk, fn_name
-        # and the compile pin counts fk programs as decode programs
-        dc = eng.split("def decode_compilations(")[1].split("\n    def ")[0]
-        assert "_fktag" in dc
-        # (b) the kernel module is pure: no tracer/cost/observatory
-        # touch — accounting stays at the engine chokepoint
-        kern = (SERVING_DIR.parent / "kernels"
-                / "pallas_fused_decode_tick.py").read_text()
-        for needle in ("tracer", "self.cost", "CostObservatory",
-                       "record_"):
-            assert needle not in kern, needle
-        # (d) census recording has ONE call site: the counted-program
+        # (c) census recording has ONE call site: the counted-program
         # chokepoint in the profiler itself
         cost_src = (SERVING_DIR.parent / "profiler" / "cost.py").read_text()
         assert cost_src.count("co.record_census(") == 1
@@ -1108,6 +1087,80 @@ class TestGuardDiscipline:
 
 
 # ---------------------------------------------------- profiler CLI (json)
+# ------------------------------------------------------ the census's rules
+def _launch(x):
+    """One interpreted kernel launch; its body holds a loop of its own."""
+    def kernel(x_ref, o_ref):
+        o_ref[...] = jax.lax.while_loop(lambda a: a[0] < 3.0,
+                                        lambda a: a + 1.0, x_ref[...])
+    return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        x.shape, x.dtype), interpret=True)(x)
+
+
+def _scanned(x):
+    return jax.lax.scan(lambda c, _: (_launch(c), None), x, None,
+                        length=3)[0]
+
+
+def _looped(x):
+    return jax.lax.while_loop(lambda c: c[0] < 5.0,
+                              lambda c: _launch(_launch(c)), x)
+
+
+def _branched(x):
+    return jax.lax.cond(x[0] > 0, _launch,
+                        lambda c: _launch(_launch(_launch(c))), x)
+
+
+def _sharded(x):
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("x",))
+    spec = jax.sharding.PartitionSpec("x")
+    return jax.shard_map(lambda c: jax.lax.psum(_launch(c), "x"),
+                         mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)
+
+
+@jax.custom_vjp
+def _with_vjp(x):
+    return _launch(x)
+
+
+_with_vjp.defvjp(lambda x: (_launch(x), None), lambda _, g: (g,))
+
+
+def _contained(x):
+    return jax.jit(_launch)(x) + jax.checkpoint(_launch)(x) + _with_vjp(x)
+
+
+def _while_in_scan(x):
+    return jax.lax.scan(lambda c, _: (_looped(c), None), x, None,
+                        length=4)[0]
+
+
+_TWO_LAUNCHES = {"pallas_calls": 2, "collectives": 0}
+
+#: rule -> (program, pallas_calls, collectives, loop_bodies)
+CENSUS_RULES = {
+    "scan-times-trip-count": (_scanned, 3, 0, []),
+    "while-once-and-its-body": (_looped, 2, 0, [_TWO_LAUNCHES]),
+    "cond-max-of-branches": (_branched, 3, 0, []),
+    "kernel-body-not-walked": (_launch, 1, 0, []),
+    "collectives-under-shard-map": (_sharded, 1, 1, []),
+    "jit-remat-custom-vjp-walked": (_contained, 3, 0, []),
+    "while-in-scan-one-body": (_while_in_scan, 8, 0, [_TWO_LAUNCHES]),
+    "empty-program-zeros": (lambda x: x, 0, 0, []),
+}
+
+
+@pytest.mark.parametrize("rule", list(CENSUS_RULES))
+def test_census_rule(rule):
+    """``cost._census_walk``'s rules, each on a program of a few lines
+    (traced by ``jax.make_jaxpr``, never run)."""
+    fn, pallas, coll, bodies = CENSUS_RULES[rule]
+    assert jaxpr_census(fn, jnp.zeros((8,), jnp.float32)) == {
+        "pallas_calls": pallas, "collectives": coll, "loop_bodies": bodies}
+
+
 class TestProfilerCLIChrome:
     @pytest.fixture(scope="class")
     def trace_file(self, model, tmp_path_factory):

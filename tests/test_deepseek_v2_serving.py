@@ -346,7 +346,6 @@ SWITCHES = {
     "quantize_activations": dict(quantize_weights=True,
                                  quantize_activations=True),
     "tp > 1": dict(tp=2),
-    "fused_tick": dict(fused_tick=True),
     "decode_ticks > 1": dict(decode_ticks=4),
     "spec_decode": dict(spec_decode=True),
     "decode_chunk > 1": dict(decode_chunk=8),

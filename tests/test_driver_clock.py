@@ -354,14 +354,16 @@ class TestMetricsFamily:
         # the thread cannot have computed for longer than it lived
         assert sum(v for k, v in fam.items() if k.endswith("/cpu")) <= wall
         # the long visits: a series a phase in both families, seconds no
-        # more than the phase's own, and a long one is longer than the bar
+        # more than the phase's own, and a long one is longer than the bar.
+        # Read with the driver stopped: only it writes the clock, and a
+        # live one may end a long idle wait between a scrape's reads
+        assert gw.shutdown(drain=True, timeout=60)
         both = _family(gw, long_visits=True)
         for p in PHASES:
             n, secs = (both[p + "/" + name] for name in LONG_FAMILIES)
             assert n == gw.driver_clock.long_visits[p]
             assert n * LONG_VISIT_S <= secs <= both[p + "/wall"] + 1e-9
         assert both["idle-wait/" + LONG_FAMILIES[0]] >= 1   # the 0.1 s above
-        gw.shutdown(drain=True, timeout=60)
 
     def test_monotonic_through_an_engine_rebuild(self, model):
         plan = FaultPlan().at_step(4, "fatal").at_step(9, "transient")

@@ -331,7 +331,6 @@ def test_the_shares_add_up():
 SWITCHES = {
     "quantize_weights": dict(quantize_weights=True),
     "tp > 1": dict(tp=2),
-    "fused_tick": dict(fused_tick=True),
     "decode_ticks > 1": dict(decode_ticks=4),
     "spec_decode": dict(spec_decode=True),
     "decode_chunk > 1": dict(decode_chunk=8),

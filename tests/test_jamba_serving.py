@@ -334,19 +334,15 @@ def test_metrics_carry_the_store_and_the_pool(model):
         assert name in text, name
 
 
-SWITCHES = (dict(quantize_weights=True), dict(tp=2), dict(fused_tick=True),
-            dict(decode_ticks=4), dict(spec_decode=True),
-            dict(decode_chunk=4), dict(prefix_cache=True),
-            dict(kv_dtype="int8"))
-
-
-@pytest.mark.parametrize("switch", SWITCHES, ids=lambda s: next(iter(s)))
+@pytest.mark.parametrize("switch", serving_support.OTHER_SWITCHES,
+                         ids=lambda s: next(iter(s)))
 def test_every_other_switch_raises_by_name(switch, model):
     geometry = {**GEOMETRY, **switch}
     # (one KV head: tensor parallelism is refused before the tree is read)
     match = "num_key_value_heads" if "tp" in switch else "mamba_layers"
-    with pytest.raises(ValueError, match=match):
+    with pytest.raises(ValueError, match=match) as e:
         serving_support.engine_as_given(model, **geometry)
+    assert all(name in str(e.value) for name in switch)
 
 
 def test_dispatch_args_count_the_two_kinds_of_layer(model):

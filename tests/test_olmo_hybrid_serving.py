@@ -352,7 +352,6 @@ SWITCHES = {
     "spec_decode": dict(spec_decode=True),
     "decode_ticks": dict(decode_ticks=4),
     "decode_chunk": dict(decode_chunk=4),
-    "fused_tick": dict(fused_tick=True),
     "quantize_weights": dict(quantize_weights=True),
 }
 

@@ -243,7 +243,7 @@ def test_stream_invariant_under_policy(knob, llama):
 
 
 # ------------------------------------------------------- the surface is shut
-@pytest.mark.parametrize("name", ["paged_attn", "ragged_step"])
+@pytest.mark.parametrize("name", ["paged_attn", "ragged_step", "fused_tick"])
 def test_removed_switches_are_gone(name, capsys):
     from paddle_tpu.serving.fleet import EngineFleet
     from paddle_tpu.serving.server import serve, serve_fleet
@@ -251,8 +251,9 @@ def test_removed_switches_are_gone(name, capsys):
     for fn in (ContinuousBatchingEngine.__init__, serve, serve_fleet,
                EngineFleet.__init__):
         assert name not in inspect.signature(fn).parameters, fn
-    flag = "--no-" + name.replace("_", "-")
-    with pytest.raises(SystemExit) as e:
-        main(["--preset", "tiny", flag])
-    assert e.value.code == 2
-    assert "unrecognized arguments: " + flag in capsys.readouterr().err
+    for flag in ("--" + name.replace("_", "-"),
+                 "--no-" + name.replace("_", "-")):
+        with pytest.raises(SystemExit) as e:
+            main(["--preset", "tiny", flag])
+        assert e.value.code == 2
+        assert "unrecognized arguments: " + flag in capsys.readouterr().err

@@ -343,8 +343,8 @@ class TestTPValidation:
                             collective_dtype="int8", start=False)
         (geom,) = set(jits) - before
         # tail of the geometry tuple: (tp, collective_dtype,
-        # fused_tick, collective_overlap)
-        assert geom[-4:] == (2, "int8", False, False)
+        # collective_overlap)
+        assert geom[-3:] == (2, "int8", False)
         assert fleet.replicas[0].gateway.engine.tp == 2
         fleet.shutdown(drain=False, timeout=5)
 
